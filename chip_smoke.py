@@ -1,0 +1,803 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the serve path still starts on the chip.
+
+One command, run from the root of a checkout on a machine with a TPU:
+
+    python3 chip_smoke.py            # Qwen2.5-7B widths, int8, one stage
+
+It drives the system's main path once, through the entry points a user
+calls: a ``convert``-format shard store written by the product's own
+streaming writer from a seeded random tensor source, then
+``python -m llm_sharding_tpu serve`` (PipelineEngine → PipelineServer over
+the paged arena with the Pallas kernels → HTTP ingress) answering a few
+``POST /v1/completions`` requests, then SIGTERM → "drained; exiting 0".
+Before the daemon, every ``pallas_call`` the CLI can select is compiled by
+Mosaic and compared with the XLA path at the daemon's geometry.
+
+A chip belongs to one process at a time, so THIS process never imports jax
+(nor anything that does): it runs the store writer as a child on
+``JAX_PLATFORMS=cpu`` and, one after the other, the two children that hold
+the chip. It exits non-zero — and prints no result line — unless the device
+did the work: the children must report ``platform == "tpu"``, every request
+must come back 200 with the asked number of tokens, nothing may have failed,
+health must be SERVING, and ``/metrics`` must show the paged decode and
+prefill KERNELS dispatched with blocks read through both. On success the
+last line of stdout is one JSON object:
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+Everything it writes (the store, child logs, ``result.json``) lands in
+``.smoke/`` inside the checkout, which ``.gitignore`` lists. The four-chip
+forms are the same script: ``--stages 4``, ``--weights bf16``,
+``--data-parallel 2 --stages 2``. ``--layers N`` cuts depth, never width.
+"""
+
+from __future__ import annotations
+
+import argparse
+import concurrent.futures
+import http.client
+import json
+import os
+import resource
+import shutil
+import signal
+import socket
+import subprocess
+import sys
+import time
+import zlib
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+WORK = os.path.join(HERE, ".smoke")
+
+#: The full-size smoke. ``serve`` is the daemon's geometry; the kernel
+#: check derives its shapes from the same numbers, so what it compiles is
+#: what the daemon dispatches. Block size 32 is kernel-eligible for the
+#: 2-byte AND the 1-byte arena; capacity 2048 leaves room for a prompt
+#: several prefill chunks long plus a radix-hit suffix that still needs
+#: chunking.
+FULL = {
+    "preset": "qwen25_7b",
+    "overrides": {},
+    "seed": 20260926,
+    "dtype": "bf16",
+    "quantize": True,  # int8 layer weights, bf16 vocab tables
+    "serve": {
+        "capacity": 2048,
+        "batch_per_slot": 4,
+        "kv_block_size": 32,
+        "kv_blocks": 513,
+        "prefill_chunk": 128,
+    },
+    "max_tokens": 16,
+    "short_prompt": 24,  # < one block: an exact resubmit cannot radix-hit
+    "long_prompt": 500,  # bucket 512 = four prefill chunks
+    "shared_prefix": 384,  # whole blocks of the long prompt, resubmitted
+    "shared_suffix": 200,  # suffix bucket 256 > chunk: chunked from offset
+}
+
+#: max |kernel - XLA| on unit-variance inputs with bf16 outputs. The two
+#: paths round in different places (the kernel keeps a running softmax in
+#: f32 and casts p to bf16 per block); ~1.6e-2 was normal for the flash
+#: kernel at S=C=2048.
+KERNEL_TOL = 2.5e-2
+
+
+# --------------------------------------------------------------- children
+# Everything below this line that imports jax or llm_sharding_tpu runs in a
+# child process (``--child``) or under pytest — never in the smoke's parent.
+
+
+def model_config(spec: dict):
+    import dataclasses
+
+    from llm_sharding_tpu.models import config as config_mod
+
+    cfg = getattr(config_mod, spec["preset"])()
+    return dataclasses.replace(cfg, **spec["overrides"])
+
+
+def tensor_source(cfg, seed: int):
+    """A seeded stand-in for a checkpoint: HF tensor name → float32 array,
+    made on demand, one tensor at a time (what ``save_shards_streaming``
+    asks of a safetensors reader). Every tensor has its own stream, keyed
+    by its name, so the store is the same whatever order it is read in.
+    Values are uniform with the usual 0.02 standard deviation (numpy draws
+    uniforms ~9x faster than normals, and 7.6 G of them are drawn); a
+    projection comes as the transpose of a row-major array, the layout the
+    converter's own ``.T`` turns back into a contiguous one."""
+    import numpy as np
+
+    H, F, V = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
+    q_out = cfg.num_attention_heads * cfg.head_dim_
+    kv_out = cfg.num_key_value_heads * cfg.head_dim_
+    shapes = {
+        "self_attn.q_proj.weight": (q_out, H),
+        "self_attn.k_proj.weight": (kv_out, H),
+        "self_attn.v_proj.weight": (kv_out, H),
+        "self_attn.o_proj.weight": (H, q_out),
+        "mlp.gate_proj.weight": (F, H),
+        "mlp.up_proj.weight": (F, H),
+        "mlp.down_proj.weight": (H, F),
+        "input_layernorm.weight": (H,),
+        "post_attention_layernorm.weight": (H,),
+    }
+    if cfg.attention_bias:  # the qwen2 family biases q/k/v, not o
+        shapes.update({
+            "self_attn.q_proj.bias": (q_out,),
+            "self_attn.k_proj.bias": (kv_out,),
+            "self_attn.v_proj.bias": (kv_out,),
+        })
+    top = {
+        "model.embed_tokens.weight": (V, H),
+        "model.norm.weight": (H,),
+        "lm_head.weight": (V, H),
+    }
+
+    def get(name: str):
+        if name in top:
+            shape = top[name]
+        else:
+            shape = shapes.get(name.split(".", 3)[-1]) if name.startswith(
+                "model.layers."
+            ) else None
+            if shape is None:
+                raise KeyError(name)
+        rng = np.random.default_rng([seed, zlib.crc32(name.encode())])
+        w = rng.random(shape[::-1], dtype=np.float32).T
+        w -= 0.5
+        if name.endswith("norm.weight"):  # RMSNorm gains sit around one
+            w *= 0.2
+            w += 1.0
+        else:
+            w *= 0.02 * 12 ** 0.5  # U(-a, a) with std 0.02
+        return w
+
+    return get
+
+
+def write_store(spec: dict, out_dir: str) -> dict:
+    """Write the smoke's shard store with the product's streaming writer.
+    A store already there with the same spec is kept (the second smoke of
+    one chip call measures a warm start, not a second write)."""
+    import jax.numpy as jnp
+
+    from llm_sharding_tpu.utils.shard_store import save_shards_streaming
+
+    marker = os.path.join(out_dir, "smoke_spec.json")
+    want = {k: spec[k] for k in ("preset", "overrides", "seed", "dtype",
+                                 "quantize")}
+    t0 = time.time()
+    reused = False
+    if os.path.exists(marker):
+        with open(marker) as f:
+            reused = json.load(f) == want
+    if not reused:
+        shutil.rmtree(out_dir, ignore_errors=True)
+        cfg = model_config(spec)
+        save_shards_streaming(
+            cfg, tensor_source(cfg, spec["seed"]), out_dir,
+            dtype={"bf16": jnp.bfloat16, "f32": jnp.float32}[spec["dtype"]],
+            quantize=spec["quantize"],
+        )
+        with open(marker, "w") as f:
+            json.dump(want, f)
+    return {
+        "reused": reused,
+        "seconds": round(time.time() - t0, 1),
+        "bytes": store_bytes(out_dir),
+    }
+
+
+def kernel_cases(spec: dict) -> list[dict]:
+    """Every pallas_call the CLI can select, at the daemon's geometry: the
+    paged decode kernel (S = 1) and the chunked-prefill kernel (S = the
+    prefill chunk) over bf16, int8 and fp8 arenas, and the flash kernel the
+    one-shot admission uses (S = the short prompt's bucket over the full
+    window)."""
+    sv = spec["serve"]
+    cases = [
+        {"kernel": kern, "kv_dtype": kvd}
+        for kern in ("paged_decode", "paged_prefill")
+        for kvd in ("bf16", "int8", "fp8")
+    ]
+    cases.append({"kernel": "flash", "kv_dtype": "bf16"})
+    bucket = 8
+    while bucket < spec["short_prompt"]:
+        bucket *= 2
+    for c in cases:
+        c.update(
+            rows=sv["batch_per_slot"], block_size=sv["kv_block_size"],
+            table_width=-(-sv["capacity"] // sv["kv_block_size"]),
+            q_len={"paged_decode": 1, "paged_prefill": sv["prefill_chunk"],
+                   "flash": bucket}[c["kernel"]],
+        )
+    return cases
+
+
+def check_kernel(cfg, case: dict, backend: str, seed: int = 0) -> float:
+    """Run one kernel variant (``backend`` = "kernel" on the chip,
+    "interpret" under pytest) and the XLA path on the same random arena;
+    return max |difference|."""
+    import numpy as np
+    import jax.numpy as jnp
+
+    import llm_sharding_tpu.models  # noqa: F401 — ops import through models
+    from llm_sharding_tpu.models.cache import POS_SENTINEL
+    from llm_sharding_tpu.ops import attention, flash_attention
+    from llm_sharding_tpu.ops import paged_attention as pa
+    from llm_sharding_tpu.ops.quant import kv_qmax, kv_storage_dtype
+
+    rng = np.random.default_rng([seed, zlib.crc32(json.dumps(
+        case, sort_keys=True).encode())])
+    B, BS, T, S = (case["rows"], case["block_size"], case["table_width"],
+                   case["q_len"])
+    Nh, Nkv, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                  cfg.head_dim_)
+    W = T * BS
+    dt = jnp.bfloat16
+    # ragged rows: each has attended context behind its S query positions
+    ctx = rng.integers(W // 4, W - S, B)  # tokens already in the window
+    qpos = (ctx[:, None] + np.arange(S)[None]).astype(np.int32)
+    cols = np.arange(W)[None]
+    kvpos = np.where(cols < (ctx + S)[:, None], cols, int(POS_SENTINEL))
+    q = jnp.asarray(rng.standard_normal((B, S, Nh, D), np.float32), dt)
+
+    if case["kernel"] == "flash":
+        k = jnp.asarray(rng.standard_normal((B, W, Nkv, D), np.float32), dt)
+        v = jnp.asarray(rng.standard_normal((B, W, Nkv, D), np.float32), dt)
+        args = (q, k, v, jnp.asarray(qpos), jnp.asarray(kvpos, jnp.int32))
+        got = flash_attention.flash_attention(
+            *args, interpret=(backend == "interpret")
+        )
+        want = attention.cached_attention(*args)
+    else:
+        nlive = -(-(ctx + S) // BS)  # blocks covering the written frontier
+        NB = int(nlive.sum()) + 1  # + the trash block 0
+        ids = rng.permutation(np.arange(1, NB))
+        table = np.zeros((B, T), np.int32)
+        at = 0
+        for b in range(B):
+            table[b, : nlive[b]] = ids[at: at + nlive[b]]
+            at += nlive[b]
+        store = kv_storage_dtype(case["kv_dtype"], dt)
+        vals = rng.standard_normal((2, NB, BS, Nkv, D), np.float32)
+        scales = {}
+        if case["kv_dtype"] == "bf16":
+            k_arena, v_arena = (jnp.asarray(a, store) for a in vals)
+        else:  # codes spanning the code range + per-block-per-head scales
+            qmax = kv_qmax(store)
+            codes = np.clip(vals * (qmax / 3.0), -qmax, qmax)
+            if case["kv_dtype"] == "int8":
+                codes = np.round(codes)
+            k_arena, v_arena = (jnp.asarray(a, store) for a in codes)
+            sc = rng.uniform(0.5, 1.5, (2, NB, Nkv)) * (3.0 / qmax)
+            scales = {"k_scale": jnp.asarray(sc[0], jnp.float32),
+                      "v_scale": jnp.asarray(sc[1], jnp.float32)}
+        args = (q, k_arena, v_arena, jnp.asarray(table), jnp.asarray(qpos),
+                jnp.asarray(kvpos, jnp.int32))
+        if case["kernel"] == "paged_decode":
+            got = pa.paged_attention(*args, backend=backend, **scales)
+        else:
+            got = pa.paged_prefill(
+                *args, backend=backend, nlive=jnp.asarray(nlive, jnp.int32),
+                **scales,
+            )
+        want = pa.paged_attention_xla(*args, **scales)
+    got = np.asarray(got.astype(jnp.float32))
+    if not np.isfinite(got).all():
+        raise AssertionError(f"{case}: non-finite kernel output")
+    return float(np.abs(got - np.asarray(want.astype(jnp.float32))).max())
+
+
+def require_tpu(platform: str, who: str) -> None:
+    if platform != "tpu":
+        raise SystemExit(
+            f"chip_smoke: {who} found no TPU: jax reports platform "
+            f"{platform!r}. The smoke measures the serve path on the chip "
+            "and never falls back to another backend."
+        )
+
+
+def child_kernels(spec: dict, out_path: str) -> None:
+    import jax
+
+    from llm_sharding_tpu.utils.compile_cache import enable_persistent_cache
+    from llm_sharding_tpu.utils.device_report import device_report
+
+    platform = jax.devices()[0].platform
+    require_tpu(platform, "the kernel check")
+    enable_persistent_cache(platform)
+    cfg = model_config(spec)
+    results = []
+    for case in kernel_cases(spec):
+        err = check_kernel(cfg, case, "kernel")
+        results.append({**case, "max_err": err, "ok": err <= KERNEL_TOL})
+        print(f"[kernels] {case['kernel']:13s} {case['kv_dtype']:4s} "
+              f"S={case['q_len']:<4d} max|err|={err:.3e} "
+              f"{'ok' if err <= KERNEL_TOL else 'OVER ' + str(KERNEL_TOL)}",
+              flush=True)
+    with open(out_path, "w") as f:
+        json.dump({"device": device_report(), "kernels": results}, f)
+    if not all(r["ok"] for r in results):
+        raise SystemExit("chip_smoke: a kernel disagrees with the XLA path")
+
+
+def child_store(spec: dict, out_path: str) -> None:
+    with open(out_path, "w") as f:
+        json.dump(write_store(spec, os.path.join(WORK, "store")), f)
+
+
+# ----------------------------------------------------------------- parent
+# stdlib only from here on.
+
+
+def store_bytes(store: str) -> dict:
+    """On-disk bytes of a store by unit kind — uncompressed npz, so also
+    what each unit weighs on a device. A unit may span several files
+    (``block_3.npz``, ``block_3.part1.npz``, ...): the writer keeps every
+    file under ``shard_store.MAX_FILE_BYTES``."""
+    units: dict[str, int] = {}
+    largest = 0
+    for name in os.listdir(store):
+        if not name.endswith(".npz"):
+            continue
+        size = os.path.getsize(os.path.join(store, name))
+        unit = name.split(".", 1)[0]
+        units[unit] = units.get(unit, 0) + size
+        largest = max(largest, size)
+    blocks = [n for u, n in units.items() if u.startswith("block_")]
+    return {"block": max(blocks), "blocks": len(blocks),
+            "head": sum(units.values()) - sum(blocks),
+            "largest_file": largest}
+
+
+def free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+def tail(path: str, n: int = 60) -> str:
+    with open(path, errors="replace") as f:
+        return "".join(f.readlines()[-n:])
+
+
+def run_child(mode: str, spec: dict, env: dict, log_name: str) -> dict:
+    """Start ``chip_smoke.py --child MODE``; returns a handle ``wait_child``
+    turns into the child's JSON result."""
+    os.makedirs(os.path.join(WORK, "logs"), exist_ok=True)
+    out_path = os.path.join(WORK, f"{mode}.json")
+    if os.path.exists(out_path):
+        os.remove(out_path)
+    log_path = os.path.join(WORK, "logs", log_name)
+    log = open(log_path, "w")
+    proc = subprocess.Popen(
+        [sys.executable, os.path.abspath(__file__), "--child", mode,
+         "--spec", json.dumps(spec), "--out", out_path],
+        stdout=log, stderr=subprocess.STDOUT, env=env, cwd=HERE,
+    )
+    return {"proc": proc, "log": log, "log_path": log_path,
+            "out_path": out_path, "mode": mode, "t0": time.time()}
+
+
+def wait_child(h: dict) -> dict:
+    rc = h["proc"].wait()
+    h["log"].close()
+    if rc != 0:
+        raise SystemExit(
+            f"chip_smoke: the {h['mode']} child exited {rc}:\n"
+            + tail(h["log_path"])
+        )
+    with open(h["out_path"]) as f:
+        res = json.load(f)
+    res["wall_s"] = round(time.time() - h["t0"], 1)
+    return res
+
+
+def metric(text: str, name: str, **labels) -> float:
+    """Sum of the series of ``name`` whose labels include ``labels`` in a
+    Prometheus text page (0.0 when there is none)."""
+    total = 0.0
+    for line in text.splitlines():
+        if not line.startswith(name) or line[len(name):len(name) + 1] not in (
+            "{", " "
+        ):
+            continue
+        head, _, value = line.rpartition(" ")
+        if all(f'{k}="{v}"' in head for k, v in labels.items()):
+            total += float(value)
+    return total
+
+
+class Daemon:
+    """``python -m llm_sharding_tpu serve`` as a child with the chip: stdin
+    held open (the daemon exits on stdin EOF), stderr to a log, stopped on
+    the way out whatever happened."""
+
+    def __init__(self, store: str, serve_args: list[str], env: dict):
+        self.http_port, self.metrics_port = free_port(), free_port()
+        os.makedirs(os.path.join(WORK, "logs"), exist_ok=True)
+        self.log_path = os.path.join(WORK, "logs", "daemon.log")
+        self._log = open(self.log_path, "w")
+        self.proc = subprocess.Popen(
+            [sys.executable, "-m", "llm_sharding_tpu", "serve", store,
+             *serve_args, "--http-port", str(self.http_port),
+             "--metrics-port", str(self.metrics_port)],
+            stdin=subprocess.PIPE, stdout=self._log, stderr=self._log,
+            env=env, cwd=HERE,
+        )
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        if self.proc.poll() is None:
+            self.proc.kill()
+            self.proc.wait()
+        self._log.close()
+
+    def _request(self, port, method, path, body=None, timeout=900.0):
+        conn = http.client.HTTPConnection("127.0.0.1", port, timeout=timeout)
+        try:
+            conn.request(
+                method, path,
+                body=None if body is None else json.dumps(body),
+                headers={"Content-Type": "application/json"},
+            )
+            resp = conn.getresponse()
+            return resp.status, resp.read().decode()
+        finally:
+            conn.close()
+
+    def wait_ready(self, timeout_s: float) -> None:
+        deadline = time.time() + timeout_s
+        while time.time() < deadline:
+            if self.proc.poll() is not None:
+                raise SystemExit(
+                    f"chip_smoke: the daemon exited {self.proc.returncode} "
+                    "before serving:\n" + tail(self.log_path)
+                )
+            try:
+                status, _ = self._request(
+                    self.http_port, "GET", "/healthz", timeout=2.0
+                )
+                if status == 200:
+                    return
+            except OSError:
+                pass  # not listening yet: the model is still loading
+            time.sleep(0.5)
+        raise SystemExit("chip_smoke: the daemon never became ready:\n"
+                         + tail(self.log_path))
+
+    def complete(self, prompt: list[int], max_tokens: int, **knobs) -> list:
+        """One ``POST /v1/completions``; returns the generated ids. Fails
+        unless it is a 200 with exactly ``max_tokens`` tokens."""
+        body = {"prompt": prompt, "max_tokens": max_tokens, **knobs}
+        status, text = self._request(
+            self.http_port, "POST", "/v1/completions", body
+        )
+        if status != 200:
+            raise SystemExit(f"chip_smoke: POST answered {status}: {text[:400]}")
+        if knobs.get("stream"):
+            events = [json.loads(l[6:]) for l in text.splitlines()
+                      if l.startswith("data: ") and l != "data: [DONE]"]
+            if not text.rstrip().endswith("data: [DONE]"):
+                raise SystemExit("chip_smoke: stream ended without [DONE]")
+            ids = [t for e in events for t in e["choices"][0]["token_ids"]]
+            reason = events[-1]["choices"][0]["finish_reason"]
+        else:
+            choice = json.loads(text)["choices"][0]
+            ids, reason = choice["token_ids"], choice["finish_reason"]
+        if len(ids) != max_tokens:
+            raise SystemExit(
+                f"chip_smoke: asked {max_tokens} tokens, got {len(ids)} "
+                f"(finish_reason {reason!r}) for a {len(prompt)}-token prompt"
+            )
+        return ids
+
+    def metrics(self) -> str:
+        return self._request(self.metrics_port, "GET", "/metrics")[1]
+
+    def statz(self) -> dict:
+        return json.loads(self._request(self.metrics_port, "GET", "/statz")[1])
+
+    def healthz(self) -> int:
+        return self._request(self.metrics_port, "GET", "/healthz")[0]
+
+    def drain(self, timeout_s: float = 180.0) -> None:
+        """SIGTERM → the daemon finishes what is in flight and exits 0."""
+        self.proc.send_signal(signal.SIGTERM)
+        rc = self.proc.wait(timeout=timeout_s)
+        self._log.flush()
+        with open(self.log_path, errors="replace") as f:
+            drained = "drained; exiting 0" in f.read()
+        if rc != 0 or not drained:
+            raise SystemExit(
+                f"chip_smoke: the daemon exited {rc} on SIGTERM without a "
+                "clean drain:\n" + tail(self.log_path, 40)
+            )
+
+
+def prompts(spec: dict, vocab: int) -> dict:
+    """Token-id prompts from the spec's seed (a plain LCG: the parent has
+    no numpy). Ids stay below ``vocab`` and clear of the low special ids."""
+    state = spec["seed"]
+
+    def ids(n):
+        nonlocal state
+        out = []
+        for _ in range(n):
+            state = (state * 6364136223846793005 + 1442695040888963407) % 2**64
+            out.append(10 + (state >> 33) % (vocab - 10))
+        return out
+
+    long = ids(spec["long_prompt"])
+    return {
+        "short": ids(spec["short_prompt"]),
+        "long": long,
+        "shared": long[: spec["shared_prefix"]] + ids(spec["shared_suffix"]),
+        "burst": [ids(spec["short_prompt"]) for _ in range(3)],
+        "stream": ids(spec["short_prompt"]),
+        "sampled": ids(spec["short_prompt"]),
+    }
+
+
+def drive(d: Daemon, spec: dict, vocab: int, routed: bool = False) -> dict:
+    """The smoke's traffic. Returns what came back plus phase times; any
+    request that is not a 200 with the asked tokens ends the run.
+
+    ``routed`` (a replica router in front): the shared-prefix hit is
+    reported, not required. The router's cluster index knows a cached
+    prompt only at its node boundary, so a prefix that ends mid-node is
+    invisible to it and the request goes by load — to the replica that
+    holds the prefix or to the other (PERF.md, PR 21)."""
+    p = prompts(spec, vocab)
+    n = spec["max_tokens"]
+    sent = 0
+    t0 = time.time()
+    first = d.complete(p["short"], n)  # compiles admit + decode programs
+    t_first = time.time() - t0
+    again = d.complete(p["short"], n)
+    sent += 2
+    if first != again:
+        raise SystemExit(
+            f"chip_smoke: the same greedy prompt gave different ids:\n"
+            f"{first}\n{again}"
+        )
+    # a prompt several prefill chunks long; others join while it runs
+    with concurrent.futures.ThreadPoolExecutor(8) as pool:
+        long_f = pool.submit(d.complete, p["long"], n)
+        time.sleep(0.2)
+        futs = [pool.submit(d.complete, q, n) for q in p["burst"]]
+        futs.append(pool.submit(d.complete, p["stream"], n, stream=True))
+        long_ids = long_f.result()
+        burst = [f.result() for f in futs]
+    sent += 1 + len(burst)
+    # the long prompt's leading blocks, resubmitted under a new suffix
+    hit0 = metric(d.metrics(), "server_prefix_cache_hit_tokens_total")
+    shared = d.complete(p["shared"], n)
+    hit1 = metric(d.metrics(), "server_prefix_cache_hit_tokens_total")
+    sent += 1
+    if not routed and hit1 - hit0 < spec["shared_prefix"]:
+        raise SystemExit(
+            f"chip_smoke: a resubmitted {spec['shared_prefix']}-token prefix "
+            f"raised the hit-token counter by {hit1 - hit0}"
+        )
+    # sampling with a seed is reproducible (top-k/top-p: the full-vocab sort)
+    knobs = dict(temperature=0.8, top_k=50, top_p=0.9, seed=7)
+    s1 = d.complete(p["sampled"], n, **knobs)
+    s2 = d.complete(p["sampled"], n, **knobs)
+    sent += 2
+    if s1 != s2:
+        raise SystemExit(
+            f"chip_smoke: one seed sampled two completions:\n{s1}\n{s2}"
+        )
+    return {
+        "sent": sent, "first_request_s": round(t_first, 1),
+        "rest_s": round(time.time() - t0 - t_first, 1),
+        "prefix_hit_tokens": hit1 - hit0,
+        "ids": {"greedy": first, "long": long_ids, "burst": burst,
+                "shared": shared, "sampled": s1},
+    }
+
+
+def check_served(d: Daemon, sent: int, expect: dict) -> dict:
+    """The assertions that make a pass mean the device did the work."""
+    st, text = d.statz(), d.metrics()
+    dev = st["device"]
+    failures = []
+    if dev["platform"] != expect["platform"]:
+        failures.append(f"the daemon ran on {dev['platform']!r}")
+    c = st["counters"]
+    if c["requests_failed"] or c["requests_completed"] != sent:
+        failures.append(f"requests: {c} (sent {sent})")
+    if d.healthz() != 200:
+        failures.append("/healthz is not SERVING")
+    impl = {b: metric(text, "server_attn_backend", backend=b)
+            for b in ("kernel", "interpret", "xla", "dense")}
+    if impl[expect["attn_backend"]] < 1 or sum(impl.values()) != impl[
+        expect["attn_backend"]
+    ]:
+        failures.append(f"decode attention resolved to {impl}")
+    if metric(text, "server_prefill_path", path="kernel") != 1:
+        failures.append("chunked prefill did not dispatch the kernel")
+    blocks = {k: metric(text, f"server_{k}_blocks_read_total")
+              for k in ("attn", "prefill")}
+    if min(blocks.values()) <= 0:
+        failures.append(f"blocks read through the kernels: {blocks}")
+    if failures:
+        raise SystemExit("chip_smoke: " + "; ".join(failures))
+    return {
+        "device": dev, "counters": c, "blocks_read": blocks,
+        "arena_bytes": metric(text, "server_arena_bytes"),
+    }
+
+
+def check_memory(dev: dict, sizes: dict, arena_bytes: float, stages: int,
+                 replicas: int, slack: float) -> list[float]:
+    """No device may ever have held more than its own stage's layers, its
+    head slice and its arena share (plus ``slack`` for activations and
+    compiler scratch): the weights went disk → host → their stage's chip.
+    Returns the peak GiB of each device in use."""
+    busy = [m for m in dev["memory"] if m["bytes_in_use"] > 0]
+    if len(busy) != stages * replicas:
+        raise SystemExit(
+            f"chip_smoke: {len(busy)} devices hold data; {stages} stages x "
+            f"{replicas} replicas were asked for"
+        )
+    layers = -(-sizes["blocks"] // stages)
+    share = (layers * sizes["block"] + sizes["head"] / stages
+             + arena_bytes / len(busy))
+    peaks = [m["peak_bytes_in_use"] for m in busy]
+    if max(peaks) > share + slack:
+        raise SystemExit(
+            f"chip_smoke: a device peaked at {max(peaks) / 2**30:.2f} GiB; "
+            f"its stage's share is {share / 2**30:.2f} GiB"
+        )
+    if min(m["bytes_in_use"] for m in busy) < 0.5 * layers * sizes["block"]:
+        raise SystemExit(
+            "chip_smoke: a device holds less than half its stage's layers: "
+            f"{[m['bytes_in_use'] for m in busy]}"
+        )
+    return [round(p / 2**30, 2) for p in peaks]
+
+
+def serve_args(spec: dict, stages: int, data_parallel: int) -> list[str]:
+    sv = spec["serve"]
+    args = [
+        # explicit: with no --stages the engine takes one stage per visible
+        # device, and a four-chip host would silently serve pp4
+        "--stages", str(stages), "--dtype", spec["dtype"],
+        "--capacity", str(sv["capacity"]),
+        "--batch-per-slot", str(sv["batch_per_slot"]),
+        "--kv-block-size", str(sv["kv_block_size"]),
+        "--kv-blocks", str(sv["kv_blocks"]),
+        "--prefill-chunk", str(sv["prefill_chunk"]),
+        "--prefix-cache", "hbm",
+    ]
+    if data_parallel > 1:
+        args += ["--data-parallel", str(data_parallel)]
+    return args
+
+
+def cache_entries(path) -> int:
+    if not path or not os.path.isdir(path):
+        return 0
+    return sum(len(files) for _, _, files in os.walk(path))
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--stages", type=int, default=1)
+    ap.add_argument("--data-parallel", type=int, default=1)
+    ap.add_argument("--weights", choices=("int8", "bf16"), default="int8")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut depth (never width)")
+    ap.add_argument("--child", choices=("kernels", "store"))
+    ap.add_argument("--spec")
+    ap.add_argument("--out")
+    args = ap.parse_args(argv)
+    if args.child:
+        spec = json.loads(args.spec)
+        {"kernels": child_kernels, "store": child_store}[args.child](
+            spec, args.out
+        )
+        return 0
+
+    spec = json.loads(json.dumps(FULL))
+    spec["quantize"] = args.weights == "int8"
+    if args.layers:
+        spec["overrides"]["num_hidden_layers"] = args.layers
+    t_start = time.time()
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [HERE] + [p for p in env.get("PYTHONPATH", "").split(os.pathsep) if p]
+    )
+    cpu_env = dict(env, JAX_PLATFORMS="cpu")
+    cpu_env.pop("JAX_COMPILATION_CACHE_DIR", None)  # no XLA:CPU cache
+
+    # the store is written on the CPU while the kernel check holds the chip
+    store_h = run_child("store", spec, cpu_env, "store.log")
+    try:
+        kern = wait_child(run_child("kernels", spec, env, "kernels.log"))
+        store = wait_child(store_h)
+    finally:
+        if store_h["proc"].poll() is None:
+            store_h["proc"].kill()
+            store_h["proc"].wait()
+    for r in kern["kernels"]:
+        print(f"kernel {r['kernel']:13s} {r['kv_dtype']:4s} S={r['q_len']:<4d}"
+              f" compiled by Mosaic, max|err| vs XLA {r['max_err']:.2e}")
+
+    with open(os.path.join(WORK, "store", "config.json")) as f:
+        vocab = json.load(f)["vocab_size"]
+    t_load = time.time()
+    with Daemon(os.path.join(WORK, "store"),
+                serve_args(spec, args.stages, args.data_parallel), env) as d:
+        d.wait_ready(900.0)
+        load_s = round(time.time() - t_load, 1)
+        loaded = d.statz()["device"]
+        entries0 = cache_entries(loaded["compile_cache_dir"])
+        traffic = drive(d, spec, vocab, routed=args.data_parallel > 1)
+        served = check_served(
+            d, traffic["sent"], {"platform": "tpu", "attn_backend": "kernel"}
+        )
+        d.drain()
+    dev = served["device"]
+    if (dev["platform"], dev["kind"]) != (
+        kern["device"]["platform"], kern["device"]["kind"]
+    ):
+        raise SystemExit("chip_smoke: the two chip children disagree on "
+                         f"the device: {kern['device']} vs {dev}")
+    mem = (store["bytes"], served["arena_bytes"], args.stages,
+           args.data_parallel)
+    after_load = check_memory(loaded, *mem, slack=2**30)
+    peaks = check_memory(dev, *mem, slack=3 * 2**30)
+    report = {
+        "device": {k: dev[k] for k in ("platform", "kind", "count")},
+        "versions": {k: dev[k] for k in ("jax", "jaxlib", "libtpu")},
+        "model": {**{k: spec[k] for k in ("preset", "overrides")},
+                  "weights": args.weights, "stages": args.stages,
+                  "data_parallel": args.data_parallel},
+        "attn_impl": "kernel",  # check_served passed: nothing else is live
+        "kernels": kern["kernels"],
+        "requests": {"sent": traffic["sent"],
+                     "succeeded": served["counters"]["requests_completed"],
+                     "failed": served["counters"]["requests_failed"]},
+        "blocks_read": served["blocks_read"],
+        "prefix_hit_tokens": traffic["prefix_hit_tokens"],
+        # the writer's per-file cap at work, beside the limit this machine
+        # puts on a file (-1 = none)
+        "store": {**store["bytes"],
+                  "rlimit_fsize": resource.getrlimit(resource.RLIMIT_FSIZE)[0]},
+        "peak_gib_after_load": after_load,
+        "peak_gib": peaks,
+        "compile_cache": {
+            "dir": dev["compile_cache_dir"], "entries_before": entries0,
+            "entries_after": cache_entries(dev["compile_cache_dir"]),
+        },
+        "wall_s": {
+            "store_write": store["seconds"], "store_reused": store["reused"],
+            "kernel_check": kern["wall_s"], "load": load_s,
+            "first_request": traffic["first_request_s"],
+            "rest": traffic["rest_s"],
+            "total": round(time.time() - t_start, 1),
+        },
+        "ids": traffic["ids"],
+    }
+    with open(os.path.join(WORK, "result.json"), "w") as f:
+        json.dump(report, f, indent=1)
+    for k in ("device", "versions", "model", "attn_impl", "requests",
+              "blocks_read", "prefix_hit_tokens", "store",
+              "peak_gib_after_load",
+              "peak_gib", "compile_cache", "wall_s"):
+        print(f"{k}: {json.dumps(report[k])}")
+    print("daemon: drained; exiting 0")
+    print(json.dumps({"ok": True, "device": report["device"]}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -71,7 +71,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .attention import cached_attention
 from .quant import kv_dequantize, kv_qmax, kv_quantize
-from .. import _compat
 
 NEG_INF = -1e30  # python float: jnp constants can't be captured by kernels
 
@@ -120,14 +119,38 @@ def kernel_sublane(cache_dtype) -> int:
     return 32 // max(jnp.dtype(cache_dtype).itemsize, 1)
 
 
-def kernel_eligible(head_dim: int, block_size: int, cache_dtype) -> bool:
-    """Mosaic-layout eligibility of the real (non-interpret) kernel:
-    the (BS, D) block tiles as (sublane, 128) — D must be a lane multiple
-    and BS a sublane multiple for the CACHE dtype (``kernel_sublane``).
-    Shared by the trace-time dispatch below and the host-side
-    serve validation (``runtime/server.py``), so ``--paged-attn kernel``
-    fails loud at construction instead of as a Mosaic error mid-serve."""
-    return head_dim % 128 == 0 and block_size % kernel_sublane(cache_dtype) == 0
+#: Scalar-memory budget for the kernels' scalar-prefetched operands (the
+#: block table, plus the prefill kernel's ``nlive``). The v5e compiler
+#: reports 1 MiB of SMEM and lays an int32 ``[rows, T]`` table out with
+#: each row padded to 128 words: a ``[128, 2048]`` table "exceeded smem
+#: capacity by 1.2K", ``[120, 2048]`` and ``[2000, 33]`` compiled. 16 KiB
+#: is held back for ``nlive`` and the compiler's own scalars.
+SMEM_TABLE_BUDGET = (1 << 20) - (16 << 10)
+
+
+def kernel_eligible(
+    head_dim: int, block_size: int, cache_dtype, *, rows: int,
+    table_width: int,
+) -> bool:
+    """Mosaic eligibility of the real (non-interpret) kernels, as learned
+    from the v5e compiler:
+
+    - the (BS, D) block tiles as (sublane, 128) — D must be a lane
+      multiple and BS a sublane multiple for the CACHE dtype
+      (``kernel_sublane``);
+    - the ``[rows, table_width]`` block table (``rows`` = the rows one call
+      attends: a slot's ``batch_per_slot``) is scalar-prefetched whole and
+      must fit ``SMEM_TABLE_BUDGET``.
+
+    Shared by the trace-time dispatch below and the host-side serve
+    validation (``runtime/server.py``), so ``--paged-attn kernel`` fails
+    loud at construction instead of as a compiler error mid-serve."""
+    table_bytes = rows * (-(-table_width // 128) * 128) * 4
+    return (
+        head_dim % 128 == 0
+        and block_size % kernel_sublane(cache_dtype) == 0
+        and table_bytes <= SMEM_TABLE_BUDGET
+    )
 
 
 def gather_block_kv(
@@ -373,6 +396,15 @@ def combine_attn_stats(
     )
 
 
+def _scale_operand(scale: jnp.ndarray) -> jnp.ndarray:
+    """The kernels' view of a ``[NB, Nkv]`` scale arena: ``[NB, Nkv, 1, 1]``
+    f32, so one block's one head is a ``(1, 1, 1, 1)`` VMEM tile whose last
+    two dims ARE the array's. Mosaic refuses a ``(1, 1)`` block of the 2-D
+    array in any memory space (the last two block dims must be multiples of
+    (8, 128) or the whole array's)."""
+    return scale.astype(jnp.float32)[:, :, None, None]
+
+
 def _online_update(q, k, v, mask, scale, acc_ref, m_ref, l_ref):
     """One flash-attention recurrence step over a streamed KV tile: score
     the tile, fold it into the (acc, m, l) running softmax scratch. Shared
@@ -405,9 +437,9 @@ def _paged_kernel(
     tbl_ref,  # scalar-prefetch [B, T] (read by the index maps + trash gate)
     q_ref,  # [1, 1, GS, D]
     *rest,  # bps k refs [1, 1, BS, D] (the arena blocks the index maps
-    #   picked), bps v refs; quantized: bps ks refs + bps vs refs ((1, 1)
-    #   SMEM per-block-per-head scales); then the common refs — qpos
-    #   [1, GS, 1], kvpos [1, 1, bps*BS], out [1, 1, GS, D], scratch
+    #   picked), bps v refs; quantized: bps ks refs + bps vs refs ([1, 1,
+    #   1, 1] per-block-per-head scales); then the common refs — qpos
+    #   [1, GS, 1], kvpos [1, bps, 1, BS], out [1, 1, GS, D], scratch
     #   acc [GS, D] f32, m [GS, 128] f32, l [GS, 128] f32
     scale,
     t_steps,
@@ -429,7 +461,6 @@ def _paged_kernel(
         l_ref[:] = jnp.zeros_like(l_ref)
 
     q = q_ref[0, 0]  # [GS, D]
-    BS = k_refs[0].shape[2]
     # bps arena blocks stream per sequential step (auto_blocks_per_step):
     # each sub-block is its own DMA'd ref, so the compiler overlaps the
     # bps fetches and double-buffers them across steps; the recurrence
@@ -444,7 +475,7 @@ def _paged_kernel(
             # never exists in HBM. Dequant target is the query dtype,
             # matching the XLA gather path bit for bit.
             k_blk = (
-                k_blk.astype(jnp.float32) * ks_refs[j][0, 0]
+                k_blk.astype(jnp.float32) * ks_refs[j][0, 0]  # [1, 1]
             ).astype(q.dtype)
             v_blk = (
                 v_blk.astype(jnp.float32) * vs_refs[j][0, 0]
@@ -464,10 +495,11 @@ def _paged_kernel(
         # positions (trash-mapped slots, never-written block tails) mask
         # out here; an all-masked block leaves a NEG_INF running max that
         # the first real block's correction factor wipes (see the flash
-        # kernel's masking note).
-        mask = (
-            kvpos_ref[0, :, j * BS:(j + 1) * BS] <= qpos_ref[0]
-        )  # [GS, BS]
+        # kernel's masking note). kvpos is tiled per BLOCK ([1, BS] rows
+        # of a [B, T, 1, BS] view): a (1, 1, bps·BS) lane tile of the flat
+        # [B, 1, T·BS] array is one Mosaic refuses unless bps·BS is a
+        # multiple of 128 or the whole window.
+        mask = kvpos_ref[0, j] <= qpos_ref[0]  # [GS, BS]
         _online_update(q, k, v, mask, scale, acc_ref, m_ref, l_ref)
 
     @pl.when(t == t_steps - 1)
@@ -514,10 +546,10 @@ def paged_attention_tpu(
 
     Quantized arenas (``k_scale``/``v_scale``): the per-block DMA moves
     1-byte codes — HALF (int8 vs bf16) the per-step attention HBM traffic
-    — plus each block's (1, 1) per-head scale riding in SMEM, and the
-    dequant multiply runs in VMEM right before the score dot (the hook PR
-    6 left open). Int8 tiles want BS a multiple of 32 (1-byte sublane —
-    ``kernel_eligible``)."""
+    — plus each block's per-head scale as a one-element VMEM tile
+    (``_scale_operand``), and the dequant multiply runs in VMEM right
+    before the score dot (the hook PR 6 left open). Int8 tiles want BS a
+    multiple of 32 (1-byte sublane — ``kernel_eligible``)."""
     B, S, Nh, D = q.shape
     NB, BS, Nkv = k_arena.shape[0], k_arena.shape[1], k_arena.shape[2]
     T = block_table.shape[1]
@@ -542,12 +574,12 @@ def paged_attention_tpu(
     qp = jnp.tile(q_positions, (1, G))[..., None]  # [B, GS, 1]
     kh = jnp.transpose(k_arena, (0, 2, 1, 3))  # [NB, Nkv, BS, D]
     vh = jnp.transpose(v_arena, (0, 2, 1, 3))
-    kp = kv_positions[:, None, :]  # [B, 1, T*BS]
+    kp = kv_positions.reshape(B, T, 1, BS)  # one [1, BS] lane row per block
 
     # the arena-block specs: each grid cell streams the bps blocks the
     # scalar-prefetched table names (one ref per sub-block — independent
-    # DMAs); quantized runs add each block's per-head scale as a (1, 1)
-    # SMEM scalar picked by the same indices
+    # DMAs); quantized runs add each block's per-head scale, picked by the
+    # same indices out of a [NB, Nkv, 1, 1] view (see _scale_operand)
     def block_spec(j):
         return pl.BlockSpec(
             (1, 1, BS, D),
@@ -556,8 +588,8 @@ def paged_attention_tpu(
 
     def scale_spec(j):
         return pl.BlockSpec(
-            (1, 1), lambda b, k, t, tbl, j=j: (tbl[b, t * bps + j], k),
-            memory_space=pltpu.SMEM,
+            (1, 1, 1, 1),
+            lambda b, k, t, tbl, j=j: (tbl[b, t * bps + j], k, 0, 0),
         )
 
     in_specs = [
@@ -572,12 +604,11 @@ def paged_attention_tpu(
             + [scale_spec(j) for j in range(bps)]
         )
         operands += (
-            [k_scale.astype(jnp.float32)] * bps
-            + [v_scale.astype(jnp.float32)] * bps
+            [_scale_operand(k_scale)] * bps + [_scale_operand(v_scale)] * bps
         )
     in_specs += [
         pl.BlockSpec((1, GS, 1), lambda b, k, t, tbl: (b, 0, 0)),
-        pl.BlockSpec((1, 1, bps * BS), lambda b, k, t, tbl: (b, 0, t)),
+        pl.BlockSpec((1, bps, 1, BS), lambda b, k, t, tbl: (b, t, 0, 0)),
     ]
     operands += [qp, kp]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -600,7 +631,7 @@ def paged_attention_tpu(
         ),
         out_shape=jax.ShapeDtypeStruct((B, Nkv, GS, D), q.dtype),
         grid_spec=grid_spec,
-        compiler_params=_compat.pallas_tpu_compiler_params()(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -621,8 +652,8 @@ def _paged_prefill_kernel(
     nlive_ref,  # scalar-prefetch [B] — live (attendable) blocks per row
     q_ref,  # [1, 1, BQ, D]
     *rest,  # bps k refs [1, 1, BS, D], bps v refs; quantized: + bps ks
-    #   refs and bps vs refs ((1, 1) SMEM); then qpos [1, BQ, 1], kvpos
-    #   [1, 1, bps*BS], out [1, 1, BQ, D], scratch acc/m/l
+    #   refs and bps vs refs ([1, 1, 1, 1]); then qpos [1, BQ, 1], kvpos
+    #   [1, bps, 1, BS], out [1, 1, BQ, D], scratch acc/m/l
     scale,
     t_steps,
     bps,
@@ -644,7 +675,6 @@ def _paged_prefill_kernel(
         l_ref[:] = jnp.zeros_like(l_ref)
 
     q = q_ref[0, 0]  # [BQ, D]
-    BS = k_refs[0].shape[2]
     for j in range(bps):
         k_blk, v_blk = k_refs[j][0, 0], v_refs[j][0, 0]  # [BS, D]
         if quantized:
@@ -670,9 +700,7 @@ def _paged_prefill_kernel(
         # (with their kv positions) before this kernel runs, so a query
         # at position p attends exactly the prefix ≤ p — earlier chunks,
         # the radix prefix, and the chunk's own earlier tokens.
-        mask = (
-            kvpos_ref[0, :, j * BS:(j + 1) * BS] <= qpos_ref[0]
-        )  # [BQ, BS]
+        mask = kvpos_ref[0, j] <= qpos_ref[0]  # [BQ, BS]
         _online_update(q, k, v, mask, scale, acc_ref, m_ref, l_ref)
 
     @pl.when(t == t_steps - 1)
@@ -761,7 +789,7 @@ def paged_prefill_tpu(
     qp = qp[..., None]  # [B, GSp, 1] — sublane-major (see _flash_kernel)
     kh = jnp.transpose(k_arena, (0, 2, 1, 3))  # [NB, Nkv, BS, D]
     vh = jnp.transpose(v_arena, (0, 2, 1, 3))
-    kp = kv_positions[:, None, :]  # [B, 1, T*BS] — lane-major
+    kp = kv_positions.reshape(B, T, 1, BS)  # lane-major, one row per block
 
     # arena-block specs: the frontier clamp lives in the INDEX MAP — a
     # dead step re-names block 0, whose DMA Pallas elides when the index
@@ -779,14 +807,13 @@ def paged_prefill_tpu(
 
     def scale_spec(j):
         return pl.BlockSpec(
-            (1, 1),
+            (1, 1, 1, 1),
             lambda b, k, i, t, tbl, nl, j=j: (
                 jnp.where(
                     t * bps + j < nl[b], tbl[b, t * bps + j], 0
                 ),
-                k,
+                k, 0, 0,
             ),
-            memory_space=pltpu.SMEM,
         )
 
     in_specs = [
@@ -803,15 +830,14 @@ def paged_prefill_tpu(
             + [scale_spec(j) for j in range(bps)]
         )
         operands += (
-            [k_scale.astype(jnp.float32)] * bps
-            + [v_scale.astype(jnp.float32)] * bps
+            [_scale_operand(k_scale)] * bps + [_scale_operand(v_scale)] * bps
         )
     in_specs += [
         pl.BlockSpec(
             (1, block_q, 1), lambda b, k, i, t, tbl, nl: (b, i, 0)
         ),
         pl.BlockSpec(
-            (1, 1, bps * BS), lambda b, k, i, t, tbl, nl: (b, 0, t)
+            (1, bps, 1, BS), lambda b, k, i, t, tbl, nl: (b, t, 0, 0)
         ),
     ]
     operands += [qp, kp]
@@ -835,7 +861,7 @@ def paged_prefill_tpu(
         ),
         out_shape=jax.ShapeDtypeStruct((B, Nkv, GSp, D), q.dtype),
         grid_spec=grid_spec,
-        compiler_params=_compat.pallas_tpu_compiler_params()(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 "parallel", "parallel", "parallel", "arbitrary",
             ),
@@ -844,6 +870,19 @@ def paged_prefill_tpu(
     )(*operands)
     out = out[:, :, :GS].reshape(B, Nkv, G, S, D)
     return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(B, S, Nh, D)
+
+
+def _ineligible_msg(op: str, k_arena, block_table) -> str:
+    rows, width = block_table.shape
+    return (
+        f"{op} backend 'kernel': head_dim={k_arena.shape[-1]} / "
+        f"block_size={k_arena.shape[1]} / block table [{rows}, {width}] are "
+        f"not Mosaic-eligible for cache dtype "
+        f"{jnp.dtype(k_arena.dtype).name} (head_dim must be a multiple of "
+        f"128, the block size a sublane multiple, and the table must fit "
+        f"{SMEM_TABLE_BUDGET} bytes of scalar memory — see "
+        f"kernel_eligible); use backend='auto' or 'xla'"
+    )
 
 
 def paged_prefill(
@@ -886,8 +925,10 @@ def paged_prefill(
         )
     if backend == "auto":
         backend = forced_backend() or "auto"
-    D = q.shape[-1]
-    BS = k_arena.shape[1]
+    eligible = kernel_eligible(
+        q.shape[-1], k_arena.shape[1], k_arena.dtype, rows=block_table.shape[0],
+        table_width=block_table.shape[1],
+    )
     if backend == "interpret":
         return paged_prefill_tpu(
             q, k_arena, v_arena, block_table, q_positions, kv_positions,
@@ -902,18 +943,12 @@ def paged_prefill(
                 f"(or PAGED_FORCE_KERNEL=interpret) to emulate the kernel "
                 f"off-TPU"
             )
-        if not kernel_eligible(D, BS, k_arena.dtype):
+        if not eligible:
             raise ValueError(
-                f"paged_prefill backend 'kernel': head_dim={D} / "
-                f"block_size={BS} are not Mosaic-eligible for cache dtype "
-                f"{jnp.dtype(k_arena.dtype).name} (head_dim must be a "
-                f"multiple of 128 and the block size a sublane multiple "
-                f"— see kernel_eligible); use backend='auto' or 'xla'"
+                _ineligible_msg("paged_prefill", k_arena, block_table)
             )
     use_pallas = backend == "kernel" or (
-        backend == "auto"
-        and jax.default_backend() == "tpu"
-        and kernel_eligible(D, BS, k_arena.dtype)
+        backend == "auto" and jax.default_backend() == "tpu" and eligible
     )
     if use_pallas:
         return paged_prefill_tpu(
@@ -967,8 +1002,10 @@ def paged_attention(
         )
     if backend == "auto":
         backend = forced_backend() or "auto"
-    D = q.shape[-1]
-    BS = k_arena.shape[1]
+    eligible = kernel_eligible(
+        q.shape[-1], k_arena.shape[1], k_arena.dtype, rows=block_table.shape[0],
+        table_width=block_table.shape[1],
+    )
     if backend == "interpret":
         return paged_attention_tpu(
             q, k_arena, v_arena, block_table, q_positions, kv_positions,
@@ -986,18 +1023,12 @@ def paged_attention(
                 f"(or PAGED_FORCE_KERNEL=interpret) to emulate the kernel "
                 f"off-TPU"
             )
-        if not kernel_eligible(D, BS, k_arena.dtype):
+        if not eligible:
             raise ValueError(
-                f"paged_attention backend 'kernel': head_dim={D} / "
-                f"block_size={BS} are not Mosaic-eligible for cache dtype "
-                f"{jnp.dtype(k_arena.dtype).name} (head_dim must be a "
-                f"multiple of 128 and the block size a sublane multiple "
-                f"— see kernel_eligible); use backend='auto' or 'xla'"
+                _ineligible_msg("paged_attention", k_arena, block_table)
             )
     use_pallas = backend == "kernel" or (
-        backend == "auto"
-        and jax.default_backend() == "tpu"
-        and kernel_eligible(D, BS, k_arena.dtype)
+        backend == "auto" and jax.default_backend() == "tpu" and eligible
     )
     if use_pallas:
         return paged_attention_tpu(

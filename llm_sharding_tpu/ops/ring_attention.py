@@ -20,8 +20,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .. import _compat
-
 
 def ring_attention(
     q: jnp.ndarray,  # [B, Sq, Nh, D] — local query chunk (RoPE'd)
@@ -40,7 +38,7 @@ def ring_attention(
     G = Nh // Nkv
     if scale is None:
         scale = D ** -0.5
-    num_chunks = _compat.axis_size(axis_name)
+    num_chunks = jax.lax.axis_size(axis_name)
     ring = [(i, (i + 1) % num_chunks) for i in range(num_chunks)]
 
     qg = q.reshape(B, Sq, Nkv, G, D).astype(jnp.float32)

@@ -31,7 +31,7 @@ from ..ops.quant import embed_rows, head_logits, tied_logits
 from ..ops.ring_attention import ring_attention
 from ..ops.rope import rope_cos_sin
 from .mesh import SEQ_AXIS
-from .._compat import shard_map
+from jax import shard_map
 
 
 def _ctx_layer(cfg: ModelConfig, p: Any, h, cos, sin, q_pos, kv_pos):

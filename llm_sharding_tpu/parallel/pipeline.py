@@ -62,7 +62,7 @@ from .head import (
     sp_sample,
 )
 from .mesh import PIPE_AXIS
-from .._compat import shard_map
+from jax import shard_map
 
 
 class ModelFns(NamedTuple):

@@ -59,7 +59,7 @@ from .pipeline import (
     ring_chain,
     validate_request,
 )
-from .._compat import shard_map
+from jax import shard_map
 
 
 class InterleavedResult(NamedTuple):

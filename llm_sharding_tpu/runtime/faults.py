@@ -105,8 +105,8 @@ class PermanentFault(InjectedFault):
 
 def is_transient(err: BaseException, extra: Tuple[type, ...] = ()) -> bool:
     """The retry policy's admit test: injected transients, plus any
-    caller-registered real exception types (e.g. a deployment that knows its
-    tunnel raises ``OSError`` on a dropped connection). Follows the
+    caller-registered real exception types (e.g. a deployment whose device
+    runtime raises ``OSError`` on a dropped connection). Follows the
     ``__cause__`` chain — the serving stack wraps device-read failures in a
     tagged ``RuntimeError`` and the classification must see through it."""
     seen: set = set()

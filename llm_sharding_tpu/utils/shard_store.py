@@ -15,6 +15,8 @@ Layout mirrors the reference's split logically — one file per unit —
       final_norm.npz                 # ≙ final_norm.pth / ln_f.pth
       lm_head.npz                    # ≙ lm_head.pth (absent when tied: the
                                      #   last stage reuses embedding.npz)
+      <unit>.part{j}.npz             # the rest of a unit bigger than one file
+                                     #   may be (``MAX_FILE_BYTES``)
 
 — but stores numpy ``.npz`` instead of torch pickles, and the loader stacks a
 stage's ``block_{start..end-1}`` into scan-ready ``[L, ...]`` arrays.
@@ -71,6 +73,16 @@ _Q4_DIM_TAG = "__q4dim"
 _SCALE_SUFFIX = "__scale"
 _INT_VIEW = {1: np.uint8, 2: np.uint16, 4: np.uint32}
 
+# No file of a store carries more than this many array bytes, so a store can
+# be written where a per-file size limit holds (an RLIMIT_FSIZE, a 2-4 GiB
+# filesystem cap): a unit that is bigger continues in `<unit>.part<j>.npz`,
+# the first file's `__files` says how many there are, and an array bigger
+# than a file is cut along its leading axis into `<name>__part<j>` pieces
+# (a 7B vocab table is 1.1 GB in bf16). Loading joins them on the host.
+MAX_FILE_BYTES = 128 << 20
+_FILES_TAG = "__files"
+_PART_TAG = "__part"
+
 
 def _pack_int4(a: np.ndarray) -> np.ndarray:
     """int8 values in [-8, 7] → packed bytes, pairs along the last axis
@@ -116,46 +128,108 @@ def _save_npz(path: str, arrays: dict[str, Any]) -> None:
             _encode_array(out, k + _SCALE_SUFFIX, v.scale)
         else:
             _encode_array(out, k, v)
-    np.savez(path, **out)
+    files = _split_files(out, MAX_FILE_BYTES)
+    if len(files) > 1:
+        files[0][_FILES_TAG] = np.asarray(len(files))
+    for j, part in enumerate(files):
+        np.savez(_part_path(path, j), **part)
+
+
+def _part_path(path: str, j: int) -> str:
+    """File ``j`` of the unit at ``path`` (``<unit>.npz``)."""
+    return path if j == 0 else f"{path[: -len('.npz')]}.part{j}.npz"
+
+
+def _split_files(
+    out: dict[str, np.ndarray], limit: int
+) -> list[dict[str, np.ndarray]]:
+    """Spread one unit's encoded arrays over files of at most ``limit``
+    array bytes, in order. An array bigger than ``limit`` is cut along its
+    leading axis (views, no copy); a row is never cut, so a row bigger than
+    ``limit`` gets a file to itself."""
+    pieces: list[tuple[str, np.ndarray]] = []
+    for k, a in out.items():
+        if a.nbytes <= limit or a.ndim == 0:
+            pieces.append((k, a))
+            continue
+        rows = max(limit // (a.nbytes // len(a)), 1)
+        pieces += [
+            (f"{k}{_PART_TAG}{j}", a[at: at + rows])
+            for j, at in enumerate(range(0, len(a), rows))
+        ]
+    files: list[dict[str, np.ndarray]] = [{}]
+    room = limit
+    for k, a in pieces:
+        if a.nbytes > room and files[-1]:
+            files.append({})
+            room = limit
+        files[-1][k] = a
+        room -= a.nbytes
+    return files
+
+
+def _read_unit(path: str) -> dict[str, np.ndarray]:
+    """The encoded arrays of one unit: its continuation files merged and
+    its cut arrays joined (the inverse of ``_split_files``)."""
+    with np.load(path) as z:
+        raw = {k: z[k] for k in z.files}
+    for j in range(1, int(raw.pop(_FILES_TAG, 1))):
+        with np.load(_part_path(path, j)) as z:
+            raw.update({k: z[k] for k in z.files})
+    cut: dict[str, dict[int, np.ndarray]] = {}
+    for k in list(raw):
+        base, tag, j = k.rpartition(_PART_TAG)
+        if tag:
+            cut.setdefault(base, {})[int(j)] = raw.pop(k)
+    for base, pieces in cut.items():
+        raw[base] = np.concatenate([pieces[j] for j in range(len(pieces))])
+    return raw
 
 
 def _load_npz(path: str, dtype) -> dict[str, Any]:
+    """One store unit → HOST arrays (numpy / ml_dtypes), QTensor leaves
+    included. Nothing here touches a device: the engine keeps the loaded
+    model as its host-resident repartition source and places each stage's
+    slice on that stage's chip — a model bigger than one chip must never
+    detour through the default device on its way in."""
     import ml_dtypes
 
     from ..ops.quant import Int4QTensor, QTensor
 
+    dtype = np.dtype(dtype)
+
     def decode(z, k) -> np.ndarray:
         a = z[k]
         tag = k + _DTYPE_TAG
-        if tag in z.files:
+        if tag in z:
             a = a.view(np.dtype(getattr(ml_dtypes, str(z[tag]))))
         return a
 
-    with np.load(path) as z:
-        res: dict[str, Any] = {}
-        for k in z.files:
-            if (
-                k.endswith(_DTYPE_TAG)
-                or k.endswith(_SCALE_SUFFIX)
-                or k.endswith(_Q4_DIM_TAG)
-            ):
-                continue
-            if k.endswith(_Q4_SUFFIX):
-                base = k[: -len(_Q4_SUFFIX)]
-                q = _unpack_int4(z[k], int(z[base + _Q4_DIM_TAG]))
-                res[base] = Int4QTensor(
-                    q=jnp.asarray(q),  # int8-resident (see Int4QTensor)
-                    scale=jnp.asarray(decode(z, base + _SCALE_SUFFIX), dtype),
-                )
-            elif k.endswith(_Q_SUFFIX):
-                base = k[: -len(_Q_SUFFIX)]
-                res[base] = QTensor(
-                    q=jnp.asarray(decode(z, k)),  # stays int8
-                    scale=jnp.asarray(decode(z, base + _SCALE_SUFFIX), dtype),
-                )
-            else:
-                res[k] = jnp.asarray(decode(z, k), dtype)
-        return res
+    z = _read_unit(path)
+    res: dict[str, Any] = {}
+    for k in z:
+        if (
+            k.endswith(_DTYPE_TAG)
+            or k.endswith(_SCALE_SUFFIX)
+            or k.endswith(_Q4_DIM_TAG)
+        ):
+            continue
+        if k.endswith(_Q4_SUFFIX):
+            base = k[: -len(_Q4_SUFFIX)]
+            res[base] = Int4QTensor(
+                # int8-resident (see Int4QTensor)
+                q=_unpack_int4(z[k], int(z[base + _Q4_DIM_TAG])),
+                scale=decode(z, base + _SCALE_SUFFIX).astype(dtype, copy=False),
+            )
+        elif k.endswith(_Q_SUFFIX):
+            base = k[: -len(_Q_SUFFIX)]
+            res[base] = QTensor(
+                q=decode(z, k),  # stays int8
+                scale=decode(z, base + _SCALE_SUFFIX).astype(dtype, copy=False),
+            )
+        else:
+            res[k] = decode(z, k).astype(dtype, copy=False)
+    return res
 
 
 def save_shards(
@@ -305,6 +379,11 @@ def load_tokenizer(shards_dir: str):
         return None
 
 
+def load_block(shards_dir: str, i: int, dtype=jnp.bfloat16) -> dict[str, Any]:
+    """Decoder layer ``i`` of a store, on the host."""
+    return _load_npz(os.path.join(shards_dir, f"block_{i}.npz"), dtype)
+
+
 def load_stage(
     shards_dir: str,
     start: int,
@@ -330,23 +409,21 @@ def load_stage(
     if user_facing is None:
         user_facing = start == 0
 
-    blocks = [
-        _load_npz(os.path.join(shards_dir, f"block_{i}.npz"), dtype)
-        for i in range(start, end)
-    ]
+    blocks = [load_block(shards_dir, i, dtype) for i in range(start, end)]
     n = end - start
     pad_to = pad_to or n
     if pad_to < n:
         raise ValueError(f"pad_to={pad_to} < stage size {n}")
     if pad_to > n:
-        pad_block = jax.tree.map(jnp.zeros_like, blocks[0])
+        pad_block = jax.tree.map(np.zeros_like, blocks[0])
         blocks = blocks + [pad_block] * (pad_to - n)
-    # stacks through QTensor leaves (q and scale stacked independently)
-    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *blocks)
+    # stacks through QTensor leaves (q and scale stacked independently) —
+    # on the host, like everything this loader returns
+    stacked = jax.tree.map(lambda *xs: np.stack(xs), *blocks)
 
     stage: dict[str, Any] = {
         "layers": stacked,
-        "layer_mask": jnp.arange(pad_to) < n,
+        "layer_mask": np.arange(pad_to) < n,
         "start": start,
         "end": end,
     }
@@ -366,7 +443,9 @@ def load_stage(
 
 
 def load_full(shards_dir: str, dtype=jnp.bfloat16) -> tuple[ModelConfig, dict]:
-    """Load the whole model (monolithic oracle path, ≙ ``inference.py``)."""
+    """Load the whole model onto the HOST (≙ ``inference.py``'s load). The
+    engines place it from there: ``PipelineEngine`` stage by stage, a
+    monolithic caller with its own ``jax.device_put``."""
     cfg = load_config(shards_dir)
     stage = load_stage(shards_dir, 0, cfg.num_hidden_layers, dtype, user_facing=True)
     params = {k: v for k, v in stage.items() if k not in ("layer_mask", "start", "end")}

@@ -1,0 +1,166 @@
+"""``chip_smoke.py`` on the CPU mesh: its store writer, HTTP driver and
+assertions at a tiny config with the kernels in interpret mode — and its own
+``main`` refusing to pass without a TPU.
+
+The smoke proper runs on the chip (see the script's docstring); these tests
+keep its pieces from rotting between chip runs.
+"""
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402
+
+#: the FULL spec's shape at toy size: block 8, chunk 16, a long prompt four
+#: chunks long and a radix-hit suffix that still needs chunking. The EOS id
+#: sits outside the vocabulary so random weights cannot end a request early.
+TINY = {
+    "preset": "tiny_llama",
+    "overrides": {"num_hidden_layers": 2, "eos_token_id": 10**6,
+                  "eos_token_ids": [10**6]},
+    "seed": 7,
+    "dtype": "f32",
+    "quantize": True,
+    "serve": {"capacity": 128, "batch_per_slot": 4, "kv_block_size": 8,
+              "kv_blocks": 65, "prefill_chunk": 16},
+    "max_tokens": 6,
+    "short_prompt": 6,
+    "long_prompt": 60,
+    "shared_prefix": 32,
+    "shared_suffix": 24,
+}
+
+
+def test_main_refuses_without_a_tpu(tmp_path):
+    """Here (no TPU) the script exits non-zero, names the missing device and
+    prints no result line — from a copy, so its ``.smoke/`` lands in tmp."""
+    shutil.copy(os.path.join(REPO, "chip_smoke.py"), tmp_path)
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
+    p = subprocess.run(
+        [sys.executable, str(tmp_path / "chip_smoke.py")],
+        capture_output=True, text=True, env=env, timeout=300,
+    )
+    assert p.returncode != 0
+    assert "found no TPU" in p.stderr and "'cpu'" in p.stderr
+    assert '"ok"' not in p.stdout
+
+
+def test_kernel_check_runs_every_variant_in_interpret_mode():
+    cfg = chip_smoke.model_config(TINY)
+    cases = chip_smoke.kernel_cases(TINY)
+    assert {(c["kernel"], c["kv_dtype"]) for c in cases} == {
+        (k, d) for k in ("paged_decode", "paged_prefill")
+        for d in ("bf16", "int8", "fp8")
+    } | {("flash", "bf16")}
+    for case in cases:
+        assert case["table_width"] == 16 and case["block_size"] == 8
+        err = chip_smoke.check_kernel(cfg, case, "interpret")
+        assert err <= chip_smoke.KERNEL_TOL, (case, err)
+
+
+def test_store_writer_driver_and_assertions_on_cpu(tmp_path, monkeypatch):
+    """The smoke's daemon phase end to end at toy size: seeded store through
+    the product's writer, the real ``serve`` daemon as a child, the smoke's
+    traffic over HTTP, its assertions, SIGTERM → drained."""
+    from llm_sharding_tpu.utils import shard_store
+
+    monkeypatch.setattr(chip_smoke, "WORK", str(tmp_path))
+    # a per-file cap the toy units exceed, as the 7B units exceed the real
+    # one: the daemon below serves a store whose units span several files
+    monkeypatch.setattr(shard_store, "MAX_FILE_BYTES", 16 << 10)
+    store = str(tmp_path / "store")
+    first = chip_smoke.write_store(TINY, store)
+    assert not first["reused"] and first["bytes"]["blocks"] == 2
+    assert first["bytes"]["largest_file"] < (16 << 10) + 4096  # + zip headers
+    assert os.path.exists(os.path.join(store, "block_0.part1.npz"))
+    assert os.path.exists(os.path.join(store, "embedding.part1.npz"))
+    assert chip_smoke.write_store(TINY, store)["reused"]
+    # one tensor at a time, each from its own stream
+    get = chip_smoke.tensor_source(chip_smoke.model_config(TINY), TINY["seed"])
+    np.testing.assert_array_equal(
+        get("model.layers.1.mlp.up_proj.weight"),
+        get("model.layers.1.mlp.up_proj.weight"),
+    )
+    with pytest.raises(KeyError):
+        get("model.layers.0.self_attn.o_proj.bias")
+
+    env = dict(
+        os.environ, JAX_PLATFORMS="cpu", PAGED_FORCE_KERNEL="interpret",
+        PYTHONPATH=REPO,
+    )
+    env.pop("XLA_FLAGS", None)  # one device: --stages 1
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)  # the program's own choice
+    with chip_smoke.Daemon(
+        store, chip_smoke.serve_args(TINY, 1, 1), env
+    ) as d:
+        d.wait_ready(300.0)
+        dev = d.statz()["device"]
+        assert dev["platform"] == "cpu" and dev["compile_cache_dir"] is None
+        traffic = chip_smoke.drive(d, TINY, vocab=256)
+        assert traffic["sent"] == 10
+        assert traffic["prefix_hit_tokens"] >= TINY["shared_prefix"]
+        served = chip_smoke.check_served(
+            d, traffic["sent"],
+            {"platform": "cpu", "attn_backend": "interpret"},
+        )
+        assert served["blocks_read"]["prefill"] > 0
+        # the assertions bite: this daemon did not run on a TPU kernel
+        with pytest.raises(SystemExit, match="ran on 'cpu'"):
+            chip_smoke.check_served(
+                d, traffic["sent"],
+                {"platform": "tpu", "attn_backend": "kernel"},
+            )
+        d.drain()
+    assert d.proc.returncode == 0
+
+
+def test_metric_parser_and_memory_check():
+    page = (
+        '# HELP x\nserver_attn_backend{backend="kernel"} 2\n'
+        'server_attn_backend{backend="xla"} 0\n'
+        "server_attn_blocks_read_total 41\n"
+        "server_attn_blocks_read_total_created 9\n"
+    )
+    assert chip_smoke.metric(page, "server_attn_backend", backend="kernel") == 2
+    assert chip_smoke.metric(page, "server_attn_blocks_read_total") == 41
+    assert chip_smoke.metric(page, "absent") == 0
+
+    gib = 2**30
+    sizes = {"block": gib // 4, "blocks": 28, "head": 2 * gib}
+    dev = {"memory": [
+        {"id": i, "bytes_in_use": 3 * gib, "peak_bytes_in_use": 3 * gib}
+        for i in range(4)
+    ]}
+    # 7 layers (1.75) + head/4 (0.5) + arena/4 (0.25) = 2.5 GiB share
+    assert chip_smoke.check_memory(dev, sizes, gib, 4, 1, slack=gib) == [3.0] * 4
+    dev["memory"][0]["peak_bytes_in_use"] = 9 * gib  # the old chip-0 detour
+    with pytest.raises(SystemExit, match="peaked at 9.00 GiB"):
+        chip_smoke.check_memory(dev, sizes, gib, 4, 1, slack=gib)
+    with pytest.raises(SystemExit, match="4 devices hold data"):
+        chip_smoke.check_memory(dev, sizes, gib, 2, 1, slack=gib)
+
+
+def test_parent_imports_nothing_that_touches_the_chip():
+    """The parent is stdlib only: importing the module (what ``main`` runs
+    under before it spawns children) must not pull in jax."""
+    code = (
+        "import sys; sys.path.insert(0, %r); import chip_smoke; "
+        "chip_smoke.prompts(chip_smoke.FULL, 1000); "
+        "print(json.dumps(sorted(m for m in sys.modules "
+        "if m.split('.')[0] in ('jax', 'jaxlib', 'numpy', "
+        "'llm_sharding_tpu'))))" % REPO
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", "import json; " + code],
+        capture_output=True, text=True, check=True,
+    ).stdout
+    assert json.loads(out) == []

@@ -546,15 +546,6 @@ def test_serve_placement_rollback_on_rebuild_failure(shards, capsys, monkeypatch
     assert '"requests_completed": 2' in captured.err
 
 
-@pytest.mark.xfail(
-    strict=False,
-    reason="known-failing since the seed in this container: the spawned "
-    "jax.distributed worker subprocesses cannot rendezvous/teardown under "
-    "the container's restricted multi-process environment (the test "
-    "passes on an unrestricted host). Marked xfail so tier-1 noise stops "
-    "masking real regressions; strict=False keeps an unexpected pass "
-    "from failing the suite where multi-process works.",
-)
 def test_launch_two_process_simulation(tmp_path, capsys):
     """``launch`` spawns N jax.distributed workers on this host (≙ the
     reference's run_this.sh:8-17 spawning per-node daemons with per-node
@@ -591,17 +582,61 @@ def test_launch_two_process_simulation(tmp_path, capsys):
         assert "2 processes, 4 global devices" in f.read()
 
 
-def test_compile_cache_toggle(tmp_path, monkeypatch):
-    """Persistent-cache helper: creates/points at the directory, honors the
-    off switch, and tolerates unwritable paths (returns None, never raises)."""
-    import os
+def test_compile_cache_contract(tmp_path, monkeypatch):
+    """Where compiled programs are kept: placed from outside → the program
+    sets nothing; unset on the CPU → no cache of its own; unset on a TPU →
+    one constant directory inside the checkout, and an unusable one is an
+    error, not a silent cold start."""
+    from llm_sharding_tpu.utils import compile_cache as cc
 
-    from llm_sharding_tpu.utils.compile_cache import enable_persistent_cache
+    updates = []
+    monkeypatch.setattr(
+        jax.config, "update", lambda k, v: updates.append((k, v))
+    )
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "placed"))
+    for platform in ("tpu", "cpu"):
+        assert cc.enable_persistent_cache(platform) == str(tmp_path / "placed")
+    assert updates == [] and not os.path.exists(tmp_path / "placed")
 
-    p = enable_persistent_cache(str(tmp_path / "xla"))
-    assert p is not None and os.path.isdir(p)
-    monkeypatch.setenv("LLM_SHARDING_TPU_CACHE", "off")
-    assert enable_persistent_cache() is None
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert cc.enable_persistent_cache("cpu") is None and updates == []
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert cc.DEFAULT_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    monkeypatch.setattr(cc, "DEFAULT_CACHE_DIR", str(tmp_path / "in_checkout"))
+    assert cc.enable_persistent_cache("tpu") == str(tmp_path / "in_checkout")
+    assert updates == [
+        ("jax_compilation_cache_dir", str(tmp_path / "in_checkout"))
+    ]
+    (tmp_path / "a_file").write_text("")
+    monkeypatch.setattr(cc, "DEFAULT_CACHE_DIR", str(tmp_path / "a_file" / "x"))
+    with pytest.raises(OSError):
+        cc.enable_persistent_cache("tpu")
+
+
+@pytest.mark.parametrize("argv", [
+    ["launch", "store", "--prompt", "hi"],
+    ["convert", "model_dir", "out_dir"],
+])
+def test_spawning_and_offline_commands_never_take_the_chip(argv, monkeypatch):
+    """A chip belongs to one process: ``launch`` (a parent of workers) and
+    ``convert`` (an offline file transform) must reach their command
+    without ``main`` initialising a backend."""
+    def boom(*a, **k):
+        raise AssertionError("cli.main initialised a backend")
+
+    monkeypatch.setattr(jax, "devices", boom)
+    seen = []
+    monkeypatch.setattr(
+        cli, "cmd_" + argv[0], lambda args: seen.append(args.command) or 0
+    )
+    assert cli.main(argv) == 0 and seen == [argv[0]]
+
+
+def test_launch_is_a_cpu_simulation_only(capsys):
+    with pytest.raises(SystemExit):
+        cli.main(["launch", "store", "--prompt", "hi", "--platform", "inherit"])
+    assert "--platform" in capsys.readouterr().err
 
 
 def test_serve_command_stop_flag(shards, capsys, monkeypatch):

@@ -201,10 +201,11 @@ def test_quantized_kernel_interpret_matches_dequantized_kernel():
 def test_kernel_eligible_names_one_byte_sublane():
     """1-byte KV dtypes tile at sublane 32: block 32 is kernel-eligible,
     16 (fine for bf16) is not."""
-    assert kernel_eligible(128, 32, jnp.int8)
-    assert not kernel_eligible(128, 16, jnp.int8)
-    assert kernel_eligible(128, 16, jnp.bfloat16)
-    assert kernel_eligible(128, 32, jnp.float8_e4m3fn)
+    tbl = dict(rows=4, table_width=32)
+    assert kernel_eligible(128, 32, jnp.int8, **tbl)
+    assert not kernel_eligible(128, 16, jnp.int8, **tbl)
+    assert kernel_eligible(128, 16, jnp.bfloat16, **tbl)
+    assert kernel_eligible(128, 32, jnp.float8_e4m3fn, **tbl)
 
 
 # ------------------------------------------------------- capacity math

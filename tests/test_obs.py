@@ -132,7 +132,7 @@ def test_record_shape_key_hit_miss():
 # ---------------------------------------------------------------- counters
 
 
-def test_counters_snapshot_roundtrip_forward_compat():
+def test_counters_snapshot_roundtrip_across_builds():
     c = Counters(requests_submitted=2, tokens_generated=9)
     snap = c.snapshot()
     assert Counters.from_snapshot(snap) == c

@@ -640,6 +640,57 @@ def test_paged_attn_kwarg_validation(setup):
         eng.serve(capacity=64, paged_attn="kernel", **paged_kw())
 
 
+def test_kernel_rules_learned_from_the_v5e_compiler(monkeypatch):
+    """What Mosaic refused during bring-up stays refused — or repaired.
+
+    Shape rule: the scalar-prefetched block table must fit scalar memory
+    (``[128, 2048]`` int32 "exceeded smem capacity"; ``[120, 2048]`` and
+    ``[2000, 33]`` — rows pad to 128 entries — compiled). Repairs: a
+    ``kv_positions`` tile that is neither 128 lanes wide nor the whole
+    window (odd table width at block 16) and the int8/fp8 scale operand
+    (a ``(1, 1)`` block of ``[NB, Nkv]``) now lower for the TPU platform —
+    the block-shape check runs at lowering, so the CPU can hold the line."""
+    from llm_sharding_tpu.ops.paged_attention import (
+        kernel_eligible, paged_attention_tpu, paged_prefill_tpu,
+    )
+
+    ok = dict(head_dim=128, block_size=16, cache_dtype=jnp.bfloat16)
+    assert not kernel_eligible(**ok, rows=128, table_width=2048)
+    assert kernel_eligible(**ok, rows=120, table_width=2048)
+    assert kernel_eligible(**ok, rows=2000, table_width=33)
+    assert not kernel_eligible(**ok, rows=4000, table_width=33)
+
+    S = jax.ShapeDtypeStruct
+    B, Nh, Nkv, D, NB = 4, 28, 4, 128, 64  # G = 7, the Qwen2.5-7B fold
+    for fn, Sq in ((paged_attention_tpu, 1), (paged_prefill_tpu, 128)):
+        for store, block, T in ((jnp.bfloat16, 16, 33), (jnp.int8, 32, 32)):
+            quant = store == jnp.int8
+            arena = S((NB, block, Nkv, D), store)
+            scale = S((NB, Nkv), jnp.float32) if quant else None
+            jax.jit(
+                lambda q, k, v, t, qp, kp, ks, vs, fn=fn: fn(
+                    q, k, v, t, qp, kp, k_scale=ks, v_scale=vs
+                )
+            ).trace(
+                S((B, Sq, Nh, D), jnp.bfloat16), arena, arena,
+                S((B, T), jnp.int32), S((B, Sq), jnp.int32),
+                S((B, T * block), jnp.int32), scale, scale,
+            ).lower(lowering_platforms=("tpu",))
+
+    # --paged-attn kernel fails at construction, by name, never mid-serve
+    cfg = tiny_llama(num_hidden_layers=2, head_dim=128)
+    eng = PipelineEngine(
+        cfg, llama.init_params(cfg, jax.random.key(0), dtype=jnp.float32),
+        num_stages=1, cache_dtype=jnp.float32,
+    )
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match=r"block table \[128, 2048\].*scalar"):
+        eng.serve(
+            capacity=32768, batch_per_slot=128, kv_block_size=16,
+            kv_blocks=4097, paged_attn="kernel",
+        )
+
+
 def test_forced_backend_env_validation(monkeypatch):
     from llm_sharding_tpu.ops.paged_attention import forced_backend
 

@@ -123,10 +123,31 @@ def test_quantized_store_round_trip(qsetup, tmp_path):
         np.asarray(qparams["layers"]["wq"].q),
     )
 
+    # disk → host: nothing the loader returns sits on a device, QTensor
+    # leaves included — the engine places each stage's slice from there
+    assert all(
+        type(leaf) is np.ndarray for leaf in jax.tree.leaves(loaded)
+    )
+    stage = shard_store.load_stage(out, 1, 3, dtype=jnp.float32, pad_to=4)
+    assert all(
+        type(leaf) is np.ndarray
+        for leaf in jax.tree.leaves((stage["layers"], stage["layer_mask"]))
+    )
+
     prompt = np.array([[5, 9, 2, 14]], np.int32)
     a = generate(CFG, qparams, prompt, 8, cache_dtype=jnp.float32)
     b = generate(CFG, loaded, prompt, 8, cache_dtype=jnp.float32)
     np.testing.assert_array_equal(a.tokens, b.tokens)
+    # and the normal entry point serves it token-exactly from the store
+    eng = PipelineEngine.from_shards(
+        out, num_stages=2, dtype=jnp.float32, cache_dtype=jnp.float32
+    )
+    srv = eng.serve(capacity=32)
+    req = srv.submit(prompt[0], 8)
+    srv.run_until_idle()
+    assert req.tokens == [
+        int(x) for x in a.tokens[0][prompt.shape[1]: int(a.lengths[0])]
+    ]
 
 
 def test_quantized_stage_loading_ragged(qsetup, tmp_path):

@@ -145,7 +145,7 @@ def test_prefetched_retry_reissues_the_device_read():
         def __array__(self, *a, **k):
             type(self).calls += 1
             if type(self).calls < 3:
-                raise OSError("tunnel dropped")
+                raise OSError("connection dropped")
             return np.arange(4)
 
     p = _Prefetched(FlakyHandle(), tag="chunk m0=0")

@@ -6,9 +6,9 @@ import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from llm_sharding_tpu._compat import shard_map
 from llm_sharding_tpu.models import llama
 from llm_sharding_tpu.models.cache import POS_SENTINEL, init_cache
 from llm_sharding_tpu.models.config import tiny_llama

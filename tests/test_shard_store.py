@@ -34,6 +34,46 @@ def test_full_roundtrip(store):
         np.testing.assert_array_equal(np.asarray(loaded["layers"][k]), np.asarray(v))
 
 
+def test_no_file_exceeds_the_cap_and_split_store_loads_equal(
+    store, tmp_path, monkeypatch
+):
+    """Units bigger than ``MAX_FILE_BYTES`` continue in ``.part<j>.npz``
+    files (arrays bigger than a file cut along their leading axis) and load
+    back bit-equal, quantized leaves and bf16 views included."""
+    import os
+
+    from llm_sharding_tpu.ops.quant import QTensor, quantize_params
+
+    _, params = store
+    cap = 8 << 10
+    for name, tree, dtype in (
+        ("f32", params, jnp.float32),
+        ("q8", quantize_params(params, quantize_head=True), jnp.float32),
+        ("bf16", jax.tree.map(lambda a: a.astype(jnp.bfloat16), params),
+         jnp.bfloat16),
+    ):
+        whole, split = str(tmp_path / f"{name}_whole"), str(tmp_path / name)
+        shard_store.save_shards(CFG, tree, whole)
+        monkeypatch.setattr(shard_store, "MAX_FILE_BYTES", cap)
+        shard_store.save_shards(CFG, tree, split)
+        monkeypatch.undo()
+        sizes = [os.path.getsize(os.path.join(split, f))
+                 for f in os.listdir(split) if f.endswith(".npz")]
+        assert len(sizes) > len(os.listdir(whole))
+        assert max(sizes) < cap + 4096  # array bytes + npy/zip headers
+        a = shard_store.load_full(split, dtype=dtype)[1]
+        b = shard_store.load_full(whole, dtype=dtype)[1]
+        la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
+        assert jax.tree.structure(a) == jax.tree.structure(b)
+        for x, y in zip(la, lb):
+            assert type(x) is type(y) and x.dtype == y.dtype
+            np.testing.assert_array_equal(
+                np.asarray(x).view(np.uint8), np.asarray(y).view(np.uint8)
+            )
+        if name == "q8":
+            assert isinstance(a["layers"]["wq"], QTensor)
+
+
 def test_role_conditional_loading(store):
     out, _ = store
     L = CFG.num_hidden_layers
