@@ -1,0 +1,462 @@
+"""The benchmark's own tests. Run on the CPU, tiny widths, Pallas in interpret
+mode; not part of the program's ``tests/``:
+
+    JAX_PLATFORMS=cpu python3 -m pytest benchmark/tests -q -p no:cacheprovider
+
+A CPU run here says correct or incorrect and counts; never a speed.
+"""
+
+import json
+import os
+import subprocess
+import sys
+import time
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["XLA_FLAGS"] = (
+    os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=4"
+)
+os.environ["PAGED_FORCE_KERNEL"] = "interpret"
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.dirname(HERE)
+ROOT = os.path.dirname(BENCH)
+sys.path.insert(0, ROOT)
+
+import numpy as np  # noqa: E402
+import pytest  # noqa: E402
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+from benchmark import (  # noqa: E402
+    harness, loadgen, reference, roofline, samples, trace_reduce, weights,
+)
+
+jax.config.update("jax_platforms", "cpu")
+
+
+def load(*parts):
+    with open(os.path.join(*parts)) as f:
+        return json.load(f)
+
+
+TINY = load(HERE, "data", "tiny_qwen2.json")
+CHAT = load(BENCH, "traffic", "chat.json")
+BACKLOG = load(BENCH, "traffic", "backlog.json")
+
+
+# ---------------------------------------------------------------- generator
+
+def plan(seed, traffic=CHAT, rate=2.0, period=10.0):
+    return loadgen.open_schedule(traffic, rate, period, 15.0 + period, 1000, seed)
+
+
+def test_schedule_repeats_for_a_seed_and_only_the_ids_differ_for_another():
+    a, b, c = plan(5), plan(5), plan(2**31 + 17)
+    assert [p.due_s for p in a] == [p.due_s for p in b]
+    assert all(np.array_equal(p.prompt, q.prompt) for p, q in zip(a, b))
+    assert [p.max_new for p in a] == [p.max_new for p in b]
+    # the chat mix as committed: another seed, other token ids — and the
+    # same arrivals and sizes in the same order
+    assert [p.due_s for p in a] == [p.due_s for p in c]
+    assert [len(p.prompt) for p in a] == [len(p.prompt) for p in c]
+    assert not any(np.array_equal(p.prompt, q.prompt) for p, q in zip(a, c))
+
+
+def test_a_window_holds_each_request_of_the_cycle_once():
+    """The schedule repeats with the window as its period, so the window
+    after the ramp holds the cycle's (prompt, reply) lengths exactly once."""
+    period, ramp = 10.0, CHAT["ramp_s"]
+    cycle = loadgen.Shape(CHAT, 20, period)
+    want = sorted(zip(cycle.prompt_len.tolist(), cycle.output_len.tolist()))
+    w = [p for p in plan(2**31 + 5, period=period)
+         if ramp <= p.due_s < ramp + period]
+    assert sorted((len(p.prompt), p.max_new) for p in w) == want
+
+
+def test_the_chat_cells_send_what_their_why_says():
+    """Five requests a window at 0.10 requests/s: the lengths BENCHMARK.json
+    names for the chat cells are the lengths the generator sends."""
+    p = loadgen.open_schedule(CHAT, 0.1, 50.0, 50.0, 1000, 1)
+    assert sorted(len(x.prompt) for x in p) == [53, 114, 192, 324, 692]
+    assert sorted(x.max_new for x in p) == [46, 84, 128, 195, 357]
+
+
+def test_lengths_follow_the_mix():
+    big = loadgen.Shape(CHAT, 2000, 100.0)
+    assert 150 <= np.median(big.prompt_len) <= 240
+    assert big.prompt_len.min() >= 16 and big.prompt_len.max() <= 2048
+    assert 100 <= np.median(big.output_len) <= 160
+    assert abs(big.offset_s[-1] - 100.0) < 100.0 / 2000 * 12
+
+
+def test_shared_prefix_and_sessions_are_parameters():
+    shared = dict(CHAT, sharing={
+        "kind": "shared_prefix", "groups": 3,
+        "prefix_len": {"dist": "fixed", "value": 64}})
+    p = loadgen.open_schedule(shared, 2.0, 10.0, 10.0, 1000, 3)
+    heads = {tuple(x.prompt[:64]) for x in p}
+    assert len(heads) == 3
+    sess = dict(CHAT, sharing={
+        "kind": "sessions", "groups": 2,
+        "prefix_len": {"dist": "fixed", "value": 32}})
+    p = loadgen.open_schedule(sess, 2.0, 10.0, 10.0, 1000, 3, max_prompt=4000)
+    first, third = p[0].prompt, p[2].prompt  # same session, next turn
+    assert np.array_equal(third[: len(first)], first) and len(third) > len(first)
+    bursty = dict(CHAT, arrivals={"process": "gamma", "cv": 3.0})
+    gaps = np.diff([x.due_s for x in
+                    loadgen.open_schedule(bursty, 20.0, 10.0, 10.0, 1000, 3)])
+    assert gaps.std() / gaps.mean() > 1.5
+
+
+def test_closed_loop_clients_cycle_from_the_seed():
+    a = loadgen.ClosedClients(BACKLOG, 4, 1000, 11)
+    b = loadgen.ClosedClients(BACKLOG, 4, 1000, 11)
+    xs, ys = [a.next(i % 4) for i in range(6)], [b.next(i % 4) for i in range(6)]
+    assert all(np.array_equal(x.prompt, y.prompt) for x, y in zip(xs, ys))
+    assert xs[0].due_s is None
+
+
+def test_reachable_buckets():
+    buckets = (8, 16, 32, 64, 128, 256, 512, 1024, 2048, 4096)
+    assert loadgen.reachable_buckets(CHAT, buckets, 3583) == [
+        16, 32, 64, 128, 256, 512, 1024, 2048]
+
+
+# ------------------------------------------------------------------ samples
+
+def test_time_to_first_token_runs_from_the_due_time():
+    rec = {"window": [100.0, 110.0], "tail_s": 3.0, "seconds": 10.0,
+           "requests": [
+               # submitted 2 s late: ttft is 3 s from DUE, not 1 s from submit
+               {"due": 101.0, "submitted": 103.0, "stamps": [104.0, 104.5, 104.5]},
+               # first token before the window: its gaps in the window count
+               {"due": 95.0, "submitted": 95.0, "stamps": [99.0, 100.5]},
+               # overdue: due long before the end, nothing shown
+               {"due": 102.0, "submitted": 102.0, "stamps": []},
+               # due near the end, nothing shown yet: not held against it
+               {"due": 109.0, "submitted": 109.0, "stamps": []},
+           ]}
+    assert sorted(samples.ttft_s(rec)) == [3.0, 8.0]
+    assert samples.overdue(rec) == [8.0]
+    assert sorted(samples.gaps_s(rec)) == [0.0, 0.5, 1.5]
+    assert samples.tokens_in_window(rec) == 4
+    assert samples.percentile([1, 2, 3, 4, 5], 50) == 3
+    # the pump stood still from 103 to 107.5: between two calls of step()
+    rec["pump_marks"] = [(101.0, 103.0, True), (107.5, 108.0, False),
+                         (108.001, 108.002, False)]
+    assert samples.longest_pause_s(rec) == 4.5
+
+
+def test_admissions_group_by_start_and_bucket():
+    rec = {"window": [0.0, 10.0], "requests": [
+        {"server_started_at": 1.0, "prompt_len": 100},
+        {"server_started_at": 1.00001, "prompt_len": 120},
+        {"server_started_at": 5.0, "prompt_len": 20},
+        {"server_started_at": None, "prompt_len": 20},
+    ]}
+    groups = samples.admissions(rec)
+    assert [len(g) for g in groups] == [2, 1]
+    assert samples.bucket(100) == 128 and samples.bucket(8) == 8
+
+
+# ---------------------------------------------------------- trace reduction
+
+def test_interval_arithmetic():
+    u = trace_reduce.union([(0, 5), (3, 8), (10, 12), (12, 13)])
+    assert u == [(0, 8), (10, 13)] and trace_reduce.total(u) == 11
+    assert trace_reduce.subtract(u, [(2, 4), (7, 11)]) == [(0, 2), (4, 7), (11, 13)]
+    assert trace_reduce.module_name("jit_serve_chunk(123abc)") == "serve_chunk"
+    assert trace_reduce.op_name("%while.18 = (s32[]{:T(128)}) while(%a)") == "while.18"
+    nested = [("while.1", 0, 100), ("fusion.2", 10, 40), ("fusion.2", 50, 60),
+              ("copy.3", 100, 120)]
+    st = trace_reduce.self_times(nested)
+    assert st["while.1"] == pytest.approx(60e-9)
+    assert st["fusion.2"] == pytest.approx(40e-9)
+    assert st["copy.3"] == pytest.approx(20e-9)
+
+
+def test_reduction_of_synthetic_planes():
+    ms = 1_000_000
+    planes = {
+        "devices": {
+            0: {"modules": [("jit_serve_chunk(1)", 0, 40 * ms),
+                            ("jit_serve_chunk(1)", 50 * ms, 90 * ms),
+                            ("jit_serve_admit(2)", 100 * ms, 130 * ms)],
+                "ops": [("fusion.1", 0, 30 * ms),
+                        ("collective-permute.3", 30 * ms, 40 * ms),
+                        ("fusion.1", 50 * ms, 90 * ms),
+                        ("all-reduce.7", 80 * ms, 95 * ms),
+                        ("fusion.2", 100 * ms, 130 * ms)]},
+        },
+        "host": {"bench.step": [(0, 45 * ms), (50 * ms, 99 * ms),
+                                (140 * ms, 160 * ms)],
+                 "bench.submit": [(96 * ms, 98 * ms)]},
+    }
+    out = trace_reduce.reduce_planes(planes, 0.2, [(0, 135 * ms)])
+    chip = out["chips"][0]
+    assert chip["busy_s"] == pytest.approx(0.115)
+    assert chip["idle_pct"] == pytest.approx(42.5)
+    assert chip["collective_s"] == pytest.approx(0.025)
+    assert chip["collective_exposed_s"] == pytest.approx(0.015)
+    assert out["modules"]["serve_chunk"] == [[0.04, 0.04]]
+    assert out["breakdown"]["device_ops"][0] == ["fusion.1", pytest.approx(0.06)]
+    gaps = dict(out["breakdown"]["idle_gaps"])
+    # the window is 0-200 (no stamp: from the first operation). gaps: 40-50
+    # (5 in step, 5 between), 95-100 (4 in step incl 2 of submit), and after
+    # the last operation 130-200: 5 between steps while a request was still
+    # there, then 65 with the server empty
+    assert gaps["bench.step"] == pytest.approx(0.009)
+    assert gaps["between steps"] == pytest.approx(0.011)
+    assert gaps["no request in flight"] == pytest.approx(0.065)
+    assert gaps["longest single gap"] == pytest.approx(0.070)
+    assert sum(v for k, v in gaps.items() if k != "longest single gap") == (
+        pytest.approx(0.200 - chip["busy_s"]))
+    # a trace with no operation at all is one gap, not none
+    planes["devices"][0]["ops"] = []
+    empty = dict(trace_reduce.idle_gaps(planes, [(0, 135 * ms)]))
+    assert empty["longest single gap"] == pytest.approx(0.160)
+
+
+def test_a_chip_that_never_rests_is_not_busier_than_the_window():
+    """The profiler records from before the harness's stamp until after its
+    last look at the clock (on the ring, 2 ms more than the window on a chip
+    that idles 0.1%): everything is cut to the stamped window, so busy_s can
+    reach window_s and not pass it, and an execution that began before the
+    stamp is not the window's."""
+    ms = 1_000_000
+    planes = {
+        "devices": {c: {
+            "modules": [("jit_serve_chunk(1)", -30 * ms, 10 * ms),
+                        ("jit_serve_chunk(1)", 10 * ms, 50 * ms),
+                        ("jit_serve_chunk(1)", 95 * ms, 135 * ms)],
+            "ops": [("while.1", -30 * ms, 135 * ms),
+                    ("fusion.1", -30 * ms, 60 * ms),
+                    ("collective-permute.3", 90 * ms, 120 * ms)],
+            "async": [("collective-permute-start.3", 85 * ms, 125 * ms)],
+        } for c in (0, 1)},
+        "host": {"bench.traced": [(0, 1)],
+                 "bench.step": [(-40 * ms, 140 * ms)]},
+    }
+    out = trace_reduce.reduce_planes(planes, 0.1, [(-50 * ms, 300 * ms)])
+    assert out["window_s"] == 0.1
+    assert out["busy_s"] == pytest.approx(0.1)
+    assert [c["idle_pct"] for c in out["chips"]] == [pytest.approx(0.0)] * 2
+    assert out["collective_s"] == pytest.approx(0.015)  # 85-100
+    assert out["modules"]["serve_chunk"] == [[0.04, 0.04]] * 2
+    ops = dict(out["breakdown"]["device_ops"])
+    assert ops["fusion.1"] == pytest.approx(0.06)
+    assert ops["while.1"] == pytest.approx(0.03)
+    assert out["breakdown"]["idle_gaps"] == []
+    # with the stamp 20 ms before the first operation, that is idle time
+    planes["host"]["bench.traced"] = [(-50 * ms, -50 * ms + 1)]
+    out = trace_reduce.reduce_planes(planes, 0.1, [(-50 * ms, 300 * ms)])
+    assert out["busy_s"] == pytest.approx(0.08)
+    gaps = dict(out["breakdown"]["idle_gaps"])
+    assert gaps["between steps"] == pytest.approx(0.010)
+    assert gaps["bench.step"] == pytest.approx(0.010)
+
+
+RECORDED = os.path.join(HERE, "data", "small.xplane.pb")
+
+
+@pytest.mark.skipif(not os.path.exists(RECORDED), reason="no recorded trace")
+def test_reduction_of_a_recorded_trace():
+    """A short trace recorded on a TPU v5 lite (tests/record_trace.py): four
+    executions of one jitted matmul chain inside bench.step annotations."""
+    planes = trace_reduce.read_planes(RECORDED)
+    assert sorted(planes["devices"]) == [0]
+    expect = load(HERE, "data", "small.expect.json")
+    out = trace_reduce.reduce_planes(planes, expect["window_s"])
+    assert len(out["modules"]["bench_probe"][0]) == expect["executions"]
+    assert out["busy_s"] == pytest.approx(expect["busy_s"], rel=1e-9)
+    assert 0.0 < out["idle_pct"] < 100.0
+    assert len(planes["host"]["bench.step"]) == expect["executions"]
+    assert out["collective_s"] == 0.0
+    assert out["breakdown"]["device_ops"]
+
+
+# ---------------------------------------------------------------- reference
+
+MODEL = harness.model_keys(TINY)
+
+
+def tiny_weights(seed=3, dtype="int8"):
+    params = weights.make_params(MODEL, seed, dtype, jax.devices()[:1])
+    tables = {k: params[k] for k in ("embed", "final_norm", "lm_head")}
+    get = lambda l: jax.tree.map(lambda a: a[l], params["layers"])
+    return params, tables, get
+
+
+def greedy(tables, get, prompt, n, **wrong):
+    """n greedy tokens from the reference, optionally made wrong."""
+    ids = list(prompt)
+    out = []
+    for _ in range(n):
+        (h,) = reference.hidden_states(MODEL, get, tables["embed"], [ids], **wrong)
+        _, best = reference.margins_from_hidden(
+            h[len(ids) - 1 : len(ids)], tables["final_norm"], tables["lm_head"],
+            jnp.zeros((1,), jnp.int32), eps=float(MODEL["rms_norm_eps"]))
+        out.append(int(best[0]))
+        ids.append(out[-1])
+    return np.asarray(out, np.int32)
+
+
+def test_weights_repeat_for_a_seed_and_differ_for_another():
+    params, _, _ = tiny_weights()
+    again, _, _ = tiny_weights()
+    assert np.array_equal(np.asarray(params["layers"]["wq"].q),
+                          np.asarray(again["layers"]["wq"].q))
+    other, _, _ = tiny_weights(seed=2**31 + 3)
+    assert not np.array_equal(np.asarray(params["embed"]), np.asarray(other["embed"]))
+    assert float(jnp.abs(params["layers"]["bq"].astype(jnp.float32)).mean()) > 0.01
+
+
+def test_weights_split_over_a_ring_are_the_same_weights():
+    one = weights.make_params(MODEL, 7, "bf16", jax.devices()[:1])
+    four = weights.make_params(MODEL, 7, "bf16", jax.devices()[:4])
+    for a, b in zip(jax.tree.leaves(one), jax.tree.leaves(four)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_reference_agrees_with_itself_and_refuses_a_wrong_model():
+    _, tables, get = tiny_weights()
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, 512, size=n, dtype=np.int32) for n in (12, 20, 31)]
+    right = [(p, greedy(tables, get, p, 6)) for p in prompts]
+    a = reference.score(MODEL, get, tables, right)
+    b = reference.score(MODEL, get, tables, right[::-1])
+    assert a["margin_max"] == 0.0 and a["argmax_share"] == 1.0
+    assert a["margin_mean"] == pytest.approx(b["margin_mean"], abs=1e-6)
+    assert reference.verdict(a)
+
+    def no_bias(l):
+        p = dict(get(l))
+        for k in ("bq", "bk", "bv"):
+            p[k] = jnp.zeros_like(p[k])
+        return p
+
+    wrong = {
+        "dropped bias": [(p, greedy(tables, no_bias, p, 6)) for p in prompts],
+        "rotary base": [(p, greedy(tables, get, p, 6, theta=1e4)) for p in prompts],
+        "4-bit cache": [(p, greedy(tables, get, p, 6, kv_round="int4"))
+                        for p in prompts],
+    }
+    for what, served in wrong.items():
+        s = reference.score(MODEL, get, tables, served)
+        assert not reference.verdict(s), (what, s)
+    # an int8 cache errs by less than bf16 arithmetic does: at four layers
+    # it flips no token at all, and token margins cannot see it (PERF.md)
+    fine = [(p, greedy(tables, get, p, 6, kv_round="int8")) for p in prompts]
+    assert reference.score(MODEL, get, tables, fine)["margin_mean"] < reference.DELTA_MEAN
+
+
+# ----------------------------------------------------------------- roofline
+
+def test_bytes_of_a_decode_step():
+    m7 = harness.model_keys(load(BENCH, "configs", "qwen25_7b.json"))
+    layer = roofline.layer_weight_bytes(m7, "int8")
+    assert 28 * layer == pytest.approx(6.53e9, rel=0.01)
+    assert roofline.head_bytes(m7) == 3584 * 152064 * 2
+    assert roofline.kv_bytes_per_token_layer(m7) * 28 == 56 * 1024
+    full = roofline.decode_step_bytes(m7, "int8", 1, 1000.0)
+    assert full == pytest.approx(28 * layer + roofline.head_bytes(m7) + 1000 * 57344)
+    m14 = harness.model_keys(load(BENCH, "configs", "qwen25_14b_pp4.json"))
+    assert 48 * roofline.layer_weight_bytes(m14, "bf16") == pytest.approx(
+        26.4e9, rel=0.01)
+
+
+# ------------------------------------------------------------------ the run
+
+def test_run_py_finds_no_tpu_on_the_cpu():
+    r = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "run.py"), "--workload",
+         "qwen25_7b.chat", "--seed", "1", "--seconds", "2", "--trace", "0"],
+        cwd=ROOT, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=120,
+    )
+    assert r.returncode != 0
+    assert "found no TPU" in r.stderr
+    assert "{" not in r.stdout  # no result line
+
+
+def _readers():
+    sys.argv = ["run.py"]
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "bench_run", os.path.join(BENCH, "run.py"))
+    run = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(run)
+    bench = load(ROOT, "BENCHMARK.json")
+    cell = bench["workloads"][0]["name"]
+    return (run.load_readers(bench, "end_to_end", cell),
+            run.load_readers(bench, "per_layer", cell), bench)
+
+
+def run_tiny(loop, stages, tmp_path, readers):
+    """What run.py calls after its device check, given a tiny configuration
+    and the CPU's devices by this test."""
+    cfg = json.loads(json.dumps(TINY))
+    if stages > 1:
+        cfg["deployment"].update(num_stages=stages, weight_dtype="bf16")
+    traffic = json.loads(json.dumps(CHAT if loop == "chat" else BACKLOG))
+    traffic["prompt_len"].update(median=24, max=100)
+    traffic["output_len"].update(median=8, max=24, min=2)
+    traffic.update(ramp_s=1.0, tail_s=4.0)
+    return harness.run_cell(
+        cell={"name": "tiny." + loop}, cfg_file=cfg, traffic=traffic,
+        cell_params={"rate_rps": 4.0, "clients_per_row": 2},
+        devices=jax.devices()[:stages], seed=2**31 + 9, seconds=4.0,
+        trace=False, out_dir=str(tmp_path), t_process=time.perf_counter(),
+        readers=readers, attn="auto",
+    )
+
+
+@pytest.mark.parametrize("loop,stages", [("chat", 1), ("backlog", 1), ("chat", 4)])
+def test_a_cell_runs_end_to_end_on_the_cpu(loop, stages, tmp_path):
+    """Both loop kinds, one chip and a ring. Counts and correctness only."""
+    e2e, layer, bench = _readers()
+    got = run_tiny(loop, stages, tmp_path, e2e)
+    res, rec = got["result"], got["records"]
+    assert set(res) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert res["correct"], rec["reference"]
+    assert res["failed"] == 0 and res["attempted"] > 0
+    assert set(res["metrics"]) == {m["name"] for m in bench["end_to_end"]}
+    assert all(m["value"] > 0 for m in res["metrics"].values())
+    assert res["device"]["platform"] == "cpu"  # named for what it is
+    assert rec["paths"]["attn_backend"] == "interpret"
+    assert rec["paths"]["prefix_hit_tokens"] == 0
+    assert rec["paths"]["arena_dtype"] == ["bfloat16"] and rec["arena_ok"]
+    assert rec["compiles_in_window"] == 0
+    # host-side per-layer readers work on an untraced run's records too
+    for name in ("rows_per_step.chat", "queue_wait_p95_ms.chat",
+                 "prompt_pad_pct.chat", "kv_in_use_peak_pct.chat",
+                 "host_ms_per_step.chat", "loadgen_late_p95_ms.chat",
+                 "ttft_median_ms.chat", "ttft_max_ms.chat"):
+        assert layer[name][0](rec) is not None, name
+    assert layer["ttft_max_ms.chat"][0](rec) >= layer["ttft_median_ms.chat"][0](rec)
+    # and the trace readers return nothing where there is no trace
+    assert layer["device_idle_pct.chat"][0](rec) is None
+    json.dumps(rec, default=float)
+
+
+def test_a_quantised_arena_under_a_bf16_label_is_not_correct(
+        tmp_path, monkeypatch):
+    """A program that kept int8 codes in the arena while the configuration
+    says bf16: the token margins pass (they cannot see it), the arena's own
+    type does not."""
+    build = harness.build_server
+
+    def quantising(cfg_file, *args, **kw):
+        lying = json.loads(json.dumps(cfg_file))
+        lying["serve"]["kv_dtype"] = "int8"
+        return build(lying, *args, **kw)
+
+    monkeypatch.setattr(harness, "build_server", quantising)
+    got = run_tiny("chat", 1, tmp_path, _readers()[0])
+    rec = got["records"]
+    assert rec["paths"]["arena_dtype"] == ["int8"]
+    assert rec["paths"]["arena_dtype_wanted"] == "bfloat16"
+    assert reference.verdict(rec["reference"]), rec["reference"]
+    assert not got["result"]["correct"]
