@@ -135,45 +135,58 @@ def attn_mlp_block(
     Nh = out_dim(p["wq"]) // D
     Nkv = out_dim(p["wk"]) // D
 
-    x = rms_norm(h, p["input_norm"], cfg.rms_norm_eps, cfg.norm_offset)
+    # The named scopes are words of ``obs.stepline.SCOPES``: a profiler
+    # trace names every device operation of the block by them.
+    with jax.named_scope("norm"):
+        x = rms_norm(h, p["input_norm"], cfg.rms_norm_eps, cfg.norm_offset)
     # Optional projection biases, keyed by PRESENCE (the Qwen2-family layout
     # biases q/k/v only — ``bq``/``bk``/``bv`` from the converter; column-
     # parallel under TP so each shard adds its slice before rope/attention)
-    qx, kx, vx = qmatmul(x, p["wq"]), qmatmul(x, p["wk"]), qmatmul(x, p["wv"])
-    if "bq" in p:
-        qx = qx + p["bq"]
-    if "bk" in p:
-        kx = kx + p["bk"]
-    if "bv" in p:
-        vx = vx + p["bv"]
-    q = apply_rope(qx.reshape(B, S, Nh, D), cos, sin)
-    k = apply_rope(kx.reshape(B, S, Nkv, D), cos, sin)
-    v = vx.reshape(B, S, Nkv, D)
+    with jax.named_scope("qkv"):
+        qx, kx, vx = (
+            qmatmul(x, p["wq"]), qmatmul(x, p["wk"]), qmatmul(x, p["wv"])
+        )
+        if "bq" in p:
+            qx = qx + p["bq"]
+        if "bk" in p:
+            kx = kx + p["bk"]
+        if "bv" in p:
+            vx = vx + p["bv"]
+    with jax.named_scope("rope"):
+        q = apply_rope(qx.reshape(B, S, Nh, D), cos, sin)
+        k = apply_rope(kx.reshape(B, S, Nkv, D), cos, sin)
+        v = vx.reshape(B, S, Nkv, D)
 
     attn = attn_fn(q, k, v)
-    attn_out = qmatmul(attn.reshape(B, S, Nh * D), p["wo"])
-    if tp_axis is not None:
-        attn_out = jax.lax.psum(attn_out, tp_axis)
-    if "bo" in p:  # row-parallel bias: added ONCE, after the psum
-        attn_out = attn_out + p["bo"]
-    h = h + attn_out
+    with jax.named_scope("o_proj"):
+        attn_out = qmatmul(attn.reshape(B, S, Nh * D), p["wo"])
+        if tp_axis is not None:
+            attn_out = jax.lax.psum(attn_out, tp_axis)
+        if "bo" in p:  # row-parallel bias: added ONCE, after the psum
+            attn_out = attn_out + p["bo"]
+        h = h + attn_out
 
-    x = rms_norm(h, p["post_norm"], cfg.rms_norm_eps, cfg.norm_offset)
+    with jax.named_scope("norm"):
+        x = rms_norm(h, p["post_norm"], cfg.rms_norm_eps, cfg.norm_offset)
     # gated MLP: activation per family (llama/qwen2 silu, gemma gelu-tanh).
     # The fp32 cast is a deliberate local deviation from HF (which runs the
     # act in model dtype): exact in the f32 parity tests, slightly more
     # accurate than HF in bf16.
-    gate = qmatmul(x, p["w_gate"]).astype(jnp.float32)
-    if cfg.hidden_act == "gelu_tanh":
-        act = jax.nn.gelu(gate, approximate=True)
-    elif cfg.hidden_act == "silu":
-        act = jax.nn.silu(gate)
-    else:  # catch raw HF spellings on hand-built configs, not silently silu
-        raise ValueError(f"unsupported hidden_act {cfg.hidden_act!r}")
-    mlp = qmatmul(act.astype(x.dtype) * qmatmul(x, p["w_up"]), p["w_down"])
-    if tp_axis is not None:
-        mlp = jax.lax.psum(mlp, tp_axis)
-    return h + mlp
+    with jax.named_scope("mlp"):
+        gate = qmatmul(x, p["w_gate"]).astype(jnp.float32)
+        if cfg.hidden_act == "gelu_tanh":
+            act = jax.nn.gelu(gate, approximate=True)
+        elif cfg.hidden_act == "silu":
+            act = jax.nn.silu(gate)
+        else:  # catch raw HF spellings on hand-built configs, not silently
+            # silu
+            raise ValueError(f"unsupported hidden_act {cfg.hidden_act!r}")
+        mlp = qmatmul(
+            act.astype(x.dtype) * qmatmul(x, p["w_up"]), p["w_down"]
+        )
+        if tp_axis is not None:
+            mlp = jax.lax.psum(mlp, tp_axis)
+        return h + mlp
 
 
 def decoder_layer(
@@ -192,12 +205,13 @@ def decoder_layer(
     rows = {}
 
     def attn_fn(q, k, v):
-        k_r = jax.lax.dynamic_update_slice(
-            k_row, k.astype(k_row.dtype), (0, length, 0, 0)
-        )
-        v_r = jax.lax.dynamic_update_slice(
-            v_row, v.astype(v_row.dtype), (0, length, 0, 0)
-        )
+        with jax.named_scope("kv_write"):
+            k_r = jax.lax.dynamic_update_slice(
+                k_row, k.astype(k_row.dtype), (0, length, 0, 0)
+            )
+            v_r = jax.lax.dynamic_update_slice(
+                v_row, v.astype(v_row.dtype), (0, length, 0, 0)
+            )
         rows["k"], rows["v"] = k_r, v_r
         return attention_step(q, k_r, v_r, positions, kv_positions, length)
 
@@ -315,7 +329,8 @@ def forward_layers_paged(
     kpos bookkeeping stays with the caller."""
     from .stack import scan_layers_paged
 
-    cos, sin = rope_cos_sin(positions, cfg, dtype=jnp.float32)
+    with jax.named_scope("rope"):
+        cos, sin = rope_cos_sin(positions, cfg, dtype=jnp.float32)
     wv = write_valid if isinstance(write_valid, bool) else jnp.asarray(
         write_valid
     )
@@ -351,7 +366,8 @@ def forward_layers(
     splits"). ``tp_axis`` turns on explicit megatron TP inside every layer
     (weights and KV cache must carry the matching local head slices).
     """
-    cos, sin = rope_cos_sin(positions, cfg, dtype=jnp.float32)
+    with jax.named_scope("rope"):
+        cos, sin = rope_cos_sin(positions, cfg, dtype=jnp.float32)
 
     def apply(p, h, k_row, v_row, kv_pos, length):
         return decoder_layer(

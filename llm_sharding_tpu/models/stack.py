@@ -49,20 +49,22 @@ def scan_layers(
     def body(carry, xs):
         h, k_all, v_all = carry
         p, l, valid = xs
-        k_row = jax.lax.dynamic_index_in_dim(k_all, l, keepdims=False)
-        v_row = jax.lax.dynamic_index_in_dim(v_all, l, keepdims=False)
+        with jax.named_scope("kv_take"):
+            k_row = jax.lax.dynamic_index_in_dim(k_all, l, keepdims=False)
+            v_row = jax.lax.dynamic_index_in_dim(v_all, l, keepdims=False)
         h_new, k_new, v_new = apply_layer(p, h, k_row, v_row, kv_pos, cache.length)
         h = jnp.where(valid, h_new, h)
-        # the layer only changed positions [length, length+S) of its row
-        start = (0, cache.length, 0, 0)
-        new_k = jax.lax.dynamic_slice(k_new, start, (k_new.shape[0], S, *k_new.shape[2:]))
-        new_v = jax.lax.dynamic_slice(v_new, start, (v_new.shape[0], S, *v_new.shape[2:]))
-        old_k = jax.lax.dynamic_slice(k_row, start, new_k.shape)
-        old_v = jax.lax.dynamic_slice(v_row, start, new_v.shape)
-        new_k = jnp.where(valid, new_k, old_k)
-        new_v = jnp.where(valid, new_v, old_v)
-        k_all = jax.lax.dynamic_update_slice(k_all, new_k[None], (l, *start))
-        v_all = jax.lax.dynamic_update_slice(v_all, new_v[None], (l, *start))
+        with jax.named_scope("kv_put"):
+            # the layer only changed positions [length, length+S) of its row
+            start = (0, cache.length, 0, 0)
+            new_k = jax.lax.dynamic_slice(k_new, start, (k_new.shape[0], S, *k_new.shape[2:]))
+            new_v = jax.lax.dynamic_slice(v_new, start, (v_new.shape[0], S, *v_new.shape[2:]))
+            old_k = jax.lax.dynamic_slice(k_row, start, new_k.shape)
+            old_v = jax.lax.dynamic_slice(v_row, start, new_v.shape)
+            new_k = jnp.where(valid, new_k, old_k)
+            new_v = jnp.where(valid, new_v, old_v)
+            k_all = jax.lax.dynamic_update_slice(k_all, new_k[None], (l, *start))
+            v_all = jax.lax.dynamic_update_slice(v_all, new_v[None], (l, *start))
         return (h, k_all, v_all), None
 
     (h, k_all, v_all), _ = jax.lax.scan(
@@ -102,12 +104,16 @@ def scan_layers_paged(
     if layer_mask is None:
         layer_mask = jnp.ones((L,), bool)
 
+    # kv_take / kv_put: the scopes a profiler trace files the per-layer
+    # arena slice and write-back under (obs.stepline.SCOPES)
+    @jax.named_scope("kv_take")
     def take(all_, l):
         return (
             None if all_ is None
             else jax.lax.dynamic_index_in_dim(all_, l, keepdims=False)
         )
 
+    @jax.named_scope("kv_put")
     def put(all_, l, one):
         if all_ is None:
             return None

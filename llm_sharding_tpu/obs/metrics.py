@@ -613,6 +613,16 @@ PREFILL_BLOCKS_READ = REGISTRY.counter(
     "prefill-attention-HBM estimate; the retired gather path moved the "
     "row's WHOLE mapped window in AND out per chunk on top of this",
 )
+PREFILL_POSITIONS = REGISTRY.counter(
+    "server_prefill_positions_total",
+    "Token positions computed by prefill dispatches (serve_admit, "
+    "serve_prefill_chunk), rows x positions each: kind=prompt are real "
+    "prompt tokens (a radix hit's matched prefix is not prefilled and not "
+    "counted), kind=pad the rest — empty rows of the slot and the padding "
+    "up to the admit bucket or chunk. pad / (prompt + pad) is the share of "
+    "prefill compute that no prompt needed",
+    labels=("kind",),
+)
 
 
 def set_prefill_path(path: str) -> None:

@@ -47,12 +47,28 @@ newest of the overlapped dispatches has already landed before the next
 dispatch, the device queue truly drained and the gap is a bubble; if any
 older entry is still in flight the device is busy and no idle is charged.
 
+Profiler annotations: the same phase stack also writes into the JAX
+profiler's trace, on the profiler's clock, through an injected ``annotate``
+factory (the server passes ``jax.profiler``'s; this module never imports
+jax). ``begin_step``/``end_step`` bracket ``serve.step`` (a step marker
+with ``step_num`` and the ``rows``/``queued``/``pending`` the server held at
+the step's START), ``push``/``pop`` enter and exit ``serve.<phase>``,
+``blocking`` wraps a wait on the device in ``serve.blocked``, ``prefill``
+wraps one prefill dispatch in ``serve.prefill``. Idle polls write nothing:
+a step is annotated when the server holds work at its start, and the one
+step after the last such is too (all three counts 0 — the end of work).
+Outside a profiler session an annotation costs about a microsecond; the
+session is the only switch. The device side of the same trace is named by
+``SCOPES``: the closed vocabulary of ``jax.named_scope`` words in the step
+programs (README "Step profiling" maps both).
+
 Everything here is stdlib-only: ``step-report`` and the lint/obs tooling
 must run without jax.
 """
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 import weakref
@@ -82,6 +98,37 @@ PHASES = (
 )
 
 _PHASE_SET = frozenset(PHASES)
+
+#: Profiler annotation names. One per phase, plus the step, the device wait
+#: and the prefill dispatch; a reader of the trace matches on these.
+STEP_ANNOTATION = "serve.step"
+BLOCKED_ANNOTATION = "serve.blocked"
+PREFILL_ANNOTATION = "serve.prefill"
+_PHASE_ANNOTATION = {p: "serve." + p for p in PHASES}
+
+#: The closed vocabulary of ``jax.named_scope`` words in the step programs
+#: (``parallel/serve.py`` and everything they call). A device operation in a
+#: profiler trace carries its scope path in ``tf_op``; the innermost of these
+#: words names what the operation is for. Program, trace reader
+#: (``benchmark/span_reduce.py``) and README agree through this tuple.
+SCOPES = (
+    "embed",      # token embedding lookup (vocab-parallel psum included)
+    "norm",       # the block's two RMS/layer norms
+    "qkv",        # q/k/v projections and their biases
+    "rope",       # rotary tables and their application
+    "kv_write",   # this step's fresh KV entries into their blocks/rows
+    "kv_take",    # one layer sliced out of the arena (scan_layers_paged)
+    "kv_layout",  # head-major transposes of the K/V operands of a kernel
+    "kv_put",     # the layer written back into the arena
+    "attn",       # the attention kernel / XLA attention and its GQA fold
+    "o_proj",     # output projection, its psum, the residual add
+    "mlp",        # gated MLP, its psum, the residual add
+    "head",       # final norm + this stage's logit slice
+    "sample",     # argmax assembly / per-row sampling over the logits
+    "ring_hop",   # stage->stage ppermute and the last stage's broadcast
+    "state",      # ServeState row slicing and bookkeeping (everything in a
+                  # step program's body that no inner word names)
+)
 
 STEP_PHASE = REGISTRY.histogram(
     "server_step_phase_seconds",
@@ -155,12 +202,13 @@ class StepRecord:
     __slots__ = (
         "ts", "wall_s", "phases", "blocked_s", "idle_s", "unattributed_s",
         "rows", "tokens", "queued", "pending", "segments", "lock_waits",
-        "exemplars",
+        "exemplars", "prompt_tokens", "prefill_positions",
     )
 
     def __init__(self, ts, wall_s, phases, blocked_s, idle_s,
                  unattributed_s, rows, tokens, queued, pending,
-                 segments=None, lock_waits=None, exemplars=None):
+                 segments=None, lock_waits=None, exemplars=None,
+                 prompt_tokens=0, prefill_positions=0):
         self.ts = ts
         self.wall_s = wall_s
         self.phases = phases
@@ -174,6 +222,10 @@ class StepRecord:
         self.segments = segments
         self.lock_waits = lock_waits
         self.exemplars = exemplars
+        # prefill dispatched in this step: real prompt tokens, and the
+        # rows x positions the programs computed for them (padding included)
+        self.prompt_tokens = prompt_tokens
+        self.prefill_positions = prefill_positions
 
     @property
     def host_s(self) -> float:
@@ -195,6 +247,8 @@ class StepRecord:
             "occupancy": self.occupancy,
             "rows": self.rows,
             "tokens": self.tokens,
+            "prompt_tokens": self.prompt_tokens,
+            "prefill_positions": self.prefill_positions,
             "queued": self.queued,
             "pending": self.pending,
         }
@@ -212,11 +266,14 @@ class StepProfiler:
 
     ``clock`` is injectable for tests (defaults to ``time.perf_counter``).
     ``set_enabled(False)`` turns every builder call into a boolean check —
-    the overhead bench's "off" arm."""
+    the overhead bench's "off" arm. ``annotate`` is the profiler-annotation
+    factory, ``annotate(name, **stats)`` → context manager (the server
+    passes ``jax.profiler``'s; None writes no annotations)."""
 
     def __init__(self, ring_size: int = 512,
                  clock: Callable[[], float] = time.perf_counter,
-                 name: str = "server"):
+                 name: str = "server",
+                 annotate: Optional[Callable] = None):
         if ring_size < 1:
             raise ValueError(f"ring_size must be >= 1, got {ring_size}")
         self.name = name
@@ -230,7 +287,12 @@ class StepProfiler:
         # builder state (step-pump thread only; unlocked by design)
         self._t0: Optional[float] = None
         self._step_armed = False
-        self._stack: List[list] = []  # [name, start, excluded_s]
+        self._stack: List[list] = []  # [name, start, excluded_s, span]
+        self._annotate = annotate
+        self._step_span = None  # the open serve.step; None = unannotated
+        self._had_work = False  # the previous step began with work
+        self._prompt_tokens = 0
+        self._prefill_positions = 0
         self._phases: Dict[str, float] = {}
         self._blocked_s = 0.0
         self._idle_s = 0.0
@@ -296,14 +358,49 @@ class StepProfiler:
 
     # -- builder API (step-pump thread only) --------------------------------
 
-    def begin_step(self) -> None:
+    def _enter(self, name: str, **stats):
+        """Open one profiler annotation — only inside an annotated step."""
+        if self._step_span is None:
+            return None
+        span = self._annotate(name, **stats)
+        span.__enter__()
+        return span
+
+    @staticmethod
+    def _exit(span) -> None:
+        if span is not None:
+            span.__exit__(None, None, None)
+
+    def begin_step(self, rows: int = 0, queued: int = 0,
+                   pending: int = 0) -> None:
+        """Open a step. ``rows``/``queued``/``pending`` are what the server
+        holds NOW (active rows, queued requests, un-applied logs): a step
+        that begins with any, and the one step after the last such, is
+        written into the profiler's trace; idle polls are not."""
         if not self._enabled:
             return
+        if self._step_span is not None:
+            # the previous step raised before end_step: close what it left
+            # open, innermost first, so the trace stays properly nested
+            for entry in reversed(self._stack):
+                self._exit(entry[3])
+            self._exit(self._step_span)
+            self._step_span = None
         self._t0 = self._clock()
         self._stack = []
         self._phases = {}
         self._blocked_s = 0.0
         self._idle_s = 0.0
+        self._prompt_tokens = 0
+        self._prefill_positions = 0
+        work = bool(rows or queued or pending)
+        if self._annotate is not None and (work or self._had_work):
+            self._step_span = self._annotate(
+                STEP_ANNOTATION, step_num=self.steps_total, rows=int(rows),
+                queued=int(queued), pending=int(pending),
+            )
+            self._step_span.__enter__()
+        self._had_work = work
         # a step only joins the capture window if it was armed at BEGIN —
         # arming mid-step (the /profilez handler races the pump) must not
         # count the half-observed step, which has no segment timeline
@@ -325,13 +422,16 @@ class StepProfiler:
             return
         if phase not in _PHASE_SET:
             raise ValueError(f"unknown phase {phase!r}; one of {PHASES}")
-        self._stack.append([phase, self._clock(), 0.0])
+        self._stack.append(
+            [phase, self._clock(), 0.0, self._enter(_PHASE_ANNOTATION[phase])]
+        )
 
     def pop(self) -> None:
         if not self._enabled or self._t0 is None or not self._stack:
             return
-        name, start, excluded = self._stack.pop()
+        name, start, excluded, span = self._stack.pop()
         now = self._clock()
+        self._exit(span)
         elapsed = now - start
         self._phases[name] = self._phases.get(name, 0.0) + max(
             0.0, elapsed - excluded
@@ -351,6 +451,44 @@ class StepProfiler:
         self._blocked_s += dt
         if self._stack:
             self._stack[-1][2] += dt
+
+    @contextlib.contextmanager
+    def blocking(self):
+        """Around a wait on the device (the log has not landed on host):
+        the wait is accounted as ``blocked_s`` — excluded from the phase it
+        interrupts, like ``blocked`` — and written as ``serve.blocked``."""
+        if not self._enabled or self._t0 is None:
+            yield
+            return
+        span = self._enter(BLOCKED_ANNOTATION)
+        t = self._clock()
+        try:
+            yield
+        finally:
+            self.blocked(self._clock() - t)
+            self._exit(span)
+
+    @contextlib.contextmanager
+    def prefill(self, rows: int, prompt_tokens: int, positions: int):
+        """Around ONE prefill dispatch: ``rows`` the program computes,
+        ``prompt_tokens`` the real prompt tokens among them (a radix hit's
+        matched prefix is not prefilled and not counted), ``positions`` =
+        rows x positions computed (padding to the bucket or chunk
+        included). Adds both to the step's record and writes
+        ``serve.prefill`` with the three as stats."""
+        if not self._enabled or self._t0 is None:
+            yield
+            return
+        self._prompt_tokens += int(prompt_tokens)
+        self._prefill_positions += int(positions)
+        span = self._enter(
+            PREFILL_ANNOTATION, rows=int(rows),
+            prompt_tokens=int(prompt_tokens), positions=int(positions),
+        )
+        try:
+            yield
+        finally:
+            self._exit(span)
 
     def idle(self, dt: float) -> None:
         """Account an estimated device-idle bubble (log landed on host at
@@ -386,6 +524,8 @@ class StepProfiler:
             self.pop()
         wall = max(self._clock() - self._t0, 0.0)
         self._t0 = None
+        self._exit(self._step_span)
+        self._step_span = None
         phases = self._phases
         host = sum(phases.values())
         unattributed = max(0.0, wall - host - self._blocked_s)
@@ -402,7 +542,8 @@ class StepProfiler:
             unattributed_s=unattributed, rows=int(rows), tokens=int(tokens),
             queued=int(queued), pending=int(pending),
             segments=self._segments, lock_waits=lock_waits,
-            exemplars=self._exemplars,
+            exemplars=self._exemplars, prompt_tokens=self._prompt_tokens,
+            prefill_positions=self._prefill_positions,
         )
         with self._ring_mu:
             if len(self._ring) < self._ring_size:

@@ -161,8 +161,9 @@ def flash_attention(
     Lp = L + pad_q
 
     # head-major layouts for Mosaic (sublane, lane) = (seq, head_dim) tiling
-    kh = jnp.transpose(k_cache, (0, 2, 1, 3))  # [B, Nkv, Cp, D]
-    vh = jnp.transpose(v_cache, (0, 2, 1, 3))
+    with jax.named_scope("kv_layout"):
+        kh = jnp.transpose(k_cache, (0, 2, 1, 3))  # [B, Nkv, Cp, D]
+        vh = jnp.transpose(v_cache, (0, 2, 1, 3))
     qp = qp[..., None]  # [B, Lp, 1] — sublane-major (see kernel)
     kp = kv_positions[:, None, :]  # [B, 1, Cp] — lane-major
 
@@ -190,6 +191,7 @@ def flash_attention(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash",
     )(qh, kh, vh, qp, kp)
     out = out[:, :, :L].reshape(B, Nkv, G, S, D)
     return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(B, S, Nh, D)
@@ -217,6 +219,7 @@ def attention_prefill(
     return cached_attention(q, k_cache, v_cache, q_positions, kv_positions, scale)
 
 
+@jax.named_scope("attn")
 def attention_step(
     q: jnp.ndarray,
     k_cache: jnp.ndarray,

@@ -192,6 +192,7 @@ def gather_block_kv(
     )
 
 
+@jax.named_scope("kv_write")
 def write_block_kv(
     k_arena: jnp.ndarray,  # [NB, BS, Nkv, D] pooled key blocks
     v_arena: jnp.ndarray,  # [NB, BS, Nkv, D]
@@ -369,6 +370,7 @@ def attn_stats_xla(
     return acc, to_bsn(m), to_bsn(l)
 
 
+@jax.named_scope("attn")
 def combine_attn_stats(
     acc: jnp.ndarray,  # [B, S, Nh, D] f32 per-shard unnormalized output
     m: jnp.ndarray,  # [B, S, Nh] f32 per-shard row max
@@ -572,8 +574,9 @@ def paged_attention_tpu(
     # GQA fold (the reshape contract of cached_attention: head h = k*G + g)
     qh = jnp.transpose(q, (0, 2, 1, 3)).reshape(B, Nkv, GS, D)
     qp = jnp.tile(q_positions, (1, G))[..., None]  # [B, GS, 1]
-    kh = jnp.transpose(k_arena, (0, 2, 1, 3))  # [NB, Nkv, BS, D]
-    vh = jnp.transpose(v_arena, (0, 2, 1, 3))
+    with jax.named_scope("kv_layout"):  # the whole arena, head-major
+        kh = jnp.transpose(k_arena, (0, 2, 1, 3))  # [NB, Nkv, BS, D]
+        vh = jnp.transpose(v_arena, (0, 2, 1, 3))
     kp = kv_positions.reshape(B, T, 1, BS)  # one [1, BS] lane row per block
 
     # the arena-block specs: each grid cell streams the bps blocks the
@@ -635,6 +638,7 @@ def paged_attention_tpu(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="paged_decode",
     )(*operands)
     out = out.reshape(B, Nkv, G, S, D)
     return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(B, S, Nh, D)
@@ -787,8 +791,9 @@ def paged_prefill_tpu(
         )
     GSp = GS + pad_q
     qp = qp[..., None]  # [B, GSp, 1] — sublane-major (see _flash_kernel)
-    kh = jnp.transpose(k_arena, (0, 2, 1, 3))  # [NB, Nkv, BS, D]
-    vh = jnp.transpose(v_arena, (0, 2, 1, 3))
+    with jax.named_scope("kv_layout"):  # the whole arena, head-major
+        kh = jnp.transpose(k_arena, (0, 2, 1, 3))  # [NB, Nkv, BS, D]
+        vh = jnp.transpose(v_arena, (0, 2, 1, 3))
     kp = kv_positions.reshape(B, T, 1, BS)  # lane-major, one row per block
 
     # arena-block specs: the frontier clamp lives in the INDEX MAP — a
@@ -867,6 +872,7 @@ def paged_prefill_tpu(
             ),
         ),
         interpret=interpret,
+        name="paged_prefill",
     )(*operands)
     out = out[:, :, :GS].reshape(B, Nkv, G, S, D)
     return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(B, S, Nh, D)
@@ -885,6 +891,7 @@ def _ineligible_msg(op: str, k_arena, block_table) -> str:
     )
 
 
+@jax.named_scope("attn")
 def paged_prefill(
     q: jnp.ndarray,
     k_arena: jnp.ndarray,
@@ -961,6 +968,7 @@ def paged_prefill(
     )
 
 
+@jax.named_scope("attn")
 def paged_attention(
     q: jnp.ndarray,
     k_arena: jnp.ndarray,

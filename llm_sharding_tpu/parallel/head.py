@@ -134,6 +134,7 @@ def local_view(head: HeadParams) -> HeadParams:
     }
 
 
+@jax.named_scope("ring_hop")
 def psum_from(x: jnp.ndarray, owner, axis: str = PIPE_AXIS) -> jnp.ndarray:
     """Broadcast ``x`` from the stage whose axis index equals ``owner`` to all
     stages (the in-program analogue of the reference's ring token-return hop,
@@ -142,6 +143,7 @@ def psum_from(x: jnp.ndarray, owner, axis: str = PIPE_AXIS) -> jnp.ndarray:
     return jax.lax.psum(jnp.where(sidx == owner, x, jnp.zeros_like(x)), axis)
 
 
+@jax.named_scope("embed")
 def sp_embed(
     cfg: ModelConfig,
     head: HeadParams,  # local view
@@ -166,6 +168,7 @@ def sp_embed(
     return h
 
 
+@jax.named_scope("head")
 def _local_logits(
     cfg: ModelConfig, head: HeadParams, h_last: jnp.ndarray
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -204,6 +207,7 @@ def _assemble_argmax(vals: jnp.ndarray, lo: jnp.ndarray) -> jnp.ndarray:
     return jnp.take_along_axis(args, best[None, :], axis=0)[0]
 
 
+@jax.named_scope("sample")
 def sp_next_token(
     cfg: ModelConfig,
     head: HeadParams,  # local view
@@ -267,6 +271,7 @@ def _sliced_gumbel(
     return jax.lax.dynamic_slice_in_dim(noise_full, sidx * Vs, Vs, axis=1)
 
 
+@jax.named_scope("sample")
 def sp_sample(
     cfg: ModelConfig,
     head: HeadParams,  # local view
@@ -330,6 +335,7 @@ def key_chain_split(row_keys: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     return jax.vmap(spl)(row_keys)
 
 
+@jax.named_scope("sample")
 def sp_sample_rows(
     cfg: ModelConfig,
     head: HeadParams,  # local view

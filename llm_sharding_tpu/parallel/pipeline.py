@@ -182,7 +182,8 @@ def ring_chain(fns, cfg, layers, lmask, sidx, ring, num_stages, h, cache, positi
         active = m == sidx
         h = jnp.where(active, h_new, h)
         cache = _tree_where(active, cache_new, cache)
-        h = jax.lax.ppermute(h, PIPE_AXIS, ring)
+        with jax.named_scope("ring_hop"):
+            h = jax.lax.ppermute(h, PIPE_AXIS, ring)
         return h, cache
 
     return jax.lax.fori_loop(0, num_stages, micro, (h, cache))
@@ -216,7 +217,8 @@ def ring_chain_paged(fns, cfg, layers, lmask, sidx, ring, num_stages, h,
             k_scale=ks, v_scale=vs, prefill=prefill, nlive=nlive,
         )
         h = jnp.where(active, h_new, h)
-        h = jax.lax.ppermute(h, PIPE_AXIS, ring)
+        with jax.named_scope("ring_hop"):
+            h = jax.lax.ppermute(h, PIPE_AXIS, ring)
         return h, ka, va, ks, vs
 
     return jax.lax.fori_loop(

@@ -631,6 +631,7 @@ def serve_admit(
     C = state.out.shape[1]
     quantized = is_kv_quantized(state.k.dtype)  # trace-time constant
 
+    @jax.named_scope("state")
     def body(stage_layers, layer_mask, head_params, state, prompts,
              prompt_len, row_valid, slot, max_new, seeds, temperature,
              top_k, top_p, prompt_embeds, prefix_kv, prefix_len,
@@ -938,6 +939,7 @@ def serve_prefill_chunk(
     if prefix_off is None:
         prefix_off = jnp.zeros((), jnp.int32)
 
+    @jax.named_scope("state")
     def body(stage_layers, layer_mask, head_params, state, tokens, positions,
              slot, chunk_off, reset, prefix_off):
         layers = jax.tree.map(lambda a: a[0], stage_layers)
@@ -1107,6 +1109,7 @@ def serve_admit_finish(
     Bs = last_tok.shape[0]
     quantized = is_kv_quantized(state.k.dtype)  # trace-time constant
 
+    @jax.named_scope("state")
     def body(head_params, state, last_tok, prompt_len, row_valid, slot,
              max_new, seeds, temperature, top_k, top_p, key_override):
         hd = local_view(head_params)
@@ -1256,6 +1259,7 @@ def serve_chunk(
     Bs = M // num_stages
     quantized = is_kv_quantized(state.k.dtype)  # trace-time constant
 
+    @jax.named_scope("state")
     def body(stage_layers, layer_mask, head_params, state):
         layers = jax.tree.map(lambda a: a[0], stage_layers)
         lmask = layer_mask[0]
@@ -1414,7 +1418,8 @@ def serve_chunk(
             # re-embed fresh tokens; last stage sends them around the ring
             h_embed = sp_embed(cfg, hd, nxt[:, None], wpos[:, None])
             h_send = jnp.where(sidx == last, h_embed.astype(s.h.dtype), h_new)
-            h_out = jax.lax.ppermute(h_send, PIPE_AXIS, ring)
+            with jax.named_scope("ring_hop"):
+                h_out = jax.lax.ppermute(h_send, PIPE_AXIS, ring)
             # Validity gating uses POST-update done state: the sent block
             # belongs to this device's served slot r (on the last stage
             # r == r_done), and a block whose slot just finished (or was
@@ -1424,12 +1429,13 @@ def serve_chunk(
             # block.
             done_sent = jax.lax.dynamic_slice_in_dim(done, row0, Bs)
             sent_valid = valid_now & ~jnp.all(done_sent)
-            h_valid_out = (
-                jax.lax.ppermute(
-                    sent_valid.astype(jnp.int32), PIPE_AXIS, ring
+            with jax.named_scope("ring_hop"):
+                h_valid_out = (
+                    jax.lax.ppermute(
+                        sent_valid.astype(jnp.int32), PIPE_AXIS, ring
+                    )
+                    > 0
                 )
-                > 0
-            )
 
             # stage 0 consumed its slot's injection this microstep — clear it
             # (identical computation on every device: stage 0's slot is m mod S)
@@ -1566,6 +1572,7 @@ def serve_verify(
     scratch = C_total - (K + 1)
     quantized = is_kv_quantized(state.k.dtype)  # trace-time constant
 
+    @jax.named_scope("state")
     def body(stage_layers, layer_mask, head_params, state, draft, draft_len,
              slot, cache_delta):
         layers = jax.tree.map(lambda a: a[0], stage_layers)

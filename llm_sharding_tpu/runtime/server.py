@@ -79,11 +79,11 @@ from ..obs.metrics import (
     CP_STREAM_SHARDS, DEFAULT_RATE_BUCKETS,
     KV_BLOCKS_IN_USE, KV_BLOCKS_TOTAL, KV_DISK_TIER_BLOCKS,
     KV_HOST_TIER_BLOCKS, KV_WASTE_FRAC,
-    PREFILL_BLOCKS_READ, PREFIX_HIT_RATE, PREFIX_HIT_TOKENS, REGISTRY,
-    record_shape_key, set_prefill_path,
+    PREFILL_BLOCKS_READ, PREFILL_POSITIONS, PREFIX_HIT_RATE,
+    PREFIX_HIT_TOKENS, REGISTRY, record_shape_key, set_prefill_path,
 )
 from ..obs.trace import TraceContext, TraceWriter, emit_span
-from ..obs.stepline import StepProfiler
+from ..obs.stepline import STEP_ANNOTATION, StepProfiler
 from ..analysis.lockorder import named_lock
 from ..parallel import serve as serve_ops
 from ..parallel.mesh import PIPE_AXIS
@@ -232,6 +232,16 @@ def _update_load_gauges() -> None:
     KV_WASTE_FRAC.set(
         0.0 if kv_slots == 0 else max(0.0, 1.0 - kv_live / kv_slots)
     )
+
+
+def _profiler_annotation(name: str, **stats):
+    """The step profiler's annotation factory: its spans go into the JAX
+    profiler's trace (``serve.step`` as the profiler's step marker, so trace
+    viewers group device work by ``step_num``). Outside a profiler session
+    these cost about a microsecond each and write nothing."""
+    if name == STEP_ANNOTATION:
+        return jax.profiler.StepTraceAnnotation(name, **stats)
+    return jax.profiler.TraceAnnotation(name, **stats)
 
 
 _M_FETCH_FAIL = REGISTRY.counter(
@@ -1421,8 +1431,11 @@ class PipelineServer:
         # continuous step profiler (obs/stepline): one StepRecord per step()
         # into a bounded ring, host-occupancy/device-idle gauges, and the
         # /profilez deep-capture window. Public: benches toggle it, the CLI
-        # and HTTP exposition read it.
-        self.stepline = StepProfiler(name="server")
+        # and HTTP exposition read it. Its phases also land in the JAX
+        # profiler's trace as serve.* annotations (a session is the switch).
+        self.stepline = StepProfiler(
+            name="server", annotate=_profiler_annotation
+        )
         # pace the per-step load/KV/attn gauge sweep: 0.0 (default) keeps
         # the historical sweep-every-step behavior; at 64+ rows the sweep's
         # row scan is real per-step host work (visible as the profiler's
@@ -2223,7 +2236,10 @@ class PipelineServer:
         derived ``server_host_occupancy`` / ``server_device_idle_frac``
         gauges — note the dispatch figure is HOST dispatch time (the chunk
         executes async on device); with ``trace_path=`` the coarse phases
-        also land as JSONL spans.
+        also land as JSONL spans. Inside a ``jax.profiler`` session the
+        same phase stack writes ``serve.step`` / ``serve.<phase>`` /
+        ``serve.blocked`` / ``serve.prefill`` annotations for every step
+        that began with work (README "Step profiling").
 
         With ``speculate=K`` the decode chunk is replaced by per-slot
         ``serve_verify`` traversals (``_spec_step``): each commits a
@@ -2256,7 +2272,7 @@ class PipelineServer:
             if self._closed:
                 return False
             sl = self.stepline
-            sl.begin_step()
+            sl.begin_step(*self._held())
             tok0 = self.counters.tokens_generated
             self._step_contained = False
             sl.push("admit")
@@ -2332,13 +2348,10 @@ class PipelineServer:
             ):
                 # a clean step after containment: recovered
                 self._set_health(SERVING)
+            rows, queued, pending = self._held()
             sl.end_step(
-                rows=sum(
-                    1 for r in self._rows if r is not None and not r.done
-                ),
-                tokens=self.counters.tokens_generated - tok0,
-                queued=len(self._queue),
-                pending=len(self._pending),
+                rows=rows, tokens=self.counters.tokens_generated - tok0,
+                queued=queued, pending=pending,
             )
         # the npz serialization + atomic rename of a potentially multi-GB
         # state runs OUTSIDE the mutex: only this pump thread pays the
@@ -2368,7 +2381,7 @@ class PipelineServer:
             if self._closed:
                 return False
             sl = self.stepline
-            sl.begin_step()
+            sl.begin_step(*self._held())
             tok0 = self.counters.tokens_generated
             # NOT reset here (unlike the serial loop): the sidecar may
             # have contained a failure BETWEEN steps — that containment
@@ -2456,13 +2469,10 @@ class PipelineServer:
             self._step_contained = False  # consumed: the next boundary
             # may recover (the serial loop resets at step START instead —
             # it has no between-step appliers)
+            rows, queued, pending = self._held()
             sl.end_step(
-                rows=sum(
-                    1 for r in self._rows if r is not None and not r.done
-                ),
-                tokens=self.counters.tokens_generated - tok0,
-                queued=len(self._queue),
-                pending=len(self._pending),
+                rows=rows, tokens=self.counters.tokens_generated - tok0,
+                queued=queued, pending=pending,
             )
             if sched is not None:
                 sched.kick()
@@ -2471,6 +2481,24 @@ class PipelineServer:
         if snap_due is not None:
             self._write_autosnapshot(snap_due)
         return progressed
+
+    def _held(self) -> tuple[int, int, int]:
+        """What the server holds right now: (active rows, queued requests,
+        un-applied logs) — a step's record carries them as they stand at its
+        end, its ``serve.step`` annotation as they stood at its start."""
+        return (
+            sum(1 for r in self._rows if r is not None and not r.done),
+            len(self._queue),
+            len(self._pending),
+        )
+
+    def _prefill_span(self, rows: int, prompt_tokens: int, positions: int):
+        """Every prefill dispatch sits in this: feeds
+        ``server_prefill_positions_total`` and the step record, and writes
+        ``serve.prefill`` (``StepProfiler.prefill``)."""
+        PREFILL_POSITIONS.labels(kind="prompt").inc(prompt_tokens)
+        PREFILL_POSITIONS.labels(kind="pad").inc(positions - prompt_tokens)
+        return self.stepline.prefill(rows, prompt_tokens, positions)
 
     def _apply_delta(self, delta) -> bool:
         """Act on the scheduler's published delta at a step boundary
@@ -2641,7 +2669,12 @@ class PipelineServer:
         trace_on = False
         if trace_dir:
             try:
-                jax.profiler.start_trace(trace_dir)
+                # the serve.* annotations and the device planes, without
+                # the Python tracer's weight on the pump
+                opts = jax.profiler.ProfileOptions()
+                opts.python_tracer_level = 0
+                opts.host_tracer_level = 2
+                jax.profiler.start_trace(trace_dir, profiler_options=opts)
                 trace_on = True
             except Exception as e:  # noqa: BLE001 — capture works without
                 logger.warning("device trace unavailable: %r", e)
@@ -4246,41 +4279,46 @@ class PipelineServer:
                      in_arena, self.engine.cache_dtype)
                     + ((self.cp,) if self.cp > 1 else ()),
                 )
-                self.state, tok0 = serve_ops.serve_admit(
-                    self.cfg,
-                    self.mesh,
-                    self._stage_layers,
-                    self._layer_masks,
-                    self._head_params,
-                    self.state,
-                    jnp.asarray(prompts),
-                    jnp.asarray(plen),
-                    jnp.asarray(row_valid),
-                    jnp.asarray(slot, jnp.int32),
-                    jnp.asarray(max_new),
-                    jnp.asarray(seeds),
-                    jnp.asarray(temps),
-                    jnp.asarray(topks),
-                    jnp.asarray(topps),
-                    self.num_stages,
-                    self.engine.cache_dtype,
-                    prompt_embeds=(
-                        None if embeds is None else jnp.asarray(embeds)
-                    ),
-                    filtering=self._filtering,
-                    prefix_kv=pkv,
-                    prefix_len=(
-                        None if pn is None else jnp.asarray(pn, jnp.int32)
-                    ),
-                    key_override=(
-                        (jnp.asarray(rngs), jnp.asarray(rng_mask))
-                        if carried else None
-                    ),
-                    tp=self.tp,
-                    block_size=self.kv_block_size or 0,
-                    prefix_in_arena=in_arena,
-                    cp=self.cp,
-                )
+                # plen counts each row's suffix past a radix match; the
+                # program computes every row of the slot at the bucket
+                with self._prefill_span(
+                    Bs, int(plen[: len(batch)].sum()), Bs * bucket
+                ):
+                    self.state, tok0 = serve_ops.serve_admit(
+                        self.cfg,
+                        self.mesh,
+                        self._stage_layers,
+                        self._layer_masks,
+                        self._head_params,
+                        self.state,
+                        jnp.asarray(prompts),
+                        jnp.asarray(plen),
+                        jnp.asarray(row_valid),
+                        jnp.asarray(slot, jnp.int32),
+                        jnp.asarray(max_new),
+                        jnp.asarray(seeds),
+                        jnp.asarray(temps),
+                        jnp.asarray(topks),
+                        jnp.asarray(topps),
+                        self.num_stages,
+                        self.engine.cache_dtype,
+                        prompt_embeds=(
+                            None if embeds is None else jnp.asarray(embeds)
+                        ),
+                        filtering=self._filtering,
+                        prefix_kv=pkv,
+                        prefix_len=(
+                            None if pn is None else jnp.asarray(pn, jnp.int32)
+                        ),
+                        key_override=(
+                            (jnp.asarray(rngs), jnp.asarray(rng_mask))
+                            if carried else None
+                        ),
+                        tp=self.tp,
+                        block_size=self.kv_block_size or 0,
+                        prefix_in_arena=in_arena,
+                        cp=self.cp,
+                    )
                 # the admission-sampled first token is applied like a chunk
                 # log — deferred, so its fetch also overlaps device compute
                 self._pending.append(
@@ -4393,26 +4431,28 @@ class PipelineServer:
                         -(-(prefix_off + off + Sc) // self.kv_block_size)
                     )
                 )
-            self.state = serve_ops.serve_prefill_chunk(
-                self.cfg,
-                self.mesh,
-                self._stage_layers,
-                self._layer_masks,
-                self._head_params,
-                self.state,
-                jnp.asarray(prompts[:, off : off + Sc]),
-                jnp.asarray(positions[:, off : off + Sc]),
-                jnp.asarray(slot, jnp.int32),
-                jnp.asarray(off, jnp.int32),
-                jnp.asarray(ci == 0),
-                self.num_stages,
-                tp=self.tp,
-                block_size=self.kv_block_size or 0,
-                cache_dtype=self.engine.cache_dtype,
-                prefix_off=jnp.asarray(prefix_off, jnp.int32),
-                attn=attn,
-                cp=self.cp,
-            )
+            real = int(np.clip(plen - off, 0, Sc)[row_valid].sum())
+            with self._prefill_span(Bs, real, Bs * Sc):
+                self.state = serve_ops.serve_prefill_chunk(
+                    self.cfg,
+                    self.mesh,
+                    self._stage_layers,
+                    self._layer_masks,
+                    self._head_params,
+                    self.state,
+                    jnp.asarray(prompts[:, off : off + Sc]),
+                    jnp.asarray(positions[:, off : off + Sc]),
+                    jnp.asarray(slot, jnp.int32),
+                    jnp.asarray(off, jnp.int32),
+                    jnp.asarray(ci == 0),
+                    self.num_stages,
+                    tp=self.tp,
+                    block_size=self.kv_block_size or 0,
+                    cache_dtype=self.engine.cache_dtype,
+                    prefix_off=jnp.asarray(prefix_off, jnp.int32),
+                    attn=attn,
+                    cp=self.cp,
+                )
             # interleave only when some OTHER request is mid-decode — the
             # admitting rows themselves are in _rows already and must not
             # count, or an idle server would pay a useless cycle per chunk
@@ -4456,30 +4496,32 @@ class PipelineServer:
             (self.num_stages, Bs, self.capacity, self.tp, carried)
             + ((self.cp,) if self.cp > 1 else ()),
         )
-        self.state = serve_ops.serve_admit_finish(
-            self.cfg,
-            self.mesh,
-            self._head_params,
-            self.state,
-            jnp.asarray(last_tok),
-            # prefix-inclusive totals: pos_slots / lengths / budget and
-            # the injected token's position all count the resident prefix
-            jnp.asarray(prefix_off + plen),
-            jnp.asarray(row_valid),
-            jnp.asarray(slot, jnp.int32),
-            jnp.asarray(max_new),
-            jnp.asarray(seeds),
-            jnp.asarray(temps),
-            jnp.asarray(topks),
-            jnp.asarray(topps),
-            self.num_stages,
-            tp=self.tp,
-            key_override=(
-                (jnp.asarray(rngs), jnp.asarray(rng_mask))
-                if carried else None
-            ),
-            cp=self.cp,
-        )
+        # arms the slot: embeds each row's last token, runs no layer
+        with self._prefill_span(Bs, 0, 0):
+            self.state = serve_ops.serve_admit_finish(
+                self.cfg,
+                self.mesh,
+                self._head_params,
+                self.state,
+                jnp.asarray(last_tok),
+                # prefix-inclusive totals: pos_slots / lengths / budget and
+                # the injected token's position all count the resident prefix
+                jnp.asarray(prefix_off + plen),
+                jnp.asarray(row_valid),
+                jnp.asarray(slot, jnp.int32),
+                jnp.asarray(max_new),
+                jnp.asarray(seeds),
+                jnp.asarray(temps),
+                jnp.asarray(topks),
+                jnp.asarray(topps),
+                self.num_stages,
+                tp=self.tp,
+                key_override=(
+                    (jnp.asarray(rngs), jnp.asarray(rng_mask))
+                    if carried else None
+                ),
+                cp=self.cp,
+            )
         self._admitting_rows.difference_update(range(row0, row0 + Bs))
 
     def _spec_step(self) -> None:
@@ -4638,10 +4680,10 @@ class PipelineServer:
                 # blocked on device: the log hasn't materialized on host
                 # yet. The wait is measured SEPARATELY from host compute
                 # (the profiler's blocked_s — excluded from the fetch
-                # phase); the retryable get below then returns instantly.
-                tb = time.perf_counter()
-                entry[1].event.wait()
-                sl.blocked(time.perf_counter() - tb)
+                # phase — and its serve.blocked annotation); the retryable
+                # get below then returns instantly.
+                with sl.blocking():
+                    entry[1].event.wait()
             self._apply_entry(entry)
         sl.pop()
         return applied

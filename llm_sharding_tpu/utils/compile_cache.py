@@ -16,6 +16,16 @@ a directory that moves never hits. The contract:
   executables are pinned to the machine that built them, and a new process
   reloading them can hang or crash at deserialization.
 
+Wherever the cache lives, its key includes the programs' metadata
+(``jax_compilation_cache_include_metadata_in_key``; JAX leaves it out by
+default). The metadata is what a profiler trace names device work by — the
+``jax.named_scope`` paths of ``obs.stepline.SCOPES``, file and line — and an
+executable loaded from the cache keeps the metadata it was COMPILED with:
+with the default key, a commit that only moves a scope is served the old
+names by a cache an earlier commit filled, and every metric read from scopes
+goes quietly wrong. The price is one recompile after an edit that shifts
+the lines of a traced function; a restart of the same code still hits.
+
 Call before the first compilation; the CLI does at entry.
 """
 
@@ -41,12 +51,13 @@ def enable_persistent_cache(platform: str) -> Optional[str]:
     path only after ``jax.distributed.initialize``. Returns the directory
     in use, or None when no cache is on."""
     placed = os.environ.get(ENV_VAR)
-    if placed:
-        return placed
-    if platform != "tpu":
+    if not placed and platform != "tpu":
         return None
     import jax
 
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    if placed:
+        return placed
     os.makedirs(DEFAULT_CACHE_DIR, exist_ok=True)  # unusable → OSError
     jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
     return DEFAULT_CACHE_DIR

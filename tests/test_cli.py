@@ -584,19 +584,24 @@ def test_launch_two_process_simulation(tmp_path, capsys):
 
 def test_compile_cache_contract(tmp_path, monkeypatch):
     """Where compiled programs are kept: placed from outside → the program
-    sets nothing; unset on the CPU → no cache of its own; unset on a TPU →
-    one constant directory inside the checkout, and an unusable one is an
-    error, not a silent cold start."""
+    sets no directory; unset on the CPU → no cache of its own; unset on a
+    TPU → one constant directory inside the checkout, and an unusable one is
+    an error, not a silent cold start. Wherever a cache is on, its key holds
+    the programs' metadata: a cached executable keeps the scope names it was
+    compiled with, and a trace is read by them."""
     from llm_sharding_tpu.utils import compile_cache as cc
 
     updates = []
     monkeypatch.setattr(
         jax.config, "update", lambda k, v: updates.append((k, v))
     )
+    in_key = ("jax_compilation_cache_include_metadata_in_key", True)
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "placed"))
     for platform in ("tpu", "cpu"):
         assert cc.enable_persistent_cache(platform) == str(tmp_path / "placed")
-    assert updates == [] and not os.path.exists(tmp_path / "placed")
+    assert updates == [in_key, in_key]
+    assert not os.path.exists(tmp_path / "placed")
+    del updates[:]
 
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
     assert cc.enable_persistent_cache("cpu") is None and updates == []
@@ -606,7 +611,7 @@ def test_compile_cache_contract(tmp_path, monkeypatch):
     monkeypatch.setattr(cc, "DEFAULT_CACHE_DIR", str(tmp_path / "in_checkout"))
     assert cc.enable_persistent_cache("tpu") == str(tmp_path / "in_checkout")
     assert updates == [
-        ("jax_compilation_cache_dir", str(tmp_path / "in_checkout"))
+        in_key, ("jax_compilation_cache_dir", str(tmp_path / "in_checkout"))
     ]
     (tmp_path / "a_file").write_text("")
     monkeypatch.setattr(cc, "DEFAULT_CACHE_DIR", str(tmp_path / "a_file" / "x"))
@@ -915,3 +920,41 @@ def test_serve_sigterm_graceful_drain(shards):
         if proc.poll() is None:
             proc.kill()
             proc.communicate()
+
+
+def test_a_cached_program_never_comes_back_with_a_stale_scope(tmp_path):
+    """Why the cache key holds metadata (``utils/compile_cache.py``): two
+    processes share one cache directory and compile the SAME computation
+    under a different ``jax.named_scope``. Loaded from the cache, the second
+    must still carry its own scope — with JAX's default key it is served the
+    first one's executable, first one's names and all, and every metric a
+    profiler trace reads by scope goes quietly wrong."""
+    import subprocess
+    import sys
+
+    code = (
+        "import re, sys, jax, jax.numpy as jnp\n"
+        "from llm_sharding_tpu.utils.compile_cache import "
+        "enable_persistent_cache\n"
+        "assert enable_persistent_cache('cpu')\n"
+        "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0.0)\n"
+        "jax.config.update('jax_persistent_cache_min_entry_size_bytes', 0)\n"
+        "def probe(x):\n"
+        "    with jax.named_scope(sys.argv[1]):\n"
+        "        return jnp.tanh(x @ x)\n"
+        "text = jax.jit(probe).lower(jnp.ones((64, 64))).compile().as_text()\n"
+        "print(sorted(set(re.findall(r'/(kv_\\w+)/dot_general', text))))\n"
+    )
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=repo,
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    seen = []
+    for scope in ("kv_take", "kv_put"):
+        out = subprocess.run(
+            [sys.executable, "-c", code, scope], env=env, cwd=repo,
+            capture_output=True, text=True, timeout=300,
+        )
+        assert out.returncode == 0, out.stderr[-2000:]
+        seen.append(out.stdout.strip().splitlines()[-1])
+    assert seen == ["['kv_take']", "['kv_put']"]
+    assert os.listdir(tmp_path / "cache"), "the cache was never written"

@@ -777,3 +777,109 @@ def test_attn_backend_metrics(setup, monkeypatch):
     srv.close()
     _update_load_gauges()
     assert ATTN_BACKEND.labels(backend="xla").value == xla_live - 1
+
+
+# ------------------------------------- named scopes in the step programs
+
+#: Which words of ``obs.stepline.SCOPES`` each step program must carry when
+#: lowered (paged arena, chunked prefill, the kernel code path emulated).
+#: serve_admit prefills a dense window and scatters it: no kernel operands
+#: to lay out. serve_prefill_chunk samples nothing. serve_admit_finish only
+#: embeds each row's last token.
+_NO_LAYOUT = {"kv_layout"}
+_NO_HEAD = {"head", "sample"}
+PROGRAM_SCOPES = {
+    "serve_chunk": set(),
+    "serve_prefill_chunk": _NO_HEAD,
+    "serve_admit": _NO_LAYOUT,
+    "serve_admit_finish": None,  # exactly: embed, state
+}
+
+
+@pytest.fixture(scope="module")
+def lowered_programs(setup):
+    """Serve a one-shot and a chunked admission through the interpreted
+    kernels, lowering each step program with the very arguments the server
+    dispatched it with. Returns ``(texts, served, oracle)``."""
+    from llm_sharding_tpu.parallel import serve as serve_ops
+
+    params, eng = setup
+    texts = {}
+
+    def spy(mp, name):
+        orig = getattr(serve_ops, name)
+
+        def call(*a, **kw):
+            if name not in texts:
+                texts[name] = orig.lower(*a, **kw).as_text(debug_info=True)
+            return orig(*a, **kw)
+
+        mp.setattr(serve_ops, name, call)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("PAGED_FORCE_KERNEL", "interpret")
+        for name in PROGRAM_SCOPES:
+            spy(mp, name)
+        srv = eng.serve(
+            capacity=64, batch_per_slot=2, kv_block_size=8, kv_blocks=65,
+            prefill_chunk=16,
+        )
+        assert srv.attn_impl == "interpret"
+        prompts = [prompt(301, n=5), prompt(302, n=20)]
+        reqs = [srv.submit(p, 5) for p in prompts]
+        srv.run_until_idle()
+        srv.close()
+    served = [list(r.tokens) for r in reqs]
+    oracle = [oracle_tokens(params, p, 5) for p in prompts]
+    return texts, served, oracle
+
+
+def _scopes_in(text):
+    """The vocabulary words on any operation's name-stack path."""
+    import re
+
+    from llm_sharding_tpu.obs.stepline import SCOPES
+
+    paths = set(re.findall(r'loc\("([^"]+)"', text))
+    return {
+        w for w in SCOPES
+        if any(re.search(rf"(^|/){w}(/|$)", p) for p in paths)
+    }, paths
+
+
+@pytest.mark.parametrize("program", sorted(PROGRAM_SCOPES))
+def test_step_programs_carry_the_scope_vocabulary(lowered_programs, program):
+    """Every step program names its device work by the closed vocabulary
+    (``obs.stepline.SCOPES``) — what a profiler trace's ``tf_op`` then
+    carries — and naming changes no token: the served ids equal the
+    monolithic oracle's, as before the scopes."""
+    from llm_sharding_tpu.obs.stepline import SCOPES
+
+    texts, served, oracle = lowered_programs
+    assert served == oracle
+    found, paths = _scopes_in(texts[program])
+    missing_ok = PROGRAM_SCOPES[program]
+    want = (
+        {"embed", "state"} if missing_ok is None
+        else set(SCOPES) - missing_ok
+    )
+    assert found == want, (sorted(want - found), sorted(found - want))
+    if program == "serve_chunk":
+        # the arena copies: the layer's slice, the kernel operands'
+        # transposes, the write-back; and the hop (MLIR locations are
+        # relative to the traced function, XLA joins them into tf_op)
+        assert any(p.endswith("kv_take/dynamic_slice") for p in paths)
+        assert any(p.endswith("kv_layout/transpose") for p in paths)
+        assert any(p.endswith("kv_put/dynamic_update_slice") for p in paths)
+        assert any(p.endswith("ring_hop/ppermute") for p in paths)
+
+
+def test_the_pallas_kernels_are_named(lowered_programs):
+    """``name=`` on the pallas_calls: a trace names the kernels
+    ``paged_decode`` / ``paged_prefill``, not by a numbered fusion."""
+    texts, _, _ = lowered_programs
+    _, decode = _scopes_in(texts["serve_chunk"])
+    _, prefill = _scopes_in(texts["serve_prefill_chunk"])
+    assert any(p.startswith("paged_decode/") for p in decode)
+    assert any(p.startswith("paged_prefill/") for p in prefill)
+    assert not any(p.startswith("paged_prefill/") for p in decode)

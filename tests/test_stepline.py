@@ -589,3 +589,282 @@ def test_step_report_merges_and_sorts_files(tmp_path):
     assert "2 step(s)" in text
     assert render_step_report([]) == "no step records in the input"
     assert step_report_json([])["summary"]["steps"] == 0
+
+
+# ------------------------------------------- profiler annotations (ISSUE 24)
+
+
+class FakeAnnotations:
+    """The injected ``annotate`` factory: records every enter and exit in
+    order, with the stats each annotation was made with."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name, **stats):
+        log = self.log
+
+        class Span:
+            def __enter__(self):
+                log.append(("enter", name, stats))
+
+            def __exit__(self, *exc):
+                log.append(("exit", name))
+
+        return Span()
+
+    def trail(self):
+        return [(e[0], e[1]) for e in self.log]
+
+
+def test_phase_stack_enters_and_exits_the_injected_annotation_in_order():
+    clk, ann = FakeClock(), FakeAnnotations()
+    p = StepProfiler(clock=clk.now, name="t-ann", annotate=ann)
+    p.begin_step(rows=2, queued=1, pending=1)
+    p.push("admit")
+    p.push("fetch")  # nested: _drain(0) inside admission
+    with p.blocking():  # the device wait, inside the phase it interrupts
+        clk.t += 0.25
+    p.pop()
+    p.pop()
+    p.push("dispatch")
+    clk.t += 1.0
+    p.pop()
+    rec = p.end_step(rows=2, tokens=3)
+    assert ann.trail() == [
+        ("enter", "serve.step"), ("enter", "serve.admit"),
+        ("enter", "serve.fetch"), ("enter", "serve.blocked"),
+        ("exit", "serve.blocked"), ("exit", "serve.fetch"),
+        ("exit", "serve.admit"), ("enter", "serve.dispatch"),
+        ("exit", "serve.dispatch"), ("exit", "serve.step"),
+    ]
+    # the step carries what the server held at its START, and its number
+    assert ann.log[0][2] == {
+        "step_num": 0, "rows": 2, "queued": 1, "pending": 1,
+    }
+    # blocking() is blocked(): accounted, and excluded from the phase
+    assert rec.blocked_s == pytest.approx(0.25)
+    assert rec.phases["fetch"] == pytest.approx(0.0)
+    assert rec.phases["dispatch"] == pytest.approx(1.0)
+    _check_invariant(rec.to_dict())
+    # every name is one the trace reader knows
+    names = {e[1] for e in ann.log}
+    assert names <= {stepline.STEP_ANNOTATION, stepline.BLOCKED_ANNOTATION,
+                     stepline.PREFILL_ANNOTATION} | {
+                         "serve." + ph for ph in PHASES}
+
+
+def test_idle_polls_write_nothing_and_the_one_closing_step_is_written():
+    ann = FakeAnnotations()
+    p = StepProfiler(name="t-idle", annotate=ann)
+
+    def step(rows=0, queued=0, pending=0):
+        p.begin_step(rows, queued, pending)
+        p.push("admit")
+        p.pop()
+        p.end_step()
+
+    for _ in range(50):  # an empty server being polled
+        step()
+    assert ann.log == [], "idle polls must not reach the trace"
+    assert p.steps_total == 50, "the ring still records every step"
+    step(queued=1)
+    step(rows=1, pending=1)
+    step()  # the closing step: the one after the last that held work
+    for _ in range(50):
+        step()
+    steps = [e[2] for e in ann.log if e[:2] == ("enter", "serve.step")]
+    assert [(s["rows"], s["queued"], s["pending"]) for s in steps] == [
+        (0, 1, 0), (1, 0, 1), (0, 0, 0),
+    ]
+    assert [s["step_num"] for s in steps] == [50, 51, 52]
+    # phases of an unannotated step are not written either
+    assert ann.trail().count(("enter", "serve.admit")) == 3
+    # no factory, no annotations, same records
+    q = StepProfiler(name="t-none")
+    q.begin_step(rows=1)
+    q.push("admit")
+    with q.blocking():
+        pass
+    with q.prefill(1, 1, 1):
+        pass
+    q.pop()
+    assert q.end_step().prompt_tokens == 1
+
+
+def test_prefill_counts_reach_the_record_and_the_annotation():
+    ann = FakeAnnotations()
+    p = StepProfiler(name="t-prefill", annotate=ann)
+    p.begin_step(queued=2)
+    p.push("admit")
+    with p.prefill(rows=4, prompt_tokens=53, positions=256):
+        pass
+    with p.prefill(rows=4, prompt_tokens=300, positions=1024):
+        pass
+    with p.prefill(rows=4, prompt_tokens=0, positions=0):  # admit_finish
+        pass
+    p.pop()
+    rec = p.end_step(tokens=1).to_dict()
+    assert (rec["prompt_tokens"], rec["prefill_positions"]) == (353, 1280)
+    stats = [e[2] for e in ann.log if e[:2] == ("enter", "serve.prefill")]
+    assert stats == [
+        {"rows": 4, "prompt_tokens": 53, "positions": 256},
+        {"rows": 4, "prompt_tokens": 300, "positions": 1024},
+        {"rows": 4, "prompt_tokens": 0, "positions": 0},
+    ]
+    # the next step starts from nothing
+    p.begin_step()
+    assert p.end_step().prefill_positions == 0
+    # outside a step it accounts nothing and does not raise
+    with p.prefill(1, 1, 1), p.blocking():
+        pass
+
+
+def test_a_step_that_raised_leaves_a_properly_nested_trace():
+    ann = FakeAnnotations()
+    p = StepProfiler(name="t-raise", annotate=ann)
+    p.begin_step(rows=1)
+    p.push("admit")
+    p.push("fetch")  # ... and step() raised here: no pop, no end_step
+    p.begin_step(rows=1)
+    p.end_step()
+    assert ann.trail() == [
+        ("enter", "serve.step"), ("enter", "serve.admit"),
+        ("enter", "serve.fetch"), ("exit", "serve.fetch"),
+        ("exit", "serve.admit"), ("exit", "serve.step"),
+        ("enter", "serve.step"), ("exit", "serve.step"),
+    ]
+
+
+def test_obs_imports_without_jax_and_names_the_vocabulary():
+    """``obs/`` stays stdlib-only (step-report and lint run without jax),
+    and the scope vocabulary is one closed tuple beside PHASES."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from llm_sharding_tpu.obs import stepline, metrics, trace, report\n"
+        "assert 'jax' not in sys.modules, 'obs/ pulled jax in'\n"
+        "print(','.join(stepline.SCOPES))\n"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        timeout=120,
+    )
+    assert out.returncode == 0, out.stderr
+    words = out.stdout.strip().split(",")
+    assert words == list(stepline.SCOPES) and len(set(words)) == len(words)
+    for must in ("kv_take", "kv_layout", "kv_put", "attn", "mlp", "state"):
+        assert must in words
+
+
+def _host_annotations(trace_dir):
+    """``[(name, stats)]`` of the serve.* events in a trace's host plane."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(
+        os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")
+    ))[-1]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("serve."):
+                    out.append((e.name, dict(e.stats), e.start_ns, e.end_ns))
+    return sorted(out, key=lambda e: e[2])
+
+
+def test_a_traced_server_leaves_its_spans_in_the_host_plane(params, tmp_path):
+    """A profiler session is the only switch: a tiny server stepped under
+    ``jax.profiler`` on the CPU backend leaves ``serve.step`` (with its
+    stats), ``serve.dispatch`` and ``serve.prefill`` in the host plane, and
+    the step record and the counter carry the same prefill counts."""
+    eng = PipelineEngine(
+        CFG, params, num_stages=2, devices=jax.devices()[:2],
+        cache_dtype=jnp.float32,
+    )
+    srv = eng.serve(capacity=CAP, batch_per_slot=2)
+    srv.submit(prompt(70), 3)  # compile outside the traced steps
+    srv.run_until_idle()
+    fam = REGISTRY.get("server_prefill_positions_total")
+    before = {k: fam.labels(kind=k).value for k in ("prompt", "pad")}
+    n0 = srv.stepline.steps_total
+    for _ in range(20):
+        srv.step()  # idle polls before the trace: the closing step passes
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        for _ in range(10):
+            srv.step()  # idle polls inside the trace
+        srv.submit(prompt(71, n=5), 4)
+        srv.run_until_idle()
+        for _ in range(10):
+            srv.step()
+    finally:
+        jax.profiler.stop_trace()
+    recs = srv.stepline_snapshot()[n0 - srv.stepline.steps_total:]
+    srv.close()
+    spans = _host_annotations(str(tmp_path))
+    steps = [s for s in spans if s[0] == "serve.step"]
+    working = [s for s in steps
+               if s[1]["rows"] or s[1]["queued"] or s[1]["pending"]]
+    # 40 idle polls wrote nothing; the steps that held work and the one
+    # closing step did
+    assert len(steps) == len(working) + 1
+    assert steps[-1][1]["rows"] == steps[-1][1]["queued"] == 0
+    assert steps[0][1]["queued"] == 1 and steps[0][1]["rows"] == 0
+    nums = [s[1]["step_num"] for s in steps]
+    assert nums == list(range(nums[0], nums[0] + len(nums)))
+    names = {s[0] for s in spans}
+    assert {"serve.step", "serve.admit", "serve.dispatch", "serve.fetch",
+            "serve.apply", "serve.prefill"} <= names
+    # every phase and prefill span lies inside a step span
+    for name, _, a, b in spans:
+        if name != "serve.step":
+            assert any(s[2] <= a and b <= s[3] for s in steps), name
+    # one admission: 5 real tokens, the slot's 2 rows at the bucket of 8
+    pre = [s[1] for s in spans if s[0] == "serve.prefill"]
+    assert pre == [{"rows": 2, "prompt_tokens": 5, "positions": 16}]
+    assert sum(r["prompt_tokens"] for r in recs) == 5
+    assert sum(r["prefill_positions"] for r in recs) == 16
+    after = {k: fam.labels(kind=k).value for k in ("prompt", "pad")}
+    assert after["prompt"] - before["prompt"] == 5
+    assert after["pad"] - before["pad"] == 11
+
+
+def test_chunked_admission_counts_every_chunk_and_agrees_with_the_buckets(
+        params):
+    """The program's own count of prefill positions equals what the
+    admission rule says from outside — ``batch_per_slot`` rows x the
+    power-of-two bucket per admission (``benchmark``'s prompt_pad_pct) —
+    one-shot and chunked, while admission goes by slot."""
+    from llm_sharding_tpu.runtime.server import ADMIT_BUCKETS
+
+    eng = PipelineEngine(
+        CFG, params, num_stages=2, devices=jax.devices()[:2],
+        cache_dtype=jnp.float32,
+    )
+    srv = eng.serve(
+        capacity=128, batch_per_slot=2, kv_block_size=8, kv_blocks=65,
+        prefill_chunk=16,
+    )
+    n0 = srv.stepline.steps_total
+    lens = [5, 20, 33]  # one-shot; a 32 bucket in two chunks; 64 in four
+    for i, n in enumerate(lens):
+        srv.submit(prompt(80 + i, n=n), 2)
+    srv.run_until_idle()
+    recs = srv.stepline_snapshot()[n0 - srv.stepline.steps_total:]
+    srv.close()
+    bucket = lambda n: next(b for b in ADMIT_BUCKETS if b >= n)
+    assert sum(r["prompt_tokens"] for r in recs) == sum(lens)
+    assert sum(r["prefill_positions"] for r in recs) == sum(
+        2 * bucket(n) for n in lens
+    )

@@ -32,7 +32,7 @@ from llm_sharding_tpu.obs.report import (
     build_traces, load_events, render_report, report_json,
 )
 from llm_sharding_tpu.obs.trace import (
-    FLIGHT_RECORDER, SpanRing, TraceContext, TraceWriter, emit_span,
+    SpanRing, TraceContext, TraceWriter, emit_span,
     valid_trace_id,
 )
 from llm_sharding_tpu.runtime.disagg import DisaggServer
@@ -422,15 +422,29 @@ def test_disagg_handoff_single_tree(params, tmp_path):
     )
 
 
-def test_ingress_x_trace_id_and_exemplar(params, tmp_path):
+def test_ingress_x_trace_id_and_exemplar(params, tmp_path, monkeypatch):
     """ACCEPTANCE (front half): X-Trace-Id is honored end to end — the
     response echoes it, the ingress root + fair-queue spans and the
     backend's request tree all carry it, and it lands as the exemplar on
-    the ingress TTFT histogram (and in the /debugz bundle)."""
+    the ingress TTFT histogram (and in the /debugz bundle).
+
+    The test OWNS the two pieces of process-wide state it asserts on. A
+    bucket's exemplar is bucket-max for ``EXEMPLAR_TTL_S``: any earlier test
+    of the worker (``-n 6 --dist loadfile`` puts several files in one
+    process) whose slower first token fell into the same bucket of
+    ``server_ingress_ttft_seconds{tenant="default"}`` within the last minute
+    keeps its own trace id there, and this one — run alone it passes — is
+    refused. So the request goes in under a tenant no other test names, and
+    the spans go to a flight recorder of this test's own, which nothing
+    else can wrap."""
     import http.client
 
-    from llm_sharding_tpu.runtime.ingress import IngressServer
+    from llm_sharding_tpu.obs import trace as trace_mod
+    from llm_sharding_tpu.runtime.ingress import IngressServer, TenantConfig
 
+    ring = SpanRing()
+    monkeypatch.setattr(trace_mod, "FLIGHT_RECORDER", ring)
+    tenant = "trace-exemplar-test"
     eng = PipelineEngine(
         CFG, params, num_stages=2, devices=jax.devices()[:2],
         cache_dtype=jnp.float32,
@@ -439,6 +453,7 @@ def test_ingress_x_trace_id_and_exemplar(params, tmp_path):
     backend = eng.serve(capacity=CAP, trace_path=tp)
     ing = IngressServer(
         backend, poll_interval_s=0.0005, trace_path=tp,
+        tenants=[TenantConfig(tenant, key="sk-trace-exemplar")],
     )
     ing.start()
     tid = "pinned-trace-0042"
@@ -449,7 +464,8 @@ def test_ingress_x_trace_id_and_exemplar(params, tmp_path):
             json.dumps({
                 "prompt": [int(t) for t in prompt(55)], "max_tokens": 6,
             }),
-            {"Content-Type": "application/json", "X-Trace-Id": tid},
+            {"Content-Type": "application/json", "X-Trace-Id": tid,
+             "Authorization": "Bearer sk-trace-exemplar"},
         )
         resp = conn.getresponse()
         body = json.loads(resp.read())
@@ -472,12 +488,10 @@ def test_ingress_x_trace_id_and_exemplar(params, tmp_path):
     assert tr.first("decode")["parent"] == req_span["span_id"]
     # exemplar: the TTFT histogram's slow bucket names this trace
     fam = REGISTRY.get("server_ingress_ttft_seconds")
-    exem = fam.labels(tenant="default").snap_exemplars()
-    assert tid in {e[0] for e in exem.values()}
+    exem = fam.labels(tenant=tenant).snap_exemplars()
+    assert {e[0] for e in exem.values()} == {tid}
     # and the flight recorder carried the spans for /debugz
-    ring_spans = [
-        e for e in FLIGHT_RECORDER.snapshot() if e.get("trace_id") == tid
-    ]
+    ring_spans = [e for e in ring.snapshot() if e.get("trace_id") == tid]
     assert {e["span"] for e in ring_spans} >= {"ingress", "request"}
 
 
