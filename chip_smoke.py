@@ -218,8 +218,11 @@ def kernel_cases(spec: dict) -> list[dict]:
 
 def check_kernel(cfg, case: dict, backend: str, seed: int = 0) -> float:
     """Run one kernel variant (``backend`` = "kernel" on the chip,
-    "interpret" under pytest) and the XLA path on the same random arena;
-    return max |difference|."""
+    "interpret" under pytest) and the XLA path on the same random arena —
+    for the paged kernels a head-major stack of three layers with other
+    contents in each, attended at a layer that is not the first (a kernel
+    that ignored its layer operand would read layer 0); return max
+    |difference|."""
     import numpy as np
     import jax.numpy as jnp
 
@@ -262,7 +265,9 @@ def check_kernel(cfg, case: dict, backend: str, seed: int = 0) -> float:
             table[b, : nlive[b]] = ids[at: at + nlive[b]]
             at += nlive[b]
         store = kv_storage_dtype(case["kv_dtype"], dt)
-        vals = rng.standard_normal((2, NB, BS, Nkv, D), np.float32)
+        L = 3
+        layer = int(rng.integers(1, L))
+        vals = rng.standard_normal((2, L, NB, Nkv, BS, D), np.float32)
         scales = {}
         if case["kv_dtype"] == "bf16":
             k_arena, v_arena = (jnp.asarray(a, store) for a in vals)
@@ -272,11 +277,11 @@ def check_kernel(cfg, case: dict, backend: str, seed: int = 0) -> float:
             if case["kv_dtype"] == "int8":
                 codes = np.round(codes)
             k_arena, v_arena = (jnp.asarray(a, store) for a in codes)
-            sc = rng.uniform(0.5, 1.5, (2, NB, Nkv)) * (3.0 / qmax)
+            sc = rng.uniform(0.5, 1.5, (2, L, NB, Nkv)) * (3.0 / qmax)
             scales = {"k_scale": jnp.asarray(sc[0], jnp.float32),
                       "v_scale": jnp.asarray(sc[1], jnp.float32)}
-        args = (q, k_arena, v_arena, jnp.asarray(table), jnp.asarray(qpos),
-                jnp.asarray(kvpos, jnp.int32))
+        args = (q, k_arena, v_arena, layer, jnp.asarray(table),
+                jnp.asarray(qpos), jnp.asarray(kvpos, jnp.int32))
         if case["kernel"] == "paged_decode":
             got = pa.paged_attention(*args, backend=backend, **scales)
         else:

@@ -74,18 +74,24 @@ def init_cache(
     )
 
 
-def block_pool_shape(
+def paged_arena_shape(
     cfg: ModelConfig,
     num_blocks: int,
     block_size: int,
     num_layers: int | None = None,
 ) -> tuple:
-    """Per-stage shape of the POOLED paged-KV arena: ``[L, num_blocks,
-    block_size, Nkv, Dh]`` — the paged replacement for a dense cache's
-    ``[L, B, C, Nkv, Dh]``. Block 0 is reserved as the trash sink
+    """Per-stage shape of the POOLED paged-KV arena, HEAD-MAJOR: ``[L,
+    num_blocks, Nkv, block_size, Dh]`` — the paged replacement for a dense
+    cache's ``[L, B, C, Nkv, Dh]``. One block's one head is the
+    ``(block_size, Dh)`` tile the Pallas kernels stream, so the pool is
+    stored in the layout it is read in (``ops/paged_attention``: kernels
+    and gathers index ``(layer, block)`` of this array in place; nothing
+    transposes or slices it). Block 0 is reserved as the trash sink
     (``runtime/blocks.TRASH_BLOCK``); rows own block subsets through the
     per-row block tables in ``parallel/serve.ServeState``, so total KV HBM
-    scales with tokens actually in flight instead of rows × capacity."""
+    scales with tokens actually in flight instead of rows × capacity.
+    Bytes persisted in this layout (host and disk tiers, paged snapshots)
+    carry its name, ``runtime/blocks.PAGED_KV_LAYOUT``."""
     L = cfg.num_hidden_layers if num_layers is None else num_layers
     if num_blocks < 2:
         raise ValueError(
@@ -96,7 +102,7 @@ def block_pool_shape(
         raise ValueError(
             f"block_size must be a power of two, got {block_size}"
         )
-    return (L, num_blocks, block_size, cfg.num_key_value_heads, cfg.head_dim_)
+    return (L, num_blocks, cfg.num_key_value_heads, block_size, cfg.head_dim_)
 
 
 def clear(cache: KVCache) -> KVCache:

@@ -174,7 +174,7 @@ def forward_layers_paged(
     cfg: ModelConfig,
     layers: Params,
     h: jnp.ndarray,
-    k_arena: jnp.ndarray,  # [L, NB, BS, Nh, D]
+    k_arena: jnp.ndarray,  # [L, NB, Nh, BS, D]
     v_arena: jnp.ndarray,
     block_table: jnp.ndarray,  # [B, T]
     cols: jnp.ndarray,  # [B, S]
@@ -206,29 +206,30 @@ def forward_layers_paged(
         write_valid
     )
 
-    def apply(p, valid, h, k_l, v_l, ks_l, vs_l):
+    def apply(p, l, valid, h, k_all, v_all, ks_all, vs_all):
         out = {}
 
         def attn_fn(q, k, v):
-            if ks_l is None:
+            if ks_all is None:
                 k_a, v_a = write_block_kv(
-                    k_l, v_l, block_table, cols, k, v, valid=wv & valid,
+                    k_all, v_all, l, block_table, cols, k, v,
+                    valid=wv & valid,
                 )
                 out["kv"] = (k_a, v_a, None, None)
             else:
                 out["kv"] = write_block_kv(
-                    k_l, v_l, block_table, cols, k, v, valid=wv & valid,
-                    k_scale=ks_l, v_scale=vs_l,
+                    k_all, v_all, l, block_table, cols, k, v,
+                    valid=wv & valid, k_scale=ks_all, v_scale=vs_all,
                 )
                 k_a, v_a = out["kv"][0], out["kv"][1]
             if prefill:
                 return paged_prefill(
-                    q, k_a, v_a, block_table, positions, kv_positions,
+                    q, k_a, v_a, l, block_table, positions, kv_positions,
                     backend=backend, k_scale=out["kv"][2],
                     v_scale=out["kv"][3], nlive=nlive,
                 )
             return paged_attention(
-                q, k_a, v_a, block_table, positions, kv_positions,
+                q, k_a, v_a, l, block_table, positions, kv_positions,
                 backend=backend, k_scale=out["kv"][2],
                 v_scale=out["kv"][3],
             )

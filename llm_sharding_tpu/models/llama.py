@@ -222,9 +222,10 @@ def decoder_layer(
 def paged_decoder_layer(
     cfg: ModelConfig,
     p: Params,  # un-stacked single-layer params
+    layer: jnp.ndarray,  # scalar int32 — this layer's index in the stacks
     valid: jnp.ndarray,  # scalar bool — masked (padding) layer gate
     h: jnp.ndarray,  # [B, S, H]
-    k_arena: jnp.ndarray,  # [NB, BS, Nkv, D] this layer's pooled blocks
+    k_arena: jnp.ndarray,  # [L, NB, Nkv, BS, D] the WHOLE stacked pool
     v_arena: jnp.ndarray,
     block_table: jnp.ndarray,  # [B, T]
     cols: jnp.ndarray,  # [B, S] logical columns of this step's entries
@@ -235,7 +236,7 @@ def paged_decoder_layer(
     write_valid,  # scalar bool — ring-inactive microsteps gate writes
     tp_axis: Optional[str] = None,
     backend: str = "auto",
-    k_scale: Optional[jnp.ndarray] = None,  # [NB, Nkv] — quantized arena
+    k_scale: Optional[jnp.ndarray] = None,  # [L, NB, Nkv] — quantized arena
     v_scale: Optional[jnp.ndarray] = None,
     prefill: bool = False,  # static: chunk-shaped queries — attend via
     #   the query-tiled paged_prefill kernel instead of the decode one
@@ -243,9 +244,10 @@ def paged_decoder_layer(
     cp_axis: Optional[str] = None,  # context-parallel combine axis
 ):
     """Decode-path layer over the pooled arena: the step's fresh KV lands
-    via a block-indexed scatter and attention streams exactly the blocks
-    the table names (``ops/paged_attention``) — the logical window is
-    never materialized. A quantized arena (``k_scale``/``v_scale``)
+    via a block-indexed scatter into layer ``layer`` of the stacked pool
+    and attention streams exactly the blocks the table names out of that
+    layer (``ops/paged_attention``) — the logical window is never
+    materialized and the layer is never sliced out of the stack. A quantized arena (``k_scale``/``v_scale``)
     quantizes the fresh entries at insert and dequantizes inside the
     attention op (fused into the kernel's per-block DMA loop). With
     ``prefill`` the attention dispatch is ``paged_prefill`` — the
@@ -271,13 +273,13 @@ def paged_decoder_layer(
     def attn_fn(q, k, v):
         if k_scale is None:
             k_a, v_a = write_block_kv(
-                k_arena, v_arena, block_table, cols, k, v,
+                k_arena, v_arena, layer, block_table, cols, k, v,
                 valid=write_valid & valid,
             )
             out["kv"] = (k_a, v_a, None, None)
         else:
             k_a, v_a, ks, vs = write_block_kv(
-                k_arena, v_arena, block_table, cols, k, v,
+                k_arena, v_arena, layer, block_table, cols, k, v,
                 valid=write_valid & valid, k_scale=k_scale, v_scale=v_scale,
             )
             out["kv"] = (k_a, v_a, ks, vs)
@@ -285,13 +287,13 @@ def paged_decoder_layer(
         kw = dict(nlive=nlive) if prefill else {}
         if cp_axis is not None:
             acc, m, l = dispatch(
-                q, k_a, v_a, block_table, positions, kv_positions,
+                q, k_a, v_a, layer, block_table, positions, kv_positions,
                 backend=backend, k_scale=out["kv"][2],
                 v_scale=out["kv"][3], stats=True, **kw,
             )
             return combine_attn_stats(acc, m, l, cp_axis).astype(q.dtype)
         return dispatch(
-            q, k_a, v_a, block_table, positions, kv_positions,
+            q, k_a, v_a, layer, block_table, positions, kv_positions,
             backend=backend, k_scale=out["kv"][2], v_scale=out["kv"][3],
             **kw,
         )
@@ -304,7 +306,7 @@ def forward_layers_paged(
     cfg: ModelConfig,
     layers: Params,  # stacked [L, ...]
     h: jnp.ndarray,
-    k_arena: jnp.ndarray,  # [L, NB, BS, Nkv, D]
+    k_arena: jnp.ndarray,  # [L, NB, Nkv, BS, D]
     v_arena: jnp.ndarray,
     block_table: jnp.ndarray,  # [B, T]
     cols: jnp.ndarray,  # [B, S]
@@ -335,11 +337,11 @@ def forward_layers_paged(
         write_valid
     )
 
-    def apply(p, valid, h, k_l, v_l, ks_l, vs_l):
+    def apply(p, l, valid, h, k_all, v_all, ks_all, vs_all):
         return paged_decoder_layer(
-            cfg, p, valid, h, k_l, v_l, block_table, cols, cos, sin,
+            cfg, p, l, valid, h, k_all, v_all, block_table, cols, cos, sin,
             positions, kv_positions, wv, tp_axis, backend,
-            k_scale=ks_l, v_scale=vs_l, prefill=prefill, nlive=nlive,
+            k_scale=ks_all, v_scale=vs_all, prefill=prefill, nlive=nlive,
             cp_axis=cp_axis,
         )
 

@@ -77,10 +77,11 @@ def scan_layers(
 def scan_layers_paged(
     layers,
     h: jnp.ndarray,
-    k_arena: jnp.ndarray,  # [L, NB, BS, Nkv, D] pooled per-layer blocks
+    k_arena: jnp.ndarray,  # [L, NB, Nkv, BS, D] pooled head-major blocks
     v_arena: jnp.ndarray,
-    apply_layer,  # (p, valid, h, k_l, v_l, ks_l, vs_l) ->
-    #   (h, k_l, v_l, ks_l, vs_l) — scale slices are None unquantized
+    apply_layer,  # (p, l, valid, h, k_all, v_all, ks_all, vs_all) ->
+    #   (h, k_all, v_all, ks_all, vs_all) — the WHOLE stacks plus the
+    #   layer index; scale stacks are None unquantized
     layer_mask: Optional[jnp.ndarray] = None,
     k_scale: Optional[jnp.ndarray] = None,  # [L, NB, Nkv] f32 per-block-
     v_scale: Optional[jnp.ndarray] = None,  # per-head scales (quantized)
@@ -88,50 +89,37 @@ def scan_layers_paged(
     """Paged analogue of ``scan_layers``: the cache is the pooled block
     arena, and a layer's update is the tiny block-indexed scatter of this
     step's entries (``ops/paged_attention.write_block_kv`` inside
-    ``apply_layer``) — never a full-row or full-window write. Key-position
-    bookkeeping stays with the CALLER (the serve programs own the logical
-    ``kpos`` window; there is no per-scan ``KVCache.pos`` here). Layer
-    validity is passed INTO ``apply_layer`` so masked (padding) layers
-    gate their scattered entries instead of ``where``-ing the whole arena;
-    the hidden-state gate stays here like the dense scan.
+    ``apply_layer``) — never a full-row or full-window write. The
+    layer-stacked arena rides the scan carry and goes to ``apply_layer``
+    WHOLE, with the layer index: the attention ops address ``(l, block)``
+    inside it (the kernels through a scalar-prefetched ``l``, the XLA path
+    through one gather), and the write scatters into it, so the scan body
+    holds no operation that produces or consumes a layer of the pool — the
+    per-layer slice / write-back pair that used to bracket ``apply_layer``
+    is gone, and a step's cost no longer follows the pool's size.
+    Key-position bookkeeping stays with the CALLER (the serve programs own
+    the logical ``kpos`` window; there is no per-scan ``KVCache.pos``
+    here). Layer validity is passed INTO ``apply_layer`` so masked
+    (padding) layers gate their scattered entries instead of ``where``-ing
+    the whole arena; the hidden-state gate stays here like the dense scan.
 
-    A QUANTIZED arena (int8/fp8 storage) carries its per-layer scale
-    arenas through the same scan (``None`` leaves are empty pytree nodes,
-    so the unquantized carry is unchanged). Returns ``(h, k_arena,
-    v_arena, k_scale, v_scale)`` — the scale outputs are None when the
-    arena is unquantized."""
+    A QUANTIZED arena (int8/fp8 storage) carries its scale stacks through
+    the same scan (``None`` leaves are empty pytree nodes, so the
+    unquantized carry is unchanged). Returns ``(h, k_arena, v_arena,
+    k_scale, v_scale)`` — the scale outputs are None when the arena is
+    unquantized."""
     L = k_arena.shape[0]
     if layer_mask is None:
         layer_mask = jnp.ones((L,), bool)
 
-    # kv_take / kv_put: the scopes a profiler trace files the per-layer
-    # arena slice and write-back under (obs.stepline.SCOPES)
-    @jax.named_scope("kv_take")
-    def take(all_, l):
-        return (
-            None if all_ is None
-            else jax.lax.dynamic_index_in_dim(all_, l, keepdims=False)
-        )
-
-    @jax.named_scope("kv_put")
-    def put(all_, l, one):
-        if all_ is None:
-            return None
-        zeros = (0,) * (all_.ndim - 1)
-        return jax.lax.dynamic_update_slice(all_, one[None], (l, *zeros))
-
     def body(carry, xs):
         h, k_all, v_all, ks_all, vs_all = carry
         p, l, valid = xs
-        h_new, k_l, v_l, ks_l, vs_l = apply_layer(
-            p, valid, h, take(k_all, l), take(v_all, l),
-            take(ks_all, l), take(vs_all, l),
+        h_new, k_all, v_all, ks_all, vs_all = apply_layer(
+            p, l, valid, h, k_all, v_all, ks_all, vs_all
         )
         h = jnp.where(valid, h_new, h)
-        return (
-            h, put(k_all, l, k_l), put(v_all, l, v_l),
-            put(ks_all, l, ks_l), put(vs_all, l, vs_l),
-        ), None
+        return (h, k_all, v_all, ks_all, vs_all), None
 
     (h, k_arena, v_arena, k_scale, v_scale), _ = jax.lax.scan(
         body, (h, k_arena, v_arena, k_scale, v_scale),

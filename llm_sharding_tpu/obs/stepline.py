@@ -117,9 +117,13 @@ SCOPES = (
     "qkv",        # q/k/v projections and their biases
     "rope",       # rotary tables and their application
     "kv_write",   # this step's fresh KV entries into their blocks/rows
-    "kv_take",    # one layer sliced out of the arena (scan_layers_paged)
-    "kv_layout",  # head-major transposes of the K/V operands of a kernel
-    "kv_put",     # the layer written back into the arena
+    "kv_take",    # one layer's rows sliced out of the DENSE cache stack
+                  # (scan_layers; the paged scan slices nothing)
+    "kv_layout",  # layout changes of a WINDOW of K/V: the flash kernel's
+                  # operands, a gathered paged window (XLA path, prefix
+                  # handle), an admitted window cut into head-major blocks
+                  # — never the paged pool, which is stored as it is read
+    "kv_put",     # the step's positions written back into the dense stack
     "attn",       # the attention kernel / XLA attention and its GQA fold
     "o_proj",     # output projection, its psum, the residual add
     "mlp",        # gated MLP, its psum, the residual add

@@ -4,10 +4,17 @@ al., SOSP'23 — the vLLM allocation model, TPU-native).
 Dense serving reserves ``capacity`` KV columns per row and decode attention
 reads all of them every step (``ops/attention.cached_attention`` over
 ``[B, C, ...]``). Paged serving stores KV in a shared arena of fixed-size
-blocks ``[num_blocks, block_size, Nkv, D]``; each row maps the blocks
-covering its ACTUAL tokens through a block table ``[B, T]`` (entry 0 — the
-reserved trash block — pads unmapped slots). This module provides the
-attention over that layout:
+blocks, HEAD-MAJOR and layer-stacked: ``[L, num_blocks, Nkv, block_size,
+D]`` (``models/cache.paged_arena_shape``) — one block's one head is the
+``(block_size, D)`` tile the kernels stream, so the pool is stored in the
+layout it is read in and nothing ever transposes it. Each row maps the
+blocks covering its ACTUAL tokens through a block table ``[B, T]`` (entry
+0 — the reserved trash block — pads unmapped slots). Every op here takes
+the WHOLE stack plus a ``layer`` index and addresses ``(layer, block)``
+inside it: the layer scan carries the stack and no operation produces or
+consumes a value of a layer's arena size (the gathers, the scatters and
+the kernels' block DMAs index the carried array in place). This module
+provides the attention over that layout:
 
 - ``gather_block_kv`` / ``paged_attention_xla``: the exact XLA path — an
   advanced-indexing gather assembles each row's logical window, then the
@@ -19,7 +26,8 @@ attention over that layout:
   the gathered window in HBM. The block table rides as a SCALAR-PREFETCH
   operand (``pltpu.PrefetchScalarGridSpec``), so each grid step's
   ``BlockSpec`` index maps pick the arena blocks to DMA directly from the
-  table — ``blocks_per_step`` of them per sequential step
+  table (and the layer from a second scalar-prefetched operand) —
+  ``blocks_per_step`` of them per sequential step
   (``auto_blocks_per_step``; independent refs the compiler overlaps and
   double-buffers) — and blocks stream through VMEM with online-softmax
   accumulation exactly like ``ops/flash_attention``.
@@ -153,20 +161,32 @@ def kernel_eligible(
     )
 
 
+@jax.named_scope("kv_layout")
+def window_from_blocks(blocks: jnp.ndarray) -> jnp.ndarray:
+    """GATHERED head-major blocks ``[A, T, Nkv, BS, D]`` laid out as the
+    token-major logical window ``[A, T*BS, Nkv, D]`` that
+    ``cached_attention`` and a prefix handle read — a layout change of the
+    gathered window (rows x T x BS entries), never of the pool."""
+    A, T, Nkv, BS, D = blocks.shape
+    return jnp.transpose(blocks, (0, 1, 3, 2, 4)).reshape(A, T * BS, Nkv, D)
+
+
 def gather_block_kv(
-    k_arena: jnp.ndarray,  # [NB, BS, Nkv, D] pooled key blocks
-    v_arena: jnp.ndarray,  # [NB, BS, Nkv, D]
+    k_arena: jnp.ndarray,  # [L, NB, Nkv, BS, D] pooled key blocks
+    v_arena: jnp.ndarray,  # [L, NB, Nkv, BS, D]
+    layer,  # scalar int32 — the layer whose blocks the table names
     block_table: jnp.ndarray,  # [B, T] int32 arena block ids per row
-    k_scale: jnp.ndarray = None,  # [NB, Nkv] f32 per-block-per-head scales
-    v_scale: jnp.ndarray = None,  # (quantized arenas only)
+    k_scale: jnp.ndarray = None,  # [L, NB, Nkv] f32 per-block-per-head
+    v_scale: jnp.ndarray = None,  # scales (quantized arenas only)
     out_dtype=None,  # dequant target; defaults to the scale dtype
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Assemble each row's logical KV window ``[B, T*BS, Nkv, D]`` from the
-    arena. The gather is the XLA fallback's only extra cost over dense
-    attention; duplicate table entries (shared prefix blocks, trash
-    padding) are plain repeated reads. Trash-mapped entries (block 0)
-    gather as ZEROS: the shared trash block accumulates parked rows'
-    garbage writes, and although attention masks those positions to
+    arena: ONE gather at ``(layer, block_table)`` of the stacked pool, then
+    ``window_from_blocks`` lays the gathered window out token-major. The gather is the XLA fallback's only extra
+    cost over dense attention; duplicate table entries (shared prefix
+    blocks, trash padding) are plain repeated reads. Trash-mapped entries
+    (block 0) gather as ZEROS: the shared trash block accumulates parked
+    rows' garbage writes, and although attention masks those positions to
     probability exactly 0, a non-finite garbage value would still produce
     ``0 × Inf = NaN`` in the PV product — zeroing closes the channel
     without touching live numerics.
@@ -175,44 +195,42 @@ def gather_block_kv(
     DEQUANTIZES: each block's values multiply by its per-head scale and
     the window comes out in ``out_dtype`` — the XLA-path analogue of the
     Pallas kernel's in-VMEM fused dequant."""
-    B, T = block_table.shape
-    BS = k_arena.shape[1]
-    k = k_arena[block_table]  # [B, T, BS, Nkv, D]
-    v = v_arena[block_table]
+    k = k_arena[layer, block_table]  # [B, T, Nkv, BS, D]
+    v = v_arena[layer, block_table]
     if k_scale is not None:
         dt = out_dtype or k_scale.dtype
-        k = kv_dequantize(k, k_scale[block_table][:, :, None, :, None], dt)
-        v = kv_dequantize(v, v_scale[block_table][:, :, None, :, None], dt)
+        k = kv_dequantize(k, k_scale[layer, block_table][..., None, None], dt)
+        v = kv_dequantize(v, v_scale[layer, block_table][..., None, None], dt)
     live = (block_table != 0)[:, :, None, None, None]
     k = jnp.where(live, k, jnp.zeros((), k.dtype))
     v = jnp.where(live, v, jnp.zeros((), v.dtype))
-    return (
-        k.reshape(B, T * BS, *k.shape[3:]),
-        v.reshape(B, T * BS, *v.shape[3:]),
-    )
+    return window_from_blocks(k), window_from_blocks(v)
 
 
 @jax.named_scope("kv_write")
 def write_block_kv(
-    k_arena: jnp.ndarray,  # [NB, BS, Nkv, D] pooled key blocks
-    v_arena: jnp.ndarray,  # [NB, BS, Nkv, D]
+    k_arena: jnp.ndarray,  # [L, NB, Nkv, BS, D] pooled key blocks
+    v_arena: jnp.ndarray,  # [L, NB, Nkv, BS, D]
+    layer,  # scalar int32 — the layer the entries belong to
     block_table: jnp.ndarray,  # [B, T] int32 arena block ids per row
     cols: jnp.ndarray,  # [B, S] int32 logical columns of the new entries
     k_new: jnp.ndarray,  # [B, S, Nkv, D]
     v_new: jnp.ndarray,  # [B, S, Nkv, D]
     valid=None,  # scalar or [B, S] bool — False entries keep old contents
-    k_scale: jnp.ndarray = None,  # [NB, Nkv] f32 — quantized arenas only
+    k_scale: jnp.ndarray = None,  # [L, NB, Nkv] f32 — quantized arenas only
     v_scale: jnp.ndarray = None,
 ):
-    """Scatter a step's fresh KV entries into their OWNING arena blocks —
-    the decode-path replacement for the full-window gather→update→scatter
-    round trip: per step the arena update is ``B × S`` slots, not the
-    logical window. Column ``c`` of row ``b`` lives in arena block
-    ``block_table[b, c // BS]`` at slot ``c % BS``; trash-mapped columns
-    (table entry 0) land in the shared trash sink, which absorbs them
-    (parked-slot garbage, spec-verify overflow past a row's mapped budget
-    — the sink's contents are never attended: readers gate entry 0 to
-    zeros and position masking excludes them anyway).
+    """Scatter a step's fresh KV entries into their OWNING arena blocks of
+    the layer-stacked pool — the decode-path replacement for the
+    full-window gather→update→scatter round trip: per step the arena
+    update is ``B × S`` entries of ``(Nkv, D)`` at ``(layer, block, :,
+    slot)`` of the array the layer scan carries, not the logical window
+    and never a layer of the pool. Column ``c`` of row ``b`` lives in
+    arena block ``block_table[b, c // BS]`` at slot ``c % BS``;
+    trash-mapped columns (table entry 0) land in the shared trash sink,
+    which absorbs them (parked-slot garbage, spec-verify overflow past a
+    row's mapped budget — the sink's contents are never attended: readers
+    gate entry 0 to zeros and position masking excludes them anyway).
 
     ``valid`` gates at ENTRY granularity — invalid entries write back the
     values just gathered from the arena, so ring-inactive microsteps and
@@ -225,18 +243,30 @@ def write_block_kv(
     QUANTIZES AT INSERT against a RUNNING per-block-per-head absmax: a
     fresh entry that raises its block's scale first requantizes the
     block's existing codes to the new scale (a dequant→requant round on
-    exactly the touched blocks — ≤ one block per written entry), then
-    lands quantized. Scale updates scatter with ``.at[].max`` so several
-    entries of one call hitting the same block resolve order-free, and
-    the block-content rewrite is identical for every colliding entry
-    (same source block, same final scale) — race-free like the prefix
-    broadcast. Returns ``(k_arena, v_arena, k_scale, v_scale)`` in
-    quantized mode, the plain ``(k_arena, v_arena)`` pair otherwise."""
-    BS = k_arena.shape[1]
+    exactly the touched blocks at ``(layer, block)`` — ≤ one block per
+    written entry), then lands quantized. Scale updates scatter with
+    ``.at[].max`` so several entries of one call hitting the same block
+    resolve order-free, and the block-content rewrite is identical for
+    every colliding entry (same source block, same final scale) —
+    race-free like the prefix broadcast. Returns ``(k_arena, v_arena,
+    k_scale, v_scale)`` in quantized mode, the plain ``(k_arena,
+    v_arena)`` pair otherwise — always the whole stacks."""
+    Nkv, BS = k_arena.shape[2], k_arena.shape[3]
     W = block_table.shape[1] * BS
     cols = jnp.clip(cols, 0, W - 1)  # defense: XLA clamps, tables don't
     blk = jnp.take_along_axis(block_table, cols // BS, axis=1)  # [B, S]
     slot = cols % BS
+    # an entry is Nkv rows of D, one per head, at (layer, blk, h, slot): the
+    # scatter (and the gate's gather) index EVERY dim but D, so the updated
+    # window is the arena's minor dim alone. With the head dim left as a
+    # window dim XLA's layout assignment re-lays the whole carried stack
+    # token-major for the scatter's sake and copies it back, per layer, for
+    # the kernel (seen in the compiled v5e program) — the very copies this
+    # layout exists to remove.
+    entry = (
+        layer, blk[:, :, None], jnp.arange(Nkv)[None, None, :],
+        slot[:, :, None],
+    )  # → [B, S, Nkv] rows of D
     if k_scale is None:
         kn = k_new.astype(k_arena.dtype)
         vn = v_new.astype(v_arena.dtype)
@@ -244,9 +274,9 @@ def write_block_kv(
             keep = jnp.asarray(valid)
             if keep.ndim:  # [B, S] → broadcast over the (Nkv, D) entry dims
                 keep = keep[..., None, None]
-            kn = jnp.where(keep, kn, k_arena[blk, slot])
-            vn = jnp.where(keep, vn, v_arena[blk, slot])
-        return k_arena.at[blk, slot].set(kn), v_arena.at[blk, slot].set(vn)
+            kn = jnp.where(keep, kn, k_arena[entry])
+            vn = jnp.where(keep, vn, v_arena[entry])
+        return k_arena.at[entry].set(kn), v_arena.at[entry].set(vn)
 
     qmax = kv_qmax(k_arena.dtype)
     keep = None
@@ -262,23 +292,23 @@ def write_block_kv(
         cand = jnp.max(jnp.abs(new.astype(jnp.float32)), axis=-1) / qmax
         if keep is not None:
             cand = jnp.where(keep[..., None], cand, 0.0)
-        s_old = scale[blk]  # [B, S, Nkv] pre-update block scales
-        scale_new = scale.at[blk].max(cand)
-        s_fin = scale_new[blk]  # post-scatter final scales
+        s_old = scale[layer, blk]  # [B, S, Nkv] pre-update block scales
+        scale_new = scale.at[layer, blk].max(cand)
+        s_fin = scale_new[layer, blk]  # post-scatter final scales
         # requantize the touched blocks' existing codes to the final scale
         # (a no-op rewrite when the scale did not grow: round(q * 1.0))
-        old = arena[blk]  # [B, S, BS, Nkv, D]
-        old_f = kv_dequantize(old, s_old[:, :, None, :, None], jnp.float32)
-        req = kv_quantize(old_f, s_fin[:, :, None, :, None], arena.dtype)
-        arena = arena.at[blk].set(req)
+        old = arena[layer, blk]  # [B, S, Nkv, BS, D]
+        old_f = kv_dequantize(old, s_old[..., None, None], jnp.float32)
+        req = kv_quantize(old_f, s_fin[..., None, None], arena.dtype)
+        arena = arena.at[layer, blk].set(req)
         qn = kv_quantize(new, s_fin[..., None], arena.dtype)
         if keep is not None:
             idx = jnp.broadcast_to(
-                slot[:, :, None, None, None], (B, S, 1, Nkv, D)
+                slot[:, :, None, None, None], (B, S, Nkv, 1, D)
             )
-            old_entry = jnp.take_along_axis(req, idx, axis=2)[:, :, 0]
+            old_entry = jnp.take_along_axis(req, idx, axis=3)[:, :, :, 0]
             qn = jnp.where(keep[..., None, None], qn, old_entry)
-        return arena.at[blk, slot].set(qn), scale_new
+        return arena.at[entry].set(qn), scale_new
 
     k_arena, k_scale = one(k_arena, k_scale, k_new)
     v_arena, v_scale = one(v_arena, v_scale, v_new)
@@ -287,33 +317,36 @@ def write_block_kv(
 
 def paged_attention_xla(
     q: jnp.ndarray,  # [B, S, Nh, D] (RoPE'd)
-    k_arena: jnp.ndarray,  # [NB, BS, Nkv, D]
+    k_arena: jnp.ndarray,  # [L, NB, Nkv, BS, D]
     v_arena: jnp.ndarray,
+    layer,  # scalar int32
     block_table: jnp.ndarray,  # [B, T]
     q_positions: jnp.ndarray,  # [B, S]
     kv_positions: jnp.ndarray,  # [B, T*BS] logical-column key positions
     scale: float | None = None,
-    k_scale: jnp.ndarray = None,  # [NB, Nkv] — quantized arenas only
+    k_scale: jnp.ndarray = None,  # [L, NB, Nkv] — quantized arenas only
     v_scale: jnp.ndarray = None,
 ) -> jnp.ndarray:
     """Gather + position-masked attention: exact on every backend. A
     quantized arena dequantizes at the gather into the QUERY dtype — the
     same dequant target as the fused kernel, so the two paths match."""
     k, v = gather_block_kv(
-        k_arena, v_arena, block_table, k_scale, v_scale, out_dtype=q.dtype
+        k_arena, v_arena, layer, block_table, k_scale, v_scale,
+        out_dtype=q.dtype,
     )
     return cached_attention(q, k, v, q_positions, kv_positions, scale)
 
 
 def attn_stats_xla(
     q: jnp.ndarray,  # [B, S, Nh, D] (RoPE'd)
-    k_arena: jnp.ndarray,  # [NB, BS, Nkv, D]
+    k_arena: jnp.ndarray,  # [L, NB, Nkv, BS, D]
     v_arena: jnp.ndarray,
+    layer,  # scalar int32
     block_table: jnp.ndarray,  # [B, T]
     q_positions: jnp.ndarray,  # [B, S]
     kv_positions: jnp.ndarray,  # [B, T*BS] logical-column key positions
     scale: float | None = None,
-    k_scale: jnp.ndarray = None,  # [NB, Nkv] — quantized arenas only
+    k_scale: jnp.ndarray = None,  # [L, NB, Nkv] — quantized arenas only
     v_scale: jnp.ndarray = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Partial-softmax attention statistics over the LOCAL arena — the
@@ -336,9 +369,10 @@ def attn_stats_xla(
     yields ``(0, NEG_INF, 0)``, which the combine's correction factor
     wipes instead of counting ``exp(0) = 1`` per dead column."""
     B, S, Nh, D = q.shape
-    BS = k_arena.shape[1]
+    BS = k_arena.shape[3]
     k, v = gather_block_kv(
-        k_arena, v_arena, block_table, k_scale, v_scale, out_dtype=q.dtype
+        k_arena, v_arena, layer, block_table, k_scale, v_scale,
+        out_dtype=q.dtype,
     )
     Nkv = k.shape[2]
     G = Nh // Nkv
@@ -399,12 +433,19 @@ def combine_attn_stats(
 
 
 def _scale_operand(scale: jnp.ndarray) -> jnp.ndarray:
-    """The kernels' view of a ``[NB, Nkv]`` scale arena: ``[NB, Nkv, 1, 1]``
-    f32, so one block's one head is a ``(1, 1, 1, 1)`` VMEM tile whose last
-    two dims ARE the array's. Mosaic refuses a ``(1, 1)`` block of the 2-D
-    array in any memory space (the last two block dims must be multiples of
-    (8, 128) or the whole array's)."""
-    return scale.astype(jnp.float32)[:, :, None, None]
+    """The kernels' view of a ``[L, NB, Nkv]`` scale arena: ``[L, NB, Nkv,
+    1, 1]`` f32, so one layer's one block's one head is a ``(1, 1, 1, 1)``
+    VMEM tile (layer dim squeezed) whose last two dims ARE the array's.
+    Mosaic refuses a ``(1, 1)`` block of the lower-rank array in any memory
+    space (the last two block dims must be multiples of (8, 128) or the
+    whole array's)."""
+    return scale.astype(jnp.float32)[..., None, None]
+
+
+def _layer_operand(layer) -> jnp.ndarray:
+    """The layer index as the kernels' scalar-prefetch operand: ``[1]``
+    int32 in scalar memory, read by every arena/scale index map."""
+    return jnp.asarray(layer, jnp.int32).reshape(1)
 
 
 def _online_update(q, k, v, mask, scale, acc_ref, m_ref, l_ref):
@@ -436,6 +477,7 @@ def _online_update(q, k, v, mask, scale, acc_ref, m_ref, l_ref):
 
 
 def _paged_kernel(
+    layer_ref,  # scalar-prefetch [1] — read by the index maps only
     tbl_ref,  # scalar-prefetch [B, T] (read by the index maps + trash gate)
     q_ref,  # [1, 1, GS, D]
     *rest,  # bps k refs [1, 1, BS, D] (the arena blocks the index maps
@@ -517,21 +559,25 @@ def _paged_kernel(
 )
 def paged_attention_tpu(
     q: jnp.ndarray,  # [B, S, Nh, D]
-    k_arena: jnp.ndarray,  # [NB, BS, Nkv, D]
+    k_arena: jnp.ndarray,  # [L, NB, Nkv, BS, D] — the layer-stacked pool
     v_arena: jnp.ndarray,
+    layer,  # scalar int32 — scalar-prefetched beside the table
     block_table: jnp.ndarray,  # [B, T] int32
     q_positions: jnp.ndarray,  # [B, S]
     kv_positions: jnp.ndarray,  # [B, T*BS]
     scale: float | None = None,
     interpret: bool = False,
-    k_scale: jnp.ndarray = None,  # [NB, Nkv] — quantized arenas only
+    k_scale: jnp.ndarray = None,  # [L, NB, Nkv] — quantized arenas only
     v_scale: jnp.ndarray = None,
     blocks_per_step: int | None = None,  # static; None = auto-selected
 ) -> jnp.ndarray:
     """Pallas paged attention: grid ``(B, Nkv, T/bps)``, the last axis
     sequential. Each step DMAs ``bps`` arena blocks (``blocks_per_step``,
     auto-selected from the table width by ``auto_blocks_per_step`` when
-    None), each chosen by the scalar-prefetched block table — the gathered
+    None), each a ``(BS, D)`` tile at ``(layer, table[b, t], k)`` of the
+    5-D stacked pool, chosen by the scalar-prefetched layer index and
+    block table — the arena is read where it lies (no slice of a layer, no
+    layout change), the gathered
     window never exists in HBM, and the ``bps`` per-step fetches are
     independent refs the compiler overlaps and double-buffers across
     steps (one skinny (BS, D) DMA per step left the MXU waiting on the
@@ -553,7 +599,7 @@ def paged_attention_tpu(
     before the score dot (the hook PR 6 left open). Int8 tiles want BS a
     multiple of 32 (1-byte sublane — ``kernel_eligible``)."""
     B, S, Nh, D = q.shape
-    NB, BS, Nkv = k_arena.shape[0], k_arena.shape[1], k_arena.shape[2]
+    Nkv, BS = k_arena.shape[2], k_arena.shape[3]
     T = block_table.shape[1]
     G = Nh // Nkv
     GS = G * S
@@ -574,33 +620,37 @@ def paged_attention_tpu(
     # GQA fold (the reshape contract of cached_attention: head h = k*G + g)
     qh = jnp.transpose(q, (0, 2, 1, 3)).reshape(B, Nkv, GS, D)
     qp = jnp.tile(q_positions, (1, G))[..., None]  # [B, GS, 1]
-    with jax.named_scope("kv_layout"):  # the whole arena, head-major
-        kh = jnp.transpose(k_arena, (0, 2, 1, 3))  # [NB, Nkv, BS, D]
-        vh = jnp.transpose(v_arena, (0, 2, 1, 3))
     kp = kv_positions.reshape(B, T, 1, BS)  # one [1, BS] lane row per block
 
     # the arena-block specs: each grid cell streams the bps blocks the
-    # scalar-prefetched table names (one ref per sub-block — independent
-    # DMAs); quantized runs add each block's per-head scale, picked by the
-    # same indices out of a [NB, Nkv, 1, 1] view (see _scale_operand)
+    # scalar-prefetched table names out of the scalar-prefetched layer of
+    # the stacked pool (one ref per sub-block — independent DMAs; the
+    # layer dim is squeezed, so the kernel sees the same [1, 1, BS, D]
+    # refs as ever); quantized runs add each block's per-head scale,
+    # picked by the same indices out of a [L, NB, Nkv, 1, 1] view (see
+    # _scale_operand)
+    def arena_index(b, k, t, lyr, tbl, j):
+        return (lyr[0], tbl[b, t * bps + j], k, 0, 0)
+
     def block_spec(j):
         return pl.BlockSpec(
-            (1, 1, BS, D),
-            lambda b, k, t, tbl, j=j: (tbl[b, t * bps + j], k, 0, 0),
+            (None, 1, 1, BS, D), functools.partial(arena_index, j=j)
         )
 
     def scale_spec(j):
         return pl.BlockSpec(
-            (1, 1, 1, 1),
-            lambda b, k, t, tbl, j=j: (tbl[b, t * bps + j], k, 0, 0),
+            (None, 1, 1, 1, 1), functools.partial(arena_index, j=j)
         )
 
     in_specs = [
-        pl.BlockSpec((1, 1, GS, D), lambda b, k, t, tbl: (b, k, 0, 0)),
+        pl.BlockSpec((1, 1, GS, D), lambda b, k, t, lyr, tbl: (b, k, 0, 0)),
         *[block_spec(j) for j in range(bps)],
         *[block_spec(j) for j in range(bps)],
     ]
-    operands = [block_table, qh, *([kh] * bps), *([vh] * bps)]
+    operands = [
+        _layer_operand(layer), block_table, qh,
+        *([k_arena] * bps), *([v_arena] * bps),
+    ]
     if quantized:
         in_specs += (
             [scale_spec(j) for j in range(bps)]
@@ -610,16 +660,18 @@ def paged_attention_tpu(
             [_scale_operand(k_scale)] * bps + [_scale_operand(v_scale)] * bps
         )
     in_specs += [
-        pl.BlockSpec((1, GS, 1), lambda b, k, t, tbl: (b, 0, 0)),
-        pl.BlockSpec((1, bps, 1, BS), lambda b, k, t, tbl: (b, t, 0, 0)),
+        pl.BlockSpec((1, GS, 1), lambda b, k, t, lyr, tbl: (b, 0, 0)),
+        pl.BlockSpec(
+            (1, bps, 1, BS), lambda b, k, t, lyr, tbl: (b, t, 0, 0)
+        ),
     ]
     operands += [qp, kp]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(B, Nkv, T // bps),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, 1, GS, D), lambda b, k, t, tbl: (b, k, 0, 0)
+            (1, 1, GS, D), lambda b, k, t, lyr, tbl: (b, k, 0, 0)
         ),
         scratch_shapes=[
             pltpu.VMEM((GS, D), jnp.float32),
@@ -652,6 +704,7 @@ BLOCK_Q_PREFILL = 256
 
 
 def _paged_prefill_kernel(
+    layer_ref,  # scalar-prefetch [1] — read by the index maps only
     tbl_ref,  # scalar-prefetch [B, T]
     nlive_ref,  # scalar-prefetch [B] — live (attendable) blocks per row
     q_ref,  # [1, 1, BQ, D]
@@ -720,14 +773,15 @@ def _paged_prefill_kernel(
 )
 def paged_prefill_tpu(
     q: jnp.ndarray,  # [B, S, Nh, D] — S = the chunk length (many rows)
-    k_arena: jnp.ndarray,  # [NB, BS, Nkv, D]
+    k_arena: jnp.ndarray,  # [L, NB, Nkv, BS, D] — the layer-stacked pool
     v_arena: jnp.ndarray,
+    layer,  # scalar int32 — scalar-prefetched beside the table
     block_table: jnp.ndarray,  # [B, T] int32
     q_positions: jnp.ndarray,  # [B, S]
     kv_positions: jnp.ndarray,  # [B, T*BS]
     scale: float | None = None,
     interpret: bool = False,
-    k_scale: jnp.ndarray = None,  # [NB, Nkv] — quantized arenas only
+    k_scale: jnp.ndarray = None,  # [L, NB, Nkv] — quantized arenas only
     v_scale: jnp.ndarray = None,
     nlive: jnp.ndarray = None,  # [B] int32 — blocks covering each row's
     #   written frontier (prefix + chunks so far); None = the full table
@@ -757,7 +811,7 @@ def paged_prefill_tpu(
     already excluded those blocks (sentinel positions); the clamp is
     pure traffic, bit-identical either way."""
     B, S, Nh, D = q.shape
-    NB, BS, Nkv = k_arena.shape[0], k_arena.shape[1], k_arena.shape[2]
+    Nkv, BS = k_arena.shape[2], k_arena.shape[3]
     T = block_table.shape[1]
     G = Nh // Nkv
     quantized = k_scale is not None
@@ -791,44 +845,38 @@ def paged_prefill_tpu(
         )
     GSp = GS + pad_q
     qp = qp[..., None]  # [B, GSp, 1] — sublane-major (see _flash_kernel)
-    with jax.named_scope("kv_layout"):  # the whole arena, head-major
-        kh = jnp.transpose(k_arena, (0, 2, 1, 3))  # [NB, Nkv, BS, D]
-        vh = jnp.transpose(v_arena, (0, 2, 1, 3))
     kp = kv_positions.reshape(B, T, 1, BS)  # lane-major, one row per block
 
-    # arena-block specs: the frontier clamp lives in the INDEX MAP — a
-    # dead step re-names block 0, whose DMA Pallas elides when the index
-    # is unchanged from the previous step
+    # arena-block specs, (layer, block, head) of the stacked pool like the
+    # decode kernel's: the frontier clamp lives in the INDEX MAP — a dead
+    # step re-names block 0, whose DMA Pallas elides when the index is
+    # unchanged from the previous step
+    def arena_index(b, k, i, t, lyr, tbl, nl, j):
+        idx = t * bps + j
+        return (lyr[0], jnp.where(idx < nl[b], tbl[b, idx], 0), k, 0, 0)
+
     def block_spec(j):
         return pl.BlockSpec(
-            (1, 1, BS, D),
-            lambda b, k, i, t, tbl, nl, j=j: (
-                jnp.where(
-                    t * bps + j < nl[b], tbl[b, t * bps + j], 0
-                ),
-                k, 0, 0,
-            ),
+            (None, 1, 1, BS, D), functools.partial(arena_index, j=j)
         )
 
     def scale_spec(j):
         return pl.BlockSpec(
-            (1, 1, 1, 1),
-            lambda b, k, i, t, tbl, nl, j=j: (
-                jnp.where(
-                    t * bps + j < nl[b], tbl[b, t * bps + j], 0
-                ),
-                k, 0, 0,
-            ),
+            (None, 1, 1, 1, 1), functools.partial(arena_index, j=j)
         )
 
     in_specs = [
         pl.BlockSpec(
-            (1, 1, block_q, D), lambda b, k, i, t, tbl, nl: (b, k, i, 0)
+            (1, 1, block_q, D),
+            lambda b, k, i, t, lyr, tbl, nl: (b, k, i, 0),
         ),
         *[block_spec(j) for j in range(bps)],
         *[block_spec(j) for j in range(bps)],
     ]
-    operands = [block_table, nlive, qh, *([kh] * bps), *([vh] * bps)]
+    operands = [
+        _layer_operand(layer), block_table, nlive, qh,
+        *([k_arena] * bps), *([v_arena] * bps),
+    ]
     if quantized:
         in_specs += (
             [scale_spec(j) for j in range(bps)]
@@ -839,19 +887,21 @@ def paged_prefill_tpu(
         )
     in_specs += [
         pl.BlockSpec(
-            (1, block_q, 1), lambda b, k, i, t, tbl, nl: (b, i, 0)
+            (1, block_q, 1), lambda b, k, i, t, lyr, tbl, nl: (b, i, 0)
         ),
         pl.BlockSpec(
-            (1, bps, 1, BS), lambda b, k, i, t, tbl, nl: (b, t, 0, 0)
+            (1, bps, 1, BS),
+            lambda b, k, i, t, lyr, tbl, nl: (b, t, 0, 0),
         ),
     ]
     operands += [qp, kp]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, Nkv, GSp // block_q, T // bps),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, 1, block_q, D), lambda b, k, i, t, tbl, nl: (b, k, i, 0)
+            (1, 1, block_q, D),
+            lambda b, k, i, t, lyr, tbl, nl: (b, k, i, 0),
         ),
         scratch_shapes=[
             pltpu.VMEM((block_q, D), jnp.float32),
@@ -882,7 +932,7 @@ def _ineligible_msg(op: str, k_arena, block_table) -> str:
     rows, width = block_table.shape
     return (
         f"{op} backend 'kernel': head_dim={k_arena.shape[-1]} / "
-        f"block_size={k_arena.shape[1]} / block table [{rows}, {width}] are "
+        f"block_size={k_arena.shape[3]} / block table [{rows}, {width}] are "
         f"not Mosaic-eligible for cache dtype "
         f"{jnp.dtype(k_arena.dtype).name} (head_dim must be a multiple of "
         f"128, the block size a sublane multiple, and the table must fit "
@@ -894,14 +944,15 @@ def _ineligible_msg(op: str, k_arena, block_table) -> str:
 @jax.named_scope("attn")
 def paged_prefill(
     q: jnp.ndarray,
-    k_arena: jnp.ndarray,
+    k_arena: jnp.ndarray,  # [L, NB, Nkv, BS, D] — the layer-stacked pool
     v_arena: jnp.ndarray,
+    layer,  # scalar int32 — which layer of the stack to attend
     block_table: jnp.ndarray,
     q_positions: jnp.ndarray,
     kv_positions: jnp.ndarray,
     scale: float | None = None,
     backend: str = "auto",
-    k_scale: jnp.ndarray = None,  # [NB, Nkv] — quantized arenas only
+    k_scale: jnp.ndarray = None,  # [L, NB, Nkv] — quantized arenas only
     v_scale: jnp.ndarray = None,
     nlive: jnp.ndarray = None,  # [B] — kernel-path traffic clamp
     stats: bool = False,  # static: return (acc, m, l) partials (cp serve)
@@ -927,20 +978,21 @@ def paged_prefill(
         )
     if stats:
         return attn_stats_xla(
-            q, k_arena, v_arena, block_table, q_positions, kv_positions,
-            scale, k_scale=k_scale, v_scale=v_scale,
+            q, k_arena, v_arena, layer, block_table, q_positions,
+            kv_positions, scale, k_scale=k_scale, v_scale=v_scale,
         )
     if backend == "auto":
         backend = forced_backend() or "auto"
     eligible = kernel_eligible(
-        q.shape[-1], k_arena.shape[1], k_arena.dtype, rows=block_table.shape[0],
+        q.shape[-1], k_arena.shape[3], k_arena.dtype,
+        rows=block_table.shape[0],
         table_width=block_table.shape[1],
     )
     if backend == "interpret":
         return paged_prefill_tpu(
-            q, k_arena, v_arena, block_table, q_positions, kv_positions,
-            scale, interpret=True, k_scale=k_scale, v_scale=v_scale,
-            nlive=nlive,
+            q, k_arena, v_arena, layer, block_table, q_positions,
+            kv_positions, scale, interpret=True, k_scale=k_scale,
+            v_scale=v_scale, nlive=nlive,
         )
     if backend == "kernel":
         if jax.default_backend() != "tpu":
@@ -959,26 +1011,27 @@ def paged_prefill(
     )
     if use_pallas:
         return paged_prefill_tpu(
-            q, k_arena, v_arena, block_table, q_positions, kv_positions,
-            scale, k_scale=k_scale, v_scale=v_scale, nlive=nlive,
+            q, k_arena, v_arena, layer, block_table, q_positions,
+            kv_positions, scale, k_scale=k_scale, v_scale=v_scale, nlive=nlive,
         )
     return paged_attention_xla(
-        q, k_arena, v_arena, block_table, q_positions, kv_positions, scale,
-        k_scale=k_scale, v_scale=v_scale,
+        q, k_arena, v_arena, layer, block_table, q_positions,
+        kv_positions, scale, k_scale=k_scale, v_scale=v_scale,
     )
 
 
 @jax.named_scope("attn")
 def paged_attention(
     q: jnp.ndarray,
-    k_arena: jnp.ndarray,
+    k_arena: jnp.ndarray,  # [L, NB, Nkv, BS, D] — the layer-stacked pool
     v_arena: jnp.ndarray,
+    layer,  # scalar int32 — which layer of the stack to attend
     block_table: jnp.ndarray,
     q_positions: jnp.ndarray,
     kv_positions: jnp.ndarray,
     scale: float | None = None,
     backend: str = "auto",
-    k_scale: jnp.ndarray = None,  # [NB, Nkv] — quantized arenas only
+    k_scale: jnp.ndarray = None,  # [L, NB, Nkv] — quantized arenas only
     v_scale: jnp.ndarray = None,
     stats: bool = False,  # static: return (acc, m, l) partials (cp serve)
 ) -> jnp.ndarray:
@@ -1005,19 +1058,21 @@ def paged_attention(
         )
     if stats:
         return attn_stats_xla(
-            q, k_arena, v_arena, block_table, q_positions, kv_positions,
-            scale, k_scale=k_scale, v_scale=v_scale,
+            q, k_arena, v_arena, layer, block_table, q_positions,
+            kv_positions, scale, k_scale=k_scale, v_scale=v_scale,
         )
     if backend == "auto":
         backend = forced_backend() or "auto"
     eligible = kernel_eligible(
-        q.shape[-1], k_arena.shape[1], k_arena.dtype, rows=block_table.shape[0],
+        q.shape[-1], k_arena.shape[3], k_arena.dtype,
+        rows=block_table.shape[0],
         table_width=block_table.shape[1],
     )
     if backend == "interpret":
         return paged_attention_tpu(
-            q, k_arena, v_arena, block_table, q_positions, kv_positions,
-            scale, interpret=True, k_scale=k_scale, v_scale=v_scale,
+            q, k_arena, v_arena, layer, block_table, q_positions,
+            kv_positions, scale, interpret=True, k_scale=k_scale,
+            v_scale=v_scale,
         )
     if backend == "kernel":
         # curated here too, not only in the serve-side resolution: a
@@ -1040,10 +1095,10 @@ def paged_attention(
     )
     if use_pallas:
         return paged_attention_tpu(
-            q, k_arena, v_arena, block_table, q_positions, kv_positions,
-            scale, k_scale=k_scale, v_scale=v_scale,
+            q, k_arena, v_arena, layer, block_table, q_positions,
+            kv_positions, scale, k_scale=k_scale, v_scale=v_scale,
         )
     return paged_attention_xla(
-        q, k_arena, v_arena, block_table, q_positions, kv_positions, scale,
-        k_scale=k_scale, v_scale=v_scale,
+        q, k_arena, v_arena, layer, block_table, q_positions,
+        kv_positions, scale, k_scale=k_scale, v_scale=v_scale,
     )

@@ -43,6 +43,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..models.cache import KVCache, POS_SENTINEL
 from ..models.config import ModelConfig
 from ..obs.metrics import REGISTRY
+from ..ops.paged_attention import window_from_blocks
 from ..ops.quant import is_kv_quantized, kv_dequantize, kv_qmax, kv_quantize
 from ..ops.sampling import is_stop as _is_stop
 from .head import (
@@ -76,9 +77,9 @@ class ServeState(NamedTuple):
     """
 
     k: jax.Array          # [dev] dense: [S, Lp, M, C, Nkv, Dh];
-    #   paged: the pooled arena [S, Lp, NB, BS, Nkv, Dh] — rows own block
-    #   subsets via ``block_tables`` (block 0 = the reserved trash sink).
-    #   Quantized KV serving stores the arena as int8/fp8 CODES
+    #   paged: the pooled arena, head-major [S, Lp, NB, Nkv, BS, Dh] —
+    #   rows own block subsets via ``block_tables`` (block 0 = the reserved
+    #   trash sink). Quantized KV serving stores the arena as int8/fp8 CODES
     v: jax.Array          # [dev] same layout as k
     k_scale: jax.Array    # [dev] [S, Lp, NB, Nkv] f32 per-block-per-head
     #   scales of a QUANTIZED arena (running absmax / qmax — see
@@ -119,29 +120,32 @@ def _dev(spec: P) -> bool:
     return len(spec) > 0 and spec[0] in (PIPE_AXIS, CP_AXIS)
 
 
-def _kv_spec(tp: int, cp: int = 1) -> P:
-    """Spec of every serve-side KV array ([S, Lp, rows, C, Nkv, Dh] state
-    leaves and the [S, Lp, 1, Spx, Nkv, Dh] prefix handle): tp > 1 megatron-
-    shards the heads dim (the stage fn computes only its tensor shard's
-    heads — the caches store exactly those). cp > 1 (paged only, tp gated
-    to 1 by the server) shards the arena's BLOCK dim instead: each cp shard
-    owns a contiguous sub-arena of ``kv_blocks`` blocks. THE single source
-    of the KV layout; state_specs, make_state and prefix_prefill all read
-    it."""
+def _kv_spec(tp: int, cp: int = 1, paged: bool = False) -> P:
+    """Spec of every serve-side KV array — the dense [S, Lp, rows, C, Nkv,
+    Dh] state leaves and the [S, Lp, 1, Spx, Nkv, Dh] prefix handle
+    (token-major: heads are dim 4), and with ``paged`` the head-major
+    arena [S, Lp, NB, Nkv, BS, Dh] (heads are dim 3). tp > 1 megatron-
+    shards the heads dim, wherever the layout asked for keeps it (the stage
+    fn computes only its tensor shard's heads — the caches store exactly
+    those). cp > 1 (paged only, tp gated to 1 by the server) shards the
+    arena's BLOCK dim instead: each cp shard owns a contiguous sub-arena of
+    ``kv_blocks`` blocks. THE single source of the KV layout; state_specs,
+    make_state, prefix_prefill and gather_prefix_kv all read it."""
     if cp > 1:
         return P(PIPE_AXIS, None, CP_AXIS)
-    return (
-        P(PIPE_AXIS) if tp == 1
-        else P(PIPE_AXIS, None, None, None, TENSOR_AXIS)
-    )
+    if tp == 1:
+        return P(PIPE_AXIS)
+    heads = 3 if paged else 4
+    return P(PIPE_AXIS, *([None] * (heads - 1)), TENSOR_AXIS)
 
 
 def state_specs(
-    state: ServeState, tp: int = 1, cp: int = 1, quantized: bool = False
+    state: ServeState, tp: int = 1, cp: int = 1, quantized: bool = False,
+    paged: bool = False,
 ) -> ServeState:
     dev = P(PIPE_AXIS)
     rep = P()
-    kv = _kv_spec(tp, cp)
+    kv = _kv_spec(tp, cp, paged)
     # scale arenas are pipe-sharded only (full Nkv per shard; quantized KV
     # is gated to tp == 1 by the server — heads-sharded scale plumbing is
     # future work). Under cp > 1 a QUANTIZED arena's scales follow the
@@ -183,12 +187,20 @@ def state_specs(
 # garbage sink) — so last-wins scatter order is immaterial.
 
 
-def _scatter_pages(arena, tbl, window, block_size):
-    """Write a logical window back through the tables (inverse gather)."""
+@jax.named_scope("kv_layout")
+def _window_blocks(window, block_size):
+    """A token-major logical window ``[Lp, Bs, W, Nkv, D]`` cut into the
+    arena's head-major blocks ``[Lp, Bs, T, Nkv, BS, D]`` — a layout change
+    of the WINDOW one admission computed, never of the pool."""
     Lp, Bs, W = window.shape[0], window.shape[1], window.shape[2]
     vals = window.reshape(Lp, Bs, W // block_size, block_size,
                           *window.shape[3:])
-    return arena.at[:, tbl].set(vals)
+    return jnp.transpose(vals, (0, 1, 2, 4, 3, 5))
+
+
+def _scatter_pages(arena, tbl, window, block_size):
+    """Write a logical window back through the tables (inverse gather)."""
+    return arena.at[:, tbl].set(_window_blocks(window, block_size))
 
 
 def _scatter_pages_q(arena, scale, tbl, window, block_size):
@@ -201,14 +213,12 @@ def _scatter_pages_q(arena, scale, tbl, window, block_size):
     broadcast values (hence identical codes AND scales) from every
     admission, and the trash block is a garbage sink whose codes/scales
     are never dequantized (readers zero-gate table entry 0)."""
-    Lp, Bs, W = window.shape[0], window.shape[1], window.shape[2]
-    T = W // block_size
-    vals = window.reshape(Lp, Bs, T, block_size, *window.shape[3:])
+    vals = _window_blocks(window, block_size)  # [Lp, Bs, T, Nkv, BS, D]
     qmax = kv_qmax(arena.dtype)
     sc = (
-        jnp.max(jnp.abs(vals.astype(jnp.float32)), axis=(3, 5)) / qmax
+        jnp.max(jnp.abs(vals.astype(jnp.float32)), axis=(4, 5)) / qmax
     )  # [Lp, Bs, T, Nkv]
-    q = kv_quantize(vals, sc[:, :, :, None, :, None], arena.dtype)
+    q = kv_quantize(vals, sc[..., None, None], arena.dtype)
     return arena.at[:, tbl].set(q), scale.at[:, tbl].set(sc)
 
 
@@ -233,12 +243,13 @@ def make_state(
     """Host-constructed empty state (all slots free / done).
 
     With ``kv_blocks``/``kv_block_size`` set, the KV leaves become the
-    POOLED paged arena ``[S, Lp, kv_blocks, kv_block_size, Nkv, Dh]``
-    (``models/cache.block_pool_shape``) instead of per-row ``[.., M, C,
-    ..]`` reservations, and every row's logical window is ``W = ceil(C /
-    BS) * BS`` columns mapped through ``block_tables`` (all entries start
-    at the trash block 0). HBM then scales with the arena size the operator
-    budgets, not rows × capacity — the whole point of paged serving.
+    POOLED paged arena, head-major ``[S, Lp, kv_blocks, Nkv,
+    kv_block_size, Dh]`` (``models/cache.paged_arena_shape``) instead of
+    per-row ``[.., M, C, ..]`` reservations, and every row's logical
+    window is ``W = ceil(C / BS) * BS`` columns mapped through
+    ``block_tables`` (all entries start at the trash block 0). HBM then
+    scales with the arena size the operator budgets, not rows × capacity —
+    the whole point of paged serving.
 
     With ``cp > 1`` (paged only) ``kv_blocks`` is PER SHARD: the global
     arena holds ``cp * kv_blocks`` blocks sharded contiguously over the cp
@@ -263,7 +274,7 @@ def make_state(
     H = cfg.hidden_size
     dev = NamedSharding(mesh, P(PIPE_AXIS))
     rep = NamedSharding(mesh, P())
-    dev_kv = NamedSharding(mesh, _kv_spec(tp, cp))
+    dev_kv = NamedSharding(mesh, _kv_spec(tp, cp, paged))
 
     single = jax.process_count() == 1
 
@@ -291,10 +302,10 @@ def make_state(
         return put_global(np.zeros(shape, dtype), sh)
 
     if paged:
-        from ..models.cache import block_pool_shape
+        from ..models.cache import paged_arena_shape
 
         kv_shape = (
-            S, *block_pool_shape(cfg, cp * kv_blocks, kv_block_size, Lp)
+            S, *paged_arena_shape(cfg, cp * kv_blocks, kv_block_size, Lp)
         )
     else:
         kv_shape = (S, Lp, M, C, cfg.num_key_value_heads, cfg.head_dim_)
@@ -406,7 +417,7 @@ def prefix_prefill(
 )
 def gather_prefix_kv(
     mesh: Mesh,
-    k_arena: jnp.ndarray,  # ServeState.k, paged arena [S, Lp, NB, BS, Nkv, Dh]
+    k_arena: jnp.ndarray,  # ServeState.k, paged arena [S, Lp, NB, Nkv, BS, Dh]
     v_arena: jnp.ndarray,
     blocks: jnp.ndarray,   # [T] int32 arena block ids covering the prefix
     block_size: int,
@@ -419,20 +430,22 @@ def gather_prefix_kv(
     THE ARENA — the device half of the automatic radix prefix cache
     (``runtime/radix.py``). Where ``prefix_prefill`` pays the prefix's
     forward pass to build ``(k [S, Lp, 1, Spx, Nkv, Dh], v, pos)``, this
-    just gathers the ``T`` cached blocks a radix match named: same output
-    layout, zero prefill FLOPs. Every token slot is real (matches are
-    block-aligned by construction), so ``pos`` is simply ``arange(Spx)``.
+    just gathers the ``T`` cached blocks a radix match named and lays the
+    gathered blocks out token-major: same output layout, zero prefill
+    FLOPs. Every token slot is real (matches are block-aligned by
+    construction), so ``pos`` is simply ``arange(Spx)``.
 
     The admission that consumes this re-scatters the identical values
     through the new row's table (shared blocks receive the bytes they
     already hold — race-free under device program order, same contract as
     the PrefixHandle broadcast), which is what lets one ``serve_admit``
     program serve both the explicit-handle and the radix path."""
-    kv_spec = _kv_spec(tp)
+    arena_spec = _kv_spec(tp, paged=True)
+    kv_spec = _kv_spec(tp)  # the handle: token-major like a dense row
 
     def body(k, v, tbl, ks, vs):
-        k, v = k[0], v[0]  # local [Lp, NB, BS, nkv, Dh]
-        gk = k[:, tbl]     # [Lp, T, BS, nkv, Dh]
+        k, v = k[0], v[0]  # local [Lp, NB, nkv, BS, Dh]
+        gk = k[:, tbl]     # [Lp, T, nkv, BS, Dh]
         gv = v[:, tbl]
         if ks is not None:
             # quantized arena: the handle carries DEQUANTIZED values (the
@@ -440,19 +453,19 @@ def gather_prefix_kv(
             # prefix compute quality is full precision either way
             sk = ks[0][:, tbl]  # [Lp, T, nkv]
             sv = vs[0][:, tbl]
-            gk = kv_dequantize(gk, sk[:, :, None, :, None], out_dtype)
-            gv = kv_dequantize(gv, sv[:, :, None, :, None], out_dtype)
-        Lp, T = gk.shape[0], gk.shape[1]
-        gk = gk.reshape(Lp, 1, T * block_size, *gk.shape[3:])
-        gv = gv.reshape(Lp, 1, T * block_size, *gv.shape[3:])
-        pos = jnp.arange(T * block_size, dtype=jnp.int32)[None]
+            gk = kv_dequantize(gk, sk[..., None, None], out_dtype)
+            gv = kv_dequantize(gv, sv[..., None, None], out_dtype)
+        # one row per layer: [Lp, T*BS, nkv, Dh] -> [Lp, 1, T*BS, nkv, Dh]
+        gk = window_from_blocks(gk)[:, None]
+        gv = window_from_blocks(gv)[:, None]
+        pos = jnp.arange(gk.shape[2], dtype=jnp.int32)[None]
         return gk[None], gv[None], pos[None]
 
     return shard_map(
         body,
         mesh=mesh,
         in_specs=(
-            kv_spec, kv_spec, P(),
+            arena_spec, arena_spec, P(),
             P(PIPE_AXIS), P(PIPE_AXIS),  # leafless no-ops when None
         ),
         out_specs=(kv_spec, kv_spec, P(PIPE_AXIS)),
@@ -467,11 +480,14 @@ def write_arena_blocks(k_arena, v_arena, blocks, k_host, v_host):
     a streamed prefix): a block-axis scatter, donated so the arena
     updates in place — restore never transiently doubles the dominant HBM
     consumer. Bit-exact: the values written are the bytes ``read`` pulled
-    out (same cache dtype end to end). On a context-parallel arena (block
-    axis sharded over cp) ``blocks`` are GLOBAL ids — positions on the
-    logical concatenated axis — so GSPMD lands each block's write on
-    exactly its owner shard; the host tensors are tiny (a prefix's
-    blocks), so the replicated operand cost is noise next to the arena."""
+    out (same cache dtype end to end), whole blocks in ARENA layout
+    (``[S, Lp, n, Nkv, BS, Dh]`` — the host tier, the disk tier and the
+    disagg hand-off carry them opaquely; the block axis is dim 2). On a
+    context-parallel arena (block axis sharded over cp) ``blocks`` are
+    GLOBAL ids — positions on the logical concatenated axis — so GSPMD
+    lands each block's write on exactly its owner shard; the host tensors
+    are tiny (a prefix's blocks), so the replicated operand cost is noise
+    next to the arena."""
     return (
         k_arena.at[:, :, blocks].set(k_host),
         v_arena.at[:, :, blocks].set(v_host),
@@ -642,7 +658,7 @@ def serve_admit(
         sidx = jax.lax.axis_index(PIPE_AXIS)
         st = jax.tree.map(
             lambda spec, leaf: leaf[0] if _dev(spec) else leaf,
-            state_specs(state, tp, cp, quantized), state,
+            state_specs(state, tp, cp, quantized, bool(block_size)), state,
         )
         row0 = slot * Bs
 
@@ -819,12 +835,13 @@ def serve_admit(
         )
         new = jax.tree.map(
             lambda spec, leaf: leaf[None] if _dev(spec) else leaf,
-            state_specs(state, tp, cp, quantized), new,
+            state_specs(state, tp, cp, quantized, bool(block_size)), new,
         )
         return new, tok0
 
     specs = state_specs(
-        ServeState(*([None] * len(ServeState._fields))), tp, cp, quantized
+        ServeState(*([None] * len(ServeState._fields))), tp, cp, quantized,
+        bool(block_size),
     )
     out_state, tok0 = shard_map(
         body,
@@ -834,11 +851,12 @@ def serve_admit(
             head_specs(head_params), specs,
             P(), P(), P(), P(), P(), P(), P(), P(), P(),
             P(),  # no-op when prompt_embeds is None (leafless pytree)
-            # prefix_kv (k, v, pos) is sharded like the serve cache ([S, Lp,
-            # ...], heads on TENSOR under tp; pos pipe-only); both entries
+            # prefix_kv (k, v, pos) is sharded like a DENSE serve cache
+            # ([S, Lp, 1, Spx, Nkv, Dh] token-major whatever the state's
+            # mode: heads on TENSOR under tp; pos pipe-only); both entries
             # are leafless no-ops when prefix caching is off
             P(PIPE_AXIS) if prefix_kv is None
-            else (specs.k, specs.v, P(PIPE_AXIS)),
+            else (_kv_spec(tp), _kv_spec(tp), P(PIPE_AXIS)),
             P(),
             P(),  # key_override: replicated (leafless no-op when None)
         ),
@@ -948,7 +966,7 @@ def serve_prefill_chunk(
         sidx = jax.lax.axis_index(PIPE_AXIS)
         st = jax.tree.map(
             lambda spec, leaf: leaf[0] if _dev(spec) else leaf,
-            state_specs(state, tp, cp, quantized), state,
+            state_specs(state, tp, cp, quantized, bool(block_size)), state,
         )
         row0 = slot * Bs
         col0 = prefix_off + chunk_off  # absolute cache column of the chunk
@@ -1048,11 +1066,12 @@ def serve_prefill_chunk(
         )
         return jax.tree.map(
             lambda spec, leaf: leaf[None] if _dev(spec) else leaf,
-            state_specs(state, tp, cp, quantized), new,
+            state_specs(state, tp, cp, quantized, bool(block_size)), new,
         )
 
     specs = state_specs(
-        ServeState(*([None] * len(ServeState._fields))), tp, cp, quantized
+        ServeState(*([None] * len(ServeState._fields))), tp, cp, quantized,
+        bool(block_size),
     )
     return shard_map(
         body,
@@ -1069,7 +1088,8 @@ def serve_prefill_chunk(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("cfg", "mesh", "num_stages", "tp", "cp"),
+    jax.jit,
+    static_argnames=("cfg", "mesh", "num_stages", "tp", "cp", "block_size"),
     donate_argnums=(3,),  # see serve_admit
 )
 def serve_admit_finish(
@@ -1091,6 +1111,8 @@ def serve_admit_finish(
     key_override: Any = None,  # ([Bs, 2] uint32, [Bs] bool) — see below
     cp: int = 1,  # static: context-parallel degree (spec plumbing only —
     #   this program touches no KV; see serve_prefill_chunk)
+    block_size: int = 0,  # static: paged-KV block size (0 = dense state) —
+    #   spec plumbing too: under tp the paged arena shards another dim
 ):
     """Arm a chunk-prefilled slot: park each row's final prompt token in the
     injection path at position ``prompt_len - 1``. The slot's first
@@ -1116,7 +1138,7 @@ def serve_admit_finish(
         sidx = jax.lax.axis_index(PIPE_AXIS)
         st = jax.tree.map(
             lambda spec, leaf: leaf[0] if _dev(spec) else leaf,
-            state_specs(state, tp, cp, quantized), state,
+            state_specs(state, tp, cp, quantized, bool(block_size)), state,
         )
         row0 = slot * Bs
 
@@ -1169,11 +1191,12 @@ def serve_admit_finish(
         )
         return jax.tree.map(
             lambda spec, leaf: leaf[None] if _dev(spec) else leaf,
-            state_specs(state, tp, cp, quantized), new,
+            state_specs(state, tp, cp, quantized, bool(block_size)), new,
         )
 
     specs = state_specs(
-        ServeState(*([None] * len(ServeState._fields))), tp, cp, quantized
+        ServeState(*([None] * len(ServeState._fields))), tp, cp, quantized,
+        bool(block_size),
     )
     return shard_map(
         body,
@@ -1267,7 +1290,7 @@ def serve_chunk(
         sidx = jax.lax.axis_index(PIPE_AXIS)
         st = jax.tree.map(
             lambda spec, leaf: leaf[0] if _dev(spec) else leaf,
-            state_specs(state, tp, cp, quantized), state,
+            state_specs(state, tp, cp, quantized, bool(block_size)), state,
         )
 
         def micro(_, s: ServeState) -> ServeState:
@@ -1465,12 +1488,13 @@ def serve_chunk(
         st, log = jax.lax.fori_loop(0, n_micro, micro_carry, (st, log0))
         st = jax.tree.map(
             lambda spec, leaf: leaf[None] if _dev(spec) else leaf,
-            state_specs(state, tp, cp, quantized), st,
+            state_specs(state, tp, cp, quantized, bool(block_size)), st,
         )
         return st, log
 
     specs = state_specs(
-        ServeState(*([None] * len(ServeState._fields))), tp, cp, quantized
+        ServeState(*([None] * len(ServeState._fields))), tp, cp, quantized,
+        bool(block_size),
     )
     return shard_map(
         body,
@@ -1581,7 +1605,7 @@ def serve_verify(
         sidx = jax.lax.axis_index(PIPE_AXIS)
         st = jax.tree.map(
             lambda spec, leaf: leaf[0] if _dev(spec) else leaf,
-            state_specs(state, tp), state,
+            state_specs(state, tp, paged=bool(block_size)), state,
         )
         row0 = slot * Bs
         rows = row0 + jnp.arange(Bs, dtype=jnp.int32)
@@ -1809,11 +1833,14 @@ def serve_verify(
         )
         new = jax.tree.map(
             lambda spec, leaf: leaf[None] if _dev(spec) else leaf,
-            state_specs(state, tp), new,
+            state_specs(state, tp, paged=bool(block_size)), new,
         )
         return new, log
 
-    specs = state_specs(ServeState(*([None] * len(ServeState._fields))), tp)
+    specs = state_specs(
+        ServeState(*([None] * len(ServeState._fields))), tp,
+        paged=bool(block_size),
+    )
     return shard_map(
         body,
         mesh=mesh,

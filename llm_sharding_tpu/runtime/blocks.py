@@ -4,8 +4,8 @@ The dense serve state reserves the full cache capacity ``C`` per row up
 front (``parallel/serve.make_state``: ``k/v [S, Lp, M, C, Nkv, Dh]``) — a
 short request holds exactly as much HBM as the longest one the server can
 admit. Paged mode (PagedAttention, Kwon et al., SOSP'23) replaces the
-per-row reservation with a POOLED arena ``[S, Lp, num_blocks, block_size,
-Nkv, Dh]``; each row owns only the blocks covering its actual prompt +
+per-row reservation with a POOLED arena, head-major ``[S, Lp, num_blocks,
+Nkv, block_size, Dh]``; each row owns only the blocks covering its actual prompt +
 budget, mapped through a per-row block table the device programs gather
 through (``parallel/serve.py``). This module is the host half: a free list
 with per-block reference counts.
@@ -37,6 +37,13 @@ from __future__ import annotations
 import numpy as np
 
 TRASH_BLOCK = 0  # reserved garbage sink; table entries default here
+
+#: The one layout paged KV is stored in (``models/cache.paged_arena_shape``
+#: behind the stage dim), everywhere it is stored: the device arena, the
+#: host tier, the disk tier's entries, a paged snapshot. Persisted bytes
+#: name it, so bytes written under another layout are refused (snapshots)
+#: or dropped (disk entries) instead of being read wrongly.
+PAGED_KV_LAYOUT = "S,L,NB,Nkv,BS,D"
 
 
 class BlockExhausted(RuntimeError):

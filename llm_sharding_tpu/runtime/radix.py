@@ -38,9 +38,11 @@ that makes it so:
   plus a meta JSON written LAST via fsync'd tmp+rename — the meta is
   the validity marker, so a crash mid-spill leaves only ignorable
   orphan files. ``adopt_pool`` rebuilds the disk-tier nodes from the
-  entries on a fresh start; snapshots (format 7) reference entries by
-  id instead of inlining their KV. A corrupt or missing entry drops
-  the node and the request re-prefills — never an error upward.
+  entries on a fresh start; snapshots (format 7+) reference entries by
+  id instead of inlining their KV. Each meta names the layout of its
+  bytes (``blocks.PAGED_KV_LAYOUT``). A corrupt or missing entry, or
+  one written under another layout, drops the node and the request
+  re-prefills — never an error upward.
 
 The tree itself is pure host bookkeeping (numpy + stdlib file I/O);
 device I/O goes through the two callbacks the owning server provides
@@ -62,7 +64,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
-from .blocks import BlockAllocator, BlockExhausted
+from .blocks import PAGED_KV_LAYOUT, BlockAllocator, BlockExhausted
 
 __all__ = ["RadixCache", "RadixNode", "RadixRef"]
 
@@ -731,6 +733,10 @@ class RadixCache:
                 os.replace(tmp, f"{base}.kv{j}.npy")
             meta = {
                 "entry": entry,
+                # the layout of the kv components' bytes: an entry that
+                # names another (or none — written before the arena went
+                # head-major) is dropped like a corrupt one, never read
+                "layout": PAGED_KV_LAYOUT,
                 "prefix": [int(t) for t in prefix],
                 "edge": int(node.key.shape[0]),
                 "comps": len(node.host_kv),
@@ -759,11 +765,14 @@ class RadixCache:
         """Load one entry's KV components (``np.load`` memory-mapped,
         then CRC-verified and materialized for the arena write). None on
         any corruption: missing/unparseable meta, missing component,
-        CRC or block-count mismatch."""
+        CRC or block-count mismatch, or bytes laid out for another arena
+        (``layout``)."""
         base = self._entry_base(entry)
         try:
             with open(f"{base}.json") as f:
                 meta = json.load(f)
+            if meta.get("layout") != PAGED_KV_LAYOUT:
+                return None
             parts = []
             for j in range(int(meta["comps"])):
                 mm = np.load(f"{base}.kv{j}.npy", mmap_mode="r")
@@ -833,8 +842,10 @@ class RadixCache:
         artifact (``restore`` handles the snapshot path instead). Entries
         adopt parent-first (shorter prefixes first); an entry whose
         parent chain is not fully on disk any more, whose slot is taken,
-        or which no longer fits the pool cap is unlinked (a re-prefill
-        re-creates it — never an error). Orphan files with no meta (a
+        which no longer fits the pool cap, or whose meta does not name
+        this build's KV layout (``PAGED_KV_LAYOUT`` — a pool that outlived
+        a layout change) is unlinked (a re-prefill re-creates it — never
+        an error, never old-layout bytes in the arena). Orphan files with no meta (a
         crash mid-spill) are swept. Returns entries adopted."""
         if not self.disk_pool_blocks:
             return 0
@@ -847,7 +858,10 @@ class RadixCache:
             try:
                 with open(os.path.join(self.disk_pool_dir, fn)) as f:
                     meta = json.load(f)
-                if meta["entry"] != m.group(1) or int(meta["edge"]) % bs:
+                if (
+                    meta["entry"] != m.group(1) or int(meta["edge"]) % bs
+                    or meta["layout"] != PAGED_KV_LAYOUT
+                ):
                     raise ValueError("inconsistent entry meta")
             except (OSError, ValueError, KeyError):
                 self._unlink_entry(m.group(1))

@@ -90,9 +90,16 @@ from ..parallel.mesh import PIPE_AXIS
 from .async_exec import (
     INFLIGHT_STEPS, SCHEDULER_LAG, _CompletionSidecar, _StepScheduler,
 )
+from .blocks import PAGED_KV_LAYOUT
 from .faults import backoff_delays, is_transient
 
 logger = logging.getLogger("llm_sharding_tpu.server")
+
+#: What ``snapshot()`` writes, and the first format whose PAGED arena is
+#: head-major (``PAGED_KV_LAYOUT``): ``restore`` refuses a paged snapshot
+#: older than that by name, since its shapes can pass for the new layout's.
+SNAPSHOT_FORMAT = 8
+HEAD_MAJOR_FORMAT = 8
 
 # -- health states (the live state machine behind /healthz) -----------------
 SERVING = "SERVING"      # admitting and decoding normally
@@ -1813,7 +1820,13 @@ class PipelineServer:
                 return d
 
             return {
-                # format 7: disk-tier radix nodes ride as REFERENCES to
+                # format 8: the paged arena is HEAD-MAJOR ([S, Lp, NB,
+                # Nkv, BS, Dh] — models/cache.PAGED_KV_LAYOUT). No key
+                # changed; the number alone tells ``restore`` that a
+                # paged snapshot of format <= 7 holds [.., BS, Nkv, Dh]
+                # bytes, which it refuses by name (where kv_block_size ==
+                # num_key_value_heads the shapes could not tell).
+                # Format 7: disk-tier radix nodes ride as REFERENCES to
                 # their on-disk pool entries (meta "entry" key, no inlined
                 # KV arrays — the pool itself is the persistent artifact)
                 # and serve_kwargs gain disk_pool_dir/disk_pool_blocks.
@@ -1830,8 +1843,9 @@ class PipelineServer:
                 # rebuilds it exactly. Format 5 added inflight_steps,
                 # format 4 kv_dtype + the scale-arena/radix host-KV keys,
                 # format 3 the prefix-cache section; formats 1 (dense)
-                # through 5 still restore — see ``restore``
-                "format": 7,
+                # through 7 still restore where they are DENSE — see
+                # ``restore``
+                "format": SNAPSHOT_FORMAT,
                 "radix": (
                     None if self._radix is None else self._radix.snapshot()
                 ),
@@ -1907,8 +1921,19 @@ class PipelineServer:
         of an unsupported model family, raises the curated
         ``NotImplementedError`` instead of an obscure mesh/sharding error
         deep in the first dispatched program."""
-        if snap.get("format") not in (1, 2, 3, 4, 5, 6, 7):
+        if snap.get("format") not in range(1, SNAPSHOT_FORMAT + 1):
             raise ValueError(f"unknown snapshot format {snap.get('format')!r}")
+        if snap.get("paged") and snap["format"] < HEAD_MAJOR_FORMAT:
+            # before any engine work: the arena (and every radix host/disk
+            # component) of such a snapshot is [.., BS, Nkv, Dh] bytes
+            raise ValueError(
+                f"paged snapshot of format {snap['format']} holds the KV "
+                f"arena in the retired [.., block_size, Nkv, Dh] layout; "
+                f"this build stores it head-major ({PAGED_KV_LAYOUT}, "
+                f"snapshot format {HEAD_MAJOR_FORMAT}+) and cannot read "
+                "the old bytes — re-serve and let requests re-admit "
+                "(dense snapshots of any format still restore)"
+            )
         validate = getattr(engine, "_validate_serve", None)
         if validate is not None:
             validate()
@@ -3220,8 +3245,8 @@ class PipelineServer:
 
     def _read_arena_blocks(self, blocks) -> tuple:
         """Device→host copy of arena blocks (radix host-tier demotion).
-        Returns (k, v) numpy ``[S, Lp, nb, BS, Nkv, Dh]`` in the ARENA
-        dtype — the exact bytes ``_write_arena_blocks`` later restores. A
+        Returns (k, v) numpy ``[S, Lp, nb, Nkv, BS, Dh]`` in the ARENA
+        layout and dtype — the exact bytes ``_write_arena_blocks`` later restores. A
         quantized arena returns (k, v, k_scale, v_scale): the codes demote
         verbatim with their per-block scales, so the host tier holds twice
         the cached tokens per host-RAM byte too (the radix tree slices
@@ -4493,7 +4518,8 @@ class PipelineServer:
         carried = rng_mask is not None and bool(rng_mask.any())
         record_shape_key(
             "serve_admit_finish",
-            (self.num_stages, Bs, self.capacity, self.tp, carried)
+            (self.num_stages, Bs, self.capacity, self.tp, carried,
+             self.kv_block_size or 0)
             + ((self.cp,) if self.cp > 1 else ()),
         )
         # arms the slot: embeds each row's last token, runs no layer
@@ -4521,6 +4547,7 @@ class PipelineServer:
                     if carried else None
                 ),
                 cp=self.cp,
+                block_size=self.kv_block_size or 0,
             )
         self._admitting_rows.difference_update(range(row0, row0 + Bs))
 

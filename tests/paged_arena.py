@@ -1,0 +1,37 @@
+"""Shared helpers for the op-level paged-KV tests: build the layer-stacked
+head-major arena the ops take (``[L, NB, Nkv, BS, D]`` — every layer with
+its own contents, so an op that read the wrong layer cannot pass) and read
+a row's logical window back out of it the plain numpy way (the oracle the
+XLA gather is held to)."""
+
+import numpy as np
+import jax.numpy as jnp
+
+#: layers of a test stack, and the layers the parametrised cases attend:
+#: the first, a middle one, the last
+LAYERS = 4
+LAYER_CASES = (0, 1, LAYERS - 1)
+
+
+def make_stack(rng, NB, Nkv, bs, D, dtype=jnp.float32, L=LAYERS):
+    """Two random stacks (K and V), every layer different."""
+    shape = (L, NB, Nkv, bs, D)
+    return (
+        jnp.asarray(rng.normal(size=shape), dtype),
+        jnp.asarray(rng.normal(size=shape), dtype),
+    )
+
+
+def window(arena, layer, tbl):
+    """Numpy oracle: the token-major logical window ``[B, T*BS, Nkv, D]``
+    of each row of ``tbl`` at ``layer`` of a head-major stack."""
+    a = np.asarray(arena)[layer][np.asarray(tbl)]  # [B, T, Nkv, BS, D]
+    B, T, Nkv, bs, D = a.shape
+    return a.transpose(0, 1, 3, 2, 4).reshape(B, T * bs, Nkv, D)
+
+
+def others_untouched(before, after, layer):
+    """Every layer but ``layer`` holds the bytes it held."""
+    before, after = np.asarray(before), np.asarray(after)
+    keep = [l for l in range(before.shape[0]) if l != layer]
+    np.testing.assert_array_equal(after[keep], before[keep])

@@ -169,7 +169,7 @@ def test_mid_flight_snapshot_restore_token_exact(setup):
     for _ in range(4):
         srv.step()  # several dispatches enqueued beyond the applied logs
     snap = srv.snapshot()
-    assert snap["format"] == 7
+    assert snap["format"] == 8
     assert snap["serve_kwargs"]["inflight_steps"] == 2
     ids = [r.id for r in reqs]
     srv.close()

@@ -6,7 +6,7 @@ spill to per-entry ``.npy`` files under a bounded on-disk pool, promote
 disk → host → arena on a later hit BYTE-exactly (including quantized
 codes+scales and cp ``host_owners`` shard tags), and the pool is the
 PERSISTENT artifact: a restarted server ``adopt_pool``s its entries cold
-and a snapshot (format 7) references them instead of inlining the KV.
+and a snapshot (format 7+) references them instead of inlining the KV.
 Failure is contained — a crash mid-spill leaves only ignorable orphan
 files, and a corrupt/missing entry drops the node so the request
 re-prefills token-identically, never erroring upward.
@@ -26,7 +26,7 @@ import jax.numpy as jnp
 
 from llm_sharding_tpu.models import llama
 from llm_sharding_tpu.models.config import tiny_llama
-from llm_sharding_tpu.runtime.blocks import BlockAllocator
+from llm_sharding_tpu.runtime.blocks import PAGED_KV_LAYOUT, BlockAllocator
 from llm_sharding_tpu.runtime.engine import PipelineEngine
 from llm_sharding_tpu.runtime.generate import generate
 from llm_sharding_tpu.runtime.radix import RadixCache
@@ -330,6 +330,56 @@ def test_unit_adopt_pool_chains_and_owner_tags(tmp_path):
     c3.check(), a3.check()
 
 
+def _strip_layout(pool, layout=None):
+    """Rewrite every entry meta of ``pool`` as a pool that outlived a
+    layout change would hold it: no ``layout`` key (written before the
+    arena went head-major), or another layout's name."""
+    n = 0
+    for fn in os.listdir(pool):
+        if fn.endswith(".json"):
+            m = json.load(open(os.path.join(pool, fn)))
+            assert m.pop("layout") == PAGED_KV_LAYOUT
+            if layout is not None:
+                m["layout"] = layout
+            json.dump(m, open(os.path.join(pool, fn), "w"))
+            n += 1
+    return n
+
+
+@pytest.mark.parametrize("layout", [None, "S,L,NB,BS,Nkv,D"])
+def test_unit_entry_of_another_layout_is_dropped_like_a_corrupt_one(
+    tmp_path, layout
+):
+    """The pool outlives the process, so it can outlive a change of the
+    arena's layout: an entry whose meta names no layout (or another one)
+    is never read — ``adopt_pool`` unlinks it exactly as it unlinks a
+    corrupt one, and a live cache that finds such a meta under a node
+    drops the node and truncates the match. The caller re-prefills."""
+    store, a, c, fill = _cache(tmp_path)
+    ids = np.arange(0, 2 * BS, dtype=np.int32)
+    b = a.alloc(2)
+    fill(b)
+    c.insert(ids, b)
+    c.demote_all(to_disk=True)
+    assert json.load(open(tmp_path / "e0.json"))["layout"] == PAGED_KV_LAYOUT
+    assert _strip_layout(tmp_path, layout) == 1
+    # the live cache: the read path refuses the bytes, drops the node
+    assert c.take(ids, 2 * BS) is None
+    assert c.disk_corrupt_dropped == 1 and c.disk_blocks == 0
+    assert c.match_tokens(ids) == 0
+    c.check(), a.check()
+    # a fresh start over such a pool adopts nothing and sweeps it
+    fill(b2 := a.alloc(2))
+    c.insert(ids, b2)
+    c.demote_all(to_disk=True)
+    assert _strip_layout(tmp_path, layout) == 1
+    store2, a2, c2, _ = _cache(tmp_path)
+    assert c2.adopt_pool() == 0
+    assert c2.disk_blocks == 0 and not os.listdir(tmp_path)
+    assert c2.match_tokens(ids) == 0
+    c2.check(), a2.check()
+
+
 # --------------------------------------------------- end-to-end, one server
 
 
@@ -447,8 +497,38 @@ def test_corrupt_entry_reprefills_token_identical(setup, tmp_path):
     srv.close()
 
 
+def test_restart_over_a_pool_without_layout_reprefills(setup, tmp_path):
+    """A server restarted over a disk pool written before the arena went
+    head-major (entry metas name no layout): every entry is dropped at
+    adoption, the warm request re-prefills cold and decodes the oracle's
+    tokens — never an error upward, never old-layout bytes in the arena."""
+    params, eng = setup
+    pool = tmp_path / "pool"
+    srv = disk_serve(eng, pool)
+    p1 = prompt(80, 3 * BS)
+    srv.submit(p1, 5)
+    srv.run_until_idle()
+    with srv._mutex:
+        srv._radix.demote_all(to_disk=True)
+    assert srv._radix.disk_blocks >= 3
+    srv.close()
+    assert _strip_layout(pool) >= 1
+
+    srv2 = disk_serve(eng, pool)
+    assert srv2._radix.disk_blocks == 0 and not os.listdir(pool)
+    assert srv2._radix.match_tokens(p1) == 0
+    p2 = np.concatenate([p1, prompt(81, 3)])
+    r2 = srv2.submit(p2, 5)
+    srv2.run_until_idle()
+    assert r2.error is None
+    assert list(r2.tokens) == oracle(params, p2, 5)
+    assert srv2.prefix_cache_stats()["disk_hit_tokens"] == 0
+    check_clean(srv2)
+    srv2.close()
+
+
 def test_snapshot_format7_references_pool_not_inlines(setup, tmp_path):
-    """Format 7: a spilled node rides the snapshot as an entry REFERENCE
+    """Format 7 and later: a spilled node rides the snapshot as an entry REFERENCE
     — no KV arrays inlined — and the restored server promotes it from
     the same pool files, token-identically."""
     params, eng = setup
@@ -460,7 +540,7 @@ def test_snapshot_format7_references_pool_not_inlines(setup, tmp_path):
     with srv._mutex:
         srv._radix.demote_all(to_disk=True)
     snap = srv.snapshot()
-    assert snap["format"] == 7
+    assert snap["format"] == 8
     disk_nodes = [
         m for m in snap["radix"]["nodes"] if m["tier"] == "disk"
     ]

@@ -59,13 +59,17 @@ def test_paged_decode_matches_full_capacity():
     k = _rand((B, C, Nkv, D), 10)
     v = _rand((B, C, Nkv, D), 11)
     # the dense cache reinterpreted as B*T arena blocks + trash block 0:
-    # row b's logical column c lives in arena block 1 + b*T + c // BS
-    k_arena = jnp.concatenate(
-        [jnp.zeros((1, BS, Nkv, D), k.dtype), k.reshape(B * T, BS, Nkv, D)]
-    )
-    v_arena = jnp.concatenate(
-        [jnp.zeros((1, BS, Nkv, D), v.dtype), v.reshape(B * T, BS, Nkv, D)]
-    )
+    # row b's logical column c lives in arena block 1 + b*T + c // BS —
+    # head-major blocks, as layer 1 of a two-layer stack (layer 0 zeros)
+    def as_arena(x):
+        blocks = jnp.concatenate(
+            [jnp.zeros((1, BS, Nkv, D), x.dtype),
+             x.reshape(B * T, BS, Nkv, D)]
+        )
+        blocks = jnp.transpose(blocks, (0, 2, 1, 3))  # [NB, Nkv, BS, D]
+        return jnp.stack([jnp.zeros_like(blocks), blocks])
+
+    k_arena, v_arena = as_arena(k), as_arena(v)
     for live in (3, 255, 256, 257, 600, 1023):
         q = _rand((B, 1, Nh, D), 12 + live)
         q_pos = jnp.full((B, 1), live, jnp.int32)
@@ -79,7 +83,7 @@ def test_paged_decode_matches_full_capacity():
         for b in range(B):
             tbl[b, :n_live] = 1 + b * T + np.arange(n_live)
         got = paged_attention_xla(
-            q, k_arena, v_arena, jnp.asarray(tbl), q_pos, kv_pos
+            q, k_arena, v_arena, 1, jnp.asarray(tbl), q_pos, kv_pos
         )
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 

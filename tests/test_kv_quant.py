@@ -35,8 +35,14 @@ from llm_sharding_tpu.ops.quant import (
 from llm_sharding_tpu.runtime.blocks import BlockAllocator
 from llm_sharding_tpu.runtime.engine import PipelineEngine
 
+from paged_arena import others_untouched
+
 CFG = tiny_llama(num_hidden_layers=8)
 BS = 8  # serve-side kv block size in the tests
+# the op units run on a head-major stack of L layers and touch layer LYR:
+# the layers around it must keep their bytes, and a read of the wrong layer
+# would find the zeros they still hold
+L, LYR = 3, 1
 
 
 # ------------------------------------------------------------- op units
@@ -76,8 +82,8 @@ def test_kv_dtype_vocabulary():
 
 
 def _empty_arena(NB=6, Nkv=2, D=8):
-    z = jnp.zeros((NB, BS, Nkv, D), jnp.int8)
-    s = jnp.zeros((NB, Nkv), jnp.float32)
+    z = jnp.zeros((L, NB, Nkv, BS, D), jnp.int8)
+    s = jnp.zeros((L, NB, Nkv), jnp.float32)
     return z, z, s, s
 
 
@@ -90,10 +96,13 @@ def test_write_block_kv_quantized_insert_then_gather():
     cols = jnp.asarray([[0, 1], [0, BS + 1]], jnp.int32)
     kn = jnp.asarray(rng.normal(size=(2, 2, 2, 8)), jnp.float32)
     vn = jnp.asarray(rng.normal(size=(2, 2, 2, 8)), jnp.float32)
+    empty = _empty_arena()
     kq, vq, ks, vs = write_block_kv(
-        kq, vq, tbl, cols, kn, vn, k_scale=ks, v_scale=vs
+        kq, vq, LYR, tbl, cols, kn, vn, k_scale=ks, v_scale=vs
     )
-    gk, gv = gather_block_kv(kq, vq, tbl, ks, vs, out_dtype=jnp.float32)
+    for before, after in zip(empty, (kq, vq, ks, vs)):
+        others_untouched(before, after, LYR)
+    gk, gv = gather_block_kv(kq, vq, LYR, tbl, ks, vs, out_dtype=jnp.float32)
     step = float(jnp.max(ks)) + 1e-7
     assert float(jnp.max(jnp.abs(gk[0, 0] - kn[0, 0]))) <= 0.5 * step
     assert float(jnp.max(jnp.abs(gv[1, BS + 1] - vn[1, 1]))) <= 0.5 * step
@@ -111,15 +120,17 @@ def test_write_block_kv_scale_growth_requantizes_block():
     small = jnp.asarray(rng.normal(size=(1, 1, 2, 8)), jnp.float32)
     big = small * 50.0
     kq, vq, ks, vs = write_block_kv(
-        kq, vq, tbl, jnp.asarray([[0]]), small, small, k_scale=ks, v_scale=vs
+        kq, vq, LYR, tbl, jnp.asarray([[0]]), small, small,
+        k_scale=ks, v_scale=vs,
     )
-    s0 = np.asarray(ks[1]).copy()
+    s0 = np.asarray(ks[LYR, 1]).copy()
     kq, vq, ks, vs = write_block_kv(
-        kq, vq, tbl, jnp.asarray([[1]]), big, big, k_scale=ks, v_scale=vs
+        kq, vq, LYR, tbl, jnp.asarray([[1]]), big, big,
+        k_scale=ks, v_scale=vs,
     )
-    assert np.all(np.asarray(ks[1]) >= s0 * 49)
-    gk, _ = gather_block_kv(kq, vq, tbl, ks, vs, out_dtype=jnp.float32)
-    new_step = np.asarray(ks[1])  # per-head step after growth
+    assert np.all(np.asarray(ks[LYR, 1]) >= s0 * 49)
+    gk, _ = gather_block_kv(kq, vq, LYR, tbl, ks, vs, out_dtype=jnp.float32)
+    new_step = np.asarray(ks[LYR, 1])  # per-head step after growth
     err_old = np.abs(np.asarray(gk[0, 0]) - np.asarray(small[0, 0]))
     assert np.all(err_old <= new_step[:, None] * 0.75 + 1e-6)
     err_new = np.abs(np.asarray(gk[0, 1]) - np.asarray(big[0, 0]))
@@ -133,7 +144,7 @@ def test_write_block_kv_quantized_valid_gating():
     tbl = jnp.asarray([[1]], jnp.int32)
     huge = jnp.full((1, 1, 2, 8), 100.0, jnp.float32)
     kq2, vq2, ks2, vs2 = write_block_kv(
-        kq, vq, tbl, jnp.asarray([[0]]), huge, huge,
+        kq, vq, LYR, tbl, jnp.asarray([[0]]), huge, huge,
         valid=jnp.asarray(False), k_scale=ks, v_scale=vs,
     )
     np.testing.assert_array_equal(np.asarray(ks2), np.asarray(ks))
@@ -143,8 +154,8 @@ def test_write_block_kv_quantized_valid_gating():
 def _quantized_attention_setup(seed=4, B=2, T=3, Nkv=2, G=2, D=8):
     rng = np.random.default_rng(seed)
     NB = B * T + 1
-    kq = vq = jnp.zeros((NB, BS, Nkv, D), jnp.int8)
-    ks = vs = jnp.zeros((NB, Nkv), jnp.float32)
+    kq = vq = jnp.zeros((L, NB, Nkv, BS, D), jnp.int8)
+    ks = vs = jnp.zeros((L, NB, Nkv), jnp.float32)
     tbl = jnp.asarray(
         np.concatenate([np.arange(1, B * T + 1).reshape(B, T)]), jnp.int32
     )
@@ -153,7 +164,7 @@ def _quantized_attention_setup(seed=4, B=2, T=3, Nkv=2, G=2, D=8):
         kn = jnp.asarray(rng.normal(size=(B, 1, Nkv, D)), jnp.float32)
         vn = jnp.asarray(rng.normal(size=(B, 1, Nkv, D)), jnp.float32)
         kq, vq, ks, vs = write_block_kv(
-            kq, vq, tbl, jnp.full((B, 1), c, jnp.int32), kn, vn,
+            kq, vq, LYR, tbl, jnp.full((B, 1), c, jnp.int32), kn, vn,
             k_scale=ks, v_scale=vs,
         )
     q = jnp.asarray(rng.normal(size=(B, 1, Nkv * G, D)), jnp.float32)
@@ -167,11 +178,11 @@ def test_quantized_xla_attention_matches_dequantized_arena():
     BIT-exact (both dequantize into the query dtype before the same
     math)."""
     q, kq, vq, tbl, qpos, kvpos, ks, vs = _quantized_attention_setup()
-    got = paged_attention_xla(q, kq, vq, tbl, qpos, kvpos,
+    got = paged_attention_xla(q, kq, vq, LYR, tbl, qpos, kvpos,
                               k_scale=ks, v_scale=vs)
-    kd = kv_dequantize(kq, ks[:, None, :, None], jnp.float32)
-    vd = kv_dequantize(vq, vs[:, None, :, None], jnp.float32)
-    want = paged_attention_xla(q, kd, vd, tbl, qpos, kvpos)
+    kd = kv_dequantize(kq, ks[..., None, None], jnp.float32)
+    vd = kv_dequantize(vq, vs[..., None, None], jnp.float32)
+    want = paged_attention_xla(q, kd, vd, LYR, tbl, qpos, kvpos)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -181,17 +192,20 @@ def test_quantized_kernel_interpret_matches_dequantized_kernel():
     be exactly the gather-path dequant."""
     q, kq, vq, tbl, qpos, kvpos, ks, vs = _quantized_attention_setup()
     got = paged_attention_tpu(
-        q, kq, vq, tbl, qpos, kvpos, interpret=True, k_scale=ks, v_scale=vs
+        q, kq, vq, LYR, tbl, qpos, kvpos, interpret=True,
+        k_scale=ks, v_scale=vs,
     )
-    kd = kv_dequantize(kq, ks[:, None, :, None], jnp.float32)
-    vd = kv_dequantize(vq, vs[:, None, :, None], jnp.float32)
-    want = paged_attention_tpu(q, kd, vd, tbl, qpos, kvpos, interpret=True)
+    kd = kv_dequantize(kq, ks[..., None, None], jnp.float32)
+    vd = kv_dequantize(vq, vs[..., None, None], jnp.float32)
+    want = paged_attention_tpu(
+        q, kd, vd, LYR, tbl, qpos, kvpos, interpret=True
+    )
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-6, atol=2e-6
     )
     # and the fused kernel tracks the fused XLA path (online softmax vs
     # cached attention: same values modulo f32 accumulation order)
-    xla = paged_attention_xla(q, kq, vq, tbl, qpos, kvpos,
+    xla = paged_attention_xla(q, kq, vq, LYR, tbl, qpos, kvpos,
                               k_scale=ks, v_scale=vs)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(xla), rtol=2e-5, atol=2e-5

@@ -30,6 +30,10 @@ from llm_sharding_tpu.runtime.faults import FaultPlan, PermanentFault
 from llm_sharding_tpu.runtime.generate import generate
 from llm_sharding_tpu.runtime.server import PipelineServer
 
+from paged_arena import (
+    LAYER_CASES, LAYERS, make_stack, others_untouched, window,
+)
+
 CFG = tiny_llama(num_hidden_layers=8)
 # CI runs this module twice: default 16, then PAGED_TEST_BLOCK_SIZE=4 to
 # stress block-boundary and multi-entry-table paths (capacity 64 → T=16)
@@ -484,9 +488,12 @@ def test_kv_gauges_track_pool(setup):
 # ------------------------------------------------------------- ragged op
 
 
-def test_paged_attention_xla_matches_dense():
+@pytest.mark.parametrize("layer", LAYER_CASES)
+def test_paged_attention_xla_matches_dense(layer):
     """The gather path over a scattered arena == dense cached_attention
-    over the contiguous equivalent, sentinels and all."""
+    over the contiguous equivalent, sentinels and all — at each layer of a
+    stack whose layers all differ (the window is read back by plain numpy
+    indexing, so a gather that ignored ``layer`` fails here)."""
     from llm_sharding_tpu.models.cache import POS_SENTINEL
     from llm_sharding_tpu.ops.attention import cached_attention
     from llm_sharding_tpu.ops.paged_attention import paged_attention_xla
@@ -495,8 +502,7 @@ def test_paged_attention_xla_matches_dense():
     B, T, bs, Nkv, G, D = 3, 4, 8, 2, 2, 16
     W, Nh = T * bs, Nkv * G
     NB = B * T + 1
-    k_arena = jnp.asarray(rng.normal(size=(NB, bs, Nkv, D)), jnp.float32)
-    v_arena = jnp.asarray(rng.normal(size=(NB, bs, Nkv, D)), jnp.float32)
+    k_arena, v_arena = make_stack(rng, NB, Nkv, bs, D)
     # shuffled non-contiguous tables (block 0 = trash for the tails)
     perm = rng.permutation(np.arange(1, NB))
     tbl = np.zeros((B, T), np.int32)
@@ -511,45 +517,54 @@ def test_paged_attention_xla_matches_dense():
     qpos = jnp.asarray([[lengths[b]] for b in range(B)], jnp.int32)
 
     got = paged_attention_xla(
-        q, k_arena, v_arena, jnp.asarray(tbl), qpos, jnp.asarray(kvpos)
-    )
-    k_dense = np.asarray(k_arena)[tbl].reshape(B, W, Nkv, D)
-    v_dense = np.asarray(v_arena)[tbl].reshape(B, W, Nkv, D)
-    want = cached_attention(
-        q, jnp.asarray(k_dense), jnp.asarray(v_dense), qpos,
+        q, k_arena, v_arena, layer, jnp.asarray(tbl), qpos,
         jnp.asarray(kvpos),
+    )
+    want = cached_attention(
+        q, jnp.asarray(window(k_arena, layer, tbl)),
+        jnp.asarray(window(v_arena, layer, tbl)), qpos, jnp.asarray(kvpos),
     )
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)
 
 
-def test_write_block_kv_scatters_into_owning_blocks():
-    """The decode-path write primitive: entries land in the block the
-    table names at the in-block slot, trash-mapped columns hit the sink,
-    untouched slots are untouched, and the ``valid`` gate (ring-inactive
-    microsteps, masked layers) makes the write a no-op per entry."""
+@pytest.mark.parametrize("layer", LAYER_CASES)
+def test_write_block_kv_scatters_into_owning_blocks(layer):
+    """The decode-path write primitive: entries land at ``(layer, block,
+    :, slot)`` of the stack — the block the table names, the in-block
+    slot — trash-mapped columns hit the sink, untouched slots are
+    untouched, EVERY OTHER LAYER keeps its bytes, and the ``valid`` gate
+    (ring-inactive microsteps, masked layers) makes the write a no-op per
+    entry."""
     from llm_sharding_tpu.ops.paged_attention import write_block_kv
 
     rng = np.random.default_rng(3)
     NB, bs, Nkv, D = 6, 4, 2, 8
     B = 3
-    k = jnp.asarray(rng.normal(size=(NB, bs, Nkv, D)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(NB, bs, Nkv, D)), jnp.float32)
+    k, v = make_stack(rng, NB, Nkv, bs, D)
     tbl = jnp.asarray([[2, 3, 0], [4, 0, 0], [5, 1, 0]], jnp.int32)
     cols = jnp.asarray([[5], [2], [9]], jnp.int32)  # row 2 → trash (entry 0)
     kn = jnp.asarray(rng.normal(size=(B, 1, Nkv, D)), jnp.float32)
     vn = jnp.asarray(rng.normal(size=(B, 1, Nkv, D)), jnp.float32)
-    k2, v2 = write_block_kv(k, v, tbl, cols, kn, vn)
-    np.testing.assert_array_equal(np.asarray(k2)[3, 1], np.asarray(kn)[0, 0])
-    np.testing.assert_array_equal(np.asarray(v2)[4, 2], np.asarray(vn)[1, 0])
-    np.testing.assert_array_equal(np.asarray(k2)[0, 1], np.asarray(kn)[2, 0])
-    np.testing.assert_array_equal(np.asarray(k2)[5], np.asarray(k)[5])
+    k2, v2 = write_block_kv(k, v, layer, tbl, cols, kn, vn)
+    kl, k2l, v2l = (np.asarray(a)[layer] for a in (k, k2, v2))
+    np.testing.assert_array_equal(k2l[3, :, 1], np.asarray(kn)[0, 0])
+    np.testing.assert_array_equal(v2l[4, :, 2], np.asarray(vn)[1, 0])
+    np.testing.assert_array_equal(k2l[0, :, 1], np.asarray(kn)[2, 0])
+    np.testing.assert_array_equal(k2l[5], kl[5])
+    np.testing.assert_array_equal(k2l[3, :, 0], kl[3, :, 0])
+    others_untouched(k, k2, layer)
+    others_untouched(v, v2, layer)
     # per-entry valid gating: only row 1 writes
     mask = jnp.asarray([[False], [True], [False]])
-    k3, _ = write_block_kv(k, v, tbl, cols, kn, vn, valid=mask)
-    np.testing.assert_array_equal(np.asarray(k3)[3, 1], np.asarray(k)[3, 1])
-    np.testing.assert_array_equal(np.asarray(k3)[4, 2], np.asarray(kn)[1, 0])
+    k3, _ = write_block_kv(k, v, layer, tbl, cols, kn, vn, valid=mask)
+    k3l = np.asarray(k3)[layer]
+    np.testing.assert_array_equal(k3l[3, :, 1], kl[3, :, 1])
+    np.testing.assert_array_equal(k3l[4, :, 2], np.asarray(kn)[1, 0])
+    others_untouched(k, k3, layer)
     # scalar False (an inactive ring microstep) is a global no-op
-    k4, v4 = write_block_kv(k, v, tbl, cols, kn, vn, valid=jnp.asarray(False))
+    k4, v4 = write_block_kv(
+        k, v, layer, tbl, cols, kn, vn, valid=jnp.asarray(False)
+    )
     np.testing.assert_array_equal(np.asarray(k4), np.asarray(k))
     np.testing.assert_array_equal(np.asarray(v4), np.asarray(v))
 
@@ -566,8 +581,7 @@ def test_paged_attention_pallas_interpret_matches_xla():
     B, T, bs, Nkv, G, D = 2, 3, 16, 2, 2, 32
     W, Nh = T * bs, Nkv * G
     NB = 8
-    k_arena = jnp.asarray(rng.normal(size=(NB, bs, Nkv, D)), jnp.float32)
-    v_arena = jnp.asarray(rng.normal(size=(NB, bs, Nkv, D)), jnp.float32)
+    k_arena, v_arena = make_stack(rng, NB, Nkv, bs, D)
     tbl = np.array([[3, 5, 0], [7, 0, 0]], np.int32)
     lengths = [bs + 9, 4]
     kvpos = np.full((B, W), POS_SENTINEL, np.int32)
@@ -576,13 +590,10 @@ def test_paged_attention_pallas_interpret_matches_xla():
     q = jnp.asarray(rng.normal(size=(B, 1, Nh, D)), jnp.float32)
     qpos = jnp.asarray([[lengths[b]] for b in range(B)], jnp.int32)
 
-    want = paged_attention_xla(
-        q, k_arena, v_arena, jnp.asarray(tbl), qpos, jnp.asarray(kvpos)
-    )
-    got = paged_attention_tpu(
-        q, k_arena, v_arena, jnp.asarray(tbl), qpos, jnp.asarray(kvpos),
-        interpret=True,
-    )
+    args = (q, k_arena, v_arena, 2, jnp.asarray(tbl), qpos,
+            jnp.asarray(kvpos))
+    want = paged_attention_xla(*args)
+    got = paged_attention_tpu(*args, interpret=True)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), atol=2e-6
     )
@@ -601,8 +612,7 @@ def test_paged_attention_pallas_interpret_multiquery_matches_xla():
     B, S, T, bs, Nkv, G, D = 2, 3, 3, 8, 2, 2, 16
     W, Nh = T * bs, Nkv * G
     NB = 8
-    k_arena = jnp.asarray(rng.normal(size=(NB, bs, Nkv, D)), jnp.float32)
-    v_arena = jnp.asarray(rng.normal(size=(NB, bs, Nkv, D)), jnp.float32)
+    k_arena, v_arena = make_stack(rng, NB, Nkv, bs, D)
     tbl = np.array([[3, 5, 0], [7, 2, 0]], np.int32)
     lengths = [bs + 5, 11]  # committed prefix per row
     kvpos = np.full((B, W), POS_SENTINEL, np.int32)
@@ -614,16 +624,99 @@ def test_paged_attention_pallas_interpret_multiquery_matches_xla():
         [[lengths[b] + i for i in range(S)] for b in range(B)], jnp.int32
     )
 
-    want = paged_attention_xla(
-        q, k_arena, v_arena, jnp.asarray(tbl), qpos, jnp.asarray(kvpos)
-    )
-    got = paged_attention_tpu(
-        q, k_arena, v_arena, jnp.asarray(tbl), qpos, jnp.asarray(kvpos),
-        interpret=True,
-    )
+    args = (q, k_arena, v_arena, 1, jnp.asarray(tbl), qpos,
+            jnp.asarray(kvpos))
+    want = paged_attention_xla(*args)
+    got = paged_attention_tpu(*args, interpret=True)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), atol=2e-6
     )
+
+
+@pytest.mark.parametrize("layer", LAYER_CASES)
+@pytest.mark.parametrize("kernel", ("decode", "prefill"))
+@pytest.mark.parametrize("kv_dtype", ("bf16", "int8"))
+def test_kernels_read_the_layer_they_are_given(kv_dtype, kernel, layer):
+    """Both Pallas kernels (interpret) against the XLA gather on a stack of
+    ``LAYERS`` layers with DIFFERENT contents in each, at the first, a
+    middle and the last layer, over a bf16 and an int8 arena: the layer
+    index rides as a scalar-prefetch operand read by every arena and scale
+    index map, and a kernel that always read layer 0 passes every
+    single-layer case. The XLA side is held to plain numpy indexing by
+    ``test_paged_attention_xla_matches_dense``. Then a WRITE at that layer
+    — the scatter into the stack — must leave every other layer's bytes
+    (codes and scales) untouched, and the kernel must see the entry."""
+    from llm_sharding_tpu.models.cache import POS_SENTINEL
+    from llm_sharding_tpu.ops import paged_attention as pa
+    from llm_sharding_tpu.ops.quant import kv_qmax
+
+    rng = np.random.default_rng([23, layer, kernel == "prefill"])
+    B, T, bs, Nkv, G, D = 2, 4, 8, 2, 2, 16
+    S = 1 if kernel == "decode" else 6
+    W, Nh, NB = T * bs, Nkv * G, 9
+    dt = jnp.bfloat16 if kv_dtype == "bf16" else jnp.float32
+    k_arena, v_arena = make_stack(rng, NB, Nkv, bs, D, dt)
+    scales = {}
+    if kv_dtype == "int8":
+        qmax = kv_qmax(jnp.int8)
+        k_arena, v_arena = (
+            jnp.asarray(np.round(np.clip(
+                np.asarray(a) * (qmax / 3.0), -qmax, qmax)), jnp.int8)
+            for a in (k_arena, v_arena)
+        )
+        sc = rng.uniform(0.5, 1.5, (2, LAYERS, NB, Nkv)) * (3.0 / qmax)
+        scales = {"k_scale": jnp.asarray(sc[0], jnp.float32),
+                  "v_scale": jnp.asarray(sc[1], jnp.float32)}
+    tbl = jnp.asarray([[3, 5, 8, 0], [7, 2, 0, 0]], jnp.int32)
+    lengths = np.array([2 * bs + 3, bs + 1])  # context behind the queries
+    cols = np.arange(W)[None]
+    kvpos = jnp.asarray(np.where(
+        cols < (lengths + S)[:, None], cols, int(POS_SENTINEL)
+    ), jnp.int32)
+    qpos = jnp.asarray(lengths[:, None] + np.arange(S)[None], jnp.int32)
+    q = jnp.asarray(rng.normal(size=(B, S, Nh, D)), dt)
+
+    def both(k_a, v_a, sc):
+        args = (q, k_a, v_a, layer, tbl, qpos, kvpos)
+        if kernel == "decode":
+            got = pa.paged_attention(*args, backend="interpret", **sc)
+        else:
+            got = pa.paged_prefill(
+                *args, backend="interpret", **sc,
+                nlive=jnp.asarray(-(-(lengths + S) // bs), jnp.int32),
+            )
+        return (np.asarray(got, np.float32),
+                np.asarray(pa.paged_attention_xla(*args, **sc), np.float32))
+
+    tol = 2e-2 if kv_dtype == "bf16" else 2e-5
+    got, want = both(k_arena, v_arena, scales)
+    np.testing.assert_allclose(got, want, atol=tol, rtol=tol)
+    # the read depends on the layer: the same call one layer over differs
+    other = (layer + 1) % LAYERS
+    far = np.asarray(pa.paged_attention_xla(
+        q, k_arena, v_arena, other, tbl, qpos, kvpos, **scales
+    ), np.float32)
+    assert np.abs(far - want).max() > 0.05
+
+    # the write at ``layer``: the queries' own entries, large enough to
+    # move the output
+    wcols = jnp.asarray(lengths[:, None] + np.arange(S)[None], jnp.int32)
+    kn = jnp.asarray(3.0 * rng.normal(size=(B, S, Nkv, D)), dt)
+    vn = jnp.asarray(3.0 * rng.normal(size=(B, S, Nkv, D)), dt)
+    out = pa.write_block_kv(
+        k_arena, v_arena, layer, tbl, wcols, kn, vn, **scales
+    )
+    for before, after in zip(
+        (k_arena, v_arena, *scales.values()), out
+    ):
+        others_untouched(before, after, layer)
+        assert not np.array_equal(
+            np.asarray(after)[layer], np.asarray(before)[layer]
+        )
+    sc2 = dict(zip(scales, out[2:]))
+    got2, want2 = both(out[0], out[1], sc2)
+    np.testing.assert_allclose(got2, want2, atol=tol, rtol=tol)
+    assert np.abs(want2 - want).max() > 0.05
 
 
 # ------------------------------------------------- kernel serve-path wiring
@@ -649,7 +742,9 @@ def test_kernel_rules_learned_from_the_v5e_compiler(monkeypatch):
     ``kv_positions`` tile that is neither 128 lanes wide nor the whole
     window (odd table width at block 16) and the int8/fp8 scale operand
     (a ``(1, 1)`` block of ``[NB, Nkv]``) now lower for the TPU platform —
-    the block-shape check runs at lowering, so the CPU can hold the line."""
+    the block-shape check runs at lowering, so the CPU can hold the line.
+    The operands are the layer-stacked head-major pool and the layer index
+    (``test_kernels_compile_for_a_described_v5e`` runs Mosaic itself)."""
     from llm_sharding_tpu.ops.paged_attention import (
         kernel_eligible, paged_attention_tpu, paged_prefill_tpu,
     )
@@ -661,20 +756,21 @@ def test_kernel_rules_learned_from_the_v5e_compiler(monkeypatch):
     assert not kernel_eligible(**ok, rows=4000, table_width=33)
 
     S = jax.ShapeDtypeStruct
-    B, Nh, Nkv, D, NB = 4, 28, 4, 128, 64  # G = 7, the Qwen2.5-7B fold
+    B, Nh, Nkv, D, NB, Lp = 4, 28, 4, 128, 64, 3  # G = 7: Qwen2.5-7B's fold
     for fn, Sq in ((paged_attention_tpu, 1), (paged_prefill_tpu, 128)):
         for store, block, T in ((jnp.bfloat16, 16, 33), (jnp.int8, 32, 32)):
             quant = store == jnp.int8
-            arena = S((NB, block, Nkv, D), store)
-            scale = S((NB, Nkv), jnp.float32) if quant else None
+            arena = S((Lp, NB, Nkv, block, D), store)
+            scale = S((Lp, NB, Nkv), jnp.float32) if quant else None
             jax.jit(
-                lambda q, k, v, t, qp, kp, ks, vs, fn=fn: fn(
-                    q, k, v, t, qp, kp, k_scale=ks, v_scale=vs
+                lambda q, k, v, l, t, qp, kp, ks, vs, fn=fn: fn(
+                    q, k, v, l, t, qp, kp, k_scale=ks, v_scale=vs
                 )
             ).trace(
                 S((B, Sq, Nh, D), jnp.bfloat16), arena, arena,
-                S((B, T), jnp.int32), S((B, Sq), jnp.int32),
-                S((B, T * block), jnp.int32), scale, scale,
+                S((), jnp.int32), S((B, T), jnp.int32),
+                S((B, Sq), jnp.int32), S((B, T * block), jnp.int32),
+                scale, scale,
             ).lower(lowering_platforms=("tpu",))
 
     # --paged-attn kernel fails at construction, by name, never mid-serve
@@ -689,6 +785,81 @@ def test_kernel_rules_learned_from_the_v5e_compiler(monkeypatch):
             capacity=32768, batch_per_slot=128, kv_block_size=16,
             kv_blocks=4097, paged_attn="kernel",
         )
+
+
+#: Both benchmark cells' kernel shapes (``benchmark/configs/*.json``): 4
+#: rows x 128 table entries of 32-token blocks, head 128; 28 q / 4 kv heads
+#: (Qwen2.5-7B, one chip) and 40 / 8 (Qwen2.5-14B, a stage of the ring);
+#: decode (S = 1) and a 256-token prefill chunk. The stack is cut to 3
+#: layers x 260 blocks: the kernels' tiles do not depend on either.
+_CELL_SHAPES = {"qwen25_7b": (28, 4), "qwen25_14b_pp4": (40, 8)}
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One chip of a DESCRIBED v5e host: the TPU's compiler is installed,
+    no chip is attached. Described here, inside a fixture of this one file
+    (never at import: only one process may load the TPU's library)."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no libtpu in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("store", ["bf16", "int8"])
+@pytest.mark.parametrize("kernel", ["paged_decode", "paged_prefill"])
+@pytest.mark.parametrize("cell", sorted(_CELL_SHAPES))
+def test_kernels_compile_for_a_described_v5e(v5e_chip, cell, kernel, store):
+    """The TPU's own compiler (Mosaic included) accepts both kernels with
+    the 5-D stacked operands — a squeezed layer dim, the ``(BS, D)`` tile at
+    ``(layer, table[b, t], head)`` — and the layer index as one more
+    scalar-prefetch operand, at both benchmark cells' shapes, over bf16 and
+    int8 arenas. No chip: the topology is described (``v5e:2x2``), the
+    compile is real, nothing runs."""
+    from llm_sharding_tpu.ops.paged_attention import (
+        paged_attention_tpu, paged_prefill_tpu,
+    )
+
+    Nh, Nkv = _CELL_SHAPES[cell]
+    B, T, BS, D, Lp, NB = 4, 128, 32, 128, 3, 260
+    Sq, fn = {
+        "paged_decode": (1, paged_attention_tpu),
+        "paged_prefill": (256, paged_prefill_tpu),
+    }[kernel]
+    dt = jnp.bfloat16 if store == "bf16" else jnp.int8
+    S = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=v5e_chip
+    )
+    arena = S((Lp, NB, Nkv, BS, D), dt)
+    scale = S((Lp, NB, Nkv), jnp.float32) if store == "int8" else None
+    # conftest asks every matmul for "highest" precision (CPU oracles);
+    # the chip runs the default, and Mosaic refuses an fp32 contraction of
+    # bf16 operands
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(
+            lambda q, k, v, l, t, qp, kp, ks, vs: fn(
+                q, k, v, l, t, qp, kp, k_scale=ks, v_scale=vs
+            )
+        ).lower(
+            S((B, Sq, Nh, D), jnp.bfloat16), arena, arena, S((), jnp.int32),
+            S((B, T), jnp.int32), S((B, Sq), jnp.int32),
+            S((B, T * BS), jnp.int32), scale, scale,
+        ).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and kernel in text
+    # the pool goes to the kernel as it lies: no copy or transpose of an
+    # arena-sized operand beside the custom call
+    import re
+
+    arena_elems = Lp * NB * Nkv * BS * D
+    for m in re.finditer(r"= \w+\[([\d,]+)\][^ ]* (copy|transpose)\(", text):
+        assert np.prod([int(x) for x in m.group(1).split(",")]) < arena_elems
 
 
 def test_forced_backend_env_validation(monkeypatch):
@@ -712,16 +883,16 @@ def test_op_level_forced_kernel_off_tpu_is_curated(monkeypatch):
     construction; the standalone op must too)."""
     from llm_sharding_tpu.ops.paged_attention import paged_attention
 
-    k = jnp.zeros((2, 8, 1, 128), jnp.float32)
+    k = jnp.zeros((1, 2, 1, 8, 128), jnp.float32)  # [L, NB, Nkv, BS, D]
     tbl = jnp.ones((1, 2), jnp.int32)
     q = jnp.zeros((1, 1, 1, 128), jnp.float32)
     qpos = jnp.zeros((1, 1), jnp.int32)
     kvpos = jnp.zeros((1, 16), jnp.int32)
     monkeypatch.setenv("PAGED_FORCE_KERNEL", "kernel")
     with pytest.raises(ValueError, match="TPU backend"):
-        paged_attention(q, k, k, tbl, qpos, kvpos, backend="auto")
+        paged_attention(q, k, k, 0, tbl, qpos, kvpos, backend="auto")
     with pytest.raises(ValueError, match="TPU backend"):
-        paged_attention(q, k, k, tbl, qpos, kvpos, backend="kernel")
+        paged_attention(q, k, k, 0, tbl, qpos, kvpos, backend="kernel")
 
 
 def test_kernel_serve_path_interpret_token_identical(setup, monkeypatch):
@@ -781,17 +952,20 @@ def test_attn_backend_metrics(setup, monkeypatch):
 
 # ------------------------------------- named scopes in the step programs
 
-#: Which words of ``obs.stepline.SCOPES`` each step program must carry when
-#: lowered (paged arena, chunked prefill, the kernel code path emulated).
-#: serve_admit prefills a dense window and scatters it: no kernel operands
-#: to lay out. serve_prefill_chunk samples nothing. serve_admit_finish only
-#: embeds each row's last token.
-_NO_LAYOUT = {"kv_layout"}
+#: Which words of ``obs.stepline.SCOPES`` each step program must NOT carry
+#: when lowered (paged arena, chunked prefill, the kernel code path
+#: emulated). The arena-native programs (serve_chunk, serve_prefill_chunk)
+#: slice no layer out of the pool, write none back and lay nothing out: the
+#: kernels index the carried stack. serve_admit prefills a DENSE window
+#: (the dense scan's kv_take / kv_put) and cuts it into head-major blocks
+#: (kv_layout) before the scatter. serve_prefill_chunk samples nothing.
+#: serve_admit_finish only embeds each row's last token.
+_NO_ARENA_COPY = {"kv_take", "kv_layout", "kv_put"}
 _NO_HEAD = {"head", "sample"}
 PROGRAM_SCOPES = {
-    "serve_chunk": set(),
-    "serve_prefill_chunk": _NO_HEAD,
-    "serve_admit": _NO_LAYOUT,
+    "serve_chunk": _NO_ARENA_COPY,
+    "serve_prefill_chunk": _NO_ARENA_COPY | _NO_HEAD,
+    "serve_admit": set(),
     "serve_admit_finish": None,  # exactly: embed, state
 }
 
@@ -864,13 +1038,17 @@ def test_step_programs_carry_the_scope_vocabulary(lowered_programs, program):
         else set(SCOPES) - missing_ok
     )
     assert found == want, (sorted(want - found), sorted(found - want))
+    if program in ("serve_chunk", "serve_prefill_chunk"):
+        # no arena copy: no layer sliced out of the pool, no operand of a
+        # kernel transposed, nothing written back around the layer — under
+        # any enclosing scope (MLIR locations are relative to the traced
+        # function, XLA joins them into tf_op)
+        for gone in ("kv_take/", "kv_layout/", "kv_put/"):
+            assert not any(gone in p + "/" for p in paths), gone
+        # the write is the scatter into the carried stack, the read the
+        # kernel's own block DMAs
+        assert any(p.endswith("kv_write/scatter") for p in paths)
     if program == "serve_chunk":
-        # the arena copies: the layer's slice, the kernel operands'
-        # transposes, the write-back; and the hop (MLIR locations are
-        # relative to the traced function, XLA joins them into tf_op)
-        assert any(p.endswith("kv_take/dynamic_slice") for p in paths)
-        assert any(p.endswith("kv_layout/transpose") for p in paths)
-        assert any(p.endswith("kv_put/dynamic_update_slice") for p in paths)
         assert any(p.endswith("ring_hop/ppermute") for p in paths)
 
 
@@ -883,3 +1061,182 @@ def test_the_pallas_kernels_are_named(lowered_programs):
     assert any(p.startswith("paged_decode/") for p in decode)
     assert any(p.startswith("paged_prefill/") for p in prefill)
     assert not any(p.startswith("paged_prefill/") for p in decode)
+
+
+# ----------------------- the arena stays where it lies (program structure)
+
+
+def _inner_jaxprs(eqn):
+    """The jaxprs an equation holds in its parameters (scan, while, cond,
+    pjit, shard_map alike)."""
+    from jax.extend import core as jex
+
+    for v in eqn.params.values():
+        for x in v if isinstance(v, (tuple, list)) else (v,):
+            if isinstance(x, jex.ClosedJaxpr):
+                yield x.jaxpr
+            elif isinstance(x, jex.Jaxpr):
+                yield x
+
+
+def _leaf_eqns(jaxpr):
+    """Every equation of ``jaxpr`` that holds no inner jaxpr (a
+    ``pallas_call`` counts as one equation), inner jaxprs walked through."""
+    for eqn in jaxpr.eqns:
+        subs = (
+            [] if eqn.primitive.name == "pallas_call"
+            else list(_inner_jaxprs(eqn))
+        )
+        if subs:
+            for sub in subs:
+                yield from _leaf_eqns(sub)
+        else:
+            yield eqn
+
+
+def _layer_scans(jaxpr, block_shape):
+    """The scans that carry a layer-stacked arena ``[L, *block_shape]``."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan" and any(
+            tuple(v.aval.shape[1:]) == block_shape and v.aval.ndim == 5
+            for v in eqn.invars
+        ):
+            yield eqn
+            continue
+        for sub in _inner_jaxprs(eqn):
+            yield from _layer_scans(sub, block_shape)
+
+
+#: what may touch a value of a layer-arena's size: the three operations
+#: that address (layer, block) INSIDE the carried stack ...
+_IN_PLACE = {"gather", "scatter", "pallas_call"}
+#: ... and, outside the layer scan, the relabelings of the whole state leaf
+#: at a program's edge (the stage dim stripped and restored: no data moves)
+_RELABEL = {"squeeze", "broadcast_in_dim", "reshape"}
+
+
+def _arena_sized_offenders(eqns, stack_shape, allowed):
+    """Equations with an operand or result of a LAYER-arena's size or more
+    that are not ``allowed`` — or that are, but touch something other than
+    the whole stack (a layer of it sliced out or put back is the copy this
+    test exists to keep out)."""
+    layer = int(np.prod(stack_shape[1:]))
+    bad = []
+    for eqn in eqns:
+        big = [
+            v.aval for v in (*eqn.invars, *eqn.outvars)
+            if hasattr(v.aval, "shape") and int(np.prod(v.aval.shape)) >= layer
+        ]
+        if not big:
+            continue
+        name = eqn.primitive.name
+        whole = all(
+            int(np.prod(a.shape)) == int(np.prod(stack_shape)) for a in big
+        )
+        full_slice = name == "slice" and (
+            eqn.invars[0].aval.shape == eqn.outvars[0].aval.shape
+        )
+        if not ((name in allowed or full_slice) and whole):
+            bad.append((name, [tuple(a.shape) for a in big]))
+    return bad
+
+
+@pytest.fixture(scope="module", params=["xla", "interpret"])
+def traced_programs(request, setup):
+    """The jaxprs of the three arena-native step programs as a paged server
+    dispatched them — a chunked admission, decode chunks, and (second
+    server) speculative verify — on one attention backend, bf16-style and
+    int8 arenas. Returns ``{(program, kv_dtype): jaxpr}``, the local arena
+    stack's shape, and what was served against the oracle."""
+    from llm_sharding_tpu.parallel import serve as serve_ops
+
+    params, eng = setup
+    backend = request.param
+    jaxprs = {}
+    served, oracle = [], []
+    with pytest.MonkeyPatch.context() as mp:
+        if backend == "interpret":
+            mp.setenv("PAGED_FORCE_KERNEL", "interpret")
+        kvd = {"now": None}
+        for name in ("serve_chunk", "serve_prefill_chunk", "serve_verify"):
+            orig = getattr(serve_ops, name)
+
+            def call(*a, _orig=orig, _name=name, **kw):
+                if (_name, kvd["now"]) not in jaxprs:
+                    jaxprs[_name, kvd["now"]] = _orig.trace(*a, **kw).jaxpr
+                return _orig(*a, **kw)
+
+            mp.setattr(serve_ops, name, call)
+        for kv_dtype in ("bf16", "int8"):
+            for spec in (0, 2):
+                kvd["now"] = kv_dtype
+                srv = eng.serve(
+                    capacity=64, batch_per_slot=2, kv_block_size=8,
+                    kv_blocks=65, kv_dtype=kv_dtype,
+                    paged_attn="xla" if backend == "xla" else "auto",
+                    # a speculative server has no chunked admission
+                    **(dict(speculate=spec) if spec
+                       else dict(prefill_chunk=16)),
+                )
+                assert srv.attn_impl == backend
+                stack_shape = tuple(srv.state.k.shape[1:])
+                prompts = [prompt(311 + spec, n=5), prompt(312 + spec, n=20)]
+                reqs = [srv.submit(p, 5) for p in prompts]
+                srv.run_until_idle()
+                srv.close()
+                if kv_dtype == "bf16":  # exact arena: token-exact serving
+                    served += [list(r.tokens) for r in reqs]
+                    oracle += [oracle_tokens(params, p, 5) for p in prompts]
+    return jaxprs, stack_shape, served, oracle
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize(
+    "program", ["serve_chunk", "serve_prefill_chunk", "serve_verify"]
+)
+def test_no_arena_sized_copy_in_a_step_program(
+    traced_programs, program, kv_dtype
+):
+    """THE invariant of the head-major, layer-indexed arena: in the body of
+    the paged layer scan no equation has an input or output of a
+    layer-arena's size or more, except the gather, the scatter and the
+    ``pallas_call`` that take the WHOLE carried stack as an operand and
+    address ``(layer, block)`` inside it. Around the scan, in the rest of
+    the program, the only other arena-sized equations are the relabelings
+    of the state leaf at the program's edge. So a decode or prefill step
+    holds no arena-sized transpose, slice or update, on either backend —
+    and what it serves still equals the dense-path oracle."""
+    jaxprs, stack_shape, served, oracle = traced_programs
+    assert served == oracle
+    jaxpr = jaxprs[program, kv_dtype]
+    scans = list(_layer_scans(jaxpr.jaxpr, stack_shape[1:]))
+    assert scans, "no layer scan carries the stacked arena"
+    for scan in scans:
+        body = scan.params["jaxpr"].jaxpr
+        eqns = list(_leaf_eqns(body))
+        assert _arena_sized_offenders(eqns, stack_shape, _IN_PLACE) == []
+        # the three in-place operations are really there
+        names = {e.primitive.name for e in eqns}
+        assert "scatter" in names
+        assert ("pallas_call" in names) or ("gather" in names)
+    assert _arena_sized_offenders(
+        _leaf_eqns(jaxpr.jaxpr), stack_shape, _IN_PLACE | _RELABEL
+    ) == []
+
+
+def test_the_structural_check_sees_a_sliced_out_layer():
+    """The check above is not vacuous: the retired pattern — a layer
+    sliced out of the stack, used, and written back — is reported."""
+    stack = jnp.zeros((3, 9, 2, 8, 16), jnp.float32)
+
+    def retired(stack, l):
+        one = jax.lax.dynamic_index_in_dim(stack, l, keepdims=False)
+        one = jnp.transpose(one, (0, 2, 1, 3))
+        one = jnp.transpose(one + 1.0, (0, 2, 1, 3))
+        return jax.lax.dynamic_update_slice(stack, one[None], (l, 0, 0, 0, 0))
+
+    eqns = list(_leaf_eqns(jax.make_jaxpr(retired)(stack, 1).jaxpr))
+    found = {n for n, _ in _arena_sized_offenders(
+        eqns, stack.shape, _IN_PLACE
+    )}
+    assert {"dynamic_slice", "transpose", "dynamic_update_slice"} <= found

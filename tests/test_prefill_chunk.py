@@ -74,13 +74,16 @@ def drive(srv, reqs):
 # ---------------------------------------------------------------- op level
 
 
-def _op_case(seed=0, S=12, T=8, sentinel_from=20):
+def _op_case(seed=0, S=12, T=8, sentinel_from=20, layer=2):
+    """The ops' positional arguments ``(q, k_stack, v_stack, layer, table,
+    q_positions, kv_positions)`` over a head-major stack of three layers,
+    every one different, attended at ``layer``."""
     rng = np.random.default_rng(seed)
-    Nkv, G, D, NB = 2, 2, 16, 24
+    L, Nkv, G, D, NB = 3, 2, 2, 16, 24
     bs = 4
     W = T * bs
-    ka = jnp.asarray(rng.normal(size=(NB, bs, Nkv, D)).astype(np.float32))
-    va = jnp.asarray(rng.normal(size=(NB, bs, Nkv, D)).astype(np.float32))
+    ka = jnp.asarray(rng.normal(size=(L, NB, Nkv, bs, D)).astype(np.float32))
+    va = jnp.asarray(rng.normal(size=(L, NB, Nkv, bs, D)).astype(np.float32))
     tbl = jnp.asarray(rng.integers(1, NB, (2, T)).astype(np.int32))
     tbl = tbl.at[0, T - 2:].set(0)  # trash tail on row 0
     kvpos = jnp.broadcast_to(jnp.arange(W, dtype=jnp.int32)[None], (2, W))
@@ -91,55 +94,50 @@ def _op_case(seed=0, S=12, T=8, sentinel_from=20):
     qp = jnp.broadcast_to(
         jnp.arange(8, 8 + S, dtype=jnp.int32)[None], (2, S)
     )
-    return q, ka, va, tbl, qp, kvpos
+    return q, ka, va, layer, tbl, qp, kvpos
 
 
 def test_paged_prefill_interpret_matches_xla_all_bps():
-    q, ka, va, tbl, qp, kvpos = _op_case()
-    ref = paged_attention_xla(q, ka, va, tbl, qp, kvpos)
+    args = _op_case()
+    ref = paged_attention_xla(*args)
     for bps in (1, 2, 4):
-        out = paged_prefill_tpu(
-            q, ka, va, tbl, qp, kvpos, interpret=True, blocks_per_step=bps
-        )
+        out = paged_prefill_tpu(*args, interpret=True, blocks_per_step=bps)
         np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
 
 
 def test_paged_prefill_nlive_clamp_is_inert():
     # nlive covering the written frontier (20 cols / bs=4 -> 5 blocks)
     # must not change the result: everything past it is sentinel-masked
-    q, ka, va, tbl, qp, kvpos = _op_case()
-    ref = paged_attention_xla(q, ka, va, tbl, qp, kvpos)
+    args = _op_case()
+    ref = paged_attention_xla(*args)
     out = paged_prefill_tpu(
-        q, ka, va, tbl, qp, kvpos, interpret=True,
+        *args, interpret=True,
         nlive=jnp.asarray([5, 5], jnp.int32), blocks_per_step=2,
     )
     np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
 
 
 def test_paged_prefill_quantized_fused_dequant():
-    q, ka, va, tbl, qp, kvpos = _op_case(seed=3)
-    sk = jnp.max(jnp.abs(ka), axis=(1, 3)) / kv_qmax(jnp.int8)
-    sv = jnp.max(jnp.abs(va), axis=(1, 3)) / kv_qmax(jnp.int8)
-    kq = kv_quantize(ka, sk[:, None, :, None], jnp.int8)
-    vq = kv_quantize(va, sv[:, None, :, None], jnp.int8)
-    ref = paged_attention_xla(
-        q, kq, vq, tbl, qp, kvpos, k_scale=sk, v_scale=sv
-    )
+    q, ka, va, *rest = _op_case(seed=3)
+    sk = jnp.max(jnp.abs(ka), axis=(3, 4)) / kv_qmax(jnp.int8)  # [L,NB,Nkv]
+    sv = jnp.max(jnp.abs(va), axis=(3, 4)) / kv_qmax(jnp.int8)
+    kq = kv_quantize(ka, sk[..., None, None], jnp.int8)
+    vq = kv_quantize(va, sv[..., None, None], jnp.int8)
+    ref = paged_attention_xla(q, kq, vq, *rest, k_scale=sk, v_scale=sv)
     out = paged_prefill_tpu(
-        q, kq, vq, tbl, qp, kvpos, interpret=True,
+        q, kq, vq, *rest, interpret=True,
         k_scale=sk, v_scale=sv, blocks_per_step=2,
     )
     np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
 
 
 def test_decode_blocks_per_step_matches_single_block():
-    q, ka, va, tbl, qp, kvpos = _op_case(S=1, sentinel_from=32)
-    qp = qp[:, :1]
-    ref = paged_attention_xla(q[:, :1], ka, va, tbl, qp, kvpos)
+    q, ka, va, lyr, tbl, qp, kvpos = _op_case(S=1, sentinel_from=32)
+    args = (q[:, :1], ka, va, lyr, tbl, qp[:, :1], kvpos)
+    ref = paged_attention_xla(*args)
     for bps in (1, 2, 4, 8):
         out = paged_attention_tpu(
-            q[:, :1], ka, va, tbl, qp, kvpos, interpret=True,
-            blocks_per_step=bps,
+            *args, interpret=True, blocks_per_step=bps,
         )
         np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
 
@@ -153,12 +151,12 @@ def test_auto_blocks_per_step():
 
 
 def test_paged_prefill_backend_validation():
-    q, ka, va, tbl, qp, kvpos = _op_case()
+    args = _op_case()
     with pytest.raises(ValueError, match="expected one of"):
-        paged_prefill(q, ka, va, tbl, qp, kvpos, backend="bogus")
+        paged_prefill(*args, backend="bogus")
     if jax.default_backend() != "tpu":
         with pytest.raises(ValueError, match="requires a TPU backend"):
-            paged_prefill(q, ka, va, tbl, qp, kvpos, backend="kernel")
+            paged_prefill(*args, backend="kernel")
 
 
 # ------------------------------------------------------------- serve level

@@ -655,7 +655,7 @@ def test_snapshot_restore_preserves_tree_and_rows(setup, tmp_path):
         srv.step()
     assert r2.row is not None and not r2.done
     snap = srv.snapshot()
-    assert snap["format"] == 7 and snap["radix"] is not None
+    assert snap["format"] == 8 and snap["radix"] is not None
     d = str(tmp_path / "snap")
     save_snapshot(snap, d)
     srv2 = PipelineServer.restore(eng, load_snapshot(d))

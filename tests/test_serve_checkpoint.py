@@ -211,7 +211,7 @@ def test_paged_snapshot_restore_mid_decode_token_exact(setup, tmp_path):
     for _ in range(4):
         srv.step()
     snap = srv.snapshot()
-    assert snap["format"] == 7 and snap["paged"] is not None
+    assert snap["format"] == 8 and snap["paged"] is not None
     import tempfile
 
     d = tempfile.mkdtemp(dir=tmp_path)
@@ -228,6 +228,98 @@ def test_paged_snapshot_restore_mid_decode_token_exact(setup, tmp_path):
     assert restored[rb.id].tokens == oracle(params, pb, 10)
     srv2._alloc.check()
     assert srv2._alloc.in_use == 0
+
+
+def _paged_snapshot_mid_decode(eng, **kw):
+    srv = eng.serve(capacity=64, **kw)
+    rng = np.random.default_rng(77)
+    p = rng.integers(1, CFG.vocab_size, 5).astype(np.int32)
+    srv.submit(p, max_new_tokens=10)
+    for _ in range(3):
+        srv.step()
+    return srv, srv.snapshot()
+
+
+@pytest.mark.parametrize("old_format", [2, 4, 7])
+@pytest.mark.parametrize(
+    "kv_block_size", [16, CFG.num_key_value_heads],
+    ids=["block16", "block_equals_kv_heads"],
+)
+def test_paged_snapshot_of_an_older_format_is_refused_by_name(
+    setup, tmp_path, kv_block_size, old_format
+):
+    """A paged snapshot of format <= 7 holds the arena as ``[.., BS, Nkv,
+    Dh]``; this build stores it head-major. ``restore`` refuses it with the
+    curated message — from the dict and after a save/load round trip —
+    BEFORE building anything, and in particular where ``kv_block_size ==
+    num_key_value_heads``: there the two layouts have the SAME shape, the
+    leaf loop's shape check would pass and the bytes would be read
+    wrongly."""
+    _, eng = setup
+    srv, snap = _paged_snapshot_mid_decode(
+        eng, kv_block_size=kv_block_size, kv_blocks=64 * 4 // kv_block_size,
+    )
+    assert snap["format"] == 8 and snap["paged"] is not None
+    if kv_block_size == CFG.num_key_value_heads:
+        k = snap["state"]["k"].shape  # [S, Lp, NB, Nkv, BS, Dh]
+        assert k[3] == k[4]  # shapes alone cannot tell the layouts apart
+    PipelineServer.restore(eng, snap).close()  # this build's own: fine
+    snap["format"] = old_format
+    with pytest.raises(ValueError, match=r"retired \[.., block_size, Nkv, Dh\] layout"):
+        PipelineServer.restore(eng, snap)
+    d = str(tmp_path / "snap")
+    save_snapshot(snap, d)
+    with pytest.raises(ValueError, match=f"paged snapshot of format {old_format}"):
+        PipelineServer.restore(eng, load_snapshot(d))
+    srv.close()
+
+
+def test_replicated_restore_refuses_an_older_paged_snapshot(setup):
+    """The router's ``restore_into`` adopts per-replica snapshots through
+    the same gate: one older paged snapshot among them refuses the lot,
+    and the fresh router is left serving."""
+    from llm_sharding_tpu.runtime.replicated import ReplicatedServer
+
+    params, _ = setup
+    kw = dict(data_parallel=2, num_stages=2, cache_dtype=jnp.float32,
+              capacity=64, kv_block_size=16, kv_blocks=17)
+    rsrv = ReplicatedServer(CFG, params, devices=jax.devices()[:4], **kw)
+    rng = np.random.default_rng(78)
+    p = rng.integers(1, CFG.vocab_size, 4).astype(np.int32)
+    rsrv.submit(p, 8)
+    for _ in range(2):
+        rsrv.step()
+    snaps = rsrv.snapshot()
+    snaps[1]["format"] = 7
+    fresh = ReplicatedServer(CFG, params, devices=jax.devices()[:4], **kw)
+    with pytest.raises(ValueError, match="paged snapshot of format 7"):
+        ReplicatedServer.restore_into(fresh, snaps)
+    r = fresh.submit(p, 8)
+    fresh.run_until_idle()
+    assert r.tokens == oracle(params, p, 8)
+
+
+@pytest.mark.parametrize("old_format", [2, 5, 7])
+def test_dense_snapshot_of_an_older_format_still_restores(setup, old_format):
+    """The gate is about the PAGED arena's bytes: a dense snapshot of any
+    earlier format restores as before and finishes token-exactly."""
+    params, eng = setup
+    srv = eng.serve(capacity=64)
+    rng = np.random.default_rng(79)
+    p = rng.integers(1, CFG.vocab_size, 4).astype(np.int32)
+    r = srv.submit(p, max_new_tokens=10)
+    for _ in range(3):
+        srv.step()
+    snap = srv.snapshot()
+    assert snap["paged"] is None
+    snap["format"] = old_format
+    srv2 = PipelineServer.restore(eng, snap)
+    got = next(
+        x for x in srv2._rows + list(srv2._queue)
+        if x is not None and x.id == r.id
+    )
+    srv2.run_until_idle()
+    assert got.done and got.tokens == oracle(params, p, 10)
 
 
 def test_dense_snapshot_refuses_paged_server(setup):
