@@ -39,12 +39,13 @@ GIB = 1 << 30
 def abstract_inputs(cfg_file: dict, mesh):
     """Shapes with shardings on ``mesh`` for everything a serve program
     takes: stage layers, masks, head, state."""
-    from benchmark import harness, weights
+    from benchmark import blocks, harness
     from llm_sharding_tpu.ops.quant import QTensor
     from llm_sharding_tpu.parallel import serve as serve_ops
     from llm_sharding_tpu.parallel.mesh import PIPE_AXIS
 
     model = harness.model_keys(cfg_file)
+    block = blocks.load(cfg_file["model_type"])
     cfg = harness.model_config(cfg_file)
     S = mesh.shape[PIPE_AXIS]
     Lp = cfg.num_hidden_layers // S
@@ -55,23 +56,25 @@ def abstract_inputs(cfg_file: dict, mesh):
     sds = lambda shape, dtype, sh: jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
 
     layers = {}
-    for name, shape in weights.leaf_shapes(model).items():
-        full = (S, Lp, *shape)
-        if int8 and name in weights.MATMUL_LEAVES:
-            layers[name] = QTensor(
+    for leaf in block.layer_leaves(model):
+        full = (S, Lp, *leaf.shape)
+        if int8 and leaf.matmul:
+            layers[leaf.name] = QTensor(
                 q=sds(full, jnp.int8, pipe),
-                scale=sds((S, Lp, shape[-1]), act, pipe),
+                scale=sds((S, Lp, leaf.shape[-1]), act, pipe),
             )
         else:
-            layers[name] = sds(full, act, pipe)
+            layers[leaf.name] = sds(full, act, pipe)
     masks = sds((S, Lp), jnp.bool_, pipe)
-    V, H = cfg.vocab_size, cfg.hidden_size
-    Vs = -(-V // S)
-    head = {
-        "embed": sds((S, Vs, H), act, pipe),
-        "lm_head": sds((S, H, Vs), act, pipe),
-        "final_norm": sds((H,), act, rep),
-    }
+    # a table with a vocabulary dimension is held as one slice a stage
+    head = {}
+    for t in block.tables(model):
+        if t.vocab_axis is None:
+            head[t.name] = sds(t.shape, act, rep)
+        else:
+            shape = list(t.shape)
+            shape[t.vocab_axis] = -(-shape[t.vocab_axis] // S)
+            head[t.name] = sds((S, *shape), act, pipe)
     serve = cfg_file["serve"]
     cpu_mesh = jax.sharding.Mesh(
         np.asarray(jax.devices("cpu")[:S]), (PIPE_AXIS,)

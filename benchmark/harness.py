@@ -200,10 +200,12 @@ def model_keys(cfg_file: dict) -> dict:
     return {k: v for k, v in cfg_file.items() if k not in OWN_KEYS}
 
 
-def build_server(cfg_file: dict, devices, seed: int, attn: str, marks: dict):
-    """Weights from the seed → engine → server. Returns ``(server, engine,
-    host_params)``; ``host_params`` is the staged host copy on a ring and
-    None on one chip (where the weights are made again for the check)."""
+def build_server(cfg_file: dict, block, devices, seed: int, attn: str,
+                 marks: dict):
+    """Weights from the seed → engine → server. ``block`` is the
+    configuration's block (``blocks.load(model_type)``). Returns ``(server,
+    engine, host_params)``; ``host_params`` is the staged host copy on a ring
+    and None on one chip (where the weights are made again for the check)."""
     from llm_sharding_tpu.runtime.engine import PipelineEngine
 
     model = model_keys(cfg_file)
@@ -215,7 +217,9 @@ def build_server(cfg_file: dict, devices, seed: int, attn: str, marks: dict):
         )
     cfg = model_config(cfg_file)
     t = time.perf_counter()
-    params = weights.make_params(model, seed, dep["weight_dtype"], devices)
+    params = weights.make_params(
+        block, model, seed, dep["weight_dtype"], devices
+    )
     jax.block_until_ready(params)
     marks["weights_s"] = time.perf_counter() - t
     t = time.perf_counter()
@@ -290,16 +294,17 @@ class Session:
     window (the sweep measures several, a cell's run exactly one), then
     ``finish`` frees the server and checks the served tokens."""
 
-    def __init__(self, *, cfg_file: dict, traffic: dict, devices, seed: int,
-                 out_dir: str, attn: str = "kernel", trace: bool = False):
-        self.cfg_file, self.traffic = cfg_file, traffic
+    def __init__(self, *, cfg_file: dict, block, traffic: dict, devices,
+                 seed: int, out_dir: str, attn: str = "kernel",
+                 trace: bool = False):
+        self.cfg_file, self.block, self.traffic = cfg_file, block, traffic
         self.devices, self.seed = list(devices), int(seed)
         self.out_dir, self.attn, self.trace = out_dir, attn, trace
-        self.vocab = int(cfg_file["vocab_size"])
+        self.vocab = block.dims(model_keys(cfg_file))["vocab"]
         self.marks: dict = {}
         self.compiles = CompileCounter()
         self.server, self.engine, self.host_params = build_server(
-            cfg_file, self.devices, self.seed, attn, self.marks
+            cfg_file, block, self.devices, self.seed, attn, self.marks
         )
         self.driver = Driver(self.server, annotate=trace)
         self.pump = threading.Thread(
@@ -444,7 +449,8 @@ class Session:
         self.server = self.engine = driver.server = None
         gc.collect()
         scored = check(
-            self.cfg_file, self.seed, self.devices, self.host_params, samples
+            self.cfg_file, self.block, self.seed, self.devices,
+            self.host_params, samples,
         )
         kernels_ok = self.attn != "kernel" or (
             paths["attn_backend"] == "kernel"
@@ -468,17 +474,18 @@ def _sleep_until(t: float, driver: Driver) -> None:
         time.sleep(min(wait, 0.05))
 
 
-def run_cell(*, cell: dict, cfg_file: dict, traffic: dict, cell_params: dict,
-             devices, seed: int, seconds: float, trace: bool, out_dir: str,
-             t_process: float, readers: dict, attn: str = "kernel",
-             peaks: Optional[dict] = None) -> dict:
-    """Everything between the device check and the result line. ``readers``
-    maps each metric this run reports (end-to-end untraced, per-layer
-    traced) to ``(read, unit)``. Returns ``{"result": the contract's last
-    line, "records": what the run's file keeps}``."""
+def run_cell(*, cell: dict, cfg_file: dict, block, traffic: dict,
+             cell_params: dict, devices, seed: int, seconds: float,
+             trace: bool, out_dir: str, t_process: float, readers: dict,
+             attn: str = "kernel", peaks: Optional[dict] = None) -> dict:
+    """Everything between the device check and the result line. ``block`` is
+    the configuration's block module; ``readers`` maps each metric this run
+    reports (end-to-end untraced, per-layer traced) to ``(read, unit)``.
+    Returns ``{"result": the contract's last line, "records": what the run's
+    file keeps}``."""
     session = Session(
-        cfg_file=cfg_file, traffic=traffic, devices=devices, seed=seed,
-        out_dir=out_dir, attn=attn, trace=trace,
+        cfg_file=cfg_file, block=block, traffic=traffic, devices=devices,
+        seed=seed, out_dir=out_dir, attn=attn, trace=trace,
     )
     rec = session.measure(cell_params, seconds)
     rec.update(session.finish())
@@ -498,7 +505,7 @@ def run_cell(*, cell: dict, cfg_file: dict, traffic: dict, cell_params: dict,
     t0, t1 = rec["window"]
     attempted = sum(1 for r in rec["requests"] if t0 <= r["due"] <= t1)
     correct = bool(
-        reference.verdict(rec["reference"])
+        reference.verdict(rec["reference"], block)
         and rec["compiles_in_window"] == 0
         and rec["kernels_ok"]
         and rec["arena_ok"]
@@ -547,10 +554,12 @@ def pick_samples(tracked: list, seed: int) -> list:
     ]
 
 
-def check(cfg_file: dict, seed: int, devices, host_params, samples) -> dict:
-    """Score the sample under the float32 reference on chip 0, with the very
-    arrays the engine was given: the staged host copy on a ring; on one chip
-    (where the engine consumed them) the same jitted call made again."""
+def check(cfg_file: dict, block, seed: int, devices, host_params,
+          samples) -> dict:
+    """Score the sample under the block's float32 reference on chip 0, with
+    the very arrays the engine was given: the staged host copy on a ring; on
+    one chip (where the engine consumed them) the same jitted call made
+    again."""
     if not samples:
         return {"positions": 0, "samples": 0, "margin_mean": float("inf"),
                 "margin_max": float("inf"), "margin_p99": float("inf"),
@@ -561,14 +570,15 @@ def check(cfg_file: dict, seed: int, devices, host_params, samples) -> dict:
     dev = devices[0]
     if host_params is None:
         params = weights.make_params(
-            model, seed, cfg_file["deployment"]["weight_dtype"], devices
+            block, model, seed, cfg_file["deployment"]["weight_dtype"],
+            devices,
         )
     else:
         params = host_params
     put = lambda tree: jax.tree.map(lambda a: jax.device_put(a, dev), tree)
-    tables = put({k: params[k] for k in ("embed", "final_norm", "lm_head")})
+    tables = put({t.name: params[t.name] for t in block.tables(model)})
 
     def get_layer(l: int):
         return put(jax.tree.map(lambda a: a[l], params["layers"]))
 
-    return reference.score(model, get_layer, tables, samples)
+    return reference.score(block, model, get_layer, tables, samples)

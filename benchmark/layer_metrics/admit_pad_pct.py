@@ -1,8 +1,7 @@
 """Admission: share of the token positions the window's prefill dispatches
 computed that no prompt needed, %, by the program's own count: 1 − Σ
 prompt_tokens ÷ Σ prefill_positions over the window's step records (fed
-where serve_admit / serve_prefill_chunk are dispatched). The outside mirror
-of this is prompt_pad_pct; the two agree while admission goes by slot."""
+where serve_admit / serve_prefill_chunk are dispatched)."""
 from benchmark import samples
 
 

@@ -1,8 +1,8 @@
 """Kernels and step: roofline share of the decode microstep, memory bound:
 the bytes one chip must read for it (its layers, its share of the head, the
-live KV of the rows in the step; roofline.decode_step_bytes) ÷ peak bytes/s
-÷ decode_step_ms, %."""
-from benchmark import roofline, samples
+live KV of the rows in the step; the block's decode_step_bytes, which is
+handed the records too) ÷ peak bytes/s ÷ decode_step_ms, %."""
+from benchmark import blocks, samples
 from benchmark.harness import model_keys
 
 
@@ -34,9 +34,9 @@ def read(rec):
     live = live_tokens_per_slot(rec, ta, tb)
     if live is None:
         return None
-    need = roofline.decode_step_bytes(
+    need = blocks.load(rec["config"]["model_type"]).decode_step_bytes(
         model_keys(rec["config"]), rec["config"]["deployment"]["weight_dtype"],
-        rec["chips"], live,
+        rec["chips"], live, rec,
     )
     least_ms = 1e3 * need / rec["peaks"]["hbm_bytes_per_s"]
     return 100.0 * least_ms / step_ms

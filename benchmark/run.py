@@ -103,6 +103,12 @@ def main() -> None:
     cell = cells[args.workload]
     cfg_entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     cfg_file = load(ROOT, cfg_entry["file"])
+    from benchmark import blocks  # imports nothing of jax until load()
+
+    try:
+        blocks.find(cfg_file.get("model_type"))
+    except FileNotFoundError as e:
+        die(f"configuration {cfg_entry['name']!r}: {e}")
     traffic = load(HERE, "traffic", cell["traffic"] + ".json")
     cell_params = load(HERE, "cells", cell["name"] + ".json")
 
@@ -124,6 +130,7 @@ def main() -> None:
 
     from benchmark import harness
 
+    block = blocks.load(cfg_file["model_type"])
     out_dir = os.path.join(HERE, "out")
     os.makedirs(out_dir, exist_ok=True)
     if args.sweep:
@@ -131,13 +138,14 @@ def main() -> None:
 
         sweep.run(
             rates=[float(r) for r in args.sweep.split(",")], cfg_file=cfg_file,
-            traffic=traffic, devices=devices, seed=args.seed,
+            block=block, traffic=traffic, devices=devices, seed=args.seed,
             seconds=args.seconds, out_dir=out_dir, cell=cell["name"],
         )
         return
     got = harness.run_cell(
-        cell=cell, cfg_file=cfg_file, traffic=traffic, cell_params=cell_params,
-        devices=devices, seed=args.seed, seconds=args.seconds,
+        cell=cell, cfg_file=cfg_file, block=block, traffic=traffic,
+        cell_params=cell_params, devices=devices, seed=args.seed,
+        seconds=args.seconds,
         trace=bool(args.trace), out_dir=out_dir, t_process=T_PROCESS,
         readers=load_readers(
             bench, "per_layer" if args.trace else "end_to_end", cell["name"]),

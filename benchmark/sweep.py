@@ -47,11 +47,11 @@ def row(rate: float, rec: dict) -> dict:
     }
 
 
-def run(*, rates, cfg_file, traffic, devices, seed, seconds, out_dir, cell,
-        attn: str = "kernel") -> list:
+def run(*, rates, cfg_file, block, traffic, devices, seed, seconds, out_dir,
+        cell, attn: str = "kernel") -> list:
     session = harness.Session(
-        cfg_file=cfg_file, traffic=traffic, devices=devices, seed=seed,
-        out_dir=out_dir, attn=attn,
+        cfg_file=cfg_file, block=block, traffic=traffic, devices=devices,
+        seed=seed, out_dir=out_dir, attn=attn,
     )
     print("set-up by phase (s):", json.dumps(session.marks), flush=True)
     rows = []

@@ -28,7 +28,7 @@ spec.loader.exec_module(run)
 
 
 def main(cell_name: str, kv_dtype: str) -> None:
-    from benchmark import harness
+    from benchmark import blocks, harness
 
     bench = run.load(run.ROOT, "BENCHMARK.json")
     cell = next(w for w in bench["workloads"] if w["name"] == cell_name)
@@ -37,7 +37,7 @@ def main(cell_name: str, kv_dtype: str) -> None:
     cfg_file["serve"]["kv_dtype"] = kv_dtype
     devices, peaks = run.find_devices(int(cell["chips"]))
     got = harness.run_cell(
-        cell=cell, cfg_file=cfg_file,
+        cell=cell, cfg_file=cfg_file, block=blocks.load(cfg_file["model_type"]),
         traffic=run.load(BENCH, "traffic", cell["traffic"] + ".json"),
         cell_params=run.load(BENCH, "cells", cell_name + ".json"),
         devices=devices, seed=77, seconds=30.0, trace=False,

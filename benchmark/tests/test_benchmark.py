@@ -6,6 +6,7 @@ mode; not part of the program's ``tests/``:
 A CPU run here says correct or incorrect and counts; never a speed.
 """
 
+import hashlib
 import json
 import os
 import subprocess
@@ -29,7 +30,8 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from benchmark import (  # noqa: E402
-    harness, loadgen, reference, roofline, samples, trace_reduce, weights,
+    blocks, harness, loadgen, reference, roofline, samples, trace_reduce,
+    weights,
 )
 
 jax.config.update("jax_platforms", "cpu")
@@ -41,6 +43,8 @@ def load(*parts):
 
 
 TINY = load(HERE, "data", "tiny_qwen2.json")
+TINY_GPT2 = load(HERE, "data", "tiny_gpt2.json")
+BENCHMARK = load(ROOT, "BENCHMARK.json")
 CHAT = load(BENCH, "traffic", "chat.json")
 BACKLOG = load(BENCH, "traffic", "backlog.json")
 
@@ -80,6 +84,56 @@ def test_the_chat_cells_send_what_their_why_says():
     p = loadgen.open_schedule(CHAT, 0.1, 50.0, 50.0, 1000, 1)
     assert sorted(len(x.prompt) for x in p) == [53, 114, 192, 324, 692]
     assert sorted(x.max_new for x in p) == [46, 84, 128, 195, 357]
+    cells = {w["name"]: w for w in BENCHMARK["workloads"]}
+    for name in ("qwen25_7b.chat", "qwen25_14b_pp4.chat"):
+        assert load(BENCH, "cells", name + ".json")["rate_rps"] == 0.1
+        assert "half" in cells[name]["why"] and "0.8" not in cells[name]["why"]
+
+
+def test_the_backlog_cell_sends_what_its_why_says():
+    """Eight clients on the configuration's four rows, lengths inside the
+    bounds the cell's ``why`` names, the same for every seed; judged on
+    ``out_tok_s``, its per-layer entries read by the readers that are there."""
+    cell = next(w for w in BENCHMARK["workloads"]
+                if w["name"] == "qwen25_7b.backlog")
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "qwen25_7b", "backlog", 1)
+    cfg = load(BENCH, "configs", "qwen25_7b.json")
+    rows = cfg["serve"]["batch_per_slot"] * cell["chips"]
+    per_row = load(BENCH, "cells", "qwen25_7b.backlog.json")["clients_per_row"]
+    assert (rows, per_row * rows) == (4, 8)
+    assert "8 clients on 4 rows" in cell["why"] and "out_tok_s" in cell["why"]
+    max_prompt = harness.max_prompt_len(cfg, BACKLOG)
+    a = loadgen.ClosedClients(BACKLOG, 8, 1000, 1, max_prompt)
+    b = loadgen.ClosedClients(BACKLOG, 8, 1000, 2**31 + 5, max_prompt)
+    xs = [a.next(i % 8) for i in range(BACKLOG["cycle_requests"])]
+    ys = [b.next(i % 8) for i in range(BACKLOG["cycle_requests"])]
+    assert [len(x.prompt) for x in xs] == [len(y.prompt) for y in ys]
+    assert [x.max_new for x in xs] == [y.max_new for y in ys]
+    assert [len(x.prompt) for x in xs[:8]] == [203, 69, 569, 637, 197, 265, 2048, 132]
+    assert min(len(x.prompt) for x in xs) >= 16
+    assert max(len(x.prompt) for x in xs) == 2048
+    assert min(x.max_new for x in xs) >= 8 and max(x.max_new for x in xs) == 512
+    # the same bounds as the chat mix: warm-up covers the same programs
+    assert loadgen.reachable_buckets(BACKLOG, (8, 16, 32, 64, 128, 256, 512,
+                                               1024, 2048, 4096), max_prompt) \
+        == loadgen.reachable_buckets(CHAT, (8, 16, 32, 64, 128, 256, 512,
+                                            1024, 2048, 4096), max_prompt)
+    e2e = {m["name"]: m for m in BENCHMARK["end_to_end"]}
+    assert e2e["out_tok_s"]["workloads"] == ["qwen25_7b.chat", "qwen25_7b.backlog"]
+    assert "workloads" not in e2e["itl_p95_ms"] and "workloads" not in e2e["setup_s"]
+    mine = [m for m in BENCHMARK["per_layer"]
+            if m.get("workloads") == ["qwen25_7b.backlog"]]
+    assert sorted(m["name"] for m in mine) == sorted(
+        s + ".backlog" for s in ("rows_per_step", "admit_pad_pct",
+                                 "queue_wait_p95_ms", "prefill_ms_per_ktok",
+                                 "kv_in_use_peak_pct"))
+    for m in mine:  # read by the reader of the name before the dot
+        assert m["moves"] == "out_tok_s"
+        assert os.path.exists(os.path.join(
+            BENCH, "layer_metrics", m["name"].split(".")[0] + ".py"))
+        assert not os.path.exists(os.path.join(
+            BENCH, "layer_metrics", m["name"] + ".py"))
 
 
 def test_lengths_follow_the_mix():
@@ -279,11 +333,13 @@ def test_reduction_of_a_recorded_trace():
 # ---------------------------------------------------------------- reference
 
 MODEL = harness.model_keys(TINY)
+BLOCK = blocks.load(TINY["model_type"])
+GPT2_BLOCK = blocks.load(TINY_GPT2["model_type"], os.path.join(HERE, "blocks"))
 
 
 def tiny_weights(seed=3, dtype="int8"):
-    params = weights.make_params(MODEL, seed, dtype, jax.devices()[:1])
-    tables = {k: params[k] for k in ("embed", "final_norm", "lm_head")}
+    params = weights.make_params(BLOCK, MODEL, seed, dtype, jax.devices()[:1])
+    tables = {t.name: params[t.name] for t in BLOCK.tables(MODEL)}
     get = lambda l: jax.tree.map(lambda a: a[l], params["layers"])
     return params, tables, get
 
@@ -292,14 +348,41 @@ def greedy(tables, get, prompt, n, **wrong):
     """n greedy tokens from the reference, optionally made wrong."""
     ids = list(prompt)
     out = []
+    kw = tuple(sorted(BLOCK.head_static(MODEL).items()))
     for _ in range(n):
-        (h,) = reference.hidden_states(MODEL, get, tables["embed"], [ids], **wrong)
+        (h,) = reference.hidden_states(BLOCK, MODEL, get, tables, [ids], **wrong)
         _, best = reference.margins_from_hidden(
-            h[len(ids) - 1 : len(ids)], tables["final_norm"], tables["lm_head"],
-            jnp.zeros((1,), jnp.int32), eps=float(MODEL["rms_norm_eps"]))
+            h[len(ids) - 1 : len(ids)], tables, jnp.zeros((1,), jnp.int32),
+            logits=BLOCK.logits, kw=kw)
         out.append(int(best[0]))
         ids.append(out[-1])
     return np.asarray(out, np.int32)
+
+
+def digests(params) -> dict:
+    """dtype, shape and bytes of every array of a parameter tree."""
+    out = {}
+    for path, a in jax.tree_util.tree_flatten_with_path(params)[0]:
+        a = np.asarray(a)
+        h = hashlib.sha256()
+        h.update(str(a.dtype).encode())
+        h.update(str(a.shape).encode())
+        h.update(np.ascontiguousarray(a).tobytes())
+        out[jax.tree_util.keystr(path)] = h.hexdigest()[:16]
+    return out
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+@pytest.mark.parametrize("dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("seed", [7, 2**31 + 3])
+def test_the_weights_of_a_seed_are_bit_for_bit_those_before_the_seam(
+        seed, dtype, chips):
+    """Recorded from the parent tree before ``blocks/qwen2.py`` existed: the
+    same leaf order, keys and arithmetic, so the served ids, the margins and
+    every metric of the cells are what they were."""
+    recorded = load(HERE, "data", "tiny_qwen2.digests.json")["digests"]
+    params = weights.make_params(BLOCK, MODEL, seed, dtype, jax.devices()[:chips])
+    assert digests(params) == recorded[f"{seed}.{dtype}"]
 
 
 def test_weights_repeat_for_a_seed_and_differ_for_another():
@@ -313,8 +396,8 @@ def test_weights_repeat_for_a_seed_and_differ_for_another():
 
 
 def test_weights_split_over_a_ring_are_the_same_weights():
-    one = weights.make_params(MODEL, 7, "bf16", jax.devices()[:1])
-    four = weights.make_params(MODEL, 7, "bf16", jax.devices()[:4])
+    one = weights.make_params(BLOCK, MODEL, 7, "bf16", jax.devices()[:1])
+    four = weights.make_params(BLOCK, MODEL, 7, "bf16", jax.devices()[:4])
     for a, b in zip(jax.tree.leaves(one), jax.tree.leaves(four)):
         assert np.array_equal(np.asarray(a), np.asarray(b))
 
@@ -324,11 +407,11 @@ def test_reference_agrees_with_itself_and_refuses_a_wrong_model():
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, 512, size=n, dtype=np.int32) for n in (12, 20, 31)]
     right = [(p, greedy(tables, get, p, 6)) for p in prompts]
-    a = reference.score(MODEL, get, tables, right)
-    b = reference.score(MODEL, get, tables, right[::-1])
+    a = reference.score(BLOCK, MODEL, get, tables, right)
+    b = reference.score(BLOCK, MODEL, get, tables, right[::-1])
     assert a["margin_max"] == 0.0 and a["argmax_share"] == 1.0
     assert a["margin_mean"] == pytest.approx(b["margin_mean"], abs=1e-6)
-    assert reference.verdict(a)
+    assert reference.verdict(a, BLOCK)
 
     def no_bias(l):
         p = dict(get(l))
@@ -343,27 +426,41 @@ def test_reference_agrees_with_itself_and_refuses_a_wrong_model():
                         for p in prompts],
     }
     for what, served in wrong.items():
-        s = reference.score(MODEL, get, tables, served)
-        assert not reference.verdict(s), (what, s)
+        s = reference.score(BLOCK, MODEL, get, tables, served)
+        assert not reference.verdict(s, BLOCK), (what, s)
     # an int8 cache errs by less than bf16 arithmetic does: at four layers
     # it flips no token at all, and token margins cannot see it (PERF.md)
     fine = [(p, greedy(tables, get, p, 6, kv_round="int8")) for p in prompts]
-    assert reference.score(MODEL, get, tables, fine)["margin_mean"] < reference.DELTA_MEAN
+    assert reference.score(BLOCK, MODEL, get, tables, fine)["margin_mean"] < BLOCK.DELTA_MEAN
 
 
 # ----------------------------------------------------------------- roofline
 
 def test_bytes_of_a_decode_step():
-    m7 = harness.model_keys(load(BENCH, "configs", "qwen25_7b.json"))
-    layer = roofline.layer_weight_bytes(m7, "int8")
+    """The numbers of before the seam, asked of the configuration's block."""
+    c7 = load(BENCH, "configs", "qwen25_7b.json")
+    m7, b7 = harness.model_keys(c7), blocks.load(c7["model_type"])
+    layer = b7.layer_weight_bytes(m7, "int8")
     assert 28 * layer == pytest.approx(6.53e9, rel=0.01)
-    assert roofline.head_bytes(m7) == 3584 * 152064 * 2
-    assert roofline.kv_bytes_per_token_layer(m7) * 28 == 56 * 1024
-    full = roofline.decode_step_bytes(m7, "int8", 1, 1000.0)
-    assert full == pytest.approx(28 * layer + roofline.head_bytes(m7) + 1000 * 57344)
-    m14 = harness.model_keys(load(BENCH, "configs", "qwen25_14b_pp4.json"))
-    assert 48 * roofline.layer_weight_bytes(m14, "bf16") == pytest.approx(
+    assert roofline.head_bytes(b7.dims(m7)) == 3584 * 152064 * 2
+    assert roofline.kv_bytes_per_token_layer(b7.dims(m7)) * 28 == 56 * 1024
+    full = b7.decode_step_bytes(m7, "int8", 1, 1000.0, rec=None)
+    assert full == pytest.approx(
+        28 * layer + roofline.head_bytes(b7.dims(m7)) + 1000 * 57344)
+    assert full == 7618723840 + 1000 * 57344  # the parent's count, to the byte
+    c14 = load(BENCH, "configs", "qwen25_14b_pp4.json")
+    m14, b14 = harness.model_keys(c14), blocks.load(c14["model_type"])
+    assert 48 * b14.layer_weight_bytes(m14, "bf16") == pytest.approx(
         26.4e9, rel=0.01)
+    assert b14.decode_step_bytes(m14, "bf16", 4, 500.0) == 7020306432.0
+    # the second block counts its own leaves, a tied head and no rotary
+    g = harness.model_keys(TINY_GPT2)
+    H, I = 128, 512
+    assert GPT2_BLOCK.layer_weight_bytes(g, "bf16") == 2 * (
+        4 * H * H + 2 * H * I + 9 * H + I)
+    assert GPT2_BLOCK.decode_step_bytes(g, "bf16", 1, 10.0) == (
+        4 * GPT2_BLOCK.layer_weight_bytes(g, "bf16") + 2 * H * 512
+        + 4 * 10.0 * 2 * H * 2)
 
 
 # ------------------------------------------------------------------ the run
@@ -380,7 +477,8 @@ def test_run_py_finds_no_tpu_on_the_cpu():
     assert "{" not in r.stdout  # no result line
 
 
-def _readers():
+def run_py():
+    """``run.py`` as a module (it is a script, not a package member)."""
     sys.argv = ["run.py"]
     import importlib.util
 
@@ -388,16 +486,19 @@ def _readers():
         "bench_run", os.path.join(BENCH, "run.py"))
     run = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(run)
-    bench = load(ROOT, "BENCHMARK.json")
-    cell = bench["workloads"][0]["name"]
-    return (run.load_readers(bench, "end_to_end", cell),
-            run.load_readers(bench, "per_layer", cell), bench)
+    return run
 
 
-def run_tiny(loop, stages, tmp_path, readers):
+def _readers(cell="qwen25_7b.chat"):
+    run = run_py()
+    return (run.load_readers(BENCHMARK, "end_to_end", cell),
+            run.load_readers(BENCHMARK, "per_layer", cell), BENCHMARK)
+
+
+def run_tiny(loop, stages, tmp_path, readers, cfg=TINY, block=BLOCK):
     """What run.py calls after its device check, given a tiny configuration
     and the CPU's devices by this test."""
-    cfg = json.loads(json.dumps(TINY))
+    cfg = json.loads(json.dumps(cfg))
     if stages > 1:
         cfg["deployment"].update(num_stages=stages, weight_dtype="bf16")
     traffic = json.loads(json.dumps(CHAT if loop == "chat" else BACKLOG))
@@ -405,7 +506,8 @@ def run_tiny(loop, stages, tmp_path, readers):
     traffic["output_len"].update(median=8, max=24, min=2)
     traffic.update(ramp_s=1.0, tail_s=4.0)
     return harness.run_cell(
-        cell={"name": "tiny." + loop}, cfg_file=cfg, traffic=traffic,
+        cell={"name": "tiny." + loop}, cfg_file=cfg, block=block,
+        traffic=traffic,
         cell_params={"rate_rps": 4.0, "clients_per_row": 2},
         devices=jax.devices()[:stages], seed=2**31 + 9, seconds=4.0,
         trace=False, out_dir=str(tmp_path), t_process=time.perf_counter(),
@@ -415,8 +517,9 @@ def run_tiny(loop, stages, tmp_path, readers):
 
 @pytest.mark.parametrize("loop,stages", [("chat", 1), ("backlog", 1), ("chat", 4)])
 def test_a_cell_runs_end_to_end_on_the_cpu(loop, stages, tmp_path):
-    """Both loop kinds, one chip and a ring. Counts and correctness only."""
-    e2e, layer, bench = _readers()
+    """Both loop kinds, one chip and a ring. Counts and correctness only.
+    Each loop kind reads the metrics of the 7B cell of its mix."""
+    e2e, layer, bench = _readers("qwen25_7b." + loop)
     got = run_tiny(loop, stages, tmp_path, e2e)
     res, rec = got["result"], got["records"]
     assert set(res) == {"correct", "attempted", "failed", "metrics", "device"}
@@ -430,14 +533,29 @@ def test_a_cell_runs_end_to_end_on_the_cpu(loop, stages, tmp_path):
     assert rec["paths"]["arena_dtype"] == ["bfloat16"] and rec["arena_ok"]
     assert rec["compiles_in_window"] == 0
     # host-side per-layer readers work on an untraced run's records too
-    for name in ("rows_per_step.chat", "queue_wait_p95_ms.chat",
-                 "prompt_pad_pct.chat", "kv_in_use_peak_pct.chat",
+    host_side = {
+        "chat": ("rows_per_step.chat", "queue_wait_p95_ms.chat",
+                 "admit_pad_pct.chat", "kv_in_use_peak_pct.chat",
                  "host_ms_per_step.chat", "loadgen_late_p95_ms.chat",
-                 "ttft_median_ms.chat", "ttft_max_ms.chat"):
+                 "ttft_median_ms.chat", "ttft_max_ms.chat"),
+        "backlog": ("rows_per_step.backlog", "queue_wait_p95_ms.backlog",
+                    "admit_pad_pct.backlog", "kv_in_use_peak_pct.backlog",
+                    "host_ms_per_step.chat"),
+    }[loop]
+    for name in host_side:
         assert layer[name][0](rec) is not None, name
-    assert layer["ttft_max_ms.chat"][0](rec) >= layer["ttft_median_ms.chat"][0](rec)
+    if loop == "chat":
+        assert (layer["ttft_max_ms.chat"][0](rec)
+                >= layer["ttft_median_ms.chat"][0](rec))
+    else:
+        # the closed loop kept more requests than rows in the server, and a
+        # chat-only metric is not among the cell's
+        assert len(rec["requests"]) > 4
+        assert "ttft_median_ms.chat" not in layer
+        assert "prefill_ms_per_ktok.backlog" in layer
     # and the trace readers return nothing where there is no trace
     assert layer["device_idle_pct.chat"][0](rec) is None
+    assert layer["decode_hbm_pct.chat"][0](rec) is None
     json.dumps(rec, default=float)
 
 
@@ -458,5 +576,117 @@ def test_a_quantised_arena_under_a_bf16_label_is_not_correct(
     rec = got["records"]
     assert rec["paths"]["arena_dtype"] == ["int8"]
     assert rec["paths"]["arena_dtype_wanted"] == "bfloat16"
-    assert reference.verdict(rec["reference"]), rec["reference"]
+    assert reference.verdict(rec["reference"], BLOCK), rec["reference"]
     assert not got["result"]["correct"]
+
+
+# ----------------------------------------------------------------- the seam
+
+def config_files():
+    folder = os.path.join(BENCH, "configs")
+    return sorted(os.path.join(folder, f) for f in os.listdir(folder))
+
+
+# (configuration file, folder its block is found in): every cell's, and the
+# two toys of these tests
+ALL_CONFIGS = [(p, blocks.HERE) for p in config_files()] + [
+    (os.path.join(HERE, "data", "tiny_qwen2.json"), blocks.HERE),
+    (os.path.join(HERE, "data", "tiny_gpt2.json"), os.path.join(HERE, "blocks")),
+]
+
+
+@pytest.mark.parametrize("path,folder", ALL_CONFIGS,
+                         ids=[os.path.basename(p) for p, _ in ALL_CONFIGS])
+def test_a_configuration_resolves_to_a_block_with_the_programs_leaves(
+        path, folder):
+    """Every file under ``configs/`` finds its block by ``model_type``, and the
+    block's leaves are, name for name and shape for shape, those of the
+    program's own ``init_layer_params`` for that ``ModelConfig`` (so a program
+    PR that renames a leaf fails here, on the CPU, not on the chip)."""
+    from llm_sharding_tpu.models import gpt2, llama
+
+    cfg_file = load(path)
+    block = blocks.load(cfg_file["model_type"], folder)
+    model = harness.model_keys(cfg_file)
+    cfg = harness.model_config(cfg_file)
+    program = {"llama": llama, "gpt2": gpt2}[cfg.model_type]
+    theirs = jax.eval_shape(
+        lambda: program.init_layer_params(cfg, jax.random.key(0), 1))
+    ours = {leaf.name: leaf.shape for leaf in block.layer_leaves(model)}
+    assert {k: tuple(v.shape[1:]) for k, v in theirs.items()} == ours
+    whole = jax.eval_shape(lambda: program.init_params(cfg, jax.random.key(0)))
+    whole.pop("layers")
+    assert {k: tuple(v.shape) for k, v in whole.items()} == {
+        t.name: t.shape for t in block.tables(model)}
+    d = block.dims(model)
+    assert (d["layers"], d["hidden"], d["vocab"], d["kv_heads"], d["head_dim"]) == (
+        cfg.num_hidden_layers, cfg.hidden_size, cfg.vocab_size,
+        cfg.num_key_value_heads, cfg.head_dim_)
+    # a matmul leaf is [in, out]; a table splits along its vocabulary only
+    assert all(len(l.shape) == 2 for l in block.layer_leaves(model) if l.matmul)
+    assert all(t.shape[t.vocab_axis] == d["vocab"]
+               for t in block.tables(model) if t.vocab_axis is not None)
+    assert block.DELTA_MEAN > 0 and block.DELTA_MAX > block.DELTA_MEAN
+
+
+def test_a_configuration_without_a_block_dies_naming_the_path(
+        monkeypatch, capsys):
+    with pytest.raises(FileNotFoundError, match="benchmark/blocks/olmoe.py"):
+        blocks.load("olmoe")
+    assert blocks.load("qwen2") is blocks.load("qwen2")  # one module a file
+    run = run_py()
+    real = run.load
+
+    def another_block(*parts):
+        got = real(*parts)
+        if parts[-1].startswith(os.path.join("benchmark", "configs")):
+            got["model_type"] = "olmoe"
+        return got
+
+    monkeypatch.setattr(run, "load", another_block)
+    monkeypatch.setattr(sys, "argv", [
+        "run.py", "--workload", "qwen25_7b.chat", "--seed", "1",
+        "--seconds", "2"])
+    with pytest.raises(SystemExit) as e:
+        run.main()
+    assert e.value.code != 0
+    err = capsys.readouterr().err
+    assert "benchmark/blocks/olmoe.py is missing" in err and "qwen25_7b" in err
+
+
+GPT2_WRONG = {"sound": None, "no position table": "pos_embed",
+              "no qkv bias": "b_qkv"}
+
+
+@pytest.mark.parametrize("what", list(GPT2_WRONG))
+def test_a_second_block_runs_through_the_harness(what, tmp_path, monkeypatch):
+    """``tests/blocks/gpt2.py`` differs from the Qwen2 block in every part of
+    the seam (LayerNorm with bias, position table, fused biased qkv, GELU,
+    tied head): served paged by the program through ``harness.run_cell`` it is
+    correct — and not, when the program is handed weights whose position
+    table or qkv bias is zero while the reference keeps the seed's."""
+    dropped = GPT2_WRONG[what]
+    make, calls = weights.make_params, []
+
+    def served_without(*args, **kw):
+        params = make(*args, **kw)
+        calls.append(1)
+        if dropped is None or len(calls) > 1:  # the second call is the check's
+            return params
+        if dropped in params:
+            return dict(params, **{dropped: jnp.zeros_like(params[dropped])})
+        layers = dict(params["layers"])
+        layers[dropped] = jnp.zeros_like(layers[dropped])
+        return dict(params, layers=layers)
+
+    monkeypatch.setattr(weights, "make_params", served_without)
+    e2e, _, _ = _readers()
+    got = run_tiny("chat", 1, tmp_path, e2e, cfg=TINY_GPT2, block=GPT2_BLOCK)
+    res, rec = got["result"], got["records"]
+    assert len(calls) == 2 and rec["reference"]["positions"] > 20
+    assert res["failed"] == 0 and rec["paths"]["attn_backend"] == "interpret"
+    assert set(res["metrics"]) == {m["name"] for m in BENCHMARK["end_to_end"]}
+    assert res["correct"] == (dropped is None), rec["reference"]
+    if dropped is not None:  # the margins say so, nothing else
+        assert rec["kernels_ok"] and rec["arena_ok"]
+        assert rec["reference"]["margin_mean"] > 3 * GPT2_BLOCK.DELTA_MEAN

@@ -306,16 +306,20 @@ def load_reader(name):
     return mod.read
 
 
-NEW = ("decode_kv_copy_pct", "decode_attn_pct", "decode_matmul_pct",
-       "decode_unscoped_pct", "prefill_attn_pct", "idle_with_work_pct",
-       "admit_pad_pct")
+NEW = ("decode_attn_pct", "decode_matmul_pct", "decode_unscoped_pct",
+       "prefill_attn_pct", "idle_with_work_pct", "admit_pad_pct")
 
 
 def test_the_new_metrics_are_entries_with_readers_and_return_nothing_untraced():
     bench = load(ROOT, "BENCHMARK.json")
     by_name = {m["name"]: m for m in bench["per_layer"]}
     e2e = {m["name"] for m in bench["end_to_end"]}
-    layers = {m["layer"] for m in bench["per_layer"][:13]}
+    first = [m["name"] for m in bench["per_layer"]].index(NEW[0] + ".chat")
+    # the layers PERF.md section 3 lists, letter for letter
+    layers = {"load generator", "scheduler", "host loop", "KV manager",
+              "admission", "step programs", "kernels and step", "ring",
+              "device"}
+    assert {m["layer"] for m in bench["per_layer"]} == layers
     for name in NEW:
         m = by_name[name + ".chat"]
         assert m["moves"] in e2e and m["unit"] == "%"
@@ -324,7 +328,10 @@ def test_the_new_metrics_are_entries_with_readers_and_return_nothing_untraced():
         # an untraced run, a run of a program without the vocabulary: nothing
         rec = {"traced": None, "steps": [], "window": [0.0, 1.0]}
         assert read(rec) is None
-    assert [m["name"] for m in bench["per_layer"][13:]] == [n + ".chat" for n in NEW]
+    assert [m["name"] for m in bench["per_layer"][first:first + len(NEW)]] == [
+        n + ".chat" for n in NEW]
+    # retired by PR 26: 0.0 in every line since PR 25; the mirror of a counter
+    assert not {"decode_kv_copy_pct.chat", "prompt_pad_pct.chat"} & set(by_name)
     # the ring reports out_tok_s in no cell: the two that move it are 7B's
     for name in ("prefill_attn_pct", "admit_pad_pct"):
         assert by_name[name + ".chat"]["workloads"] == ["qwen25_7b.chat"]
@@ -336,7 +343,7 @@ def test_a_program_without_the_vocabulary_reads_as_nothing(monkeypatch):
     monkeypatch.setattr(sr, "program_scopes", lambda: None)
     rec = {"traced": (0.0, 1.0), "trace": {"xplane_bytes": 1}}
     assert sr.spans(rec) is None and rec["spans"] is None
-    assert load_reader("decode_kv_copy_pct")(rec) is None
+    assert load_reader("decode_attn_pct")(rec) is None
     assert load_reader("idle_with_work_pct")(rec) is None
 
 
@@ -355,8 +362,8 @@ def test_spans_reduces_the_runs_own_trace_once_and_keeps_it(tmp_path, monkeypatc
     assert_same(out["scopes"], expect["scopes"])
     assert sr.spans(rec) is out
     json.dumps(rec)  # the records file keeps it
-    assert load_reader("decode_kv_copy_pct")(rec) == pytest.approx(
-        sr.scope_share(rec, ("serve_chunk",), ("kv_take", "kv_layout", "kv_put")))
+    assert load_reader("decode_attn_pct")(rec) == pytest.approx(
+        sr.scope_share(rec, ("serve_chunk",), ("attn",)))
     # a trace that is not the one trace_reduce measured is not this run's
     other = {"traced": (0.0, 1.0), "trace": {"xplane_bytes": 1}}
     assert sr.spans(other) is None
@@ -364,13 +371,21 @@ def test_spans_reduces_the_runs_own_trace_once_and_keeps_it(tmp_path, monkeypatc
 
 def test_the_programs_count_of_padding_agrees_with_the_outside_rule(tmp_path):
     """``admit_pad_pct`` (the program's counter, fed where prefills are
-    dispatched) against ``prompt_pad_pct`` (PR 23's mirror of the admit
-    buckets) on a tiny cell on the CPU: equal while admission goes by slot."""
+    dispatched) against the rule of admission by slot worked out from outside
+    — every admission prefills ``batch_per_slot`` rows at the bucket of its
+    longest prompt — on a tiny cell on the CPU: equal while admission goes by
+    slot. (The rule was the metric ``prompt_pad_pct`` until PR 26 retired it:
+    it goes wrong the day admission is by row; the counter does not.)"""
     import test_benchmark as tb
+    from benchmark import samples
 
     e2e, layer, _ = tb._readers()
     rec = tb.run_tiny("chat", 1, tmp_path, e2e)["records"]
     inside = layer["admit_pad_pct.chat"][0](rec)
-    outside = layer["prompt_pad_pct.chat"][0](rec)
+    rows = rec["config"]["serve"]["batch_per_slot"]
+    groups = samples.admissions(rec)
+    real = sum(r["prompt_len"] for g in groups for r in g)
+    padded = sum(rows * samples.bucket(max(r["prompt_len"] for r in g))
+                 for g in groups)
     assert inside is not None and 0 < inside < 100
-    assert inside == pytest.approx(outside, abs=1e-9)
+    assert inside == pytest.approx(100.0 * (1.0 - real / padded), abs=1e-9)

@@ -1,17 +1,14 @@
 """Seeded random weights, made on the device in one jitted call.
 
 The weights are DATA: the engine serves them and ``reference.py`` scores the
-served tokens against the same arrays. Every leaf of layer ``l`` is drawn
-from ``fold_in(fold_in(root, l), leaf_index)``: the same on one chip and split
-over a ring.
+served tokens against the same arrays. WHICH leaves a layer has, their
+shapes, the rule each is drawn by and the model's tables are the block's
+(``blocks/<model_type>.py``: ``layer_leaves``, ``tables``); this file is the
+generator every block shares. Leaf ``i`` of layer ``l`` is drawn from
+``fold_in(fold_in(root, l), i)`` and table ``i`` from ``fold_in(fold_in(root,
+1 << 20), i)``: the same on one chip and split over a ring.
 
-- matmul weights: normal, scaled by fan-in ** -0.5;
-- q/k/v biases: normal × 0.1 — NOT zero, or a dropped bias would go unseen;
-- norm gains: 1 + normal × 0.1;
-- embedding: normal; output head: normal × hidden ** -0.5 (logits of about
-  unit variance).
-
-``weight_dtype == "int8"`` quantises the seven matmul weights of a layer as
+``weight_dtype == "int8"`` quantises the leaves a block marks ``matmul`` as
 the program's loader does — symmetric, per output channel, absmax / 127 —
 inside the same call, layer by layer under ``lax.map``, so the bf16 form of
 more than one layer never exists. The arithmetic is written out here rather
@@ -25,6 +22,8 @@ of its own stage. On one chip the mesh has one device.
 from __future__ import annotations
 
 import functools
+import json
+from typing import Callable, NamedTuple, Optional
 
 import numpy as np
 import jax
@@ -34,14 +33,17 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 import llm_sharding_tpu.models  # noqa: F401  (first: ops.* imports it in a cycle)
 from llm_sharding_tpu.ops.quant import QTensor  # the container the engine reads
 
-MATMUL_LEAVES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
-LEAF_ORDER = (
-    "input_norm", "wq", "wk", "wv", "wo", "post_norm",
-    "w_gate", "w_up", "w_down", "bq", "bk", "bv",
-)
-BIAS_STD = 0.1
-GAIN_STD = 0.1
 DTYPES = {"bf16": jnp.bfloat16, "f32": jnp.float32}
+
+
+class Leaf(NamedTuple):
+    """One array a block asks for: a leaf of a layer or a table."""
+
+    name: str
+    shape: tuple
+    rule: Callable  # a standard-normal float32 sample → the float32 value
+    matmul: bool = False  # a matmul weight [in, out]: quantised under int8
+    vocab_axis: Optional[int] = None  # tables: the dimension a ring splits
 
 
 def root_key(seed: int) -> jax.Array:
@@ -53,19 +55,6 @@ def root_key(seed: int) -> jax.Array:
     )
 
 
-def leaf_shapes(model: dict) -> dict:
-    H, I = model["hidden_size"], model["intermediate_size"]
-    D = model.get("head_dim") or H // model["num_attention_heads"]
-    Nh, Nkv = model["num_attention_heads"], model["num_key_value_heads"]
-    return {
-        "input_norm": (H,), "post_norm": (H,),
-        "wq": (H, Nh * D), "wk": (H, Nkv * D), "wv": (H, Nkv * D),
-        "wo": (Nh * D, H),
-        "w_gate": (H, I), "w_up": (H, I), "w_down": (I, H),
-        "bq": (Nh * D,), "bk": (Nkv * D,), "bv": (Nkv * D,),
-    }
-
-
 def quantize(w: jax.Array, dtype) -> QTensor:
     """Symmetric per-output-channel int8 of ``w[in, out]``."""
     w32 = w.astype(jnp.float32)
@@ -74,83 +63,73 @@ def quantize(w: jax.Array, dtype) -> QTensor:
     return QTensor(q=q.astype(jnp.int8), scale=(absmax / 127.0).astype(dtype))
 
 
-def make_layer(shapes: dict, root: jax.Array, layer, dtype, int8: bool) -> dict:
+def make_layer(leaves: tuple, root: jax.Array, layer, dtype, int8: bool) -> dict:
     """One layer's leaves. ``layer`` may be traced."""
     key = jax.random.fold_in(root, layer)
     out = {}
-    for i, name in enumerate(LEAF_ORDER):
-        shape = shapes[name]
-        x = jax.random.normal(jax.random.fold_in(key, i), shape, jnp.float32)
-        if name in MATMUL_LEAVES:
-            w = (x * shape[0] ** -0.5).astype(dtype)
-            out[name] = quantize(w, dtype) if int8 else w
-        elif name.endswith("_norm"):
-            out[name] = (1.0 + GAIN_STD * x).astype(dtype)
-        else:
-            out[name] = (BIAS_STD * x).astype(dtype)
+    for i, leaf in enumerate(leaves):
+        x = jax.random.normal(jax.random.fold_in(key, i), leaf.shape, jnp.float32)
+        w = leaf.rule(x).astype(dtype)
+        out[leaf.name] = quantize(w, dtype) if int8 and leaf.matmul else w
     return out
 
 
-def make_tables(model: dict, root: jax.Array, dtype) -> dict:
-    V, H = model["vocab_size"], model["hidden_size"]
+def make_tables(tables: tuple, root: jax.Array, dtype) -> dict:
     k = jax.random.fold_in(root, 1 << 20)  # past any layer index
-    n = lambda i, shape: jax.random.normal(
-        jax.random.fold_in(k, i), shape, jnp.float32
-    )
     return {
-        "embed": n(0, (V, H)).astype(dtype),
-        "final_norm": (1.0 + GAIN_STD * n(1, (H,))).astype(dtype),
-        "lm_head": (n(2, (H, V)) * H ** -0.5).astype(dtype),
+        t.name: t.rule(jax.random.normal(
+            jax.random.fold_in(k, i), t.shape, jnp.float32)).astype(dtype)
+        for i, t in enumerate(tables)
     }
 
 
 @functools.lru_cache(maxsize=None)
-def _generator(shapes_items, num_layers: int, table_dims, mesh: Mesh,
-               dtype, int8: bool):
-    shapes = dict(shapes_items)
-    model = dict(table_dims)
+def _generator(block, model_json: str, mesh: Mesh, dtype, int8: bool):
+    model = json.loads(model_json)
+    leaves, tables = block.layer_leaves(model), block.tables(model)
+    num_layers = block.dims(model)["layers"]
     axis = mesh.axis_names[0]
 
     def stage(root_data, layer_ids):
         root = jax.random.wrap_key_data(root_data)
         return jax.lax.map(
-            lambda l: make_layer(shapes, root, l, dtype, int8), layer_ids
+            lambda l: make_layer(leaves, root, l, dtype, int8), layer_ids
         )
 
     def whole(root_data):
         layers = jax.shard_map(
             stage, mesh=mesh, in_specs=(P(), P(axis)), out_specs=P(axis),
         )(root_data, jnp.arange(num_layers, dtype=jnp.int32))
-        tables = make_tables(model, jax.random.wrap_key_data(root_data), dtype)
+        made = make_tables(tables, jax.random.wrap_key_data(root_data), dtype)
         # each chip makes its slice of the vocabulary, so no chip holds the
         # float32 form of a whole table (3 GB at 14B) beside its layers
-        split = {"embed": P(axis, None), "lm_head": P(None, axis),
-                 "final_norm": P()}
-        tables = {
-            k: jax.lax.with_sharding_constraint(v, NamedSharding(mesh, split[k]))
-            for k, v in tables.items()
+        def split(t: Leaf) -> P:
+            if t.vocab_axis is None:
+                return P()
+            return P(*(axis if d == t.vocab_axis else None
+                       for d in range(len(t.shape))))
+
+        made = {
+            t.name: jax.lax.with_sharding_constraint(
+                made[t.name], NamedSharding(mesh, split(t)))
+            for t in tables
         }
-        return {"layers": layers, **tables}
+        return {"layers": layers, **made}
 
     return jax.jit(whole)
 
 
-def make_params(model: dict, seed: int, weight_dtype: str, devices) -> dict:
+def make_params(block, model: dict, seed: int, weight_dtype: str, devices) -> dict:
     """The whole model on ``devices`` (layers split evenly along the ring),
-    in the engine's layout: ``{"embed", "layers": {leaf: [L, ...]},
-    "final_norm", "lm_head"}``; int8 leaves are ``QTensor(q, scale)``."""
+    in the engine's layout: ``{"layers": {leaf: [L, ...]}, <table>: ...}``;
+    int8 leaves are ``QTensor(q, scale)``."""
     int8 = weight_dtype == "int8"
     dtype = jnp.bfloat16 if int8 else DTYPES[weight_dtype]
-    L = int(model["num_hidden_layers"])
+    L = block.dims(model)["layers"]
     if L % len(devices):
         raise ValueError(f"{L} layers do not split over {len(devices)} chips")
     mesh = Mesh(np.asarray(devices), ("pipe",))
-    fn = _generator(
-        tuple(sorted(leaf_shapes(model).items())), L,
-        (("vocab_size", model["vocab_size"]),
-         ("hidden_size", model["hidden_size"])),
-        mesh, dtype, int8,
-    )
+    fn = _generator(block, json.dumps(model, sort_keys=True), mesh, dtype, int8)
     root_data = jax.device_put(
         jax.random.key_data(root_key(seed)), NamedSharding(mesh, P())
     )
