@@ -1,0 +1,160 @@
+"""The OLMoE block's own rehearsal (``blocks/olmoe.py``, the configuration
+``olmoe_1b_7b`` and its four readers). CPU, tiny widths, Pallas in interpret
+mode:
+
+    JAX_PLATFORMS=cpu python3 -m pytest benchmark/tests/test_olmoe_block.py -q -p no:cacheprovider
+
+``test_benchmark.py::test_a_configuration_resolves_to_a_block_with_the_programs_leaves``
+picks up ``configs/olmoe_1b_7b.json`` as a case by itself.
+"""
+
+import os
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, HERE)
+
+import test_benchmark as tb  # noqa: E402  (sets the CPU, interpret mode, paths)
+
+import numpy as np  # noqa: E402
+import pytest  # noqa: E402
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+from benchmark import blocks, harness, weights  # noqa: E402
+
+TINY = tb.load(HERE, "data", "tiny_olmoe.json")
+BLOCK = blocks.load("olmoe")
+CELL = "olmoe_1b_7b.backlog"
+NEW = ("decode_moe_pct.backlog", "moe_hbm_pct.backlog",
+       "experts_read_per_layer.backlog", "expert_tokens_max_over_mean.backlog")
+
+# what the program is handed in place of the seed's leaves; the reference
+# keeps the seed's. A router of zeros routes every token to experts 0..k-1
+# with equal weights; without the two gains the program's block, which keys
+# the q/k norm by their presence, runs with no such norm at all.
+WRONG = {
+    "sound": None,
+    "router zeroed": "router",
+    "q/k norm dropped": "q_norm",
+}
+
+
+def test_the_tiny_configuration_is_a_case_of_the_leaves_test():
+    tb.test_a_configuration_resolves_to_a_block_with_the_programs_leaves(
+        os.path.join(HERE, "data", "tiny_olmoe.json"), blocks.HERE)
+
+
+@pytest.mark.parametrize("what", list(WRONG))
+def test_the_olmoe_block_runs_through_the_harness(what, tmp_path, monkeypatch):
+    """A tiny ``olmoe`` configuration served paged through
+    ``harness.run_cell`` is correct, its step records carry the experts'
+    counters and the host-side readers read them — and it is not correct when
+    the program is handed a zero router or unit q/k gains."""
+    make, calls = weights.make_params, []
+
+    def served_wrong(*args, **kw):
+        params = make(*args, **kw)
+        calls.append(1)
+        if WRONG[what] is None or len(calls) > 1:  # the second is the check's
+            return params
+        layers = dict(params["layers"])
+        if WRONG[what] == "router":
+            layers["router"] = jnp.zeros_like(layers["router"])
+        else:
+            del layers["q_norm"], layers["k_norm"]
+        return dict(params, layers=layers)
+
+    monkeypatch.setattr(weights, "make_params", served_wrong)
+    e2e, layer, _ = tb._readers(CELL)
+    got = tb.run_tiny("backlog", 1, tmp_path, e2e, cfg=TINY, block=BLOCK)
+    res, rec = got["result"], got["records"]
+    assert len(calls) == 2 and rec["reference"]["positions"] > 20
+    assert res["failed"] == 0 and rec["paths"]["attn_backend"] == "interpret"
+    assert set(res["metrics"]) == {"itl_p95_ms", "setup_s"}
+    assert res["correct"] == (WRONG[what] is None), rec["reference"]
+    print(what, rec["reference"])
+    if WRONG[what] is not None:
+        assert rec["kernels_ok"] and rec["arena_ok"]
+        assert rec["reference"]["margin_mean"] > 3 * BLOCK.DELTA_MEAN
+        return
+    # the counters: never more experts than k x the live rows, per step
+    k, E, L = 2, 8, 2
+    steps = [s for s in rec["steps"] if s.get("expert_steps")]
+    assert steps
+    for s in steps:
+        assert len(s["experts_read"]) == L and len(s["expert_tokens"]) == E
+        assert max(s["experts_read"]) <= min(E * s["expert_steps"],
+                                             k * s["expert_rows"])
+        assert min(s["experts_read"]) >= s["expert_steps"] and s["expert_rows"]
+    n = layer["experts_read_per_layer.backlog"][0](rec)
+    assert k <= n <= 2 * k  # batch_per_slot 2
+    assert 1.0 <= layer["expert_tokens_max_over_mean.backlog"][0](rec) < E
+    # the device-side readers have nothing to read in an untraced run
+    assert layer["decode_moe_pct.backlog"][0](rec) is None
+    assert layer["moe_hbm_pct.backlog"][0](rec) is None
+    # and the byte count takes what the records say
+    model = harness.model_keys(TINY)
+    rec["traced"] = rec["window"]
+    assert BLOCK.decode_step_bytes(model, "int8", 1, 0.0, rec) == pytest.approx(
+        hand_count(model, n))
+
+
+def hand_count(model, experts_read):
+    """Bytes of a decode microstep of the tiny model, by hand (int8)."""
+    H, F, E, L, V = 128, 64, 8, 2, 512
+    attn = 4 * H * H + 4 * H * 2  # four square int8 matmuls, their scales
+    rest = (H * E + 4 * H) * 2 + H * 2  # router, four gains; we_down's scale
+    expert = 3 * H * F + 2 * F * 2  # three int8 matrices, gate and up scales
+    return L * (attn + rest + experts_read * expert) + H * V * 2
+
+
+def test_bytes_of_a_decode_step_follow_the_counter():
+    model = harness.model_keys(tb.load(tb.BENCH, "configs", "olmoe_1b_7b.json"))
+    rec = {"window": [0.0, 10.0], "traced": [0.0, 10.0], "steps": [
+        {"t": 1.0, "experts_read": [9] * 16, "expert_steps": 1},
+        {"t": 2.0, "experts_read": [22] * 16, "expert_steps": 2},
+        {"t": 3.0, "experts_read": [], "expert_steps": 0},
+        {"t": 11.0, "experts_read": [64] * 16, "expert_steps": 1},
+    ]}
+    assert BLOCK.experts_read_per_layer(rec) == pytest.approx(31 / 3)
+    expert = 3 * 2048 * 1024 + 2 * 1024 * 2
+    assert BLOCK.expert_bytes(model, "int8") == expert
+    dense = 4 * 2048 * 2048 + (2048 * 64 + 4 * 2048) * 2 + 5 * 2048 * 2
+    assert BLOCK.dense_layer_bytes(model, "int8") == dense
+    want = (16 * (dense + 31 / 3 * expert) + 2048 * 50304 * 2
+            + 16 * 100.0 * 2 * 16 * 128 * 2)
+    assert BLOCK.decode_step_bytes(model, "int8", 1, 100.0, rec) == pytest.approx(want)
+    with pytest.raises(ValueError, match="experts_read"):
+        BLOCK.decode_step_bytes(model, "int8", 1, 100.0, {"window": [0, 1], "steps": []})
+
+
+def test_the_new_readers_on_a_recorded_run():
+    """The four readers on hand-built records: the shares from the scopes'
+    seconds, the roofline share from counter, bytes and the ``moe`` scope's
+    time per microstep — and None (not an error) on a program without the
+    scopes or the counters, as the parent commit is."""
+    _, layer, _ = tb._readers(CELL)
+    read = {n: layer[n][0] for n in NEW}
+    cfg = tb.load(tb.BENCH, "configs", "olmoe_1b_7b.json")
+    steps = [{"t": 1.0 + i, "rows": 1, "experts_read": [10] * 16,
+              "expert_steps": 1, "expert_rows": 1,
+              "expert_tokens": [4] * 32 + [1] * 32} for i in range(4)]
+    rec = {
+        "config": cfg, "chips": 1, "window": [0.0, 10.0], "traced": [0.0, 10.0],
+        "peaks": {"hbm_bytes_per_s": 819e9}, "steps": steps,
+        "trace": {"modules": {"serve_chunk": [[0.004] * 4]}},
+        "spans": {"scopes": {"serve_chunk": {
+            "attn": 0.008, "moe": 0.004, "router": 0.001, "qkv": 0.003}}},
+    }
+    assert read["decode_moe_pct.backlog"](rec) == pytest.approx(100 * 5 / 16)
+    assert read["experts_read_per_layer.backlog"](rec) == pytest.approx(10.0)
+    assert read["expert_tokens_max_over_mean.backlog"](rec) == pytest.approx(4 / 2.5)
+    need = 10 * 16 * (3 * 2048 * 1024 + 4096)
+    assert read["moe_hbm_pct.backlog"](rec) == pytest.approx(
+        100 * need / 819e9 / 0.001)
+    # a program without the words or the counters: nothing, and no error
+    old = dict(rec, steps=[{"t": 1.0, "rows": 1}],
+               spans={"scopes": {"serve_chunk": {"attn": 0.008, "mlp": 0.004}}})
+    assert all(read[n](old) is None for n in NEW)
+    assert all(read[n](dict(old, spans=None, trace=None)) is None for n in NEW)

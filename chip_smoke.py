@@ -30,12 +30,18 @@ Everything it writes (the store, child logs, ``result.json``) lands in
 ``.smoke/`` inside the checkout, which ``.gitignore`` lists. The four-chip
 forms are the same script: ``--stages 4``, ``--weights bf16``,
 ``--data-parallel 2 --stages 2``. ``--layers N`` cuts depth, never width.
+``--moe`` runs one check only and no daemon: the expert kernel of
+``ops/moe.py`` (``moe_experts``) against its XLA path at OLMoE-1B-7B's
+published widths, in the decode regime (4 rows) and the grouped prefill
+regime (1,024 positions), a third of the rows dead; the last line is then
+``{"ok": true, "moe": [...], "device": {...}}``.
 """
 
 from __future__ import annotations
 
 import argparse
 import concurrent.futures
+import functools
 import http.client
 import json
 import os
@@ -294,6 +300,88 @@ def check_kernel(cfg, case: dict, backend: str, seed: int = 0) -> float:
     if not np.isfinite(got).all():
         raise AssertionError(f"{case}: non-finite kernel output")
     return float(np.abs(got - np.asarray(want.astype(jnp.float32))).max())
+
+
+#: the expert kernel's two regimes: a decode step's rows, a prefill chunk's
+#: positions (``batch_per_slot`` rows x ``prefill_chunk``)
+MOE_ROWS = (4, 1024)
+
+
+def check_moe_kernel(cfg, rows: int, backend: str, seed: int = 0) -> float:
+    """The expert product of ``ops/moe.py`` through ``backend`` ("kernel" on
+    the chip, "interpret" under pytest) and through its XLA path on the same
+    seeded int8 experts — a stack of two layers read at the second, a third
+    of the rows dead; max |difference| relative to the output's scale."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+
+    import llm_sharding_tpu.models  # noqa: F401 — ops import through models
+    from llm_sharding_tpu.ops import moe
+    from llm_sharding_tpu.ops.quant import QTensor
+
+    H, F = cfg.hidden_size, cfg.intermediate_size
+    E, k, L = cfg.num_experts, cfg.num_experts_per_tok, 2
+    ks = jax.random.split(jax.random.key(seed * 7919 + rows), 9)
+    dt = jnp.bfloat16
+
+    def codes(key, *shape):
+        return jax.random.randint(key, shape, -127, 128, jnp.int8)
+
+    def scales(key, n, fan):
+        return (jax.random.uniform(key, (L, n), jnp.float32, 0.5, 1.5)
+                * (fan ** -0.5 / 64.0)).astype(dt)
+
+    wg = QTensor(codes(ks[0], L, H, E * F), scales(ks[1], E * F, H))
+    wu = QTensor(codes(ks[2], L, H, E * F), scales(ks[3], E * F, H))
+    wd = QTensor(codes(ks[4], L, E * F, H), scales(ks[5], H, F))
+    x = jax.random.normal(ks[6], (rows, H), jnp.float32).astype(dt)
+    router = jax.random.normal(ks[7], (H, E), jnp.float32).astype(dt)
+    live = jax.random.uniform(ks[8], (rows,)) > 1 / 3
+
+    # the weights are arguments: closed over, 0.8 GB of them would be baked
+    # into each program as constants
+    @functools.partial(jax.jit, static_argnames="how")
+    def run(x, live, router, wg, wu, wd, how):
+        w, ids = moe.route(x, router, k, cfg.norm_topk_prob)
+        return moe.expert_mlp(
+            x, w, ids, wg, wu, wd, E, live=live, layer=jnp.int32(1),
+            backend=how,
+        )
+
+    got, st = run(x, live, router, wg, wu, wd, how=backend)
+    want, st_x = run(x, live, router, wg, wu, wd, how="xla")
+    got = np.asarray(got.astype(jnp.float32))
+    want = np.asarray(want.astype(jnp.float32))
+    if not np.isfinite(got).all():
+        raise AssertionError(f"rows={rows}: non-finite expert output")
+    if not np.array_equal(st.expert_tokens, st_x.expert_tokens):
+        raise AssertionError(f"rows={rows}: the two paths count differently")
+    return float(np.abs(got - want).max() / np.abs(want).max())
+
+
+def child_moe(spec: dict, out_path: str) -> None:
+    import jax
+
+    from llm_sharding_tpu.utils.compile_cache import enable_persistent_cache
+    from llm_sharding_tpu.utils.device_report import device_report
+
+    platform = jax.devices()[0].platform
+    require_tpu(platform, "the expert kernel check")
+    enable_persistent_cache(platform)
+    cfg = model_config(spec)
+    results = []
+    for rows in MOE_ROWS:
+        err = check_moe_kernel(cfg, rows, "kernel")
+        results.append({"kernel": "moe_experts", "rows": rows,
+                        "max_rel_err": err, "ok": err <= KERNEL_TOL})
+        print(f"[moe] moe_experts rows={rows:<5d} max rel err={err:.3e} "
+              f"{'ok' if err <= KERNEL_TOL else 'OVER ' + str(KERNEL_TOL)}",
+              flush=True)
+    with open(out_path, "w") as f:
+        json.dump({"device": device_report(), "kernels": results}, f)
+    if not all(r["ok"] for r in results):
+        raise SystemExit("chip_smoke: the expert kernel disagrees with XLA")
 
 
 def require_tpu(platform: str, who: str) -> None:
@@ -700,15 +788,25 @@ def main(argv=None) -> int:
     ap.add_argument("--weights", choices=("int8", "bf16"), default="int8")
     ap.add_argument("--layers", type=int, default=None,
                     help="cut depth (never width)")
-    ap.add_argument("--child", choices=("kernels", "store"))
+    ap.add_argument("--moe", action="store_true",
+                    help="only check the expert kernel (ops/moe.py) against "
+                         "its XLA path at OLMoE-1B-7B's published widths")
+    ap.add_argument("--child", choices=("kernels", "store", "moe"))
     ap.add_argument("--spec")
     ap.add_argument("--out")
     args = ap.parse_args(argv)
     if args.child:
         spec = json.loads(args.spec)
-        {"kernels": child_kernels, "store": child_store}[args.child](
-            spec, args.out
-        )
+        {"kernels": child_kernels, "store": child_store,
+         "moe": child_moe}[args.child](spec, args.out)
+        return 0
+    if args.moe:
+        os.makedirs(WORK, exist_ok=True)
+        got = wait_child(run_child(
+            "moe", {"preset": "olmoe_1b_7b", "overrides": {}},
+            dict(os.environ, PYTHONPATH=HERE), "moe.log"))
+        print(json.dumps({"ok": True, "moe": got["kernels"],
+                          "device": got["device"]}))
         return 0
 
     spec = json.loads(json.dumps(FULL))

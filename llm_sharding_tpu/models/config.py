@@ -55,6 +55,16 @@ class ModelConfig:
     hidden_act: str = "silu"  # "silu" | "gelu_tanh"
     norm_offset: float = 0.0
     embed_multiplier: float = 1.0
+    # Sparse experts (OLMoE): with ``num_experts`` > 0 every layer's MLP is
+    # ``num_experts`` gated MLPs of width ``intermediate_size`` and a token
+    # runs the ``num_experts_per_tok`` the router scores highest; the kept
+    # router probabilities are renormalised only under ``norm_topk_prob``.
+    # ``qk_norm``: q and k pass through an RMSNorm over the whole projected
+    # width before the heads are split and rotated.
+    num_experts: int = 0
+    num_experts_per_tok: int = 0
+    norm_topk_prob: bool = False
+    qk_norm: bool = False
     # GPT-2 specifics
     layer_norm_epsilon: float = 1e-5
     # Token ids. ``eos_token_ids`` holds ALL stop ids (Llama-3.x instruct
@@ -133,6 +143,34 @@ class ModelConfig:
                 tie_word_embeddings=True,
             )
             mt = "llama"
+        if mt == "olmoe":
+            # OLMoE is the llama block with two deltas (HF modeling_olmoe.py):
+            # an RMSNorm over the full q and k projections, and the dense MLP
+            # replaced by ``num_experts`` gated MLPs chosen per token. What
+            # the block cannot honour is refused by name.
+            if hf.get("clip_qkv") is not None:
+                raise ValueError(
+                    "olmoe clip_qkv is not supported; this maps checkpoints "
+                    "with clip_qkv null (OLMoE-1B-7B-0125 and later)"
+                )
+            for key in ("num_experts", "num_experts_per_tok"):
+                if key not in hf:
+                    raise ValueError(f"olmoe config.json lacks {key!r}")
+            if not 0 < hf["num_experts_per_tok"] <= hf["num_experts"]:
+                raise ValueError(
+                    f"olmoe num_experts_per_tok {hf['num_experts_per_tok']} "
+                    f"is not in 1..num_experts {hf['num_experts']}"
+                )
+            moe = dict(
+                num_experts=hf["num_experts"],
+                num_experts_per_tok=hf["num_experts_per_tok"],
+                norm_topk_prob=bool(hf.get("norm_topk_prob", False)),
+                qk_norm=True,
+            )
+            hf = dict(hf, model_type="llama")
+            mt = "llama"
+        else:
+            moe = {}
         if mt in ("llama",):
             act = hf.get("hidden_act", "silu")
             if act not in ("silu", "gelu_tanh"):
@@ -182,7 +220,11 @@ class ModelConfig:
                 hidden_act=act,
                 norm_offset=hf.get("norm_offset", 0.0),
                 embed_multiplier=hf.get("embed_multiplier", 1.0),
-                bos_token_id=hf.get("bos_token_id", 1),
+                **moe,
+                bos_token_id=(
+                    1 if hf.get("bos_token_id") is None
+                    else hf["bos_token_id"]
+                ),
                 eos_token_id=eos_ids[0],
                 eos_token_ids=eos_ids,
             )
@@ -338,6 +380,51 @@ def gemma_7b() -> ModelConfig:
         "bos_token_id": 2,
         "eos_token_id": 1,
     })
+
+
+def olmoe_1b_7b() -> ModelConfig:
+    """OLMoE-1B-7B-0125-Instruct: the llama block with a q/k RMSNorm and 64
+    experts of width 1024, 8 a token, kept probabilities not renormalised;
+    plain MHA (16 key/value heads), untied head."""
+    return ModelConfig.from_hf_config({
+        "model_type": "olmoe",
+        "vocab_size": 50304,
+        "hidden_size": 2048,
+        "intermediate_size": 1024,
+        "num_hidden_layers": 16,
+        "num_attention_heads": 16,
+        "num_key_value_heads": 16,
+        "max_position_embeddings": 4096,
+        "rms_norm_eps": 1e-5,
+        "rope_theta": 10000.0,
+        "tie_word_embeddings": False,
+        "clip_qkv": None,
+        "num_experts": 64,
+        "num_experts_per_tok": 8,
+        "norm_topk_prob": False,
+        "bos_token_id": None,
+        "eos_token_id": 50279,
+    })
+
+
+def tiny_olmoe(**kw) -> ModelConfig:
+    """Tiny olmoe-layout config (q/k norm, 8 experts of width 32, 2 a token)
+    for CPU tests."""
+    base = dict(
+        model_type="olmoe",
+        vocab_size=256,
+        hidden_size=64,
+        intermediate_size=32,
+        num_hidden_layers=2,
+        num_attention_heads=4,
+        num_key_value_heads=4,
+        max_position_embeddings=128,
+        num_experts=8,
+        num_experts_per_tok=2,
+        norm_topk_prob=False,
+    )
+    base.update(kw)
+    return ModelConfig.from_hf_config(base)
 
 
 def tiny_qwen2(**kw) -> ModelConfig:

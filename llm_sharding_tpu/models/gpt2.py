@@ -150,7 +150,7 @@ def decoder_layer(
         return attention_step(q, k_r, v_r, positions, kv_positions, length)
 
     h = attn_mlp_block(cfg, p, h, attn_fn, tp_axis)
-    return h, rows["k"], rows["v"]
+    return h, rows["k"], rows["v"], None  # no stats (models/stack.py)
 
 
 def forward_layers(
@@ -161,7 +161,9 @@ def forward_layers(
     positions: jnp.ndarray,
     layer_mask: Optional[jnp.ndarray] = None,
     tp_axis: Optional[str] = None,
-) -> tuple[jnp.ndarray, KVCache]:
+):
+    """Returns ``(h, cache, None)``: the third slot is a model's layer
+    stats (``models/stack.scan_layers``), which GPT-2 has none of."""
     def apply(p, h, k_row, v_row, kv_pos, length):
         return decoder_layer(
             cfg, p, h, k_row, v_row, positions, kv_pos, length, tp_axis
@@ -235,7 +237,7 @@ def forward_layers_paged(
             )
 
         h = attn_mlp_block(cfg, p, h, attn_fn, tp_axis)
-        return (h, *out["kv"])
+        return (h, *out["kv"], None)  # no stats (models/stack.py)
 
     return scan_layers_paged(
         layers, h, k_arena, v_arena, apply, layer_mask,
@@ -259,5 +261,5 @@ def forward(
     positions: jnp.ndarray,
 ) -> tuple[jnp.ndarray, KVCache]:
     h = embed(params, token_ids, positions)
-    h, cache = forward_layers(cfg, params["layers"], h, cache, positions)
+    h, cache, _ = forward_layers(cfg, params["layers"], h, cache, positions)
     return final_logits(cfg, params, h), cache

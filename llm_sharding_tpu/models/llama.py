@@ -66,10 +66,27 @@ def init_layer_params(
         "wv": w(ks[2], H, Nkv * D),
         "wo": w(ks[3], Nh * D, H),
         "post_norm": jnp.ones((L, H), dtype),
-        "w_gate": w(ks[4], H, I),
-        "w_up": w(ks[5], H, I),
-        "w_down": w(ks[6], I, H),
     }
+    if cfg.num_experts:
+        # sparse experts: the layer's E MLPs of width I as ONE block-sparse
+        # MLP of width E·I (expert e = columns, resp. rows, e·I..(e+1)·I) and
+        # the router that picks among them — see ops/moe.py
+        E = cfg.num_experts
+        p.update(
+            router=w(jax.random.fold_in(key, 7), H, E),
+            we_gate=w(ks[4], H, E * I),
+            we_up=w(ks[5], H, E * I),
+            # an expert's own fan-in is I, not the leaf's E·I rows
+            we_down=w(ks[6], E * I, H) * jnp.asarray(E**0.5, dtype),
+        )
+    else:
+        p.update(
+            w_gate=w(ks[4], H, I), w_up=w(ks[5], H, I), w_down=w(ks[6], I, H)
+        )
+    if cfg.qk_norm:
+        # RMSNorm gains over the whole projected q / k width (OLMoE)
+        p["q_norm"] = jnp.ones((L, Nh * D), dtype)
+        p["k_norm"] = jnp.ones((L, Nkv * D), dtype)
     if cfg.attention_bias:
         # qkv biases (the Qwen2-family layout: q/k/v biased, o not); presence
         # of the keys — not the flag — drives the forward path, so converted
@@ -116,7 +133,10 @@ def attn_mlp_block(
     sin: jnp.ndarray,
     attn_fn,  # (q[B,S,Nh,D], k[B,S,Nkv,D], v[B,S,Nkv,D]) -> [B,S,Nh,D]
     tp_axis: Optional[str] = None,
-) -> jnp.ndarray:
+    moe_live: Optional[jnp.ndarray] = None,  # [B, S] bool: positions that
+    #   route (a model with experts only; None = all of them)
+    moe_backend: str = "auto",
+):
     """One llama block with the attention mechanism injected — the single
     implementation behind the cached (pipeline/decode) path and the
     ring-attention (context-parallel) path.
@@ -127,6 +147,11 @@ def attn_mlp_block(
     head slice, and the two row-parallel matmuls are completed with a psum
     over ``tp_axis``. With ``tp_axis=None`` and full weights this reduces to
     the plain single-device block.
+
+    Returns ``(h, stats)``: ``stats`` is the layer's ``MoeStats`` for a
+    model with experts and None (an empty pytree) for a dense one — the one
+    convention every layer, scan, stage and ring function above this keeps,
+    always as the LAST result.
     """
     B, S, H = h.shape
     D = cfg.head_dim_
@@ -152,6 +177,12 @@ def attn_mlp_block(
             kx = kx + p["bk"]
         if "bv" in p:
             vx = vx + p["bv"]
+    if "q_norm" in p:
+        # OLMoE: an RMSNorm over the WHOLE projected width of q and of k,
+        # before the heads are split and rotated; keyed by presence
+        with jax.named_scope("norm"):
+            qx = rms_norm(qx, p["q_norm"], cfg.rms_norm_eps, cfg.norm_offset)
+            kx = rms_norm(kx, p["k_norm"], cfg.rms_norm_eps, cfg.norm_offset)
     with jax.named_scope("rope"):
         q = apply_rope(qx.reshape(B, S, Nh, D), cos, sin)
         k = apply_rope(kx.reshape(B, S, Nkv, D), cos, sin)
@@ -168,6 +199,31 @@ def attn_mlp_block(
 
     with jax.named_scope("norm"):
         x = rms_norm(h, p["post_norm"], cfg.rms_norm_eps, cfg.norm_offset)
+    if "router" in p:
+        # sparse experts, keyed by the presence of the leaves: the router
+        # picks ``num_experts_per_tok`` of the layer's experts per position
+        # and only those are read and computed (ops/moe.py). ``layer`` is
+        # there when the scan handed the expert leaves over as whole
+        # layer-stacked arrays (models/stack.py) for the kernel to index.
+        from ..ops import moe
+
+        if tp_axis is not None:
+            raise NotImplementedError(
+                "tensor parallelism over a model with experts is not "
+                "implemented (an expert axis on the mesh is a later step)"
+            )
+        x2 = x.reshape(B * S, H)
+        with jax.named_scope("router"):
+            weights, ids = moe.route(
+                x2, p["router"], cfg.num_experts_per_tok, cfg.norm_topk_prob
+            )
+        y, stats = moe.expert_mlp(
+            x2, weights, ids, p["we_gate"], p["we_up"], p["we_down"],
+            cfg.num_experts,
+            live=None if moe_live is None else moe_live.reshape(B * S),
+            layer=p.get("layer"), backend=moe_backend,
+        )
+        return h + y.reshape(B, S, H), stats
     # gated MLP: activation per family (llama/qwen2 silu, gemma gelu-tanh).
     # The fp32 cast is a deliberate local deviation from HF (which runs the
     # act in model dtype): exact in the f32 parity tests, slightly more
@@ -186,7 +242,7 @@ def attn_mlp_block(
         )
         if tp_axis is not None:
             mlp = jax.lax.psum(mlp, tp_axis)
-        return h + mlp
+        return h + mlp, None
 
 
 def decoder_layer(
@@ -201,7 +257,9 @@ def decoder_layer(
     kv_positions: jnp.ndarray,  # [B, C] per-slot key positions (post-write)
     length: jnp.ndarray,  # scalar int32: shared write offset for this step
     tp_axis: Optional[str] = None,
+    moe_live: Optional[jnp.ndarray] = None,
 ):
+    """Returns ``(h, k_row, v_row, stats)`` (``attn_mlp_block``)."""
     rows = {}
 
     def attn_fn(q, k, v):
@@ -215,8 +273,10 @@ def decoder_layer(
         rows["k"], rows["v"] = k_r, v_r
         return attention_step(q, k_r, v_r, positions, kv_positions, length)
 
-    h = attn_mlp_block(cfg, p, h, cos, sin, attn_fn, tp_axis)
-    return h, rows["k"], rows["v"]
+    h, stats = attn_mlp_block(
+        cfg, p, h, cos, sin, attn_fn, tp_axis, moe_live=moe_live
+    )
+    return h, rows["k"], rows["v"], stats
 
 
 def paged_decoder_layer(
@@ -242,6 +302,7 @@ def paged_decoder_layer(
     #   the query-tiled paged_prefill kernel instead of the decode one
     nlive: Optional[jnp.ndarray] = None,  # [B] prefill traffic clamp
     cp_axis: Optional[str] = None,  # context-parallel combine axis
+    moe_live: Optional[jnp.ndarray] = None,  # [B, S] positions that route
 ):
     """Decode-path layer over the pooled arena: the step's fresh KV lands
     via a block-indexed scatter into layer ``layer`` of the stacked pool
@@ -298,8 +359,18 @@ def paged_decoder_layer(
             **kw,
         )
 
-    h = attn_mlp_block(cfg, p, h, cos, sin, attn_fn, tp_axis)
-    return (h, *out["kv"])
+    if "router" in p:
+        # a masked layer and a ring-inactive microstep route nowhere: their
+        # result is discarded, so no expert is read or counted for them
+        gate = jnp.asarray(write_valid) & valid
+        moe_live = jnp.broadcast_to(
+            gate if moe_live is None else moe_live & gate, h.shape[:2]
+        )
+    h, stats = attn_mlp_block(
+        cfg, p, h, cos, sin, attn_fn, tp_axis, moe_live=moe_live,
+        moe_backend=backend,
+    )
+    return (h, *out["kv"], stats)
 
 
 def forward_layers_paged(
@@ -323,12 +394,17 @@ def forward_layers_paged(
     nlive: Optional[jnp.ndarray] = None,  # [B] prefill traffic clamp
     cp_axis: Optional[str] = None,  # context-parallel combine axis (the
     #   arena/table are per-shard; see paged_decoder_layer)
+    moe_live: Optional[jnp.ndarray] = None,  # [B, S] bool — a model with
+    #   experts: the positions that route (dead rows and pads route nowhere)
 ):
     """Paged counterpart of ``forward_layers`` for the serve decode path:
     scans the layer stack over the pooled arena (``stack.scan_layers_paged``)
     instead of a materialized per-row window. Returns ``(h, k_arena,
-    v_arena, k_scale, v_scale)`` — scale outputs are None unquantized;
-    kpos bookkeeping stays with the caller."""
+    v_arena, k_scale, v_scale, stats)`` — scale outputs are None
+    unquantized; kpos bookkeeping stays with the caller. ``stats`` is None
+    for a dense model, else its ``MoeStats`` stacked over layers (``[L, E]``
+    tokens per expert, ``[L]`` distinct experts read; zero at masked
+    layers)."""
     from .stack import scan_layers_paged
 
     with jax.named_scope("rope"):
@@ -342,7 +418,7 @@ def forward_layers_paged(
             cfg, p, l, valid, h, k_all, v_all, block_table, cols, cos, sin,
             positions, kv_positions, wv, tp_axis, backend,
             k_scale=ks_all, v_scale=vs_all, prefill=prefill, nlive=nlive,
-            cp_axis=cp_axis,
+            cp_axis=cp_axis, moe_live=moe_live,
         )
 
     return scan_layers_paged(
@@ -359,7 +435,9 @@ def forward_layers(
     positions: jnp.ndarray,
     layer_mask: Optional[jnp.ndarray] = None,  # [L] bool — False = pass-through
     tp_axis: Optional[str] = None,
-) -> tuple[jnp.ndarray, KVCache]:
+    moe_live: Optional[jnp.ndarray] = None,  # [B, S] bool (experts only):
+    #   the positions that route; None = all of them
+):
     """Run ``h`` through a stack of decoder layers via ``lax.scan``.
 
     ``layer_mask`` enables ragged pipeline stages: masked-out layers leave the
@@ -367,6 +445,8 @@ def forward_layers(
     same (padded) layer count in one SPMD program (SURVEY.md §7 "uneven layer
     splits"). ``tp_axis`` turns on explicit megatron TP inside every layer
     (weights and KV cache must carry the matching local head slices).
+    Returns ``(h, cache, stats)``; ``stats`` is None for a dense model,
+    else the ``MoeStats`` stacked over layers.
     """
     with jax.named_scope("rope"):
         cos, sin = rope_cos_sin(positions, cfg, dtype=jnp.float32)
@@ -374,7 +454,7 @@ def forward_layers(
     def apply(p, h, k_row, v_row, kv_pos, length):
         return decoder_layer(
             cfg, p, h, k_row, v_row, cos, sin, positions, kv_pos, length,
-            tp_axis,
+            tp_axis, moe_live=moe_live,
         )
 
     return scan_layers(layers, h, cache, positions, apply, layer_mask)
@@ -406,5 +486,5 @@ def forward(
     h = embed(params, token_ids)
     if cfg.embed_multiplier != 1.0:  # gemma: hidden scaled by sqrt(H)
         h = h * jnp.asarray(cfg.embed_multiplier, h.dtype)
-    h, cache = forward_layers(cfg, params["layers"], h, cache, positions)
+    h, cache, _ = forward_layers(cfg, params["layers"], h, cache, positions)
     return final_logits(cfg, params, h), cache

@@ -17,8 +17,36 @@ import jax.numpy as jnp
 
 from .cache import KVCache
 
-# apply_layer(p, h, k_row, v_row, kv_pos, length) -> (h, k_row, v_row)
+# apply_layer(p, h, k_row, v_row, kv_pos, length) -> (h, k_row, v_row, stats)
+# ``stats`` is what the layer counted (a model with experts: ``MoeStats``) or
+# None; both scans return it stacked over layers as their LAST result. A None
+# is an empty pytree: it adds no operand or output to a dense model's program.
 ApplyLayerFn = Callable
+
+#: Leaves a scan hands ``apply_layer`` WHOLE (layer-stacked, beside the
+#: layer index under ``"layer"``) instead of slicing a layer out per
+#: iteration: the experts of a sparse MLP (``ops/moe.py``). A step reads a
+#: few experts of a layer through indices chosen at run time; slicing the
+#: layer first would copy all of them (0.4 GB a layer at OLMoE's widths).
+WHOLE_KEYS = ("we_gate", "we_up", "we_down")
+
+
+def split_whole(layers):
+    """``(scanned, whole)``: ``whole`` is None for a model with no such leaf,
+    and ``layers`` then comes back as it is."""
+    if not isinstance(layers, dict) or not any(k in layers for k in WHOLE_KEYS):
+        return layers, None
+    whole = {k: layers[k] for k in WHOLE_KEYS if k in layers}
+    return {k: v for k, v in layers.items() if k not in whole}, whole
+
+
+def join_whole(p, whole, l):
+    return p if whole is None else {**p, **whole, "layer": l}
+
+
+def masked_stats(stats, valid):
+    """A masked (padding) layer read and counted nothing."""
+    return jax.tree.map(lambda a: jnp.where(valid, a, jnp.zeros_like(a)), stats)
 
 
 def scan_layers(
@@ -28,8 +56,10 @@ def scan_layers(
     positions: jnp.ndarray,
     apply_layer: ApplyLayerFn,
     layer_mask: Optional[jnp.ndarray] = None,
-) -> tuple[jnp.ndarray, KVCache]:
+):
+    """Returns ``(h, cache, stats)``."""
     S = h.shape[1]
+    layers, whole = split_whole(layers)
     L = cache.num_layers
     if layer_mask is None:
         layer_mask = jnp.ones((L,), bool)
@@ -52,7 +82,9 @@ def scan_layers(
         with jax.named_scope("kv_take"):
             k_row = jax.lax.dynamic_index_in_dim(k_all, l, keepdims=False)
             v_row = jax.lax.dynamic_index_in_dim(v_all, l, keepdims=False)
-        h_new, k_new, v_new = apply_layer(p, h, k_row, v_row, kv_pos, cache.length)
+        h_new, k_new, v_new, stats = apply_layer(
+            join_whole(p, whole, l), h, k_row, v_row, kv_pos, cache.length
+        )
         h = jnp.where(valid, h_new, h)
         with jax.named_scope("kv_put"):
             # the layer only changed positions [length, length+S) of its row
@@ -65,13 +97,14 @@ def scan_layers(
             new_v = jnp.where(valid, new_v, old_v)
             k_all = jax.lax.dynamic_update_slice(k_all, new_k[None], (l, *start))
             v_all = jax.lax.dynamic_update_slice(v_all, new_v[None], (l, *start))
-        return (h, k_all, v_all), None
+        return (h, k_all, v_all), masked_stats(stats, valid)
 
-    (h, k_all, v_all), _ = jax.lax.scan(
+    (h, k_all, v_all), stats = jax.lax.scan(
         body, (h, cache.k, cache.v),
         (layers, jnp.arange(L, dtype=jnp.int32), layer_mask),
     )
-    return h, KVCache(k=k_all, v=v_all, pos=kv_pos, length=cache.length + S)
+    new_cache = KVCache(k=k_all, v=v_all, pos=kv_pos, length=cache.length + S)
+    return h, new_cache, stats
 
 
 def scan_layers_paged(
@@ -80,8 +113,9 @@ def scan_layers_paged(
     k_arena: jnp.ndarray,  # [L, NB, Nkv, BS, D] pooled head-major blocks
     v_arena: jnp.ndarray,
     apply_layer,  # (p, l, valid, h, k_all, v_all, ks_all, vs_all) ->
-    #   (h, k_all, v_all, ks_all, vs_all) — the WHOLE stacks plus the
-    #   layer index; scale stacks are None unquantized
+    #   (h, k_all, v_all, ks_all, vs_all, stats) — the WHOLE stacks plus
+    #   the layer index; scale stacks are None unquantized, ``stats`` as in
+    #   ``scan_layers``
     layer_mask: Optional[jnp.ndarray] = None,
     k_scale: Optional[jnp.ndarray] = None,  # [L, NB, Nkv] f32 per-block-
     v_scale: Optional[jnp.ndarray] = None,  # per-head scales (quantized)
@@ -106,23 +140,24 @@ def scan_layers_paged(
     A QUANTIZED arena (int8/fp8 storage) carries its scale stacks through
     the same scan (``None`` leaves are empty pytree nodes, so the
     unquantized carry is unchanged). Returns ``(h, k_arena, v_arena,
-    k_scale, v_scale)`` — the scale outputs are None when the arena is
-    unquantized."""
+    k_scale, v_scale, stats)`` — the scale outputs are None when the arena
+    is unquantized."""
     L = k_arena.shape[0]
     if layer_mask is None:
         layer_mask = jnp.ones((L,), bool)
+    layers, whole = split_whole(layers)
 
     def body(carry, xs):
         h, k_all, v_all, ks_all, vs_all = carry
         p, l, valid = xs
-        h_new, k_all, v_all, ks_all, vs_all = apply_layer(
-            p, l, valid, h, k_all, v_all, ks_all, vs_all
+        h_new, k_all, v_all, ks_all, vs_all, stats = apply_layer(
+            join_whole(p, whole, l), l, valid, h, k_all, v_all, ks_all, vs_all
         )
         h = jnp.where(valid, h_new, h)
-        return (h, k_all, v_all, ks_all, vs_all), None
+        return (h, k_all, v_all, ks_all, vs_all), masked_stats(stats, valid)
 
-    (h, k_arena, v_arena, k_scale, v_scale), _ = jax.lax.scan(
+    (h, k_arena, v_arena, k_scale, v_scale), stats = jax.lax.scan(
         body, (h, k_arena, v_arena, k_scale, v_scale),
         (layers, jnp.arange(L, dtype=jnp.int32), layer_mask),
     )
-    return h, k_arena, v_arena, k_scale, v_scale
+    return h, k_arena, v_arena, k_scale, v_scale, stats

@@ -625,6 +625,22 @@ PREFILL_POSITIONS = REGISTRY.counter(
 )
 
 
+MOE_EXPERT_TOKENS = REGISTRY.counter(
+    "server_moe_expert_tokens_total",
+    "A model with sparse experts: (token, expert) pairs each expert "
+    "received from live rows and prompt positions, summed over layers "
+    "(dead rows of a slot and pad positions route nowhere and are not "
+    "counted). Uneven counts are uneven load",
+    labels=("expert",),
+)
+MOE_EXPERTS_READ = REGISTRY.gauge(
+    "server_moe_experts_read",
+    "A model with sparse experts: mean distinct experts read per layer per "
+    "decode microstep over the newest applied chunk log (k at one live "
+    "row, at most k x the live rows, never above the layer's experts)",
+)
+
+
 def set_prefill_path(path: str) -> None:
     """One-hot flip of ``server_prefill_path`` (the chunk-dispatch-site
     analogue of the ``server_attn_backend`` sweep)."""

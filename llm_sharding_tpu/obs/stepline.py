@@ -127,6 +127,9 @@ SCOPES = (
     "attn",       # the attention kernel / XLA attention and its GQA fold
     "o_proj",     # output projection, its psum, the residual add
     "mlp",        # gated MLP, its psum, the residual add
+    "router",     # a sparse MLP's router: float32 logits, softmax, top-k
+    "moe",        # a sparse MLP's expert product: tiles, the expert kernel
+                  # (``moe_experts``), the weighted sum, its counters
     "head",       # final norm + this stage's logit slice
     "sample",     # argmax assembly / per-row sampling over the logits
     "ring_hop",   # stage->stage ppermute and the last stage's broadcast
@@ -207,12 +210,14 @@ class StepRecord:
         "ts", "wall_s", "phases", "blocked_s", "idle_s", "unattributed_s",
         "rows", "tokens", "queued", "pending", "segments", "lock_waits",
         "exemplars", "prompt_tokens", "prefill_positions",
+        "expert_tokens", "experts_read", "expert_steps", "expert_rows",
     )
 
     def __init__(self, ts, wall_s, phases, blocked_s, idle_s,
                  unattributed_s, rows, tokens, queued, pending,
                  segments=None, lock_waits=None, exemplars=None,
-                 prompt_tokens=0, prefill_positions=0):
+                 prompt_tokens=0, prefill_positions=0, expert_tokens=None,
+                 experts_read=None, expert_steps=0, expert_rows=0):
         self.ts = ts
         self.wall_s = wall_s
         self.phases = phases
@@ -230,6 +235,15 @@ class StepRecord:
         # rows x positions the programs computed for them (padding included)
         self.prompt_tokens = prompt_tokens
         self.prefill_positions = prefill_positions
+        # a model with experts (None otherwise), from the counters applied
+        # in this step: tokens each expert received from LIVE rows and
+        # positions, decode and prefill, summed over layers ([E]); distinct
+        # experts read per layer, summed over the DECODE microsteps applied
+        # ([L]); how many microsteps those were and the live rows they held
+        self.expert_tokens = expert_tokens
+        self.experts_read = experts_read
+        self.expert_steps = expert_steps
+        self.expert_rows = expert_rows
 
     @property
     def host_s(self) -> float:
@@ -256,6 +270,11 @@ class StepRecord:
             "queued": self.queued,
             "pending": self.pending,
         }
+        if self.expert_tokens is not None:
+            d["expert_tokens"] = list(self.expert_tokens)
+            d["experts_read"] = list(self.experts_read)
+            d["expert_steps"] = self.expert_steps
+            d["expert_rows"] = self.expert_rows
         if self.segments is not None:
             d["segments"] = [list(s) for s in self.segments]
         if self.lock_waits is not None:
@@ -297,6 +316,7 @@ class StepProfiler:
         self._had_work = False  # the previous step began with work
         self._prompt_tokens = 0
         self._prefill_positions = 0
+        self._experts: Optional[list] = None  # [tokens, read, steps, rows]
         self._phases: Dict[str, float] = {}
         self._blocked_s = 0.0
         self._idle_s = 0.0
@@ -397,6 +417,7 @@ class StepProfiler:
         self._idle_s = 0.0
         self._prompt_tokens = 0
         self._prefill_positions = 0
+        self._experts = None
         work = bool(rows or queued or pending)
         if self._annotate is not None and (work or self._had_work):
             self._step_span = self._annotate(
@@ -494,6 +515,22 @@ class StepProfiler:
         finally:
             self._exit(span)
 
+    def experts(self, tokens, read=None, steps: int = 0, rows: int = 0) -> None:
+        """Add a fetched set of expert counters to the step's record:
+        ``tokens`` [E] per expert; for decode microsteps also ``read`` [L]
+        distinct experts per layer, how many microsteps and their live
+        rows. A prefill dispatch passes ``tokens`` alone."""
+        if not self._enabled or self._t0 is None:
+            return
+        if self._experts is None:
+            self._experts = [[0] * len(tokens), None, 0, 0]
+        acc = self._experts
+        acc[0] = [a + int(b) for a, b in zip(acc[0], tokens)]
+        if read is not None:
+            acc[1] = [a + int(b) for a, b in zip(acc[1] or [0] * len(read), read)]
+            acc[2] += int(steps)
+            acc[3] += int(rows)
+
     def idle(self, dt: float) -> None:
         """Account an estimated device-idle bubble (log landed on host at
         T, next dispatch at T+dt). Host time, not excluded from phases."""
@@ -549,6 +586,9 @@ class StepProfiler:
             exemplars=self._exemplars, prompt_tokens=self._prompt_tokens,
             prefill_positions=self._prefill_positions,
         )
+        if self._experts is not None:
+            tokens, read, rec.expert_steps, rec.expert_rows = self._experts
+            rec.expert_tokens, rec.experts_read = tokens, read or []
         with self._ring_mu:
             if len(self._ring) < self._ring_size:
                 self._ring.append(rec)

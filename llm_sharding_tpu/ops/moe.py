@@ -1,0 +1,390 @@
+"""Sparse experts: the router and the expert product of a mixture-of-experts
+MLP (OLMoE: ``E`` gated MLPs of width ``F`` a layer, ``k`` a token).
+
+A layer's experts are ONE block-sparse MLP of width ``E·F``: ``we_gate``,
+``we_up`` ``[H, E·F]`` and ``we_down`` ``[E·F, H]``, expert ``e`` being
+columns (rows) ``e·F … (e+1)·F`` — plain 2-D matmul leaves, so quantisation,
+stacking over layers, the shard store and the ring split treat them like any
+other weight (``ops/quant.QTensor``: int8 codes, one scale per output
+channel). What is new is that a step reads a SUBSET of them chosen at run
+time: a decode step of four rows touches 8-32 of 64 experts, and reading all
+of them would cost five times the bytes.
+
+**The router** (``route``): logits ``x · router`` multiplied out in float32
+(a bf16 router flips the top-k between near ties), softmax over ALL experts,
+the ``k`` largest kept as they are — renormalised to sum 1 only under
+``norm_topk_prob``.
+
+**The expert product** (``expert_mlp``) runs as row TILES, each tile one
+expert: ``y_tile = (silu(x_tile · Wg_e) ⊙ (x_tile · Wu_e)) · Wd_e``. Two
+regimes build the tiles, one kernel runs them:
+
+- *decode* (a handful of rows): one tile per DISTINCT expert the live rows
+  chose, every tile holding all the rows; the rows' outputs are then summed
+  with their router weights (zero where a row did not choose the expert).
+  Each distinct expert of a layer is read once.
+- *prefill* (hundreds of positions): (token, expert) pairs sorted by expert
+  and padded per expert to whole tiles of ``TILE_ROWS`` — a grouped matmul;
+  consecutive tiles of one expert reuse the fetched weights.
+
+The Pallas kernel takes the whole LAYER-STACKED weights and reads the
+``(H, F)`` / ``(F, H)`` int8 tiles of expert ``tile_expert[i]`` of layer
+``layer`` through scalar-prefetched indices — the stack is never sliced,
+and a tile past the live count re-names the last live tile's blocks, so it
+costs no DMA and its compute is skipped. The XLA path (``backend="xla"``:
+the CPU tests, and the dense-cache oracle paths) gathers the same tiles and
+does the same arithmetic.
+
+**Dead rows and pad positions route nowhere** (``live``): they form no pair,
+are neither read for nor counted, and get a zero MLP output. No token is
+dropped and there is no capacity factor.
+
+``stats``: ``expert_tokens [E]`` — (live token, expert) pairs per expert —
+and ``experts_read`` — distinct experts the layer read (scalar).
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .quant import QTensor
+
+#: rows of one grouped-matmul tile in the prefill regime
+TILE_ROWS = 128
+#: at most this many rows (B·S) run the decode regime (one tile per
+#: distinct expert); more are grouped by expert
+DECODE_ROWS_MAX = 32
+#: columns of an expert's width one kernel step handles (VMEM: three int8
+#: blocks of H x F_CHUNK, double-buffered, plus their converted forms)
+F_CHUNK = 512
+_VMEM_LIMIT = 64 * 1024 * 1024
+
+BACKENDS = ("auto", "kernel", "xla", "interpret")
+
+
+class MoeStats(NamedTuple):
+    expert_tokens: jax.Array  # [E] int32 (live token, expert) pairs
+    experts_read: jax.Array  # scalar int32 distinct experts read
+
+
+def route(x, router, top_k: int, renormalize: bool = False):
+    """``x [N, H]``, ``router [H, E]`` → ``(weights [N, k] f32, ids [N, k])``:
+    float32 softmax over all experts, the ``top_k`` largest kept as they are
+    (renormalised only when asked)."""
+    logits = jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    probs = jax.nn.softmax(logits, axis=-1)
+    w, ids = jax.lax.top_k(probs, top_k)
+    if renormalize:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return w, ids.astype(jnp.int32)
+
+
+# ------------------------------------------------------------------ tiles
+
+class Tiles(NamedTuple):
+    """Row tiles for the expert product."""
+
+    x: jax.Array  # [R, tm, H] distinct row tiles
+    row: jax.Array  # [NT] int32 which row tile each tile reads
+    expert: jax.Array  # [NT] int32 the tile's expert
+    n_live: jax.Array  # scalar int32 tiles before this index are real
+
+
+def _clamp_tail(values, n_live):
+    """Tiles past ``n_live`` name what the last live tile names (no new DMA)."""
+    idx = jnp.minimum(
+        jnp.arange(values.shape[0], dtype=jnp.int32),
+        jnp.maximum(n_live - 1, 0),
+    )
+    return values[idx]
+
+
+def _decode_tiles(x, w, ids, live, E):
+    """One tile per distinct expert of the live rows, all rows in each.
+    Returns ``(tiles, combine [NT, N] f32, counts [E])``."""
+    N, H = x.shape
+    k = ids.shape[1]
+    onehot = (ids[:, :, None] == jnp.arange(E, dtype=jnp.int32)) & live[
+        :, None, None
+    ]  # [N, k, E]
+    comb = jnp.sum(jnp.where(onehot, w[:, :, None], 0.0), axis=1)  # [N, E]
+    counts = jnp.sum(onehot, axis=(0, 1)).astype(jnp.int32)  # [E]
+    hit = counts > 0
+    n_live = jnp.sum(hit).astype(jnp.int32)
+    NT = min(E, N * k)
+    # the hit experts first, in ascending order (a stable sort of ~hit)
+    order = jnp.argsort(~hit, stable=True).astype(jnp.int32)[:NT]
+    expert = _clamp_tail(order, n_live)
+    alive = jnp.arange(NT, dtype=jnp.int32) < n_live
+    cw = jnp.where(alive[:, None], comb.T[expert], 0.0)  # [NT, N]
+    pad = -N % 8
+    xt = jnp.pad(x, ((0, pad), (0, 0)))[None]  # [1, N + pad, H]
+    tiles = Tiles(xt, jnp.zeros((NT,), jnp.int32), expert, n_live)
+    return tiles, cw, counts
+
+
+def _grouped_tiles(x, ids, live, E, tm):
+    """(token, expert) pairs sorted by expert, each expert's run padded to
+    whole tiles of ``tm`` rows. Returns ``(tiles, pos [N, k], counts [E])``:
+    ``pos`` is each pair's row in the flattened tile output (dead pairs
+    point at a row whose weight the caller zeroes)."""
+    N, H = x.shape
+    k = ids.shape[1]
+    P = N * k
+    flat_e = jnp.where(live[:, None], ids, E).reshape(P)  # dead pairs last
+    flat_tok = jnp.repeat(jnp.arange(N, dtype=jnp.int32), k)
+    order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
+    sorted_e = flat_e[order]
+    counts = jnp.sum(
+        flat_e[:, None] == jnp.arange(E, dtype=jnp.int32), axis=0
+    ).astype(jnp.int32)
+    tiles_per = (counts + tm - 1) // tm
+    tile_end = jnp.cumsum(tiles_per)
+    tile_start = tile_end - tiles_per
+    group_start = jnp.cumsum(counts) - counts
+    NT = -(-P // tm) + E  # sum of ceil(c_e / tm) never exceeds this
+    n_live = tile_end[-1].astype(jnp.int32)
+    # padded row of each sorted pair: its expert's first tile + its rank
+    e_safe = jnp.minimum(sorted_e, E - 1)
+    rank = jnp.arange(P, dtype=jnp.int32) - group_start[e_safe]
+    pos_sorted = jnp.where(
+        sorted_e < E, tile_start[e_safe] * tm + rank, NT * tm
+    )  # dead pairs scatter out of range (dropped)
+    src = jnp.full((NT * tm,), N, jnp.int32).at[pos_sorted].set(
+        flat_tok[order], mode="drop"
+    )
+    x_ext = jnp.concatenate([x, jnp.zeros((1, H), x.dtype)], axis=0)
+    xt = x_ext[src].reshape(NT, tm, H)
+    pos = jnp.zeros((P,), jnp.int32).at[order].set(
+        jnp.minimum(pos_sorted, NT * tm - 1)
+    ).reshape(N, k)
+    expert = jnp.minimum(
+        jnp.searchsorted(
+            tile_end, jnp.arange(NT, dtype=jnp.int32), side="right"
+        ).astype(jnp.int32),
+        E - 1,
+    )
+    expert = _clamp_tail(expert, n_live)
+    tiles = Tiles(xt, jnp.arange(NT, dtype=jnp.int32), expert, n_live)
+    return tiles, pos, counts
+
+
+# --------------------------------------------------------------- the product
+
+def _leaf(w, layer):
+    """``(codes or raw weight, scale)`` of a maybe-quantised leaf, both
+    layer-stacked: a leaf handed over already sliced (``layer`` None — the
+    block called on one layer's params) becomes a stack of one, and a raw
+    weight's scale is one."""
+    q, s = (w.q, w.scale) if isinstance(w, QTensor) else (w, None)
+    if layer is None:
+        q, s = q[None], None if s is None else s[None]
+    if s is None:
+        s = jnp.ones((q.shape[0], q.shape[-1]), jnp.float32)
+    return q, s
+
+
+def _tiles_xla(tiles: Tiles, layer, wg, wu, wd, sg, su, E, out_dtype):
+    """The kernel's arithmetic in plain XLA: gather each tile's expert."""
+    x = tiles.x[tiles.row]  # [NT, tm, H]
+    H = x.shape[-1]
+    F = wg.shape[-1] // E
+
+    def cols(w, s):  # [L, H, E·F] → the tiles' [NT, H, F]
+        wl = jax.lax.dynamic_index_in_dim(w, layer, keepdims=False)
+        wt = jnp.moveaxis(wl.reshape(H, E, F), 1, 0)[tiles.expert]
+        sl = jax.lax.dynamic_index_in_dim(s, layer, keepdims=False)
+        return wt.astype(x.dtype), sl.reshape(E, F)[tiles.expert][:, None, :]
+
+    g_w, g_s = cols(wg, sg)
+    u_w, u_s = cols(wu, su)
+    d_w = jax.lax.dynamic_index_in_dim(wd, layer, keepdims=False).reshape(
+        E, F, H
+    )[tiles.expert].astype(x.dtype)
+    f32 = jnp.float32
+    g = jnp.einsum("jth,jhf->jtf", x, g_w, preferred_element_type=f32) * g_s
+    u = jnp.einsum("jth,jhf->jtf", x, u_w, preferred_element_type=f32) * u_s
+    a = (jax.nn.silu(g) * u).astype(x.dtype)
+    y = jnp.einsum("jtf,jfh->jth", a, d_w, preferred_element_type=f32)
+    alive = jnp.arange(y.shape[0], dtype=jnp.int32) < tiles.n_live
+    return jnp.where(alive[:, None, None], y, 0.0).astype(out_dtype)
+
+
+def _expert_kernel(lyr, texp, trow, nlive, x_ref, wg_ref, wu_ref, wd_ref,
+                   sg_ref, su_ref, o_ref, acc_ref, *, n_f):
+    i, f = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(f == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(i < nlive[0])
+    def _tile():
+        x = x_ref[...]
+        f32 = jnp.float32
+        g = jnp.dot(
+            x, wg_ref[...].astype(x.dtype), preferred_element_type=f32
+        ) * sg_ref[...].astype(f32)
+        u = jnp.dot(
+            x, wu_ref[...].astype(x.dtype), preferred_element_type=f32
+        ) * su_ref[...].astype(f32)
+        a = (g * jax.nn.sigmoid(g) * u).astype(x.dtype)
+        acc_ref[...] += jnp.dot(
+            a, wd_ref[...].astype(x.dtype), preferred_element_type=f32
+        )
+
+    @pl.when(f == n_f - 1)
+    def _finish():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("E", "out_dtype", "interpret"))
+def expert_tiles_tpu(tiles: Tiles, layer, wg, wu, wd, sg, su, *, E: int,
+                     out_dtype, interpret: bool = False):
+    """The Pallas expert product: grid ``(tiles, F / F_CHUNK)``, the second
+    axis accumulating a tile's output over slices of the expert's width.
+    Weights are the layer-stacked ``[L, H, E·F]`` / ``[L, E·F, H]`` arrays,
+    read where they lie."""
+    R, tm, H = tiles.x.shape
+    NT = tiles.row.shape[0]
+    F = wg.shape[-1] // E
+    fc = min(F, F_CHUNK)
+    if F % fc:
+        raise ValueError(f"F_CHUNK {fc} does not divide the expert width {F}")
+    n_f = F // fc
+    # the layer's row of each scale stack, sliced here: 128 KB, where handing
+    # the kernel a [L, 1, E·F] view of the stack made XLA re-lay the whole
+    # 2 MiB stack out per layer and step (18 us each, a third of this scope)
+    sg2, su2 = (
+        jax.lax.dynamic_index_in_dim(s, layer, keepdims=True) for s in (sg, su)
+    )
+    def fblock(i, f, texp, nlive):
+        # a dead tile stays on the last block the last live tile fetched
+        f_eff = jnp.where(i < nlive[0], f, n_f - 1)
+        return texp[i] * n_f + f_eff
+
+    def col_map(i, f, lyr, texp, trow, nlive):
+        return (lyr[0], 0, fblock(i, f, texp, nlive))
+
+    def scale_map(i, f, lyr, texp, trow, nlive):
+        return (0, fblock(i, f, texp, nlive))
+
+    def row_map(i, f, lyr, texp, trow, nlive):
+        return (lyr[0], fblock(i, f, texp, nlive), 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(NT, n_f),
+        in_specs=[
+            pl.BlockSpec(
+                (None, tm, H),
+                lambda i, f, lyr, texp, trow, nlive: (trow[i], 0, 0),
+            ),
+            pl.BlockSpec((None, H, fc), col_map),
+            pl.BlockSpec((None, H, fc), col_map),
+            pl.BlockSpec((None, fc, H), row_map),
+            pl.BlockSpec((1, fc), scale_map),
+            pl.BlockSpec((1, fc), scale_map),
+        ],
+        out_specs=pl.BlockSpec(
+            (None, tm, H), lambda i, f, lyr, texp, trow, nlive: (i, 0, 0)
+        ),
+        scratch_shapes=[pltpu.VMEM((tm, H), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_expert_kernel, n_f=n_f),
+        out_shape=jax.ShapeDtypeStruct((NT, tm, H), out_dtype),
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
+        interpret=interpret,
+        name="moe_experts",
+    )(
+        jnp.reshape(layer, (1,)).astype(jnp.int32), tiles.expert, tiles.row,
+        jnp.reshape(tiles.n_live, (1,)), tiles.x, wg, wu, wd, sg2, su2,
+    )
+
+
+def resolve_backend(backend: str) -> str:
+    """``auto`` follows ``PAGED_FORCE_KERNEL`` like the paged attention ops,
+    then the platform: the kernel on a TPU, XLA elsewhere."""
+    from .paged_attention import forced_backend
+
+    if backend not in BACKENDS:
+        raise ValueError(
+            f"moe backend {backend!r}: expected one of {BACKENDS}"
+        )
+    if backend == "auto":
+        backend = forced_backend() or (
+            "kernel" if jax.default_backend() == "tpu" else "xla"
+        )
+    if backend == "kernel" and jax.default_backend() != "tpu":
+        raise ValueError(
+            "moe backend 'kernel' requires a TPU backend (got "
+            f"{jax.default_backend()}); use backend='interpret' to emulate "
+            "the kernel or 'xla'"
+        )
+    return backend
+
+
+def expert_mlp(
+    x,  # [N, H]
+    weights,  # [N, k] f32 router weights of the chosen experts
+    ids,  # [N, k] int32 the chosen experts
+    we_gate, we_up, we_down,  # [H, E·F] / [E·F, H], or layer-stacked [L, ..]
+    num_experts: int,
+    live=None,  # [N] bool — rows that route; None = all
+    layer=None,  # scalar int32 index into layer-stacked weights; None = 2-D
+    backend: str = "auto",
+):
+    """``Σ_k weights[n, k] · MLP_{ids[n, k]}(x[n])`` for the live rows (zero
+    for the others) and the layer's ``MoeStats``."""
+    N, H = x.shape
+    E = num_experts
+    backend = resolve_backend(backend)
+    if live is None:
+        live = jnp.ones((N,), bool)
+    (wg, sg), (wu, su), (wd, sd) = (
+        _leaf(w, layer) for w in (we_gate, we_up, we_down)
+    )
+    lyr = jnp.zeros((), jnp.int32) if layer is None else layer
+    decode = N <= DECODE_ROWS_MAX
+    with jax.named_scope("moe"):
+        if decode:
+            tiles, cw, counts = _decode_tiles(x, weights, ids, live, E)
+        else:
+            tiles, pos, counts = _grouped_tiles(x, ids, live, E, TILE_ROWS)
+        out_dtype = jnp.float32 if decode else x.dtype
+        if backend == "xla":
+            y = _tiles_xla(tiles, lyr, wg, wu, wd, sg, su, E, out_dtype)
+        else:
+            y = expert_tiles_tpu(
+                tiles, lyr, wg, wu, wd, sg, su, E=E, out_dtype=out_dtype,
+                interpret=backend == "interpret",
+            )
+        if decode:
+            out = jnp.einsum(
+                "jn,jnh->nh", cw, y[:, :N].astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST,
+            )
+        else:
+            picked = y.reshape(-1, H)[pos].astype(jnp.float32)  # [N, k, H]
+            w_live = jnp.where(live[:, None], weights, 0.0)
+            out = jnp.sum(picked * w_live[:, :, None], axis=1)
+        # we_down's scale: one per output channel, every expert's alike
+        out = out * jax.lax.dynamic_index_in_dim(
+            sd, lyr, keepdims=False
+        ).astype(jnp.float32)
+        stats = MoeStats(counts, jnp.sum(counts > 0).astype(jnp.int32))
+        return out.astype(x.dtype), stats

@@ -169,7 +169,13 @@ def tied_logits(x: jnp.ndarray, table: WeightLike) -> jnp.ndarray:
 
 # Layer-weight keys quantized by default: the matmul weights. Norm gains and
 # biases stay in the model dtype (tiny, precision-critical).
-LLAMA_QUANT_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+# A model with experts (OLMoE) carries its expert matrices ``we_*`` in place
+# of the dense MLP's; its router stays in the model dtype (it is multiplied
+# out in float32 — a coarser router flips the top-k between near ties).
+LLAMA_QUANT_KEYS = (
+    "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
+    "we_gate", "we_up", "we_down",
+)
 GPT2_QUANT_KEYS = ("w_qkv", "w_out", "w_fc", "w_proj")
 
 

@@ -49,7 +49,7 @@ def _ctx_layer(cfg: ModelConfig, p: Any, h, cos, sin, q_pos, kv_pos):
     if cfg.model_type == "llama":
         from ..models.llama import attn_mlp_block
 
-        h = attn_mlp_block(cfg, p, h, cos, sin, attn_fn)
+        h, _ = attn_mlp_block(cfg, p, h, cos, sin, attn_fn)
     else:  # gpt2: nothing positional inside the layers (wpe added at embed)
         from ..models.gpt2 import attn_mlp_block
 

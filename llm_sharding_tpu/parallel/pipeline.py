@@ -98,13 +98,22 @@ def model_fns(
     else:
         raise ValueError(f"unsupported model_type: {cfg.model_type!r}")
 
-    def stage(cfg_, layers, h, cache, positions, mask):
-        return fwd(cfg_, layers, h, cache, positions, mask, tp_axis=tp_axis)
+    # ``moe_live`` ([B, S] bool; a model with experts only): the positions
+    # that route. Both stage fns return the layers' stats as their LAST
+    # result (``MoeStats``; None for a dense model — models/stack.py).
+    def stage(cfg_, layers, h, cache, positions, mask, moe_live=None):
+        kw = {} if moe_live is None else {"moe_live": moe_live}
+        return fwd(
+            cfg_, layers, h, cache, positions, mask, tp_axis=tp_axis, **kw
+        )
 
     def stage_paged(cfg_, layers, h, k_arena, v_arena, tbl, cols, kv_pos,
                     positions, mask, write_valid=True, backend="auto",
-                    k_scale=None, v_scale=None, prefill=False, nlive=None):
+                    k_scale=None, v_scale=None, prefill=False, nlive=None,
+                    moe_live=None):
         kw = {} if cp_axis is None else {"cp_axis": cp_axis}
+        if moe_live is not None:
+            kw["moe_live"] = moe_live
         return fwd_paged(
             cfg_, layers, h, k_arena, v_arena, tbl, cols, kv_pos,
             positions, mask, write_valid=write_valid, tp_axis=tp_axis,
@@ -169,30 +178,55 @@ def _tree_where(pred, new, old):
     return jax.tree.map(lambda a, b: jnp.where(pred, a, b), new, old)
 
 
-def ring_chain(fns, cfg, layers, lmask, sidx, ring, num_stages, h, cache, positions):
+def moe_stats_zero(cfg: ModelConfig, num_layers: int):
+    """An all-zero ``MoeStats`` stacked over ``num_layers`` layers: what the
+    ring chains start their sums from. None for a dense model (an empty
+    pytree: no leaf joins its loop carry)."""
+    from ..ops.moe import MoeStats
+
+    if not cfg.num_experts:
+        return None
+    return MoeStats(
+        jnp.zeros((num_layers, cfg.num_experts), jnp.int32),
+        jnp.zeros((num_layers,), jnp.int32),
+    )
+
+
+def ring_chain(fns, cfg, layers, lmask, sidx, ring, num_stages, h, cache,
+               positions, moe_live=None):
     """One full trip around the ring: each stage applies its layer slice on
     its active microstep, then the block hops to the next device
     (≙ one traversal of the reference's device chain,
     ``node_worker.py:541-543``). Shared by the sequential pipeline and the
-    interleaved scheduler's prefill."""
+    interleaved scheduler's prefill. Returns ``(h, cache, stats)``: this
+    stage's ``MoeStats`` of its active microstep, stacked over its layers
+    (None for a dense model); ``moe_live`` names the positions that route."""
 
     def micro(m, carry):
-        h, cache = carry
-        h_new, cache_new = fns.stage(cfg, layers, h, cache, positions, lmask)
+        h, cache, stats = carry
+        h_new, cache_new, stats_new = fns.stage(
+            cfg, layers, h, cache, positions, lmask, moe_live=moe_live
+        )
         active = m == sidx
         h = jnp.where(active, h_new, h)
         cache = _tree_where(active, cache_new, cache)
         with jax.named_scope("ring_hop"):
             h = jax.lax.ppermute(h, PIPE_AXIS, ring)
-        return h, cache
+        # only the active microstep's pass over the layers counts
+        stats = jax.tree.map(
+            lambda t, n: t + jnp.where(active, n, 0), stats, stats_new
+        )
+        return h, cache, stats
 
-    return jax.lax.fori_loop(0, num_stages, micro, (h, cache))
+    return jax.lax.fori_loop(
+        0, num_stages, micro, (h, cache, moe_stats_zero(cfg, lmask.shape[0]))
+    )
 
 
 def ring_chain_paged(fns, cfg, layers, lmask, sidx, ring, num_stages, h,
                      k_arena, v_arena, tbl, cols, kv_positions, positions,
                      backend="auto", k_scale=None, v_scale=None,
-                     prefill=False, nlive=None):
+                     prefill=False, nlive=None, moe_live=None):
     """``ring_chain`` over the pooled paged arena (the serve programs'
     kernel decode path): the per-microstep activity gate moves from a
     whole-cache ``_tree_where`` (which would copy the ARENA — the whole
@@ -201,28 +235,33 @@ def ring_chain_paged(fns, cfg, layers, lmask, sidx, ring, num_stages, h,
     arena update writes back the values it just read. The hidden-state
     gate is unchanged. Quantized arenas carry their scale arenas through
     the loop (None carries are empty pytree nodes — the bf16 path is
-    unchanged); returns ``(h, k_arena, v_arena, k_scale, v_scale)``.
+    unchanged); returns ``(h, k_arena, v_arena, k_scale, v_scale, stats)``.
     ``prefill`` (static) runs the traversal as a CHUNKED-PREFILL one:
     chunk-shaped queries attend through the query-tiled
     ``paged_prefill`` kernel, with ``nlive`` clamping its per-row KV
     streaming to the written frontier — the ``stage_paged``-style
-    prefill traversal behind ``serve_prefill_chunk``."""
+    prefill traversal behind ``serve_prefill_chunk``. The sixth result is
+    this stage's ``MoeStats`` as in ``ring_chain`` (an inactive microstep
+    routes nowhere and counts nothing)."""
 
     def micro(m, carry):
-        h, ka, va, ks, vs = carry
+        h, ka, va, ks, vs, stats = carry
         active = m == sidx
-        h_new, ka, va, ks, vs = fns.stage_paged(
+        h_new, ka, va, ks, vs, stats_new = fns.stage_paged(
             cfg, layers, h, ka, va, tbl, cols, kv_positions, positions,
             lmask, write_valid=active, backend=backend,
             k_scale=ks, v_scale=vs, prefill=prefill, nlive=nlive,
+            moe_live=moe_live,
         )
         h = jnp.where(active, h_new, h)
         with jax.named_scope("ring_hop"):
             h = jax.lax.ppermute(h, PIPE_AXIS, ring)
-        return h, ka, va, ks, vs
+        return h, ka, va, ks, vs, jax.tree.map(jnp.add, stats, stats_new)
 
     return jax.lax.fori_loop(
-        0, num_stages, micro, (h, k_arena, v_arena, k_scale, v_scale)
+        0, num_stages, micro,
+        (h, k_arena, v_arena, k_scale, v_scale,
+         moe_stats_zero(cfg, lmask.shape[0])),
     )
 
 
@@ -346,7 +385,7 @@ def _pipeline_generate_jit(
         def chain(h, cache, positions):
             return ring_chain(
                 fns, cfg, layers, mask, sidx, ring, num_stages, h, cache, positions
-            )
+            )[:2]
 
         # ---- prefill (≙ receive_user_request → chain traversal,
         # node_worker.py:188-272) ----

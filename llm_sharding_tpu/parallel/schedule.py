@@ -121,7 +121,7 @@ def _interleaved_jit(
             idx[None, :] < prompt_len[:, None], idx[None, :], POS_SENTINEL
         )
         h = sp_embed(cfg, hd, prompts, positions)
-        h, cache = ring_chain(
+        h, cache, _ = ring_chain(
             fns, cfg, layers, lmask, sidx, ring, num_stages, h, cache, positions
         )
         # full-depth block landed on stage 0; assemble the first token for
@@ -205,7 +205,7 @@ def _interleaved_jit(
                 pos=jax.lax.dynamic_slice_in_dim(s["cache"].pos, row0, Bs, axis=0),
                 length=off_r,
             )
-            h_new, cache_r_new = fns.stage(
+            h_new, cache_r_new, _ = fns.stage(
                 cfg, layers, h_in, cache_r, pos_rows[:, None], lmask
             )
             # Commit the slot cache UNCONDITIONALLY — a ramp-in garbage write

@@ -222,6 +222,34 @@ def _scatter_pages_q(arena, scale, tbl, window, block_size):
     return arena.at[:, tbl].set(q), scale.at[:, tbl].set(sc)
 
 
+def moe_log_width(cfg: ModelConfig, num_stages: int, layers_per_stage: int) -> int:
+    """Columns a model with experts appends to what the host already
+    fetches (``serve_chunk``'s log rows, ``serve_admit``'s first tokens):
+    tokens per expert ``[E]``, distinct experts read per layer slot
+    ``[S·Lp]``, and the live rows (positions) they came from. 0 for a model
+    without experts: its programs return what they always did."""
+    if not cfg.num_experts:
+        return 0
+    return cfg.num_experts + num_stages * layers_per_stage + 1
+
+
+def _moe_counts(stats, live, sidx, num_stages):
+    """One stage's ``MoeStats`` (stacked over its layers) and its live
+    ``[B, S]`` mask → the replicated ``[E + S·Lp + 1]`` int32 vector of
+    ``moe_log_width``, summed over the ring."""
+    with jax.named_scope("moe"):
+        Lp = stats.experts_read.shape[0]
+        read = jax.lax.dynamic_update_slice(
+            jnp.zeros((num_stages * Lp,), jnp.int32),
+            stats.experts_read.astype(jnp.int32), (sidx * Lp,),
+        )
+        vec = jnp.concatenate([
+            jnp.sum(stats.expert_tokens, axis=0).astype(jnp.int32), read,
+            jnp.sum(live).astype(jnp.int32)[None],
+        ])
+        return jax.lax.psum(vec, PIPE_AXIS)
+
+
 def _slot_tables(st, row0, Bs):
     return jax.lax.dynamic_slice_in_dim(st.block_tables, row0, Bs, axis=0)
 
@@ -393,9 +421,10 @@ def prefix_prefill(
             idx[None, :] < prefix_len, idx[None, :], POS_SENTINEL
         )
         h = sp_embed(cfg, hd, prefix, positions)
-        _, cache = ring_chain(
+        _, cache, _ = ring_chain(
             fns, cfg, layers, lmask, sidx, ring, num_stages, h, cache,
             positions,
+            moe_live=(positions != POS_SENTINEL) if cfg.num_experts else None,
         )
         return cache.k[None], cache.v[None], cache.pos[None]
 
@@ -605,7 +634,9 @@ def serve_admit(
     Returns ``(state, tok0)``: the first generated token per row, sampled at
     admission — the host appends it to the request and mirrors lengths/done
     from it, so steady-state serving needs NO bookkeeping fetches (see
-    ``serve_chunk``'s log).
+    ``serve_chunk``'s log). A model with experts appends the prefill's
+    ``moe_log_width`` counters to ``tok0`` (pads and free rows route nowhere
+    and count nothing).
 
     With ``prompt_embeds`` the admission skips the vocab-parallel embedding
     lookup and enters the ring with caller-provided hidden states (≙ the
@@ -703,8 +734,15 @@ def serve_admit(
             h = sp_embed(cfg, hd, prompts, positions)
         else:
             h = prompt_embeds
-        h, cache = ring_chain(
-            fns, cfg, layers, lmask, sidx, ring, num_stages, h, cache, positions
+        # a model with experts: pads and free rows route nowhere, neither
+        # read for nor counted
+        moe_live = (
+            (positions != POS_SENTINEL) & row_valid[:, None]
+            if cfg.num_experts else None
+        )
+        h, cache, moe_stats = ring_chain(
+            fns, cfg, layers, lmask, sidx, ring, num_stages, h, cache,
+            positions, moe_live=moe_live,
         )
         h_last = jnp.take_along_axis(
             h, (prompt_len - 1)[:, None, None], axis=1
@@ -837,6 +875,11 @@ def serve_admit(
             lambda spec, leaf: leaf[None] if _dev(spec) else leaf,
             state_specs(state, tp, cp, quantized, bool(block_size)), new,
         )
+        if moe_stats is not None:
+            # the experts' counters ride the array the host fetches anyway
+            tok0 = jnp.concatenate(
+                [tok0, _moe_counts(moe_stats, moe_live, sidx, num_stages)]
+            )
         return new, tok0
 
     specs = state_specs(
@@ -946,6 +989,10 @@ def serve_prefill_chunk(
     for a quantized arena that also keeps their codes+scales byte-stable
     under concurrent readers, the same argument as ``serve_admit``'s
     ``prefix_in_arena``.
+
+    Returns the state; for a model with experts ``(state, counts)`` with the
+    chunk's ``moe_log_width`` counters — a device array the host need not
+    wait for (it reads it once a later fetch has shown the chunk done).
     """
     fns = model_fns(
         cfg, tp_axis=TENSOR_AXIS if tp > 1 else None,
@@ -970,6 +1017,8 @@ def serve_prefill_chunk(
         )
         row0 = slot * Bs
         col0 = prefix_off + chunk_off  # absolute cache column of the chunk
+        # a model with experts: pad positions (sentinel) route nowhere
+        moe_live = (positions != POS_SENTINEL) if cfg.num_experts else None
         p_rows = jax.lax.dynamic_slice_in_dim(st.kpos, row0, Bs, axis=0)
         W = p_rows.shape[1]
         scale_upd = {}
@@ -1015,10 +1064,11 @@ def serve_prefill_chunk(
                 (col0 + Sc + block_size - 1) // block_size, (Bs,)
             ).astype(jnp.int32)
             h = sp_embed(cfg, hd, tokens, positions)
-            h, k_new, v_new, ks_new, vs_new = ring_chain_paged(
+            h, k_new, v_new, ks_new, vs_new, moe_stats = ring_chain_paged(
                 fns, cfg, layers, lmask, sidx, ring, num_stages, h,
                 st.k, st.v, tbl, cols, kv_pos, positions, backend=attn,
                 k_scale=ks, v_scale=vs, prefill=True, nlive=nlive,
+                moe_live=moe_live,
             )
             if quantized:
                 scale_upd = {"k_scale": ks_new, "v_scale": vs_new}
@@ -1037,9 +1087,9 @@ def serve_prefill_chunk(
                 length=chunk_off,
             )
             h = sp_embed(cfg, hd, tokens, positions)
-            h, cache = ring_chain(
+            h, cache, moe_stats = ring_chain(
                 fns, cfg, layers, lmask, sidx, ring, num_stages, h, cache,
-                positions,
+                positions, moe_live=moe_live,
             )
             k_new = jax.lax.dynamic_update_slice_in_dim(
                 st.k, cache.k, row0, axis=1
@@ -1064,10 +1114,13 @@ def serve_prefill_chunk(
             k=k_new, v=v_new, kpos=kpos_new, write_off=write_off, out=out,
             **scale_upd,
         )
-        return jax.tree.map(
+        new = jax.tree.map(
             lambda spec, leaf: leaf[None] if _dev(spec) else leaf,
             state_specs(state, tp, cp, quantized, bool(block_size)), new,
         )
+        if moe_stats is not None:
+            return new, _moe_counts(moe_stats, moe_live, sidx, num_stages)
+        return new
 
     specs = state_specs(
         ServeState(*([None] * len(ServeState._fields))), tp, cp, quantized,
@@ -1081,7 +1134,7 @@ def serve_prefill_chunk(
             head_specs(head_params), specs,
             P(), P(), P(), P(), P(), P(),
         ),
-        out_specs=specs,
+        out_specs=(specs, P()) if cfg.num_experts else specs,
         check_vma=False,
     )(stage_layers, layer_masks, head_params, state, tokens, positions,
       slot, chunk_off, reset, jnp.asarray(prefix_off, jnp.int32))
@@ -1251,7 +1304,9 @@ def serve_chunk(
     ``(m - (S-1)) mod S`` (the host mirrors ``m``), so lengths/done are
     reconstructed host-side from a few hundred bytes instead of fetching the
     bookkeeping arrays — each fetch is a blocking device→host sync, and
-    r3's step paid three of them.
+    r3's step paid three of them. A model with experts appends
+    ``moe_log_width`` columns to each log row: that microstep's tokens per
+    expert and distinct experts read per layer, from LIVE rows only.
 
     ``sampling`` statically selects the token-selection path: False compiles
     pure greedy (no per-row key splits, no full-vocab noise regeneration —
@@ -1316,6 +1371,10 @@ def serve_chunk(
             valid_now = injecting | s.h_valid
             slot_active = ~jnp.all(done_served)
             advance = valid_now & slot_active
+            # a model with experts: the slot's dead rows route nowhere
+            moe_live = (
+                (advance & ~done_served)[:, None] if cfg.num_experts else None
+            )
 
             # Unconditional commit: a garbage write lands at an offset the
             # next real serve overwrites (offsets only advance on `advance`).
@@ -1353,13 +1412,14 @@ def serve_chunk(
                 kv_pos = jax.lax.dynamic_update_slice(
                     kpos_rows, pos_rows[:, None], (0, off_r)
                 )
-                h_new, k_st, v_st, ks_st, vs_st = fns.stage_paged(
+                h_new, k_st, v_st, ks_st, vs_st, moe_stats = fns.stage_paged(
                     cfg, layers, h_in, s.k, s.v, tbl_r,
                     jnp.broadcast_to(off_r, (Bs, 1)), kv_pos,
                     pos_rows[:, None], lmask, write_valid=advance,
                     backend=attn,
                     k_scale=s.k_scale if quantized else None,
                     v_scale=s.v_scale if quantized else None,
+                    moe_live=moe_live,
                 )
                 scale_upd = (
                     {"k_scale": ks_st, "v_scale": vs_st} if quantized
@@ -1375,8 +1435,9 @@ def serve_chunk(
                     pos=jax.lax.dynamic_slice_in_dim(s.kpos, row0, Bs, axis=0),
                     length=off_r,
                 )
-                h_new, cache_r_new = fns.stage(
-                    cfg, layers, h_in, cache_r, pos_rows[:, None], lmask
+                h_new, cache_r_new, moe_stats = fns.stage(
+                    cfg, layers, h_in, cache_r, pos_rows[:, None], lmask,
+                    moe_live=moe_live,
                 )
                 k_st = upd(s.k, cache_r_new.k, 1)
                 v_st = upd(s.v, cache_r_new.v, 1)
@@ -1468,6 +1529,11 @@ def serve_chunk(
             inject_pending = s.inject_pending.at[clear0].set(False)
 
             log_i = jnp.where(commit, nxt, -1)  # [Bs] this microstep's commits
+            if moe_stats is not None:
+                log_i = jnp.concatenate([
+                    log_i,
+                    _moe_counts(moe_stats, moe_live, sidx, num_stages),
+                ])
 
             new_s = s._replace(
                 k=k_st, v=v_st, kpos=kpos_st, h=h_out, h_valid=h_valid_out,
@@ -1484,7 +1550,11 @@ def serve_chunk(
                 log, log_i[None], i, axis=0
             )
 
-        log0 = jnp.full((n_micro, Bs), -1, jnp.int32)
+        log0 = jnp.full(
+            (n_micro,
+             Bs + moe_log_width(cfg, num_stages, layer_mask.shape[1])),
+            -1, jnp.int32,
+        )
         st, log = jax.lax.fori_loop(0, n_micro, micro_carry, (st, log0))
         st = jax.tree.map(
             lambda spec, leaf: leaf[None] if _dev(spec) else leaf,
@@ -1651,7 +1721,7 @@ def serve_verify(
                 st.kpos, row0, Bs, axis=0
             )
             kv_pos = kpos_rows.at[rowsel, colsel].set(positions)
-            h, k_full, v_full, ks_full, vs_full = ring_chain_paged(
+            h, k_full, v_full, ks_full, vs_full, _ = ring_chain_paged(
                 fns, cfg, layers, lmask, sidx, ring, num_stages, h,
                 st.k, st.v, tbl, cols, kv_pos, positions, backend=attn,
                 k_scale=st.k_scale if quantized else None,
@@ -1669,7 +1739,7 @@ def serve_verify(
                 pos=jax.lax.dynamic_slice_in_dim(st.kpos, row0, Bs, axis=0),
                 length=jnp.asarray(scratch, jnp.int32),
             )
-            h, cache = ring_chain(
+            h, cache, _ = ring_chain(
                 fns, cfg, layers, lmask, sidx, ring, num_stages, h, cache,
                 positions,
             )

@@ -131,6 +131,13 @@ class PipelineEngine:
         if self.tensor_parallel > 1:
             from ..parallel.tensor import validate_tp
 
+            if cfg.num_experts:
+                raise NotImplementedError(
+                    "tensor parallelism over a model with sparse experts "
+                    f"(num_experts={cfg.num_experts}) is not implemented: "
+                    "an expert axis on the mesh is a later step. One chip "
+                    "and a ring of stages (num_stages) serve it"
+                )
             validate_tp(cfg, self.tensor_parallel)
 
         self._devices = devices
@@ -591,6 +598,13 @@ class PipelineEngine:
         see ``PipelineServer`` for the exact gates. ``cp=1`` (default)
         compiles the exact pre-existing programs."""
         self._validate_serve()
+        if cp > 1 and self.cfg.num_experts:
+            raise NotImplementedError(
+                "serve×cp over a model with sparse experts is not "
+                "implemented (untested: the experts' counters and reads "
+                "would repeat per context shard); serve it on one chip or "
+                "a ring of stages"
+            )
         if cp > 1 and self.tensor_parallel > 1:
             raise NotImplementedError(
                 "serve×cp×tp: the cp arena sharding and megatron heads "

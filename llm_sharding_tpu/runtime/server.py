@@ -78,7 +78,7 @@ from ..obs.metrics import (
     ARENA_BYTES, ATTN_BACKEND, ATTN_BACKENDS, ATTN_BLOCKS_READ,
     CP_STREAM_SHARDS, DEFAULT_RATE_BUCKETS,
     KV_BLOCKS_IN_USE, KV_BLOCKS_TOTAL, KV_DISK_TIER_BLOCKS,
-    KV_HOST_TIER_BLOCKS, KV_WASTE_FRAC,
+    KV_HOST_TIER_BLOCKS, KV_WASTE_FRAC, MOE_EXPERT_TOKENS, MOE_EXPERTS_READ,
     PREFILL_BLOCKS_READ, PREFILL_POSITIONS, PREFIX_HIT_RATE,
     PREFIX_HIT_TOKENS, REGISTRY, record_shape_key, set_prefill_path,
 )
@@ -1305,6 +1305,22 @@ class PipelineServer:
             self._cp_layer_masks = place(engine.layer_masks)
             self._cp_head_params = place(engine.head_params)
         CP_SHARDS.set(float(self.cp))
+        # a model with experts: the step programs append their counters to
+        # what is fetched anyway (serve_ops.moe_log_width); the layer slots
+        # that hold a real layer, and a chunked prefill's counters waiting
+        # for a fetch to show them computed
+        self._moe_width = serve_ops.moe_log_width(
+            self.cfg, self.num_stages, Lp
+        )
+        if self._moe_width:
+            self._moe_layers = np.flatnonzero(
+                np.asarray(engine.layer_masks).reshape(-1)
+            )
+            self._moe_lazy: list = []
+            self._moe_children = [
+                MOE_EXPERT_TOKENS.labels(expert=str(e))
+                for e in range(self.cfg.num_experts)
+            ]
         self.state = serve_ops.make_state(
             self.cfg,
             self.mesh,
@@ -4458,7 +4474,7 @@ class PipelineServer:
                 )
             real = int(np.clip(plen - off, 0, Sc)[row_valid].sum())
             with self._prefill_span(Bs, real, Bs * Sc):
-                self.state = serve_ops.serve_prefill_chunk(
+                chunk_out = serve_ops.serve_prefill_chunk(
                     self.cfg,
                     self.mesh,
                     self._stage_layers,
@@ -4478,6 +4494,11 @@ class PipelineServer:
                     attn=attn,
                     cp=self.cp,
                 )
+                if self._moe_width:
+                    self.state, moe_counts = chunk_out
+                    self._moe_lazy.append(moe_counts)
+                else:
+                    self.state = chunk_out
             # interleave only when some OTHER request is mid-decode — the
             # admitting rows themselves are in _rows already and must not
             # count, or an idle server would pay a useless cycle per chunk
@@ -4746,6 +4767,8 @@ class PipelineServer:
             self._contain_lost_log(entry, err)
             return False
         sl.push("apply")
+        if self._moe_width and entry[0] in ("chunk", "admit"):
+            value = self._apply_moe(value, decode=entry[0] == "chunk")
         if entry[0] == "chunk":
             self._apply_log(value, entry[2])
         elif entry[0] == "spec":
@@ -4757,6 +4780,36 @@ class PipelineServer:
                 self._apply_token(row, req, int(value[i]))
         sl.pop()
         return True
+
+    def _apply_moe(self, value: np.ndarray, decode: bool) -> np.ndarray:
+        """Split a fetched chunk log or admission result of a model with
+        experts into its tokens (returned) and the ``moe_log_width``
+        counters behind them, which go to the step record and the
+        ``server_moe_*`` series. A chunked prefill's counters, parked in
+        ``_moe_lazy``, are read here once ready: no wait of their own."""
+        E, W = self.cfg.num_experts, self._moe_width
+        own = np.asarray(value)[..., -W:].reshape(-1, W)
+        ready = [a for a in self._moe_lazy if a.is_ready()]
+        done = {id(a) for a in ready}
+        self._moe_lazy = [a for a in self._moe_lazy if id(a) not in done]
+        tokens = own[:, :E].sum(axis=0)
+        for arr in ready:
+            tokens = tokens + np.asarray(arr)[:E]
+        for child, n in zip(self._moe_children, tokens):
+            if n:
+                child.inc(int(n))
+        if decode:  # a chunk log: one row of counters per decode microstep
+            read = own[:, E:-1][:, self._moe_layers]
+            busy = own[:, -1] > 0
+            self.stepline.experts(
+                tokens, read.sum(axis=0), int(busy.sum()),
+                int(own[:, -1].sum()),
+            )
+            if busy.any():
+                MOE_EXPERTS_READ.set(float(read[busy].mean()))
+        else:
+            self.stepline.experts(tokens)
+        return value[..., :-W]
 
     def _apply_log(self, log: np.ndarray, m0: int) -> None:
         """Replay one chunk's token log into the host mirrors. At microstep

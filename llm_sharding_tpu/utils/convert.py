@@ -59,10 +59,33 @@ def llama_layer_arrays(
         "wv": lin("self_attn.v_proj"),
         "wo": lin("self_attn.o_proj"),
         "post_norm": jnp.asarray(get(pre + "post_attention_layernorm.weight"), dtype),
-        "w_gate": lin("mlp.gate_proj"),
-        "w_up": lin("mlp.up_proj"),
-        "w_down": lin("mlp.down_proj"),
     }
+    if cfg.num_experts:
+        # OLMoE's published layout: ``mlp.gate`` (the router, [E, H]) and
+        # per-expert ``mlp.experts.{e}.{gate,up,down}_proj`` → the layer's
+        # experts as one block-sparse MLP of width E·F, expert e being
+        # columns (rows) e·F..(e+1)·F (ops/moe.py)
+        def experts(name, axis):
+            return jnp.concatenate(
+                [lin(f"mlp.experts.{e}.{name}") for e in range(cfg.num_experts)],
+                axis=axis,
+            )
+
+        p.update(
+            router=lin("mlp.gate"),
+            we_gate=experts("gate_proj", 1),
+            we_up=experts("up_proj", 1),
+            we_down=experts("down_proj", 0),
+        )
+    else:
+        p.update(
+            w_gate=lin("mlp.gate_proj"),
+            w_up=lin("mlp.up_proj"),
+            w_down=lin("mlp.down_proj"),
+        )
+    if cfg.qk_norm:
+        p["q_norm"] = jnp.asarray(get(pre + "self_attn.q_norm.weight"), dtype)
+        p["k_norm"] = jnp.asarray(get(pre + "self_attn.k_norm.weight"), dtype)
     if cfg.attention_bias:
         for key, name in (
             ("bq", "self_attn.q_proj"),

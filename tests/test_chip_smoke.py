@@ -67,6 +67,16 @@ def test_kernel_check_runs_every_variant_in_interpret_mode():
         assert err <= chip_smoke.KERNEL_TOL, (case, err)
 
 
+@pytest.mark.parametrize("rows", [4, 160])
+def test_expert_kernel_check_runs_in_interpret_mode(rows):
+    """``chip_smoke.py --moe``'s check at toy widths: the expert kernel
+    emulated against its XLA path, decode rows and grouped prefill rows."""
+    from llm_sharding_tpu.models.config import tiny_olmoe
+
+    err = chip_smoke.check_moe_kernel(tiny_olmoe(), rows, "interpret")
+    assert err <= chip_smoke.KERNEL_TOL, (rows, err)
+
+
 def test_store_writer_driver_and_assertions_on_cpu(tmp_path, monkeypatch):
     """The smoke's daemon phase end to end at toy size: seeded store through
     the product's writer, the real ``serve`` daemon as a child, the smoke's

@@ -105,7 +105,7 @@ def test_layer_mask_passthrough(params):
     cache = init_cache(CFG, B, capacity=S, dtype=jnp.float32)
 
     mask = jnp.array([True, False, True, False])
-    h_out, cache_out = llama.forward_layers(
+    h_out, cache_out, _ = llama.forward_layers(
         CFG, params["layers"], h, cache, positions, layer_mask=mask
     )
     # Layers 1 and 3 wrote nothing
@@ -116,7 +116,7 @@ def test_layer_mask_passthrough(params):
     # Equivalent to running a 2-layer model of layers {0, 2}
     sub_layers = jax.tree.map(lambda a: a[jnp.array([0, 2])], params["layers"])
     sub_cache = init_cache(CFG, B, capacity=S, num_layers=2, dtype=jnp.float32)
-    h_sub, _ = llama.forward_layers(CFG, sub_layers, h, sub_cache, positions)
+    h_sub, _, _ = llama.forward_layers(CFG, sub_layers, h, sub_cache, positions)
     np.testing.assert_allclose(np.asarray(h_out), np.asarray(h_sub), atol=1e-5)
 
 

@@ -1,0 +1,147 @@
+"""``ops/moe.py``: the router and the expert product, decode and prefill
+regimes, the Pallas kernel in interpret mode and the XLA path of the same
+arithmetic, against the DENSE form (every expert computed, the unchosen
+multiplied by zero) — over experts, k, rows with dead rows, experts repeated
+across rows, raw and int8 weights, a layer of a stack that is not the first."""
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+
+import llm_sharding_tpu.models  # noqa: F401 — ops import through models
+from llm_sharding_tpu.ops import moe
+from llm_sharding_tpu.ops.quant import QTensor, dequantize, quantize_tensor
+
+
+def dense_form(x, weights, ids, wg, wu, wd, E, live):
+    """Σ_e p_e · (silu(x Wg_e) ⊙ (x Wu_e)) Wd_e with p_e = 0 off the kept
+    set, all experts computed; zero for dead rows."""
+    N, F = x.shape[0], wg.shape[-1] // E
+    mask = jnp.zeros((N, E), jnp.float32).at[
+        jnp.arange(N)[:, None], ids].add(weights)
+    hp = jax.lax.Precision.HIGHEST
+    g = jnp.dot(x, wg, precision=hp).reshape(N, E, F)
+    u = jnp.dot(x, wu, precision=hp).reshape(N, E, F)
+    a = (jax.nn.silu(g) * u * mask[:, :, None]).reshape(N, E * F)
+    return jnp.where(live[:, None], jnp.dot(a, wd, precision=hp), 0.0)
+
+
+def make(seed, N, E, F, H, L, quant):
+    ks = jax.random.split(jax.random.key(seed), 6)
+    x = jax.random.normal(ks[0], (N, H), jnp.float32)
+    router = jax.random.normal(ks[1], (H, E), jnp.float32)
+    wg = jax.random.normal(ks[2], (L, H, E * F), jnp.float32) * H ** -0.5
+    wu = jax.random.normal(ks[3], (L, H, E * F), jnp.float32) * H ** -0.5
+    wd = jax.random.normal(ks[4], (L, E * F, H), jnp.float32) * F ** -0.5
+    live = jax.random.bernoulli(ks[5], 0.6, (N,)).at[N // 2].set(True)
+    given = (wg, wu, wd)
+    if quant:
+        given = tuple(quantize_tensor(w) for w in given)
+        wg, wu, wd = (dequantize(w) for w in given)
+    return x, router, live, given, (wg, wu, wd)
+
+
+CASES = [
+    # N rows, E experts, k, F, H, layers, int8
+    (1, 8, 2, 32, 64, 2, False),    # one live row: k experts read
+    (4, 8, 2, 32, 64, 2, False),    # a slot with dead rows
+    (4, 8, 8, 32, 64, 1, False),    # k = E: every row repeats every expert
+    (3, 16, 4, 128, 128, 3, True),  # int8, a stack of three
+    (8, 64, 8, 16, 64, 2, True),    # OLMoE's E and k, N·k = E tiles
+    (100, 8, 2, 32, 64, 2, False),  # grouped: more rows than DECODE_ROWS_MAX
+    (300, 16, 4, 128, 128, 2, True),  # grouped, several tiles an expert
+    (40, 64, 8, 16, 64, 1, False),  # grouped, most experts under one tile
+]
+
+
+@pytest.mark.parametrize("backend", ["xla", "interpret"])
+@pytest.mark.parametrize("N,E,k,F,H,L,quant", CASES)
+def test_expert_product_equals_the_dense_form(N, E, k, F, H, L, quant, backend):
+    x, router, live, given, plain = make(N + E, N, E, F, H, L, quant)
+    layer = L - 1
+    w, ids = moe.route(x, router, k)
+    out, stats = moe.expert_mlp(
+        x, w, ids, *given, num_experts=E, live=live,
+        layer=jnp.int32(layer), backend=backend,
+    )
+    want = dense_form(x, w, ids, *(a[layer] for a in plain), E, live)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
+    # the counters: pairs of live rows only, each distinct expert once
+    counts = np.zeros(E, int)
+    for n in np.flatnonzero(np.asarray(live)):
+        counts[np.asarray(ids[n])] += 1
+    assert np.array_equal(np.asarray(stats.expert_tokens), counts)
+    assert int(stats.experts_read) == (counts > 0).sum() <= min(E, k * live.sum())
+    assert counts.sum() == k * int(live.sum())
+
+
+@pytest.mark.parametrize("backend", ["xla", "interpret"])
+def test_unstacked_weights_and_no_live_mask(backend):
+    """A layer handed over already sliced (2-D leaves, ``layer=None``) and no
+    mask: every row routes."""
+    x, router, _, given, plain = make(3, 5, 8, 32, 64, 2, True)
+    one = tuple(QTensor(g.q[1], g.scale[1]) for g in given)
+    w, ids = moe.route(x, router, 2)
+    out, stats = moe.expert_mlp(x, w, ids, *one, num_experts=8, backend=backend)
+    want = dense_form(x, w, ids, *(a[1] for a in plain), 8, jnp.ones(5, bool))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
+    assert int(stats.expert_tokens.sum()) == 10
+
+
+def test_no_live_row_reads_and_counts_nothing():
+    x, router, _, given, _ = make(9, 4, 8, 32, 64, 1, False)
+    w, ids = moe.route(x, router, 2)
+    for backend in ("xla", "interpret"):
+        out, stats = moe.expert_mlp(
+            x, w, ids, *given, num_experts=8, live=jnp.zeros(4, bool),
+            layer=jnp.int32(0), backend=backend,
+        )
+        assert not np.asarray(out).any()
+        assert int(stats.experts_read) == 0 and not np.asarray(stats.expert_tokens).any()
+
+
+def test_router_is_float32_topk_unnormalised_unless_asked():
+    ks = jax.random.split(jax.random.key(0), 2)
+    x = jax.random.normal(ks[0], (64, 32), jnp.float32)
+    router = jax.random.normal(ks[1], (32, 16), jnp.float32)
+    w, ids = moe.route(x, router, 4)
+    p = jax.nn.softmax(jnp.dot(x, router, precision="highest"), -1)
+    order = np.argsort(-np.asarray(p), axis=-1)[:, :4]
+    assert np.array_equal(np.asarray(ids), order)
+    np.testing.assert_allclose(
+        np.asarray(w), np.take_along_axis(np.asarray(p), order, -1), rtol=1e-6)
+    assert float(w.sum(-1).max()) < 1.0  # kept as they are
+    wn, idn = moe.route(x, router, 4, renormalize=True)
+    assert np.array_equal(np.asarray(idn), np.asarray(ids))
+    np.testing.assert_allclose(np.asarray(wn.sum(-1)), 1.0, rtol=1e-6)
+    # bf16 inputs are multiplied out in float32 all the same
+    wb, _ = moe.route(x.astype(jnp.bfloat16), router.astype(jnp.bfloat16), 4)
+    assert wb.dtype == jnp.float32
+
+
+def test_a_hand_built_routing_counts_exactly():
+    """Rows 0 and 2 live and choosing {1, 3} and {3, 5}; row 1 dead, choosing
+    {0, 7}: three distinct experts read, expert 3 twice."""
+    E, F, H = 8, 16, 32
+    x, _, _, given, plain = make(1, 3, E, F, H, 1, False)
+    ids = jnp.asarray([[1, 3], [0, 7], [3, 5]], jnp.int32)
+    w = jnp.asarray([[0.5, 0.25], [0.9, 0.05], [0.4, 0.3]], jnp.float32)
+    live = jnp.asarray([True, False, True])
+    for backend in ("xla", "interpret"):
+        out, stats = moe.expert_mlp(
+            x, w, ids, *given, num_experts=E, live=live,
+            layer=jnp.int32(0), backend=backend,
+        )
+        assert np.asarray(stats.expert_tokens).tolist() == [0, 1, 0, 2, 0, 1, 0, 0]
+        assert int(stats.experts_read) == 3
+        want = dense_form(x, w, ids, *(a[0] for a in plain), E, live)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
+
+
+def test_backend_names():
+    with pytest.raises(ValueError, match="expected one of"):
+        moe.resolve_backend("pallas")
+    with pytest.raises(ValueError, match="requires a TPU"):
+        moe.resolve_backend("kernel")
+    assert moe.resolve_backend("xla") == "xla"

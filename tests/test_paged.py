@@ -792,7 +792,9 @@ def test_kernel_rules_learned_from_the_v5e_compiler(monkeypatch):
 #: (Qwen2.5-7B, one chip) and 40 / 8 (Qwen2.5-14B, a stage of the ring);
 #: decode (S = 1) and a 256-token prefill chunk. The stack is cut to 3
 #: layers x 260 blocks: the kernels' tiles do not depend on either.
-_CELL_SHAPES = {"qwen25_7b": (28, 4), "qwen25_14b_pp4": (40, 8)}
+#: OLMoE-1B-7B is plain MHA: 16 key/value heads and a query tile of G = 1.
+_CELL_SHAPES = {"qwen25_7b": (28, 4), "qwen25_14b_pp4": (40, 8),
+                "olmoe_1b_7b": (16, 16)}
 
 
 @pytest.fixture(scope="module")
@@ -860,6 +862,51 @@ def test_kernels_compile_for_a_described_v5e(v5e_chip, cell, kernel, store):
     arena_elems = Lp * NB * Nkv * BS * D
     for m in re.finditer(r"= \w+\[([\d,]+)\][^ ]* (copy|transpose)\(", text):
         assert np.prod([int(x) for x in m.group(1).split(",")]) < arena_elems
+
+
+@pytest.mark.parametrize("weights", ["int8", "bf16"])
+@pytest.mark.parametrize("rows", [4, 1024])
+def test_expert_kernel_compiles_for_a_described_v5e(v5e_chip, rows, weights):
+    """Mosaic accepts the expert kernel (``ops/moe.py``) at OLMoE-1B-7B's
+    published widths — 64 experts of 2048 x 1024, the layer-stacked weights
+    read in place through scalar-prefetched layer and expert indices — in
+    both regimes: a decode step's 4 rows (one tile per distinct expert) and a
+    prefill chunk's 1,024 positions (grouped tiles of 128 rows). The stack is
+    cut to 2 layers; no weight-sized copy may stand beside the custom call."""
+    from unittest import mock
+
+    from llm_sharding_tpu.ops import moe
+    from llm_sharding_tpu.ops.quant import QTensor
+
+    L, H, E, F, k = 2, 2048, 64, 1024, 8
+    S = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=v5e_chip
+    )
+    if weights == "int8":
+        leaf = lambda *shape: QTensor(
+            S((L, *shape), jnp.int8), S((L, shape[-1]), jnp.bfloat16))
+    else:
+        leaf = lambda *shape: S((L, *shape), jnp.bfloat16)
+
+    def fn(x, w, ids, live, layer, wg, wu, wd):
+        return moe.expert_mlp(
+            x, w, ids, wg, wu, wd, E, live=live, layer=layer, backend="kernel"
+        )
+
+    with jax.default_matmul_precision("default"), mock.patch.object(
+        jax, "default_backend", lambda: "tpu"
+    ):
+        compiled = jax.jit(fn).lower(
+            S((rows, H), jnp.bfloat16), S((rows, k), jnp.float32),
+            S((rows, k), jnp.int32), S((rows,), jnp.bool_), S((), jnp.int32),
+            leaf(H, E * F), leaf(H, E * F), leaf(E * F, H),
+        ).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "moe_experts" in text
+    import re
+
+    for m in re.finditer(r"= \w+\[([\d,]+)\][^ ]* (copy|transpose)\(", text):
+        assert np.prod([int(x) for x in m.group(1).split(",")]) < H * E * F
 
 
 def test_forced_backend_env_validation(monkeypatch):
@@ -960,8 +1007,12 @@ def test_attn_backend_metrics(setup, monkeypatch):
 #: (the dense scan's kv_take / kv_put) and cuts it into head-major blocks
 #: (kv_layout) before the scatter. serve_prefill_chunk samples nothing.
 #: serve_admit_finish only embeds each row's last token.
+#: A model's MLP is dense (``mlp``) or sparse experts (``router`` and
+#: ``moe``, ``ops/moe.py``), never both: the model of a case says which
+#: words its programs lack.
 _NO_ARENA_COPY = {"kv_take", "kv_layout", "kv_put"}
 _NO_HEAD = {"head", "sample"}
+_MLP_WORDS = {"dense": {"router", "moe"}, "experts": {"mlp"}}
 PROGRAM_SCOPES = {
     "serve_chunk": _NO_ARENA_COPY,
     "serve_prefill_chunk": _NO_ARENA_COPY | _NO_HEAD,
@@ -972,12 +1023,27 @@ PROGRAM_SCOPES = {
 
 @pytest.fixture(scope="module")
 def lowered_programs(setup):
+    return _lower_programs(*setup, cfg=CFG)
+
+
+@pytest.fixture(scope="module")
+def lowered_programs_experts():
+    """The same through a model with sparse experts (a ring of two)."""
+    from llm_sharding_tpu.models.config import tiny_olmoe
+
+    cfg = tiny_olmoe(max_position_embeddings=CFG.max_position_embeddings)
+    params = llama.init_params(cfg, jax.random.key(12), dtype=jnp.float32)
+    eng = PipelineEngine(cfg, params, num_stages=2, cache_dtype=jnp.float32,
+                         devices=jax.devices()[:2])
+    return _lower_programs(params, eng, cfg=cfg)
+
+
+def _lower_programs(params, eng, cfg):
     """Serve a one-shot and a chunked admission through the interpreted
     kernels, lowering each step program with the very arguments the server
     dispatched it with. Returns ``(texts, served, oracle)``."""
     from llm_sharding_tpu.parallel import serve as serve_ops
 
-    params, eng = setup
     texts = {}
 
     def spy(mp, name):
@@ -1004,7 +1070,10 @@ def lowered_programs(setup):
         srv.run_until_idle()
         srv.close()
     served = [list(r.tokens) for r in reqs]
-    oracle = [oracle_tokens(params, p, 5) for p in prompts]
+    oracle = []
+    for p in prompts:
+        res = generate(cfg, params, p, 5, cache_dtype=jnp.float32)
+        oracle.append(list(res.tokens[0, len(p): int(res.lengths[0])]))
     return texts, served, oracle
 
 
@@ -1021,21 +1090,26 @@ def _scopes_in(text):
     }, paths
 
 
+@pytest.mark.parametrize("model", sorted(_MLP_WORDS))
 @pytest.mark.parametrize("program", sorted(PROGRAM_SCOPES))
-def test_step_programs_carry_the_scope_vocabulary(lowered_programs, program):
+def test_step_programs_carry_the_scope_vocabulary(request, program, model):
     """Every step program names its device work by the closed vocabulary
     (``obs.stepline.SCOPES``) — what a profiler trace's ``tf_op`` then
     carries — and naming changes no token: the served ids equal the
-    monolithic oracle's, as before the scopes."""
+    monolithic oracle's, as before the scopes. A model with sparse experts
+    carries ``router`` and ``moe`` where a dense one carries ``mlp``, in all
+    three programs that run layers."""
     from llm_sharding_tpu.obs.stepline import SCOPES
 
-    texts, served, oracle = lowered_programs
+    texts, served, oracle = request.getfixturevalue(
+        "lowered_programs" if model == "dense" else "lowered_programs_experts"
+    )
     assert served == oracle
     found, paths = _scopes_in(texts[program])
     missing_ok = PROGRAM_SCOPES[program]
     want = (
         {"embed", "state"} if missing_ok is None
-        else set(SCOPES) - missing_ok
+        else set(SCOPES) - missing_ok - _MLP_WORDS[model]
     )
     assert found == want, (sorted(want - found), sorted(found - want))
     if program in ("serve_chunk", "serve_prefill_chunk"):
@@ -1061,6 +1135,13 @@ def test_the_pallas_kernels_are_named(lowered_programs):
     assert any(p.startswith("paged_decode/") for p in decode)
     assert any(p.startswith("paged_prefill/") for p in prefill)
     assert not any(p.startswith("paged_prefill/") for p in decode)
+
+
+def test_the_expert_kernel_is_named(lowered_programs_experts):
+    texts, _, _ = lowered_programs_experts
+    for program in ("serve_chunk", "serve_prefill_chunk", "serve_admit"):
+        _, paths = _scopes_in(texts[program])
+        assert any("moe_experts" in p for p in paths), program
 
 
 # ----------------------- the arena stays where it lies (program structure)

@@ -116,9 +116,9 @@ def test_padded_stage_equals_unpadded(store):
     assert list(np.asarray(padded["layer_mask"])) == [True, True, False, False]
 
     c1 = init_cache(CFG, B, S, num_layers=2, dtype=jnp.float32)
-    h1, _ = llama.forward_layers(CFG, plain["layers"], h, c1, positions)
+    h1, _, _ = llama.forward_layers(CFG, plain["layers"], h, c1, positions)
     c2 = init_cache(CFG, B, S, num_layers=4, dtype=jnp.float32)
-    h2, _ = llama.forward_layers(
+    h2, _, _ = llama.forward_layers(
         CFG, padded["layers"], h, c2, positions, layer_mask=padded["layer_mask"]
     )
     np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), atol=1e-6)
