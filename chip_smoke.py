@@ -196,19 +196,30 @@ def write_store(spec: dict, out_dir: str) -> dict:
     }
 
 
+#: The decode kernel's work follows each row's written frontier, so its
+#: cases carry a STATE: ``ragged`` (every row live, each at its own
+#: length), ``one_row_eighth`` (one live row at 1/8 of the table beside
+#: dead rows: what a lightly loaded server decodes) and ``all_rows_full``
+#: (every row at the full table: where the walk saves nothing and the
+#: kernel must still be no slower than before).
+DECODE_STATES = ("ragged", "one_row_eighth", "all_rows_full")
+
+
 def kernel_cases(spec: dict) -> list[dict]:
     """Every pallas_call the CLI can select, at the daemon's geometry: the
-    paged decode kernel (S = 1) and the chunked-prefill kernel (S = the
-    prefill chunk) over bf16, int8 and fp8 arenas, and the flash kernel the
-    one-shot admission uses (S = the short prompt's bucket over the full
-    window)."""
+    paged decode kernel (S = 1, in each of ``DECODE_STATES``) and the
+    chunked-prefill kernel (S = the prefill chunk) over bf16, int8 and fp8
+    arenas, and the flash kernel the one-shot admission uses (S = the short
+    prompt's bucket over the full window)."""
     sv = spec["serve"]
     cases = [
-        {"kernel": kern, "kv_dtype": kvd}
-        for kern in ("paged_decode", "paged_prefill")
+        {"kernel": "paged_decode", "kv_dtype": kvd, "state": state}
+        for kvd in ("bf16", "int8", "fp8") for state in DECODE_STATES
+    ] + [
+        {"kernel": "paged_prefill", "kv_dtype": kvd, "state": "ragged"}
         for kvd in ("bf16", "int8", "fp8")
     ]
-    cases.append({"kernel": "flash", "kv_dtype": "bf16"})
+    cases.append({"kernel": "flash", "kv_dtype": "bf16", "state": "ragged"})
     bucket = 8
     while bucket < spec["short_prompt"]:
         bucket *= 2
@@ -222,20 +233,19 @@ def kernel_cases(spec: dict) -> list[dict]:
     return cases
 
 
-def check_kernel(cfg, case: dict, backend: str, seed: int = 0) -> float:
-    """Run one kernel variant (``backend`` = "kernel" on the chip,
-    "interpret" under pytest) and the XLA path on the same random arena —
-    for the paged kernels a head-major stack of three layers with other
-    contents in each, attended at a layer that is not the first (a kernel
-    that ignored its layer operand would read layer 0); return max
-    |difference|."""
+def kernel_inputs(cfg, case: dict, seed: int = 0):
+    """One kernel case's operands on a seeded random arena: ``(args,
+    scales, nlive)`` — for the paged kernels the ops' positional arguments
+    over a head-major stack of three layers with other contents in each,
+    attended at a layer that is not the first (a kernel that ignored its
+    layer operand would read layer 0), the scale keywords of a quantized
+    arena, and the blocks covering each row's written frontier; for the
+    flash kernel its ``(q, k, v, q_positions, kv_positions)`` alone."""
     import numpy as np
     import jax.numpy as jnp
 
     import llm_sharding_tpu.models  # noqa: F401 — ops import through models
     from llm_sharding_tpu.models.cache import POS_SENTINEL
-    from llm_sharding_tpu.ops import attention, flash_attention
-    from llm_sharding_tpu.ops import paged_attention as pa
     from llm_sharding_tpu.ops.quant import kv_qmax, kv_storage_dtype
 
     rng = np.random.default_rng([seed, zlib.crc32(json.dumps(
@@ -246,8 +256,14 @@ def check_kernel(cfg, case: dict, backend: str, seed: int = 0) -> float:
                   cfg.head_dim_)
     W = T * BS
     dt = jnp.bfloat16
-    # ragged rows: each has attended context behind its S query positions
-    ctx = rng.integers(W // 4, W - S, B)  # tokens already in the window
+    # tokens already in the window behind each row's S query positions
+    if case["state"] == "all_rows_full":
+        ctx = np.full(B, W - S)
+    elif case["state"] == "one_row_eighth":
+        ctx = np.zeros(B, np.int64)
+        ctx[0] = W // 8 - S
+    else:  # ragged rows
+        ctx = rng.integers(W // 4, W - S, B)
     qpos = (ctx[:, None] + np.arange(S)[None]).astype(np.int32)
     cols = np.arange(W)[None]
     kvpos = np.where(cols < (ctx + S)[:, None], cols, int(POS_SENTINEL))
@@ -256,38 +272,56 @@ def check_kernel(cfg, case: dict, backend: str, seed: int = 0) -> float:
     if case["kernel"] == "flash":
         k = jnp.asarray(rng.standard_normal((B, W, Nkv, D), np.float32), dt)
         v = jnp.asarray(rng.standard_normal((B, W, Nkv, D), np.float32), dt)
-        args = (q, k, v, jnp.asarray(qpos), jnp.asarray(kvpos, jnp.int32))
+        return ((q, k, v, jnp.asarray(qpos), jnp.asarray(kvpos, jnp.int32)),
+                {}, None)
+    # blocks covering the written frontier; a row with nothing behind its
+    # query is dead: its table stays all trash
+    nlive = np.where(ctx > 0, -(-(ctx + S) // BS), 0)
+    NB = int(nlive.sum()) + 1  # + the trash block 0
+    ids = rng.permutation(np.arange(1, NB))
+    table = np.zeros((B, T), np.int32)
+    at = 0
+    for b in range(B):
+        table[b, : nlive[b]] = ids[at: at + nlive[b]]
+        at += nlive[b]
+    store = kv_storage_dtype(case["kv_dtype"], dt)
+    L = 3
+    layer = int(rng.integers(1, L))
+    vals = rng.standard_normal((2, L, NB, Nkv, BS, D), np.float32)
+    scales = {}
+    if case["kv_dtype"] == "bf16":
+        k_arena, v_arena = (jnp.asarray(a, store) for a in vals)
+    else:  # codes spanning the code range + per-block-per-head scales
+        qmax = kv_qmax(store)
+        codes = np.clip(vals * (qmax / 3.0), -qmax, qmax)
+        if case["kv_dtype"] == "int8":
+            codes = np.round(codes)
+        k_arena, v_arena = (jnp.asarray(a, store) for a in codes)
+        sc = rng.uniform(0.5, 1.5, (2, L, NB, Nkv)) * (3.0 / qmax)
+        scales = {"k_scale": jnp.asarray(sc[0], jnp.float32),
+                  "v_scale": jnp.asarray(sc[1], jnp.float32)}
+    args = (q, k_arena, v_arena, layer, jnp.asarray(table),
+            jnp.asarray(qpos), jnp.asarray(kvpos, jnp.int32))
+    return args, scales, nlive
+
+
+def check_kernel(cfg, case: dict, backend: str, seed: int = 0) -> float:
+    """Run one kernel variant (``backend`` = "kernel" on the chip,
+    "interpret" under pytest) and the XLA path on the same operands
+    (``kernel_inputs``); return max |difference|."""
+    import numpy as np
+    import jax.numpy as jnp
+
+    from llm_sharding_tpu.ops import attention, flash_attention
+    from llm_sharding_tpu.ops import paged_attention as pa
+
+    args, scales, nlive = kernel_inputs(cfg, case, seed)
+    if case["kernel"] == "flash":
         got = flash_attention.flash_attention(
             *args, interpret=(backend == "interpret")
         )
         want = attention.cached_attention(*args)
     else:
-        nlive = -(-(ctx + S) // BS)  # blocks covering the written frontier
-        NB = int(nlive.sum()) + 1  # + the trash block 0
-        ids = rng.permutation(np.arange(1, NB))
-        table = np.zeros((B, T), np.int32)
-        at = 0
-        for b in range(B):
-            table[b, : nlive[b]] = ids[at: at + nlive[b]]
-            at += nlive[b]
-        store = kv_storage_dtype(case["kv_dtype"], dt)
-        L = 3
-        layer = int(rng.integers(1, L))
-        vals = rng.standard_normal((2, L, NB, Nkv, BS, D), np.float32)
-        scales = {}
-        if case["kv_dtype"] == "bf16":
-            k_arena, v_arena = (jnp.asarray(a, store) for a in vals)
-        else:  # codes spanning the code range + per-block-per-head scales
-            qmax = kv_qmax(store)
-            codes = np.clip(vals * (qmax / 3.0), -qmax, qmax)
-            if case["kv_dtype"] == "int8":
-                codes = np.round(codes)
-            k_arena, v_arena = (jnp.asarray(a, store) for a in codes)
-            sc = rng.uniform(0.5, 1.5, (2, L, NB, Nkv)) * (3.0 / qmax)
-            scales = {"k_scale": jnp.asarray(sc[0], jnp.float32),
-                      "v_scale": jnp.asarray(sc[1], jnp.float32)}
-        args = (q, k_arena, v_arena, layer, jnp.asarray(table),
-                jnp.asarray(qpos), jnp.asarray(kvpos, jnp.int32))
         if case["kernel"] == "paged_decode":
             got = pa.paged_attention(*args, backend=backend, **scales)
         else:
@@ -300,6 +334,43 @@ def check_kernel(cfg, case: dict, backend: str, seed: int = 0) -> float:
     if not np.isfinite(got).all():
         raise AssertionError(f"{case}: non-finite kernel output")
     return float(np.abs(got - np.asarray(want.astype(jnp.float32))).max())
+
+
+def time_decode(cfg, case: dict, backend: str, calls: int = 64) -> dict:
+    """Milliseconds per call of the paged decode op through ``backend`` and
+    through the XLA path on one case's operands: ``calls`` calls inside ONE
+    program (a loop over the stack's layers, so no host dispatch is timed),
+    warmed up, best of three."""
+    import jax
+    import jax.numpy as jnp
+
+    from llm_sharding_tpu.ops import paged_attention as pa
+
+    args, scales, _ = kernel_inputs(cfg, case)
+    q, k_arena, v_arena, _, table, qpos, kvpos = args
+    layers = jnp.arange(calls, dtype=jnp.int32) % k_arena.shape[0]
+
+    def timed(how):
+        @jax.jit
+        def run(q, k_arena, v_arena, table, qpos, kvpos, scales):
+            def one(total, layer):
+                out = pa.paged_attention(
+                    q, k_arena, v_arena, layer, table, qpos, kvpos,
+                    backend=how, **scales,
+                )
+                return total + out.astype(jnp.float32).sum(), None
+            return jax.lax.scan(one, jnp.float32(0), layers)[0]
+
+        operands = (q, k_arena, v_arena, table, qpos, kvpos, scales)
+        run(*operands).block_until_ready()
+        best = float("inf")
+        for _ in range(3):
+            t0 = time.perf_counter()
+            run(*operands).block_until_ready()
+            best = min(best, time.perf_counter() - t0)
+        return round(best / calls * 1e3, 4)
+
+    return {"kernel_ms": timed(backend), "xla_ms": timed("xla")}
 
 
 #: the expert kernel's two regimes: a decode step's rows, a prefill chunk's
@@ -406,10 +477,18 @@ def child_kernels(spec: dict, out_path: str) -> None:
     results = []
     for case in kernel_cases(spec):
         err = check_kernel(cfg, case, "kernel")
-        results.append({**case, "max_err": err, "ok": err <= KERNEL_TOL})
+        times = (
+            time_decode(cfg, case, "kernel")
+            if case["kernel"] == "paged_decode" else {}
+        )
+        results.append(
+            {**case, "max_err": err, "ok": err <= KERNEL_TOL, **times}
+        )
         print(f"[kernels] {case['kernel']:13s} {case['kv_dtype']:4s} "
-              f"S={case['q_len']:<4d} max|err|={err:.3e} "
-              f"{'ok' if err <= KERNEL_TOL else 'OVER ' + str(KERNEL_TOL)}",
+              f"{case['state']:14s} S={case['q_len']:<4d} "
+              f"max|err|={err:.3e} "
+              f"{'ok' if err <= KERNEL_TOL else 'OVER ' + str(KERNEL_TOL)}"
+              + "".join(f" {k}={v}" for k, v in times.items()),
               flush=True)
     with open(out_path, "w") as f:
         json.dump({"device": device_report(), "kernels": results}, f)
@@ -831,8 +910,11 @@ def main(argv=None) -> int:
             store_h["proc"].kill()
             store_h["proc"].wait()
     for r in kern["kernels"]:
-        print(f"kernel {r['kernel']:13s} {r['kv_dtype']:4s} S={r['q_len']:<4d}"
-              f" compiled by Mosaic, max|err| vs XLA {r['max_err']:.2e}")
+        print(f"kernel {r['kernel']:13s} {r['kv_dtype']:4s} {r['state']:14s}"
+              f" S={r['q_len']:<4d} compiled by Mosaic, max|err| vs XLA "
+              f"{r['max_err']:.2e}"
+              + (f", {r['kernel_ms']} ms a call (XLA {r['xla_ms']})"
+                 if "kernel_ms" in r else ""))
 
     with open(os.path.join(WORK, "store", "config.json")) as f:
         vocab = json.load(f)["vocab_size"]

@@ -588,6 +588,20 @@ ATTN_BLOCKS_READ = REGISTRY.counter(
     "capacity slots per row regardless of length",
 )
 
+DECODE_BLOCKS_LIVE = REGISTRY.counter(
+    "server_decode_blocks_live_total",
+    "Table entries the paged decode kernel had to walk: per decode/verify "
+    "step, each live row's blocks up to its written frontier — "
+    "ceil(written columns / block_size), admission padding included "
+    "(host-side, from the length mirrors). A dead row walks none",
+)
+DECODE_BLOCKS_RESERVED = REGISTRY.counter(
+    "server_decode_blocks_reserved_total",
+    "Table entries the same steps' block tables reserved: every row the "
+    "kernel was called for, dead ones included, times the table width. "
+    "live / reserved is the share of the table's width that is real work",
+)
+
 #: Chunked-prefill implementations a dispatch can take: ``kernel`` = the
 #: Pallas flash-style chunked-prefill kernel over the arena (interpret
 #: mode counts here — it is the same code path emulated off-TPU),

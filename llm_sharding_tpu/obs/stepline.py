@@ -211,13 +211,15 @@ class StepRecord:
         "rows", "tokens", "queued", "pending", "segments", "lock_waits",
         "exemplars", "prompt_tokens", "prefill_positions",
         "expert_tokens", "experts_read", "expert_steps", "expert_rows",
+        "decode_blocks_live", "decode_blocks_reserved",
     )
 
     def __init__(self, ts, wall_s, phases, blocked_s, idle_s,
                  unattributed_s, rows, tokens, queued, pending,
                  segments=None, lock_waits=None, exemplars=None,
                  prompt_tokens=0, prefill_positions=0, expert_tokens=None,
-                 experts_read=None, expert_steps=0, expert_rows=0):
+                 experts_read=None, expert_steps=0, expert_rows=0,
+                 decode_blocks_live=0, decode_blocks_reserved=0):
         self.ts = ts
         self.wall_s = wall_s
         self.phases = phases
@@ -244,6 +246,12 @@ class StepRecord:
         self.experts_read = experts_read
         self.expert_steps = expert_steps
         self.expert_rows = expert_rows
+        # paged decode dispatched in this step: table entries its rows'
+        # written frontiers made the decode kernel walk, and the entries
+        # the tables of the rows it was called for reserved (dead rows
+        # walk 0 and reserve the table's width)
+        self.decode_blocks_live = decode_blocks_live
+        self.decode_blocks_reserved = decode_blocks_reserved
 
     @property
     def host_s(self) -> float:
@@ -267,6 +275,8 @@ class StepRecord:
             "tokens": self.tokens,
             "prompt_tokens": self.prompt_tokens,
             "prefill_positions": self.prefill_positions,
+            "decode_blocks_live": self.decode_blocks_live,
+            "decode_blocks_reserved": self.decode_blocks_reserved,
             "queued": self.queued,
             "pending": self.pending,
         }
@@ -317,6 +327,7 @@ class StepProfiler:
         self._prompt_tokens = 0
         self._prefill_positions = 0
         self._experts: Optional[list] = None  # [tokens, read, steps, rows]
+        self._decode_blocks = [0, 0]  # [live, reserved]
         self._phases: Dict[str, float] = {}
         self._blocked_s = 0.0
         self._idle_s = 0.0
@@ -418,6 +429,7 @@ class StepProfiler:
         self._prompt_tokens = 0
         self._prefill_positions = 0
         self._experts = None
+        self._decode_blocks = [0, 0]
         work = bool(rows or queued or pending)
         if self._annotate is not None and (work or self._had_work):
             self._step_span = self._annotate(
@@ -515,6 +527,14 @@ class StepProfiler:
         finally:
             self._exit(span)
 
+    def decode_blocks(self, live: int, reserved: int) -> None:
+        """Add one decode dispatch's walk to the step's record: the table
+        entries its rows' frontiers cover, and the entries reserved."""
+        if not self._enabled or self._t0 is None:
+            return
+        self._decode_blocks[0] += int(live)
+        self._decode_blocks[1] += int(reserved)
+
     def experts(self, tokens, read=None, steps: int = 0, rows: int = 0) -> None:
         """Add a fetched set of expert counters to the step's record:
         ``tokens`` [E] per expert; for decode microsteps also ``read`` [L]
@@ -585,6 +605,8 @@ class StepProfiler:
             segments=self._segments, lock_waits=lock_waits,
             exemplars=self._exemplars, prompt_tokens=self._prompt_tokens,
             prefill_positions=self._prefill_positions,
+            decode_blocks_live=self._decode_blocks[0],
+            decode_blocks_reserved=self._decode_blocks[1],
         )
         if self._experts is not None:
             tokens, read, rec.expert_steps, rec.expert_rows = self._experts

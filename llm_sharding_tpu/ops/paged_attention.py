@@ -5,16 +5,16 @@ Dense serving reserves ``capacity`` KV columns per row and decode attention
 reads all of them every step (``ops/attention.cached_attention`` over
 ``[B, C, ...]``). Paged serving stores KV in a shared arena of fixed-size
 blocks, HEAD-MAJOR and layer-stacked: ``[L, num_blocks, Nkv, block_size,
-D]`` (``models/cache.paged_arena_shape``) — one block's one head is the
-``(block_size, D)`` tile the kernels stream, so the pool is stored in the
-layout it is read in and nothing ever transposes it. Each row maps the
-blocks covering its ACTUAL tokens through a block table ``[B, T]`` (entry
-0 — the reserved trash block — pads unmapped slots). Every op here takes
-the WHOLE stack plus a ``layer`` index and addresses ``(layer, block)``
-inside it: the layer scan carries the stack and no operation produces or
-consumes a value of a layer's arena size (the gathers, the scatters and
-the kernels' block DMAs index the carried array in place). This module
-provides the attention over that layout:
+D]`` (``models/cache.paged_arena_shape``) — one block's one head is a
+``(block_size, D)`` tile and one block's ``Nkv`` heads are contiguous, so
+the pool is stored in the layout it is read in and nothing ever transposes
+it. Each row maps the blocks covering its ACTUAL tokens through a block
+table ``[B, T]`` (entry 0 — the reserved trash block — pads unmapped
+slots). Every op here takes the WHOLE stack plus a ``layer`` index and
+addresses ``(layer, block)`` inside it: the layer scan carries the stack
+and no operation produces or consumes a value of a layer's arena size (the
+gathers, the scatters and the kernels' block DMAs index the carried array
+in place). This module provides the attention over that layout:
 
 - ``gather_block_kv`` / ``paged_attention_xla``: the exact XLA path — an
   advanced-indexing gather assembles each row's logical window, then the
@@ -23,14 +23,22 @@ provides the attention over that layout:
   at the shard_map boundary) execute; numerics are identical to dense
   attention over the same positions by construction.
 - ``paged_attention_tpu``: a Pallas DECODE kernel that never materializes
-  the gathered window in HBM. The block table rides as a SCALAR-PREFETCH
-  operand (``pltpu.PrefetchScalarGridSpec``), so each grid step's
-  ``BlockSpec`` index maps pick the arena blocks to DMA directly from the
-  table (and the layer from a second scalar-prefetched operand) —
-  ``blocks_per_step`` of them per sequential step
-  (``auto_blocks_per_step``; independent refs the compiler overlaps and
-  double-buffers) — and blocks stream through VMEM with online-softmax
-  accumulation exactly like ``ops/flash_attention``.
+  the gathered window in HBM and whose work is the tokens that are
+  WRITTEN, not the width the table reserves. It derives each row's
+  frontier — the last table entry holding a key some query may attend —
+  from the table and the position arrays it is given, and its one grid
+  axis runs over the rows' live cells laid end to end (a traced bound): a
+  dead row, and the unwritten tail of a live one, cost nothing. The
+  block table, the layer index, the frontiers and each grid step's (row,
+  cell) ride as SCALAR-PREFETCH operands
+  (``pltpu.PrefetchScalarGridSpec``), so each step's ``BlockSpec`` index
+  maps pick the arena blocks to DMA directly from the table —
+  ``blocks_per_step`` of them per step (``auto_blocks_per_step``;
+  independent refs the compiler overlaps and double-buffers), each ALL
+  key/value heads of a block in one ``(Nkv, block_size, D)`` DMA scored by
+  one dot under a block-diagonal head mask — and blocks stream through
+  VMEM with online-softmax accumulation exactly like
+  ``ops/flash_attention``.
 - ``paged_prefill_tpu``: the CHUNKED-PREFILL kernel — same table-driven
   KV streaming, but the query axis is a whole prompt chunk, GQA-folded
   and tiled at ``BLOCK_Q_PREFILL`` like the flash kernel, with an
@@ -72,6 +80,7 @@ from __future__ import annotations
 import functools
 import os
 
+import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -104,18 +113,30 @@ def forced_backend() -> str | None:
     return raw
 
 
-def auto_blocks_per_step(t_blocks: int, block_size: int) -> int:
+def auto_blocks_per_step(
+    t_blocks: int, block_size: int, kv_heads: int = 1
+) -> int:
     """Auto-selected KV blocks batched per sequential grid step of the
     Pallas kernels: the largest of 8/4/2/1 that divides the table width
-    and keeps the batched score tile at or under 512 lanes (Mosaic's
-    sweet spot; per-step K+V VMEM stays ≤ 256 KB at D=128 bf16). At
-    small serving block sizes one arena block is a skinny (BS, D) tile
-    that underfeeds the MXU and pays one DMA turnaround per block;
-    batching ``bps`` blocks per step gives the compiler ``bps``
-    independent in-flight DMAs (double-buffered across steps) and a
-    (GS, bps·BS) score tile per dot."""
+    and keeps a step's keys at or under 512 tokens and its score tile at
+    or under 2,048 lanes — ``bps·BS`` lanes in the prefill kernel (one
+    head a step), ``bps·BS·kv_heads`` in the decode kernel, which takes a
+    block's ``kv_heads`` heads together (so its K and V, double buffered,
+    stay ≤ 2 MiB at D=128 bf16). At small serving block sizes one arena
+    block is a skinny tile that underfeeds the MXU and pays one DMA
+    turnaround and the pipeline's per-operand bookkeeping (~50 ns a ref a
+    step on a v5e) per block; batching ``bps`` blocks per step gives the
+    compiler ``bps`` independent in-flight DMAs (double-buffered across
+    steps) and dots that do not wait on each other. Swept on the chip at
+    the three benchmark cells' decode shapes (PERF.md, PR 28): 8 is best
+    or within 5% of it at 4 and 8 key/value heads in every state the
+    cells decode in, 4 at 16 heads; 16 wins (10%) only where every row
+    holds the full table."""
     for bps in (8, 4, 2, 1):
-        if t_blocks % bps == 0 and bps * block_size <= 512:
+        if (
+            t_blocks % bps == 0 and bps * block_size <= 512
+            and bps * block_size * kv_heads <= 2048
+        ):
             return bps
     return 1
 
@@ -127,18 +148,22 @@ def kernel_sublane(cache_dtype) -> int:
     return 32 // max(jnp.dtype(cache_dtype).itemsize, 1)
 
 
-#: Scalar-memory budget for the kernels' scalar-prefetched operands (the
-#: block table, plus the prefill kernel's ``nlive``). The v5e compiler
-#: reports 1 MiB of SMEM and lays an int32 ``[rows, T]`` table out with
-#: each row padded to 128 words: a ``[128, 2048]`` table "exceeded smem
-#: capacity by 1.2K", ``[120, 2048]`` and ``[2000, 33]`` compiled. 16 KiB
-#: is held back for ``nlive`` and the compiler's own scalars.
+#: Scalar-memory budget for the kernels' scalar-prefetched operands: the
+#: block table, and the decode kernel's walk (``nlive`` and ``start`` per
+#: row, ``row_of`` per cell). The v5e compiler reports 1 MiB of SMEM and
+#: lays an int32 ``[rows, T]`` table out with rows padded to a multiple of
+#: 8 and each row to 128 words, a 1-D array in whole KiB-words: a ``[128,
+#: 2048]`` table alone "exceeded smem capacity by 1.2K"; with the walk of
+#: the decode kernel (PR 28) ``[120, 2048]`` exceeds it by 62.1K and
+#: ``[2000, 33]`` (one block a cell: 66,001 entries) by 253.1K, while
+#: ``[110, 2048]``, ``[104, 2048]`` and ``[1500, 33]`` compile. 16 KiB is
+#: held back for the compiler's own scalars.
 SMEM_TABLE_BUDGET = (1 << 20) - (16 << 10)
 
 
 def kernel_eligible(
     head_dim: int, block_size: int, cache_dtype, *, rows: int,
-    table_width: int,
+    table_width: int, kv_heads: int = 1,
 ) -> bool:
     """Mosaic eligibility of the real (non-interpret) kernels, as learned
     from the v5e compiler:
@@ -147,17 +172,29 @@ def kernel_eligible(
       multiple and BS a sublane multiple for the CACHE dtype
       (``kernel_sublane``);
     - the ``[rows, table_width]`` block table (``rows`` = the rows one call
-      attends: a slot's ``batch_per_slot``) is scalar-prefetched whole and
-      must fit ``SMEM_TABLE_BUDGET``.
+      attends: a slot's ``batch_per_slot``) is scalar-prefetched whole,
+      and beside it the decode kernel's walk — one entry per cell of every
+      row, ``table_width / bps`` of them at the ``bps`` that
+      ``auto_blocks_per_step`` picks for ``kv_heads`` local key/value
+      heads, and two entries per row; together they must fit
+      ``SMEM_TABLE_BUDGET``.
 
     Shared by the trace-time dispatch below and the host-side serve
     validation (``runtime/server.py``), so ``--paged-attn kernel`` fails
     loud at construction instead of as a compiler error mid-serve."""
-    table_bytes = rows * (-(-table_width // 128) * 128) * 4
+    def pad(n, m):
+        return -(-n // m) * m
+
+    bps = auto_blocks_per_step(table_width, block_size, kv_heads)
+    smem_bytes = 4 * (
+        pad(rows, 8) * pad(table_width, 128)
+        + pad(rows * (table_width // bps) + 1, 1024)
+        + 2 * pad(rows, 128)
+    )
     return (
         head_dim % 128 == 0
         and block_size % kernel_sublane(cache_dtype) == 0
-        and table_bytes <= SMEM_TABLE_BUDGET
+        and smem_bytes <= SMEM_TABLE_BUDGET
     )
 
 
@@ -434,11 +471,12 @@ def combine_attn_stats(
 
 def _scale_operand(scale: jnp.ndarray) -> jnp.ndarray:
     """The kernels' view of a ``[L, NB, Nkv]`` scale arena: ``[L, NB, Nkv,
-    1, 1]`` f32, so one layer's one block's one head is a ``(1, 1, 1, 1)``
-    VMEM tile (layer dim squeezed) whose last two dims ARE the array's.
-    Mosaic refuses a ``(1, 1)`` block of the lower-rank array in any memory
-    space (the last two block dims must be multiples of (8, 128) or the
-    whole array's)."""
+    1, 1]`` f32, so one layer's one block's scales are a VMEM tile (layer
+    dim squeezed) whose last two dims ARE the array's — ``(1, 1, 1, 1)``
+    for one head (the prefill kernel), ``(1, Nkv, 1, 1)`` for the block's
+    heads together (the decode kernel). Mosaic refuses a ``(1, 1)`` block
+    of the lower-rank array in any memory space (the last two block dims
+    must be multiples of (8, 128) or the whole array's)."""
     return scale.astype(jnp.float32)[..., None, None]
 
 
@@ -448,45 +486,82 @@ def _layer_operand(layer) -> jnp.ndarray:
     return jnp.asarray(layer, jnp.int32).reshape(1)
 
 
-def _online_update(q, k, v, mask, scale, acc_ref, m_ref, l_ref):
-    """One flash-attention recurrence step over a streamed KV tile: score
-    the tile, fold it into the (acc, m, l) running softmax scratch. Shared
-    by the decode kernel and the chunked-prefill kernel — the masking and
+def _online_update(q, tiles, scale, acc_ref, m_ref, l_ref):
+    """One flash-attention recurrence step over the KV tiles one grid cell
+    streamed — ``tiles`` is a sequence of ``(k, v, mask)``, ``k``/``v``
+    ``[N, D]`` and ``mask`` ``[rows, N]``: score every tile, take ONE running
+    max over all of them, fold them into the (acc, m, l) running-softmax
+    scratch with one rescale. The tiles' dots do not depend on each other
+    (a chain of per-tile updates would serialize them through ``m``).
+    Shared by the decode kernel (a cell's ``bps`` blocks) and the
+    chunked-prefill kernel (one tile per call) — the masking and
     accumulation contract is ``ops/flash_attention._flash_kernel``'s
     (NEG_INF masking; an all-masked tile's garbage is wiped by the first
     real tile's correction factor)."""
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # [GS, BS] f32
-    s = jnp.where(mask, s, NEG_INF)
+    scores = []
+    for k, _, mask in tiles:
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [rows, N] f32
+        scores.append(jnp.where(mask, s, NEG_INF))
 
     m_prev = m_ref[:, :1]
     l_prev = l_ref[:, :1]
-    m_blk = jnp.max(s, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_blk)
-    p = jnp.exp(s - m_new)
+    m_new = m_prev
+    for s in scores:
+        m_new = jnp.maximum(m_new, jnp.max(s, axis=-1, keepdims=True))
     corr = jnp.exp(m_prev - m_new)
-    l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-    pv = jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # [GS, D]
+    l_new = l_prev * corr
+    pv = None
+    for s, (_, v, _) in zip(scores, tiles):
+        p = jnp.exp(s - m_new)
+        l_new = l_new + jnp.sum(p, axis=-1, keepdims=True)
+        d = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [rows, D]
+        pv = d if pv is None else pv + d
     acc_ref[:] = acc_ref[:] * corr + pv
     m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
     l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
 
+def _live_blocks(block_table, q_positions, kv_positions):
+    """Per-row count of table entries the decode kernel must walk, ``[B]``
+    int32: the leading entries of row ``b`` up to the LAST one that is not
+    trash (``block_table != 0``) and holds a key position some query of the
+    row may attend (``kv_pos <= `` the row's largest real query position —
+    a finished row of ``serve_verify`` queries at the sentinel and counts
+    for nothing). Computed from the very arrays the mask is made of, so a
+    block past it is one the mask wipes whole: a reduce over ``[B, T, BS]``
+    int32. A dead row (table all trash, or no real query) reads 0."""
+    from ..models.cache import POS_SENTINEL  # models imports this module
+
+    B, T = block_table.shape
+    q_hi = jnp.max(
+        jnp.where(q_positions < POS_SENTINEL, q_positions, -1), axis=1
+    )
+    seen = (kv_positions <= q_hi[:, None]).reshape(B, T, -1).any(axis=2)
+    seen &= block_table != 0
+    ends = jnp.where(seen, jnp.arange(1, T + 1, dtype=jnp.int32), 0)
+    return jnp.max(ends, axis=1)
+
+
 def _paged_kernel(
     layer_ref,  # scalar-prefetch [1] — read by the index maps only
-    tbl_ref,  # scalar-prefetch [B, T] (read by the index maps + trash gate)
-    q_ref,  # [1, 1, GS, D]
-    *rest,  # bps k refs [1, 1, BS, D] (the arena blocks the index maps
-    #   picked), bps v refs; quantized: bps ks refs + bps vs refs ([1, 1,
-    #   1, 1] per-block-per-head scales); then the common refs — qpos
-    #   [1, GS, 1], kvpos [1, bps, 1, BS], out [1, 1, GS, D], scratch
-    #   acc [GS, D] f32, m [GS, 128] f32, l [GS, 128] f32
+    tbl_ref,  # scalar-prefetch [B, T] (index maps + the trash gate)
+    nlive_ref,  # scalar-prefetch [B] — the row's frontier (_live_blocks)
+    start_ref,  # scalar-prefetch [B] — the grid step of the row's cell 0
+    row_ref,  # scalar-prefetch [B·T/bps + 1] — the row grid step i walks
+    q_ref,  # [1, M, D] — every head's query rows, M = Nkv·G·S
+    *rest,  # bps k refs [1, Nkv, BS, D] (the arena blocks the index maps
+    #   picked, ALL key/value heads of each), bps v refs; quantized: bps ks
+    #   refs + bps vs refs ([1, Nkv, 1, 1] per-block-per-head scales); then
+    #   the common refs — qpos [1, M, 1], qhead [M, 1], kvpos [1, bps, 1,
+    #   BS], khead [1, Nkv·BS], out [1, M, D], scratch acc [M, D] f32, m
+    #   [M, 128] f32, l [M, 128] f32
     scale,
-    t_steps,
     bps,
     quantized=False,
 ):
@@ -495,8 +570,12 @@ def _paged_kernel(
     if quantized:
         ks_refs, rest = rest[:bps], rest[bps:]
         vs_refs, rest = rest[:bps], rest[bps:]
-    qpos_ref, kvpos_ref, out_ref, acc_ref, m_ref, l_ref = rest
-    t = pl.program_id(2)
+    (qpos_ref, qhead_ref, kvpos_ref, khead_ref, out_ref, acc_ref, m_ref,
+     l_ref) = rest
+    i = pl.program_id(0)
+    b = row_ref[i]
+    t = i - start_ref[b]  # which cell of the row
+    nlive = nlive_ref[b]
 
     @pl.when(t == 0)
     def _init():
@@ -504,14 +583,20 @@ def _paged_kernel(
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0]  # [GS, D]
-    # bps arena blocks stream per sequential step (auto_blocks_per_step):
-    # each sub-block is its own DMA'd ref, so the compiler overlaps the
-    # bps fetches and double-buffers them across steps; the recurrence
-    # folds them in table order (associative up to fp reassociation —
-    # identical to bps=1 up to the usual flash rounding)
+    q = q_ref[0]  # [M, D]
+    Nkv, BS, D = k_refs[0].shape[1:]
+    # one block's Nkv head tiles are one [Nkv·BS, D] tile, and the score of
+    # EVERY query row against it is one dot: a query row keeps the columns
+    # of its own key/value head (block-diagonal) and of positions it may
+    # attend. Same layout contract as ops/flash_attention._flash_kernel:
+    # qpos/qhead ride sublane-major, kvpos/khead lane-major, so the mask
+    # broadcast maps onto the score tile with no Mosaic relayout. Sentinel
+    # positions (never-written block tails) mask out here.
+    own = qhead_ref[...] == khead_ref[...]  # [M, Nkv·BS]
+    qpos = qpos_ref[0]  # [M, 1]
+    tiles = []
     for j in range(bps):
-        k_blk, v_blk = k_refs[j][0, 0], v_refs[j][0, 0]  # [BS, D]
+        k_blk, v_blk = k_refs[j][0], v_refs[j][0]  # [Nkv, BS, D]
         if quantized:
             # THE fused dequant: the block streamed into VMEM as 1-byte
             # codes (half/quarter the DMA bytes of bf16) and dequantizes
@@ -519,37 +604,32 @@ def _paged_kernel(
             # never exists in HBM. Dequant target is the query dtype,
             # matching the XLA gather path bit for bit.
             k_blk = (
-                k_blk.astype(jnp.float32) * ks_refs[j][0, 0]  # [1, 1]
+                k_blk.astype(jnp.float32) * ks_refs[j][0]  # [Nkv, 1, 1]
             ).astype(q.dtype)
             v_blk = (
-                v_blk.astype(jnp.float32) * vs_refs[j][0, 0]
+                v_blk.astype(jnp.float32) * vs_refs[j][0]
             ).astype(q.dtype)
-        # trash blocks (table entry 0) stream as zeros: their garbage
-        # contents are position-masked to probability 0 below, but
-        # non-finite garbage would still NaN the masked positions
-        # (0 x Inf) through the score and PV products. where(), not
-        # multiply — Inf * 0 is itself NaN.
-        live = tbl_ref[pl.program_id(0), t * bps + j] != 0
-        k = jnp.where(live, k_blk, jnp.zeros_like(k_blk))  # [BS, D]
-        v = jnp.where(live, v_blk, jnp.zeros_like(v_blk))
+        # trash blocks (table entry 0, and what a sub-block past the
+        # frontier inside the frontier's cell names) stream as zeros:
+        # their garbage contents are position-masked to probability 0
+        # below, but non-finite garbage would still NaN the masked
+        # positions (0 x Inf) through the score and PV products. where(),
+        # not multiply — Inf * 0 is itself NaN.
+        idx = t * bps + j
+        live = (idx < nlive) & (tbl_ref[b, idx] != 0)
+        k_blk = jnp.where(live, k_blk, jnp.zeros_like(k_blk))
+        v_blk = jnp.where(live, v_blk, jnp.zeros_like(v_blk))
+        kvpos = jnp.concatenate([kvpos_ref[0, j]] * Nkv, axis=1)
+        tiles.append((
+            k_blk.reshape(Nkv * BS, D), v_blk.reshape(Nkv * BS, D),
+            own & (kvpos <= qpos),
+        ))
+    _online_update(q, tiles, scale, acc_ref, m_ref, l_ref)
 
-        # same layout contract as ops/flash_attention._flash_kernel: qpos
-        # rides sublane-major, kvpos lane-major, so the mask broadcast
-        # maps onto the score tile with no Mosaic relayout. Sentinel
-        # positions (trash-mapped slots, never-written block tails) mask
-        # out here; an all-masked block leaves a NEG_INF running max that
-        # the first real block's correction factor wipes (see the flash
-        # kernel's masking note). kvpos is tiled per BLOCK ([1, BS] rows
-        # of a [B, T, 1, BS] view): a (1, 1, bps·BS) lane tile of the flat
-        # [B, 1, T·BS] array is one Mosaic refuses unless bps·BS is a
-        # multiple of 128 or the whole window.
-        mask = kvpos_ref[0, j] <= qpos_ref[0]  # [GS, BS]
-        _online_update(q, k, v, mask, scale, acc_ref, m_ref, l_ref)
-
-    @pl.when(t == t_steps - 1)
+    @pl.when((t + 1) * bps >= nlive)  # the row's frontier cell
     def _finish():
         l = l_ref[:, :1]
-        out_ref[0, 0] = (acc_ref[:] / jnp.maximum(l, 1e-30)).astype(
+        out_ref[0] = (acc_ref[:] / jnp.maximum(l, 1e-30)).astype(
             out_ref.dtype
         )
 
@@ -571,38 +651,57 @@ def paged_attention_tpu(
     v_scale: jnp.ndarray = None,
     blocks_per_step: int | None = None,  # static; None = auto-selected
 ) -> jnp.ndarray:
-    """Pallas paged attention: grid ``(B, Nkv, T/bps)``, the last axis
-    sequential. Each step DMAs ``bps`` arena blocks (``blocks_per_step``,
-    auto-selected from the table width by ``auto_blocks_per_step`` when
-    None), each a ``(BS, D)`` tile at ``(layer, table[b, t], k)`` of the
-    5-D stacked pool, chosen by the scalar-prefetched layer index and
-    block table — the arena is read where it lies (no slice of a layer, no
-    layout change), the gathered
-    window never exists in HBM, and the ``bps`` per-step fetches are
-    independent refs the compiler overlaps and double-buffers across
-    steps (one skinny (BS, D) DMA per step left the MXU waiting on the
-    fetch turnaround at small serving block sizes). GQA-folded like the
-    flash kernel (each KV block streams once per KV head, not per query
-    head). Decode-shaped: GS = G·S query rows stay in one tile, so keep
-    ``G·S`` small (serving decode is S=1).
+    """Pallas paged DECODE attention whose work is the tokens that are
+    written: ONE sequential grid axis over the LIVE cells of the call, a
+    cell being ``bps`` consecutive table entries of one row
+    (``blocks_per_step``, auto-selected by ``auto_blocks_per_step`` when
+    None), for all key/value heads.
 
-    VMEM per step is bps (BS, D) K blocks + V blocks + the (GS, bps·BS)
-    score tiles + (GS, D)+2·(GS, 128) scratch — ≤ ~400 KB at the auto
-    cap (bps·BS ≤ 512, D=128). Real-TPU use wants D a lane multiple
-    (128) and BS a sublane multiple for the cache dtype;
-    ``paged_attention`` gates on that and interpret-mode covers the rest.
+    The frontier. ``nlive[b]`` (``_live_blocks``) is derived here from the
+    table and the two position arrays — no caller passes it — and with it
+    the walk: row ``b`` has ``ceil(nlive[b] / bps)`` cells, the grid's
+    bound is their sum over the rows (a traced scalar; at least one), and
+    two small arrays name the row of every grid step and the step each
+    row starts at. All three ride as scalar-prefetch operands beside the
+    layer index and the table, so the ``BlockSpec`` index maps pick the arena blocks to DMA
+    straight from the table: a row costs the blocks that are written, a
+    dead row nothing. A skipped block is one the position mask wiped
+    whole, so the result is the whole table's; a row with no live block
+    returns zeros.
+
+    A block's heads together. Each cell DMAs ``bps`` arena blocks, each
+    the ``(Nkv, BS, D)`` tile at ``(layer, table[b, t])`` of the 5-D
+    stacked pool — ALL key/value heads of a block in one contiguous DMA;
+    the arena is read where it lies (no slice of a layer, no layout
+    change), the gathered window never exists in HBM, and the ``bps``
+    fetches are independent refs the compiler overlaps and double-buffers
+    across cells. Every head of a block is scored in ONE dot: the query
+    tile is all ``M = Nkv·G·S`` rows (head ``h = k·G + g``, the fold of
+    ``cached_attention``), a block is one ``[Nkv·BS, D]`` tile, and a
+    query row keeps the columns of its own key/value head — the mask is
+    block-diagonal over heads times ``kv_pos <= q_pos``. The cell's
+    ``bps`` score tiles fold into the running softmax with one rescale
+    (``_online_update``). Decode-shaped: the ``M`` query rows stay in one
+    tile, so keep ``Nh·S`` small (serving decode is S = 1, verify K + 1).
+
+    VMEM per cell is 2 x 2 x bps ``(Nkv, BS, D)`` blocks (K and V, double
+    buffered: 2 MiB at 16 bf16 heads of 32 x 128 and bps 4) + the ``(M,
+    Nkv·BS)`` f32 score tiles + (M, D) + 2·(M, 128) scratch. Real-TPU use
+    wants D a lane multiple (128) and BS a sublane multiple for the cache
+    dtype; ``paged_attention`` gates on that and interpret mode covers the
+    rest.
 
     Quantized arenas (``k_scale``/``v_scale``): the per-block DMA moves
     1-byte codes — HALF (int8 vs bf16) the per-step attention HBM traffic
-    — plus each block's per-head scale as a one-element VMEM tile
-    (``_scale_operand``), and the dequant multiply runs in VMEM right
-    before the score dot (the hook PR 6 left open). Int8 tiles want BS a
-    multiple of 32 (1-byte sublane — ``kernel_eligible``)."""
+    — plus the block's ``Nkv`` per-head scales as one ``(Nkv, 1, 1)`` VMEM
+    tile (``_scale_operand``), and the dequant multiply runs in VMEM right
+    before the score dot. Int8 tiles want BS a multiple of 32 (1-byte
+    sublane — ``kernel_eligible``)."""
     B, S, Nh, D = q.shape
     Nkv, BS = k_arena.shape[2], k_arena.shape[3]
     T = block_table.shape[1]
     G = Nh // Nkv
-    GS = G * S
+    M = Nh * S
     quantized = k_scale is not None
     if scale is None:
         scale = D ** -0.5
@@ -611,44 +710,75 @@ def paged_attention_tpu(
             f"kv_positions must be [B, T*BS]={B, T * BS}, got "
             f"{kv_positions.shape}"
         )
-    bps = blocks_per_step or auto_blocks_per_step(T, BS)
+    bps = blocks_per_step or auto_blocks_per_step(T, BS, Nkv)
     if T % bps != 0:
         raise ValueError(
             f"blocks_per_step={bps} does not divide the table width {T}"
         )
 
-    # GQA fold (the reshape contract of cached_attention: head h = k*G + g)
-    qh = jnp.transpose(q, (0, 2, 1, 3)).reshape(B, Nkv, GS, D)
-    qp = jnp.tile(q_positions, (1, G))[..., None]  # [B, GS, 1]
+    # GQA fold (the reshape contract of cached_attention: head h = k*G + g):
+    # query row r = (k*G + g)*S + s belongs to key/value head r // (G*S)
+    # and sits at position q_positions[s]
+    qh = jnp.transpose(q, (0, 2, 1, 3)).reshape(B, M, D)
+    qp = jnp.tile(q_positions, (1, Nh))[..., None]  # [B, M, 1]
+    qhead = (np.arange(M, dtype=np.int32) // (G * S))[:, None]  # [M, 1]
+    khead = (np.arange(Nkv * BS, dtype=np.int32) // BS)[None]  # [1, Nkv*BS]
     kp = kv_positions.reshape(B, T, 1, BS)  # one [1, BS] lane row per block
 
-    # the arena-block specs: each grid cell streams the bps blocks the
+    # the walk: the rows' live cells laid end to end — grid step i is cell
+    # ``i - start[b]`` of row ``b = row_of[i]``. Steps past the cells' sum
+    # never run, but the pipeline evaluates the index maps one step AHEAD
+    # of the one it runs, so ``row_of`` holds one entry more than the most
+    # steps there can be (and the index maps keep the cell inside the
+    # table)
+    nlive = _live_blocks(block_table, q_positions, kv_positions)
+    cells = -(-nlive // bps)
+    ends = jnp.cumsum(cells)
+    start = ends - cells
+    step = jnp.arange(B * (T // bps) + 1, dtype=jnp.int32)
+    row_of = jnp.minimum(
+        jnp.sum(step[:, None] >= ends[None, :], axis=1, dtype=jnp.int32),
+        B - 1,
+    )  # the rows whose cells end at or before step i
+
+    # the arena-block specs: a cell streams the bps blocks the
     # scalar-prefetched table names out of the scalar-prefetched layer of
-    # the stacked pool (one ref per sub-block — independent DMAs; the
-    # layer dim is squeezed, so the kernel sees the same [1, 1, BS, D]
-    # refs as ever); quantized runs add each block's per-head scale,
-    # picked by the same indices out of a [L, NB, Nkv, 1, 1] view (see
-    # _scale_operand)
-    def arena_index(b, k, t, lyr, tbl, j):
-        return (lyr[0], tbl[b, t * bps + j], k, 0, 0)
+    # the stacked pool, every head of each (one ref per sub-block —
+    # independent DMAs; the layer dim is squeezed). A sub-block past the
+    # frontier inside the row's last cell names the trash block.
+    # Quantized runs add each block's per-head scales, picked by the same
+    # indices out of a [L, NB, Nkv, 1, 1] view (see _scale_operand).
+    def cell(i, b, st):
+        return jnp.minimum(i - st[b], T // bps - 1)
+
+    def arena_index(i, lyr, tbl, nl, st, row, j):
+        b = row[i]
+        idx = cell(i, b, st) * bps + j
+        return (lyr[0], jnp.where(idx < nl[b], tbl[b, idx], 0), 0, 0, 0)
 
     def block_spec(j):
         return pl.BlockSpec(
-            (None, 1, 1, BS, D), functools.partial(arena_index, j=j)
+            (None, 1, Nkv, BS, D), functools.partial(arena_index, j=j)
         )
 
     def scale_spec(j):
         return pl.BlockSpec(
-            (None, 1, 1, 1, 1), functools.partial(arena_index, j=j)
+            (None, 1, Nkv, 1, 1), functools.partial(arena_index, j=j)
         )
 
+    def of_row(i, lyr, tbl, nl, st, row):
+        return (row[i], 0, 0)
+
+    def whole(i, lyr, tbl, nl, st, row):
+        return (0, 0)
+
     in_specs = [
-        pl.BlockSpec((1, 1, GS, D), lambda b, k, t, lyr, tbl: (b, k, 0, 0)),
+        pl.BlockSpec((1, M, D), of_row),
         *[block_spec(j) for j in range(bps)],
         *[block_spec(j) for j in range(bps)],
     ]
     operands = [
-        _layer_operand(layer), block_table, qh,
+        _layer_operand(layer), block_table, nlive, start, row_of, qh,
         *([k_arena] * bps), *([v_arena] * bps),
     ]
     if quantized:
@@ -660,40 +790,43 @@ def paged_attention_tpu(
             [_scale_operand(k_scale)] * bps + [_scale_operand(v_scale)] * bps
         )
     in_specs += [
-        pl.BlockSpec((1, GS, 1), lambda b, k, t, lyr, tbl: (b, 0, 0)),
+        pl.BlockSpec((1, M, 1), of_row),
+        pl.BlockSpec((M, 1), whole),
         pl.BlockSpec(
-            (1, bps, 1, BS), lambda b, k, t, lyr, tbl: (b, t, 0, 0)
+            (1, bps, 1, BS),
+            lambda i, lyr, tbl, nl, st, row: (
+                row[i], cell(i, row[i], st), 0, 0
+            ),
         ),
+        pl.BlockSpec((1, Nkv * BS), whole),
     ]
-    operands += [qp, kp]
+    operands += [qp, qhead, kp, khead]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, Nkv, T // bps),
+        num_scalar_prefetch=5,
+        grid=(jnp.maximum(ends[-1], 1),),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, GS, D), lambda b, k, t, lyr, tbl: (b, k, 0, 0)
-        ),
+        out_specs=pl.BlockSpec((1, M, D), of_row),
         scratch_shapes=[
-            pltpu.VMEM((GS, D), jnp.float32),
-            pltpu.VMEM((GS, 128), jnp.float32),
-            pltpu.VMEM((GS, 128), jnp.float32),
+            pltpu.VMEM((M, D), jnp.float32),
+            pltpu.VMEM((M, 128), jnp.float32),
+            pltpu.VMEM((M, 128), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(
-            _paged_kernel, scale=scale, t_steps=T // bps, bps=bps,
-            quantized=quantized,
+            _paged_kernel, scale=scale, bps=bps, quantized=quantized,
         ),
-        out_shape=jax.ShapeDtypeStruct((B, Nkv, GS, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, M, D), q.dtype),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
         name="paged_decode",
     )(*operands)
-    out = out.reshape(B, Nkv, G, S, D)
-    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(B, S, Nh, D)
+    # a row the walk never visited was never written: it reads zeros
+    out = jnp.where((nlive > 0)[:, None, None], out, jnp.zeros_like(out))
+    return jnp.transpose(out.reshape(B, Nh, S, D), (0, 2, 1, 3))
 
 
 #: Query-row tile of the chunked-prefill kernel (G·Sc folded rows per
@@ -758,7 +891,7 @@ def _paged_prefill_kernel(
         # at position p attends exactly the prefix ≤ p — earlier chunks,
         # the radix prefix, and the chunk's own earlier tokens.
         mask = kvpos_ref[0, j] <= qpos_ref[0]  # [BQ, BS]
-        _online_update(q, k, v, mask, scale, acc_ref, m_ref, l_ref)
+        _online_update(q, [(k, v, mask)], scale, acc_ref, m_ref, l_ref)
 
     @pl.when(t == t_steps - 1)
     def _finish():
@@ -935,9 +1068,9 @@ def _ineligible_msg(op: str, k_arena, block_table) -> str:
         f"block_size={k_arena.shape[3]} / block table [{rows}, {width}] are "
         f"not Mosaic-eligible for cache dtype "
         f"{jnp.dtype(k_arena.dtype).name} (head_dim must be a multiple of "
-        f"128, the block size a sublane multiple, and the table must fit "
-        f"{SMEM_TABLE_BUDGET} bytes of scalar memory — see "
-        f"kernel_eligible); use backend='auto' or 'xla'"
+        f"128, the block size a sublane multiple, and the table with the "
+        f"decode kernel's walk must fit {SMEM_TABLE_BUDGET} bytes of scalar "
+        f"memory — see kernel_eligible); use backend='auto' or 'xla'"
     )
 
 
@@ -986,7 +1119,7 @@ def paged_prefill(
     eligible = kernel_eligible(
         q.shape[-1], k_arena.shape[3], k_arena.dtype,
         rows=block_table.shape[0],
-        table_width=block_table.shape[1],
+        table_width=block_table.shape[1], kv_heads=k_arena.shape[2],
     )
     if backend == "interpret":
         return paged_prefill_tpu(
@@ -1066,7 +1199,7 @@ def paged_attention(
     eligible = kernel_eligible(
         q.shape[-1], k_arena.shape[3], k_arena.dtype,
         rows=block_table.shape[0],
-        table_width=block_table.shape[1],
+        table_width=block_table.shape[1], kv_heads=k_arena.shape[2],
     )
     if backend == "interpret":
         return paged_attention_tpu(

@@ -76,7 +76,8 @@ import jax.numpy as jnp
 
 from ..obs.metrics import (
     ARENA_BYTES, ATTN_BACKEND, ATTN_BACKENDS, ATTN_BLOCKS_READ,
-    CP_STREAM_SHARDS, DEFAULT_RATE_BUCKETS,
+    CP_STREAM_SHARDS, DECODE_BLOCKS_LIVE, DECODE_BLOCKS_RESERVED,
+    DEFAULT_RATE_BUCKETS,
     KV_BLOCKS_IN_USE, KV_BLOCKS_TOTAL, KV_DISK_TIER_BLOCKS,
     KV_HOST_TIER_BLOCKS, KV_WASTE_FRAC, MOE_EXPERT_TOKENS, MOE_EXPERTS_READ,
     PREFILL_BLOCKS_READ, PREFILL_POSITIONS, PREFIX_HIT_RATE,
@@ -1542,6 +1543,7 @@ class PipelineServer:
         eligible = kernel_eligible(
             self.cfg.head_dim_, self.kv_block_size, self.kv_store_dtype,
             rows=self.batch_per_slot, table_width=table_width,
+            kv_heads=max(self.cfg.num_key_value_heads // self.tp, 1),
         )
 
         def check_kernel(source: str) -> None:
@@ -1565,7 +1567,9 @@ class PipelineServer:
                     f"the dtype's sublane count ({sublane} for "
                     f"{jnp.dtype(self.kv_store_dtype).name}), and the "
                     f"table (batch_per_slot x ceil(capacity / "
-                    f"kv_block_size), rows padded to 128 entries) must "
+                    f"kv_block_size), rows padded to 128 entries) and the "
+                    f"decode kernel's walk over it (an entry per group of "
+                    f"blocks of every row) must "
                     f"fit {SMEM_TABLE_BUDGET} bytes of scalar memory — "
                     f"see ops/paged_attention.kernel_eligible; use "
                     f"paged_attn='auto' or 'xla'"
@@ -1583,21 +1587,33 @@ class PipelineServer:
             return forced
         return "kernel" if (on_tpu and eligible) else "xla"
 
-    def _record_blocks_read(self, rows, steps: int = 1) -> None:
-        """Feed ``server_attn_blocks_read_total`` from the host length
-        mirrors: an estimate (mirrors trail the device by the in-flight
-        chunk) of the arena blocks each row's decode attention streams —
-        ``ceil(len / block_size)`` per row per decode/verify step. The
-        bench multiplies by block bytes × layers for its
-        attention-bytes-per-step figure."""
+    def _record_blocks_read(self, rows, served: int, steps: int = 1) -> None:
+        """Feed the decode-attention block counters from the host length
+        mirrors (an estimate: mirrors trail the device by the in-flight
+        chunk), for ``steps`` decode/verify steps over the live ``rows``
+        out of the ``served`` rows the kernel is called for.
+        ``server_attn_blocks_read_total``: the blocks each row's tokens
+        fill, ``ceil(len / block_size)`` — the bench multiplies by block
+        bytes × layers for its attention-bytes-per-step figure.
+        ``server_decode_blocks_live_total`` / ``_reserved_total`` and the
+        step record: the table entries the decode kernel walks — up to the
+        row's written COLUMN, admission padding included (``len`` + the
+        row's slot − position delta) — against the ``served`` × table
+        width entries the tables reserve."""
         if not self.paged:
             return
         bs = self.kv_block_size
-        blocks = sum(
-            -(-max(int(self._mirror_len[r]), 1) // bs) for r in rows
-        )
+        blocks = live = 0
+        for r in rows:
+            n = max(int(self._mirror_len[r]), 1)
+            blocks += -(-n // bs)
+            live += -(-(n + int(self._mirror_cachedelta[r])) // bs)
+        reserved = served * self._tables.shape[1]
         if blocks:
             ATTN_BLOCKS_READ.inc(blocks * steps)
+            DECODE_BLOCKS_LIVE.inc(live * steps)
+        DECODE_BLOCKS_RESERVED.inc(reserved * steps)
+        self.stepline.decode_blocks(live * steps, reserved * steps)
 
     # ------------------------------------------------------------------ API
 
@@ -2669,7 +2685,7 @@ class PipelineServer:
         self._record_blocks_read(
             [i for i, r in enumerate(self._rows)
              if r is not None and not r.done],
-            steps=self.chunk_cycles,
+            served=len(self._rows), steps=self.chunk_cycles,
         )
         self.stepline.pop()
         dt_dispatch = time.perf_counter() - t0
@@ -4670,7 +4686,7 @@ class PipelineServer:
                     ],
                 )
             )
-            self._record_blocks_read([row for row, _ in live])
+            self._record_blocks_read([row for row, _ in live], served=Bs)
             self.counters.inc("chunks")
 
     def _apply_spec(self, log: np.ndarray, entries: list) -> None:

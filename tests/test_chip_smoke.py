@@ -61,10 +61,35 @@ def test_kernel_check_runs_every_variant_in_interpret_mode():
         (k, d) for k in ("paged_decode", "paged_prefill")
         for d in ("bf16", "int8", "fp8")
     } | {("flash", "bf16")}
+    # the decode kernel in each state of its walk, over every arena
+    assert {(c["kv_dtype"], c["state"]) for c in cases
+            if c["kernel"] == "paged_decode"} == {
+        (d, s) for d in ("bf16", "int8", "fp8")
+        for s in chip_smoke.DECODE_STATES
+    }
     for case in cases:
         assert case["table_width"] == 16 and case["block_size"] == 8
         err = chip_smoke.check_kernel(cfg, case, "interpret")
         assert err <= chip_smoke.KERNEL_TOL, (case, err)
+    # one live row at an eighth of the table beside dead rows; every row at
+    # the full table
+    _, _, nlive = chip_smoke.kernel_inputs(cfg, next(
+        c for c in cases if c["state"] == "one_row_eighth"))
+    assert list(nlive) == [2, 0, 0, 0]
+    _, _, nlive = chip_smoke.kernel_inputs(cfg, next(
+        c for c in cases if c["state"] == "all_rows_full"))
+    assert list(nlive) == [16] * 4
+
+
+def test_decode_timing_runs_in_interpret_mode():
+    """The chip check's timing of the decode kernel against the XLA path
+    (``time_decode``), at toy size: both sides run and report a time."""
+    cfg = chip_smoke.model_config(TINY)
+    case = next(c for c in chip_smoke.kernel_cases(TINY)
+                if c["state"] == "one_row_eighth" and c["kv_dtype"] == "bf16")
+    times = chip_smoke.time_decode(cfg, case, "interpret", calls=2)
+    assert set(times) == {"kernel_ms", "xla_ms"}
+    assert all(t > 0 for t in times.values())
 
 
 @pytest.mark.parametrize("rows", [4, 160])

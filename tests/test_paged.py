@@ -31,7 +31,7 @@ from llm_sharding_tpu.runtime.generate import generate
 from llm_sharding_tpu.runtime.server import PipelineServer
 
 from paged_arena import (
-    LAYER_CASES, LAYERS, make_stack, others_untouched, window,
+    LAYER_CASES, LAYERS, int8_stack, make_stack, others_untouched, window,
 )
 
 CFG = tiny_llama(num_hidden_layers=8)
@@ -633,6 +633,97 @@ def test_paged_attention_pallas_interpret_multiquery_matches_xla():
     )
 
 
+def _frontier_case(seed, S, Nkv, kv_dtype, T=8, bs=4):
+    """Four rows at four frontiers in ONE call, over a stack of ``LAYERS``
+    different layers: row 0 dead, row 1 one block, row 2 a frontier inside
+    a group of four blocks with a TRASH entry below it, row 3 the full
+    table. The dead row is a finished row as each decode program leaves
+    it: S = 1 (``serve_chunk``) a real query position over a table the
+    host remapped to trash; S > 1 (``serve_verify``) sentinel queries over
+    a table still mapped. Returns the ops' positional arguments, the scale
+    keywords, and the expected live blocks per row."""
+    from llm_sharding_tpu.models.cache import POS_SENTINEL
+
+    rng = np.random.default_rng([seed, S, Nkv, kv_dtype == "int8"])
+    G, D, B = 2, 16, 4
+    Nh, W, NB = Nkv * G, T * bs, 4 * T + 1
+    k_arena, v_arena = make_stack(rng, NB, Nkv, bs, D)
+    scales = {}
+    if kv_dtype == "int8":
+        k_arena, v_arena, scales = int8_stack(rng, k_arena, v_arena)
+    nlive = np.array([0, 1, 6, T])
+    # tokens in the window, the S in flight included (their KV is written
+    # before the kernel runs)
+    ctx = np.array([9, max(S, 2), 6 * bs - 1, T * bs])
+    ids = rng.permutation(np.arange(1, NB))
+    tbl = np.zeros((B, T), np.int32)
+    for b in range(B):
+        mapped = T if b == 3 else min(nlive[b] + 1, T)  # + a budget block
+        tbl[b, :mapped] = ids[b * T: b * T + mapped]
+    tbl[2, 2] = 0  # trash below row 2's frontier
+    cols = np.arange(W)[None]
+    kvpos = np.where(cols < ctx[:, None], cols, int(POS_SENTINEL))
+    qpos = (ctx - S)[:, None] + np.arange(S)[None]
+    if S == 1:
+        tbl[0] = 0  # finished, remapped to trash; its position stays real
+    else:
+        tbl[0, :3] = ids[-3:]
+        qpos[0] = int(POS_SENTINEL)
+    q = jnp.asarray(rng.normal(size=(B, S, Nh, D)), jnp.float32)
+    args = (q, k_arena, v_arena, 1, jnp.asarray(tbl),
+            jnp.asarray(qpos, jnp.int32), jnp.asarray(kvpos, jnp.int32))
+    return args, scales, nlive
+
+
+@pytest.mark.parametrize("kv_dtype", ("bf16", "int8"))
+@pytest.mark.parametrize("Nkv", (1, 4, 16))
+@pytest.mark.parametrize("S", (1, 3))
+def test_decode_walk_ends_at_each_rows_frontier(S, Nkv, kv_dtype):
+    """The decode kernel (interpret) walks each row to its written
+    frontier and no further, all key/value heads of a block in one tile:
+    rows at four frontiers in one call — dead, one block, inside a
+    ``bps`` group with a trash entry below it, the full table — at S = 1
+    and verify-shaped S = 3, ``Nkv`` 1 / 4 / 16, float and int8 arenas.
+    ``_live_blocks`` reads the frontiers off the operands; live rows equal
+    the XLA gather and the single-block grid; the dead row comes back
+    zeros; and the cells the walk skips contribute NOTHING: a row's output
+    is bit for bit that of the same call on a table cut off at the row's
+    frontier cell."""
+    from llm_sharding_tpu.ops import paged_attention as pa
+
+    args, scales, nlive = _frontier_case(5, S, Nkv, kv_dtype)
+    q, ka, va, layer, tbl, qpos, kvpos = args
+    bs = ka.shape[3]
+    np.testing.assert_array_equal(
+        np.asarray(pa._live_blocks(tbl, qpos, kvpos)), nlive
+    )
+    want = np.asarray(pa.paged_attention_xla(*args, **scales))
+    single = np.asarray(pa.paged_attention_tpu(
+        *args, interpret=True, blocks_per_step=1, **scales
+    ))
+    live = nlive > 0
+    for bps in (4, 8):
+        got = np.asarray(pa.paged_attention_tpu(
+            *args, interpret=True, blocks_per_step=bps, **scales
+        ))
+        assert not got[~live].any()
+        np.testing.assert_allclose(got[live], want[live], rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(
+            got[live], single[live], rtol=2e-6, atol=2e-6
+        )
+        for b in np.flatnonzero(live) if bps == 4 else ():
+            width = -(-nlive[b] // bps) * bps  # the frontier cell's end
+            cut = np.asarray(pa.paged_attention_tpu(
+                q, ka, va, layer, tbl[:, :width], qpos,
+                kvpos[:, : width * bs], interpret=True,
+                blocks_per_step=bps, **scales,
+            ))
+            np.testing.assert_array_equal(got[b], cut[b])
+    # S = 1: the dead row attends zeros on the XLA path too
+    if S == 1:
+        assert not want[~live].any()
+
+
 @pytest.mark.parametrize("layer", LAYER_CASES)
 @pytest.mark.parametrize("kernel", ("decode", "prefill"))
 @pytest.mark.parametrize("kv_dtype", ("bf16", "int8"))
@@ -648,7 +739,6 @@ def test_kernels_read_the_layer_they_are_given(kv_dtype, kernel, layer):
     (codes and scales) untouched, and the kernel must see the entry."""
     from llm_sharding_tpu.models.cache import POS_SENTINEL
     from llm_sharding_tpu.ops import paged_attention as pa
-    from llm_sharding_tpu.ops.quant import kv_qmax
 
     rng = np.random.default_rng([23, layer, kernel == "prefill"])
     B, T, bs, Nkv, G, D = 2, 4, 8, 2, 2, 16
@@ -658,15 +748,7 @@ def test_kernels_read_the_layer_they_are_given(kv_dtype, kernel, layer):
     k_arena, v_arena = make_stack(rng, NB, Nkv, bs, D, dt)
     scales = {}
     if kv_dtype == "int8":
-        qmax = kv_qmax(jnp.int8)
-        k_arena, v_arena = (
-            jnp.asarray(np.round(np.clip(
-                np.asarray(a) * (qmax / 3.0), -qmax, qmax)), jnp.int8)
-            for a in (k_arena, v_arena)
-        )
-        sc = rng.uniform(0.5, 1.5, (2, LAYERS, NB, Nkv)) * (3.0 / qmax)
-        scales = {"k_scale": jnp.asarray(sc[0], jnp.float32),
-                  "v_scale": jnp.asarray(sc[1], jnp.float32)}
+        k_arena, v_arena, scales = int8_stack(rng, k_arena, v_arena)
     tbl = jnp.asarray([[3, 5, 8, 0], [7, 2, 0, 0]], jnp.int32)
     lengths = np.array([2 * bs + 3, bs + 1])  # context behind the queries
     cols = np.arange(W)[None]
@@ -736,9 +818,14 @@ def test_paged_attn_kwarg_validation(setup):
 def test_kernel_rules_learned_from_the_v5e_compiler(monkeypatch):
     """What Mosaic refused during bring-up stays refused — or repaired.
 
-    Shape rule: the scalar-prefetched block table must fit scalar memory
-    (``[128, 2048]`` int32 "exceeded smem capacity"; ``[120, 2048]`` and
-    ``[2000, 33]`` — rows pad to 128 entries — compiled). Repairs: a
+    Shape rule: the scalar-prefetched block table, and since PR 28 the
+    decode kernel's walk beside it (an entry per cell of every row), must
+    fit scalar memory: ``[128, 2048]`` int32 alone "exceeded smem
+    capacity"; with the walk at 4 key/value heads ``[120, 2048]`` exceeds
+    it by 62.1K and ``[2000, 33]`` (an odd width: one block a cell) by
+    253.1K, while ``[104, 2048]`` and ``[1500, 33]`` — rows pad to 128
+    entries — compile (AOT compiles of ``paged_attention_tpu`` for a
+    described v5e; PERF.md, PR 28). Repairs: a
     ``kv_positions`` tile that is neither 128 lanes wide nor the whole
     window (odd table width at block 16) and the int8/fp8 scale operand
     (a ``(1, 1)`` block of ``[NB, Nkv]``) now lower for the TPU platform —
@@ -749,11 +836,18 @@ def test_kernel_rules_learned_from_the_v5e_compiler(monkeypatch):
         kernel_eligible, paged_attention_tpu, paged_prefill_tpu,
     )
 
-    ok = dict(head_dim=128, block_size=16, cache_dtype=jnp.bfloat16)
+    ok = dict(head_dim=128, block_size=16, cache_dtype=jnp.bfloat16,
+              kv_heads=4)
     assert not kernel_eligible(**ok, rows=128, table_width=2048)
-    assert kernel_eligible(**ok, rows=120, table_width=2048)
-    assert kernel_eligible(**ok, rows=2000, table_width=33)
+    assert not kernel_eligible(**ok, rows=120, table_width=2048)
+    assert kernel_eligible(**ok, rows=104, table_width=2048)
+    assert not kernel_eligible(**ok, rows=2000, table_width=33)
+    assert kernel_eligible(**ok, rows=1500, table_width=33)
     assert not kernel_eligible(**ok, rows=4000, table_width=33)
+    # more heads a block, fewer blocks a cell, a longer walk
+    assert not kernel_eligible(**{**ok, "kv_heads": 32}, rows=100,
+                               table_width=2048)
+    assert kernel_eligible(**ok, rows=100, table_width=2048)
 
     S = jax.ShapeDtypeStruct
     B, Nh, Nkv, D, NB, Lp = 4, 28, 4, 128, 64, 3  # G = 7: Qwen2.5-7B's fold
@@ -862,6 +956,66 @@ def test_kernels_compile_for_a_described_v5e(v5e_chip, cell, kernel, store):
     arena_elems = Lp * NB * Nkv * BS * D
     for m in re.finditer(r"= \w+\[([\d,]+)\][^ ]* (copy|transpose)\(", text):
         assert np.prod([int(x) for x in m.group(1).split(",")]) < arena_elems
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation under ``jaxpr``, inner jaxprs walked."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        else:
+            for sub in _inner_jaxprs(eqn):
+                yield from _pallas_calls(sub)
+
+
+def _block_shapes(eqn):
+    """A ``pallas_call``'s operand and result blocks as the kernel sees
+    them, from its grid mapping: one tuple per block, a squeezed dim None."""
+    return [
+        tuple(getattr(d, "block_size", None) for d in bm.block_shape)
+        for bm in eqn.params["grid_mapping"].block_mappings
+    ]
+
+
+@pytest.mark.parametrize("store", ["bf16", "int8"])
+@pytest.mark.parametrize("cell", sorted(_CELL_SHAPES))
+def test_decode_kernel_takes_a_blocks_heads_together(cell, store):
+    """The decode kernel's ONE grid at the three cells' shapes, read from
+    the traced ``pallas_call`` (nothing runs): a single axis whose bound is
+    a traced scalar (the call's live cells) — no head axis, no row axis —,
+    five scalar-prefetch operands (layer, table, the frontier, each
+    step's row and cell), and every arena operand block ``(Nkv, BS, D)``
+    wide: all key/value heads of one block of the squeezed layer; an int8
+    arena's scale block is the block's ``Nkv`` scales."""
+    from llm_sharding_tpu.ops import paged_attention as pa
+
+    Nh, Nkv = _CELL_SHAPES[cell]
+    B, T, BS, D, Lp, NB = 4, 128, 32, 128, 3, 260
+    S = jax.ShapeDtypeStruct
+    arena = S((Lp, NB, Nkv, BS, D),
+              jnp.bfloat16 if store == "bf16" else jnp.int8)
+    scale = S((Lp, NB, Nkv), jnp.float32) if store == "int8" else None
+    jaxpr = jax.make_jaxpr(
+        lambda q, k, v, l, t, qp, kp, ks, vs: pa.paged_attention_tpu(
+            q, k, v, l, t, qp, kp, k_scale=ks, v_scale=vs
+        )
+    )(
+        S((B, 1, Nh, D), jnp.bfloat16), arena, arena, S((), jnp.int32),
+        S((B, T), jnp.int32), S((B, 1), jnp.int32),
+        S((B, T * BS), jnp.int32), scale, scale,
+    )
+    (call,) = _pallas_calls(jaxpr.jaxpr)
+    gm = call.params["grid_mapping"]
+    bps = pa.auto_blocks_per_step(T, BS, Nkv)
+    assert len(gm.grid) == 1 and not isinstance(gm.grid[0], int)
+    assert gm.num_index_operands == 5
+    blocks = _block_shapes(call)
+    assert blocks.count((None, 1, Nkv, BS, D)) == 2 * bps  # K and V
+    assert blocks.count((None, 1, Nkv, 1, 1)) == (
+        2 * bps if store == "int8" else 0
+    )
+    # nothing else of the pool reaches the kernel
+    assert not [b for b in blocks if len(b) == 5 and b[2] != Nkv]
 
 
 @pytest.mark.parametrize("weights", ["int8", "bf16"])
@@ -1303,6 +1457,35 @@ def test_no_arena_sized_copy_in_a_step_program(
     assert _arena_sized_offenders(
         _leaf_eqns(jaxpr.jaxpr), stack_shape, _IN_PLACE | _RELABEL
     ) == []
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("program", ["serve_chunk", "serve_verify"])
+def test_a_layer_scan_holds_one_decode_kernel_over_whole_blocks(
+    traced_programs, program, kv_dtype
+):
+    """The decode programs as a server dispatched them: every layer scan
+    that carries the arena holds exactly ONE ``pallas_call``, named
+    ``paged_decode``, and its arena operand blocks are ``(Nkv, BS, D)``
+    wide — a block's key/value heads together, the layer dim squeezed."""
+    jaxprs, stack_shape, _, _ = traced_programs
+    jaxpr = jaxprs[program, kv_dtype]
+    _, _, Nkv, BS, D = stack_shape
+    scans = list(_layer_scans(jaxpr.jaxpr, stack_shape[1:]))
+    assert scans
+    if not list(_pallas_calls(jaxpr.jaxpr)):
+        # the XLA backend: the same scans read the pool by a gather
+        for scan in scans:
+            names = {e.primitive.name
+                     for e in _leaf_eqns(scan.params["jaxpr"].jaxpr)}
+            assert "gather" in names
+        return
+    for scan in scans:
+        (call,) = _pallas_calls(scan.params["jaxpr"].jaxpr)
+        assert call.params["name"] == "paged_decode"
+        arena_blocks = [b for b in _block_shapes(call) if len(b) == 5
+                        and b[-1] == D]
+        assert arena_blocks and set(arena_blocks) == {(None, 1, Nkv, BS, D)}
 
 
 def test_the_structural_check_sees_a_sliced_out_layer():

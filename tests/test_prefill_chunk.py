@@ -148,6 +148,12 @@ def test_auto_blocks_per_step():
     assert auto_blocks_per_step(64, 64) == 8
     assert auto_blocks_per_step(64, 512) == 1  # tile cap
     assert auto_blocks_per_step(6, 8) == 2
+    # the decode kernel takes a block's key/value heads together: the
+    # step's score tile is bps x block_size x heads lanes wide
+    assert auto_blocks_per_step(128, 32, 4) == 8  # Qwen2.5-7B
+    assert auto_blocks_per_step(128, 32, 8) == 8  # a Qwen2.5-14B stage
+    assert auto_blocks_per_step(128, 32, 16) == 4  # OLMoE-1B-7B
+    assert auto_blocks_per_step(128, 128, 32) == 1
 
 
 def test_paged_prefill_backend_validation():

@@ -840,6 +840,72 @@ def test_a_traced_server_leaves_its_spans_in_the_host_plane(params, tmp_path):
     assert after["pad"] - before["pad"] == 11
 
 
+def test_decode_blocks_reach_the_record():
+    p = StepProfiler(name="t-decode-blocks")
+    p.begin_step(rows=1)
+    p.decode_blocks(live=33, reserved=512)
+    p.decode_blocks(live=34, reserved=512)
+    rec = p.end_step(rows=1, tokens=2)
+    assert (rec.decode_blocks_live, rec.decode_blocks_reserved) == (67, 1024)
+    d = rec.to_dict()
+    assert (d["decode_blocks_live"], d["decode_blocks_reserved"]) == (67, 1024)
+    # the next step starts from nothing; outside a step nothing is counted
+    p.begin_step()
+    d = p.end_step().to_dict()
+    assert (d["decode_blocks_live"], d["decode_blocks_reserved"]) == (0, 0)
+    p.decode_blocks(live=1, reserved=1)
+    p.begin_step()
+    assert p.end_step().decode_blocks_reserved == 0
+
+
+def test_a_paged_server_counts_the_walk_and_the_reservation(params):
+    """The decode kernel's walk, counted on the host from the length
+    mirrors: every decode chunk reserves rows x table width entries and
+    walks each LIVE row's blocks up to its written column (the admission
+    bucket's padding included) — a dead row, and a dead slot, walk 0. The
+    step records and ``/metrics`` carry the same two sums."""
+    eng = PipelineEngine(
+        CFG, params, num_stages=2, devices=jax.devices()[:2],
+        cache_dtype=jnp.float32,
+    )
+    bs, cap = 8, 64
+    srv = eng.serve(
+        capacity=cap, batch_per_slot=2, kv_block_size=bs, kv_blocks=65
+    )
+    live_c = REGISTRY.get("server_decode_blocks_live_total")
+    resv_c = REGISTRY.get("server_decode_blocks_reserved_total")
+    before = (live_c.value, resv_c.value, srv.counters.snapshot()["chunks"])
+    n0 = srv.stepline.steps_total
+    new = 12
+    srv.submit(prompt(90, n=5), new)  # one row of one slot; bucket 8
+    srv.run_until_idle()
+    for _ in range(3):
+        srv.step()
+    recs = srv.stepline_snapshot()[n0 - srv.stepline.steps_total:]
+    srv.close()
+    live = sum(r["decode_blocks_live"] for r in recs)
+    reserved = sum(r["decode_blocks_reserved"] for r in recs)
+    chunks = srv.counters.snapshot()["chunks"] - before[2]
+    assert chunks >= new - 1
+    # 2 slots x 2 rows, a table of capacity / block_size entries, per chunk
+    assert reserved == chunks * 4 * (cap // bs)
+    # one live row, written columns 8 (the bucket) .. 8 + new: 2 or 3
+    # blocks a step (the mirrors trail the device by the token in flight,
+    # so the first step still reads the bucket's 1); the other three rows
+    # walk nothing
+    busy = [r["decode_blocks_live"] for r in recs if r["decode_blocks_live"]]
+    assert len(busy) >= new - 1
+    assert busy == sorted(busy) and set(busy[1:]) == {2, 3}
+    assert 0 < live <= 3 * chunks and live < reserved / 8
+    # steps after the reply ended dispatched nothing
+    assert recs[-1]["decode_blocks_reserved"] == 0
+    assert live_c.value - before[0] == live
+    assert resv_c.value - before[1] == reserved
+    text = REGISTRY.prometheus_text()
+    assert "\nserver_decode_blocks_live_total " in text
+    assert "\nserver_decode_blocks_reserved_total " in text
+
+
 def test_chunked_admission_counts_every_chunk_and_agrees_with_the_buckets(
         params):
     """The program's own count of prefill positions equals what the
