@@ -177,6 +177,15 @@ def attn_mlp_block(
             kx = kx + p["bk"]
         if "bv" in p:
             vx = vx + p["bv"]
+        # k and v leave the projection as the dot made them. Without this
+        # edge XLA folds the head split below into the two small dots: a
+        # decode step's ``[B, H] @ [H, Nkv*D]`` becomes a convolution
+        # windowed over the heads that wants ``wk``/``wv`` with the input
+        # dim minor, and the compiled v5e program re-lays the WHOLE layer
+        # stack of both (parameters: they cannot stay transposed) at the
+        # top of every call (tests/test_paged.py holds the compiled program
+        # to it).
+        kx, vx = jax.lax.optimization_barrier((kx, vx))
     if "q_norm" in p:
         # OLMoE: an RMSNorm over the WHOLE projected width of q and of k,
         # before the heads are split and rotated; keyed by presence

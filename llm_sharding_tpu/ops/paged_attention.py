@@ -253,7 +253,8 @@ def write_block_kv(
     cols: jnp.ndarray,  # [B, S] int32 logical columns of the new entries
     k_new: jnp.ndarray,  # [B, S, Nkv, D]
     v_new: jnp.ndarray,  # [B, S, Nkv, D]
-    valid=None,  # scalar or [B, S] bool — False entries keep old contents
+    valid=None,  # scalar or [B, S] bool — False entries go to the trash
+    #   block of their layer; their owning blocks keep their contents
     k_scale: jnp.ndarray = None,  # [L, NB, Nkv] f32 — quantized arenas only
     v_scale: jnp.ndarray = None,
 ):
@@ -269,12 +270,16 @@ def write_block_kv(
     row's mapped budget — the sink's contents are never attended: readers
     gate entry 0 to zeros and position masking excludes them anyway).
 
-    ``valid`` gates at ENTRY granularity — invalid entries write back the
-    values just gathered from the arena, so ring-inactive microsteps and
-    masked pipeline layers stay no-ops without a full-arena ``where``
-    (which would copy the pool per layer per microstep). Collisions
-    (several rows trash-mapped onto the same slot) resolve last-wins:
-    only the sink can collide, and it is a garbage sink by contract.
+    ``valid`` gates at ENTRY granularity and BY ADDRESS — an invalid
+    entry is steered to the trash block of its OWN layer (``(layer, 0, :,
+    slot)``), so ring-inactive microsteps, masked pipeline layers and a
+    verify's rejected positions leave every owned block bit for bit alone
+    without reading it back and without a full-arena ``where`` (which
+    would copy the pool per layer per microstep). Collisions (several
+    rows trash-mapped onto the same slot) resolve last-wins: only the
+    sink can collide, and it is a garbage sink by contract. (The
+    quantised branch below still gates by value: its block rewrite needs
+    the owning block anyway.)
 
     With ``k_scale``/``v_scale`` (quantized int8/fp8 arena) the write
     QUANTIZES AT INSERT against a RUNNING per-block-per-head absmax: a
@@ -300,20 +305,19 @@ def write_block_kv(
     # token-major for the scatter's sake and copies it back, per layer, for
     # the kernel (seen in the compiled v5e program) — the very copies this
     # layout exists to remove.
+    if k_scale is None and valid is not None:
+        # gate by ADDRESS: an invalid entry lands in the trash block of its
+        # own layer, so its owning block is never read back
+        blk = jnp.where(valid, blk, 0)
     entry = (
         layer, blk[:, :, None], jnp.arange(Nkv)[None, None, :],
         slot[:, :, None],
     )  # → [B, S, Nkv] rows of D
     if k_scale is None:
-        kn = k_new.astype(k_arena.dtype)
-        vn = v_new.astype(v_arena.dtype)
-        if valid is not None:
-            keep = jnp.asarray(valid)
-            if keep.ndim:  # [B, S] → broadcast over the (Nkv, D) entry dims
-                keep = keep[..., None, None]
-            kn = jnp.where(keep, kn, k_arena[entry])
-            vn = jnp.where(keep, vn, v_arena[entry])
-        return k_arena.at[entry].set(kn), v_arena.at[entry].set(vn)
+        return (
+            k_arena.at[entry].set(k_new.astype(k_arena.dtype)),
+            v_arena.at[entry].set(v_new.astype(v_arena.dtype)),
+        )
 
     qmax = kv_qmax(k_arena.dtype)
     keep = None

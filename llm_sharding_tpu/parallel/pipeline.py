@@ -232,7 +232,7 @@ def ring_chain_paged(fns, cfg, layers, lmask, sidx, ring, num_stages, h,
     whole-cache ``_tree_where`` (which would copy the ARENA — the whole
     pool, not one slot's window — every microstep) down to
     ``write_block_kv``'s per-entry ``valid``, so an inactive microstep's
-    arena update writes back the values it just read. The hidden-state
+    entries land in the layer's trash block. The hidden-state
     gate is unchanged. Quantized arenas carry their scale arenas through
     the loop (None carries are empty pytree nodes — the bf16 path is
     unchanged); returns ``(h, k_arena, v_arena, k_scale, v_scale, stats)``.

@@ -13,7 +13,10 @@ this module at a tiny size (block-boundary + table-growth stress) without a
 second test body.
 """
 
+import json
 import os
+import re
+from unittest import mock
 
 import numpy as np
 import pytest
@@ -533,8 +536,8 @@ def test_write_block_kv_scatters_into_owning_blocks(layer):
     :, slot)`` of the stack — the block the table names, the in-block
     slot — trash-mapped columns hit the sink, untouched slots are
     untouched, EVERY OTHER LAYER keeps its bytes, and the ``valid`` gate
-    (ring-inactive microsteps, masked layers) makes the write a no-op per
-    entry."""
+    (ring-inactive microsteps, masked layers) leaves an invalid entry's
+    owning block alone: the entry goes to the trash block of its layer."""
     from llm_sharding_tpu.ops.paged_attention import write_block_kv
 
     rng = np.random.default_rng(3)
@@ -561,12 +564,176 @@ def test_write_block_kv_scatters_into_owning_blocks(layer):
     np.testing.assert_array_equal(k3l[3, :, 1], kl[3, :, 1])
     np.testing.assert_array_equal(k3l[4, :, 2], np.asarray(kn)[1, 0])
     others_untouched(k, k3, layer)
-    # scalar False (an inactive ring microstep) is a global no-op
+    # scalar False (an inactive ring microstep) touches no block but the
+    # layer's trash: rows 0 and 2 collide on its slot 1 (last wins, either
+    # may), row 1 has slot 2 to itself
     k4, v4 = write_block_kv(
         k, v, layer, tbl, cols, kn, vn, valid=jnp.asarray(False)
     )
-    np.testing.assert_array_equal(np.asarray(k4), np.asarray(k))
-    np.testing.assert_array_equal(np.asarray(v4), np.asarray(v))
+    for before, after, new in ((k, k4, kn), (v, v4, vn)):
+        np.testing.assert_array_equal(
+            np.asarray(after)[:, 1:], np.asarray(before)[:, 1:]
+        )
+        others_untouched(before, after, layer)
+        trash, new = np.asarray(after)[layer, 0], np.asarray(new)
+        np.testing.assert_array_equal(trash[:, 2], new[1, 0])
+        assert any(np.array_equal(trash[:, 1], new[b, 0]) for b in (0, 2))
+        np.testing.assert_array_equal(
+            trash[:, [0, 3]], np.asarray(before)[layer, 0][:, [0, 3]]
+        )
+
+
+def _write_with_read_back(k_arena, v_arena, layer, tbl, cols, kn, vn, valid):
+    """The write as it stood before the gate moved to the address: an
+    invalid entry gathers the old rows of its owning block and writes them
+    back. Kept here as the oracle of what the attended blocks must hold."""
+    Nkv, bs = k_arena.shape[2], k_arena.shape[3]
+    blk = jnp.take_along_axis(tbl, cols // bs, axis=1)
+    entry = (layer, blk[:, :, None], jnp.arange(Nkv)[None, None, :],
+             (cols % bs)[:, :, None])
+    keep = jnp.asarray(valid)
+    if keep.ndim:
+        keep = keep[..., None, None]
+    return (
+        k_arena.at[entry].set(jnp.where(keep, kn, k_arena[entry])),
+        v_arena.at[entry].set(jnp.where(keep, vn, v_arena[entry])),
+    )
+
+
+#: the gate as its callers hand it over: a scalar (a ring microstep, a
+#: masked layer: ``write_valid & valid``) or one flag per entry (verify's
+#: ``[B, S]``; a parked row of a decode step)
+_VALID_CASES = {
+    "scalar_true": lambda B, S: jnp.asarray(True),
+    "scalar_false": lambda B, S: jnp.asarray(False),
+    "per_entry": lambda B, S: jnp.asarray(
+        (np.arange(B)[:, None] + np.arange(S)[None]) % 3 != 1
+    ),
+    "per_row": lambda B, S: jnp.broadcast_to(
+        jnp.asarray([True, False, True])[:B, None], (B, S)
+    ),
+}
+
+
+@pytest.mark.parametrize("S", (1, 3))
+@pytest.mark.parametrize("valid_case", sorted(_VALID_CASES))
+def test_an_invalid_entry_lands_in_the_trash_of_its_own_layer(valid_case, S):
+    """The gate by address against the gate by value: every block a table
+    can name (1 ...) holds, bit for bit, what the read-back formulation
+    left there — valid entries written, invalid ones' owning slots as they
+    were — every other layer is untouched, an invalid entry is found in
+    block 0 of ITS layer at its slot, and attention over the rows' tables
+    reads the same from both arenas."""
+    from llm_sharding_tpu.models.cache import POS_SENTINEL
+    from llm_sharding_tpu.ops.paged_attention import (
+        paged_attention_xla, write_block_kv,
+    )
+
+    rng = np.random.default_rng(31)
+    NB, bs, Nkv, G, D, B, T = 9, 4, 2, 2, 8, 3, 3
+    layer = 2
+    k, v = make_stack(rng, NB, Nkv, bs, D)
+    tbl = jnp.asarray([[2, 3, 0], [4, 6, 0], [5, 1, 7]], jnp.int32)
+    lengths = np.asarray([4, 2, 7])
+    cols = jnp.asarray(lengths[:, None] + np.arange(S)[None], jnp.int32)
+    kn = jnp.asarray(rng.normal(size=(B, S, Nkv, D)), jnp.float32)
+    vn = jnp.asarray(rng.normal(size=(B, S, Nkv, D)), jnp.float32)
+    valid = _VALID_CASES[valid_case](B, S)
+
+    got = write_block_kv(k, v, layer, tbl, cols, kn, vn, valid=valid)
+    want = _write_with_read_back(k, v, layer, tbl, cols, kn, vn, valid)
+    flags = np.broadcast_to(np.asarray(valid), (B, S))
+    for before, a, w, new in zip((k, v), got, want, (kn, vn)):
+        a, w, new = np.asarray(a), np.asarray(w), np.asarray(new)
+        np.testing.assert_array_equal(a[:, 1:], w[:, 1:])
+        others_untouched(before, a, layer)
+        trash = a[layer, 0]  # [Nkv, bs, D]
+        slots = np.asarray(cols) % bs
+        for b, s in zip(*np.nonzero(~flags)):
+            same_slot = [
+                new[b2, s2] for b2, s2 in zip(*np.nonzero(~flags))
+                if slots[b2, s2] == slots[b, s]
+            ]
+            assert any(
+                np.array_equal(trash[:, slots[b, s]], e) for e in same_slot
+            )
+            # ... and its owning slot holds what it held
+            blk = int(np.asarray(tbl)[b, int(cols[b, s]) // bs])
+            np.testing.assert_array_equal(
+                a[layer, blk, :, slots[b, s]],
+                np.asarray(before)[layer, blk, :, slots[b, s]],
+            )
+        if flags.all():
+            np.testing.assert_array_equal(trash, np.asarray(before)[layer, 0])
+
+    # what a decode step attends: the rows' windows after the write, the
+    # valid entries visible, read through both arenas
+    W = T * bs
+    kvpos = np.full((B, W), POS_SENTINEL, np.int32)
+    for b in range(B):
+        n = lengths[b] + S
+        kvpos[b, :n] = np.arange(n)
+    q = jnp.asarray(rng.normal(size=(B, S, Nkv * G, D)), jnp.float32)
+    out = [
+        np.asarray(paged_attention_xla(
+            q, ka, va, layer, tbl, cols, jnp.asarray(kvpos)
+        ))
+        for ka, va in (got, want)
+    ]
+    np.testing.assert_array_equal(out[0], out[1])
+
+
+def test_a_masked_layer_writes_to_its_own_trash_block():
+    """A padding layer of a stage (``layer_mask`` False) runs the block
+    and discards it: its entries must land in block 0 of ITS layer index —
+    not layer 0's, not a block the table owns — and the hidden state must
+    pass through as if the layer were not there."""
+    from llm_sharding_tpu.models.cache import POS_SENTINEL
+
+    cfg = tiny_llama(num_hidden_layers=3)
+    params = llama.init_params(cfg, jax.random.key(5), dtype=jnp.float32)
+    rng = np.random.default_rng(7)
+    B, T, bs, NB = 2, 2, 4, 6
+    Nkv, D = cfg.num_key_value_heads, cfg.head_dim_
+    k, v = make_stack(rng, NB, Nkv, bs, D, L=3)
+    tbl = jnp.asarray([[2, 3], [4, 0]], jnp.int32)
+    cols = jnp.asarray([[5], [1]], jnp.int32)
+    window_cols = np.arange(T * bs)[None]
+    kvpos = jnp.asarray(
+        np.where(window_cols <= np.asarray(cols), window_cols, POS_SENTINEL),
+        jnp.int32,
+    )
+    h = jnp.asarray(rng.normal(size=(B, 1, cfg.hidden_size)), jnp.float32)
+
+    def run(mask):
+        return llama.forward_layers_paged(
+            cfg, params["layers"], h, k, v, tbl, cols, kvpos, cols,
+            layer_mask=jnp.asarray(mask), backend="xla",
+        )
+
+    h_all, k_all, v_all, *_ = run([True, True, True])
+    h_m, k_m, v_m, *_ = run([True, False, True])
+    for before, full, masked in ((k, k_all, k_m), (v, v_all, v_m)):
+        before, full, masked = map(np.asarray, (before, full, masked))
+        # the masked layer: owned blocks as they were, the trash written
+        np.testing.assert_array_equal(masked[1, 1:], before[1, 1:])
+        assert not np.array_equal(masked[1, 0], before[1, 0])
+        assert not np.array_equal(full[1, 1:], before[1, 1:])
+        # no other layer's trash was touched, and layer 0 wrote as ever
+        np.testing.assert_array_equal(masked[[0, 2], 0], before[[0, 2], 0])
+        np.testing.assert_array_equal(masked[0], full[0])
+    # the hidden state skips the masked layer: layers 0 and 2 alone
+    two = {
+        n: jnp.stack([a[0], a[2]]) for n, a in params["layers"].items()
+    }
+    h_two, *_ = llama.forward_layers_paged(
+        cfg, two, h, k[jnp.asarray([0, 2])], v[jnp.asarray([0, 2])], tbl,
+        cols, kvpos, cols, backend="xla",
+    )
+    np.testing.assert_allclose(
+        np.asarray(h_m), np.asarray(h_two), rtol=1e-6, atol=1e-6
+    )
+    assert np.abs(np.asarray(h_m) - np.asarray(h_all)).max() > 1e-3
 
 
 def test_paged_attention_pallas_interpret_matches_xla():
@@ -892,12 +1059,12 @@ _CELL_SHAPES = {"qwen25_7b": (28, 4), "qwen25_14b_pp4": (40, 8),
 
 
 @pytest.fixture(scope="module")
-def v5e_chip():
-    """One chip of a DESCRIBED v5e host: the TPU's compiler is installed,
-    no chip is attached. Described here, inside a fixture of this one file
-    (never at import: only one process may load the TPU's library)."""
+def v5e_host():
+    """The four chips of a DESCRIBED v5e host: the TPU's compiler is
+    installed, no chip is attached. Described here, inside a fixture of
+    this one file (never at import: only one process may load the TPU's
+    library)."""
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
         topo = topologies.get_topology_desc(
@@ -905,7 +1072,15 @@ def v5e_chip():
         )
     except Exception as e:  # noqa: BLE001 — no libtpu in this installation
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+    return list(topo.devices)
+
+
+@pytest.fixture(scope="module")
+def v5e_chip(v5e_host):
+    """One chip of that host, as the sharding of a single-chip program."""
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e_host[0])
 
 
 @pytest.mark.parametrize("store", ["bf16", "int8"])
@@ -951,11 +1126,107 @@ def test_kernels_compile_for_a_described_v5e(v5e_chip, cell, kernel, store):
     assert text.count("tpu_custom_call") == 1 and kernel in text
     # the pool goes to the kernel as it lies: no copy or transpose of an
     # arena-sized operand beside the custom call
-    import re
-
     arena_elems = Lp * NB * Nkv * BS * D
     for m in re.finditer(r"= \w+\[([\d,]+)\][^ ]* (copy|transpose)\(", text):
         assert np.prod([int(x) for x in m.group(1).split(",")]) < arena_elems
+
+
+_HLO_BYTES = {"s8": 1, "u8": 1, "bf16": 2, "f16": 2, "f32": 4, "s32": 4}
+
+
+def _weight_stack_relayouts(text, floor=16 << 20):
+    """The ``copy`` instructions of a compiled program that re-lay a weight
+    out: the operand a ``stage_layers`` / ``head_params`` parameter (by the
+    name jax gave it), the result above ``floor`` bytes, the result's
+    minor-to-major order another than its operand's. A prefetch into
+    another memory (the same order, an ``S(1)`` suffix) is a move and is
+    not returned."""
+    order = dict(re.findall(
+        r"(%[\w.\-]+) = \w+\[[\d,]*\]\{([\d,]*)", text
+    ))
+    found = []
+    for m in re.finditer(
+        r"(%[\w.\-]+) = (\w+)\[([\d,]*)\]\{([\d,]*)[^ ]* "
+        r"copy\((%[\w.\-]+)\)[^\n]*"
+        r"op_name=\"(?:stage_layers|head_params)[^\n]*", text,
+    ):
+        name, dtype, shape, minor_to_major, operand = m.groups()
+        size = _HLO_BYTES.get(dtype, 4) * int(
+            np.prod([int(x) for x in shape.split(",")])
+        )
+        if size > floor and order.get(operand) != minor_to_major:
+            found.append(m.group(0)[:160])
+    return found
+
+
+def _windowed_projections(text):
+    """The ``qkv`` dots of a compiled program, and those of them the
+    compiler wrote as a convolution over a window wider than 1 (the head
+    axis as a spatial dim: the form that wants its weights input-minor)."""
+    dots = [
+        l for l in text.splitlines()
+        if " convolution(" in l and "/qkv/dot_general" in l
+    ]
+    windowed = [
+        l.strip()[:200] for l in dots
+        if any(
+            int(n) > 1 for w in re.findall(r"window=\{size=([\dx]+)", l)
+            for n in w.split("x")
+        )
+    ]
+    return dots, windowed
+
+
+@pytest.mark.parametrize("cell", sorted(_CELL_SHAPES))
+def test_a_decode_step_reads_its_weights_as_they_are_stored(v5e_host, cell):
+    """The compiled ``serve_chunk`` of each benchmark configuration, at its
+    real geometry for the described v5e (``benchmark/aot_check.py`` builds
+    the abstract inputs; the ring takes four chips), consumes every weight
+    stack in the layout it is stored in: no re-laying ``copy`` of a
+    parameter above 16 MiB, inside or outside the layer loop, and the k
+    and v projections plain dots like q's. Before the projection's edge
+    was held (``models/llama.py::attn_mlp_block``) XLA folded the head
+    split into the two small dots and transposed the whole ``wk`` / ``wv``
+    stacks at the top of every call: 0.25-0.27 ms of a decode step on the
+    chip (``PERF.md``, PR 31). Nothing runs: a compile is not a time."""
+    from benchmark import aot_check
+    from llm_sharding_tpu.parallel.mesh import pipeline_mesh
+
+    with open(os.path.join(aot_check.HERE, "configs", cell + ".json")) as f:
+        cfg_file = json.load(f)
+    stages = int(cfg_file["deployment"]["num_stages"])
+    mesh = pipeline_mesh(stages, v5e_host[:stages])
+    # conftest's "highest" matmul precision is the CPU oracles'; the
+    # program asks jax.default_backend() which attention to lower
+    with jax.default_matmul_precision("default"), mock.patch.object(
+        jax, "default_backend", lambda: "tpu"
+    ):
+        name, lowered = next(aot_check.programs(cfg_file, mesh))
+    assert name == "serve_chunk"
+    text = lowered.compile().as_text()
+    assert _weight_stack_relayouts(text) == []
+    dots, windowed = _windowed_projections(text)
+    assert len(dots) >= 3 and windowed == []
+
+
+def test_the_compiled_program_guard_sees_a_transposed_weight_stack():
+    """The guard's own reading, on the lines the parent's compiled 7B
+    program held: the transposed int8 stack and the windowed dot are
+    found; a prefetch of the router stack (a move, 4 MiB) is not."""
+    text = """
+  %stage_layers__wk___q.1 = s8[1,28,3584,512]{3,2,1,0:T(8,128)(4,1)} parameter(9), metadata={op_name="stage_layers['wk'].q"}
+  %copy.18 = s8[1,28,3584,512]{2,3,1,0:T(8,128)(4,1)S(1)} copy(%stage_layers__wk___q.1), sharding={replicated}, metadata={op_name="stage_layers['wk'].q"}
+  %copy-done.9 = bf16[1,16,2048,64]{3,2,1,0:T(8,128)(2,1)S(1)} copy-done(%copy-start.9)
+  %copy.40 = bf16[1,16,2048,64]{3,2,1,0:T(8,128)(2,1)S(1)} copy(%copy-done.9), metadata={op_name="stage_layers['router']"}
+  %stage_layers__wo___q.1 = s8[1,28,3584,3584]{3,2,1,0:T(8,128)(4,1)} parameter(11), metadata={op_name="stage_layers['wo'].q"}
+  %copy.50 = s8[1,28,3584,3584]{3,2,1,0:T(8,128)(4,1)S(1)} copy(%stage_layers__wo___q.1), metadata={op_name="stage_layers['wo'].q"}
+  %convolution.45 = bf16[4,4,128]{2,0,1:T(4,128)(2,1)} convolution(%fusion.188, %fusion.189), window={size=4 pad=3_3 rhs_reversal=1}, dim_labels=bf0_0oi->b0f, metadata={op_name="jit(serve_chunk)/state/while/body/closed_call/qkv/dot_general"}
+  %convolution.9 = bf16[4,3584]{1,0:T(4,128)(2,1)} convolution(%fusion.1, %fusion.2), dim_labels=bf_io->bf, metadata={op_name="jit(serve_chunk)/state/while/body/closed_call/qkv/dot_general"}
+"""
+    found = _weight_stack_relayouts(text)
+    assert len(found) == 1 and found[0].startswith("%copy.18 ")
+    dots, windowed = _windowed_projections(text)
+    assert len(dots) == 2 and len(windowed) == 1
 
 
 def _pallas_calls(jaxpr):
