@@ -39,7 +39,7 @@ GIB = 1 << 30
 def abstract_inputs(cfg_file: dict, mesh):
     """Shapes with shardings on ``mesh`` for everything a serve program
     takes: stage layers, masks, head, state."""
-    from benchmark import blocks, harness
+    from benchmark import blocks, harness, weights
     from llm_sharding_tpu.ops.quant import QTensor
     from llm_sharding_tpu.parallel import serve as serve_ops
     from llm_sharding_tpu.parallel.mesh import PIPE_AXIS
@@ -55,16 +55,28 @@ def abstract_inputs(cfg_file: dict, mesh):
     rep = NamedSharding(mesh, P())
     sds = lambda shape, dtype, sh: jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
 
-    layers = {}
-    for leaf in block.layer_leaves(model):
-        full = (S, Lp, *leaf.shape)
-        if int8 and leaf.matmul:
-            layers[leaf.name] = QTensor(
-                q=sds(full, jnp.int8, pipe),
-                scale=sds((S, Lp, leaf.shape[-1]), act, pipe),
-            )
-        else:
-            layers[leaf.name] = sds(full, act, pipe)
+    def stack(leaves, per_stage: int) -> dict:
+        out = {}
+        for leaf in leaves:
+            full = (S, per_stage, *leaf.shape)
+            if int8 and leaf.matmul:
+                out[leaf.name] = QTensor(
+                    q=sds(full, jnp.int8, pipe),
+                    scale=sds((S, per_stage, leaf.shape[-1]), act, pipe),
+                )
+            else:
+                out[leaf.name] = sds(full, act, pipe)
+        return out
+
+    kinds = blocks.kinds(block, model)
+    if kinds is None:
+        layers = stack(block.layer_leaves(model), Lp)
+    else:  # the per-kind tree the engine is handed (weights.make_params)
+        leaves = block.layer_leaves(model)
+        layers = {
+            kind: stack(leaves[kind], len(ids) // S)
+            for kind, ids in weights.layers_of_kinds(kinds, S).items()
+        }
     masks = sds((S, Lp), jnp.bool_, pipe)
     # a table with a vocabulary dimension is held as one slice a stage
     head = {}

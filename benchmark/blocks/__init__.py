@@ -10,6 +10,10 @@ the comparison), and the bytes one chip must read for a decode microstep
 (``roofline.py`` holds what every block shares). ``README.md``, "A block",
 lists what the file must give; ``tests/blocks/gpt2.py`` is a second one that
 differs in every part, kept at tiny widths for the tests.
+
+A block whose layers are not all alike also gives ``layer_kinds(model)``, one
+kind name per layer; ``kinds`` below is how the shared code asks, and a block
+without it is read exactly as before there was such a thing.
 """
 
 from __future__ import annotations
@@ -50,3 +54,38 @@ def load(model_type, folder: str = HERE):
     """The block module of ``model_type``; one module object per file, so it
     can key a cache or be a static argument."""
     return _load_file(find(model_type, folder))
+
+
+def kinds(block, model: dict):
+    """One kind name per layer, in layer order — or None for a block whose
+    layers are all alike (it has no ``layer_kinds``)."""
+    if not hasattr(block, "layer_kinds"):
+        return None
+    got = tuple(block.layer_kinds(model))
+    layers = block.dims(model)["layers"]
+    if len(got) != layers:
+        raise ValueError(
+            f"layer_kinds names {len(got)} layers, the model has {layers}")
+    return got
+
+
+def place(kinds_, layer: int):
+    """Where layer ``layer`` lies in the parameter tree: ``(kind, index in
+    that kind's stack)``; ``(None, layer)`` where there are no kinds. A
+    kind's stack holds its layers in layer order."""
+    if kinds_ is None:
+        return None, layer
+    kind = kinds_[layer]
+    return kind, kinds_[:layer].count(kind)
+
+
+def static_of(block, model: dict, kinds_, layer: int) -> dict:
+    """The static keywords of ``layer_forward`` for layer ``layer``: the
+    block's ``layer_static`` — which a block with kinds may give per kind,
+    ``{kind: {...}}`` — and, with kinds, ``kind=`` itself."""
+    static = block.layer_static(model)
+    if kinds_ is None:
+        return dict(static)
+    if static and all(isinstance(v, dict) for v in static.values()):
+        static = static[kinds_[layer]]
+    return dict(static, kind=kinds_[layer])

@@ -28,7 +28,7 @@ from typing import Optional
 import numpy as np
 import jax
 
-from benchmark import loadgen, reference, samples, weights
+from benchmark import blocks, loadgen, reference, samples, weights
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 WARM_NEW_TOKENS = 2
@@ -510,6 +510,19 @@ def run_cell(*, cell: dict, cfg_file: dict, block, traffic: dict,
         and rec["kernels_ok"]
         and rec["arena_ok"]
     )
+    # each number ``correct`` rests on beside its limit: [value, limit]; the
+    # first is a floor, the rest ceilings; no sample scored leaves no margin
+    ref = rec["reference"]
+    scored = ref["positions"] > 0
+    compared = {
+        "scored_positions": [ref["positions"], 1],
+        "margin_mean": [ref["margin_mean"] if scored else None,
+                        block.DELTA_MEAN],
+        "margin_max": [ref["margin_max"] if scored else None, block.DELTA_MAX],
+        "compiles_in_window": [rec["compiles_in_window"], 0],
+        "kernels_off_path": [int(not rec["kernels_ok"]), 0],
+        "arena_of_another_type": [int(not rec["arena_ok"]), 0],
+    }
     values = {}
     for name, (read, unit) in readers.items():
         v = read(rec)
@@ -530,6 +543,7 @@ def run_cell(*, cell: dict, cfg_file: dict, block, traffic: dict,
         device["busy_s"] = rec["trace"]["busy_s"]
         device["window_s"] = rec["trace"]["window_s"]
         result["breakdown"] = rec["trace"]["breakdown"]
+    result["compared"] = compared  # last in the line
     return {"result": result, "records": rec}
 
 
@@ -578,7 +592,9 @@ def check(cfg_file: dict, block, seed: int, devices, host_params,
     put = lambda tree: jax.tree.map(lambda a: jax.device_put(a, dev), tree)
     tables = put({t.name: params[t.name] for t in block.tables(model)})
 
-    def get_layer(l: int):
-        return put(jax.tree.map(lambda a: a[l], params["layers"]))
+    kinds = blocks.kinds(block, model)
+
+    def get_layer(l: int):  # one layer resident, out of its kind's stack
+        return put(weights.take_layer(params["layers"], kinds, l))
 
     return reference.score(block, model, get_layer, tables, samples)

@@ -3,9 +3,10 @@
 The plain reference itself — one decoder layer over a whole sequence, the
 embedding, the final norm and logits, and the two thresholds — is the
 block's (``blocks/<model_type>.py``: ``layer_forward``, ``embed``, ``logits``,
-``DELTA_MEAN``, ``DELTA_MAX``). It reads nothing from the program under test;
-weights come in as plain arrays (an int8 weight as its ``(q, scale)`` pair,
-dequantised here as ``q · scale``), one layer at a time.
+``DELTA_MEAN``, ``DELTA_MAX``; layers are walked in order, and a block with
+``layer_kinds`` is told each layer's ``kind``). It reads nothing from the
+program under test; weights come in as plain arrays (an int8 weight as its
+``(q, scale)`` pair, dequantised here as ``q · scale``), one layer at a time.
 
 Departure from the published descriptions: a sequence is padded at its END to
 a multiple of ``PAD_TO`` so that a handful of shapes compile; under a causal
@@ -30,6 +31,8 @@ import functools
 import numpy as np
 import jax
 import jax.numpy as jnp
+
+from benchmark import blocks
 
 PAD_TO = 256
 
@@ -78,11 +81,11 @@ def _as_ref_layer(layer: dict) -> dict:
 def hidden_states(block, model: dict, get_layer, tables: dict,
                   sequences: list, **overrides) -> list:
     """Final hidden states [S_padded, H] of each id sequence. ``get_layer(l)``
-    gives layer l's leaves on the device; only one layer is resident at a
-    time. Layers are the outer loop, so each is fetched once for all
-    sequences. ``overrides`` (tests only) replace a keyword of the block's
-    ``layer_forward``, e.g. a wrong ``theta``."""
-    kw = dict(block.layer_static(model), **overrides)
+    gives layer l's leaves on the device (of whatever kind layer l is); only
+    one layer is resident at a time. Layers are the outer loop, so each is
+    fetched once for all sequences. ``overrides`` (tests only) replace a
+    keyword of the block's ``layer_forward``, e.g. a wrong ``theta``."""
+    kinds = blocks.kinds(block, model)
     head_kw = block.head_static(model)
     hidden = []
     for ids in sequences:
@@ -90,6 +93,8 @@ def hidden_states(block, model: dict, get_layer, tables: dict,
         padded = jnp.asarray(np.pad(ids, (0, -len(ids) % PAD_TO)))
         hidden.append(block.embed(tables, padded, **head_kw))
     for l in range(block.dims(model)["layers"]):
+        # a block with ``layer_kinds`` is told which kind layer l is
+        kw = dict(blocks.static_of(block, model, kinds, l), **overrides)
         p = _as_ref_layer(get_layer(l))
         hidden = [block.layer_forward(h, p, **kw) for h in hidden]
         del p

@@ -5,7 +5,9 @@
 
 Run from the root of a checkout on a machine that holds the chips the cell
 asks for. The last line of standard output is one JSON object (``correct``,
-``attempted``, ``failed``, ``metrics``, ``device`` and, traced, ``breakdown``);
+``attempted``, ``failed``, ``metrics``, ``device``, traced ``breakdown``, and
+last ``compared``: each number ``correct`` rests on beside its limit, which
+are also the last lines of standard error);
 everything else the run learned goes to ``benchmark/out/``. With ``--trace 0``
 the metrics are the cell's end-to-end metrics, with ``--trace 1`` its
 per-layer metrics.
@@ -51,9 +53,15 @@ def load_readers(bench: dict, group: str, cell: str) -> dict:
     for a name with a suffix after a dot (``queue_wait_p95_ms.chat``), the
     file of the name before the dot: the suffix only says which cells."""
     folder = {"end_to_end": "end_to_end", "per_layer": "layer_metrics"}[group]
+    judged = {m["name"] for m in bench["end_to_end"]
+              if cell in m.get("workloads", (cell,))}
     readers = {}
     for m in bench[group]:
         if "workloads" in m and cell not in m["workloads"]:
+            continue
+        # without a list: every cell (end to end), or every cell that
+        # reports the end-to-end metric the per-layer metric moves
+        if "workloads" not in m and m.get("moves", m["name"]) not in judged:
             continue
         for stem in (m["name"], m["name"].split(".", 1)[0]):
             path = os.path.join(HERE, folder, stem + ".py")
@@ -173,6 +181,8 @@ def main() -> None:
     if args.trace:
         print("per chip:", json.dumps(rec["trace"]["chips"]))
     print(json.dumps(result), flush=True)
+    for what, (value, limit) in result["compared"].items():
+        print(f"compared: {what} {value} limit {limit}", file=sys.stderr)
 
 
 if __name__ == "__main__":

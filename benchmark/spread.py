@@ -5,11 +5,13 @@
 
 Reads the result lines of two sets of runs (``<cell>.A.<seed>.log`` and
 ``<cell>.B.<seed>.log``, as ``tests/sets.sh`` leaves them) and prints, per
-end-to-end metric, each set's median and its spread — the distance between the
-first and third quartile (``statistics.quantiles(values, n=4)``) as a share of
-the median — the wider of the two, and each again without the set's run
-farthest from the median. A bound is about five times the widest spread over
-the cells, and never under 1%.
+end-to-end metric (and per statistic of ``tests/gap_stats.py``, where
+``sets.sh`` left one beside a log), each set's median and its spread — the
+distance between the first and third quartile (``statistics.quantiles(values,
+n=4)``) as a share of the median — the wider of the two, and each again
+without the set's run farthest from the median. A bound is about five times
+the widest spread over the cells, and never under 1%; a metric is judged in a
+cell only where both sets spread by at most half its bound.
 """
 
 import glob
@@ -20,9 +22,21 @@ import sys
 
 
 def last_json(path: str):
+    """A run's result line; its metrics joined by what ``tests/gap_stats.py``
+    read from the same run's records (``<log>.gaps`` beside the log), so that
+    a statistic nobody is judged on is tabulated beside those that are."""
     with open(path) as f:
         lines = [l for l in f.read().splitlines() if l.startswith("{")]
-    return json.loads(lines[-1]) if lines else None
+    if not lines:
+        return None
+    run = json.loads(lines[-1])
+    side = path[: -len(".log")] + ".gaps"
+    if os.path.exists(side):
+        with open(side) as f:
+            extra = json.loads(f.read().split("gaps:", 1)[1])
+        for name, value in extra.items():
+            run["metrics"].setdefault(name, {"value": value})
+    return run
 
 
 def spread(values: list) -> float:

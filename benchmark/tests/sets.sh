@@ -11,6 +11,8 @@ for set in A B; do
     python3 benchmark/run.py --workload $cell --seed $s --seconds $secs --trace 0 > $out/sets/$cell.$set.$s.log 2> $out/sets/$cell.$set.$s.err
     rc=$?
     echo "$set $s rc=$rc $(tail -n 1 $out/sets/$cell.$set.$s.log | cut -c1-700)"
+    # what else the run's records say of its gaps: spread.py tabulates it
+    [ $rc -eq 0 ] && python3 benchmark/tests/gap_stats.py benchmark/out $cell | tee -a $out/sets/$cell.$set.$s.gaps
     grep -h "^reference\|^set-up\|^samples\|^paths" $out/sets/$cell.$set.$s.log | cut -c1-400
     if [ $rc -ne 0 ] && [ $set = A ] && [ $i -eq 0 ]; then
       tail -n 30 $out/sets/$cell.$set.$s.err; exit $rc  # broken: stop here
@@ -22,4 +24,5 @@ rc=$?
 echo "T rc=$rc $(tail -n 1 $out/sets/$cell.T.log | cut -c1-4000)"
 grep -h "^reference\|^set-up\|^per chip\|^samples\|^paths" $out/sets/$cell.T.log | cut -c1-600
 [ $rc -ne 0 ] && tail -n 30 $out/sets/$cell.T.err
-cp benchmark/out/$cell.*.json $out/out/
+# the records of a backlog run are tens of MB: the small ones come back
+find benchmark/out -name "$cell.*.json" -size -4M -exec cp {} $out/out/ \;

@@ -33,6 +33,8 @@ NEW = ("decode_moe_pct.backlog", "moe_hbm_pct.backlog",
 # keeps the seed's. A router of zeros routes every token to experts 0..k-1
 # with equal weights; without the two gains the program's block, which keys
 # the q/k norm by their presence, runs with no such norm at all.
+TOY_DELTA_MEAN = 0.01
+
 WRONG = {
     "sound": None,
     "router zeroed": "router",
@@ -66,12 +68,18 @@ def test_the_olmoe_block_runs_through_the_harness(what, tmp_path, monkeypatch):
         return dict(params, layers=layers)
 
     monkeypatch.setattr(weights, "make_params", served_wrong)
+    # the block's DELTA_MEAN was read on the chip over ~1,000 positions of a
+    # 50k vocabulary; this toy scores ~80 of a vocabulary of 512, where ONE
+    # expert chosen the other way at a near-tie reads 0.157 / 82 = 0.0019
+    # (seed 2**31 + 9 since PR 28; four other seeds read 0-0.00036), and the
+    # wrong models below read 0.16 and 0.57. A toy's limit: no cell has it.
+    monkeypatch.setattr(BLOCK, "DELTA_MEAN", TOY_DELTA_MEAN)
     e2e, layer, _ = tb._readers(CELL)
     got = tb.run_tiny("backlog", 1, tmp_path, e2e, cfg=TINY, block=BLOCK)
     res, rec = got["result"], got["records"]
     assert len(calls) == 2 and rec["reference"]["positions"] > 20
     assert res["failed"] == 0 and rec["paths"]["attn_backend"] == "interpret"
-    assert set(res["metrics"]) == {"itl_p95_ms", "setup_s"}
+    assert set(res["metrics"]) == {"out_tok_s", "setup_s"}  # PERF.md section 2
     assert res["correct"] == (WRONG[what] is None), rec["reference"]
     print(what, rec["reference"])
     if WRONG[what] is not None:

@@ -3,8 +3,9 @@
 The weights are DATA: the engine serves them and ``reference.py`` scores the
 served tokens against the same arrays. WHICH leaves a layer has, their
 shapes, the rule each is drawn by and the model's tables are the block's
-(``blocks/<model_type>.py``: ``layer_leaves``, ``tables``); this file is the
-generator every block shares. Leaf ``i`` of layer ``l`` is drawn from
+(``blocks/<model_type>.py``: ``layer_leaves``, ``tables``, and ``layer_kinds``
+where the layers are not all alike); this file is the generator every block
+shares. Leaf ``i`` of layer ``l`` is drawn from
 ``fold_in(fold_in(root, l), i)`` and table ``i`` from ``fold_in(fold_in(root,
 1 << 20), i)``: the same on one chip and split over a ring.
 
@@ -29,6 +30,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from benchmark import blocks
 
 import llm_sharding_tpu.models  # noqa: F401  (first: ops.* imports it in a cycle)
 from llm_sharding_tpu.ops.quant import QTensor  # the container the engine reads
@@ -89,17 +92,30 @@ def _generator(block, model_json: str, mesh: Mesh, dtype, int8: bool):
     leaves, tables = block.layer_leaves(model), block.tables(model)
     num_layers = block.dims(model)["layers"]
     axis = mesh.axis_names[0]
+    kinds = blocks.kinds(block, model)
 
-    def stage(root_data, layer_ids):
-        root = jax.random.wrap_key_data(root_data)
-        return jax.lax.map(
-            lambda l: make_layer(leaves, root, l, dtype, int8), layer_ids
-        )
+    def stack(leaves, root_data, layer_ids):
+        """The layers ``layer_ids`` (their index in the WHOLE model) as one
+        stack of ``leaves``, each chip making its own share of them."""
+        def stage(root_data, layer_ids):
+            root = jax.random.wrap_key_data(root_data)
+            return jax.lax.map(
+                lambda l: make_layer(leaves, root, l, dtype, int8), layer_ids
+            )
+
+        return jax.shard_map(
+            stage, mesh=mesh, in_specs=(P(), P(axis)), out_specs=P(axis),
+        )(root_data, layer_ids)
 
     def whole(root_data):
-        layers = jax.shard_map(
-            stage, mesh=mesh, in_specs=(P(), P(axis)), out_specs=P(axis),
-        )(root_data, jnp.arange(num_layers, dtype=jnp.int32))
+        if kinds is None:
+            layers = stack(
+                leaves, root_data, jnp.arange(num_layers, dtype=jnp.int32))
+        else:  # one stack per kind, in layer order
+            layers = {
+                kind: stack(leaves[kind], root_data, jnp.asarray(ids, jnp.int32))
+                for kind, ids in layers_of_kinds(kinds, mesh.size).items()
+            }
         made = make_tables(tables, jax.random.wrap_key_data(root_data), dtype)
         # each chip makes its slice of the vocabulary, so no chip holds the
         # float32 form of a whole table (3 GB at 14B) beside its layers
@@ -119,10 +135,40 @@ def _generator(block, model_json: str, mesh: Mesh, dtype, int8: bool):
     return jax.jit(whole)
 
 
+def layers_of_kinds(kinds: tuple, stages: int) -> dict:
+    """``{kind: [its layers' indices in the whole model, in order]}``. A ring
+    gives each stage as many consecutive layers as every other, and a stack
+    is split evenly too, so every stage must hold the same count of each
+    kind: whole periods of the model."""
+    per_stage = len(kinds) // stages
+    out = {}
+    for kind in dict.fromkeys(kinds):
+        counts = [kinds[s * per_stage:(s + 1) * per_stage].count(kind)
+                  for s in range(stages)]
+        if len(set(counts)) != 1:
+            raise ValueError(
+                f"layers of kind {kind!r} do not split evenly over {stages} "
+                f"stages of {per_stage} layers: {counts} a stage (a stage "
+                "must hold whole periods of the model's layer kinds)")
+        out[kind] = [l for l, k in enumerate(kinds) if k == kind]
+    return out
+
+
+def take_layer(layers: dict, kinds, layer: int) -> dict:
+    """Layer ``layer`` of the model out of ``params["layers"]``: out of the
+    one stack, or with ``kinds`` (``blocks.kinds``) out of its kind's."""
+    kind, i = blocks.place(kinds, layer)
+    stack = layers if kind is None else layers[kind]
+    return jax.tree.map(lambda a: a[i], stack)
+
+
 def make_params(block, model: dict, seed: int, weight_dtype: str, devices) -> dict:
     """The whole model on ``devices`` (layers split evenly along the ring),
     in the engine's layout: ``{"layers": {leaf: [L, ...]}, <table>: ...}``;
-    int8 leaves are ``QTensor(q, scale)``."""
+    int8 leaves are ``QTensor(q, scale)``. For a block with ``layer_kinds``
+    ``"layers"`` is ``{kind: {leaf: [L_kind, ...]}}``, one stack per kind in
+    layer order; layer ``l`` of the model keeps the key ``fold_in(root, l)``
+    whatever its kind, so a cut in depth keeps each kept layer's weights."""
     int8 = weight_dtype == "int8"
     dtype = jnp.bfloat16 if int8 else DTYPES[weight_dtype]
     L = block.dims(model)["layers"]
