@@ -1,1 +1,1 @@
-from . import cache, config, gpt2, llama, stack  # noqa: F401
+from . import cache, config, deepseek_v3, gpt2, llama, stack  # noqa: F401

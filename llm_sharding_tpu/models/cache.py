@@ -7,7 +7,9 @@ a fixed-capacity ring of arrays plus a scalar length, updated functionally with
 ``lax.dynamic_update_slice`` so the whole decode loop stays inside one compiled
 program (SURVEY.md §7 "KV cache shape discipline under jit").
 
-Layout: ``k, v: [num_layers, batch, capacity, num_kv_heads, head_dim]`` plus
+Layout: ``k, v: [num_layers, batch, capacity, num_kv_heads, head_dim]``
+(a latent cache, ``cfg.latent_kv``: ``k`` one entry of ``cfg.cache_k_dim`` a
+token, ``v`` zero wide) plus
 ``pos: [batch, capacity]`` — the absolute token position of each slot's key,
 initialized to a large sentinel. Attention masks on ``pos <= query_position``,
 so uninitialized slots and padded prompt tokens (written with the sentinel)
@@ -65,10 +67,10 @@ def init_cache(
 ) -> KVCache:
     """Allocate an empty cache for ``num_layers`` (a pipeline stage's slice)."""
     L = cfg.num_hidden_layers if num_layers is None else num_layers
-    shape = (L, batch_size, capacity, cfg.num_key_value_heads, cfg.head_dim_)
+    shape = (L, batch_size, capacity, cfg.cache_heads)
     return KVCache(
-        k=jnp.zeros(shape, dtype),
-        v=jnp.zeros(shape, dtype),
+        k=jnp.zeros((*shape, cfg.cache_k_dim), dtype),
+        v=jnp.zeros((*shape, cfg.cache_v_dim), dtype),
         pos=jnp.full((batch_size, capacity), POS_SENTINEL, jnp.int32),
         length=jnp.zeros((), jnp.int32),
     )
@@ -102,7 +104,7 @@ def paged_arena_shape(
         raise ValueError(
             f"block_size must be a power of two, got {block_size}"
         )
-    return (L, num_blocks, cfg.num_key_value_heads, block_size, cfg.head_dim_)
+    return (L, num_blocks, cfg.cache_heads, block_size, cfg.cache_k_dim)
 
 
 def clear(cache: KVCache) -> KVCache:

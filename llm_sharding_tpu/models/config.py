@@ -17,13 +17,22 @@ from typing import Any, Optional
 
 @dataclasses.dataclass(frozen=True)
 class RopeScaling:
-    """Llama-3 style RoPE frequency scaling (``rope_type="llama3"``)."""
+    """RoPE frequency scaling: Llama-3 style (``rope_type="llama3"``) or
+    YaRN (``rope_type="yarn"``: ``beta_fast`` / ``beta_slow`` bound the ramp
+    between interpolated and extrapolated frequencies, ``mscale`` /
+    ``mscale_all_dim`` give the cos/sin factor and, for ``deepseek_v3``, the
+    softmax scale — ``ops/rope.py``)."""
 
     factor: float = 8.0
     low_freq_factor: float = 1.0
     high_freq_factor: float = 4.0
     original_max_position_embeddings: int = 8192
     rope_type: str = "llama3"
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+    truncate: bool = True
 
 
 @dataclasses.dataclass(frozen=True)
@@ -65,6 +74,31 @@ class ModelConfig:
     num_experts_per_tok: int = 0
     norm_topk_prob: bool = False
     qk_norm: bool = False
+    # ``deepseek_v3`` (models/deepseek_v3.py). Latent attention (MLA): q through
+    # a rank-``q_lora_rank`` bottleneck, keys and values decompressed from ONE
+    # latent of ``kv_lora_rank`` a token, plus ``qk_rope_head_dim`` rotated
+    # values shared by all heads — what the cache holds. The first
+    # ``first_k_dense_replace`` layers are dense MLPs of ``intermediate_size``;
+    # the rest route over ``num_experts`` (ALL of the layer's routed experts)
+    # by sigmoid scores with a correction bias, ``n_group`` groups of which
+    # ``topk_group`` are kept, weights scaled by ``routed_scaling_factor``,
+    # beside ``n_shared_experts`` always-on experts of the same width
+    # ``moe_intermediate_size``. A chip's share of the experts: it holds
+    # ``experts_held`` of them, ids ``ep_rank * experts_held ..``, and
+    # computes only their terms (0 = holds them all).
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    moe_intermediate_size: int = 0
+    n_shared_experts: int = 0
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    first_k_dense_replace: int = 0
+    experts_held: int = 0
+    ep_rank: int = 0
     # GPT-2 specifics
     layer_norm_epsilon: float = 1e-5
     # Token ids. ``eos_token_ids`` holds ALL stop ids (Llama-3.x instruct
@@ -87,6 +121,54 @@ class ModelConfig:
     @property
     def num_kv_groups(self) -> int:
         return self.num_attention_heads // self.num_key_value_heads
+
+    # What one token of one layer is in the KV cache (dense rows and the
+    # paged arena alike): ``cache_heads`` entries of ``cache_k_dim`` keys and
+    # ``cache_v_dim`` values. A latent cache holds ONE entry, ``[c_kv |
+    # k_pe]`` padded to whole 128-lane tiles, and no values: the value read
+    # is the first ``kv_lora_rank`` lanes of the key read.
+    @property
+    def latent_kv(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def cache_heads(self) -> int:
+        return 1 if self.latent_kv else self.num_key_value_heads
+
+    @property
+    def cache_k_dim(self) -> int:
+        if not self.latent_kv:
+            return self.head_dim_
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    @property
+    def cache_v_dim(self) -> int:
+        return 0 if self.latent_kv else self.head_dim_
+
+    @property
+    def rope_dim(self) -> int:
+        """Width of the rotated part of a head."""
+        return self.qk_rope_head_dim if self.latent_kv else self.head_dim_
+
+    @property
+    def experts_held_(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
+    def held_experts_(self) -> tuple:
+        """``(first id, count)`` of the routed experts this chip holds."""
+        return self.ep_rank * self.experts_held_, self.experts_held_
+
+    @property
+    def layer_kinds(self) -> tuple:
+        """One kind name per layer, or () where the layers are all alike
+        (the tree is then ``params["layers"][leaf]``, else
+        ``params["layers"][kind][leaf]``, one stack per kind in layer
+        order)."""
+        if self.model_type != "deepseek_v3":
+            return ()
+        k = self.first_k_dense_replace
+        return ("dense",) * k + ("moe",) * (self.num_hidden_layers - k)
 
     def to_json(self) -> str:
         d = dataclasses.asdict(self)
@@ -171,6 +253,8 @@ class ModelConfig:
             mt = "llama"
         else:
             moe = {}
+        if mt == "deepseek_v3":
+            return cls._from_deepseek_v3(hf)
         if mt in ("llama",):
             act = hf.get("hidden_act", "silu")
             if act not in ("silu", "gelu_tanh"):
@@ -245,6 +329,127 @@ class ModelConfig:
                 eos_token_id=hf.get("eos_token_id", 50256),
             )
         raise ValueError(f"unsupported model_type: {mt!r}")
+
+    @classmethod
+    def _from_deepseek_v3(cls, hf: dict[str, Any]) -> "ModelConfig":
+        """``deepseek_v3`` as published (HF ``modeling_deepseek_v3.py``):
+        latent attention with a q bottleneck, YaRN or plain RoPE, leading
+        dense layers, then sigmoid group-limited routing (``noaux_tc``)
+        beside shared experts. Beside the published keys, two of a chip's
+        share of the experts: ``n_routed_experts`` is how many are HELD,
+        ``n_routed_experts_total`` (default: the same) how many the router
+        scores, ``ep_rank`` which run of them this is. What is not done is
+        refused by name."""
+        need = (
+            "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+            "qk_rope_head_dim", "v_head_dim", "n_routed_experts",
+            "num_experts_per_tok", "moe_intermediate_size",
+        )
+        for key in need:
+            if hf.get(key) is None:
+                raise ValueError(
+                    f"deepseek_v3 config.json lacks {key!r} (a model "
+                    "without the q bottleneck, q_lora_rank null, is not "
+                    "supported)"
+                )
+        refuse = {
+            "topk_method": ("noaux_tc",), "scoring_func": ("sigmoid",),
+            "hidden_act": ("silu",), "moe_layer_freq": (1,),
+            "attention_bias": (False,), "norm_topk_prob": (True,),
+            "num_nextn_predict_layers": (0,), "tie_word_embeddings": (False,),
+        }
+        for key, ok in refuse.items():
+            if key in hf and hf[key] is not None and hf[key] not in ok:
+                raise ValueError(
+                    f"deepseek_v3 {key}={hf[key]!r} is not supported (only "
+                    f"{', '.join(map(repr, ok))}"
+                    + ("; the multi-token-prediction module is not served: "
+                       "drop its layers at conversion"
+                       if key == "num_nextn_predict_layers" else "")
+                    + ")"
+                )
+        if hf.get("rope_interleave") is False:
+            raise ValueError(
+                "deepseek_v3 rope_interleave=false is not supported: the "
+                "converter stores the rotated columns de-interleaved"
+            )
+        held = int(hf["n_routed_experts"])
+        total = int(hf.get("n_routed_experts_total", held))
+        rank = int(hf.get("ep_rank", 0))
+        groups = int(hf.get("n_group", 1))
+        if total % held or not 0 <= rank < total // held:
+            raise ValueError(
+                f"deepseek_v3 share: {held} experts held of {total}, rank "
+                f"{rank}: the held count must divide the total and the rank "
+                f"lie in 0..{total // max(held, 1) - 1}"
+            )
+        if total % groups or (total // groups) < 2:
+            raise ValueError(
+                f"deepseek_v3 n_group {groups} does not split {total} "
+                "experts into groups of at least 2"
+            )
+        rs = None
+        raw = hf.get("rope_scaling")
+        if raw:
+            rt = raw.get("rope_type", raw.get("type"))
+            if rt == "yarn":
+                rs = RopeScaling(
+                    factor=float(raw["factor"]),
+                    original_max_position_embeddings=int(
+                        raw.get("original_max_position_embeddings")
+                        or hf.get("max_position_embeddings", 4096)
+                    ),
+                    rope_type="yarn",
+                    beta_fast=float(raw.get("beta_fast") or 32.0),
+                    beta_slow=float(raw.get("beta_slow") or 1.0),
+                    mscale=float(raw.get("mscale") or 0.0),
+                    mscale_all_dim=float(raw.get("mscale_all_dim") or 0.0),
+                    truncate=bool(raw.get("truncate", True)),
+                )
+            elif rt not in ("default", None):
+                raise ValueError(
+                    f"deepseek_v3 rope_scaling type {rt!r} is not "
+                    "supported; only 'yarn' and default RoPE are"
+                )
+        eos = hf.get("eos_token_id", 1)
+        eos_ids = tuple(eos) if isinstance(eos, list) else (eos,)
+        return cls(
+            model_type="deepseek_v3",
+            vocab_size=hf["vocab_size"],
+            hidden_size=hf["hidden_size"],
+            intermediate_size=hf["intermediate_size"],
+            num_hidden_layers=hf["num_hidden_layers"],
+            num_attention_heads=hf["num_attention_heads"],
+            num_key_value_heads=hf.get(
+                "num_key_value_heads", hf["num_attention_heads"]
+            ),
+            head_dim=int(hf["qk_nope_head_dim"]) + int(hf["qk_rope_head_dim"]),
+            max_position_embeddings=hf.get("max_position_embeddings", 4096),
+            rms_norm_eps=hf.get("rms_norm_eps", 1e-6),
+            rope_theta=float(hf.get("rope_theta", 10000.0)),
+            rope_scaling=rs,
+            num_experts=total,
+            num_experts_per_tok=int(hf["num_experts_per_tok"]),
+            norm_topk_prob=True,
+            q_lora_rank=int(hf["q_lora_rank"]),
+            kv_lora_rank=int(hf["kv_lora_rank"]),
+            qk_nope_head_dim=int(hf["qk_nope_head_dim"]),
+            qk_rope_head_dim=int(hf["qk_rope_head_dim"]),
+            v_head_dim=int(hf["v_head_dim"]),
+            moe_intermediate_size=int(hf["moe_intermediate_size"]),
+            n_shared_experts=int(hf.get("n_shared_experts") or 0),
+            n_group=groups,
+            topk_group=int(hf.get("topk_group", 1)),
+            routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+            first_k_dense_replace=int(hf.get("first_k_dense_replace", 0)),
+            experts_held=held,
+            ep_rank=rank,
+            bos_token_id=(
+                0 if hf.get("bos_token_id") is None else hf["bos_token_id"]
+            ),
+            eos_token_id=eos_ids[0],
+            eos_token_ids=eos_ids,
+        )
 
 
 # Convenience presets (sizes mirror the models the reference targets:
@@ -425,6 +630,54 @@ def tiny_olmoe(**kw) -> ModelConfig:
     )
     base.update(kw)
     return ModelConfig.from_hf_config(base)
+
+
+def tiny_deepseek_v3_keys(**kw) -> dict:
+    """The published-style keys of ``tiny_deepseek_v3`` (what a
+    ``config.json`` of it would hold)."""
+    base = dict(
+        model_type="deepseek_v3",
+        vocab_size=256,
+        hidden_size=64,
+        intermediate_size=128,
+        moe_intermediate_size=32,
+        num_hidden_layers=3,
+        first_k_dense_replace=1,
+        num_attention_heads=4,
+        num_key_value_heads=4,
+        q_lora_rank=24,
+        kv_lora_rank=32,
+        qk_nope_head_dim=16,
+        qk_rope_head_dim=8,
+        v_head_dim=24,
+        n_routed_experts=8,
+        n_shared_experts=1,
+        num_experts_per_tok=2,
+        n_group=4,
+        topk_group=2,
+        routed_scaling_factor=2.5,
+        norm_topk_prob=True,
+        scoring_func="sigmoid",
+        topk_method="noaux_tc",
+        max_position_embeddings=128,
+        rms_norm_eps=1e-6,
+        rope_theta=10000.0,
+        rope_scaling={
+            "rope_type": "yarn", "factor": 4.0, "beta_fast": 32,
+            "beta_slow": 1, "mscale": 1.0, "mscale_all_dim": 1.0,
+            "original_max_position_embeddings": 32,
+        },
+        eos_token_id=255,
+    )
+    base.update(kw)
+    return base
+
+
+def tiny_deepseek_v3(**kw) -> ModelConfig:
+    """Tiny deepseek_v3-layout config for CPU tests: 1 dense + 2 expert
+    layers, ``v_head_dim`` != ``qk_nope_head_dim``, YaRN on, 8 experts in 4
+    groups of which 2 are kept, 2 a token, one shared expert."""
+    return ModelConfig.from_hf_config(tiny_deepseek_v3_keys(**kw))
 
 
 def tiny_qwen2(**kw) -> ModelConfig:

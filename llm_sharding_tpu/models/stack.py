@@ -44,6 +44,22 @@ def join_whole(p, whole, l):
     return p if whole is None else {**p, **whole, "layer": l}
 
 
+def kind_spans(layers: dict, kinds: tuple):
+    """``[(kind, first, count)]`` of a per-kind tree ``{kind: {leaf: [L_kind,
+    ...]}}`` (a model whose layers are of several kinds, ``cfg.layer_kinds``):
+    a stage's layer SLOTS are its kinds' stacks laid end to end in the order
+    the kinds first appear in the model — slot ``first + i`` of the stage's
+    layer mask, cache and arena belongs to layer ``i`` of ``kind``'s stack.
+    One stage of a model with leading dense layers holds them in model
+    order; a padded stack's tail slots are masked like any padding layer."""
+    spans, first = [], 0
+    for kind in dict.fromkeys(kinds):
+        count = jax.tree.leaves(layers[kind])[0].shape[0]
+        spans.append((kind, first, count))
+        first += count
+    return spans
+
+
 def masked_stats(stats, valid):
     """A masked (padding) layer read and counted nothing."""
     return jax.tree.map(lambda a: jnp.where(valid, a, jnp.zeros_like(a)), stats)
@@ -56,11 +72,14 @@ def scan_layers(
     positions: jnp.ndarray,
     apply_layer: ApplyLayerFn,
     layer_mask: Optional[jnp.ndarray] = None,
+    first_layer: int = 0,
 ):
-    """Returns ``(h, cache, stats)``."""
+    """Returns ``(h, cache, stats)``. ``layers`` fill the cache's layer slots
+    ``first_layer …`` (one kind's stack of a model with several,
+    ``kind_spans``); ``layer_mask`` is theirs."""
     S = h.shape[1]
     layers, whole = split_whole(layers)
-    L = cache.num_layers
+    L = cache.num_layers if layer_mask is None else layer_mask.shape[0]
     if layer_mask is None:
         layer_mask = jnp.ones((L,), bool)
 
@@ -78,12 +97,13 @@ def scan_layers(
     # write on a loop carry is the standard aliasing pattern).
     def body(carry, xs):
         h, k_all, v_all = carry
-        p, l, valid = xs
+        p, i, valid = xs
+        l = i + first_layer if first_layer else i  # the cache's layer slot
         with jax.named_scope("kv_take"):
             k_row = jax.lax.dynamic_index_in_dim(k_all, l, keepdims=False)
             v_row = jax.lax.dynamic_index_in_dim(v_all, l, keepdims=False)
         h_new, k_new, v_new, stats = apply_layer(
-            join_whole(p, whole, l), h, k_row, v_row, kv_pos, cache.length
+            join_whole(p, whole, i), h, k_row, v_row, kv_pos, cache.length
         )
         h = jnp.where(valid, h_new, h)
         with jax.named_scope("kv_put"):
@@ -119,6 +139,8 @@ def scan_layers_paged(
     layer_mask: Optional[jnp.ndarray] = None,
     k_scale: Optional[jnp.ndarray] = None,  # [L, NB, Nkv] f32 per-block-
     v_scale: Optional[jnp.ndarray] = None,  # per-head scales (quantized)
+    first_layer: int = 0,  # ``layers`` fill the arena's layer slots from
+    #   here on (one kind's stack, ``kind_spans``); ``layer_mask`` is theirs
 ):
     """Paged analogue of ``scan_layers``: the cache is the pooled block
     arena, and a layer's update is the tiny block-indexed scatter of this
@@ -142,16 +164,17 @@ def scan_layers_paged(
     unquantized carry is unchanged). Returns ``(h, k_arena, v_arena,
     k_scale, v_scale, stats)`` — the scale outputs are None when the arena
     is unquantized."""
-    L = k_arena.shape[0]
+    L = k_arena.shape[0] if layer_mask is None else layer_mask.shape[0]
     if layer_mask is None:
         layer_mask = jnp.ones((L,), bool)
     layers, whole = split_whole(layers)
 
     def body(carry, xs):
         h, k_all, v_all, ks_all, vs_all = carry
-        p, l, valid = xs
+        p, i, valid = xs
+        l = i + first_layer if first_layer else i  # the arena's layer slot
         h_new, k_all, v_all, ks_all, vs_all, stats = apply_layer(
-            join_whole(p, whole, l), l, valid, h, k_all, v_all, ks_all, vs_all
+            join_whole(p, whole, i), l, valid, h, k_all, v_all, ks_all, vs_all
         )
         h = jnp.where(valid, h_new, h)
         return (h, k_all, v_all, ks_all, vs_all), masked_stats(stats, valid)

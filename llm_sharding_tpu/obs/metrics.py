@@ -515,6 +515,12 @@ ARENA_BYTES = REGISTRY.gauge(
     "--kv-dtype are observable, not just asserted)",
     labels=("dtype",),
 )
+KV_ENTRY_BYTES = REGISTRY.gauge(
+    "server_kv_entry_bytes",
+    "Bytes ONE token of ONE layer holds in the paged KV arena of the newest "
+    "paged server: 2 x kv heads x head dim x itemsize, or a latent cache's "
+    "single padded entry (deepseek_v3: [c_kv | k_pe], 1,280 in bf16)",
+)
 KV_WASTE_FRAC = REGISTRY.gauge(
     "server_kv_waste_frac",
     "1 - live tokens / allocated token slots over the in-use blocks: the "
@@ -646,6 +652,18 @@ MOE_EXPERT_TOKENS = REGISTRY.counter(
     "(dead rows of a slot and pad positions route nowhere and are not "
     "counted). Uneven counts are uneven load",
     labels=("expert",),
+)
+MOE_PAIRS_ROUTED = REGISTRY.counter(
+    "server_moe_pairs_routed_total",
+    "A model with sparse experts: (token, expert) pairs the router chose "
+    "for live rows and prompt positions, over all of the layer's experts "
+    "and summed over layers",
+)
+MOE_PAIRS_HELD = REGISTRY.counter(
+    "server_moe_pairs_held_total",
+    "Of server_moe_pairs_routed_total, the pairs that fell on experts this "
+    "chip holds (a chip's share of the experts; all of them where it holds "
+    "every expert): the others form no tile and are not read for",
 )
 MOE_EXPERTS_READ = REGISTRY.gauge(
     "server_moe_experts_read",

@@ -124,6 +124,9 @@ SCOPES = (
                   # handle), an admitted window cut into head-major blocks
                   # — never the paged pool, which is stored as it is read
     "kv_put",     # the step's positions written back into the dense stack
+    "absorb",     # latent attention's two absorbed products: the query's
+                  # nope part into the latent space before the kernel, the
+                  # latent output out of it after (models/deepseek_v3.py)
     "attn",       # the attention kernel / XLA attention and its GQA fold
     "o_proj",     # output projection, its psum, the residual add
     "mlp",        # gated MLP, its psum, the residual add

@@ -63,7 +63,7 @@ def cached_attention(
         "bkgst,btkd->bskgd", probs.astype(v_cache.dtype), v_cache,
         preferred_element_type=jnp.float32,
     )
-    return out.reshape(B, S, Nh, D).astype(q.dtype)
+    return out.reshape(B, S, Nh, v_cache.shape[-1]).astype(q.dtype)
 
 
 # ``bucketed_decode_attention`` (the decode-window ``lax.switch`` over

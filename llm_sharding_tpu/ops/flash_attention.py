@@ -121,7 +121,8 @@ def _flash_kernel(
 def flash_attention(
     q: jnp.ndarray,  # [B, S, Nh, D] (RoPE'd)
     k_cache: jnp.ndarray,  # [B, C, Nkv, D] — keys already written
-    v_cache: jnp.ndarray,  # [B, C, Nkv, D]
+    v_cache: jnp.ndarray,  # [B, C, Nkv, Dv] — Dv may differ from D (latent
+    #   attention: values are a slice of the keys); the output is Dv wide
     q_positions: jnp.ndarray,  # [B, S]
     kv_positions: jnp.ndarray,  # [B, C]
     scale: float | None = None,
@@ -129,6 +130,7 @@ def flash_attention(
 ) -> jnp.ndarray:
     B, S, Nh, D = q.shape
     C, Nkv = k_cache.shape[1], k_cache.shape[2]
+    Dv = v_cache.shape[-1]
     G = Nh // Nkv
     if scale is None:
         scale = D ** -0.5
@@ -170,20 +172,20 @@ def flash_attention(
     grid = (B, Nkv, Lp // block_q, kv_blocks)
     out = pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, kv_blocks=kv_blocks),
-        out_shape=jax.ShapeDtypeStruct((B, Nkv, Lp, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Nkv, Lp, Dv), q.dtype),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D), lambda b, k, i, j: (b, k, i, 0)),
             pl.BlockSpec((1, 1, block_k, D), lambda b, k, i, j: (b, k, j, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, k, i, j: (b, k, j, 0)),
+            pl.BlockSpec((1, 1, block_k, Dv), lambda b, k, i, j: (b, k, j, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, k, i, j: (b, i, 0)),
             pl.BlockSpec((1, 1, block_k), lambda b, k, i, j: (b, 0, j)),
         ],
         out_specs=pl.BlockSpec(
-            (1, 1, block_q, D), lambda b, k, i, j: (b, k, i, 0)
+            (1, 1, block_q, Dv), lambda b, k, i, j: (b, k, i, 0)
         ),
         scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
@@ -193,8 +195,8 @@ def flash_attention(
         interpret=interpret,
         name="flash",
     )(qh, kh, vh, qp, kp)
-    out = out[:, :, :L].reshape(B, Nkv, G, S, D)
-    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(B, S, Nh, D)
+    out = out[:, :, :L].reshape(B, Nkv, G, S, Dv)
+    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(B, S, Nh, Dv)
 
 
 def attention_prefill(
@@ -213,6 +215,7 @@ def attention_prefill(
         jax.default_backend() == "tpu"
         and S > 1
         and D % 128 == 0
+        and v_cache.shape[-1] % 128 == 0
     )
     if use_pallas:
         return flash_attention(q, k_cache, v_cache, q_positions, kv_positions, scale)

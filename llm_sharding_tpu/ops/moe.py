@@ -41,6 +41,17 @@ dropped and there is no capacity factor.
 
 ``stats``: ``expert_tokens [E]`` — (live token, expert) pairs per expert —
 and ``experts_read`` — distinct experts the layer read (scalar).
+
+**A chip's share of the experts** (``expert_mlp(held=(first, count))``;
+``deepseek_v3``, ``models/deepseek_v3.py``): the leaves hold ``count`` of the
+layer's ``E`` experts, ids ``first … first + count - 1``. The router scores
+and normalises over ALL ``E`` (``route_noaux_tc``: sigmoid scores, a
+correction bias used for the choice only, groups of which the best are
+kept); a pair that falls on an expert held elsewhere forms no tile, is not
+read for, and adds nothing — what the absent experts would add is left out,
+and nothing stands in for the chips that hold them. ``expert_tokens`` stays
+``[E]`` (every pair routed, so pairs held = its slice over the held ids),
+``experts_read`` counts held experts only.
 """
 
 from __future__ import annotations
@@ -65,6 +76,16 @@ DECODE_ROWS_MAX = 32
 F_CHUNK = 512
 _VMEM_LIMIT = 64 * 1024 * 1024
 
+
+def f_chunk(hidden: int) -> int:
+    """``F_CHUNK`` while an ``H x F_CHUNK`` int8 block is at most 2 MiB (H up
+    to 4,096), else the largest multiple of 128 columns that keeps it so:
+    256 at H 7,168, where 512 would put three 3.5 MiB blocks, double
+    buffered, and their converted forms past the VMEM limit."""
+    if hidden * F_CHUNK <= 2 << 20:
+        return F_CHUNK
+    return max(128, ((2 << 20) // hidden) // 128 * 128)
+
 BACKENDS = ("auto", "kernel", "xla", "interpret")
 
 
@@ -85,6 +106,36 @@ def route(x, router, top_k: int, renormalize: bool = False):
     w, ids = jax.lax.top_k(probs, top_k)
     if renormalize:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return w, ids.astype(jnp.int32)
+
+
+def route_noaux_tc(x, router, bias, top_k: int, n_group: int,
+                   topk_group: int, scale: float):
+    """The ``deepseek_v3`` router (HF ``DeepseekV3TopkRouter``), ``x [N, H]``,
+    ``router [H, E]``, ``bias [E]`` → ``(weights [N, k] f32, ids [N, k])``:
+    ``s = sigmoid(x · router)`` in float32; the CHOICE is made on ``s +
+    bias``: a group's score is the sum of its two largest, the ``topk_group``
+    best groups are kept and the others' scores set to 0.0 (as
+    ``transformers`` masks them), the ``top_k`` largest name the experts;
+    their weights are the UNbiased ``s`` there, divided by their sum (+1e-20)
+    and multiplied by ``scale``."""
+    N, E = x.shape[0], router.shape[-1]
+    logits = jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    s = jax.nn.sigmoid(logits)
+    choice = s + bias.astype(jnp.float32)
+    grouped = choice.reshape(N, n_group, E // n_group)
+    group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+    _, kept = jax.lax.top_k(group_score, topk_group)
+    keep = jnp.any(
+        kept[:, :, None] == jnp.arange(n_group, dtype=kept.dtype), axis=1
+    )  # [N, n_group]
+    choice = jnp.where(keep[:, :, None], grouped, 0.0).reshape(N, E)
+    _, ids = jax.lax.top_k(choice, top_k)
+    w = jnp.take_along_axis(s, ids, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * scale
     return w, ids.astype(jnp.int32)
 
 
@@ -110,7 +161,8 @@ def _clamp_tail(values, n_live):
 
 def _decode_tiles(x, w, ids, live, E):
     """One tile per distinct expert of the live rows, all rows in each.
-    Returns ``(tiles, combine [NT, N] f32, counts [E])``."""
+    Returns ``(tiles, combine [NT, N] f32, counts [E])``. An id of ``E`` (an
+    expert held elsewhere, ``expert_mlp(held=)``) matches no tile."""
     N, H = x.shape
     k = ids.shape[1]
     onehot = (ids[:, :, None] == jnp.arange(E, dtype=jnp.int32)) & live[
@@ -257,7 +309,7 @@ def expert_tiles_tpu(tiles: Tiles, layer, wg, wu, wd, sg, su, *, E: int,
     R, tm, H = tiles.x.shape
     NT = tiles.row.shape[0]
     F = wg.shape[-1] // E
-    fc = min(F, F_CHUNK)
+    fc = min(F, f_chunk(H))
     if F % fc:
         raise ValueError(f"F_CHUNK {fc} does not divide the expert width {F}")
     n_f = F // fc
@@ -347,14 +399,30 @@ def expert_mlp(
     live=None,  # [N] bool — rows that route; None = all
     layer=None,  # scalar int32 index into layer-stacked weights; None = 2-D
     backend: str = "auto",
+    held=None,  # static (first, count): the leaves hold only these experts
 ):
     """``Σ_k weights[n, k] · MLP_{ids[n, k]}(x[n])`` for the live rows (zero
-    for the others) and the layer's ``MoeStats``."""
+    for the others) and the layer's ``MoeStats``. With ``held`` the sum is
+    over the pairs whose expert is held here (the module docstring)."""
     N, H = x.shape
     E = num_experts
     backend = resolve_backend(backend)
     if live is None:
         live = jnp.ones((N,), bool)
+    routed = None
+    if held is not None and tuple(held) != (0, E):
+        first, E = held
+        # every pair routed, per expert of the WHOLE layer: the counters'
+        # view; the tiles below see ids relative to the first held expert
+        routed = jnp.sum(
+            (ids[:, :, None] == jnp.arange(num_experts, dtype=jnp.int32))
+            & live[:, None, None], axis=(0, 1),
+        ).astype(jnp.int32)
+        # an expert held elsewhere becomes id E with weight 0: a dead pair in
+        # both regimes
+        here = (ids >= first) & (ids < first + E)
+        ids = jnp.where(here, ids - first, E)
+        weights = jnp.where(here, weights, 0.0)
     (wg, sg), (wu, su), (wd, sd) = (
         _leaf(w, layer) for w in (we_gate, we_up, we_down)
     )
@@ -386,5 +454,8 @@ def expert_mlp(
         out = out * jax.lax.dynamic_index_in_dim(
             sd, lyr, keepdims=False
         ).astype(jnp.float32)
-        stats = MoeStats(counts, jnp.sum(counts > 0).astype(jnp.int32))
+        stats = MoeStats(
+            counts if routed is None else routed,
+            jnp.sum(counts > 0).astype(jnp.int32),
+        )
         return out.astype(x.dtype), stats

@@ -316,7 +316,10 @@ def write_block_kv(
     if k_scale is None:
         return (
             k_arena.at[entry].set(k_new.astype(k_arena.dtype)),
-            v_arena.at[entry].set(v_new.astype(v_arena.dtype)),
+            # a latent arena holds no values (its value read is a slice of
+            # the key read): nothing to write
+            v_arena if v_arena.shape[-1] == 0
+            else v_arena.at[entry].set(v_new.astype(v_arena.dtype)),
         )
 
     qmax = kv_qmax(k_arena.dtype)
@@ -367,14 +370,18 @@ def paged_attention_xla(
     scale: float | None = None,
     k_scale: jnp.ndarray = None,  # [L, NB, Nkv] — quantized arenas only
     v_scale: jnp.ndarray = None,
+    latent_v: int = 0,
 ) -> jnp.ndarray:
     """Gather + position-masked attention: exact on every backend. A
     quantized arena dequantizes at the gather into the QUERY dtype — the
-    same dequant target as the fused kernel, so the two paths match."""
+    same dequant target as the fused kernel, so the two paths match. With
+    ``latent_v`` the values are the first ``latent_v`` lanes of the keys."""
     k, v = gather_block_kv(
         k_arena, v_arena, layer, block_table, k_scale, v_scale,
         out_dtype=q.dtype,
     )
+    if latent_v:
+        v = k[..., :latent_v]
     return cached_attention(q, k, v, q_positions, kv_positions, scale)
 
 
@@ -568,9 +575,12 @@ def _paged_kernel(
     scale,
     bps,
     quantized=False,
+    latent_v=0,  # a latent arena: no v refs, a block's values are the first
+    #   ``latent_v`` lanes of its keys (one DMA a block, not two)
 ):
     k_refs, rest = rest[:bps], rest[bps:]
-    v_refs, rest = rest[:bps], rest[bps:]
+    if not latent_v:
+        v_refs, rest = rest[:bps], rest[bps:]
     if quantized:
         ks_refs, rest = rest[:bps], rest[bps:]
         vs_refs, rest = rest[:bps], rest[bps:]
@@ -600,7 +610,8 @@ def _paged_kernel(
     qpos = qpos_ref[0]  # [M, 1]
     tiles = []
     for j in range(bps):
-        k_blk, v_blk = k_refs[j][0], v_refs[j][0]  # [Nkv, BS, D]
+        k_blk = k_refs[j][0]  # [Nkv, BS, D]
+        v_blk = k_blk[..., :latent_v] if latent_v else v_refs[j][0]
         if quantized:
             # THE fused dequant: the block streamed into VMEM as 1-byte
             # codes (half/quarter the DMA bytes of bf16) and dequantizes
@@ -625,7 +636,8 @@ def _paged_kernel(
         v_blk = jnp.where(live, v_blk, jnp.zeros_like(v_blk))
         kvpos = jnp.concatenate([kvpos_ref[0, j]] * Nkv, axis=1)
         tiles.append((
-            k_blk.reshape(Nkv * BS, D), v_blk.reshape(Nkv * BS, D),
+            k_blk.reshape(Nkv * BS, D),
+            v_blk.reshape(Nkv * BS, v_blk.shape[-1]),
             own & (kvpos <= qpos),
         ))
     _online_update(q, tiles, scale, acc_ref, m_ref, l_ref)
@@ -639,7 +651,8 @@ def _paged_kernel(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("scale", "interpret", "blocks_per_step")
+    jax.jit,
+    static_argnames=("scale", "interpret", "blocks_per_step", "latent_v"),
 )
 def paged_attention_tpu(
     q: jnp.ndarray,  # [B, S, Nh, D]
@@ -654,6 +667,9 @@ def paged_attention_tpu(
     k_scale: jnp.ndarray = None,  # [L, NB, Nkv] — quantized arenas only
     v_scale: jnp.ndarray = None,
     blocks_per_step: int | None = None,  # static; None = auto-selected
+    latent_v: int = 0,  # static: a latent arena — ``v_arena`` is not read,
+    #   a block's values are the first ``latent_v`` lanes of its keys and
+    #   the output is ``[B, S, Nh, latent_v]``
 ) -> jnp.ndarray:
     """Pallas paged DECODE attention whose work is the tokens that are
     written: ONE sequential grid axis over the LIVE cells of the call, a
@@ -707,6 +723,9 @@ def paged_attention_tpu(
     G = Nh // Nkv
     M = Nh * S
     quantized = k_scale is not None
+    Dv = latent_v or D
+    if latent_v and quantized:
+        raise NotImplementedError("a quantized latent arena is not done")
     if scale is None:
         scale = D ** -0.5
     if kv_positions.shape != (B, T * BS):
@@ -776,14 +795,14 @@ def paged_attention_tpu(
     def whole(i, lyr, tbl, nl, st, row):
         return (0, 0)
 
+    n_kv = 1 if latent_v else 2  # a latent block is read once
     in_specs = [
         pl.BlockSpec((1, M, D), of_row),
-        *[block_spec(j) for j in range(bps)],
-        *[block_spec(j) for j in range(bps)],
+        *[block_spec(j) for j in range(bps)] * n_kv,
     ]
     operands = [
         _layer_operand(layer), block_table, nlive, start, row_of, qh,
-        *([k_arena] * bps), *([v_arena] * bps),
+        *([k_arena] * bps), *([] if latent_v else [v_arena] * bps),
     ]
     if quantized:
         in_specs += (
@@ -809,9 +828,9 @@ def paged_attention_tpu(
         num_scalar_prefetch=5,
         grid=(jnp.maximum(ends[-1], 1),),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, M, D), of_row),
+        out_specs=pl.BlockSpec((1, M, Dv), of_row),
         scratch_shapes=[
-            pltpu.VMEM((M, D), jnp.float32),
+            pltpu.VMEM((M, Dv), jnp.float32),
             pltpu.VMEM((M, 128), jnp.float32),
             pltpu.VMEM((M, 128), jnp.float32),
         ],
@@ -819,8 +838,9 @@ def paged_attention_tpu(
     out = pl.pallas_call(
         functools.partial(
             _paged_kernel, scale=scale, bps=bps, quantized=quantized,
+            latent_v=latent_v,
         ),
-        out_shape=jax.ShapeDtypeStruct((B, M, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, M, Dv), q.dtype),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
@@ -830,7 +850,7 @@ def paged_attention_tpu(
     )(*operands)
     # a row the walk never visited was never written: it reads zeros
     out = jnp.where((nlive > 0)[:, None, None], out, jnp.zeros_like(out))
-    return jnp.transpose(out.reshape(B, Nh, S, D), (0, 2, 1, 3))
+    return jnp.transpose(out.reshape(B, Nh, S, Dv), (0, 2, 1, 3))
 
 
 #: Query-row tile of the chunked-prefill kernel (G·Sc folded rows per
@@ -852,9 +872,11 @@ def _paged_prefill_kernel(
     t_steps,
     bps,
     quantized=False,
+    latent_v=0,  # as in the decode kernel: values are a slice of the keys
 ):
     k_refs, rest = rest[:bps], rest[bps:]
-    v_refs, rest = rest[:bps], rest[bps:]
+    if not latent_v:
+        v_refs, rest = rest[:bps], rest[bps:]
     if quantized:
         ks_refs, rest = rest[:bps], rest[bps:]
         vs_refs, rest = rest[:bps], rest[bps:]
@@ -868,34 +890,61 @@ def _paged_prefill_kernel(
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0]  # [BQ, D]
-    for j in range(bps):
-        k_blk, v_blk = k_refs[j][0, 0], v_refs[j][0, 0]  # [BS, D]
-        if quantized:
-            # fused dequant, same contract as the decode kernel: codes
-            # stream, the bf16 window never exists in HBM
-            k_blk = (
-                k_blk.astype(jnp.float32) * ks_refs[j][0, 0]
-            ).astype(q.dtype)
-            v_blk = (
-                v_blk.astype(jnp.float32) * vs_refs[j][0, 0]
-            ).astype(q.dtype)
-        # live gate: trash blocks (table entry 0) AND blocks past the
-        # row's written frontier (the index maps redirected their DMA to
-        # block 0 — see paged_prefill_tpu) stream as zeros. Their
-        # positions are sentinel-masked below anyway; zeroing closes the
-        # 0 × Inf = NaN channel of the shared trash block's garbage.
-        idx = t * bps + j
-        live = (tbl_ref[b, idx] != 0) & (idx < nlive_ref[b])
-        k = jnp.where(live, k_blk, jnp.zeros_like(k_blk))
-        v = jnp.where(live, v_blk, jnp.zeros_like(v_blk))
-        # causal masking WITHIN the chunk falls out of the position
-        # compare: the chunk's own entries were scattered into the arena
-        # (with their kv positions) before this kernel runs, so a query
-        # at position p attends exactly the prefix ≤ p — earlier chunks,
-        # the radix prefix, and the chunk's own earlier tokens.
-        mask = kvpos_ref[0, j] <= qpos_ref[0]  # [BQ, BS]
-        _online_update(q, [(k, v, mask)], scale, acc_ref, m_ref, l_ref)
+    def _cell():
+        q = q_ref[0, 0]  # [BQ, D]
+        tiles = []
+        for j in range(bps):
+            k_blk = k_refs[j][0, 0]  # [BS, D]
+            v_blk = k_blk[:, :latent_v] if latent_v else v_refs[j][0, 0]
+            if quantized:
+                # fused dequant, same contract as the decode kernel: codes
+                # stream, the bf16 window never exists in HBM
+                k_blk = (
+                    k_blk.astype(jnp.float32) * ks_refs[j][0, 0]
+                ).astype(q.dtype)
+                v_blk = (
+                    v_blk.astype(jnp.float32) * vs_refs[j][0, 0]
+                ).astype(q.dtype)
+            # live gate: trash blocks (table entry 0) AND blocks past the
+            # row's written frontier (the index maps redirected their DMA to
+            # block 0 — see paged_prefill_tpu) stream as zeros. Their
+            # positions are sentinel-masked below anyway; zeroing closes the
+            # 0 × Inf = NaN channel of the shared trash block's garbage.
+            idx = t * bps + j
+            live = (tbl_ref[b, idx] != 0) & (idx < nlive_ref[b])
+            k = jnp.where(live, k_blk, jnp.zeros_like(k_blk))
+            v = jnp.where(live, v_blk, jnp.zeros_like(v_blk))
+            # causal masking WITHIN the chunk falls out of the position
+            # compare: the chunk's own entries were scattered into the arena
+            # (with their kv positions) before this kernel runs, so a query
+            # at position p attends exactly the prefix ≤ p — earlier chunks,
+            # the radix prefix, and the chunk's own earlier tokens.
+            if latent_v:
+                tiles.append((k, v, kvpos_ref[0, j]))
+                continue
+            mask = kvpos_ref[0, j] <= qpos_ref[0]  # [BQ, BS]
+            _online_update(q, [(k, v, mask)], scale, acc_ref, m_ref, l_ref)
+        if latent_v:
+            # the step's blocks as ONE key tile: one score dot bps·BS keys
+            # wide (a block alone is 32) and one rescale of the 64-head
+            # accumulator a step, not bps. (Positions are joined, not masks:
+            # Mosaic joins no booleans along lanes.)
+            ks, vs, kvpos = zip(*tiles)
+            mask = jnp.concatenate(kvpos, axis=1) <= qpos_ref[0]
+            _online_update(
+                q, [(jnp.concatenate(ks, axis=0), jnp.concatenate(vs, axis=0),
+                     mask)],
+                scale, acc_ref, m_ref, l_ref,
+            )
+
+    if latent_v:
+        # a latent chunk folds 64 heads into its query rows: a step whose
+        # blocks all lie past the written frontier (masked whole, and never
+        # the first) is skipped, compute and all — 15 of 16 steps of a
+        # prompt's first chunk. The other models' kernel is what it was.
+        pl.when(t * bps < nlive_ref[b])(_cell)
+    else:
+        _cell()
 
     @pl.when(t == t_steps - 1)
     def _finish():
@@ -906,7 +955,8 @@ def _paged_prefill_kernel(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("scale", "interpret", "blocks_per_step")
+    jax.jit,
+    static_argnames=("scale", "interpret", "blocks_per_step", "latent_v"),
 )
 def paged_prefill_tpu(
     q: jnp.ndarray,  # [B, S, Nh, D] — S = the chunk length (many rows)
@@ -923,6 +973,7 @@ def paged_prefill_tpu(
     nlive: jnp.ndarray = None,  # [B] int32 — blocks covering each row's
     #   written frontier (prefix + chunks so far); None = the full table
     blocks_per_step: int | None = None,  # static; None = auto-selected
+    latent_v: int = 0,  # static: a latent arena (see paged_attention_tpu)
 ) -> jnp.ndarray:
     """Flash-style CHUNKED-PREFILL attention over the paged arena: the
     query axis is a whole prompt chunk (folded with the GQA groups and
@@ -952,6 +1003,9 @@ def paged_prefill_tpu(
     T = block_table.shape[1]
     G = Nh // Nkv
     quantized = k_scale is not None
+    Dv = latent_v or D
+    if latent_v and quantized:
+        raise NotImplementedError("a quantized latent arena is not done")
     if scale is None:
         scale = D ** -0.5
     if kv_positions.shape != (B, T * BS):
@@ -1007,12 +1061,11 @@ def paged_prefill_tpu(
             (1, 1, block_q, D),
             lambda b, k, i, t, lyr, tbl, nl: (b, k, i, 0),
         ),
-        *[block_spec(j) for j in range(bps)],
-        *[block_spec(j) for j in range(bps)],
+        *[block_spec(j) for j in range(bps)] * (1 if latent_v else 2),
     ]
     operands = [
         _layer_operand(layer), block_table, nlive, qh,
-        *([k_arena] * bps), *([v_arena] * bps),
+        *([k_arena] * bps), *([] if latent_v else [v_arena] * bps),
     ]
     if quantized:
         in_specs += (
@@ -1037,11 +1090,11 @@ def paged_prefill_tpu(
         grid=(B, Nkv, GSp // block_q, T // bps),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, 1, block_q, D),
+            (1, 1, block_q, Dv),
             lambda b, k, i, t, lyr, tbl, nl: (b, k, i, 0),
         ),
         scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
@@ -1049,9 +1102,9 @@ def paged_prefill_tpu(
     out = pl.pallas_call(
         functools.partial(
             _paged_prefill_kernel, scale=scale, t_steps=T // bps,
-            bps=bps, quantized=quantized,
+            bps=bps, quantized=quantized, latent_v=latent_v,
         ),
-        out_shape=jax.ShapeDtypeStruct((B, Nkv, GSp, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Nkv, GSp, Dv), q.dtype),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
@@ -1061,8 +1114,8 @@ def paged_prefill_tpu(
         interpret=interpret,
         name="paged_prefill",
     )(*operands)
-    out = out[:, :, :GS].reshape(B, Nkv, G, S, D)
-    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(B, S, Nh, D)
+    out = out[:, :, :GS].reshape(B, Nkv, G, S, Dv)
+    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(B, S, Nh, Dv)
 
 
 def _ineligible_msg(op: str, k_arena, block_table) -> str:
@@ -1093,6 +1146,8 @@ def paged_prefill(
     v_scale: jnp.ndarray = None,
     nlive: jnp.ndarray = None,  # [B] — kernel-path traffic clamp
     stats: bool = False,  # static: return (acc, m, l) partials (cp serve)
+    latent_v: int = 0,  # static: a latent arena — values are the first
+    #   ``latent_v`` lanes of the keys, ``v_arena`` (zero wide) is not read
 ) -> jnp.ndarray:
     """Backend dispatch for CHUNKED-PREFILL attention over the arena,
     mirroring ``paged_attention``: the Pallas prefill kernel on TPU for
@@ -1113,6 +1168,10 @@ def paged_prefill(
             f"paged_prefill backend {backend!r}: expected one of "
             f"{BACKENDS}"
         )
+    if stats and latent_v:
+        raise NotImplementedError(
+            "context-parallel attention over a latent arena is not done"
+        )
     if stats:
         return attn_stats_xla(
             q, k_arena, v_arena, layer, block_table, q_positions,
@@ -1129,7 +1188,7 @@ def paged_prefill(
         return paged_prefill_tpu(
             q, k_arena, v_arena, layer, block_table, q_positions,
             kv_positions, scale, interpret=True, k_scale=k_scale,
-            v_scale=v_scale, nlive=nlive,
+            v_scale=v_scale, nlive=nlive, latent_v=latent_v,
         )
     if backend == "kernel":
         if jax.default_backend() != "tpu":
@@ -1150,10 +1209,12 @@ def paged_prefill(
         return paged_prefill_tpu(
             q, k_arena, v_arena, layer, block_table, q_positions,
             kv_positions, scale, k_scale=k_scale, v_scale=v_scale, nlive=nlive,
+            latent_v=latent_v,
         )
     return paged_attention_xla(
         q, k_arena, v_arena, layer, block_table, q_positions,
         kv_positions, scale, k_scale=k_scale, v_scale=v_scale,
+        latent_v=latent_v,
     )
 
 
@@ -1171,6 +1232,7 @@ def paged_attention(
     k_scale: jnp.ndarray = None,  # [L, NB, Nkv] — quantized arenas only
     v_scale: jnp.ndarray = None,
     stats: bool = False,  # static: return (acc, m, l) partials (cp serve)
+    latent_v: int = 0,  # static: a latent arena (see paged_prefill)
 ) -> jnp.ndarray:
     """Backend dispatch: the Pallas kernel on TPU for MXU-aligned shapes,
     the exact XLA gather path otherwise (CPU meshes, ragged head dims,
@@ -1193,6 +1255,10 @@ def paged_attention(
             f"paged_attention backend {backend!r}: expected one of "
             f"{BACKENDS}"
         )
+    if stats and latent_v:
+        raise NotImplementedError(
+            "context-parallel attention over a latent arena is not done"
+        )
     if stats:
         return attn_stats_xla(
             q, k_arena, v_arena, layer, block_table, q_positions,
@@ -1209,7 +1275,7 @@ def paged_attention(
         return paged_attention_tpu(
             q, k_arena, v_arena, layer, block_table, q_positions,
             kv_positions, scale, interpret=True, k_scale=k_scale,
-            v_scale=v_scale,
+            v_scale=v_scale, latent_v=latent_v,
         )
     if backend == "kernel":
         # curated here too, not only in the serve-side resolution: a
@@ -1234,8 +1300,10 @@ def paged_attention(
         return paged_attention_tpu(
             q, k_arena, v_arena, layer, block_table, q_positions,
             kv_positions, scale, k_scale=k_scale, v_scale=v_scale,
+            latent_v=latent_v,
         )
     return paged_attention_xla(
         q, k_arena, v_arena, layer, block_table, q_positions,
         kv_positions, scale, k_scale=k_scale, v_scale=v_scale,
+        latent_v=latent_v,
     )

@@ -177,6 +177,19 @@ LLAMA_QUANT_KEYS = (
     "we_gate", "we_up", "we_down",
 )
 GPT2_QUANT_KEYS = ("w_qkv", "w_out", "w_fc", "w_proj")
+# deepseek_v3 (models/deepseek_v3.py): the latent attention's projections and
+# absorbed factors and the shared expert, beside ``wo`` / ``w_*`` / ``we_*``
+# above; the router and its float32 correction bias stay as they are
+DEEPSEEK_QUANT_KEYS = (
+    "wq_a", "wq_b", "wkv_a", "w_uk", "w_uv", "ws_gate", "ws_up", "ws_down",
+)
+
+
+def is_kinds_tree(layers: dict) -> bool:
+    """A per-kind layers tree ``{kind: {leaf: ...}}`` (a model whose layers
+    are of several kinds, ``ModelConfig.layer_kinds``) and not ``{leaf:
+    ...}``: its values are dicts."""
+    return bool(layers) and all(isinstance(v, dict) for v in layers.values())
 
 
 def quantize_layer_params(
@@ -188,7 +201,12 @@ def quantize_layer_params(
     int8 leaf — required to quantize a 7B-class model in place on a 16 GB
     chip; the caller's original arrays are invalidated)."""
     if keys is None:
-        keys = LLAMA_QUANT_KEYS + GPT2_QUANT_KEYS
+        keys = LLAMA_QUANT_KEYS + GPT2_QUANT_KEYS + DEEPSEEK_QUANT_KEYS
+    if is_kinds_tree(layers):  # one stack per kind: each kind's leaves
+        return {
+            kind: quantize_layer_params(sub, keys, donate=donate, bits=bits)
+            for kind, sub in layers.items()
+        }
     if not donate:
         return {
             k: (
@@ -250,6 +268,8 @@ def quantize_params(
 
 
 def is_quantized(layers: dict) -> bool:
+    if is_kinds_tree(layers):
+        return any(is_quantized(sub) for sub in layers.values())
     return any(isinstance(v, QTensor) for v in layers.values())
 
 

@@ -10,10 +10,13 @@ activation hop (``/root/reference/utils/node_worker.py:149-153, 238-243,
 Conventions match HF's ``rotate_half`` formulation so that weights converted
 from HF checkpoints reproduce logits exactly. Includes Llama-3 frequency
 scaling (``rope_type="llama3"``) for the Llama-3-8B config ladder entry
-(BASELINE.md config #4).
+(BASELINE.md config #4) and YaRN (``rope_type="yarn"``, as ``deepseek_v3``
+publishes it; the rotated width is ``cfg.rope_dim``).
 """
 
 from __future__ import annotations
+
+import math
 
 import numpy as np
 import jax.numpy as jnp
@@ -36,14 +39,61 @@ def _llama3_scale_inv_freq(inv_freq: np.ndarray, rs: RopeScaling) -> np.ndarray:
     return np.where(is_medium, smoothed, scaled)
 
 
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's magnitude correction ``0.1 · mscale · ln(factor) + 1`` (1 at
+    factors up to 1)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_attention_factor(rs: RopeScaling) -> float:
+    """What YaRN multiplies cos and sin by (HF ``_compute_yarn_parameters``):
+    ``mscale(factor, mscale) / mscale(factor, mscale_all_dim)`` when both
+    are given, else ``mscale(factor)``."""
+    if rs.mscale and rs.mscale_all_dim:
+        return yarn_mscale(rs.factor, rs.mscale) / yarn_mscale(
+            rs.factor, rs.mscale_all_dim
+        )
+    return yarn_mscale(rs.factor)
+
+
+def _yarn_inv_freq(dim: int, base: float, rs: RopeScaling) -> np.ndarray:
+    """YaRN (HF ``_compute_yarn_parameters``): frequencies that turn more
+    than ``beta_fast`` times in the original context are kept, those that
+    turn fewer than ``beta_slow`` times are divided by ``factor``, a linear
+    ramp over the pair index in between."""
+    def correction_dim(rotations):
+        return dim * math.log(
+            rs.original_max_position_embeddings / (rotations * 2 * math.pi)
+        ) / (2 * math.log(base))
+
+    low, high = correction_dim(rs.beta_fast), correction_dim(rs.beta_slow)
+    if rs.truncate:
+        low, high = math.floor(low), math.ceil(high)
+    low, high = max(low, 0), min(high, dim - 1)
+    if low == high:
+        high += 0.001  # prevent singularity
+    ramp = np.clip(
+        (np.arange(dim // 2, dtype=np.float32) - low) / (high - low), 0, 1
+    )
+    pos_freqs = base ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    extrapolation = 1.0 - ramp
+    return (
+        (1.0 / (rs.factor * pos_freqs)) * (1 - extrapolation)
+        + (1.0 / pos_freqs) * extrapolation
+    )
+
+
 def inv_frequencies(cfg: ModelConfig) -> np.ndarray:
-    """Static (trace-time) inverse frequencies, shape [head_dim/2], fp32."""
-    d = cfg.head_dim_
+    """Static (trace-time) inverse frequencies, shape [rope_dim/2], fp32."""
+    d = cfg.rope_dim
+    rs = cfg.rope_scaling
+    if rs is not None and rs.rope_type == "yarn":
+        return _yarn_inv_freq(d, cfg.rope_theta, rs).astype(np.float32)
     inv_freq = 1.0 / (
         cfg.rope_theta ** (np.arange(0, d, 2, dtype=np.float64) / d)
     ).astype(np.float64)
-    if cfg.rope_scaling is not None and cfg.rope_scaling.rope_type == "llama3":
-        inv_freq = _llama3_scale_inv_freq(inv_freq, cfg.rope_scaling)
+    if rs is not None and rs.rope_type == "llama3":
+        inv_freq = _llama3_scale_inv_freq(inv_freq, rs)
     return inv_freq.astype(np.float32)
 
 
@@ -58,7 +108,13 @@ def rope_cos_sin(
     inv_freq = jnp.asarray(inv_frequencies(cfg))  # [D/2]
     freqs = positions.astype(jnp.float32)[..., None] * inv_freq  # [..., D/2]
     emb = jnp.concatenate([freqs, freqs], axis=-1)  # [..., D]
-    return jnp.cos(emb).astype(dtype), jnp.sin(emb).astype(dtype)
+    cos, sin = jnp.cos(emb), jnp.sin(emb)
+    rs = cfg.rope_scaling
+    if rs is not None and rs.rope_type == "yarn":
+        factor = yarn_attention_factor(rs)
+        if factor != 1.0:
+            cos, sin = cos * factor, sin * factor
+    return cos.astype(dtype), sin.astype(dtype)
 
 
 def apply_rope(

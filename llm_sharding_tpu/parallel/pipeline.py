@@ -95,6 +95,15 @@ def model_fns(
                 "context-parallel serving supports the llama family only"
             )
         fwd, fwd_paged = gpt2.forward_layers, gpt2.forward_layers_paged
+    elif cfg.model_type == "deepseek_v3":
+        from ..models import deepseek_v3 as deepseek
+
+        if tp_axis is not None or cp_axis is not None:
+            raise NotImplementedError(
+                "tensor / context parallelism over deepseek_v3 (latent "
+                "attention, a share of the experts) is not implemented"
+            )
+        fwd, fwd_paged = deepseek.forward_layers, deepseek.forward_layers_paged
     else:
         raise ValueError(f"unsupported model_type: {cfg.model_type!r}")
 
@@ -350,7 +359,7 @@ def _pipeline_generate_jit(
     Bl = B // dp  # rows per data replica
     total = S + max_new_tokens
     Lp = layer_masks.shape[1]
-    Nkv_local = cfg.num_key_value_heads // tp
+    Nkv_local = cfg.cache_heads // tp
     ring = [(i, (i + 1) % num_stages) for i in range(num_stages)]
 
     def body(stage_layers, layer_mask, head_params, prompt, prompt_len, rng,
@@ -371,11 +380,11 @@ def _pipeline_generate_jit(
 
         cache = KVCache(
             k=jnp.zeros(
-                (Lp, Bl, capacity, Nkv_local, cfg.head_dim_),
+                (Lp, Bl, capacity, Nkv_local, cfg.cache_k_dim),
                 cache_dtype,
             ),
             v=jnp.zeros(
-                (Lp, Bl, capacity, Nkv_local, cfg.head_dim_),
+                (Lp, Bl, capacity, Nkv_local, cfg.cache_v_dim),
                 cache_dtype,
             ),
             pos=jnp.full((Bl, capacity), POS_SENTINEL, jnp.int32),

@@ -167,7 +167,7 @@ class PlacementSpec:
 
 
 def stack_stage_params(
-    spec: PlacementSpec, full_layers: dict[str, Any]
+    spec: PlacementSpec, full_layers: dict[str, Any], kinds: tuple = (),
 ) -> tuple[dict[str, np.ndarray], np.ndarray]:
     """Slice full-model stacked layers [L, ...] into per-stage padded stacks.
 
@@ -175,17 +175,26 @@ def stack_stage_params(
     ``[num_stages, max_layers_per_stage, ...]`` (shard axis 0 over "pipe") and
     ``layer_masks`` is ``[num_stages, max_layers_per_stage]`` bool.
 
+    A model whose layers are of several ``kinds`` (one name per layer,
+    ``ModelConfig.layer_kinds``) hands over ``{kind: {leaf: [L_kind, ...]}}``,
+    one stack per kind in layer order, and gets the same back per kind:
+    ``{kind: {leaf: [num_stages, P_kind, ...]}}``, each kind padded to the
+    most any stage holds of it. A stage's layer SLOTS are then its kinds'
+    stacks laid end to end (``models/stack.kind_spans``) and ``layer_masks``
+    is ``[num_stages, sum of P_kind]`` in that order. A stage must hold its
+    layers in slot order, i.e. each stage's layers must be sorted by first
+    appearance of their kind — true of any contiguous range of a model with
+    leading layers of one kind.
+
     Works on HOST (numpy) arrays and returns numpy: the caller device_puts the
     result with the mesh sharding (see ``runtime/engine.py``), so the padded
     stack never materializes whole on a single device — only each device's
     slice lands in its HBM.
     """
-    P = spec.max_layers_per_stage
-
-    def slice_leaf(leaf) -> np.ndarray:
+    def pad_stack(leaf, ranges, P) -> np.ndarray:
         leaf = np.asarray(leaf)
         parts = []
-        for start, end in spec.stages:
+        for start, end in ranges:
             chunk = leaf[start:end]
             if end - start < P:
                 pad = np.zeros((P - (end - start), *chunk.shape[1:]), chunk.dtype)
@@ -193,8 +202,36 @@ def stack_stage_params(
             parts.append(chunk)
         return np.stack(parts)
 
-    stage_layers = jax.tree.map(slice_leaf, full_layers)
-    masks = np.zeros((spec.num_stages, P), bool)
-    for i, (start, end) in enumerate(spec.stages):
-        masks[i, : end - start] = True
-    return stage_layers, masks
+    def masks_of(ranges, P) -> np.ndarray:
+        masks = np.zeros((spec.num_stages, P), bool)
+        for i, (start, end) in enumerate(ranges):
+            masks[i, : end - start] = True
+        return masks
+
+    if not kinds:
+        P = spec.max_layers_per_stage
+        stage_layers = jax.tree.map(
+            lambda leaf: pad_stack(leaf, spec.stages, P), full_layers
+        )
+        return stage_layers, masks_of(spec.stages, P)
+
+    order = list(dict.fromkeys(kinds))
+    stage_layers, masks = {}, []
+    for kind in order:
+        # each stage's layers of this kind as a range of the KIND's stack
+        ranges = []
+        for start, end in spec.stages:
+            mine = list(kinds[start:end])
+            if mine != sorted(mine, key=order.index):
+                raise ValueError(
+                    f"stage layers {start}..{end} hold kinds {mine}: a "
+                    "stage's layers must come kind after kind"
+                )
+            before = kinds[:start].count(kind)
+            ranges.append((before, before + mine.count(kind)))
+        P = max(e - s for s, e in ranges)
+        stage_layers[kind] = jax.tree.map(
+            lambda leaf: pad_stack(leaf, ranges, P), full_layers[kind]
+        )
+        masks.append(masks_of(ranges, P))
+    return stage_layers, np.concatenate(masks, axis=1)

@@ -111,8 +111,8 @@ def _interleaved_jit(
 
         # ---- batched prefill: all M rows in one chain traversal ----
         cache = KVCache(
-            k=jnp.zeros((Lp, M, capacity, cfg.num_key_value_heads, cfg.head_dim_), cache_dtype),
-            v=jnp.zeros((Lp, M, capacity, cfg.num_key_value_heads, cfg.head_dim_), cache_dtype),
+            k=jnp.zeros((Lp, M, capacity, cfg.cache_heads, cfg.cache_k_dim), cache_dtype),
+            v=jnp.zeros((Lp, M, capacity, cfg.cache_heads, cfg.cache_v_dim), cache_dtype),
             pos=jnp.full((M, capacity), POS_SENTINEL, jnp.int32),
             length=jnp.zeros((), jnp.int32),
         )

@@ -321,6 +321,10 @@ def make_state(
         materialize the global array on the default device first) and no
         host→device transfer of hundreds of MB of zeros. Multi-controller
         keeps the per-process put_global assembly."""
+        if single and 0 in shape:
+            # a latent cache's empty value array: nothing to fill, and XLA
+            # gives a zero-sized result a sharding of its own
+            return jax.device_put(np.zeros(shape, dtype), sh)
         if single:
             return jax.jit(
                 lambda: jnp.zeros(shape, dtype), out_shardings=sh
@@ -336,13 +340,18 @@ def make_state(
             S, *paged_arena_shape(cfg, cp * kv_blocks, kv_block_size, Lp)
         )
     else:
-        kv_shape = (S, Lp, M, C, cfg.num_key_value_heads, cfg.head_dim_)
+        kv_shape = (S, Lp, M, C, cfg.cache_heads, cfg.cache_k_dim)
+    # the value array beside it: the same but for the last dim — ZERO wide
+    # for a latent cache, whose value read is a slice of the key read. The
+    # array stays, empty, so that state, snapshots and host tiers keep one
+    # structure for every model
+    v_shape = (*kv_shape[:-1], cfg.cache_v_dim)
     # quantized (int8/fp8) arenas carry per-block-per-head scale arenas;
     # everything else gets the minimal placeholder (pytree parity — same
     # treatment as dense mode's [M, 1] block-table stub)
     quantized = paged and is_kv_quantized(cache_dtype)
     scale_shape = (
-        (S, Lp, cp * kv_blocks, cfg.num_key_value_heads) if quantized
+        (S, Lp, cp * kv_blocks, cfg.cache_heads) if quantized
         else (S, 1, 1, 1)
     )
     dev_scale = (
@@ -353,7 +362,7 @@ def make_state(
     tbl_sh = NamedSharding(mesh, P(CP_AXIS)) if cp > 1 else rep
     state = ServeState(
         k=zeros(kv_shape, cache_dtype, dev_kv),
-        v=zeros(kv_shape, cache_dtype, dev_kv),
+        v=zeros(v_shape, cache_dtype, dev_kv),
         k_scale=zeros(scale_shape, jnp.float32, dev_scale),
         v_scale=zeros(scale_shape, jnp.float32, dev_scale),
         kpos=put(np.full((S, M, C), int(POS_SENTINEL), np.int32), dev),
@@ -401,7 +410,7 @@ def prefix_prefill(
     a 1-row slice of the serve state's cache."""
     fns = model_fns(cfg, tp_axis=TENSOR_AXIS if tp > 1 else None)
     Sp = prefix.shape[1]
-    nkv = cfg.num_key_value_heads // tp  # heads LOCAL to a tensor shard
+    nkv = cfg.cache_heads // tp  # heads LOCAL to a tensor shard
     ring = [(i, (i + 1) % num_stages) for i in range(num_stages)]
 
     def body(stage_layers, layer_mask, head_params, prefix, prefix_len):
@@ -411,8 +420,8 @@ def prefix_prefill(
         sidx = jax.lax.axis_index(PIPE_AXIS)
         Lp = lmask.shape[0]
         cache = KVCache(
-            k=jnp.zeros((Lp, 1, Sp, nkv, cfg.head_dim_), cache_dtype),
-            v=jnp.zeros((Lp, 1, Sp, nkv, cfg.head_dim_), cache_dtype),
+            k=jnp.zeros((Lp, 1, Sp, nkv, cfg.cache_k_dim), cache_dtype),
+            v=jnp.zeros((Lp, 1, Sp, nkv, cfg.cache_v_dim), cache_dtype),
             pos=jnp.full((1, Sp), POS_SENTINEL, jnp.int32),
             length=jnp.zeros((), jnp.int32),
         )
@@ -673,7 +682,7 @@ def serve_admit(
     unchanged, so carried and fresh requests co-admit in one batch."""
     fns = model_fns(cfg, tp_axis=TENSOR_AXIS if tp > 1 else None)
     Bs, Sp = prompts.shape
-    nkv = cfg.num_key_value_heads // tp  # heads LOCAL to a tensor shard
+    nkv = cfg.cache_heads // tp  # heads LOCAL to a tensor shard
     ring = [(i, (i + 1) % num_stages) for i in range(num_stages)]
     C = state.out.shape[1]
     quantized = is_kv_quantized(state.k.dtype)  # trace-time constant
@@ -695,10 +704,10 @@ def serve_admit(
 
         # fresh cache rows for this slot only
         Lp = lmask.shape[0]
-        kv_shape = (Lp, Bs, C, nkv, cfg.head_dim_)
+        kv_shape = (Lp, Bs, C, nkv)
         cache = KVCache(
-            k=jnp.zeros(kv_shape, cache_dtype),
-            v=jnp.zeros(kv_shape, cache_dtype),
+            k=jnp.zeros((*kv_shape, cfg.cache_k_dim), cache_dtype),
+            v=jnp.zeros((*kv_shape, cfg.cache_v_dim), cache_dtype),
             pos=jnp.full((Bs, C), POS_SENTINEL, jnp.int32),
             length=jnp.zeros((), jnp.int32),
         )

@@ -34,6 +34,8 @@ Design points:
 
 from __future__ import annotations
 
+from typing import Optional
+
 import numpy as np
 
 TRASH_BLOCK = 0  # reserved garbage sink; table entries default here
@@ -87,7 +89,7 @@ class BlockAllocator:
 
     def bytes_per_block(
         self, *, num_layers: int, num_kv_heads: int, head_dim: int,
-        kv_dtype,
+        kv_dtype, value_dim: Optional[int] = None,
     ) -> int:
         """Device bytes ONE arena block costs across all layers: K + V
         codes (``2 × L × BS × Nkv × Dh × itemsize``) plus, for quantized
@@ -96,22 +98,24 @@ class BlockAllocator:
         behind the ``server_arena_bytes{dtype=...}`` gauge and the
         capacity table in README — at equal HBM budget,
         ``budget // bytes_per_block`` is how many blocks each dtype
-        admits (int8 ≈ 2× bf16)."""
+        admits (int8 ≈ 2× bf16). ``value_dim`` is the width of a value
+        entry where it is not the key's (a latent arena: 0)."""
         item = np.dtype(kv_dtype).itemsize
-        kv = 2 * num_layers * self.block_size * num_kv_heads * head_dim * item
+        widths = head_dim + (head_dim if value_dim is None else value_dim)
+        kv = num_layers * self.block_size * num_kv_heads * widths * item
         scales = 2 * num_layers * num_kv_heads * 4 if item == 1 else 0
         return kv + scales
 
     def arena_bytes(
         self, *, num_layers: int, num_kv_heads: int, head_dim: int,
-        kv_dtype,
+        kv_dtype, value_dim: Optional[int] = None,
     ) -> int:
         """Total device bytes of this pool's arena (every block including
         the reserved trash sink — the arrays exist whether or not a block
         is allocatable)."""
         return self.num_blocks * self.bytes_per_block(
             num_layers=num_layers, num_kv_heads=num_kv_heads,
-            head_dim=head_dim, kv_dtype=kv_dtype,
+            head_dim=head_dim, kv_dtype=kv_dtype, value_dim=value_dim,
         )
 
     @property

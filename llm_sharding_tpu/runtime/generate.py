@@ -23,7 +23,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..models import gpt2, llama
+from ..models import deepseek_v3, gpt2, llama
 from ..models.cache import KVCache, POS_SENTINEL, init_cache
 from ..models.config import ModelConfig
 from ..ops.sampling import (
@@ -38,7 +38,10 @@ ForwardFn = Callable[..., tuple[jnp.ndarray, KVCache]]
 def forward_fn_for(cfg: ModelConfig) -> ForwardFn:
     """Architecture dispatch (≙ the llama/gpt branch in
     ``/root/reference/utils/model_sharder.py:64,96``)."""
-    return {"llama": llama.forward, "gpt2": gpt2.forward}[cfg.model_type]
+    return {
+        "llama": llama.forward, "gpt2": gpt2.forward,
+        "deepseek_v3": deepseek_v3.forward,
+    }[cfg.model_type]
 
 
 _is_stop = _is_stop_op

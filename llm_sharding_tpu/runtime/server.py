@@ -79,7 +79,8 @@ from ..obs.metrics import (
     CP_STREAM_SHARDS, DECODE_BLOCKS_LIVE, DECODE_BLOCKS_RESERVED,
     DEFAULT_RATE_BUCKETS,
     KV_BLOCKS_IN_USE, KV_BLOCKS_TOTAL, KV_DISK_TIER_BLOCKS,
-    KV_HOST_TIER_BLOCKS, KV_WASTE_FRAC, MOE_EXPERT_TOKENS, MOE_EXPERTS_READ,
+    KV_ENTRY_BYTES, KV_HOST_TIER_BLOCKS, KV_WASTE_FRAC, MOE_EXPERT_TOKENS,
+    MOE_EXPERTS_READ, MOE_PAIRS_HELD, MOE_PAIRS_ROUTED,
     PREFILL_BLOCKS_READ, PREFILL_POSITIONS, PREFIX_HIT_RATE,
     PREFIX_HIT_TOKENS, REGISTRY, record_shape_key, set_prefill_path,
 )
@@ -1102,6 +1103,26 @@ class PipelineServer:
                 "kv_dtype='fp8': this jax backend cannot round-trip "
                 "float8_e4m3fn arrays — use kv_dtype='int8'"
             )
+        if self.cfg.latent_kv:
+            # a latent cache (deepseek_v3): what is not carried is refused
+            # by name, never computed as something else
+            if kv_dtype != "bf16":
+                raise NotImplementedError(
+                    f"kv_dtype={kv_dtype!r} over a latent KV cache "
+                    f"({self.cfg.model_type}): a quantized latent arena is "
+                    "not implemented — serve it with kv_dtype='bf16'"
+                )
+            if self.speculate:
+                raise NotImplementedError(
+                    f"speculate over a latent KV cache "
+                    f"({self.cfg.model_type}): serve_verify is not carried "
+                    "over the latent arena — serve it with speculate=0"
+                )
+            if cp > 1 or self.tp > 1:
+                raise NotImplementedError(
+                    f"cp / tp over a latent KV cache "
+                    f"({self.cfg.model_type}) is not implemented"
+                )
         self.kv_dtype = kv_dtype
         #: the arena STORAGE dtype (engine.cache_dtype stays the compute
         #: dtype — prefill windows, prefix handles and dense state use it)
@@ -1361,10 +1382,18 @@ class PipelineServer:
             # pipeline layers count (their arena rows are allocated).
             self.arena_bytes_device = self._alloc.arena_bytes(
                 num_layers=self.num_stages * Lp,
-                num_kv_heads=self.cfg.num_key_value_heads,
-                head_dim=self.cfg.head_dim_,
+                num_kv_heads=self.cfg.cache_heads,
+                head_dim=self.cfg.cache_k_dim,
                 kv_dtype=self.kv_store_dtype,
+                value_dim=self.cfg.cache_v_dim,
             )
+            # what ONE token of one layer holds in the arena: 2 x Nkv x Dh
+            # values, or a latent cache's one padded entry
+            KV_ENTRY_BYTES.set(float(
+                self.cfg.cache_heads
+                * (self.cfg.cache_k_dim + self.cfg.cache_v_dim)
+                * np.dtype(self.kv_store_dtype).itemsize
+            ))
             # host mirror of the device block tables (all-trash at birth);
             # _push_tables ships it whole — [M, T] int32 is a few hundred
             # bytes, far below one chunk log
@@ -1541,9 +1570,9 @@ class PipelineServer:
             -(self.capacity + self._spec_cols) // self.kv_block_size
         )
         eligible = kernel_eligible(
-            self.cfg.head_dim_, self.kv_block_size, self.kv_store_dtype,
+            self.cfg.cache_k_dim, self.kv_block_size, self.kv_store_dtype,
             rows=self.batch_per_slot, table_width=table_width,
-            kv_heads=max(self.cfg.num_key_value_heads // self.tp, 1),
+            kv_heads=max(self.cfg.cache_heads // self.tp, 1),
         )
 
         def check_kernel(source: str) -> None:
@@ -1557,7 +1586,7 @@ class PipelineServer:
             if not eligible:
                 sublane = kernel_sublane(self.kv_store_dtype)
                 raise ValueError(
-                    f"{source}: head_dim={self.cfg.head_dim_} / "
+                    f"{source}: head_dim={self.cfg.cache_k_dim} / "
                     f"kv_block_size={self.kv_block_size} / block table "
                     f"[{self.batch_per_slot}, {table_width}] are not "
                     f"Mosaic-eligible for KV storage dtype "
@@ -4814,6 +4843,9 @@ class PipelineServer:
         for child, n in zip(self._moe_children, tokens):
             if n:
                 child.inc(int(n))
+        lo, held = self.cfg.held_experts_
+        MOE_PAIRS_ROUTED.inc(int(tokens.sum()))
+        MOE_PAIRS_HELD.inc(int(tokens[lo:lo + held].sum()))
         if decode:  # a chunk log: one row of counters per decode microstep
             read = own[:, E:-1][:, self._moe_layers]
             busy = own[:, -1] > 0

@@ -99,6 +99,89 @@ def llama_layer_arrays(
     return p
 
 
+def deepseek_layer_arrays(
+    cfg: ModelConfig, get: TensorGetter, i: int, dtype
+) -> dict[str, jnp.ndarray]:
+    """One ``deepseek_v3`` layer (HF ``modeling_deepseek_v3.py`` names) in
+    the layout of ``models/deepseek_v3.py``; the layer's kind is
+    ``cfg.layer_kinds[i]``. Three re-layouts, none of them arithmetic:
+
+    - a head's ROTATED columns of ``q_b_proj`` and of ``kv_a_proj_with_mqa``
+      are de-interleaved (pairs ``(2j, 2j+1)`` → halves ``(j, j + d/2)``):
+      ``transformers`` does that to the activations on every call
+      (``rope_interleave``); done to the columns once, rotation is the
+      rotate-half of ``ops/rope.py`` and q·k is unchanged;
+      ``kv_a_proj_with_mqa`` also gets zero columns up to the arena entry's
+      width (``cfg.cache_k_dim``: whole 128-lane tiles);
+    - ``kv_b_proj`` ``[Nh·(nope+v), kv_lora]`` is split per head into the
+      two absorbed factors ``w_uk [Nh·nope, kv_lora]`` and ``w_uv
+      [Nh·v, kv_lora]`` (its own rows, no transpose);
+    - of the routed experts only those this chip HOLDS are read
+      (``cfg.held_experts_``), as one block-sparse MLP;
+      the router and its correction bias keep all ``num_experts``."""
+    pre = f"model.layers.{i}."
+    Nh = cfg.num_attention_heads
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    r = cfg.kv_lora_rank
+    halves = np.concatenate([np.arange(0, dr, 2), np.arange(1, dr, 2)])
+
+    def raw(name):  # torch Linear stores [out, in]; we use [in, out]
+        return np.asarray(get(pre + name + ".weight")).T
+
+    def arr(x):
+        return jnp.asarray(x, dtype)
+
+    def norm(name):
+        return arr(get(pre + name + ".weight"))
+
+    wq_b = raw("self_attn.q_b_proj").reshape(-1, Nh, dn + dr)
+    wq_b = np.concatenate([wq_b[..., :dn], wq_b[..., dn:][..., halves]], -1)
+    wkv_a = raw("self_attn.kv_a_proj_with_mqa")
+    wkv_a = np.concatenate([wkv_a[:, :r], wkv_a[:, r:][:, halves]], -1)
+    wkv_a = np.pad(wkv_a, ((0, 0), (0, cfg.cache_k_dim - r - dr)))
+    kv_b = np.asarray(get(pre + "self_attn.kv_b_proj.weight")).reshape(
+        Nh, dn + dv, r
+    )
+    p = {
+        "input_norm": norm("input_layernorm"),
+        "wq_a": arr(raw("self_attn.q_a_proj")),
+        "q_a_norm": norm("self_attn.q_a_layernorm"),
+        "wq_b": arr(wq_b.reshape(-1, Nh * (dn + dr))),
+        "wkv_a": arr(wkv_a),
+        "kv_a_norm": norm("self_attn.kv_a_layernorm"),
+        "w_uk": arr(kv_b[:, :dn].reshape(Nh * dn, r)),
+        "w_uv": arr(kv_b[:, dn:].reshape(Nh * dv, r)),
+        "wo": arr(raw("self_attn.o_proj")),
+        "post_norm": norm("post_attention_layernorm"),
+    }
+    if cfg.layer_kinds[i] == "dense":
+        p.update(
+            w_gate=arr(raw("mlp.gate_proj")), w_up=arr(raw("mlp.up_proj")),
+            w_down=arr(raw("mlp.down_proj")),
+        )
+        return p
+    first, count = cfg.held_experts_
+    held = range(first, first + count)
+
+    def experts(name, axis):
+        return arr(np.concatenate(
+            [raw(f"mlp.experts.{e}.{name}") for e in held], axis=axis
+        ))
+
+    p.update(
+        router=arr(raw("mlp.gate")),
+        router_bias=jnp.asarray(
+            get(pre + "mlp.gate.e_score_correction_bias"), jnp.float32
+        ),
+        we_gate=experts("gate_proj", 1), we_up=experts("up_proj", 1),
+        we_down=experts("down_proj", 0),
+        ws_gate=arr(raw("mlp.shared_experts.gate_proj")),
+        ws_up=arr(raw("mlp.shared_experts.up_proj")),
+        ws_down=arr(raw("mlp.shared_experts.down_proj")),
+    )
+    return p
+
+
 def gpt2_layer_arrays(
     cfg: ModelConfig, get: TensorGetter, i: int, dtype
 ) -> dict[str, jnp.ndarray]:
@@ -160,6 +243,23 @@ def params_from_hf(
         # tied: no duplicate vocab×hidden buffer — final_logits contracts
         # against the embedding table (see models/llama.py:final_logits)
         return params
+    elif cfg.model_type == "deepseek_v3":
+        # one stack per kind, in layer order (cfg.layer_kinds); the
+        # vocabulary tables keep the rows held here (rows 0..vocab_size-1)
+        kinds = cfg.layer_kinds
+        V = cfg.vocab_size
+        return {
+            "embed": jnp.asarray(get("model.embed_tokens.weight")[:V], dtype),
+            "layers": {
+                kind: _stack([
+                    deepseek_layer_arrays(cfg, get, i, dtype)
+                    for i in range(cfg.num_hidden_layers) if kinds[i] == kind
+                ])
+                for kind in dict.fromkeys(kinds)
+            },
+            "final_norm": jnp.asarray(get("model.norm.weight"), dtype),
+            "lm_head": jnp.asarray(get("lm_head.weight")[:V].T, dtype),
+        }
     elif cfg.model_type == "gpt2":
         pre = "transformer." if _has(get, "transformer.wte.weight") else ""
         wte = jnp.asarray(get(pre + "wte.weight"), dtype)

@@ -46,6 +46,7 @@ from ..models.config import ModelConfig
 from .convert import (
     TensorGetter,
     _getter,
+    deepseek_layer_arrays,
     gpt2_layer_arrays,
     llama_layer_arrays,
 )
@@ -251,11 +252,17 @@ def save_shards(
     _save_npz(os.path.join(out_dir, "embedding.npz"), emb)
 
     layers = src["layers"]
+    kinds = cfg.layer_kinds
     for i in range(cfg.num_hidden_layers):
-        # tree.map slices through QTensor leaves (q AND scale) correctly
+        # tree.map slices through QTensor leaves (q AND scale) correctly. A
+        # model whose layers are of several kinds keeps one stack per kind
+        # (in layer order); a block file is one layer whatever its kind
+        stack, j = (layers, i) if not kinds else (
+            layers[kinds[i]], kinds[:i].count(kinds[i])
+        )
         _save_npz(
             os.path.join(out_dir, f"block_{i}.npz"),
-            jax.tree.map(lambda a, i=i: a[i], layers),
+            jax.tree.map(lambda a, j=j: a[j], stack),
         )
 
     fn = {"final_norm": src["final_norm"]}
@@ -299,15 +306,20 @@ def save_shards_streaming(
     if tokenizer_dir:
         copy_tokenizer_files(tokenizer_dir, out_dir)
 
-    layer_fn = llama_layer_arrays if cfg.model_type == "llama" else gpt2_layer_arrays
+    layer_fn = {
+        "llama": llama_layer_arrays, "gpt2": gpt2_layer_arrays,
+        "deepseek_v3": deepseek_layer_arrays,
+    }[cfg.model_type]
     for i in range(cfg.num_hidden_layers):
         block = layer_fn(cfg, get, i, dtype)
         if quantize:
             block = quantize_layer_params(block, bits=quant_bits)
         _save_npz(os.path.join(out_dir, f"block_{i}.npz"), block)
 
-    if cfg.model_type == "llama":
-        embed = jnp.asarray(get("model.embed_tokens.weight"), dtype)
+    if cfg.model_type in ("llama", "deepseek_v3"):
+        # deepseek_v3 may hold a SLICE of the vocabulary: rows 0..V-1
+        V = cfg.vocab_size
+        embed = jnp.asarray(get("model.embed_tokens.weight")[:V], dtype)
         _save_npz(
             os.path.join(out_dir, "embedding.npz"),
             {"embed": maybe_q_embed(embed)},
@@ -317,7 +329,7 @@ def save_shards_streaming(
             {"final_norm": jnp.asarray(get("model.norm.weight"), dtype)},
         )
         if not cfg.tie_word_embeddings:
-            head = jnp.asarray(get("lm_head.weight").T, dtype)
+            head = jnp.asarray(get("lm_head.weight")[:V].T, dtype)
             if quantize_head:
                 head = quantize_tensor(head, contract_axis=-2, bits=quant_bits)
             _save_npz(os.path.join(out_dir, "lm_head.npz"), {"lm_head": head})
@@ -414,12 +426,26 @@ def load_stage(
     pad_to = pad_to or n
     if pad_to < n:
         raise ValueError(f"pad_to={pad_to} < stage size {n}")
+    kinds = cfg.layer_kinds[start:end]
+    if kinds and pad_to > n:
+        raise NotImplementedError(
+            f"pad_to over a model with layers of several kinds "
+            f"({cfg.model_type}): load the stage unpadded; the engine pads "
+            "each kind's stack (parallel/placement.stack_stage_params)"
+        )
     if pad_to > n:
         pad_block = jax.tree.map(np.zeros_like, blocks[0])
         blocks = blocks + [pad_block] * (pad_to - n)
     # stacks through QTensor leaves (q and scale stacked independently) —
     # on the host, like everything this loader returns
-    stacked = jax.tree.map(lambda *xs: np.stack(xs), *blocks)
+    stack = lambda some: jax.tree.map(lambda *xs: np.stack(xs), *some)
+    if kinds:  # one stack per kind, in layer order
+        stacked = {
+            kind: stack([b for b, k in zip(blocks, kinds) if k == kind])
+            for kind in dict.fromkeys(kinds)
+        }
+    else:
+        stacked = stack(blocks)
 
     stage: dict[str, Any] = {
         "layers": stacked,

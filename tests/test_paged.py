@@ -1177,7 +1177,8 @@ def _windowed_projections(text):
     return dots, windowed
 
 
-@pytest.mark.parametrize("cell", sorted(_CELL_SHAPES))
+@pytest.mark.parametrize(
+    "cell", sorted(_CELL_SHAPES) + ["gigachat31_702b_a36b"])
 def test_a_decode_step_reads_its_weights_as_they_are_stored(v5e_host, cell):
     """The compiled ``serve_chunk`` of each benchmark configuration, at its
     real geometry for the described v5e (``benchmark/aot_check.py`` builds
@@ -1188,7 +1189,12 @@ def test_a_decode_step_reads_its_weights_as_they_are_stored(v5e_host, cell):
     was held (``models/llama.py::attn_mlp_block``) XLA folded the head
     split into the two small dots and transposed the whole ``wk`` / ``wv``
     stacks at the top of every call: 0.25-0.27 ms of a decode step on the
-    chip (``PERF.md``, PR 31). Nothing runs: a compile is not a time."""
+    chip (``PERF.md``, PR 31). Latent attention (PR 34) met the same twice
+    (``wq_b``'s head split, held the same way) and once from the STORED side:
+    a ``[H, 576]`` weight is not whole lane tiles, the chip keeps it
+    input-minor, and the stack of ``wkv_a`` was re-laid every call until the
+    leaf was padded to the arena entry's 640 columns. Nothing runs: a
+    compile is not a time."""
     from benchmark import aot_check
     from llm_sharding_tpu.parallel.mesh import pipeline_mesh
 
@@ -1437,7 +1443,9 @@ def test_attn_backend_metrics(setup, monkeypatch):
 #: words its programs lack.
 _NO_ARENA_COPY = {"kv_take", "kv_layout", "kv_put"}
 _NO_HEAD = {"head", "sample"}
-_MLP_WORDS = {"dense": {"router", "moe"}, "experts": {"mlp"}}
+#: ``absorb`` is latent attention's (``models/deepseek_v3.py``): neither model here has it.
+_MLP_WORDS = {"dense": {"router", "moe", "absorb"},
+              "experts": {"mlp", "absorb"}}
 PROGRAM_SCOPES = {
     "serve_chunk": _NO_ARENA_COPY,
     "serve_prefill_chunk": _NO_ARENA_COPY | _NO_HEAD,
