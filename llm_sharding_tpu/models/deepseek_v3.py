@@ -399,7 +399,7 @@ def forward_layers_paged(
     k_scale: Optional[jnp.ndarray] = None,
     v_scale: Optional[jnp.ndarray] = None,
     prefill: bool = False,
-    nlive: Optional[jnp.ndarray] = None,
+    walk=None,
     cp_axis: Optional[str] = None,
     moe_live: Optional[jnp.ndarray] = None,
 ):
@@ -434,7 +434,7 @@ def forward_layers_paged(
             if prefill:
                 return paged_prefill(
                     q_full, k_a, v_all, l, block_table, positions,
-                    kv_positions, scale, backend=backend, nlive=nlive,
+                    kv_positions, scale, backend=backend, walk=walk,
                     latent_v=r,
                 ), k_a
             return paged_attention(

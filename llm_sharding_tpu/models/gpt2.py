@@ -190,7 +190,7 @@ def forward_layers_paged(
     v_scale: Optional[jnp.ndarray] = None,
     prefill: bool = False,  # static: chunked-prefill traversal — attend
     #   via the query-tiled paged_prefill kernel (see llama counterpart)
-    nlive: Optional[jnp.ndarray] = None,  # [B] prefill traffic clamp
+    walk=None,  # the prefill kernel's work list (``prefill_walk``)
 ):
     """Paged serve-decode counterpart of ``forward_layers`` (see
     ``models/llama.forward_layers_paged`` — same contract: fresh KV lands
@@ -228,7 +228,7 @@ def forward_layers_paged(
                 return paged_prefill(
                     q, k_a, v_a, l, block_table, positions, kv_positions,
                     backend=backend, k_scale=out["kv"][2],
-                    v_scale=out["kv"][3], nlive=nlive,
+                    v_scale=out["kv"][3], walk=walk,
                 )
             return paged_attention(
                 q, k_a, v_a, l, block_table, positions, kv_positions,

@@ -309,7 +309,7 @@ def paged_decoder_layer(
     v_scale: Optional[jnp.ndarray] = None,
     prefill: bool = False,  # static: chunk-shaped queries — attend via
     #   the query-tiled paged_prefill kernel instead of the decode one
-    nlive: Optional[jnp.ndarray] = None,  # [B] prefill traffic clamp
+    walk=None,  # the prefill kernel's work list (``prefill_walk``)
     cp_axis: Optional[str] = None,  # context-parallel combine axis
     moe_live: Optional[jnp.ndarray] = None,  # [B, S] positions that route
 ):
@@ -322,8 +322,8 @@ def paged_decoder_layer(
     attention op (fused into the kernel's per-block DMA loop). With
     ``prefill`` the attention dispatch is ``paged_prefill`` — the
     flash-style chunked-prefill kernel whose query axis is the whole
-    chunk (``nlive`` bounds its KV streaming to each row's written
-    frontier); write-then-attend order is identical, so intra-chunk
+    chunk (``walk``: what the chunk's real queries have to walk, built
+    once for all layers); write-then-attend order is identical, so intra-chunk
     causality falls out of the position masking either way.
 
     ``cp_axis`` (context-parallel serving, ``serve(cp=N)``): the arena
@@ -354,7 +354,7 @@ def paged_decoder_layer(
             )
             out["kv"] = (k_a, v_a, ks, vs)
         dispatch = paged_prefill if prefill else paged_attention
-        kw = dict(nlive=nlive) if prefill else {}
+        kw = dict(walk=walk) if prefill else {}
         if cp_axis is not None:
             acc, m, l = dispatch(
                 q, k_a, v_a, layer, block_table, positions, kv_positions,
@@ -400,7 +400,7 @@ def forward_layers_paged(
     v_scale: Optional[jnp.ndarray] = None,
     prefill: bool = False,  # static: chunked-prefill traversal (see
     #   paged_decoder_layer) — queries are a whole prompt chunk
-    nlive: Optional[jnp.ndarray] = None,  # [B] prefill traffic clamp
+    walk=None,  # the prefill kernel's work list (``prefill_walk``)
     cp_axis: Optional[str] = None,  # context-parallel combine axis (the
     #   arena/table are per-shard; see paged_decoder_layer)
     moe_live: Optional[jnp.ndarray] = None,  # [B, S] bool — a model with
@@ -426,7 +426,7 @@ def forward_layers_paged(
         return paged_decoder_layer(
             cfg, p, l, valid, h, k_all, v_all, block_table, cols, cos, sin,
             positions, kv_positions, wv, tp_axis, backend,
-            k_scale=ks_all, v_scale=vs_all, prefill=prefill, nlive=nlive,
+            k_scale=ks_all, v_scale=vs_all, prefill=prefill, walk=walk,
             cp_axis=cp_axis, moe_live=moe_live,
         )
 

@@ -633,6 +633,22 @@ PREFILL_BLOCKS_READ = REGISTRY.counter(
     "prefill-attention-HBM estimate; the retired gather path moved the "
     "row's WHOLE mapped window in AND out per chunk on top of this",
 )
+PREFILL_CELLS_LIVE = REGISTRY.counter(
+    "server_prefill_cells_live_total",
+    "Cells the chunked-prefill kernel walked, summed over the layer calls "
+    "of the chunks whose counters have landed: a cell is one grid step — "
+    "a (row, key/value head, query tile) scoring one group of table "
+    "entries — and only those a real query may attend are in the grid "
+    "(device-side: the grid's own length, ops/paged_attention."
+    "prefill_walk). 0 where the XLA path serves prefill",
+)
+PREFILL_CELLS_WALKED = REGISTRY.counter(
+    "server_prefill_cells_walked_total",
+    "Cells the same layer calls would walk over every row of the slot and "
+    "the table's whole width: rows x key/value heads x query tiles x "
+    "groups of table entries. live / walked is the share of that "
+    "rectangle that is real work",
+)
 PREFILL_POSITIONS = REGISTRY.counter(
     "server_prefill_positions_total",
     "Token positions computed by prefill dispatches (serve_admit, "

@@ -215,6 +215,7 @@ class StepRecord:
         "exemplars", "prompt_tokens", "prefill_positions",
         "expert_tokens", "experts_read", "expert_steps", "expert_rows",
         "decode_blocks_live", "decode_blocks_reserved",
+        "prefill_cells_live", "prefill_cells_walked",
     )
 
     def __init__(self, ts, wall_s, phases, blocked_s, idle_s,
@@ -222,7 +223,8 @@ class StepRecord:
                  segments=None, lock_waits=None, exemplars=None,
                  prompt_tokens=0, prefill_positions=0, expert_tokens=None,
                  experts_read=None, expert_steps=0, expert_rows=0,
-                 decode_blocks_live=0, decode_blocks_reserved=0):
+                 decode_blocks_live=0, decode_blocks_reserved=0,
+                 prefill_cells_live=0, prefill_cells_walked=0):
         self.ts = ts
         self.wall_s = wall_s
         self.phases = phases
@@ -255,6 +257,11 @@ class StepRecord:
         # walk 0 and reserve the table's width)
         self.decode_blocks_live = decode_blocks_live
         self.decode_blocks_reserved = decode_blocks_reserved
+        # chunked prefills whose counters landed in this step: the cells
+        # (grid steps) the prefill kernel walked over their layer calls,
+        # and the cells of every row at the table's whole width
+        self.prefill_cells_live = prefill_cells_live
+        self.prefill_cells_walked = prefill_cells_walked
 
     @property
     def host_s(self) -> float:
@@ -280,6 +287,8 @@ class StepRecord:
             "prefill_positions": self.prefill_positions,
             "decode_blocks_live": self.decode_blocks_live,
             "decode_blocks_reserved": self.decode_blocks_reserved,
+            "prefill_cells_live": self.prefill_cells_live,
+            "prefill_cells_walked": self.prefill_cells_walked,
             "queued": self.queued,
             "pending": self.pending,
         }
@@ -331,6 +340,7 @@ class StepProfiler:
         self._prefill_positions = 0
         self._experts: Optional[list] = None  # [tokens, read, steps, rows]
         self._decode_blocks = [0, 0]  # [live, reserved]
+        self._prefill_cells = [0, 0]  # [live, walked]
         self._phases: Dict[str, float] = {}
         self._blocked_s = 0.0
         self._idle_s = 0.0
@@ -433,6 +443,7 @@ class StepProfiler:
         self._prefill_positions = 0
         self._experts = None
         self._decode_blocks = [0, 0]
+        self._prefill_cells = [0, 0]
         work = bool(rows or queued or pending)
         if self._annotate is not None and (work or self._had_work):
             self._step_span = self._annotate(
@@ -538,6 +549,14 @@ class StepProfiler:
         self._decode_blocks[0] += int(live)
         self._decode_blocks[1] += int(reserved)
 
+    def prefill_cells(self, live: int, walked: int) -> None:
+        """Add landed chunked prefills' kernel walk to the step's record:
+        the cells walked, and those of the slot's whole rectangle."""
+        if not self._enabled or self._t0 is None:
+            return
+        self._prefill_cells[0] += int(live)
+        self._prefill_cells[1] += int(walked)
+
     def experts(self, tokens, read=None, steps: int = 0, rows: int = 0) -> None:
         """Add a fetched set of expert counters to the step's record:
         ``tokens`` [E] per expert; for decode microsteps also ``read`` [L]
@@ -610,6 +629,8 @@ class StepProfiler:
             prefill_positions=self._prefill_positions,
             decode_blocks_live=self._decode_blocks[0],
             decode_blocks_reserved=self._decode_blocks[1],
+            prefill_cells_live=self._prefill_cells[0],
+            prefill_cells_walked=self._prefill_cells[1],
         )
         if self._experts is not None:
             tokens, read, rec.expert_steps, rec.expert_rows = self._experts

@@ -41,11 +41,14 @@ in place). This module provides the attention over that layout:
   ``ops/flash_attention``.
 - ``paged_prefill_tpu``: the CHUNKED-PREFILL kernel — same table-driven
   KV streaming, but the query axis is a whole prompt chunk, GQA-folded
-  and tiled at ``BLOCK_Q_PREFILL`` like the flash kernel, with an
-  ``nlive`` per-row clamp that redirects blocks past the written
-  frontier to the (DMA-elided) trash block. This is what lets
-  ``serve_prefill_chunk`` attend the arena in place instead of
-  round-tripping a gathered O(window) copy per chunk.
+  and tiled at ``BLOCK_Q_PREFILL`` like the flash kernel, and the one
+  grid axis runs over the live cells of the chunk's (row, key/value
+  head, query tile) runs (``prefill_walk``, the decode kernel's recipe:
+  a traced bound, the walk scalar-prefetched): a padded row of the slot,
+  a query tile past a short prompt and the cells past a tile's causal
+  frontier cost nothing. This is what lets ``serve_prefill_chunk``
+  attend the arena in place instead of round-tripping a gathered
+  O(window) copy per chunk.
 - ``paged_attention`` / ``paged_prefill``: backend dispatch (pallas on
   TPU for MXU-aligned head_dim, XLA elsewhere). Same masking contract
   everywhere: ``kv_pos <= q_pos``, sentinel = masked — so never-written
@@ -79,6 +82,7 @@ from __future__ import annotations
 
 import functools
 import os
+from typing import NamedTuple
 
 import numpy as np
 import jax
@@ -149,9 +153,9 @@ def kernel_sublane(cache_dtype) -> int:
 
 
 #: Scalar-memory budget for the kernels' scalar-prefetched operands: the
-#: block table, and the decode kernel's walk (``nlive`` and ``start`` per
-#: row, ``row_of`` per cell). The v5e compiler reports 1 MiB of SMEM and
-#: lays an int32 ``[rows, T]`` table out with rows padded to a multiple of
+#: block table, and a kernel's walk (decode: ``nlive`` and ``start`` per
+#: row, ``row_of`` per cell; prefill: ``PrefillWalk``). The v5e compiler
+#: reports 1 MiB of SMEM and lays an int32 ``[rows, T]`` table out with rows padded to a multiple of
 #: 8 and each row to 128 words, a 1-D array in whole KiB-words: a ``[128,
 #: 2048]`` table alone "exceeded smem capacity by 1.2K"; with the walk of
 #: the decode kernel (PR 28) ``[120, 2048]`` exceeds it by 62.1K and
@@ -163,7 +167,7 @@ SMEM_TABLE_BUDGET = (1 << 20) - (16 << 10)
 
 def kernel_eligible(
     head_dim: int, block_size: int, cache_dtype, *, rows: int,
-    table_width: int, kv_heads: int = 1,
+    table_width: int, kv_heads: int = 1, prefill_tiles: int = 0,
 ) -> bool:
     """Mosaic eligibility of the real (non-interpret) kernels, as learned
     from the v5e compiler:
@@ -177,7 +181,10 @@ def kernel_eligible(
       row, ``table_width / bps`` of them at the ``bps`` that
       ``auto_blocks_per_step`` picks for ``kv_heads`` local key/value
       heads, and two entries per row; together they must fit
-      ``SMEM_TABLE_BUDGET``.
+      ``SMEM_TABLE_BUDGET``. So must the table and the prefill kernel's
+      walk where chunks are prefilled (``prefill_tiles`` =
+      ``prefill_query_tiles`` of the chunk, 0 = none): an entry per cell of
+      every (row, key/value head, query tile) and two per run.
 
     Shared by the trace-time dispatch below and the host-side serve
     validation (``runtime/server.py``), so ``--paged-attn kernel`` fails
@@ -185,16 +192,23 @@ def kernel_eligible(
     def pad(n, m):
         return -(-n // m) * m
 
-    bps = auto_blocks_per_step(table_width, block_size, kv_heads)
-    smem_bytes = 4 * (
-        pad(rows, 8) * pad(table_width, 128)
-        + pad(rows * (table_width // bps) + 1, 1024)
-        + 2 * pad(rows, 128)
-    )
+    def smem_bytes(runs, bps):
+        return 4 * (
+            pad(rows, 8) * pad(table_width, 128)
+            + pad(runs * (table_width // bps) + 1, 1024)
+            + 2 * pad(runs, 128)
+        )
+
     return (
         head_dim % 128 == 0
         and block_size % kernel_sublane(cache_dtype) == 0
-        and smem_bytes <= SMEM_TABLE_BUDGET
+        and smem_bytes(
+            rows, auto_blocks_per_step(table_width, block_size, kv_heads)
+        ) <= SMEM_TABLE_BUDGET
+        and smem_bytes(
+            rows * kv_heads * prefill_tiles,
+            auto_blocks_per_step(table_width, block_size),
+        ) <= SMEM_TABLE_BUDGET
     )
 
 
@@ -559,6 +573,26 @@ def _live_blocks(block_table, q_positions, kv_positions):
     return jnp.max(ends, axis=1)
 
 
+def _end_to_end(nent, bps, width):
+    """Runs of ``nent[r]`` table entries laid end to end in cells of ``bps``
+    (a kernel's walk: the decode kernel's rows, the prefill kernel's runs):
+    ``start[r]`` the grid step of run ``r``'s cell 0, ``owner[i]`` the run
+    grid step ``i`` walks, ``ends`` the running sum of the runs' cells
+    (``ends[-1]`` = the grid's length). Steps past the cells' sum never run,
+    but the pipeline evaluates the index maps one step AHEAD of the one it
+    runs: ``owner`` holds one entry more than the most steps there can be
+    (``width`` cells a run), or the core halts."""
+    cells = -(-nent // bps)
+    ends = jnp.cumsum(cells)
+    start = ends - cells
+    step = jnp.arange(nent.shape[0] * width + 1, dtype=jnp.int32)
+    owner = jnp.minimum(
+        jnp.sum(step[:, None] >= ends[None, :], axis=1, dtype=jnp.int32),
+        nent.shape[0] - 1,
+    )  # the runs whose cells end at or before step i
+    return start, owner, ends
+
+
 def _paged_kernel(
     layer_ref,  # scalar-prefetch [1] — read by the index maps only
     tbl_ref,  # scalar-prefetch [B, T] (index maps + the trash gate)
@@ -749,20 +783,10 @@ def paged_attention_tpu(
     kp = kv_positions.reshape(B, T, 1, BS)  # one [1, BS] lane row per block
 
     # the walk: the rows' live cells laid end to end — grid step i is cell
-    # ``i - start[b]`` of row ``b = row_of[i]``. Steps past the cells' sum
-    # never run, but the pipeline evaluates the index maps one step AHEAD
-    # of the one it runs, so ``row_of`` holds one entry more than the most
-    # steps there can be (and the index maps keep the cell inside the
-    # table)
+    # ``i - start[b]`` of row ``b = row_of[i]`` (``_end_to_end``; the index
+    # maps keep the cell inside the table)
     nlive = _live_blocks(block_table, q_positions, kv_positions)
-    cells = -(-nlive // bps)
-    ends = jnp.cumsum(cells)
-    start = ends - cells
-    step = jnp.arange(B * (T // bps) + 1, dtype=jnp.int32)
-    row_of = jnp.minimum(
-        jnp.sum(step[:, None] >= ends[None, :], axis=1, dtype=jnp.int32),
-        B - 1,
-    )  # the rows whose cells end at or before step i
+    start, row_of, ends = _end_to_end(nlive, bps, T // bps)
 
     # the arena-block specs: a cell streams the bps blocks the
     # scalar-prefetched table names out of the scalar-prefetched layer of
@@ -860,17 +884,99 @@ def paged_attention_tpu(
 BLOCK_Q_PREFILL = 256
 
 
+def prefill_query_tiles(group: int, chunk: int) -> int:
+    """Query tiles a key/value head's ``group`` x ``chunk`` folded query
+    rows make in the chunked-prefill kernel."""
+    return -(-group * chunk // min(BLOCK_Q_PREFILL, group * chunk))
+
+
+def _folded_q_positions(q_positions: jnp.ndarray, group: int) -> jnp.ndarray:
+    """``[B, S]`` query positions as the prefill kernel's query tiles see
+    them, ``[B, tiles, BQ]``: folded row ``g·S + s`` carries position
+    ``q_positions[s]``, the last tile's padding the sentinel."""
+    from ..models.cache import POS_SENTINEL  # models imports this module
+
+    B, S = q_positions.shape
+    tiles = prefill_query_tiles(group, S)
+    qp = jnp.tile(q_positions, (1, group))
+    pad_q = tiles * min(BLOCK_Q_PREFILL, group * S) - group * S
+    if pad_q:
+        qp = jnp.pad(qp, ((0, 0), (0, pad_q)), constant_values=POS_SENTINEL)
+    return qp.reshape(B, tiles, -1)
+
+
+class PrefillWalk(NamedTuple):
+    """The chunked-prefill kernel's work list (``prefill_walk``): the live
+    cells of every run laid end to end, a RUN being one (row, key/value
+    head, query tile) — run ``r = (b·Nkv + k)·tiles + tile`` — and a cell
+    ``bps`` consecutive table entries. All int32."""
+
+    nent: jnp.ndarray  # [R] table entries the run walks (its frontier)
+    start: jnp.ndarray  # [R] the grid step of the run's cell 0
+    run_of: jnp.ndarray  # [R·T/bps + 1] the run grid step i walks
+    steps: jnp.ndarray  # scalar: live cells = the grid's length
+
+
+def prefill_walk(
+    block_table: jnp.ndarray,  # [B, T] int32
+    q_positions: jnp.ndarray,  # [B, S]
+    kv_positions: jnp.ndarray,  # [B, T*BS]
+    nlive: jnp.ndarray = None,  # [B] the caller's clamp; None = the table
+    *,
+    q_heads: int,
+    kv_heads: int,
+    blocks_per_step: int | None = None,
+) -> PrefillWalk:
+    """What a chunk's queries have to walk, from the arrays the mask is
+    made of (the prefill counterpart of ``_live_blocks``). A query tile's
+    frontier is the LAST table entry that is not trash (``block_table !=
+    0``), lies under the row's ``nlive`` and holds a key position ``<=``
+    the tile's largest real query position; the tile's runs (one a
+    key/value head) walk the cells up to it. A dead row (every query at
+    the sentinel: the padded rows of a slot), a dead query tile (the
+    chunk's tail past a short prompt) and the cells past a tile's causal
+    frontier are then in no run: everything left out is what the position
+    mask wipes whole. ``nlive`` is trusted as a clamp only — a padded row
+    is found from its positions, whatever ``nlive`` says of it.
+
+    It does not depend on the layer: ``serve_prefill_chunk`` builds it
+    once a chunk, outside the layer scan, and hands it to every layer's
+    ``paged_prefill``."""
+    from ..models.cache import POS_SENTINEL  # models imports this module
+
+    B, T = block_table.shape
+    BS = kv_positions.shape[1] // T
+    bps = blocks_per_step or auto_blocks_per_step(T, BS)
+    qp = _folded_q_positions(q_positions, q_heads // kv_heads)
+    tiles = qp.shape[1]
+    q_hi = jnp.max(jnp.where(qp < POS_SENTINEL, qp, -1), axis=2)  # [B, tiles]
+    k_lo = jnp.min(kv_positions.reshape(B, T, BS), axis=2)  # [B, T]
+    entry = jnp.arange(T, dtype=jnp.int32)
+    ok = block_table != 0
+    if nlive is not None:
+        ok &= entry[None, :] < nlive[:, None]
+    seen = ok[:, None, :] & (k_lo[:, None, :] <= q_hi[:, :, None])
+    nent = jnp.max(jnp.where(seen, entry + 1, 0), axis=2)  # [B, tiles]
+    nent = jnp.broadcast_to(
+        nent[:, None, :], (B, kv_heads, tiles)
+    ).reshape(-1).astype(jnp.int32)
+    start, run_of, ends = _end_to_end(nent, bps, T // bps)
+    return PrefillWalk(nent, start, run_of, ends[-1])
+
+
 def _paged_prefill_kernel(
     layer_ref,  # scalar-prefetch [1] — read by the index maps only
     tbl_ref,  # scalar-prefetch [B, T]
-    nlive_ref,  # scalar-prefetch [B] — live (attendable) blocks per row
-    q_ref,  # [1, 1, BQ, D]
+    nent_ref,  # scalar-prefetch [R] — the run's frontier (PrefillWalk)
+    start_ref,  # scalar-prefetch [R] — the grid step of the run's cell 0
+    run_ref,  # scalar-prefetch [R·T/bps + 1] — the run grid step i walks
+    q_ref,  # [1, BQ, D] — the run's query tile
     *rest,  # bps k refs [1, 1, BS, D], bps v refs; quantized: + bps ks
     #   refs and bps vs refs ([1, 1, 1, 1]); then qpos [1, BQ, 1], kvpos
-    #   [1, bps, 1, BS], out [1, 1, BQ, D], scratch acc/m/l
+    #   [1, 1, 1, bps·BS], out [1, BQ, Dv], scratch acc/m/l
     scale,
-    t_steps,
     bps,
+    runs_per_row,  # Nkv · query tiles
     quantized=False,
     latent_v=0,  # as in the decode kernel: values are a slice of the keys
 ):
@@ -881,8 +987,11 @@ def _paged_prefill_kernel(
         ks_refs, rest = rest[:bps], rest[bps:]
         vs_refs, rest = rest[:bps], rest[bps:]
     qpos_ref, kvpos_ref, out_ref, acc_ref, m_ref, l_ref = rest
-    b = pl.program_id(0)
-    t = pl.program_id(3)
+    i = pl.program_id(0)
+    r = run_ref[i]
+    t = i - start_ref[r]  # which cell of the run
+    nent = nent_ref[r]
+    b = r // runs_per_row
 
     @pl.when(t == 0)
     def _init():
@@ -890,66 +999,46 @@ def _paged_prefill_kernel(
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    def _cell():
-        q = q_ref[0, 0]  # [BQ, D]
-        tiles = []
-        for j in range(bps):
-            k_blk = k_refs[j][0, 0]  # [BS, D]
-            v_blk = k_blk[:, :latent_v] if latent_v else v_refs[j][0, 0]
-            if quantized:
-                # fused dequant, same contract as the decode kernel: codes
-                # stream, the bf16 window never exists in HBM
-                k_blk = (
-                    k_blk.astype(jnp.float32) * ks_refs[j][0, 0]
-                ).astype(q.dtype)
-                v_blk = (
-                    v_blk.astype(jnp.float32) * vs_refs[j][0, 0]
-                ).astype(q.dtype)
-            # live gate: trash blocks (table entry 0) AND blocks past the
-            # row's written frontier (the index maps redirected their DMA to
-            # block 0 — see paged_prefill_tpu) stream as zeros. Their
-            # positions are sentinel-masked below anyway; zeroing closes the
-            # 0 × Inf = NaN channel of the shared trash block's garbage.
-            idx = t * bps + j
-            live = (tbl_ref[b, idx] != 0) & (idx < nlive_ref[b])
-            k = jnp.where(live, k_blk, jnp.zeros_like(k_blk))
-            v = jnp.where(live, v_blk, jnp.zeros_like(v_blk))
-            # causal masking WITHIN the chunk falls out of the position
-            # compare: the chunk's own entries were scattered into the arena
-            # (with their kv positions) before this kernel runs, so a query
-            # at position p attends exactly the prefix ≤ p — earlier chunks,
-            # the radix prefix, and the chunk's own earlier tokens.
-            if latent_v:
-                tiles.append((k, v, kvpos_ref[0, j]))
-                continue
-            mask = kvpos_ref[0, j] <= qpos_ref[0]  # [BQ, BS]
-            _online_update(q, [(k, v, mask)], scale, acc_ref, m_ref, l_ref)
-        if latent_v:
-            # the step's blocks as ONE key tile: one score dot bps·BS keys
-            # wide (a block alone is 32) and one rescale of the 64-head
-            # accumulator a step, not bps. (Positions are joined, not masks:
-            # Mosaic joins no booleans along lanes.)
-            ks, vs, kvpos = zip(*tiles)
-            mask = jnp.concatenate(kvpos, axis=1) <= qpos_ref[0]
-            _online_update(
-                q, [(jnp.concatenate(ks, axis=0), jnp.concatenate(vs, axis=0),
-                     mask)],
-                scale, acc_ref, m_ref, l_ref,
-            )
+    q = q_ref[0]  # [BQ, D]
+    ks, vs = [], []
+    for j in range(bps):
+        k_blk = k_refs[j][0, 0]  # [BS, D]
+        v_blk = k_blk[:, :latent_v] if latent_v else v_refs[j][0, 0]
+        if quantized:
+            # fused dequant, same contract as the decode kernel: codes
+            # stream, the bf16 window never exists in HBM
+            k_blk = (
+                k_blk.astype(jnp.float32) * ks_refs[j][0, 0]
+            ).astype(q.dtype)
+            v_blk = (
+                v_blk.astype(jnp.float32) * vs_refs[j][0, 0]
+            ).astype(q.dtype)
+        # trash blocks (table entry 0, and what a sub-block past the run's
+        # frontier inside its last cell names) stream as zeros. Their
+        # positions are masked below anyway; zeroing closes the 0 × Inf =
+        # NaN channel of the shared trash block's garbage.
+        idx = t * bps + j
+        live = (idx < nent) & (tbl_ref[b, idx] != 0)
+        ks.append(jnp.where(live, k_blk, jnp.zeros_like(k_blk)))
+        vs.append(jnp.where(live, v_blk, jnp.zeros_like(v_blk)))
+    # the cell's blocks as ONE key tile: one score dot bps·BS keys wide (a
+    # block alone is 32) and one rescale of the accumulator a cell, not
+    # bps; the cell's key positions arrive joined (Mosaic joins no
+    # booleans along lanes). Causal masking WITHIN the chunk falls out of
+    # the position compare: the chunk's own entries were scattered into
+    # the arena (with their kv positions) before this kernel runs, so a
+    # query at position p attends exactly the prefix ≤ p — earlier chunks,
+    # the radix prefix, and the chunk's own earlier tokens.
+    mask = kvpos_ref[0, 0] <= qpos_ref[0]  # [BQ, bps·BS]
+    _online_update(
+        q, [(jnp.concatenate(ks, axis=0), jnp.concatenate(vs, axis=0), mask)],
+        scale, acc_ref, m_ref, l_ref,
+    )
 
-    if latent_v:
-        # a latent chunk folds 64 heads into its query rows: a step whose
-        # blocks all lie past the written frontier (masked whole, and never
-        # the first) is skipped, compute and all — 15 of 16 steps of a
-        # prompt's first chunk. The other models' kernel is what it was.
-        pl.when(t * bps < nlive_ref[b])(_cell)
-    else:
-        _cell()
-
-    @pl.when(t == t_steps - 1)
+    @pl.when((t + 1) * bps >= nent)  # the run's frontier cell
     def _finish():
         l = l_ref[:, :1]
-        out_ref[0, 0] = (acc_ref[:] / jnp.maximum(l, 1e-30)).astype(
+        out_ref[0] = (acc_ref[:] / jnp.maximum(l, 1e-30)).astype(
             out_ref.dtype
         )
 
@@ -970,34 +1059,46 @@ def paged_prefill_tpu(
     interpret: bool = False,
     k_scale: jnp.ndarray = None,  # [L, NB, Nkv] — quantized arenas only
     v_scale: jnp.ndarray = None,
-    nlive: jnp.ndarray = None,  # [B] int32 — blocks covering each row's
-    #   written frontier (prefix + chunks so far); None = the full table
+    nlive: jnp.ndarray = None,  # [B] int32 — the caller's clamp: blocks
+    #   covering each row's written frontier; None = the table's width
     blocks_per_step: int | None = None,  # static; None = auto-selected
     latent_v: int = 0,  # static: a latent arena (see paged_attention_tpu)
+    walk: PrefillWalk = None,  # ``prefill_walk`` of these very operands,
+    #   built by a caller that runs many layers over them; None = built here
 ) -> jnp.ndarray:
-    """Flash-style CHUNKED-PREFILL attention over the paged arena: the
-    query axis is a whole prompt chunk (folded with the GQA groups and
-    tiled at ``BLOCK_Q_PREFILL`` like ``ops/flash_attention``), the KV
-    axis streams the arena blocks the scalar-prefetched table names
-    (``blocks_per_step`` per sequential step, like the decode kernel) —
-    the gathered [B, W, Nkv, D] window of the retired
-    ``_gather_window`` round trip never exists in HBM, and nothing is
-    scattered back (the chunk's own KV landed via ``write_block_kv``
-    before the call).
+    """Flash-style CHUNKED-PREFILL attention over the paged arena whose
+    work is what the chunk's real queries can see: the query axis is a
+    whole prompt chunk (folded with the GQA groups and tiled at
+    ``BLOCK_Q_PREFILL`` like ``ops/flash_attention``), the KV axis streams
+    the arena blocks the scalar-prefetched table names — the gathered [B,
+    W, Nkv, D] window of the retired ``_gather_window`` round trip never
+    exists in HBM, and nothing is scattered back (the chunk's own KV
+    landed via ``write_block_kv`` before the call).
 
-    Grid ``(B, Nkv, ceil(G·S / BQ), T/bps)``, last axis sequential with
-    (acc, m, l) online-softmax scratch carried across it — the blocked
-    flash recurrence, causality enforced by the ``kv_pos <= q_pos``
-    position compare (intra-chunk included: the chunk's entries carry
-    their real positions).
+    ONE sequential grid axis over the call's live cells (``prefill_walk``:
+    a traced bound; the walk rides as scalar-prefetch operands beside the
+    layer index and the table, the decode kernel's recipe). A run — one
+    (row, key/value head, query tile) — walks its cells in order with
+    (acc, m, l) online-softmax scratch carried across them, initialised at
+    its first cell and written out at its last: the blocked flash
+    recurrence, causality enforced by the ``kv_pos <= q_pos`` position
+    compare (intra-chunk included: the chunk's entries carry their real
+    positions). A cell's ``blocks_per_step`` blocks (one head of each: a
+    chunk is bound by its dots, and a block-diagonal dot over ``Nkv``
+    heads would do ``Nkv`` times the work) are scored as one key tile.
+    A row with no real query, a query tile with none and the cells past a
+    tile's frontier are in no run and cost nothing; a skipped cell is one
+    the mask wiped whole, so a real query reads what the whole table's
+    walk gave it.
 
-    ``nlive`` bounds per-row KV traffic by the WRITTEN frontier: the
-    index maps redirect blocks at or past ``nlive[b]`` to block 0, and
-    Pallas elides the DMA when consecutive steps name the same block —
-    so a chunk early in a long prompt streams ~its own prefix, not the
-    row's whole mapped window (decode-budget blocks included). Masking
-    already excluded those blocks (sentinel positions); the clamp is
-    pure traffic, bit-identical either way."""
+    **The rows of pad queries are zeros** (position at the sentinel; and
+    a query whose row maps no key it may attend): a tile no grid step
+    visits is never written, so the result is selected by the real-query
+    mask after the call. (The XLA path gives a pad query a softmax over
+    the whole window; nothing reads either: a pad position routes to no
+    expert, writes its KV under the sentinel and samples nothing.)"""
+    from ..models.cache import POS_SENTINEL  # models imports this module
+
     B, S, Nh, D = q.shape
     Nkv, BS = k_arena.shape[2], k_arena.shape[3]
     T = block_table.shape[1]
@@ -1013,38 +1114,51 @@ def paged_prefill_tpu(
             f"kv_positions must be [B, T*BS]={B, T * BS}, got "
             f"{kv_positions.shape}"
         )
-    if nlive is None:
-        nlive = jnp.full((B,), T, jnp.int32)
-    nlive = jnp.clip(nlive.astype(jnp.int32), 0, T)
     bps = blocks_per_step or auto_blocks_per_step(T, BS)
     if T % bps != 0:
         raise ValueError(
             f"blocks_per_step={bps} does not divide the table width {T}"
         )
+    tiles = prefill_query_tiles(G, S)
+    R = B * Nkv * tiles
+    if walk is None:
+        walk = prefill_walk(
+            block_table, q_positions, kv_positions, nlive,
+            q_heads=Nh, kv_heads=Nkv, blocks_per_step=bps,
+        )
+    if walk.run_of.shape != (R * (T // bps) + 1,):
+        raise ValueError(
+            f"walk of {walk.run_of.shape[0] - 1} cells was not built for "
+            f"{B} rows x {Nkv} heads x {tiles} query tiles x {T // bps} "
+            f"cells (prefill_walk's q_heads / kv_heads / blocks_per_step)"
+        )
 
     # GQA fold + query tiling (the flash_attention pattern): head h =
-    # k*G + g, folded row g*S + s carries position q_positions[s]
+    # k*G + g, folded row g*S + s carries position q_positions[s]; run r =
+    # (b*Nkv + k)*tiles + tile owns query tile r of the flat [R, BQ, D]
     GS = G * S
-    qh = jnp.transpose(q, (0, 2, 1, 3)).reshape(B, Nkv, GS, D)
-    qp = jnp.tile(q_positions, (1, G))  # [B, GS]
     block_q = min(BLOCK_Q_PREFILL, GS)
-    pad_q = (-GS) % block_q
+    pad_q = tiles * block_q - GS
+    qh = jnp.transpose(q, (0, 2, 1, 3)).reshape(B, Nkv, GS, D)
     if pad_q:
         qh = jnp.pad(qh, ((0, 0), (0, 0), (0, pad_q), (0, 0)))
-        qp = jnp.pad(
-            qp, ((0, 0), (0, pad_q)), constant_values=jnp.int32(2**30)
-        )
-    GSp = GS + pad_q
-    qp = qp[..., None]  # [B, GSp, 1] — sublane-major (see _flash_kernel)
-    kp = kv_positions.reshape(B, T, 1, BS)  # lane-major, one row per block
+    qh = qh.reshape(R, block_q, D)
+    # sublane-major query positions, lane-major key positions, a cell's
+    # together (see _flash_kernel)
+    qp = _folded_q_positions(q_positions, G).reshape(B * tiles, block_q, 1)
+    kp = kv_positions.reshape(B, T // bps, 1, bps * BS)
+
+    def cell(i, ne, st, run):
+        return jnp.minimum(i - st[run[i]], T // bps - 1)
 
     # arena-block specs, (layer, block, head) of the stacked pool like the
-    # decode kernel's: the frontier clamp lives in the INDEX MAP — a dead
-    # step re-names block 0, whose DMA Pallas elides when the index is
-    # unchanged from the previous step
-    def arena_index(b, k, i, t, lyr, tbl, nl, j):
-        idx = t * bps + j
-        return (lyr[0], jnp.where(idx < nl[b], tbl[b, idx], 0), k, 0, 0)
+    # decode kernel's; a sub-block past the run's frontier inside its last
+    # cell names the trash block
+    def arena_index(i, lyr, tbl, ne, st, run, j):
+        r = run[i]
+        idx = cell(i, ne, st, run) * bps + j
+        blk = jnp.where(idx < ne[r], tbl[r // (Nkv * tiles), idx], 0)
+        return (lyr[0], blk, (r // tiles) % Nkv, 0, 0)
 
     def block_spec(j):
         return pl.BlockSpec(
@@ -1056,15 +1170,16 @@ def paged_prefill_tpu(
             (None, 1, 1, 1, 1), functools.partial(arena_index, j=j)
         )
 
+    def of_run(i, lyr, tbl, ne, st, run):
+        return (run[i], 0, 0)
+
     in_specs = [
-        pl.BlockSpec(
-            (1, 1, block_q, D),
-            lambda b, k, i, t, lyr, tbl, nl: (b, k, i, 0),
-        ),
+        pl.BlockSpec((1, block_q, D), of_run),
         *[block_spec(j) for j in range(bps)] * (1 if latent_v else 2),
     ]
     operands = [
-        _layer_operand(layer), block_table, nlive, qh,
+        _layer_operand(layer), block_table, walk.nent, walk.start,
+        walk.run_of, qh,
         *([k_arena] * bps), *([] if latent_v else [v_arena] * bps),
     ]
     if quantized:
@@ -1077,22 +1192,24 @@ def paged_prefill_tpu(
         )
     in_specs += [
         pl.BlockSpec(
-            (1, block_q, 1), lambda b, k, i, t, lyr, tbl, nl: (b, i, 0)
+            (1, block_q, 1),
+            lambda i, lyr, tbl, ne, st, run: (
+                run[i] // (Nkv * tiles) * tiles + run[i] % tiles, 0, 0
+            ),
         ),
         pl.BlockSpec(
-            (1, bps, 1, BS),
-            lambda b, k, i, t, lyr, tbl, nl: (b, t, 0, 0),
+            (1, 1, 1, bps * BS),
+            lambda i, lyr, tbl, ne, st, run: (
+                run[i] // (Nkv * tiles), cell(i, ne, st, run), 0, 0
+            ),
         ),
     ]
     operands += [qp, kp]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, Nkv, GSp // block_q, T // bps),
+        num_scalar_prefetch=5,
+        grid=(walk.steps,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, block_q, Dv),
-            lambda b, k, i, t, lyr, tbl, nl: (b, k, i, 0),
-        ),
+        out_specs=pl.BlockSpec((1, block_q, Dv), of_run),
         scratch_shapes=[
             pltpu.VMEM((block_q, Dv), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
@@ -1101,20 +1218,28 @@ def paged_prefill_tpu(
     )
     out = pl.pallas_call(
         functools.partial(
-            _paged_prefill_kernel, scale=scale, t_steps=T // bps,
-            bps=bps, quantized=quantized, latent_v=latent_v,
+            _paged_prefill_kernel, scale=scale, bps=bps,
+            runs_per_row=Nkv * tiles, quantized=quantized, latent_v=latent_v,
         ),
-        out_shape=jax.ShapeDtypeStruct((B, Nkv, GSp, Dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((R, block_q, Dv), q.dtype),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=(
-                "parallel", "parallel", "parallel", "arbitrary",
-            ),
+            dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
         name="paged_prefill",
     )(*operands)
-    out = out[:, :, :GS].reshape(B, Nkv, G, S, Dv)
+    # a tile no run visited was never written, and a pad query's row of a
+    # tile that was is a softmax over what its sentinel lets it see: zeros
+    keep = (qp.reshape(B, 1, tiles, block_q) < POS_SENTINEL) & (
+        walk.nent.reshape(B, Nkv, tiles, 1) > 0
+    )
+    out = jnp.where(
+        keep[..., None], out.reshape(B, Nkv, tiles, block_q, Dv),
+        jnp.zeros((), out.dtype),
+    )
+    out = out.reshape(B, Nkv, tiles * block_q, Dv)[:, :, :GS]
+    out = out.reshape(B, Nkv, G, S, Dv)
     return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(B, S, Nh, Dv)
 
 
@@ -1125,8 +1250,8 @@ def _ineligible_msg(op: str, k_arena, block_table) -> str:
         f"block_size={k_arena.shape[3]} / block table [{rows}, {width}] are "
         f"not Mosaic-eligible for cache dtype "
         f"{jnp.dtype(k_arena.dtype).name} (head_dim must be a multiple of "
-        f"128, the block size a sublane multiple, and the table with the "
-        f"decode kernel's walk must fit {SMEM_TABLE_BUDGET} bytes of scalar "
+        f"128, the block size a sublane multiple, and the table with a "
+        f"kernel's walk must fit {SMEM_TABLE_BUDGET} bytes of scalar "
         f"memory — see kernel_eligible); use backend='auto' or 'xla'"
     )
 
@@ -1148,15 +1273,19 @@ def paged_prefill(
     stats: bool = False,  # static: return (acc, m, l) partials (cp serve)
     latent_v: int = 0,  # static: a latent arena — values are the first
     #   ``latent_v`` lanes of the keys, ``v_arena`` (zero wide) is not read
+    walk: PrefillWalk = None,  # the kernel path's work list, where the
+    #   caller built it once for many layers (``prefill_walk``)
 ) -> jnp.ndarray:
     """Backend dispatch for CHUNKED-PREFILL attention over the arena,
     mirroring ``paged_attention``: the Pallas prefill kernel on TPU for
     Mosaic-eligible shapes, the exact XLA gather path otherwise;
     ``backend`` pins a path, ``PAGED_FORCE_KERNEL`` overrides ``auto``
     only, ``interpret`` emulates the kernel off-TPU (the CI lane).
-    Identical numerics on every path (the XLA gather is the oracle the
-    chunked-prefill tests assert against); ``nlive`` only trims kernel
-    KV traffic — the gather path reads the whole window regardless.
+    Identical numerics on every path for a REAL query (the XLA gather is
+    the oracle the chunked-prefill tests assert against; a pad query's row
+    is zeros from the kernel and a softmax over the whole window from the
+    gather); ``nlive`` and ``walk`` only trim the kernel's work — the
+    gather path reads the whole window regardless.
 
     ``stats=True`` (the context-parallel serve path) returns
     ``attn_stats_xla``'s unnormalized ``(acc, m, l)`` triple instead of a
@@ -1183,12 +1312,15 @@ def paged_prefill(
         q.shape[-1], k_arena.shape[3], k_arena.dtype,
         rows=block_table.shape[0],
         table_width=block_table.shape[1], kv_heads=k_arena.shape[2],
+        prefill_tiles=prefill_query_tiles(
+            q.shape[2] // k_arena.shape[2], q.shape[1]
+        ),
     )
     if backend == "interpret":
         return paged_prefill_tpu(
             q, k_arena, v_arena, layer, block_table, q_positions,
             kv_positions, scale, interpret=True, k_scale=k_scale,
-            v_scale=v_scale, nlive=nlive, latent_v=latent_v,
+            v_scale=v_scale, nlive=nlive, latent_v=latent_v, walk=walk,
         )
     if backend == "kernel":
         if jax.default_backend() != "tpu":
@@ -1209,7 +1341,7 @@ def paged_prefill(
         return paged_prefill_tpu(
             q, k_arena, v_arena, layer, block_table, q_positions,
             kv_positions, scale, k_scale=k_scale, v_scale=v_scale, nlive=nlive,
-            latent_v=latent_v,
+            latent_v=latent_v, walk=walk,
         )
     return paged_attention_xla(
         q, k_arena, v_arena, layer, block_table, q_positions,

@@ -118,7 +118,7 @@ def model_fns(
 
     def stage_paged(cfg_, layers, h, k_arena, v_arena, tbl, cols, kv_pos,
                     positions, mask, write_valid=True, backend="auto",
-                    k_scale=None, v_scale=None, prefill=False, nlive=None,
+                    k_scale=None, v_scale=None, prefill=False, walk=None,
                     moe_live=None):
         kw = {} if cp_axis is None else {"cp_axis": cp_axis}
         if moe_live is not None:
@@ -127,7 +127,7 @@ def model_fns(
             cfg_, layers, h, k_arena, v_arena, tbl, cols, kv_pos,
             positions, mask, write_valid=write_valid, tp_axis=tp_axis,
             backend=backend, k_scale=k_scale, v_scale=v_scale,
-            prefill=prefill, nlive=nlive, **kw,
+            prefill=prefill, walk=walk, **kw,
         )
 
     return ModelFns(stage=stage, stage_paged=stage_paged)
@@ -235,7 +235,7 @@ def ring_chain(fns, cfg, layers, lmask, sidx, ring, num_stages, h, cache,
 def ring_chain_paged(fns, cfg, layers, lmask, sidx, ring, num_stages, h,
                      k_arena, v_arena, tbl, cols, kv_positions, positions,
                      backend="auto", k_scale=None, v_scale=None,
-                     prefill=False, nlive=None, moe_live=None):
+                     prefill=False, walk=None, moe_live=None):
     """``ring_chain`` over the pooled paged arena (the serve programs'
     kernel decode path): the per-microstep activity gate moves from a
     whole-cache ``_tree_where`` (which would copy the ARENA — the whole
@@ -247,8 +247,8 @@ def ring_chain_paged(fns, cfg, layers, lmask, sidx, ring, num_stages, h,
     unchanged); returns ``(h, k_arena, v_arena, k_scale, v_scale, stats)``.
     ``prefill`` (static) runs the traversal as a CHUNKED-PREFILL one:
     chunk-shaped queries attend through the query-tiled
-    ``paged_prefill`` kernel, with ``nlive`` clamping its per-row KV
-    streaming to the written frontier — the ``stage_paged``-style
+    ``paged_prefill`` kernel, ``walk`` its work list where the caller
+    built it for all layers (``prefill_walk``) — the ``stage_paged``-style
     prefill traversal behind ``serve_prefill_chunk``. The sixth result is
     this stage's ``MoeStats`` as in ``ring_chain`` (an inactive microstep
     routes nowhere and counts nothing)."""
@@ -259,7 +259,7 @@ def ring_chain_paged(fns, cfg, layers, lmask, sidx, ring, num_stages, h,
         h_new, ka, va, ks, vs, stats_new = fns.stage_paged(
             cfg, layers, h, ka, va, tbl, cols, kv_positions, positions,
             lmask, write_valid=active, backend=backend,
-            k_scale=ks, v_scale=vs, prefill=prefill, nlive=nlive,
+            k_scale=ks, v_scale=vs, prefill=prefill, walk=walk,
             moe_live=moe_live,
         )
         h = jnp.where(active, h_new, h)

@@ -43,7 +43,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..models.cache import KVCache, POS_SENTINEL
 from ..models.config import ModelConfig
 from ..obs.metrics import REGISTRY
-from ..ops.paged_attention import window_from_blocks
+from ..ops.paged_attention import prefill_walk, window_from_blocks
 from ..ops.quant import is_kv_quantized, kv_dequantize, kv_qmax, kv_quantize
 from ..ops.sampling import is_stop as _is_stop
 from .head import (
@@ -999,9 +999,13 @@ def serve_prefill_chunk(
     under concurrent readers, the same argument as ``serve_admit``'s
     ``prefix_in_arena``.
 
-    Returns the state; for a model with experts ``(state, counts)`` with the
-    chunk's ``moe_log_width`` counters — a device array the host need not
-    wait for (it reads it once a later fetch has shown the chunk done).
+    Returns ``(state, counts)``, ``counts`` a device array the host need
+    not wait for (it reads it once a later fetch has shown the chunk done):
+    a model with experts' ``moe_log_width`` counters, then the prefill
+    kernel's walk over the chunk's layer calls — the cells it walked
+    (``prefill_walk``, one walk for all layers, times a stage's layer
+    slots, summed over the ring) and the ``rows x heads x query tiles x
+    cells`` of the table's whole width; both 0 where no kernel ran.
     """
     fns = model_fns(
         cfg, tp_axis=TENSOR_AXIS if tp > 1 else None,
@@ -1031,6 +1035,7 @@ def serve_prefill_chunk(
         p_rows = jax.lax.dynamic_slice_in_dim(st.kpos, row0, Bs, axis=0)
         W = p_rows.shape[1]
         scale_upd = {}
+        walk, counts = None, jnp.zeros((2,), jnp.int32)
         if block_size:
             tbl = _slot_tables(st, row0, Bs)
             # first chunk: the resident prefix columns carry their real
@@ -1066,17 +1071,27 @@ def serve_prefill_chunk(
                 )
             else:
                 ks = vs = None
-            # blocks covering the written frontier after this chunk — the
-            # prefill kernel's per-row KV traffic clamp (sentinel masking
-            # already excludes everything past it)
-            nlive = jnp.broadcast_to(
-                (col0 + Sc + block_size - 1) // block_size, (Bs,)
-            ).astype(jnp.int32)
+            if attn in ("kernel", "interpret") and cp == 1:
+                # what the chunk's real queries have to walk, once for all
+                # layers; ``nlive``, the blocks covering the written
+                # frontier after this chunk, clamps it (sentinel masking
+                # already excludes everything past it)
+                nlive = jnp.broadcast_to(
+                    (col0 + Sc + block_size - 1) // block_size, (Bs,)
+                ).astype(jnp.int32)
+                walk = prefill_walk(
+                    tbl, positions, kv_pos, nlive,
+                    q_heads=cfg.num_attention_heads // tp,
+                    kv_heads=st.k.shape[2],
+                )
+                counts = jnp.stack(
+                    [walk.steps, walk.run_of.shape[0] - 1]
+                ).astype(jnp.int32) * lmask.shape[0]
             h = sp_embed(cfg, hd, tokens, positions)
             h, k_new, v_new, ks_new, vs_new, moe_stats = ring_chain_paged(
                 fns, cfg, layers, lmask, sidx, ring, num_stages, h,
                 st.k, st.v, tbl, cols, kv_pos, positions, backend=attn,
-                k_scale=ks, v_scale=vs, prefill=True, nlive=nlive,
+                k_scale=ks, v_scale=vs, prefill=True, walk=walk,
                 moe_live=moe_live,
             )
             if quantized:
@@ -1127,9 +1142,12 @@ def serve_prefill_chunk(
             lambda spec, leaf: leaf[None] if _dev(spec) else leaf,
             state_specs(state, tp, cp, quantized, bool(block_size)), new,
         )
+        counts = jax.lax.psum(counts, PIPE_AXIS)
         if moe_stats is not None:
-            return new, _moe_counts(moe_stats, moe_live, sidx, num_stages)
-        return new
+            counts = jnp.concatenate(
+                [_moe_counts(moe_stats, moe_live, sidx, num_stages), counts]
+            )
+        return new, counts
 
     specs = state_specs(
         ServeState(*([None] * len(ServeState._fields))), tp, cp, quantized,
@@ -1143,7 +1161,7 @@ def serve_prefill_chunk(
             head_specs(head_params), specs,
             P(), P(), P(), P(), P(), P(),
         ),
-        out_specs=(specs, P()) if cfg.num_experts else specs,
+        out_specs=(specs, P()),
         check_vma=False,
     )(stage_layers, layer_masks, head_params, state, tokens, positions,
       slot, chunk_off, reset, jnp.asarray(prefix_off, jnp.int32))

@@ -81,7 +81,8 @@ from ..obs.metrics import (
     KV_BLOCKS_IN_USE, KV_BLOCKS_TOTAL, KV_DISK_TIER_BLOCKS,
     KV_ENTRY_BYTES, KV_HOST_TIER_BLOCKS, KV_WASTE_FRAC, MOE_EXPERT_TOKENS,
     MOE_EXPERTS_READ, MOE_PAIRS_HELD, MOE_PAIRS_ROUTED,
-    PREFILL_BLOCKS_READ, PREFILL_POSITIONS, PREFIX_HIT_RATE,
+    PREFILL_BLOCKS_READ, PREFILL_CELLS_LIVE, PREFILL_CELLS_WALKED,
+    PREFILL_POSITIONS, PREFIX_HIT_RATE,
     PREFIX_HIT_TOKENS, REGISTRY, record_shape_key, set_prefill_path,
 )
 from ..obs.trace import TraceContext, TraceWriter, emit_span
@@ -1329,16 +1330,17 @@ class PipelineServer:
         CP_SHARDS.set(float(self.cp))
         # a model with experts: the step programs append their counters to
         # what is fetched anyway (serve_ops.moe_log_width); the layer slots
-        # that hold a real layer, and a chunked prefill's counters waiting
-        # for a fetch to show them computed
+        # that hold a real layer. Every model: a chunked prefill's counters
+        # (the experts', then the prefill kernel's walk) waiting for a
+        # fetch to show them computed
         self._moe_width = serve_ops.moe_log_width(
             self.cfg, self.num_stages, Lp
         )
+        self._chunk_lazy: list = []
         if self._moe_width:
             self._moe_layers = np.flatnonzero(
                 np.asarray(engine.layer_masks).reshape(-1)
             )
-            self._moe_lazy: list = []
             self._moe_children = [
                 MOE_EXPERT_TOKENS.labels(expert=str(e))
                 for e in range(self.cfg.num_experts)
@@ -1557,7 +1559,7 @@ class PipelineServer:
         how CI pins ``interpret`` across a whole test run."""
         from ..ops.paged_attention import (
             SMEM_TABLE_BUDGET, forced_backend, kernel_eligible,
-            kernel_sublane,
+            kernel_sublane, prefill_query_tiles,
         )
 
         on_tpu = jax.default_backend() == "tpu"
@@ -1573,6 +1575,10 @@ class PipelineServer:
             self.cfg.cache_k_dim, self.kv_block_size, self.kv_store_dtype,
             rows=self.batch_per_slot, table_width=table_width,
             kv_heads=max(self.cfg.cache_heads // self.tp, 1),
+            prefill_tiles=prefill_query_tiles(
+                self.cfg.num_attention_heads // self.cfg.cache_heads,
+                self.prefill_chunk,
+            ) if self.prefill_chunk else 0,
         )
 
         def check_kernel(source: str) -> None:
@@ -1597,8 +1603,9 @@ class PipelineServer:
                     f"{jnp.dtype(self.kv_store_dtype).name}), and the "
                     f"table (batch_per_slot x ceil(capacity / "
                     f"kv_block_size), rows padded to 128 entries) and the "
-                    f"decode kernel's walk over it (an entry per group of "
-                    f"blocks of every row) must "
+                    f"kernels' walks over it (an entry per group of blocks "
+                    f"of every row; in a prefilled chunk of every row, "
+                    f"head and query tile) must "
                     f"fit {SMEM_TABLE_BUDGET} bytes of scalar memory — "
                     f"see ops/paged_attention.kernel_eligible; use "
                     f"paged_attn='auto' or 'xla'"
@@ -4539,11 +4546,8 @@ class PipelineServer:
                     attn=attn,
                     cp=self.cp,
                 )
-                if self._moe_width:
-                    self.state, moe_counts = chunk_out
-                    self._moe_lazy.append(moe_counts)
-                else:
-                    self.state = chunk_out
+                self.state, counts = chunk_out
+                self._chunk_lazy.append(counts)
             # interleave only when some OTHER request is mid-decode — the
             # admitting rows themselves are in _rows already and must not
             # count, or an idle server would pay a useless cycle per chunk
@@ -4812,6 +4816,7 @@ class PipelineServer:
             self._contain_lost_log(entry, err)
             return False
         sl.push("apply")
+        self._apply_chunk_counts()
         if self._moe_width and entry[0] in ("chunk", "admit"):
             value = self._apply_moe(value, decode=entry[0] == "chunk")
         if entry[0] == "chunk":
@@ -4830,22 +4835,11 @@ class PipelineServer:
         """Split a fetched chunk log or admission result of a model with
         experts into its tokens (returned) and the ``moe_log_width``
         counters behind them, which go to the step record and the
-        ``server_moe_*`` series. A chunked prefill's counters, parked in
-        ``_moe_lazy``, are read here once ready: no wait of their own."""
+        ``server_moe_*`` series."""
         E, W = self.cfg.num_experts, self._moe_width
         own = np.asarray(value)[..., -W:].reshape(-1, W)
-        ready = [a for a in self._moe_lazy if a.is_ready()]
-        done = {id(a) for a in ready}
-        self._moe_lazy = [a for a in self._moe_lazy if id(a) not in done]
         tokens = own[:, :E].sum(axis=0)
-        for arr in ready:
-            tokens = tokens + np.asarray(arr)[:E]
-        for child, n in zip(self._moe_children, tokens):
-            if n:
-                child.inc(int(n))
-        lo, held = self.cfg.held_experts_
-        MOE_PAIRS_ROUTED.inc(int(tokens.sum()))
-        MOE_PAIRS_HELD.inc(int(tokens[lo:lo + held].sum()))
+        self._count_expert_tokens(tokens)
         if decode:  # a chunk log: one row of counters per decode microstep
             read = own[:, E:-1][:, self._moe_layers]
             busy = own[:, -1] > 0
@@ -4858,6 +4852,35 @@ class PipelineServer:
         else:
             self.stepline.experts(tokens)
         return value[..., :-W]
+
+    def _count_expert_tokens(self, tokens: np.ndarray) -> None:
+        for child, n in zip(self._moe_children, tokens):
+            if n:
+                child.inc(int(n))
+        lo, held = self.cfg.held_experts_
+        MOE_PAIRS_ROUTED.inc(int(tokens.sum()))
+        MOE_PAIRS_HELD.inc(int(tokens[lo:lo + held].sum()))
+
+    def _apply_chunk_counts(self) -> None:
+        """Read the counters of the chunked prefills that have landed
+        (``serve_prefill_chunk``'s second result, parked in ``_chunk_lazy``:
+        no wait of their own): the prefill kernel's walk goes to the step
+        record and ``server_prefill_cells_*``, a model with experts' tokens
+        per expert where ``_apply_moe`` sends a fetched log's."""
+        ready = [a for a in self._chunk_lazy if a.is_ready()]
+        if not ready:
+            return
+        done = {id(a) for a in ready}
+        self._chunk_lazy = [a for a in self._chunk_lazy if id(a) not in done]
+        total = sum(np.asarray(a).astype(np.int64) for a in ready)
+        live, walked = int(total[-2]), int(total[-1])
+        PREFILL_CELLS_LIVE.inc(live)
+        PREFILL_CELLS_WALKED.inc(walked)
+        self.stepline.prefill_cells(live, walked)
+        if self._moe_width:
+            tokens = total[:self.cfg.num_experts]
+            self._count_expert_tokens(tokens)
+            self.stepline.experts(tokens)
 
     def _apply_log(self, log: np.ndarray, m0: int) -> None:
         """Replay one chunk's token log into the host mirrors. At microstep

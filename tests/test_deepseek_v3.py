@@ -83,7 +83,6 @@ def paged_logits(cfg, params, ids, n_prefill, backend, round_to=None):
             h, k, v, _, _, stats = deepseek.forward_layers_paged(
                 cfg, p["layers"], h, k, v, table, pos, kv_pos, pos,
                 backend=backend, prefill=prefill,
-                nlive=jnp.full((1,), T, jnp.int32) if prefill else None,
             )
             return deepseek.final_logits(cfg, p, h)[0], k, v, stats
 
@@ -303,14 +302,17 @@ def test_what_is_not_done_is_refused_by_name(params):
 # PARENT of PR 34 lowered them (recorded there with this very test, under
 # tests/conftest.py: the text depends on its settings): the
 # guard that the accepted configurations' programs are what they were. A PR
-# that changes a one-kind program on purpose re-records them and says so.
+# that changes a one-kind program on purpose re-records them and says so:
+# PR 36 re-recorded both ``serve_prefill_chunk`` (the prefill kernel's grid is
+# its live cells, the program returns the walk's counters); ``serve_chunk``
+# and ``serve_admit`` are still the parent of PR 34's.
 GOLDEN = {
     ("qwen2", "serve_admit"): "41a2afe52004928f",
     ("qwen2", "serve_chunk"): "51598fb15a9f1c1a",
-    ("qwen2", "serve_prefill_chunk"): "f02fb2297748a94a",
+    ("qwen2", "serve_prefill_chunk"): "ae47930ef924992e",
     ("olmoe", "serve_admit"): "12d7e0faf495e8ff",
     ("olmoe", "serve_chunk"): "17d456dda6d44557",
-    ("olmoe", "serve_prefill_chunk"): "eafac0f9b7c37a95",
+    ("olmoe", "serve_prefill_chunk"): "404d9b0e5b495843",
 }
 
 

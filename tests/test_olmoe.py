@@ -105,8 +105,6 @@ def test_prefill_then_decode_through_the_paged_arena(params, backend):
             CFG, params["layers"], h, k_arena, v_arena, table,
             jnp.asarray(cols), jnp.asarray(kv_pos), jnp.asarray(positions),
             backend=backend, prefill=prefill, moe_live=jnp.asarray(live),
-            **({"nlive": jnp.asarray([(cols.max() + BS) // BS] * B, jnp.int32)}
-               if prefill else {}),
         )
         return np.asarray(llama.final_logits(CFG, params, h))[0], stats
 

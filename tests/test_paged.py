@@ -1131,6 +1131,86 @@ def test_kernels_compile_for_a_described_v5e(v5e_chip, cell, kernel, store):
         assert np.prod([int(x) for x in m.group(1).split(",")]) < arena_elems
 
 
+@pytest.mark.parametrize("walk", ["built_in_the_op", "handed_in"])
+@pytest.mark.parametrize(
+    "cell", sorted(_CELL_SHAPES) + ["gigachat31_702b_a36b"]
+)
+def test_the_prefill_kernel_has_one_grid_axis_of_traced_length(
+        v5e_chip, cell, walk):
+    """All four configurations' chunk shapes (the latent one: 64 heads over
+    one latent head of 640 lanes, values its first 512) compile for the
+    described v5e with ONE grid axis whose bound is a traced scalar — the
+    walk's length, no shape of the program — whether the op builds the
+    walk or ``serve_prefill_chunk`` hands it in."""
+    from llm_sharding_tpu.ops.paged_attention import (
+        paged_prefill_tpu, prefill_walk,
+    )
+
+    Nh, Nkv, D, lv = {**{k: (*v, 128, 0) for k, v in _CELL_SHAPES.items()},
+                      "gigachat31_702b_a36b": (64, 1, 640, 512)}[cell]
+    B, T, BS, Lp, NB, Sq = 4, 128, 32, 3, 260, 256
+    S = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=v5e_chip
+    )
+
+    def fn(q, k, v, l, t, qp, kp):
+        w = prefill_walk(t, qp, kp, q_heads=Nh, kv_heads=Nkv)
+        return paged_prefill_tpu(
+            q, k, v, l, t, qp, kp, latent_v=lv,
+            walk=w if walk == "handed_in" else None,
+        )
+
+    with jax.default_matmul_precision("default"):
+        lowered = jax.jit(fn).lower(
+            S((B, Sq, Nh, D), jnp.bfloat16),
+            S((Lp, NB, Nkv, BS, D), jnp.bfloat16),
+            S((Lp, NB, Nkv, BS, 0 if lv else D), jnp.bfloat16),
+            S((), jnp.int32), S((B, T), jnp.int32), S((B, Sq), jnp.int32),
+            S((B, T * BS), jnp.int32),
+        )
+        text = lowered.compile().as_text()
+    assert text.count("tpu_custom_call") == 1 and "paged_prefill" in text
+    # one grid axis, its bound no constant of the kernel (Mosaic writes a
+    # dynamic bound as the least int64): handed to it at run time
+    import base64
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    body = re.search(
+        r'\\22body\\22: \\22([A-Za-z0-9+/=]*)\\22', lowered.as_text()
+    ).group(1)
+    ctx = mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        kernel = ir.Module.parse(base64.b64decode(body)).operation.get_asm(
+            enable_debug_info=False
+        )
+    bounds = re.findall(r"iteration_bounds = array<i64: ([^>]*)>", kernel)
+    assert bounds == [str(-2**63)]
+
+
+def test_the_prefill_walk_counts_against_scalar_memory():
+    """``kernel_eligible`` holds the table AND the prefill kernel's walk
+    (an entry per cell of every row, head and query tile) to the scalar
+    memory the v5e has: the benchmark's geometries fit with room, a slot of
+    64 rows of Qwen2.5-7B at a 32k capacity does not."""
+    from llm_sharding_tpu.ops.paged_attention import (
+        kernel_eligible, prefill_query_tiles,
+    )
+
+    assert prefill_query_tiles(7, 256) == 7  # Qwen2.5-7B: a tile a group
+    assert prefill_query_tiles(1, 256) == 1  # MHA
+    assert prefill_query_tiles(64, 256) == 64  # absorbed latent attention
+    assert prefill_query_tiles(2, 16) == 1  # a chunk under the tile
+    ok = dict(head_dim=128, block_size=32, cache_dtype=jnp.bfloat16)
+    for kv, tiles in ((4, 7), (8, 5), (16, 1), (1, 64)):
+        assert kernel_eligible(**ok, rows=4, table_width=128, kv_heads=kv,
+                               prefill_tiles=tiles)
+    big = dict(rows=64, table_width=1024, kv_heads=4)
+    assert kernel_eligible(**ok, **big)  # the decode walk alone fits
+    assert not kernel_eligible(**ok, **big, prefill_tiles=7)
+
+
 _HLO_BYTES = {"s8": 1, "u8": 1, "bf16": 2, "f16": 2, "f32": 4, "s32": 4}
 
 

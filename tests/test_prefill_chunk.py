@@ -25,11 +25,13 @@ import jax.numpy as jnp
 
 from llm_sharding_tpu.models import llama
 from llm_sharding_tpu.models.config import tiny_llama
+from llm_sharding_tpu.models.cache import POS_SENTINEL
+from llm_sharding_tpu.ops import paged_attention as pa
 from llm_sharding_tpu.ops.paged_attention import (
     auto_blocks_per_step, paged_attention_tpu, paged_attention_xla,
-    paged_prefill, paged_prefill_tpu,
+    paged_prefill, paged_prefill_tpu, prefill_walk,
 )
-from llm_sharding_tpu.ops.quant import kv_qmax, kv_quantize
+from llm_sharding_tpu.ops.quant import fp8_kv_supported, kv_qmax, kv_quantize
 from llm_sharding_tpu.runtime.engine import PipelineEngine
 from llm_sharding_tpu.runtime.generate import generate
 
@@ -163,6 +165,223 @@ def test_paged_prefill_backend_validation():
     if jax.default_backend() != "tpu":
         with pytest.raises(ValueError, match="requires a TPU backend"):
             paged_prefill(*args, backend="kernel")
+
+
+# ---------------------------------------------------------- the live walk
+
+
+def _slot(plens, col0, S, *, T=16, bs=4, heads=(4, 2), D=16, latent_v=0,
+          store=None, trash=(), seed=0, mapped=None, nan_trash=True):
+    """One chunk ``[col0, col0 + S)`` of a slot's rows, row ``b`` holding a
+    prompt of ``plens[b]`` tokens (0: a padded row): the ops' positional
+    arguments and the extra keywords of a quantized arena. A row's table
+    maps ``mapped`` columns (default: through the chunk's end) and is trash
+    past them; ``trash`` names table entries made trash INSIDE the written
+    part; block 0 holds NaN (what parked rows may have left there)."""
+    rng = np.random.default_rng(seed)
+    Nh, Nkv = heads
+    B, L, W = len(plens), 3, T * bs
+    Dv = 0 if latent_v else D
+    NB = B * T + 1
+    ka = rng.normal(size=(L, NB, Nkv, bs, D)).astype(np.float32)
+    va = rng.normal(size=(L, NB, Nkv, bs, Dv)).astype(np.float32)
+    if nan_trash and store is None:
+        ka[:, 0] = np.nan
+        va[:, 0] = np.nan
+    tbl = np.zeros((B, T), np.int32)
+    kvpos = np.full((B, W), POS_SENTINEL, np.int32)
+    qpos = np.full((B, S), POS_SENTINEL, np.int32)
+    for b, n in enumerate(plens):
+        if not n:
+            continue
+        cols = (col0 + S) if mapped is None else mapped
+        nb = -(-cols // bs)
+        tbl[b, :nb] = 1 + b * T + np.arange(nb)
+        tbl[b, list(trash)] = 0
+        written = min(n, col0 + S)
+        kvpos[b, :written] = np.arange(written)
+        real = np.arange(col0, col0 + S) < n
+        qpos[b, real] = col0 + np.flatnonzero(real)
+    q = jnp.asarray(rng.normal(size=(B, S, Nh, D)).astype(np.float32))
+    ka, va = jnp.asarray(ka), jnp.asarray(va)
+    kw = {}
+    if store is not None:
+        sk = jnp.max(jnp.abs(ka), axis=(3, 4)) / kv_qmax(store)
+        sv = jnp.max(jnp.abs(va), axis=(3, 4)) / kv_qmax(store)
+        ka = kv_quantize(ka, sk[..., None, None], store)
+        va = kv_quantize(va, sv[..., None, None], store)
+        kw = dict(k_scale=sk, v_scale=sv)
+    args = (q, ka, va, 1, jnp.asarray(tbl), jnp.asarray(qpos),
+            jnp.asarray(kvpos))
+    return args, kw
+
+
+def _kernel(args, **kw):
+    """The kernel emulated, traced afresh (``BLOCK_Q_PREFILL`` is read at
+    trace time and some cases change it)."""
+    raw = paged_prefill_tpu.__wrapped__
+    return jax.jit(lambda *a: raw(*a, interpret=True, **kw))(*args)
+
+
+def _check(args, kw, latent_v=0, **kernel_kw):
+    """Real queries read what the gather path gives them; every other row
+    of the result is exactly zero, whatever the buffers held."""
+    out = np.asarray(_kernel(args, latent_v=latent_v, **kw, **kernel_kw))
+    ref = np.asarray(paged_attention_xla(*args, latent_v=latent_v, **kw))
+    real = np.asarray(args[5]) < POS_SENTINEL
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(out[real], ref[real], rtol=2e-5, atol=2e-5)
+    assert not out[~real].any()
+    return out
+
+
+_FP8 = pytest.param(
+    "fp8", marks=pytest.mark.skipif(
+        not fp8_kv_supported(), reason="no fp8 on this backend"
+    ),
+)
+_WALKS = {
+    # padded rows in a slot: 1, 2, 3 of 4 rows dead
+    "one_row_dead": dict(plens=(40, 0, 33, 48), col0=32, S=16),
+    "two_rows_dead": dict(plens=(0, 40, 0, 48), col0=32, S=16),
+    "three_rows_dead": dict(plens=(0, 0, 37, 0), col0=32, S=16),
+    # a second and a later chunk: the table maps the whole bucket, the
+    # cells past the chunk's own keys are past every tile's frontier
+    "first_chunk": dict(plens=(64, 0, 0, 9), col0=0, S=16, mapped=64),
+    "second_chunk": dict(plens=(64, 0, 0, 20), col0=16, S=16, mapped=64),
+    "fourth_chunk": dict(plens=(64, 0, 0, 50), col0=48, S=16, mapped=64),
+    # a radix prefix (prefix_off 24: the suffix's first chunk starts there)
+    # with trash entries inside the table
+    "radix_prefix_trash_inside": dict(
+        plens=(50, 44, 0, 0), col0=24, S=16, trash=(1, 4),
+    ),
+    "radix_prefix_a_whole_cell_trash": dict(
+        plens=(50, 0, 0, 41), col0=24, S=16, trash=(0, 1, 2, 3),
+    ),
+    # the fold: GQA 7:1 (Qwen2.5-7B), MHA (OLMoE), 64 heads over one
+    # latent head (absorbed latent attention)
+    "gqa_7_to_1": dict(plens=(30, 0, 0, 0), col0=16, S=16, heads=(7, 1)),
+    "mha": dict(plens=(0, 30, 22, 0), col0=16, S=16, heads=(4, 4)),
+    "latent": dict(
+        plens=(30, 0, 19, 0), col0=16, S=16, heads=(8, 1), D=24,
+        latent_v=16,
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WALKS))
+def test_live_walk_matches_the_gather_on_real_queries(case):
+    spec = dict(_WALKS[case])
+    latent_v = spec.get("latent_v", 0)
+    args, kw = _slot(spec.pop("plens"), spec.pop("col0"), spec.pop("S"),
+                     **spec)
+    _check(args, kw, latent_v=latent_v)
+
+
+@pytest.mark.parametrize("bps", [1, 2, 4, 8])
+def test_live_walk_at_every_blocks_per_step(bps):
+    args, kw = _slot((60, 0, 35, 0), 32, 16, trash=(2,))
+    _check(args, kw, blocks_per_step=bps)
+    walk = prefill_walk(
+        *args[4:], q_heads=4, kv_heads=2, blocks_per_step=bps
+    )
+    # row 0: 12 entries, row 2: 9 (35 tokens), two heads, one query tile
+    assert int(walk.steps) == 2 * (-(-12 // bps) + -(-9 // bps))
+
+
+@pytest.mark.parametrize("store", ["int8", _FP8])
+def test_live_walk_over_a_quantized_arena(store):
+    dt = jnp.int8 if store == "int8" else jnp.float8_e4m3fn
+    args, kw = _slot((0, 45, 0, 38), 32, 16, store=dt, trash=(3,))
+    _check(args, kw)
+
+
+@pytest.mark.parametrize("tile", [8, 16])
+def test_a_short_prompt_in_a_long_bucket_walks_no_dead_query_tile(
+    tile, monkeypatch
+):
+    """MHA, a chunk of 32 with 11 real queries: at a query tile of 8 the
+    chunk is four tiles of which two hold a real query, at 16 two of which
+    one does; the dead ones are in no run and their rows read zero."""
+    monkeypatch.setattr(pa, "BLOCK_Q_PREFILL", tile)
+    args, kw = _slot((43, 0, 0, 0), 32, 32, heads=(2, 2))
+    _check(args, kw)
+    walk = prefill_walk(*args[4:], q_heads=2, kv_heads=2)
+    live_tiles = -(-11 // tile)
+    assert np.count_nonzero(np.asarray(walk.nent)) == 2 * live_tiles
+    # each live run walks the 43 written tokens: 11 entries, two cells
+    assert int(walk.steps) == 2 * live_tiles * 2
+
+
+def test_a_chunk_whose_every_row_is_dead_walks_nothing_and_reads_zero():
+    """Grid length 0: every query at the sentinel (a bucket's chunk past
+    the prompt, or an empty slot). The kernel runs no step, so its output
+    buffer is whatever it was: the result is zeros all the same."""
+    args, kw = _slot((20, 0, 0, 9), 32, 16, mapped=48)
+    assert not (np.asarray(args[5]) < POS_SENTINEL).any()
+    walk = prefill_walk(*args[4:], q_heads=4, kv_heads=2)
+    assert int(walk.steps) == 0 and not np.asarray(walk.nent).any()
+    out = _check(args, kw)
+    assert out.shape == args[0].shape and not out.any()
+
+
+def test_the_walk_lists_hold_one_entry_more_than_the_most_steps():
+    """The pipeline evaluates the index maps one step AHEAD of the one it
+    runs: at the last step of a walk that is ALL live it reads
+    ``run_of[steps]``. That entry exists and names a run of the call (the
+    core halts on an index past the list; PERF.md, PR 28)."""
+    B, T, bs, S = 2, 8, 4, 16
+    args, kw = _slot((32, 32), 16, S, T=T, bs=bs, heads=(4, 2))
+    walk = prefill_walk(*args[4:], q_heads=4, kv_heads=2, blocks_per_step=2)
+    runs = B * 2 * 1
+    most = runs * (T // 2)
+    assert int(walk.steps) == most  # every cell of every run is live
+    assert walk.run_of.shape == (most + 1,)
+    run_of = np.asarray(walk.run_of)
+    assert run_of[most] == runs - 1 and (np.diff(run_of) >= 0).all()
+    assert (np.asarray(walk.start) + -(-np.asarray(walk.nent) // 2)
+            <= most).all()
+    _check(args, kw, blocks_per_step=2)
+    # a walk built for another tiling is refused by name, not run
+    with pytest.raises(ValueError, match="walk of .* cells was not built"):
+        _kernel(args, blocks_per_step=4, walk=walk)
+
+
+def test_the_walk_finds_padded_rows_whatever_nlive_says():
+    """``serve_prefill_chunk`` hands every row of the slot the same
+    ``nlive``; the walk is the live row's alone, and ``nlive`` still
+    clamps it. Built by the caller or inside the op: one result."""
+    args, kw = _slot((0, 0, 64, 0), 16, 16, mapped=64)
+    same = jnp.full((4,), 8, jnp.int32)  # (16 + 16) / 4 blocks, every row
+    walk = prefill_walk(*args[4:], same, q_heads=4, kv_heads=2)
+    assert np.asarray(walk.nent).reshape(4, 2).tolist() == [
+        [0, 0], [0, 0], [8, 8], [0, 0]
+    ]
+    assert int(walk.steps) == 2 * 1  # bps 8: one cell a head
+    clamped = prefill_walk(
+        *args[4:], jnp.full((4,), 5, jnp.int32), q_heads=4, kv_heads=2
+    )
+    assert np.asarray(clamped.nent).max() == 5
+    out = _check(args, kw, nlive=same)
+    np.testing.assert_array_equal(out, _kernel(args, walk=walk))
+
+
+def test_the_walk_of_a_324_token_prompt_is_84_cells_of_3584():
+    """ISSUE 36's arithmetic at Qwen2.5-7B's geometry (4 rows, 28 query /
+    4 key/value heads, chunks of 256, 128 table entries of 32 tokens): one
+    324-token prompt walks 1 x 4 x 7 x (1 + 2) cells over its two chunks
+    where the rectangular grid walked 2 x 1,792."""
+    live = walked = 0
+    for col0 in (0, 256):
+        args, _ = _slot(
+            (324, 0, 0, 0), col0, 256, T=128, bs=32, heads=(28, 4), D=8,
+            mapped=512,
+        )
+        nlive = jnp.full((4,), (col0 + 256) // 32, jnp.int32)
+        walk = prefill_walk(*args[4:], nlive, q_heads=28, kv_heads=4)
+        live += int(walk.steps)
+        walked += walk.run_of.shape[0] - 1
+    assert (live, walked) == (84, 3584)
 
 
 # ------------------------------------------------------------- serve level

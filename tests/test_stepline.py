@@ -934,3 +934,90 @@ def test_chunked_admission_counts_every_chunk_and_agrees_with_the_buckets(
     assert sum(r["prefill_positions"] for r in recs) == sum(
         2 * bucket(n) for n in lens
     )
+
+
+def test_prefill_cells_reach_the_record():
+    p = StepProfiler(name="t-prefill-cells")
+    p.begin_step(rows=1)
+    p.prefill_cells(live=84, walked=3584)
+    p.prefill_cells(live=28, walked=1792)
+    rec = p.end_step(rows=1)
+    assert (rec.prefill_cells_live, rec.prefill_cells_walked) == (112, 5376)
+    d = rec.to_dict()
+    assert (d["prefill_cells_live"], d["prefill_cells_walked"]) == (112, 5376)
+    # a step without prefill reads zero; outside a step nothing is counted
+    p.begin_step()
+    d = p.end_step().to_dict()
+    assert (d["prefill_cells_live"], d["prefill_cells_walked"]) == (0, 0)
+    p.prefill_cells(live=1, walked=1)
+    p.begin_step()
+    assert p.end_step().prefill_cells_walked == 0
+
+
+@pytest.mark.parametrize("stages", [1, 2])
+def test_a_one_request_slot_of_four_walks_a_fraction_of_its_rectangle(
+        params, stages, monkeypatch):
+    """The chunked-prefill kernel's walk, counted by the program itself
+    (the grid's length, ``serve_prefill_chunk``'s second result, read once
+    a later fetch shows it landed — no wait of its own): ONE request in a
+    slot of four rows walks its own row's written cells, summed over the
+    chunks' layer calls, where the rectangle is every row at the table's
+    whole width. Step records and ``/metrics`` carry the same two sums."""
+    monkeypatch.setenv("PAGED_FORCE_KERNEL", "interpret")
+    eng = PipelineEngine(
+        CFG, params, num_stages=stages, devices=jax.devices()[:stages],
+        cache_dtype=jnp.float32,
+    )
+    bs, cap, chunk, rows = 8, 128, 16, 4
+    srv = eng.serve(
+        capacity=cap, batch_per_slot=rows, kv_block_size=bs, kv_blocks=129,
+        prefill_chunk=chunk,
+    )
+    live_c = REGISTRY.get("server_prefill_cells_live_total")
+    walk_c = REGISTRY.get("server_prefill_cells_walked_total")
+    before = (live_c.value, walk_c.value)
+    n0 = srv.stepline.steps_total
+    srv.submit(prompt(70, n=40), 6)  # a bucket of 64: four chunks of 16
+    srv.run_until_idle()
+    for _ in range(3):
+        srv.step()
+    recs = srv.stepline_snapshot()[n0 - srv.stepline.steps_total:]
+    srv.close()
+    live = sum(r["prefill_cells_live"] for r in recs)
+    walked = sum(r["prefill_cells_walked"] for r in recs)
+    T = cap // bs  # 16 table entries, 8 a cell: two cells a run
+    kv, tiles = CFG.num_key_value_heads, 1
+    assert walked == 4 * rows * kv * tiles * (T // 8) * CFG.num_hidden_layers
+    # chunks 1-3 hold real queries and see 2, 4 and 5 entries of one row
+    # (one cell each); the fourth is past the prompt and walks nothing
+    assert live == 3 * kv * tiles * 1 * CFG.num_hidden_layers
+    assert 0 < live / walked < 0.30
+    assert live_c.value - before[0] == live
+    assert walk_c.value - before[1] == walked
+    # the decode steps after the admission landed no prefill counters
+    assert (recs[-1]["prefill_cells_live"], recs[-1]["prefill_cells_walked"]
+            ) == (0, 0)
+    text = REGISTRY.prometheus_text()
+    assert "\nserver_prefill_cells_live_total " in text
+    assert "\nserver_prefill_cells_walked_total " in text
+
+
+def test_the_gather_path_walks_no_prefill_cell(params):
+    """``paged_attn='xla'`` serves prefill by the gather: no kernel, no
+    walk, both counters stay where they were."""
+    eng = PipelineEngine(
+        CFG, params, num_stages=1, devices=jax.devices()[:1],
+        cache_dtype=jnp.float32,
+    )
+    srv = eng.serve(
+        capacity=64, batch_per_slot=2, kv_block_size=8, kv_blocks=33,
+        prefill_chunk=16, paged_attn="xla",
+    )
+    n0 = srv.stepline.steps_total
+    srv.submit(prompt(71, n=20), 3)
+    srv.run_until_idle()
+    recs = srv.stepline_snapshot()[n0 - srv.stepline.steps_total:]
+    srv.close()
+    assert sum(r["prefill_positions"] for r in recs) == 2 * 32
+    assert sum(r["prefill_cells_walked"] for r in recs) == 0
+    assert sum(r["prefill_cells_live"] for r in recs) == 0
