@@ -81,6 +81,7 @@ def paged_arena_shape(
     num_blocks: int,
     block_size: int,
     num_layers: int | None = None,
+    heads: int | None = None,
 ) -> tuple:
     """Per-stage shape of the POOLED paged-KV arena, HEAD-MAJOR: ``[L,
     num_blocks, Nkv, block_size, Dh]`` — the paged replacement for a dense
@@ -93,7 +94,9 @@ def paged_arena_shape(
     per-row block tables in ``parallel/serve.ServeState``, so total KV HBM
     scales with tokens actually in flight instead of rows × capacity.
     Bytes persisted in this layout (host and disk tiers, paged snapshots)
-    carry its name, ``runtime/blocks.PAGED_KV_LAYOUT``."""
+    carry its name, ``runtime/blocks.PAGED_KV_LAYOUT``. ``heads`` overrides
+    ``cfg.cache_heads``: a windowed model (``cfg.windowed``) has one arena per
+    kind of attention, each with that kind's key/value heads."""
     L = cfg.num_hidden_layers if num_layers is None else num_layers
     if num_blocks < 2:
         raise ValueError(
@@ -104,7 +107,10 @@ def paged_arena_shape(
         raise ValueError(
             f"block_size must be a power of two, got {block_size}"
         )
-    return (L, num_blocks, cfg.cache_heads, block_size, cfg.cache_k_dim)
+    return (
+        L, num_blocks, cfg.cache_heads if heads is None else heads,
+        block_size, cfg.cache_k_dim,
+    )
 
 
 def clear(cache: KVCache) -> KVCache:

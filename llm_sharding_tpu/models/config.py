@@ -99,6 +99,25 @@ class ModelConfig:
     first_k_dense_replace: int = 0
     experts_held: int = 0
     ep_rank: int = 0
+    # ``mimo_v2`` (models/mimo_v2.py): window and full attention in one stack.
+    # ``layer_attn[l]`` is 1 where layer ``l`` attends a sliding window of
+    # ``sliding_window`` keys (0: full causal attention), ``layer_moe[l]`` 1
+    # where its feed-forward is the routed experts (0: the dense MLP). Window
+    # layers have ``swa_num_key_value_heads`` key/value heads and rotate at
+    # ``swa_rope_theta``; every head's keys are ``head_dim`` wide, its values
+    # ``v_head_dim``; rotary covers the first ``int(head_dim *
+    # partial_rotary_factor)`` dims; values are multiplied by
+    # ``attention_value_scale``; ``swa_sink`` / ``full_sink``: a learned
+    # per-head logit joins the softmax's denominator in that kind of layer.
+    layer_attn: tuple = ()
+    layer_moe: tuple = ()
+    sliding_window: int = 0
+    swa_num_key_value_heads: int = 0
+    swa_rope_theta: float = 0.0
+    partial_rotary_factor: float = 1.0
+    attention_value_scale: float = 1.0
+    swa_sink: bool = False
+    full_sink: bool = False
     # GPT-2 specifics
     layer_norm_epsilon: float = 1e-5
     # Token ids. ``eos_token_ids`` holds ALL stop ids (Llama-3.x instruct
@@ -113,6 +132,8 @@ class ModelConfig:
             object.__setattr__(self, "eos_token_ids", (self.eos_token_id,))
         else:
             object.__setattr__(self, "eos_token_ids", tuple(self.eos_token_ids))
+        for name in ("layer_attn", "layer_moe"):  # from_json hands lists
+            object.__setattr__(self, name, tuple(getattr(self, name)))
 
     @property
     def head_dim_(self) -> int:
@@ -132,22 +153,46 @@ class ModelConfig:
         return self.kv_lora_rank > 0
 
     @property
+    def windowed(self) -> bool:
+        """Some layers attend a sliding window: they keep a KV state of their
+        own (an arena and a block table per kind of attention)."""
+        return self.sliding_window > 0 and any(self.layer_attn)
+
+    @property
     def cache_heads(self) -> int:
-        return 1 if self.latent_kv else self.num_key_value_heads
+        """Key/value heads of a DENSE cache row (a windowed model: the most
+        any kind of layer has; its paged arenas have each kind's own count,
+        ``kv_heads_of``)."""
+        if self.latent_kv:
+            return 1
+        return max(self.num_key_value_heads, self.swa_num_key_value_heads)
+
+    def kv_heads_of(self, attn: str) -> int:
+        """Key/value heads of a ``"full"`` or ``"swa"`` attention layer."""
+        if attn == "swa" and self.swa_num_key_value_heads:
+            return self.swa_num_key_value_heads
+        return self.num_key_value_heads
 
     @property
     def cache_k_dim(self) -> int:
+        if self.model_type == "mimo_v2":
+            # a key padded to whole 128-lane tiles (192 is one and a half)
+            return -(-self.head_dim_ // 128) * 128
         if not self.latent_kv:
             return self.head_dim_
         return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
 
     @property
     def cache_v_dim(self) -> int:
+        if self.model_type == "mimo_v2":
+            return self.v_head_dim
         return 0 if self.latent_kv else self.head_dim_
 
     @property
     def rope_dim(self) -> int:
         """Width of the rotated part of a head."""
+        if self.model_type == "mimo_v2":
+            return int(self.head_dim_ * self.partial_rotary_factor)
         return self.qk_rope_head_dim if self.latent_kv else self.head_dim_
 
     @property
@@ -165,6 +210,11 @@ class ModelConfig:
         (the tree is then ``params["layers"][leaf]``, else
         ``params["layers"][kind][leaf]``, one stack per kind in layer
         order)."""
+        if self.model_type == "mimo_v2":
+            return tuple(
+                ("moe" if m else "dense") + ("_swa" if a else "_full")
+                for a, m in zip(self.layer_attn, self.layer_moe)
+            )
         if self.model_type != "deepseek_v3":
             return ()
         k = self.first_k_dense_replace
@@ -255,6 +305,8 @@ class ModelConfig:
             moe = {}
         if mt == "deepseek_v3":
             return cls._from_deepseek_v3(hf)
+        if mt == "mimo_v2":
+            return cls._from_mimo_v2(hf)
         if mt in ("llama",):
             act = hf.get("hidden_act", "silu")
             if act not in ("silu", "gelu_tanh"):
@@ -446,6 +498,113 @@ class ModelConfig:
             ep_rank=rank,
             bos_token_id=(
                 0 if hf.get("bos_token_id") is None else hf["bos_token_id"]
+            ),
+            eos_token_id=eos_ids[0],
+            eos_token_ids=eos_ids,
+        )
+
+
+    @classmethod
+    def _from_mimo_v2(cls, hf: dict[str, Any]) -> "ModelConfig":
+        """``mimo_v2`` as published (MiMo-V2-Flash / MiMo-V2.5's language model):
+        per-layer attention kind ``hybrid_layer_pattern`` (0 full, 1 sliding
+        window) and feed-forward kind ``moe_layer_freq`` (0 dense, 1 experts),
+        head counts and rotary base per attention kind, keys of ``head_dim`` and
+        values of ``v_head_dim``, partial rotary, a value scale, a sink logit per
+        head where ``add_*_attention_sink_bias`` says so, ``noaux_tc`` routing
+        over one group with no shared expert. Beside the published keys a chip's
+        share of the experts as ``deepseek_v3`` has it (``n_routed_experts`` HELD
+        of ``n_routed_experts_total``, ``ep_rank``). ``attention_chunk_size`` is
+        kept and NOT read. What is not done is refused by name."""
+        L = int(hf["num_hidden_layers"])
+        need = (
+            "hybrid_layer_pattern", "moe_layer_freq", "head_dim", "v_head_dim",
+            "sliding_window", "n_routed_experts", "num_experts_per_tok",
+            "moe_intermediate_size",
+        )
+        for key in need:
+            if hf.get(key) is None:
+                raise ValueError(f"mimo_v2 config.json lacks {key!r}")
+        attn = tuple(int(a) for a in hf["hybrid_layer_pattern"])[:L]
+        ffn = hf["moe_layer_freq"]
+        ffn = (tuple(int(m) for m in ffn)[:L] if isinstance(ffn, (list, tuple))
+               else tuple(int(l % int(ffn) == 0) for l in range(L)))
+        if len(attn) != L or len(ffn) != L:
+            raise ValueError(
+                f"mimo_v2 hybrid_layer_pattern / moe_layer_freq name "
+                f"{len(attn)} / {len(ffn)} layers, the model has {L}"
+            )
+        refuse = {
+            "topk_method": ("noaux_tc",), "scoring_func": ("sigmoid",),
+            "hidden_act": ("silu",), "attention_bias": (False,),
+            "norm_topk_prob": (True,), "tie_word_embeddings": (False,),
+            "n_group": (1,), "topk_group": (1,), "n_shared_experts": (0,),
+            "swa_head_dim": (hf["head_dim"],), "swa_v_head_dim": (hf["v_head_dim"],),
+            "swa_num_attention_heads": (hf["num_attention_heads"],),
+            "sliding_window_size": (hf["sliding_window"],),
+        }
+        for key, ok in refuse.items():
+            if hf.get(key) is not None and hf[key] not in ok:
+                raise ValueError(
+                    f"mimo_v2 {key}={hf[key]!r} is not supported (only "
+                    f"{', '.join(map(repr, ok))})"
+                )
+        raw = hf.get("rope_scaling")
+        if raw and raw.get("rope_type", raw.get("type")) not in ("default", None):
+            raise ValueError(
+                f"mimo_v2 rope_scaling {raw!r} is not supported; only default "
+                "RoPE is"
+            )
+        held = int(hf["n_routed_experts"])
+        total = int(hf.get("n_routed_experts_total", held))
+        rank = int(hf.get("ep_rank", 0))
+        if total % held or not 0 <= rank < total // held:
+            raise ValueError(
+                f"mimo_v2 share: {held} experts held of {total}, rank {rank}: "
+                f"the held count must divide the total and the rank lie in "
+                f"0..{total // max(held, 1) - 1}"
+            )
+        eos = hf.get("eos_token_id", 2)
+        eos_ids = tuple(eos) if isinstance(eos, list) else (eos,)
+        scale = hf.get("routed_scaling_factor")
+        return cls(
+            model_type="mimo_v2",
+            vocab_size=hf["vocab_size"],
+            hidden_size=hf["hidden_size"],
+            intermediate_size=hf["intermediate_size"],
+            num_hidden_layers=L,
+            num_attention_heads=hf["num_attention_heads"],
+            num_key_value_heads=hf["num_key_value_heads"],
+            head_dim=int(hf["head_dim"]),
+            v_head_dim=int(hf["v_head_dim"]),
+            max_position_embeddings=hf.get("max_position_embeddings", 4096),
+            rms_norm_eps=float(hf.get("layernorm_epsilon",
+                                      hf.get("rms_norm_eps", 1e-5))),
+            rope_theta=float(hf.get("rope_theta", 10000.0)),
+            num_experts=total,
+            num_experts_per_tok=int(hf["num_experts_per_tok"]),
+            norm_topk_prob=True,
+            moe_intermediate_size=int(hf["moe_intermediate_size"]),
+            n_group=1,
+            topk_group=1,
+            routed_scaling_factor=1.0 if scale is None else float(scale),
+            experts_held=held,
+            ep_rank=rank,
+            layer_attn=attn,
+            layer_moe=ffn,
+            sliding_window=int(hf["sliding_window"]),
+            swa_num_key_value_heads=int(
+                hf.get("swa_num_key_value_heads") or hf["num_key_value_heads"]
+            ),
+            swa_rope_theta=float(
+                hf.get("swa_rope_theta") or hf.get("rope_theta", 10000.0)
+            ),
+            partial_rotary_factor=float(hf.get("partial_rotary_factor", 1.0)),
+            attention_value_scale=float(hf.get("attention_value_scale") or 1.0),
+            swa_sink=bool(hf.get("add_swa_attention_sink_bias", False)),
+            full_sink=bool(hf.get("add_full_attention_sink_bias", False)),
+            bos_token_id=(
+                1 if hf.get("bos_token_id") is None else hf["bos_token_id"]
             ),
             eos_token_id=eos_ids[0],
             eos_token_ids=eos_ids,
@@ -678,6 +837,55 @@ def tiny_deepseek_v3(**kw) -> ModelConfig:
     layers, ``v_head_dim`` != ``qk_nope_head_dim``, YaRN on, 8 experts in 4
     groups of which 2 are kept, 2 a token, one shared expert."""
     return ModelConfig.from_hf_config(tiny_deepseek_v3_keys(**kw))
+
+
+def tiny_mimo_v2_keys(**kw) -> dict:
+    """The published-style keys of ``tiny_mimo_v2``."""
+    base = dict(
+        model_type="mimo_v2",
+        vocab_size=256,
+        hidden_size=64,
+        intermediate_size=128,
+        moe_intermediate_size=32,
+        num_hidden_layers=4,
+        hybrid_layer_pattern=[0, 1, 1, 0],
+        moe_layer_freq=[0, 1, 1, 1],
+        num_attention_heads=4,
+        num_key_value_heads=1,
+        swa_num_key_value_heads=2,
+        head_dim=24,
+        v_head_dim=16,
+        partial_rotary_factor=0.334,
+        sliding_window=8,
+        attention_chunk_size=8,
+        attention_value_scale=0.707,
+        add_swa_attention_sink_bias=True,
+        add_full_attention_sink_bias=False,
+        n_routed_experts=8,
+        n_shared_experts=None,
+        num_experts_per_tok=2,
+        n_group=1,
+        topk_group=1,
+        routed_scaling_factor=None,
+        norm_topk_prob=True,
+        scoring_func="sigmoid",
+        topk_method="noaux_tc",
+        max_position_embeddings=256,
+        layernorm_epsilon=1e-5,
+        rope_theta=1e7,
+        swa_rope_theta=1e4,
+        eos_token_id=255,
+    )
+    base.update(kw)
+    return base
+
+
+def tiny_mimo_v2(**kw) -> ModelConfig:
+    """Tiny mimo_v2-layout config for CPU tests: a dense full-attention
+    layer, two expert window layers (window 8, a sink, 2 key/value heads)
+    and an expert full-attention layer (1 head); keys of 24 (rotary on the
+    first 8), values of 16."""
+    return ModelConfig.from_hf_config(tiny_mimo_v2_keys(**kw))
 
 
 def tiny_qwen2(**kw) -> ModelConfig:

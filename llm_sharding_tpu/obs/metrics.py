@@ -521,6 +521,48 @@ KV_ENTRY_BYTES = REGISTRY.gauge(
     "paged server: 2 x kv heads x head dim x itemsize, or a latent cache's "
     "single padded entry (deepseek_v3: [c_kv | k_pe], 1,280 in bf16)",
 )
+# a model with a KV state per kind of layer (window and full attention in one
+# stack, models/mimo_v2.py): one pool, one table and one entry size per kind.
+# Named apart from the unlabeled gauges above, which stay the FULL layers'
+# pool (a metric family has one set of labels).
+KV_KIND_BLOCKS_TOTAL = REGISTRY.gauge(
+    "server_kv_kind_blocks_total",
+    "Allocatable KV arena blocks of each kind of attention layer's pool "
+    "(kind = full | swa) across live servers of a windowed model",
+    labels=("kind",),
+)
+KV_KIND_BLOCKS_IN_USE = REGISTRY.gauge(
+    "server_kv_kind_blocks_in_use",
+    "KV arena blocks of each kind's pool currently held by live rows: a "
+    "window layer's pool holds what the window can still reach",
+    labels=("kind",),
+)
+KV_KIND_ENTRY_BYTES = REGISTRY.gauge(
+    "server_kv_kind_entry_bytes",
+    "Bytes ONE token of ONE layer of a kind holds in that kind's arena "
+    "(kv heads x (padded key + value) x itemsize) of the newest windowed "
+    "server",
+    labels=("kind",),
+)
+KV_WINDOW_BLOCKS_FREED = REGISTRY.counter(
+    "server_kv_window_blocks_freed_total",
+    "Window-layer KV blocks handed back to their pool because the window "
+    "passed them (a row still decoding; blocks of finished rows not "
+    "counted)",
+)
+DECODE_KIND_BLOCKS_LIVE = REGISTRY.counter(
+    "server_decode_kind_blocks_live_total",
+    "Table entries the paged decode kernel had to walk, per kind of "
+    "attention layer (a window layer: from the window's first block to the "
+    "frontier), per decode step, host-side from the length mirrors",
+    labels=("kind",),
+)
+DECODE_KIND_BLOCKS_RESERVED = REGISTRY.counter(
+    "server_decode_kind_blocks_reserved_total",
+    "Table entries the tables of the rows a decode step served reserve, "
+    "per kind of attention layer",
+    labels=("kind",),
+)
 KV_WASTE_FRAC = REGISTRY.gauge(
     "server_kv_waste_frac",
     "1 - live tokens / allocated token slots over the in-use blocks: the "

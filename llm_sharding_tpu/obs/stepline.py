@@ -215,7 +215,7 @@ class StepRecord:
         "exemplars", "prompt_tokens", "prefill_positions",
         "expert_tokens", "experts_read", "expert_steps", "expert_rows",
         "decode_blocks_live", "decode_blocks_reserved",
-        "prefill_cells_live", "prefill_cells_walked",
+        "prefill_cells_live", "prefill_cells_walked", "kv_kinds",
     )
 
     def __init__(self, ts, wall_s, phases, blocked_s, idle_s,
@@ -262,6 +262,12 @@ class StepRecord:
         # and the cells of every row at the table's whole width
         self.prefill_cells_live = prefill_cells_live
         self.prefill_cells_walked = prefill_cells_walked
+        # a model with a KV state per kind of layer (None otherwise), per
+        # kind ("full", "swa"): blocks its pool holds for live rows at this
+        # step's decode dispatch and the pool's size, the table entries the
+        # step's decode walked and reserved, and (window layers) the blocks
+        # handed back to the pool behind the window in this step
+        self.kv_kinds = None
 
     @property
     def host_s(self) -> float:
@@ -292,6 +298,8 @@ class StepRecord:
             "queued": self.queued,
             "pending": self.pending,
         }
+        if self.kv_kinds is not None:
+            d["kv_kinds"] = {k: dict(v) for k, v in self.kv_kinds.items()}
         if self.expert_tokens is not None:
             d["expert_tokens"] = list(self.expert_tokens)
             d["experts_read"] = list(self.experts_read)
@@ -341,6 +349,7 @@ class StepProfiler:
         self._experts: Optional[list] = None  # [tokens, read, steps, rows]
         self._decode_blocks = [0, 0]  # [live, reserved]
         self._prefill_cells = [0, 0]  # [live, walked]
+        self._kv_kinds = None
         self._phases: Dict[str, float] = {}
         self._blocked_s = 0.0
         self._idle_s = 0.0
@@ -444,6 +453,7 @@ class StepProfiler:
         self._experts = None
         self._decode_blocks = [0, 0]
         self._prefill_cells = [0, 0]
+        self._kv_kinds = None
         work = bool(rows or queued or pending)
         if self._annotate is not None and (work or self._had_work):
             self._step_span = self._annotate(
@@ -549,6 +559,25 @@ class StepProfiler:
         self._decode_blocks[0] += int(live)
         self._decode_blocks[1] += int(reserved)
 
+    def kv_kinds(self, kinds: dict) -> None:
+        """Add a decode dispatch's per-kind KV accounting to the step's
+        record (a model with a KV state per kind of layer): ``{kind:
+        {"blocks_in_use", "blocks_total", "decode_blocks_live",
+        "decode_blocks_reserved", "blocks_freed"}}``; counts add up over the
+        step's dispatches, the gauges keep the newest."""
+        if not self._enabled or self._t0 is None:
+            return
+        if self._kv_kinds is None:
+            self._kv_kinds = {k: dict(v) for k, v in kinds.items()}
+            return
+        for k, v in kinds.items():
+            mine = self._kv_kinds.setdefault(k, {})
+            for name, n in v.items():
+                if name in ("blocks_in_use", "blocks_total"):  # gauges
+                    mine[name] = n
+                else:
+                    mine[name] = mine.get(name, 0) + n
+
     def prefill_cells(self, live: int, walked: int) -> None:
         """Add landed chunked prefills' kernel walk to the step's record:
         the cells walked, and those of the slot's whole rectangle."""
@@ -632,6 +661,7 @@ class StepProfiler:
             prefill_cells_live=self._prefill_cells[0],
             prefill_cells_walked=self._prefill_cells[1],
         )
+        rec.kv_kinds = self._kv_kinds
         if self._experts is not None:
             tokens, read, rec.expert_steps, rec.expert_rows = self._experts
             rec.expert_tokens, rec.experts_read = tokens, read or []

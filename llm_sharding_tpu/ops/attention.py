@@ -27,8 +27,11 @@ def cached_attention(
     kv_positions: jnp.ndarray,  # [B, C] absolute position of each cache slot's
     #   key; empty/pad slots carry POS_SENTINEL and are masked out automatically
     scale: float | None = None,
+    window: int = 0,  # static: > 0 keeps keys ``q_pos - window < kv_pos``
+    sink=None,  # [Nh] f32: a per-head logit that joins the row's softmax
+    #   and whose column is dropped — ``exp(sink)`` in the denominator only
 ) -> jnp.ndarray:
-    """Causal attention of ``q`` over the cache. Returns ``[B, S, Nh, D]``.
+    """Causal attention of ``q`` over the cache. Returns ``[B, S, Nh, Dv]``.
 
     The mask is position-based (``kv_pos <= q_pos``), not slot-index-based, so
     one rule covers prefill, decode, right-padded batches, and uninitialized
@@ -51,11 +54,19 @@ def cached_attention(
     ) * scale
 
     mask = kv_positions[:, None, :] <= q_positions[:, :, None]  # [B, S, C]
+    if window:
+        mask &= kv_positions[:, None, :] > q_positions[:, :, None] - window
     mask = mask[:, None, None, :, :]  # [B,1,1,S,C]
     scores = jnp.where(mask, scores, jnp.float32(-1e30))
 
-    probs = jnp.exp(scores - scores.max(axis=-1, keepdims=True))
-    probs = probs / probs.sum(axis=-1, keepdims=True)
+    if sink is None:
+        probs = jnp.exp(scores - scores.max(axis=-1, keepdims=True))
+        probs = probs / probs.sum(axis=-1, keepdims=True)
+    else:
+        s_h = sink.astype(jnp.float32).reshape(1, Nkv, G, 1, 1)
+        m = jnp.maximum(scores.max(axis=-1, keepdims=True), s_h)
+        probs = jnp.exp(scores - m)
+        probs = probs / (probs.sum(axis=-1, keepdims=True) + jnp.exp(s_h - m))
 
     # probs down-cast to the cache dtype for the PV matmul — the same
     # precision contract as the Pallas kernel (`p.astype(v.dtype)`).

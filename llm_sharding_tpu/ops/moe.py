@@ -126,13 +126,14 @@ def route_noaux_tc(x, router, bias, top_k: int, n_group: int,
     )
     s = jax.nn.sigmoid(logits)
     choice = s + bias.astype(jnp.float32)
-    grouped = choice.reshape(N, n_group, E // n_group)
-    group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
-    _, kept = jax.lax.top_k(group_score, topk_group)
-    keep = jnp.any(
-        kept[:, :, None] == jnp.arange(n_group, dtype=kept.dtype), axis=1
-    )  # [N, n_group]
-    choice = jnp.where(keep[:, :, None], grouped, 0.0).reshape(N, E)
+    if n_group > 1:  # (one group, mimo_v2: no group step)
+        grouped = choice.reshape(N, n_group, E // n_group)
+        group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+        _, kept = jax.lax.top_k(group_score, topk_group)
+        keep = jnp.any(
+            kept[:, :, None] == jnp.arange(n_group, dtype=kept.dtype), axis=1
+        )  # [N, n_group]
+        choice = jnp.where(keep[:, :, None], grouped, 0.0).reshape(N, E)
     _, ids = jax.lax.top_k(choice, top_k)
     w = jnp.take_along_axis(s, ids, axis=-1)
     w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * scale

@@ -385,18 +385,23 @@ def paged_attention_xla(
     k_scale: jnp.ndarray = None,  # [L, NB, Nkv] — quantized arenas only
     v_scale: jnp.ndarray = None,
     latent_v: int = 0,
+    window: int = 0,
+    sink: jnp.ndarray = None,
 ) -> jnp.ndarray:
     """Gather + position-masked attention: exact on every backend. A
     quantized arena dequantizes at the gather into the QUERY dtype — the
     same dequant target as the fused kernel, so the two paths match. With
-    ``latent_v`` the values are the first ``latent_v`` lanes of the keys."""
+    ``latent_v`` the values are the first ``latent_v`` lanes of the keys;
+    ``window`` / ``sink`` as ``ops/attention.cached_attention``."""
     k, v = gather_block_kv(
         k_arena, v_arena, layer, block_table, k_scale, v_scale,
         out_dtype=q.dtype,
     )
     if latent_v:
         v = k[..., :latent_v]
-    return cached_attention(q, k, v, q_positions, kv_positions, scale)
+    return cached_attention(
+        q, k, v, q_positions, kv_positions, scale, window=window, sink=sink,
+    )
 
 
 def attn_stats_xla(
@@ -552,6 +557,27 @@ def _online_update(q, tiles, scale, acc_ref, m_ref, l_ref):
     l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
 
+def _first_blocks(block_table, q_positions, kv_positions, window, nlive):
+    """The walk from the other side: per row the index of the FIRST table
+    entry a windowed layer (``window`` keys back from a query) must read,
+    ``[B]`` int32 — the first entry that is not trash and holds a key
+    position ``>`` the row's smallest real query position ``- window``. An
+    entry before it is one the window's mask wipes whole (or one the host
+    already gave back to the pool: trash). ``nlive`` where there is none."""
+    from ..models.cache import POS_SENTINEL  # models imports this module
+
+    B, T = block_table.shape
+    q_lo = jnp.min(q_positions, axis=1)  # pad queries sit at the sentinel
+    need = (kv_positions > q_lo[:, None] - window) & (
+        kv_positions < POS_SENTINEL
+    )
+    need = need.reshape(B, T, -1).any(axis=2) & (block_table != 0)
+    first = jnp.min(
+        jnp.where(need, jnp.arange(T, dtype=jnp.int32), T), axis=1
+    )
+    return jnp.minimum(first, nlive).astype(jnp.int32)
+
+
 def _live_blocks(block_table, q_positions, kv_positions):
     """Per-row count of table entries the decode kernel must walk, ``[B]``
     int32: the leading entries of row ``b`` up to the LAST one that is not
@@ -582,13 +608,18 @@ def _end_to_end(nent, bps, width):
     but the pipeline evaluates the index maps one step AHEAD of the one it
     runs: ``owner`` holds one entry more than the most steps there can be
     (``width`` cells a run), or the core halts."""
-    cells = -(-nent // bps)
+    return _cells_end_to_end(-(-nent // bps), width)
+
+
+def _cells_end_to_end(cells, width):
+    """``_end_to_end`` of runs given as their COUNT of cells (a windowed
+    walk's run does not start at the table's cell 0)."""
     ends = jnp.cumsum(cells)
     start = ends - cells
-    step = jnp.arange(nent.shape[0] * width + 1, dtype=jnp.int32)
+    step = jnp.arange(cells.shape[0] * width + 1, dtype=jnp.int32)
     owner = jnp.minimum(
         jnp.sum(step[:, None] >= ends[None, :], axis=1, dtype=jnp.int32),
-        nent.shape[0] - 1,
+        cells.shape[0] - 1,
     )  # the runs whose cells end at or before step i
     return start, owner, ends
 
@@ -599,8 +630,9 @@ def _paged_kernel(
     nlive_ref,  # scalar-prefetch [B] — the row's frontier (_live_blocks)
     start_ref,  # scalar-prefetch [B] — the grid step of the row's cell 0
     row_ref,  # scalar-prefetch [B·T/bps + 1] — the row grid step i walks
-    q_ref,  # [1, M, D] — every head's query rows, M = Nkv·G·S
-    *rest,  # bps k refs [1, Nkv, BS, D] (the arena blocks the index maps
+    *rest,  # windowed: first scalar-prefetch [B] — the table CELL the
+    #   row's walk starts at; then q [1, M, D] — every head's query rows,
+    #   M = Nkv·G·S; bps k refs [1, Nkv, BS, D] (the arena blocks the index maps
     #   picked, ALL key/value heads of each), bps v refs; quantized: bps ks
     #   refs + bps vs refs ([1, Nkv, 1, 1] per-block-per-head scales); then
     #   the common refs — qpos [1, M, 1], qhead [M, 1], kvpos [1, bps, 1,
@@ -611,21 +643,34 @@ def _paged_kernel(
     quantized=False,
     latent_v=0,  # a latent arena: no v refs, a block's values are the first
     #   ``latent_v`` lanes of its keys (one DMA a block, not two)
+    window=0,  # > 0: a query keeps keys ``q_pos - window < kv_pos`` and the
+    #   walk starts at the row's ``first`` cell (cells behind it are not in
+    #   the grid; the cell the window's edge cuts is masked)
+    sink=False,  # a [M, 1] f32 ref after khead: a per-head logit that joins
+    #   the softmax's denominator and nothing else
 ):
+    if window:
+        first_ref, rest = rest[0], rest[1:]
+    q_ref, rest = rest[0], rest[1:]
     k_refs, rest = rest[:bps], rest[bps:]
     if not latent_v:
         v_refs, rest = rest[:bps], rest[bps:]
     if quantized:
         ks_refs, rest = rest[:bps], rest[bps:]
         vs_refs, rest = rest[:bps], rest[bps:]
+    if sink:
+        sink_ref, rest = rest[4], rest[:4] + rest[5:]
     (qpos_ref, qhead_ref, kvpos_ref, khead_ref, out_ref, acc_ref, m_ref,
      l_ref) = rest
     i = pl.program_id(0)
     b = row_ref[i]
-    t = i - start_ref[b]  # which cell of the row
+    t = i - start_ref[b]  # which cell of the row's walk
     nlive = nlive_ref[b]
+    first = t == 0
+    if window:
+        t = t + first_ref[b]  # which cell of the row's table
 
-    @pl.when(t == 0)
+    @pl.when(first)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
@@ -669,16 +714,19 @@ def _paged_kernel(
         k_blk = jnp.where(live, k_blk, jnp.zeros_like(k_blk))
         v_blk = jnp.where(live, v_blk, jnp.zeros_like(v_blk))
         kvpos = jnp.concatenate([kvpos_ref[0, j]] * Nkv, axis=1)
-        tiles.append((
-            k_blk.reshape(Nkv * BS, D),
-            v_blk.reshape(Nkv * BS, v_blk.shape[-1]),
-            own & (kvpos <= qpos),
-        ))
+        k_tile = k_blk.reshape(Nkv * BS, D)
+        v_tile = v_blk.reshape(Nkv * BS, v_blk.shape[-1])
+        seen = own & (kvpos <= qpos)
+        if window:
+            seen &= kvpos > qpos - window
+        tiles.append((k_tile, v_tile, seen))
     _online_update(q, tiles, scale, acc_ref, m_ref, l_ref)
 
     @pl.when((t + 1) * bps >= nlive)  # the row's frontier cell
     def _finish():
         l = l_ref[:, :1]
+        if sink:
+            l = l + jnp.exp(sink_ref[...] - m_ref[:, :1])
         out_ref[0] = (acc_ref[:] / jnp.maximum(l, 1e-30)).astype(
             out_ref.dtype
         )
@@ -686,7 +734,9 @@ def _paged_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("scale", "interpret", "blocks_per_step", "latent_v"),
+    static_argnames=(
+        "scale", "interpret", "blocks_per_step", "latent_v", "window",
+    ),
 )
 def paged_attention_tpu(
     q: jnp.ndarray,  # [B, S, Nh, D]
@@ -704,6 +754,11 @@ def paged_attention_tpu(
     latent_v: int = 0,  # static: a latent arena — ``v_arena`` is not read,
     #   a block's values are the first ``latent_v`` lanes of its keys and
     #   the output is ``[B, S, Nh, latent_v]``
+    window: int = 0,  # static: a windowed layer — a query keeps the keys
+    #   ``q_pos - window < kv_pos <= q_pos`` and a row's walk STARTS at the
+    #   first cell that holds one (``_first_blocks``)
+    sink: jnp.ndarray = None,  # [Nh] a per-head logit in the softmax's
+    #   denominator (its column dropped: it adds nothing to the output)
 ) -> jnp.ndarray:
     """Pallas paged DECODE attention whose work is the tokens that are
     written: ONE sequential grid axis over the LIVE cells of the call, a
@@ -757,7 +812,7 @@ def paged_attention_tpu(
     G = Nh // Nkv
     M = Nh * S
     quantized = k_scale is not None
-    Dv = latent_v or D
+    Dv = latent_v or v_arena.shape[-1]  # a value may be narrower than a key
     if latent_v and quantized:
         raise NotImplementedError("a quantized latent arena is not done")
     if scale is None:
@@ -786,7 +841,20 @@ def paged_attention_tpu(
     # ``i - start[b]`` of row ``b = row_of[i]`` (``_end_to_end``; the index
     # maps keep the cell inside the table)
     nlive = _live_blocks(block_table, q_positions, kv_positions)
-    start, row_of, ends = _end_to_end(nlive, bps, T // bps)
+    if window:
+        # the walk from the other side: cells wholly behind the window are
+        # not in the grid, the walk's cell 0 is the table's cell ``first``
+        first = _first_blocks(
+            block_table, q_positions, kv_positions, window, nlive
+        ) // bps
+        start, row_of, ends = _cells_end_to_end(
+            -(-nlive // bps) - first, T // bps
+        )
+        lead = [first]
+    else:
+        start, row_of, ends = _end_to_end(nlive, bps, T // bps)
+        lead = []
+    n_pre = 5 + len(lead)
 
     # the arena-block specs: a cell streams the bps blocks the
     # scalar-prefetched table names out of the scalar-prefetched layer of
@@ -795,17 +863,20 @@ def paged_attention_tpu(
     # frontier inside the row's last cell names the trash block.
     # Quantized runs add each block's per-head scales, picked by the same
     # indices out of a [L, NB, Nkv, 1, 1] view (see _scale_operand).
-    def cell(i, b, st):
-        return jnp.minimum(i - st[b], T // bps - 1)
+    def cell(i, b, st, *fst):
+        c = i - st[b]
+        if fst:
+            c = c + fst[0][b]
+        return jnp.minimum(c, T // bps - 1)
 
-    def arena_index(i, lyr, tbl, nl, st, row, j):
+    def arena_index(i, lyr, tbl, nl, st, row, *fst, j):
         b = row[i]
-        idx = cell(i, b, st) * bps + j
+        idx = cell(i, b, st, *fst) * bps + j
         return (lyr[0], jnp.where(idx < nl[b], tbl[b, idx], 0), 0, 0, 0)
 
-    def block_spec(j):
+    def block_spec(j, width=D):
         return pl.BlockSpec(
-            (None, 1, Nkv, BS, D), functools.partial(arena_index, j=j)
+            (None, 1, Nkv, BS, width), functools.partial(arena_index, j=j)
         )
 
     def scale_spec(j):
@@ -813,19 +884,20 @@ def paged_attention_tpu(
             (None, 1, Nkv, 1, 1), functools.partial(arena_index, j=j)
         )
 
-    def of_row(i, lyr, tbl, nl, st, row):
+    def of_row(i, lyr, tbl, nl, st, row, *fst):
         return (row[i], 0, 0)
 
-    def whole(i, lyr, tbl, nl, st, row):
+    def whole(i, lyr, tbl, nl, st, row, *fst):
         return (0, 0)
 
-    n_kv = 1 if latent_v else 2  # a latent block is read once
     in_specs = [
         pl.BlockSpec((1, M, D), of_row),
-        *[block_spec(j) for j in range(bps)] * n_kv,
+        *[block_spec(j) for j in range(bps)],
+        # a latent block is read once: its values are a slice of its keys
+        *([] if latent_v else [block_spec(j, Dv) for j in range(bps)]),
     ]
     operands = [
-        _layer_operand(layer), block_table, nlive, start, row_of, qh,
+        _layer_operand(layer), block_table, nlive, start, row_of, *lead, qh,
         *([k_arena] * bps), *([] if latent_v else [v_arena] * bps),
     ]
     if quantized:
@@ -841,15 +913,21 @@ def paged_attention_tpu(
         pl.BlockSpec((M, 1), whole),
         pl.BlockSpec(
             (1, bps, 1, BS),
-            lambda i, lyr, tbl, nl, st, row: (
-                row[i], cell(i, row[i], st), 0, 0
+            lambda i, lyr, tbl, nl, st, row, *fst: (
+                row[i], cell(i, row[i], st, *fst), 0, 0
             ),
         ),
         pl.BlockSpec((1, Nkv * BS), whole),
     ]
     operands += [qp, qhead, kp, khead]
+    if sink is not None:
+        # query row r = h·S + s carries head h's logit, sublane-major
+        in_specs.append(pl.BlockSpec((M, 1), whole))
+        operands.append(
+            jnp.repeat(sink.astype(jnp.float32), S)[:, None]
+        )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=n_pre,
         grid=(jnp.maximum(ends[-1], 1),),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, M, Dv), of_row),
@@ -863,6 +941,7 @@ def paged_attention_tpu(
         functools.partial(
             _paged_kernel, scale=scale, bps=bps, quantized=quantized,
             latent_v=latent_v,
+            window=window, sink=sink is not None,
         ),
         out_shape=jax.ShapeDtypeStruct((B, M, Dv), q.dtype),
         grid_spec=grid_spec,
@@ -873,7 +952,8 @@ def paged_attention_tpu(
         name="paged_decode",
     )(*operands)
     # a row the walk never visited was never written: it reads zeros
-    out = jnp.where((nlive > 0)[:, None, None], out, jnp.zeros_like(out))
+    walked = nlive > first * bps if window else nlive > 0
+    out = jnp.where(walked[:, None, None], out, jnp.zeros_like(out))
     return jnp.transpose(out.reshape(B, Nh, S, Dv), (0, 2, 1, 3))
 
 
@@ -915,6 +995,8 @@ class PrefillWalk(NamedTuple):
     start: jnp.ndarray  # [R] the grid step of the run's cell 0
     run_of: jnp.ndarray  # [R·T/bps + 1] the run grid step i walks
     steps: jnp.ndarray  # scalar: live cells = the grid's length
+    first: jnp.ndarray = None  # [R] a WINDOWED walk: the table cell the
+    #   run's walk starts at (cells behind the window are in no run)
 
 
 def prefill_walk(
@@ -926,6 +1008,7 @@ def prefill_walk(
     q_heads: int,
     kv_heads: int,
     blocks_per_step: int | None = None,
+    window: int = 0,  # a windowed layer: a query keeps ``window`` keys back
 ) -> PrefillWalk:
     """What a chunk's queries have to walk, from the arrays the mask is
     made of (the prefill counterpart of ``_live_blocks``). A query tile's
@@ -957,9 +1040,29 @@ def prefill_walk(
         ok &= entry[None, :] < nlive[:, None]
     seen = ok[:, None, :] & (k_lo[:, None, :] <= q_hi[:, :, None])
     nent = jnp.max(jnp.where(seen, entry + 1, 0), axis=2)  # [B, tiles]
-    nent = jnp.broadcast_to(
-        nent[:, None, :], (B, kv_heads, tiles)
-    ).reshape(-1).astype(jnp.int32)
+
+    def per_run(a):
+        return jnp.broadcast_to(
+            a[:, None, :], (B, kv_heads, tiles)
+        ).reshape(-1).astype(jnp.int32)
+
+    if window:
+        # from the other side: the first entry holding a key some real query
+        # of the tile keeps (``k_hi > q_lo - window``); pad columns between
+        # a short prompt and the decode region carry the sentinel
+        kv = kv_positions.reshape(B, T, BS)
+        k_hi = jnp.max(jnp.where(kv < POS_SENTINEL, kv, -1), axis=2)
+        q_lo = jnp.min(qp, axis=2)  # [B, tiles]; pad queries: the sentinel
+        need = ok[:, None, :] & (k_hi[:, None, :] > q_lo[:, :, None] - window)
+        first = jnp.minimum(
+            jnp.min(jnp.where(need, entry, T), axis=2), nent
+        ) // bps
+        nent, first = per_run(nent), per_run(first)
+        start, run_of, ends = _cells_end_to_end(
+            -(-nent // bps) - first, T // bps
+        )
+        return PrefillWalk(nent, start, run_of, ends[-1], first)
+    nent = per_run(nent)
     start, run_of, ends = _end_to_end(nent, bps, T // bps)
     return PrefillWalk(nent, start, run_of, ends[-1])
 
@@ -970,8 +1073,9 @@ def _paged_prefill_kernel(
     nent_ref,  # scalar-prefetch [R] — the run's frontier (PrefillWalk)
     start_ref,  # scalar-prefetch [R] — the grid step of the run's cell 0
     run_ref,  # scalar-prefetch [R·T/bps + 1] — the run grid step i walks
-    q_ref,  # [1, BQ, D] — the run's query tile
-    *rest,  # bps k refs [1, 1, BS, D], bps v refs; quantized: + bps ks
+    *rest,  # windowed: first scalar-prefetch [R] — the table cell the run's
+    #   walk starts at; then q [1, BQ, D] — the run's query tile; bps k refs
+    #   [1, 1, BS, D], bps v refs; quantized: + bps ks
     #   refs and bps vs refs ([1, 1, 1, 1]); then qpos [1, BQ, 1], kvpos
     #   [1, 1, 1, bps·BS], out [1, BQ, Dv], scratch acc/m/l
     scale,
@@ -979,21 +1083,31 @@ def _paged_prefill_kernel(
     runs_per_row,  # Nkv · query tiles
     quantized=False,
     latent_v=0,  # as in the decode kernel: values are a slice of the keys
+    window=0,  # as in the decode kernel
+    sink=False,  # a [1, BQ, 1] f32 ref after kvpos: the tile's rows' logits
 ):
+    if window:
+        first_ref, rest = rest[0], rest[1:]
+    q_ref, rest = rest[0], rest[1:]
     k_refs, rest = rest[:bps], rest[bps:]
     if not latent_v:
         v_refs, rest = rest[:bps], rest[bps:]
     if quantized:
         ks_refs, rest = rest[:bps], rest[bps:]
         vs_refs, rest = rest[:bps], rest[bps:]
+    if sink:
+        sink_ref, rest = rest[2], rest[:2] + rest[3:]
     qpos_ref, kvpos_ref, out_ref, acc_ref, m_ref, l_ref = rest
     i = pl.program_id(0)
     r = run_ref[i]
-    t = i - start_ref[r]  # which cell of the run
+    t = i - start_ref[r]  # which cell of the run's walk
     nent = nent_ref[r]
     b = r // runs_per_row
+    first = t == 0
+    if window:
+        t = t + first_ref[r]  # which cell of the row's table
 
-    @pl.when(t == 0)
+    @pl.when(first)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
@@ -1030,6 +1144,8 @@ def _paged_prefill_kernel(
     # query at position p attends exactly the prefix ≤ p — earlier chunks,
     # the radix prefix, and the chunk's own earlier tokens.
     mask = kvpos_ref[0, 0] <= qpos_ref[0]  # [BQ, bps·BS]
+    if window:
+        mask &= kvpos_ref[0, 0] > qpos_ref[0] - window
     _online_update(
         q, [(jnp.concatenate(ks, axis=0), jnp.concatenate(vs, axis=0), mask)],
         scale, acc_ref, m_ref, l_ref,
@@ -1038,6 +1154,8 @@ def _paged_prefill_kernel(
     @pl.when((t + 1) * bps >= nent)  # the run's frontier cell
     def _finish():
         l = l_ref[:, :1]
+        if sink:
+            l = l + jnp.exp(sink_ref[0] - m_ref[:, :1])
         out_ref[0] = (acc_ref[:] / jnp.maximum(l, 1e-30)).astype(
             out_ref.dtype
         )
@@ -1045,7 +1163,9 @@ def _paged_prefill_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("scale", "interpret", "blocks_per_step", "latent_v"),
+    static_argnames=(
+        "scale", "interpret", "blocks_per_step", "latent_v", "window",
+    ),
 )
 def paged_prefill_tpu(
     q: jnp.ndarray,  # [B, S, Nh, D] — S = the chunk length (many rows)
@@ -1065,6 +1185,9 @@ def paged_prefill_tpu(
     latent_v: int = 0,  # static: a latent arena (see paged_attention_tpu)
     walk: PrefillWalk = None,  # ``prefill_walk`` of these very operands,
     #   built by a caller that runs many layers over them; None = built here
+    window: int = 0,  # static: a windowed layer (see paged_attention_tpu);
+    #   ``walk`` must have been built with the same window
+    sink: jnp.ndarray = None,  # [Nh] (see paged_attention_tpu)
 ) -> jnp.ndarray:
     """Flash-style CHUNKED-PREFILL attention over the paged arena whose
     work is what the chunk's real queries can see: the query axis is a
@@ -1104,7 +1227,7 @@ def paged_prefill_tpu(
     T = block_table.shape[1]
     G = Nh // Nkv
     quantized = k_scale is not None
-    Dv = latent_v or D
+    Dv = latent_v or v_arena.shape[-1]  # a value may be narrower than a key
     if latent_v and quantized:
         raise NotImplementedError("a quantized latent arena is not done")
     if scale is None:
@@ -1124,7 +1247,11 @@ def paged_prefill_tpu(
     if walk is None:
         walk = prefill_walk(
             block_table, q_positions, kv_positions, nlive,
-            q_heads=Nh, kv_heads=Nkv, blocks_per_step=bps,
+            q_heads=Nh, kv_heads=Nkv, blocks_per_step=bps, window=window,
+        )
+    if (walk.first is None) != (not window):
+        raise ValueError(
+            "the walk was built for another window than the call's"
         )
     if walk.run_of.shape != (R * (T // bps) + 1,):
         raise ValueError(
@@ -1148,21 +1275,24 @@ def paged_prefill_tpu(
     qp = _folded_q_positions(q_positions, G).reshape(B * tiles, block_q, 1)
     kp = kv_positions.reshape(B, T // bps, 1, bps * BS)
 
-    def cell(i, ne, st, run):
-        return jnp.minimum(i - st[run[i]], T // bps - 1)
+    def cell(i, ne, st, run, *fst):
+        c = i - st[run[i]]
+        if fst:
+            c = c + fst[0][run[i]]
+        return jnp.minimum(c, T // bps - 1)
 
     # arena-block specs, (layer, block, head) of the stacked pool like the
     # decode kernel's; a sub-block past the run's frontier inside its last
     # cell names the trash block
-    def arena_index(i, lyr, tbl, ne, st, run, j):
+    def arena_index(i, lyr, tbl, ne, st, run, *fst, j):
         r = run[i]
-        idx = cell(i, ne, st, run) * bps + j
+        idx = cell(i, ne, st, run, *fst) * bps + j
         blk = jnp.where(idx < ne[r], tbl[r // (Nkv * tiles), idx], 0)
         return (lyr[0], blk, (r // tiles) % Nkv, 0, 0)
 
-    def block_spec(j):
+    def block_spec(j, width=D):
         return pl.BlockSpec(
-            (None, 1, 1, BS, D), functools.partial(arena_index, j=j)
+            (None, 1, 1, BS, width), functools.partial(arena_index, j=j)
         )
 
     def scale_spec(j):
@@ -1170,16 +1300,18 @@ def paged_prefill_tpu(
             (None, 1, 1, 1, 1), functools.partial(arena_index, j=j)
         )
 
-    def of_run(i, lyr, tbl, ne, st, run):
+    def of_run(i, lyr, tbl, ne, st, run, *fst):
         return (run[i], 0, 0)
 
+    lead = [walk.first] if window else []
     in_specs = [
         pl.BlockSpec((1, block_q, D), of_run),
-        *[block_spec(j) for j in range(bps)] * (1 if latent_v else 2),
+        *[block_spec(j) for j in range(bps)],
+        *([] if latent_v else [block_spec(j, Dv) for j in range(bps)]),
     ]
     operands = [
         _layer_operand(layer), block_table, walk.nent, walk.start,
-        walk.run_of, qh,
+        walk.run_of, *lead, qh,
         *([k_arena] * bps), *([] if latent_v else [v_arena] * bps),
     ]
     if quantized:
@@ -1193,20 +1325,33 @@ def paged_prefill_tpu(
     in_specs += [
         pl.BlockSpec(
             (1, block_q, 1),
-            lambda i, lyr, tbl, ne, st, run: (
+            lambda i, lyr, tbl, ne, st, run, *fst: (
                 run[i] // (Nkv * tiles) * tiles + run[i] % tiles, 0, 0
             ),
         ),
         pl.BlockSpec(
             (1, 1, 1, bps * BS),
-            lambda i, lyr, tbl, ne, st, run: (
-                run[i] // (Nkv * tiles), cell(i, ne, st, run), 0, 0
+            lambda i, lyr, tbl, ne, st, run, *fst: (
+                run[i] // (Nkv * tiles), cell(i, ne, st, run, *fst), 0, 0
             ),
         ),
     ]
     operands += [qp, kp]
+    if sink is not None:
+        # folded row g·S + s of key/value head k carries head k·G + g's
+        # logit; tile (k, tile) of every row alike
+        sk = jnp.repeat(sink.astype(jnp.float32).reshape(Nkv, G), S, axis=1)
+        if pad_q:
+            sk = jnp.pad(sk, ((0, 0), (0, pad_q)))
+        in_specs.append(pl.BlockSpec(
+            (1, block_q, 1),
+            lambda i, lyr, tbl, ne, st, run, *fst: (
+                run[i] % (Nkv * tiles), 0, 0
+            ),
+        ))
+        operands.append(sk.reshape(Nkv * tiles, block_q, 1))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=5 + len(lead),
         grid=(walk.steps,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, block_q, Dv), of_run),
@@ -1220,6 +1365,7 @@ def paged_prefill_tpu(
         functools.partial(
             _paged_prefill_kernel, scale=scale, bps=bps,
             runs_per_row=Nkv * tiles, quantized=quantized, latent_v=latent_v,
+            window=window, sink=sink is not None,
         ),
         out_shape=jax.ShapeDtypeStruct((R, block_q, Dv), q.dtype),
         grid_spec=grid_spec,
@@ -1232,7 +1378,8 @@ def paged_prefill_tpu(
     # a tile no run visited was never written, and a pad query's row of a
     # tile that was is a softmax over what its sentinel lets it see: zeros
     keep = (qp.reshape(B, 1, tiles, block_q) < POS_SENTINEL) & (
-        walk.nent.reshape(B, Nkv, tiles, 1) > 0
+        (walk.nent > walk.first * bps).reshape(B, Nkv, tiles, 1) if window
+        else walk.nent.reshape(B, Nkv, tiles, 1) > 0
     )
     out = jnp.where(
         keep[..., None], out.reshape(B, Nkv, tiles, block_q, Dv),
@@ -1275,6 +1422,8 @@ def paged_prefill(
     #   ``latent_v`` lanes of the keys, ``v_arena`` (zero wide) is not read
     walk: PrefillWalk = None,  # the kernel path's work list, where the
     #   caller built it once for many layers (``prefill_walk``)
+    window: int = 0,  # static: a windowed layer keeps ``window`` keys back
+    sink: jnp.ndarray = None,  # [Nh] a per-head logit in the denominator
 ) -> jnp.ndarray:
     """Backend dispatch for CHUNKED-PREFILL attention over the arena,
     mirroring ``paged_attention``: the Pallas prefill kernel on TPU for
@@ -1297,9 +1446,10 @@ def paged_prefill(
             f"paged_prefill backend {backend!r}: expected one of "
             f"{BACKENDS}"
         )
-    if stats and latent_v:
+    if stats and (latent_v or window or sink is not None):
         raise NotImplementedError(
-            "context-parallel attention over a latent arena is not done"
+            "context-parallel attention over a latent arena, a windowed "
+            "layer or a sink logit is not done"
         )
     if stats:
         return attn_stats_xla(
@@ -1321,6 +1471,7 @@ def paged_prefill(
             q, k_arena, v_arena, layer, block_table, q_positions,
             kv_positions, scale, interpret=True, k_scale=k_scale,
             v_scale=v_scale, nlive=nlive, latent_v=latent_v, walk=walk,
+            window=window, sink=sink,
         )
     if backend == "kernel":
         if jax.default_backend() != "tpu":
@@ -1342,11 +1493,12 @@ def paged_prefill(
             q, k_arena, v_arena, layer, block_table, q_positions,
             kv_positions, scale, k_scale=k_scale, v_scale=v_scale, nlive=nlive,
             latent_v=latent_v, walk=walk,
+            window=window, sink=sink,
         )
     return paged_attention_xla(
         q, k_arena, v_arena, layer, block_table, q_positions,
         kv_positions, scale, k_scale=k_scale, v_scale=v_scale,
-        latent_v=latent_v,
+        latent_v=latent_v, window=window, sink=sink,
     )
 
 
@@ -1365,6 +1517,8 @@ def paged_attention(
     v_scale: jnp.ndarray = None,
     stats: bool = False,  # static: return (acc, m, l) partials (cp serve)
     latent_v: int = 0,  # static: a latent arena (see paged_prefill)
+    window: int = 0,  # static: a windowed layer keeps ``window`` keys back
+    sink: jnp.ndarray = None,  # [Nh] a per-head logit in the denominator
 ) -> jnp.ndarray:
     """Backend dispatch: the Pallas kernel on TPU for MXU-aligned shapes,
     the exact XLA gather path otherwise (CPU meshes, ragged head dims,
@@ -1387,9 +1541,10 @@ def paged_attention(
             f"paged_attention backend {backend!r}: expected one of "
             f"{BACKENDS}"
         )
-    if stats and latent_v:
+    if stats and (latent_v or window or sink is not None):
         raise NotImplementedError(
-            "context-parallel attention over a latent arena is not done"
+            "context-parallel attention over a latent arena, a windowed "
+            "layer or a sink logit is not done"
         )
     if stats:
         return attn_stats_xla(
@@ -1407,7 +1562,7 @@ def paged_attention(
         return paged_attention_tpu(
             q, k_arena, v_arena, layer, block_table, q_positions,
             kv_positions, scale, interpret=True, k_scale=k_scale,
-            v_scale=v_scale, latent_v=latent_v,
+            v_scale=v_scale, latent_v=latent_v, window=window, sink=sink,
         )
     if backend == "kernel":
         # curated here too, not only in the serve-side resolution: a
@@ -1432,10 +1587,10 @@ def paged_attention(
         return paged_attention_tpu(
             q, k_arena, v_arena, layer, block_table, q_positions,
             kv_positions, scale, k_scale=k_scale, v_scale=v_scale,
-            latent_v=latent_v,
+            latent_v=latent_v, window=window, sink=sink,
         )
     return paged_attention_xla(
         q, k_arena, v_arena, layer, block_table, q_positions,
         kv_positions, scale, k_scale=k_scale, v_scale=v_scale,
-        latent_v=latent_v,
+        latent_v=latent_v, window=window, sink=sink,
     )

@@ -183,6 +183,9 @@ GPT2_QUANT_KEYS = ("w_qkv", "w_out", "w_fc", "w_proj")
 DEEPSEEK_QUANT_KEYS = (
     "wq_a", "wq_b", "wkv_a", "w_uk", "w_uv", "ws_gate", "ws_up", "ws_down",
 )
+# mimo_v2 (models/mimo_v2.py): the fused qkv projection beside ``wo`` /
+# ``w_*`` / ``we_*``; router, correction bias and the float32 sink stay
+MIMO_QUANT_KEYS = ("wqkv",)
 
 
 def is_kinds_tree(layers: dict) -> bool:
@@ -201,7 +204,10 @@ def quantize_layer_params(
     int8 leaf — required to quantize a 7B-class model in place on a 16 GB
     chip; the caller's original arrays are invalidated)."""
     if keys is None:
-        keys = LLAMA_QUANT_KEYS + GPT2_QUANT_KEYS + DEEPSEEK_QUANT_KEYS
+        keys = (
+            LLAMA_QUANT_KEYS + GPT2_QUANT_KEYS + DEEPSEEK_QUANT_KEYS
+            + MIMO_QUANT_KEYS
+        )
     if is_kinds_tree(layers):  # one stack per kind: each kind's leaves
         return {
             kind: quantize_layer_params(sub, keys, donate=donate, bits=bits)

@@ -83,14 +83,17 @@ def _yarn_inv_freq(dim: int, base: float, rs: RopeScaling) -> np.ndarray:
     )
 
 
-def inv_frequencies(cfg: ModelConfig) -> np.ndarray:
-    """Static (trace-time) inverse frequencies, shape [rope_dim/2], fp32."""
+def inv_frequencies(cfg: ModelConfig, theta: float | None = None) -> np.ndarray:
+    """Static (trace-time) inverse frequencies, shape [rope_dim/2], fp32.
+    ``theta`` overrides ``cfg.rope_theta`` (a model whose kinds of layer
+    rotate at bases of their own)."""
     d = cfg.rope_dim
     rs = cfg.rope_scaling
+    theta = cfg.rope_theta if theta is None else theta
     if rs is not None and rs.rope_type == "yarn":
-        return _yarn_inv_freq(d, cfg.rope_theta, rs).astype(np.float32)
+        return _yarn_inv_freq(d, theta, rs).astype(np.float32)
     inv_freq = 1.0 / (
-        cfg.rope_theta ** (np.arange(0, d, 2, dtype=np.float64) / d)
+        theta ** (np.arange(0, d, 2, dtype=np.float64) / d)
     ).astype(np.float64)
     if rs is not None and rs.rope_type == "llama3":
         inv_freq = _llama3_scale_inv_freq(inv_freq, rs)
@@ -98,14 +101,15 @@ def inv_frequencies(cfg: ModelConfig) -> np.ndarray:
 
 
 def rope_cos_sin(
-    positions: jnp.ndarray, cfg: ModelConfig, dtype=jnp.float32
+    positions: jnp.ndarray, cfg: ModelConfig, dtype=jnp.float32,
+    theta: float | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """cos/sin tables for absolute ``positions`` (any shape ``[...]``).
 
     Returns ``cos, sin`` of shape ``[..., head_dim]`` (HF layout: the half
     frequencies tiled twice, consumed by :func:`apply_rope`).
     """
-    inv_freq = jnp.asarray(inv_frequencies(cfg))  # [D/2]
+    inv_freq = jnp.asarray(inv_frequencies(cfg, theta))  # [D/2]
     freqs = positions.astype(jnp.float32)[..., None] * inv_freq  # [..., D/2]
     emb = jnp.concatenate([freqs, freqs], axis=-1)  # [..., D]
     cos, sin = jnp.cos(emb), jnp.sin(emb)
@@ -120,7 +124,14 @@ def rope_cos_sin(
 def apply_rope(
     x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray
 ) -> jnp.ndarray:
-    """Rotate ``x: [B, S, N, D]`` by per-position ``cos/sin: [B, S, D]``."""
+    """Rotate ``x: [B, S, N, D]`` by per-position ``cos/sin: [B, S, R]``:
+    the first ``R`` of the ``D`` dims by halves, the rest pass (partial
+    rotary; ``R == D`` rotates the whole head)."""
+    R = cos.shape[-1]
+    if R < x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rope(x[..., :R], cos, sin), x[..., R:]], axis=-1
+        )
     c = cos[:, :, None, :].astype(jnp.float32)
     s = sin[:, :, None, :].astype(jnp.float32)
     x32 = x.astype(jnp.float32)

@@ -75,6 +75,10 @@ class ModelFns(NamedTuple):
     # (h, k_arena, v_arena, k_scale, v_scale) — the scale arenas ride a
     # quantized (int8/fp8) arena and come back None otherwise
     stage_paged: Any = None
+    # a model with a KV state per kind of attention layer: the chunked-
+    # prefill kernel's work lists, one per kind, and their counts —
+    # (cfg, tables, positions, kv_pos, nlive, layers) -> (walks, counts)
+    prefill_walks: Any = None
 
 
 def model_fns(
@@ -87,6 +91,7 @@ def model_fns(
     arena/table slice and attention partials reduce across ``cp_axis``
     (``models/llama.paged_decoder_layer``). Gated to llama upstream
     (``engine.serve`` validation) — gpt2's paged path never sees it."""
+    walks = None
     if cfg.model_type == "llama":
         fwd, fwd_paged = llama.forward_layers, llama.forward_layers_paged
     elif cfg.model_type == "gpt2":
@@ -104,6 +109,16 @@ def model_fns(
                 "attention, a share of the experts) is not implemented"
             )
         fwd, fwd_paged = deepseek.forward_layers, deepseek.forward_layers_paged
+    elif cfg.model_type == "mimo_v2":
+        from ..models import mimo_v2
+
+        if tp_axis is not None or cp_axis is not None:
+            raise NotImplementedError(
+                "tensor / context parallelism over mimo_v2 (a KV state per "
+                "kind of layer, a share of the experts) is not implemented"
+            )
+        fwd, fwd_paged = mimo_v2.forward_layers, mimo_v2.forward_layers_paged
+        walks = mimo_v2.prefill_walks
     else:
         raise ValueError(f"unsupported model_type: {cfg.model_type!r}")
 
@@ -130,7 +145,7 @@ def model_fns(
             prefill=prefill, walk=walk, **kw,
         )
 
-    return ModelFns(stage=stage, stage_paged=stage_paged)
+    return ModelFns(stage=stage, stage_paged=stage_paged, prefill_walks=walks)
 
 
 def mesh_axis_sizes(mesh: Mesh) -> tuple[int, int, int]:

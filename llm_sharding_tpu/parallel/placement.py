@@ -216,16 +216,24 @@ def stack_stage_params(
         return stage_layers, masks_of(spec.stages, P)
 
     order = list(dict.fromkeys(kinds))
+    # a model whose kinds ALTERNATE down the stack (window and full
+    # attention: models/mimo_v2.py) runs a stage's layers as runs in model
+    # order and reads the sequence off the model's first layers: every stage
+    # must then hold that same sequence
+    seqs = [tuple(kinds[start:end]) for start, end in spec.stages]
+    alike = all(q == seqs[0] for q in seqs) and spec.stages[0][0] == 0
     stage_layers, masks = {}, []
     for kind in order:
         # each stage's layers of this kind as a range of the KIND's stack
         ranges = []
         for start, end in spec.stages:
             mine = list(kinds[start:end])
-            if mine != sorted(mine, key=order.index):
+            if mine != sorted(mine, key=order.index) and not alike:
                 raise ValueError(
                     f"stage layers {start}..{end} hold kinds {mine}: a "
-                    "stage's layers must come kind after kind"
+                    "stage's layers must come kind after kind, or every "
+                    "stage hold the same sequence of kinds (whole periods "
+                    "of the model's pattern)"
                 )
             before = kinds[:start].count(kind)
             ranges.append((before, before + mine.count(kind)))

@@ -110,6 +110,16 @@ class ServeState(NamedTuple):
     #   dispatches). Dense mode carries a [M, 1] placeholder so the pytree
     #   structure (state_specs parity, snapshots) is mode-independent.
     m: jax.Array          # scalar int32 microstep counter
+    # A windowed model (``cfg.windowed``, paged only) keeps a KV state PER
+    # KIND of attention: ``k`` / ``v`` / ``block_tables`` above are the FULL
+    # layers' (``[S, L_full, NB, Hkv, BS, ·]``), these three the WINDOW
+    # layers' — an arena with its own layer count, pool size and head count
+    # (``[S, L_swa, NB_swa, Hkv_swa, BS, ·]``) and a table whose entries
+    # behind the window the host hands back to the pool (trash). None (an
+    # empty pytree: no operand of any program) for every other model.
+    k_swa: Any = None
+    v_swa: Any = None
+    tables_swa: Any = None
 
 
 def _dev(spec: P) -> bool:
@@ -158,11 +168,14 @@ def state_specs(
     # the leaf is cp-stacked and the bodies strip the leading dim like any
     # pipe leaf.
     tbl = P(CP_AXIS) if cp > 1 else rep
+    swa = state.k_swa is not None  # a windowed model's second KV state
     return ServeState(
         k=kv, v=kv, k_scale=scale, v_scale=scale, kpos=dev, h=dev,
         h_valid=dev, pos_slots=dev, write_off=dev, out=rep, lengths=rep,
         done=rep, budget=rep, inject=rep, inject_pending=rep, rng=rep,
         temp=rep, topk=rep, topp=rep, block_tables=tbl, m=rep,
+        k_swa=kv if swa else None, v_swa=kv if swa else None,
+        tables_swa=tbl if swa else None,
     )
 
 
@@ -251,7 +264,27 @@ def _moe_counts(stats, live, sidx, num_stages):
 
 
 def _slot_tables(st, row0, Bs):
-    return jax.lax.dynamic_slice_in_dim(st.block_tables, row0, Bs, axis=0)
+    """The slot rows' block tables — a windowed model's a PAIR, full then
+    window (``models/mimo_v2.forward_layers_paged`` takes pairs)."""
+    tbl = jax.lax.dynamic_slice_in_dim(st.block_tables, row0, Bs, axis=0)
+    if st.tables_swa is None:
+        return tbl
+    return tbl, jax.lax.dynamic_slice_in_dim(st.tables_swa, row0, Bs, axis=0)
+
+
+def _arenas(st):
+    """``(k, v)`` as the stage function takes them: the arrays, or a
+    windowed model's pairs."""
+    if st.k_swa is None:
+        return st.k, st.v
+    return (st.k, st.k_swa), (st.v, st.v_swa)
+
+
+def _arena_upd(st, k_new, v_new) -> dict:
+    """The ``_replace`` keywords that put a stage function's arenas back."""
+    if st.k_swa is None:
+        return {"k": k_new, "v": v_new}
+    return {"k": k_new[0], "k_swa": k_new[1], "v": v_new[0], "v_swa": v_new[1]}
 
 
 def make_state(
@@ -267,8 +300,16 @@ def make_state(
     kv_blocks: int = 0,
     kv_block_size: int = 0,
     cp: int = 1,
+    swa_layers: int = 0,
+    kv_blocks_swa: int = 0,
 ) -> ServeState:
     """Host-constructed empty state (all slots free / done).
+
+    A windowed model (``cfg.windowed``, paged): ``swa_layers`` of the
+    stage's ``layers_per_stage`` slots are window layers; the full layers'
+    arena has the rest and ``kv_blocks`` blocks, the window layers' arena
+    ``kv_blocks_swa`` — each with its kind's key/value heads — and a row has
+    a block table per kind.
 
     With ``kv_blocks``/``kv_block_size`` set, the KV leaves become the
     POOLED paged arena, head-major ``[S, Lp, kv_blocks, Nkv,
@@ -337,7 +378,10 @@ def make_state(
         from ..models.cache import paged_arena_shape
 
         kv_shape = (
-            S, *paged_arena_shape(cfg, cp * kv_blocks, kv_block_size, Lp)
+            S, *paged_arena_shape(
+                cfg, cp * kv_blocks, kv_block_size, Lp - swa_layers,
+                heads=cfg.kv_heads_of("full") if swa_layers else None,
+            )
         )
     else:
         kv_shape = (S, Lp, M, C, cfg.cache_heads, cfg.cache_k_dim)
@@ -383,6 +427,25 @@ def make_state(
         block_tables=put(np.zeros(tbl_shape, np.int32), tbl_sh),
         m=put(np.zeros((), np.int32), rep),
     )
+    if swa_layers:
+        if not paged or cp > 1 or tp > 1 or quantized:
+            raise NotImplementedError(
+                "a windowed model's per-kind KV state needs a paged bf16 "
+                "arena and no tp / cp"
+            )
+        swa_shape = (
+            S, *paged_arena_shape(
+                cfg, kv_blocks_swa, kv_block_size, swa_layers,
+                heads=cfg.kv_heads_of("swa"),
+            )
+        )
+        state = state._replace(
+            k_swa=zeros(swa_shape, cache_dtype, dev_kv),
+            v_swa=zeros(
+                (*swa_shape[:-1], cfg.cache_v_dim), cache_dtype, dev_kv
+            ),
+            tables_swa=put(np.zeros(tbl_shape, np.int32), tbl_sh),
+        )
     return state
 
 
@@ -892,7 +955,7 @@ def serve_admit(
         return new, tok0
 
     specs = state_specs(
-        ServeState(*([None] * len(ServeState._fields))), tp, cp, quantized,
+        state, tp, cp, quantized,
         bool(block_size),
     )
     out_state, tok0 = shard_map(
@@ -1079,18 +1142,25 @@ def serve_prefill_chunk(
                 nlive = jnp.broadcast_to(
                     (col0 + Sc + block_size - 1) // block_size, (Bs,)
                 ).astype(jnp.int32)
-                walk = prefill_walk(
-                    tbl, positions, kv_pos, nlive,
-                    q_heads=cfg.num_attention_heads // tp,
-                    kv_heads=st.k.shape[2],
-                )
-                counts = jnp.stack(
-                    [walk.steps, walk.run_of.shape[0] - 1]
-                ).astype(jnp.int32) * lmask.shape[0]
+                if fns.prefill_walks is not None:
+                    # a walk per kind of attention, the window's with its
+                    # lower bound; the counts hold each kind's layer calls
+                    walk, counts = fns.prefill_walks(
+                        cfg, tbl, positions, kv_pos, nlive, layers
+                    )
+                else:
+                    walk = prefill_walk(
+                        tbl, positions, kv_pos, nlive,
+                        q_heads=cfg.num_attention_heads // tp,
+                        kv_heads=st.k.shape[2],
+                    )
+                    counts = jnp.stack(
+                        [walk.steps, walk.run_of.shape[0] - 1]
+                    ).astype(jnp.int32) * lmask.shape[0]
             h = sp_embed(cfg, hd, tokens, positions)
             h, k_new, v_new, ks_new, vs_new, moe_stats = ring_chain_paged(
                 fns, cfg, layers, lmask, sidx, ring, num_stages, h,
-                st.k, st.v, tbl, cols, kv_pos, positions, backend=attn,
+                *_arenas(st), tbl, cols, kv_pos, positions, backend=attn,
                 k_scale=ks, v_scale=vs, prefill=True, walk=walk,
                 moe_live=moe_live,
             )
@@ -1135,8 +1205,8 @@ def serve_prefill_chunk(
         out = jax.lax.dynamic_update_slice(out, tokens, (row0, chunk_off))
 
         new = st._replace(
-            k=k_new, v=v_new, kpos=kpos_new, write_off=write_off, out=out,
-            **scale_upd,
+            **_arena_upd(st, k_new, v_new), kpos=kpos_new,
+            write_off=write_off, out=out, **scale_upd,
         )
         new = jax.tree.map(
             lambda spec, leaf: leaf[None] if _dev(spec) else leaf,
@@ -1150,7 +1220,7 @@ def serve_prefill_chunk(
         return new, counts
 
     specs = state_specs(
-        ServeState(*([None] * len(ServeState._fields))), tp, cp, quantized,
+        state, tp, cp, quantized,
         bool(block_size),
     )
     return shard_map(
@@ -1275,7 +1345,7 @@ def serve_admit_finish(
         )
 
     specs = state_specs(
-        ServeState(*([None] * len(ServeState._fields))), tp, cp, quantized,
+        state, tp, cp, quantized,
         bool(block_size),
     )
     return shard_map(
@@ -1440,7 +1510,7 @@ def serve_chunk(
                     kpos_rows, pos_rows[:, None], (0, off_r)
                 )
                 h_new, k_st, v_st, ks_st, vs_st, moe_stats = fns.stage_paged(
-                    cfg, layers, h_in, s.k, s.v, tbl_r,
+                    cfg, layers, h_in, *_arenas(s), tbl_r,
                     jnp.broadcast_to(off_r, (Bs, 1)), kv_pos,
                     pos_rows[:, None], lmask, write_valid=advance,
                     backend=attn,
@@ -1563,7 +1633,8 @@ def serve_chunk(
                 ])
 
             new_s = s._replace(
-                k=k_st, v=v_st, kpos=kpos_st, h=h_out, h_valid=h_valid_out,
+                **_arena_upd(s, k_st, v_st), kpos=kpos_st, h=h_out,
+                h_valid=h_valid_out,
                 pos_slots=pos_slots, write_off=write_off, out=out,
                 lengths=lengths, done=done, inject_pending=inject_pending,
                 rng=rng, m=m + 1, **scale_upd,
@@ -1590,7 +1661,7 @@ def serve_chunk(
         return st, log
 
     specs = state_specs(
-        ServeState(*([None] * len(ServeState._fields))), tp, cp, quantized,
+        state, tp, cp, quantized,
         bool(block_size),
     )
     return shard_map(
@@ -1935,7 +2006,7 @@ def serve_verify(
         return new, log
 
     specs = state_specs(
-        ServeState(*([None] * len(ServeState._fields))), tp,
+        state, tp,
         paged=bool(block_size),
     )
     return shard_map(

@@ -598,7 +598,16 @@ class PipelineEngine:
         ``cp=1``. Requires ``tensor_parallel == 1``, the llama family, no
         speculation, and (with ``prefix_cache``) ``prefill_chunk`` set —
         see ``PipelineServer`` for the exact gates. ``cp=1`` (default)
-        compiles the exact pre-existing programs."""
+        compiles the exact pre-existing programs.
+
+        A model with sliding-window layers (``cfg.windowed``; paged +
+        ``prefill_chunk`` only) keeps an arena and a block table per kind of
+        attention layer: ``kv_blocks`` sizes the full layers' pool, the
+        window layers' is every row's share of the window (nothing to
+        size). A window layer's blocks behind the window go back to its pool
+        while the row decodes. Prefix-cache hits are not offered; snapshots,
+        prefix handles, the embeddings entry, speculation, tp / cp and a
+        quantized arena are refused by name."""
         self._validate_serve()
         if cp > 1 and self.cfg.num_experts:
             raise NotImplementedError(

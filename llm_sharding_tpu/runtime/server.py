@@ -77,6 +77,9 @@ import jax.numpy as jnp
 from ..obs.metrics import (
     ARENA_BYTES, ATTN_BACKEND, ATTN_BACKENDS, ATTN_BLOCKS_READ,
     CP_STREAM_SHARDS, DECODE_BLOCKS_LIVE, DECODE_BLOCKS_RESERVED,
+    DECODE_KIND_BLOCKS_LIVE, DECODE_KIND_BLOCKS_RESERVED,
+    KV_KIND_BLOCKS_IN_USE, KV_KIND_BLOCKS_TOTAL, KV_KIND_ENTRY_BYTES,
+    KV_WINDOW_BLOCKS_FREED,
     DEFAULT_RATE_BUCKETS,
     KV_BLOCKS_IN_USE, KV_BLOCKS_TOTAL, KV_DISK_TIER_BLOCKS,
     KV_ENTRY_BYTES, KV_HOST_TIER_BLOCKS, KV_WASTE_FRAC, MOE_EXPERT_TOKENS,
@@ -190,6 +193,8 @@ def _update_load_gauges() -> None:
 
     queued = active = 0
     kv_total = kv_used = kv_slots = kv_live = 0
+    kind_total: dict = {}
+    kind_used: dict = {}
     host_blocks = disk_blocks = hit_tok = elig_tok = 0
     backends = dict.fromkeys(ATTN_BACKENDS, 0)
     arena_bytes = dict.fromkeys(KV_DTYPES, 0)
@@ -205,6 +210,12 @@ def _update_load_gauges() -> None:
         if getattr(s, "paged", False):
             kv_total += s._alloc.capacity_blocks
             kv_used += s._alloc.in_use
+            if getattr(s, "windowed", False):
+                for kind, alloc in (("full", s._alloc), ("swa", s._alloc_swa)):
+                    kind_total[kind] = (
+                        kind_total.get(kind, 0) + alloc.capacity_blocks
+                    )
+                    kind_used[kind] = kind_used.get(kind, 0) + alloc.in_use
             if not getattr(s, "_closed", False):
                 arena_bytes[s.kv_dtype] += s.arena_bytes_device
             # COLD prefix-cache blocks (tree-held, no row mapping them) are
@@ -227,6 +238,9 @@ def _update_load_gauges() -> None:
                 elig_tok += rad.eligible_tokens
     _M_QUEUE_DEPTH.set(queued)
     _M_ACTIVE.set(active)
+    for kind, n in kind_total.items():
+        KV_KIND_BLOCKS_TOTAL.labels(kind=kind).set(n)
+        KV_KIND_BLOCKS_IN_USE.labels(kind=kind).set(kind_used[kind])
     for b, n in backends.items():
         ATTN_BACKEND.labels(backend=b).set(n)
     for name, nbytes in arena_bytes.items():
@@ -387,28 +401,74 @@ _FIELD_COUNTERS = {
 
 
 class _Prefetched:
-    """A device→host read issued eagerly on a background thread. The serving
-    loop dispatches a chunk, hands its token log here, and keeps going; by
-    the time the loop wants the numpy value (one pipeline_depth later) the
-    transfer has already ridden out the chunk's device time and the
-    device→host copy — the steady-state step loop never blocks on the
-    read, and the device queue stays full (what the synchronous fetch
-    costs per step on this installation: not measured — see PERF.md)."""
+    """A device→host read of a few hundred bytes a step needs one
+    ``pipeline_depth`` later: by then the transfer has ridden out the
+    chunk's device time, so the steady-state step loop never waits for the
+    copy itself and the device queue stays full. Who finishes the read:
+
+    - the process-wide prefetch thread (``_Prefetcher.fetch``; ``event`` is
+      set when the value has landed): the async executor's way, whose
+      sidecar waits on events, and exact about WHEN a log landed;
+    - the thread that waits for it (``direct``: ``event`` is None, the copy
+      begun with ``copy_to_host_async`` at dispatch): the serial step's way.
+      A log handed from thread to thread reached the step 0.17 ms after it
+      landed (sd 0.04: an event's wake and the interpreter lock's, each a
+      futex under a sandboxed kernel) — at the moment a stream's reader is
+      waiting for the token (PERF.md §6, PR 39)."""
 
     __slots__ = ("handle", "value", "error", "event", "tag", "done_at")
 
-    def __init__(self, handle, tag: str = "?"):
+    def __init__(self, handle, tag: str = "?", direct: bool = False):
         self.handle = handle
         self.tag = tag  # what this read belongs to ("chunk m0=…", "admit …")
         self.value = None
         self.error: Optional[BaseException] = None
-        self.event = threading.Event()
+        self.event = None if direct else threading.Event()
         # perf_counter stamp of when the value landed on host — the step
-        # profiler's device-idle estimate (log ready vs next dispatch)
+        # profiler's device-idle estimate (log ready vs next dispatch). A
+        # direct read knows it to the moment only when its thread waited;
+        # otherwise it is when ``landed`` first found the device done
         self.done_at: Optional[float] = None
+        if direct:
+            begin = getattr(handle, "copy_to_host_async", None)
+            if begin is not None:
+                begin()
+
+    def read(self) -> None:
+        """The blocking read, on the calling thread; a failure is kept WITH
+        the handle (``get_retryable`` re-issues the read)."""
+        try:
+            self.value = np.asarray(self.handle)
+        except BaseException as e:  # noqa: BLE001 — surfaced via get()
+            self.error = e
+            _M_FETCH_FAIL.inc()
+            logger.warning("prefetch failed for %s: %r", self.tag, e)
+        else:
+            self.handle = None  # drop the device reference promptly
+            self.done_at = time.perf_counter()
+        if self.event is not None:
+            self.event.set()
+
+    def landed(self) -> bool:
+        """Has the value (or its failure) reached the host? Never waits for
+        the device: a direct read whose device work is done is finished
+        here."""
+        if self.event is not None:
+            return self.event.is_set()
+        if self.value is None and self.error is None:
+            ready = getattr(self.handle, "is_ready", None)
+            if ready is None or ready():
+                self.read()
+        return self.value is not None or self.error is not None
+
+    def wait(self) -> None:
+        if self.event is not None:
+            self.event.wait()
+        elif self.value is None and self.error is None:
+            self.read()
 
     def get(self) -> np.ndarray:
-        self.event.wait()
+        self.wait()
         if self.error is not None:
             # name the chunk/admission the failed device→host read belonged
             # to — a bare re-raise surfaced "transfer failed" with no way to
@@ -426,7 +486,7 @@ class _Prefetched:
         the handle kept on error (a plain ``get`` retry would only re-raise
         the cached error — the read itself must be retried for the bounded
         log-fetch retry policy to absorb real transient transfer faults)."""
-        self.event.wait()
+        self.wait()
         if self.error is None:
             return self.value
         if self.handle is None:
@@ -479,18 +539,7 @@ class _Prefetcher:
 
     def _run(self) -> None:
         while True:
-            p = self._q.get()
-            try:
-                p.value = np.asarray(p.handle)
-            except BaseException as e:  # noqa: BLE001 — surfaced via get()
-                p.error = e
-                _M_FETCH_FAIL.inc()
-                logger.warning("prefetch failed for %s: %r", p.tag, e)
-                p.event.set()
-                continue  # KEEP the handle: get_retryable re-issues the read
-            p.handle = None  # drop the device reference promptly
-            p.done_at = time.perf_counter()
-            p.event.set()
+            self._q.get().read()
 
 
 def save_snapshot(snap: dict, path: str) -> None:
@@ -1124,6 +1173,64 @@ class PipelineServer:
                     f"cp / tp over a latent KV cache "
                     f"({self.cfg.model_type}) is not implemented"
                 )
+        #: window and full attention in one stack (``cfg.windowed``): a KV
+        #: state per kind of attention layer — the full layers' pool is
+        #: ``kv_blocks``; the window layers' is every row's share of
+        #: ``_swa_quota`` blocks (``_init_window_pool``): nothing to size
+        self.windowed = bool(self.cfg.windowed)
+        if self.windowed:
+            # what a window layer breaks is refused by name, never computed
+            # as something else (ROADMAP M2 lists what is left)
+            name = f"a windowed model ({self.cfg.model_type})"
+            if not self.paged or prefill_chunk is None:
+                raise ValueError(
+                    f"{name} serves from a paged arena per kind of layer, "
+                    "admitted chunk by chunk: set kv_block_size, kv_blocks "
+                    "and prefill_chunk"
+                )
+            if kv_dtype != "bf16":
+                raise NotImplementedError(
+                    f"kv_dtype={kv_dtype!r} over {name}: a quantized arena "
+                    "per kind of layer is not implemented"
+                )
+            if self.speculate:
+                raise NotImplementedError(
+                    f"speculate over {name}: serve_verify is not carried "
+                    "over a KV state per kind of layer (a rejected draft "
+                    "would have to un-free window blocks)"
+                )
+            if cp > 1 or self.tp > 1:
+                raise NotImplementedError(
+                    f"cp / tp over {name} is not implemented"
+                )
+            if snapshot_every_s is not None or snapshot_path is not None:
+                raise NotImplementedError(
+                    f"snapshots of {name}: SNAPSHOT_FORMAT carries one arena "
+                    "and one table a row — not implemented"
+                )
+            if prefix_cache != "off":
+                # a hit would map the full layers' old blocks while the
+                # window layers' are gone: a hit is not OFFERED (the tree is
+                # not built; every prompt prefills cold)
+                logger.info(
+                    "prefix_cache=%r over %s: hits are not offered (a "
+                    "window layer's old blocks are gone)", prefix_cache, name,
+                )
+                prefix_cache = self.prefix_cache = "off"
+                host_pool_blocks = self.host_pool_blocks = 0
+        if self.windowed:
+            # the most a row's window layers may hold: a chunk's queries and
+            # the window behind its first (ISSUE 39: ceil((window +
+            # prefill_chunk) / BS) + 1). Every row has that share of the
+            # pool from the start (+ the trash block 0), so a window layer
+            # never waits for a block and admission counts the full
+            # layers' pool alone
+            self._swa_quota = -(
+                -(self.cfg.sliding_window + prefill_chunk) // kv_block_size
+            ) + 1
+            self._swa_blocks = (
+                self.num_stages * batch_per_slot * self._swa_quota + 1
+            )
         self.kv_dtype = kv_dtype
         #: the arena STORAGE dtype (engine.cache_dtype stays the compute
         #: dtype — prefill windows, prefix handles and dense state use it)
@@ -1337,6 +1444,7 @@ class PipelineServer:
             self.cfg, self.num_stages, Lp
         )
         self._chunk_lazy: list = []
+        self._parked_counts: list = []  # (counters, decode) of applied logs
         if self._moe_width:
             self._moe_layers = np.flatnonzero(
                 np.asarray(engine.layer_masks).reshape(-1)
@@ -1360,6 +1468,7 @@ class PipelineServer:
             kv_blocks=self.kv_blocks or 0,
             kv_block_size=self.kv_block_size or 0,
             cp=self.cp,
+            **self._window_state_kwargs(),
         )
 
         M = self.num_stages * batch_per_slot
@@ -1414,6 +1523,8 @@ class PipelineServer:
             # host mirror edited but not yet shipped to device — releases
             # coalesce into ONE push before the next KV-touching dispatch
             self._tables_dirty = False
+            if self.windowed:
+                self._init_window_pool(M, Lp)
         else:
             self._alloc = None
         if self.prefix_cache != "off":
@@ -1468,7 +1579,11 @@ class PipelineServer:
         self._mirror_cachedelta = np.zeros(M, np.int64)
         self._m = 0  # host mirror of state.m (chunks advance it)
         self._pending: collections.deque = collections.deque()
-        self._prefetcher = _Prefetcher.shared()
+        # the async executor's sidecar waits on the prefetch thread's
+        # events; the serial step reads its logs itself (_Prefetched)
+        self._prefetcher = (
+            _Prefetcher.shared() if self.inflight_steps > 1 else None
+        )
         self._stop_ids = frozenset(int(t) for t in self.cfg.eos_token_ids)
         # rows mid-chunked-admission: the slot is parked done on device until
         # serve_admit_finish arms it; no log entries arrive for it
@@ -1650,6 +1765,30 @@ class PipelineServer:
             DECODE_BLOCKS_LIVE.inc(live * steps)
         DECODE_BLOCKS_RESERVED.inc(reserved * steps)
         self.stepline.decode_blocks(live * steps, reserved * steps)
+        if self.windowed:
+            # per kind of layer: a window layer's walk starts at the first
+            # block its window reaches (what the row still holds)
+            walked = {
+                "full": live,
+                "swa": sum(len(self._row_swa[r]) for r in rows),
+            }
+            kinds = {}
+            for kind, alloc in (("full", self._alloc), ("swa", self._alloc_swa)):
+                DECODE_KIND_BLOCKS_LIVE.labels(kind=kind).inc(
+                    walked[kind] * steps
+                )
+                DECODE_KIND_BLOCKS_RESERVED.labels(kind=kind).inc(
+                    reserved * steps
+                )
+                kinds[kind] = {
+                    "blocks_in_use": alloc.in_use,
+                    "blocks_total": alloc.capacity_blocks,
+                    "decode_blocks_live": walked[kind] * steps,
+                    "decode_blocks_reserved": reserved * steps,
+                }
+            kinds["swa"]["blocks_freed"] = self._window_freed_step
+            self._window_freed_step = 0
+            self.stepline.kv_kinds(kinds)
 
     # ------------------------------------------------------------------ API
 
@@ -1741,6 +1880,11 @@ class PipelineServer:
         prefixes of similar length share one compiled shape; positions for
         suffix requests resume at the REAL length ``n``, so generation is
         token-exact vs prefilling ``prefix + suffix`` whole."""
+        self._refuse_windowed(
+            "prefill_prefix over",
+            "a handle carries one arena's blocks, and a window layer's are "
+            "gone behind the window",
+        )
         if self.cp > 1:
             raise NotImplementedError(
                 "prefill_prefix does not support context-parallel serving "
@@ -1824,6 +1968,10 @@ class PipelineServer:
         (the slot is parked half-prefilled on device) and while queued
         requests hold prefix handles (device-bound KV — let them admit
         first, or resubmit them after restore)."""
+        self._refuse_windowed(
+            "snapshot of",
+            "SNAPSHOT_FORMAT carries one arena and one table a row",
+        )
         with self._mutex:
             if self._closed:
                 raise ServerClosed("cannot snapshot a closed server")
@@ -1964,7 +2112,12 @@ class PipelineServer:
                     "row_blocks": [list(b) for b in self._row_blocks],
                     "row_shared": [list(b) for b in self._row_shared],
                 },
-                "state": jax.tree.map(np.asarray, self.state._asdict()),
+                # (a windowed model's second KV state is refused above; its
+                # leaves are None for every other model and are not carried)
+                "state": jax.tree.map(np.asarray, {
+                    k: v for k, v in self.state._asdict().items()
+                    if v is not None
+                }),
                 "m": self._m,
                 "sampling": self._sampling,
                 "filtering": self._filtering,
@@ -2065,6 +2218,7 @@ class PipelineServer:
         tmpl = {
             name: (leaf.shape, leaf.dtype, leaf.sharding)
             for name, leaf in zip(serve_ops.ServeState._fields, srv.state)
+            if leaf is not None
         }
         srv.state = None
         for name, (shape, dtype, _) in tmpl.items():
@@ -2086,7 +2240,7 @@ class PipelineServer:
         srv.state = serve_ops.ServeState(
             **{
                 name: jax.device_put(np.asarray(host[name]), tmpl[name][2])
-                for name in serve_ops.ServeState._fields
+                for name in tmpl
             }
         )
         if engine.tokenizer is None and any(
@@ -2265,6 +2419,11 @@ class PipelineServer:
         ``submit_embedding(engine.embed_prompt(ids)[0], ...)`` decodes
         token-exactly vs ``submit(ids, ...)``. Embeds requests always use
         one-shot admission (chunked prefill is an ids-path optimization)."""
+        self._refuse_windowed(
+            "submit_embedding over",
+            "the embeddings entry admits through the one-shot dense window, "
+            "which this model's per-kind KV state does not have",
+        )
         top_k, top_p = self._resolve_filters(top_k, top_p)
         deadline_s = self._resolve_deadline(deadline_s)
         h = np.asarray(prompt_embeds, self._act_dtype)
@@ -2379,6 +2538,7 @@ class PipelineServer:
                 self._drain(0)
                 progressed |= self._admit_pending()
             sl.pop()
+            swept = False
             if self.speculate and self._any_active():
                 # speculative decode replaces the interleaved chunk: per
                 # active slot, draft on host, verify K+1 positions in one
@@ -2392,11 +2552,23 @@ class PipelineServer:
             elif self._any_active():
                 self._dispatch_chunk()
                 progressed = True
+                # what no reader of the tokens waits for runs HERE, while
+                # the device works on the chunk just dispatched: the parked
+                # counters of the last log and the gauge sweep (a step
+                # stale, as its docstring allows). Between the log's landing
+                # and this step's return — when a stream's reader sees the
+                # token — is then only the tokens' own replay (PERF.md §6,
+                # PR 39: that stretch's jitter was most of the gap's width)
+                sl.push("apply")
+                self._settle_counts()
+                sl.pop()
+                swept = self._sweep_gauges_if_due()
                 t0 = time.perf_counter()
-                applied = self._drain(self.pipeline_depth)
+                applied = self._drain(self.pipeline_depth, park_counts=True)
             else:
                 t0 = time.perf_counter()
                 applied = self._drain(0)
+                self._settle_counts()  # nothing in flight: the series are whole
             dt_apply = time.perf_counter() - t0
             if progressed or applied:
                 # span emission is real per-step host work (the flight
@@ -2405,16 +2577,8 @@ class PipelineServer:
                 sl.push("apply")
                 self._span("apply", dur_s=dt_apply, applied=applied)
                 sl.pop()
-                now = time.perf_counter()
-                if (
-                    self.gauge_sweep_every_s <= 0.0
-                    or now - self._last_gauge_sweep
-                    >= self.gauge_sweep_every_s
-                ):
-                    sl.push("gauge_sweep")
-                    _update_load_gauges()
-                    sl.pop()
-                    self._last_gauge_sweep = now
+                if not swept:
+                    self._sweep_gauges_if_due()
             if self._radix is not None and self._queue:
                 # stage the NEXT admission's radix plan now, AFTER this
                 # step's decode dispatch: a host-tier restore it triggers
@@ -2441,6 +2605,11 @@ class PipelineServer:
             ):
                 # a clean step after containment: recovered
                 self._set_health(SERVING)
+            if self._pending:
+                # a look at the newest log as the step ends: a device that
+                # is already done is idle from here (at least) until the
+                # next dispatch, which counts it (_dispatch_chunk)
+                self._pending[-1][1].landed()
             rows, queued, pending = self._held()
             sl.end_step(
                 rows=rows, tokens=self.counters.tokens_generated - tok0,
@@ -2649,6 +2818,27 @@ class PipelineServer:
             _update_load_gauges()
         return shed
 
+    def _fetch(self, handle, tag: str) -> _Prefetched:
+        """Begin the device→host read of a step's log."""
+        if self._prefetcher is not None:
+            return self._prefetcher.fetch(handle, tag=tag)
+        return _Prefetched(handle, tag, direct=True)
+
+    def _sweep_gauges_if_due(self) -> bool:
+        """The serial step's paced gauge sweep (``gauge_sweep_every_s``);
+        True if it ran."""
+        now = time.perf_counter()
+        if (
+            self.gauge_sweep_every_s > 0.0
+            and now - self._last_gauge_sweep < self.gauge_sweep_every_s
+        ):
+            return False
+        self.stepline.push("gauge_sweep")
+        _update_load_gauges()
+        self.stepline.pop()
+        self._last_gauge_sweep = now
+        return True
+
     def _sweep_gauges(self) -> None:
         """Scheduler-thread hook for the paced load-gauge sweep (the
         module-level ``_update_load_gauges`` is not importable from
@@ -2664,9 +2854,11 @@ class PipelineServer:
             # device-idle estimate: the newest in-flight chunk is the last
             # work the device was given — if its log has already landed on
             # host (done_at stamped), the device has been draining/idle
-            # since then, and this dispatch ends the bubble
+            # since then, and this dispatch ends the bubble. The serial
+            # step reads its own logs (_Prefetched) and knows that moment
+            # as the last step's end found it: its estimate is a lower bound
             newest = self._pending[-1][1]
-            if newest.done_at is not None and newest.event.is_set():
+            if newest.landed() and newest.done_at is not None:
                 self.stepline.idle(t0 - newest.done_at)
         self.stepline.push("dispatch")
         cycles = self.num_stages * self.chunk_cycles
@@ -2701,6 +2893,7 @@ class PipelineServer:
                 cp=self.cp,
             )
 
+        self._slide_windows()
         self._flush_tables()
         t_dispatch = time.perf_counter()
         try:
@@ -2715,7 +2908,7 @@ class PipelineServer:
             CP_COMBINE_SECONDS.observe(time.perf_counter() - t_dispatch)
         self._pending.append(
             ("chunk",
-             self._prefetcher.fetch(log, tag=f"chunk m0={self._m}"),
+             self._fetch(log, tag=f"chunk m0={self._m}"),
              self._m)
         )
         self._record_blocks_read(
@@ -3218,6 +3411,8 @@ class PipelineServer:
         self._row_shared[row] = []
         self._tables[row] = 0
         self._tables_dirty = True
+        if self.windowed:
+            self._release_window_blocks(row)
         rel_priv = [b for b in priv if b not in consumed] if consumed else priv
         if rel_priv:
             self._alloc.free(rel_priv)
@@ -3225,6 +3420,161 @@ class PipelineServer:
             self._alloc.free(shared)
         if rref is not None:
             self._radix.release(rref)
+
+    # ------------------------------- a windowed model's second KV state
+
+    def _window_state_kwargs(self) -> dict:
+        """``make_state``'s keywords for a KV state per kind of layer."""
+        if not self.windowed:
+            return {}
+        kinds = self.cfg.layer_kinds
+        stages = self.engine.exec_placement.stages
+        for start, end in stages:
+            if kinds[start:end] != kinds[:end - start]:
+                raise NotImplementedError(
+                    f"stage layers {start}..{end} of a windowed model "
+                    f"({self.cfg.model_type}) hold kinds "
+                    f"{list(kinds[start:end])}: every stage must hold the "
+                    "same sequence of layer kinds as the first (whole "
+                    "periods of the pattern)"
+                )
+        return {
+            # a stage's window layers (every stage holds the same kinds)
+            "swa_layers": max(
+                sum(self.cfg.layer_attn[start:end]) for start, end in stages
+            ),
+            "kv_blocks_swa": self._swa_blocks,
+        }
+
+    def _init_window_pool(self, M: int, Lp: int) -> None:
+        """The window layers' pool, tables and per-row bookkeeping. A row's
+        window layers hold the blocks its window can still reach and the
+        ones the next dispatches write — never more than ``_swa_quota`` —
+        and the pool holds that many for EVERY row, so the per-dispatch
+        free / alloc below can never find it empty."""
+        from .blocks import BlockAllocator
+
+        bs = self.kv_block_size
+        item = np.dtype(self.kv_store_dtype).itemsize
+        self._alloc_swa = BlockAllocator(self._swa_blocks, bs)
+        self._tables_swa = np.zeros_like(self._tables)
+        #: per row: table entry -> block of the window pool
+        self._row_swa: list[dict] = [{} for _ in range(M)]
+        #: per row: (real prompt columns, the first decode column): key
+        #: position p sits at column p below the first and at ``first
+        #: decode column + p - real prompt columns`` from it on
+        self._swa_meta: list = [None] * M
+        self._window_freed_step = 0
+        n_swa = int(self.state.k_swa.shape[1])
+        self._kind_layers = {"full": Lp - n_swa, "swa": n_swa}
+        widths = self.cfg.cache_k_dim + self.cfg.cache_v_dim
+        self._kind_entry_bytes = {
+            kind: self.cfg.kv_heads_of(kind) * widths * item
+            for kind in ("full", "swa")
+        }
+        for kind, n in self._kind_entry_bytes.items():
+            KV_KIND_ENTRY_BYTES.labels(kind=kind).set(float(n))
+        # the arena's bytes: both kinds' (the full layers' was counted with
+        # every layer slot and the dense rows' head count)
+        self.arena_bytes_device = sum(
+            alloc.num_blocks * bs * self.num_stages
+            * self._kind_layers[kind] * self._kind_entry_bytes[kind]
+            for kind, alloc in (("full", self._alloc), ("swa", self._alloc_swa))
+        )
+        KV_ENTRY_BYTES.set(float(self._kind_entry_bytes["full"]))
+
+    def _release_window_blocks(self, row: int) -> None:
+        held = self._row_swa[row]
+        if held:
+            self._alloc_swa.free(list(held.values()))
+            held.clear()
+            self._tables_swa[row] = 0
+        self._swa_meta[row] = None
+
+    def _hold_window_blocks(self, row: int, spans) -> int:
+        """Make ``row``'s window table name exactly the blocks that cover
+        the column ``spans`` (``[(lo, hi)]``, hi exclusive): the others go
+        back to the pool, missing ones come from it. Returns how many were
+        handed back. The device table follows at the next flush."""
+        bs, width = self.kv_block_size, self._tables_swa.shape[1]
+        want: set = set()
+        for lo, hi in spans:
+            if hi > lo:
+                want.update(range(max(lo, 0) // bs, min(-(-hi // bs), width)))
+        held = self._row_swa[row]
+        drop = [j for j in held if j not in want]
+        add = sorted(want.difference(held))
+        if not drop and not add:
+            return 0
+        tbl = self._tables_swa[row]
+        if drop:
+            self._alloc_swa.free([held.pop(j) for j in drop])
+            tbl[drop] = 0
+        if add:
+            for j, b in zip(add, self._alloc_swa.alloc(len(add))):
+                held[j] = tbl[j] = b
+        if len(held) > self._swa_quota:
+            raise AssertionError(
+                f"row {row} holds {len(held)} window blocks, over its "
+                f"share of {self._swa_quota}"
+            )
+        self._tables_dirty = True
+        return len(drop)
+
+    def _window_spans(self, row: int, ahead: int) -> list:
+        """The columns a decode dispatch can read or write in ``row``'s
+        window layers, from the host's length mirror (which trails the
+        device by the dispatches in flight: ``ahead`` steps at most): the
+        query at position ``n - 1`` keeps keys ``>= n - window``; the
+        newest key written lies at most ``ahead`` positions on."""
+        n = int(self._mirror_len[row])
+        prompt_cols, decode_col = self._swa_meta[row]
+        lo = max(n - self.cfg.sliding_window, 0)
+        spans = []
+        if lo < prompt_cols:
+            spans.append((lo, prompt_cols))
+        first = max(lo, prompt_cols) - prompt_cols
+        spans.append(
+            (decode_col + first, decode_col + n + ahead - prompt_cols)
+        )
+        return spans
+
+    def _slide_windows(self) -> None:
+        """Before a decode dispatch: every live row's window layers let go
+        of the blocks wholly behind the window and take the block the next
+        steps write. Host arithmetic over the length mirrors — no device
+        read, no new program: the tables are data."""
+        if not self.windowed:
+            return
+        ahead = (len(self._pending) + 1) * self.chunk_cycles + 1
+        freed = 0
+        for row, req in enumerate(self._rows):
+            if (
+                req is None or req.done or row in self._admitting_rows
+                or self._swa_meta[row] is None
+            ):
+                continue
+            freed += self._hold_window_blocks(
+                row, self._window_spans(row, ahead)
+            )
+        if freed:
+            KV_WINDOW_BLOCKS_FREED.inc(freed)
+        self._window_freed_step += freed
+
+    def _refuse_windowed(self, what: str, why: str) -> None:
+        """What a window layer breaks is refused by name, never computed as
+        something else (ROADMAP M2 lists what is left)."""
+        if self.windowed:
+            raise NotImplementedError(
+                f"{what} a windowed model ({self.cfg.model_type}): {why} — "
+                "not implemented"
+            )
+
+    def _refuse_window_kv_move(self) -> None:
+        self._refuse_windowed(
+            "moving KV blocks (hand-off, host / disk tier) of",
+            "a row's window layers hold other blocks than its full layers",
+        )
 
     def _push_tables(self) -> None:
         """Ship the host block-table mirror to the device state (replicated
@@ -3251,6 +3601,15 @@ class PipelineServer:
                 tables, self.state.block_tables.sharding
             )
         )
+        if self.windowed:
+            # a COPY: the mirror is edited in place every few steps, and on
+            # the CPU backend device_put may alias an aligned numpy array —
+            # a dispatch still in flight would read the edit
+            self.state = self.state._replace(
+                tables_swa=jax.device_put(
+                    self._tables_swa.copy(), self.state.tables_swa.sharding
+                )
+            )
         self.stepline.pop()
 
     def _flush_tables(self) -> None:
@@ -3297,6 +3656,7 @@ class PipelineServer:
         it into per-shard slices + a concat. ``_cp_stream_check`` walks
         the owner shards first for fault injection and stream
         accounting."""
+        self._refuse_window_kv_move()
         blocks = list(blocks)
         self._cp_stream_check(blocks)
         idx = jnp.asarray(np.asarray(blocks, np.int32))
@@ -3337,6 +3697,7 @@ class PipelineServer:
         arithmetic as the read path; block bytes are cp-agnostic, which
         is what lets a cp=1 peer's stream land on a cp=2 arena and vice
         versa)."""
+        self._refuse_window_kv_move()
         blocks = list(blocks)
         self._cp_stream_check(blocks)
         idx = jnp.asarray(np.asarray(blocks, np.int32))
@@ -4048,12 +4409,24 @@ class PipelineServer:
         return True
 
     def _bucket(self, n: int) -> int:
+        if self.windowed:
+            # every prompt admits chunk by chunk (``_chunked``), in WHOLE
+            # chunks: one ``serve_prefill_chunk`` program whatever the
+            # prompt's length, where a bucket under the chunk would compile
+            # its own (five more programs to trace, compile and warm up)
+            n = max(n, self.prefill_chunk)
         for b in ADMIT_BUCKETS:
             if b >= n and b <= self.capacity:
                 return b
         raise ValueError(f"prompt length {n} exceeds admit buckets/capacity")
 
     def _chunked(self, bucket: int) -> bool:
+        # a windowed model admits EVERY prompt chunk by chunk: the chunked
+        # path is arena-native, so no dense window of ``capacity`` columns
+        # is ever built for its window layers (``serve_admit`` builds one
+        # for every layer; it costs nothing here whatever the capacity)
+        if self.windowed:
+            return True
         return self.prefill_chunk is not None and bucket > self.prefill_chunk
 
     def _use_chunked(self, bucket: int, spx_n: int = 0) -> bool:
@@ -4274,6 +4647,13 @@ class PipelineServer:
                         else (rplan.blocks if rplan is not None else None),
                         chunked,
                     )
+                    if self.windowed:
+                        # the prompt's real columns [0, len - 1) hold their
+                        # own positions; position len - 1 (the injected last
+                        # prompt token) sits at column ``bucket``
+                        self._swa_meta[r.row] = (
+                            spx + sfx_len - 1, spx + bucket
+                        )
                     if rplan is not None:
                         # one pin per mapping row (the take() pin covers
                         # the first row; later rows add their own)
@@ -4417,7 +4797,7 @@ class PipelineServer:
                 self._pending.append(
                     (
                         "admit",
-                        self._prefetcher.fetch(
+                        self._fetch(
                             tok0,
                             tag=f"admit slot={slot} "
                                 f"ids={[r.id for r in batch]}",
@@ -4515,6 +4895,19 @@ class PipelineServer:
         )
         n_valid = int(row_valid.sum())
         for ci, off in enumerate(range(0, bucket, Sc)):
+            if self.windowed:
+                # the window layers' blocks this chunk writes and its
+                # queries can reach; those behind go back to the pool
+                # (a row's real prompt columns end at ``len - 1``: the
+                # chunks past them are padding, and what the row's LAST
+                # token — injected after the chunks — can reach must stay)
+                col0, window = prefix_off + off, self.cfg.sliding_window
+                for r in np.flatnonzero(row_valid):
+                    end = prefix_off + int(plen[r]) - 1
+                    self._hold_window_blocks(
+                        row0 + int(r),
+                        [(min(col0, end) - window + 1, min(col0 + Sc, end))],
+                    )
             self._flush_tables()
             if self.paged:
                 # blocks this chunk's queries attend = the written
@@ -4559,6 +4952,7 @@ class PipelineServer:
                      self.tp, self.kv_block_size, attn, self.kv_dtype)
                     + ((self.cp,) if self.cp > 1 else ()),
                 )
+                self._slide_windows()
                 self._flush_tables()
                 self.state, log = serve_ops.serve_chunk(
                     self.cfg,
@@ -4578,7 +4972,7 @@ class PipelineServer:
                 )
                 self._pending.append(
                     ("chunk",
-                     self._prefetcher.fetch(log, tag=f"chunk m0={self._m}"),
+                     self._fetch(log, tag=f"chunk m0={self._m}"),
                      self._m)
                 )
                 self._m += self.num_stages
@@ -4711,7 +5105,7 @@ class PipelineServer:
             self._pending.append(
                 (
                     "spec",
-                    self._prefetcher.fetch(log, tag=f"verify slot={slot}"),
+                    self._fetch(log, tag=f"verify slot={slot}"),
                     [
                         (row, req, int(draft_len[row - slot * Bs]),
                          draft[row - slot * Bs].copy())
@@ -4756,12 +5150,15 @@ class PipelineServer:
                     break  # stop-string truncation mid-run
                 self._apply_token(row, req, t)
 
-    def _drain(self, max_pending: int) -> int:
+    def _drain(self, max_pending: int, park_counts: bool = False) -> int:
         """Apply queued device reads until at most ``max_pending`` remain.
         ``max_pending=1`` is the steady-state pipeline depth (the newest
         chunk's log stays in flight while its chunk executes);
         ``max_pending=0`` is a full flush (before admission decisions and at
         drain time). Returns the number of entries applied.
+        ``park_counts`` (the serial step's steady-state drain) replays the
+        tokens only and parks the counters the logs carry for
+        ``_settle_counts``.
 
         Fetch failures retry for transient faults; a log lost past retries
         fails the requests whose tokens it carried (``_contain_lost_log``)
@@ -4773,15 +5170,15 @@ class PipelineServer:
         while len(self._pending) > max_pending:
             entry = self._pending.popleft()
             applied += 1
-            if not entry[1].event.is_set():
+            if not entry[1].landed():
                 # blocked on device: the log hasn't materialized on host
                 # yet. The wait is measured SEPARATELY from host compute
                 # (the profiler's blocked_s — excluded from the fetch
                 # phase — and its serve.blocked annotation); the retryable
                 # get below then returns instantly.
                 with sl.blocking():
-                    entry[1].event.wait()
-            self._apply_entry(entry)
+                    entry[1].wait()
+            self._apply_entry(entry, park_counts)
         sl.pop()
         return applied
 
@@ -4793,17 +5190,20 @@ class PipelineServer:
         safely here: the mutex guarantees the pump is between steps, so
         the profiler has no open step."""
         applied = 0
-        while self._pending and self._pending[0][1].event.is_set():
+        while self._pending and self._pending[0][1].landed():
             self._apply_entry(self._pending.popleft())
             applied += 1
         return applied
 
-    def _apply_entry(self, entry) -> bool:
+    def _apply_entry(self, entry, park_counts: bool = False) -> bool:
         """Fetch (with retry/containment) and apply ONE popped ``_pending``
         entry; shared by the blocking ``_drain`` and the sidecar's
         ``_drain_landed``. Returns False when the log was lost and its
         requests were failed (``_contain_lost_log``) — draining continues
-        with the next entry either way."""
+        with the next entry either way. With ``park_counts`` the counters
+        behind the tokens wait for ``_settle_counts`` (the next step's, while
+        the device works); without it they are counted here, after whatever
+        was parked, so the series keep their order."""
         sl = self.stepline
         try:
             value = self._retry(
@@ -4816,9 +5216,15 @@ class PipelineServer:
             self._contain_lost_log(entry, err)
             return False
         sl.push("apply")
-        self._apply_chunk_counts()
+        if not park_counts:
+            self._settle_counts()
         if self._moe_width and entry[0] in ("chunk", "admit"):
-            value = self._apply_moe(value, decode=entry[0] == "chunk")
+            W = self._moe_width
+            own, value = np.asarray(value)[..., -W:], value[..., :-W]
+            if park_counts:
+                self._parked_counts.append((own, entry[0] == "chunk"))
+            else:
+                self._count_moe(own, decode=entry[0] == "chunk")
         if entry[0] == "chunk":
             self._apply_log(value, entry[2])
         elif entry[0] == "spec":
@@ -4831,13 +5237,20 @@ class PipelineServer:
         sl.pop()
         return True
 
-    def _apply_moe(self, value: np.ndarray, decode: bool) -> np.ndarray:
-        """Split a fetched chunk log or admission result of a model with
-        experts into its tokens (returned) and the ``moe_log_width``
-        counters behind them, which go to the step record and the
-        ``server_moe_*`` series."""
+    def _settle_counts(self) -> None:
+        """Count what ``_apply_entry`` parked, oldest first, then the
+        chunked prefills' counters that have landed."""
+        for own, decode in self._parked_counts:
+            self._count_moe(own, decode)
+        self._parked_counts.clear()
+        self._apply_chunk_counts()
+
+    def _count_moe(self, own: np.ndarray, decode: bool) -> None:
+        """The ``moe_log_width`` counters behind the tokens of a fetched
+        chunk log or admission result of a model with experts go to the
+        step record and the ``server_moe_*`` series."""
         E, W = self.cfg.num_experts, self._moe_width
-        own = np.asarray(value)[..., -W:].reshape(-1, W)
+        own = own.reshape(-1, W)
         tokens = own[:, :E].sum(axis=0)
         self._count_expert_tokens(tokens)
         if decode:  # a chunk log: one row of counters per decode microstep
@@ -4851,7 +5264,6 @@ class PipelineServer:
                 MOE_EXPERTS_READ.set(float(read[busy].mean()))
         else:
             self.stepline.experts(tokens)
-        return value[..., :-W]
 
     def _count_expert_tokens(self, tokens: np.ndarray) -> None:
         for child, n in zip(self._moe_children, tokens):
@@ -4866,7 +5278,7 @@ class PipelineServer:
         (``serve_prefill_chunk``'s second result, parked in ``_chunk_lazy``:
         no wait of their own): the prefill kernel's walk goes to the step
         record and ``server_prefill_cells_*``, a model with experts' tokens
-        per expert where ``_apply_moe`` sends a fetched log's."""
+        per expert where ``_count_moe`` sends a fetched log's."""
         ready = [a for a in self._chunk_lazy if a.is_ready()]
         if not ready:
             return

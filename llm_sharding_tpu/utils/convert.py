@@ -182,6 +182,66 @@ def deepseek_layer_arrays(
     return p
 
 
+def mimo_layer_arrays(
+    cfg: ModelConfig, get: TensorGetter, i: int, dtype
+) -> dict[str, jnp.ndarray]:
+    """One ``mimo_v2`` layer in the layout of ``models/mimo_v2.py``; the
+    layer's kind is ``cfg.layer_kinds[i]``. The tensor NAMES are those of the
+    published ``attention_projection_layout: fused_qkv`` as this sandbox
+    could read them (no network: not checked against a checkpoint) —
+    ``self_attn.qkv_proj`` ``[Hq·Dk + Hkv·Dk + Hkv·Dv, H]`` (q heads, k heads,
+    v heads; transposed to ``[in, out]``, nothing re-laid),
+    ``self_attn.attention_sink_bias`` ``[Hq]`` where the layer's kind has a
+    sink, ``self_attn.o_proj``, the two layer norms, and the feed-forward as
+    ``deepseek_v3`` names it (``mlp.gate`` + ``e_score_correction_bias``,
+    ``mlp.experts.{e}.*``; a dense layer's ``mlp.*_proj``). Of the routed
+    experts only those this chip HOLDS are read (``cfg.held_experts_``)."""
+    from ..models.mimo_v2 import attn_of, has_sink
+
+    pre = f"model.layers.{i}."
+    kind = cfg.layer_kinds[i]
+
+    def raw(name):  # torch Linear stores [out, in]; we use [in, out]
+        return np.asarray(get(pre + name + ".weight")).T
+
+    def arr(x):
+        return jnp.asarray(x, dtype)
+
+    p = {
+        "input_norm": arr(get(pre + "input_layernorm.weight")),
+        "wqkv": arr(raw("self_attn.qkv_proj")),
+        "wo": arr(raw("self_attn.o_proj")),
+        "post_norm": arr(get(pre + "post_attention_layernorm.weight")),
+    }
+    if has_sink(cfg, attn_of(kind)):
+        p["sink"] = jnp.asarray(
+            get(pre + "self_attn.attention_sink_bias"), jnp.float32
+        )
+    if kind.startswith("dense"):
+        p.update(
+            w_gate=arr(raw("mlp.gate_proj")), w_up=arr(raw("mlp.up_proj")),
+            w_down=arr(raw("mlp.down_proj")),
+        )
+        return p
+    first, count = cfg.held_experts_
+    held = range(first, first + count)
+
+    def experts(name, axis):
+        return arr(np.concatenate(
+            [raw(f"mlp.experts.{e}.{name}") for e in held], axis=axis
+        ))
+
+    p.update(
+        router=arr(raw("mlp.gate")),
+        router_bias=jnp.asarray(
+            get(pre + "mlp.gate.e_score_correction_bias"), jnp.float32
+        ),
+        we_gate=experts("gate_proj", 1), we_up=experts("up_proj", 1),
+        we_down=experts("down_proj", 0),
+    )
+    return p
+
+
 def gpt2_layer_arrays(
     cfg: ModelConfig, get: TensorGetter, i: int, dtype
 ) -> dict[str, jnp.ndarray]:
@@ -243,16 +303,20 @@ def params_from_hf(
         # tied: no duplicate vocab×hidden buffer — final_logits contracts
         # against the embedding table (see models/llama.py:final_logits)
         return params
-    elif cfg.model_type == "deepseek_v3":
+    elif cfg.model_type in ("deepseek_v3", "mimo_v2"):
         # one stack per kind, in layer order (cfg.layer_kinds); the
         # vocabulary tables keep the rows held here (rows 0..vocab_size-1)
         kinds = cfg.layer_kinds
         V = cfg.vocab_size
+        layer_arrays = (
+            deepseek_layer_arrays if cfg.model_type == "deepseek_v3"
+            else mimo_layer_arrays
+        )
         return {
             "embed": jnp.asarray(get("model.embed_tokens.weight")[:V], dtype),
             "layers": {
                 kind: _stack([
-                    deepseek_layer_arrays(cfg, get, i, dtype)
+                    layer_arrays(cfg, get, i, dtype)
                     for i in range(cfg.num_hidden_layers) if kinds[i] == kind
                 ])
                 for kind in dict.fromkeys(kinds)

@@ -47,6 +47,7 @@ from .convert import (
     TensorGetter,
     _getter,
     deepseek_layer_arrays,
+    mimo_layer_arrays,
     gpt2_layer_arrays,
     llama_layer_arrays,
 )
@@ -308,7 +309,7 @@ def save_shards_streaming(
 
     layer_fn = {
         "llama": llama_layer_arrays, "gpt2": gpt2_layer_arrays,
-        "deepseek_v3": deepseek_layer_arrays,
+        "deepseek_v3": deepseek_layer_arrays, "mimo_v2": mimo_layer_arrays,
     }[cfg.model_type]
     for i in range(cfg.num_hidden_layers):
         block = layer_fn(cfg, get, i, dtype)
@@ -316,8 +317,8 @@ def save_shards_streaming(
             block = quantize_layer_params(block, bits=quant_bits)
         _save_npz(os.path.join(out_dir, f"block_{i}.npz"), block)
 
-    if cfg.model_type in ("llama", "deepseek_v3"):
-        # deepseek_v3 may hold a SLICE of the vocabulary: rows 0..V-1
+    if cfg.model_type in ("llama", "deepseek_v3", "mimo_v2"):
+        # deepseek_v3 / mimo_v2 may hold a SLICE of the vocabulary: rows 0..V-1
         V = cfg.vocab_size
         embed = jnp.asarray(get("model.embed_tokens.weight")[:V], dtype)
         _save_npz(

@@ -252,6 +252,69 @@ def test_prefetch_error_names_its_chunk():
     assert REGISTRY.get("server_fetch_failures_total").value == before + 1
 
 
+class _Slow:
+    """A device array in miniature: ready when told, copied when read."""
+
+    def __init__(self, value, fail=0):
+        self.value, self.fail = value, fail
+        self.ready = self.began = False
+        self.reads = 0
+
+    def copy_to_host_async(self):
+        self.began = True
+
+    def is_ready(self):
+        return self.ready
+
+    def __array__(self, *a, **k):
+        self.reads += 1
+        if self.reads <= self.fail:
+            raise OSError("connection dropped")
+        return np.asarray(self.value)
+
+
+def test_a_direct_read_begins_at_dispatch_and_never_waits_to_say_landed():
+    """The serial step's way: the copy begins when the read is made, a look
+    at it reads nothing while the device works, and the first look after
+    the device is done finishes it and stamps when."""
+    from llm_sharding_tpu.runtime.server import _Prefetched
+
+    h = _Slow([3, 1, 4])
+    p = _Prefetched(h, tag="chunk m0=4", direct=True)
+    assert h.began and p.event is None
+    assert not p.landed() and h.reads == 0 and p.done_at is None
+    h.ready = True
+    assert p.landed() and h.reads == 1 and p.done_at is not None
+    assert list(p.get()) == [3, 1, 4] and p.handle is None
+    p.wait()  # landed: nothing more to read
+    assert h.reads == 1
+
+
+def test_a_direct_read_waits_on_its_own_thread():
+    from llm_sharding_tpu.runtime.server import _Prefetched
+
+    h = _Slow([7])
+    p = _Prefetched(h, tag="admit slot=0", direct=True)
+    assert list(p.get_retryable()) == [7] and h.reads == 1  # not ready: it waits
+    assert p.landed() and p.done_at is not None
+
+
+def test_a_failed_direct_read_keeps_its_handle_and_names_its_chunk():
+    from llm_sharding_tpu.runtime.server import _Prefetched
+
+    before = REGISTRY.get("server_fetch_failures_total").value
+    h = _Slow([1, 2], fail=2)
+    p = _Prefetched(h, tag="chunk m0=17", direct=True)
+    h.ready = True
+    assert p.landed() and p.error is not None and p.handle is h
+    assert REGISTRY.get("server_fetch_failures_total").value == before + 1
+    with pytest.raises(RuntimeError, match=r"chunk m0=17"):
+        p.get()
+    with pytest.raises(RuntimeError, match=r"retry failed for chunk m0=17"):
+        p.get_retryable()  # the second failure, re-issued from the handle
+    assert list(p.get_retryable()) == [1, 2] and p.error is None
+
+
 # ------------------------------------------------------ live serve telemetry
 
 

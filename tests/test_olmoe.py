@@ -220,6 +220,39 @@ def test_counters_count_live_rows_and_real_positions_only(served_one_stage):
     assert 0 < REGISTRY.get("server_moe_experts_read").value <= 2 * k
 
 
+def test_a_decoding_step_counts_the_log_before_it_waits_for_the_next(params):
+    """What a log carries beside its tokens is counted by the NEXT step,
+    while the device works and before that step waits (the tokens alone lie
+    between a log's landing and a reader's eyes): in steady decode the
+    series run one log behind the tokens; an idle server's are whole; the
+    serial step reads its logs on its own thread."""
+    from llm_sharding_tpu.obs.metrics import REGISTRY
+
+    fam = REGISTRY.get("server_moe_expert_tokens_total")
+    total = lambda: sum(c.value for _, c in fam.series())
+    eng = PipelineEngine(CFG, params, num_stages=1, devices=jax.devices()[:1],
+                         cache_dtype=jnp.float32)
+    srv = eng.serve(capacity=128, batch_per_slot=2, kv_block_size=8,
+                    kv_blocks=65, prefill_chunk=16, prefix_cache="hbm")
+    assert srv._prefetcher is None
+    k, L = 2, 2
+    t0 = total()
+    req = srv.submit(PROMPTS[0], NEW)
+    while len(req.tokens) < 4:
+        srv.step()
+    assert srv._pending and srv._pending[-1][1].event is None
+    assert len(srv._parked_counts) == 1  # the log whose token just surfaced
+    seen = total() - t0
+    srv.step()  # one more token: the parked log is counted, the next parked
+    assert len(srv._parked_counts) == 1 and total() - t0 == seen + k * L
+    srv.run_until_idle()
+    assert not srv._parked_counts
+    assert total() - t0 == (len(PROMPTS[0]) + NEW - 1) * k * L
+    recs = srv.stepline_snapshot(10_000)
+    assert sum(sum(r.get("expert_tokens", ())) for r in recs) == total() - t0
+    srv.close()
+
+
 def test_int8_experts_round_trip(params):
     layers = quantize_layer_params(dict(params["layers"]))
     for name in ("we_gate", "we_up", "we_down", "wq"):

@@ -174,10 +174,17 @@ def test_state_specs_field_parity(setup):
         )
         specs = serve_ops.state_specs(state)
         assert state._fields == specs._fields
-        for name, spec in specs._asdict().items():
+        # a windowed model's second KV state (k_swa / v_swa / tables_swa) is
+        # None — an empty pytree, no operand of any program — for every
+        # other model, in the state and in its specs alike
+        live = {k: v for k, v in specs._asdict().items() if v is not None}
+        assert set(specs._fields) - set(live) == {
+            "k_swa", "v_swa", "tables_swa"}
+        assert all(getattr(state, k) is None for k in set(specs._fields) - set(live))
+        for name, spec in live.items():
             assert isinstance(spec, jax.sharding.PartitionSpec), name
         # one spec leaf per state leaf (the shard_map in/out contract)
-        assert len(jax.tree.leaves(state)) == len(specs._fields)
+        assert len(jax.tree.leaves(state)) == len(live)
         # block table leaf exists in BOTH modes (dense: [M,1] placeholder)
         # so the pytree shape — and with it snapshots — is mode-independent
         assert state.block_tables.ndim == 2
@@ -1293,6 +1300,36 @@ def test_a_decode_step_reads_its_weights_as_they_are_stored(v5e_host, cell):
     assert _weight_stack_relayouts(text) == []
     dots, windowed = _windowed_projections(text)
     assert len(dots) >= 3 and windowed == []
+
+
+def test_a_windowed_models_step_programs_compile_and_read_weights_as_stored(
+        v5e_host):
+    """``mimo_v25`` (a KV state per kind of attention layer, which
+    ``aot_check.py`` cannot describe: ``benchmark/tests/aot_windowed.py``
+    makes the state as the server does): the decode program and the chunked
+    prefill compile for the described v5e — both paged kernels with a lower
+    bound on their walk, a sink operand, keys of 256 lanes and values of 128
+    — and the decode step re-lays no weight stack: the fused qkv projection
+    leaves its dot through a barrier, and the runs take each layer out of its
+    kind's stack inside the scan."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "aot_windowed", os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "tests",
+            "aot_windowed.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    with jax.default_matmul_precision("default"):
+        texts = mod.check("mimo_v25", chunks=(256,), texts=True)
+    decode = texts["serve_chunk"]
+    assert _weight_stack_relayouts(decode) == []
+    dots, windowed = _windowed_projections(decode)
+    assert len(dots) >= 3 and windowed == []
+    # five runs of one kind: an attention kernel each, an expert kernel in four
+    assert decode.count("tpu_custom_call") == 9
+    assert "paged_decode" in decode and "paged_prefill" in texts[
+        "serve_prefill_chunk[256]"]
 
 
 def test_the_compiled_program_guard_sees_a_transposed_weight_stack():
