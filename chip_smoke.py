@@ -33,8 +33,11 @@ forms are the same script: ``--stages 4``, ``--weights bf16``,
 ``--moe`` runs one check only and no daemon: the expert kernel of
 ``ops/moe.py`` (``moe_experts``) against its XLA path at OLMoE-1B-7B's
 published widths, in the decode regime (4 rows) and the grouped prefill
-regime (1,024 positions), a third of the rows dead; the last line is then
-``{"ok": true, "moe": [...], "device": {...}}``.
+regime (1,024 positions), a third of the rows dead; then it TIMES a decode
+call (one live row of four) that meets 0, 1 and 8 experts at OLMoE's and at
+GigaChat's shapes, microseconds a call: the call's fixed part and what one
+more expert costs. The last line is then ``{"ok": true, "moe": [...],
+"moe_us_per_call": [...], "device": {...}}``.
 """
 
 from __future__ import annotations
@@ -336,6 +339,18 @@ def check_kernel(cfg, case: dict, backend: str, seed: int = 0) -> float:
     return float(np.abs(got - np.asarray(want.astype(jnp.float32))).max())
 
 
+def best_of_three(run, operands) -> float:
+    """Seconds of the fastest of three calls of a program that is warm."""
+    import jax
+
+    best = float("inf")
+    for _ in range(3):
+        t0 = time.perf_counter()
+        jax.block_until_ready(run(*operands))
+        best = min(best, time.perf_counter() - t0)
+    return best
+
+
 def time_decode(cfg, case: dict, backend: str, calls: int = 64) -> dict:
     """Milliseconds per call of the paged decode op through ``backend`` and
     through the XLA path on one case's operands: ``calls`` calls inside ONE
@@ -363,12 +378,7 @@ def time_decode(cfg, case: dict, backend: str, calls: int = 64) -> dict:
 
         operands = (q, k_arena, v_arena, table, qpos, kvpos, scales)
         run(*operands).block_until_ready()
-        best = float("inf")
-        for _ in range(3):
-            t0 = time.perf_counter()
-            run(*operands).block_until_ready()
-            best = min(best, time.perf_counter() - t0)
-        return round(best / calls * 1e3, 4)
+        return round(best_of_three(run, operands) / calls * 1e3, 4)
 
     return {"kernel_ms": timed(backend), "xla_ms": timed("xla")}
 
@@ -431,6 +441,80 @@ def check_moe_kernel(cfg, rows: int, backend: str, seed: int = 0) -> float:
     return float(np.abs(got - want).max() / np.abs(want).max())
 
 
+#: a decode call's shapes, timed: the hidden and expert widths, the experts
+#: the router scores, the share of them held here (None: all)
+MOE_TIMED = (
+    {"name": "olmoe_1b_7b", "H": 2048, "F": 1024, "E": 64, "held": None},
+    {"name": "gigachat31_702b_a36b", "H": 7168, "F": 2048, "E": 256,
+     "held": (0, 16)},
+)
+MOE_MET = (0, 1, 8)
+
+
+def time_moe(shape: dict, met: int, backend: str, calls: int = 64,
+             k: int = 8) -> float:
+    """Microseconds per call of ``expert_mlp`` through ``backend`` with ONE
+    live row of four whose ``k`` choices meet ``met`` distinct held experts
+    (the rest of them repeat the first, or fall on experts held elsewhere;
+    0: no row routes) — the tiles' building, the kernel and the combine, as
+    a decode step pays them. ``calls`` calls inside ONE program over a stack
+    of two layers, each call's input hanging on the one before, warmed up,
+    best of three."""
+    import jax
+    import jax.numpy as jnp
+
+    import llm_sharding_tpu.models  # noqa: F401 — ops import through models
+    from llm_sharding_tpu.ops import moe
+    from llm_sharding_tpu.ops.quant import QTensor
+
+    H, F, E, L = shape["H"], shape["F"], shape["E"], 2
+    first, count = shape["held"] or (0, E)
+    ks = jax.random.split(jax.random.key(met), 5)
+    dt = jnp.bfloat16
+
+    def leaf(key, rows, cols):
+        return QTensor(
+            jax.random.randint(key, (L, rows, cols), -127, 128, jnp.int8),
+            jnp.full((L, cols), rows ** -0.5 / 64.0, dt),
+        )
+
+    wg, wu = leaf(ks[0], H, count * F), leaf(ks[1], H, count * F)
+    wd = leaf(ks[2], count * F, H)
+    x = jax.random.normal(ks[3], (4, H), jnp.float32).astype(dt)
+    # a row's choices: ``met`` distinct held experts, the rest a repeat of
+    # the first (every expert is held here) or an expert held elsewhere
+    other = first if shape["held"] is None else first + count
+    ids = jnp.asarray(
+        [[first + j if j < met else other for j in range(k)]] * 4, jnp.int32
+    )
+    w = jnp.full((4, k), 1.0 / k, jnp.float32)
+    live = jnp.asarray([met > 0, False, False, False])
+    layers = jnp.arange(calls, dtype=jnp.int32) % L
+
+    @jax.jit
+    def run(x, w, ids, live, wg, wu, wd):
+        def one(carry, layer):
+            total, read = carry
+            # hangs on the call before: nothing is hoisted out of the loop
+            tied = (total * 0.0).astype(jnp.int32)
+            out, st = moe.expert_mlp(
+                x + tied.astype(dt), w, ids + tied, wg, wu, wd, E, live=live,
+                layer=layer, backend=backend, held=shape["held"],
+            )
+            total = total + out.astype(jnp.float32).sum()
+            return (total, read + st.experts_read), None
+        return jax.lax.scan(one, (jnp.float32(0), jnp.int32(0)), layers)[0]
+
+    operands = (x, w, ids, live, wg, wu, wd)
+    _, read = run(*operands)
+    if int(read) != met * calls:
+        raise AssertionError(
+            f"{shape['name']}: {int(read)} experts read in {calls} calls "
+            f"that should meet {met} each"
+        )
+    return round(best_of_three(run, operands) / calls * 1e6, 2)
+
+
 def child_moe(spec: dict, out_path: str) -> None:
     import jax
 
@@ -449,8 +533,16 @@ def child_moe(spec: dict, out_path: str) -> None:
         print(f"[moe] moe_experts rows={rows:<5d} max rel err={err:.3e} "
               f"{'ok' if err <= KERNEL_TOL else 'OVER ' + str(KERNEL_TOL)}",
               flush=True)
+    timed = []
+    for shape in MOE_TIMED:
+        us = {met: time_moe(shape, met, "kernel") for met in MOE_MET}
+        timed.append({"shape": shape["name"], "us_per_call": us})
+        print(f"[moe] moe call {shape['name']}: "
+              + ", ".join(f"{m} met {u} us" for m, u in us.items()),
+              flush=True)
     with open(out_path, "w") as f:
-        json.dump({"device": device_report(), "kernels": results}, f)
+        json.dump({"device": device_report(), "kernels": results,
+                   "moe_us_per_call": timed}, f)
     if not all(r["ok"] for r in results):
         raise SystemExit("chip_smoke: the expert kernel disagrees with XLA")
 
@@ -885,6 +977,7 @@ def main(argv=None) -> int:
             "moe", {"preset": "olmoe_1b_7b", "overrides": {}},
             dict(os.environ, PYTHONPATH=HERE), "moe.log"))
         print(json.dumps({"ok": True, "moe": got["kernels"],
+                          "moe_us_per_call": got["moe_us_per_call"],
                           "device": got["device"]}))
         return 0
 

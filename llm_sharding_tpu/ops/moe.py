@@ -29,11 +29,22 @@ regimes build the tiles, one kernel runs them:
 
 The Pallas kernel takes the whole LAYER-STACKED weights and reads the
 ``(H, F)`` / ``(F, H)`` int8 tiles of expert ``tile_expert[i]`` of layer
-``layer`` through scalar-prefetched indices — the stack is never sliced,
-and a tile past the live count re-names the last live tile's blocks, so it
-costs no DMA and its compute is skipped. The XLA path (``backend="xla"``:
-the CPU tests, and the dense-cache oracle paths) gathers the same tiles and
-does the same arithmetic.
+``layer`` through scalar-prefetched indices — the stack is never sliced.
+Its grid is ``(live tiles, slices of an expert's width)`` and the FIRST
+extent is traced: a call walks its ``n_live`` tiles and ends where they do
+(a grid step costs the pipeline's bookkeeping for seven operands even when
+nothing is fetched: a tile is skipped by not being in the grid). Two rules
+follow. The pipeline evaluates the index maps one step AHEAD of the one it
+runs, so ``Tiles.expert`` and ``Tiles.row`` hold one entry more than the
+most tiles there can be. And a tile past ``n_live`` is NOT WRITTEN: both
+combines select the live tiles (or pairs) before they weigh them — zero
+times what a buffer happened to hold is not zero. A call with no live tile
+would still walk one tile's slices and fetch that expert to compute nothing;
+where the leaves hold a SHARE of the experts most calls of a row or two are
+such calls, and the decode regime branches around the kernel for them
+(``lax.cond`` on ``n_live``). The XLA path (``backend="xla"``: the CPU
+tests, and the dense-cache oracle paths) gathers the same tiles and does the
+same arithmetic.
 
 **Dead rows and pad positions route nowhere** (``live``): they form no pair,
 are neither read for nor counted, and get a zero MLP output. No token is
@@ -146,18 +157,11 @@ class Tiles(NamedTuple):
     """Row tiles for the expert product."""
 
     x: jax.Array  # [R, tm, H] distinct row tiles
-    row: jax.Array  # [NT] int32 which row tile each tile reads
-    expert: jax.Array  # [NT] int32 the tile's expert
+    # one entry more than the most tiles (NT): the kernel's index maps are
+    # evaluated one grid step ahead of the step that runs
+    row: jax.Array  # [NT + 1] int32 which row tile each tile reads
+    expert: jax.Array  # [NT + 1] int32 the tile's expert
     n_live: jax.Array  # scalar int32 tiles before this index are real
-
-
-def _clamp_tail(values, n_live):
-    """Tiles past ``n_live`` name what the last live tile names (no new DMA)."""
-    idx = jnp.minimum(
-        jnp.arange(values.shape[0], dtype=jnp.int32),
-        jnp.maximum(n_live - 1, 0),
-    )
-    return values[idx]
 
 
 def _decode_tiles(x, w, ids, live, E):
@@ -175,13 +179,12 @@ def _decode_tiles(x, w, ids, live, E):
     n_live = jnp.sum(hit).astype(jnp.int32)
     NT = min(E, N * k)
     # the hit experts first, in ascending order (a stable sort of ~hit)
-    order = jnp.argsort(~hit, stable=True).astype(jnp.int32)[:NT]
-    expert = _clamp_tail(order, n_live)
-    alive = jnp.arange(NT, dtype=jnp.int32) < n_live
-    cw = jnp.where(alive[:, None], comb.T[expert], 0.0)  # [NT, N]
+    order = jnp.argsort(~hit, stable=True).astype(jnp.int32)
+    expert = order[jnp.minimum(jnp.arange(NT + 1, dtype=jnp.int32), E - 1)]
+    cw = comb.T[expert[:NT]]  # [NT, N]; no row chose a tile past n_live: 0
     pad = -N % 8
     xt = jnp.pad(x, ((0, pad), (0, 0)))[None]  # [1, N + pad, H]
-    tiles = Tiles(xt, jnp.zeros((NT,), jnp.int32), expert, n_live)
+    tiles = Tiles(xt, jnp.zeros((NT + 1,), jnp.int32), expert, n_live)
     return tiles, cw, counts
 
 
@@ -189,7 +192,7 @@ def _grouped_tiles(x, ids, live, E, tm):
     """(token, expert) pairs sorted by expert, each expert's run padded to
     whole tiles of ``tm`` rows. Returns ``(tiles, pos [N, k], counts [E])``:
     ``pos`` is each pair's row in the flattened tile output (dead pairs
-    point at a row whose weight the caller zeroes)."""
+    point at a row the caller does not select)."""
     N, H = x.shape
     k = ids.shape[1]
     P = N * k
@@ -220,14 +223,12 @@ def _grouped_tiles(x, ids, live, E, tm):
     pos = jnp.zeros((P,), jnp.int32).at[order].set(
         jnp.minimum(pos_sorted, NT * tm - 1)
     ).reshape(N, k)
+    ahead = jnp.arange(NT + 1, dtype=jnp.int32)
     expert = jnp.minimum(
-        jnp.searchsorted(
-            tile_end, jnp.arange(NT, dtype=jnp.int32), side="right"
-        ).astype(jnp.int32),
+        jnp.searchsorted(tile_end, ahead, side="right").astype(jnp.int32),
         E - 1,
     )
-    expert = _clamp_tail(expert, n_live)
-    tiles = Tiles(xt, jnp.arange(NT, dtype=jnp.int32), expert, n_live)
+    tiles = Tiles(xt, jnp.minimum(ahead, NT - 1), expert, n_live)
     return tiles, pos, counts
 
 
@@ -248,21 +249,22 @@ def _leaf(w, layer):
 
 def _tiles_xla(tiles: Tiles, layer, wg, wu, wd, sg, su, E, out_dtype):
     """The kernel's arithmetic in plain XLA: gather each tile's expert."""
-    x = tiles.x[tiles.row]  # [NT, tm, H]
+    row, expert = tiles.row[:-1], tiles.expert[:-1]  # the NT tiles
+    x = tiles.x[row]  # [NT, tm, H]
     H = x.shape[-1]
     F = wg.shape[-1] // E
 
     def cols(w, s):  # [L, H, E·F] → the tiles' [NT, H, F]
         wl = jax.lax.dynamic_index_in_dim(w, layer, keepdims=False)
-        wt = jnp.moveaxis(wl.reshape(H, E, F), 1, 0)[tiles.expert]
+        wt = jnp.moveaxis(wl.reshape(H, E, F), 1, 0)[expert]
         sl = jax.lax.dynamic_index_in_dim(s, layer, keepdims=False)
-        return wt.astype(x.dtype), sl.reshape(E, F)[tiles.expert][:, None, :]
+        return wt.astype(x.dtype), sl.reshape(E, F)[expert][:, None, :]
 
     g_w, g_s = cols(wg, sg)
     u_w, u_s = cols(wu, su)
     d_w = jax.lax.dynamic_index_in_dim(wd, layer, keepdims=False).reshape(
         E, F, H
-    )[tiles.expert].astype(x.dtype)
+    )[expert].astype(x.dtype)
     f32 = jnp.float32
     g = jnp.einsum("jth,jhf->jtf", x, g_w, preferred_element_type=f32) * g_s
     u = jnp.einsum("jth,jhf->jtf", x, u_w, preferred_element_type=f32) * u_s
@@ -303,12 +305,16 @@ def _expert_kernel(lyr, texp, trow, nlive, x_ref, wg_ref, wu_ref, wd_ref,
 @functools.partial(jax.jit, static_argnames=("E", "out_dtype", "interpret"))
 def expert_tiles_tpu(tiles: Tiles, layer, wg, wu, wd, sg, su, *, E: int,
                      out_dtype, interpret: bool = False):
-    """The Pallas expert product: grid ``(tiles, F / F_CHUNK)``, the second
+    """The Pallas expert product: grid ``(max(n_live, 1), F / F_CHUNK)``, the
+    first extent TRACED (Mosaic takes a traced extent on either axis; two
+    axes keep a step's tile and slice the grid's own indices), the second
     axis accumulating a tile's output over slices of the expert's width.
-    Weights are the layer-stacked ``[L, H, E·F]`` / ``[L, E·F, H]`` arrays,
-    read where they lie."""
+    Returns ``[NT, tm, H]`` of which only the tiles before ``n_live`` are
+    WRITTEN (with none live, one tile of zeros). Weights are the
+    layer-stacked ``[L, H, E·F]`` / ``[L, E·F, H]`` arrays, read where they
+    lie."""
     R, tm, H = tiles.x.shape
-    NT = tiles.row.shape[0]
+    NT = tiles.row.shape[0] - 1
     F = wg.shape[-1] // E
     fc = min(F, f_chunk(H))
     if F % fc:
@@ -320,23 +326,22 @@ def expert_tiles_tpu(tiles: Tiles, layer, wg, wu, wd, sg, su, *, E: int,
     sg2, su2 = (
         jax.lax.dynamic_index_in_dim(s, layer, keepdims=True) for s in (sg, su)
     )
-    def fblock(i, f, texp, nlive):
-        # a dead tile stays on the last block the last live tile fetched
-        f_eff = jnp.where(i < nlive[0], f, n_f - 1)
-        return texp[i] * n_f + f_eff
+    def fblock(i, f, texp):  # slice f of tile i's expert
+        return texp[i] * n_f + f
 
     def col_map(i, f, lyr, texp, trow, nlive):
-        return (lyr[0], 0, fblock(i, f, texp, nlive))
+        return (lyr[0], 0, fblock(i, f, texp))
 
     def scale_map(i, f, lyr, texp, trow, nlive):
-        return (0, fblock(i, f, texp, nlive))
+        return (0, fblock(i, f, texp))
 
     def row_map(i, f, lyr, texp, trow, nlive):
-        return (lyr[0], fblock(i, f, texp, nlive), 0)
+        return (lyr[0], fblock(i, f, texp), 0)
 
+    n_live = jnp.reshape(tiles.n_live, (1,))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(NT, n_f),
+        grid=(jnp.maximum(n_live[0], 1), n_f),
         in_specs=[
             pl.BlockSpec(
                 (None, tm, H),
@@ -349,7 +354,10 @@ def expert_tiles_tpu(tiles: Tiles, layer, wg, wu, wd, sg, su, *, E: int,
             pl.BlockSpec((1, fc), scale_map),
         ],
         out_specs=pl.BlockSpec(
-            (None, tm, H), lambda i, f, lyr, texp, trow, nlive: (i, 0, 0)
+            (None, tm, H),  # (the step looked at ahead may be tile NT)
+            lambda i, f, lyr, texp, trow, nlive: (
+                jnp.minimum(i, NT - 1), 0, 0
+            ),
         ),
         scratch_shapes=[pltpu.VMEM((tm, H), jnp.float32)],
     )
@@ -365,7 +373,7 @@ def expert_tiles_tpu(tiles: Tiles, layer, wg, wu, wd, sg, su, *, E: int,
         name="moe_experts",
     )(
         jnp.reshape(layer, (1,)).astype(jnp.int32), tiles.expert, tiles.row,
-        jnp.reshape(tiles.n_live, (1,)), tiles.x, wg, wu, wd, sg2, su2,
+        n_live, tiles.x, wg, wu, wd, sg2, su2,
     )
 
 
@@ -435,22 +443,43 @@ def expert_mlp(
         else:
             tiles, pos, counts = _grouped_tiles(x, ids, live, E, TILE_ROWS)
         out_dtype = jnp.float32 if decode else x.dtype
-        if backend == "xla":
-            y = _tiles_xla(tiles, lyr, wg, wu, wd, sg, su, E, out_dtype)
-        else:
-            y = expert_tiles_tpu(
+
+        def product():
+            if backend == "xla":
+                return _tiles_xla(tiles, lyr, wg, wu, wd, sg, su, E, out_dtype)
+            return expert_tiles_tpu(
                 tiles, lyr, wg, wu, wd, sg, su, E=E, out_dtype=out_dtype,
                 interpret=backend == "interpret",
             )
+
+        # the kernel leaves the tiles past n_live unwritten: select what is
+        # live, THEN weigh it (0 x whatever the buffer held is not 0)
         if decode:
-            out = jnp.einsum(
-                "jn,jnh->nh", cw, y[:, :N].astype(jnp.float32),
-                precision=jax.lax.Precision.HIGHEST,
-            )
+            def combined():
+                alive = jnp.arange(cw.shape[0], dtype=jnp.int32) < tiles.n_live
+                return jnp.einsum(
+                    "jn,jnh->nh", cw,
+                    jnp.where(alive[:, None, None], product()[:, :N], 0.0),
+                    precision=jax.lax.Precision.HIGHEST,
+                )
+
+            if routed is None:
+                out = combined()
+            else:
+                # a share of the experts: most calls of a row or two meet
+                # none of them (without a share a live row always meets
+                # one), and a call launched for nothing still fetches an
+                # expert; the branch costs a call that runs ~2 us
+                out = jax.lax.cond(
+                    tiles.n_live > 0, combined,
+                    lambda: jnp.zeros((N, H), jnp.float32),
+                )
         else:
-            picked = y.reshape(-1, H)[pos].astype(jnp.float32)  # [N, k, H]
-            w_live = jnp.where(live[:, None], weights, 0.0)
-            out = jnp.sum(picked * w_live[:, :, None], axis=1)
+            picked = product().reshape(-1, H)[pos].astype(jnp.float32)
+            paired = (live[:, None] & (ids < E))[:, :, None]  # in a live tile
+            out = jnp.sum(
+                jnp.where(paired, picked * weights[:, :, None], 0.0), axis=1
+            )  # [N, k, H] → [N, H]
         # we_down's scale: one per output channel, every expert's alike
         out = out * jax.lax.dynamic_index_in_dim(
             sd, lyr, keepdims=False

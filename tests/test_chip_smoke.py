@@ -102,6 +102,16 @@ def test_expert_kernel_check_runs_in_interpret_mode(rows):
     assert err <= chip_smoke.KERNEL_TOL, (rows, err)
 
 
+@pytest.mark.parametrize("held", [None, (0, 8)])
+@pytest.mark.parametrize("met", chip_smoke.MOE_MET)
+def test_expert_call_timing_meets_the_experts_it_says(met, held):
+    """``chip_smoke.py --moe``'s timing of a decode call at toy widths: one
+    live row meets 0, 1 and 8 experts, all held here or a share of them (the
+    call itself checks what it read); a CPU time is no speed."""
+    shape = {"name": "toy", "H": 64, "F": 128, "E": 16, "held": held}
+    assert chip_smoke.time_moe(shape, met, "interpret", calls=2) > 0
+
+
 def test_store_writer_driver_and_assertions_on_cpu(tmp_path, monkeypatch):
     """The smoke's daemon phase end to end at toy size: seeded store through
     the product's writer, the real ``serve`` daemon as a child, the smoke's

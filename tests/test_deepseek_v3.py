@@ -304,15 +304,17 @@ def test_what_is_not_done_is_refused_by_name(params):
 # guard that the accepted configurations' programs are what they were. A PR
 # that changes a one-kind program on purpose re-records them and says so:
 # PR 36 re-recorded both ``serve_prefill_chunk`` (the prefill kernel's grid is
-# its live cells, the program returns the walk's counters); ``serve_chunk``
-# and ``serve_admit`` are still the parent of PR 34's.
+# its live cells, the program returns the walk's counters); PR 40 re-recorded
+# the three of ``olmoe`` (the expert kernel's grid is its live tiles, the
+# combines select them); ``qwen2``'s ``serve_chunk`` and ``serve_admit`` are
+# still the parent of PR 34's.
 GOLDEN = {
     ("qwen2", "serve_admit"): "41a2afe52004928f",
     ("qwen2", "serve_chunk"): "51598fb15a9f1c1a",
     ("qwen2", "serve_prefill_chunk"): "ae47930ef924992e",
-    ("olmoe", "serve_admit"): "12d7e0faf495e8ff",
-    ("olmoe", "serve_chunk"): "17d456dda6d44557",
-    ("olmoe", "serve_prefill_chunk"): "404d9b0e5b495843",
+    ("olmoe", "serve_admit"): "ff5a01947e2fda27",
+    ("olmoe", "serve_chunk"): "3fdd88d900928e52",
+    ("olmoe", "serve_prefill_chunk"): "9b6331278d0d9382",
 }
 
 

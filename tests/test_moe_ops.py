@@ -2,7 +2,10 @@
 regimes, the Pallas kernel in interpret mode and the XLA path of the same
 arithmetic, against the DENSE form (every expert computed, the unchosen
 multiplied by zero) — over experts, k, rows with dead rows, experts repeated
-across rows, raw and int8 weights, a layer of a stack that is not the first."""
+across rows, raw and int8 weights, a layer of a stack that is not the first;
+and over how many of a call's tiles are live (none, one, every one, a chunk
+whose pairs all fall on experts held elsewhere): the kernel's grid ends where
+the live tiles do and what lies past them is never written."""
 
 import numpy as np
 import pytest
@@ -14,12 +17,15 @@ from llm_sharding_tpu.ops import moe
 from llm_sharding_tpu.ops.quant import QTensor, dequantize, quantize_tensor
 
 
-def dense_form(x, weights, ids, wg, wu, wd, E, live):
+def dense_form(x, weights, ids, wg, wu, wd, E, live, held=None):
     """Σ_e p_e · (silu(x Wg_e) ⊙ (x Wu_e)) Wd_e with p_e = 0 off the kept
-    set, all experts computed; zero for dead rows."""
-    N, F = x.shape[0], wg.shape[-1] // E
+    set, all experts computed; zero for dead rows. ``held = (first, count)``:
+    the weights are those ``count`` of the ``E`` experts, the sum over them."""
+    first, count = held or (0, E)
+    N, F = x.shape[0], wg.shape[-1] // count
     mask = jnp.zeros((N, E), jnp.float32).at[
-        jnp.arange(N)[:, None], ids].add(weights)
+        jnp.arange(N)[:, None], ids].add(weights)[:, first:first + count]
+    E = count
     hp = jax.lax.Precision.HIGHEST
     g = jnp.dot(x, wg, precision=hp).reshape(N, E, F)
     u = jnp.dot(x, wu, precision=hp).reshape(N, E, F)
@@ -52,28 +58,102 @@ CASES = [
     (100, 8, 2, 32, 64, 2, False),  # grouped: more rows than DECODE_ROWS_MAX
     (300, 16, 4, 128, 128, 2, True),  # grouped, several tiles an expert
     (40, 64, 8, 16, 64, 1, False),  # grouped, most experts under one tile
+    # how many tiles are live — (rows that route, the experts they may
+    # choose, the experts held here, the experts read); the kernel's grid is
+    # the live tiles
+    (4, 8, 2, 32, 64, 2, False, "none", None, None, 0),  # decode: no tile
+    (4, 8, 1, 32, 64, 2, True, "all", (5, 6), None, 1),  # decode: ONE tile
+    (4, 8, 2, 32, 64, 2, True, "all", None, None, 8),  # decode: all N·k tiles
+    (4, 16, 8, 32, 64, 1, False, "all", None, None, 16),  # decode: all E
+    (4, 16, 2, 32, 64, 2, False, "some", (0, 8), (8, 8), 0),  # all elsewhere
+    (4, 16, 4, 32, 64, 2, True, "all", None, (4, 8), 8),  # a share, all met
+    (100, 16, 2, 32, 64, 2, True, "some", (0, 8), (8, 8), 0),  # grouped: same
+    (100, 16, 4, 32, 64, 2, False, "some", None, (4, 8), None),  # a share
+    (100, 8, 2, 32, 64, 1, False, "none", None, None, 0),  # grouped: no row
 ]
 
 
-@pytest.mark.parametrize("backend", ["xla", "interpret"])
-@pytest.mark.parametrize("N,E,k,F,H,L,quant", CASES)
-def test_expert_product_equals_the_dense_form(N, E, k, F, H, L, quant, backend):
-    x, router, live, given, plain = make(N + E, N, E, F, H, L, quant)
-    layer = L - 1
+def routing(x, router, k, live, rows, among):
+    """Router weights and ids with the rows that route and the experts they
+    may choose pinned: ``among = (lo, hi)`` keeps every choice inside experts
+    ``lo … hi - 1`` (the others' logits pushed far down); ``rows`` "all"
+    without it has the rows choose every expert in turn."""
+    N, E = x.shape[0], router.shape[1]
+    if among is not None:
+        inside = (jnp.arange(E) >= among[0]) & (jnp.arange(E) < among[1])
+        x = x.at[:, 0].set(1.0)
+        router = router.at[0].set(jnp.where(inside, 0.0, -1e4))
     w, ids = moe.route(x, router, k)
+    if rows == "all" and among is None:
+        ids = (jnp.arange(N * k, dtype=jnp.int32) % E).reshape(N, k)
+    live = {"all": jnp.ones((N,), bool), "none": jnp.zeros((N,), bool),
+            "some": live}[rows]
+    return x, w, ids, live
+
+
+@pytest.mark.parametrize("backend", ["xla", "interpret"])
+@pytest.mark.parametrize(
+    "N,E,k,F,H,L,quant,rows,among,held,read",
+    [c if len(c) == 11 else c + ("some", None, None, None) for c in CASES],
+)
+def test_expert_product_equals_the_dense_form(
+    N, E, k, F, H, L, quant, rows, among, held, read, backend
+):
+    first, count = held or (0, E)
+    # the leaves hold the ``count`` experts of ``held``; the router scores E
+    x, _, live, given, plain = make(N + E, N, count, F, H, L, quant)
+    router = jax.random.normal(jax.random.key(N * E), (H, E), jnp.float32)
+    layer = L - 1
+    x, w, ids, live = routing(x, router, k, live, rows, among)
     out, stats = moe.expert_mlp(
         x, w, ids, *given, num_experts=E, live=live,
-        layer=jnp.int32(layer), backend=backend,
+        layer=jnp.int32(layer), backend=backend, held=held,
     )
-    want = dense_form(x, w, ids, *(a[layer] for a in plain), E, live)
+    want = dense_form(x, w, ids, *(a[layer] for a in plain), E, live, held)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
-    # the counters: pairs of live rows only, each distinct expert once
+    # the counters: pairs of live rows only, each distinct HELD expert once
     counts = np.zeros(E, int)
     for n in np.flatnonzero(np.asarray(live)):
         counts[np.asarray(ids[n])] += 1
+    here = counts[first:first + count]
     assert np.array_equal(np.asarray(stats.expert_tokens), counts)
-    assert int(stats.experts_read) == (counts > 0).sum() <= min(E, k * live.sum())
+    assert int(stats.experts_read) == (here > 0).sum() <= min(E, k * live.sum())
     assert counts.sum() == k * int(live.sum())
+    if read is not None:  # the case pins how many tiles are live
+        assert int(stats.experts_read) == read
+        assert np.asarray(out).any() == (read > 0)
+
+
+@pytest.mark.parametrize("N", [4, 100])  # decode tiles, grouped tiles
+@pytest.mark.parametrize("poison", [np.nan, np.inf, 3e38])
+def test_what_lies_past_the_live_tiles_never_reaches_the_output(
+    N, poison, monkeypatch
+):
+    """The kernel writes the live tiles only. Whatever the rest of its
+    output buffer holds — here every unwritten tile is overwritten with
+    ``poison`` between the kernel and the combine, over whatever interpret
+    mode left there — the output is finite and the dense form's."""
+    E, k = 16, 2
+    x, router, live, given, plain = make(N, N, E, 32, 64, 2, False)
+    w, ids = moe.route(x, router, k)
+    kernel, seen = moe.expert_tiles_tpu, []
+
+    def poisoned(tiles, *args, **kw):
+        y = kernel(tiles, *args, **kw)
+        dead = jnp.arange(y.shape[0]) >= tiles.n_live
+        seen.append((int(tiles.n_live), y.shape[0]))
+        return jnp.where(dead[:, None, None], poison, y).astype(y.dtype)
+
+    monkeypatch.setattr(moe, "expert_tiles_tpu", poisoned)
+    out, _ = moe.expert_mlp(
+        x, w, ids, *given, num_experts=E, live=live, layer=jnp.int32(1),
+        backend="interpret",
+    )
+    (n_live, n_tiles), = seen
+    assert 0 < n_live < n_tiles  # there WERE unwritten tiles
+    assert np.isfinite(np.asarray(out)).all()
+    want = dense_form(x, w, ids, *(a[1] for a in plain), E, live)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
 
 
 @pytest.mark.parametrize("backend", ["xla", "interpret"])
