@@ -79,6 +79,7 @@ ORDER: Tuple[str, ...] = (
     "obs.trace.ring",         # flight-recorder ring
     "obs.trace.writer",       # JSONL span writer
     "obs.stepline.ring",      # step-profiler record ring
+    "obs.setup.ledger",       # set-up span ledger (held for list edits only)
     "obs.metrics.registry",   # family name -> family map
     "obs.metrics.stategauge", # one-hot flip serialization (then family)
     "obs.metrics.family",     # every counter/gauge/histogram child

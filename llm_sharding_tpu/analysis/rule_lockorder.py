@@ -85,7 +85,8 @@ METHOD_HINTS: Dict[str, Tuple[str, ...]] = {
 #: graph doesn't depend on tracing through the metrics/trace internals at
 #: every call site.
 FUNC_EFFECTS: Dict[str, Set[str]] = {
-    "record_shape_key": {"obs.metrics.shape_keys", "obs.metrics.family"},
+    "record_shape_key": {"obs.metrics.shape_keys", "obs.metrics.family",
+                         "obs.setup.ledger"},
     "emit_span": {"obs.trace.ring", "obs.trace.writer"},
     "set_prefill_path": {"obs.metrics.family"},
     "set_replica_state": {"obs.metrics.family"},

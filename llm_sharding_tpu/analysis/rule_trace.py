@@ -8,11 +8,15 @@ operator will wait for forever. Span names are collected from literal
 first-name arguments of ``emit_span(writer, "<name>", ...)`` and the
 ``self._span("<name>", ...)`` / ``self._decision("<name>", ...)``
 helpers; pass-through helpers forwarding a ``name`` variable are the
-helpers themselves and are skipped.
+helpers themselves and are skipped. The set-up ledger's spans
+(``obs/setupline.py``) reach ``emit_span`` through such a pass-through: their
+names are the package's string constants of the form ``setup.<word>…``,
+whoever opens them.
 """
 
 from __future__ import annotations
 
+import ast
 import re
 from typing import Dict, List, Set, Tuple
 
@@ -24,6 +28,7 @@ DOC = "emit_span names must match the README span-schema table"
 
 _HELPERS = {"_span", "_decision"}
 _TOKEN_RE = re.compile(r"`([^`]+)`")
+_SETUP_RE = re.compile(r"^setup(\.[a-z_]+)+$")
 
 
 def _code_spans(pkg: Package) -> Dict[str, Tuple[str, int]]:
@@ -38,6 +43,13 @@ def _code_spans(pkg: Package) -> Dict[str, Tuple[str, int]]:
                 lit = astutil.literal_str(call.args[0])
             if lit is not None:
                 spans.setdefault(lit, (rel, call.lineno))
+        for node in ast.walk(pf.tree):
+            if (
+                isinstance(node, ast.Constant)
+                and isinstance(node.value, str)
+                and _SETUP_RE.match(node.value)
+            ):
+                spans.setdefault(node.value, (rel, node.lineno))
     return spans
 
 
@@ -60,7 +72,7 @@ def _schema_rows(readme: str) -> List[Tuple[str, int]]:
         if cells and set(cells[0]) <= {"-", ":", " "}:
             continue  # the |---|---| separator row
         for tok in _TOKEN_RE.findall(cells[0]):
-            if re.match(r"^[a-z_]+$", tok):
+            if re.match(r"^[a-z_]+$", tok) or _SETUP_RE.match(tok):
                 rows.append((tok, i))
     return rows
 

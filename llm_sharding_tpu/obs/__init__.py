@@ -1,6 +1,6 @@
 """Serving telemetry: metrics registry, latency spans, HTTP exposition.
 
-Three modules, all stdlib-only (importable before jax backend init):
+The modules, all stdlib-only (importable before jax backend init):
 
 - ``metrics`` — thread-safe labeled counters/gauges/histograms with quantile
   readout, Prometheus text + JSON snapshot, and the process-wide
@@ -13,6 +13,10 @@ Three modules, all stdlib-only (importable before jax backend init):
   idle-bubble estimate) in a bounded ring, the derived
   ``server_host_occupancy`` / ``server_device_idle_frac`` gauges, the
   lock-wait metric sink, and the armable ``/profilez`` deep capture;
+- ``setupline`` — set-up's own account: the process-wide ``SETUP`` ledger of
+  ``setup.*`` spans (engine staging, server construction, every compile or
+  cache load by program and shape key, each program's first run), served
+  as ``/statz``'s ``setup`` and rendered as the restart table in the log;
 - ``http``    — ``MetricsServer``: a background stdlib-``http.server``
   thread serving ``/metrics`` (Prometheus, with slow-request exemplars),
   ``/statz`` (JSON), ``/debugz`` (the flight-recorder postmortem bundle),
@@ -47,4 +51,5 @@ from .stepline import (  # noqa: F401
     StepRecord,
     debug_snapshot,
 )
+from .setupline import SETUP, SetupLedger  # noqa: F401
 from .http import MetricsServer  # noqa: F401

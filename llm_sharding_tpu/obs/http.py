@@ -48,6 +48,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Callable, Dict, Optional
 
 from .metrics import REGISTRY, Registry
+from .setupline import SETUP
 from .stepline import debug_snapshot as stepline_debug_snapshot
 from .trace import FLIGHT_RECORDER
 
@@ -153,7 +154,11 @@ class MetricsServer:
     # ------------------------------------------------------------ internals
 
     def _statz_payload(self) -> dict:
-        payload: dict = {"metrics": self.registry.json_snapshot()}
+        payload: dict = {
+            "metrics": self.registry.json_snapshot(),
+            # set-up's account, whole: engine, server, every program built
+            "setup": SETUP.snapshot(),
+        }
         for name, provider in list(self._extra.items()):
             try:
                 payload[name] = provider()

@@ -27,6 +27,7 @@ import time
 from typing import Callable, Dict, Optional, Sequence, Tuple
 
 from ..analysis.lockorder import named_lock
+from .setupline import SETUP, compile_seconds
 
 _NAME_RE = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:]*$")
 _LABEL_RE = re.compile(r"^[a-zA-Z_][a-zA-Z0-9_]*$")
@@ -957,6 +958,26 @@ _SHAPE_KEYS = REGISTRY.counter(
     "(program, static-shape key) is a miss (a compile), repeats are hits",
     labels=("program", "result"),
 )
+# what each miss then cost, from the set-up ledger's ``setup.compile`` span
+# (obs/setupline.py): "which program recompiled, and did the cache have it"
+_COMPILE_SECONDS = REGISTRY.histogram(
+    "server_compile_seconds",
+    "Wall time of building one program: tracing + lowering + the backend's "
+    "compile (cache=miss|off) or the persistent cache's load (cache=hit); "
+    "program is the shape-key name, '-' for a compile no dispatch site "
+    "announced",
+    labels=("program", "cache"),
+    buckets=(0.01, 0.03, 0.1, 0.3, 1.0, 3.0, 10.0, 30.0, 100.0, 300.0),
+)
+SETUP.on_compile = lambda span: _COMPILE_SECONDS.labels(
+    program=span["program"], cache=span["cache"]
+).observe(compile_seconds(span))
+
+
+def _load_gauge(name: str) -> int:
+    """A load gauge as the server's last sweep left it (0 with no server)."""
+    fam = REGISTRY.get(name)
+    return 0 if fam is None else int(fam.value)
 
 
 def record_shape_key(program: str, key) -> bool:
@@ -972,4 +993,11 @@ def record_shape_key(program: str, key) -> bool:
         if not hit:
             _SHAPE_KEYS_SEEN.add(k)
     _SHAPE_KEYS.labels(program=program, result="hit" if hit else "miss").inc()
+    if not hit:
+        # the compile this dispatch pays is named in the set-up ledger, with
+        # the load it stalls: rows and queue as of the last gauge sweep
+        SETUP.miss(
+            program, key, in_flight=_load_gauge("server_slots_active"),
+            queued=_load_gauge("server_queue_depth"),
+        )
     return hit

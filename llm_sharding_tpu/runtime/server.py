@@ -89,6 +89,7 @@ from ..obs.metrics import (
     PREFIX_HIT_TOKENS, REGISTRY, record_shape_key, set_prefill_path,
 )
 from ..obs.trace import TraceContext, TraceWriter, emit_span
+from ..obs.setupline import SETUP
 from ..obs.stepline import STEP_ANNOTATION, StepProfiler
 from ..analysis.lockorder import named_lock
 from ..parallel import serve as serve_ops
@@ -540,6 +541,55 @@ class _Prefetcher:
     def _run(self) -> None:
         while True:
             self._q.get().read()
+
+
+class _FirstLog(_Prefetched):
+    """The log whose landing ends a ``setup.first_run`` span: a program met
+    for the first time has then run (the device works in order), and the
+    ledger is told when."""
+
+    __slots__ = ("on_landed",)
+
+    def read(self) -> None:
+        super().read()
+        self.on_landed(self.done_at, log=self.tag)
+
+
+def _watch_next_log(landed) -> bool:
+    """``SETUP.watch_landing``: called on a shape-key MISS only, by the
+    thread that is about to dispatch the new program. The next log that
+    thread fetches, on whichever live server it is stepping, is made a
+    ``_FirstLog``. One call of ``_fetch`` is shadowed on the instances and
+    the shadow takes itself off again: the step loop has no line for this,
+    and a step that met no new program runs the code it always ran. False
+    where no server could take the watch (none live, or one still set)."""
+    me = threading.get_ident()
+    armed: list = []
+
+    def disarm() -> None:
+        while armed:
+            armed.pop().__dict__.pop("_fetch", None)
+
+    def shadow(srv):
+        def fetch(handle, tag: str) -> _Prefetched:
+            if threading.get_ident() != me:
+                return PipelineServer._fetch(srv, handle, tag)
+            disarm()
+            log = _FirstLog(handle, tag, direct=srv._prefetcher is None)
+            log.on_landed = landed
+            if srv._prefetcher is not None:
+                srv._prefetcher._q.put(log)
+            return log
+        return fetch
+
+    for srv in list(_LIVE_SERVERS):
+        if not srv._closed and "_fetch" not in srv.__dict__:
+            srv._fetch = shadow(srv)
+            armed.append(srv)
+    return bool(armed)
+
+
+SETUP.watch_landing = _watch_next_log
 
 
 def save_snapshot(snap: dict, path: str) -> None:
@@ -1354,6 +1404,10 @@ class PipelineServer:
         # (the dp router overwrites it with the replica's group label).
         self._trace = TraceWriter(trace_path) if trace_path else None
         self._span_src = "s0"
+        if self._trace is not None:
+            # set-up's spans (src="setup") ride the same file, the engine's
+            # — closed before this writer existed — first
+            SETUP.attach_writer(self._trace)
 
         from ..ops.quant import QTensor
 
@@ -1453,6 +1507,7 @@ class PipelineServer:
                 MOE_EXPERT_TOKENS.labels(expert=str(e))
                 for e in range(self.cfg.num_experts)
             ]
+        arena = SETUP.begin("setup.server.arena")
         self.state = serve_ops.make_state(
             self.cfg,
             self.mesh,
@@ -1470,6 +1525,19 @@ class PipelineServer:
             cp=self.cp,
             **self._window_state_kwargs(),
         )
+        # the span covers the fills themselves, not their dispatch
+        jax.block_until_ready(self.state)
+        arena.update(
+            bytes=sum(int(a.nbytes) for a in jax.tree.leaves(self.state)),
+            blocks={
+                kind: int(k.shape[2]) for kind, k in
+                (("full", self.state.k), ("swa", self.state.k_swa))
+                if self.paged and k is not None
+            },
+        )
+        SETUP.end(arena)
+        # pools, radix tree, mirrors, the async executor's threads
+        host = SETUP.begin("setup.server.host")
 
         M = self.num_stages * batch_per_slot
         if self.paged:
@@ -1640,6 +1708,7 @@ class PipelineServer:
         _LIVE_SERVERS.add(self)  # load gauges sum over live servers
         _update_health_gauge()  # one-hot shows SERVING from birth, not
         # only after the first health transition
+        SETUP.end(host)
 
     # -- stage/head arrays the serve programs dispatch against -------------
     # cp=1 reads the engine's LIVE attributes at every dispatch (hot
@@ -2131,6 +2200,7 @@ class PipelineServer:
             }
 
     @classmethod
+    @SETUP.wraps("setup.server", then=SETUP.server_built)
     def restore(cls, engine, snap: dict) -> "PipelineServer":
         """Rebuild a serving daemon from ``snapshot`` output over an engine
         with the SAME model/placement (same stage count, layer split and
@@ -3071,7 +3141,12 @@ class PipelineServer:
             self._set_health(DRAINING)
             _update_load_gauges()
             if self._trace is not None:
+                SETUP.detach_writer(self._trace)
                 self._trace.close()
+            # a first-run watch still armed (_watch_next_log) has no log to
+            # wait for any more
+            self.__dict__.pop("_fetch", None)
+        SETUP.log_account("close")
         # async-executor threads: signal outside the mutex (their loops
         # re-check _closed under it) and join bounded — a parked thread
         # wakes within its condition-wait timeout

@@ -330,6 +330,24 @@ def finish(self, writer, trace):
     assert rule_trace.check(pkg) == []
 
 
+def test_trace_discipline_holds_the_setup_ledgers_names_both_ways(tmp_path):
+    """The set-up ledger's spans reach ``emit_span`` through a pass-through:
+    their names are the package's ``setup.<word>`` constants (ISSUE 41)."""
+    readme = TRACE_README + "| `setup.engine` | engine | whole |\n" \
+        "| `setup.server.arena` | nobody | stale row |\n"
+    pkg = make_pkg(tmp_path, {"mod.py": '''
+ARENA = "setup.server.host"
+def build(self, writer, trace):
+    emit_span(writer, "request", trace=trace)
+    self._span("phantom", x=1)
+    with SETUP.span("setup.engine"):
+        """a docstring that names setup.engine.put opens nothing"""
+'''}, readme=readme)
+    fs = rule_trace.check(pkg)
+    assert sorted(f.key for f in fs) == [
+        "stale:setup.server.arena", "undocumented:setup.server.host"]
+
+
 # ------------------------------------------------------- gate + baseline
 
 def test_clean_tree_lint_exit_zero():
