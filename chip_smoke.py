@@ -38,6 +38,16 @@ call (one live row of four) that meets 0, 1 and 8 experts at OLMoE's and at
 GigaChat's shapes, microseconds a call: the call's fixed part and what one
 more expert costs. The last line is then ``{"ok": true, "moe": [...],
 "moe_us_per_call": [...], "device": {...}}``.
+``--kv-write`` likewise runs one check only: a prefill chunk's K/V write
+(``ops/paged_attention.write_chunk_kv``) at the arena shapes of the
+benchmark's cells, microseconds a layer call with the arenas carried as the
+layer scan carries them — the whole-block tiles the chunk program writes
+(``tile``), the row-wise scatter they replaced (``rows``) and the two other
+whole-block forms that were timed against them (a loop of
+``dynamic_update_slice``, a copy kernel) — each first held bit for bit to
+the row-wise write outside block 0. The last line, also left in
+``chiprun_out/kv_write.json``: ``{"ok": true, "kv_write": [...], "device":
+{...}}``.
 """
 
 from __future__ import annotations
@@ -547,6 +557,234 @@ def child_moe(spec: dict, out_path: str) -> None:
         raise SystemExit("chip_smoke: the expert kernel disagrees with XLA")
 
 
+#: a prefill chunk's K/V write at the shapes the benchmark's cells have: the
+#: key/value heads of the arena, the lanes of a stored key and of a value
+#: (0: a latent arena holds none), the pool's blocks and the stage's layers.
+#: The chunk is ``batch_per_slot`` 4 rows x ``prefill_chunk`` 256 over
+#: ``kv_block_size`` 32 everywhere.
+KV_WRITE_SHAPES = (
+    {"name": "olmoe_1b_7b", "heads": 16, "dk": 128, "dv": 128,
+     "blocks": 1025, "layers": 16},
+    {"name": "qwen25_7b", "heads": 4, "dk": 128, "dv": 128,
+     "blocks": 1921, "layers": 28},
+    {"name": "qwen25_14b_pp4", "heads": 8, "dk": 128, "dv": 128,
+     "blocks": 2305, "layers": 12},
+    {"name": "gigachat31_702b_a36b", "heads": 1, "dk": 640, "dv": 0,
+     "blocks": 4097, "layers": 9},
+    # a key of 192 is stored in 256 lanes (models/mimo_v2.py)
+    {"name": "mimo_v25.swa", "heads": 8, "dk": 256, "dv": 128,
+     "blocks": 53, "layers": 9},
+    {"name": "mimo_v25.full", "heads": 4, "dk": 256, "dv": 128,
+     "blocks": 2049, "layers": 3},
+)
+#: ``tile``: ``write_chunk_kv`` as the chunk program calls it; ``rows``:
+#: the row-wise ``write_block_kv`` it replaced; ``dus`` and ``kernel``: the
+#: two other whole-block forms, kept here for the timing alone
+KV_WRITE_FORMS = ("tile", "rows", "dus", "kernel")
+KV_CHUNK = {"rows": 4, "chunk": 256, "block_size": 32}
+
+
+def _write_tiles_dus(arena, layer, blk, tiles):
+    """One ``dynamic_update_slice`` a tile, in a loop."""
+    import jax
+
+    B, nb = blk.shape
+
+    def one(i, arena):
+        b, j = i // nb, i % nb
+        return jax.lax.dynamic_update_slice(
+            arena, tiles[b, j][None, None], (layer, blk[b, j], 0, 0, 0)
+        )
+    return jax.lax.fori_loop(0, B * nb, one, arena)
+
+
+def _write_tiles_kernel(arena, layer, blk, tiles, interpret=False):
+    """A copy kernel: one grid step a tile, the table scalar-prefetched, the
+    arena aliased in and out so that the blocks no step names are kept."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, nb, Nkv, BS, D = tiles.shape
+
+    def copy(layer_ref, blk_ref, tile_ref, arena_ref, out_ref):
+        out_ref[...] = tile_ref[...]
+
+    spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B * nb,),
+        in_specs=[
+            pl.BlockSpec((None, Nkv, BS, D), lambda i, l, t: (i, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec(
+            (None, None, Nkv, BS, D), lambda i, l, t: (l[0], t[i], 0, 0, 0)
+        ),
+    )
+    return pl.pallas_call(
+        copy, grid_spec=spec,
+        out_shape=jax.ShapeDtypeStruct(arena.shape, arena.dtype),
+        input_output_aliases={3: 0}, interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), blk.reshape(-1),
+      tiles.reshape(B * nb, Nkv, BS, D), arena)
+
+
+def kv_write_form(form: str, interpret: bool = False):
+    """``write(k_arena, v_arena, layer, table, col0, k_new, v_new)`` →
+    ``(k_arena, v_arena)`` in one of ``KV_WRITE_FORMS``."""
+    import jax.numpy as jnp
+
+    import llm_sharding_tpu.models  # noqa: F401 — ops import through models
+    from llm_sharding_tpu.ops import paged_attention as pa
+
+    if form == "tile":
+        return pa.write_chunk_kv
+    if form == "rows":
+        def rows(k_arena, v_arena, layer, table, col0, k_new, v_new):
+            B, Sc = k_new.shape[:2]
+            cols = jnp.broadcast_to(
+                col0 + jnp.arange(Sc, dtype=jnp.int32)[None, :], (B, Sc)
+            )
+            return pa.write_block_kv(
+                k_arena, v_arena, layer, table, cols, k_new, v_new
+            )
+        return rows
+    one = {"dus": _write_tiles_dus,
+           "kernel": functools.partial(_write_tiles_kernel,
+                                       interpret=interpret)}[form]
+
+    def whole_blocks(k_arena, v_arena, layer, table, col0, k_new, v_new):
+        B, Sc, Nkv = k_new.shape[:3]
+        BS = k_arena.shape[3]
+        nb = Sc // BS
+        blk = jnp.take(
+            table, col0 // BS + jnp.arange(nb, dtype=jnp.int32), axis=1
+        )
+
+        def put(arena, new):
+            if not arena.shape[-1]:
+                return arena
+            t = new.astype(arena.dtype).reshape(B, nb, BS, Nkv, -1)
+            return one(arena, layer, blk, jnp.transpose(t, (0, 1, 3, 2, 4)))
+        return put(k_arena, k_new), put(v_arena, v_new)
+    return whole_blocks
+
+
+def kv_write_inputs(shape: dict, seed: int = 0, blocks: int = None):
+    """Arenas, a table that maps each of the chunk's rows to blocks of its
+    own, and a chunk's fresh keys and values at ``shape``."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+
+    B, Sc, BS = KV_CHUNK["rows"], KV_CHUNK["chunk"], KV_CHUNK["block_size"]
+    NB = blocks or shape["blocks"]
+    L, Nkv, dt = shape["layers"], shape["heads"], jnp.bfloat16
+    T = 2 * Sc // BS  # room for a chunk at a later column
+    rng = np.random.default_rng(seed)
+    # blocks of its own for every row where the pool has that many; MiMo's
+    # window pool (53 blocks) maps what a row can hold, the rest to block 0
+    own = min(T, (NB - 1) // B)
+    table = np.zeros((B, T), np.int32)
+    table[:, T - own:] = (
+        1 + rng.permutation(NB - 1)[: B * own].reshape(B, own)
+    )
+    ks = jax.random.split(jax.random.key(seed), 4)
+    k_arena = jnp.zeros((L, NB, Nkv, BS, shape["dk"]), dt)
+    v_arena = jnp.zeros((L, NB, Nkv, BS, shape["dv"]), dt)
+    k_new = jax.random.normal(ks[0], (B, Sc, Nkv, shape["dk"]), dt)
+    v_new = jax.random.normal(ks[1], (B, Sc, Nkv, shape["dv"]), dt)
+    col0 = jnp.asarray(Sc, jnp.int32)
+    return k_arena, v_arena, jnp.asarray(table), col0, k_new, v_new
+
+
+def check_kv_write(shape: dict, form: str, interpret: bool = False,
+                   blocks: int = None) -> bool:
+    """Whether ``form`` leaves both arenas, outside block 0, bit for bit as
+    the row-wise write leaves them, one layer of the stack written."""
+    import jax
+    import jax.numpy as jnp
+
+    k_arena, v_arena, table, col0, k_new, v_new = kv_write_inputs(
+        shape, seed=1, blocks=blocks
+    )
+    layer = shape["layers"] - 1
+    got = jax.jit(kv_write_form(form, interpret))(
+        k_arena, v_arena, layer, table, col0, k_new, v_new)
+    want = jax.jit(kv_write_form("rows"))(
+        k_arena, v_arena, layer, table, col0, k_new, v_new)
+    return all(
+        bool(jnp.array_equal(g[:, 1:], w[:, 1:])) for g, w in zip(got, want)
+    )
+
+
+def time_kv_write(shape: dict, form: str, calls: int = 64,
+                  interpret: bool = False, blocks: int = None) -> float:
+    """Microseconds per layer call of a chunk's K/V write in ``form``: the
+    arenas are carried through ``calls`` calls of ONE program as the layer
+    scan carries them (donated, so the write is in place or shows that it is
+    not), each call's entries hanging on the arena the call before left,
+    warmed up, best of three."""
+    import jax
+    import jax.numpy as jnp
+
+    write = kv_write_form(form, interpret)
+    k_arena, v_arena, table, col0, k_new, v_new = kv_write_inputs(
+        shape, blocks=blocks
+    )
+    layers = jnp.arange(calls, dtype=jnp.int32) % shape["layers"]
+
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    def run(k_arena, v_arena, table, col0, k_new, v_new):
+        def one(carry, layer):
+            ka, va = carry
+            # hangs on the call before: nothing is hoisted out of the loop
+            tied = ka[layer, 0, 0, 0, :1] * 0
+            return write(
+                ka, va, layer, table, col0, k_new + tied, v_new + tied
+            ), None
+        return jax.lax.scan(one, (k_arena, v_arena), layers)[0]
+
+    best = float("inf")
+    for _ in range(4):  # the first call compiles
+        t0 = time.perf_counter()
+        k_arena, v_arena = jax.block_until_ready(
+            run(k_arena, v_arena, table, col0, k_new, v_new)
+        )
+        best = min(best, time.perf_counter() - t0)
+    return round(best / calls * 1e6, 2)
+
+
+def child_kv_write(spec: dict, out_path: str) -> None:
+    import jax
+
+    from llm_sharding_tpu.utils.compile_cache import enable_persistent_cache
+    from llm_sharding_tpu.utils.device_report import device_report
+
+    platform = jax.devices()[0].platform
+    require_tpu(platform, "the chunk's K/V write")
+    enable_persistent_cache(platform)
+    results = []
+    for shape in KV_WRITE_SHAPES:
+        same = {  # at a pool that leaves room for two copies of it
+            f: check_kv_write(shape, f, blocks=min(shape["blocks"], 129))
+            for f in KV_WRITE_FORMS if f != "rows"
+        }
+        us = {f: time_kv_write(shape, f) for f in KV_WRITE_FORMS}
+        results.append({"shape": shape["name"], "us_per_layer_call": us,
+                        "same_as_rows": same})
+        print(f"[kv-write] {shape['name']}: "
+              + ", ".join(f"{f} {u} us" for f, u in us.items())
+              + f"; same as rows: {same}", flush=True)
+    with open(out_path, "w") as f:
+        json.dump({"device": device_report(), "kv_write": results}, f)
+    if not all(all(r["same_as_rows"].values()) for r in results):
+        raise SystemExit(
+            "chip_smoke: a whole-block write differs from the row-wise one"
+        )
+
+
 def require_tpu(platform: str, who: str) -> None:
     if platform != "tpu":
         raise SystemExit(
@@ -962,14 +1200,33 @@ def main(argv=None) -> int:
     ap.add_argument("--moe", action="store_true",
                     help="only check the expert kernel (ops/moe.py) against "
                          "its XLA path at OLMoE-1B-7B's published widths")
-    ap.add_argument("--child", choices=("kernels", "store", "moe"))
+    ap.add_argument("--kv-write", action="store_true",
+                    help="only time a prefill chunk's K/V write (ops/"
+                         "paged_attention.py) at the cells' arena shapes: "
+                         "whole-block tiles beside the row-wise scatter")
+    ap.add_argument("--child",
+                    choices=("kernels", "store", "moe", "kv_write"))
     ap.add_argument("--spec")
     ap.add_argument("--out")
     args = ap.parse_args(argv)
     if args.child:
         spec = json.loads(args.spec)
         {"kernels": child_kernels, "store": child_store,
-         "moe": child_moe}[args.child](spec, args.out)
+         "moe": child_moe,
+         "kv_write": child_kv_write}[args.child](spec, args.out)
+        return 0
+    if args.kv_write:
+        os.makedirs(WORK, exist_ok=True)
+        got = wait_child(run_child(
+            "kv_write", {}, dict(os.environ, PYTHONPATH=HERE),
+            "kv_write.log"))
+        line = json.dumps({"ok": True, "kv_write": got["kv_write"],
+                           "device": got["device"]})
+        os.makedirs(os.path.join(HERE, "chiprun_out"), exist_ok=True)
+        with open(os.path.join(HERE, "chiprun_out", "kv_write.json"),
+                  "w") as f:
+            f.write(line + "\n")
+        print(line)
         return 0
     if args.moe:
         os.makedirs(WORK, exist_ok=True)

@@ -409,7 +409,7 @@ def forward_layers_paged(
     value is the first ``kv_lora_rank`` lanes of the key). Returns ``(h,
     k_arena, v_arena, None, None, stats)``."""
     from ..ops.paged_attention import (
-        paged_attention, paged_prefill, write_block_kv,
+        paged_attention, paged_prefill, write_block_kv, write_chunk_kv,
     )
 
     _refuse_tp(tp_axis, cp_axis)
@@ -417,6 +417,10 @@ def forward_layers_paged(
         raise NotImplementedError(
             "a quantized (int8/fp8) latent cache is not implemented"
         )
+    # a chunk writes whole blocks from its first column on (llama's note)
+    write, at = (write_chunk_kv, cols[0, 0]) if prefill else (
+        write_block_kv, cols
+    )
     with jax.named_scope("rope"):
         cos, sin = rope_cos_sin(positions, cfg, dtype=jnp.float32)
     wv = write_valid if isinstance(write_valid, bool) else jnp.asarray(
@@ -427,8 +431,8 @@ def forward_layers_paged(
 
     def apply(p, l, valid, h, k_all, v_all, ks_all, vs_all):
         def attend(q_full, entry):
-            k_a, _ = write_block_kv(
-                k_all, v_all, l, block_table, cols, entry, None,
+            k_a, _ = write(
+                k_all, v_all, l, block_table, at, entry, None,
                 valid=wv & valid,
             )
             if prefill:

@@ -200,12 +200,16 @@ def forward_layers_paged(
     ``prefill`` switches the attention dispatch to ``paged_prefill``
     for chunk-shaped queries."""
     from ..ops.paged_attention import (
-        paged_attention, paged_prefill, write_block_kv,
+        paged_attention, paged_prefill, write_block_kv, write_chunk_kv,
     )
     from .stack import scan_layers_paged
 
     wv = write_valid if isinstance(write_valid, bool) else jnp.asarray(
         write_valid
+    )
+    # a chunk writes whole blocks from its first column on (llama's note)
+    write, at = (write_chunk_kv, cols[0, 0]) if prefill else (
+        write_block_kv, cols
     )
 
     def apply(p, l, valid, h, k_all, v_all, ks_all, vs_all):
@@ -213,14 +217,14 @@ def forward_layers_paged(
 
         def attn_fn(q, k, v):
             if ks_all is None:
-                k_a, v_a = write_block_kv(
-                    k_all, v_all, l, block_table, cols, k, v,
+                k_a, v_a = write(
+                    k_all, v_all, l, block_table, at, k, v,
                     valid=wv & valid,
                 )
                 out["kv"] = (k_a, v_a, None, None)
             else:
-                out["kv"] = write_block_kv(
-                    k_all, v_all, l, block_table, cols, k, v,
+                out["kv"] = write(
+                    k_all, v_all, l, block_table, at, k, v,
                     valid=wv & valid, k_scale=ks_all, v_scale=vs_all,
                 )
                 k_a, v_a = out["kv"][0], out["kv"][1]

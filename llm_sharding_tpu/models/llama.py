@@ -323,8 +323,10 @@ def paged_decoder_layer(
     ``prefill`` the attention dispatch is ``paged_prefill`` — the
     flash-style chunked-prefill kernel whose query axis is the whole
     chunk (``walk``: what the chunk's real queries have to walk, built
-    once for all layers); write-then-attend order is identical, so intra-chunk
-    causality falls out of the position masking either way.
+    once for all layers) and the write is ``write_chunk_kv`` (whole-block
+    tiles from the chunk's first column on, where the chunk is whole blocks);
+    write-then-attend order is identical, so intra-chunk causality falls out
+    of the position masking either way.
 
     ``cp_axis`` (context-parallel serving, ``serve(cp=N)``): the arena
     this layer sees is ONE SHARD of the pooled blocks and the table maps
@@ -336,20 +338,26 @@ def paged_decoder_layer(
     full window, so everything downstream stays shard-replicated."""
     from ..ops.paged_attention import (
         combine_attn_stats, paged_attention, paged_prefill, write_block_kv,
+        write_chunk_kv,
     )
 
     out = {}
+    # a chunk's rows share their columns: it writes whole blocks from its
+    # first column on, a decode step rows at each row's own
+    write, at = (write_chunk_kv, cols[0, 0]) if prefill else (
+        write_block_kv, cols
+    )
 
     def attn_fn(q, k, v):
         if k_scale is None:
-            k_a, v_a = write_block_kv(
-                k_arena, v_arena, layer, block_table, cols, k, v,
+            k_a, v_a = write(
+                k_arena, v_arena, layer, block_table, at, k, v,
                 valid=write_valid & valid,
             )
             out["kv"] = (k_a, v_a, None, None)
         else:
-            k_a, v_a, ks, vs = write_block_kv(
-                k_arena, v_arena, layer, block_table, cols, k, v,
+            k_a, v_a, ks, vs = write(
+                k_arena, v_arena, layer, block_table, at, k, v,
                 valid=write_valid & valid, k_scale=k_scale, v_scale=v_scale,
             )
             out["kv"] = (k_a, v_a, ks, vs)

@@ -439,7 +439,7 @@ def forward_layers_paged(
     bound on the walk, a mask at the edge) and its sink. Returns ``(h,
     k_arena, v_arena, None, None, stats)``."""
     from ..ops.paged_attention import (
-        paged_attention, paged_prefill, write_block_kv,
+        paged_attention, paged_prefill, write_block_kv, write_chunk_kv,
     )
 
     _refuse_tp(tp_axis, cp_axis)
@@ -447,6 +447,10 @@ def forward_layers_paged(
         raise NotImplementedError(
             "a quantized (int8/fp8) arena under mimo_v2 is not implemented"
         )
+    # a chunk writes whole blocks from its first column on (llama's note)
+    write, at = (write_chunk_kv, cols[0, 0]) if prefill else (
+        write_block_kv, cols
+    )
     rope = _rope_tables(cfg, positions)
     wv = write_valid if isinstance(write_valid, bool) else jnp.asarray(
         write_valid
@@ -471,8 +475,8 @@ def forward_layers_paged(
             l = i + run.arena_first  # the layer's slot in its kind's arena
 
             def attend(q, k, v):
-                k_a, v_a = write_block_kv(
-                    k_all, v_all, l, tbl, cols, k, v, valid=wv & valid,
+                k_a, v_a = write(
+                    k_all, v_all, l, tbl, at, k, v, valid=wv & valid,
                 )
                 kw = {"window": win, "sink": p.get("sink")}
                 if prefill:

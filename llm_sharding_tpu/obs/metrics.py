@@ -676,6 +676,22 @@ PREFILL_BLOCKS_READ = REGISTRY.counter(
     "prefill-attention-HBM estimate; the retired gather path moved the "
     "row's WHOLE mapped window in AND out per chunk on top of this",
 )
+#: The two forms of a prefill chunk's K/V write
+#: (``ops/paged_attention.write_chunk_kv``): whole-block tiles, or the
+#: decode write's rows.
+PREFILL_KV_WRITES = ("tile", "rows")
+PREFILL_KV_BLOCKS_WRITTEN = REGISTRY.counter(
+    "server_prefill_kv_blocks_written_total",
+    "KV arena blocks a chunked-prefill dispatch's fresh keys and values "
+    "land in, per layer, summed over admitting rows per chunk (host-side: "
+    "ceil(chunk / block_size) per row), by the form the chunk program's "
+    "statics chose: write=tile — whole (heads, block_size, head_dim) "
+    "blocks through the table, a chunk of whole blocks over a plain arena; "
+    "write=rows — the decode write's row-wise scatter (a chunk under a "
+    "block, an int8/fp8 arena). tile / (tile + rows) is the share of "
+    "prefill writes that took the block-sized form",
+    labels=("write",),
+)
 PREFILL_CELLS_LIVE = REGISTRY.counter(
     "server_prefill_cells_live_total",
     "Cells the chunked-prefill kernel walked, summed over the layer calls "

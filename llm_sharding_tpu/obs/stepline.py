@@ -216,6 +216,7 @@ class StepRecord:
         "expert_tokens", "experts_read", "expert_steps", "expert_rows",
         "decode_blocks_live", "decode_blocks_reserved",
         "prefill_cells_live", "prefill_cells_walked", "kv_kinds",
+        "prefill_kv_blocks",
     )
 
     def __init__(self, ts, wall_s, phases, blocked_s, idle_s,
@@ -268,6 +269,10 @@ class StepRecord:
         # step's decode walked and reserved, and (window layers) the blocks
         # handed back to the pool behind the window in this step
         self.kv_kinds = None
+        # chunked prefills dispatched in this step: the arena blocks their
+        # fresh K/V landed in, by the form of the write ("tile" / "rows");
+        # None in a step that dispatched no chunk
+        self.prefill_kv_blocks = None
 
     @property
     def host_s(self) -> float:
@@ -300,6 +305,8 @@ class StepRecord:
         }
         if self.kv_kinds is not None:
             d["kv_kinds"] = {k: dict(v) for k, v in self.kv_kinds.items()}
+        if self.prefill_kv_blocks is not None:
+            d["prefill_kv_blocks"] = dict(self.prefill_kv_blocks)
         if self.expert_tokens is not None:
             d["expert_tokens"] = list(self.expert_tokens)
             d["experts_read"] = list(self.experts_read)
@@ -349,6 +356,7 @@ class StepProfiler:
         self._experts: Optional[list] = None  # [tokens, read, steps, rows]
         self._decode_blocks = [0, 0]  # [live, reserved]
         self._prefill_cells = [0, 0]  # [live, walked]
+        self._prefill_kv_blocks = None  # {"tile" | "rows": blocks}
         self._kv_kinds = None
         self._phases: Dict[str, float] = {}
         self._blocked_s = 0.0
@@ -453,6 +461,7 @@ class StepProfiler:
         self._experts = None
         self._decode_blocks = [0, 0]
         self._prefill_cells = [0, 0]
+        self._prefill_kv_blocks = None
         self._kv_kinds = None
         work = bool(rows or queued or pending)
         if self._annotate is not None and (work or self._had_work):
@@ -586,6 +595,17 @@ class StepProfiler:
         self._prefill_cells[0] += int(live)
         self._prefill_cells[1] += int(walked)
 
+    def prefill_kv_blocks(self, write: str, blocks: int) -> None:
+        """Add one chunk dispatch's K/V write to the step's record: the
+        arena blocks it landed in, under the form of the write (``tile``
+        or ``rows``)."""
+        if not self._enabled or self._t0 is None:
+            return
+        if self._prefill_kv_blocks is None:
+            self._prefill_kv_blocks = {}
+        acc = self._prefill_kv_blocks
+        acc[write] = acc.get(write, 0) + int(blocks)
+
     def experts(self, tokens, read=None, steps: int = 0, rows: int = 0) -> None:
         """Add a fetched set of expert counters to the step's record:
         ``tokens`` [E] per expert; for decode microsteps also ``read`` [L]
@@ -662,6 +682,7 @@ class StepProfiler:
             prefill_cells_walked=self._prefill_cells[1],
         )
         rec.kv_kinds = self._kv_kinds
+        rec.prefill_kv_blocks = self._prefill_kv_blocks
         if self._experts is not None:
             tokens, read, rec.expert_steps, rec.expert_rows = self._experts
             rec.expert_tokens, rec.experts_read = tokens, read or []

@@ -70,6 +70,29 @@ scatter, never a full-window round trip), so per-step attention HBM
 traffic scales with the blocks a row actually owns. The XLA gather path
 remains the bit-exact CPU/tier-1 fallback behind the same dispatch.
 
+Who writes the arena, and at what granularity (one algorithm — fresh
+entries land in the blocks the table names, invalid ones in block 0 of
+their layer — at two granularities, chosen by what a program's statics
+say, never by an option):
+
+- ROWS, ``write_block_kv``: ``B x S x Nkv`` rows of ``D``, each with its
+  own ``(layer, block, head, slot)``. Every decode step (``serve_chunk``:
+  one entry a row, at each row's own column), speculation's verify
+  (``serve_verify``: per-row columns), and a prefill chunk that cannot be
+  tiles — shorter than a block (a context-parallel radix admission with a
+  short suffix), or over an int8/fp8 arena (running per-block scales).
+- TILES, ``write_chunk_kv``: a prefill chunk (``serve_prefill_chunk``)
+  of whole blocks over a plain arena. Its rows share their columns, which
+  start on a block boundary, and the arena is head-major, so a layer
+  call's fresh K/V is ``Sc / BS`` whole contiguous ``(Nkv, BS, D)`` blocks
+  a row: that many block-sized writes where the row-wise scatter made
+  ``B x Sc x Nkv`` (16,384 rows of 256 bytes a layer call at OLMoE's 16
+  heads: 2.29 ms where the tiles take 0.064, ``chip_smoke.py
+  --kv-write``, PERF.md PR 42).
+- WHOLE WINDOWS, ``parallel/serve._scatter_pages`` (``serve_admit``'s
+  one-shot prefill; every layer at once) and ``write_arena_blocks`` (the
+  hand-off's block moves).
+
 Backend selection (``paged_attention``'s ``backend=`` + the
 ``PAGED_FORCE_KERNEL`` env var): ``auto`` picks the Pallas kernel on TPU
 for Mosaic-eligible shapes and the XLA gather elsewhere; ``kernel``/
@@ -371,6 +394,81 @@ def write_block_kv(
     k_arena, k_scale = one(k_arena, k_scale, k_new)
     v_arena, v_scale = one(v_arena, v_scale, v_new)
     return k_arena, v_arena, k_scale, v_scale
+
+
+def chunk_writes_tiles(chunk: int, block_size: int, quantized: bool) -> bool:
+    """Whether ``write_chunk_kv`` writes a prefill chunk of ``chunk``
+    positions as whole-block tiles: what the chunk program's statics say —
+    the chunk is whole blocks and the arena holds plain values. The host
+    asks the same question for its counter (``runtime/server.py``)."""
+    return bool(block_size) and chunk % block_size == 0 and not quantized
+
+
+@jax.named_scope("kv_write")
+def write_chunk_kv(
+    k_arena: jnp.ndarray,  # [L, NB, Nkv, BS, D] pooled key blocks
+    v_arena: jnp.ndarray,  # [L, NB, Nkv, BS, Dv]
+    layer,  # scalar int32
+    block_table: jnp.ndarray,  # [B, T]
+    col0,  # scalar int32 — the chunk's first column, the same in every row
+    k_new: jnp.ndarray,  # [B, Sc, Nkv, D]
+    v_new: jnp.ndarray,  # [B, Sc, Nkv, Dv]
+    valid=None,  # SCALAR bool (ring-inactive microstep, masked layer)
+    k_scale: jnp.ndarray = None,
+    v_scale: jnp.ndarray = None,
+):
+    """``write_block_kv`` for a PREFILL CHUNK: every row's entries are the
+    columns ``col0 .. col0 + Sc - 1``, so where the chunk is whole blocks
+    (``chunk_writes_tiles``; ``col0`` is then a multiple of the block size:
+    radix matches and chunk offsets are, and ``_admit_chunked`` refuses a
+    start that is not) a layer call's fresh K/V is ``Sc / BS`` WHOLE
+    ``(Nkv, BS, D)`` blocks a row — contiguous in the head-major arena —
+    and lands as that many tiles at ``(layer, table[row, col0 // BS + j])``
+    instead of ``B x Sc x Nkv`` rows of ``D``. The token-major activations
+    are re-laid ``[B, Sc / BS, Nkv, BS, D]`` first: a transpose of the
+    chunk's own entries, never of the pool. The scatter's window is the
+    arena's three MINOR dims (``write_block_kv`` warns of a window that is
+    not: XLA re-lays the stack), what ``serve_admit``'s ``_scatter_pages``
+    writes through every layer at once.
+
+    The same bytes land in the same blocks: pad positions and trash-mapped
+    table entries (a padded row, a window layer's freed block) are written
+    as the row-wise write writes them, the latter into block 0 of the
+    layer. ``valid`` False steers EVERY tile there, by address: no owned
+    block is read back. A chunk under a block (a cp-forced radix admission
+    with a short suffix) and a quantized arena (its running per-block
+    scales; a whole-block write would need none, as ``_scatter_pages_q``
+    shows — ROADMAP C14) take the row-wise write as it is."""
+    B, Sc, Nkv = k_new.shape[:3]
+    BS = k_arena.shape[3]
+    if not chunk_writes_tiles(Sc, BS, k_scale is not None):
+        cols = jnp.broadcast_to(
+            col0 + jnp.arange(Sc, dtype=jnp.int32)[None, :], (B, Sc)
+        )
+        return write_block_kv(
+            k_arena, v_arena, layer, block_table, cols, k_new, v_new,
+            valid=valid, k_scale=k_scale, v_scale=v_scale,
+        )
+    nb = Sc // BS
+    # past the table's width (the server never asks: a row's budget is
+    # mapped before its chunks run) a tile goes to the sink, not to a
+    # clamped neighbour
+    blk = jnp.take(
+        block_table, col0 // BS + jnp.arange(nb, dtype=jnp.int32), axis=1,
+        mode="fill", fill_value=0,
+    )  # [B, nb]
+    if valid is not None:
+        blk = jnp.where(valid, blk, 0)
+
+    def tiles(arena, new):
+        t = new.astype(arena.dtype).reshape(B, nb, BS, Nkv, new.shape[-1])
+        return arena.at[layer, blk].set(jnp.transpose(t, (0, 1, 3, 2, 4)))
+
+    return (
+        tiles(k_arena, k_new),
+        # a latent arena holds no values: nothing to write
+        v_arena if v_arena.shape[-1] == 0 else tiles(v_arena, v_new),
+    )
 
 
 def paged_attention_xla(

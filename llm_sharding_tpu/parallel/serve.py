@@ -187,9 +187,16 @@ def state_specs(
 # OTHER paged path is arena-native: decode microsteps (serve_chunk),
 # spec-verify traversals (serve_verify) AND chunked prefill
 # (serve_prefill_chunk — the ``_gather_window`` gather→recompute→scatter
-# round trip it used to pay per chunk is retired) land fresh KV via
-# ops/paged_attention.write_block_kv (a per-entry scatter into the owning
-# blocks) and attend straight off the arena through ``paged_attention`` /
+# round trip it used to pay per chunk is retired) land fresh KV in the
+# owning blocks — serve_chunk and serve_verify as ROWS
+# (ops/paged_attention.write_block_kv: a per-entry scatter, each row at
+# its own column), serve_prefill_chunk as whole-block TILES
+# (write_chunk_kv: its rows share their columns and start on a block
+# boundary, so a chunk is ``Sc / BS`` contiguous blocks a row — the
+# block-sized write ``_scatter_pages`` below makes for a whole window;
+# rows again where the chunk is under a block or the arena is int8/fp8,
+# which is what the program's statics say and no option) — and attend
+# straight off the arena through ``paged_attention`` /
 # ``paged_prefill`` — the Pallas kernels stream exactly the blocks the
 # tables name (per-step HBM traffic ∝ blocks actually written), the XLA
 # backend gathers inside the op (the bit-exact CPU/tier-1 fallback, which
@@ -1042,9 +1049,11 @@ def serve_prefill_chunk(
     chunk resumes exactly where the previous one stopped.
 
     Paged mode attends the arena IN PLACE (flash-style chunked prefill —
-    ROADMAP item 3): the chunk's fresh KV lands via ``write_block_kv``
-    (quantizing at insert on an int8/fp8 arena — no inter-chunk
-    dequant→requant round trip) and its queries attend every
+    ROADMAP item 3): the chunk's fresh KV lands via ``write_chunk_kv`` —
+    whole-block tiles through the rows' tables where the chunk is whole
+    blocks, ``write_block_kv``'s rows otherwise (quantizing at insert on an
+    int8/fp8 arena — no inter-chunk dequant→requant round trip) — and its
+    queries attend every
     previously-written block through ``ops/paged_attention.paged_prefill``
     (scalar-prefetched block tables, online-softmax, causal masking by
     position — intra-chunk included), so the retired ``_gather_window``

@@ -85,7 +85,7 @@ from ..obs.metrics import (
     KV_ENTRY_BYTES, KV_HOST_TIER_BLOCKS, KV_WASTE_FRAC, MOE_EXPERT_TOKENS,
     MOE_EXPERTS_READ, MOE_PAIRS_HELD, MOE_PAIRS_ROUTED,
     PREFILL_BLOCKS_READ, PREFILL_CELLS_LIVE, PREFILL_CELLS_WALKED,
-    PREFILL_POSITIONS, PREFIX_HIT_RATE,
+    PREFILL_KV_BLOCKS_WRITTEN, PREFILL_POSITIONS, PREFIX_HIT_RATE,
     PREFIX_HIT_TOKENS, REGISTRY, record_shape_key, set_prefill_path,
 )
 from ..obs.trace import TraceContext, TraceWriter, emit_span
@@ -4946,6 +4946,23 @@ class PipelineServer:
         # exactly the bucket; bucket and prefill_chunk are both powers of
         # two, so larger buckets still split into whole chunks
         Sc = min(self.prefill_chunk, bucket)
+        kv_write = None
+        if self.paged:
+            from ..ops.paged_attention import chunk_writes_tiles
+
+            # what the chunk program's statics choose (write_chunk_kv)
+            kv_write = "tile" if chunk_writes_tiles(
+                Sc, self.kv_block_size, self.kv_quantized
+            ) else "rows"
+            if kv_write == "tile" and prefix_off % self.kv_block_size:
+                # the row-wise write forgave a start inside a block; a tile
+                # would overwrite the head of the shared block before it
+                raise ValueError(
+                    f"chunked admission at prefix_off={prefix_off}: a chunk "
+                    f"of whole blocks must start on a block boundary "
+                    f"(kv_block_size={self.kv_block_size}; radix matches "
+                    "are block-aligned by construction)"
+                )
         row0 = slot * Bs
         self._admitting_rows.update(range(row0, row0 + Bs))
         idx = np.arange(bucket, dtype=np.int32)[None, :]
@@ -4992,6 +5009,12 @@ class PipelineServer:
                         -(-(prefix_off + off + Sc) // self.kv_block_size)
                     )
                 )
+                # ... and those its fresh K/V lands in, by the write's form
+                n_written = n_valid * -(-Sc // self.kv_block_size)
+                PREFILL_KV_BLOCKS_WRITTEN.labels(write=kv_write).inc(
+                    n_written
+                )
+                self.stepline.prefill_kv_blocks(kv_write, n_written)
             real = int(np.clip(plen - off, 0, Sc)[row_valid].sum())
             with self._prefill_span(Bs, real, Bs * Sc):
                 chunk_out = serve_ops.serve_prefill_chunk(

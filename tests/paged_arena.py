@@ -4,6 +4,9 @@ its own contents, so an op that read the wrong layer cannot pass) and read
 a row's logical window back out of it the plain numpy way (the oracle the
 XLA gather is held to)."""
 
+import contextlib
+from unittest import mock
+
 import numpy as np
 import jax.numpy as jnp
 
@@ -53,3 +56,55 @@ def others_untouched(before, after, layer):
     before, after = np.asarray(before), np.asarray(after)
     keep = [l for l in range(before.shape[0]) if l != layer]
     np.testing.assert_array_equal(after[keep], before[keep])
+
+
+@contextlib.contextmanager
+def row_wise_chunk_write():
+    """Within: every chunk program built writes its K/V row-wise
+    (``write_block_kv``), as the program before the tile write did, and the
+    host counts it so — what the tile write is held to. The chunk programs
+    built either way are dropped from jit's cache on the way in and out, so
+    no test meets the other's program."""
+    from llm_sharding_tpu.ops import paged_attention as pa
+    from llm_sharding_tpu.parallel import serve as serve_ops
+
+    serve_ops.serve_prefill_chunk.clear_cache()
+    try:
+        with mock.patch.object(pa, "chunk_writes_tiles", lambda *a: False):
+            yield
+    finally:
+        serve_ops.serve_prefill_chunk.clear_cache()
+
+
+def kv_blocks_written() -> dict:
+    """``server_prefill_kv_blocks_written_total`` by the write's form."""
+    from llm_sharding_tpu.obs.metrics import (
+        PREFILL_KV_BLOCKS_WRITTEN, PREFILL_KV_WRITES,
+    )
+
+    return {
+        w: PREFILL_KV_BLOCKS_WRITTEN.labels(write=w).value
+        for w in PREFILL_KV_WRITES
+    }
+
+
+def counted(run):
+    """``(run(), the blocks its chunks wrote by the write's form)``."""
+    c0 = kv_blocks_written()
+    out = run()
+    c1 = kv_blocks_written()
+    return out, {w: c1[w] - c0[w] for w in c0}
+
+
+def tiles_then_rows(run):
+    """``run()`` → served token lists, once as the program stands and once
+    with the row-wise chunk write: the chunks of the first wrote tiles only,
+    those of the second as many blocks row-wise, and both served the same
+    tokens. Returns them."""
+    tiles, n_tiles = counted(run)
+    with row_wise_chunk_write():
+        rows, n_rows = counted(run)
+    assert n_tiles["tile"] > 0 and n_tiles["rows"] == 0, n_tiles
+    assert n_rows == {"tile": 0, "rows": n_tiles["tile"]}, n_rows
+    assert tiles == rows
+    return tiles

@@ -112,6 +112,52 @@ def test_expert_call_timing_meets_the_experts_it_says(met, held):
     assert chip_smoke.time_moe(shape, met, "interpret", calls=2) > 0
 
 
+#: ``--kv-write``'s shapes at toy widths: key and value alike, a latent
+#: arena, keys wider than values over a pool too small to give every row
+#: blocks of its own (MiMo's window pool)
+KV_TOY = {
+    "alike": {"name": "toy", "heads": 2, "dk": 16, "dv": 16, "blocks": 40,
+              "layers": 3},
+    "latent": {"name": "toy_latent", "heads": 1, "dk": 24, "dv": 0,
+               "blocks": 40, "layers": 2},
+    "small_pool": {"name": "toy_swa", "heads": 2, "dk": 16, "dv": 8,
+                   "blocks": 5, "layers": 2},
+}
+
+
+@pytest.mark.parametrize("form", chip_smoke.KV_WRITE_FORMS)
+@pytest.mark.parametrize("shape", sorted(KV_TOY))
+def test_kv_write_forms_agree_and_are_timed(shape, form, monkeypatch):
+    """``chip_smoke.py --kv-write`` at toy widths: each form of a chunk's
+    K/V write (the tiles the program writes, the rows they replaced, the
+    loop of slices and the copy kernel timed beside them) leaves the arenas
+    as the row-wise write does outside block 0, and its timing loop runs (a
+    CPU time is no speed)."""
+    monkeypatch.setattr(
+        chip_smoke, "KV_CHUNK", {"rows": 2, "chunk": 32, "block_size": 8})
+    assert chip_smoke.check_kv_write(KV_TOY[shape], form, interpret=True)
+    assert chip_smoke.time_kv_write(
+        KV_TOY[shape], form, calls=2, interpret=True) > 0
+
+
+def test_kv_write_shapes_are_the_cells(monkeypatch):
+    """The shapes ``--kv-write`` times are those of the benchmark's five
+    configurations (MiMo's two kinds of layer each), at their pools."""
+    import json
+    import os
+
+    cfgs = os.path.join(chip_smoke.HERE, "benchmark", "configs")
+    got = {s["name"]: s for s in chip_smoke.KV_WRITE_SHAPES}
+    for name in ("olmoe_1b_7b", "qwen25_7b", "qwen25_14b_pp4",
+                 "gigachat31_702b_a36b"):
+        with open(os.path.join(cfgs, name + ".json")) as f:
+            serve = json.load(f)["serve"]
+        assert got[name]["blocks"] == serve["kv_blocks"], name
+        assert (serve["batch_per_slot"], serve["prefill_chunk"],
+                serve["kv_block_size"]) == tuple(chip_smoke.KV_CHUNK.values())
+    assert {"mimo_v25.swa", "mimo_v25.full"} <= set(got)
+
+
 def test_store_writer_driver_and_assertions_on_cpu(tmp_path, monkeypatch):
     """The smoke's daemon phase end to end at toy size: seeded store through
     the product's writer, the real ``serve`` daemon as a child, the smoke's

@@ -306,15 +306,16 @@ def test_what_is_not_done_is_refused_by_name(params):
 # PR 36 re-recorded both ``serve_prefill_chunk`` (the prefill kernel's grid is
 # its live cells, the program returns the walk's counters); PR 40 re-recorded
 # the three of ``olmoe`` (the expert kernel's grid is its live tiles, the
-# combines select them); ``qwen2``'s ``serve_chunk`` and ``serve_admit`` are
-# still the parent of PR 34's.
+# combines select them); PR 42 re-recorded both ``serve_prefill_chunk`` again
+# (a chunk writes its K/V as whole-block tiles); ``qwen2``'s ``serve_chunk``
+# and ``serve_admit`` are still the parent of PR 34's.
 GOLDEN = {
     ("qwen2", "serve_admit"): "41a2afe52004928f",
     ("qwen2", "serve_chunk"): "51598fb15a9f1c1a",
-    ("qwen2", "serve_prefill_chunk"): "ae47930ef924992e",
+    ("qwen2", "serve_prefill_chunk"): "1f5a149bea8b1099",
     ("olmoe", "serve_admit"): "ff5a01947e2fda27",
     ("olmoe", "serve_chunk"): "3fdd88d900928e52",
-    ("olmoe", "serve_prefill_chunk"): "9b6331278d0d9382",
+    ("olmoe", "serve_prefill_chunk"): "2578c200e397507d",
 }
 
 

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 from llm_sharding_tpu.runtime.engine import PipelineEngine
 from llm_sharding_tpu.runtime.generate import generate
 
+from paged_arena import tiles_then_rows
 from test_deepseek_v3 import CFG, params  # noqa: F401  (the fixture)
 
 
@@ -78,6 +79,27 @@ def test_serving_through_the_engine_latent_arena_prefix_cache_and_snapshot(
     assert list(revived.tokens) == list(
         res.tokens[0, len(prompts[1]):int(res.lengths[0])])
     back.close()
+
+
+def test_a_latent_chunk_writes_tiles_and_serves_what_the_rows_serve(
+        params, monkeypatch):
+    """The chunk write over the latent arena (one head of ``Dk`` lanes, no
+    values): chunked admissions — cold, and over a radix hit — write whole
+    blocks, and the tokens (the monolith's) are those of the row-wise
+    write, kernels interpreted."""
+    monkeypatch.setenv("PAGED_FORCE_KERNEL", "interpret")
+    eng = PipelineEngine(CFG, params, num_stages=1, cache_dtype=jnp.float32,
+                         devices=jax.devices()[:1])
+
+    def run():
+        srv, prompts, reqs = serve_and_check(eng, params)
+        again = srv.submit(
+            np.concatenate([prompts[2][:32], prompts[1]]), 6)  # a hit, chunked
+        srv.run_until_idle()
+        srv.close()
+        return [list(r.tokens) for r in (*reqs, again)]
+
+    tiles_then_rows(run)
 
 
 def test_extract_and_adopt_move_a_request_between_latent_arenas(

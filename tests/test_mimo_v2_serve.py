@@ -20,6 +20,7 @@ from llm_sharding_tpu.obs.metrics import REGISTRY
 from llm_sharding_tpu.runtime.engine import PipelineEngine
 from llm_sharding_tpu.runtime.generate import generate
 
+from paged_arena import tiles_then_rows
 from test_mimo_v2 import CFG, params  # noqa: F401  (the fixture)
 
 PAGED = dict(capacity=128, batch_per_slot=2, kv_block_size=4, kv_blocks=80,
@@ -132,6 +133,33 @@ def test_the_xla_path_commits_the_same_tokens(params, aligned):
     srv.run_until_idle()
     assert list(req.tokens) == oracle(CFG, params, prompt, 30)
     srv.close()
+
+
+@pytest.mark.parametrize("attn", ["xla", "interpret"])
+def test_a_chunk_writes_each_kinds_arena_as_tiles(params, monkeypatch, attn):
+    """The chunk write over a KV state per kind (keys wider than values; a
+    window layer's table maps the blocks behind the window and a short
+    row's pad blocks to block 0, where their tiles land): prompts of one,
+    two and three chunks write whole blocks into both kinds' arenas, and
+    the tokens — the monolith's — are those of the row-wise write."""
+    if attn == "interpret":
+        monkeypatch.setenv("PAGED_FORCE_KERNEL", "interpret")
+    eng = engine(params)
+    rng = np.random.default_rng(4)
+    prompts = [rng.integers(0, 250, size=n).astype(np.int32)
+               for n in (7, 30, 41)]
+
+    def run():
+        srv = eng.serve(paged_attn="auto" if attn == "interpret" else "xla",
+                        **PAGED)
+        assert srv.attn_impl == attn
+        reqs = [srv.submit(p, 12) for p in prompts]
+        srv.run_until_idle()
+        check_pools(srv)
+        srv.close()
+        return [list(r.tokens) for r in reqs]
+
+    assert tiles_then_rows(run) == [oracle(CFG, params, p, 12) for p in prompts]
 
 
 def test_the_bound_on_a_window_layers_blocks_holds_under_a_seeded_run(params):

@@ -189,6 +189,19 @@ def test_served_ids_equal_generate_and_pass_the_reference(params, served_one_sta
     assert not reference.verdict(scored, BLOCK), scored
 
 
+@pytest.mark.parametrize("stages", [1, 2])
+def test_chunks_write_tiles_and_serve_what_the_rows_serve(
+        params, served_one_stage, stages):
+    """The chunk write in the expert model's chunk program, on one stage and
+    on a ring of two (an inactive microstep's tiles go to block 0 of their
+    layer): the 40-token prompt's chunks write whole blocks, and the served
+    ids are those of the row-wise write."""
+    from paged_arena import tiles_then_rows
+
+    ids = tiles_then_rows(lambda: served(params, PROMPTS, NEW, stages)[0])
+    assert ids == served_one_stage[0]
+
+
 def test_one_stage_equals_a_ring_of_two(params, served_one_stage):
     ids, recs = served(params, PROMPTS, NEW, stages=2)
     assert ids == served_one_stage[0]

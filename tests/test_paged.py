@@ -690,6 +690,111 @@ def test_an_invalid_entry_lands_in_the_trash_of_its_own_layer(valid_case, S):
     np.testing.assert_array_equal(out[0], out[1])
 
 
+#: the arenas a chunk writes: key and value widths alike (llama, gpt2,
+#: OLMoE), a latent arena that holds no values (deepseek_v3), keys wider
+#: than values (mimo_v2: 192 stored beside 128)
+_CHUNK_ARENAS = {"alike": (8, 8), "latent": (8, 0), "unlike": (12, 8)}
+#: the tables a chunk meets: every row with blocks of its own; block 0 in
+#: the chunk's range (a padded row of the slot, a short row's pad blocks, a
+#: window layer's freed block)
+_CHUNK_TABLES = {
+    "owned": [[2, 3, 4, 5, 6], [7, 8, 9, 10, 11], [12, 13, 14, 15, 16]],
+    "trash_in_range": [[2, 3, 0, 5, 0], [0, 0, 0, 0, 0], [12, 0, 14, 15, 16]],
+}
+
+
+def _chunk_case(arena, table, NB=17, bs=4, Nkv=2, B=3, Sc=8, seed=5):
+    rng = np.random.default_rng(seed)
+    D, Dv = _CHUNK_ARENAS[arena]
+    k, _ = make_stack(rng, NB, Nkv, bs, D)
+    v, _ = make_stack(rng, NB, Nkv, bs, Dv)
+    kn = jnp.asarray(rng.normal(size=(B, Sc, Nkv, D)), jnp.float32)
+    vn = jnp.asarray(rng.normal(size=(B, Sc, Nkv, Dv)), jnp.float32)
+    return k, v, jnp.asarray(_CHUNK_TABLES[table], jnp.int32), kn, vn
+
+
+def _chunk_cols(col0, B, Sc):
+    return jnp.broadcast_to(
+        col0 + jnp.arange(Sc, dtype=jnp.int32)[None, :], (B, Sc)
+    )
+
+
+@pytest.mark.parametrize("valid", (None, True, False))
+@pytest.mark.parametrize("table", sorted(_CHUNK_TABLES))
+@pytest.mark.parametrize("arena", sorted(_CHUNK_ARENAS))
+@pytest.mark.parametrize("col0", (0, 12))
+@pytest.mark.parametrize("layer", (0, LAYERS - 1))
+def test_a_chunk_written_as_tiles_leaves_what_the_rows_leave(
+    layer, col0, arena, table, valid
+):
+    """``write_chunk_kv`` against ``write_block_kv`` on the same chunk:
+    both arenas equal bit for bit in every block a table can own (1 ...),
+    every other layer untouched, and under ``valid=False`` no owned block
+    changed at all — at the chunk's first column 0 and at a later block,
+    with block 0 inside the chunk's range, for a latent arena and for keys
+    wider than values."""
+    from llm_sharding_tpu.ops.paged_attention import (
+        chunk_writes_tiles, write_block_kv, write_chunk_kv,
+    )
+
+    k, v, tbl, kn, vn = _chunk_case(arena, table)
+    B, Sc = kn.shape[:2]
+    assert chunk_writes_tiles(Sc, k.shape[3], False)
+    gate = None if valid is None else jnp.asarray(valid)
+    got = jax.jit(write_chunk_kv)(
+        k, v, layer, tbl, jnp.asarray(col0, jnp.int32), kn, vn, gate
+    )
+    want = write_block_kv(
+        k, v, layer, tbl, _chunk_cols(col0, B, Sc), kn, vn, valid=gate
+    )
+    for before, a, w in zip((k, v), got, want):
+        a, w = np.asarray(a), np.asarray(w)
+        assert a.shape == w.shape == before.shape
+        np.testing.assert_array_equal(a[:, 1:], w[:, 1:])
+        others_untouched(before, a, layer)
+        if valid is False:
+            np.testing.assert_array_equal(
+                a[:, 1:], np.asarray(before)[:, 1:]
+            )
+    if valid is not False and arena != "latent" and table == "owned":
+        # the tiles are the chunk's own entries, block by block
+        bs, j0 = k.shape[3], col0 // k.shape[3]
+        np.testing.assert_array_equal(
+            window(got[1], layer, tbl)[:, j0 * bs: j0 * bs + Sc],
+            np.asarray(vn),
+        )
+
+
+@pytest.mark.parametrize("case", ("under_a_block", "int8_arena"))
+def test_a_chunk_that_cannot_be_tiles_takes_the_row_wise_write(case):
+    """What the chunk program can see statically decides the form: a chunk
+    that is not whole blocks, and a quantized arena (its running per-block
+    scales), are written by ``write_block_kv`` itself — the tile scatter
+    is not in their program."""
+    from llm_sharding_tpu.ops import paged_attention as pa
+
+    k, v, tbl, kn, vn = _chunk_case("alike", "owned")
+    col0, scales = jnp.asarray(4, jnp.int32), {}
+    if case == "under_a_block":
+        kn, vn = kn[:, :2], vn[:, :2]
+        assert not pa.chunk_writes_tiles(2, k.shape[3], False)
+    else:
+        k, v, scales = int8_stack(np.random.default_rng(6), k, v)
+        assert not pa.chunk_writes_tiles(kn.shape[1], k.shape[3], True)
+    B, Sc = kn.shape[:2]
+    with mock.patch.object(
+        pa, "write_block_kv", wraps=pa.write_block_kv
+    ) as rows:
+        got = pa.write_chunk_kv(k, v, 1, tbl, col0, kn, vn, **scales)
+    assert rows.call_count == 1
+    want = pa.write_block_kv(
+        k, v, 1, tbl, _chunk_cols(col0, B, Sc), kn, vn, **scales
+    )
+    assert len(got) == len(want) == (4 if scales else 2)
+    for a, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(w))
+
+
 def test_a_masked_layer_writes_to_its_own_trash_block():
     """A padding layer of a stage (``layer_mask`` False) runs the block
     and discards it: its entries must land in block 0 of ITS layer index —
@@ -1136,6 +1241,65 @@ def test_kernels_compile_for_a_described_v5e(v5e_chip, cell, kernel, store):
     arena_elems = Lp * NB * Nkv * BS * D
     for m in re.finditer(r"= \w+\[([\d,]+)\][^ ]* (copy|transpose)\(", text):
         assert np.prod([int(x) for x in m.group(1).split(",")]) < arena_elems
+
+
+#: a chunk's write at the cells' arena entries: key/value heads, the lanes
+#: of a stored key and of a value (0: the latent arena holds none; MiMo's
+#: window layers store a key of 192 in 256 lanes beside a value of 128)
+_CHUNK_WRITE_SHAPES = {
+    "olmoe_1b_7b": (16, 128, 128), "qwen25_7b": (4, 128, 128),
+    "gigachat31_702b_a36b": (1, 640, 0), "mimo_v25_swa": (8, 256, 128),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_CHUNK_WRITE_SHAPES))
+def test_a_chunks_tile_write_leaves_the_carried_stack_where_it_lies(
+        v5e_chip, cell):
+    """``write_chunk_kv`` inside a scan that carries both arenas, as the
+    layer scan does, compiled for the described v5e: the only operations
+    whose result is as large as an arena are the scatters themselves — no
+    copy, transpose or select of the stack (what a scatter with a
+    non-contiguous window costs: ``write_block_kv``'s note) — and the
+    program's temporaries stay far under one arena."""
+    from llm_sharding_tpu.ops.paged_attention import write_chunk_kv
+
+    Nkv, Dk, Dv = _CHUNK_WRITE_SHAPES[cell]
+    B, Sc, BS, T, Lp, NB = 4, 256, 32, 128, 3, 260
+    S = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=v5e_chip
+    )
+
+    def run(k_arena, v_arena, table, col0, k_new, v_new, valid):
+        def one(carry, layer):
+            return write_chunk_kv(
+                *carry, layer, table, col0, k_new, v_new, valid=valid
+            ), None
+        return jax.lax.scan(
+            one, (k_arena, v_arena), jnp.arange(Lp, dtype=jnp.int32)
+        )[0]
+
+    compiled = jax.jit(run, donate_argnums=(0, 1)).lower(
+        S((Lp, NB, Nkv, BS, Dk), jnp.bfloat16),
+        S((Lp, NB, Nkv, BS, Dv), jnp.bfloat16), S((B, T), jnp.int32),
+        S((), jnp.int32), S((B, Sc, Nkv, Dk), jnp.bfloat16),
+        S((B, Sc, Nkv, Dv), jnp.bfloat16), S((), jnp.bool_),
+    ).compile()
+    text = compiled.as_text()
+    arena_elems = Lp * NB * Nkv * BS * min(d for d in (Dk, Dv) if d)
+    big = [
+        # an arena-sized fusion is the scatter's own (in place), no loop
+        "scatter" if m.group(2) == "fusion" and "kind=kCustom" in m.group(0)
+        else m.group(2)
+        for m in re.finditer(
+            r"= \w+\[([\d,]+)\][^ ]* ([\w-]+)\(.*", text)
+        if np.prod([int(x) for x in m.group(1).split(",")]) >= arena_elems
+    ]
+    assert "scatter" in big
+    assert set(big) <= {
+        "scatter", "parameter", "get-tuple-element", "bitcast", "while",
+        "tuple",
+    }, sorted(set(big))
+    assert compiled.memory_analysis().temp_size_in_bytes < arena_elems // 4
 
 
 @pytest.mark.parametrize("walk", ["built_in_the_op", "handed_in"])

@@ -35,6 +35,8 @@ from llm_sharding_tpu.ops.quant import fp8_kv_supported, kv_qmax, kv_quantize
 from llm_sharding_tpu.runtime.engine import PipelineEngine
 from llm_sharding_tpu.runtime.generate import generate
 
+from paged_arena import counted, row_wise_chunk_write
+
 CFG = tiny_llama(num_hidden_layers=8, max_position_embeddings=512)
 BS = int(os.environ.get("PAGED_TEST_BLOCK_SIZE", "8"))
 CAP = 256
@@ -497,6 +499,96 @@ def test_radix_chunked_quantized_token_match(setup):
         return r.tokens
 
     assert run("hbm") == run("off")
+
+
+@pytest.mark.parametrize("attn", ["xla", "interpret"])
+def test_tile_write_serves_the_tokens_of_the_row_wise_write(
+    setup, monkeypatch, attn
+):
+    """A cold chunked admission of four chunks, then a radix-hit admission
+    whose chunks start at ``prefix_off`` > 0: with the chunks' K/V written
+    as whole-block tiles the served tokens are the row-wise write's (and
+    the oracle's), the shared prefix's blocks hold the same bytes after
+    the hit's chunks ran as before, and the counter and the step records
+    say which form each run's chunks took."""
+    params, eng = setup
+    if attn == "interpret":
+        monkeypatch.setenv("PAGED_FORCE_KERNEL", "interpret")
+    else:
+        monkeypatch.delenv("PAGED_FORCE_KERNEL", raising=False)
+    shared = prompt(61, 24)
+    cold = np.concatenate([shared, prompt(62, 32)])  # bucket 64: 4 chunks
+    hit = np.concatenate([shared, prompt(63, 40)])
+
+    def run():
+        srv = serve(
+            eng, prefix_cache="hbm",
+            paged_attn="auto" if attn == "interpret" else "xla",
+        )
+        assert srv.attn_impl == attn
+        toks = drive(srv, [srv.submit(cold, max_new_tokens=6)])
+        n = srv._radix.match_tokens(hit)
+        assert n == 24 // BS * BS
+        blocks = [
+            b for node, used in srv._radix._walk(hit, n)
+            for b in node.blocks[: used // BS]
+        ]
+        before = [
+            np.asarray(a)[:, :, blocks] for a in (srv.state.k, srv.state.v)
+        ]
+        hit0 = srv._radix.hit_tokens
+        toks += drive(srv, [srv.submit(hit, max_new_tokens=6)])
+        assert srv._radix.hit_tokens - hit0 == n
+        for b, a in zip(before, (srv.state.k, srv.state.v)):
+            np.testing.assert_array_equal(np.asarray(a)[:, :, blocks], b)
+        recs = [
+            r["prefill_kv_blocks"] for r in srv.stepline.snapshot()
+            if "prefill_kv_blocks" in r
+        ]
+        srv._alloc.check()
+        srv.close()
+        return toks, recs
+
+    (tiles, rec_tiles), n_tiles = counted(run)
+    with row_wise_chunk_write():
+        (rows, rec_rows), n_rows = counted(run)
+    assert tiles == rows == [oracle(params, cold, 6), oracle(params, hit, 6)]
+    # 4 + 4 chunks (the hit's suffix of 40 + 24 % BS buckets to 64) of one row
+    assert n_tiles == {"tile": 8 * CHUNK // BS, "rows": 0}
+    assert n_rows == {"tile": 0, "rows": 8 * CHUNK // BS}
+    assert sum(r.get("tile", 0) for r in rec_tiles) == n_tiles["tile"]
+    assert all(set(r) == {"tile"} for r in rec_tiles)
+    assert all(set(r) == {"rows"} for r in rec_rows)
+
+
+def test_a_chunk_under_a_block_is_counted_as_rows(setup):
+    """A chunk shorter than a block (here by ``prefill_chunk``; in a
+    deployment a context-parallel radix admission whose suffix bucket is
+    under a block) cannot be whole-block tiles: the row-wise write serves
+    it, token for token, and the counter says ``rows``."""
+    params, eng = setup
+    srv = serve(eng, prefill_chunk=BS // 2)
+    p = prompt(71, 2 * BS - 3)  # bucket 2 * BS: four chunks of half a block
+    toks, n = counted(lambda: drive(srv, [srv.submit(p, max_new_tokens=5)]))
+    assert toks == [oracle(params, p, 5)]
+    assert n == {"tile": 0, "rows": 4}
+    srv.close()
+
+
+def test_a_tile_chunk_refuses_a_start_inside_a_block(setup):
+    """What the tiles rest on, stated by the host: a chunk of whole blocks
+    starts on a block boundary. The row-wise write forgave a start inside
+    a block; a tile there would overwrite the head of the block before —
+    a shared prefix block."""
+    _, eng = setup
+    srv = serve(eng)
+    with pytest.raises(ValueError, match="block boundary"):
+        srv._admit_chunked(
+            0, np.zeros((srv.batch_per_slot, 2 * CHUNK), np.int32),
+            *[None] * 8, prefix_off=BS + 1,
+        )
+    assert not srv._admitting_rows
+    srv.close()
 
 
 def test_prefill_path_metrics(setup):

@@ -954,6 +954,24 @@ def test_prefill_cells_reach_the_record():
     assert p.end_step().prefill_cells_walked == 0
 
 
+def test_prefill_kv_blocks_reach_the_record_by_the_writes_form():
+    p = StepProfiler(name="t-prefill-kv-blocks")
+    p.begin_step(rows=1)
+    p.prefill_kv_blocks("tile", 32)
+    p.prefill_kv_blocks("tile", 8)
+    p.prefill_kv_blocks("rows", 2)
+    rec = p.end_step(rows=1)
+    assert rec.prefill_kv_blocks == {"tile": 40, "rows": 2}
+    assert rec.to_dict()["prefill_kv_blocks"] == {"tile": 40, "rows": 2}
+    # a step that dispatched no chunk carries no such key; outside a step
+    # nothing is counted
+    p.begin_step()
+    assert "prefill_kv_blocks" not in p.end_step().to_dict()
+    p.prefill_kv_blocks("tile", 1)
+    p.begin_step()
+    assert p.end_step().prefill_kv_blocks is None
+
+
 @pytest.mark.parametrize("stages", [1, 2])
 def test_a_one_request_slot_of_four_walks_a_fraction_of_its_rectangle(
         params, stages, monkeypatch):
