@@ -35,6 +35,10 @@ class RopeScaling:
     truncate: bool = True
 
 
+#: ``nemotron_h``: a character of ``hybrid_override_pattern`` → the layer's kind
+NEMOTRON_KINDS = {"M": "mamba", "E": "moe", "*": "attn"}
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     """Architecture hyper-parameters for a causal LM.
@@ -118,6 +122,27 @@ class ModelConfig:
     attention_value_scale: float = 1.0
     swa_sink: bool = False
     full_sink: bool = False
+    # ``nemotron_h`` (models/nemotron_h.py): every layer is ONE sub-block,
+    # ``layer_pattern[l]`` naming it — ``M`` a Mamba-2 mixer, ``E`` a LatentMoE
+    # feed-forward, ``*`` attention (no rotary embedding). The mixer:
+    # ``mamba_num_heads`` heads of ``mamba_head_dim``, a state of
+    # ``ssm_state_size`` a head value, ``ssm_groups`` groups sharing ``B`` / ``C``,
+    # a causal depthwise conv of ``conv_kernel`` taps, the block form over
+    # ``ssm_chunk`` positions. The experts live in a ``moe_latent_size``-wide
+    # space (one projection down, one up), not gated (``relu2``), beside one
+    # shared expert of ``moe_shared_intermediate_size`` on the full width.
+    # ``time_step_min`` / ``_max`` only seed ``dt_bias`` (initialisation).
+    layer_pattern: str = ""
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state_size: int = 0
+    ssm_groups: int = 1
+    conv_kernel: int = 4
+    ssm_chunk: int = 128
+    moe_latent_size: int = 0
+    moe_shared_intermediate_size: int = 0
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
     # GPT-2 specifics
     layer_norm_epsilon: float = 1e-5
     # Token ids. ``eos_token_ids`` holds ALL stop ids (Llama-3.x instruct
@@ -157,6 +182,34 @@ class ModelConfig:
         """Some layers attend a sliding window: they keep a KV state of their
         own (an arena and a block table per kind of attention)."""
         return self.sliding_window > 0 and any(self.layer_attn)
+
+    @property
+    def recurrent(self) -> bool:
+        """Some layers keep a recurrent state of FIXED size a request
+        (``nemotron_h``'s Mamba-2 mixers): indexed by row beside the paged
+        arenas, not paged by token."""
+        return "M" in self.layer_pattern
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the mixer's conv runs over: ``[x | B | C]``."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state_size
+
+    @property
+    def recurrent_row_bytes(self) -> int:
+        """Bytes ONE request's recurrent state holds in ONE mixer layer
+        (float32): the state ``[heads, head_dim, state]`` and the conv's last
+        ``conv_kernel - 1`` inputs."""
+        if not self.recurrent:
+            return 0
+        return 4 * (
+            self.ssm_inner * self.ssm_state_size
+            + (self.conv_kernel - 1) * self.conv_dim
+        )
 
     @property
     def cache_heads(self) -> int:
@@ -215,6 +268,8 @@ class ModelConfig:
                 ("moe" if m else "dense") + ("_swa" if a else "_full")
                 for a, m in zip(self.layer_attn, self.layer_moe)
             )
+        if self.model_type == "nemotron_h":
+            return tuple(NEMOTRON_KINDS[c] for c in self.layer_pattern)
         if self.model_type != "deepseek_v3":
             return ()
         k = self.first_k_dense_replace
@@ -307,6 +362,8 @@ class ModelConfig:
             return cls._from_deepseek_v3(hf)
         if mt == "mimo_v2":
             return cls._from_mimo_v2(hf)
+        if mt == "nemotron_h":
+            return cls._from_nemotron_h(hf)
         if mt in ("llama",):
             act = hf.get("hidden_act", "silu")
             if act not in ("silu", "gelu_tanh"):
@@ -611,6 +668,137 @@ class ModelConfig:
         )
 
 
+    @classmethod
+    def _from_nemotron_h(cls, hf: dict[str, Any]) -> "ModelConfig":
+        """``nemotron_h`` as Nemotron-3-Super-120B-A12B publishes it: the
+        first ``num_hidden_layers`` characters of ``hybrid_override_pattern``
+        name each layer's ONE sub-block (``M`` Mamba-2 mixer, ``E`` LatentMoE,
+        ``*`` attention; ``-``, a plain MLP, is refused). Read: the mixer's
+        ``mamba_num_heads`` / ``mamba_head_dim`` / ``ssm_state_size`` /
+        ``n_groups`` / ``conv_kernel`` / ``chunk_size`` (``expand`` is checked
+        against heads x head size); the experts' ``n_routed_experts``,
+        ``num_experts_per_tok``, ``moe_intermediate_size``,
+        ``moe_latent_size``, ``moe_shared_expert_intermediate_size``,
+        ``routed_scaling_factor``, ``n_group`` / ``topk_group``; attention's
+        head counts and ``head_dim``; ``layer_norm_epsilon`` (= ``norm_eps``).
+        Beside them a chip's share of the experts as ``deepseek_v3`` has it
+        (``n_routed_experts`` HELD of ``n_routed_experts_total``,
+        ``ep_rank``). Kept and NOT read, each for its reason: ``rope_theta``,
+        ``partial_rotary_factor`` (the ``nemotron_h`` attention block applies
+        no rotary embedding), ``use_mamba_kernels`` (names an implementation),
+        ``moe_shared_expert_overlap`` (a schedule), ``rescale_prenorm_residual``,
+        ``time_step_floor`` (initialisation; ``time_step_min`` / ``_max`` are
+        kept for ``init_params``), ``num_logits_to_keep`` (a generation
+        option), ``max_position_embeddings`` beyond the request check,
+        ``intermediate_size`` (the ``-`` block's width: no such layer),
+        ``mtp_hybrid_override_pattern`` (read only with the module it
+        describes). What is not done is refused by name."""
+        L = int(hf["num_hidden_layers"])
+        need = (
+            "hybrid_override_pattern", "mamba_num_heads", "mamba_head_dim",
+            "ssm_state_size", "n_groups", "n_routed_experts",
+            "num_experts_per_tok", "moe_intermediate_size", "moe_latent_size",
+            "moe_shared_expert_intermediate_size", "head_dim",
+        )
+        for key in need:
+            if hf.get(key) is None:
+                raise ValueError(f"nemotron_h config.json lacks {key!r}")
+        pattern = str(hf["hybrid_override_pattern"])[:L]
+        if len(pattern) != L or set(pattern) - set(NEMOTRON_KINDS):
+            raise ValueError(
+                f"nemotron_h hybrid_override_pattern {pattern!r}: {L} layers "
+                "need that many of 'M' (Mamba-2), 'E' (LatentMoE) and '*' "
+                "(attention); '-' (a plain MLP layer) is not supported"
+            )
+        refuse = {
+            "mamba_hidden_act": ("silu",), "mlp_hidden_act": ("relu2",),
+            "attention_bias": (False,), "mlp_bias": (False,),
+            "mamba_proj_bias": (False,), "use_bias": (False,),
+            "use_conv_bias": (True,), "norm_topk_prob": (True,),
+            "tie_word_embeddings": (False,), "n_group": (1,),
+            "topk_group": (1,), "n_shared_experts": (1,),
+            "residual_in_fp32": (False,), "sliding_window": (None,),
+            "num_nextn_predict_layers": (0,), "time_step_limit": (None,),
+            "expand": (
+                int(hf["mamba_num_heads"]) * int(hf["mamba_head_dim"])
+                // int(hf["hidden_size"]),
+            ),
+        }
+        for key, ok in refuse.items():
+            if key in hf and hf[key] not in ok:
+                raise ValueError(
+                    f"nemotron_h {key}={hf[key]!r} is not supported (only "
+                    f"{', '.join(map(repr, ok))})"
+                    + (": the multi-token-prediction module is not built"
+                       if key == "num_nextn_predict_layers" else "")
+                )
+        heads, groups = int(hf["mamba_num_heads"]), int(hf["n_groups"])
+        if heads % groups or heads * int(hf["mamba_head_dim"]) % groups:
+            raise ValueError(
+                f"nemotron_h n_groups {groups} does not divide "
+                f"mamba_num_heads {heads}"
+            )
+        eps = hf.get("layer_norm_epsilon", hf.get("norm_eps", 1e-5))
+        if hf.get("norm_eps", eps) != eps:
+            raise ValueError(
+                f"nemotron_h norm_eps {hf['norm_eps']!r} differs from "
+                f"layer_norm_epsilon {eps!r}: one epsilon serves every norm"
+            )
+        held = int(hf["n_routed_experts"])
+        total = int(hf.get("n_routed_experts_total", held))
+        rank = int(hf.get("ep_rank", 0))
+        if total % held or not 0 <= rank < total // held:
+            raise ValueError(
+                f"nemotron_h share: n_routed_experts {held} held of "
+                f"n_routed_experts_total {total}, ep_rank {rank}: the held "
+                f"count must divide the total and the rank lie in "
+                f"0..{total // max(held, 1) - 1}"
+            )
+        eos = hf.get("eos_token_id", 2)
+        eos_ids = tuple(eos) if isinstance(eos, list) else (eos,)
+        scale = hf.get("routed_scaling_factor")
+        return cls(
+            model_type="nemotron_h",
+            vocab_size=hf["vocab_size"],
+            hidden_size=hf["hidden_size"],
+            intermediate_size=int(hf["moe_shared_expert_intermediate_size"]),
+            num_hidden_layers=L,
+            num_attention_heads=hf["num_attention_heads"],
+            num_key_value_heads=hf["num_key_value_heads"],
+            head_dim=int(hf["head_dim"]),
+            max_position_embeddings=hf.get("max_position_embeddings", 4096),
+            rms_norm_eps=float(eps),
+            num_experts=total,
+            num_experts_per_tok=int(hf["num_experts_per_tok"]),
+            norm_topk_prob=True,
+            moe_intermediate_size=int(hf["moe_intermediate_size"]),
+            n_shared_experts=1,
+            n_group=1,
+            topk_group=1,
+            routed_scaling_factor=1.0 if scale is None else float(scale),
+            experts_held=held,
+            ep_rank=rank,
+            layer_pattern=pattern,
+            mamba_num_heads=heads,
+            mamba_head_dim=int(hf["mamba_head_dim"]),
+            ssm_state_size=int(hf["ssm_state_size"]),
+            ssm_groups=groups,
+            conv_kernel=int(hf.get("conv_kernel", 4)),
+            ssm_chunk=int(hf.get("chunk_size", 128)),
+            moe_latent_size=int(hf["moe_latent_size"]),
+            moe_shared_intermediate_size=int(
+                hf["moe_shared_expert_intermediate_size"]
+            ),
+            time_step_min=float(hf.get("time_step_min", 0.001)),
+            time_step_max=float(hf.get("time_step_max", 0.1)),
+            bos_token_id=(
+                1 if hf.get("bos_token_id") is None else hf["bos_token_id"]
+            ),
+            eos_token_id=eos_ids[0],
+            eos_token_ids=eos_ids,
+        )
+
+
 # Convenience presets (sizes mirror the models the reference targets:
 # Llama-2-7B / Llama-3.2-3B / GPT-2, /root/reference/README.md + model_sharder.py)
 def llama2_7b() -> ModelConfig:
@@ -886,6 +1074,67 @@ def tiny_mimo_v2(**kw) -> ModelConfig:
     and an expert full-attention layer (1 head); keys of 24 (rotary on the
     first 8), values of 16."""
     return ModelConfig.from_hf_config(tiny_mimo_v2_keys(**kw))
+
+
+def nemotron3_super_keys(**kw) -> dict:
+    """Nemotron-3-Super-120B-A12B's published ``config.json`` keys (88 layers,
+    512 experts; the multi-token-prediction module, which is not built, set
+    to 0 of the published 1)."""
+    base = dict(
+        model_type="nemotron_h",
+        attention_bias=False, chunk_size=128, conv_kernel=4, expand=2,
+        head_dim=128, hidden_size=4096,
+        hybrid_override_pattern=(
+            "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+            "EMEMEMEMEM*EMEMEMEM*EMEMEMEME"
+        ),
+        intermediate_size=2688, layer_norm_epsilon=1e-5, mamba_head_dim=64,
+        mamba_hidden_act="silu", mamba_num_heads=128, mamba_proj_bias=False,
+        max_position_embeddings=262144, mlp_bias=False, mlp_hidden_act="relu2",
+        moe_intermediate_size=2688, moe_latent_size=1024,
+        moe_shared_expert_intermediate_size=5376,
+        moe_shared_expert_overlap=False, mtp_hybrid_override_pattern="*E",
+        n_group=1, n_groups=8, n_routed_experts=512, n_shared_experts=1,
+        norm_eps=1e-5, norm_topk_prob=True, num_attention_heads=32,
+        num_experts_per_tok=22, num_hidden_layers=88, num_key_value_heads=2,
+        num_logits_to_keep=1, num_nextn_predict_layers=0,
+        partial_rotary_factor=1, rescale_prenorm_residual=True,
+        residual_in_fp32=False, rope_theta=10000, routed_scaling_factor=5,
+        sliding_window=None, ssm_state_size=128, tie_word_embeddings=False,
+        time_step_floor=0.0001, time_step_max=0.1, time_step_min=0.001,
+        topk_group=1, use_bias=False, use_conv_bias=True,
+        use_mamba_kernels=True, vocab_size=131072,
+    )
+    base.update(kw)
+    return base
+
+
+def nemotron3_super_120b_a12b(**kw) -> ModelConfig:
+    return ModelConfig.from_hf_config(nemotron3_super_keys(**kw))
+
+
+def tiny_nemotron_h_keys(**kw) -> dict:
+    """The published-style keys of ``tiny_nemotron_h``."""
+    base = nemotron3_super_keys(
+        vocab_size=256, hidden_size=64, num_hidden_layers=6,
+        hybrid_override_pattern="MEM*EM", num_attention_heads=4,
+        num_key_value_heads=2, head_dim=16, mamba_num_heads=8,
+        mamba_head_dim=16, ssm_state_size=16, n_groups=2, chunk_size=8,
+        n_routed_experts=8, num_experts_per_tok=3, moe_intermediate_size=24,
+        moe_latent_size=32, moe_shared_expert_intermediate_size=48,
+        intermediate_size=24, routed_scaling_factor=2.5,
+        max_position_embeddings=256, eos_token_id=255,
+    )
+    base.update(kw)
+    return base
+
+
+def tiny_nemotron_h(**kw) -> ModelConfig:
+    """Tiny nemotron_h-layout config for CPU tests: three Mamba-2 mixers (8
+    heads of 16, a state of 16, 2 groups, blocks of 8 positions), two
+    LatentMoE layers (8 experts of 24 in a latent space of 32, 3 a token, a
+    shared expert of 48) and one attention layer (4 heads, 2 key/value)."""
+    return ModelConfig.from_hf_config(tiny_nemotron_h_keys(**kw))
 
 
 def tiny_qwen2(**kw) -> ModelConfig:

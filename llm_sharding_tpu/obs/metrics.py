@@ -692,6 +692,32 @@ PREFILL_KV_BLOCKS_WRITTEN = REGISTRY.counter(
     "prefill writes that took the block-sized form",
     labels=("write",),
 )
+# a model with recurrent layers (models/nemotron_h.py): a state of fixed size
+# a request, indexed by row beside the paged arena
+RECURRENT_ROWS_IN_USE = REGISTRY.gauge(
+    "server_recurrent_rows_in_use",
+    "Rows holding a live request's recurrent state (a Mamba-2 mixer's "
+    "float32 state and conv tail in every mixer layer) across live servers "
+    "of a model with recurrent layers; host-side, from the rows in flight",
+)
+RECURRENT_ROW_BYTES = REGISTRY.gauge(
+    "server_recurrent_row_bytes",
+    "Bytes ONE request's recurrent state holds over ALL of a stage's mixer "
+    "layers (layers x (heads x head_dim x state + (kernel - 1) x conv_dim) "
+    "x 4) of the newest server of a model with recurrent layers: fixed, "
+    "whatever the context",
+)
+PREFILL_SCAN_KINDS = ("real", "pad")
+PREFILL_SCAN_POSITIONS = REGISTRY.counter(
+    "server_prefill_scan_positions_total",
+    "Positions a chunked-prefill dispatch puts through the block-form "
+    "state-space scan, per mixer layer (slot rows x chunk a dispatch; "
+    "host-side): kind=real — prompt tokens, which advance the state; "
+    "kind=pad — padding (a short row, an empty row of the slot), which "
+    "has dt = 0 and leaves it as it was. pad / (real + pad) is the scan's "
+    "wasted share",
+    labels=("kind",),
+)
 PREFILL_CELLS_LIVE = REGISTRY.counter(
     "server_prefill_cells_live_total",
     "Cells the chunked-prefill kernel walked, summed over the layer calls "

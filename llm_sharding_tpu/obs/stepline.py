@@ -133,6 +133,13 @@ SCOPES = (
     "router",     # a sparse MLP's router: float32 logits, softmax, top-k
     "moe",        # a sparse MLP's expert product: tiles, the expert kernel
                   # (``moe_experts``), the weighted sum, its counters
+    "ssm_proj",   # a Mamba-2 mixer's two projections, ``w_in`` and ``w_out``
+                  # with its residual add (models/nemotron_h.py)
+    "conv",       # the mixer's causal depthwise conv, its tail shift, silu
+    "ssm",        # the state update (a decode step) or the block-form scan
+                  # (a prefill chunk), its read-out, the gated grouped norm
+    "moe_latent", # a LatentMoE's two projections, into the experts' latent
+                  # space and out of it
     "head",       # final norm + this stage's logit slice
     "sample",     # argmax assembly / per-row sampling over the logits
     "ring_hop",   # stage->stage ppermute and the last stage's broadcast
@@ -216,7 +223,7 @@ class StepRecord:
         "expert_tokens", "experts_read", "expert_steps", "expert_rows",
         "decode_blocks_live", "decode_blocks_reserved",
         "prefill_cells_live", "prefill_cells_walked", "kv_kinds",
-        "prefill_kv_blocks",
+        "prefill_kv_blocks", "recurrent_rows", "scan_positions",
     )
 
     def __init__(self, ts, wall_s, phases, blocked_s, idle_s,
@@ -273,6 +280,13 @@ class StepRecord:
         # fresh K/V landed in, by the form of the write ("tile" / "rows");
         # None in a step that dispatched no chunk
         self.prefill_kv_blocks = None
+        # a model with recurrent layers (None otherwise): rows holding a
+        # recurrent state at this step's decode dispatch, and the positions
+        # its prefill chunks put through the block-form scan, ``{"real":
+        # prompt tokens, "pad": padding}`` per mixer layer (host arithmetic
+        # at dispatch)
+        self.recurrent_rows = None
+        self.scan_positions = None
 
     @property
     def host_s(self) -> float:
@@ -307,6 +321,10 @@ class StepRecord:
             d["kv_kinds"] = {k: dict(v) for k, v in self.kv_kinds.items()}
         if self.prefill_kv_blocks is not None:
             d["prefill_kv_blocks"] = dict(self.prefill_kv_blocks)
+        if self.recurrent_rows is not None:
+            d["recurrent_rows"] = self.recurrent_rows
+        if self.scan_positions is not None:
+            d["scan_positions"] = dict(self.scan_positions)
         if self.expert_tokens is not None:
             d["expert_tokens"] = list(self.expert_tokens)
             d["experts_read"] = list(self.experts_read)
@@ -357,6 +375,8 @@ class StepProfiler:
         self._decode_blocks = [0, 0]  # [live, reserved]
         self._prefill_cells = [0, 0]  # [live, walked]
         self._prefill_kv_blocks = None  # {"tile" | "rows": blocks}
+        self._recurrent_rows = None
+        self._scan_positions = None  # {"real" | "pad": positions}
         self._kv_kinds = None
         self._phases: Dict[str, float] = {}
         self._blocked_s = 0.0
@@ -462,6 +482,8 @@ class StepProfiler:
         self._decode_blocks = [0, 0]
         self._prefill_cells = [0, 0]
         self._prefill_kv_blocks = None
+        self._recurrent_rows = None
+        self._scan_positions = None
         self._kv_kinds = None
         work = bool(rows or queued or pending)
         if self._annotate is not None and (work or self._had_work):
@@ -606,6 +628,23 @@ class StepProfiler:
         acc = self._prefill_kv_blocks
         acc[write] = acc.get(write, 0) + int(blocks)
 
+    def recurrent_rows(self, rows: int) -> None:
+        """Record the rows holding a recurrent state at a decode dispatch
+        (a gauge: the step's newest)."""
+        if not self._enabled or self._t0 is None:
+            return
+        self._recurrent_rows = int(rows)
+
+    def scan_positions(self, real: int, pad: int) -> None:
+        """Add one chunk dispatch's positions through the block-form scan
+        to the step's record: prompt tokens and padding."""
+        if not self._enabled or self._t0 is None:
+            return
+        acc = self._scan_positions or {"real": 0, "pad": 0}
+        acc["real"] += int(real)
+        acc["pad"] += int(pad)
+        self._scan_positions = acc
+
     def experts(self, tokens, read=None, steps: int = 0, rows: int = 0) -> None:
         """Add a fetched set of expert counters to the step's record:
         ``tokens`` [E] per expert; for decode microsteps also ``read`` [L]
@@ -683,6 +722,8 @@ class StepProfiler:
         )
         rec.kv_kinds = self._kv_kinds
         rec.prefill_kv_blocks = self._prefill_kv_blocks
+        rec.recurrent_rows = self._recurrent_rows
+        rec.scan_positions = self._scan_positions
         if self._experts is not None:
             tokens, read, rec.expert_steps, rec.expert_rows = self._experts
             rec.expert_tokens, rec.experts_read = tokens, read or []

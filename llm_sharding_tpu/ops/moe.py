@@ -97,6 +97,31 @@ def f_chunk(hidden: int) -> int:
         return F_CHUNK
     return max(128, ((2 << 20) // hidden) // 128 * 128)
 
+
+def f_tile(hidden: int, width: int) -> int:
+    """Columns of an expert's ``width`` one kernel step handles:
+    ``f_chunk(hidden)`` (or the whole width under it) where that divides the
+    width — every gated model's — else the largest multiple of 128 that does
+    and keeps an ``H x tile`` int8 block at most 2 MiB: 896 for a width of
+    2,688 = 21·128 at ``H`` 1,024 (``nemotron_h``'s latent experts), three
+    steps an expert where 384 would take seven."""
+    fc = min(width, f_chunk(hidden))
+    if width % fc == 0:
+        return fc
+    most = (2 << 20) // hidden
+    for n in range(min(most, width) // 128, 0, -1):
+        if width % (128 * n) == 0:
+            return 128 * n
+    raise ValueError(
+        f"no tile of whole 128 columns divides the expert width {width}"
+    )
+
+
+#: the expert product's activation (static): ``silu`` — gated, ``silu(x Wg) ⊙
+#: (x Wu)`` — or ``relu2`` — NOT gated, ONE up matrix, ``relu(x Wu)²``
+#: (``nemotron_h``; ``we_gate`` is then None)
+ACTS = ("silu", "relu2")
+
 BACKENDS = ("auto", "kernel", "xla", "interpret")
 
 
@@ -247,12 +272,13 @@ def _leaf(w, layer):
     return q, s
 
 
-def _tiles_xla(tiles: Tiles, layer, wg, wu, wd, sg, su, E, out_dtype):
+def _tiles_xla(tiles: Tiles, layer, wg, wu, wd, sg, su, E, out_dtype,
+               act: str = "silu"):
     """The kernel's arithmetic in plain XLA: gather each tile's expert."""
     row, expert = tiles.row[:-1], tiles.expert[:-1]  # the NT tiles
     x = tiles.x[row]  # [NT, tm, H]
     H = x.shape[-1]
-    F = wg.shape[-1] // E
+    F = wu.shape[-1] // E
 
     def cols(w, s):  # [L, H, E·F] → the tiles' [NT, H, F]
         wl = jax.lax.dynamic_index_in_dim(w, layer, keepdims=False)
@@ -260,15 +286,21 @@ def _tiles_xla(tiles: Tiles, layer, wg, wu, wd, sg, su, E, out_dtype):
         sl = jax.lax.dynamic_index_in_dim(s, layer, keepdims=False)
         return wt.astype(x.dtype), sl.reshape(E, F)[expert][:, None, :]
 
-    g_w, g_s = cols(wg, sg)
+    if act != "relu2":
+        g_w, g_s = cols(wg, sg)
     u_w, u_s = cols(wu, su)
     d_w = jax.lax.dynamic_index_in_dim(wd, layer, keepdims=False).reshape(
         E, F, H
     )[expert].astype(x.dtype)
     f32 = jnp.float32
-    g = jnp.einsum("jth,jhf->jtf", x, g_w, preferred_element_type=f32) * g_s
     u = jnp.einsum("jth,jhf->jtf", x, u_w, preferred_element_type=f32) * u_s
-    a = (jax.nn.silu(g) * u).astype(x.dtype)
+    if act == "relu2":
+        a = jnp.square(jax.nn.relu(u)).astype(x.dtype)
+    else:
+        g = jnp.einsum(
+            "jth,jhf->jtf", x, g_w, preferred_element_type=f32
+        ) * g_s
+        a = (jax.nn.silu(g) * u).astype(x.dtype)
     y = jnp.einsum("jtf,jfh->jth", a, d_w, preferred_element_type=f32)
     alive = jnp.arange(y.shape[0], dtype=jnp.int32) < tiles.n_live
     return jnp.where(alive[:, None, None], y, 0.0).astype(out_dtype)
@@ -302,9 +334,38 @@ def _expert_kernel(lyr, texp, trow, nlive, x_ref, wg_ref, wu_ref, wd_ref,
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("E", "out_dtype", "interpret"))
+def _expert_kernel_relu2(lyr, texp, trow, nlive, x_ref, wu_ref, wd_ref,
+                         su_ref, o_ref, acc_ref, *, n_f):
+    """``_expert_kernel`` for an expert that is NOT gated: ``relu(x Wu)² Wd``."""
+    i, f = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(f == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(i < nlive[0])
+    def _tile():
+        x = x_ref[...]
+        f32 = jnp.float32
+        u = jnp.dot(
+            x, wu_ref[...].astype(x.dtype), preferred_element_type=f32
+        ) * su_ref[...].astype(f32)
+        u = jnp.maximum(u, 0.0)
+        acc_ref[...] += jnp.dot(
+            (u * u).astype(x.dtype), wd_ref[...].astype(x.dtype),
+            preferred_element_type=f32,
+        )
+
+    @pl.when(f == n_f - 1)
+    def _finish():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("E", "out_dtype", "interpret", "act")
+)
 def expert_tiles_tpu(tiles: Tiles, layer, wg, wu, wd, sg, su, *, E: int,
-                     out_dtype, interpret: bool = False):
+                     out_dtype, interpret: bool = False, act: str = "silu"):
     """The Pallas expert product: grid ``(max(n_live, 1), F / F_CHUNK)``, the
     first extent TRACED (Mosaic takes a traced extent on either axis; two
     axes keep a step's tile and slice the grid's own indices), the second
@@ -315,16 +376,17 @@ def expert_tiles_tpu(tiles: Tiles, layer, wg, wu, wd, sg, su, *, E: int,
     lie."""
     R, tm, H = tiles.x.shape
     NT = tiles.row.shape[0] - 1
-    F = wg.shape[-1] // E
-    fc = min(F, f_chunk(H))
-    if F % fc:
-        raise ValueError(f"F_CHUNK {fc} does not divide the expert width {F}")
+    gated = act != "relu2"
+    F = wu.shape[-1] // E
+    fc = f_tile(H, F)
     n_f = F // fc
     # the layer's row of each scale stack, sliced here: 128 KB, where handing
     # the kernel a [L, 1, E·F] view of the stack made XLA re-lay the whole
     # 2 MiB stack out per layer and step (18 us each, a third of this scope)
     sg2, su2 = (
-        jax.lax.dynamic_index_in_dim(s, layer, keepdims=True) for s in (sg, su)
+        None if s is None
+        else jax.lax.dynamic_index_in_dim(s, layer, keepdims=True)
+        for s in (sg, su)
     )
     def fblock(i, f, texp):  # slice f of tile i's expert
         return texp[i] * n_f + f
@@ -339,6 +401,9 @@ def expert_tiles_tpu(tiles: Tiles, layer, wg, wu, wd, sg, su, *, E: int,
         return (lyr[0], fblock(i, f, texp), 0)
 
     n_live = jnp.reshape(tiles.n_live, (1,))
+    col, scale = pl.BlockSpec((None, H, fc), col_map), pl.BlockSpec(
+        (1, fc), scale_map
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(jnp.maximum(n_live[0], 1), n_f),
@@ -347,11 +412,9 @@ def expert_tiles_tpu(tiles: Tiles, layer, wg, wu, wd, sg, su, *, E: int,
                 (None, tm, H),
                 lambda i, f, lyr, texp, trow, nlive: (trow[i], 0, 0),
             ),
-            pl.BlockSpec((None, H, fc), col_map),
-            pl.BlockSpec((None, H, fc), col_map),
+            *((col, col) if gated else (col,)),
             pl.BlockSpec((None, fc, H), row_map),
-            pl.BlockSpec((1, fc), scale_map),
-            pl.BlockSpec((1, fc), scale_map),
+            *((scale, scale) if gated else (scale,)),
         ],
         out_specs=pl.BlockSpec(
             (None, tm, H),  # (the step looked at ahead may be tile NT)
@@ -361,8 +424,13 @@ def expert_tiles_tpu(tiles: Tiles, layer, wg, wu, wd, sg, su, *, E: int,
         ),
         scratch_shapes=[pltpu.VMEM((tm, H), jnp.float32)],
     )
+    operands = (
+        (wg, wu, wd, sg2, su2) if gated else (wu, wd, su2)
+    )
     return pl.pallas_call(
-        functools.partial(_expert_kernel, n_f=n_f),
+        functools.partial(
+            _expert_kernel if gated else _expert_kernel_relu2, n_f=n_f
+        ),
         out_shape=jax.ShapeDtypeStruct((NT, tm, H), out_dtype),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(
@@ -373,7 +441,7 @@ def expert_tiles_tpu(tiles: Tiles, layer, wg, wu, wd, sg, su, *, E: int,
         name="moe_experts",
     )(
         jnp.reshape(layer, (1,)).astype(jnp.int32), tiles.expert, tiles.row,
-        n_live, tiles.x, wg, wu, wd, sg2, su2,
+        n_live, tiles.x, *operands,
     )
 
 
@@ -409,12 +477,18 @@ def expert_mlp(
     layer=None,  # scalar int32 index into layer-stacked weights; None = 2-D
     backend: str = "auto",
     held=None,  # static (first, count): the leaves hold only these experts
+    act: str = "silu",  # static, one of ``ACTS``; "relu2": ``we_gate`` None
 ):
     """``Σ_k weights[n, k] · MLP_{ids[n, k]}(x[n])`` for the live rows (zero
     for the others) and the layer's ``MoeStats``. With ``held`` the sum is
     over the pairs whose expert is held here (the module docstring)."""
     N, H = x.shape
     E = num_experts
+    if act not in ACTS or (act == "relu2") != (we_gate is None):
+        raise ValueError(
+            f"expert activation {act!r}: one of {ACTS}, and 'relu2' (not "
+            "gated) goes with we_gate=None, 'silu' with a gate matrix"
+        )
     backend = resolve_backend(backend)
     if live is None:
         live = jnp.ones((N,), bool)
@@ -432,9 +506,10 @@ def expert_mlp(
         here = (ids >= first) & (ids < first + E)
         ids = jnp.where(here, ids - first, E)
         weights = jnp.where(here, weights, 0.0)
-    (wg, sg), (wu, su), (wd, sd) = (
-        _leaf(w, layer) for w in (we_gate, we_up, we_down)
-    )
+    # (the gate first: the gated path's program is operation for operation
+    # what it was before an expert could be without one)
+    wg, sg = (None, None) if we_gate is None else _leaf(we_gate, layer)
+    (wu, su), (wd, sd) = (_leaf(w, layer) for w in (we_up, we_down))
     lyr = jnp.zeros((), jnp.int32) if layer is None else layer
     decode = N <= DECODE_ROWS_MAX
     with jax.named_scope("moe"):
@@ -446,10 +521,12 @@ def expert_mlp(
 
         def product():
             if backend == "xla":
-                return _tiles_xla(tiles, lyr, wg, wu, wd, sg, su, E, out_dtype)
+                return _tiles_xla(
+                    tiles, lyr, wg, wu, wd, sg, su, E, out_dtype, act
+                )
             return expert_tiles_tpu(
                 tiles, lyr, wg, wu, wd, sg, su, E=E, out_dtype=out_dtype,
-                interpret=backend == "interpret",
+                interpret=backend == "interpret", act=act,
             )
 
         # the kernel leaves the tiles past n_live unwritten: select what is

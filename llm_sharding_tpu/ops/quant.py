@@ -186,6 +186,10 @@ DEEPSEEK_QUANT_KEYS = (
 # mimo_v2 (models/mimo_v2.py): the fused qkv projection beside ``wo`` /
 # ``w_*`` / ``we_*``; router, correction bias and the float32 sink stay
 MIMO_QUANT_KEYS = ("wqkv",)
+# nemotron_h (models/nemotron_h.py): the mixer's two projections and the two
+# latent projections beside ``wq`` .. ``wo`` / ``ws_*`` / ``we_*``; router,
+# conv, ``A_log``, ``D``, ``dt_bias`` and the norms stay
+NEMOTRON_QUANT_KEYS = ("w_in", "w_out", "w_lat_down", "w_lat_up")
 
 
 def is_kinds_tree(layers: dict) -> bool:
@@ -206,7 +210,7 @@ def quantize_layer_params(
     if keys is None:
         keys = (
             LLAMA_QUANT_KEYS + GPT2_QUANT_KEYS + DEEPSEEK_QUANT_KEYS
-            + MIMO_QUANT_KEYS
+            + MIMO_QUANT_KEYS + NEMOTRON_QUANT_KEYS
         )
     if is_kinds_tree(layers):  # one stack per kind: each kind's leaves
         return {

@@ -75,8 +75,9 @@ class ModelFns(NamedTuple):
     # (h, k_arena, v_arena, k_scale, v_scale) — the scale arenas ride a
     # quantized (int8/fp8) arena and come back None otherwise
     stage_paged: Any = None
-    # a model with a KV state per kind of attention layer: the chunked-
-    # prefill kernel's work lists, one per kind, and their counts —
+    # a model whose layers do not all attend alike (a KV state per kind of
+    # attention layer; attention in a few layers of many): the chunked-
+    # prefill kernel's work lists (one per kind) and their counts —
     # (cfg, tables, positions, kv_pos, nlive, layers) -> (walks, counts)
     prefill_walks: Any = None
 
@@ -119,6 +120,14 @@ def model_fns(
             )
         fwd, fwd_paged = mimo_v2.forward_layers, mimo_v2.forward_layers_paged
         walks = mimo_v2.prefill_walks
+    elif cfg.model_type == "nemotron_h":
+        from ..models import nemotron_h
+
+        nemotron_h._refuse_tp(tp_axis, cp_axis)
+        fwd, fwd_paged = (
+            nemotron_h.forward_layers, nemotron_h.forward_layers_paged
+        )
+        walks = nemotron_h.prefill_walks
     else:
         raise ValueError(f"unsupported model_type: {cfg.model_type!r}")
 

@@ -120,6 +120,14 @@ class ServeState(NamedTuple):
     k_swa: Any = None
     v_swa: Any = None
     tables_swa: Any = None
+    # A model with recurrent layers (``cfg.recurrent``, paged only;
+    # ``models/nemotron_h.py``) keeps, beside the arena, a state of FIXED size
+    # a request, indexed by ROW and not paged by token: a small tree by name —
+    # ``{"ssm": [S, L_mamba, M, heads, head_dim, state] f32, "conv": [S,
+    # L_mamba, M, K-1, conv_dim] f32}`` [dev]. ``k`` / ``v`` above are then
+    # the ATTENTION layers' arena alone (``[S, L_attn, NB, ...]``). None (an
+    # empty pytree: no operand of any program) for every other model.
+    recurrent: Any = None
 
 
 def _dev(spec: P) -> bool:
@@ -176,6 +184,10 @@ def state_specs(
         temp=rep, topk=rep, topp=rep, block_tables=tbl, m=rep,
         k_swa=kv if swa else None, v_swa=kv if swa else None,
         tables_swa=tbl if swa else None,
+        recurrent=(
+            None if state.recurrent is None
+            else {name: dev for name in state.recurrent}
+        ),
     )
 
 
@@ -279,9 +291,15 @@ def _slot_tables(st, row0, Bs):
     return tbl, jax.lax.dynamic_slice_in_dim(st.tables_swa, row0, Bs, axis=0)
 
 
-def _arenas(st):
+def _arenas(st, row0=None, fresh=None):
     """``(k, v)`` as the stage function takes them: the arrays, or a
-    windowed model's pairs."""
+    windowed model's pairs. A model with recurrent layers gets its state
+    beside ``k`` — ``(k, {"ssm", "conv", "row0", "fresh"})``: the slot's first
+    row, and whether this dispatch is the rows' first chunk (the state then
+    starts from zero inside the program; False in a decode step)."""
+    if st.recurrent is not None:
+        fresh = jnp.zeros((), bool) if fresh is None else fresh
+        return (st.k, {**st.recurrent, "row0": row0, "fresh": fresh}), st.v
     if st.k_swa is None:
         return st.k, st.v
     return (st.k, st.k_swa), (st.v, st.v_swa)
@@ -289,6 +307,12 @@ def _arenas(st):
 
 def _arena_upd(st, k_new, v_new) -> dict:
     """The ``_replace`` keywords that put a stage function's arenas back."""
+    if st.recurrent is not None:
+        k_new, rec = k_new
+        return {
+            "k": k_new, "v": v_new,
+            "recurrent": {name: rec[name] for name in st.recurrent},
+        }
     if st.k_swa is None:
         return {"k": k_new, "v": v_new}
     return {"k": k_new[0], "k_swa": k_new[1], "v": v_new[0], "v_swa": v_new[1]}
@@ -311,6 +335,11 @@ def make_state(
     kv_blocks_swa: int = 0,
 ) -> ServeState:
     """Host-constructed empty state (all slots free / done).
+
+    A model with recurrent layers (``cfg.recurrent``, paged): the mixers
+    among a stage's ``layers_per_stage`` layers (the model's first that many:
+    every stage holds the same kinds) keep their state in the ``recurrent``
+    entry; the arena holds the stage's ATTENTION layers only.
 
     A windowed model (``cfg.windowed``, paged): ``swa_layers`` of the
     stage's ``layers_per_stage`` slots are window layers; the full layers'
@@ -337,6 +366,9 @@ def make_state(
     Bs = batch_per_slot
     M = S * Bs
     Lp = layers_per_stage
+    recurrent_layers = (
+        cfg.layer_kinds[:Lp].count("mamba") if cfg.recurrent else 0
+    )
     paged = kv_block_size > 0
     if paged:
         # logical window: capacity rounded up to whole blocks. out/kpos are
@@ -384,9 +416,13 @@ def make_state(
     if paged:
         from ..models.cache import paged_arena_shape
 
+        arena_layers = Lp - swa_layers
+        if recurrent_layers:
+            # a stage's first Lp layers (every stage holds the same kinds)
+            arena_layers = cfg.layer_kinds[:Lp].count("attn")
         kv_shape = (
             S, *paged_arena_shape(
-                cfg, cp * kv_blocks, kv_block_size, Lp - swa_layers,
+                cfg, cp * kv_blocks, kv_block_size, arena_layers,
                 heads=cfg.kv_heads_of("full") if swa_layers else None,
             )
         )
@@ -453,6 +489,23 @@ def make_state(
             ),
             tables_swa=put(np.zeros(tbl_shape, np.int32), tbl_sh),
         )
+    if recurrent_layers:
+        if not paged or cp > 1 or tp > 1 or quantized:
+            raise NotImplementedError(
+                "a recurrent state beside the arena needs a paged bf16 arena "
+                "and no tp / cp"
+            )
+        Lm = recurrent_layers
+        state = state._replace(recurrent={
+            "ssm": zeros(
+                (S, Lm, M, cfg.mamba_num_heads, cfg.mamba_head_dim,
+                 cfg.ssm_state_size), jnp.float32, dev,
+            ),
+            "conv": zeros(
+                (S, Lm, M, cfg.conv_kernel - 1, cfg.conv_dim), jnp.float32,
+                dev,
+            ),
+        })
     return state
 
 
@@ -1169,7 +1222,8 @@ def serve_prefill_chunk(
             h = sp_embed(cfg, hd, tokens, positions)
             h, k_new, v_new, ks_new, vs_new, moe_stats = ring_chain_paged(
                 fns, cfg, layers, lmask, sidx, ring, num_stages, h,
-                *_arenas(st), tbl, cols, kv_pos, positions, backend=attn,
+                *_arenas(st, row0, reset), tbl, cols, kv_pos, positions,
+                backend=attn,
                 k_scale=ks, v_scale=vs, prefill=True, walk=walk,
                 moe_live=moe_live,
             )
@@ -1519,7 +1573,7 @@ def serve_chunk(
                     kpos_rows, pos_rows[:, None], (0, off_r)
                 )
                 h_new, k_st, v_st, ks_st, vs_st, moe_stats = fns.stage_paged(
-                    cfg, layers, h_in, *_arenas(s), tbl_r,
+                    cfg, layers, h_in, *_arenas(s, row0), tbl_r,
                     jnp.broadcast_to(off_r, (Bs, 1)), kv_pos,
                     pos_rows[:, None], lmask, write_valid=advance,
                     backend=attn,

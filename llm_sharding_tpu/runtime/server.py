@@ -85,7 +85,8 @@ from ..obs.metrics import (
     KV_ENTRY_BYTES, KV_HOST_TIER_BLOCKS, KV_WASTE_FRAC, MOE_EXPERT_TOKENS,
     MOE_EXPERTS_READ, MOE_PAIRS_HELD, MOE_PAIRS_ROUTED,
     PREFILL_BLOCKS_READ, PREFILL_CELLS_LIVE, PREFILL_CELLS_WALKED,
-    PREFILL_KV_BLOCKS_WRITTEN, PREFILL_POSITIONS, PREFIX_HIT_RATE,
+    PREFILL_KV_BLOCKS_WRITTEN, PREFILL_POSITIONS, PREFILL_SCAN_POSITIONS,
+    PREFIX_HIT_RATE, RECURRENT_ROW_BYTES, RECURRENT_ROWS_IN_USE,
     PREFIX_HIT_TOKENS, REGISTRY, record_shape_key, set_prefill_path,
 )
 from ..obs.trace import TraceContext, TraceWriter, emit_span
@@ -114,6 +115,35 @@ DEGRADED = "DEGRADED"    # a containment event this window: some requests
 #                          failed, the daemon is still serving the rest
 DRAINING = "DRAINING"    # shutting down: no admits, queued requests failed
 _HEALTH_SEVERITY = {SERVING: 0, DEGRADED: 1, DRAINING: 2}
+
+
+def kind_state_name(cfg) -> Optional[str]:
+    """How a refusal names a model whose per-request state is more than ONE
+    paged arena: a KV state per kind of attention layer (``cfg.windowed``), a
+    recurrent state beside the arena (``cfg.recurrent``). None otherwise."""
+    if cfg.windowed:
+        return f"a windowed model ({cfg.model_type})"
+    if cfg.recurrent:
+        return f"a recurrent-state model ({cfg.model_type})"
+    return None
+
+
+def refuse_kind_state(cfg, what: str, why) -> None:
+    """What such a state breaks is refused by name, never computed as
+    something else (ROADMAP M2 / M4 list what is left). ``why``: one reason,
+    or the pair (a windowed model's, a recurrent-state model's)."""
+    name = kind_state_name(cfg)
+    if name is not None:
+        if not isinstance(why, str):
+            why = why[0 if cfg.windowed else 1]
+        raise NotImplementedError(f"{what} {name}: {why} — not implemented")
+
+
+_SNAPSHOT_WHY = (
+    "SNAPSHOT_FORMAT carries one arena and one table a row",
+    "SNAPSHOT_FORMAT carries the arena and its tables, not a recurrent "
+    "state a row",
+)
 
 
 class QueueFull(RuntimeError):
@@ -192,7 +222,7 @@ def _update_load_gauges() -> None:
     fragmentation the operator tunes ``kv_block_size`` against."""
     from ..ops.quant import KV_DTYPES
 
-    queued = active = 0
+    queued = active = recurrent_rows = 0
     kv_total = kv_used = kv_slots = kv_live = 0
     kind_total: dict = {}
     kind_used: dict = {}
@@ -201,7 +231,10 @@ def _update_load_gauges() -> None:
     arena_bytes = dict.fromkeys(KV_DTYPES, 0)
     for s in list(_LIVE_SERVERS):
         queued += len(s._queue)
-        active += sum(r is not None and not r.done for r in s._rows)
+        live = sum(r is not None and not r.done for r in s._rows)
+        active += live
+        if getattr(s, "recurrent", False):
+            recurrent_rows += live
         # like the health gauge's filter: a closed server lingering in the
         # WeakSet (e.g. the old daemon across a :placement rebuild) must
         # not double-count a backend — the gauge's one-hot contract for a
@@ -239,6 +272,7 @@ def _update_load_gauges() -> None:
                 elig_tok += rad.eligible_tokens
     _M_QUEUE_DEPTH.set(queued)
     _M_ACTIVE.set(active)
+    RECURRENT_ROWS_IN_USE.set(recurrent_rows)
     for kind, n in kind_total.items():
         KV_KIND_BLOCKS_TOTAL.labels(kind=kind).set(n)
         KV_KIND_BLOCKS_IN_USE.labels(kind=kind).set(kind_used[kind])
@@ -1116,6 +1150,14 @@ class PipelineServer:
         # per-slot write_off bookkeeping a spec server does not maintain.
         if speculate < 0:
             raise ValueError(f"speculate must be >= 0, got {speculate}")
+        if speculate and self.cfg.recurrent:
+            # (before the clash with prefill_chunk, which such a model needs:
+            # the reason that holds whatever the admission path is)
+            refuse_kind_state(
+                self.cfg, "speculate over",
+                "serve_verify is not carried over a recurrent state (a "
+                "rejected draft would have to roll the state back)",
+            )
         if speculate and prefill_chunk is not None:
             raise ValueError(
                 "speculate is incompatible with prefill_chunk (chunked "
@@ -1228,43 +1270,53 @@ class PipelineServer:
         #: ``kv_blocks``; the window layers' is every row's share of
         #: ``_swa_quota`` blocks (``_init_window_pool``): nothing to size
         self.windowed = bool(self.cfg.windowed)
-        if self.windowed:
-            # what a window layer breaks is refused by name, never computed
-            # as something else (ROADMAP M2 lists what is left)
-            name = f"a windowed model ({self.cfg.model_type})"
+        #: recurrent layers beside attention (``cfg.recurrent``): a state of
+        #: FIXED size a request, indexed by row beside the arena, which holds
+        #: the attention layers alone (``ServeState.recurrent``)
+        self.recurrent = bool(self.cfg.recurrent)
+        if self.windowed or self.recurrent:
+            # what a state beyond ONE paged arena breaks is refused by name,
+            # never computed as something else (ROADMAP M2 / M4 list what is
+            # left)
+            name = kind_state_name(self.cfg)
             if not self.paged or prefill_chunk is None:
                 raise ValueError(
-                    f"{name} serves from a paged arena per kind of layer, "
-                    "admitted chunk by chunk: set kv_block_size, kv_blocks "
-                    "and prefill_chunk"
+                    f"{name} serves from a paged arena "
+                    + ("per kind of layer" if self.windowed
+                       else "beside its recurrent state")
+                    + ", admitted chunk by chunk: set kv_block_size, "
+                    "kv_blocks and prefill_chunk"
                 )
             if kv_dtype != "bf16":
-                raise NotImplementedError(
-                    f"kv_dtype={kv_dtype!r} over {name}: a quantized arena "
-                    "per kind of layer is not implemented"
+                self._refuse_kind_state(
+                    f"kv_dtype={kv_dtype!r} over",
+                    ("a quantized arena per kind of layer",
+                     "a quantized arena beside a recurrent state"),
                 )
-            if self.speculate:
-                raise NotImplementedError(
-                    f"speculate over {name}: serve_verify is not carried "
-                    "over a KV state per kind of layer (a rejected draft "
-                    "would have to un-free window blocks)"
+            if self.speculate:  # (a recurrent state: refused above)
+                self._refuse_kind_state(
+                    "speculate over",
+                    "serve_verify is not carried over a KV state per kind "
+                    "of layer (a rejected draft would have to un-free "
+                    "window blocks)",
                 )
             if cp > 1 or self.tp > 1:
-                raise NotImplementedError(
-                    f"cp / tp over {name} is not implemented"
+                self._refuse_kind_state(
+                    "cp / tp over", "its state is not sharded that way"
                 )
             if snapshot_every_s is not None or snapshot_path is not None:
-                raise NotImplementedError(
-                    f"snapshots of {name}: SNAPSHOT_FORMAT carries one arena "
-                    "and one table a row — not implemented"
-                )
+                self._refuse_kind_state("snapshots of", _SNAPSHOT_WHY)
             if prefix_cache != "off":
                 # a hit would map the full layers' old blocks while the
-                # window layers' are gone: a hit is not OFFERED (the tree is
-                # not built; every prompt prefills cold)
+                # window layers' are gone — or the attention layers' while a
+                # recurrent state cannot be sliced at the hit's length: a hit
+                # is not OFFERED (the tree is not built; every prompt
+                # prefills cold)
                 logger.info(
-                    "prefix_cache=%r over %s: hits are not offered (a "
-                    "window layer's old blocks are gone)", prefix_cache, name,
+                    "prefix_cache=%r over %s: hits are not offered (%s)",
+                    prefix_cache, name,
+                    "a window layer's old blocks are gone" if self.windowed
+                    else "a recurrent state cannot be sliced at a hit's length",
                 )
                 prefix_cache = self.prefix_cache = "off"
                 host_pool_blocks = self.host_pool_blocks = 0
@@ -1523,7 +1575,7 @@ class PipelineServer:
             kv_blocks=self.kv_blocks or 0,
             kv_block_size=self.kv_block_size or 0,
             cp=self.cp,
-            **self._window_state_kwargs(),
+            **self._kind_state_kwargs(),
         )
         # the span covers the fills themselves, not their dispatch
         jax.block_until_ready(self.state)
@@ -1560,7 +1612,8 @@ class PipelineServer:
             # the observable side of the --kv-dtype capacity claim. Padded
             # pipeline layers count (their arena rows are allocated).
             self.arena_bytes_device = self._alloc.arena_bytes(
-                num_layers=self.num_stages * Lp,
+                # (a model with recurrent layers: its attention layers only)
+                num_layers=self.num_stages * int(self.state.k.shape[1]),
                 num_kv_heads=self.cfg.cache_heads,
                 head_dim=self.cfg.cache_k_dim,
                 kv_dtype=self.kv_store_dtype,
@@ -1568,6 +1621,11 @@ class PipelineServer:
             )
             # what ONE token of one layer holds in the arena: 2 x Nkv x Dh
             # values, or a latent cache's one padded entry
+            if self.recurrent:
+                RECURRENT_ROW_BYTES.set(float(
+                    int(self.state.recurrent["ssm"].shape[1])
+                    * self.cfg.recurrent_row_bytes
+                ))
             KV_ENTRY_BYTES.set(float(
                 self.cfg.cache_heads
                 * (self.cfg.cache_k_dim + self.cfg.cache_v_dim)
@@ -1834,6 +1892,8 @@ class PipelineServer:
             DECODE_BLOCKS_LIVE.inc(live * steps)
         DECODE_BLOCKS_RESERVED.inc(reserved * steps)
         self.stepline.decode_blocks(live * steps, reserved * steps)
+        if self.recurrent:
+            self.stepline.recurrent_rows(len(rows))
         if self.windowed:
             # per kind of layer: a window layer's walk starts at the first
             # block its window reaches (what the row still holds)
@@ -1949,10 +2009,12 @@ class PipelineServer:
         prefixes of similar length share one compiled shape; positions for
         suffix requests resume at the REAL length ``n``, so generation is
         token-exact vs prefilling ``prefix + suffix`` whole."""
-        self._refuse_windowed(
+        self._refuse_kind_state(
             "prefill_prefix over",
-            "a handle carries one arena's blocks, and a window layer's are "
-            "gone behind the window",
+            ("a handle carries one arena's blocks, and a window layer's are "
+             "gone behind the window",
+             "a handle carries arena blocks, and a recurrent state cannot be "
+             "sliced at the prefix's length"),
         )
         if self.cp > 1:
             raise NotImplementedError(
@@ -2037,10 +2099,7 @@ class PipelineServer:
         (the slot is parked half-prefilled on device) and while queued
         requests hold prefix handles (device-bound KV — let them admit
         first, or resubmit them after restore)."""
-        self._refuse_windowed(
-            "snapshot of",
-            "SNAPSHOT_FORMAT carries one arena and one table a row",
-        )
+        self._refuse_kind_state("snapshot of", _SNAPSHOT_WHY)
         with self._mutex:
             if self._closed:
                 raise ServerClosed("cannot snapshot a closed server")
@@ -2228,6 +2287,7 @@ class PipelineServer:
         validate = getattr(engine, "_validate_serve", None)
         if validate is not None:
             validate()
+        refuse_kind_state(engine.cfg, "restore into", _SNAPSHOT_WHY)
         kwargs = dict(snap["serve_kwargs"])
         # pre-format-6 snapshots lack the key and restore as cp=1 via the
         # constructor default; a cp>1 snapshot refuses up front when the
@@ -2489,10 +2549,12 @@ class PipelineServer:
         ``submit_embedding(engine.embed_prompt(ids)[0], ...)`` decodes
         token-exactly vs ``submit(ids, ...)``. Embeds requests always use
         one-shot admission (chunked prefill is an ids-path optimization)."""
-        self._refuse_windowed(
+        self._refuse_kind_state(
             "submit_embedding over",
-            "the embeddings entry admits through the one-shot dense window, "
-            "which this model's per-kind KV state does not have",
+            ("the embeddings entry admits through the one-shot dense window, "
+             "which this model's per-kind KV state does not have",
+             "the embeddings entry admits through the one-shot dense window, "
+             "which carries no recurrent state"),
         )
         top_k, top_p = self._resolve_filters(top_k, top_p)
         deadline_s = self._resolve_deadline(deadline_s)
@@ -3498,21 +3560,24 @@ class PipelineServer:
 
     # ------------------------------- a windowed model's second KV state
 
-    def _window_state_kwargs(self) -> dict:
-        """``make_state``'s keywords for a KV state per kind of layer."""
-        if not self.windowed:
+    def _kind_state_kwargs(self) -> dict:
+        """``make_state``'s keywords for a KV state per kind of layer, or
+        for a recurrent state beside the arena."""
+        if not (self.windowed or self.recurrent):
             return {}
         kinds = self.cfg.layer_kinds
         stages = self.engine.exec_placement.stages
         for start, end in stages:
             if kinds[start:end] != kinds[:end - start]:
                 raise NotImplementedError(
-                    f"stage layers {start}..{end} of a windowed model "
-                    f"({self.cfg.model_type}) hold kinds "
+                    f"stage layers {start}..{end} of "
+                    f"{kind_state_name(self.cfg)} hold kinds "
                     f"{list(kinds[start:end])}: every stage must hold the "
                     "same sequence of layer kinds as the first (whole "
                     "periods of the pattern)"
                 )
+        if self.recurrent:
+            return {}  # make_state reads the stage's mixers off the kinds
         return {
             # a stage's window layers (every stage holds the same kinds)
             "swa_layers": max(
@@ -3636,19 +3701,15 @@ class PipelineServer:
             KV_WINDOW_BLOCKS_FREED.inc(freed)
         self._window_freed_step += freed
 
-    def _refuse_windowed(self, what: str, why: str) -> None:
-        """What a window layer breaks is refused by name, never computed as
-        something else (ROADMAP M2 lists what is left)."""
-        if self.windowed:
-            raise NotImplementedError(
-                f"{what} a windowed model ({self.cfg.model_type}): {why} — "
-                "not implemented"
-            )
+    def _refuse_kind_state(self, what: str, why) -> None:
+        refuse_kind_state(self.cfg, what, why)
 
-    def _refuse_window_kv_move(self) -> None:
-        self._refuse_windowed(
+    def _refuse_kv_move(self) -> None:
+        self._refuse_kind_state(
             "moving KV blocks (hand-off, host / disk tier) of",
-            "a row's window layers hold other blocks than its full layers",
+            ("a row's window layers hold other blocks than its full layers",
+             "a row's recurrent state is no block of the arena, and a "
+             "request is its blocks AND its state"),
         )
 
     def _push_tables(self) -> None:
@@ -3731,7 +3792,7 @@ class PipelineServer:
         it into per-shard slices + a concat. ``_cp_stream_check`` walks
         the owner shards first for fault injection and stream
         accounting."""
-        self._refuse_window_kv_move()
+        self._refuse_kv_move()
         blocks = list(blocks)
         self._cp_stream_check(blocks)
         idx = jnp.asarray(np.asarray(blocks, np.int32))
@@ -3772,7 +3833,7 @@ class PipelineServer:
         arithmetic as the read path; block bytes are cp-agnostic, which
         is what lets a cp=1 peer's stream land on a cp=2 arena and vice
         versa)."""
-        self._refuse_window_kv_move()
+        self._refuse_kv_move()
         blocks = list(blocks)
         self._cp_stream_check(blocks)
         idx = jnp.asarray(np.asarray(blocks, np.int32))
@@ -4484,7 +4545,7 @@ class PipelineServer:
         return True
 
     def _bucket(self, n: int) -> int:
-        if self.windowed:
+        if self.windowed or self.recurrent:
             # every prompt admits chunk by chunk (``_chunked``), in WHOLE
             # chunks: one ``serve_prefill_chunk`` program whatever the
             # prompt's length, where a bucket under the chunk would compile
@@ -4499,8 +4560,10 @@ class PipelineServer:
         # a windowed model admits EVERY prompt chunk by chunk: the chunked
         # path is arena-native, so no dense window of ``capacity`` columns
         # is ever built for its window layers (``serve_admit`` builds one
-        # for every layer; it costs nothing here whatever the capacity)
-        if self.windowed:
+        # for every layer; it costs nothing here whatever the capacity).
+        # A model with recurrent layers likewise: the chunk program is the
+        # one that carries the state, and zeroes it on a row's first chunk
+        if self.windowed or self.recurrent:
             return True
         return self.prefill_chunk is not None and bucket > self.prefill_chunk
 
@@ -5015,6 +5078,19 @@ class PipelineServer:
                     n_written
                 )
                 self.stepline.prefill_kv_blocks(kv_write, n_written)
+            if self.recurrent:
+                # positions through the block-form scan, per mixer layer:
+                # the rows' prompt tokens in this chunk but each row's LAST
+                # (it enters through the injection path, a decode step), and
+                # the padding beside them
+                scanned = int(
+                    np.clip(plen - 1 - off, 0, Sc)[row_valid].sum()
+                )
+                PREFILL_SCAN_POSITIONS.labels(kind="real").inc(scanned)
+                PREFILL_SCAN_POSITIONS.labels(kind="pad").inc(
+                    Bs * Sc - scanned
+                )
+                self.stepline.scan_positions(scanned, Bs * Sc - scanned)
             real = int(np.clip(plen - off, 0, Sc)[row_valid].sum())
             with self._prefill_span(Bs, real, Bs * Sc):
                 chunk_out = serve_ops.serve_prefill_chunk(

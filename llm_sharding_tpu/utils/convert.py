@@ -242,6 +242,73 @@ def mimo_layer_arrays(
     return p
 
 
+def nemotron_layer_arrays(
+    cfg: ModelConfig, get: TensorGetter, i: int, dtype
+) -> dict[str, jnp.ndarray]:
+    """One ``nemotron_h`` layer in the layout of ``models/nemotron_h.py``; the
+    layer's kind is ``cfg.layer_kinds[i]``. The tensor NAMES are those of the
+    published checkpoint as this sandbox could read them (no network: not
+    checked against a checkpoint) — ``backbone.layers.{i}.norm`` and
+    ``backbone.layers.{i}.mixer.*``: a mixer's ``in_proj`` (``[z | xBC | dt]``
+    rows, transposed to ``[in, out]``, nothing re-laid), ``conv1d`` (``[C, 1,
+    K]`` → ``[K, C]``, float32 like its bias, ``dt_bias``, ``A_log`` and
+    ``D``), the gated ``norm`` and ``out_proj``; attention's ``q_proj`` ..
+    ``o_proj``; an expert layer's ``gate`` + ``e_score_correction_bias``,
+    ``fc1_latent_proj`` / ``fc2_latent_proj``, ``experts.{e}.up_proj`` /
+    ``down_proj`` and ``shared_experts.*``. Of the routed experts only those
+    this chip HOLDS are read (``cfg.held_experts_``)."""
+    pre = f"backbone.layers.{i}."
+    kind = cfg.layer_kinds[i]
+
+    def raw(name):  # torch Linear stores [out, in]; we use [in, out]
+        return np.asarray(get(pre + "mixer." + name + ".weight")).T
+
+    def arr(x):
+        return jnp.asarray(x, dtype)
+
+    def flt(name):
+        return jnp.asarray(get(pre + "mixer." + name), jnp.float32)
+
+    p = {"norm": arr(get(pre + "norm.weight"))}
+    if kind == "attn":
+        p.update(
+            wq=arr(raw("q_proj")), wk=arr(raw("k_proj")),
+            wv=arr(raw("v_proj")), wo=arr(raw("o_proj")),
+        )
+        return p
+    if kind == "mamba":
+        p.update(
+            w_in=arr(raw("in_proj")),
+            conv_w=jnp.asarray(
+                np.asarray(get(pre + "mixer.conv1d.weight"))[:, 0, :].T,
+                jnp.float32,
+            ),
+            conv_b=flt("conv1d.bias"), dt_bias=flt("dt_bias"),
+            A_log=flt("A_log"), D=flt("D"),
+            gate_norm=arr(get(pre + "mixer.norm.weight")),
+            w_out=arr(raw("out_proj")),
+        )
+        return p
+    first, count = cfg.held_experts_
+    held = range(first, first + count)
+
+    def experts(name, axis):
+        return arr(np.concatenate(
+            [raw(f"experts.{e}.{name}") for e in held], axis=axis
+        ))
+
+    p.update(
+        router=arr(raw("gate")),
+        router_bias=flt("gate.e_score_correction_bias"),
+        w_lat_down=arr(raw("fc1_latent_proj")),
+        w_lat_up=arr(raw("fc2_latent_proj")),
+        we_up=experts("up_proj", 1), we_down=experts("down_proj", 0),
+        ws_up=arr(raw("shared_experts.up_proj")),
+        ws_down=arr(raw("shared_experts.down_proj")),
+    )
+    return p
+
+
 def gpt2_layer_arrays(
     cfg: ModelConfig, get: TensorGetter, i: int, dtype
 ) -> dict[str, jnp.ndarray]:
@@ -281,6 +348,21 @@ def _stack(layer_dicts: list[dict[str, jnp.ndarray]]) -> dict[str, jnp.ndarray]:
     return {k: jnp.stack([d[k] for d in layer_dicts]) for k in layer_dicts[0]}
 
 
+#: the models whose layers are of several kinds (one stack per kind)
+KIND_LAYER_ARRAYS = {
+    "deepseek_v3": deepseek_layer_arrays, "mimo_v2": mimo_layer_arrays,
+    "nemotron_h": nemotron_layer_arrays,
+}
+
+
+def head_names(cfg: ModelConfig) -> tuple:
+    """``(embedding, final norm)`` tensor names of a llama-style checkpoint
+    (``nemotron_h`` keeps its stack under ``backbone``)."""
+    if cfg.model_type == "nemotron_h":
+        return "backbone.embeddings.weight", "backbone.norm_f.weight"
+    return "model.embed_tokens.weight", "model.norm.weight"
+
+
 def params_from_hf(
     cfg: ModelConfig,
     src: Mapping[str, np.ndarray] | TensorGetter,
@@ -303,17 +385,15 @@ def params_from_hf(
         # tied: no duplicate vocab×hidden buffer — final_logits contracts
         # against the embedding table (see models/llama.py:final_logits)
         return params
-    elif cfg.model_type in ("deepseek_v3", "mimo_v2"):
+    elif cfg.model_type in KIND_LAYER_ARRAYS:
         # one stack per kind, in layer order (cfg.layer_kinds); the
         # vocabulary tables keep the rows held here (rows 0..vocab_size-1)
         kinds = cfg.layer_kinds
         V = cfg.vocab_size
-        layer_arrays = (
-            deepseek_layer_arrays if cfg.model_type == "deepseek_v3"
-            else mimo_layer_arrays
-        )
+        layer_arrays = KIND_LAYER_ARRAYS[cfg.model_type]
+        embed_name, norm_name = head_names(cfg)
         return {
-            "embed": jnp.asarray(get("model.embed_tokens.weight")[:V], dtype),
+            "embed": jnp.asarray(get(embed_name)[:V], dtype),
             "layers": {
                 kind: _stack([
                     layer_arrays(cfg, get, i, dtype)
@@ -321,7 +401,7 @@ def params_from_hf(
                 ])
                 for kind in dict.fromkeys(kinds)
             },
-            "final_norm": jnp.asarray(get("model.norm.weight"), dtype),
+            "final_norm": jnp.asarray(get(norm_name), dtype),
             "lm_head": jnp.asarray(get("lm_head.weight")[:V].T, dtype),
         }
     elif cfg.model_type == "gpt2":

@@ -48,6 +48,8 @@ from .convert import (
     _getter,
     deepseek_layer_arrays,
     mimo_layer_arrays,
+    nemotron_layer_arrays,
+    head_names,
     gpt2_layer_arrays,
     llama_layer_arrays,
 )
@@ -310,6 +312,7 @@ def save_shards_streaming(
     layer_fn = {
         "llama": llama_layer_arrays, "gpt2": gpt2_layer_arrays,
         "deepseek_v3": deepseek_layer_arrays, "mimo_v2": mimo_layer_arrays,
+        "nemotron_h": nemotron_layer_arrays,
     }[cfg.model_type]
     for i in range(cfg.num_hidden_layers):
         block = layer_fn(cfg, get, i, dtype)
@@ -317,17 +320,19 @@ def save_shards_streaming(
             block = quantize_layer_params(block, bits=quant_bits)
         _save_npz(os.path.join(out_dir, f"block_{i}.npz"), block)
 
-    if cfg.model_type in ("llama", "deepseek_v3", "mimo_v2"):
-        # deepseek_v3 / mimo_v2 may hold a SLICE of the vocabulary: rows 0..V-1
+    if cfg.model_type in ("llama", "deepseek_v3", "mimo_v2", "nemotron_h"):
+        # a model with a share of the experts may hold a SLICE of the
+        # vocabulary: rows 0..V-1
         V = cfg.vocab_size
-        embed = jnp.asarray(get("model.embed_tokens.weight")[:V], dtype)
+        embed_name, norm_name = head_names(cfg)
+        embed = jnp.asarray(get(embed_name)[:V], dtype)
         _save_npz(
             os.path.join(out_dir, "embedding.npz"),
             {"embed": maybe_q_embed(embed)},
         )
         _save_npz(
             os.path.join(out_dir, "final_norm.npz"),
-            {"final_norm": jnp.asarray(get("model.norm.weight"), dtype)},
+            {"final_norm": jnp.asarray(get(norm_name), dtype)},
         )
         if not cfg.tie_word_embeddings:
             head = jnp.asarray(get("lm_head.weight")[:V].T, dtype)

@@ -225,3 +225,84 @@ def test_backend_names():
     with pytest.raises(ValueError, match="requires a TPU"):
         moe.resolve_backend("kernel")
     assert moe.resolve_backend("xla") == "xla"
+
+
+# ---- an expert that is NOT gated: relu(x Wu)² Wd (``act="relu2"``) ----------
+
+def plain_loop_relu2(x, weights, ids, wu, wd, E, live, held=None):
+    """Σ over the chosen experts, one expert at a time, in plain numpy."""
+    first, count = held or (0, E)
+    x, wu, wd = (np.asarray(a, np.float64) for a in (x, wu, wd))
+    F = wu.shape[-1] // count
+    out = np.zeros((x.shape[0], wd.shape[-1]))
+    for n in np.flatnonzero(np.asarray(live)):
+        for w, e in zip(np.asarray(weights[n]), np.asarray(ids[n])):
+            if first <= e < first + count:
+                j = e - first
+                a = np.maximum(x[n] @ wu[:, j * F:(j + 1) * F], 0.0) ** 2
+                out[n] += w * (a @ wd[j * F:(j + 1) * F])
+    return out
+
+
+@pytest.mark.parametrize("backend", ["xla", "interpret"])
+@pytest.mark.parametrize("N,E,k,F,H,L,quant,held", [
+    (1, 8, 3, 32, 64, 2, False, None),     # one live row
+    (4, 8, 3, 24, 32, 2, True, None),      # a slot, int8, a width of 24
+    (4, 16, 6, 32, 64, 3, True, (4, 4)),   # a share: 4 held of 16
+    (100, 8, 3, 32, 64, 2, False, None),   # grouped tiles
+    (70, 16, 6, 32, 64, 1, True, (8, 4)),  # grouped, a share
+])
+def test_the_relu2_kernel_is_the_xla_path_is_the_plain_loop(
+        N, E, k, F, H, L, quant, held, backend):
+    first, count = held or (0, E)
+    x, _, live, given, plain = make(N + E + 1, N, count, F, H, L, quant)
+    router = jax.random.normal(jax.random.key(N * E), (H, E), jnp.float32)
+    layer = L - 1
+    x, w, ids, live = routing(x, router, k, live, "some", None)
+    out, stats = moe.expert_mlp(
+        x, w, ids, None, given[1], given[2], num_experts=E, live=live,
+        layer=jnp.int32(layer), backend=backend, held=held, act="relu2",
+    )
+    want = plain_loop_relu2(
+        x, w, ids, plain[1][layer], plain[2][layer], E, live, held)
+    np.testing.assert_allclose(np.asarray(out), want, atol=5e-5)
+    counts = np.zeros(E, int)
+    for n in np.flatnonzero(np.asarray(live)):
+        counts[np.asarray(ids[n])] += 1
+    assert np.array_equal(np.asarray(stats.expert_tokens), counts)
+    assert int(stats.experts_read) == (counts[first:first + count] > 0).sum()
+
+
+def test_the_gated_path_is_bitwise_what_it_was_without_the_argument():
+    """``act="silu"`` is the default and the program it was: the same bits
+    with and without the keyword, on both paths."""
+    x, router, live, given, _ = make(5, 4, 8, 32, 64, 2, True)
+    x, w, ids, live = routing(x, router, 2, live, "some", None)
+    for backend in ("xla", "interpret"):
+        kw = dict(num_experts=8, live=live, layer=jnp.int32(1), backend=backend)
+        a, _ = moe.expert_mlp(x, w, ids, *given, **kw)
+        b, _ = moe.expert_mlp(x, w, ids, *given, act="silu", **kw)
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_an_activation_goes_with_its_leaves():
+    x, router, live, given, _ = make(5, 4, 8, 32, 64, 2, False)
+    x, w, ids, live = routing(x, router, 2, live, "some", None)
+    with pytest.raises(ValueError, match="relu2"):
+        moe.expert_mlp(x, w, ids, *given, num_experts=8, act="relu2")
+    with pytest.raises(ValueError, match="relu2"):
+        moe.expert_mlp(x, w, ids, None, given[1], given[2], num_experts=8)
+    with pytest.raises(ValueError, match="gelu"):
+        moe.expert_mlp(x, w, ids, *given, num_experts=8, act="gelu")
+
+
+@pytest.mark.parametrize("hidden, width, tile", [
+    (2048, 1024, 512), (4096, 2048, 512), (7168, 2048, 256),  # as before
+    (64, 32, 32), (4096, 1408, 128),
+    (1024, 2688, 896),  # nemotron_h's latent experts: 21 x 128 = 3 x 896
+    (4096, 2688, 384),
+])
+def test_the_tile_of_an_experts_width(hidden, width, tile):
+    assert moe.f_tile(hidden, width) == tile and width % tile == 0
+    if width % min(width, moe.f_chunk(hidden)) == 0:
+        assert tile == min(width, moe.f_chunk(hidden))

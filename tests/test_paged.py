@@ -174,12 +174,13 @@ def test_state_specs_field_parity(setup):
         )
         specs = serve_ops.state_specs(state)
         assert state._fields == specs._fields
-        # a windowed model's second KV state (k_swa / v_swa / tables_swa) is
-        # None — an empty pytree, no operand of any program — for every
-        # other model, in the state and in its specs alike
+        # a windowed model's second KV state (k_swa / v_swa / tables_swa) and
+        # a recurrent-state model's ``recurrent`` tree are None — an empty
+        # pytree, no operand of any program — for every other model, in the
+        # state and in its specs alike
         live = {k: v for k, v in specs._asdict().items() if v is not None}
         assert set(specs._fields) - set(live) == {
-            "k_swa", "v_swa", "tables_swa"}
+            "k_swa", "v_swa", "tables_swa", "recurrent"}
         assert all(getattr(state, k) is None for k in set(specs._fields) - set(live))
         for name, spec in live.items():
             assert isinstance(spec, jax.sharding.PartitionSpec), name
@@ -1429,7 +1430,8 @@ def _windowed_projections(text):
 
 
 @pytest.mark.parametrize(
-    "cell", sorted(_CELL_SHAPES) + ["gigachat31_702b_a36b"])
+    "cell", sorted(_CELL_SHAPES) + [
+        "gigachat31_702b_a36b", "nemotron3_super_120b_a12b"])
 def test_a_decode_step_reads_its_weights_as_they_are_stored(v5e_host, cell):
     """The compiled ``serve_chunk`` of each benchmark configuration, at its
     real geometry for the described v5e (``benchmark/aot_check.py`` builds
@@ -1494,6 +1496,45 @@ def test_a_windowed_models_step_programs_compile_and_read_weights_as_stored(
     assert decode.count("tpu_custom_call") == 9
     assert "paged_decode" in decode and "paged_prefill" in texts[
         "serve_prefill_chunk[256]"]
+
+
+def test_a_recurrent_models_step_programs_compile_and_read_weights_as_stored(
+        v5e_host):
+    """``nemotron3_super_120b_a12b`` (a recurrent state beside the arena;
+    ``benchmark/tests/aot_recurrent.py`` compiles the two programs such a
+    model dispatches): the decode program and the chunked
+    prefill compile for the described v5e at the published widths — the
+    ``relu2`` expert kernel over tiles of 896 columns, both paged kernels for
+    the two attention layers, the state update and the block-form scan in XLA
+    — and the decode step re-lays no weight stack: ``w_in`` leaves its dot
+    through a barrier, no ``w_in`` / ``w_out`` / expert stack is copied."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "aot_recurrent", os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "tests",
+            "aot_recurrent.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    with jax.default_matmul_precision("default"):
+        texts = mod.check("nemotron3_super_120b_a12b", texts=True)
+    decode = texts["serve_chunk"]
+    assert _weight_stack_relayouts(decode) == []
+    dots, windowed = _windowed_projections(decode)
+    assert len(dots) >= 3 and windowed == []
+    # seventeen runs of one kind: an expert kernel in seven, the decode
+    # kernel in two
+    assert decode.count("tpu_custom_call") == 9
+    assert "paged_decode" in decode and "moe_experts" in decode
+    prefill = texts["serve_prefill_chunk[256]"]
+    assert "paged_prefill" in prefill and "moe_experts" in prefill
+    assert _weight_stack_relayouts(prefill) == []
+    # the recurrent state is updated where it lies: neither program copies
+    # an array of the state's size (134 MB in and out of every step, 22% of
+    # it, before the carried state went through a barrier)
+    for text in (decode, prefill):
+        assert [line for line in text.split("\n")
+                if " copy(" in line and "128,64,128]" in line] == []
 
 
 def test_the_compiled_program_guard_sees_a_transposed_weight_stack():
@@ -1725,8 +1766,11 @@ def test_attn_backend_metrics(setup, monkeypatch):
 _NO_ARENA_COPY = {"kv_take", "kv_layout", "kv_put"}
 _NO_HEAD = {"head", "sample"}
 #: ``absorb`` is latent attention's (``models/deepseek_v3.py``): neither model here has it.
-_MLP_WORDS = {"dense": {"router", "moe", "absorb"},
-              "experts": {"mlp", "absorb"}}
+# (a Mamba-2 mixer's and a LatentMoE's words are ``nemotron_h``'s alone:
+# ``tests/test_nemotron_h_serve.py`` holds its programs to them)
+_RECURRENT_WORDS = {"ssm_proj", "conv", "ssm", "moe_latent"}
+_MLP_WORDS = {"dense": {"router", "moe", "absorb"} | _RECURRENT_WORDS,
+              "experts": {"mlp", "absorb"} | _RECURRENT_WORDS}
 PROGRAM_SCOPES = {
     "serve_chunk": _NO_ARENA_COPY,
     "serve_prefill_chunk": _NO_ARENA_COPY | _NO_HEAD,

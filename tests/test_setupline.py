@@ -587,7 +587,8 @@ def test_the_benchmark_names_the_eight_with_the_six_cells():
     cells = [w["name"] for w in bench["workloads"]]
     moving = [m for m in bench["per_layer"] if m["moves"] == "setup_s"]
     assert [m["name"] for m in moving] == list(READINGS)
-    assert bench["per_layer"][-len(moving):] == moving  # appended, in order
+    first = bench["per_layer"].index(moving[0])  # appended together, in order
+    assert bench["per_layer"][first:first + len(moving)] == moving
     layers = {m["layer"] for m in bench["per_layer"] if m not in moving}
     for m in moving:
         assert m["workloads"] == cells[:6] and m["better"] == "lower"
