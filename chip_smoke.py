@@ -48,6 +48,14 @@ whole-block forms that were timed against them (a loop of
 the row-wise write outside block 0. The last line, also left in
 ``chiprun_out/kv_write.json``: ``{"ok": true, "kv_write": [...], "device":
 {...}}``.
+``--ssm`` likewise: a decode step's state update of a recurrent-state model
+(``ops/ssm.ssm_step_rows``) at Nemotron-3-Super's published mixer shape (128
+heads of 64, a state of 128, 8 groups; 8 layers x 4 rows of float32 state
+carried), 1 and 4 live rows of the slot's 4: the kernel (``ssm_rows``)
+against the XLA loop — the largest difference of the read-out and of the new
+state, a row that is not live bit for bit — and microseconds a layer call of
+each. The last line, also left in ``chiprun_out/ssm.json``: ``{"ok": true,
+"ssm": [...], "device": {...}}``; exit 1 where the two disagree.
 """
 
 from __future__ import annotations
@@ -785,6 +793,142 @@ def child_kv_write(spec: dict, out_path: str) -> None:
         )
 
 
+#: the one-step state update at Nemotron-3-Super-120B-A12B's published mixer
+#: shape, carried as the cell carries it: 8 mixer layers x 4 rows of float32
+SSM_SHAPE = {"layers": 8, "rows": 4, "heads": 128, "head_dim": 64,
+             "state": 128, "groups": 8}
+SSM_LIVE = (1, 4)
+#: float32 against float32: only the order of the sum over ``state`` differs
+SSM_TOL = 1e-4
+
+
+def ssm_inputs(shape: dict, live: int, seed: int = 0) -> tuple:
+    """``(s_all, alive, x, dt, A, Bm, Cm, D)``: a carried state and one
+    position of a slot whose LAST ``live`` rows are live (so ``order`` is no
+    identity)."""
+    import jax
+    import jax.numpy as jnp
+
+    L, R, nh, hd, ds, g = (shape[k] for k in (
+        "layers", "rows", "heads", "head_dim", "state", "groups"))
+    k = jax.random.split(jax.random.key(seed), 7)
+    alive = jnp.arange(R) >= R - live
+    dt = jax.nn.softplus(jax.random.normal(k[2], (R, nh)) - 2.0)
+    return (
+        jax.random.normal(k[0], (L, R, nh, hd, ds)), alive,
+        jax.random.normal(k[1], (R, nh, hd)),
+        jnp.where(alive[:, None], dt, 0.0),
+        -jnp.exp(jax.random.uniform(k[3], (nh,), minval=0.0, maxval=2.77)),
+        jax.random.normal(k[4], (R, g, ds)),
+        jax.random.normal(k[5], (R, g, ds)),
+        jax.random.uniform(k[6], (nh,), minval=0.5, maxval=1.5),
+    )
+
+
+def ssm_rows_call(backend: str):
+    """``(s_all, layer, alive, x, dt, A, Bm, Cm, D) -> (y, s_all)``: the
+    slot's update as ``models/nemotron_h.mamba_decode_rows`` asks for it."""
+    import jax.numpy as jnp
+
+    import llm_sharding_tpu.models  # noqa: F401  (import cycle: models first)
+    from llm_sharding_tpu.ops import ssm
+
+    def call(s_all, layer, alive, x, dt, A, Bm, Cm, D):
+        return ssm.ssm_step_rows(
+            s_all, (layer, jnp.int32(0)), jnp.argsort(~alive),
+            jnp.sum(alive.astype(jnp.int32)), x, dt, A, Bm, Cm, D,
+            backend=backend,
+        )
+    return call
+
+
+def check_ssm_rows(shape: dict, live: int, backend: str = "kernel") -> dict:
+    """``backend`` against the XLA loop, one layer of the stack advanced: the
+    largest difference of ``y`` and of the new state (relative to the largest
+    value, 1 at least), and whether every row that is not live — and every other layer —
+    came back bit for bit."""
+    import jax
+    import jax.numpy as jnp
+
+    s_all, alive, *ops = ssm_inputs(shape, live, seed=1)
+    layer = jnp.int32(shape["layers"] - 1)
+    y, s = jax.jit(ssm_rows_call(backend))(s_all, layer, alive, *ops)
+    y_want, s_want = jax.jit(ssm_rows_call("xla"))(s_all, layer, alive, *ops)
+    still = jnp.ones(s_all.shape[:2], bool).at[layer].set(~alive)
+    def err(got, want):  # (with no live row ``y`` is all zeros)
+        return float(
+            jnp.abs(got - want).max() / jnp.maximum(jnp.abs(want).max(), 1.0)
+        )
+
+    return {
+        "y_err": err(y, y_want), "s_err": err(s, s_want),
+        "dead_rows_untouched": bool(
+            jnp.array_equal(s[still], s_all[still])
+            & jnp.array_equal(y[~alive], y_want[~alive])
+        ),
+    }
+
+
+def time_ssm_rows(shape: dict, live: int, backend: str,
+                  calls: int = 64) -> float:
+    """Microseconds per layer call: the state is carried through ``calls``
+    calls of ONE program as the layer scan carries it (donated: the update
+    is in place or shows that it is not), warmed up, best of three."""
+    import jax
+    import jax.numpy as jnp
+
+    call = ssm_rows_call(backend)
+    s_all, alive, x, *ops = ssm_inputs(shape, live)
+    layers = jnp.arange(calls, dtype=jnp.int32) % shape["layers"]
+
+    @functools.partial(jax.jit, donate_argnums=0)
+    def run(s_all, alive, x, *ops):
+        def one(carry, layer):
+            s_all, y = carry
+            # hangs on the call before: nothing is hoisted out of the loop
+            return call(s_all, layer, alive, x + y * 1e-9, *ops)[::-1], None
+        return jax.lax.scan(one, (s_all, jnp.zeros_like(x)), layers)[0]
+
+    best = float("inf")
+    for _ in range(4):  # the first call compiles
+        t0 = time.perf_counter()
+        s_all, _ = jax.block_until_ready(run(s_all, alive, x, *ops))
+        best = min(best, time.perf_counter() - t0)
+    return round(best / calls * 1e6, 2)
+
+
+def child_ssm(spec: dict, out_path: str) -> None:
+    import jax
+
+    from llm_sharding_tpu.utils.compile_cache import enable_persistent_cache
+    from llm_sharding_tpu.utils.device_report import device_report
+
+    platform = jax.devices()[0].platform
+    require_tpu(platform, "the state update's kernel check")
+    enable_persistent_cache(platform)
+    results = []
+    for live in SSM_LIVE:
+        got = check_ssm_rows(SSM_SHAPE, live)
+        got["ok"] = (
+            got["dead_rows_untouched"]
+            and max(got["y_err"], got["s_err"]) <= SSM_TOL
+        )
+        us = {b: time_ssm_rows(SSM_SHAPE, live, b) for b in ("kernel", "xla")}
+        results.append({"kernel": "ssm_rows", "live_rows": live, **got,
+                        "us_per_layer_call": us})
+        print(f"[ssm] ssm_rows {live} live of {SSM_SHAPE['rows']}: y err "
+              f"{got['y_err']:.2e}, state err {got['s_err']:.2e}, dead rows "
+              f"untouched {got['dead_rows_untouched']}; kernel "
+              f"{us['kernel']} us, xla {us['xla']} us a layer call",
+              flush=True)
+    with open(out_path, "w") as f:
+        json.dump({"device": device_report(), "ssm": results}, f)
+    if not all(r["ok"] for r in results):
+        raise SystemExit(
+            "chip_smoke: the state update's kernel disagrees with XLA"
+        )
+
+
 def require_tpu(platform: str, who: str) -> None:
     if platform != "tpu":
         raise SystemExit(
@@ -1204,30 +1348,34 @@ def main(argv=None) -> int:
                     help="only time a prefill chunk's K/V write (ops/"
                          "paged_attention.py) at the cells' arena shapes: "
                          "whole-block tiles beside the row-wise scatter")
+    ap.add_argument("--ssm", action="store_true",
+                    help="only check and time a decode step's state update "
+                         "(ops/ssm.py) at Nemotron-3-Super's mixer shape: "
+                         "the kernel beside the XLA loop")
     ap.add_argument("--child",
-                    choices=("kernels", "store", "moe", "kv_write"))
+                    choices=("kernels", "store", "moe", "kv_write", "ssm"))
     ap.add_argument("--spec")
     ap.add_argument("--out")
     args = ap.parse_args(argv)
     if args.child:
         spec = json.loads(args.spec)
         {"kernels": child_kernels, "store": child_store,
-         "moe": child_moe,
-         "kv_write": child_kv_write}[args.child](spec, args.out)
+         "moe": child_moe, "kv_write": child_kv_write,
+         "ssm": child_ssm}[args.child](spec, args.out)
         return 0
-    if args.kv_write:
-        os.makedirs(WORK, exist_ok=True)
-        got = wait_child(run_child(
-            "kv_write", {}, dict(os.environ, PYTHONPATH=HERE),
-            "kv_write.log"))
-        line = json.dumps({"ok": True, "kv_write": got["kv_write"],
-                           "device": got["device"]})
-        os.makedirs(os.path.join(HERE, "chiprun_out"), exist_ok=True)
-        with open(os.path.join(HERE, "chiprun_out", "kv_write.json"),
-                  "w") as f:
-            f.write(line + "\n")
-        print(line)
-        return 0
+    for mode in ("ssm", "kv_write"):  # one check, its line left behind too
+        if getattr(args, mode):
+            os.makedirs(WORK, exist_ok=True)
+            got = wait_child(run_child(
+                mode, {}, dict(os.environ, PYTHONPATH=HERE), mode + ".log"))
+            line = json.dumps({"ok": True, mode: got[mode],
+                               "device": got["device"]})
+            os.makedirs(os.path.join(HERE, "chiprun_out"), exist_ok=True)
+            with open(os.path.join(HERE, "chiprun_out", mode + ".json"),
+                      "w") as f:
+                f.write(line + "\n")
+            print(line)
+            return 0
     if args.moe:
         os.makedirs(WORK, exist_ok=True)
         got = wait_child(run_child(

@@ -23,8 +23,9 @@ and the conv's last ``conv_kernel - 1`` inputs (float32): the RECURRENT STATE,
 conv_dim]``, indexed by ROW. It rides the layer scan's carry and is updated
 where it lies (a slot's rows of one layer are sliced out, advanced, written
 back: nothing of a layer's size is produced beside it). A decode step
-advances one position a row (``ssm_step``) and touches the LIVE rows only
-(``mamba_decode_rows``: a loop over them; a dead row's 4 MB are neither read
+advances one position a row and touches the LIVE rows only
+(``mamba_decode_rows`` → ``ssm.ssm_step_rows``: ONE kernel call a layer over
+them on the chip, a loop over them in XLA; a dead row's 4 MB are neither read
 nor written); a prefill chunk runs the block form over ``cfg.ssm_chunk``
 positions (``ssm_chunk``) with the row's stored state as the carry in and
 out. A position that is no real token (a pad, a dead row, a masked layer, a
@@ -297,7 +298,8 @@ def mamba_block(cfg: ModelConfig, p: Params, h, state, tail, live):
     return _mixer_out(cfg, p, h, y, z), state, tail
 
 
-def mamba_decode_rows(cfg: ModelConfig, p: Params, h, s_all, at, tail, live):
+def mamba_decode_rows(cfg: ModelConfig, p: Params, h, s_all, at, tail, live,
+                      backend: str = "auto"):
     """A decode step of a slot's rows with the state updated WHERE IT LIES
     and only where a row is live: ``s_all [L_mamba, rows, heads, head_dim,
     state]`` the whole carried state, ``at = (layer, first row)``, ``h [B, 1,
@@ -305,40 +307,16 @@ def mamba_decode_rows(cfg: ModelConfig, p: Params, h, s_all, at, tail, live):
     (a finished request, an empty row of the slot, a parked slot) costs
     neither a read nor a write of its 4 MB: one live row of four moves a
     quarter of what ``mamba_block`` over the slot's rows would (its ``dt =
-    0`` leaves a dead row's state as it was, but reads and writes it)."""
+    0`` leaves a dead row's state as it was, but reads and writes it).
+    ``backend``: ``ops/ssm.ssm_step_rows``'s (ONE kernel call a layer on the
+    chip, a loop over the live rows in XLA)."""
     z, xs, dt, A, Bm, Cm, tail = _mixer_in(cfg, p, h, tail, live)
-    l, row0 = at
-    B = h.shape[0]
     with jax.named_scope("ssm"):
         alive = live[:, 0]
-        order = jnp.argsort(~alive)  # the live rows first
-        xs, dt, Bm, Cm = xs[:, 0], dt[:, 0], Bm[:, 0], Cm[:, 0]
-
-        def advance(i, carry):
-            # ONE live row: its state sliced out of the carried array,
-            # advanced, written back — a loop's carried buffer is updated
-            # in place (a ``lax.cond`` a row copied the whole state)
-            s_all, y_all = carry
-            b = order[i]
-            where = (l, row0 + b, 0, 0, 0)
-            s = jax.lax.dynamic_slice(
-                s_all, where, (1, 1, *s_all.shape[2:])
-            )[0]
-
-            def row(a):
-                return jax.lax.dynamic_slice_in_dim(a, b, 1, axis=0)
-
-            y, s = ssm.ssm_step(
-                s, row(xs), row(dt), A, row(Bm), row(Cm), p["D"]
-            )
-            return (
-                jax.lax.dynamic_update_slice(s_all, s[None], where),
-                jax.lax.dynamic_update_slice_in_dim(y_all, y, b, axis=0),
-            )
-
-        s_all, y = jax.lax.fori_loop(
-            0, jnp.sum(alive.astype(jnp.int32)), advance,
-            (s_all, jnp.zeros(xs.shape, f32)),
+        y, s_all = ssm.ssm_step_rows(
+            s_all, at, jnp.argsort(~alive),  # the live rows first
+            jnp.sum(alive.astype(jnp.int32)), xs[:, 0], dt[:, 0], A,
+            Bm[:, 0], Cm[:, 0], p["D"], backend=backend,
         )
     return _mixer_out(cfg, p, h, y[:, None], z), s_all, tail
 
@@ -532,7 +510,7 @@ def forward_layers_paged(
                         )
                 else:  # a decode step: the live rows' state, where it lies
                     h_new, s_all, c = mamba_decode_rows(
-                        cfg, p, h, s_all, (l, row0), c, live
+                        cfg, p, h, s_all, (l, row0), c, live, backend
                     )
                 with jax.named_scope("state"):
                     c_all = jax.lax.dynamic_update_slice(c_all, c[None], at_c)

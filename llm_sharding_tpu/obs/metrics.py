@@ -707,6 +707,20 @@ RECURRENT_ROW_BYTES = REGISTRY.gauge(
     "x 4) of the newest server of a model with recurrent layers: fixed, "
     "whatever the context",
 )
+#: paths a decode step's state update can take (``ops/ssm.ssm_step_rows``)
+RECURRENT_BACKENDS = ("kernel", "interpret", "xla")
+RECURRENT_BACKEND = REGISTRY.gauge(
+    "server_recurrent_backend",
+    "Live servers of a model with recurrent layers by the path their decode "
+    "step advances the live rows' state on (ops/ssm.rows_backend at the "
+    "server's resolved attention backend and the mixer's shapes): kernel = "
+    "ONE Pallas call a mixer layer that reads and writes each live row's "
+    "state once where it lies, xla = a loop over the live rows (the CPU "
+    "path; on a TPU, a shape the kernel cannot tile), interpret = the "
+    "kernel emulated off-TPU. One-hot for a single-server process, all zero "
+    "where no live server's model has recurrent layers",
+    labels=("backend",),
+)
 PREFILL_SCAN_KINDS = ("real", "pad")
 PREFILL_SCAN_POSITIONS = REGISTRY.counter(
     "server_prefill_scan_positions_total",

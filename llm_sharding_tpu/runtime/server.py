@@ -86,7 +86,8 @@ from ..obs.metrics import (
     MOE_EXPERTS_READ, MOE_PAIRS_HELD, MOE_PAIRS_ROUTED,
     PREFILL_BLOCKS_READ, PREFILL_CELLS_LIVE, PREFILL_CELLS_WALKED,
     PREFILL_KV_BLOCKS_WRITTEN, PREFILL_POSITIONS, PREFILL_SCAN_POSITIONS,
-    PREFIX_HIT_RATE, RECURRENT_ROW_BYTES, RECURRENT_ROWS_IN_USE,
+    PREFIX_HIT_RATE, RECURRENT_BACKEND, RECURRENT_BACKENDS,
+    RECURRENT_ROW_BYTES, RECURRENT_ROWS_IN_USE,
     PREFIX_HIT_TOKENS, REGISTRY, record_shape_key, set_prefill_path,
 )
 from ..obs.trace import TraceContext, TraceWriter, emit_span
@@ -228,6 +229,7 @@ def _update_load_gauges() -> None:
     kind_used: dict = {}
     host_blocks = disk_blocks = hit_tok = elig_tok = 0
     backends = dict.fromkeys(ATTN_BACKENDS, 0)
+    state_backends = dict.fromkeys(RECURRENT_BACKENDS, 0)
     arena_bytes = dict.fromkeys(KV_DTYPES, 0)
     for s in list(_LIVE_SERVERS):
         queued += len(s._queue)
@@ -241,6 +243,8 @@ def _update_load_gauges() -> None:
         # single-server process depends on it
         if not getattr(s, "_closed", False):
             backends[getattr(s, "attn_impl", "dense")] += 1
+            if getattr(s, "recurrent", False):
+                state_backends[s.recurrent_backend] += 1
         if getattr(s, "paged", False):
             kv_total += s._alloc.capacity_blocks
             kv_used += s._alloc.in_use
@@ -278,6 +282,8 @@ def _update_load_gauges() -> None:
         KV_KIND_BLOCKS_IN_USE.labels(kind=kind).set(kind_used[kind])
     for b, n in backends.items():
         ATTN_BACKEND.labels(backend=b).set(n)
+    for b, n in state_backends.items():
+        RECURRENT_BACKEND.labels(backend=b).set(n)
     for name, nbytes in arena_bytes.items():
         ARENA_BYTES.labels(dtype=name).set(nbytes)
     KV_BLOCKS_TOTAL.set(kv_total)
@@ -1361,6 +1367,15 @@ class PipelineServer:
         self.attn_impl = (
             self._resolve_attn_impl(paged_attn) if self.paged else "dense"
         )
+        if self.recurrent:
+            # the path a decode step's state update takes under the static
+            # the serve programs compile against (ops/ssm.ssm_step_rows)
+            from ..ops.ssm import rows_backend
+
+            self.recurrent_backend = rows_backend(
+                self.attn_impl, self.cfg.mamba_num_heads, self.cfg.ssm_groups,
+                self.cfg.mamba_head_dim, self.cfg.ssm_state_size,
+            )
         # -- automatic prefix cache (runtime/radix.py) ---------------------
         # "hbm": radix tree over token ids — every submit transparently
         # reuses the longest cached prefix, finished rows' prompt blocks

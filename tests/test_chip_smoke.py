@@ -255,3 +255,36 @@ def test_parent_imports_nothing_that_touches_the_chip():
         capture_output=True, text=True, check=True,
     ).stdout
     assert json.loads(out) == []
+
+
+SSM_TOY = {"layers": 2, "rows": 3, "heads": 8, "head_dim": 8, "state": 128,
+           "groups": 2}
+
+
+@pytest.mark.parametrize("live", [0, 1, 3])
+def test_ssm_rows_check_and_timing_at_toy_widths(live):
+    """``chip_smoke.py --ssm``'s check at toy widths, the kernel emulated: it
+    agrees with the XLA loop, a row that is not live comes back bit for bit,
+    and the timing loop runs with the state carried (a CPU time is no
+    speed)."""
+    got = chip_smoke.check_ssm_rows(SSM_TOY, live, backend="interpret")
+    assert got["dead_rows_untouched"]
+    assert max(got["y_err"], got["s_err"]) <= chip_smoke.SSM_TOL
+    assert chip_smoke.time_ssm_rows(SSM_TOY, live, "interpret", calls=2) > 0
+
+
+def test_ssm_shape_is_the_cells():
+    """The shape ``--ssm`` runs is the benchmark configuration's mixer."""
+    import json
+    import os
+
+    with open(os.path.join(chip_smoke.HERE, "benchmark", "configs",
+                           "nemotron3_super_120b_a12b.json")) as f:
+        cfg = json.load(f)
+    s = chip_smoke.SSM_SHAPE
+    assert (s["heads"], s["head_dim"], s["state"], s["groups"]) == (
+        cfg["mamba_num_heads"], cfg["mamba_head_dim"], cfg["ssm_state_size"],
+        cfg["n_groups"])
+    assert s["layers"] == cfg["hybrid_override_pattern"][
+        :cfg["num_hidden_layers"]].count("M")
+    assert s["rows"] == cfg["serve"]["batch_per_slot"]

@@ -170,16 +170,20 @@ def test_a_dead_row_of_a_decode_step_keeps_its_state(params):
     assert np.abs(c[0, -1] - c0[0, -1]).max() > 1e-3
 
 
+@pytest.mark.parametrize("backend", ["xla", "interpret"])
 @pytest.mark.parametrize("alive", [
     (True, False, True), (False, False, False), (True, True, True),
     (False, True, False)])
-def test_a_decode_step_moves_the_live_rows_state_where_it_lies(params, alive):
-    """``mamba_decode_rows`` (what the paged decode step runs: a loop over
-    the LIVE rows of the slot, each one's state sliced out of the carried
-    array, advanced, written back) against ``mamba_block`` over the slot's
-    rows: the same hidden state and the same new state for a live row, bit
-    for bit the old state for a dead one — and for every row and layer of
-    the carried array outside the slot."""
+def test_a_decode_step_moves_the_live_rows_state_where_it_lies(
+        params, alive, backend):
+    """``mamba_decode_rows`` (what the paged decode step runs: the LIVE rows
+    of the slot advanced inside the carried array — a loop over them in XLA,
+    ONE kernel call whose grid is the live rows under ``interpret``) against
+    ``mamba_block`` over the slot's rows: the same hidden state and the same
+    new state for a live row, bit for bit the old state for a dead one — and
+    for every row and layer of the carried array outside the slot. With no
+    live row the kernel's grid is one step that puts a block back as it
+    came."""
     p = jax.tree.map(lambda a: a[1], params["layers"]["mamba"])
     k = jax.random.split(jax.random.key(11), 3)
     B, rows, row0, layer = len(alive), 6, 2, 1
@@ -192,7 +196,7 @@ def test_a_decode_step_moves_the_live_rows_state_where_it_lies(params, alive):
     h_want, s_want, c_want = nemotron_h.mamba_block(CFG, p, h, slot, c0, live)
     h_got, s_got, c_got = jax.jit(
         lambda s_all, at: nemotron_h.mamba_decode_rows(
-            CFG, p, h, s_all, at, c0, live)
+            CFG, p, h, s_all, at, c0, live, backend)
     )(s_all, (jnp.int32(layer), jnp.int32(row0)))
     assert np.abs(c_got - c_want).max() < 1e-6
     for b, on in enumerate(alive):
@@ -200,11 +204,48 @@ def test_a_decode_step_moves_the_live_rows_state_where_it_lies(params, alive):
         if on:
             assert np.abs(got - s_want[b]).max() < 1e-6
             assert np.abs(h_got[b] - h_want[b]).max() < 1e-5
-        else:
+        else:  # its read-out is ZERO: the mixer adds nothing to the row
             assert np.array_equal(got, slot[b])
+            assert np.array_equal(h_got[b], h[b])
     outside = np.ones(s_all.shape[:2], bool)
     outside[layer, row0:row0 + B] = False
     assert np.array_equal(np.asarray(s_got)[outside], np.asarray(s_all)[outside])
+
+
+@pytest.mark.parametrize("alive", [(False, True), (True, True)])
+def test_the_state_kernel_at_the_published_head_shape(alive):
+    """``ssm_step_rows`` at the shape the chip runs (128 heads of 64, a state
+    of 128, 8 groups: blocks of two groups' 32 heads, 1 MiB, four a row),
+    the kernel emulated against the loop over ``ssm_step``: ``y`` (zero for a
+    row that is not live), the live rows' new state, and every other row of
+    the carried array bit for bit."""
+    nh, hd, ds, g, B = 128, 64, 128, 8, len(alive)
+    assert ssm.head_tile(nh, g, hd, ds) == 32
+    assert ssm.kernel_eligible(nh, g, hd, ds)
+    k = jax.random.split(jax.random.key(44), 8)
+    s_all = jax.random.normal(k[0], (2, B + 1, nh, hd, ds))
+    x = jax.random.normal(k[1], (B, nh, hd))
+    dt = jax.nn.softplus(jax.random.normal(k[2], (B, nh)))
+    A = -jnp.exp(jax.random.normal(k[3], (nh,)))
+    Bm, Cm = (jax.random.normal(k[i], (B, g, ds)) for i in (4, 5))
+    D = jax.random.normal(k[6], (nh,))
+    on = jnp.asarray(alive)
+    at = (jnp.int32(1), jnp.int32(1))
+
+    def run(backend):
+        return jax.jit(lambda s_all: ssm.ssm_step_rows(
+            s_all, at, jnp.argsort(~on), jnp.sum(on.astype(jnp.int32)), x,
+            jnp.where(on[:, None], dt, 0.0), A, Bm, Cm, D, backend=backend,
+        ))(s_all)
+
+    (y_want, s_want), (y_got, s_got) = run("xla"), run("interpret")
+    assert np.abs(y_got - y_want).max() < 2e-4 * np.abs(y_want).max()
+    assert np.abs(s_got - s_want).max() < 1e-5
+    dead = np.ones(s_all.shape[:2], bool)
+    dead[1, 1:] = np.logical_not(alive)
+    assert np.array_equal(np.asarray(s_got)[dead], np.asarray(s_all)[dead])
+    assert np.array_equal(y_got[~np.asarray(alive)], y_want[~np.asarray(alive)])
+    assert np.abs(s_got[1, 1:][np.asarray(alive)] - s_all[1, 1:][np.asarray(alive)]).max() > 1e-3
 
 
 def test_the_shares_of_the_experts_add_up_to_the_uncut_layer(params):

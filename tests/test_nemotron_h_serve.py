@@ -289,6 +289,43 @@ def test_the_counters(params):
     plain.close()
 
 
+@pytest.mark.parametrize("forced,want", [("", "xla"), ("interpret", "interpret")])
+def test_metrics_name_the_path_that_advances_the_state(
+        params, monkeypatch, forced, want):
+    """``server_recurrent_backend`` is one-hot for the path the decode step's
+    state update takes (``ops/ssm.rows_backend``: the server's attention
+    backend at the mixer's shapes), beside ``server_attn_backend``; a server
+    of a model without recurrent layers adds to none of its labels; and a
+    shape the kernel cannot tile falls back to XLA by name."""
+    from llm_sharding_tpu.ops import ssm
+    from llm_sharding_tpu.runtime.server import _update_load_gauges
+
+    def gauge():
+        _update_load_gauges()
+        return {b: metrics.RECURRENT_BACKEND.labels(backend=b).value
+                for b in metrics.RECURRENT_BACKENDS}
+
+    before = gauge()
+    monkeypatch.setenv("PAGED_FORCE_KERNEL", forced)
+    srv = engine(params).serve(**PAGED)
+    assert srv.recurrent_backend == want == srv.attn_impl
+    after = gauge()
+    assert {b: after[b] - before[b] for b in after} == {
+        b: float(b == want) for b in after}
+    assert f'server_recurrent_backend{{backend="{want}"}}' in (
+        metrics.REGISTRY.prometheus_text())
+    r = srv.submit(np.arange(5, dtype=np.int32), 4)
+    srv.run_until_idle()
+    assert len(r.tokens) == 4
+    srv.close()
+    assert gauge() == before
+    # on the chip the tiny mixer (a state of 16) is no whole lane tile
+    monkeypatch.setattr(ssm.jax, "default_backend", lambda: "tpu")
+    assert ssm.rows_backend("kernel", 8, 2, 16, 16) == "xla"
+    assert ssm.rows_backend("kernel", 128, 8, 64, 128) == "kernel"
+    assert ssm.rows_backend("interpret", 8, 2, 16, 16) == "interpret"
+
+
 def test_the_shard_store_and_the_converter_carry_the_kinds(params, tmp_path):
     """The store keeps one block a layer whatever its kind; the converter
     maps the published names (``backbone.layers.N.mixer.*``) onto the kinds'

@@ -1505,8 +1505,9 @@ def test_a_recurrent_models_step_programs_compile_and_read_weights_as_stored(
     model dispatches): the decode program and the chunked
     prefill compile for the described v5e at the published widths — the
     ``relu2`` expert kernel over tiles of 896 columns, both paged kernels for
-    the two attention layers, the state update and the block-form scan in XLA
-    — and the decode step re-lays no weight stack: ``w_in`` leaves its dot
+    the two attention layers, the decode step's state update as ONE kernel a
+    mixer layer (``ssm_rows``) and the block-form scan in XLA — and the
+    decode step re-lays no weight stack: ``w_in`` leaves its dot
     through a barrier, no ``w_in`` / ``w_out`` / expert stack is copied."""
     import importlib.util
 
@@ -1523,9 +1524,10 @@ def test_a_recurrent_models_step_programs_compile_and_read_weights_as_stored(
     dots, windowed = _windowed_projections(decode)
     assert len(dots) >= 3 and windowed == []
     # seventeen runs of one kind: an expert kernel in seven, the decode
-    # kernel in two
-    assert decode.count("tpu_custom_call") == 9
+    # kernel in two, the state kernel in eight
+    assert decode.count("tpu_custom_call") == 17
     assert "paged_decode" in decode and "moe_experts" in decode
+    assert decode.count("ssm_rows/pallas_call") >= 8
     prefill = texts["serve_prefill_chunk[256]"]
     assert "paged_prefill" in prefill and "moe_experts" in prefill
     assert _weight_stack_relayouts(prefill) == []
