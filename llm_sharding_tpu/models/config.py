@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import math
 from typing import Any, Optional
 
 
@@ -35,7 +36,9 @@ class RopeScaling:
     truncate: bool = True
 
 
-#: ``nemotron_h``: a character of ``hybrid_override_pattern`` → the layer's kind
+#: a character of ``layer_pattern`` → the layer's kind (``nemotron_h``
+#: publishes the string as ``hybrid_override_pattern``; ``jamba``'s is made
+#: from its period and offset)
 NEMOTRON_KINDS = {"M": "mamba", "E": "moe", "*": "attn"}
 
 
@@ -143,6 +146,14 @@ class ModelConfig:
     moe_shared_intermediate_size: int = 0
     time_step_min: float = 0.001
     time_step_max: float = 0.1
+    # ``jamba`` (models/jamba.py): every layer is TWO sub-blocks, a mixer —
+    # ``M`` a Mamba-1 mixer, ``*`` attention, by ``layer_pattern`` — and the
+    # dense gated MLP. The mixer runs over ``mamba_d_inner`` channels with a
+    # state of ``ssm_state_size`` a channel; its step comes through a
+    # low-rank path of ``ssm_dt_rank`` (> 0 marks Mamba-1: a decay per
+    # channel AND state value, no heads).
+    mamba_d_inner: int = 0
+    ssm_dt_rank: int = 0
     # GPT-2 specifics
     layer_norm_epsilon: float = 1e-5
     # Token ids. ``eos_token_ids`` holds ALL stop ids (Llama-3.x instruct
@@ -185,30 +196,49 @@ class ModelConfig:
 
     @property
     def recurrent(self) -> bool:
-        """Some layers keep a recurrent state of FIXED size a request
-        (``nemotron_h``'s Mamba-2 mixers): indexed by row beside the paged
+        """Some layers keep a recurrent state of FIXED size a request (a
+        Mamba mixer's, of either family): indexed by row beside the paged
         arenas, not paged by token."""
         return "M" in self.layer_pattern
 
     @property
     def ssm_inner(self) -> int:
-        return self.mamba_num_heads * self.mamba_head_dim
+        return self.mamba_d_inner or self.mamba_num_heads * self.mamba_head_dim
 
     @property
     def conv_dim(self) -> int:
-        """Channels the mixer's conv runs over: ``[x | B | C]``."""
+        """Channels the mixer's conv runs over: Mamba-2's ``[x | B | C]``,
+        Mamba-1's ``x`` alone."""
+        if self.ssm_dt_rank:
+            return self.ssm_inner
         return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state_size
+
+    @property
+    def recurrent_shapes(self) -> dict:
+        """What ONE request keeps in ONE mixer layer, by name (float32; {}
+        for a model without such layers) — the ONE expression of the state's
+        shape: ``ServeState.recurrent``, ``models/stack.zero_recurrent``,
+        ``recurrent_row_bytes`` and ``ops/ssm.rows_backend`` read it.
+        ``ssm``: Mamba-2's ``[heads, head_dim, state]``; Mamba-1's ``[state,
+        8, d_inner / 8]`` — channel ``c`` at ``(c // (d_inner / 8), c %
+        (d_inner / 8))``, so that a state value's channels fill whole (8,
+        128) tiles where ``ops/ssm.scan_rows_tpu`` advances them. ``conv``:
+        the conv's last ``conv_kernel - 1`` inputs."""
+        if not self.recurrent:
+            return {}
+        if self.ssm_dt_rank:
+            ssm = (self.ssm_state_size, 8, self.ssm_inner // 8)
+        else:
+            ssm = (self.mamba_num_heads, self.mamba_head_dim,
+                   self.ssm_state_size)
+        return {"ssm": ssm, "conv": (self.conv_kernel - 1, self.conv_dim)}
 
     @property
     def recurrent_row_bytes(self) -> int:
         """Bytes ONE request's recurrent state holds in ONE mixer layer
-        (float32): the state ``[heads, head_dim, state]`` and the conv's last
-        ``conv_kernel - 1`` inputs."""
-        if not self.recurrent:
-            return 0
-        return 4 * (
-            self.ssm_inner * self.ssm_state_size
-            + (self.conv_kernel - 1) * self.conv_dim
+        (float32): the state and the conv's last ``conv_kernel - 1`` inputs."""
+        return 4 * sum(
+            math.prod(shape) for shape in self.recurrent_shapes.values()
         )
 
     @property
@@ -268,7 +298,7 @@ class ModelConfig:
                 ("moe" if m else "dense") + ("_swa" if a else "_full")
                 for a, m in zip(self.layer_attn, self.layer_moe)
             )
-        if self.model_type == "nemotron_h":
+        if self.model_type in ("nemotron_h", "jamba"):
             return tuple(NEMOTRON_KINDS[c] for c in self.layer_pattern)
         if self.model_type != "deepseek_v3":
             return ()
@@ -364,6 +394,8 @@ class ModelConfig:
             return cls._from_mimo_v2(hf)
         if mt == "nemotron_h":
             return cls._from_nemotron_h(hf)
+        if mt == "jamba":
+            return cls._from_jamba(hf)
         if mt in ("llama",):
             act = hf.get("hidden_act", "silu")
             if act not in ("silu", "gelu_tanh"):
@@ -798,6 +830,91 @@ class ModelConfig:
             eos_token_ids=eos_ids,
         )
 
+    @classmethod
+    def _from_jamba(cls, hf: dict[str, Any]) -> "ModelConfig":
+        """``jamba`` as AI21-Jamba2-3B publishes it. Layer ``l`` is an
+        attention layer where ``l % attn_layer_period == attn_layer_offset``
+        and a Mamba-1 mixer otherwise (the published model code's
+        ``layers_block_type``); every layer's feed-forward is the dense gated
+        MLP. Read: ``mamba_expand`` (x ``hidden_size`` = the mixer's
+        channels), ``mamba_d_state``, ``mamba_d_conv``, ``mamba_dt_rank``
+        (``"auto"``: ``ceil(hidden_size / 16)``), the head counts (``head_dim``
+        where given, else ``hidden_size / num_attention_heads``),
+        ``intermediate_size``, ``rms_norm_eps`` (every norm's, the three inside
+        a mixer too), ``tie_word_embeddings``. Kept and NOT read, each for its
+        reason (``JAMBA_KEYS_NOT_READ``): ``expert_layer_period`` /
+        ``expert_layer_offset`` / ``num_experts_per_tok`` (they choose among
+        layers and experts only where ``num_experts > 1``, which is refused),
+        ``use_mamba_kernels`` (names an implementation), ``num_logits_to_keep``
+        (a generation option), ``max_position_embeddings`` beyond the request
+        check (the block has NO positional embedding). What is not done is
+        refused by name."""
+        need = (
+            "attn_layer_period", "attn_layer_offset", "mamba_d_state",
+            "mamba_expand", "mamba_dt_rank", "intermediate_size",
+        )
+        for key in need:
+            if hf.get(key) is None:
+                raise ValueError(f"jamba config.json lacks {key!r}")
+        refuse = {
+            "num_experts": (1,), "mamba_proj_bias": (False,),
+            "mamba_conv_bias": (True,), "sliding_window": (None,),
+            "hidden_act": ("silu",), "attention_bias": (False,),
+        }
+        for key, ok in refuse.items():
+            if key in hf and hf[key] not in ok:
+                raise ValueError(
+                    f"jamba {key}={hf[key]!r} is not supported (only "
+                    f"{', '.join(map(repr, ok))})"
+                    + (": the routed feed-forward (JambaSparseMoeBlock) is "
+                       "not built" if key == "num_experts" else "")
+                )
+        L, H = int(hf["num_hidden_layers"]), int(hf["hidden_size"])
+        period, offset = (
+            int(hf["attn_layer_period"]), int(hf["attn_layer_offset"])
+        )
+        if not 0 <= offset < period:
+            raise ValueError(
+                f"jamba attn_layer_offset {offset} is not in 0..{period - 1} "
+                f"(attn_layer_period {period})"
+            )
+        d_inner = int(hf["mamba_expand"]) * H
+        if d_inner % 8:
+            raise ValueError(
+                f"jamba mamba_expand x hidden_size = {d_inner} channels do "
+                "not split into 8 (the recurrent state's layout)"
+            )
+        rank = hf["mamba_dt_rank"]
+        eos = hf.get("eos_token_id", 2)
+        eos_ids = tuple(eos) if isinstance(eos, list) else (eos,)
+        return cls(
+            model_type="jamba",
+            vocab_size=hf["vocab_size"],
+            hidden_size=H,
+            intermediate_size=int(hf["intermediate_size"]),
+            num_hidden_layers=L,
+            num_attention_heads=hf["num_attention_heads"],
+            num_key_value_heads=hf.get(
+                "num_key_value_heads", hf["num_attention_heads"]
+            ),
+            head_dim=hf.get("head_dim"),
+            max_position_embeddings=hf.get("max_position_embeddings", 4096),
+            rms_norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
+            tie_word_embeddings=bool(hf.get("tie_word_embeddings", False)),
+            layer_pattern="".join(
+                "*" if l % period == offset else "M" for l in range(L)
+            ),
+            mamba_d_inner=d_inner,
+            ssm_state_size=int(hf["mamba_d_state"]),
+            ssm_dt_rank=-(-H // 16) if rank == "auto" else int(rank),
+            conv_kernel=int(hf.get("mamba_d_conv", 4)),
+            bos_token_id=(
+                1 if hf.get("bos_token_id") is None else hf["bos_token_id"]
+            ),
+            eos_token_id=eos_ids[0],
+            eos_token_ids=eos_ids,
+        )
+
 
 # Convenience presets (sizes mirror the models the reference targets:
 # Llama-2-7B / Llama-3.2-3B / GPT-2, /root/reference/README.md + model_sharder.py)
@@ -1135,6 +1252,57 @@ def tiny_nemotron_h(**kw) -> ModelConfig:
     LatentMoE layers (8 experts of 24 in a latent space of 32, 3 a token, a
     shared expert of 48) and one attention layer (4 heads, 2 key/value)."""
     return ModelConfig.from_hf_config(tiny_nemotron_h_keys(**kw))
+
+
+#: published ``jamba`` keys that ``_from_jamba`` keeps and does NOT read
+JAMBA_KEYS_NOT_READ = (
+    "expert_layer_period", "expert_layer_offset", "num_experts_per_tok",
+    "use_mamba_kernels", "num_logits_to_keep",
+)
+
+
+def jamba2_3b_keys(**kw) -> dict:
+    """AI21-Jamba2-3B's published ``config.json`` keys (28 layers: 26 Mamba-1
+    mixers, attention at layers 7 and 21; a dense gated MLP in every layer)."""
+    base = dict(
+        model_type="jamba",
+        attn_layer_offset=7, attn_layer_period=14, expert_layer_offset=1,
+        expert_layer_period=2, hidden_act="silu", hidden_size=2560,
+        intermediate_size=8192, mamba_conv_bias=True, mamba_d_conv=4,
+        mamba_d_state=16, mamba_dt_rank=160, mamba_expand=2,
+        mamba_proj_bias=False, max_position_embeddings=262144,
+        num_attention_heads=20, num_experts=1, num_experts_per_tok=1,
+        num_hidden_layers=28, num_key_value_heads=1, num_logits_to_keep=1,
+        rms_norm_eps=1e-6, sliding_window=None, tie_word_embeddings=True,
+        use_mamba_kernels=True, vocab_size=65536,
+    )
+    base.update(kw)
+    return base
+
+
+def jamba2_3b(**kw) -> ModelConfig:
+    return ModelConfig.from_hf_config(jamba2_3b_keys(**kw))
+
+
+def tiny_jamba_keys(**kw) -> dict:
+    """The published-style keys of ``tiny_jamba``."""
+    base = jamba2_3b_keys(
+        vocab_size=256, hidden_size=64, num_hidden_layers=6,
+        attn_layer_period=4, attn_layer_offset=2, num_attention_heads=4,
+        num_key_value_heads=1, intermediate_size=96, mamba_d_state=4,
+        mamba_dt_rank=3, max_position_embeddings=256, eos_token_id=255,
+    )
+    base.update(kw)
+    return base
+
+
+def tiny_jamba(**kw) -> ModelConfig:
+    """Tiny jamba-layout config for CPU tests: a period of 4 with the
+    attention layer inside it (``MM*MMM``; 4 query heads over ONE key/value
+    head of 16), five Mamba-1 mixers of 128 channels with a state of 4 a
+    channel and a step rank of 3, a gated MLP of 96 in every layer, a tied
+    head."""
+    return ModelConfig.from_hf_config(tiny_jamba_keys(**kw))
 
 
 def tiny_qwen2(**kw) -> ModelConfig:

@@ -125,6 +125,21 @@ def embed(params: Params, token_ids: jnp.ndarray) -> jnp.ndarray:
     return embed_rows(params["embed"], token_ids)
 
 
+def gated_mlp(cfg: ModelConfig, p: Params, x: jnp.ndarray) -> jnp.ndarray:
+    """``(act(x W_g) ⊙ x W_u) W_d``, the activation in float32 (a deliberate
+    local deviation from HF, which runs it in the model dtype: exact in the
+    f32 parity tests, slightly more accurate than HF in bf16). Activation per
+    family: llama / qwen2 / jamba silu, gemma gelu-tanh."""
+    gate = qmatmul(x, p["w_gate"]).astype(jnp.float32)
+    if cfg.hidden_act == "gelu_tanh":
+        act = jax.nn.gelu(gate, approximate=True)
+    elif cfg.hidden_act == "silu":
+        act = jax.nn.silu(gate)
+    else:  # catch raw HF spellings on hand-built configs, not silently silu
+        raise ValueError(f"unsupported hidden_act {cfg.hidden_act!r}")
+    return qmatmul(act.astype(x.dtype) * qmatmul(x, p["w_up"]), p["w_down"])
+
+
 def attn_mlp_block(
     cfg: ModelConfig,
     p: Params,
@@ -233,22 +248,8 @@ def attn_mlp_block(
             layer=p.get("layer"), backend=moe_backend,
         )
         return h + y.reshape(B, S, H), stats
-    # gated MLP: activation per family (llama/qwen2 silu, gemma gelu-tanh).
-    # The fp32 cast is a deliberate local deviation from HF (which runs the
-    # act in model dtype): exact in the f32 parity tests, slightly more
-    # accurate than HF in bf16.
     with jax.named_scope("mlp"):
-        gate = qmatmul(x, p["w_gate"]).astype(jnp.float32)
-        if cfg.hidden_act == "gelu_tanh":
-            act = jax.nn.gelu(gate, approximate=True)
-        elif cfg.hidden_act == "silu":
-            act = jax.nn.silu(gate)
-        else:  # catch raw HF spellings on hand-built configs, not silently
-            # silu
-            raise ValueError(f"unsupported hidden_act {cfg.hidden_act!r}")
-        mlp = qmatmul(
-            act.astype(x.dtype) * qmatmul(x, p["w_up"]), p["w_down"]
-        )
+        mlp = gated_mlp(cfg, p, x)
         if tp_axis is not None:
             mlp = jax.lax.psum(mlp, tp_axis)
         return h + mlp, None

@@ -69,7 +69,7 @@ from ..ops.quant import qmatmul
 from .config import ModelConfig
 from .llama import embed, final_logits  # noqa: F401  (the family's own)
 from .mimo_v2 import _place_stats, _scan_run
-from .stack import kind_spans
+from .stack import kind_spans, zero_recurrent  # noqa: F401
 
 Params = dict[str, Any]
 f32 = jnp.float32
@@ -95,8 +95,9 @@ def stage_runs(cfg: ModelConfig, layers: Params) -> list:
     for kind, (_, n) in spans.items():
         if seq.count(kind) != n:
             raise NotImplementedError(
-                f"nemotron_h: a stage holds {n} layers of kind {kind!r} where "
-                f"the model's first {total} layers have {seq.count(kind)}: "
+                f"{cfg.model_type}: a stage holds {n} layers of kind {kind!r} "
+                f"where the model's first {total} layers have "
+                f"{seq.count(kind)}: "
                 "every stage must hold the same sequence of layer kinds "
                 "(whole periods of the pattern, none padded)"
             )
@@ -207,19 +208,6 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
         "lm_head": (
             jax.random.normal(k_head, (H, V), f32) * H ** -0.5
         ).astype(dtype),
-    }
-
-
-def zero_recurrent(cfg: ModelConfig, layers: int, rows: int) -> dict:
-    """An empty recurrent state of ``layers`` mixers and ``rows`` rows."""
-    return {
-        "ssm": jnp.zeros(
-            (layers, rows, cfg.mamba_num_heads, cfg.mamba_head_dim,
-             cfg.ssm_state_size), f32,
-        ),
-        "conv": jnp.zeros(
-            (layers, rows, cfg.conv_kernel - 1, cfg.conv_dim), f32
-        ),
     }
 
 

@@ -60,6 +60,17 @@ def kind_spans(layers: dict, kinds: tuple):
     return spans
 
 
+def zero_recurrent(cfg, layers: int, rows: int) -> dict:
+    """An empty recurrent state of ``layers`` mixers and ``rows`` rows, by
+    name: ``{name: [layers, rows, *cfg.recurrent_shapes[name]]}`` float32 —
+    the ONE constructor of the tree (every model with such a state, the
+    tests; ``parallel/serve.init_state`` lays the same shapes out per stage)."""
+    return {
+        name: jnp.zeros((layers, rows, *shape), jnp.float32)
+        for name, shape in cfg.recurrent_shapes.items()
+    }
+
+
 def masked_stats(stats, valid):
     """A masked (padding) layer read and counted nothing."""
     return jax.tree.map(lambda a: jnp.where(valid, a, jnp.zeros_like(a)), stats)

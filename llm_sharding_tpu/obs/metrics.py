@@ -692,20 +692,20 @@ PREFILL_KV_BLOCKS_WRITTEN = REGISTRY.counter(
     "prefill writes that took the block-sized form",
     labels=("write",),
 )
-# a model with recurrent layers (models/nemotron_h.py): a state of fixed size
-# a request, indexed by row beside the paged arena
+# a model with recurrent layers (models/nemotron_h.py, models/jamba.py): a
+# recurrent state of fixed size a request, indexed by row beside the paged arena
 RECURRENT_ROWS_IN_USE = REGISTRY.gauge(
     "server_recurrent_rows_in_use",
-    "Rows holding a live request's recurrent state (a Mamba-2 mixer's "
-    "float32 state and conv tail in every mixer layer) across live servers "
-    "of a model with recurrent layers; host-side, from the rows in flight",
+    "Rows holding a live request's recurrent state (a mixer's float32 state "
+    "and conv tail in every mixer layer) across live servers of a model "
+    "with recurrent layers; host-side, from the rows in flight",
 )
 RECURRENT_ROW_BYTES = REGISTRY.gauge(
     "server_recurrent_row_bytes",
     "Bytes ONE request's recurrent state holds over ALL of a stage's mixer "
-    "layers (layers x (heads x head_dim x state + (kernel - 1) x conv_dim) "
-    "x 4) of the newest server of a model with recurrent layers: fixed, "
-    "whatever the context",
+    "layers (layers x ModelConfig.recurrent_row_bytes: the state and the "
+    "conv's last kernel - 1 inputs, float32) of the newest server of a model "
+    "with recurrent layers: fixed, whatever the context",
 )
 #: paths a decode step's state update can take (``ops/ssm.ssm_step_rows``)
 RECURRENT_BACKENDS = ("kernel", "interpret", "xla")
@@ -721,11 +721,25 @@ RECURRENT_BACKEND = REGISTRY.gauge(
     "where no live server's model has recurrent layers",
     labels=("backend",),
 )
+#: paths a prefill chunk's scan can take (``ops/ssm.scan_path``)
+RECURRENT_SCAN_PATHS = ("block", "kernel", "interpret", "xla")
+RECURRENT_SCAN_PATH = REGISTRY.gauge(
+    "server_recurrent_scan_path",
+    "Live servers of a model with recurrent layers by the path a prefill "
+    "chunk's scan takes (ops/ssm.scan_path): block = Mamba-2's block form, "
+    "matrix products inside blocks of positions (XLA); kernel = Mamba-1's "
+    "scan in time as ONE Pallas call a mixer layer, the state resident "
+    "while the positions loop inside it; xla = that scan as lax.scan over "
+    "positions (the CPU path; on a TPU, a shape the kernel cannot tile); "
+    "interpret = the kernel emulated off-TPU. One-hot for a single-server "
+    "process, all zero where no live server's model has recurrent layers",
+    labels=("path",),
+)
 PREFILL_SCAN_KINDS = ("real", "pad")
 PREFILL_SCAN_POSITIONS = REGISTRY.counter(
     "server_prefill_scan_positions_total",
-    "Positions a chunked-prefill dispatch puts through the block-form "
-    "state-space scan, per mixer layer (slot rows x chunk a dispatch; "
+    "Positions a chunked-prefill dispatch puts through the state-space scan "
+    "(server_recurrent_scan_path says which), per mixer layer (slot rows x chunk a dispatch; "
     "host-side): kind=real — prompt tokens, which advance the state; "
     "kind=pad — padding (a short row, an empty row of the slot), which "
     "has dt = 0 and leaves it as it was. pad / (real + pad) is the scan's "

@@ -133,11 +133,15 @@ SCOPES = (
     "router",     # a sparse MLP's router: float32 logits, softmax, top-k
     "moe",        # a sparse MLP's expert product: tiles, the expert kernel
                   # (``moe_experts``), the weighted sum, its counters
-    "ssm_proj",   # a Mamba-2 mixer's two projections, ``w_in`` and ``w_out``
-                  # with its residual add (models/nemotron_h.py)
+    "ssm_proj",   # a Mamba mixer's two projections, ``w_in`` and ``w_out``
+                  # with its residual add (models/nemotron_h.py, jamba.py)
     "conv",       # the mixer's causal depthwise conv, its tail shift, silu
-    "ssm",        # the state update (a decode step) or the block-form scan
-                  # (a prefill chunk), its read-out, the gated grouped norm
+    "ssm",        # the state update (a decode step) or a prefill chunk's
+                  # scan (Mamba-2's block form, Mamba-1's scan in time), its
+                  # read-out, the gate (Mamba-2: the gated grouped norm)
+    "ssm_x",      # a Mamba-1 mixer's path to ``dt``, ``B``, ``C``: ``w_x``,
+                  # the three norms, ``w_dt`` and its softplus, ``A`` — what
+                  # Mamba-2 has no counterpart of (models/jamba.py)
     "moe_latent", # a LatentMoE's two projections, into the experts' latent
                   # space and out of it
     "head",       # final norm + this stage's logit slice
@@ -282,7 +286,7 @@ class StepRecord:
         self.prefill_kv_blocks = None
         # a model with recurrent layers (None otherwise): rows holding a
         # recurrent state at this step's decode dispatch, and the positions
-        # its prefill chunks put through the block-form scan, ``{"real":
+        # its prefill chunks put through the mixers' scan, ``{"real":
         # prompt tokens, "pad": padding}`` per mixer layer (host arithmetic
         # at dispatch)
         self.recurrent_rows = None
@@ -636,7 +640,7 @@ class StepProfiler:
         self._recurrent_rows = int(rows)
 
     def scan_positions(self, real: int, pad: int) -> None:
-        """Add one chunk dispatch's positions through the block-form scan
+        """Add one chunk dispatch's positions through the mixers' scan
         to the step's record: prompt tokens and padding."""
         if not self._enabled or self._t0 is None:
             return

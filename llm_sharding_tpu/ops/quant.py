@@ -190,6 +190,11 @@ MIMO_QUANT_KEYS = ("wqkv",)
 # latent projections beside ``wq`` .. ``wo`` / ``ws_*`` / ``we_*``; router,
 # conv, ``A_log``, ``D``, ``dt_bias`` and the norms stay
 NEMOTRON_QUANT_KEYS = ("w_in", "w_out", "w_lat_down", "w_lat_up")
+# jamba (models/jamba.py): a Mamba-1 mixer's ``w_in`` / ``w_out`` are
+# nemotron_h's names and its MLP llama's; the low-rank path to ``dt``, ``B``
+# and ``C`` beside them (``w_x``, ``w_dt``); conv, ``A_log``, ``D``,
+# ``dt_bias`` and the norms stay
+JAMBA_QUANT_KEYS = ("w_x", "w_dt")
 
 
 def is_kinds_tree(layers: dict) -> bool:
@@ -210,7 +215,7 @@ def quantize_layer_params(
     if keys is None:
         keys = (
             LLAMA_QUANT_KEYS + GPT2_QUANT_KEYS + DEEPSEEK_QUANT_KEYS
-            + MIMO_QUANT_KEYS + NEMOTRON_QUANT_KEYS
+            + MIMO_QUANT_KEYS + NEMOTRON_QUANT_KEYS + JAMBA_QUANT_KEYS
         )
     if is_kinds_tree(layers):  # one stack per kind: each kind's leaves
         return {

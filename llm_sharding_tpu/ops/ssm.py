@@ -1,14 +1,18 @@
-"""State-space operations of a Mamba-2 mixer (``models/nemotron_h.py``): the
-causal depthwise conv with its tail, the selective state update in its two
-forms, and the gated grouped norm. float32 throughout: a request's recurrent
-state is a float32 array of FIXED size (``[heads, head_dim, state]`` a layer,
-beside the conv's last ``K - 1`` inputs) that thousands of decode steps
-multiply through, so nothing here rounds it to a narrower type. Everything
-is plain XLA but ONE Pallas kernel, the paged decode step's state update
-(``ssm_step_rows``, below).
+"""State-space operations of a model with a recurrent state: the causal
+depthwise conv with its tail, the selective state update of BOTH Mamba
+families in its two forms each, and Mamba-2's gated grouped norm. float32
+throughout: a request's recurrent state is a float32 array of FIXED size (a
+layer's state beside the conv's last ``K - 1`` inputs) that thousands of
+decode steps multiply through, so nothing here rounds it to a narrower type.
+Everything is plain XLA but TWO Pallas kernels: Mamba-2's paged decode update
+(``ssm_rows_tpu``) and Mamba-1's scan in time (``scan_rows_tpu``: a decode
+step and a prefill chunk alike). The families share ``conv_step`` /
+``conv_chunk``, the rule for pads, and ONE entry for the decode step
+(``ssm_step_rows``) with ONE question a server asks (``rows_backend``).
 
-The recurrence, per head ``h`` (``A_h < 0`` a scalar, ``D_h`` a skip gain;
-``B_t``, ``C_t [state]`` shared by the heads of a group)::
+**Mamba-2** (``models/nemotron_h.py``): the recurrence, per head ``h`` (``A_h <
+0`` a scalar, ``D_h`` a skip gain; ``B_t``, ``C_t [state]`` shared by the heads
+of a group)::
 
     S_t = exp(dt_t A) S_{t-1} + dt_t (x_t ⊗ B_t)        S [head_dim, state]
     y_t = S_t C_t + D x_t
@@ -41,6 +45,28 @@ block adds ``exp(c_i) C_i·S_in``, and the state leaving it is ``exp(c_last)
 S_in + Σ_j exp(c_last - c_j) dt_j x_j ⊗ B_j`` — matrix products inside a
 block, the recurrence only from block to block, the row's stored state the
 carry in and out. No token-by-token scan.
+
+**Mamba-1** (``models/jamba.py``): the decay differs per channel ``c`` AND
+per state value ``n`` (``A [state, channels] < 0``; ``dt_t`` a step a CHANNEL;
+``B_t``, ``C_t [state]`` shared by all channels)::
+
+    S_t[n, c] = exp(dt_t[c] A[n, c]) S_{t-1}[n, c] + dt_t[c] B_t[n] x_t[c]
+    y_t[c]    = (Σ_n S_t[n, c] C_t[n] + D[c] x_t[c]) · silu(z_t[c])
+
+With a decay per ``(n, c)`` no block form exists (the masked product would
+cost ``S² · channels · state`` and be no matrix product): a chunk is a SCAN IN
+TIME, sequential over positions and parallel over ``channels × state``.
+``scan_rows`` is both forms — ``S`` positions of a slot's LIVE rows advanced
+where the state lies, ``S = 1`` a decode step (through ``ssm_step_rows``),
+``S`` a chunk's positions in a prefill chunk. ``kernel`` is ``scan_rows_tpu``,
+ONE Pallas call a layer: grid ``(live rows, channel tiles, position blocks)``,
+a tile's state ``[state, 8, 128]`` resident for the row's whole chunk while
+the positions loop INSIDE the kernel, ``x``, ``dt``, ``z`` read once, ``B`` and
+``C`` as scalars, ``y`` written once, the stored state the carry in and out
+(aliased) — nothing of shape ``[positions, channels, state]`` reaches HBM.
+``xla`` is ``lax.scan`` over positions with the state as the carry (the CPU
+path and the kernel's oracle), ``interpret`` the kernel emulated. The state
+lies as ``[state, 8, channels / 8]`` (``ModelConfig.recurrent_shapes``).
 
 **Pads.** A position that is no real token has ``dt = 0`` (the caller forces
 it): ``exp(0) = 1`` and ``0·(x ⊗ B) = 0``, so it leaves the state EXACTLY as
@@ -145,16 +171,41 @@ def kernel_eligible(nh: int, g: int, hd: int, ds: int) -> bool:
     )
 
 
-def rows_backend(backend: str, nh: int, g: int, hd: int, ds: int) -> str:
-    """The path ``ssm_step_rows`` takes for ``backend`` at these shapes:
-    ``ops/moe.resolve_backend``'s answer, and ``xla`` where that is the
-    compiled kernel and the shapes are not ``kernel_eligible``."""
+def _resolve(backend: str, eligible: bool) -> str:
+    """``ops/moe.resolve_backend``'s answer, and ``xla`` where that is the
+    compiled kernel and the shapes are not ``eligible``."""
     from .moe import resolve_backend
 
     backend = resolve_backend(backend)
-    if backend == "kernel" and not kernel_eligible(nh, g, hd, ds):
-        return "xla"
-    return backend
+    return "xla" if backend == "kernel" and not eligible else backend
+
+
+def rows_backend(backend: str, cfg) -> str:
+    """The path the state update of ``cfg``'s mixers takes for ``backend``
+    (``ssm_step_rows``; a Mamba-1 chunk's ``scan_rows`` too): ``kernel``,
+    ``interpret`` or ``xla`` — the one question a server asks, whatever the
+    family, from ``cfg.recurrent_shapes``."""
+    shape = cfg.recurrent_shapes["ssm"]
+    if cfg.ssm_dt_rank:
+        return _resolve(backend, scan_eligible(*shape))
+    nh, hd, ds = shape
+    return _resolve(backend, kernel_eligible(nh, cfg.ssm_groups, hd, ds))
+
+
+def scan_path(backend: str, cfg) -> str:
+    """The path a prefill chunk's scan of ``cfg``'s mixers takes: ``block``
+    (Mamba-2's block form, ``ssm_chunk``) or, Mamba-1, ``rows_backend``'s
+    answer (``scan_rows``)."""
+    return rows_backend(backend, cfg) if cfg.ssm_dt_rank else "block"
+
+
+def _visited(order, n_live):
+    """``[B]`` bool: the rows a kernel's grid visited — the first ``n_live``
+    of ``order``."""
+    at = jnp.arange(order.shape[0], dtype=jnp.int32)
+    return jnp.any(
+        (order[None, :] == at[:, None]) & (at[None, :] < n_live), axis=1
+    )
 
 
 def _rows_kernel(lyr, row0, order, nlive, da, s_ref, x_ref, b_ref, c_ref,
@@ -250,17 +301,26 @@ def ssm_rows_tpu(s_all, at, order, n_live, dA, xdt, Bm, Cm, *,
 
 
 def ssm_step_rows(s_all, at, order, n_live, x, dt, A, Bm, Cm, D,
-                  backend: str = "auto"):
+                  backend: str = "auto", z=None):
     """One position of a slot's LIVE rows with the state advanced WHERE IT
-    LIES: ``s_all [L_mamba, rows, nh, hd, ds]`` the whole carried state, ``at
-    = (layer, first row of the slot)``, ``order [B]`` the slot's rows with
-    the live ones first and ``n_live`` their count; ``x [B, nh, hd]``, ``dt
-    [B, nh]``, ``Bm``, ``Cm [B, g, ds]`` as ``ssm_step``'s → ``(y [B, nh, hd]``
-    f32 — ZERO for a row that is not live —, ``s_all)``. A row that is not
-    live is neither read nor written (module docstring: the backends)."""
+    LIES: ``s_all [L_mamba, rows, ...]`` the whole carried state, ``at =
+    (layer, first row of the slot)``, ``order [B]`` the slot's rows with the
+    live ones first and ``n_live`` their count. Mamba-2 (``A [nh]``): ``s_all
+    [.., nh, hd, ds]``, ``x [B, nh, hd]``, ``dt [B, nh]``, ``Bm``, ``Cm [B, g,
+    ds]`` as ``ssm_step``'s → ``(y [B, nh, hd]`` f32 — ZERO for a row that is
+    not live —, ``s_all)``. Mamba-1 (``A [ds, di]``: a decay per channel and
+    state value): ``s_all [.., ds, 8, di / 8]``, ``x``, ``dt``, the gate ``z
+    [B, di]``, ``Bm``, ``Cm [B, ds]`` → ``y [B, di]``, gated (``scan_rows`` at
+    ONE position). A row that is not live is neither read nor written (module
+    docstring: the backends)."""
+    if A.ndim == 2:
+        y, s_all = scan_rows(
+            s_all, at, order, n_live, x[:, None], dt[:, None], z[:, None], A,
+            Bm[:, None], Cm[:, None], D, backend=backend,
+        )
+        return y[:, 0], s_all
     _, _, nh, hd, ds = s_all.shape
-    B = x.shape[0]
-    backend = rows_backend(backend, nh, Bm.shape[1], hd, ds)
+    backend = _resolve(backend, kernel_eligible(nh, Bm.shape[1], hd, ds))
     if backend == "xla":
         l, row0 = at
 
@@ -294,11 +354,7 @@ def ssm_step_rows(s_all, at, order, n_live, x, dt, A, Bm, Cm, D,
         x * dt[..., None], Bm.astype(f32), Cm.astype(f32),
         interpret=backend == "interpret",
     )
-    # the rows the grid visited: the first ``n_live`` of ``order``
-    at_i = jnp.arange(B, dtype=jnp.int32)
-    live = jnp.any(
-        (order[None, :] == at_i[:, None]) & (at_i[None, :] < n_live), axis=1
-    )
+    live = _visited(order, n_live)
     y = y + D.astype(f32)[None, :, None] * x
     return jnp.where(live[:, None, None], y, 0.0), s_all
 
@@ -361,3 +417,211 @@ def gated_group_norm(y, z, gain, groups: int, eps: float):
     v = v.reshape(*shape[:-1], groups, shape[-1] // groups)
     v = v * jax.lax.rsqrt(jnp.mean(v * v, axis=-1, keepdims=True) + eps)
     return v.reshape(shape) * gain.astype(f32)
+
+
+# ------------------------------------------------- Mamba-1: the scan in time
+
+#: positions a grid step of ``scan_rows_tpu`` holds of ``x``, ``dt``, ``z``
+#: and ``y`` (512 KiB each at a tile of 8 x 128 channels)
+_SCAN_POSITIONS = 128
+
+
+def scan_eligible(ds: int, sub: int, lanes: int) -> bool:
+    """Whether a state value's channels ``[8, channels / 8]`` split into
+    whole (8, 128) tiles, as ``scan_rows_tpu`` advances them."""
+    return sub == 8 and lanes % 128 == 0
+
+
+def scan_step(state, x, dt, z, A, Bm, Cm, D):
+    """One position a row, as the equations. ``state [B, ds, di]`` f32, ``x``,
+    ``dt`` (after softplus; 0 for a row that must not advance), ``z [B, di]``,
+    ``A [ds, di]``, ``Bm``, ``Cm [B, ds]``, ``D [di]`` → ``(y [B, di]`` f32,
+    gated, ``state)``."""
+    x, dt, z = x.astype(f32), dt.astype(f32), z.astype(f32)
+    Bm, Cm = Bm.astype(f32), Cm.astype(f32)
+    dA = jnp.exp(dt[:, None, :] * A.astype(f32)[None])  # [B, ds, di]
+    state = state * dA + (dt * x)[:, None, :] * Bm[:, :, None]
+    y = jnp.sum(state * Cm[:, :, None], axis=1) + D.astype(f32) * x
+    return y * jax.nn.silu(z), state
+
+
+def scan_chunk(state, x, dt, z, A, Bm, Cm, D):
+    """A chunk as ``lax.scan`` over its positions, the state the carry:
+    ``state [B, ds, di]``, ``x``, ``dt``, ``z [B, S, di]``, ``Bm``, ``Cm [B, S,
+    ds]`` → ``(y [B, S, di] f32, state)``. The XLA twin of ``scan_rows_tpu``."""
+    def step(s, t):
+        xt, dtt, zt, bt, ct = t
+        y, s = scan_step(s, xt, dtt, zt, A, bt, ct, D)
+        return s, y
+
+    state, y = jax.lax.scan(
+        step, state, tuple(jnp.swapaxes(a, 0, 1) for a in (x, dt, z, Bm, Cm))
+    )
+    return jnp.swapaxes(y, 0, 1), state
+
+
+def _scan_kernel(lyr, row0, order, nlive, b_ref, c_ref, s_ref, x_ref, dt_ref,
+                 z_ref, a_ref, d_ref, so_ref, y_ref, *, S):
+    """One live row's channel tile over one block of positions: ``s_ref`` /
+    ``so_ref [ds, 8, 128]`` the tile's state in and out (``so_ref`` stays
+    resident over the row's position blocks), ``x_ref``, ``dt_ref``, ``z_ref``,
+    ``y_ref [P, 8, 128]``, ``a_ref [ds, 8, 128]``, ``d_ref [8, 128]``; ``b_ref``,
+    ``c_ref [B * S * ds]`` in scalar memory (row, position, state value): a
+    state value's ``B`` and ``C`` meet its ``[8, 128]`` channels as scalars.
+    The positions loop in here, the state in registers."""
+    i, k = pl.program_id(0), pl.program_id(2)
+    ds, P = s_ref.shape[0], x_ref.shape[0]
+
+    @pl.when(i < nlive[0])
+    def _advance():
+        @pl.when(k == 0)
+        def _first_block():
+            so_ref[...] = s_ref[...]
+
+        A = [a_ref[n] for n in range(ds)]
+        D = d_ref[...]
+        first = (order[i] * S + k * P) * ds  # the block's first scalar
+
+        def position(t, s):
+            x, dt, z = x_ref[t], dt_ref[t], z_ref[t]
+            xdt = x * dt
+            y = D * x
+            new = []
+            for n in range(ds):
+                at = first + t * ds + n
+                sn = s[n] * jnp.exp(dt * A[n]) + xdt * b_ref[at]
+                y = y + sn * c_ref[at]
+                new.append(sn)
+            y_ref[t] = y * z * jax.nn.sigmoid(z)
+            return tuple(new)
+
+        s = jax.lax.fori_loop(
+            0, P, position, tuple(so_ref[n] for n in range(ds))
+        )
+        for n in range(ds):
+            so_ref[n] = s[n]
+
+    @pl.when(i >= nlive[0])
+    def _none_live():
+        # the ONE step of a grid with no live row: its block goes back as
+        # it came
+        so_ref[...] = s_ref[...]
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def scan_rows_tpu(s_all, at, order, n_live, x, dt, z, A, Bm, Cm, D, *,
+                  interpret: bool = False):
+    """The Pallas scan of a slot's live rows in the carried array: ``s_all
+    [L, rows, ds, 8, T]``; ``x``, ``dt``, ``z [B, S, 8, T]``, ``A [ds, 8, T]``,
+    ``D [8, T]``, ``Bm``, ``Cm [B * S * ds]`` (all float32; these two whole in
+    scalar memory, 64 KiB each at 4 rows of 256 positions) → ``(y [B, S, 8, T]``
+    — rows before ``n_live`` in ``order`` WRITTEN, the others not —,
+    ``s_all)``. Grid ``(max(n_live, 1), T / 128, S / P)`` with the first
+    extent TRACED (as ``ssm_rows_tpu``'s); with no live row it is ONE step
+    that writes one block of the first row back as it was read."""
+    _, _, ds, sub, T = s_all.shape
+    B, S = x.shape[:2]
+    P = _SCAN_POSITIONS if S % _SCAN_POSITIONS == 0 else S
+    W = 128 if T % 128 == 0 else T  # (a tiny model's, emulated: all of them)
+    n = jnp.reshape(n_live, (1,)).astype(jnp.int32)
+
+    def row(i, order):
+        return order[jnp.minimum(i, B - 1)]
+
+    state = pl.BlockSpec(
+        (None, None, ds, sub, W),
+        lambda i, j, k, lyr, row0, order, *_: (
+            lyr[0], row0[0] + row(i, order), 0, 0, j
+        ),
+    )
+    acts = pl.BlockSpec(
+        (None, P, sub, W),
+        lambda i, j, k, lyr, row0, order, *_: (row(i, order), k, 0, j),
+    )
+    s_all, y = pl.pallas_call(
+        functools.partial(_scan_kernel, S=S),
+        out_shape=[
+            jax.ShapeDtypeStruct(s_all.shape, f32),
+            jax.ShapeDtypeStruct(x.shape, f32),
+        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,
+            grid=(
+                jnp.maximum(n[0], 1), jnp.where(n[0] > 0, T // W, 1),
+                jnp.where(n[0] > 0, S // P, 1),
+            ),
+            in_specs=[
+                state, acts, acts, acts,
+                pl.BlockSpec((ds, sub, W), lambda i, j, k, *_: (0, 0, j)),
+                pl.BlockSpec((sub, W), lambda i, j, k, *_: (0, j)),
+            ],
+            out_specs=[state, acts],
+        ),
+        input_output_aliases={6: 0},  # the carried state, over itself
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+        ),
+        interpret=interpret,
+        name="ssm_scan",
+    )(
+        *(jnp.reshape(a, (1,)).astype(jnp.int32) for a in at),
+        order.astype(jnp.int32), n, Bm, Cm, s_all, x, dt, z, A, D,
+    )
+    return y, s_all
+
+
+def scan_rows(s_all, at, order, n_live, x, dt, z, A, Bm, Cm, D,
+              backend: str = "auto"):
+    """``S`` positions of a slot's LIVE rows with the state advanced WHERE IT
+    LIES: ``s_all [L_mamba, rows, ds, 8, di / 8]`` the whole carried state,
+    ``at = (layer, first row of the slot)``, ``order [B]`` the slot's rows
+    with the live ones first and ``n_live`` their count; ``x``, ``dt`` (0 at
+    every position that is no real token), ``z [B, S, di]``, ``A [ds, di]``,
+    ``Bm``, ``Cm [B, S, ds]``, ``D [di]`` → ``(y [B, S, di]`` f32, gated —
+    ZERO for a row that is not live —, ``s_all)``. A row that is not live is
+    neither read nor written (module docstring: the backends)."""
+    _, _, ds, sub, T = s_all.shape
+    B, S, di = x.shape
+    backend = _resolve(backend, scan_eligible(ds, sub, T))
+    x, dt, z = x.astype(f32), dt.astype(f32), z.astype(f32)
+    A, D, Bm, Cm = A.astype(f32), D.astype(f32), Bm.astype(f32), Cm.astype(f32)
+    if backend == "xla":
+        l, row0 = at
+
+        def advance(i, carry):
+            # ONE live row (``ssm_step_rows``'s loop says why a loop)
+            s_all, y_all = carry
+            b = order[i]
+            where = (l, row0 + b, 0, 0, 0)
+            s = jax.lax.dynamic_slice(s_all, where, (1, 1, ds, sub, T))[0]
+
+            def row(a):
+                return jax.lax.dynamic_slice_in_dim(a, b, 1, axis=0)
+
+            y, s = scan_chunk(
+                s.reshape(1, ds, di), row(x), row(dt), row(z), A, row(Bm),
+                row(Cm), D,
+            )
+            return (
+                jax.lax.dynamic_update_slice(
+                    s_all, s.reshape(1, 1, ds, sub, T), where
+                ),
+                jax.lax.dynamic_update_slice_in_dim(y_all, y, b, axis=0),
+            )
+
+        s_all, y = jax.lax.fori_loop(
+            0, n_live, advance, (s_all, jnp.zeros(x.shape, f32))
+        )
+        return y, s_all
+
+    def tiles(a):  # [.., di] → [.., 8, di / 8], as the state lies
+        return a.reshape(*a.shape[:-1], sub, T)
+
+    y, s_all = scan_rows_tpu(
+        s_all, at, order, n_live, tiles(x), tiles(dt), tiles(z), tiles(A),
+        Bm.reshape(B * S * ds), Cm.reshape(B * S * ds), tiles(D),
+        interpret=backend == "interpret",
+    )
+    live = _visited(order, n_live)
+    return jnp.where(live[:, None, None], y.reshape(B, S, di), 0.0), s_all

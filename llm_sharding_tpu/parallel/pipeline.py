@@ -128,6 +128,12 @@ def model_fns(
             nemotron_h.forward_layers, nemotron_h.forward_layers_paged
         )
         walks = nemotron_h.prefill_walks
+    elif cfg.model_type == "jamba":
+        from ..models import jamba
+
+        jamba._refuse_tp(tp_axis, cp_axis)
+        fwd, fwd_paged = jamba.forward_layers, jamba.forward_layers_paged
+        walks = jamba.prefill_walks
     else:
         raise ValueError(f"unsupported model_type: {cfg.model_type!r}")
 

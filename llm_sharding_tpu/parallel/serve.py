@@ -121,12 +121,14 @@ class ServeState(NamedTuple):
     v_swa: Any = None
     tables_swa: Any = None
     # A model with recurrent layers (``cfg.recurrent``, paged only;
-    # ``models/nemotron_h.py``) keeps, beside the arena, a state of FIXED size
-    # a request, indexed by ROW and not paged by token: a small tree by name —
-    # ``{"ssm": [S, L_mamba, M, heads, head_dim, state] f32, "conv": [S,
-    # L_mamba, M, K-1, conv_dim] f32}`` [dev]. ``k`` / ``v`` above are then
-    # the ATTENTION layers' arena alone (``[S, L_attn, NB, ...]``). None (an
-    # empty pytree: no operand of any program) for every other model.
+    # ``models/nemotron_h.py``, ``models/jamba.py``) keeps, beside the arena,
+    # a recurrent state of FIXED size a request, indexed by ROW and not paged
+    # by token: a small tree by name — ``{name: [S, L_mamba, M,
+    # *cfg.recurrent_shapes[name]] f32}`` [dev], ``ssm`` the mixers' state and
+    # ``conv`` the conv's last inputs, shaped by the configuration alone.
+    # ``k`` / ``v`` above are then the ATTENTION layers' arena alone (``[S,
+    # L_attn, NB, ...]``). None (an empty pytree: no operand of any program)
+    # for every other model.
     recurrent: Any = None
 
 
@@ -495,16 +497,9 @@ def make_state(
                 "a recurrent state beside the arena needs a paged bf16 arena "
                 "and no tp / cp"
             )
-        Lm = recurrent_layers
         state = state._replace(recurrent={
-            "ssm": zeros(
-                (S, Lm, M, cfg.mamba_num_heads, cfg.mamba_head_dim,
-                 cfg.ssm_state_size), jnp.float32, dev,
-            ),
-            "conv": zeros(
-                (S, Lm, M, cfg.conv_kernel - 1, cfg.conv_dim), jnp.float32,
-                dev,
-            ),
+            name: zeros((S, recurrent_layers, M, *shape), jnp.float32, dev)
+            for name, shape in cfg.recurrent_shapes.items()
         })
     return state
 
@@ -1155,8 +1150,12 @@ def serve_prefill_chunk(
         )
         row0 = slot * Bs
         col0 = prefix_off + chunk_off  # absolute cache column of the chunk
-        # a model with experts: pad positions (sentinel) route nowhere
-        moe_live = (positions != POS_SENTINEL) if cfg.num_experts else None
+        # a model with experts: pad positions (sentinel) route nowhere; a
+        # recurrent state: they do not advance it
+        moe_live = (
+            (positions != POS_SENTINEL)
+            if cfg.num_experts or cfg.recurrent else None
+        )
         p_rows = jax.lax.dynamic_slice_in_dim(st.kpos, row0, Bs, axis=0)
         W = p_rows.shape[1]
         scale_upd = {}
@@ -1531,9 +1530,11 @@ def serve_chunk(
             valid_now = injecting | s.h_valid
             slot_active = ~jnp.all(done_served)
             advance = valid_now & slot_active
-            # a model with experts: the slot's dead rows route nowhere
+            # a model with experts: the slot's dead rows route nowhere; a
+            # recurrent state: they are neither read nor written
             moe_live = (
-                (advance & ~done_served)[:, None] if cfg.num_experts else None
+                (advance & ~done_served)[:, None]
+                if cfg.num_experts or cfg.recurrent else None
             )
 
             # Unconditional commit: a garbage write lands at an offset the

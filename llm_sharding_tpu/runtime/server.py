@@ -87,7 +87,8 @@ from ..obs.metrics import (
     PREFILL_BLOCKS_READ, PREFILL_CELLS_LIVE, PREFILL_CELLS_WALKED,
     PREFILL_KV_BLOCKS_WRITTEN, PREFILL_POSITIONS, PREFILL_SCAN_POSITIONS,
     PREFIX_HIT_RATE, RECURRENT_BACKEND, RECURRENT_BACKENDS,
-    RECURRENT_ROW_BYTES, RECURRENT_ROWS_IN_USE,
+    RECURRENT_ROW_BYTES, RECURRENT_ROWS_IN_USE, RECURRENT_SCAN_PATH,
+    RECURRENT_SCAN_PATHS,
     PREFIX_HIT_TOKENS, REGISTRY, record_shape_key, set_prefill_path,
 )
 from ..obs.trace import TraceContext, TraceWriter, emit_span
@@ -230,6 +231,7 @@ def _update_load_gauges() -> None:
     host_blocks = disk_blocks = hit_tok = elig_tok = 0
     backends = dict.fromkeys(ATTN_BACKENDS, 0)
     state_backends = dict.fromkeys(RECURRENT_BACKENDS, 0)
+    scan_paths = dict.fromkeys(RECURRENT_SCAN_PATHS, 0)
     arena_bytes = dict.fromkeys(KV_DTYPES, 0)
     for s in list(_LIVE_SERVERS):
         queued += len(s._queue)
@@ -245,6 +247,7 @@ def _update_load_gauges() -> None:
             backends[getattr(s, "attn_impl", "dense")] += 1
             if getattr(s, "recurrent", False):
                 state_backends[s.recurrent_backend] += 1
+                scan_paths[s.recurrent_scan_path] += 1
         if getattr(s, "paged", False):
             kv_total += s._alloc.capacity_blocks
             kv_used += s._alloc.in_use
@@ -284,6 +287,8 @@ def _update_load_gauges() -> None:
         ATTN_BACKEND.labels(backend=b).set(n)
     for b, n in state_backends.items():
         RECURRENT_BACKEND.labels(backend=b).set(n)
+    for path, n in scan_paths.items():
+        RECURRENT_SCAN_PATH.labels(path=path).set(n)
     for name, nbytes in arena_bytes.items():
         ARENA_BYTES.labels(dtype=name).set(nbytes)
     KV_BLOCKS_TOTAL.set(kv_total)
@@ -1370,12 +1375,11 @@ class PipelineServer:
         if self.recurrent:
             # the path a decode step's state update takes under the static
             # the serve programs compile against (ops/ssm.ssm_step_rows)
-            from ..ops.ssm import rows_backend
+            from ..ops.ssm import rows_backend, scan_path
 
-            self.recurrent_backend = rows_backend(
-                self.attn_impl, self.cfg.mamba_num_heads, self.cfg.ssm_groups,
-                self.cfg.mamba_head_dim, self.cfg.ssm_state_size,
-            )
+            self.recurrent_backend = rows_backend(self.attn_impl, self.cfg)
+            #: ... and a prefill chunk's scan
+            self.recurrent_scan_path = scan_path(self.attn_impl, self.cfg)
         # -- automatic prefix cache (runtime/radix.py) ---------------------
         # "hbm": radix tree over token ids — every submit transparently
         # reuses the longest cached prefix, finished rows' prompt blocks
@@ -5094,7 +5098,7 @@ class PipelineServer:
                 )
                 self.stepline.prefill_kv_blocks(kv_write, n_written)
             if self.recurrent:
-                # positions through the block-form scan, per mixer layer:
+                # positions through the mixers' scan, per mixer layer:
                 # the rows' prompt tokens in this chunk but each row's LAST
                 # (it enters through the injection path, a decode step), and
                 # the padding beside them

@@ -309,6 +309,64 @@ def nemotron_layer_arrays(
     return p
 
 
+#: ``jamba``: this repo's leaves ← the published checkpoint's tensors under
+#: ``model.layers.{i}.`` (``modeling_jamba.py``'s module names; no network:
+#: not checked against a checkpoint). ``T``: a torch Linear's ``[out, in]``
+#: weight, transposed to ``[in, out]``; ``f32``: kept in float32 whatever
+#: the model dtype (the conv, ``A_log``, ``D``, the step's bias).
+JAMBA_NAMES = {
+    "shared": {
+        "norm": ("input_layernorm.weight", ""),
+        "post_norm": ("pre_ff_layernorm.weight", ""),
+        "w_gate": ("feed_forward.gate_proj.weight", "T"),
+        "w_up": ("feed_forward.up_proj.weight", "T"),
+        "w_down": ("feed_forward.down_proj.weight", "T"),
+    },
+    "attn": {
+        "wq": ("self_attn.q_proj.weight", "T"),
+        "wk": ("self_attn.k_proj.weight", "T"),
+        "wv": ("self_attn.v_proj.weight", "T"),
+        "wo": ("self_attn.o_proj.weight", "T"),
+    },
+    "mamba": {
+        "w_in": ("mamba.in_proj.weight", "T"),  # rows [x | z]
+        "conv_b": ("mamba.conv1d.bias", "f32"),
+        "w_x": ("mamba.x_proj.weight", "T"),  # columns [δ | B | C]
+        "dt_norm": ("mamba.dt_layernorm.weight", ""),
+        "b_norm": ("mamba.b_layernorm.weight", ""),
+        "c_norm": ("mamba.c_layernorm.weight", ""),
+        "w_dt": ("mamba.dt_proj.weight", "T"),
+        "dt_bias": ("mamba.dt_proj.bias", "f32"),
+        "A_log": ("mamba.A_log", "f32"),  # [d_inner, state]
+        "D": ("mamba.D", "f32"),
+        "w_out": ("mamba.out_proj.weight", "T"),
+    },
+}
+
+
+def jamba_layer_arrays(
+    cfg: ModelConfig, get: TensorGetter, i: int, dtype
+) -> dict[str, jnp.ndarray]:
+    """One ``jamba`` layer — its mixer, of kind ``cfg.layer_kinds[i]``, AND
+    its MLP — in the layout of ``models/jamba.py``, by ``JAMBA_NAMES``;
+    ``conv1d.weight`` (``[C, 1, K]`` → ``[K, C]``, float32) is the one tensor
+    re-laid."""
+    pre = f"model.layers.{i}."
+    kind = cfg.layer_kinds[i]
+    p = {}
+    for leaf, (name, how) in {**JAMBA_NAMES["shared"], **JAMBA_NAMES[kind]}.items():
+        t = np.asarray(get(pre + name))
+        p[leaf] = jnp.asarray(
+            t.T if how == "T" else t, jnp.float32 if how == "f32" else dtype
+        )
+    if kind == "mamba":
+        p["conv_w"] = jnp.asarray(
+            np.asarray(get(pre + "mamba.conv1d.weight"))[:, 0, :].T,
+            jnp.float32,
+        )
+    return p
+
+
 def gpt2_layer_arrays(
     cfg: ModelConfig, get: TensorGetter, i: int, dtype
 ) -> dict[str, jnp.ndarray]:
@@ -351,15 +409,18 @@ def _stack(layer_dicts: list[dict[str, jnp.ndarray]]) -> dict[str, jnp.ndarray]:
 #: the models whose layers are of several kinds (one stack per kind)
 KIND_LAYER_ARRAYS = {
     "deepseek_v3": deepseek_layer_arrays, "mimo_v2": mimo_layer_arrays,
-    "nemotron_h": nemotron_layer_arrays,
+    "nemotron_h": nemotron_layer_arrays, "jamba": jamba_layer_arrays,
 }
 
 
 def head_names(cfg: ModelConfig) -> tuple:
     """``(embedding, final norm)`` tensor names of a llama-style checkpoint
-    (``nemotron_h`` keeps its stack under ``backbone``)."""
+    (``nemotron_h`` keeps its stack under ``backbone``; ``jamba`` names its
+    last norm ``final_layernorm``)."""
     if cfg.model_type == "nemotron_h":
         return "backbone.embeddings.weight", "backbone.norm_f.weight"
+    if cfg.model_type == "jamba":
+        return "model.embed_tokens.weight", "model.final_layernorm.weight"
     return "model.embed_tokens.weight", "model.norm.weight"
 
 
@@ -392,7 +453,7 @@ def params_from_hf(
         V = cfg.vocab_size
         layer_arrays = KIND_LAYER_ARRAYS[cfg.model_type]
         embed_name, norm_name = head_names(cfg)
-        return {
+        params = {
             "embed": jnp.asarray(get(embed_name)[:V], dtype),
             "layers": {
                 kind: _stack([
@@ -402,8 +463,12 @@ def params_from_hf(
                 for kind in dict.fromkeys(kinds)
             },
             "final_norm": jnp.asarray(get(norm_name), dtype),
-            "lm_head": jnp.asarray(get("lm_head.weight")[:V].T, dtype),
         }
+        if not cfg.tie_word_embeddings:
+            params["lm_head"] = jnp.asarray(
+                get("lm_head.weight")[:V].T, dtype
+            )
+        return params
     elif cfg.model_type == "gpt2":
         pre = "transformer." if _has(get, "transformer.wte.weight") else ""
         wte = jnp.asarray(get(pre + "wte.weight"), dtype)

@@ -44,11 +44,9 @@ import jax.numpy as jnp
 
 from ..models.config import ModelConfig
 from .convert import (
+    KIND_LAYER_ARRAYS,
     TensorGetter,
     _getter,
-    deepseek_layer_arrays,
-    mimo_layer_arrays,
-    nemotron_layer_arrays,
     head_names,
     gpt2_layer_arrays,
     llama_layer_arrays,
@@ -311,8 +309,7 @@ def save_shards_streaming(
 
     layer_fn = {
         "llama": llama_layer_arrays, "gpt2": gpt2_layer_arrays,
-        "deepseek_v3": deepseek_layer_arrays, "mimo_v2": mimo_layer_arrays,
-        "nemotron_h": nemotron_layer_arrays,
+        **KIND_LAYER_ARRAYS,
     }[cfg.model_type]
     for i in range(cfg.num_hidden_layers):
         block = layer_fn(cfg, get, i, dtype)
@@ -320,7 +317,7 @@ def save_shards_streaming(
             block = quantize_layer_params(block, bits=quant_bits)
         _save_npz(os.path.join(out_dir, f"block_{i}.npz"), block)
 
-    if cfg.model_type in ("llama", "deepseek_v3", "mimo_v2", "nemotron_h"):
+    if cfg.model_type in ("llama", *KIND_LAYER_ARRAYS):
         # a model with a share of the experts may hold a SLICE of the
         # vocabulary: rows 0..V-1
         V = cfg.vocab_size

@@ -52,6 +52,7 @@ def test_prefill_then_decode_through_the_state_is_the_references_forward(
     replies of 24 tokens decoded through the state: every served token is the
     reference's argmax over the WHOLE sequence (float32: margin under 1e-4)."""
     monkeypatch.setenv("PAGED_FORCE_KERNEL", "interpret")
+    seen_before = set(metrics._SHAPE_KEYS_SEEN)  # other files' of this worker
     srv = engine(params).serve(prefix_cache="hbm", **PAGED)
     assert srv.attn_impl == "interpret" and srv.recurrent and not srv.windowed
     # a hit cannot slice a recurrent state: accepted and switched off
@@ -75,7 +76,7 @@ def test_prefill_then_decode_through_the_state_is_the_references_forward(
               if prog == "serve_prefill_chunk" and key[2] == 128}
     assert {key[3] for key in chunks} == {16}
     assert not any(prog == "serve_admit" and key[2] == 128
-                   for prog, key in metrics._SHAPE_KEYS_SEEN)
+                   for prog, key in metrics._SHAPE_KEYS_SEEN - seen_before)
     srv.close()
 
 
@@ -321,9 +322,11 @@ def test_metrics_name_the_path_that_advances_the_state(
     assert gauge() == before
     # on the chip the tiny mixer (a state of 16) is no whole lane tile
     monkeypatch.setattr(ssm.jax, "default_backend", lambda: "tpu")
-    assert ssm.rows_backend("kernel", 8, 2, 16, 16) == "xla"
-    assert ssm.rows_backend("kernel", 128, 8, 64, 128) == "kernel"
-    assert ssm.rows_backend("interpret", 8, 2, 16, 16) == "interpret"
+    from llm_sharding_tpu.models.config import nemotron3_super_120b_a12b
+
+    assert ssm.rows_backend("kernel", CFG) == "xla"
+    assert ssm.rows_backend("kernel", nemotron3_super_120b_a12b()) == "kernel"
+    assert ssm.rows_backend("interpret", CFG) == "interpret"
 
 
 def test_the_shard_store_and_the_converter_carry_the_kinds(params, tmp_path):

@@ -1768,9 +1768,10 @@ def test_attn_backend_metrics(setup, monkeypatch):
 _NO_ARENA_COPY = {"kv_take", "kv_layout", "kv_put"}
 _NO_HEAD = {"head", "sample"}
 #: ``absorb`` is latent attention's (``models/deepseek_v3.py``): neither model here has it.
-# (a Mamba-2 mixer's and a LatentMoE's words are ``nemotron_h``'s alone:
-# ``tests/test_nemotron_h_serve.py`` holds its programs to them)
-_RECURRENT_WORDS = {"ssm_proj", "conv", "ssm", "moe_latent"}
+# (a Mamba mixer's and a LatentMoE's words are ``nemotron_h``'s and
+# ``jamba``'s alone: ``tests/test_nemotron_h_serve.py`` and
+# ``tests/test_jamba_serve.py`` hold their programs to them)
+_RECURRENT_WORDS = {"ssm_proj", "conv", "ssm", "ssm_x", "moe_latent"}
 _MLP_WORDS = {"dense": {"router", "moe", "absorb"} | _RECURRENT_WORDS,
               "experts": {"mlp", "absorb"} | _RECURRENT_WORDS}
 PROGRAM_SCOPES = {
