@@ -48,6 +48,14 @@ whole-block forms that were timed against them (a loop of
 the row-wise write outside block 0. The last line, also left in
 ``chiprun_out/kv_write.json``: ``{"ok": true, "kv_write": [...], "device":
 {...}}``.
+``--kv-decode`` likewise: a DECODE step's K/V write with the attention it
+feeds at OLMoE's, the 7B's and MiMo's window shapes, one and four live rows
+of four — the one op a layer calls (``paged_attention_write``) against the
+pair it replaced (``write_block_kv`` then ``paged_attention``): the arenas
+bit for bit, and the DEVICE time of one layer call of each from a profiler
+trace, with the operations it is made of. The last line, also left in
+``chiprun_out/kv_decode.json``: ``{"ok": true, "kv_decode": [...], "device":
+{...}}``.
 ``--ssm`` likewise: a decode step's state update of a recurrent-state model
 (``ops/ssm.ssm_step_rows``) at Nemotron-3-Super's published mixer shape (128
 heads of 64, a state of 128, 8 groups; 8 layers x 4 rows of float32 state
@@ -793,6 +801,227 @@ def child_kv_write(spec: dict, out_path: str) -> None:
         )
 
 
+#: A DECODE step's K/V write with the attention it feeds, at three of the
+#: cells' shapes: ``group`` query heads a key/value head, ``table`` entries a
+#: row (capacity / 32), each live row ``context`` tokens long; MiMo's window
+#: layers keep the blocks their window reaches and a sink logit.
+KV_DECODE_SHAPES = (
+    {"name": "olmoe_1b_7b", "heads": 16, "group": 1, "dk": 128, "dv": 128,
+     "blocks": 1025, "layers": 16, "table": 128, "context": 1024},
+    {"name": "qwen25_7b", "heads": 4, "group": 7, "dk": 128, "dv": 128,
+     "blocks": 1921, "layers": 28, "table": 128, "context": 1024},
+    {"name": "mimo_v25.swa", "heads": 8, "group": 8, "dk": 256, "dv": 128,
+     "blocks": 53, "layers": 9, "table": 256, "context": 4096,
+     "window": 128},
+)
+#: ``scatter``: ``write_block_kv`` then ``paged_attention``, the pair a layer
+#: called before; ``fused``: ``paged_attention_write``, the one op it calls
+KV_DECODE_FORMS = ("scatter", "fused")
+KV_DECODE_LIVE = (1, 4)
+
+
+def kv_decode_form(form: str, backend: str, window: int = 0):
+    """``step(q, k_new, v_new, k_arena, v_arena, layer, table, cols, qpos,
+    kvpos, sink)`` → ``(out, k_arena, v_arena)`` in one of
+    ``KV_DECODE_FORMS``."""
+    import llm_sharding_tpu.models  # noqa: F401 — ops import through models
+    from llm_sharding_tpu.ops import paged_attention as pa
+
+    def scatter(q, k_new, v_new, k_arena, v_arena, layer, table, cols, qpos,
+                kvpos, sink):
+        k_arena, v_arena = pa.write_block_kv(
+            k_arena, v_arena, layer, table, cols, k_new, v_new
+        )
+        return pa.paged_attention(
+            q, k_arena, v_arena, layer, table, qpos, kvpos, backend=backend,
+            window=window, sink=sink,
+        ), k_arena, v_arena
+
+    def fused(q, k_new, v_new, k_arena, v_arena, layer, table, cols, qpos,
+              kvpos, sink):
+        return pa.paged_attention_write(
+            q, k_new, v_new, k_arena, v_arena, layer, table, cols, qpos,
+            kvpos, backend=backend, window=window, sink=sink,
+        )[:3]
+
+    return {"scatter": scatter, "fused": fused}[form]
+
+
+def kv_decode_inputs(shape: dict, live: int, seed: int = 0):
+    """What one decode layer call takes at ``shape``: the first ``live`` of
+    four rows ``context`` tokens long with the step's entry at the next
+    column (a window layer's rows hold only the blocks the window reaches),
+    the others dead — table all trash, position at the sentinel."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    from llm_sharding_tpu.models.cache import POS_SENTINEL
+
+    B, BS = KV_CHUNK["rows"], KV_CHUNK["block_size"]
+    L, NB, Nkv, T = (shape[k] for k in ("layers", "blocks", "heads", "table"))
+    col, window = shape["context"], shape.get("window", 0)
+    first = max(col - window + 1, 0) // BS if window else 0
+    own = col // BS + 1 - first
+    rng = np.random.default_rng(seed)
+    table = np.zeros((B, T), np.int32)
+    table[:live, first:first + own] = (
+        1 + rng.permutation(NB - 1)[: live * own].reshape(live, own)
+    )
+    kvpos = np.full((B, T * BS), POS_SENTINEL, np.int32)
+    kvpos[:live, : col + 1] = np.arange(col + 1)
+    at = np.where(np.arange(B) < live, col, 0).astype(np.int32)[:, None]
+    qpos = np.where(np.arange(B)[:, None] < live, at, POS_SENTINEL)
+    ks = jax.random.split(jax.random.key(seed), 6)
+    dt = jnp.bfloat16
+    normal = lambda k, *s: jax.random.normal(k, s, dt)
+    return dict(
+        q=normal(ks[0], B, 1, Nkv * shape["group"], shape["dk"]),
+        k_new=normal(ks[1], B, 1, Nkv, shape["dk"]),
+        v_new=normal(ks[2], B, 1, Nkv, shape["dv"]),
+        k_arena=normal(ks[3], L, NB, Nkv, BS, shape["dk"]),
+        v_arena=normal(ks[4], L, NB, Nkv, BS, shape["dv"]),
+        table=jnp.asarray(table), cols=jnp.asarray(at),
+        qpos=jnp.asarray(qpos.astype(np.int32)), kvpos=jnp.asarray(kvpos),
+        sink=jax.random.normal(ks[5], (Nkv * shape["group"],)) if window
+        else None,
+    )
+
+
+def _kv_decode_program(shape: dict, form: str, backend: str):
+    """Every layer of ``shape`` called once, the arenas carried (donated) as
+    the layer scan carries them."""
+    import jax
+    import jax.numpy as jnp
+
+    step = kv_decode_form(form, backend, shape.get("window", 0))
+
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    def run(k_arena, v_arena, q, k_new, v_new, table, cols, qpos, kvpos,
+            sink):
+        def one(carry, layer):
+            ka, va = carry
+            out, ka, va = step(
+                q, k_new, v_new, ka, va, layer, table, cols, qpos, kvpos,
+                sink,
+            )
+            return (ka, va), out
+        return jax.lax.scan(
+            one, (k_arena, v_arena),
+            jnp.arange(shape["layers"], dtype=jnp.int32),
+        )
+
+    def call(inp):
+        rest = [inp[k] for k in (
+            "q", "k_new", "v_new", "table", "cols", "qpos", "kvpos", "sink")]
+        (inp["k_arena"], inp["v_arena"]), out = run(
+            inp["k_arena"], inp["v_arena"], *rest)
+        return out
+
+    return call
+
+
+def check_kv_decode(shape: dict, live: int, backend: str) -> dict:
+    """The fused call against the pair it replaced on the same inputs:
+    whether both arenas are the same bit for bit outside block 0, and the
+    largest difference of the outputs (the same kernel over the same bytes:
+    none)."""
+    import jax.numpy as jnp
+
+    got = {}
+    for form in KV_DECODE_FORMS:
+        inp = kv_decode_inputs(shape, live, seed=1)
+        out = _kv_decode_program(shape, form, backend)(inp)
+        got[form] = (out, inp["k_arena"], inp["v_arena"])
+    (o_s, k_s, v_s), (o_f, k_f, v_f) = got["scatter"], got["fused"]
+    return {
+        "arenas_same": bool(jnp.array_equal(k_s[:, 1:], k_f[:, 1:]))
+        and bool(jnp.array_equal(v_s[:, 1:], v_f[:, 1:])),
+        "max_err": float(jnp.max(jnp.abs(
+            o_s.astype(jnp.float32) - o_f.astype(jnp.float32)))),
+    }
+
+
+def device_op_us(trace_dir: str) -> dict:
+    """Microseconds on the first TPU by operation name, from the profiler
+    trace under ``trace_dir``. A loop's own event spans its body's: names
+    that start with ``while`` are left out so the body counts once."""
+    import collections
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(
+        os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    us = collections.defaultdict(float)
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/device:TPU:0":
+            continue
+        for line in plane.lines:
+            if line.name != "XLA Ops":
+                continue
+            for ev in line.events:
+                name = ev.name.split(" = ", 1)[0].lstrip("%")
+                if not name.startswith("while"):
+                    us[name] += ev.duration_ns / 1e3
+    return dict(us)
+
+
+def time_kv_decode(shape: dict, live: int, form: str, backend: str = "kernel",
+                   runs: int = 8) -> dict:
+    """DEVICE microseconds of ONE layer call in ``form``, from a profiler
+    trace of ``runs`` executions of the program that calls every layer once
+    (a clock around a loop of calls reads the loop's glue: PERF.md, PR 44
+    and PR 45): the sum over the operations and the largest of them."""
+    import jax
+
+    call = _kv_decode_program(shape, form, backend)
+    inp = kv_decode_inputs(shape, live)
+    jax.block_until_ready(call(inp))  # compiles
+    trace_dir = os.path.join(WORK, "trace_kv_decode")
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    jax.profiler.start_trace(trace_dir)
+    for _ in range(runs):
+        out = call(inp)
+    jax.block_until_ready((out, inp["k_arena"], inp["v_arena"]))
+    jax.profiler.stop_trace()
+    calls = runs * shape["layers"]
+    ops = sorted(device_op_us(trace_dir).items(), key=lambda kv: -kv[1])
+    return {
+        "us_per_layer_call": round(sum(u for _, u in ops) / calls, 2),
+        "ops_us": [[n, round(u / calls, 2)] for n, u in ops[:8]],
+    }
+
+
+def child_kv_decode(spec: dict, out_path: str) -> None:
+    import jax
+
+    from llm_sharding_tpu.utils.compile_cache import enable_persistent_cache
+    from llm_sharding_tpu.utils.device_report import device_report
+
+    platform = jax.devices()[0].platform
+    require_tpu(platform, "the decode step's K/V write")
+    enable_persistent_cache(platform)
+    results = []
+    for shape in KV_DECODE_SHAPES:
+        for live in KV_DECODE_LIVE:
+            same = check_kv_decode(shape, live, "kernel")
+            timed = {f: time_kv_decode(shape, live, f)
+                     for f in KV_DECODE_FORMS}
+            results.append({"shape": shape["name"], "live": live, **same,
+                            **timed})
+            print(f"[kv-decode] {shape['name']} live {live}: "
+                  + ", ".join(f"{f} {t['us_per_layer_call']} us {t['ops_us']}"
+                              for f, t in timed.items())
+                  + f"; {same}", flush=True)
+    with open(out_path, "w") as f:
+        json.dump({"device": device_report(), "kv_decode": results}, f)
+    if not all(r["arenas_same"] and r["max_err"] <= KERNEL_TOL
+               for r in results):
+        raise SystemExit(
+            "chip_smoke: the fused decode write differs from the scatter's"
+        )
+
+
 #: the one-step state update at Nemotron-3-Super-120B-A12B's published mixer
 #: shape, carried as the cell carries it: 8 mixer layers x 4 rows of float32
 SSM_SHAPE = {"layers": 8, "rows": 4, "heads": 128, "head_dim": 64,
@@ -1348,12 +1577,17 @@ def main(argv=None) -> int:
                     help="only time a prefill chunk's K/V write (ops/"
                          "paged_attention.py) at the cells' arena shapes: "
                          "whole-block tiles beside the row-wise scatter")
+    ap.add_argument("--kv-decode", action="store_true",
+                    help="only check and time a decode step's K/V write "
+                         "with the attention it feeds (ops/paged_attention."
+                         "py): the one fused op beside the scatter pair")
     ap.add_argument("--ssm", action="store_true",
                     help="only check and time a decode step's state update "
                          "(ops/ssm.py) at Nemotron-3-Super's mixer shape: "
                          "the kernel beside the XLA loop")
     ap.add_argument("--child",
-                    choices=("kernels", "store", "moe", "kv_write", "ssm"))
+                    choices=("kernels", "store", "moe", "kv_write",
+                             "kv_decode", "ssm"))
     ap.add_argument("--spec")
     ap.add_argument("--out")
     args = ap.parse_args(argv)
@@ -1361,9 +1595,11 @@ def main(argv=None) -> int:
         spec = json.loads(args.spec)
         {"kernels": child_kernels, "store": child_store,
          "moe": child_moe, "kv_write": child_kv_write,
+         "kv_decode": child_kv_decode,
          "ssm": child_ssm}[args.child](spec, args.out)
         return 0
-    for mode in ("ssm", "kv_write"):  # one check, its line left behind too
+    # one check, its line left behind too
+    for mode in ("ssm", "kv_write", "kv_decode"):
         if getattr(args, mode):
             os.makedirs(WORK, exist_ok=True)
             got = wait_child(run_child(

@@ -404,12 +404,13 @@ def forward_layers_paged(
     moe_live: Optional[jnp.ndarray] = None,
 ):
     """Paged path (``models/llama.forward_layers_paged``'s contract): the
-    step's latent entries land via ``write_block_kv`` and attention streams
-    the table's blocks of the latent pool, each read ONCE (``latent_v``: the
+    step's latent entries land through ``paged_attention_write`` (keys
+    only: a latent arena holds no values) and attention streams the
+    table's blocks of the latent pool, each read ONCE (``latent_v``: the
     value is the first ``kv_lora_rank`` lanes of the key). Returns ``(h,
     k_arena, v_arena, None, None, stats)``."""
     from ..ops.paged_attention import (
-        paged_attention, paged_prefill, write_block_kv, write_chunk_kv,
+        paged_attention_write, paged_prefill, write_chunk_kv,
     )
 
     _refuse_tp(tp_axis, cp_axis)
@@ -417,10 +418,9 @@ def forward_layers_paged(
         raise NotImplementedError(
             "a quantized (int8/fp8) latent cache is not implemented"
         )
-    # a chunk writes whole blocks from its first column on (llama's note)
-    write, at = (write_chunk_kv, cols[0, 0]) if prefill else (
-        write_block_kv, cols
-    )
+    # a chunk's rows share their columns: it writes whole blocks from its
+    # first column on (llama's note)
+    col0 = cols[0, 0] if prefill else None
     with jax.named_scope("rope"):
         cos, sin = rope_cos_sin(positions, cfg, dtype=jnp.float32)
     wv = write_valid if isinstance(write_valid, bool) else jnp.asarray(
@@ -431,19 +431,21 @@ def forward_layers_paged(
 
     def apply(p, l, valid, h, k_all, v_all, ks_all, vs_all):
         def attend(q_full, entry):
-            k_a, _ = write(
-                k_all, v_all, l, block_table, at, entry, None,
+            if not prefill:  # a decode step (llama's note)
+                o, k_a, *_ = paged_attention_write(
+                    q_full, entry, None, k_all, v_all, l, block_table, cols,
+                    positions, kv_positions, valid=wv & valid, scale=scale,
+                    backend=backend, latent_v=r,
+                )
+                return o, k_a
+            k_a, _ = write_chunk_kv(
+                k_all, v_all, l, block_table, col0, entry, None,
                 valid=wv & valid,
             )
-            if prefill:
-                return paged_prefill(
-                    q_full, k_a, v_all, l, block_table, positions,
-                    kv_positions, scale, backend=backend, walk=walk,
-                    latent_v=r,
-                ), k_a
-            return paged_attention(
-                q_full, k_a, v_all, l, block_table, positions, kv_positions,
-                scale, backend=backend, latent_v=r,
+            return paged_prefill(
+                q_full, k_a, v_all, l, block_table, positions,
+                kv_positions, scale, backend=backend, walk=walk,
+                latent_v=r,
             ), k_a
 
         live = moe_live

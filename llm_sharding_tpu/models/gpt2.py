@@ -194,50 +194,43 @@ def forward_layers_paged(
 ):
     """Paged serve-decode counterpart of ``forward_layers`` (see
     ``models/llama.forward_layers_paged`` — same contract: fresh KV lands
-    via ``write_block_kv`` (quantizing at insert when the arena carries
-    scales), attention streams the table's blocks (dequant fused), kpos
+    through ``paged_attention_write`` (quantizing at insert when the arena
+    carries scales), attention streams the table's blocks (dequant fused), kpos
     bookkeeping stays with the caller; returns scale arenas too).
     ``prefill`` switches the attention dispatch to ``paged_prefill``
     for chunk-shaped queries."""
     from ..ops.paged_attention import (
-        paged_attention, paged_prefill, write_block_kv, write_chunk_kv,
+        paged_attention_write, paged_prefill, write_chunk_kv,
     )
     from .stack import scan_layers_paged
 
     wv = write_valid if isinstance(write_valid, bool) else jnp.asarray(
         write_valid
     )
-    # a chunk writes whole blocks from its first column on (llama's note)
-    write, at = (write_chunk_kv, cols[0, 0]) if prefill else (
-        write_block_kv, cols
-    )
+    # a chunk's rows share their columns: it writes whole blocks from its
+    # first column on (llama's note)
+    col0 = cols[0, 0] if prefill else None
 
     def apply(p, l, valid, h, k_all, v_all, ks_all, vs_all):
         out = {}
 
         def attn_fn(q, k, v):
-            if ks_all is None:
-                k_a, v_a = write(
-                    k_all, v_all, l, block_table, at, k, v,
-                    valid=wv & valid,
+            if not prefill:  # a decode step (llama's note)
+                o, *out["kv"] = paged_attention_write(
+                    q, k, v, k_all, v_all, l, block_table, cols, positions,
+                    kv_positions, valid=wv & valid, backend=backend,
+                    k_scale=ks_all, v_scale=vs_all,
                 )
-                out["kv"] = (k_a, v_a, None, None)
-            else:
-                out["kv"] = write(
-                    k_all, v_all, l, block_table, at, k, v,
-                    valid=wv & valid, k_scale=ks_all, v_scale=vs_all,
-                )
-                k_a, v_a = out["kv"][0], out["kv"][1]
-            if prefill:
-                return paged_prefill(
-                    q, k_a, v_a, l, block_table, positions, kv_positions,
-                    backend=backend, k_scale=out["kv"][2],
-                    v_scale=out["kv"][3], walk=walk,
-                )
-            return paged_attention(
+                return o
+            kv = write_chunk_kv(
+                k_all, v_all, l, block_table, col0, k, v,
+                valid=wv & valid, k_scale=ks_all, v_scale=vs_all,
+            )
+            out["kv"] = kv if ks_all is not None else (*kv, None, None)
+            k_a, v_a, ks, vs = out["kv"]
+            return paged_prefill(
                 q, k_a, v_a, l, block_table, positions, kv_positions,
-                backend=backend, k_scale=out["kv"][2],
-                v_scale=out["kv"][3],
+                backend=backend, k_scale=ks, v_scale=vs, walk=walk,
             )
 
         h = attn_mlp_block(cfg, p, h, attn_fn, tp_axis)

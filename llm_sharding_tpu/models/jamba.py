@@ -289,7 +289,7 @@ def forward_layers_paged(
     """Paged path (``models/nemotron_h.forward_layers_paged``'s contract).
     Returns ``(h, (k_arena, recurrent), v_arena, None, None, None)``."""
     from ..ops.paged_attention import (
-        paged_attention, paged_prefill, write_block_kv, write_chunk_kv,
+        paged_attention_write, paged_prefill, write_chunk_kv,
     )
 
     _refuse_tp(tp_axis, cp_axis)
@@ -303,9 +303,9 @@ def forward_layers_paged(
     # (models/nemotron_h.py says what the edge saves)
     s_in, c_in = jax.lax.optimization_barrier((rec["ssm"], rec["conv"]))
     B, S = h.shape[:2]
-    write, at = (write_chunk_kv, cols[0, 0]) if prefill else (
-        write_block_kv, cols
-    )
+    # a chunk's rows share their columns: it writes whole blocks from its
+    # first column on (llama's note)
+    col0 = cols[0, 0] if prefill else None
     wv = write_valid if isinstance(write_valid, bool) else jnp.asarray(
         write_valid
     )
@@ -325,19 +325,20 @@ def forward_layers_paged(
             )
             if run.kind == "attn":
                 def attend(q, k, v):
-                    k_n, v_n = write(
-                        k_a, v_a, l, block_table, at, k, v, valid=gate
+                    if not prefill:  # a decode step (llama's note)
+                        o, k_n, v_n, *_ = paged_attention_write(
+                            q, k, v, k_a, v_a, l, block_table, cols,
+                            positions, kv_positions, valid=gate,
+                            scale=scale, backend=backend,
+                        )
+                        return o, (k_n, v_n)
+                    k_n, v_n = write_chunk_kv(
+                        k_a, v_a, l, block_table, col0, k, v, valid=gate,
                     )
-                    if prefill:
-                        o = paged_prefill(
-                            q, k_n, v_n, l, block_table, positions,
-                            kv_positions, scale, backend=backend, walk=walk,
-                        )
-                    else:
-                        o = paged_attention(
-                            q, k_n, v_n, l, block_table, positions,
-                            kv_positions, scale, backend=backend,
-                        )
+                    o = paged_prefill(
+                        q, k_n, v_n, l, block_table, positions,
+                        kv_positions, scale, backend=backend, walk=walk,
+                    )
                     return o, (k_n, v_n)
 
                 h_new, (k_a, v_a) = attn_block(cfg, p, h, attend)

@@ -315,8 +315,10 @@ def paged_decoder_layer(
     moe_live: Optional[jnp.ndarray] = None,  # [B, S] positions that route
 ):
     """Decode-path layer over the pooled arena: the step's fresh KV lands
-    via a block-indexed scatter into layer ``layer`` of the stacked pool
-    and attention streams exactly the blocks the table names out of that
+    in the blocks the table names of layer ``layer`` of the stacked pool
+    (``paged_attention_write``: a write kernel that leaves the arena in
+    place where the step's statics allow, the block-indexed scatter
+    otherwise) and attention streams exactly those blocks out of that
     layer (``ops/paged_attention``) — the logical window is never
     materialized and the layer is never sliced out of the stack. A quantized arena (``k_scale``/``v_scale``)
     quantizes the fresh entries at insert and dequantizes inside the
@@ -338,44 +340,41 @@ def paged_decoder_layer(
     flash recurrence — the combined output equals attention over the
     full window, so everything downstream stays shard-replicated."""
     from ..ops.paged_attention import (
-        combine_attn_stats, paged_attention, paged_prefill, write_block_kv,
+        combine_attn_stats, paged_attention_write, paged_prefill,
         write_chunk_kv,
     )
 
     out = {}
+    cp = cp_axis is not None
     # a chunk's rows share their columns: it writes whole blocks from its
-    # first column on, a decode step rows at each row's own
-    write, at = (write_chunk_kv, cols[0, 0]) if prefill else (
-        write_block_kv, cols
-    )
+    # first column on
+    col0 = cols[0, 0] if prefill else None
 
     def attn_fn(q, k, v):
-        if k_scale is None:
-            k_a, v_a = write(
-                k_arena, v_arena, layer, block_table, at, k, v,
-                valid=write_valid & valid,
+        gate = write_valid & valid
+        if prefill:
+            kv = write_chunk_kv(
+                k_arena, v_arena, layer, block_table, col0, k, v,
+                valid=gate, k_scale=k_scale, v_scale=v_scale,
             )
-            out["kv"] = (k_a, v_a, None, None)
-        else:
-            k_a, v_a, ks, vs = write(
-                k_arena, v_arena, layer, block_table, at, k, v,
-                valid=write_valid & valid, k_scale=k_scale, v_scale=v_scale,
-            )
-            out["kv"] = (k_a, v_a, ks, vs)
-        dispatch = paged_prefill if prefill else paged_attention
-        kw = dict(walk=walk) if prefill else {}
-        if cp_axis is not None:
-            acc, m, l = dispatch(
+            out["kv"] = kv if k_scale is not None else (*kv, None, None)
+            k_a, v_a, ks, vs = out["kv"]
+            o = paged_prefill(
                 q, k_a, v_a, layer, block_table, positions, kv_positions,
-                backend=backend, k_scale=out["kv"][2],
-                v_scale=out["kv"][3], stats=True, **kw,
+                backend=backend, k_scale=ks, v_scale=vs, stats=cp,
+                walk=walk,
             )
-            return combine_attn_stats(acc, m, l, cp_axis).astype(q.dtype)
-        return dispatch(
-            q, k_a, v_a, layer, block_table, positions, kv_positions,
-            backend=backend, k_scale=out["kv"][2], v_scale=out["kv"][3],
-            **kw,
-        )
+        else:
+            # a decode step: a row's entries at its own columns, written
+            # and attended by one op (which picks the write's form)
+            o, *out["kv"] = paged_attention_write(
+                q, k, v, k_arena, v_arena, layer, block_table, cols,
+                positions, kv_positions, valid=gate, backend=backend,
+                k_scale=k_scale, v_scale=v_scale, stats=cp,
+            )
+        if cp:
+            return combine_attn_stats(*o, cp_axis).astype(q.dtype)
+        return o
 
     if "router" in p:
         # a masked layer and a ring-inactive microstep route nowhere: their

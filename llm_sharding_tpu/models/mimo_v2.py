@@ -439,7 +439,7 @@ def forward_layers_paged(
     bound on the walk, a mask at the edge) and its sink. Returns ``(h,
     k_arena, v_arena, None, None, stats)``."""
     from ..ops.paged_attention import (
-        paged_attention, paged_prefill, write_block_kv, write_chunk_kv,
+        paged_attention_write, paged_prefill, write_chunk_kv,
     )
 
     _refuse_tp(tp_axis, cp_axis)
@@ -447,10 +447,9 @@ def forward_layers_paged(
         raise NotImplementedError(
             "a quantized (int8/fp8) arena under mimo_v2 is not implemented"
         )
-    # a chunk writes whole blocks from its first column on (llama's note)
-    write, at = (write_chunk_kv, cols[0, 0]) if prefill else (
-        write_block_kv, cols
-    )
+    # a chunk's rows share their columns: it writes whole blocks from its
+    # first column on (llama's note)
+    col0 = cols[0, 0] if prefill else None
     rope = _rope_tables(cfg, positions)
     wv = write_valid if isinstance(write_valid, bool) else jnp.asarray(
         write_valid
@@ -475,20 +474,21 @@ def forward_layers_paged(
             l = i + run.arena_first  # the layer's slot in its kind's arena
 
             def attend(q, k, v):
-                k_a, v_a = write(
-                    k_all, v_all, l, tbl, at, k, v, valid=wv & valid,
-                )
                 kw = {"window": win, "sink": p.get("sink")}
-                if prefill:
-                    o = paged_prefill(
-                        q, k_a, v_a, l, tbl, positions, kv_positions,
-                        scale, backend=backend, walk=wk, **kw,
+                if not prefill:  # a decode step (llama's note)
+                    o, k_a, v_a, *_ = paged_attention_write(
+                        q, k, v, k_all, v_all, l, tbl, cols, positions,
+                        kv_positions, valid=wv & valid, scale=scale,
+                        backend=backend, **kw,
                     )
-                else:
-                    o = paged_attention(
-                        q, k_a, v_a, l, tbl, positions, kv_positions,
-                        scale, backend=backend, **kw,
-                    )
+                    return o, (k_a, v_a)
+                k_a, v_a = write_chunk_kv(
+                    k_all, v_all, l, tbl, col0, k, v, valid=wv & valid,
+                )
+                o = paged_prefill(
+                    q, k_a, v_a, l, tbl, positions, kv_positions,
+                    scale, backend=backend, walk=wk, **kw,
+                )
                 return o, (k_a, v_a)
 
             live = moe_live

@@ -154,9 +154,10 @@ def scan_layers_paged(
     #   here on (one kind's stack, ``kind_spans``); ``layer_mask`` is theirs
 ):
     """Paged analogue of ``scan_layers``: the cache is the pooled block
-    arena, and a layer's update is the tiny block-indexed scatter of this
-    step's entries (``ops/paged_attention.write_block_kv`` inside
-    ``apply_layer``) — never a full-row or full-window write. The
+    arena, and a layer's update is the tiny block-indexed write of this
+    step's entries (``ops/paged_attention.paged_attention_write`` inside
+    ``apply_layer``: a write kernel or ``write_block_kv``'s scatter) —
+    never a full-row or full-window write. The
     layer-stacked arena rides the scan carry and goes to ``apply_layer``
     WHOLE, with the layer index: the attention ops address ``(l, block)``
     inside it (the kernels through a scalar-prefetched ``l``, the XLA path

@@ -227,7 +227,8 @@ class StepRecord:
         "expert_tokens", "experts_read", "expert_steps", "expert_rows",
         "decode_blocks_live", "decode_blocks_reserved",
         "prefill_cells_live", "prefill_cells_walked", "kv_kinds",
-        "prefill_kv_blocks", "recurrent_rows", "scan_positions",
+        "prefill_kv_blocks", "decode_kv_entries", "recurrent_rows",
+        "scan_positions",
     )
 
     def __init__(self, ts, wall_s, phases, blocked_s, idle_s,
@@ -284,6 +285,10 @@ class StepRecord:
         # fresh K/V landed in, by the form of the write ("tile" / "rows");
         # None in a step that dispatched no chunk
         self.prefill_kv_blocks = None
+        # decode / verify dispatches of this step: the fresh K/V entries
+        # they land in the arena, by the form of the write ("kernel" /
+        # "scatter"); None in a step that dispatched none over a paged arena
+        self.decode_kv_entries = None
         # a model with recurrent layers (None otherwise): rows holding a
         # recurrent state at this step's decode dispatch, and the positions
         # its prefill chunks put through the mixers' scan, ``{"real":
@@ -325,6 +330,8 @@ class StepRecord:
             d["kv_kinds"] = {k: dict(v) for k, v in self.kv_kinds.items()}
         if self.prefill_kv_blocks is not None:
             d["prefill_kv_blocks"] = dict(self.prefill_kv_blocks)
+        if self.decode_kv_entries is not None:
+            d["decode_kv_entries"] = dict(self.decode_kv_entries)
         if self.recurrent_rows is not None:
             d["recurrent_rows"] = self.recurrent_rows
         if self.scan_positions is not None:
@@ -379,6 +386,7 @@ class StepProfiler:
         self._decode_blocks = [0, 0]  # [live, reserved]
         self._prefill_cells = [0, 0]  # [live, walked]
         self._prefill_kv_blocks = None  # {"tile" | "rows": blocks}
+        self._decode_kv_entries = None  # {"kernel" | "scatter": entries}
         self._recurrent_rows = None
         self._scan_positions = None  # {"real" | "pad": positions}
         self._kv_kinds = None
@@ -486,6 +494,7 @@ class StepProfiler:
         self._decode_blocks = [0, 0]
         self._prefill_cells = [0, 0]
         self._prefill_kv_blocks = None
+        self._decode_kv_entries = None
         self._recurrent_rows = None
         self._scan_positions = None
         self._kv_kinds = None
@@ -632,6 +641,17 @@ class StepProfiler:
         acc = self._prefill_kv_blocks
         acc[write] = acc.get(write, 0) + int(blocks)
 
+    def decode_kv_entries(self, write: str, entries: int) -> None:
+        """Add one decode or verify dispatch's K/V write to the step's
+        record: the entries it landed, under the form of the write
+        (``kernel`` or ``scatter``)."""
+        if not self._enabled or self._t0 is None:
+            return
+        if self._decode_kv_entries is None:
+            self._decode_kv_entries = {}
+        acc = self._decode_kv_entries
+        acc[write] = acc.get(write, 0) + int(entries)
+
     def recurrent_rows(self, rows: int) -> None:
         """Record the rows holding a recurrent state at a decode dispatch
         (a gauge: the step's newest)."""
@@ -726,6 +746,7 @@ class StepProfiler:
         )
         rec.kv_kinds = self._kv_kinds
         rec.prefill_kv_blocks = self._prefill_kv_blocks
+        rec.decode_kv_entries = self._decode_kv_entries
         rec.recurrent_rows = self._recurrent_rows
         rec.scan_positions = self._scan_positions
         if self._experts is not None:

@@ -63,24 +63,34 @@ whose branch copies made it SLOWER than full-capacity attention — see the
 measured note in README) is superseded by this op: block granularity gives
 the live-prefix-only HBM traffic the bucketed switch was after, without
 copying the cache into a conditional branch. The SERVE programs call it
-too: ``parallel/serve.serve_chunk`` / ``serve_verify`` route decode-step
-attention through ``paged_attention(backend=...)`` directly on the pooled
-arena (new KV entries land via ``write_block_kv`` — a block-indexed
-scatter, never a full-window round trip), so per-step attention HBM
+too: ``parallel/serve.serve_chunk`` / ``serve_verify`` route a decode
+step's write and attention through ``paged_attention_write(backend=...)``
+directly on the pooled arena (new KV entries land in the blocks the table
+names, never through a full-window round trip), so per-step attention HBM
 traffic scales with the blocks a row actually owns. The XLA gather path
 remains the bit-exact CPU/tier-1 fallback behind the same dispatch.
 
 Who writes the arena, and at what granularity (one algorithm — fresh
 entries land in the blocks the table names, invalid ones in block 0 of
-their layer — at two granularities, chosen by what a program's statics
+their layer — at three granularities, chosen by what a program's statics
 say, never by an option):
 
+- ONE SUBLANE TILE A ROW, ``write_rows_tpu`` (the kernel
+  ``paged_kv_write``): a decode step (``serve_chunk``: one entry a row, at
+  each row's own column) over a plain arena with the attention on its
+  kernel (``decode_writes_in_kernel``). The arena rides in once, aliased
+  over itself; a grid step a row moves the ``(Nkv, SUB, D)`` tile that
+  holds the slot in, puts the entry into it and moves it back, K and V
+  together — 3.2-3.5 us a layer call at OLMoE's 16 heads where the two
+  scatters took 12.8 (``chip_smoke.py --kv-decode``, PERF.md PR 46), and
+  XLA, seeing no scatter, re-lays and stages nothing.
 - ROWS, ``write_block_kv``: ``B x S x Nkv`` rows of ``D``, each with its
-  own ``(layer, block, head, slot)``. Every decode step (``serve_chunk``:
-  one entry a row, at each row's own column), speculation's verify
-  (``serve_verify``: per-row columns), and a prefill chunk that cannot be
-  tiles — shorter than a block (a context-parallel radix admission with a
-  short suffix), or over an int8/fp8 arena (running per-block scales).
+  own ``(layer, block, head, slot)``. What a decode step writes everywhere
+  else — speculation's verify (``serve_verify``: ``K + 1`` entries a row),
+  an int8/fp8 arena (running per-block scales), context parallel, the XLA
+  attention path (the CPU mesh) — and a prefill chunk that cannot be
+  tiles: shorter than a block (a context-parallel radix admission with a
+  short suffix), or over an int8/fp8 arena.
 - TILES, ``write_chunk_kv``: a prefill chunk (``serve_prefill_chunk``)
   of whole blocks over a plain arena. Its rows share their columns, which
   start on a block boundary, and the arena is head-major, so a layer
@@ -1600,6 +1610,50 @@ def paged_prefill(
     )
 
 
+def _check_backend(backend: str) -> None:
+    if backend not in BACKENDS:
+        raise ValueError(
+            f"paged_attention backend {backend!r}: expected one of "
+            f"{BACKENDS}"
+        )
+
+
+def decode_path(backend: str, head_dim: int, k_arena, block_table) -> str:
+    """What ``paged_attention`` runs for ``backend`` on these shapes:
+    ``"kernel"`` (the Pallas kernel, compiled), ``"interpret"`` (the kernel
+    emulated off-TPU) or ``"xla"`` (the gather). ``PAGED_FORCE_KERNEL``
+    overrides ``auto`` only; ``kernel`` on a host without a TPU or on a
+    shape Mosaic refuses is an error here, not a lowering failure later."""
+    _check_backend(backend)
+    if backend == "auto":
+        backend = forced_backend() or "auto"
+    if backend in ("interpret", "xla"):
+        return backend
+    eligible = kernel_eligible(
+        head_dim, k_arena.shape[3], k_arena.dtype,
+        rows=block_table.shape[0],
+        table_width=block_table.shape[1], kv_heads=k_arena.shape[2],
+    )
+    if backend == "kernel":
+        # curated here too, not only in the serve-side resolution: a
+        # lingering PAGED_FORCE_KERNEL=kernel reaching a CPU host (or a
+        # Mosaic-ineligible shape on TPU) through backend="auto" would
+        # otherwise surface as a raw Pallas/Mosaic lowering error
+        if jax.default_backend() != "tpu":
+            raise ValueError(
+                f"paged_attention backend 'kernel' requires a TPU backend "
+                f"(got {jax.default_backend()}); use backend='interpret' "
+                f"(or PAGED_FORCE_KERNEL=interpret) to emulate the kernel "
+                f"off-TPU"
+            )
+        if not eligible:
+            raise ValueError(
+                _ineligible_msg("paged_attention", k_arena, block_table)
+            )
+        return "kernel"
+    return "kernel" if jax.default_backend() == "tpu" and eligible else "xla"
+
+
 @jax.named_scope("attn")
 def paged_attention(
     q: jnp.ndarray,
@@ -1634,61 +1688,232 @@ def paged_attention(
     the XLA gather path (the stats-emitting kernel is the ROADMAP
     ring-fusion leftover), so ``backend`` only governs the plain
     single-shard dispatch."""
-    if backend not in BACKENDS:
-        raise ValueError(
-            f"paged_attention backend {backend!r}: expected one of "
-            f"{BACKENDS}"
-        )
     if stats and (latent_v or window or sink is not None):
         raise NotImplementedError(
             "context-parallel attention over a latent arena, a windowed "
             "layer or a sink logit is not done"
         )
+    _check_backend(backend)
     if stats:
         return attn_stats_xla(
             q, k_arena, v_arena, layer, block_table, q_positions,
             kv_positions, scale, k_scale=k_scale, v_scale=v_scale,
         )
-    if backend == "auto":
-        backend = forced_backend() or "auto"
-    eligible = kernel_eligible(
-        q.shape[-1], k_arena.shape[3], k_arena.dtype,
-        rows=block_table.shape[0],
-        table_width=block_table.shape[1], kv_heads=k_arena.shape[2],
-    )
-    if backend == "interpret":
+    path = decode_path(backend, q.shape[-1], k_arena, block_table)
+    if path != "xla":
         return paged_attention_tpu(
             q, k_arena, v_arena, layer, block_table, q_positions,
-            kv_positions, scale, interpret=True, k_scale=k_scale,
-            v_scale=v_scale, latent_v=latent_v, window=window, sink=sink,
-        )
-    if backend == "kernel":
-        # curated here too, not only in the serve-side resolution: a
-        # lingering PAGED_FORCE_KERNEL=kernel reaching a CPU host (or a
-        # Mosaic-ineligible shape on TPU) through backend="auto" would
-        # otherwise surface as a raw Pallas/Mosaic lowering error
-        if jax.default_backend() != "tpu":
-            raise ValueError(
-                f"paged_attention backend 'kernel' requires a TPU backend "
-                f"(got {jax.default_backend()}); use backend='interpret' "
-                f"(or PAGED_FORCE_KERNEL=interpret) to emulate the kernel "
-                f"off-TPU"
-            )
-        if not eligible:
-            raise ValueError(
-                _ineligible_msg("paged_attention", k_arena, block_table)
-            )
-    use_pallas = backend == "kernel" or (
-        backend == "auto" and jax.default_backend() == "tpu" and eligible
-    )
-    if use_pallas:
-        return paged_attention_tpu(
-            q, k_arena, v_arena, layer, block_table, q_positions,
-            kv_positions, scale, k_scale=k_scale, v_scale=v_scale,
-            latent_v=latent_v, window=window, sink=sink,
+            kv_positions, scale, interpret=path == "interpret",
+            k_scale=k_scale, v_scale=v_scale, latent_v=latent_v,
+            window=window, sink=sink,
         )
     return paged_attention_xla(
         q, k_arena, v_arena, layer, block_table, q_positions,
         kv_positions, scale, k_scale=k_scale, v_scale=v_scale,
         latent_v=latent_v, window=window, sink=sink,
     )
+
+
+def decode_writes_in_kernel(
+    entries: int, quantized: bool, stats: bool, path: str
+) -> bool:
+    """Whether ``paged_attention_write`` lands a step's fresh K/V through
+    the write KERNEL (``write_rows_tpu``) instead of ``write_block_kv``'s
+    scatters: what the step program's statics say — ONE entry a row (a
+    decode step; a verify's ``K + 1`` entries are rows), a plain arena (an
+    int8/fp8 one keeps running per-block scales), no partial statistics
+    (context parallel: its attention is the gather) and the attention
+    itself on the kernel (``decode_path``: ``"kernel"`` or ``"interpret"``).
+    The host asks the same question for its counter
+    (``runtime/server.py``)."""
+    return entries == 1 and not quantized and not stats and path != "xla"
+
+
+def _write_rows_kernel(
+    layer_ref,  # scalar-prefetch [1] — read by the index maps only
+    tbl_ref,  # scalar-prefetch [B, T] — index maps only
+    ok_ref,  # scalar-prefetch [B] — index maps only: 0 = the trash block
+    col_ref,  # scalar-prefetch [B] — the entry's column
+    *refs,  # per arena: new [1, Nkv, D] (the row's fresh entry), then per
+    #   arena the tile [Nkv, SUB, D] read, then the same tile written
+    column,  # the map from a row's column operand to its clipped column
+    block_size,
+):
+    n = len(refs) // 3
+    slot = column(col_ref[pl.program_id(0)]) % block_size
+    for new_ref, old_ref, out_ref in zip(
+        refs[:n], refs[n:2 * n], refs[2 * n:]
+    ):
+        Nkv, sub, D = old_ref.shape
+        # a select along the tile's sublanes against the slot, on 32-bit
+        # lanes (a 2-byte float widens and narrows again exactly): the
+        # entry's bits land as the scatter would have stored them and
+        # every other row of the tile goes back as it came
+        wide = old_ref.dtype if old_ref.dtype.itemsize == 4 else jnp.float32
+        here = jax.lax.broadcasted_iota(jnp.int32, (sub, D), 0) == slot % sub
+        new = new_ref[0].astype(wide)  # [Nkv, D]: a head a sublane
+        for h in range(Nkv):
+            out_ref[h] = jnp.where(
+                here, new[h:h + 1], old_ref[h].astype(wide)
+            ).astype(out_ref.dtype)
+
+
+@jax.named_scope("kv_write")
+def write_rows_tpu(
+    k_arena: jnp.ndarray,  # [L, NB, Nkv, BS, D] pooled key blocks
+    v_arena: jnp.ndarray,  # [L, NB, Nkv, BS, Dv]; Dv == 0: a latent arena
+    layer,  # scalar int32
+    block_table: jnp.ndarray,  # [B, T]
+    cols: jnp.ndarray,  # [B] int32 — each row's ONE fresh entry's column
+    k_new: jnp.ndarray,  # [B, Nkv, D]
+    v_new: jnp.ndarray,  # [B, Nkv, Dv] (not read over a latent arena)
+    valid=None,  # scalar or [B] bool — False: the layer's trash block
+    interpret: bool = False,
+):
+    """``write_block_kv`` at ONE entry a row over a plain arena as a KERNEL
+    that leaves the arena where it lies: each arena rides in ONCE, aliased
+    over itself (``input_output_aliases``, as ``ops/ssm.py`` carries its
+    state), and one grid step a row reads the sublane tile ``(Nkv, SUB,
+    D)`` of the entry's block that holds its slot, puts the fresh entry
+    into it in VMEM and hands it back — keys and values in the same step.
+    A block no row names is never touched, and XLA sees no scatter into
+    the arena (on the chip the scatter pair cost more device time than the
+    attention it fed: PERF.md, PR 46).
+
+    The same bytes land in the same blocks as ``write_block_kv``'s: a
+    trash-mapped column and a gated entry (``valid`` False) name block 0
+    of the layer BY ADDRESS (the index map reads the gate), so no owned
+    block is read back or rewritten; only the sink can be named by two
+    rows, and it is a garbage sink by contract (its other rows go back as
+    one of the racing steps read them). A row's frontier block is its own
+    (radix-shared prefix blocks are full), so no step reads what another
+    writes. The table and the columns ride as scalar-prefetch operands and
+    the index maps look the block up, so no XLA operation prepares the
+    call but the gate's conversion.
+
+    Why not inside ``paged_decode``, whose frontier cell holds this very
+    block: that kernel takes the arena once per sub-block ref, and XLA
+    answers a buffer that one call both reads through other operands and
+    aliases to an output with a copy of the WHOLE arena a layer call
+    (compiled for a v5e: PERF.md, PR 46)."""
+    B, Nkv, _ = k_new.shape
+    BS = k_arena.shape[3]
+    W = block_table.shape[1] * BS
+
+    # the clip (as write_block_kv's) and the gather through the table are
+    # the index map's: scalar work on operands the kernel prefetches, no
+    # XLA operation a layer call
+    def column(c):
+        return jnp.clip(c, 0, W - 1)
+
+    ok = jnp.broadcast_to(
+        jnp.ones((), jnp.int32) if valid is None
+        else jnp.asarray(valid).astype(jnp.int32), (B,)
+    )
+    # the tile a step moves: the storage dtype's sublanes (one (SUB, 128)
+    # tile a head and lane group), or the block where it is not tiles
+    sub = kernel_sublane(k_arena.dtype)
+    sub = sub if BS % sub == 0 else BS
+    arenas = [(k_arena, k_new)]
+    if v_arena.shape[-1]:  # a latent arena holds no values
+        arenas.append((v_arena, v_new))
+
+    def of_row(b, lyr, tbl, ok, col):
+        return (b, 0, 0)
+
+    def tile_index(b, lyr, tbl, ok, col):
+        c = column(col[b])
+        blk = jnp.where(ok[b] != 0, tbl[b, c // BS], 0)  # by ADDRESS
+        return (lyr[0], blk, 0, c % BS // sub, 0)
+
+    new_specs, tile_specs, news = [], [], []
+    for arena, new in arenas:
+        D = arena.shape[-1]
+        new_specs.append(pl.BlockSpec((1, Nkv, D), of_row))
+        tile_specs.append(pl.BlockSpec((None, None, Nkv, sub, D), tile_index))
+        news.append(new.astype(arena.dtype))
+    n = len(arenas)
+    out = pl.pallas_call(
+        functools.partial(_write_rows_kernel, column=column, block_size=BS),
+        out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype) for a, _ in arenas],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B,),
+            in_specs=new_specs + tile_specs,
+            out_specs=tile_specs,
+        ),
+        # each arena over itself: operand 4 + n + i is output i
+        input_output_aliases={4 + n + i: i for i in range(n)},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+        ),
+        interpret=interpret,
+        name="paged_kv_write",
+    )(
+        _layer_operand(layer), block_table, ok, cols.astype(jnp.int32), *news,
+        *(a for a, _ in arenas),
+    )
+    return out[0], (out[1] if n == 2 else v_arena)
+
+
+def paged_attention_write(
+    q: jnp.ndarray,  # [B, S, Nh, D] (RoPE'd)
+    k_new: jnp.ndarray,  # [B, S, Nkv, D] the step's fresh keys
+    v_new: jnp.ndarray,  # [B, S, Nkv, Dv]
+    k_arena: jnp.ndarray,  # [L, NB, Nkv, BS, D] — the layer-stacked pool
+    v_arena: jnp.ndarray,
+    layer,  # scalar int32
+    block_table: jnp.ndarray,  # [B, T]
+    cols: jnp.ndarray,  # [B, S] logical columns of the fresh entries
+    q_positions: jnp.ndarray,  # [B, S]
+    kv_positions: jnp.ndarray,  # [B, T*BS]
+    valid=None,  # scalar or [B, S] bool — write_block_kv's gate
+    scale: float | None = None,
+    backend: str = "auto",
+    k_scale: jnp.ndarray = None,  # [L, NB, Nkv] — quantized arenas only
+    v_scale: jnp.ndarray = None,
+    stats: bool = False,
+    latent_v: int = 0,
+    window: int = 0,
+    sink: jnp.ndarray = None,
+):
+    """A DECODE layer call's two halves as one op: the step's fresh K/V
+    lands in the arena, then ``paged_attention`` attends it (the fresh
+    entries included). Returns ``(out, k_arena, v_arena, k_scale,
+    v_scale)`` — the scales None over a plain arena, ``out`` the ``(acc, m,
+    l)`` triple with ``stats``.
+
+    HOW the entries land follows what the call can see
+    (``decode_writes_in_kernel``), never an option: one entry a row over a
+    plain arena with the attention on its kernel → ``write_rows_tpu``, a
+    kernel that moves one sublane tile a row and leaves the arena in place;
+    everything else — a verify's ``S = K + 1`` entries, an int8/fp8 arena's
+    running scales, context parallel's partial statistics, the XLA path
+    (the CPU mesh) — ``write_block_kv``'s scatter as before. The stored
+    bytes and the scores are the same on both."""
+    path = "xla" if stats else decode_path(
+        backend, q.shape[-1], k_arena, block_table
+    )
+    ks, vs = k_scale, v_scale
+    if decode_writes_in_kernel(q.shape[1], k_scale is not None, stats, path):
+        k_arena, v_arena = write_rows_tpu(
+            k_arena, v_arena, layer, block_table, cols[:, 0], k_new[:, 0],
+            None if v_new is None else v_new[:, 0],
+            valid=valid if valid is None or not jnp.ndim(valid)
+            else valid[:, 0],
+            interpret=path == "interpret",
+        )
+    else:
+        wrote = write_block_kv(
+            k_arena, v_arena, layer, block_table, cols, k_new, v_new,
+            valid=valid, k_scale=k_scale, v_scale=v_scale,
+        )
+        k_arena, v_arena, ks, vs = (
+            wrote if k_scale is not None else (*wrote, None, None)
+        )
+    out = paged_attention(
+        q, k_arena, v_arena, layer, block_table, q_positions, kv_positions,
+        scale, backend=backend, k_scale=ks, v_scale=vs, stats=stats,
+        latent_v=latent_v, window=window, sink=sink,
+    )
+    return out, k_arena, v_arena, ks, vs

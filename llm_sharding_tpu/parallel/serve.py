@@ -202,9 +202,12 @@ def state_specs(
 # spec-verify traversals (serve_verify) AND chunked prefill
 # (serve_prefill_chunk — the ``_gather_window`` gather→recompute→scatter
 # round trip it used to pay per chunk is retired) land fresh KV in the
-# owning blocks — serve_chunk and serve_verify as ROWS
-# (ops/paged_attention.write_block_kv: a per-entry scatter, each row at
-# its own column), serve_prefill_chunk as whole-block TILES
+# owning blocks — serve_chunk through the write KERNEL
+# (ops/paged_attention.write_rows_tpu: one entry a row over a plain arena
+# with the attention on its kernel, the arena left in place) and otherwise,
+# like serve_verify's K + 1 entries a row, as ROWS (write_block_kv: a
+# per-entry scatter, each row at its own column; paged_attention_write
+# picks, from the program's statics), serve_prefill_chunk as whole-block TILES
 # (write_chunk_kv: its rows share their columns and start on a block
 # boundary, so a chunk is ``Sc / BS`` contiguous blocks a row — the
 # block-sized write ``_scatter_pages`` below makes for a whole window;

@@ -78,6 +78,7 @@ from ..obs.metrics import (
     ARENA_BYTES, ATTN_BACKEND, ATTN_BACKENDS, ATTN_BLOCKS_READ,
     CP_STREAM_SHARDS, DECODE_BLOCKS_LIVE, DECODE_BLOCKS_RESERVED,
     DECODE_KIND_BLOCKS_LIVE, DECODE_KIND_BLOCKS_RESERVED,
+    DECODE_KV_ENTRIES_WRITTEN,
     KV_KIND_BLOCKS_IN_USE, KV_KIND_BLOCKS_TOTAL, KV_KIND_ENTRY_BYTES,
     KV_WINDOW_BLOCKS_FREED,
     DEFAULT_RATE_BUCKETS,
@@ -1884,11 +1885,14 @@ class PipelineServer:
             return forced
         return "kernel" if (on_tpu and eligible) else "xla"
 
-    def _record_blocks_read(self, rows, served: int, steps: int = 1) -> None:
+    def _record_blocks_read(
+        self, rows, served: int, steps: int = 1, entries: int = 1
+    ) -> None:
         """Feed the decode-attention block counters from the host length
         mirrors (an estimate: mirrors trail the device by the in-flight
         chunk), for ``steps`` decode/verify steps over the live ``rows``
-        out of the ``served`` rows the kernel is called for.
+        out of the ``served`` rows the kernel is called for, each step
+        writing ``entries`` fresh K/V entries a row (1; a verify's K + 1).
         ``server_attn_blocks_read_total``: the blocks each row's tokens
         fill, ``ceil(len / block_size)`` — the bench multiplies by block
         bytes × layers for its attention-bytes-per-step figure.
@@ -1911,6 +1915,17 @@ class PipelineServer:
             DECODE_BLOCKS_LIVE.inc(live * steps)
         DECODE_BLOCKS_RESERVED.inc(reserved * steps)
         self.stepline.decode_blocks(live * steps, reserved * steps)
+        # the form of the step's K/V write: what the step program's statics
+        # choose (paged_attention_write asks the same function)
+        from ..ops.paged_attention import decode_writes_in_kernel
+
+        kv_write = "kernel" if decode_writes_in_kernel(
+            entries, self.kv_quantized, self.cp > 1, self.attn_impl
+        ) else "scatter"
+        if rows:
+            written = len(rows) * entries * steps
+            DECODE_KV_ENTRIES_WRITTEN.labels(write=kv_write).inc(written)
+            self.stepline.decode_kv_entries(kv_write, written)
         if self.recurrent:
             self.stepline.recurrent_rows(len(rows))
         if self.windowed:
@@ -5306,7 +5321,9 @@ class PipelineServer:
                     ],
                 )
             )
-            self._record_blocks_read([row for row, _ in live], served=Bs)
+            self._record_blocks_read(
+                [row for row, _ in live], served=Bs, entries=K + 1
+            )
             self.counters.inc("chunks")
 
     def _apply_spec(self, log: np.ndarray, entries: list) -> None:

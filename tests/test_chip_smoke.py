@@ -158,6 +158,60 @@ def test_kv_write_shapes_are_the_cells(monkeypatch):
     assert {"mimo_v25.swa", "mimo_v25.full"} <= set(got)
 
 
+#: ``--kv-decode``'s shapes at toy widths: a fold of query heads with keys
+#: and values alike, and a window layer with a sink, keys wider than values
+#: and the blocks behind the window freed
+KV_DECODE_TOY = {
+    "fold": {"name": "toy", "heads": 2, "group": 3, "dk": 16, "dv": 16,
+             "blocks": 40, "layers": 3, "table": 8, "context": 21},
+    "window": {"name": "toy_swa", "heads": 2, "group": 2, "dk": 16, "dv": 8,
+               "blocks": 13, "layers": 2, "table": 8, "context": 40,
+               "window": 12},
+}
+
+
+@pytest.mark.parametrize("live", chip_smoke.KV_DECODE_LIVE)
+@pytest.mark.parametrize("shape", sorted(KV_DECODE_TOY))
+def test_kv_decode_forms_agree_and_are_traced(
+    shape, live, monkeypatch, tmp_path
+):
+    """``chip_smoke.py --kv-decode`` at toy widths: the fused decode call
+    leaves the arenas as the scatter pair does outside block 0 and returns
+    the same output (the same kernel over the same bytes), one and four
+    live rows of the slot's four; and its traced timing runs end to end — a
+    CPU trace holds no TPU plane, so it reads nothing, and no speed."""
+    monkeypatch.setattr(
+        chip_smoke, "KV_CHUNK", {"rows": 4, "chunk": 32, "block_size": 8})
+    monkeypatch.setattr(chip_smoke, "WORK", str(tmp_path))
+    got = chip_smoke.check_kv_decode(KV_DECODE_TOY[shape], live, "interpret")
+    assert got == {"arenas_same": True, "max_err": 0.0}
+    if live == 1:
+        timed = chip_smoke.time_kv_decode(
+            KV_DECODE_TOY[shape], live, "fused", backend="interpret", runs=1)
+        assert timed == {"us_per_layer_call": 0.0, "ops_us": []}
+
+
+def test_kv_decode_shapes_are_the_cells():
+    """The shapes ``--kv-decode`` times are OLMoE's, the 7B's and MiMo's
+    window layers' as ``--kv-write`` holds them, a table as wide as the
+    cell's capacity."""
+    import json
+    import os
+
+    cfgs = os.path.join(chip_smoke.HERE, "benchmark", "configs")
+    write = {s["name"]: s for s in chip_smoke.KV_WRITE_SHAPES}
+    for shape in chip_smoke.KV_DECODE_SHAPES:
+        for key in ("heads", "dk", "dv", "blocks", "layers"):
+            assert shape[key] == write[shape["name"]][key], (shape, key)
+        with open(os.path.join(
+                cfgs, shape["name"].split(".")[0] + ".json")) as f:
+            cfg = json.load(f)
+        serve = cfg["serve"]
+        assert shape["table"] == serve["capacity"] // serve["kv_block_size"]
+        assert shape.get("window", 0) == (
+            cfg.get("sliding_window") or 0 if "." in shape["name"] else 0)
+
+
 def test_store_writer_driver_and_assertions_on_cpu(tmp_path, monkeypatch):
     """The smoke's daemon phase end to end at toy size: seeded store through
     the product's writer, the real ``serve`` daemon as a child, the smoke's

@@ -307,14 +307,17 @@ def test_what_is_not_done_is_refused_by_name(params):
 # its live cells, the program returns the walk's counters); PR 40 re-recorded
 # the three of ``olmoe`` (the expert kernel's grid is its live tiles, the
 # combines select them); PR 42 re-recorded both ``serve_prefill_chunk`` again
-# (a chunk writes its K/V as whole-block tiles); ``qwen2``'s ``serve_chunk``
-# and ``serve_admit`` are still the parent of PR 34's.
+# (a chunk writes its K/V as whole-block tiles); PR 46 re-recorded both
+# ``serve_chunk`` (a decode step's fresh K/V lands through the write kernel
+# ``paged_kv_write`` before ``paged_decode``; ``serve_prefill_chunk`` and
+# ``serve_admit`` kept their texts); ``qwen2``'s ``serve_admit`` is still the
+# parent of PR 34's.
 GOLDEN = {
     ("qwen2", "serve_admit"): "41a2afe52004928f",
-    ("qwen2", "serve_chunk"): "51598fb15a9f1c1a",
+    ("qwen2", "serve_chunk"): "cf9864066f9e42c4",
     ("qwen2", "serve_prefill_chunk"): "1f5a149bea8b1099",
     ("olmoe", "serve_admit"): "ff5a01947e2fda27",
-    ("olmoe", "serve_chunk"): "3fdd88d900928e52",
+    ("olmoe", "serve_chunk"): "a2611ac08ff133e0",
     ("olmoe", "serve_prefill_chunk"): "2578c200e397507d",
 }
 

@@ -13,6 +13,8 @@ this module at a tiny size (block-boundary + table-growth stress) without a
 second test body.
 """
 
+import collections
+import functools
 import json
 import os
 import re
@@ -796,6 +798,219 @@ def test_a_chunk_that_cannot_be_tiles_takes_the_row_wise_write(case):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(w))
 
 
+# ---------------- a decode step's write and its attention as one op
+
+#: the arenas a decode step writes (key/value heads, query heads a head, key
+#: and value lanes, storage): the 7B's fold, OLMoE's sixteen heads in the
+#: chip's dtype, keys wider than values with a window, a sink and a freed
+#: block behind it (mimo_v2's window layers), a latent arena
+_FUSED_ARENAS = {
+    "gqa_4x7": dict(Nkv=4, G=7, D=8, Dv=8),
+    "mha_16x1_bf16": dict(Nkv=16, G=1, D=8, Dv=8, dtype=jnp.bfloat16),
+    "window_sink_k_wider": dict(Nkv=2, G=2, D=12, Dv=8, window=6, sink=True),
+    "latent": dict(Nkv=1, G=4, D=8, Dv=0, latent_v=6),
+}
+
+
+def _fused_case(arena, bs=4, T=5, NB=24, seed=9):
+    """Four rows of a slot at a decode step: row 0's entry at slot 0 of a
+    block never written, row 1's at the last slot of its block, row 2 dead
+    (table all trash, no real query), row 3 parked on a trash-mapped column
+    (its entry goes to the sink, its query attends what it holds). Under a
+    window the blocks behind it are freed (table entry 0)."""
+    from llm_sharding_tpu.models.cache import POS_SENTINEL
+
+    a = dict(_FUSED_ARENAS[arena])
+    rng = np.random.default_rng(seed)
+    dt = a.pop("dtype", jnp.float32)
+    Nkv, G, D, Dv = a.pop("Nkv"), a.pop("G"), a.pop("D"), a.pop("Dv")
+    k, _ = make_stack(rng, NB, Nkv, bs, D, dt)
+    v, _ = make_stack(rng, NB, Nkv, bs, Dv, dt)
+    cols = np.asarray([3 * bs, 2 * bs - 1, 0, 4 * bs + 1], np.int32)
+    table = np.zeros((4, T), np.int32)
+    table[0, :4] = [2, 3, 4, 5]
+    table[1, :2] = [6, 7]
+    table[3, :4] = [8, 9, 10, 11]  # column 4·bs + 1 is trash-mapped
+    if a.get("window"):
+        table[0, :1] = 0  # behind the window: handed back to the pool
+    kvpos = np.full((4, T * bs), POS_SENTINEL, np.int32)
+    for b in (0, 1):
+        kvpos[b, : cols[b] + 1] = np.arange(cols[b] + 1)
+    kvpos[3, : 4 * bs] = np.arange(4 * bs)
+    qpos = np.asarray([cols[0], cols[1], POS_SENTINEL, 4 * bs + 1], np.int32)
+    normal = lambda *shape: jnp.asarray(rng.normal(size=shape), dt)
+    kw = {k_: a[k_] for k_ in ("window", "latent_v") if k_ in a}
+    if a.get("sink"):
+        kw["sink"] = jnp.asarray(rng.normal(size=(Nkv * G,)), jnp.float32)
+    return dict(
+        q=normal(4, 1, Nkv * G, D), k_new=normal(4, 1, Nkv, D),
+        v_new=normal(4, 1, Nkv, Dv) if Dv else None, k=k, v=v,
+        table=jnp.asarray(table), cols=jnp.asarray(cols[:, None]),
+        qpos=jnp.asarray(qpos[:, None]), kvpos=jnp.asarray(kvpos), kw=kw,
+    )
+
+
+def _scatter_then_attend(c, layer, valid, **more):
+    """What a decode layer called before the fused op: ``write_block_kv``
+    then the exact XLA attention."""
+    from llm_sharding_tpu.ops.paged_attention import (
+        paged_attention_xla, write_block_kv,
+    )
+
+    k, v = write_block_kv(
+        c["k"], c["v"], layer, c["table"], c["cols"], c["k_new"], c["v_new"],
+        valid=valid,
+    )
+    return paged_attention_xla(
+        c["q"], k, v, layer, c["table"], c["qpos"], c["kvpos"], **c["kw"],
+        **more,
+    ), k, v
+
+
+def _close(got, want, dtype):
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        atol=tol, rtol=tol,
+    )
+
+
+@pytest.mark.parametrize("valid", (None, True, False, "rows"))
+@pytest.mark.parametrize("arena", sorted(_FUSED_ARENAS))
+@pytest.mark.parametrize("layer", (0, LAYERS - 1))
+def test_the_fused_decode_write_leaves_what_the_scatter_leaves(
+    layer, arena, valid
+):
+    """``paged_attention_write`` on the kernel path (interpreted) against
+    ``write_block_kv`` then ``paged_attention_xla``: both arenas bit for bit
+    in every block a table can own, every other layer untouched, the
+    output within the kernel's tolerance; under ``valid=False`` no owned
+    block changes at all, and a gate a row steers only that row's entry."""
+    from llm_sharding_tpu.ops import paged_attention as pa
+
+    c = _fused_case(arena)
+    gate = {
+        None: None, True: jnp.asarray(True), False: jnp.asarray(False),
+        "rows": jnp.asarray([[True], [False], [True], [True]]),
+    }[valid]
+    assert pa.decode_writes_in_kernel(1, False, False, "interpret")
+    with mock.patch.object(
+        pa, "write_block_kv", wraps=pa.write_block_kv
+    ) as scatter:
+        out, k, v, ks, vs = jax.jit(
+            lambda k, v: pa.paged_attention_write(
+                c["q"], c["k_new"], c["v_new"], k, v, layer, c["table"],
+                c["cols"], c["qpos"], c["kvpos"], valid=gate,
+                backend="interpret", **c["kw"],
+            )
+        )(c["k"], c["v"])
+    assert scatter.call_count == 0 and ks is None and vs is None
+    want, k_w, v_w = _scatter_then_attend(c, layer, gate)
+    _close(out, want, c["k"].dtype)
+    for before, a, w in zip((c["k"], c["v"]), (k, v), (k_w, v_w)):
+        a, w = np.asarray(a), np.asarray(w)
+        assert a.shape == w.shape == before.shape and a.dtype == w.dtype
+        np.testing.assert_array_equal(a[:, 1:], w[:, 1:])
+        others_untouched(before, a, layer)
+        if valid is False:
+            np.testing.assert_array_equal(
+                a[:, 1:], np.asarray(before)[:, 1:]
+            )
+    if valid in (None, True):
+        # the entries are where the table says: row 0's at slot 0 of its
+        # fourth block, row 1's at the last slot of its second
+        bs = c["k"].shape[3]
+        for b, (blk, slot) in enumerate(((5, 0), (7, bs - 1))):
+            np.testing.assert_array_equal(
+                np.asarray(k)[layer, blk, :, slot],
+                np.asarray(c["k_new"].astype(k.dtype))[b, 0],
+            )
+
+
+def test_the_fused_decode_write_carries_the_arena_through_a_layer_scan():
+    """Inside ``lax.scan`` over two layers with the arenas donated (the
+    kernel's output aliased over its operand, as the step programs carry
+    them): what two scatter-then-attend calls leave and return."""
+    from llm_sharding_tpu.ops import paged_attention as pa
+
+    c = _fused_case("gqa_4x7")
+    layers = jnp.asarray([1, 2], jnp.int32)
+    want, k_w, v_w = [], c["k"], c["v"]
+    for l in (1, 2):
+        o, k_w, v_w = _scatter_then_attend(dict(c, k=k_w, v=v_w), l, None)
+        want.append(o)
+
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    def run(k, v):
+        def one(carry, l):
+            o, k, v, _, _ = pa.paged_attention_write(
+                c["q"], c["k_new"], c["v_new"], *carry, l, c["table"],
+                c["cols"], c["qpos"], c["kvpos"], backend="interpret",
+            )
+            return (k, v), o
+        return jax.lax.scan(one, (k, v), layers)
+
+    (k, v), out = run(c["k"] + 0, c["v"] + 0)
+    _close(out, jnp.stack(want), jnp.float32)
+    np.testing.assert_array_equal(np.asarray(k)[:, 1:], np.asarray(k_w)[:, 1:])
+    np.testing.assert_array_equal(np.asarray(v)[:, 1:], np.asarray(v_w)[:, 1:])
+
+
+@pytest.mark.parametrize(
+    "case", ("two_entries", "int8_arena", "stats", "xla_backend")
+)
+def test_a_decode_write_the_kernel_cannot_take_is_the_scatter(case):
+    """What the call can see decides the form: a verify's two entries a
+    row, an int8 arena (its running scales), partial statistics (context
+    parallel) and the XLA attention path write through ``write_block_kv``
+    itself — the write kernel is not in their program — and return what
+    the pair of calls returned before."""
+    from llm_sharding_tpu.ops import paged_attention as pa
+
+    c = _fused_case("gqa_4x7")
+    more, scales, backend = {}, {}, "interpret"
+    if case == "two_entries":
+        rng = np.random.default_rng(3)
+        wide = lambda x: jnp.concatenate(
+            [x, jnp.asarray(rng.normal(size=x.shape), x.dtype)], axis=1)
+        c.update(q=wide(c["q"]), k_new=wide(c["k_new"]),
+                 v_new=wide(c["v_new"]),
+                 cols=jnp.concatenate([c["cols"], c["cols"] + 1], axis=1),
+                 qpos=jnp.concatenate([c["qpos"], c["qpos"]], axis=1))
+        assert not pa.decode_writes_in_kernel(2, False, False, backend)
+    elif case == "int8_arena":
+        k8, v8, scales = int8_stack(np.random.default_rng(6), c["k"], c["v"])
+        c.update(k=k8, v=v8)
+        assert not pa.decode_writes_in_kernel(1, True, False, backend)
+    elif case == "stats":
+        more = {"stats": True}
+        assert not pa.decode_writes_in_kernel(1, False, True, backend)
+    else:
+        backend = "xla"
+        assert not pa.decode_writes_in_kernel(1, False, False, "xla")
+    args = (c["table"], c["cols"], c["qpos"], c["kvpos"])
+    with mock.patch.object(pa, "write_rows_tpu") as kernel, mock.patch.object(
+        pa, "write_block_kv", wraps=pa.write_block_kv
+    ) as scatter:
+        out, k, v, ks, vs = pa.paged_attention_write(
+            c["q"], c["k_new"], c["v_new"], c["k"], c["v"], 1, *args,
+            backend=backend, **scales, **more,
+        )
+    assert kernel.call_count == 0 and scatter.call_count == 1
+    wrote = pa.write_block_kv(
+        c["k"], c["v"], 1, c["table"], c["cols"], c["k_new"], c["v_new"],
+        **scales,
+    )
+    k_w, v_w, ks_w, vs_w = wrote if scales else (*wrote, None, None)
+    want = pa.paged_attention(
+        c["q"], k_w, v_w, 1, *args[:1], *args[2:], backend=backend,
+        k_scale=ks_w, v_scale=vs_w, **more,
+    )
+    for a, w in zip(jax.tree.leaves((out, k, v, ks, vs)),
+                    jax.tree.leaves((want, k_w, v_w, ks_w, vs_w))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(w))
+
+
 def test_a_masked_layer_writes_to_its_own_trash_block():
     """A padding layer of a stage (``layer_mask`` False) runs the block
     and discards it: its entries must land in block 0 of ITS layer index —
@@ -1411,6 +1626,25 @@ def _weight_stack_relayouts(text, floor=16 << 20):
     return found
 
 
+def _arena_ops(text, floor=4 << 20):
+    """The instructions of a compiled program, kernels aside, whose result is
+    a whole K/V arena: bf16, ``[..., NB, Nkv, 32, D]`` with the layer (and
+    the stage) in front, ``floor`` elements or more — a scatter into the
+    carried stack, a copy of it, a move of it into another memory (a
+    ``copy-start``'s result is a tuple that begins with the copy). Returns
+    ``[(operation, dims)]``."""
+    found = []
+    for m in re.finditer(
+        r"%[\w.\-]+ = \(?bf16\[([\d,]+)\][^\n]*? "
+        r"(copy|copy-start|scatter|dynamic-update-slice|fusion|select)\(",
+        text,
+    ):
+        dims = [int(x) for x in m.group(1).split(",")]
+        if len(dims) in (5, 6) and dims[-2] == 32 and np.prod(dims) >= floor:
+            found.append((m.group(2), tuple(dims)))
+    return found
+
+
 def _windowed_projections(text):
     """The ``qkv`` dots of a compiled program, and those of them the
     compiler wrote as a convolution over a window wider than 1 (the head
@@ -1442,7 +1676,9 @@ def test_a_decode_step_reads_its_weights_as_they_are_stored(v5e_host, cell):
     was held (``models/llama.py::attn_mlp_block``) XLA folded the head
     split into the two small dots and transposed the whole ``wk`` / ``wv``
     stacks at the top of every call: 0.25-0.27 ms of a decode step on the
-    chip (``PERF.md``, PR 31). Latent attention (PR 34) met the same twice
+    chip (``PERF.md``, PR 31). Nor does any operation but a kernel produce
+    an arena (PR 46: the two scatters a layer of a step's fresh K/V went,
+    and with them what XLA copied around them). Latent attention (PR 34) met the same twice
     (``wq_b``'s head split, held the same way) and once from the STORED side:
     a ``[H, 576]`` weight is not whole lane tiles, the chip keeps it
     input-minor, and the stack of ``wkv_a`` was re-laid every call until the
@@ -1466,6 +1702,12 @@ def test_a_decode_step_reads_its_weights_as_they_are_stored(v5e_host, cell):
     assert _weight_stack_relayouts(text) == []
     dots, windowed = _windowed_projections(text)
     assert len(dots) >= 3 and windowed == []
+    # and writes its arena where it lies (PR 46): the step's fresh K/V
+    # lands through the write kernel, so no operation of the program but a
+    # kernel produces an arena — no scatter into the carried stack, no copy
+    # or staging of it around one
+    assert "paged_kv_write/pallas_call" in text
+    assert _arena_ops(text) == []
 
 
 def test_a_windowed_models_step_programs_compile_and_read_weights_as_stored(
@@ -1492,10 +1734,22 @@ def test_a_windowed_models_step_programs_compile_and_read_weights_as_stored(
     assert _weight_stack_relayouts(decode) == []
     dots, windowed = _windowed_projections(decode)
     assert len(dots) >= 3 and windowed == []
-    # five runs of one kind: an attention kernel each, an expert kernel in four
-    assert decode.count("tpu_custom_call") == 9
+    # five runs of one kind: a write kernel and an attention kernel each, an
+    # expert kernel in four
+    assert decode.count("tpu_custom_call") == 14
+    assert decode.count("paged_kv_write/pallas_call") >= 5
     assert "paged_decode" in decode and "paged_prefill" in texts[
         "serve_prefill_chunk[256]"]
+    # a decode step's fresh K/V lands through the write kernel: XLA scatters
+    # into no arena. What is left is its own choice of memory for a SMALL
+    # array the loop carries: the window layers' 31 MB value arena moves
+    # into fast memory before the step's loops and back after them, once a
+    # step, as it did around the scatters (40 + 3 us of a 2.9 ms step on
+    # the chip: PERF.md, PR 46)
+    assert _arena_ops(decode) == [
+        ("copy-start", (1, 9, 53, 8, 32, 128)),
+        ("copy-start", (9, 53, 8, 32, 128)),
+    ]
 
 
 def test_a_recurrent_models_step_programs_compile_and_read_weights_as_stored(
@@ -1523,9 +1777,9 @@ def test_a_recurrent_models_step_programs_compile_and_read_weights_as_stored(
     assert _weight_stack_relayouts(decode) == []
     dots, windowed = _windowed_projections(decode)
     assert len(dots) >= 3 and windowed == []
-    # seventeen runs of one kind: an expert kernel in seven, the decode
-    # kernel in two, the state kernel in eight
-    assert decode.count("tpu_custom_call") == 17
+    # seventeen runs of one kind: an expert kernel in seven, the write and
+    # the decode kernel in two, the state kernel in eight
+    assert decode.count("tpu_custom_call") == 19
     assert "paged_decode" in decode and "moe_experts" in decode
     assert decode.count("ssm_rows/pallas_call") >= 8
     prefill = texts["serve_prefill_chunk[256]"]
@@ -1880,9 +2134,15 @@ def test_step_programs_carry_the_scope_vocabulary(request, program, model):
         # function, XLA joins them into tf_op)
         for gone in ("kv_take/", "kv_layout/", "kv_put/"):
             assert not any(gone in p + "/" for p in paths), gone
-        # the write is the scatter into the carried stack, the read the
-        # kernel's own block DMAs
-        assert any(p.endswith("kv_write/scatter") for p in paths)
+        # the read is the kernel's own block DMAs; a chunk's write is the
+        # scatter into the carried stack, a decode step's (one entry a row,
+        # a plain arena, the attention on its kernel) the write kernel,
+        # which leaves XLA no scatter into the arena
+        scatter = any(p.endswith("kv_write/scatter") for p in paths)
+        kernel = any("kv_write/paged_kv_write" in p for p in paths)
+        assert (scatter, kernel) == (
+            (False, True) if program == "serve_chunk" else (True, False)
+        )
     if program == "serve_chunk":
         assert any(p.endswith("ring_hop/ppermute") for p in paths)
 
@@ -1989,12 +2249,22 @@ def traced_programs(request, setup):
     dispatched them — a chunked admission, decode chunks, and (second
     server) speculative verify — on one attention backend, bf16-style and
     int8 arenas. Returns ``{(program, kv_dtype): jaxpr}``, the local arena
-    stack's shape, and what was served against the oracle."""
+    stack's shape, what was served against the oracle, and per ``(kv_dtype,
+    speculate)`` server what its decode / verify dispatches wrote by the
+    write's form: the counter's rise and the step records' sum."""
+    from llm_sharding_tpu.obs.metrics import (
+        DECODE_KV_ENTRIES_WRITTEN, DECODE_KV_WRITES,
+    )
+
+    def written():
+        return {w: DECODE_KV_ENTRIES_WRITTEN.labels(write=w).value
+                for w in DECODE_KV_WRITES}
+
     from llm_sharding_tpu.parallel import serve as serve_ops
 
     params, eng = setup
     backend = request.param
-    jaxprs = {}
+    jaxprs, writes = {}, {}
     served, oracle = [], []
     with pytest.MonkeyPatch.context() as mp:
         if backend == "interpret":
@@ -2012,6 +2282,7 @@ def traced_programs(request, setup):
         for kv_dtype in ("bf16", "int8"):
             for spec in (0, 2):
                 kvd["now"] = kv_dtype
+                w0 = written()
                 srv = eng.serve(
                     capacity=64, batch_per_slot=2, kv_block_size=8,
                     kv_blocks=65, kv_dtype=kv_dtype,
@@ -2025,11 +2296,18 @@ def traced_programs(request, setup):
                 prompts = [prompt(311 + spec, n=5), prompt(312 + spec, n=20)]
                 reqs = [srv.submit(p, 5) for p in prompts]
                 srv.run_until_idle()
+                recs = collections.Counter()
+                for r in srv.stepline.snapshot():
+                    recs.update(r.get("decode_kv_entries", {}))
                 srv.close()
+                w1 = written()
+                writes[kv_dtype, spec] = (
+                    {w: w1[w] - w0[w] for w in w0}, dict(recs)
+                )
                 if kv_dtype == "bf16":  # exact arena: token-exact serving
                     served += [list(r.tokens) for r in reqs]
                     oracle += [oracle_tokens(params, p, 5) for p in prompts]
-    return jaxprs, stack_shape, served, oracle
+    return jaxprs, stack_shape, served, oracle, writes
 
 
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
@@ -2048,7 +2326,7 @@ def test_no_arena_sized_copy_in_a_step_program(
     of the state leaf at the program's edge. So a decode or prefill step
     holds no arena-sized transpose, slice or update, on either backend —
     and what it serves still equals the dense-path oracle."""
-    jaxprs, stack_shape, served, oracle = traced_programs
+    jaxprs, stack_shape, served, oracle, _ = traced_programs
     assert served == oracle
     jaxpr = jaxprs[program, kv_dtype]
     scans = list(_layer_scans(jaxpr.jaxpr, stack_shape[1:]))
@@ -2072,10 +2350,14 @@ def test_a_layer_scan_holds_one_decode_kernel_over_whole_blocks(
     traced_programs, program, kv_dtype
 ):
     """The decode programs as a server dispatched them: every layer scan
-    that carries the arena holds exactly ONE ``pallas_call``, named
-    ``paged_decode``, and its arena operand blocks are ``(Nkv, BS, D)``
-    wide — a block's key/value heads together, the layer dim squeezed."""
-    jaxprs, stack_shape, _, _ = traced_programs
+    that carries the arena holds exactly ONE attention ``pallas_call``,
+    named ``paged_decode``, and its arena operand blocks are ``(Nkv, BS,
+    D)`` wide — a block's key/value heads together, the layer dim squeezed.
+    Before it, where a step writes one entry a row into a plain arena
+    (``serve_chunk`` over bf16), ONE write kernel ``paged_kv_write`` whose
+    arena blocks are the sublane tile ``(Nkv, SUB, D)`` that holds the slot;
+    a verify's ``K + 1`` entries and an int8 arena keep the scatter."""
+    jaxprs, stack_shape, _, _, _ = traced_programs
     jaxpr = jaxprs[program, kv_dtype]
     _, _, Nkv, BS, D = stack_shape
     scans = list(_layer_scans(jaxpr.jaxpr, stack_shape[1:]))
@@ -2087,12 +2369,47 @@ def test_a_layer_scan_holds_one_decode_kernel_over_whole_blocks(
                      for e in _leaf_eqns(scan.params["jaxpr"].jaxpr)}
             assert "gather" in names
         return
+    from llm_sharding_tpu.ops.paged_attention import kernel_sublane
+
+    writes = program == "serve_chunk" and kv_dtype == "bf16"
+    sub = kernel_sublane(jnp.float32)  # the fixture's cache dtype
+    sub = sub if BS % sub == 0 else BS
     for scan in scans:
-        (call,) = _pallas_calls(scan.params["jaxpr"].jaxpr)
+        *write, call = _pallas_calls(scan.params["jaxpr"].jaxpr)
+        assert [c.params["name"] for c in write] == (
+            ["paged_kv_write"] if writes else []
+        )
         assert call.params["name"] == "paged_decode"
         arena_blocks = [b for b in _block_shapes(call) if len(b) == 5
                         and b[-1] == D]
         assert arena_blocks and set(arena_blocks) == {(None, 1, Nkv, BS, D)}
+        for w in write:
+            tiles = [b for b in _block_shapes(w) if len(b) == 5]
+            assert tiles and set(tiles) == {(None, None, Nkv, sub, D)}
+
+
+@pytest.mark.parametrize("spec", [0, 2])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_the_decode_write_is_counted_by_its_form(
+    traced_programs, request, kv_dtype, spec
+):
+    """``server_decode_kv_entries_written_total{write=}`` and the step
+    record's ``decode_kv_entries`` say how a served step's fresh K/V landed:
+    ``kernel`` where the step program's statics choose the write kernel (one
+    entry a row, a plain arena, the attention on its kernel) — the same
+    predicate ``paged_attention_write`` asks, so the count is the program's
+    — and ``scatter`` for a verify step, an int8 arena and the XLA path."""
+    *_, writes = traced_programs
+    backend = request.node.callspec.params["traced_programs"]
+    counted, recorded = writes[kv_dtype, spec]
+    form = "kernel" if (
+        backend == "interpret" and kv_dtype == "bf16" and not spec
+    ) else "scatter"
+    other = "scatter" if form == "kernel" else "kernel"
+    assert counted[form] > 0 and counted[other] == 0, counted
+    assert recorded == {form: counted[form]}
+    if spec:  # a verify writes K + 1 entries a live row
+        assert counted[form] % (spec + 1) == 0
 
 
 def test_the_structural_check_sees_a_sliced_out_layer():

@@ -972,6 +972,24 @@ def test_prefill_kv_blocks_reach_the_record_by_the_writes_form():
     assert p.end_step().prefill_kv_blocks is None
 
 
+def test_decode_kv_entries_reach_the_record_by_the_writes_form():
+    p = StepProfiler(name="t-decode-kv-entries")
+    p.begin_step(rows=3)
+    p.decode_kv_entries("kernel", 3)
+    p.decode_kv_entries("kernel", 3)
+    p.decode_kv_entries("scatter", 9)
+    rec = p.end_step(rows=3)
+    assert rec.decode_kv_entries == {"kernel": 6, "scatter": 9}
+    assert rec.to_dict()["decode_kv_entries"] == {"kernel": 6, "scatter": 9}
+    # a step that dispatched no decode over a paged arena carries no such
+    # key; outside a step nothing is counted
+    p.begin_step()
+    assert "decode_kv_entries" not in p.end_step().to_dict()
+    p.decode_kv_entries("kernel", 1)
+    p.begin_step()
+    assert p.end_step().decode_kv_entries is None
+
+
 @pytest.mark.parametrize("stages", [1, 2])
 def test_a_one_request_slot_of_four_walks_a_fraction_of_its_rectangle(
         params, stages, monkeypatch):
