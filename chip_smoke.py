@@ -62,8 +62,17 @@ heads of 64, a state of 128, 8 groups; 8 layers x 4 rows of float32 state
 carried), 1 and 4 live rows of the slot's 4: the kernel (``ssm_rows``)
 against the XLA loop — the largest difference of the read-out and of the new
 state, a row that is not live bit for bit — and microseconds a layer call of
-each. The last line, also left in ``chiprun_out/ssm.json``: ``{"ok": true,
-"ssm": [...], "device": {...}}``; exit 1 where the two disagree.
+each. Then a Mamba-1 mixer's decode step at AI21-Jamba2-3B's published mixer
+widths (5,120 channels, a state of 16, a rank of 160; 26 layers x 4 rows
+carried; projections of a hidden size of 128, so that what lies between them
+is what is timed): the FUSED step (``ops/ssm.mixer_step_rows``, the kernel
+``ssm_mixer``) beside the SPLIT path through ``models/jamba.mixer_block`` at 1
+and 4 live rows — the differences, a dead row's state and tail bit for bit,
+DEVICE microseconds a layer call of each from a profiler trace with the
+operations it is made of, and ``mixer_step_path``: what
+``server_recurrent_mixer_step`` reads on this chip. The last line, also left
+in ``chiprun_out/ssm.json``: ``{"ok": true, "ssm": [...], "device": {...}}``;
+exit 1 where a kernel and its reference disagree.
 """
 
 from __future__ import annotations
@@ -1126,9 +1135,138 @@ def time_ssm_rows(shape: dict, live: int, backend: str,
     return round(best / calls * 1e6, 2)
 
 
+#: a Mamba-1 mixer at AI21-Jamba2-3B's published mixer widths (5,120
+#: channels, a state of 16, a step rank of 160, 4 taps) between projections
+#: of a hidden size of 128, so that what lies BETWEEN them is what is timed;
+#: the carried state of 26 mixer layers x 4 rows, leaves in bf16 as the cell's
+MIXER_KEYS = dict(hidden_size=128, mamba_expand=40, intermediate_size=128,
+                  num_attention_heads=1, num_key_value_heads=1, vocab_size=256)
+MIXER_LAYERS, MIXER_ROWS = 26, 4
+#: bf16 operands into the two products on both paths; the split path rounds
+#: the products to bf16, the fused one keeps their float32 sums
+MIXER_TOL = 2e-2
+
+
+def mixer_inputs(live: int, seed: int = 0):
+    """``(cfg, stack, h, s_all, c_all, alive)``: a stack of Mamba-1 mixers
+    (bf16 leaves) and one decode step of a slot whose LAST ``live`` rows are
+    live."""
+    import jax
+    import jax.numpy as jnp
+
+    from llm_sharding_tpu.models import jamba
+    from llm_sharding_tpu.models.config import ModelConfig, jamba2_3b_keys
+    from llm_sharding_tpu.models.stack import zero_recurrent
+
+    cfg = ModelConfig.from_hf_config(jamba2_3b_keys(**MIXER_KEYS))
+    k = jax.random.split(jax.random.key(seed), 4)
+    stack = jax.tree.map(
+        lambda a: a.astype(jnp.bfloat16),
+        jamba.init_layer_params(cfg, k[0], MIXER_LAYERS, kind="mamba"),
+    )
+    rec = zero_recurrent(cfg, MIXER_LAYERS, MIXER_ROWS)
+    return (
+        cfg, stack,
+        jax.random.normal(k[1], (MIXER_ROWS, 1, cfg.hidden_size), jnp.bfloat16),
+        rec["ssm"] + jax.random.normal(k[2], rec["ssm"].shape),
+        rec["conv"] + jax.random.normal(k[3], rec["conv"].shape),
+        jnp.arange(MIXER_ROWS) >= MIXER_ROWS - live,
+    )
+
+
+def mixer_program(cfg, form: str, calls: int, backend: str = "kernel"):
+    """``(stack, h, s_all, c_all, alive) -> (h, s_all, c_all)``: ``calls``
+    mixer layers of a decode step as the layer scan runs them
+    (``models/jamba.mixer_block``, the live rows counted once): ``fused``
+    hands the stack's leaves WHOLE beside the layer's index, as the serve
+    programs' scan does, ``split`` a layer's slices of them."""
+    import jax
+    import jax.numpy as jnp
+
+    from llm_sharding_tpu.models import jamba
+    from llm_sharding_tpu.models.stack import join_whole, split_whole
+
+    @functools.partial(jax.jit, donate_argnums=(2, 3))
+    def run(stack, h, s_all, c_all, alive):
+        live = alive[:, None]
+        rows = jamba.live_rows(live)
+        scanned, whole = (
+            (stack, None) if form == "split" else split_whole(stack)
+        )
+
+        def one(carry, l):
+            p = jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, l, keepdims=False),
+                scanned,
+            )
+            return jamba.mixer_block(
+                cfg, join_whole(p, whole, l), *carry, (l, jnp.int32(0)), live,
+                rows, backend,
+            ), None
+        layers = jnp.arange(calls, dtype=jnp.int32) % MIXER_LAYERS
+        return jax.lax.scan(one, (h, s_all, c_all), layers)[0]
+    return run
+
+
+def check_mixer_step(live: int, backend: str = "kernel") -> dict:
+    """The fused decode step against the split path, every layer once: the
+    largest difference of ``h``, of the state and of the conv's tail
+    (relative to the largest value), and whether every row that is not live
+    came back bit for bit."""
+    import jax.numpy as jnp
+
+    got = {}
+    for form in ("split", "fused"):
+        cfg, stack, h, s_all, c_all, alive = mixer_inputs(live, seed=1)
+        got[form] = mixer_program(cfg, form, MIXER_LAYERS, backend)(
+            stack, h, s_all, c_all, alive)
+    _, _, _, s_all, c_all, alive = mixer_inputs(live, seed=1)
+
+    def err(a, b):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        return float(jnp.abs(a - b).max() / jnp.maximum(jnp.abs(b).max(), 1.0))
+
+    (h_s, s_s, c_s), (h_f, s_f, c_f) = got["split"], got["fused"]
+    return {
+        "h_err": err(h_f, h_s), "s_err": err(s_f, s_s), "c_err": err(c_f, c_s),
+        "dead_rows_untouched": bool(
+            jnp.array_equal(s_f[:, ~alive], s_all[:, ~alive])
+            & jnp.array_equal(c_f[:, ~alive], c_all[:, ~alive])
+        ),
+    }
+
+
+def time_mixer_step(live: int, form: str, runs: int = 8,
+                    calls: int = 32) -> dict:
+    """DEVICE microseconds of ONE mixer layer call in ``form`` (the tiny
+    projections included), from a profiler trace (``time_kv_decode`` says
+    why): the sum over the operations and the largest of them."""
+    import jax
+
+    cfg, stack, h, s_all, c_all, alive = mixer_inputs(live)
+    run = mixer_program(cfg, form, calls)
+    h, s_all, c_all = jax.block_until_ready(
+        run(stack, h, s_all, c_all, alive))  # compiles
+    trace_dir = os.path.join(WORK, "trace_mixer_step")
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    jax.profiler.start_trace(trace_dir)
+    for _ in range(runs):
+        h, s_all, c_all = run(stack, h, s_all, c_all, alive)
+    jax.block_until_ready((h, s_all, c_all))
+    jax.profiler.stop_trace()
+    n = runs * calls
+    ops = sorted(device_op_us(trace_dir).items(), key=lambda kv: -kv[1])
+    return {
+        "us_per_layer_call": round(sum(u for _, u in ops) / n, 2),
+        "ops_us": [[name, round(u / n, 2)] for name, u in ops[:8]],
+    }
+
+
 def child_ssm(spec: dict, out_path: str) -> None:
     import jax
 
+    import llm_sharding_tpu.models  # noqa: F401  (import cycle: models first)
+    from llm_sharding_tpu.ops import ssm
     from llm_sharding_tpu.utils.compile_cache import enable_persistent_cache
     from llm_sharding_tpu.utils.device_report import device_report
 
@@ -1150,11 +1288,28 @@ def child_ssm(spec: dict, out_path: str) -> None:
               f"untouched {got['dead_rows_untouched']}; kernel "
               f"{us['kernel']} us, xla {us['xla']} us a layer call",
               flush=True)
+    # Mamba-1: a mixer's decode step as ONE kernel beside the split path
+    path = ssm.mixer_step_path("kernel", mixer_inputs(1)[0])
+    for live in SSM_LIVE:
+        got = check_mixer_step(live)
+        got["ok"] = path == "fused" and got["dead_rows_untouched"] and max(
+            got["h_err"], got["s_err"], got["c_err"]) <= MIXER_TOL
+        timed = {f: time_mixer_step(live, f) for f in ("fused", "split")}
+        results.append({"kernel": "ssm_mixer", "live_rows": live,
+                        "mixer_step_path": path, **got, **timed})
+        print(f"[ssm] ssm_mixer {live} live of {MIXER_ROWS} (path {path}): "
+              f"h err {got['h_err']:.2e}, state err {got['s_err']:.2e}, tail "
+              f"err {got['c_err']:.2e}, dead rows untouched "
+              f"{got['dead_rows_untouched']}; device us a layer call: fused "
+              f"{timed['fused']['us_per_layer_call']} "
+              f"{timed['fused']['ops_us'][:3]}, split "
+              f"{timed['split']['us_per_layer_call']} "
+              f"{timed['split']['ops_us'][:4]}", flush=True)
     with open(out_path, "w") as f:
         json.dump({"device": device_report(), "ssm": results}, f)
     if not all(r["ok"] for r in results):
         raise SystemExit(
-            "chip_smoke: the state update's kernel disagrees with XLA"
+            "chip_smoke: a state-space kernel disagrees with its reference"
         )
 
 
@@ -1583,8 +1738,10 @@ def main(argv=None) -> int:
                          "py): the one fused op beside the scatter pair")
     ap.add_argument("--ssm", action="store_true",
                     help="only check and time a decode step's state update "
-                         "(ops/ssm.py) at Nemotron-3-Super's mixer shape: "
-                         "the kernel beside the XLA loop")
+                         "(ops/ssm.py) at Nemotron-3-Super's mixer shape, the "
+                         "kernel beside the XLA loop, and a Mamba-1 mixer's "
+                         "fused decode step beside the split path at "
+                         "Jamba2-3B's")
     ap.add_argument("--child",
                     choices=("kernels", "store", "moe", "kv_write",
                              "kv_decode", "ssm"))

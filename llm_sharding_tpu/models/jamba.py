@@ -25,13 +25,26 @@ plain gate, no gated norm) and ``w_out``. What a request keeps per layer is
 ``S`` and the conv's last ``conv_kernel - 1`` inputs (float32): the RECURRENT
 STATE, ``cfg.recurrent_shapes`` a row, indexed by ROW, riding the layer
 scan's carry and updated where it lies. A decode step advances one position a
-row and touches the LIVE rows only (``ssm.ssm_step_rows``, the entry
-``nemotron_h`` uses); a prefill chunk is a SCAN IN TIME over the chunk's
+row and touches the LIVE rows only, in one of two forms chosen from what the
+code sees (``ssm.mixer_step_path``: the backend, the shapes, plain ``w_x`` /
+``w_dt``; no option). FUSED (``mamba_mixer_step``: the chip, and the kernels
+interpreted): everything between ``w_in`` and ``w_out`` is ONE Pallas call a
+layer, ``ssm.mixer_step_rows`` — the conv step, ``w_x``, the norms, ``w_dt``,
+softplus, the update and the gate — that advances the state AND the conv's
+tail inside the carried arrays and reads the stack's leaves through the
+layer's index (the scan hands them WHOLE: ``models/stack.MAMBA1_WHOLE_KEYS``);
+the live rows are counted ONCE a step (``live_rows``), and only ``A`` is made
+outside the call (``ssm_x``). SPLIT (``mamba_mixer`` at ``S == 1``: the CPU's
+XLA path, a shape the kernel cannot tile, int8 ``w_x`` / ``w_dt``): the tail
+sliced out and written back (``state``), ``ssm.conv_step``, the path to ``dt``
+in XLA, ``ssm.ssm_step_rows`` (the entry ``nemotron_h`` uses). A prefill
+chunk is a SCAN IN TIME over the chunk's
 positions (``ssm.scan_rows``: ONE Pallas kernel a layer call on the chip, the
 rows with a real token its live rows) with the row's stored state the carry
 in and out. A position that is no real token (a pad, a dead row, a masked
 layer, a ring-inactive microstep) has ``dt = 0`` and leaves the conv's tail
-alone: the state stays EXACTLY what it was. A row's first chunk starts from
+alone — the fused step does not visit it at all —: the state stays EXACTLY
+what it was. A row's first chunk starts from
 a zero state inside the chunk program (``fresh``).
 
 **``attn``**. ``num_attention_heads`` query heads over ``num_key_value_heads``
@@ -66,7 +79,7 @@ from .mimo_v2 import _scan_run
 from .nemotron_h import (  # noqa: F401  (``prefill_walks``: the family's)
     attn_block, kind_layer_counts, prefill_walks, stage_runs,
 )
-from .stack import zero_recurrent
+from .stack import MAMBA1_WHOLE_KEYS, zero_recurrent
 
 Params = dict[str, Any]
 f32 = jnp.float32
@@ -165,6 +178,37 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
 # The sub-blocks. The named scopes are words of ``obs.stepline.SCOPES``.
 # ---------------------------------------------------------------------------
 
+def _layer_leaves(p: Params) -> Params:
+    """``p`` with every leaf the layer's own: the leaves a scan handed WHOLE
+    (``models/stack.MAMBA1_WHOLE_KEYS``, beside the layer's index under
+    ``"layer"``) sliced at that index — what the scan itself would have done
+    — for the paths that read a layer's leaves in XLA."""
+    if "layer" not in p:
+        return p
+    p = dict(p)
+    at = p.pop("layer")
+    for k in MAMBA1_WHOLE_KEYS:
+        p[k] = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, at, keepdims=False), p[k]
+        )
+    return p
+
+
+def _w_in(cfg: ModelConfig, p: Params, h):
+    """A mixer's first projection: ``[x | z] = RMSNorm_in(h) w_in``."""
+    with jax.named_scope("norm"):
+        xh = rms_norm(h, p["norm"], cfg.rms_norm_eps)
+    with jax.named_scope("ssm_proj"):
+        # the projection leaves as the dot made it (models/llama.py, PR 31):
+        # the column split after it must not be folded into the dot
+        return jax.lax.optimization_barrier(qmatmul(xh, p["w_in"]))
+
+
+def _decay(p: Params):
+    """``A = -exp(A_log).T [state, d_inner]``, float32."""
+    return -jnp.exp(p["A_log"].astype(f32)).T
+
+
 def _mixer_in(cfg: ModelConfig, p: Params, h, tail, live):
     """A mixer up to its state update: the norm, ``w_in``, the conv over
     ``x`` (its ``tail`` shifted for the live positions) and the path that
@@ -173,12 +217,7 @@ def _mixer_in(cfg: ModelConfig, p: Params, h, tail, live):
     S = h.shape[1]
     di, ds, R = cfg.ssm_inner, cfg.ssm_state_size, cfg.ssm_dt_rank
     eps = cfg.rms_norm_eps
-    with jax.named_scope("norm"):
-        xh = rms_norm(h, p["norm"], eps)
-    with jax.named_scope("ssm_proj"):
-        # the projection leaves as the dot made it (models/llama.py, PR 31):
-        # the column split below must not be folded into the dot
-        xz = jax.lax.optimization_barrier(qmatmul(xh, p["w_in"]))
+    xz = _w_in(cfg, p, h)
     x, z = xz[..., :di], xz[..., di:]
     with jax.named_scope("conv"):
         if S == 1:
@@ -199,25 +238,35 @@ def _mixer_in(cfg: ModelConfig, p: Params, h, tail, live):
             + p["dt_bias"].astype(f32)
         )
         dt = jnp.where(live[..., None], dt, 0.0)
-        A = -jnp.exp(p["A_log"].astype(f32)).T  # [state, d_inner]
+        A = _decay(p)
     return x, z, dt, A, Bm, Cm, tail
 
 
+def live_rows(live):
+    """``(order [B], n_live)`` of ``live [B, S]``: the slot's rows with those
+    that hold a live position first, and their count — what the kernels of
+    ``ops/ssm.py`` walk."""
+    alive = jnp.any(live, axis=1)
+    return jnp.argsort(~alive), jnp.sum(alive.astype(jnp.int32))
+
+
 def mamba_mixer(cfg: ModelConfig, p: Params, h, s_all, at, tail, live,
-                backend: str = "auto"):
+                backend: str = "auto", rows=None):
     """A Mamba-1 mixer of a slot's rows with the state updated WHERE IT LIES
     and only where a row is live: ``s_all [L_mamba, rows, state, 8, d_inner /
     8]`` the whole carried state, ``at = (layer, first row)``, ``h [B, S, H]``,
     conv ``tail [B, K-1, d_inner]``, ``live [B, S]`` the positions that are
     real tokens (a row's FIRST ``Σ live``) → ``(h, s_all, tail)``. ``S == 1``
-    is the decode step (``ssm.ssm_step_rows``), else the scan in time over the
-    chunk's positions (``ssm.scan_rows``); a row with no live position costs
-    neither a read nor a write of its state. ``backend``: ``ops/ssm``'s."""
+    is the decode step in its SPLIT form (``ssm.conv_step``, the path to
+    ``dt`` in XLA, ``ssm.ssm_step_rows``; the fused form is
+    ``mamba_mixer_step``), else the scan in time over the chunk's positions
+    (``ssm.scan_rows``); a row with no live position costs neither a read nor
+    a write of its state. ``rows``: ``live_rows(live)`` where the caller has
+    it already. ``backend``: ``ops/ssm``'s."""
+    p = _layer_leaves(p)
     x, z, dt, A, Bm, Cm, tail = _mixer_in(cfg, p, h, tail, live)
     with jax.named_scope("ssm"):
-        alive = jnp.any(live, axis=1)
-        order = jnp.argsort(~alive)  # the live rows first
-        n_live = jnp.sum(alive.astype(jnp.int32))
+        order, n_live = live_rows(live) if rows is None else rows
         if h.shape[1] == 1:
             y, s_all = ssm.ssm_step_rows(
                 s_all, at, order, n_live, x[:, 0], dt[:, 0], A, Bm[:, 0],
@@ -231,6 +280,73 @@ def mamba_mixer(cfg: ModelConfig, p: Params, h, s_all, at, tail, live,
             )
     with jax.named_scope("ssm_proj"):
         return h + qmatmul(y.astype(h.dtype), p["w_out"]), s_all, tail
+
+
+def mixer_step_fused(cfg: ModelConfig, p: Params, backend: str) -> bool:
+    """Whether a decode step of this mixer runs as ``mamba_mixer_step``, from
+    what the code can see: the leaves handed whole beside the layer's index,
+    ``w_x`` and ``w_dt`` plain arrays, and ``ssm.mixer_step_path``'s answer
+    for the backend and the shapes."""
+    return "layer" in p and ssm.mixer_step_path(backend, cfg, p) == "fused"
+
+
+def mamba_mixer_step(cfg: ModelConfig, p: Params, h, s_all, c_all, at, rows,
+                     backend: str = "auto"):
+    """A Mamba-1 mixer's DECODE step, fused: between ``w_in`` and ``w_out``
+    ONE Pallas call (``ssm.mixer_step_rows``, under the scope ``ssm``) that
+    advances the conv's tail AND the state of the slot's live rows where they
+    lie — ``c_all [L_mamba, rows, K-1, d_inner]`` beside ``s_all``, ``at =
+    (layer, first row)``, ``rows = (order, n_live)`` — and reads the stack's
+    leaves through the same layer index (``models/stack.MAMBA1_WHOLE_KEYS``
+    come whole: a layer's slot in the state is its index in its stack).
+    Outside it only ``A = -exp(A_log).T`` (``ssm_x``). ``h [B, 1, H]`` →
+    ``(h, s_all, c_all)``."""
+    xz = _w_in(cfg, p, h)
+    with jax.named_scope("ssm_x"):
+        A = _decay(p)
+    with jax.named_scope("ssm"):
+        y, s_all, c_all = ssm.mixer_step_rows(
+            s_all, c_all, at, *rows, xz[:, 0],
+            {k: p[k] for k in MAMBA1_WHOLE_KEYS}, A, cfg.rms_norm_eps,
+            backend=backend,
+        )
+    with jax.named_scope("ssm_proj"):
+        return (
+            h + qmatmul(y[:, None].astype(h.dtype), p["w_out"]), s_all, c_all
+        )
+
+
+def mixer_block(cfg: ModelConfig, p: Params, h, s_all, c_all, at, live,
+                rows=None, backend: str = "auto", zero=None):
+    """A mixer layer's first sub-block over the CARRIED recurrent state:
+    ``s_all`` and ``c_all [L_mamba, rows, K-1, d_inner]`` whole, ``at =
+    (layer, first row)``, ``live [B, S]``, ``rows = (order, n_live)`` where
+    the caller counted them → ``(h, s_all, c_all)``. A decode step (``S ==
+    1``, ``zero`` None) whose leaves and backend allow it
+    (``mixer_step_fused``) is ``mamba_mixer_step`` — the conv's tail in the
+    kernel's pass: no slice of ``c_all`` and no write-back. Anything else
+    slices the slot's tails out, runs ``mamba_mixer`` and writes them back
+    (``state``); a prefill chunk hands ``zero``, whether this is its rows'
+    FIRST chunk: they then start from nothing."""
+    B, S = h.shape[:2]
+    if S == 1 and zero is None and mixer_step_fused(cfg, p, backend):
+        return mamba_mixer_step(
+            cfg, p, h, s_all, c_all, at,
+            live_rows(live) if rows is None else rows, backend,
+        )
+    at_c = at + (0,) * (c_all.ndim - 2)
+    with jax.named_scope("state"):
+        c = jax.lax.dynamic_slice(c_all, at_c, (1, B, *c_all.shape[2:]))[0]
+        if zero is not None:
+            at_s = at + (0,) * (s_all.ndim - 2)
+            s = jax.lax.dynamic_slice(s_all, at_s, (1, B, *s_all.shape[2:]))
+            s_all = jax.lax.dynamic_update_slice(
+                s_all, jnp.where(zero, jnp.zeros_like(s), s), at_s
+            )
+            c = jnp.where(zero, jnp.zeros_like(c), c)
+    h, s_all, c = mamba_mixer(cfg, p, h, s_all, at, c, live, backend, rows)
+    with jax.named_scope("state"):
+        return h, s_all, jax.lax.dynamic_update_slice(c_all, c[None], at_c)
 
 
 def mlp_block(cfg: ModelConfig, p: Params, h):
@@ -313,6 +429,12 @@ def forward_layers_paged(
     n_slots = sum(kind_layer_counts(cfg, layers, axis=0).values())
     if layer_mask is None:
         layer_mask = jnp.ones((n_slots,), bool)
+    # a decode step's live rows, ONCE for all the mixer layers: a layer
+    # that is masked has none of them (``apply``)
+    rows_live = None if prefill else live_rows(jnp.broadcast_to(
+        jnp.asarray(wv) if moe_live is None else moe_live & jnp.asarray(wv),
+        (B, S),
+    ))
     carry = (h, k_all, v_arena, s_in, c_in)
     for run in stage_runs(cfg, layers):
 
@@ -343,26 +465,13 @@ def forward_layers_paged(
 
                 h_new, (k_a, v_a) = attn_block(cfg, p, h, attend)
             else:
-                at_c = (l, row0) + (0,) * (c_all.ndim - 2)
-                rows = (1, B, *s_all.shape[2:])
-                with jax.named_scope("state"):
-                    c = jax.lax.dynamic_slice(
-                        c_all, at_c, (1, B, *c_all.shape[2:])
-                    )[0]
-                    if prefill:
-                        # a row's first chunk starts from nothing
-                        zero = fresh & gate
-                        at_s = (l, row0) + (0,) * (s_all.ndim - 2)
-                        s = jax.lax.dynamic_slice(s_all, at_s, rows)
-                        s_all = jax.lax.dynamic_update_slice(
-                            s_all, jnp.where(zero, jnp.zeros_like(s), s), at_s
-                        )
-                        c = jnp.where(zero, jnp.zeros_like(c), c)
-                h_new, s_all, c = mamba_mixer(
-                    cfg, p, h, s_all, (l, row0), c, live, backend
+                h_new, s_all, c_all = mixer_block(
+                    cfg, p, h, s_all, c_all, (l, row0), live,
+                    None if prefill else (
+                        rows_live[0], jnp.where(valid, rows_live[1], 0)
+                    ),
+                    backend, zero=fresh & gate if prefill else None,
                 )
-                with jax.named_scope("state"):
-                    c_all = jax.lax.dynamic_update_slice(c_all, c[None], at_c)
             h_new = mlp_block(cfg, p, h_new)
             return (
                 jnp.where(valid, h_new, h), k_a, v_a, s_all, c_all

@@ -30,13 +30,26 @@ ApplyLayerFn = Callable
 #: layer first would copy all of them (0.4 GB a layer at OLMoE's widths).
 WHOLE_KEYS = ("we_gate", "we_up", "we_down")
 
+#: ... and, of a Mamba-1 stack ALONE (one that has ``w_x``: Mamba-2's stack
+#: has leaves of some of these names and stays scanned), what a decode
+#: step's one kernel a mixer layer reads through the layer index
+#: (``ops/ssm.mixer_step_rows``): a per-layer slice handed to a Pallas call
+#: would be copied first, 2 MB of ``w_x`` a layer.
+MAMBA1_WHOLE_KEYS = (
+    "conv_w", "conv_b", "w_x", "dt_norm", "b_norm", "c_norm", "w_dt",
+    "dt_bias", "D",
+)
+
 
 def split_whole(layers):
     """``(scanned, whole)``: ``whole`` is None for a model with no such leaf,
     and ``layers`` then comes back as it is."""
-    if not isinstance(layers, dict) or not any(k in layers for k in WHOLE_KEYS):
+    if not isinstance(layers, dict):
         return layers, None
-    whole = {k: layers[k] for k in WHOLE_KEYS if k in layers}
+    keys = WHOLE_KEYS + (MAMBA1_WHOLE_KEYS if "w_x" in layers else ())
+    if not any(k in layers for k in keys):
+        return layers, None
+    whole = {k: layers[k] for k in keys if k in layers}
     return {k: v for k, v in layers.items() if k not in whole}, whole
 
 

@@ -752,6 +752,23 @@ RECURRENT_SCAN_PATH = REGISTRY.gauge(
     "process, all zero where no live server's model has recurrent layers",
     labels=("path",),
 )
+#: forms a Mamba-1 mixer's decode step can take (``ops/ssm.mixer_step_path``)
+RECURRENT_MIXER_STEPS = ("fused", "split")
+RECURRENT_MIXER_STEP = REGISTRY.gauge(
+    "server_recurrent_mixer_step",
+    "Live servers of a model with Mamba-1 mixers by the form a mixer "
+    "layer's decode step takes between its two projections "
+    "(ops/ssm.mixer_step_path at the server's resolved attention backend, "
+    "the mixer's shapes and its leaves' types): fused = ONE Pallas call a "
+    "layer — the conv step, w_x, the three norms, w_dt, softplus and the "
+    "state update, the state AND the conv's tail advanced where they lie, "
+    "the live rows only; split = the conv step and the path to dt as XLA "
+    "operations around the state update (server_recurrent_backend says "
+    "which that is): the CPU path, a shape the kernel cannot tile, "
+    "quantised w_x / w_dt. One-hot for a single-server process, all zero "
+    "where no live server's model has Mamba-1 mixers",
+    labels=("path",),
+)
 PREFILL_SCAN_KINDS = ("real", "pad")
 PREFILL_SCAN_POSITIONS = REGISTRY.counter(
     "server_prefill_scan_positions_total",

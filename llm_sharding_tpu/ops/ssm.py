@@ -4,11 +4,15 @@ families in its two forms each, and Mamba-2's gated grouped norm. float32
 throughout: a request's recurrent state is a float32 array of FIXED size (a
 layer's state beside the conv's last ``K - 1`` inputs) that thousands of
 decode steps multiply through, so nothing here rounds it to a narrower type.
-Everything is plain XLA but TWO Pallas kernels: Mamba-2's paged decode update
-(``ssm_rows_tpu``) and Mamba-1's scan in time (``scan_rows_tpu``: a decode
-step and a prefill chunk alike). The families share ``conv_step`` /
-``conv_chunk``, the rule for pads, and ONE entry for the decode step
-(``ssm_step_rows``) with ONE question a server asks (``rows_backend``).
+Everything is plain XLA but THREE Pallas kernels: Mamba-2's paged decode update
+(``ssm_rows_tpu``), Mamba-1's scan in time (``scan_rows_tpu``: a prefill
+chunk, and the state update of a decode step in its split form) and
+Mamba-1's whole mixer step between its projections (``mixer_step_tpu``: a
+decode step in its fused form). The families share ``conv_step`` /
+``conv_chunk``, the rule for pads, and ONE entry for the decode step's state
+update (``ssm_step_rows``) with ONE question a server asks of it
+(``rows_backend``); they share NO logic past that — Mamba-1's fused step
+(``mixer_step_rows``, ``mixer_step_path``) is its own.
 
 **Mamba-2** (``models/nemotron_h.py``): the recurrence, per head ``h`` (``A_h <
 0`` a scalar, ``D_h`` a skip gain; ``B_t``, ``C_t [state]`` shared by the heads
@@ -68,6 +72,26 @@ the positions loop INSIDE the kernel, ``x``, ``dt``, ``z`` read once, ``B`` and
 path and the kernel's oracle), ``interpret`` the kernel emulated. The state
 lies as ``[state, 8, channels / 8]`` (``ModelConfig.recurrent_shapes``).
 
+**A decode step of a Mamba-1 mixer, fused** (``mixer_step_rows``): what lies
+between ``w_in`` and ``w_out`` — the conv step over the row's tail, ``[δ | B |
+C] = x w_x``, the three norms, ``dt = softplus(δ w_dt + b_dt)``, the update
+and the gated read-out — is latency, not bytes or arithmetic, when it is
+fifteen XLA operations a layer (a ``[1, 5120] x [5120, 192]`` product, 82 K
+state values); ``mixer_step_tpu`` is ONE Pallas call: the carried state AND
+the carried conv tails aliased over themselves, the stack's leaves read
+through a scalar-prefetched layer index (a per-layer slice of a scanned stack
+handed to a Pallas call is copied first: ``models/stack.MAMBA1_WHOLE_KEYS``),
+grid ``(live rows, 2 x channel tiles)`` — ``w_x`` reduces over ALL channels,
+so a row runs PHASE 1 over every tile (conv, the shifted tail written back,
+the product accumulated, the conv's output kept in VMEM) before PHASE 2 runs
+over them (norms once, then ``w_dt``, softplus, update and read-out a tile).
+Phase 2 works on ``[1, di / 8]`` rows — one sublane of the state ``[state, 8,
+di / 8]`` at a time — because both products make channels along LANES. A row
+that is not live is neither read nor written, its state and tail bit for
+bit what they were. ``mixer_step_path`` says whether a step takes it
+(``fused``) or the operations above (``split``): the backend, the shapes
+(``mixer_eligible``), plain ``w_x`` / ``w_dt`` — nothing else chooses.
+
 **Pads.** A position that is no real token has ``dt = 0`` (the caller forces
 it): ``exp(0) = 1`` and ``0·(x ⊗ B) = 0``, so it leaves the state EXACTLY as
 it was, and a right-padded chunk ends in the state of its last real token.
@@ -78,6 +102,7 @@ the chunk's end.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -625,3 +650,310 @@ def scan_rows(s_all, at, order, n_live, x, dt, z, A, Bm, Cm, D,
     )
     live = _visited(order, n_live)
     return jnp.where(live[:, None, None], y.reshape(B, S, di), 0.0), s_all
+
+
+# --------------------------------- Mamba-1: a decode step's mixer in one pass
+
+#: bytes of ``w_x`` one grid step of ``mixer_step_tpu`` holds ONE way (in VMEM
+#: twice: double buffered, its lanes padded to whole tiles). At the published
+#: widths (5,120 x 192 bf16: 2.6 MB padded) HALF of it: two channel tiles, four
+#: grid steps a row. In the decode program a layer call takes 6.9-7.3 us so
+#: against 7.2-7.6 with ONE tile (a tile's fetch overlaps the tile before's
+#: work); in a program whose stacks lie in fast memory already 4.8 against
+#: 4.3, and 5.6 / 7.4 at 4 / 8 tiles: ~0.4 us a grid step (``PERF.md`` §6,
+#: PR 47)
+_W_X_BLOCK_BYTES = 2 * 1024 * 1024
+
+
+def mixer_tiles(di: int, width: int, itemsize: int) -> int:
+    """Channel tiles ``mixer_step_tpu`` cuts a live row's two phases into:
+    the fewest of 1, 2, 4, 8 (whole sublanes of the state ``[state, 8, di /
+    8]``) whose ``[di / tiles, width]`` block of ``w_x`` stays within
+    ``_W_X_BLOCK_BYTES``."""
+    padded = -(-width // 128) * 128 * itemsize
+    fits = [t for t in (1, 2, 4, 8) if di // t * padded <= _W_X_BLOCK_BYTES]
+    return min(fits, default=8)
+
+
+def mixer_eligible(ds: int, sub: int, lanes: int, rank: int) -> bool:
+    """Whether Mosaic tiles ``mixer_step_tpu``'s blocks: the state as
+    ``scan_rows_tpu`` takes it, and whole sublane tiles of the step's rank
+    and of the state values (``w_dt``'s rows, the norms' slices)."""
+    return scan_eligible(ds, sub, lanes) and rank % 8 == 0 and ds % 8 == 0
+
+
+def _mixer_leaves_plain(layers) -> bool:
+    """Whether every ``w_x`` / ``w_dt`` leaf of a tree of leaves by name is
+    a plain array (no ``ops/quant.QTensor``)."""
+    if not isinstance(layers, dict):
+        return True
+    return all(
+        isinstance(v, jax.Array) if k in ("w_x", "w_dt")
+        else _mixer_leaves_plain(v)
+        for k, v in layers.items()
+    )
+
+
+def mixer_step_path(backend: str, cfg, layers=None) -> str:
+    """The form a Mamba-1 mixer's decode step takes for ``backend``:
+    ``fused`` — ONE Pallas call a layer between ``w_in`` and ``w_out``
+    (``mixer_step_rows``) — or ``split`` (``conv_step``, the ``w_x`` path in
+    XLA, ``ssm_step_rows``): the CPU's ``xla``, a shape Mosaic cannot tile,
+    quantised ``w_x`` / ``w_dt`` among ``layers`` (a tree of leaves by name:
+    a layer's, a stage's; None asks for the shapes alone)."""
+    ds, sub, lanes = cfg.recurrent_shapes["ssm"]
+    eligible = mixer_eligible(ds, sub, lanes, cfg.ssm_dt_rank)
+    plain = _mixer_leaves_plain(layers)
+    return "fused" if plain and _resolve(backend, eligible) != "xla" else "split"
+
+
+def _softplus(v):
+    return jnp.maximum(v, 0.0) + jnp.log1p(jnp.exp(-jnp.abs(v)))
+
+
+def _mixer_kernel(lyr, row0, order, nlive, x_ref, z_ref, c_ref, s_ref, cw_ref,
+                  cb_ref, wx_ref, gdt_ref, gb_ref, gc_ref, wdt_ref, bdt_ref,
+                  d_ref, a_ref, co_ref, so_ref, y_ref, xc_ref, acc_ref, dl_ref,
+                  bc_ref, *, NT, R, eps):
+    """Step ``(i, j)`` of live row ``order[i]``: ``j < NT`` is PHASE 1 on
+    channel tile ``j`` — the conv step over the row's tail (``c_ref`` /
+    ``co_ref [K-1, W]``, the shifted tail written back), its ``silu`` kept in
+    ``xc_ref [NT, 1, W]``, its share of ``x w_x`` added into ``acc_ref [1,
+    rank + 2 state]`` —; ``j >= NT`` is PHASE 2 on channel tile ``j - NT`` —
+    at its first step the three norms (``dl_ref [1, rank]``, ``bc_ref [2,
+    state]``), then ``dt = softplus(δ w_dt + b_dt)`` of the tile, the update
+    of the tile's state rows (``s_ref`` / ``so_ref [state, 8, T]``, resident
+    for the row: tile ``t`` holds its sublanes ``t W / T …``) and the gated
+    read-out into ``y_ref [B, d_inner]`` (resident for the call, zeroed at
+    its first step). ``x_ref`` / ``z_ref [B, W]`` are the tile's columns of
+    ``xz``; a per-layer vector comes as the block of stack rows that holds
+    the layer's (``pick``)."""
+    i, j = pl.program_id(0), pl.program_id(1)
+    B, W = x_ref.shape
+    ds, _, T = s_ref.shape
+    K = cw_ref.shape[0]
+    l = lyr[0]
+    b = order[jnp.minimum(i, B - 1)]
+    live = i < nlive[0]
+
+    def pick(ref, r):  # row ``r`` of a small block → [1, N] float32
+        v = ref[...].astype(f32)
+        rows = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+        return jnp.sum(jnp.where(rows == r, v, 0.0), axis=0, keepdims=True)
+
+    def vector(ref):  # the layer's row of a stack's block of rows
+        return pick(ref, l % ref.shape[0])
+
+    @pl.when((i == 0) & (j == 0))
+    def _first_step():
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+    @pl.when(jnp.logical_not(live))
+    def _none_live():
+        # the ONE step of a grid with no live row: its blocks go back as
+        # they came
+        so_ref[...] = s_ref[...]
+        co_ref[...] = c_ref[...]
+
+    @pl.when(live & (j < NT))
+    def _conv_and_w_x():
+        x = pick(x_ref, b)  # [1, W]
+        tail = c_ref[...]
+        w = cw_ref[...].astype(f32)
+        conv = tail[0:1] * w[0:1]
+        for t in range(1, K - 1):
+            conv = conv + tail[t:t + 1] * w[t:t + 1]
+            co_ref[t - 1:t, :] = tail[t:t + 1]
+        conv = conv + x * w[K - 1:K] + vector(cb_ref)
+        co_ref[K - 2:K - 1, :] = x
+        xc = conv * jax.nn.sigmoid(conv)
+        xc_ref[j] = xc
+        part = jnp.dot(
+            xc.astype(wx_ref.dtype), wx_ref[...], preferred_element_type=f32
+        )
+
+        @pl.when(j == 0)
+        def _first_tile():
+            acc_ref[...] = part
+
+        @pl.when(j > 0)
+        def _next_tile():
+            acc_ref[...] += part
+
+    @pl.when(live & (j == NT))
+    def _norms():
+        v = acc_ref[...]
+
+        def norm(u, g_ref):
+            u = u * jax.lax.rsqrt(jnp.mean(u * u, axis=-1, keepdims=True) + eps)
+            return u * pick(g_ref, l)
+
+        dl_ref[...] = norm(v[:, :R], gdt_ref)
+        bc_ref[0:1, :] = norm(v[:, R:R + ds], gb_ref)
+        bc_ref[1:2, :] = norm(v[:, R + ds:], gc_ref)
+
+    @pl.when(live & (j >= NT))
+    def _update():
+        t = j - NT
+        dt = _softplus(jnp.dot(
+            dl_ref[...].astype(wdt_ref.dtype), wdt_ref[...],
+            preferred_element_type=f32,
+        ) + vector(bdt_ref))  # [1, W]
+        xc, z, D = xc_ref[t], pick(z_ref, b), vector(d_ref)
+        bm, cm = bc_ref[0:1, :], bc_ref[1:2, :]
+        for r in range(W // T):  # the tile's sublanes of the state
+            at = pl.ds(t * (W // T) + r, 1)
+            lanes = slice(r * T, (r + 1) * T)
+            dtr, xr, zr = dt[:, lanes], xc[:, lanes], z[:, lanes]
+            xdt = dtr * xr
+            y = D[:, lanes] * xr
+            for n in range(ds):
+                sn = (
+                    s_ref[n, at, :] * jnp.exp(dtr * a_ref[n, at, :])
+                    + xdt * bm[:, n:n + 1]
+                )
+                so_ref[n, at, :] = sn
+                y = y + sn * cm[:, n:n + 1]
+            col = t * W + r * T
+            if T % 128 == 0:
+                col = pl.multiple_of(col, 128)
+            y_ref[pl.ds(b, 1), pl.ds(col, T)] = y * zr * jax.nn.sigmoid(zr)
+
+
+@functools.partial(jax.jit, static_argnames=("eps", "tiles", "interpret"))
+def mixer_step_tpu(s_all, c_all, at, order, n_live, xz, leaves, A, *,
+                   eps: float, tiles: Optional[int] = None,
+                   interpret: bool = False):
+    """The Pallas mixer step of a slot's live rows (``mixer_step_rows``:
+    the operands): grid ``(max(n_live, 1), 2 tiles)`` with the first extent
+    TRACED (as ``scan_rows_tpu``'s); a row's steps run phase 1 over its
+    ``tiles`` channel tiles (``mixer_tiles`` unless given: the tests walk
+    them all), then phase 2 over them — ``w_x`` reduces over
+    ALL channels, so every channel's conv output exists (in VMEM) before any
+    channel's ``dt`` does. ``s_all`` and ``c_all`` are aliased over
+    themselves; the stacks of ``leaves`` are indexed by the scalar-prefetched
+    layer in their ``BlockSpec``s (a ``[L, N]`` vector by the block of 8 or 16
+    stack rows that holds the layer's). With no live row the grid is ONE step
+    that writes a state block and a tail tile of the first row back as they
+    were read. ``A [ds, 8, T]`` float32."""
+    L, _, ds, sub, T = s_all.shape
+    K1, di = c_all.shape[2:]
+    B = xz.shape[0]
+    p = leaves
+    R = p["w_dt"].shape[1]
+    NT = tiles or mixer_tiles(di, R + 2 * ds, p["w_x"].dtype.itemsize)
+    W = di // NT
+    n = jnp.reshape(n_live, (1,)).astype(jnp.int32)
+
+    def row(i, order):
+        return order[jnp.minimum(i, B - 1)]
+
+    def p1(j):  # phase 1's tile at step j (phase 2 holds its last)
+        return jnp.minimum(j, NT - 1)
+
+    def p2(j):  # phase 2's tile (phase 1 holds its first)
+        return jnp.maximum(j - NT, 0)
+
+    def vector(a, tile):
+        # the block of stack rows that holds the layer's: a whole sublane
+        # tile of ``a``'s type (8 rows of float32, 16 of bf16)
+        rb = min(L, 8 * 4 // a.dtype.itemsize)
+        return pl.BlockSpec(
+            (rb, W), lambda i, j, lyr, *_: (lyr[0] // rb, tile(j))
+        )
+
+    def whole(a):
+        return pl.BlockSpec(a.shape, lambda i, j, *_: (0,) * a.ndim)
+
+    tail = pl.BlockSpec(
+        (None, None, K1, W),
+        lambda i, j, lyr, row0, order, nl: (
+            lyr[0], row0[0] + row(i, order), 0, p1(j)
+        ),
+    )
+    state = pl.BlockSpec(
+        (None, None, ds, sub, T),
+        lambda i, j, lyr, row0, order, nl: (
+            lyr[0], row0[0] + row(i, order), 0, 0, 0
+        ),
+    )
+    in_specs = [
+        pl.BlockSpec((B, W), lambda i, j, *_: (0, p1(j))),  # x of xz
+        pl.BlockSpec((B, W), lambda i, j, *_: (0, NT + p2(j))),  # z of xz
+        tail, state,
+        pl.BlockSpec(
+            (None, K1 + 1, W), lambda i, j, lyr, *_: (lyr[0], 0, p1(j))
+        ),
+        vector(p["conv_b"], p1),
+        pl.BlockSpec(
+            (None, W, R + 2 * ds), lambda i, j, lyr, *_: (lyr[0], p1(j), 0)
+        ),
+        whole(p["dt_norm"]), whole(p["b_norm"]), whole(p["c_norm"]),
+        pl.BlockSpec((None, R, W), lambda i, j, lyr, *_: (lyr[0], 0, p2(j))),
+        vector(p["dt_bias"], p2), vector(p["D"], p2),
+        whole(A),
+    ]
+    c_all, s_all, y = pl.pallas_call(
+        functools.partial(_mixer_kernel, NT=NT, R=R, eps=eps),
+        out_shape=[
+            jax.ShapeDtypeStruct(c_all.shape, f32),
+            jax.ShapeDtypeStruct(s_all.shape, f32),
+            jax.ShapeDtypeStruct((B, di), f32),
+        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(jnp.maximum(n[0], 1), jnp.where(n[0] > 0, 2 * NT, 1)),
+            in_specs=in_specs,
+            out_specs=[
+                tail, state, pl.BlockSpec((B, di), lambda i, j, *_: (0, 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((NT, 1, W), f32),
+                pltpu.VMEM((1, R + 2 * ds), f32),
+                pltpu.VMEM((1, R), f32),
+                pltpu.VMEM((2, ds), f32),
+            ],
+        ),
+        # the carried tail and state, each over itself
+        input_output_aliases={6: 0, 7: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+        ),
+        interpret=interpret,
+        name="ssm_mixer",
+    )(
+        *(jnp.reshape(a, (1,)).astype(jnp.int32) for a in at),
+        order.astype(jnp.int32), n, xz, xz, c_all, s_all, p["conv_w"],
+        p["conv_b"], p["w_x"], p["dt_norm"], p["b_norm"], p["c_norm"],
+        p["w_dt"], p["dt_bias"], p["D"], A,
+    )
+    return y, s_all, c_all
+
+
+def mixer_step_rows(s_all, c_all, at, order, n_live, xz, leaves, A, eps,
+                    backend: str = "auto"):
+    """A Mamba-1 mixer's decode step between its two projections, ONE Pallas
+    call, with the state AND the conv's tail advanced where they lie:
+    ``s_all [L_mamba, rows, ds, 8, di / 8]`` and ``c_all [L_mamba, rows, K-1,
+    di]`` the whole carried arrays, ``at = (layer, first row of the slot)``,
+    ``order [B]`` the slot's rows with the live ones first and ``n_live``
+    their count, ``xz [B, 2 di]`` what ``w_in`` made, ``leaves`` the stack's
+    leaves of ``models/stack.MAMBA1_WHOLE_KEYS`` WHOLE (``[L_mamba, ...]``,
+    plain arrays), ``A [ds, di] = -exp(A_log).T`` of the layer → ``(y [B,
+    di]`` f32, gated — ZERO for a row that is not live —, ``s_all, c_all)``. Inside the call, per live
+    row: the conv step over its tail, ``[δ | B | C] = x w_x``, the three
+    norms, ``dt = softplus(δ w_dt + b_dt)``, ``S ← exp(dt A) S + dt B x``,
+    ``y = (S·C + D x) · silu(z)`` — the two products on operands of the
+    weights' type with float32 accumulation, everything else float32. A row
+    that is not live is neither read nor written. ``backend`` must resolve
+    to ``kernel`` or ``interpret`` (``mixer_step_path`` says ``fused``)."""
+    _, _, ds, sub, T = s_all.shape
+    backend = _resolve(
+        backend, mixer_eligible(ds, sub, T, leaves["w_dt"].shape[1])
+    )
+    assert backend != "xla", "mixer_step_path: this shape takes the split path"
+    return mixer_step_tpu(
+        s_all, c_all, at, order, n_live, xz, leaves,
+        A.astype(f32).reshape(ds, sub, T), eps=eps,
+        interpret=backend == "interpret",
+    )

@@ -88,8 +88,8 @@ from ..obs.metrics import (
     PREFILL_BLOCKS_READ, PREFILL_CELLS_LIVE, PREFILL_CELLS_WALKED,
     PREFILL_KV_BLOCKS_WRITTEN, PREFILL_POSITIONS, PREFILL_SCAN_POSITIONS,
     PREFIX_HIT_RATE, RECURRENT_BACKEND, RECURRENT_BACKENDS,
-    RECURRENT_ROW_BYTES, RECURRENT_ROWS_IN_USE, RECURRENT_SCAN_PATH,
-    RECURRENT_SCAN_PATHS,
+    RECURRENT_MIXER_STEP, RECURRENT_MIXER_STEPS, RECURRENT_ROW_BYTES,
+    RECURRENT_ROWS_IN_USE, RECURRENT_SCAN_PATH, RECURRENT_SCAN_PATHS,
     PREFIX_HIT_TOKENS, REGISTRY, record_shape_key, set_prefill_path,
 )
 from ..obs.trace import TraceContext, TraceWriter, emit_span
@@ -233,6 +233,7 @@ def _update_load_gauges() -> None:
     backends = dict.fromkeys(ATTN_BACKENDS, 0)
     state_backends = dict.fromkeys(RECURRENT_BACKENDS, 0)
     scan_paths = dict.fromkeys(RECURRENT_SCAN_PATHS, 0)
+    mixer_steps = dict.fromkeys(RECURRENT_MIXER_STEPS, 0)
     arena_bytes = dict.fromkeys(KV_DTYPES, 0)
     for s in list(_LIVE_SERVERS):
         queued += len(s._queue)
@@ -249,6 +250,8 @@ def _update_load_gauges() -> None:
             if getattr(s, "recurrent", False):
                 state_backends[s.recurrent_backend] += 1
                 scan_paths[s.recurrent_scan_path] += 1
+                if s.recurrent_mixer_step:
+                    mixer_steps[s.recurrent_mixer_step] += 1
         if getattr(s, "paged", False):
             kv_total += s._alloc.capacity_blocks
             kv_used += s._alloc.in_use
@@ -290,6 +293,8 @@ def _update_load_gauges() -> None:
         RECURRENT_BACKEND.labels(backend=b).set(n)
     for path, n in scan_paths.items():
         RECURRENT_SCAN_PATH.labels(path=path).set(n)
+    for path, n in mixer_steps.items():
+        RECURRENT_MIXER_STEP.labels(path=path).set(n)
     for name, nbytes in arena_bytes.items():
         ARENA_BYTES.labels(dtype=name).set(nbytes)
     KV_BLOCKS_TOTAL.set(kv_total)
@@ -1376,11 +1381,16 @@ class PipelineServer:
         if self.recurrent:
             # the path a decode step's state update takes under the static
             # the serve programs compile against (ops/ssm.ssm_step_rows)
-            from ..ops.ssm import rows_backend, scan_path
+            from ..ops.ssm import mixer_step_path, rows_backend, scan_path
 
             self.recurrent_backend = rows_backend(self.attn_impl, self.cfg)
             #: ... and a prefill chunk's scan
             self.recurrent_scan_path = scan_path(self.attn_impl, self.cfg)
+            #: ... and the form of a Mamba-1 mixer's decode step (None for
+            #: Mamba-2: its step is the state update alone)
+            self.recurrent_mixer_step = mixer_step_path(
+                self.attn_impl, self.cfg, engine.stage_layers
+            ) if self.cfg.ssm_dt_rank else None
         # -- automatic prefix cache (runtime/radix.py) ---------------------
         # "hbm": radix tree over token ids — every submit transparently
         # reuses the longest cached prefix, finished rows' prompt blocks

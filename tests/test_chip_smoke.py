@@ -342,3 +342,42 @@ def test_ssm_shape_is_the_cells():
     assert s["layers"] == cfg["hybrid_override_pattern"][
         :cfg["num_hidden_layers"]].count("M")
     assert s["rows"] == cfg["serve"]["batch_per_slot"]
+
+
+MIXER_TOY = dict(hidden_size=16, mamba_expand=8, intermediate_size=16,
+                 num_attention_heads=1, num_key_value_heads=1, vocab_size=256,
+                 mamba_dt_rank=8, mamba_d_state=8)
+
+
+@pytest.mark.parametrize("live", [0, 1, 4])
+def test_mixer_step_check_at_toy_widths(live, monkeypatch):
+    """``chip_smoke.py --ssm``'s Mamba-1 check at toy widths, the kernel
+    emulated: the fused decode step (the leaves handed whole) agrees with the
+    split path (a layer's slices) over every layer of the stack, and a row
+    that is not live keeps its state and its conv tail bit for bit."""
+    monkeypatch.setattr(chip_smoke, "MIXER_KEYS", MIXER_TOY)
+    monkeypatch.setattr(chip_smoke, "MIXER_LAYERS", 3)
+    got = chip_smoke.check_mixer_step(live, backend="interpret")
+    assert got["dead_rows_untouched"]
+    assert max(got["h_err"], got["s_err"], got["c_err"]) <= chip_smoke.MIXER_TOL
+
+
+def test_mixer_shape_is_the_cells():
+    """The mixer ``--ssm`` times is the benchmark configuration's, between
+    projections of another hidden size."""
+    import json
+    import os
+
+    from llm_sharding_tpu.models.config import ModelConfig, jamba2_3b_keys
+
+    with open(os.path.join(chip_smoke.HERE, "benchmark", "configs",
+                           "jamba2_3b.json")) as f:
+        cfg = json.load(f)
+    toy = ModelConfig.from_hf_config(jamba2_3b_keys(**chip_smoke.MIXER_KEYS))
+    assert toy.ssm_inner == cfg["mamba_expand"] * cfg["hidden_size"] == 5120
+    assert (toy.ssm_dt_rank, toy.ssm_state_size, toy.conv_kernel) == (
+        cfg["mamba_dt_rank"], cfg["mamba_d_state"], cfg["mamba_d_conv"])
+    period, offset = cfg["attn_layer_period"], cfg["attn_layer_offset"]
+    assert chip_smoke.MIXER_LAYERS == sum(
+        l % period != offset for l in range(cfg["num_hidden_layers"]))
+    assert chip_smoke.MIXER_ROWS == cfg["serve"]["batch_per_slot"]

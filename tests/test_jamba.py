@@ -221,6 +221,249 @@ def test_a_dead_rows_state_is_untouched_by_a_decode_step(params, backend):
     assert np.array_equal(np.asarray(h1)[[0, 2]], np.asarray(h)[[0, 2]])
 
 
+# ---- a decode step's mixer as ONE kernel (``ssm.mixer_step_rows``) ----------
+
+def whole_leaves(stack):
+    from llm_sharding_tpu.models.stack import MAMBA1_WHOLE_KEYS
+
+    return {k: stack[k] for k in MAMBA1_WHOLE_KEYS}
+
+
+def split_step(stack, l, row0, live, s_all, c_all, xz_h):
+    """The oracle: ``_mixer_in`` and ``ssm.scan_step`` over the slot's rows,
+    each array of their own — ``(y, state, tail)`` of the slot."""
+    B = live.shape[0]
+    p = jax.tree.map(lambda a: a[l], stack)
+    x, z, dt, A, Bm, Cm, tail = jamba._mixer_in(
+        CFG, p, xz_h, c_all[l, row0:row0 + B], live[:, None]
+    )
+    s = s_all[l, row0:row0 + B]
+    y, s1 = ssm.scan_step(
+        s.reshape(B, CFG.ssm_state_size, -1), x[:, 0], dt[:, 0], z[:, 0], A,
+        Bm[:, 0], Cm[:, 0], p["D"],
+    )
+    return jnp.where(live[:, None], y, 0.0), s1.reshape(s.shape), tail
+
+
+def fused_step(stack, l, row0, order, n_live, s_all, c_all, h, tiles=None):
+    """``ssm.mixer_step_rows``; with ``tiles``, the kernel at that tiling."""
+    p = jax.tree.map(lambda a: a[l], stack)
+    xz = jamba._w_in(CFG, p, h)[:, 0]
+    args = (
+        s_all, c_all, (jnp.int32(l), jnp.int32(row0)), jnp.asarray(order),
+        jnp.int32(n_live), xz, whole_leaves(stack),
+    )
+    if tiles is None:
+        return ssm.mixer_step_rows(
+            *args, jamba._decay(p), CFG.rms_norm_eps, backend="interpret"
+        )
+    return ssm.mixer_step_tpu(
+        *args, jamba._decay(p).reshape(CFG.recurrent_shapes["ssm"]),
+        eps=CFG.rms_norm_eps, tiles=tiles, interpret=True,
+    )
+
+
+def carried(seed, rows=12):
+    stack_layers = CFG.layer_kinds.count("mamba")
+    k = jax.random.split(jax.random.key(seed), 3)
+    rec = zero_recurrent(CFG, stack_layers, rows)
+    return (
+        jax.random.normal(k[0], rec["ssm"].shape),
+        jax.random.normal(k[1], rec["conv"].shape),
+        jax.random.normal(k[2], (4, 1, CFG.hidden_size)),
+    )
+
+
+def check_fused_step(stack, live, order, n_live, **kw):
+    """Layer 3 of the stack's 5, rows 4-7 of 12: the fused call against the
+    oracle, and everything it must not touch bit for bit."""
+    l, row0 = 3, 4
+    s0, c0, h = carried(21)
+    counted = jnp.asarray(live, bool) & (n_live > 0)
+    y_w, s_w, c_w = split_step(stack, l, row0, counted, s0, c0, h)
+    y, s1, c1 = fused_step(stack, l, row0, order, n_live, s0, c0, h, **kw)
+    at = np.asarray(counted)
+    assert np.abs(np.asarray(y) - np.asarray(y_w)).max() < 2e-5
+    assert np.array_equal(np.asarray(y)[~at], np.zeros_like(y)[~at])
+    slot_s, slot_c = np.asarray(s1[l, row0:row0 + 4]), np.asarray(c1[l, row0:row0 + 4])
+    assert np.abs(slot_s[at] - np.asarray(s_w)[at]).max(initial=0) < 2e-6
+    assert np.array_equal(slot_c[at], np.asarray(c_w)[at])
+    still = np.ones(s0.shape[:2], bool)
+    still[l, row0:row0 + 4] = ~at
+    assert np.array_equal(np.asarray(s1)[still], np.asarray(s0)[still])
+    assert np.array_equal(np.asarray(c1)[still], np.asarray(c0)[still])
+    if at.any():
+        assert np.abs(slot_s[at] - np.asarray(s0[l, row0:row0 + 4])[at]).max() > 1e-3
+
+
+@pytest.mark.parametrize("live, order, n_live", [
+    ([0, 0, 0, 0], [0, 1, 2, 3], 0),
+    ([0, 0, 1, 0], [2, 0, 1, 3], 1),
+    ([1, 0, 0, 1], [3, 0, 2, 1], 2),
+    ([1, 0, 0, 1], [0, 3, 1, 2], 2),
+    ([1, 1, 1, 1], [2, 0, 3, 1], 4),
+    ([1, 1, 1, 1], [2, 0, 3, 1], 0),  # a masked layer: live rows, none counted
+])
+def test_the_fused_mixer_step_is_the_split_steps(params, live, order, n_live):
+    """``ssm.mixer_step_rows`` (interpreted) against ``_mixer_in`` +
+    ``ssm.scan_step``: 0, 1, 2 and 4 live rows of a slot of 4 in a shuffled
+    ``order``, a first row that is not 0, a layer that is not the stack's
+    first, a masked layer; a dead row's state AND tail, every other layer and
+    slot bit for bit what they were; a dead row's ``y`` zero."""
+    check_fused_step(params["layers"]["mamba"], live, order, n_live)
+
+
+@pytest.mark.parametrize("tiles", [1, 2, 4, 8])
+def test_the_fused_mixer_step_at_every_channel_tiling(params, tiles):
+    """The two phases over 1, 2, 4 and 8 channel tiles a row: the same step
+    (``w_x`` reduces over all channels whatever the tiling)."""
+    check_fused_step(
+        params["layers"]["mamba"], [1, 0, 1, 1], [3, 0, 2, 1], 3, tiles=tiles
+    )
+
+
+def test_sixty_four_chained_fused_steps_hold_the_tolerance(params):
+    """64 decode steps of one layer, the state and the tail carried by each
+    side for itself: ``y`` of every step and the state at the end within the
+    float32 logits tolerance (sound ~1e-5)."""
+    stack = params["layers"]["mamba"]
+    l, row0 = 2, 4
+    live = jnp.array([True, False, True, True])
+    order, n_live = jamba.live_rows(live[:, None])
+    s_f, c_f, _ = carried(22)
+    s_w, c_w = s_f, c_f
+    fused = jax.jit(lambda s, c, h: fused_step(
+        stack, l, row0, order, n_live, s, c, h))
+
+    @jax.jit
+    def split(s_all, c_all, h):
+        y, s, c = split_step(stack, l, row0, live, s_all, c_all, h)
+        sel = live[:, None, None]
+        return (
+            y,
+            s_all.at[l, row0:row0 + 4].set(
+                jnp.where(sel[..., None], s, s_all[l, row0:row0 + 4])),
+            c_all.at[l, row0:row0 + 4].set(c),
+        )
+
+    worst = 0.0
+    for t in range(64):
+        h = jax.random.normal(jax.random.key(100 + t), (4, 1, CFG.hidden_size))
+        y_f, s_f, c_f = fused(s_f, c_f, h)
+        y_w, s_w, c_w = split(s_w, c_w, h)
+        worst = max(worst, float(jnp.abs(y_f - y_w).max()))
+    assert worst < TOL
+    assert float(jnp.abs(s_f - s_w).max()) < TOL
+    assert np.array_equal(np.asarray(c_f), np.asarray(c_w))
+    assert float(jnp.abs(s_f[l, row0] - carried(22)[0][l, row0]).max()) > 1e-2
+
+
+def whole_layer(stack, l):
+    """A layer's leaves as the serve programs' scan hands them."""
+    from llm_sharding_tpu.models.stack import join_whole, split_whole
+
+    scanned, whole = split_whole(stack)
+    return join_whole(jax.tree.map(lambda a: a[l], scanned), whole, jnp.int32(l))
+
+
+def test_a_dead_rows_state_and_tail_are_untouched_by_the_fused_step(params):
+    """``mixer_block`` with the leaves handed whole, interpreted: the fused
+    form is taken, it is ``mamba_mixer``'s step, and rows 0 and 2 of the slot
+    — their state AND their conv tails — and every other layer and slot
+    come back bit for bit."""
+    stack = params["layers"]["mamba"]
+    s0, c0, h = carried(23, rows=6)
+    p = whole_layer(stack, 1)
+    assert jamba.mixer_step_fused(CFG, p, "interpret")
+    assert not jamba.mixer_step_fused(CFG, p, "xla")
+    live = jnp.array([False, True, False, True])[:, None]
+    at = (jnp.int32(1), jnp.int32(2))
+    h1, s1, c1 = jamba.mixer_block(CFG, p, h, s0, c0, at, live,
+                                   backend="interpret")
+    h2, s2, c2 = jamba.mixer_block(
+        CFG, jax.tree.map(lambda a: a[1], stack), h, s0, c0, at, live,
+        backend="interpret",
+    )
+    changed = np.zeros(s0.shape[:2], bool)
+    changed[1, [3, 5]] = True
+    for got, was in ((s1, s0), (c1, c0)):
+        got, was = np.asarray(got), np.asarray(was)
+        assert np.array_equal(got[~changed], was[~changed])
+        assert np.abs(got[changed] - was[changed]).max() > 1e-3
+    assert np.array_equal(np.asarray(h1)[[0, 2]], np.asarray(h)[[0, 2]])
+    assert np.abs(np.asarray(h1) - np.asarray(h2)).max() < 2e-5
+    assert np.abs(np.asarray(s1) - np.asarray(s2)).max() < 2e-6
+    assert np.array_equal(np.asarray(c1), np.asarray(c2))
+
+
+@pytest.mark.parametrize("what", ["quantised", "shape", "xla", "sliced"])
+def test_what_the_fused_step_cannot_take_falls_back(params, what, monkeypatch):
+    """The form is chosen from what the code can see: int8 ``w_x`` / ``w_dt``,
+    a shape Mosaic cannot tile under the compiled kernel, the XLA backend and
+    leaves that come as a layer's slices each take the split path — and the
+    quantised stack still runs through ``mixer_block``."""
+    from llm_sharding_tpu.ops.quant import quantize_params
+
+    stack = params["layers"]["mamba"]
+    if what == "quantised":
+        q = quantize_params({"layers": {"mamba": stack}})["layers"]["mamba"]
+        assert not isinstance(q["w_x"], jax.Array)
+        p = whole_layer(q, 1)
+        assert ssm.mixer_step_path("interpret", CFG, q) == "split"
+        assert not jamba.mixer_step_fused(CFG, p, "interpret")
+        s0, c0, h = carried(24, rows=4)
+        live = jnp.ones((4, 1), bool)
+        at = (jnp.int32(1), jnp.int32(0))
+        h1, s1, c1 = jamba.mixer_block(CFG, p, h, s0, c0, at, live,
+                                       backend="interpret")
+        # ... as the same leaves do when they come as the layer's slices
+        h2, s2, c2 = jamba.mixer_block(
+            CFG, jax.tree.map(lambda a: a[1], q), h, s0, c0, at, live,
+            backend="interpret",
+        )
+        for got, want in ((h1, h2), (s1, s2), (c1, c2)):
+            assert np.abs(np.asarray(got) - np.asarray(want)).max() < 1e-5
+        assert np.abs(np.asarray(s1) - np.asarray(s0)).max() > 1e-3
+    elif what == "shape":
+        monkeypatch.setattr(ssm.jax, "default_backend", lambda: "tpu")
+        assert ssm.mixer_step_path("kernel", CFG) == "split"
+        assert ssm.mixer_step_path("kernel", jamba2_3b()) == "fused"
+        assert not ssm.mixer_eligible(16, 8, 640, 4)
+    elif what == "xla":
+        assert ssm.mixer_step_path("xla", CFG) == "split"
+        assert ssm.mixer_step_path("xla", jamba2_3b()) == "split"
+        assert ssm.mixer_step_path("interpret", CFG) == "fused"
+    else:
+        p = jax.tree.map(lambda a: a[1], stack)
+        assert not jamba.mixer_step_fused(CFG, p, "interpret")
+
+
+def test_only_a_mamba_1_stack_hands_its_mixer_leaves_whole(params):
+    """The whole-leaf rule is keyed by ``w_x``: Jamba's mixer stack is split
+    (the nine leaves the kernel reads through the layer index), its attention
+    stack is not, and ``nemotron_h``'s Mamba-2 stack — which HAS leaves called
+    ``conv_w``, ``conv_b``, ``A_log``, ``D`` — comes back as it is."""
+    from llm_sharding_tpu.models import nemotron_h
+    from llm_sharding_tpu.models.config import tiny_nemotron_h
+    from llm_sharding_tpu.models.stack import MAMBA1_WHOLE_KEYS, split_whole
+
+    scanned, whole = split_whole(params["layers"]["mamba"])
+    assert set(whole) == set(MAMBA1_WHOLE_KEYS)
+    assert not set(scanned) & set(whole)
+    assert {"w_in", "w_out", "A_log", "norm", "w_gate"} <= set(scanned)
+    attn = params["layers"]["attn"]
+    assert split_whole(attn) == (attn, None)
+    cfg2 = tiny_nemotron_h()
+    for kind, stack in nemotron_h.init_layer_params(
+            cfg2, jax.random.key(0), 2, jnp.float32).items():
+        scanned, whole = split_whole(stack)
+        if kind == "mamba":
+            assert {"conv_w", "conv_b", "A_log", "D"} <= set(stack)
+            assert scanned is stack and whole is None
+        else:
+            assert not set(whole or ()) & set(MAMBA1_WHOLE_KEYS)
+
+
 def test_the_parameter_count_at_the_published_widths():
     """3.03 B from the layer equations: 26 mixer layers of 104.16 M (the
     mixer and its norm 41.24 M), 2 attention layers of 76.68 M (13.77 M), the

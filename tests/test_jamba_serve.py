@@ -98,6 +98,47 @@ def test_the_xla_path_serves_the_same_logits(params):
         assert served_logit_gaps(params, r).max() < 3e-4
 
 
+def test_the_fused_and_the_split_mixer_step_serve_the_same_tokens(
+        params, monkeypatch):
+    """A server whose kernels are interpreted takes the FUSED decode step of
+    a mixer (one call a layer between the projections, the conv's tail in
+    its pass), the XLA server the SPLIT one: the same prompts — a slot of two
+    rows of unlike lengths, a row joining later — reply with the same tokens,
+    and ``server_recurrent_mixer_step`` is one-hot for each while it lives."""
+    from llm_sharding_tpu.runtime.server import _update_load_gauges
+
+    def gauge():
+        _update_load_gauges()
+        return {p: metrics.RECURRENT_MIXER_STEP.labels(path=p).value
+                for p in metrics.RECURRENT_MIXER_STEPS}
+
+    def serve(want, **kw):
+        before = gauge()
+        srv = engine(params).serve(**kw, **PAGED)
+        assert srv.recurrent_mixer_step == want
+        now = gauge()
+        assert {p: now[p] - before[p] for p in now} == {
+            p: float(p == want) for p in now}
+        assert f'server_recurrent_mixer_step{{path="{want}"}}' in (
+            metrics.REGISTRY.prometheus_text())
+        rng = np.random.default_rng(6)
+        reqs = [srv.submit(rng.integers(0, 250, size=n).astype(np.int32), 20)
+                for n in (7, 21)]
+        for _ in range(6):
+            srv.step()
+        reqs.append(srv.submit(rng.integers(0, 250, size=18).astype(np.int32), 20))
+        srv.run_until_idle()
+        srv.close()
+        assert gauge() == before
+        return [list(r.tokens) for r in reqs]
+
+    split = serve("split", paged_attn="xla")
+    monkeypatch.setenv("PAGED_FORCE_KERNEL", "interpret")
+    fused = serve("fused")
+    assert all(len(t) == 20 for t in fused)
+    assert fused == split
+
+
 def test_a_reused_row_starts_from_zero(params):
     """One row: the second request decodes in the row the first left its
     state in, and reads what a fresh server gives it."""
@@ -327,6 +368,8 @@ def test_the_counters_and_the_metrics_rows(params, monkeypatch):
     assert ssm.rows_backend("kernel", jamba2_3b()) == "kernel"
     assert ssm.scan_path("kernel", jamba2_3b()) == "kernel"
     assert ssm.scan_path("interpret", CFG) == "interpret"
+    assert ssm.mixer_step_path("kernel", jamba2_3b()) == "fused"
+    assert ssm.mixer_step_path("kernel", CFG) == "split"
     assert 26 * jamba2_3b().recurrent_row_bytes == 26 * 389_120
 
 
