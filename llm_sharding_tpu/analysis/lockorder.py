@@ -66,10 +66,7 @@ ORDER: Tuple[str, ...] = (
     "ingress.state",          # IngressServer._mutex: live-set + counters
     "autoscale.controller",   # tick state; holds while spawn/drain/rebal
     "replica.router",         # ReplicatedServer._lock (RLock)
-    "server.prefetcher",      # _Prefetcher singleton construction
     "server.mutex",           # PipelineServer._mutex (RLock): step state
-    "server.scheduler",       # async-exec scheduler kick/delta condition
-    "server.exec_sidecar",    # async-exec completion-sidecar wake condition
     "disagg.handoff",         # sidecar rendezvous condition (counters only)
     "cluster.index",          # global radix index map (publish/lookup)
     "engine.reconfig",        # PipelineEngine._lock: placement swap vs use

@@ -19,7 +19,6 @@ those five entry points into subcommands:
 - ``profile``  — capability sweeps, hop latency, artifacts + an optional
   capability-weighted placement suggestion (≙ ``profiling.py``; closes the
   profiler→scheduler loop of the reference's README)
-- ``bench``    — the repo benchmark (one JSON line)
 
 Placements: ``--stages N`` for a balanced split or ``--ranges 0:6,6:7,7:32``
 for the reference-style ragged chains (``send_config.py:10-34``).
@@ -28,6 +27,7 @@ for the reference-style ragged chains (``send_config.py:10-34``).
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import logging
 import os
@@ -181,6 +181,50 @@ def cmd_generate(args) -> int:
     return 0
 
 
+#: the serve flags whose ``dest`` is not the name of the option they set
+#: (``runtime/options.ServeOptions``)
+_FLAG_OF = {
+    "default_deadline_s": "default_deadline",
+    "snapshot_every_s": "snapshot_every",
+    "snapshot_path": "snapshot_dir",
+    "gauge_sweep_every_s": "gauge_sweep_every",
+}
+
+
+def _serve_flags():
+    """(option name, its flag's ``dest``) for every serve option."""
+    from .runtime.options import ServeOptions
+
+    return [(n, _FLAG_OF.get(n, n)) for n in ServeOptions.names()]
+
+
+def _serve_options(args):
+    """The ``ServeOptions`` record of the serve flags. An option that
+    defaults to None has a flag on which 0 (or nothing) means unset; an
+    option without a flag keeps its default."""
+    from .runtime.options import ServeOptions
+
+    defaults = ServeOptions()
+    kw = {}
+    for name, flag in _serve_flags():
+        if hasattr(args, flag):
+            got = getattr(args, flag)
+            kw[name] = (got or None) if getattr(defaults, name) is None else got
+    return ServeOptions(**kw)
+
+
+def _flag_error(e: ValueError) -> str:
+    """A refusal of ``ServeOptions.validate`` as the daemon prints it: the
+    record's own words, then the flags of the options they name."""
+    import re
+
+    named = [
+        "--" + flag.replace("_", "-") for name, flag in _serve_flags()
+        if re.search(rf"\b{name}\b", str(e))
+    ]
+    return f"error: {e} (flags: {', '.join(named)})"
+
+
 def _serve_control(eng, srv, line: str, args):
     """Daemon control lines (≙ the reference's hot config push checked every
     loop iteration, ``/root/reference/utils/node_worker.py:445-474`` — there
@@ -278,42 +322,14 @@ def _serve_control(eng, srv, line: str, args):
             print(f"bad placement: {e}", file=sys.stderr)
             return srv
         def build():
-            # every serve kwarg reads the LIVE server, not args: a
-            # --restore'd daemon's config came from the snapshot and may
-            # not be on the command line at all — re-sharding must not
-            # silently reset capacity/speculation/paged mode to the
-            # argparse defaults. (trace_path stays args-sourced: an ops
-            # knob the live server only holds as an opened writer.)
-            return eng.serve(
-                capacity=srv.capacity,
-                batch_per_slot=srv.batch_per_slot,
-                chunk_cycles=srv.chunk_cycles,
-                prefill_chunk=srv.prefill_chunk,
-                pipeline_depth=srv.pipeline_depth,
-                inflight_steps=srv.inflight_steps,
-                top_k=srv.top_k,
-                top_p=srv.top_p,
-                trace_path=getattr(args, "trace_path", None),
-                speculate=srv.speculate,
-                spec_ngram=srv.spec_ngram,
-                max_queue=srv.max_queue,
-                default_deadline_s=srv.default_deadline_s,
-                snapshot_every_s=srv._snapshot_every_s,
-                snapshot_path=srv._snapshot_path,
-                kv_block_size=srv.kv_block_size,
-                kv_blocks=srv.kv_blocks,
-                kv_dtype=srv.kv_dtype,
-                paged_attn=srv.paged_attn,
-                prefix_cache=srv.prefix_cache,
-                host_pool_blocks=(
-                    srv.host_pool_blocks
-                    if srv.prefix_cache in ("host", "disk") else 0
-                ),
-                disk_pool_dir=srv.disk_pool_dir,
-                disk_pool_blocks=srv.disk_pool_blocks,
-                gauge_sweep_every_s=srv.gauge_sweep_every_s,
-                cp=srv.cp,
-            )
+            # the LIVE server's record, not args: a --restore'd daemon's
+            # options came from the snapshot and may not be on the command
+            # line at all — re-sharding must not silently reset capacity/
+            # speculation/paged mode to the argparse defaults. (trace_path
+            # stays args-sourced: the revived daemon's record has none.)
+            return eng.serve(**vars(dataclasses.replace(
+                srv.options, trace_path=getattr(args, "trace_path", None)
+            )))
 
         try:
             new_srv = build()
@@ -444,110 +460,25 @@ def cmd_serve(args) -> int:
     from .runtime.server import QueueFull, RequestFailed, ServerClosed
     from .utils.device_report import device_report
 
-    # fail the flag mismatch in milliseconds, not after minutes of model
-    # loading (PipelineServer validates the same pairing, but only once the
-    # engine is up)
-    if bool(args.snapshot_every) != bool(args.snapshot_dir):
-        print(
-            "error: --snapshot-every and --snapshot-dir go together "
-            f"(got --snapshot-every {args.snapshot_every or 0}, "
-            f"--snapshot-dir {args.snapshot_dir!r})",
-            file=sys.stderr,
-        )
+    # fail an inconsistent set of flags in milliseconds, not after minutes
+    # of model loading (PipelineServer makes the same call, but only once
+    # the engine is up)
+    try:
+        options = _serve_options(args)
+        options.validate()
+    except ValueError as e:
+        print(_flag_error(e), file=sys.stderr)
         return 2
-    if bool(args.kv_block_size) != bool(args.kv_blocks):
-        print(
-            "error: --kv-block-size and --kv-blocks go together "
-            f"(got --kv-block-size {args.kv_block_size or 0}, "
-            f"--kv-blocks {args.kv_blocks or 0})",
-            file=sys.stderr,
-        )
-        return 2
-    if getattr(args, "inflight_steps", 1) < 1:
-        # same fast-fail-before-model-load pattern: PipelineServer validates
-        # this too, but only after minutes of checkpoint loading
-        print(
-            f"error: --inflight-steps must be >= 1, got "
-            f"{args.inflight_steps}",
-            file=sys.stderr,
-        )
-        return 2
-    if getattr(args, "paged_attn", "auto") != "auto" and not args.kv_block_size:
-        # same fast-fail-before-model-load pattern as the kv flag pairing
-        print(
-            f"error: --paged-attn {args.paged_attn} needs paged KV serving "
-            "(--kv-block-size/--kv-blocks); dense decode has no block "
-            "tables to stream",
-            file=sys.stderr,
-        )
-        return 2
-    if getattr(args, "kv_dtype", "bf16") != "bf16" and not args.kv_block_size:
-        print(
-            f"error: --kv-dtype {args.kv_dtype} needs paged KV serving "
-            "(--kv-block-size/--kv-blocks); quantization scales live per "
-            "arena block",
-            file=sys.stderr,
-        )
-        return 2
-    if getattr(args, "prefix_cache", "off") != "off" and not args.kv_block_size:
-        print(
-            f"error: --prefix-cache {args.prefix_cache} needs paged KV "
-            "serving (--kv-block-size/--kv-blocks); the cache shares "
-            "refcounted arena blocks",
-            file=sys.stderr,
-        )
-        return 2
-    if getattr(args, "host_pool_blocks", 0) and getattr(
-        args, "prefix_cache", "off"
-    ) not in ("host", "disk"):
-        print(
-            "error: --host-pool-blocks sizes the host-RAM tier — it needs "
-            f"--prefix-cache host or disk (got --prefix-cache "
-            f"{getattr(args, 'prefix_cache', 'off')})",
-            file=sys.stderr,
-        )
-        return 2
-    if getattr(args, "prefix_cache", "off") == "disk" and not getattr(
-        args, "disk_pool_dir", None
+    if options.cp > 1 and (
+        getattr(args, "tensor_parallel", 1) > 1 or options.speculate
     ):
+        # what the engine and the server refuse as not implemented
         print(
-            "error: --prefix-cache disk needs --disk-pool-dir (the on-disk "
-            "KV pool is the persistent artifact — it must have a home)",
+            "error: --cp with --tensor-parallel or --speculate is not "
+            "supported yet",
             file=sys.stderr,
         )
         return 2
-    if (
-        getattr(args, "disk_pool_dir", None)
-        or getattr(args, "disk_pool_blocks", 0)
-    ) and getattr(args, "prefix_cache", "off") != "disk":
-        print(
-            "error: --disk-pool-dir/--disk-pool-blocks configure the disk "
-            "KV tier — they need --prefix-cache disk (got --prefix-cache "
-            f"{getattr(args, 'prefix_cache', 'off')})",
-            file=sys.stderr,
-        )
-        return 2
-    if getattr(args, "cp", 1) > 1:
-        # same fast-fail-before-model-load pattern: PipelineServer and
-        # PipelineEngine.serve validate all of these too, but only after
-        # minutes of checkpoint loading
-        cp_bad = None
-        if not args.kv_block_size:
-            cp_bad = ("--cp needs paged KV serving "
-                      "(--kv-block-size/--kv-blocks): context parallelism "
-                      "shards the paged arena")
-        elif getattr(args, "tensor_parallel", 1) > 1:
-            cp_bad = "--cp with --tensor-parallel is not supported yet"
-        elif getattr(args, "speculate", 0):
-            cp_bad = "--cp with --speculate is not supported yet"
-        elif (getattr(args, "prefix_cache", "off") != "off"
-              and not args.prefill_chunk):
-            cp_bad = ("--cp with --prefix-cache needs --prefill-chunk: "
-                      "radix hits admit through the chunked ring-prefill "
-                      "path under context parallelism")
-        if cp_bad:
-            print(f"error: {cp_bad}", file=sys.stderr)
-            return 2
     if getattr(args, "tenants_config", None) and not getattr(
         args, "http_port", 0
     ):
@@ -587,7 +518,7 @@ def cmd_serve(args) -> int:
                 file=sys.stderr,
             )
             return 2
-        if not args.kv_block_size:
+        if not options.paged:
             print(
                 "error: --disagg needs paged KV serving "
                 "(--kv-block-size/--kv-blocks): the hand-off engine "
@@ -595,7 +526,7 @@ def cmd_serve(args) -> int:
                 file=sys.stderr,
             )
             return 2
-        if getattr(args, "prefix_cache", "off") == "off":
+        if options.prefix_cache == "off":
             print(
                 "error: --disagg needs --prefix-cache hbm, host or disk: the "
                 "hand-off lands streamed KV in the decode replica's radix "
@@ -707,33 +638,10 @@ def cmd_serve(args) -> int:
             tensor_parallel=getattr(args, "tensor_parallel", 1),
             placement=placement,
             tokenizer=shard_store.load_tokenizer(args.shards),
-            capacity=args.capacity,
-            batch_per_slot=args.batch_per_slot,
-            prefill_chunk=args.prefill_chunk,
-            top_k=args.top_k,
-            top_p=args.top_p,
-            trace_path=args.trace_path,
-            speculate=args.speculate,
-            spec_ngram=args.spec_ngram,
-            inflight_steps=getattr(args, "inflight_steps", 1),
-            max_queue=args.max_queue or None,
-            default_deadline_s=args.default_deadline or None,
-            snapshot_every_s=args.snapshot_every or None,
-            snapshot_path=args.snapshot_dir,
-            kv_block_size=args.kv_block_size or None,
-            kv_blocks=args.kv_blocks or None,
-            kv_dtype=getattr(args, "kv_dtype", "bf16"),
-            paged_attn=getattr(args, "paged_attn", "auto"),
-            prefix_cache=getattr(args, "prefix_cache", "off"),
-            host_pool_blocks=getattr(args, "host_pool_blocks", 0),
-            disk_pool_dir=getattr(args, "disk_pool_dir", None),
-            disk_pool_blocks=getattr(args, "disk_pool_blocks", 0),
-            gauge_sweep_every_s=getattr(args, "gauge_sweep_every", 0.0),
             min_replicas=getattr(args, "min_replicas", 1),
-            # context-parallel replicas: each replica's paged arena is
-            # sharded over cp chips of its own device group (dp × cp ×
-            # stages total)
-            cp=getattr(args, "cp", 1),
+            # (--cp: each replica's paged arena is sharded over cp chips of
+            # its own device group — dp × cp × stages in all)
+            **vars(options),
         )
         eng = srv.engines[0]
         extra = ""
@@ -786,45 +694,13 @@ def cmd_serve(args) -> int:
             # say so explicitly instead of silently ignoring them (the old
             # banner printed the CLI --capacity while the daemon actually
             # ran at the snapshot's; ADVICE r5)
+            flag_of = dict(_serve_flags())
             ignored = [
-                f"--{flag.replace('_', '-')} {got} (snapshot: {used})"
-                for flag, got, used in (
-                    ("capacity", args.capacity, srv.capacity),
-                    ("batch_per_slot", args.batch_per_slot,
-                     srv.batch_per_slot),
-                    ("prefill_chunk", args.prefill_chunk, srv.prefill_chunk),
-                    ("top_k", args.top_k, srv.top_k),
-                    ("top_p", args.top_p, srv.top_p),
-                    ("speculate", getattr(args, "speculate", 0),
-                     srv.speculate),
-                    ("spec_ngram", getattr(args, "spec_ngram", 3),
-                     srv.spec_ngram),
-                    ("inflight_steps", getattr(args, "inflight_steps", 1),
-                     srv.inflight_steps),
-                    ("max_queue", args.max_queue or None, srv.max_queue),
-                    ("default_deadline", args.default_deadline or None,
-                     srv.default_deadline_s),
-                    ("kv_block_size", args.kv_block_size or None,
-                     srv.kv_block_size),
-                    ("kv_blocks", args.kv_blocks or None, srv.kv_blocks),
-                    ("kv_dtype", getattr(args, "kv_dtype", "bf16"),
-                     srv.kv_dtype),
-                    ("paged_attn", getattr(args, "paged_attn", "auto"),
-                     srv.paged_attn),
-                    ("prefix_cache", getattr(args, "prefix_cache", "off"),
-                     srv.prefix_cache),
-                    ("host_pool_blocks",
-                     getattr(args, "host_pool_blocks", 0) or None,
-                     srv.host_pool_blocks or None),
-                    ("disk_pool_dir",
-                     getattr(args, "disk_pool_dir", None),
-                     srv.disk_pool_dir),
-                    ("disk_pool_blocks",
-                     getattr(args, "disk_pool_blocks", 0) or None,
-                     srv.disk_pool_blocks or None),
-                    ("cp", getattr(args, "cp", 1), srv.cp),
-                )
-                if got != used
+                f"--{flag_of[name].replace('_', '-')} "
+                f"{getattr(options, name)} (snapshot: {used})"
+                for name, used in srv.options.portable().items()
+                if hasattr(args, flag_of[name])
+                and getattr(options, name) != used
             ]
             if ignored:
                 print(
@@ -842,31 +718,7 @@ def cmd_serve(args) -> int:
                     print(t.decode(r.tokens, skip_special_tokens=True),
                           flush=True)
         else:
-            srv = eng.serve(
-                capacity=args.capacity,
-                batch_per_slot=args.batch_per_slot,
-                prefill_chunk=args.prefill_chunk,
-                top_k=args.top_k,
-                top_p=args.top_p,
-                trace_path=args.trace_path,
-                speculate=args.speculate,
-                spec_ngram=args.spec_ngram,
-                inflight_steps=getattr(args, "inflight_steps", 1),
-                max_queue=args.max_queue or None,
-                default_deadline_s=args.default_deadline or None,
-                snapshot_every_s=args.snapshot_every or None,
-                snapshot_path=args.snapshot_dir,
-                kv_block_size=args.kv_block_size or None,
-                kv_blocks=args.kv_blocks or None,
-                kv_dtype=getattr(args, "kv_dtype", "bf16"),
-                paged_attn=getattr(args, "paged_attn", "auto"),
-                prefix_cache=getattr(args, "prefix_cache", "off"),
-                host_pool_blocks=getattr(args, "host_pool_blocks", 0),
-                disk_pool_dir=getattr(args, "disk_pool_dir", None),
-                disk_pool_blocks=getattr(args, "disk_pool_blocks", 0),
-                gauge_sweep_every_s=getattr(args, "gauge_sweep_every", 0.0),
-                cp=getattr(args, "cp", 1),
-            )
+            srv = eng.serve(**vars(options))
         # srv.capacity, not args.capacity: after --restore the daemon runs
         # at the SNAPSHOT's serve_kwargs (ADVICE r5 — the banner used to
         # claim the CLI value)
@@ -1458,18 +1310,6 @@ def cmd_lint(args) -> int:
     )
 
 
-def cmd_bench(args) -> int:
-    import importlib.util
-    import os
-
-    path = os.path.join(os.path.dirname(os.path.dirname(__file__)), "bench.py")
-    spec = importlib.util.spec_from_file_location("bench", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    mod.main()
-    return 0
-
-
 def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(
         prog="python -m llm_sharding_tpu",
@@ -1561,16 +1401,6 @@ def build_parser() -> argparse.ArgumentParser:
         help="longest n-gram the drafter matches against the request's "
         "prompt+generation suffix (with --speculate)",
     )
-    s.add_argument(
-        "--inflight-steps", type=int, default=1, dest="inflight_steps",
-        help="async executor depth (runtime/async_exec.py): keep up to N "
-        "decode dispatches enqueued on device while an off-thread "
-        "scheduler plans admissions/evictions and a completion sidecar "
-        "applies landed tokens — the host-side step bubble overlaps "
-        "device compute. Greedy output stays token-identical at any "
-        "depth; tokens surface up to N chunks late. 1 (default) is the "
-        "historical serial step loop and the rollback",
-    )
     s.add_argument("--dtype", default="bf16")
     s.add_argument("--temperature", type=float, default=0.0)
     s.add_argument(
@@ -1623,7 +1453,7 @@ def build_parser() -> argparse.ArgumentParser:
         "per-block DMA loop — ~2x the arena blocks at equal HBM (and 2x "
         "the radix/host-tier capacity) and half the decode-attention "
         "bandwidth, at a small bounded greedy-token drift (gate rollouts "
-        "on bench's kv-quant token-match fraction; bf16 stays default). "
+        "on the benchmark's correctness check; bf16 stays default). "
         "int8 with --paged-attn kernel wants --kv-block-size a multiple "
         "of 32 (1-byte Mosaic sublane)",
     )
@@ -1901,9 +1731,6 @@ def build_parser() -> argparse.ArgumentParser:
         help="emit a capability-weighted placement for N stages",
     )
     pr.set_defaults(fn=cmd_profile)
-
-    b = sub.add_parser("bench", help="repo benchmark (one JSON line)")
-    b.set_defaults(fn=cmd_bench)
 
     tr = sub.add_parser(
         "trace-report",

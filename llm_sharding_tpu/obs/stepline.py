@@ -1,16 +1,13 @@
 """Continuous step profiler: the serving loop's host–device overlap ledger.
 
-ROADMAP item 2 (the async executor that kills the host-side bubble) needs a
-measurement layer that proves the bubble exists and sizes it per phase
-BEFORE the refactor — the role the reference repo's fitted per-device
-latency models play for placement. This module is that layer:
+The measurement layer that says whether a host-side bubble exists and sizes
+it per phase — the role the reference repo's fitted per-device latency
+models play for placement:
 
 - The step pump records one :class:`StepRecord` per serve-loop step into a
   bounded ring: per-phase host durations (``admit`` / ``radix_plan`` /
-  ``table_push`` / ``dispatch`` / ``fetch`` / ``apply`` / ``gauge_sweep``,
-  plus the async executor's ``publish`` / ``drain`` and the overlapped
-  ``plan`` — finer than the old three-bucket histogram), time *blocked on
-  device*
+  ``table_push`` / ``dispatch`` / ``fetch`` / ``apply`` / ``gauge_sweep``
+  — finer than the old three-bucket histogram), time *blocked on device*
   (the log-fetch materialization wait, measured separately from host
   compute), the estimated device-idle bubble, rows in flight, tokens
   applied, and queue depths.
@@ -36,16 +33,9 @@ The builder API (``begin_step``/``push``/``pop``/``blocked``/``idle``/
 ``end_step``) is single-threaded by construction — only the step pump calls
 it — so builder state is unlocked; only the ring itself takes a lock
 (``obs.stepline.ring``), and gauge/histogram feeds happen outside it. The
-async executor's helper threads (scheduler, completion sidecar) must NOT
-touch the builder: work that overlaps the pump's wall clock is reported
-through :meth:`StepProfiler.observe_offthread`, which feeds the phase
-histogram only and deliberately stays out of :class:`StepRecord` — folding
-overlapped time into a step's phases would break the accounting invariant
-below. With ``inflight_steps > 1`` the device-idle estimate (``idle``)
-still keys off the NEWEST in-flight chunk's ``done_at``: if even the
-newest of the overlapped dispatches has already landed before the next
-dispatch, the device queue truly drained and the gap is a bubble; if any
-older entry is still in flight the device is busy and no idle is charged.
+device-idle estimate (``idle``) keys off the NEWEST in-flight chunk's
+``done_at``: if it has already landed before the next dispatch, the device
+queue truly drained and the gap is a bubble.
 
 Profiler annotations: the same phase stack also writes into the JAX
 profiler's trace, on the profiler's clock, through an injected ``annotate``
@@ -88,13 +78,6 @@ PHASES = (
     "fetch",       # drain bookkeeping around the log fetch (host part)
     "apply",       # applying fetched token logs to requests
     "gauge_sweep", # load/KV/attn gauge sweep (pace via gauge_sweep_every_s)
-    # async-executor phases (inflight_steps > 1):
-    "plan",        # scheduler's off-thread planning (histogram-only: it
-                   # OVERLAPS executor wall, so it never enters StepRecord
-                   # phases — see observe_offthread)
-    "publish",     # executor consuming the scheduler's published delta
-    "drain",       # executor-inline settle/backpressure drain of in-flight
-                   # dispatches (the fetch/apply sub-phases nest inside)
 )
 
 _PHASE_SET = frozenset(PHASES)
@@ -157,10 +140,7 @@ STEP_PHASE = REGISTRY.histogram(
     "ingress drain + prefill admission), radix_plan (chunk planning), "
     "table_push (block-table push), dispatch (host-side chunk/spec "
     "dispatch), fetch (drain bookkeeping around the log fetch), apply "
-    "(token-log application), gauge_sweep (load/KV/attn gauge sweep), and "
-    "with the async executor (inflight_steps > 1): plan (scheduler's "
-    "overlapped off-thread planning; histogram-only), publish (delta "
-    "consumption), drain (executor-inline settle of in-flight dispatches)",
+    "(token-log application), gauge_sweep (load/KV/attn gauge sweep)",
     labels=("phase",),
 )
 STEP_WALL = REGISTRY.histogram(
@@ -691,19 +671,6 @@ class StepProfiler:
         if not self._enabled or self._t0 is None or dt <= 0.0:
             return
         self._idle_s += dt
-
-    def observe_offthread(self, phase: str, dt: float) -> None:
-        """Feed ``dt`` seconds into the phase histogram from a thread that
-        is NOT the step pump (scheduler plan, sidecar work). Histogram
-        observes are thread-safe; builder state is never touched, and the
-        sample stays out of StepRecord — off-thread work overlaps the
-        pump's wall, so folding it into a step's phases would break the
-        ``sum(phases) + blocked + unattributed == wall`` invariant."""
-        if not self._enabled or dt < 0.0:
-            return
-        if phase not in _PHASE_SET:
-            raise ValueError(f"unknown phase {phase!r}; one of {PHASES}")
-        _PHASE_CHILD[phase].observe(dt)
 
     def note_exemplar(self, trace_id: str) -> None:
         """Record an applied row's trace_id — deep-capture steps only."""

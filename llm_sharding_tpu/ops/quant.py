@@ -7,8 +7,7 @@ through bitsandbytes (``/root/reference/utils/model_sharder.py:28-45`` —
 TPU-native equivalent keeps weights as int8 arrays in HBM with
 per-output-channel scales and lets XLA fuse the int8→bf16 convert into the
 dot's operand load. Single-chip decode is weight-read bandwidth-bound, so
-halving weight bytes is a direct throughput lever (measured on v5e, 3B:
-see ``bench.py`` int8 metric).
+halving weight bytes is a direct throughput lever.
 
 Scheme: symmetric per-output-channel absmax. For a weight ``[.., in, out]``
 the scale is ``absmax(w, axis=in) / 127`` per ``out`` column (stacked layer
@@ -303,8 +302,8 @@ def is_quantized(layers: dict) -> bool:
 # a RUNNING absmax per block — when a new entry raises a block's scale,
 # the block's existing codes are requantized to the new scale (a
 # dequant→requant round on exactly the touched blocks). bf16 KV stays the
-# serving default; quantized is opt-in and drift-gated (see bench's
-# kv-quant token-match fraction).
+# serving default; quantized is opt-in and drift-gated (the benchmark's
+# ``correct``).
 
 #: ``--kv-dtype`` vocabulary. "bf16" means "store in the engine's compute
 #: cache dtype" (no quantization — the pre-existing exact path).

@@ -1477,15 +1477,14 @@ def serve_chunk(
     is admitted (one extra compile, then cached). ``filtering`` likewise
     compiles the top-k/top-p machinery in only when some request uses it.
 
-    MULTI-DISPATCH CONTRACT (the async executor's load-bearing property,
-    runtime/async_exec.py): ``state`` is donated and the chunk is fully
-    self-contained — everything the next chunk needs is in the returned
-    ``ServeState`` handle, nothing depends on the host having read ``log``.
-    Chunk k+1 may therefore be dispatched off chunk k's returned handle
-    BEFORE k's log is fetched, to any depth: the dispatches serialize on
-    the device as one deterministic state chain, so the committed tokens
-    are identical whether the host fetches each log immediately (serial
-    step loop) or ``inflight_steps`` chunks later (async executor). The
+    MULTI-DISPATCH CONTRACT (what the step loop's ``pipeline_depth`` rests
+    on): ``state`` is donated and the chunk is fully self-contained —
+    everything the next chunk needs is in the returned ``ServeState``
+    handle, nothing depends on the host having read ``log``. Chunk k+1 may
+    therefore be dispatched off chunk k's returned handle BEFORE k's log is
+    fetched, to any depth: the dispatches serialize on the device as one
+    deterministic state chain, so the committed tokens are identical
+    however many chunks later the host fetches each log. The
     host block-table push (``_flush_tables``) needs only the PLANNED
     mirror deltas, never fetched tokens, so it keeps its place before
     each dispatch."""

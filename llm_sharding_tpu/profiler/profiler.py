@@ -597,7 +597,7 @@ def measure_hop_latency(
     chain minus a short chain, divided by the hop delta — dispatch overhead
     and the host↔device sync cost cancel. The sync itself FETCHES a few
     bytes of the result, so the clock stops only once execution provably
-    finished (see bench.py's kernel timing for the same discipline).
+    finished.
     ``n_hops`` is the short-chain length; the long chain is auto-scaled so
     the hop-work delta dwarfs sync jitter.
     """

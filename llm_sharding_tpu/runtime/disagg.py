@@ -561,11 +561,6 @@ class DisaggServer(ReplicatedServer):
                     )
                 return True
         try:
-            # with the async executor (inflight_steps>1) this extract
-            # SETTLES the healthy prefill replica's in-flight dispatches
-            # first (extract's settle=None default), so the hand-off
-            # always leaves from a settled boundary: the streamed KV and
-            # resumed prompt carry every token the device computed
             st = src.extract(req)
         except (ValueError, RuntimeError) as e:
             # raced a completion or a mid-admission state: retry next sweep

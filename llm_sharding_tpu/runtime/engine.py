@@ -562,42 +562,13 @@ class PipelineEngine:
             )
 
     @SETUP.wraps("setup.server", then=SETUP.server_built)
-    def serve(
-        self,
-        *,
-        capacity: int = 1024,
-        batch_per_slot: int = 1,
-        chunk_cycles: int = 1,
-        top_k: int = 0,
-        top_p: float = 1.0,
-        prefill_chunk: Optional[int] = None,
-        pipeline_depth: int = 1,
-        inflight_steps: int = 1,
-        trace_path: Optional[str] = None,
-        speculate: int = 0,
-        spec_ngram: int = 3,
-        max_queue: Optional[int] = None,
-        default_deadline_s: Optional[float] = None,
-        fault_plan=None,
-        fault_retries: int = 3,
-        fault_backoff_s: float = 0.01,
-        retryable_exceptions: tuple = (),
-        snapshot_every_s: Optional[float] = None,
-        snapshot_path: Optional[str] = None,
-        kv_block_size: Optional[int] = None,
-        kv_blocks: Optional[int] = None,
-        kv_dtype: str = "bf16",
-        paged_attn: str = "auto",
-        prefix_cache: str = "off",
-        host_pool_blocks: int = 0,
-        disk_pool_dir: Optional[str] = None,
-        disk_pool_blocks: int = 0,
-        gauge_sweep_every_s: float = 0.0,
-        cp: int = 1,
-    ):
+    def serve(self, **options):
         """Build a continuous-batching server over this engine's sharded
         arrays (≙ the reference's persistent ``run_worker_loop`` daemon,
-        ``node_worker.py:493-559``). See ``runtime/server.py``.
+        ``node_worker.py:493-559``). See ``runtime/server.py``. The keywords
+        are the fields of ``runtime/options.ServeOptions`` (names, defaults
+        and the checks that need no model are written there, once); an
+        unknown one is a ``TypeError``.
 
         Composes with tensor parallelism: a pp×tp engine serves with
         megatron-sharded stage fns and a heads-sharded KV state (the serve
@@ -641,14 +612,6 @@ class PipelineEngine:
         transient-retry policy, and ``snapshot_every_s=``+``snapshot_path=``
         arm periodic atomic crash-recovery checkpoints.
 
-        ``inflight_steps=N`` (N>1) turns on the ASYNC EXECUTOR
-        (``runtime/async_exec.py``): a scheduler/executor split that keeps
-        up to N decode dispatches enqueued on the device so the host-side
-        step overhead (log fetch, token apply, stream fan-out, admission
-        planning) overlaps device compute instead of serializing with it.
-        Greedy output stays token-identical at any depth; ``1`` (the
-        default) is the historical fully-serial path and the rollback.
-
         ``gauge_sweep_every_s=`` paces the per-step load/KV/attn gauge
         sweep (0, the default, sweeps every step — the historical
         behavior); the step profiler (``server.stepline``) makes the
@@ -674,54 +637,25 @@ class PipelineEngine:
         while the row decodes. Prefix-cache hits are not offered; snapshots,
         prefix handles, the embeddings entry, speculation, tp / cp and a
         quantized arena are refused by name."""
+        from .options import ServeOptions
+        from .server import PipelineServer
+
+        options = ServeOptions(**options)  # (an unknown keyword: TypeError)
         self._validate_serve()
-        if cp > 1 and self.cfg.num_experts:
+        if options.cp > 1 and self.cfg.num_experts:
             raise NotImplementedError(
                 "serve×cp over a model with sparse experts is not "
                 "implemented (untested: the experts' counters and reads "
                 "would repeat per context shard); serve it on one chip or "
                 "a ring of stages"
             )
-        if cp > 1 and self.tensor_parallel > 1:
+        if options.cp > 1 and self.tensor_parallel > 1:
             raise NotImplementedError(
                 "serve×cp×tp: the cp arena sharding and megatron heads "
                 "sharding both claim the KV leaves' trailing dims — pick "
                 "one (cp for long context, tp for big models)"
             )
-        from .server import PipelineServer
-
-        return PipelineServer(
-            self,
-            capacity=capacity,
-            batch_per_slot=batch_per_slot,
-            chunk_cycles=chunk_cycles,
-            top_k=top_k,
-            top_p=top_p,
-            prefill_chunk=prefill_chunk,
-            pipeline_depth=pipeline_depth,
-            inflight_steps=inflight_steps,
-            trace_path=trace_path,
-            speculate=speculate,
-            spec_ngram=spec_ngram,
-            max_queue=max_queue,
-            default_deadline_s=default_deadline_s,
-            fault_plan=fault_plan,
-            fault_retries=fault_retries,
-            fault_backoff_s=fault_backoff_s,
-            retryable_exceptions=retryable_exceptions,
-            snapshot_every_s=snapshot_every_s,
-            snapshot_path=snapshot_path,
-            kv_block_size=kv_block_size,
-            kv_blocks=kv_blocks,
-            kv_dtype=kv_dtype,
-            paged_attn=paged_attn,
-            prefix_cache=prefix_cache,
-            host_pool_blocks=host_pool_blocks,
-            disk_pool_dir=disk_pool_dir,
-            disk_pool_blocks=disk_pool_blocks,
-            gauge_sweep_every_s=gauge_sweep_every_s,
-            cp=cp,
-        )
+        return PipelineServer(self, options)
 
     def _shared_server(self, prompt_len: int, max_new: int):
         """A capacity LADDER of coexisting shared servers (r3 weak #6): a

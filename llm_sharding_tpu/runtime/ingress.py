@@ -351,11 +351,7 @@ class IngressServer:
         # STEP-OWNERSHIP CONTRACT: this pump thread is the only caller of
         # backend.step() for the daemon's lifetime — the stepline builder
         # (single-threaded by design) and every per-step phase record key
-        # off that. The async executor (inflight_steps>1) does NOT change
-        # the contract: its scheduler/sidecar threads are internal to each
-        # PipelineServer, never call step(), and synchronize with the pump
-        # only through the server mutex — from here, an async step() is
-        # simply a step that returns without blocking on the log fetch.
+        # off that.
         while not self._stop:
             if self._paused:
                 time.sleep(self._poll_s)

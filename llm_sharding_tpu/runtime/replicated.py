@@ -584,8 +584,8 @@ class ReplicatedServer:
                 # log fetch would convert migratable requests into
                 # contained failures — its in-flight tokens replay on the
                 # adopter, token-identically. Elective drain() settles
-                # before calling here, and settle=True keeps any async-
-                # executor entry landed between then and this extract.
+                # before calling here; settle=True lands what a step
+                # dispatched between then and this extract.
                 st = src.extract(req, settle=cause is None)
             except Exception as e:  # noqa: BLE001 — classified below
                 if req.done and req.error is None:
@@ -689,9 +689,8 @@ class ReplicatedServer:
             self._set_replica_gauge(d, "DRAINING")
             self._retire(s)  # no new admissions from here on
             # apply every fetched-but-unapplied log first so the migrated
-            # state carries all committed tokens — with the async executor
-            # (inflight_steps>1) this settles ALL overlapped in-flight
-            # dispatches, landing the migration on a settled boundary
+            # state carries all committed tokens and the migration leaves
+            # from a settled boundary
             # (elective drain runs on a healthy replica; on failure the
             # flush is skipped — see _fail_replica — and the adopter
             # regenerates the in-flight tokens identically)

@@ -64,7 +64,6 @@ from __future__ import annotations
 import collections
 import dataclasses
 import logging
-import queue
 import threading
 import time
 import weakref
@@ -98,10 +97,8 @@ from ..obs.stepline import STEP_ANNOTATION, StepProfiler
 from ..analysis.lockorder import named_lock
 from ..parallel import serve as serve_ops
 from ..parallel.mesh import PIPE_AXIS
-from .async_exec import (
-    INFLIGHT_STEPS, SCHEDULER_LAG, _CompletionSidecar, _StepScheduler,
-)
 from .blocks import PAGED_KV_LAYOUT
+from .options import ServeOptions
 from .faults import backoff_delays, is_transient
 
 logger = logging.getLogger("llm_sharding_tpu.server")
@@ -456,35 +453,28 @@ class _Prefetched:
     """A device→host read of a few hundred bytes a step needs one
     ``pipeline_depth`` later: by then the transfer has ridden out the
     chunk's device time, so the steady-state step loop never waits for the
-    copy itself and the device queue stays full. Who finishes the read:
+    copy itself and the device queue stays full. The copy begins at dispatch
+    (``copy_to_host_async``) and the thread that waits for it finishes the
+    read: a log handed from a fetching thread to the step reached it 0.17 ms
+    after it landed (sd 0.04: an event's wake and the interpreter lock's,
+    each a futex under a sandboxed kernel) — at the moment a stream's reader
+    is waiting for the token (PERF.md §6, PR 39)."""
 
-    - the process-wide prefetch thread (``_Prefetcher.fetch``; ``event`` is
-      set when the value has landed): the async executor's way, whose
-      sidecar waits on events, and exact about WHEN a log landed;
-    - the thread that waits for it (``direct``: ``event`` is None, the copy
-      begun with ``copy_to_host_async`` at dispatch): the serial step's way.
-      A log handed from thread to thread reached the step 0.17 ms after it
-      landed (sd 0.04: an event's wake and the interpreter lock's, each a
-      futex under a sandboxed kernel) — at the moment a stream's reader is
-      waiting for the token (PERF.md §6, PR 39)."""
+    __slots__ = ("handle", "value", "error", "tag", "done_at")
 
-    __slots__ = ("handle", "value", "error", "event", "tag", "done_at")
-
-    def __init__(self, handle, tag: str = "?", direct: bool = False):
+    def __init__(self, handle, tag: str = "?"):
         self.handle = handle
         self.tag = tag  # what this read belongs to ("chunk m0=…", "admit …")
         self.value = None
         self.error: Optional[BaseException] = None
-        self.event = None if direct else threading.Event()
         # perf_counter stamp of when the value landed on host — the step
-        # profiler's device-idle estimate (log ready vs next dispatch). A
-        # direct read knows it to the moment only when its thread waited;
-        # otherwise it is when ``landed`` first found the device done
+        # profiler's device-idle estimate (log ready vs next dispatch): known
+        # to the moment only when the thread waited; otherwise it is when
+        # ``landed`` first found the device done
         self.done_at: Optional[float] = None
-        if direct:
-            begin = getattr(handle, "copy_to_host_async", None)
-            if begin is not None:
-                begin()
+        begin = getattr(handle, "copy_to_host_async", None)
+        if begin is not None:
+            begin()
 
     def read(self) -> None:
         """The blocking read, on the calling thread; a failure is kept WITH
@@ -498,15 +488,10 @@ class _Prefetched:
         else:
             self.handle = None  # drop the device reference promptly
             self.done_at = time.perf_counter()
-        if self.event is not None:
-            self.event.set()
 
     def landed(self) -> bool:
         """Has the value (or its failure) reached the host? Never waits for
-        the device: a direct read whose device work is done is finished
-        here."""
-        if self.event is not None:
-            return self.event.is_set()
+        the device: a read whose device work is done is finished here."""
         if self.value is None and self.error is None:
             ready = getattr(self.handle, "is_ready", None)
             if ready is None or ready():
@@ -514,9 +499,7 @@ class _Prefetched:
         return self.value is not None or self.error is not None
 
     def wait(self) -> None:
-        if self.event is not None:
-            self.event.wait()
-        elif self.value is None and self.error is None:
+        if self.value is None and self.error is None:
             self.read()
 
     def get(self) -> np.ndarray:
@@ -560,40 +543,6 @@ class _Prefetched:
         return self.value
 
 
-class _Prefetcher:
-    """One PROCESS-WIDE daemon thread fetching queued device arrays FIFO
-    (np.asarray releases the GIL during the transfer). Shared by every
-    server instance — servers are created per placement and discarded on
-    repartition, so a per-server thread would leak one parked thread per
-    rebuild."""
-
-    _instance: Optional["_Prefetcher"] = None
-    _instance_lock = named_lock("server.prefetcher")
-
-    def __init__(self):
-        self._q: queue.Queue = queue.Queue()
-        self._thread = threading.Thread(
-            target=self._run, daemon=True, name="serve-log-prefetch"
-        )
-        self._thread.start()
-
-    @classmethod
-    def shared(cls) -> "_Prefetcher":
-        with cls._instance_lock:
-            if cls._instance is None:
-                cls._instance = cls()
-            return cls._instance
-
-    def fetch(self, handle, tag: str = "?") -> _Prefetched:
-        p = _Prefetched(handle, tag)
-        self._q.put(p)
-        return p
-
-    def _run(self) -> None:
-        while True:
-            self._q.get().read()
-
-
 class _FirstLog(_Prefetched):
     """The log whose landing ends a ``setup.first_run`` span: a program met
     for the first time has then run (the device works in order), and the
@@ -626,10 +575,8 @@ def _watch_next_log(landed) -> bool:
             if threading.get_ident() != me:
                 return PipelineServer._fetch(srv, handle, tag)
             disarm()
-            log = _FirstLog(handle, tag, direct=srv._prefetcher is None)
+            log = _FirstLog(handle, tag)
             log.on_landed = landed
-            if srv._prefetcher is not None:
-                srv._prefetcher._q.put(log)
             return log
         return fetch
 
@@ -1061,102 +1008,89 @@ class PipelineServer:
     live servers (build a new one after ``apply_placement``).
     """
 
-    def __init__(
-        self,
-        engine,  # PipelineEngine (kept untyped: avoid circular import)
-        *,
-        capacity: int = 1024,
-        batch_per_slot: int = 1,
-        chunk_cycles: int = 1,
-        top_k: int = 0,
-        top_p: float = 1.0,
-        prefill_chunk: Optional[int] = None,
-        pipeline_depth: int = 1,
-        inflight_steps: int = 1,
-        trace_path: Optional[str] = None,
-        speculate: int = 0,
-        spec_ngram: int = 3,
-        max_queue: Optional[int] = None,
-        default_deadline_s: Optional[float] = None,
-        fault_plan=None,  # runtime.faults.FaultPlan (tests/chaos/bench)
-        fault_retries: int = 3,
-        fault_backoff_s: float = 0.01,
-        retryable_exceptions: tuple = (),
-        snapshot_every_s: Optional[float] = None,
-        snapshot_path: Optional[str] = None,
-        kv_block_size: Optional[int] = None,
-        kv_blocks: Optional[int] = None,
-        kv_dtype: str = "bf16",
-        paged_attn: str = "auto",
-        prefix_cache: str = "off",
-        host_pool_blocks: int = 0,
-        disk_pool_dir: Optional[str] = None,
-        disk_pool_blocks: int = 0,
-        gauge_sweep_every_s: float = 0.0,
-        cp: int = 1,
-    ):
+    def __init__(self, engine, options: ServeOptions):
+        # engine: a PipelineEngine (kept untyped: avoid circular import)
         self.engine = engine
         self.cfg = engine.cfg
         self.mesh = engine.mesh
         self.num_stages = self.mesh.shape[PIPE_AXIS]
-        if cp < 1:
-            raise ValueError(f"cp must be >= 1, got {cp}")
-        self.cp = int(cp)
         # tensor-parallel degree: the serve programs run megatron-sharded
         # stage fns and keep the KV state heads-sharded over TENSOR_AXIS
         self.tp = int(getattr(engine, "tensor_parallel", 1))
-        self.batch_per_slot = batch_per_slot
-        self.capacity = capacity
-        self.chunk_cycles = chunk_cycles
-        # top-k/top-p are PER-REQUEST row state (dynamic arrays in the serve
-        # programs — no recompile per request, VERDICT r3 next-#7); the
-        # constructor values are only the defaults ``submit`` falls back to.
+        #: window and full attention in one stack (``cfg.windowed``): a KV
+        #: state per kind of attention layer — the full layers' pool is
+        #: ``kv_blocks``; the window layers' is every row's share of
+        #: ``_swa_quota`` blocks (``_init_window_pool``): nothing to size
+        self.windowed = bool(self.cfg.windowed)
+        #: recurrent layers beside attention (``cfg.recurrent``): a state of
+        #: FIXED size a request, indexed by row beside the arena, which holds
+        #: the attention layers alone (``ServeState.recurrent``)
+        self.recurrent = bool(self.cfg.recurrent)
+        name = kind_state_name(self.cfg)
+        if name is not None and (
+            not options.paged or options.prefill_chunk is None
+        ):
+            # (before the record's own checks: what THIS model needs, by name)
+            raise ValueError(
+                f"{name} serves from a paged arena "
+                + ("per kind of layer" if self.windowed
+                   else "beside its recurrent state")
+                + ", admitted chunk by chunk: set kv_block_size, "
+                "kv_blocks and prefill_chunk"
+            )
+        options.validate()
+        self.paged = options.paged
+        if name is not None and options.prefix_cache != "off":
+            # a hit would map the full layers' old blocks while the window
+            # layers' are gone — or the attention layers' while a recurrent
+            # state cannot be sliced at the hit's length: a hit is not
+            # OFFERED (the tree is not built; every prompt prefills cold)
+            logger.info(
+                "prefix_cache=%r over %s: hits are not offered (%s)",
+                options.prefix_cache, name,
+                "a window layer's old blocks are gone" if self.windowed
+                else "a recurrent state cannot be sliced at a hit's length",
+            )
+            options = dataclasses.replace(
+                options, prefix_cache="off", host_pool_blocks=0
+            )
+        from ..ops.sampling import validate_top_p
+
+        tiered = options.prefix_cache in ("host", "disk")
+        on_disk = options.prefix_cache == "disk"
+        #: the record as this server runs it — what ``snapshot()`` carries
+        #: and a re-shard rebuilds from (the host tier defaults to an
+        #: arena-sized pool: the cache can spill everything it holds exactly
+        #: once over; the disk tier below it to another arena's worth).
+        #: Every field is an attribute too (``srv.capacity``,
+        #: ``srv.kv_block_size``, ``srv.paged_attn``: the REQUESTED backend,
+        #: ``attn_impl`` is the resolved one, ...)
+        self.options = options = dataclasses.replace(
+            options,
+            top_p=validate_top_p(options.top_p),
+            host_pool_blocks=(
+                options.host_pool_blocks or options.kv_blocks if tiered
+                else options.host_pool_blocks
+            ),
+            disk_pool_dir=options.disk_pool_dir if on_disk else None,
+            disk_pool_blocks=(
+                options.disk_pool_blocks or options.kv_blocks if on_disk
+                else 0
+            ),
+        )
+        for field, value in vars(options).items():
+            setattr(self, field, value)
+        cp, speculate, prefill_chunk = self.cp, self.speculate, self.prefill_chunk
+        kv_dtype, kv_block_size = self.kv_dtype, self.kv_block_size
         # The decode program compiles greedy-only until the first sampled
         # request arrives (the sampler costs ~20% steady-state throughput;
         # top-k/top-p alone cannot change an argmax), then sticks with the
         # sampling variant.
-        from ..ops.sampling import validate_top_p
-
-        self.top_k = top_k
-        self.top_p = validate_top_p(top_p)
         self._sampling = False
         # like _sampling: the decode program compiles WITHOUT the top-k/top-p
         # machinery (vocab gather + sort per completion) until the first
         # request that actually uses a filter arrives — then recompiles once
         self._filtering = False
-        # chunked admission (r2 weak #4): prompts longer than this are
-        # prefilled in bounded chunks with decode cycles interleaved, so a
-        # long admission never stalls live streams. None → one-shot admit.
-        if prefill_chunk is not None and (
-            prefill_chunk < 1 or prefill_chunk & (prefill_chunk - 1)
-        ):
-            raise ValueError("prefill_chunk must be a power of two")
-        self.prefill_chunk = prefill_chunk
-        # how many chunk logs may stay in flight: 1 overlaps the fetch with
-        # the next chunk's compute; 2 additionally hides the post-completion
-        # fetch latency (the device→host copy) at the cost of tokens
-        # surfacing one more chunk late (throughput mode)
-        if pipeline_depth < 1:
-            raise ValueError("pipeline_depth must be >= 1")
-        self.pipeline_depth = pipeline_depth
-        # Async executor depth (runtime/async_exec.py): how many decode
-        # dispatches may stay enqueued on device before the executor
-        # applies logs inline. 1 (default) is the serial step loop —
-        # rollback from the async executor is this flag flip. N>1 splits
-        # step() into executor + off-thread scheduler + completion
-        # sidecar: the device queue never drains behind the host's
-        # fetch/apply work, generalizing pipeline_depth (which only keeps
-        # LOGS un-fetched, one dispatch per blocking step) to multiple
-        # overlapped dispatches. Greedy output stays token-identical at
-        # every depth; tokens surface up to N chunks late (the sidecar
-        # applies them between steps). Speculative decode caps the
-        # effective depth at 1 (drafts need committed ids) but keeps the
-        # scheduler/sidecar offload.
-        if inflight_steps < 1:
-            raise ValueError(
-                f"inflight_steps must be >= 1, got {inflight_steps}"
-            )
-        self.inflight_steps = int(inflight_steps)
         # Speculative decoding (runtime/spec.py + parallel/serve.serve_verify):
         # speculate=K replaces the interleaved serve_chunk decode with
         # per-slot verify traversals — the host n-gram-drafts up to K tokens
@@ -1165,13 +1099,11 @@ class PipelineServer:
         # token-identical to chunk mode. Incompatible with prefill_chunk:
         # chunked admission interleaves serve_chunk microstep cycles, whose
         # per-slot write_off bookkeeping a spec server does not maintain.
-        if speculate < 0:
-            raise ValueError(f"speculate must be >= 0, got {speculate}")
-        if speculate and self.cfg.recurrent:
+        if speculate and self.recurrent:
             # (before the clash with prefill_chunk, which such a model needs:
             # the reason that holds whatever the admission path is)
-            refuse_kind_state(
-                self.cfg, "speculate over",
+            self._refuse_kind_state(
+                "speculate over",
                 "serve_verify is not carried over a recurrent state (a "
                 "rejected draft would have to roll the state back)",
             )
@@ -1181,75 +1113,16 @@ class PipelineServer:
                 "admission interleaves serve_chunk decode cycles; the "
                 "speculative step loop replaces serve_chunk entirely)"
             )
-        self.speculate = int(speculate)
-        self.spec_ngram = int(spec_ngram)
         # spec mode: K+1 SCRATCH columns over the usable capacity — the
         # verify forward writes its draft-position KV there, then compacts
         # the accepted prefix into each row's canonical columns (rollback is
         # a position rewind, never a copy of live state). Budget validation
         # everywhere uses the USABLE self.capacity.
-        self._spec_cols = self.speculate + 1 if self.speculate else 0
-        # -- resilience knobs (see module docstring) -----------------------
-        if max_queue is not None and max_queue < 1:
-            raise ValueError(f"max_queue must be >= 1, got {max_queue}")
-        if default_deadline_s is not None and default_deadline_s <= 0:
-            raise ValueError(
-                f"default_deadline_s must be > 0, got {default_deadline_s}"
-            )
-        self.max_queue = max_queue
-        self.default_deadline_s = default_deadline_s
-        # -- paged KV (PagedAttention-style block-granular serving) --------
-        # kv_block_size + kv_blocks switch the serve state from per-row
-        # dense reservations ([.., M, capacity, ..]) to a pooled arena
-        # ([.., kv_blocks, kv_block_size, ..]) with per-row block tables: a
-        # request holds only the blocks covering its prompt + budget, so
-        # skewed-length workloads admit several times more concurrent rows
-        # in the same HBM. Greedy output is token-identical to dense (the
-        # programs see the same logical window either way); dense stays the
-        # default.
-        if (kv_block_size is None) != (kv_blocks is None):
-            raise ValueError(
-                "kv_block_size and kv_blocks go together (got "
-                f"kv_block_size={kv_block_size!r}, kv_blocks={kv_blocks!r})"
-            )
-        self.paged = kv_block_size is not None
-        if self.paged:
-            kv_block_size = int(kv_block_size)
-            kv_blocks = int(kv_blocks)
-            if kv_block_size < 1 or (kv_block_size & (kv_block_size - 1)):
-                raise ValueError(
-                    f"kv_block_size must be a power of two, got "
-                    f"{kv_block_size}"
-                )
-            if kv_blocks < 2:
-                raise ValueError(
-                    f"kv_blocks must be >= 2 (block 0 is the reserved "
-                    f"trash sink), got {kv_blocks}"
-                )
-        self.kv_block_size = kv_block_size
-        self.kv_blocks = kv_blocks
-        # -- quantized KV arena (--kv-dtype; ops/quant KV section) ---------
-        # "bf16" (the default) stores the arena in the engine's compute
-        # cache dtype — the pre-existing exact path. "int8"/"fp8" store
-        # 1-byte codes with per-block-per-head scales in a parallel scale
-        # arena: ~2× the blocks at equal HBM and half the decode-attention
-        # DMA bytes, at a bounded greedy-token drift (the FIRST
-        # intentionally non-bit-exact serve variant — gate rollouts on the
-        # bench's kv-quant token-match fraction).
+        self._spec_cols = speculate + 1 if speculate else 0
         from ..ops.quant import (
-            KV_DTYPES, fp8_kv_supported, is_kv_quantized, kv_storage_dtype,
+            fp8_kv_supported, is_kv_quantized, kv_storage_dtype,
         )
 
-        if kv_dtype not in KV_DTYPES:
-            raise ValueError(
-                f"kv_dtype must be one of {KV_DTYPES}, got {kv_dtype!r}"
-            )
-        if kv_dtype != "bf16" and not self.paged:
-            raise ValueError(
-                f"kv_dtype={kv_dtype!r} needs paged KV serving (set "
-                "kv_block_size/kv_blocks): quantization scales live per "
-                "arena block — dense per-row reservations have no blocks"
-            )
         if kv_dtype != "bf16" and self.tp > 1:
             raise NotImplementedError(
                 f"kv_dtype={kv_dtype!r} with tensor_parallel={self.tp}: "
@@ -1271,7 +1144,7 @@ class PipelineServer:
                     f"({self.cfg.model_type}): a quantized latent arena is "
                     "not implemented — serve it with kv_dtype='bf16'"
                 )
-            if self.speculate:
+            if speculate:
                 raise NotImplementedError(
                     f"speculate over a latent KV cache "
                     f"({self.cfg.model_type}): serve_verify is not carried "
@@ -1282,35 +1155,17 @@ class PipelineServer:
                     f"cp / tp over a latent KV cache "
                     f"({self.cfg.model_type}) is not implemented"
                 )
-        #: window and full attention in one stack (``cfg.windowed``): a KV
-        #: state per kind of attention layer — the full layers' pool is
-        #: ``kv_blocks``; the window layers' is every row's share of
-        #: ``_swa_quota`` blocks (``_init_window_pool``): nothing to size
-        self.windowed = bool(self.cfg.windowed)
-        #: recurrent layers beside attention (``cfg.recurrent``): a state of
-        #: FIXED size a request, indexed by row beside the arena, which holds
-        #: the attention layers alone (``ServeState.recurrent``)
-        self.recurrent = bool(self.cfg.recurrent)
-        if self.windowed or self.recurrent:
+        if name is not None:
             # what a state beyond ONE paged arena breaks is refused by name,
             # never computed as something else (ROADMAP M2 / M4 list what is
             # left)
-            name = kind_state_name(self.cfg)
-            if not self.paged or prefill_chunk is None:
-                raise ValueError(
-                    f"{name} serves from a paged arena "
-                    + ("per kind of layer" if self.windowed
-                       else "beside its recurrent state")
-                    + ", admitted chunk by chunk: set kv_block_size, "
-                    "kv_blocks and prefill_chunk"
-                )
             if kv_dtype != "bf16":
                 self._refuse_kind_state(
                     f"kv_dtype={kv_dtype!r} over",
                     ("a quantized arena per kind of layer",
                      "a quantized arena beside a recurrent state"),
                 )
-            if self.speculate:  # (a recurrent state: refused above)
+            if speculate:  # (a recurrent state: refused above)
                 self._refuse_kind_state(
                     "speculate over",
                     "serve_verify is not carried over a KV state per kind "
@@ -1321,22 +1176,8 @@ class PipelineServer:
                 self._refuse_kind_state(
                     "cp / tp over", "its state is not sharded that way"
                 )
-            if snapshot_every_s is not None or snapshot_path is not None:
+            if self.snapshot_path is not None:
                 self._refuse_kind_state("snapshots of", _SNAPSHOT_WHY)
-            if prefix_cache != "off":
-                # a hit would map the full layers' old blocks while the
-                # window layers' are gone — or the attention layers' while a
-                # recurrent state cannot be sliced at the hit's length: a hit
-                # is not OFFERED (the tree is not built; every prompt
-                # prefills cold)
-                logger.info(
-                    "prefix_cache=%r over %s: hits are not offered (%s)",
-                    prefix_cache, name,
-                    "a window layer's old blocks are gone" if self.windowed
-                    else "a recurrent state cannot be sliced at a hit's length",
-                )
-                prefix_cache = self.prefix_cache = "off"
-                host_pool_blocks = self.host_pool_blocks = 0
         if self.windowed:
             # the most a row's window layers may hold: a chunk's queries and
             # the window behind its first (ISSUE 39: ceil((window +
@@ -1348,9 +1189,8 @@ class PipelineServer:
                 -(self.cfg.sliding_window + prefill_chunk) // kv_block_size
             ) + 1
             self._swa_blocks = (
-                self.num_stages * batch_per_slot * self._swa_quota + 1
+                self.num_stages * self.batch_per_slot * self._swa_quota + 1
             )
-        self.kv_dtype = kv_dtype
         #: the arena STORAGE dtype (engine.cache_dtype stays the compute
         #: dtype — prefill windows, prefix handles and dense state use it)
         self.kv_store_dtype = kv_storage_dtype(kv_dtype, engine.cache_dtype)
@@ -1364,19 +1204,8 @@ class PipelineServer:
         # the kernel code path through the serve programs every PR).
         # Resolved ONCE here so --paged-attn kernel fails loud at
         # construction, not as a Mosaic error mid-serve.
-        if paged_attn not in ("auto", "kernel", "xla"):
-            raise ValueError(
-                f"paged_attn must be auto, kernel or xla, got {paged_attn!r}"
-            )
-        if paged_attn != "auto" and not self.paged:
-            raise ValueError(
-                "paged_attn is only meaningful with paged KV serving "
-                "(set kv_block_size/kv_blocks); dense decode has no block "
-                "tables to stream"
-            )
-        self.paged_attn = paged_attn
         self.attn_impl = (
-            self._resolve_attn_impl(paged_attn) if self.paged else "dense"
+            self._resolve_attn_impl(self.paged_attn) if self.paged else "dense"
         )
         if self.recurrent:
             # the path a decode step's state update takes under the static
@@ -1391,80 +1220,12 @@ class PipelineServer:
             self.recurrent_mixer_step = mixer_step_path(
                 self.attn_impl, self.cfg, engine.stage_layers
             ) if self.cfg.ssm_dt_rank else None
-        # -- automatic prefix cache (runtime/radix.py) ---------------------
-        # "hbm": radix tree over token ids — every submit transparently
-        # reuses the longest cached prefix, finished rows' prompt blocks
-        # are indexed instead of freed, cold entries evict under allocator
-        # pressure. "host": additionally demotes cold blocks to a pinned
-        # host-RAM pool (device→host copy, streamed back bit-exact on a
-        # later hit) before dropping — HBM becomes a cache level, not a
-        # hard ceiling. "disk": additionally spills cold host-pool nodes
-        # to memory-mapped files under a bounded on-disk pool that
-        # survives restarts (promoted disk→host→arena on a later hit).
-        # Explicit PrefixHandles remain the manual/pinned escape hatch
-        # and bypass the tree entirely.
-        if prefix_cache not in ("off", "hbm", "host", "disk"):
+        if tiered and jax.process_count() > 1:
             raise ValueError(
-                f"prefix_cache must be off, hbm, host or disk, got "
-                f"{prefix_cache!r}"
-            )
-        if prefix_cache != "off" and not self.paged:
-            raise ValueError(
-                "prefix_cache needs paged KV serving (set kv_block_size/"
-                "kv_blocks): the cache shares refcounted arena blocks — "
-                "dense per-row reservations have nothing to share"
-            )
-        if host_pool_blocks and prefix_cache not in ("host", "disk"):
-            raise ValueError(
-                "host_pool_blocks sizes the host-RAM tier — it needs "
-                f"prefix_cache='host' or 'disk' (got "
-                f"prefix_cache={prefix_cache!r})"
-            )
-        if host_pool_blocks < 0:
-            raise ValueError(
-                f"host_pool_blocks must be >= 0, got {host_pool_blocks}"
-            )
-        if (disk_pool_dir or disk_pool_blocks) and prefix_cache != "disk":
-            raise ValueError(
-                "disk_pool_dir/disk_pool_blocks size the on-disk tier — "
-                f"they need prefix_cache='disk' (got "
-                f"prefix_cache={prefix_cache!r})"
-            )
-        if prefix_cache == "disk" and not disk_pool_dir:
-            raise ValueError(
-                "prefix_cache='disk' needs disk_pool_dir: the bounded "
-                "pool of memory-mapped entry files is the persistent "
-                "artifact cold nodes spill into"
-            )
-        if disk_pool_blocks < 0:
-            raise ValueError(
-                f"disk_pool_blocks must be >= 0, got {disk_pool_blocks}"
-            )
-        if prefix_cache in ("host", "disk") and jax.process_count() > 1:
-            raise ValueError(
-                f"prefix_cache={prefix_cache!r} moves block KV through "
+                f"prefix_cache={self.prefix_cache!r} moves block KV through "
                 "host numpy — unsupported on multi-controller meshes; "
                 "use 'hbm'"
             )
-        self.prefix_cache = prefix_cache
-        # host tier default: an arena-sized pool (the cache can spill
-        # everything it holds exactly once over); the disk tier sits
-        # below it and defaults to another arena's worth on disk
-        self.host_pool_blocks = (
-            int(host_pool_blocks) if prefix_cache not in ("host", "disk")
-            else int(host_pool_blocks or kv_blocks)
-        )
-        self.disk_pool_dir = disk_pool_dir if prefix_cache == "disk" else None
-        self.disk_pool_blocks = (
-            int(disk_pool_blocks or kv_blocks) if prefix_cache == "disk"
-            else 0
-        )
-        self._fault_plan = fault_plan
-        if fault_retries < 0:
-            raise ValueError(f"fault_retries must be >= 0, got {fault_retries}")
-        self._fault_retries = int(fault_retries)
-        self._fault_backoff_s = float(fault_backoff_s)
-        self._retryable = tuple(retryable_exceptions)
         self._health = SERVING
         self._closed = False
         self._step_contained = False  # a containment event this step
@@ -1472,11 +1233,7 @@ class PipelineServer:
         # signal (it samples the delta per step and quarantines a replica
         # whose events cross the threshold inside the window)
         self.containment_events = 0
-        self._snapshot_every_s: Optional[float] = None
-        self._snapshot_path: Optional[str] = None
         self._last_snapshot_at = time.perf_counter()
-        if snapshot_every_s is not None or snapshot_path is not None:
-            self.enable_auto_snapshot(snapshot_path, snapshot_every_s)
         self.counters = Counters()
         # optional JSONL span trace (obs/trace.py). Deliberately NOT part of
         # serve_kwargs in snapshot(): an observability knob, not serving
@@ -1484,7 +1241,7 @@ class PipelineServer:
         # the process-wide flight-recorder ring (served by /debugz) whether
         # or not a file is configured; _span_src names this server in them
         # (the dp router overwrites it with the replica's group label).
-        self._trace = TraceWriter(trace_path) if trace_path else None
+        self._trace = TraceWriter(self.trace_path) if self.trace_path else None
         self._span_src = "s0"
         if self._trace is not None:
             # set-up's spans (src="setup") ride the same file, the engine's
@@ -1513,13 +1270,6 @@ class PipelineServer:
         # blocks + its own block-table plane), which is what buys ~cp× the
         # admissible context at equal per-chip HBM.
         if self.cp > 1:
-            if not self.paged:
-                raise ValueError(
-                    "cp > 1 needs paged KV serving (set kv_block_size/"
-                    "kv_blocks): context-parallel serving shards the block "
-                    "arena — dense per-row reservations have no block dim "
-                    "to shard"
-                )
             if self.tp > 1:
                 raise NotImplementedError(
                     "cp × tp serving: the cp arena sharding and megatron "
@@ -1538,14 +1288,6 @@ class PipelineServer:
                     "commits have no cross-shard combine yet — serve "
                     "speculative on cp=1, or long-context on cp without "
                     "speculation (ROADMAP: cp-aware speculation)"
-                )
-            if self.prefix_cache != "off" and self.prefill_chunk is None:
-                raise ValueError(
-                    "cp > 1 with prefix_cache needs prefill_chunk: a radix "
-                    "hit's resident prefix spans multiple shards, so its "
-                    "suffix must prefill arena-native (chunked) — the "
-                    "one-shot gather path cannot assemble a cross-shard "
-                    "window"
                 )
             if jax.process_count() > 1:
                 raise NotImplementedError(
@@ -1594,8 +1336,8 @@ class PipelineServer:
             self.cfg,
             self.mesh,
             Lp,
-            capacity=capacity + self._spec_cols,
-            batch_per_slot=batch_per_slot,
+            capacity=self.capacity + self._spec_cols,
+            batch_per_slot=self.batch_per_slot,
             # the ARENA dtype: int8/fp8 codes under kv quantization (the
             # compute dtype stays engine.cache_dtype — prefill windows and
             # prefix handles dequantize into it)
@@ -1618,10 +1360,10 @@ class PipelineServer:
             },
         )
         SETUP.end(arena)
-        # pools, radix tree, mirrors, the async executor's threads
+        # pools, radix tree, mirrors
         host = SETUP.begin("setup.server.host")
 
-        M = self.num_stages * batch_per_slot
+        M = self.num_stages * self.batch_per_slot
         if self.paged:
             from .blocks import BlockAllocator, ShardedBlockAllocator
 
@@ -1689,10 +1431,7 @@ class PipelineServer:
             self._radix: Optional["RadixCache"] = RadixCache(
                 self._alloc,
                 self.kv_block_size,
-                host_pool_blocks=(
-                    self.host_pool_blocks
-                    if self.prefix_cache in ("host", "disk") else 0
-                ),
+                host_pool_blocks=self.host_pool_blocks if tiered else 0,
                 read_kv=self._read_arena_blocks,
                 write_kv=self._write_arena_blocks,
                 # cp>1: demoted host-pool nodes carry a shard-tagged
@@ -1735,11 +1474,6 @@ class PipelineServer:
         self._mirror_cachedelta = np.zeros(M, np.int64)
         self._m = 0  # host mirror of state.m (chunks advance it)
         self._pending: collections.deque = collections.deque()
-        # the async executor's sidecar waits on the prefetch thread's
-        # events; the serial step reads its logs itself (_Prefetched)
-        self._prefetcher = (
-            _Prefetcher.shared() if self.inflight_steps > 1 else None
-        )
         self._stop_ids = frozenset(int(t) for t in self.cfg.eos_token_ids)
         # rows mid-chunked-admission: the slot is parked done on device until
         # serve_admit_finish arms it; no log entries arrive for it
@@ -1762,34 +1496,11 @@ class PipelineServer:
         self.stepline = StepProfiler(
             name="server", annotate=_profiler_annotation
         )
-        # pace the per-step load/KV/attn gauge sweep: 0.0 (default) keeps
-        # the historical sweep-every-step behavior; at 64+ rows the sweep's
-        # row scan is real per-step host work (visible as the profiler's
-        # gauge_sweep phase), so ops can stretch it to e.g. 0.5 s.
-        if gauge_sweep_every_s < 0:
-            raise ValueError(
-                f"gauge_sweep_every_s must be >= 0, got {gauge_sweep_every_s}"
-            )
-        self.gauge_sweep_every_s = float(gauge_sweep_every_s)
+        # gauge_sweep_every_s paces the per-step load/KV/attn gauge sweep:
+        # 0.0 (default) sweeps every step; at 64+ rows the sweep's row scan
+        # is real per-step host work (visible as the profiler's gauge_sweep
+        # phase), so ops can stretch it to e.g. 0.5 s.
         self._last_gauge_sweep = 0.0  # perf_counter of the last in-step sweep
-        # a LOWER BOUND on the earliest live deadline (None = no armed
-        # deadline): enqueue sites tighten it, _shed_expired recomputes it
-        # exactly. The async executor sweeps inline only when it has
-        # passed — the serial contract (expired rows cancelled at the NEXT
-        # chunk boundary) must not depend on scheduler-thread timing, and
-        # a bound that only ever undershoots can never miss an expiry.
-        self._deadline_hint: Optional[float] = None
-        # async-executor helper threads, started only at depth > 1 (they
-        # hold a weakref to the server and need the mutex above — so this
-        # block stays after every attribute they read exists)
-        self._scheduler: Optional[_StepScheduler] = None
-        self._sidecar: Optional[_CompletionSidecar] = None
-        if self.inflight_steps > 1:
-            self._scheduler = _StepScheduler(self)
-            self._sidecar = _CompletionSidecar(self)
-            self._scheduler.start()
-            self._sidecar.start()
-        INFLIGHT_STEPS.set(float(self.inflight_steps))
         # register LAST: a concurrent gauge sweep from another serving
         # thread must never see a half-constructed server (_alloc,
         # _mirror_len, _queue, _rows are all read by _update_load_gauges)
@@ -2037,7 +1748,6 @@ class PipelineServer:
             if top_k > 0 or top_p < 1.0:
                 self._filtering = True
             self._queue.append(req)
-            self._arm_deadline(req.deadline_at)
             self.counters.inc("requests_submitted")
             _update_load_gauges()
         logger.info(
@@ -2228,7 +1938,8 @@ class PipelineServer:
                 # host table mirror already keeps GLOBAL block ids — the
                 # ShardedBlockAllocator partition is a pure function of
                 # (cp, kv_blocks) plus the per-row lists, so restore
-                # rebuilds it exactly. Format 5 added inflight_steps,
+                # rebuilds it exactly. Format 5 added an option since
+                # retired (``options.RETIRED``),
                 # format 4 kv_dtype + the scale-arena/radix host-KV keys,
                 # format 3 the prefix-cache section; formats 1 (dense)
                 # through 7 still restore where they are DENSE — see
@@ -2237,43 +1948,20 @@ class PipelineServer:
                 "radix": (
                     None if self._radix is None else self._radix.snapshot()
                 ),
-                "serve_kwargs": dict(
-                    capacity=self.capacity,
-                    batch_per_slot=self.batch_per_slot,
-                    chunk_cycles=self.chunk_cycles,
-                    top_k=self.top_k,
-                    top_p=self.top_p,
-                    prefill_chunk=self.prefill_chunk,
-                    pipeline_depth=self.pipeline_depth,
-                    inflight_steps=self.inflight_steps,
-                    speculate=self.speculate,
-                    spec_ngram=self.spec_ngram,
-                    max_queue=self.max_queue,
-                    default_deadline_s=self.default_deadline_s,
-                    kv_block_size=self.kv_block_size,
-                    kv_blocks=self.kv_blocks,
-                    # KV storage dtype rides the checkpoint: a quantized
-                    # snapshot's arena bytes ARE codes — restoring them
-                    # into a bf16 server would reinterpret garbage (the
-                    # dtype check below catches a hand-edited mismatch)
-                    kv_dtype=self.kv_dtype,
-                    # the REQUESTED backend, not the resolved impl: an
-                    # operator's explicit kernel/xla pin survives restore
-                    # (snapshot-wins, like every serve kwarg), while
-                    # "auto" re-resolves against the restoring host's
-                    # backend — a snapshot taken on TPU still restores on
-                    # a CPU mesh (pre-PR-6 snapshots lack the key and
-                    # restore as "auto" via the constructor default)
-                    paged_attn=self.paged_attn,
-                    prefix_cache=self.prefix_cache,
-                    host_pool_blocks=self.host_pool_blocks,
-                    disk_pool_dir=self.disk_pool_dir,
-                    disk_pool_blocks=self.disk_pool_blocks,
-                    # the cp shard count: restore refuses a mesh it cannot
-                    # rebuild (cp×stages devices) rather than silently
-                    # reshaping the arena
-                    cp=self.cp,
-                ),
+                # the record's portable fields (runtime/options.py), as
+                # this server runs them. kv_dtype rides the checkpoint: a
+                # quantized snapshot's arena bytes ARE codes — restoring
+                # them into a bf16 server would reinterpret garbage (the
+                # dtype check in ``restore`` catches a hand-edited
+                # mismatch). paged_attn is the REQUESTED backend, not the
+                # resolved impl: an operator's explicit kernel/xla pin
+                # survives restore (snapshot-wins, like every serve
+                # option), while "auto" re-resolves against the restoring
+                # host's backend — a snapshot taken on TPU still restores
+                # on a CPU mesh. cp: restore refuses a mesh it cannot
+                # rebuild (cp×stages devices) rather than silently
+                # reshaping the arena
+                "serve_kwargs": self.options.portable(),
                 # block ownership travels with the checkpoint: restore
                 # rebuilds the allocator's free list/refcounts from the
                 # per-row lists (a prefix HANDLE's own reference dies with
@@ -2332,13 +2020,14 @@ class PipelineServer:
         if validate is not None:
             validate()
         refuse_kind_state(engine.cfg, "restore into", _SNAPSHOT_WHY)
-        kwargs = dict(snap["serve_kwargs"])
-        # pre-format-6 snapshots lack the key and restore as cp=1 via the
-        # constructor default; a cp>1 snapshot refuses up front when the
-        # restoring engine cannot host the mesh — the arena leaves were
-        # captured against a cp-sharded placement and restoring them onto
-        # fewer shards would need a resharding pass this path does not do
-        cp = int(kwargs.get("cp", 1) or 1)
+        # a key an older format lacks takes the record's default (cp 1,
+        # paged_attn "auto", ...)
+        options = ServeOptions.from_snapshot(snap["serve_kwargs"])
+        # a cp>1 snapshot refuses up front when the restoring engine cannot
+        # host the mesh — the arena leaves were captured against a
+        # cp-sharded placement and restoring them onto fewer shards would
+        # need a resharding pass this path does not do
+        cp = int(options.cp or 1)
         if cp > 1:
             devs = getattr(engine, "_devices", None)
             have = len(devs) if devs is not None else len(jax.devices())
@@ -2355,7 +2044,7 @@ class PipelineServer:
                 )
         # dense/paged are different device layouts — the mismatch gets a
         # curated refusal up front, not a shape error deep in the leaf loop
-        paged = kwargs.get("kv_block_size") is not None
+        paged = options.paged
         if paged and not snap.get("paged"):
             raise ValueError(
                 "dense-mode snapshot cannot restore into a paged server "
@@ -2368,7 +2057,7 @@ class PipelineServer:
                 "paged-mode snapshot cannot restore into a dense server: "
                 "keep the snapshot's kv_block_size/kv_blocks serve kwargs"
             )
-        srv = cls(engine, **kwargs)
+        srv = cls(engine, options)
         host = dict(snap["state"])
         if "block_tables" not in host:
             # legacy (format 1) snapshot: dense by construction — the
@@ -2638,7 +2327,6 @@ class PipelineServer:
             if top_k > 0 or top_p < 1.0:
                 self._filtering = True
             self._queue.append(req)
-            self._arm_deadline(req.deadline_at)
             self.counters.inc("requests_submitted")
             _update_load_gauges()
         logger.info(
@@ -2680,22 +2368,7 @@ class PipelineServer:
         failure is contained to its affected requests (health drops to
         DEGRADED) and the daemon keeps stepping — a subsequent clean
         productive step restores SERVING. With auto-snapshot armed the step
-        ends by checkpointing once per interval. A closed server no-ops.
-
-        With ``inflight_steps=N>1`` the serial body below is replaced by
-        the async executor (``_step_async``): up to N decode dispatches
-        stay enqueued on device, the deadline sweep / radix staging /
-        gauge sweep move onto the scheduler thread's published delta, and
-        token apply moves onto the completion sidecar — the hot loop is
-        publish → admit → dispatch, with inline draining only at the
-        in-flight cap. Greedy output is token-identical at every depth."""
-        if self.inflight_steps > 1:
-            return self._step_async()
-        return self._step_serial()
-
-    def _step_serial(self) -> bool:
-        """The historical single-threaded step body (``inflight_steps=1``):
-        see ``step`` for the full contract."""
+        ends by checkpointing once per interval. A closed server no-ops."""
         with self._mutex:
             if self._closed:
                 return False
@@ -2798,128 +2471,6 @@ class PipelineServer:
             self._write_autosnapshot(snap_due)
         return progressed
 
-    def _step_async(self) -> bool:
-        """The async executor's hot loop (``inflight_steps=N>1``): apply
-        the scheduler's published delta, admit, dispatch — and drain
-        inline only when the in-flight window is full or the server went
-        passive. Stepline phases: ``publish`` (delta consumption, with
-        the inline ``_shed_expired`` fallback when the scheduler hasn't
-        published), ``admit``, ``dispatch``, and ``drain`` (the inline
-        settle, with the historical ``fetch``/``apply`` sub-phases nested
-        disjointly inside); the scheduler's overlapped ``plan`` time
-        reaches the phase histogram off-thread and deliberately stays out
-        of StepRecords, so the exact-accounting invariant holds unchanged.
-
-        The step ends by kicking the scheduler (plan the next boundary)
-        and waking the sidecar (apply whatever lands while the pump is
-        between steps). Both notifies happen under the mutex — their
-        conditions rank after it in the canonical lock order."""
-        sched, sidecar = self._scheduler, self._sidecar
-        with self._mutex:
-            if self._closed:
-                return False
-            sl = self.stepline
-            sl.begin_step(*self._held())
-            tok0 = self.counters.tokens_generated
-            # NOT reset here (unlike the serial loop): the sidecar may
-            # have contained a failure BETWEEN steps — that containment
-            # must suppress this step's health recovery exactly like an
-            # in-step one, so DEGRADED stays observable for at least one
-            # full step boundary at any depth. Consumed at step end.
-            sl.push("publish")
-            delta = sched.take() if sched is not None else None
-            if delta is not None:
-                progressed = self._apply_delta(delta)
-                if (
-                    self._deadline_hint is not None
-                    and time.perf_counter() >= self._deadline_hint
-                ):
-                    # staleness backstop: a deadline passed AFTER the
-                    # delta was planned (it can be one boundary old) —
-                    # sweep inline so expiry still lands at this chunk
-                    # boundary, exactly like the serial loop. Costs
-                    # nothing until a deadline has actually passed.
-                    progressed |= self._shed_expired()
-            else:
-                # scheduler hasn't published (first step, or it lost the
-                # race for the mutex): the inline sweep keeps deadline
-                # correctness independent of thread timing
-                progressed = self._shed_expired()
-            sl.pop()
-            sl.push("admit")
-            if self._queue and self._free_slots():
-                # admission needs accurate mirrors → land every in-flight
-                # log first (same stale-mirror gate as the serial loop)
-                self._drain(0)
-                progressed |= self._admit_pending()
-            sl.pop()
-            if self.speculate and self._any_active():
-                # effective in-flight depth 1: the next step's drafts need
-                # this verify's committed ids — the async win here is only
-                # the scheduler/sidecar offload
-                sl.push("dispatch")
-                self._spec_step()
-                sl.pop()
-                progressed = True
-                t0 = time.perf_counter()
-                sl.push("drain")
-                applied = self._drain(0)
-                sl.pop()
-            elif self._any_active():
-                # backpressure BEFORE dispatch: cap un-applied logs at
-                # inflight_steps-1 so the dispatch below tops the window
-                # up to exactly inflight_steps. In steady state the
-                # sidecar has already landed these and this drain pops
-                # nothing — the executor only blocks when the sidecar
-                # fell a full window behind.
-                t0 = time.perf_counter()
-                sl.push("drain")
-                applied = self._drain(self.inflight_steps - 1)
-                sl.pop()
-                self._dispatch_chunk()
-                progressed = True
-            else:
-                t0 = time.perf_counter()
-                sl.push("drain")
-                applied = self._drain(0)
-                sl.pop()
-            dt_apply = time.perf_counter() - t0
-            if progressed or applied:
-                # same attribution as the serial loop: the span's flight-
-                # recorder write is apply-phase work, not step slop
-                sl.push("apply")
-                self._span("apply", dur_s=dt_apply, applied=applied)
-                sl.pop()
-            # NOT here at depth>1: gauge sweep + radix staging — the
-            # scheduler thread does both off the critical path (_plan)
-            snap_due = self._capture_autosnapshot()
-            if (
-                self._health == DEGRADED
-                and not self._step_contained
-                and (
-                    progressed or applied
-                    or not (
-                        self._queue or self._any_active() or self._pending
-                    )
-                )
-            ):
-                self._set_health(SERVING)
-            self._step_contained = False  # consumed: the next boundary
-            # may recover (the serial loop resets at step START instead —
-            # it has no between-step appliers)
-            rows, queued, pending = self._held()
-            sl.end_step(
-                rows=rows, tokens=self.counters.tokens_generated - tok0,
-                queued=queued, pending=pending,
-            )
-            if sched is not None:
-                sched.kick()
-            if sidecar is not None and self._pending:
-                sidecar.notify()
-        if snap_due is not None:
-            self._write_autosnapshot(snap_due)
-        return progressed
-
     def _held(self) -> tuple[int, int, int]:
         """What the server holds right now: (active rows, queued requests,
         un-applied logs) — a step's record carries them as they stand at its
@@ -2938,71 +2489,13 @@ class PipelineServer:
         PREFILL_POSITIONS.labels(kind="pad").inc(positions - prompt_tokens)
         return self.stepline.prefill(rows, prompt_tokens, positions)
 
-    def _apply_delta(self, delta) -> bool:
-        """Act on the scheduler's published delta at a step boundary
-        (mutex held). Every candidate is RE-VALIDATED against live state:
-        plan-time state may be stale by apply time (the request finished,
-        admitted, or was cancelled in between), and a newly-expired
-        request the plan missed is caught by the next delta — the
-        one-boundary staleness ``server_scheduler_lag_seconds`` bounds."""
-        now = time.perf_counter()
-        SCHEDULER_LAG.observe(now - delta.planned_at)
-        shed = False
-        if delta.expire_queued:
-            doomed = {
-                id(r) for r in delta.expire_queued
-                if not r.done and r.deadline_at is not None
-                and now >= r.deadline_at
-            }
-            if doomed:
-                keep: collections.deque = collections.deque()
-                for r in self._queue:
-                    if id(r) in doomed:
-                        _M_DEADLINE.labels(where="queued").inc()
-                        self._fail_request(r, DeadlineExceeded(
-                            f"request {r.id} expired after "
-                            f"{now - r.submitted_at:.3f}s in queue"
-                        ))
-                        shed = True
-                    else:
-                        keep.append(r)
-                self._queue = keep
-        expired = [
-            (i, r) for i, r in delta.expire_rows
-            if self._rows[i] is r and not r.done
-            and r.deadline_at is not None and now >= r.deadline_at
-            and i not in self._admitting_rows
-        ]
-        if expired:
-            try:
-                self._cancel_rows([i for i, _ in expired])
-            except Exception:  # noqa: BLE001 — same guard as the inline
-                # sweep: the requests still fail host-side, the device
-                # rows run to budget exhaustion and free
-                logger.exception(
-                    "deadline cancel dispatch failed for rows %s",
-                    [i for i, _ in expired],
-                )
-            for i, r in expired:
-                _M_DEADLINE.labels(where="in_flight").inc()
-                self._fail_request(r, DeadlineExceeded(
-                    f"request {r.id} expired mid-decode "
-                    f"({len(r.tokens)}/{r.max_new} tokens)"
-                ))
-            shed = True
-        if shed:
-            _update_load_gauges()
-        return shed
-
     def _fetch(self, handle, tag: str) -> _Prefetched:
         """Begin the device→host read of a step's log."""
-        if self._prefetcher is not None:
-            return self._prefetcher.fetch(handle, tag=tag)
-        return _Prefetched(handle, tag, direct=True)
+        return _Prefetched(handle, tag)
 
     def _sweep_gauges_if_due(self) -> bool:
-        """The serial step's paced gauge sweep (``gauge_sweep_every_s``);
-        True if it ran."""
+        """The step's paced gauge sweep (``gauge_sweep_every_s``); True if
+        it ran."""
         now = time.perf_counter()
         if (
             self.gauge_sweep_every_s > 0.0
@@ -3015,12 +2508,6 @@ class PipelineServer:
         self._last_gauge_sweep = now
         return True
 
-    def _sweep_gauges(self) -> None:
-        """Scheduler-thread hook for the paced load-gauge sweep (the
-        module-level ``_update_load_gauges`` is not importable from
-        ``async_exec`` without a cycle)."""
-        _update_load_gauges()
-
     def _dispatch_chunk(self) -> None:
         """Dispatch one interleaved decode chunk, retrying transient
         dispatch failures; a persistent failure is contained (the rows this
@@ -3030,14 +2517,14 @@ class PipelineServer:
             # device-idle estimate: the newest in-flight chunk is the last
             # work the device was given — if its log has already landed on
             # host (done_at stamped), the device has been draining/idle
-            # since then, and this dispatch ends the bubble. The serial
-            # step reads its own logs (_Prefetched) and knows that moment
-            # as the last step's end found it: its estimate is a lower bound
+            # since then, and this dispatch ends the bubble. The step reads
+            # its own logs (_Prefetched) and knows that moment as the last
+            # step's end found it: its estimate is a lower bound
             newest = self._pending[-1][1]
             if newest.landed() and newest.done_at is not None:
                 self.stepline.idle(t0 - newest.done_at)
         self.stepline.push("dispatch")
-        cycles = self.num_stages * self.chunk_cycles
+        cycles = self.num_stages  # one ring cycle: a token a live row
         # the dispatched static, not attn_impl: dense servers compile the
         # programs with attn="xla" (the arg is inert at block_size=0), and
         # the shape key must name the variant the jit cache actually keys
@@ -3090,7 +2577,7 @@ class PipelineServer:
         self._record_blocks_read(
             [i for i, r in enumerate(self._rows)
              if r is not None and not r.done],
-            served=len(self._rows), steps=self.chunk_cycles,
+            served=len(self._rows), steps=1,
         )
         self.stepline.pop()
         dt_dispatch = time.perf_counter() - t0
@@ -3178,15 +2665,13 @@ class PipelineServer:
         snapshotting on a revived daemon — like ``trace_path``, snapshot
         destinations are ops knobs and deliberately NOT serving state, so
         they never ride in the checkpoint's ``serve_kwargs``."""
-        if (path is None) != (every_s is None):
-            raise ValueError(
-                "snapshot_path and snapshot_every_s go together (got "
-                f"path={path!r}, every_s={every_s!r})"
-            )
-        if every_s is not None and every_s < 0:
-            raise ValueError(f"snapshot_every_s must be >= 0, got {every_s}")
-        self._snapshot_path = path
-        self._snapshot_every_s = every_s
+        options = dataclasses.replace(
+            self.options, snapshot_path=path, snapshot_every_s=every_s
+        )
+        options.validate()
+        self.options = options
+        self.snapshot_path = path
+        self.snapshot_every_s = every_s
         self._last_snapshot_at = time.perf_counter()
 
     def result(self, req: Request) -> list:
@@ -3253,13 +2738,6 @@ class PipelineServer:
             # wait for any more
             self.__dict__.pop("_fetch", None)
         SETUP.log_account("close")
-        # async-executor threads: signal outside the mutex (their loops
-        # re-check _closed under it) and join bounded — a parked thread
-        # wakes within its condition-wait timeout
-        for t in (self._scheduler, self._sidecar):
-            if t is not None:
-                t.stop()
-                t.join(timeout=2.0)
         logger.info("server closed")
 
     def cancel(self, req: Request) -> bool:
@@ -3730,7 +3208,7 @@ class PipelineServer:
         read, no new program: the tables are data."""
         if not self.windowed:
             return
-        ahead = (len(self._pending) + 1) * self.chunk_cycles + 1
+        ahead = len(self._pending) + 2
         freed = 0
         for row, req in enumerate(self._rows):
             if (
@@ -4014,9 +3492,7 @@ class PipelineServer:
 
     # ------------------------------------ live migration (dp supervision)
 
-    def extract(
-        self, req: Request, *, settle: Optional[bool] = None
-    ) -> RequestState:
+    def extract(self, req: Request, *, settle: bool = False) -> RequestState:
         """Pull a LIVE request off this server as portable host-side state
         (``RequestState``) WITHOUT failing it: the request leaves the queue
         or its slot row (device cancel is best-effort — a dead replica's
@@ -4032,15 +3508,13 @@ class PipelineServer:
         consumers saw (a dispatched-but-unapplied chunk's tokens were never
         yielded; the adopter simply regenerates them, token-identically).
 
-        ``settle``: with the async executor (``inflight_steps>1``) several
-        chunks' tokens may be in flight — settling (``_drain(0)``) first
-        lands them so the migrated state carries every token the device
-        already computed instead of re-generating them on the adopter.
-        ``None`` (default) settles exactly when it can succeed: a healthy
-        (SERVING) async server with pending logs. Failover passes
-        ``settle=False`` — a dead replica's fetch would only convert
-        migratable requests into contained failures; its in-flight tokens
-        REPLAY on the adopter, token-identically, which is the documented
+        ``settle``: a dispatched chunk's tokens may be in flight — settling
+        (``_drain(0)``) first lands them, so the migrated state carries
+        every token the device already computed instead of re-generating
+        them on the adopter (an elective migration's way). Failover leaves
+        it ``False`` — a dead replica's fetch would only convert migratable
+        requests into contained failures; its in-flight tokens REPLAY on
+        the adopter, token-identically, which is the documented
         drain-or-replay contract.
 
         On a SPECULATIVE sampled server the device chain advances per
@@ -4055,12 +3529,6 @@ class PipelineServer:
         run at ANY cp (a different-cp survivor re-admits through chunked
         prefill and regenerates nothing the consumer saw)."""
         with self._mutex:
-            if settle is None:
-                settle = (
-                    self.inflight_steps > 1
-                    and self._health == SERVING
-                    and not self._closed
-                )
             if settle and self._pending and not req.done:
                 self._drain(0)
             if req.done:
@@ -4252,7 +3720,6 @@ class PipelineServer:
                 self._queue.appendleft(req)
             else:
                 self._queue.append(req)
-            self._arm_deadline(req.deadline_at)
             self._span(
                 "adopt", req=req, resumed_prompt=req.prompt_len,
                 remaining=remaining,
@@ -4267,8 +3734,8 @@ class PipelineServer:
     # ------------------------------------------------- resilience internals
 
     def _fault_check(self, site: str, key=None) -> None:
-        if self._fault_plan is not None:
-            self._fault_plan.check(site, key=key)
+        if self.fault_plan is not None:
+            self.fault_plan.check(site, key=key)
 
     def _retry(self, site: str, fn, real_ok: bool = True):
         """Run ``fn``, absorbing transient failures (injected
@@ -4285,21 +3752,21 @@ class PipelineServer:
         retryable where the operation is re-issuable: log fetch
         (``get_retryable`` re-reads from the kept handle) and snapshot
         capture."""
-        delays = backoff_delays(self._fault_retries, self._fault_backoff_s)
-        retryable = self._retryable if real_ok else ()
+        delays = backoff_delays(self.fault_retries, self.fault_backoff_s)
+        retryable = self.retryable_exceptions if real_ok else ()
         attempt = 0
         while True:
             try:
                 return fn()
             except Exception as e:  # noqa: BLE001 — classified right below
-                if attempt >= self._fault_retries or not is_transient(
+                if attempt >= self.fault_retries or not is_transient(
                     e, retryable
                 ):
                     raise
                 _M_RETRIES.labels(site=site).inc()
                 logger.warning(
                     "transient failure at %s (attempt %d/%d): %r",
-                    site, attempt + 1, self._fault_retries, e,
+                    site, attempt + 1, self.fault_retries, e,
                 )
                 if delays[attempt]:
                     time.sleep(delays[attempt])
@@ -4416,17 +3883,6 @@ class PipelineServer:
             ]
         self._contain_rows("log_fetch", victims, err)
 
-    def _arm_deadline(self, deadline_at: Optional[float]) -> None:
-        """Tighten ``_deadline_hint`` for a request entering the queue
-        (mutex held): the hint stays a lower bound on the earliest live
-        deadline, so the async executor's inline backstop sweep fires at
-        (or before) every actual expiry without scanning per step."""
-        if deadline_at is not None and (
-            self._deadline_hint is None
-            or deadline_at < self._deadline_hint
-        ):
-            self._deadline_hint = deadline_at
-
     def _shed_expired(self) -> bool:
         """Deadline sweep, start of every step: expired queued requests are
         shed before they ever cost a prefill; expired in-flight rows are
@@ -4476,16 +3932,6 @@ class PipelineServer:
             shed = True
         if shed:
             _update_load_gauges()
-        # the sweep touched every live request anyway — recompute the
-        # hint exactly so the async executor's backstop stops firing
-        # until the next real deadline approaches
-        hints = [
-            r.deadline_at for r in self._queue if r.deadline_at is not None
-        ] + [
-            r.deadline_at for r in self._rows
-            if r is not None and not r.done and r.deadline_at is not None
-        ]
-        self._deadline_hint = min(hints) if hints else None
         return shed
 
     def _capture_autosnapshot(self) -> Optional[dict]:
@@ -4497,10 +3943,10 @@ class PipelineServer:
         snapshot source must never stop serving. The interval clock
         advances on failure too, so a persistently failing capture costs
         one attempt per interval, not one per step."""
-        if self._snapshot_every_s is None:
+        if self.snapshot_every_s is None:
             return None
         now = time.perf_counter()
-        if now - self._last_snapshot_at < self._snapshot_every_s:
+        if now - self._last_snapshot_at < self.snapshot_every_s:
             return None
         self._last_snapshot_at = now
 
@@ -4519,7 +3965,7 @@ class PipelineServer:
         """The disk half of auto-snapshot (atomic tmp+rename), lock-free: a
         full disk is counted, never fatal."""
         try:
-            save_snapshot(snap, self._snapshot_path)
+            save_snapshot(snap, self.snapshot_path)
         except Exception as e:  # noqa: BLE001 — kept serving
             _M_SNAPSHOT_FAIL.inc()
             logger.warning("auto-snapshot write failed: %r", e)
@@ -5402,23 +4848,9 @@ class PipelineServer:
         sl.pop()
         return applied
 
-    def _drain_landed(self) -> int:
-        """Sidecar drain (mutex held): apply every in-flight entry whose
-        log has already LANDED on host, oldest first, stopping at the
-        first still-in-flight one — applies are ordered and this path
-        never blocks. The builder calls inside ``_apply_entry`` no-op
-        safely here: the mutex guarantees the pump is between steps, so
-        the profiler has no open step."""
-        applied = 0
-        while self._pending and self._pending[0][1].landed():
-            self._apply_entry(self._pending.popleft())
-            applied += 1
-        return applied
-
     def _apply_entry(self, entry, park_counts: bool = False) -> bool:
         """Fetch (with retry/containment) and apply ONE popped ``_pending``
-        entry; shared by the blocking ``_drain`` and the sidecar's
-        ``_drain_landed``. Returns False when the log was lost and its
+        entry of ``_drain``. Returns False when the log was lost and its
         requests were failed (``_contain_lost_log``) — draining continues
         with the next entry either way. With ``park_counts`` the counters
         behind the tokens wait for ``_settle_counts`` (the next step's, while
@@ -5543,7 +4975,7 @@ class PipelineServer:
         ``request_apply`` fault keyed to this request's id fails exactly
         this request (its row frees, co-resident rows keep decoding) —
         the poisoned-request containment the chaos suite exercises."""
-        if self._fault_plan is not None:
+        if self.fault_plan is not None:
             try:
                 self._retry(
                     "request_apply",

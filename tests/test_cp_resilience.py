@@ -68,28 +68,6 @@ def setup():
     return params, eng
 
 
-@pytest.fixture(scope="module", autouse=True)
-def _inflight_env():
-    """``SERVE_TEST_INFLIGHT=N`` reruns the module with the async executor
-    at depth N (the CI cp lane sets 2): every snapshot/migration/hand-off
-    here must hold while overlapped dispatches are in flight."""
-    depth = int(os.environ.get("SERVE_TEST_INFLIGHT", "1") or "1")
-    if depth <= 1:
-        yield
-        return
-    orig = PipelineEngine.serve
-
-    def serve(self, **kw):
-        kw.setdefault("inflight_steps", depth)
-        return orig(self, **kw)
-
-    PipelineEngine.serve = serve
-    try:
-        yield
-    finally:
-        PipelineEngine.serve = orig
-
-
 def oracle(params, p, n, **kw):
     res = generate(CFG, params, p, n, cache_dtype=jnp.float32, **kw)
     return [int(x) for x in res.tokens[0, len(p): int(res.lengths[0])]]

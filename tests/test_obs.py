@@ -239,14 +239,14 @@ def _get(port, path):
 
 
 def test_prefetch_error_names_its_chunk():
-    from llm_sharding_tpu.runtime.server import _Prefetcher
+    from llm_sharding_tpu.runtime.server import _Prefetched
 
     class Exploding:
         def __array__(self, *a, **k):
             raise RuntimeError("transfer died")
 
     before = REGISTRY.get("server_fetch_failures_total").value
-    p = _Prefetcher.shared().fetch(Exploding(), tag="chunk m0=17")
+    p = _Prefetched(Exploding(), tag="chunk m0=17")
     with pytest.raises(RuntimeError, match=r"chunk m0=17"):
         p.get()
     assert REGISTRY.get("server_fetch_failures_total").value == before + 1
@@ -274,14 +274,14 @@ class _Slow:
 
 
 def test_a_direct_read_begins_at_dispatch_and_never_waits_to_say_landed():
-    """The serial step's way: the copy begins when the read is made, a look
+    """The step's way: the copy begins when the read is made, a look
     at it reads nothing while the device works, and the first look after
     the device is done finishes it and stamps when."""
     from llm_sharding_tpu.runtime.server import _Prefetched
 
     h = _Slow([3, 1, 4])
-    p = _Prefetched(h, tag="chunk m0=4", direct=True)
-    assert h.began and p.event is None
+    p = _Prefetched(h, tag="chunk m0=4")
+    assert h.began
     assert not p.landed() and h.reads == 0 and p.done_at is None
     h.ready = True
     assert p.landed() and h.reads == 1 and p.done_at is not None
@@ -294,7 +294,7 @@ def test_a_direct_read_waits_on_its_own_thread():
     from llm_sharding_tpu.runtime.server import _Prefetched
 
     h = _Slow([7])
-    p = _Prefetched(h, tag="admit slot=0", direct=True)
+    p = _Prefetched(h, tag="admit slot=0")
     assert list(p.get_retryable()) == [7] and h.reads == 1  # not ready: it waits
     assert p.landed() and p.done_at is not None
 
@@ -304,7 +304,7 @@ def test_a_failed_direct_read_keeps_its_handle_and_names_its_chunk():
 
     before = REGISTRY.get("server_fetch_failures_total").value
     h = _Slow([1, 2], fail=2)
-    p = _Prefetched(h, tag="chunk m0=17", direct=True)
+    p = _Prefetched(h, tag="chunk m0=17")
     h.ready = True
     assert p.landed() and p.error is not None and p.handle is h
     assert REGISTRY.get("server_fetch_failures_total").value == before + 1

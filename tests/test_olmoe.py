@@ -237,8 +237,7 @@ def test_a_decoding_step_counts_the_log_before_it_waits_for_the_next(params):
     """What a log carries beside its tokens is counted by the NEXT step,
     while the device works and before that step waits (the tokens alone lie
     between a log's landing and a reader's eyes): in steady decode the
-    series run one log behind the tokens; an idle server's are whole; the
-    serial step reads its logs on its own thread."""
+    series run one log behind the tokens; an idle server's are whole."""
     from llm_sharding_tpu.obs.metrics import REGISTRY
 
     fam = REGISTRY.get("server_moe_expert_tokens_total")
@@ -247,13 +246,12 @@ def test_a_decoding_step_counts_the_log_before_it_waits_for_the_next(params):
                          cache_dtype=jnp.float32)
     srv = eng.serve(capacity=128, batch_per_slot=2, kv_block_size=8,
                     kv_blocks=65, prefill_chunk=16, prefix_cache="hbm")
-    assert srv._prefetcher is None
     k, L = 2, 2
     t0 = total()
     req = srv.submit(PROMPTS[0], NEW)
     while len(req.tokens) < 4:
         srv.step()
-    assert srv._pending and srv._pending[-1][1].event is None
+    assert srv._pending
     assert len(srv._parked_counts) == 1  # the log whose token just surfaced
     seen = total() - t0
     srv.step()  # one more token: the parked log is counted, the next parked

@@ -329,7 +329,7 @@ def test_quarantine_reroutes_queued_requests(params):
     assert in_flight and queued
     # poison exactly this replica's decode dispatch (a per-replica plan:
     # the shared-plan sites would fault every replica at once)
-    victim._fault_plan = FaultPlan.permanent("chunk_dispatch")
+    victim.fault_plan = FaultPlan.permanent("chunk_dispatch")
     srv.run_until_idle()
     assert len(srv.servers) == DP - 1
     for r in in_flight:

@@ -18,7 +18,9 @@ from llm_sharding_tpu.runtime.server import (
     PipelineServer, load_snapshot, save_snapshot,
 )
 
-CFG = tiny_llama(num_hidden_layers=8)
+# an end-of-text id the 256-token vocabulary cannot emit: random weights then
+# never end a request before a test extracts or snapshots it
+CFG = tiny_llama(num_hidden_layers=8, eos_token_id=256)
 
 
 @pytest.fixture(scope="module")
@@ -496,6 +498,53 @@ def test_adopt_refuses_oversized_resume(two_servers):
     assert not r.done and r.error is None  # still adoptable elsewhere
     sb.adopt(st, r)
     assert sb.result(r) == oracle(params, p, 12)
+
+
+def test_mid_flight_snapshot_restore_token_exact(setup):
+    """snapshot() with a dispatched chunk's log still in flight settles to a
+    step boundary first; the restored server finishes every request
+    token-identically to the uninterrupted oracle."""
+    params, eng = setup
+    srv = eng.serve(capacity=64)
+    rng = np.random.default_rng(31)
+    ps = [rng.integers(1, CFG.vocab_size, 5).astype(np.int32) for _ in range(3)]
+    reqs = [srv.submit(p, 12) for p in ps]
+    for _ in range(4):
+        srv.step()
+    assert srv._pending  # a chunk dispatched, its tokens not yet applied
+    seen = [len(r.tokens) for r in reqs]
+    snap = srv.snapshot()
+    assert snap["format"] == 8 and not srv._pending
+    # the settle landed them
+    assert all(len(r.tokens) > n for r, n in zip(reqs, seen))
+    srv.close()
+    srv2 = PipelineServer.restore(eng, snap)
+    restored = {
+        r.id: r for r in srv2._rows + list(srv2._queue) if r is not None
+    }
+    srv2.run_until_idle()
+    for r, p in zip(reqs, ps):
+        assert restored[r.id].tokens == oracle(params, p, 12)
+    srv2.close()
+
+
+@pytest.mark.parametrize("settle", [True, False])
+def test_extract_settles_in_flight_dispatches(two_servers, settle):
+    """extract(settle=True) — an elective migration's — lands the chunk in
+    flight first, so the migrated state carries its tokens; without it
+    (failover's) they replay on the adopter. Token-identical either way."""
+    params, src, dst = two_servers
+    p = np.random.default_rng(37).integers(1, CFG.vocab_size, 5).astype(np.int32)
+    r = src.submit(p, 14)
+    for _ in range(3):
+        src.step()
+    assert src._pending
+    seen = len(r.tokens)
+    st = src.extract(r, settle=settle)
+    assert (len(r.tokens) > seen) == settle and not (settle and src._pending)
+    dst.adopt(st, r)
+    assert dst.result(r) == oracle(params, p, 14)
+    src.run_until_idle()  # (the log left in flight applies to no one)
 
 
 def test_migrated_request_snapshot_roundtrip(two_servers, tmp_path):
