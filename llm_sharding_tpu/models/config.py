@@ -81,6 +81,17 @@ class ModelConfig:
     num_experts_per_tok: int = 0
     norm_topk_prob: bool = False
     qk_norm: bool = False
+    # ``KeyeVL2`` (the llama block again, ``models/llama.py``): the q/k
+    # RMSNorm is over each HEAD's ``head_dim`` (``qk_norm_per_head``; gains
+    # ``[head_dim]``), and a learned indexer chooses which keys a query
+    # attends (DeepSeek-V3.2-Exp's sparse attention, over GQA): ``index_heads``
+    # index queries of ``index_head_dim`` against ONE index key a token (a
+    # third paged arena), the ``index_topk`` best-scored positions kept — all
+    # of them while the context is no longer than that.
+    qk_norm_per_head: bool = False
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
     # ``deepseek_v3`` (models/deepseek_v3.py). Latent attention (MLA): q through
     # a rank-``q_lora_rank`` bottleneck, keys and values decompressed from ONE
     # latent of ``kv_lora_rank`` a token, plus ``qk_rope_head_dim`` rotated
@@ -193,6 +204,21 @@ class ModelConfig:
         """Some layers attend a sliding window: they keep a KV state of their
         own (an arena and a block table per kind of attention)."""
         return self.sliding_window > 0 and any(self.layer_attn)
+
+    @property
+    def sparse_attn(self) -> bool:
+        """A query attends the ``index_topk`` keys its indexer scores highest:
+        every layer keeps an index key a token beside K and V (a third arena
+        under the same block table, ``ServeState.idx``)."""
+        return self.index_topk > 0
+
+    @property
+    def index_cache_dim(self) -> int:
+        """Lanes of one STORED index key: ``index_head_dim`` padded with zeros
+        to whole 128-lane tiles, as ``mimo_v2`` stores its 192-wide key in 256
+        (at 64 lanes the chip's compiler gives the arena a layout of its own
+        for the gather and re-lays all of it around every write kernel)."""
+        return -(-self.index_head_dim // 128) * 128
 
     @property
     def recurrent(self) -> bool:
@@ -386,6 +412,9 @@ class ModelConfig:
             )
             hf = dict(hf, model_type="llama")
             mt = "llama"
+        elif mt == "KeyeVL2":
+            hf, moe = cls._keye_vl2_keys(hf)
+            mt = "llama"
         else:
             moe = {}
         if mt == "deepseek_v3":
@@ -470,6 +499,57 @@ class ModelConfig:
                 eos_token_id=hf.get("eos_token_id", 50256),
             )
         raise ValueError(f"unsupported model_type: {mt!r}")
+
+    @staticmethod
+    def _keye_vl2_keys(hf: dict[str, Any]) -> tuple:
+        """Keye-VL-2.0's language model as the llama block's keys: the
+        Qwen3-MoE shape (per-head q/k RMSNorm, ``num_experts`` softmax-routed
+        experts of ``moe_intermediate_size`` in EVERY layer) with
+        ``sa_config``'s indexer. ``mrope_section`` is accepted: with text
+        alone the three position streams are equal and the rotation is the
+        plain one. The vision tower has no keys and is not built. What the
+        block cannot honour is refused by name."""
+        sa = hf.get("sa_config") or {}
+        for key in ("indexer_num_heads", "indexer_head_dim", "topk"):
+            if not sa.get(key):
+                raise ValueError(f"KeyeVL2 sa_config lacks {key!r}")
+        if sa.get("indexer_num_kv_heads", 1) != 1:
+            raise ValueError(
+                "KeyeVL2 sa_config.indexer_num_kv_heads "
+                f"{sa['indexer_num_kv_heads']}: the index arena holds ONE "
+                "index key a token"
+            )
+        if hf.get("mlp_only_layers") or hf.get("decoder_sparse_step", 1) != 1:
+            raise ValueError(
+                "KeyeVL2 with dense MLP layers (mlp_only_layers / "
+                "decoder_sparse_step != 1) is not supported: every layer's "
+                "MLP is the routed experts"
+            )
+        if hf.get("use_sliding_window") or hf.get("sliding_window"):
+            raise ValueError("KeyeVL2 sliding-window attention is not supported")
+        rs = hf.get("rope_scaling") or {}
+        if rs.get("rope_type", rs.get("type", "default")) != "default":
+            raise ValueError(
+                f"KeyeVL2 rope_scaling {rs!r}: only the default rotation "
+                "(with an mrope_section, read as plain rotary for text)"
+            )
+        moe = dict(
+            num_experts=hf["num_experts"],
+            num_experts_per_tok=hf["num_experts_per_tok"],
+            norm_topk_prob=bool(hf.get("norm_topk_prob", False)),
+            qk_norm=True,
+            qk_norm_per_head=True,
+            index_heads=int(sa["indexer_num_heads"]),
+            index_head_dim=int(sa["indexer_head_dim"]),
+            index_topk=int(sa["topk"]),
+        )
+        # the expert width is the llama block's ``intermediate_size`` (the
+        # published ``intermediate_size`` is the dense MLP's, which no layer has)
+        hf = dict(
+            hf, model_type="llama", rope_scaling=None,
+            intermediate_size=hf["moe_intermediate_size"],
+        )
+        return hf, moe
 
     @classmethod
     def _from_deepseek_v3(cls, hf: dict[str, Any]) -> "ModelConfig":
@@ -1094,6 +1174,45 @@ def tiny_olmoe(**kw) -> ModelConfig:
     )
     base.update(kw)
     return ModelConfig.from_hf_config(base)
+
+
+def tiny_keye_vl2_keys(**kw) -> dict:
+    """The published-style keys of ``tiny_keye_vl2``: GQA 4 / 2 heads of 16,
+    per-head q/k norm, 8 experts of width 32 (2 a token, renormalised), an
+    indexer of 4 heads x 8 over one 8-wide index key, ``topk`` 16."""
+    base = dict(
+        model_type="KeyeVL2",
+        vocab_size=256,
+        hidden_size=64,
+        intermediate_size=128,
+        moe_intermediate_size=32,
+        num_hidden_layers=2,
+        num_attention_heads=4,
+        num_key_value_heads=2,
+        head_dim=16,
+        max_position_embeddings=256,
+        rms_norm_eps=1e-6,
+        rope_theta=10000.0,
+        rope_scaling={"mrope_section": [2, 3, 3], "rope_type": "default",
+                      "type": "default"},
+        num_experts=8,
+        num_experts_per_tok=2,
+        norm_topk_prob=True,
+        decoder_sparse_step=1,
+        mlp_only_layers=[],
+        sa_config={"indexer_head_dim": 8, "indexer_num_heads": 4,
+                   "indexer_num_kv_heads": 1, "kv_chunk_size": 512,
+                   "q_chunk_size": 512, "topk": 16},
+        tie_word_embeddings=False,
+    )
+    base.update(kw)
+    return base
+
+
+def tiny_keye_vl2(**kw) -> ModelConfig:
+    """Tiny KeyeVL2-layout config (a token-selecting llama block) for CPU
+    tests."""
+    return ModelConfig.from_hf_config(tiny_keye_vl2_keys(**kw))
 
 
 def tiny_deepseek_v3_keys(**kw) -> dict:

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.flash_attention import attention_step
-from ..ops.norms import rms_norm
+from ..ops.norms import layer_norm, rms_norm
 from ..ops.quant import embed_rows, head_logits, out_dim, qmatmul, tied_logits
 from ..ops.rope import apply_rope, rope_cos_sin
 from .cache import KVCache
@@ -84,9 +84,23 @@ def init_layer_params(
             w_gate=w(ks[4], H, I), w_up=w(ks[5], H, I), w_down=w(ks[6], I, H)
         )
     if cfg.qk_norm:
-        # RMSNorm gains over the whole projected q / k width (OLMoE)
-        p["q_norm"] = jnp.ones((L, Nh * D), dtype)
-        p["k_norm"] = jnp.ones((L, Nkv * D), dtype)
+        # RMSNorm gains over the whole projected q / k width (OLMoE), or
+        # over one head's (``qk_norm_per_head``: KeyeVL2)
+        per_head = cfg.qk_norm_per_head
+        p["q_norm"] = jnp.ones((L, D if per_head else Nh * D), dtype)
+        p["k_norm"] = jnp.ones((L, D if per_head else Nkv * D), dtype)
+    if cfg.sparse_attn:
+        # the indexer that chooses a query's keys: ``index_heads`` index
+        # queries, ONE index key a token (LayerNorm with bias) and a weight
+        # an index head; keyed by presence like every other leaf
+        HI, DI = cfg.index_heads, cfg.index_head_dim
+        ki = jax.random.split(jax.random.fold_in(key, 8), 3)
+        p.update(
+            wq_idx=w(ki[0], H, HI * DI), wk_idx=w(ki[1], H, DI),
+            w_idx=w(ki[2], H, HI),
+            k_idx_norm=jnp.ones((L, DI), dtype),
+            k_idx_bias=jnp.zeros((L, DI), dtype),
+        )
     if cfg.attention_bias:
         # qkv biases (the Qwen2-family layout: q/k/v biased, o not); presence
         # of the keys — not the flag — drives the forward path, so converted
@@ -146,11 +160,14 @@ def attn_mlp_block(
     h: jnp.ndarray,  # [B, S, H]
     cos: jnp.ndarray,
     sin: jnp.ndarray,
-    attn_fn,  # (q[B,S,Nh,D], k[B,S,Nkv,D], v[B,S,Nkv,D]) -> [B,S,Nh,D]
+    attn_fn,  # (q[B,S,Nh,D], k[B,S,Nkv,D], v[B,S,Nkv,D]) -> [B,S,Nh,D];
+    #   a token-selecting model's takes ``index=`` too (below)
     tp_axis: Optional[str] = None,
     moe_live: Optional[jnp.ndarray] = None,  # [B, S] bool: positions that
     #   route (a model with experts only; None = all of them)
     moe_backend: str = "auto",
+    index_rope=None,  # (cos, sin) [B, S, index_head_dim] — the indexer's
+    #   own rotation (a token-selecting model only)
 ):
     """One llama block with the attention mechanism injected — the single
     implementation behind the cached (pipeline/decode) path and the
@@ -192,6 +209,9 @@ def attn_mlp_block(
             kx = kx + p["bk"]
         if "bv" in p:
             vx = vx + p["bv"]
+        if cfg.qk_norm_per_head:
+            # (q too where the heads are split before the norm: see below)
+            qx = jax.lax.optimization_barrier(qx)
         # k and v leave the projection as the dot made them. Without this
         # edge XLA folds the head split below into the two small dots: a
         # decode step's ``[B, H] @ [H, Nkv*D]`` becomes a convolution
@@ -203,8 +223,11 @@ def attn_mlp_block(
         kx, vx = jax.lax.optimization_barrier((kx, vx))
     if "q_norm" in p:
         # OLMoE: an RMSNorm over the WHOLE projected width of q and of k,
-        # before the heads are split and rotated; keyed by presence
+        # before the heads are split and rotated; KeyeVL2: over each HEAD's
+        # width (the gains are one head wide); keyed by presence
         with jax.named_scope("norm"):
+            if cfg.qk_norm_per_head:
+                qx, kx = qx.reshape(B, S, Nh, D), kx.reshape(B, S, Nkv, D)
             qx = rms_norm(qx, p["q_norm"], cfg.rms_norm_eps, cfg.norm_offset)
             kx = rms_norm(kx, p["k_norm"], cfg.rms_norm_eps, cfg.norm_offset)
     with jax.named_scope("rope"):
@@ -212,7 +235,28 @@ def attn_mlp_block(
         k = apply_rope(kx.reshape(B, S, Nkv, D), cos, sin)
         v = vx.reshape(B, S, Nkv, D)
 
-    attn = attn_fn(q, k, v)
+    if "wq_idx" in p:
+        # the indexer (DeepSeek-V3.2-Exp, eq. 1), off the same normed input:
+        # index queries and the token's ONE index key, both rotated over
+        # their whole width, and a weight an index head. The attention
+        # mechanism scores, selects and attends (``ops/paged_attention``)
+        HI, DI = cfg.index_heads, cfg.index_head_dim
+        with jax.named_scope("indexer"):
+            # (held as the dots made them, like k and v above: a head split
+            # folded into the dot re-lays the weight's whole layer stack)
+            qi, ki, wi = jax.lax.optimization_barrier((
+                qmatmul(x, p["wq_idx"]), qmatmul(x, p["wk_idx"]),
+                qmatmul(x, p["w_idx"]),
+            ))
+            ki = layer_norm(
+                ki, p["k_idx_norm"], p["k_idx_bias"], cfg.rms_norm_eps
+            )
+            qi = apply_rope(qi.reshape(B, S, HI, DI), *index_rope)
+            ki = apply_rope(ki.reshape(B, S, 1, DI), *index_rope)
+            wi = wi.astype(jnp.float32) * (HI * DI) ** -0.5
+        attn = attn_fn(q, k, v, index=(qi, ki, wi))
+    else:
+        attn = attn_fn(q, k, v)
     with jax.named_scope("o_proj"):
         attn_out = qmatmul(attn.reshape(B, S, Nh * D), p["wo"])
         if tp_axis is not None:
@@ -270,6 +314,11 @@ def decoder_layer(
     moe_live: Optional[jnp.ndarray] = None,
 ):
     """Returns ``(h, k_row, v_row, stats)`` (``attn_mlp_block``)."""
+    if cfg.sparse_attn:
+        raise NotImplementedError(
+            "a token-selecting model (an indexer beside the attention) runs "
+            "over the paged arenas only: a dense cache row holds no index keys"
+        )
     rows = {}
 
     def attn_fn(q, k, v):
@@ -295,7 +344,9 @@ def paged_decoder_layer(
     layer: jnp.ndarray,  # scalar int32 — this layer's index in the stacks
     valid: jnp.ndarray,  # scalar bool — masked (padding) layer gate
     h: jnp.ndarray,  # [B, S, H]
-    k_arena: jnp.ndarray,  # [L, NB, Nkv, BS, D] the WHOLE stacked pool
+    k_arena: jnp.ndarray,  # [L, NB, Nkv, BS, D] the WHOLE stacked pool; a
+    #   token-selecting model's (``cfg.sparse_attn``): the pair ``(k_arena,
+    #   idx_arena [L, NB, 1, BS, cfg.index_cache_dim])``, and so it comes back
     v_arena: jnp.ndarray,
     block_table: jnp.ndarray,  # [B, T]
     cols: jnp.ndarray,  # [B, S] logical columns of this step's entries
@@ -313,6 +364,7 @@ def paged_decoder_layer(
     walk=None,  # the prefill kernel's work list (``prefill_walk``)
     cp_axis: Optional[str] = None,  # context-parallel combine axis
     moe_live: Optional[jnp.ndarray] = None,  # [B, S] positions that route
+    index_rope=None,  # a token-selecting model: the indexer's (cos, sin)
 ):
     """Decode-path layer over the pooled arena: the step's fresh KV lands
     in the blocks the table names of layer ``layer`` of the stacked pool
@@ -338,20 +390,42 @@ def paged_decoder_layer(
     ``(acc, m, l)`` softmax statistics over the local blocks and
     ``combine_attn_stats`` reduces them across ``cp_axis`` with the
     flash recurrence — the combined output equals attention over the
-    full window, so everything downstream stays shard-replicated."""
+    full window, so everything downstream stays shard-replicated.
+
+    A token-selecting model (``cfg.sparse_attn``): the layer's index key
+    lands in the index arena beside K and V, under the same table, and the
+    attention reads the ``cfg.index_topk`` keys the indexer scores highest
+    (``select=``: a decode step gathers exactly those tokens; a chunk masks
+    the others out of the dense product)."""
     from ..ops.paged_attention import (
-        combine_attn_stats, paged_attention_write, paged_prefill,
-        write_chunk_kv,
+        Selection, combine_attn_stats, paged_attention_write, paged_prefill,
+        write_chunk_kv, write_index_keys,
     )
 
     out = {}
     cp = cp_axis is not None
+    idx_arena = None
+    if cfg.sparse_attn:
+        if cp or tp_axis is not None or k_scale is not None:
+            raise NotImplementedError(
+                "a token-selecting model under tp / cp or over a quantized "
+                "arena is not implemented (the index arena is not carried)"
+            )
+        k_arena, idx_arena = k_arena
     # a chunk's rows share their columns: it writes whole blocks from its
     # first column on
     col0 = cols[0, 0] if prefill else None
 
-    def attn_fn(q, k, v):
+    def attn_fn(q, k, v, index=None):
         gate = write_valid & valid
+        select = None
+        if index is not None:
+            qi, ki, wi = index
+            out["idx"] = write_index_keys(
+                idx_arena, layer, block_table, col0 if prefill else cols, ki,
+                valid=gate, backend=backend,
+            )
+            select = Selection(qi, wi, out["idx"], cfg.index_topk)
         if prefill:
             kv = write_chunk_kv(
                 k_arena, v_arena, layer, block_table, col0, k, v,
@@ -362,7 +436,7 @@ def paged_decoder_layer(
             o = paged_prefill(
                 q, k_a, v_a, layer, block_table, positions, kv_positions,
                 backend=backend, k_scale=ks, v_scale=vs, stats=cp,
-                walk=walk,
+                walk=walk, select=select,
             )
         else:
             # a decode step: a row's entries at its own columns, written
@@ -370,7 +444,7 @@ def paged_decoder_layer(
             o, *out["kv"] = paged_attention_write(
                 q, k, v, k_arena, v_arena, layer, block_table, cols,
                 positions, kv_positions, valid=gate, backend=backend,
-                k_scale=k_scale, v_scale=v_scale, stats=cp,
+                k_scale=k_scale, v_scale=v_scale, stats=cp, select=select,
             )
         if cp:
             return combine_attn_stats(*o, cp_axis).astype(q.dtype)
@@ -385,9 +459,12 @@ def paged_decoder_layer(
         )
     h, stats = attn_mlp_block(
         cfg, p, h, cos, sin, attn_fn, tp_axis, moe_live=moe_live,
-        moe_backend=backend,
+        moe_backend=backend, index_rope=index_rope,
     )
-    return (h, *out["kv"], stats)
+    k_new, *rest = out["kv"]
+    if idx_arena is not None:
+        k_new = (k_new, out["idx"])
+    return (h, k_new, *rest, stats)
 
 
 def forward_layers_paged(
@@ -426,6 +503,12 @@ def forward_layers_paged(
 
     with jax.named_scope("rope"):
         cos, sin = rope_cos_sin(positions, cfg, dtype=jnp.float32)
+        index_rope = None
+        if cfg.sparse_attn:
+            # the indexer rotates its 64-wide vectors whole, at the same base
+            index_rope = rope_cos_sin(
+                positions, cfg, dtype=jnp.float32, dim=cfg.index_head_dim
+            )
     wv = write_valid if isinstance(write_valid, bool) else jnp.asarray(
         write_valid
     )
@@ -435,7 +518,7 @@ def forward_layers_paged(
             cfg, p, l, valid, h, k_all, v_all, block_table, cols, cos, sin,
             positions, kv_positions, wv, tp_axis, backend,
             k_scale=ks_all, v_scale=vs_all, prefill=prefill, walk=walk,
-            cp_axis=cp_axis, moe_live=moe_live,
+            cp_axis=cp_axis, moe_live=moe_live, index_rope=index_rope,
         )
 
     return scan_layers_paged(

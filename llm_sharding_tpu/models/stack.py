@@ -189,7 +189,9 @@ def scan_layers_paged(
     unquantized carry is unchanged). Returns ``(h, k_arena, v_arena,
     k_scale, v_scale, stats)`` — the scale outputs are None when the arena
     is unquantized."""
-    L = k_arena.shape[0] if layer_mask is None else layer_mask.shape[0]
+    # (``k_arena`` may be a pair, a token-selecting model's K and index
+    # arenas: a carry is a pytree)
+    L = v_arena.shape[0] if layer_mask is None else layer_mask.shape[0]
     if layer_mask is None:
         layer_mask = jnp.ones((L,), bool)
     layers, whole = split_whole(layers)

@@ -529,7 +529,8 @@ KV_ENTRY_BYTES = REGISTRY.gauge(
 KV_KIND_BLOCKS_TOTAL = REGISTRY.gauge(
     "server_kv_kind_blocks_total",
     "Allocatable KV arena blocks of each kind of attention layer's pool "
-    "(kind = full | swa) across live servers of a windowed model",
+    "(kind = full | swa) across live servers of a windowed model; a "
+    "token-selecting model: kind = kv | index, the two arenas of the ONE pool",
     labels=("kind",),
 )
 KV_KIND_BLOCKS_IN_USE = REGISTRY.gauge(
@@ -544,6 +545,26 @@ KV_KIND_ENTRY_BYTES = REGISTRY.gauge(
     "(kv heads x (padded key + value) x itemsize) of the newest windowed "
     "server",
     labels=("kind",),
+)
+# a token-selecting model (a learned sparse-attention indexer,
+# ``cfg.sparse_attn``): per decode step, summed over layers, host-side from
+# the length mirrors — read / live is the share of the context a step's
+# attention reads (100% for a program that reads every live token)
+SPARSE_TOKENS_SCORED = REGISTRY.counter(
+    "server_sparse_tokens_scored_total",
+    "Index keys a decode step's queries were scored against (a row's live "
+    "context once it is longer than topk; 0 while the selection is "
+    "everything), summed over rows and layers",
+)
+SPARSE_TOKENS_READ = REGISTRY.counter(
+    "server_sparse_tokens_read_total",
+    "Tokens whose K/V a decode step's attention read: min(context, topk) a "
+    "row and layer, summed over rows and layers",
+)
+SPARSE_TOKENS_LIVE = REGISTRY.counter(
+    "server_sparse_tokens_live_total",
+    "Live context tokens of the rows in a decode step of a token-selecting "
+    "model, summed over rows and layers",
 )
 KV_WINDOW_BLOCKS_FREED = REGISTRY.counter(
     "server_kv_window_blocks_freed_total",

@@ -111,6 +111,11 @@ SCOPES = (
                   # nope part into the latent space before the kernel, the
                   # latent output out of it after (models/deepseek_v3.py)
     "attn",       # the attention kernel / XLA attention and its GQA fold
+    "indexer",    # a token-selecting model's indexer: its three projections,
+                  # the index key's LayerNorm and rotary, and the scores of a
+                  # query against the row's live index keys
+    "select",     # the top-k over those scores and what turns it into the
+                  # attention's list (a decode step) or mask (a chunk)
     "o_proj",     # output projection, its psum, the residual add
     "mlp",        # gated MLP, its psum, the residual add
     "router",     # a sparse MLP's router: float32 logits, softmax, top-k
@@ -208,7 +213,7 @@ class StepRecord:
         "decode_blocks_live", "decode_blocks_reserved",
         "prefill_cells_live", "prefill_cells_walked", "kv_kinds",
         "prefill_kv_blocks", "decode_kv_entries", "recurrent_rows",
-        "scan_positions",
+        "scan_positions", "sparse_tokens",
     )
 
     def __init__(self, ts, wall_s, phases, blocked_s, idle_s,
@@ -276,6 +281,12 @@ class StepRecord:
         # at dispatch)
         self.recurrent_rows = None
         self.scan_positions = None
+        # a token-selecting model (None otherwise), summed over the layers
+        # and decode dispatches of this step: ``{"scored": index keys a
+        # row's query was scored against, "read": tokens whose K/V the
+        # attention read, "live": live context tokens of the rows}`` (host
+        # arithmetic at dispatch, from the length mirrors)
+        self.sparse_tokens = None
 
     @property
     def host_s(self) -> float:
@@ -316,6 +327,8 @@ class StepRecord:
             d["recurrent_rows"] = self.recurrent_rows
         if self.scan_positions is not None:
             d["scan_positions"] = dict(self.scan_positions)
+        if self.sparse_tokens is not None:
+            d["sparse_tokens"] = dict(self.sparse_tokens)
         if self.expert_tokens is not None:
             d["expert_tokens"] = list(self.expert_tokens)
             d["experts_read"] = list(self.experts_read)
@@ -369,6 +382,7 @@ class StepProfiler:
         self._decode_kv_entries = None  # {"kernel" | "scatter": entries}
         self._recurrent_rows = None
         self._scan_positions = None  # {"real" | "pad": positions}
+        self._sparse_tokens = None  # {"scored" | "read" | "live": tokens}
         self._kv_kinds = None
         self._phases: Dict[str, float] = {}
         self._blocked_s = 0.0
@@ -477,6 +491,7 @@ class StepProfiler:
         self._decode_kv_entries = None
         self._recurrent_rows = None
         self._scan_positions = None
+        self._sparse_tokens = None
         self._kv_kinds = None
         work = bool(rows or queued or pending)
         if self._annotate is not None and (work or self._had_work):
@@ -649,6 +664,18 @@ class StepProfiler:
         acc["pad"] += int(pad)
         self._scan_positions = acc
 
+    def sparse_tokens(self, scored: int, read: int, live: int) -> None:
+        """Add one decode dispatch's selection to the step's record (a
+        token-selecting model): index keys scored, tokens whose K/V was
+        read, live context tokens — each summed over rows and layers."""
+        if not self._enabled or self._t0 is None:
+            return
+        acc = self._sparse_tokens or {"scored": 0, "read": 0, "live": 0}
+        acc["scored"] += int(scored)
+        acc["read"] += int(read)
+        acc["live"] += int(live)
+        self._sparse_tokens = acc
+
     def experts(self, tokens, read=None, steps: int = 0, rows: int = 0) -> None:
         """Add a fetched set of expert counters to the step's record:
         ``tokens`` [E] per expert; for decode microsteps also ``read`` [L]
@@ -716,6 +743,7 @@ class StepProfiler:
         rec.decode_kv_entries = self._decode_kv_entries
         rec.recurrent_rows = self._recurrent_rows
         rec.scan_positions = self._scan_positions
+        rec.sparse_tokens = self._sparse_tokens
         if self._experts is not None:
             tokens, read, rec.expert_steps, rec.expert_rows = self._experts
             rec.expert_tokens, rec.experts_read = tokens, read or []

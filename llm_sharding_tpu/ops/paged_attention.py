@@ -1532,6 +1532,7 @@ def paged_prefill(
     #   caller built it once for many layers (``prefill_walk``)
     window: int = 0,  # static: a windowed layer keeps ``window`` keys back
     sink: jnp.ndarray = None,  # [Nh] a per-head logit in the denominator
+    select=None,  # a ``Selection``: each query attends the keys it chose
 ) -> jnp.ndarray:
     """Backend dispatch for CHUNKED-PREFILL attention over the arena,
     mirroring ``paged_attention``: the Pallas prefill kernel on TPU for
@@ -1553,6 +1554,11 @@ def paged_prefill(
         raise ValueError(
             f"paged_prefill backend {backend!r}: expected one of "
             f"{BACKENDS}"
+        )
+    if select is not None:
+        return selected_prefill(
+            q, k_arena, v_arena, layer, block_table, q_positions,
+            kv_positions, select, scale, backend=backend, walk=walk,
         )
     if stats and (latent_v or window or sink is not None):
         raise NotImplementedError(
@@ -1876,6 +1882,7 @@ def paged_attention_write(
     latent_v: int = 0,
     window: int = 0,
     sink: jnp.ndarray = None,
+    select=None,  # a ``Selection``: the query attends the keys it chose
 ):
     """A DECODE layer call's two halves as one op: the step's fresh K/V
     lands in the arena, then ``paged_attention`` attends it (the fresh
@@ -1911,9 +1918,364 @@ def paged_attention_write(
         k_arena, v_arena, ks, vs = (
             wrote if k_scale is not None else (*wrote, None, None)
         )
+    if select is not None:
+        out = selected_attention(
+            q, k_arena, v_arena, layer, block_table, q_positions,
+            kv_positions, select, scale, backend=backend,
+        )
+        return out, k_arena, v_arena, ks, vs
     out = paged_attention(
         q, k_arena, v_arena, layer, block_table, q_positions, kv_positions,
         scale, backend=backend, k_scale=ks, v_scale=vs, stats=stats,
         latent_v=latent_v, window=window, sink=sink,
     )
     return out, k_arena, v_arena, ks, vs
+
+
+# ---- token selection (a learned sparse-attention indexer) ------------------
+# A token-selecting model (``cfg.sparse_attn``; DeepSeek-V3.2-Exp's indexer,
+# here over GQA) keeps ONE index key a token and layer in a THIRD arena,
+# ``[L, NB, 1, BS, lanes]`` (the key padded with zeros to whole 128-lane tiles,
+# ``cfg.index_cache_dim``), in the same blocks under the same table as K and V.
+# A query scores every live index key of its row (``index_scores``), keeps the
+# ``topk`` best (``select_tokens``; all of them while the context is no longer
+# than that) and attends those tokens ONLY. A decode step GATHERS the chosen
+# tokens' K and V, one row of ``D`` a token and head (``selected_attention``:
+# what it reads of K/V is ``min(context, topk)`` tokens a row and layer); a
+# prefill chunk masks the others out of the dense product over the row's
+# window (``selected_prefill``: for 256 queries a gather of 256 x topk tokens
+# would be 1 GB a layer, and the masked product is the same mathematics).
+# While no query of the call reaches past ``topk`` keys the selection is
+# everything, and both take the unselected kernel as it is (``lax.cond``).
+
+
+class Selection(NamedTuple):
+    """What a layer hands its attention so that it selects: the index queries
+    ``[B, S, Hi, Di]`` (rotated), a weight an index head ``[B, S, Hi]`` f32
+    (the two ``^-1/2`` factors folded in), the layer-stacked index arena with
+    this step's index keys already written, and how many keys a query keeps."""
+
+    qi: jnp.ndarray
+    wi: jnp.ndarray
+    idx_arena: jnp.ndarray
+    topk: int
+
+
+def write_index_keys(
+    idx_arena: jnp.ndarray,  # [L, NB, 1, BS, Di]
+    layer,  # scalar int32
+    block_table: jnp.ndarray,  # [B, T]
+    cols,  # [B, S] the entries' columns (a decode step), or a chunk's first
+    #   column, a scalar (its rows share their columns: ``write_chunk_kv``)
+    ki: jnp.ndarray,  # [B, S, 1, Di]
+    valid=None,
+    backend: str = "auto",
+) -> jnp.ndarray:
+    """The step's index keys into the blocks the table names — K's writers
+    over an arena of one head and NO values (a zero-wide value array, as a
+    latent arena has): whole-block tiles for a chunk, the write kernel for a
+    decode step whose attention is on its kernel, the row-wise scatter
+    otherwise. An invalid entry lands in block 0 of its layer."""
+    empty = jnp.zeros((*idx_arena.shape[:-1], 0), idx_arena.dtype)
+    # a stored index key is whole 128-lane tiles, zeros past its own width
+    ki = jnp.pad(ki, [(0, 0)] * 3 + [(0, idx_arena.shape[-1] - ki.shape[-1])])
+    if jnp.ndim(cols) == 0:
+        return write_chunk_kv(
+            idx_arena, empty, layer, block_table, cols, ki, ki, valid=valid
+        )[0]
+    path = decode_path(backend, idx_arena.shape[-1], idx_arena, block_table)
+    if decode_writes_in_kernel(ki.shape[1], False, False, path):
+        return write_rows_tpu(
+            idx_arena, empty, layer, block_table, cols[:, 0], ki[:, 0], None,
+            valid=valid if valid is None or not jnp.ndim(valid)
+            else valid[:, 0],
+            interpret=path == "interpret",
+        )[0]
+    return write_block_kv(
+        idx_arena, empty, layer, block_table, cols, ki, ki, valid=valid
+    )[0]
+
+
+def _attendable(block_table, q_positions, kv_positions, block_size):
+    """``[B, S, W]``: the columns a query may attend — a key position at or
+    before it in a block the row owns (a stale index key in the trash block
+    or in a block another request left behind sits at the sentinel)."""
+    from ..models.cache import POS_SENTINEL  # models imports this module
+
+    live = jnp.repeat(block_table != 0, block_size, axis=1)  # [B, W]
+    kv = kv_positions[:, None, :]
+    return (
+        (kv <= q_positions[:, :, None]) & (kv < POS_SENTINEL)
+        & live[:, None, :]
+    )
+
+
+def _index_kernel(
+    layer_ref,  # scalar-prefetch [1] — read by the index maps only
+    tbl_ref,  # scalar-prefetch [B, T] (index maps + the trash gate)
+    nlive_ref,  # scalar-prefetch [B] — the row's frontier (_live_blocks)
+    start_ref,  # scalar-prefetch [B] — the grid step of the row's cell 0
+    row_ref,  # scalar-prefetch [B·T/bps + 1] — the row grid step i walks
+    qi_ref,  # [1, Hi, lanes] the row's index queries
+    wi_ref,  # [1, Hi, 1] f32 a weight an index head
+    *rest,  # bps index-key refs [1, 1, BS, lanes]; out [1, 1, 1, bps·BS] f32
+    bps,
+):
+    k_refs, out_ref = rest[:bps], rest[bps]
+    i = pl.program_id(0)
+    b = row_ref[i]
+    t = i - start_ref[b]
+    tiles = []
+    for j in range(bps):
+        k = k_refs[j][0, 0]  # [BS, lanes]
+        idx = t * bps + j
+        # a trash block's garbage may be non-finite: it scores as zeros (and
+        # its columns are masked by position outside)
+        live = (idx < nlive_ref[b]) & (tbl_ref[b, idx] != 0)
+        tiles.append(jnp.where(live, k, jnp.zeros_like(k)))
+    s = jax.lax.dot_general(
+        qi_ref[0], jnp.concatenate(tiles, axis=0), (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )  # [Hi, bps·BS]
+    out_ref[0, 0] = jnp.sum(
+        wi_ref[0] * jnp.maximum(s, 0.0), axis=0, keepdims=True
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def index_scores_tpu(qi, wi, idx_arena, layer, block_table, q_positions,
+                     kv_positions, interpret: bool = False):
+    """The decode kernel's walk over the INDEX arena: one grid axis over the
+    rows' live cells (``bps`` blocks of one row: ``_live_blocks``,
+    ``_end_to_end``), a cell's index keys scored against the row's ``Hi``
+    index queries in one dot, ``relu``, weighted and summed over the index
+    heads. ``qi [B, Hi, lanes]`` (padded to the stored key's lanes), ``wi [B,
+    Hi]`` f32 → ``[B, T·BS]`` f32 scores by logical column; a cell the walk
+    did not visit is UNWRITTEN (the caller masks by position)."""
+    B, Hi, lanes = qi.shape
+    BS = idx_arena.shape[3]
+    T = block_table.shape[1]
+    bps = auto_blocks_per_step(T, BS)
+    nlive = _live_blocks(block_table, q_positions, kv_positions)
+    start, row_of, ends = _end_to_end(nlive, bps, T // bps)
+
+    def cell(i, st, row):
+        return jnp.minimum(i - st[row[i]], T // bps - 1)
+
+    def arena_index(i, lyr, tbl, nl, st, row, *, j):
+        b = row[i]
+        idx = cell(i, st, row) * bps + j
+        return (lyr[0], jnp.where(idx < nl[b], tbl[b, idx], 0), 0, 0, 0)
+
+    def of_row(i, lyr, tbl, nl, st, row):
+        return (row[i], 0, 0)
+
+    out = pl.pallas_call(
+        functools.partial(_index_kernel, bps=bps),
+        out_shape=jax.ShapeDtypeStruct((B, T // bps, 1, bps * BS), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(jnp.maximum(ends[-1], 1),),
+            in_specs=[
+                pl.BlockSpec((1, Hi, lanes), of_row),
+                pl.BlockSpec((1, Hi, 1), of_row),
+                *[pl.BlockSpec((None, 1, 1, BS, lanes),
+                               functools.partial(arena_index, j=j))
+                  for j in range(bps)],
+            ],
+            out_specs=pl.BlockSpec(
+                (1, 1, 1, bps * BS),
+                lambda i, lyr, tbl, nl, st, row: (
+                    row[i], cell(i, st, row), 0, 0),
+            ),
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+        ),
+        interpret=interpret,
+        name="index_scores",
+    )(
+        _layer_operand(layer), block_table, nlive, start, row_of, qi,
+        wi.astype(jnp.float32)[..., None], *([idx_arena] * bps),
+    )
+    return out.reshape(B, T * BS)
+
+
+@jax.named_scope("indexer")
+def index_scores(select: Selection, layer, block_table, q_positions,
+                 kv_positions, ok, path: str = "xla") -> jnp.ndarray:
+    """``I[b, s, w] = sum_j wi[b, s, j] · relu(qi[b, s, j] · ki[w])`` in
+    float32 over the row's logical window (the index arena's blocks through
+    the table), ``-inf`` where ``ok`` is False. ``path`` (``decode_path``):
+    a decode step whose attention is on its kernel scores through
+    ``index_scores_tpu``, which reads the rows' LIVE blocks where they lie;
+    everything else gathers the window."""
+    B, T = block_table.shape
+    lanes = select.idx_arena.shape[-1]
+    # the stored key's lanes past its own width are zeros: so are the query's
+    qi = jnp.pad(
+        select.qi, [(0, 0)] * 3 + [(0, lanes - select.qi.shape[-1])],
+    )
+    if path != "xla" and qi.shape[1] == 1:
+        score = index_scores_tpu(
+            qi[:, 0], select.wi[:, 0], select.idx_arena, layer, block_table,
+            q_positions, kv_positions, interpret=path == "interpret",
+        )[:, None]
+        return jnp.where(ok, score, -jnp.inf)
+    ki = select.idx_arena[layer, block_table, 0]  # [B, T, BS, lanes]
+    ki = ki.reshape(B, -1, lanes)
+    s = jnp.einsum(
+        "bshd,bwd->bshw", qi, ki, preferred_element_type=jnp.float32
+    )
+    score = jnp.einsum("bsh,bshw->bsw", select.wi, jax.nn.relu(s))
+    return jnp.where(ok, score, -jnp.inf)
+
+
+@jax.named_scope("select")
+def select_tokens(scores: jnp.ndarray, topk: int):
+    """The ``topk`` best-scored columns of each query, ``[..., K]`` (``K =
+    min(topk, W)``) and whether each is a real choice (a query with fewer
+    attendable keys than ``K`` fills up with masked ones). A tie goes to the
+    lower column (``lax.top_k`` is stable), which is the lower position."""
+    vals, cols = jax.lax.top_k(scores, min(topk, scores.shape[-1]))
+    return cols.astype(jnp.int32), vals > -jnp.inf
+
+
+@jax.named_scope("select")
+def select_mask(scores: jnp.ndarray, topk: int) -> jnp.ndarray:
+    """``select_tokens`` as a mask over the window ``[..., W]``: above the
+    ``topk``-th largest score, and of the columns that tie with it the lowest
+    ones, as many as are left to keep."""
+    K = min(topk, scores.shape[-1])
+    kth = jax.lax.top_k(scores, K)[0][..., -1:]
+    above = scores > kth
+    tie = (scores == kth) & (scores > -jnp.inf)
+    left = K - jnp.sum(above, axis=-1, keepdims=True)
+    return above | (tie & (jnp.cumsum(tie, axis=-1) <= left))
+
+
+@jax.named_scope("attn")
+def _attend_list(q, k_arena, v_arena, layer, block_table, cols, chosen,
+                 q_positions, kv_positions, scale, live):
+    """Attention of ``q [B, 1, Nh, D]`` over the LIST of columns ``cols [B,
+    K]``: each chosen token's K and V rows are gathered out of the arena where
+    they lie — one row of ``D`` a token and head, indexed in every dim but the
+    last (a 2-D row gather of the flattened pool: nothing of a layer's or a
+    block's size is produced) — and nothing else of K/V is read. The LIVE
+    rows only (``live [B]``), one at a time: a row gather costs the chip ~10
+    ns a row of ``D`` whatever it reads (PERF.md, PR 49), so a dead row of the
+    slot would cost what a live one does. A dead row returns zeros."""
+    from ..models.cache import POS_SENTINEL  # models imports this module
+
+    _, NB, Nkv, BS, D = k_arena.shape
+    kf = k_arena.reshape(-1, D)
+    vf = v_arena.reshape(-1, v_arena.shape[-1])
+    order = jnp.argsort(~live, stable=True).astype(jnp.int32)  # live first
+
+    def one_row(i, out):
+        b = order[i]
+        row = lambda a: jax.lax.dynamic_slice_in_dim(a, b, 1, axis=0)
+        c = row(cols)  # [1, K]
+        blk = jnp.take_along_axis(row(block_table), c // BS, axis=1)
+        rows = (
+            ((layer * NB + blk)[:, :, None] * Nkv + jnp.arange(Nkv)) * BS
+            + (c % BS)[:, :, None]
+        )  # [1, K, Nkv]
+        kv_pos = jnp.where(
+            row(chosen), jnp.take_along_axis(row(kv_positions), c, axis=1),
+            POS_SENTINEL,
+        )
+        o = cached_attention(
+            row(q), kf.at[rows].get(mode="promise_in_bounds"),
+            vf.at[rows].get(mode="promise_in_bounds"), row(q_positions),
+            kv_pos, scale,
+        )
+        return jax.lax.dynamic_update_slice_in_dim(out, o, b, axis=0)
+
+    out = jnp.zeros((*q.shape[:-1], vf.shape[-1]), q.dtype)
+    return jax.lax.fori_loop(0, jnp.sum(live), one_row, out)
+
+
+def selected_attention(
+    q, k_arena, v_arena, layer, block_table, q_positions, kv_positions,
+    select: Selection, scale=None, backend: str = "auto",
+):
+    """A DECODE step's attention under a selection (one query a row)."""
+    if q.shape[1] != 1:
+        raise NotImplementedError(
+            "a selecting decode step takes one query a row"
+        )
+    BS = k_arena.shape[3]
+    ok = _attendable(block_table, q_positions, kv_positions, BS)
+
+    def dense(_):
+        return paged_attention(
+            q, k_arena, v_arena, layer, block_table, q_positions,
+            kv_positions, scale, backend=backend,
+        )
+
+    path = decode_path(backend, q.shape[-1], k_arena, block_table)
+
+    def chosen(_):
+        scores = index_scores(
+            select, layer, block_table, q_positions, kv_positions, ok, path
+        )[:, 0]
+        cols, real = select_tokens(scores, select.topk)
+        return _attend_list(
+            q, k_arena, v_arena, layer, block_table, cols, real,
+            q_positions, kv_positions, scale, jnp.any(ok, axis=(1, 2)),
+        )
+
+    # while no row's query reaches past topk keys the selection is everything
+    beyond = jnp.any(jnp.sum(ok, axis=-1) > select.topk)
+    return jax.lax.cond(beyond, chosen, dense, None)
+
+
+def selected_prefill(
+    q, k_arena, v_arena, layer, block_table, q_positions, kv_positions,
+    select: Selection, scale=None, backend: str = "auto", walk=None,
+):
+    """A prefill CHUNK's attention under a selection: per query, the dense
+    product over the row's window with every key the query did not choose
+    masked out — a row at a time, so that what it builds is one row's
+    ``[heads, chunk, window]`` scores."""
+    B, S, Nh, D = q.shape
+    Nkv, BS = k_arena.shape[2], k_arena.shape[3]
+    ok = _attendable(block_table, q_positions, kv_positions, BS)
+
+    def dense(_):
+        return paged_prefill(
+            q, k_arena, v_arena, layer, block_table, q_positions,
+            kv_positions, scale, backend=backend, walk=walk,
+        )
+
+    def one_row(args):
+        q_r, qi_r, wi_r, tbl_r, ok_r = args
+        sel = Selection(qi_r[None], wi_r[None], select.idx_arena, select.topk)
+        keep = select_mask(
+            index_scores(sel, layer, tbl_r[None], None, None, ok_r[None]),
+            select.topk,
+        )[0]  # [S, W]
+        with jax.named_scope("attn"):
+            k, v = gather_block_kv(k_arena, v_arena, layer, tbl_r[None])
+            qg = q_r.reshape(S, Nkv, Nh // Nkv, D)
+            sc = jnp.einsum(
+                "skgd,tkd->kgst", qg, k[0], preferred_element_type=jnp.float32
+            ) * (D ** -0.5 if scale is None else scale)
+            sc = jnp.where(keep[None, None], sc, jnp.float32(NEG_INF))
+            p = jnp.exp(sc - sc.max(axis=-1, keepdims=True))
+            p = jnp.where(keep[None, None], p, 0.0)
+            p = p / jnp.maximum(p.sum(axis=-1, keepdims=True), 1e-30)
+            o = jnp.einsum(
+                "kgst,tkd->skgd", p.astype(v.dtype), v[0],
+                preferred_element_type=jnp.float32,
+            )
+            return o.reshape(S, Nh, D).astype(q.dtype)
+
+    def chosen(_):
+        return jax.lax.map(
+            one_row, (q, select.qi, select.wi, block_table, ok)
+        )
+
+    beyond = jnp.any(jnp.sum(ok, axis=-1) > select.topk)
+    return jax.lax.cond(beyond, chosen, dense, None)

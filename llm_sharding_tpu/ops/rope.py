@@ -83,11 +83,14 @@ def _yarn_inv_freq(dim: int, base: float, rs: RopeScaling) -> np.ndarray:
     )
 
 
-def inv_frequencies(cfg: ModelConfig, theta: float | None = None) -> np.ndarray:
+def inv_frequencies(
+    cfg: ModelConfig, theta: float | None = None, dim: int | None = None,
+) -> np.ndarray:
     """Static (trace-time) inverse frequencies, shape [rope_dim/2], fp32.
     ``theta`` overrides ``cfg.rope_theta`` (a model whose kinds of layer
-    rotate at bases of their own)."""
-    d = cfg.rope_dim
+    rotate at bases of their own), ``dim`` the rotated width (an indexer's
+    vectors, narrower than a head)."""
+    d = cfg.rope_dim if dim is None else dim
     rs = cfg.rope_scaling
     theta = cfg.rope_theta if theta is None else theta
     if rs is not None and rs.rope_type == "yarn":
@@ -102,14 +105,14 @@ def inv_frequencies(cfg: ModelConfig, theta: float | None = None) -> np.ndarray:
 
 def rope_cos_sin(
     positions: jnp.ndarray, cfg: ModelConfig, dtype=jnp.float32,
-    theta: float | None = None,
+    theta: float | None = None, dim: int | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """cos/sin tables for absolute ``positions`` (any shape ``[...]``).
 
     Returns ``cos, sin`` of shape ``[..., head_dim]`` (HF layout: the half
     frequencies tiled twice, consumed by :func:`apply_rope`).
     """
-    inv_freq = jnp.asarray(inv_frequencies(cfg, theta))  # [D/2]
+    inv_freq = jnp.asarray(inv_frequencies(cfg, theta, dim))  # [D/2]
     freqs = positions.astype(jnp.float32)[..., None] * inv_freq  # [..., D/2]
     emb = jnp.concatenate([freqs, freqs], axis=-1)  # [..., D]
     cos, sin = jnp.cos(emb), jnp.sin(emb)

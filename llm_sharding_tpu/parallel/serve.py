@@ -130,6 +130,13 @@ class ServeState(NamedTuple):
     # L_attn, NB, ...]``). None (an empty pytree: no operand of any program)
     # for every other model.
     recurrent: Any = None
+    # A token-selecting model (``cfg.sparse_attn``, paged only): ONE index key
+    # a token and layer, ``[S, Lp, NB, 1, BS, cfg.index_cache_dim]`` [dev] (the
+    # key padded with zeros to whole 128-lane tiles), in the
+    # same blocks under the same ``block_tables`` as ``k`` / ``v`` — allocated,
+    # freed and written with them. None (an empty pytree: no operand of any
+    # program) for every other model.
+    idx: Any = None
 
 
 def _dev(spec: P) -> bool:
@@ -190,6 +197,7 @@ def state_specs(
             None if state.recurrent is None
             else {name: dev for name in state.recurrent}
         ),
+        idx=None if state.idx is None else kv,
     )
 
 
@@ -305,6 +313,8 @@ def _arenas(st, row0=None, fresh=None):
     if st.recurrent is not None:
         fresh = jnp.zeros((), bool) if fresh is None else fresh
         return (st.k, {**st.recurrent, "row0": row0, "fresh": fresh}), st.v
+    if st.idx is not None:  # a token-selecting model: its index keys beside k
+        return (st.k, st.idx), st.v
     if st.k_swa is None:
         return st.k, st.v
     return (st.k, st.k_swa), (st.v, st.v_swa)
@@ -318,6 +328,8 @@ def _arena_upd(st, k_new, v_new) -> dict:
             "k": k_new, "v": v_new,
             "recurrent": {name: rec[name] for name in st.recurrent},
         }
+    if st.idx is not None:
+        return {"k": k_new[0], "idx": k_new[1], "v": v_new}
     if st.k_swa is None:
         return {"k": k_new, "v": v_new}
     return {"k": k_new[0], "k_swa": k_new[1], "v": v_new[0], "v_swa": v_new[1]}
@@ -494,6 +506,16 @@ def make_state(
             ),
             tables_swa=put(np.zeros(tbl_shape, np.int32), tbl_sh),
         )
+    if cfg.sparse_attn:
+        if not paged or cp > 1 or tp > 1 or quantized:
+            raise NotImplementedError(
+                "a token-selecting model's index arena needs a paged bf16 "
+                "arena and no tp / cp"
+            )
+        state = state._replace(idx=zeros(
+            (*kv_shape[:3], 1, kv_block_size, cfg.index_cache_dim),
+            cache_dtype, dev_kv,
+        ))
     if recurrent_layers:
         if not paged or cp > 1 or tp > 1 or quantized:
             raise NotImplementedError(

@@ -89,7 +89,7 @@ class BlockAllocator:
 
     def bytes_per_block(
         self, *, num_layers: int, num_kv_heads: int, head_dim: int,
-        kv_dtype, value_dim: Optional[int] = None,
+        kv_dtype, value_dim: Optional[int] = None, index_dim: int = 0,
     ) -> int:
         """Device bytes ONE arena block costs across all layers: K + V
         codes (``2 × L × BS × Nkv × Dh × itemsize``) plus, for quantized
@@ -99,16 +99,20 @@ class BlockAllocator:
         capacity table in README — at equal HBM budget,
         ``budget // bytes_per_block`` is how many blocks each dtype
         admits (int8 ≈ 2× bf16). ``value_dim`` is the width of a value
-        entry where it is not the key's (a latent arena: 0)."""
+        entry where it is not the key's (a latent arena: 0); ``index_dim``
+        the width of the ONE index key a token a token-selecting model keeps
+        in a third arena under the same blocks."""
         item = np.dtype(kv_dtype).itemsize
         widths = head_dim + (head_dim if value_dim is None else value_dim)
-        kv = num_layers * self.block_size * num_kv_heads * widths * item
+        kv = num_layers * self.block_size * (
+            num_kv_heads * widths + index_dim
+        ) * item
         scales = 2 * num_layers * num_kv_heads * 4 if item == 1 else 0
         return kv + scales
 
     def arena_bytes(
         self, *, num_layers: int, num_kv_heads: int, head_dim: int,
-        kv_dtype, value_dim: Optional[int] = None,
+        kv_dtype, value_dim: Optional[int] = None, index_dim: int = 0,
     ) -> int:
         """Total device bytes of this pool's arena (every block including
         the reserved trash sink — the arrays exist whether or not a block
@@ -116,6 +120,7 @@ class BlockAllocator:
         return self.num_blocks * self.bytes_per_block(
             num_layers=num_layers, num_kv_heads=num_kv_heads,
             head_dim=head_dim, kv_dtype=kv_dtype, value_dim=value_dim,
+            index_dim=index_dim,
         )
 
     @property

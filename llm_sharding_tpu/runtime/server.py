@@ -80,6 +80,7 @@ from ..obs.metrics import (
     DECODE_KV_ENTRIES_WRITTEN,
     KV_KIND_BLOCKS_IN_USE, KV_KIND_BLOCKS_TOTAL, KV_KIND_ENTRY_BYTES,
     KV_WINDOW_BLOCKS_FREED,
+    SPARSE_TOKENS_LIVE, SPARSE_TOKENS_READ, SPARSE_TOKENS_SCORED,
     DEFAULT_RATE_BUCKETS,
     KV_BLOCKS_IN_USE, KV_BLOCKS_TOTAL, KV_DISK_TIER_BLOCKS,
     KV_ENTRY_BYTES, KV_HOST_TIER_BLOCKS, KV_WASTE_FRAC, MOE_EXPERT_TOKENS,
@@ -120,22 +121,37 @@ _HEALTH_SEVERITY = {SERVING: 0, DEGRADED: 1, DRAINING: 2}
 def kind_state_name(cfg) -> Optional[str]:
     """How a refusal names a model whose per-request state is more than ONE
     paged arena: a KV state per kind of attention layer (``cfg.windowed``), a
-    recurrent state beside the arena (``cfg.recurrent``). None otherwise."""
+    recurrent state beside the arena (``cfg.recurrent``), an index arena
+    beside K and V (``cfg.sparse_attn``). None otherwise."""
     if cfg.windowed:
         return f"a windowed model ({cfg.model_type})"
     if cfg.recurrent:
         return f"a recurrent-state model ({cfg.model_type})"
+    if cfg.sparse_attn:
+        return f"a token-selecting model ({cfg.model_type})"
     return None
+
+
+#: what a token-selecting model's refusals give as their reason where the
+#: other two kinds have one each: the index arena is not carried there
+_INDEX_ARENA_WHY = (
+    "the index arena beside K and V (one index key a token and layer, which "
+    "the selection reads) is not carried there"
+)
 
 
 def refuse_kind_state(cfg, what: str, why) -> None:
     """What such a state breaks is refused by name, never computed as
-    something else (ROADMAP M2 / M4 list what is left). ``why``: one reason,
-    or the pair (a windowed model's, a recurrent-state model's)."""
+    something else (ROADMAP M2 / M4 / M6 list what is left). ``why``: one
+    reason, or the pair (a windowed model's, a recurrent-state model's); a
+    token-selecting model's is ``_INDEX_ARENA_WHY`` wherever a pair is given."""
     name = kind_state_name(cfg)
     if name is not None:
         if not isinstance(why, str):
-            why = why[0 if cfg.windowed else 1]
+            why = (
+                why[0] if cfg.windowed else why[1] if cfg.recurrent
+                else _INDEX_ARENA_WHY
+            )
         raise NotImplementedError(f"{what} {name}: {why} — not implemented")
 
 
@@ -252,12 +268,17 @@ def _update_load_gauges() -> None:
         if getattr(s, "paged", False):
             kv_total += s._alloc.capacity_blocks
             kv_used += s._alloc.in_use
+            kind_pools = ()
             if getattr(s, "windowed", False):
-                for kind, alloc in (("full", s._alloc), ("swa", s._alloc_swa)):
-                    kind_total[kind] = (
-                        kind_total.get(kind, 0) + alloc.capacity_blocks
-                    )
-                    kind_used[kind] = kind_used.get(kind, 0) + alloc.in_use
+                kind_pools = (("full", s._alloc), ("swa", s._alloc_swa))
+            elif getattr(s, "sparse", False):
+                # one pool, two arenas: an index block is held with its K/V
+                kind_pools = (("kv", s._alloc), ("index", s._alloc))
+            for kind, alloc in kind_pools:
+                kind_total[kind] = (
+                    kind_total.get(kind, 0) + alloc.capacity_blocks
+                )
+                kind_used[kind] = kind_used.get(kind, 0) + alloc.in_use
             if not getattr(s, "_closed", False):
                 arena_bytes[s.kv_dtype] += s.arena_bytes_device
             # COLD prefix-cache blocks (tree-held, no row mapping them) are
@@ -1026,7 +1047,15 @@ class PipelineServer:
         #: FIXED size a request, indexed by row beside the arena, which holds
         #: the attention layers alone (``ServeState.recurrent``)
         self.recurrent = bool(self.cfg.recurrent)
+        #: a query attends the keys its indexer chose (``cfg.sparse_attn``):
+        #: an index key a token beside K and V, in the same blocks under the
+        #: same tables (``ServeState.idx``)
+        self.sparse = bool(self.cfg.sparse_attn)
         name = kind_state_name(self.cfg)
+        #: any of the three: every prompt admits chunk by chunk, in WHOLE
+        #: chunks (``_bucket``, ``_chunked``) — the chunk program is the one
+        #: that carries what such a model keeps beside ONE arena
+        self._chunked_only = name is not None
         if name is not None and (
             not options.paged or options.prefill_chunk is None
         ):
@@ -1034,7 +1063,8 @@ class PipelineServer:
             raise ValueError(
                 f"{name} serves from a paged arena "
                 + ("per kind of layer" if self.windowed
-                   else "beside its recurrent state")
+                   else "beside its recurrent state" if self.recurrent
+                   else "with its index keys beside K and V")
                 + ", admitted chunk by chunk: set kv_block_size, "
                 "kv_blocks and prefill_chunk"
             )
@@ -1049,7 +1079,10 @@ class PipelineServer:
                 "prefix_cache=%r over %s: hits are not offered (%s)",
                 options.prefix_cache, name,
                 "a window layer's old blocks are gone" if self.windowed
-                else "a recurrent state cannot be sliced at a hit's length",
+                else "a recurrent state cannot be sliced at a hit's length"
+                if self.recurrent
+                else "a hit's suffix would admit through the one-shot dense "
+                "window, which holds no index keys",
             )
             options = dataclasses.replace(
                 options, prefix_cache="off", host_pool_blocks=0
@@ -1099,13 +1132,16 @@ class PipelineServer:
         # token-identical to chunk mode. Incompatible with prefill_chunk:
         # chunked admission interleaves serve_chunk microstep cycles, whose
         # per-slot write_off bookkeeping a spec server does not maintain.
-        if speculate and self.recurrent:
+        if speculate and (self.recurrent or self.sparse):
             # (before the clash with prefill_chunk, which such a model needs:
             # the reason that holds whatever the admission path is)
             self._refuse_kind_state(
                 "speculate over",
                 "serve_verify is not carried over a recurrent state (a "
-                "rejected draft would have to roll the state back)",
+                "rejected draft would have to roll the state back)"
+                if self.recurrent else
+                "serve_verify writes no index keys and selects nothing (a "
+                "verify's K + 1 queries a row would each choose their own)",
             )
         if speculate and prefill_chunk is not None:
             raise ValueError(
@@ -1390,6 +1426,8 @@ class PipelineServer:
                 head_dim=self.cfg.cache_k_dim,
                 kv_dtype=self.kv_store_dtype,
                 value_dim=self.cfg.cache_v_dim,
+                # a token-selecting model: its index keys, in the same blocks
+                index_dim=self.cfg.index_cache_dim if self.sparse else 0,
             )
             # what ONE token of one layer holds in the arena: 2 x Nkv x Dh
             # values, or a latent cache's one padded entry
@@ -1398,11 +1436,19 @@ class PipelineServer:
                     int(self.state.recurrent["ssm"].shape[1])
                     * self.cfg.recurrent_row_bytes
                 ))
-            KV_ENTRY_BYTES.set(float(
+            item = np.dtype(self.kv_store_dtype).itemsize
+            kv_entry = float(
                 self.cfg.cache_heads
-                * (self.cfg.cache_k_dim + self.cfg.cache_v_dim)
-                * np.dtype(self.kv_store_dtype).itemsize
-            ))
+                * (self.cfg.cache_k_dim + self.cfg.cache_v_dim) * item
+            )
+            KV_ENTRY_BYTES.set(kv_entry)
+            if self.sparse:
+                # the two arenas of the ONE pool, by kind: K and V, and the
+                # index keys beside them (the unlabeled gauge stays K/V's)
+                KV_KIND_ENTRY_BYTES.labels(kind="kv").set(kv_entry)
+                KV_KIND_ENTRY_BYTES.labels(kind="index").set(
+                    float(self.cfg.index_cache_dim * item)
+                )
             # host mirror of the device block tables (all-trash at birth);
             # _push_tables ships it whole — [M, T] int32 is a few hundred
             # bytes, far below one chunk log
@@ -1649,6 +1695,27 @@ class PipelineServer:
             self.stepline.decode_kv_entries(kv_write, written)
         if self.recurrent:
             self.stepline.recurrent_rows(len(rows))
+        if self.sparse and rows:
+            # what the selection made of the step (ops/paged_attention.
+            # selected_attention asks the same of the device arrays): once a
+            # row's context is longer than topk every row's query is scored
+            # against its live index keys and each reads the tokens it chose
+            topk = self.cfg.index_topk
+            layers = self.num_stages * int(self.state.k.shape[1])
+            ctx = [max(int(self._mirror_len[r]), 1) for r in rows]
+            beyond = max(ctx) > topk
+            counts = [
+                n * layers * steps for n in (
+                    sum(ctx) if beyond else 0,
+                    sum(min(n, topk) for n in ctx), sum(ctx),
+                )
+            ]
+            for counter, n in zip(
+                (SPARSE_TOKENS_SCORED, SPARSE_TOKENS_READ, SPARSE_TOKENS_LIVE),
+                counts,
+            ):
+                counter.inc(n)
+            self.stepline.sparse_tokens(*counts)
         if self.windowed:
             # per kind of layer: a window layer's walk starts at the first
             # block its window reaches (what the row still holds)
@@ -3086,7 +3153,7 @@ class PipelineServer:
         """``make_state``'s keywords for a KV state per kind of layer, or
         for a recurrent state beside the arena."""
         if not (self.windowed or self.recurrent):
-            return {}
+            return {}  # (a token-selecting model: make_state reads the cfg)
         kinds = self.cfg.layer_kinds
         stages = self.engine.exec_placement.stages
         for start, end in stages:
@@ -4035,7 +4102,7 @@ class PipelineServer:
         return True
 
     def _bucket(self, n: int) -> int:
-        if self.windowed or self.recurrent:
+        if self._chunked_only:
             # every prompt admits chunk by chunk (``_chunked``), in WHOLE
             # chunks: one ``serve_prefill_chunk`` program whatever the
             # prompt's length, where a bucket under the chunk would compile
@@ -4052,8 +4119,10 @@ class PipelineServer:
         # is ever built for its window layers (``serve_admit`` builds one
         # for every layer; it costs nothing here whatever the capacity).
         # A model with recurrent layers likewise: the chunk program is the
-        # one that carries the state, and zeroes it on a row's first chunk
-        if self.windowed or self.recurrent:
+        # one that carries the state, and zeroes it on a row's first chunk.
+        # A token-selecting model too: the chunk program is the one that
+        # writes the index keys
+        if self._chunked_only:
             return True
         return self.prefill_chunk is not None and bucket > self.prefill_chunk
 

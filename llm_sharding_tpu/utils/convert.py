@@ -33,6 +33,19 @@ def _getter(src: Mapping[str, np.ndarray] | TensorGetter) -> TensorGetter:
     return lambda name: np.asarray(src[name])
 
 
+def _refuse_unmapped(cfg: ModelConfig) -> None:
+    """A family whose checkpoint names this repository does not hold is
+    refused by name, never mapped by guess."""
+    if cfg.sparse_attn:
+        raise NotImplementedError(
+            "model_type 'KeyeVL2': the names of a Keye checkpoint's tensors "
+            "(its indexer's three projections and LayerNorm, its language "
+            "model's prefix beside the vision tower) are in no file of this "
+            "repository — the converter maps it once they are; the block runs "
+            "on seeded weights (benchmark/blocks/KeyeVL2.py)"
+        )
+
+
 def llama_layer_arrays(
     cfg: ModelConfig, get: TensorGetter, i: int, dtype
 ) -> dict[str, jnp.ndarray]:
@@ -47,6 +60,7 @@ def llama_layer_arrays(
             "mlp_bias checkpoints are not wired through yet; refusing to "
             "silently drop bias tensors"
         )
+    _refuse_unmapped(cfg)
     pre = f"model.layers.{i}."
 
     def lin(name):  # torch Linear stores [out, in]; we use [in, out]
@@ -430,6 +444,7 @@ def params_from_hf(
     dtype=jnp.bfloat16,
 ) -> dict:
     """Full-model params pytree from an HF name→tensor source."""
+    _refuse_unmapped(cfg)
     get = _getter(src)
     if cfg.model_type == "llama":
         embed = jnp.asarray(get("model.embed_tokens.weight"), dtype)

@@ -1,0 +1,297 @@
+"""Keye-VL-2.0's language block on the program's paths (``tiny_keye_vl2``-sized:
+2 layers, hidden 64, GQA 4 / 2 heads of 16 with a per-head q/k norm, 8 experts,
+an indexer of 4 heads x 8 over ONE 8-wide index key a token, ``topk`` 16),
+against the ONE plain reference the repo has for it —
+``benchmark/blocks/KeyeVL2.py``, through ``benchmark.blocks.load`` — in float32:
+
+- prefill in chunks of 32, then decode one token at a time through the paged
+  arenas (K, V and the index keys beside them), contexts to 96: ``topk`` is
+  crossed INSIDE the first chunk and again between chunks; LOGITS at every
+  position equal the reference's full forward, on both backends (XLA; the
+  paged kernels in interpret mode);
+- while the context is no longer than ``topk`` the layer equals the llama
+  path on the same leaves (the selection is everything);
+- the positions a decode step chooses are the reference's;
+- a wrong ``theta``, a dropped LayerNorm bias of the index key, a dropped
+  ``wI``, the most recent ``topk`` keys in place of the indexer's choice and
+  no selection at all each move a logit by far more than the tolerance.
+
+The tolerance: program and reference are both float32 on the CPU and differ in
+the order of their sums, 5e-5 on logits of unit scale. The two sides score the
+index keys in the same precision here, so their choices agree at every
+position but exact ties (none with these weights); on the chip the program's
+scores are bf16 products and a few keys at the edge of the top-k differ
+(``benchmark/tests/test_keye_vl2_block.py`` counts them).
+"""
+
+import dataclasses
+import functools
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+
+from benchmark import blocks, reference, weights
+from llm_sharding_tpu.models import llama
+from llm_sharding_tpu.models.cache import POS_SENTINEL, paged_arena_shape
+from llm_sharding_tpu.models.config import (
+    ModelConfig, tiny_keye_vl2, tiny_keye_vl2_keys,
+)
+from llm_sharding_tpu.ops import paged_attention as pa
+
+MODEL = tiny_keye_vl2_keys(eos_token_id=1 << 20)  # no reply ends early
+CFG = ModelConfig.from_hf_config(MODEL)
+BLOCK = blocks.load("KeyeVL2")
+TOL = 5e-5
+S = 96
+IDS = np.random.default_rng(0).integers(0, 255, (S,)).astype(np.int32)
+BS, T, B = 8, 16, 2  # blocks of 8 tokens, 16 a row: a window of 128 columns
+
+
+@pytest.fixture(scope="module")
+def params():
+    """The block's seeded float32 weights, in the engine's layout."""
+    return weights.make_params(BLOCK, MODEL, 5, "f32", jax.devices()[:1])
+
+
+def ref_logits(params, ids, **wrong):
+    tables = {t.name: params[t.name] for t in BLOCK.tables(MODEL)}
+    h = reference.hidden_states(
+        BLOCK, MODEL, lambda l: jax.tree.map(lambda a: a[l], params["layers"]),
+        tables, [ids], **wrong,
+    )[0][: len(ids)]
+    with jax.default_matmul_precision("highest"):
+        return np.asarray(BLOCK.logits(h, tables, **BLOCK.head_static(MODEL)))
+
+
+def test_the_preset_is_the_models_config():
+    cfg = tiny_keye_vl2()
+    assert cfg.model_type == "llama"  # flags of the llama block, no new type
+    assert cfg.sparse_attn and cfg.qk_norm_per_head and cfg.norm_topk_prob
+    assert (cfg.index_heads, cfg.index_head_dim, cfg.index_topk) == (4, 8, 16)
+    assert cfg.intermediate_size == 32 and cfg.num_experts == 8
+    theirs = jax.eval_shape(
+        lambda: llama.init_layer_params(CFG, jax.random.key(0), 1))
+    assert {k: v.shape[1:] for k, v in theirs.items()} == {
+        l.name: l.shape for l in BLOCK.layer_leaves(MODEL)}
+
+
+@pytest.mark.parametrize("what, keys, match", [
+    ("a second index key a token",
+     dict(sa_config=dict(MODEL["sa_config"], indexer_num_kv_heads=2)),
+     "ONE index key"),
+    ("no topk", dict(sa_config={"indexer_num_heads": 4, "indexer_head_dim": 8}),
+     "lacks 'topk'"),
+    ("dense MLP layers", dict(mlp_only_layers=[0]), "mlp_only_layers"),
+    ("a sliding window", dict(use_sliding_window=True), "sliding-window"),
+    ("a scaled rotation", dict(rope_scaling={"rope_type": "yarn", "factor": 4}),
+     "rope_scaling"),
+])
+def test_what_the_block_cannot_honour_is_refused_by_name(what, keys, match):
+    with pytest.raises(ValueError, match=match):
+        ModelConfig.from_hf_config(dict(MODEL, **keys))
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "backend", "prefill"))
+def _layers(cfg, params, tokens, k, v, table, cols, kv_pos, positions, live,
+            *, backend, prefill):
+    with jax.default_matmul_precision("highest"):
+        h = llama.embed(params, tokens)
+        h, k, v, _, _, _ = llama.forward_layers_paged(
+            cfg, params["layers"], h, k, v, table, cols, kv_pos, positions,
+            backend=backend, prefill=prefill, moe_live=live,
+        )
+        return llama.final_logits(cfg, params, h)[0], k, v
+
+
+class Arenas:
+    """K, V and the index keys of ``B`` rows of ``T`` blocks, and one row's
+    key positions, driven a chunk or a token at a time."""
+
+    def __init__(self, cfg, params, backend):
+        self.cfg, self.params, self.backend = cfg, params, backend
+        shape = paged_arena_shape(cfg, 1 + B * T, BS)
+        self.k = jnp.zeros(shape, jnp.float32)
+        self.v = jnp.zeros(shape, jnp.float32)
+        self.idx = jnp.zeros((*shape[:2], 1, BS, cfg.index_cache_dim or 1),
+                             jnp.float32)
+        self.table = jnp.asarray(1 + np.arange(B * T).reshape(B, T), jnp.int32)
+        self.kv_pos = np.full((B, T * BS), POS_SENTINEL, np.int32)
+
+    def step(self, tokens, positions, cols, prefill):
+        """Row 0 is live, row 1 dead; returns row 0's logits."""
+        real = positions[0] != POS_SENTINEL
+        self.kv_pos[0, cols[0][real]] = positions[0][real]
+        k_in = (self.k, self.idx) if self.cfg.sparse_attn else self.k
+        live = np.zeros(positions.shape, bool)
+        live[0] = real
+        logits, k_out, self.v = _layers(
+            self.cfg, self.params, jnp.asarray(tokens), k_in, self.v,
+            self.table, jnp.asarray(cols), jnp.asarray(self.kv_pos),
+            jnp.asarray(positions), jnp.asarray(live), backend=self.backend,
+            prefill=prefill,
+        )
+        self.k, self.idx = k_out if self.cfg.sparse_attn else (k_out, self.idx)
+        return np.asarray(logits)
+
+
+def run(cfg, params, backend, ids, prompt, chunk=32):
+    """Prefill ``ids[:prompt]`` in chunks (the last padded), then decode the
+    rest one token at a time: the logits at every position."""
+    a = Arenas(cfg, params, backend)
+    logits = []
+    with jax.default_matmul_precision("highest"):
+        for c0 in range(0, prompt, chunk):
+            n = min(chunk, prompt - c0)
+            tokens = np.zeros((B, chunk), np.int32)
+            tokens[0, :n] = ids[c0:c0 + n]
+            positions = np.full((B, chunk), POS_SENTINEL, np.int32)
+            positions[0, :n] = np.arange(c0, c0 + n)
+            cols = np.broadcast_to(
+                c0 + np.arange(chunk, dtype=np.int32), (B, chunk)).copy()
+            logits.append(a.step(tokens, positions, cols, True)[:n])
+        col = -(-prompt // chunk) * chunk  # decode columns follow the chunks
+        for t in range(prompt, len(ids)):
+            tok = np.asarray([[ids[t]], [0]], np.int32)
+            pos = np.asarray([[t], [POS_SENTINEL]], np.int32)
+            cols = np.asarray([[col], [0]], np.int32)
+            logits.append(a.step(tok, pos, cols, False)[:1])
+            col += 1
+    return np.concatenate(logits), a
+
+
+@pytest.mark.parametrize("backend", ["xla", "interpret"])
+def test_prefill_then_decode_through_the_paged_arenas(params, backend):
+    """A 72-token prompt prefilled in chunks of 32 (``topk`` 16 is crossed
+    inside the first chunk; the third chunk is 8 tokens and 24 pads; a dead
+    second row), then 24 tokens decoded one at a time through the arenas:
+    every position's logits equal the reference's full forward pass."""
+    got, arenas = run(CFG, params, backend, IDS, 72)
+    np.testing.assert_allclose(got, ref_logits(params, IDS), atol=TOL)
+    # the index keys landed beside K, in the same blocks, for every layer:
+    # the three chunks' 96 columns (the last chunk's 24 pads are written as
+    # K's are, at the sentinel position) and the 24 decoded ones behind them
+    written = np.abs(np.asarray(arenas.idx)).sum(axis=(2, 4)) > 0  # [L, NB, BS]
+    assert written[:, 1:1 + T].reshape(2, -1).sum(axis=1).tolist() == [120, 120]
+
+
+def test_a_decode_only_request_crosses_topk(params):
+    """A 5-token prompt (one padded chunk) and 40 decoded tokens: the
+    selection starts mid-decode (position 16 is the first to leave a key
+    out), on the XLA path."""
+    ids = IDS[:45]
+    got, _ = run(CFG, params, "xla", ids, 5)
+    np.testing.assert_allclose(got, ref_logits(params, ids), atol=TOL)
+
+
+def test_while_the_context_fits_topk_the_layer_is_the_llama_path(params):
+    """``context <= topk``: the selection is everything, and the layer with
+    its indexer equals the llama block on the same leaves without one (the
+    olmoe path with a per-head norm) — and the reference with no selection."""
+    wide = dataclasses.replace(CFG, index_topk=10_000)
+    got, _ = run(wide, params, "xla", IDS, 72)
+    plain_cfg = dataclasses.replace(
+        CFG, index_topk=0, index_heads=0, index_head_dim=0)
+    layers = {k: v for k, v in params["layers"].items()
+              if k not in ("wq_idx", "wk_idx", "w_idx", "k_idx_norm",
+                           "k_idx_bias")}
+    want, _ = run(plain_cfg, dict(params, layers=layers), "xla", IDS, 72)
+    np.testing.assert_allclose(got, want, atol=TOL)
+    np.testing.assert_allclose(
+        got, ref_logits(params, IDS, select="all"), atol=TOL)
+    # ... and under topk 16 it is NOT: the selection does something
+    assert np.abs(got - ref_logits(params, IDS)).max() > 100 * TOL
+
+
+def test_a_decode_step_chooses_the_references_positions(params):
+    """Layer 0's choice at the last position (95): the program's list of
+    columns, mapped to positions, is the reference's kept set (no two scores
+    tie with these weights)."""
+    _, a = run(CFG, params, "xla", IDS, 72)
+    p = jax.tree.map(lambda w: w[0], params["layers"])
+    x = np.asarray(llama.embed(params, jnp.asarray(IDS)[None]))[0]
+    with jax.default_matmul_precision("highest"):
+        xn = BLOCK.rms_norm(jnp.asarray(x), p["input_norm"], CFG.rms_norm_eps)
+        qi = BLOCK.rotary((xn @ p["wq_idx"]).reshape(S, 4, 8), CFG.rope_theta)
+        ki = BLOCK.rotary(BLOCK.layer_norm(
+            xn @ p["wk_idx"], p["k_idx_norm"], p["k_idx_bias"],
+            CFG.rms_norm_eps)[:, None], CFG.rope_theta)[:, 0]
+        wi = (xn @ p["w_idx"]) * 32 ** -0.5
+        score = jnp.einsum("h,ht->t", wi[-1], jax.nn.relu(
+            jnp.einsum("hd,td->ht", qi[-1], ki)))
+        want = np.flatnonzero(np.asarray(BLOCK.keep_topk(score[None], 16))[0])
+        # the program, from its arenas: the same index keys, the same query
+        sel = pa.Selection(qi[-1][None, None], wi[-1][None, None],
+                           jnp.asarray(a.idx), 16)
+        tbl, kv = a.table[:1], jnp.asarray(a.kv_pos[:1])
+        ok = pa._attendable(tbl, jnp.asarray([[S - 1]]), kv, BS)
+        cols, real = pa.select_tokens(
+            pa.index_scores(sel, 0, tbl, None, None, ok)[:, 0], 16)
+    assert bool(np.asarray(real).all())
+    got = np.sort(a.kv_pos[0][np.asarray(cols)[0]])
+    assert got.tolist() == want.tolist() and len(want) == 16
+
+
+def test_a_stale_index_key_is_never_scored(params):
+    """The trash block and a block another request left behind hold index
+    keys of ANY value (here huge ones): they sit at the sentinel position or
+    in a block the row does not own, and the served logits do not move."""
+    want, clean = run(CFG, params, "xla", IDS[:60], 40)
+    poisoned = Arenas(CFG, params, "xla")
+    poisoned.idx = jnp.full_like(poisoned.idx, 1e4)  # every block, trash too
+    poisoned.k = jnp.full_like(poisoned.k, 1e4)
+    real_step = Arenas.step
+    logits = []
+    with jax.default_matmul_precision("highest"):
+        for c0 in (0, 32):
+            n = min(32, 40 - c0)
+            tokens = np.zeros((B, 32), np.int32)
+            tokens[0, :n] = IDS[c0:c0 + n]
+            positions = np.full((B, 32), POS_SENTINEL, np.int32)
+            positions[0, :n] = np.arange(c0, c0 + n)
+            cols = np.broadcast_to(c0 + np.arange(32, dtype=np.int32),
+                                   (B, 32)).copy()
+            logits.append(real_step(poisoned, tokens, positions, cols, True)[:n])
+        for i, t in enumerate(range(40, 60)):
+            logits.append(real_step(
+                poisoned, np.asarray([[IDS[t]], [0]], np.int32),
+                np.asarray([[t], [POS_SENTINEL]], np.int32),
+                np.asarray([[64 + i], [0]], np.int32), False)[:1])
+    np.testing.assert_allclose(np.concatenate(logits), want, atol=TOL)
+
+
+WRONG = {
+    "a wrong theta": dict(theta=1e6),
+    "the index key's LayerNorm bias dropped": dict(index_bias=False),
+    "wI dropped": dict(index_weights=False),
+    "the most recent topk keys": dict(select="recent"),
+    "no selection": dict(select="all"),
+}
+
+
+@pytest.mark.parametrize("what", list(WRONG))
+def test_a_wrong_model_reads_not_correct(params, what):
+    """Each of these is a model the program must NOT be: its logits lie far
+    outside the tolerance the program is held to (4 x and much more)."""
+    err = np.abs(
+        ref_logits(params, IDS, **WRONG[what]) - ref_logits(params, IDS)
+    ).max()
+    assert err > 100 * TOL, (what, err)
+
+
+def test_the_dense_cache_path_refuses_by_name(params):
+    from llm_sharding_tpu.models.cache import init_cache
+
+    cache = init_cache(CFG, 1, capacity=32, dtype=jnp.float32)
+    with pytest.raises(NotImplementedError, match="paged arenas only"):
+        llama.forward(CFG, params, jnp.asarray(IDS[:8])[None], cache,
+                      jnp.arange(8)[None])
+
+
+def test_the_converter_refuses_a_keye_checkpoint_by_name():
+    """The names of its tensors are in no file here: nothing is guessed."""
+    from llm_sharding_tpu.utils import convert
+
+    with pytest.raises(NotImplementedError, match="KeyeVL2.*names of a Keye"):
+        convert.params_from_hf(CFG, {}, jnp.float32)

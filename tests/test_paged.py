@@ -176,13 +176,14 @@ def test_state_specs_field_parity(setup):
         )
         specs = serve_ops.state_specs(state)
         assert state._fields == specs._fields
-        # a windowed model's second KV state (k_swa / v_swa / tables_swa) and
-        # a recurrent-state model's ``recurrent`` tree are None — an empty
-        # pytree, no operand of any program — for every other model, in the
-        # state and in its specs alike
+        # a windowed model's second KV state (k_swa / v_swa / tables_swa), a
+        # recurrent-state model's ``recurrent`` tree and a token-selecting
+        # model's index arena ``idx`` are None — an empty pytree, no operand
+        # of any program — for every other model, in the state and in its
+        # specs alike
         live = {k: v for k, v in specs._asdict().items() if v is not None}
         assert set(specs._fields) - set(live) == {
-            "k_swa", "v_swa", "tables_swa", "recurrent"}
+            "k_swa", "v_swa", "tables_swa", "recurrent", "idx"}
         assert all(getattr(state, k) is None for k in set(specs._fields) - set(live))
         for name, spec in live.items():
             assert isinstance(spec, jax.sharding.PartitionSpec), name
@@ -1665,7 +1666,8 @@ def _windowed_projections(text):
 
 @pytest.mark.parametrize(
     "cell", sorted(_CELL_SHAPES) + [
-        "gigachat31_702b_a36b", "nemotron3_super_120b_a12b"])
+        "gigachat31_702b_a36b", "nemotron3_super_120b_a12b",
+        "keye_vl2_30b_a3b"])
 def test_a_decode_step_reads_its_weights_as_they_are_stored(v5e_host, cell):
     """The compiled ``serve_chunk`` of each benchmark configuration, at its
     real geometry for the described v5e (``benchmark/aot_check.py`` builds
@@ -2026,8 +2028,13 @@ _NO_HEAD = {"head", "sample"}
 # ``jamba``'s alone: ``tests/test_nemotron_h_serve.py`` and
 # ``tests/test_jamba_serve.py`` hold their programs to them)
 _RECURRENT_WORDS = {"ssm_proj", "conv", "ssm", "ssm_x", "moe_latent"}
-_MLP_WORDS = {"dense": {"router", "moe", "absorb"} | _RECURRENT_WORDS,
-              "experts": {"mlp", "absorb"} | _RECURRENT_WORDS}
+# (``indexer`` / ``select`` are a token-selecting model's alone:
+# ``tests/test_keye_vl2_serve.py`` holds its programs to them)
+_SELECT_WORDS = {"indexer", "select"}
+_MLP_WORDS = {
+    "dense": {"router", "moe", "absorb"} | _RECURRENT_WORDS | _SELECT_WORDS,
+    "experts": {"mlp", "absorb"} | _RECURRENT_WORDS | _SELECT_WORDS,
+}
 PROGRAM_SCOPES = {
     "serve_chunk": _NO_ARENA_COPY,
     "serve_prefill_chunk": _NO_ARENA_COPY | _NO_HEAD,
