@@ -395,8 +395,8 @@ def paged_decoder_layer(
     A token-selecting model (``cfg.sparse_attn``): the layer's index key
     lands in the index arena beside K and V, under the same table, and the
     attention reads the ``cfg.index_topk`` keys the indexer scores highest
-    (``select=``: a decode step gathers exactly those tokens; a chunk masks
-    the others out of the dense product)."""
+    (``select=``: a decode step masks the others out of the decode kernel's
+    walk by their key positions; a chunk out of the dense product)."""
     from ..ops.paged_attention import (
         Selection, combine_attn_stats, paged_attention_write, paged_prefill,
         write_chunk_kv, write_index_keys,

@@ -549,22 +549,29 @@ KV_KIND_ENTRY_BYTES = REGISTRY.gauge(
 # a token-selecting model (a learned sparse-attention indexer,
 # ``cfg.sparse_attn``): per decode step, summed over layers, host-side from
 # the length mirrors — read / live is the share of the context a step's
-# attention reads (100% for a program that reads every live token)
+# attention KEEPS (100% for a program that attends every live token), walked
+# / live the share whose K/V blocks it streams to do so
 SPARSE_TOKENS_SCORED = REGISTRY.counter(
     "server_sparse_tokens_scored_total",
     "Index keys a decode step's queries were scored against (a row's live "
     "context once it is longer than topk; 0 while the selection is "
-    "everything), summed over rows and layers",
+    "everything and nothing is scored), summed over rows and layers",
 )
 SPARSE_TOKENS_READ = REGISTRY.counter(
     "server_sparse_tokens_read_total",
-    "Tokens whose K/V a decode step's attention read: min(context, topk) a "
-    "row and layer, summed over rows and layers",
+    "Tokens kept by the selection, the ones a decode step's attention "
+    "attends: min(context, topk) a row and layer, summed over rows and layers",
 )
 SPARSE_TOKENS_LIVE = REGISTRY.counter(
     "server_sparse_tokens_live_total",
     "Live context tokens of the rows in a decode step of a token-selecting "
     "model, summed over rows and layers",
+)
+SPARSE_TOKENS_WALKED = REGISTRY.counter(
+    "server_sparse_tokens_walked_total",
+    "Tokens whose K/V blocks a decode step's attention streamed: the rows' "
+    "live context a layer (the selection is a mask over the decode kernel's "
+    "walk, not a gather of the kept tokens), summed over rows and layers",
 )
 KV_WINDOW_BLOCKS_FREED = REGISTRY.counter(
     "server_kv_window_blocks_freed_total",

@@ -283,9 +283,10 @@ class StepRecord:
         self.scan_positions = None
         # a token-selecting model (None otherwise), summed over the layers
         # and decode dispatches of this step: ``{"scored": index keys a
-        # row's query was scored against, "read": tokens whose K/V the
-        # attention read, "live": live context tokens of the rows}`` (host
-        # arithmetic at dispatch, from the length mirrors)
+        # row's query was scored against, "read": tokens kept by the
+        # selection, "live": live context tokens of the rows, "walked":
+        # tokens whose K/V blocks the attention streamed}`` (host arithmetic
+        # at dispatch, from the length mirrors)
         self.sparse_tokens = None
 
     @property
@@ -382,7 +383,7 @@ class StepProfiler:
         self._decode_kv_entries = None  # {"kernel" | "scatter": entries}
         self._recurrent_rows = None
         self._scan_positions = None  # {"real" | "pad": positions}
-        self._sparse_tokens = None  # {"scored" | "read" | "live": tokens}
+        self._sparse_tokens = None  # {"scored" | "read" | "live" | "walked": n}
         self._kv_kinds = None
         self._phases: Dict[str, float] = {}
         self._blocked_s = 0.0
@@ -664,16 +665,19 @@ class StepProfiler:
         acc["pad"] += int(pad)
         self._scan_positions = acc
 
-    def sparse_tokens(self, scored: int, read: int, live: int) -> None:
+    def sparse_tokens(
+        self, scored: int, read: int, live: int, walked: int
+    ) -> None:
         """Add one decode dispatch's selection to the step's record (a
-        token-selecting model): index keys scored, tokens whose K/V was
-        read, live context tokens — each summed over rows and layers."""
+        token-selecting model): index keys scored, tokens kept by the
+        selection, live context tokens, tokens whose K/V blocks the
+        attention streamed — each summed over rows and layers."""
         if not self._enabled or self._t0 is None:
             return
-        acc = self._sparse_tokens or {"scored": 0, "read": 0, "live": 0}
-        acc["scored"] += int(scored)
-        acc["read"] += int(read)
-        acc["live"] += int(live)
+        acc = self._sparse_tokens or {}
+        for key, n in (("scored", scored), ("read", read), ("live", live),
+                       ("walked", walked)):
+            acc[key] = acc.get(key, 0) + int(n)
         self._sparse_tokens = acc
 
     def experts(self, tokens, read=None, steps: int = 0, rows: int = 0) -> None:

@@ -1938,15 +1938,21 @@ def paged_attention_write(
 # ``[L, NB, 1, BS, lanes]`` (the key padded with zeros to whole 128-lane tiles,
 # ``cfg.index_cache_dim``), in the same blocks under the same table as K and V.
 # A query scores every live index key of its row (``index_scores``), keeps the
-# ``topk`` best (``select_tokens``; all of them while the context is no longer
-# than that) and attends those tokens ONLY. A decode step GATHERS the chosen
-# tokens' K and V, one row of ``D`` a token and head (``selected_attention``:
-# what it reads of K/V is ``min(context, topk)`` tokens a row and layer); a
-# prefill chunk masks the others out of the dense product over the row's
-# window (``selected_prefill``: for 256 queries a gather of 256 x topk tokens
-# would be 1 GB a layer, and the masked product is the same mathematics).
-# While no query of the call reaches past ``topk`` keys the selection is
-# everything, and both take the unselected kernel as it is (``lax.cond``).
+# ``topk`` best (``select_mask``: a mask over the row's columns; all of them
+# while the context is no longer than that; ``select_tokens`` is the same choice
+# as a list) and attends those tokens ONLY. A decode step hands the mask to the
+# attention as KEY POSITIONS — a column the query did not choose sits at the
+# sentinel (``selected_attention``) — and ``paged_attention`` runs as it is: it
+# streams the row's live blocks once, where they lie, and its own position test
+# keeps ``min(context, topk)`` tokens a row and layer (a row gather of the
+# chosen tokens costs the chip ~10 ns a row of ``D``, ~150 us a layer call at
+# any context, where the walk takes 22 us up to 2.5 k of context and 59 at
+# 8.7 k: PERF.md, PR 49 and 50). A prefill chunk masks the others
+# out of the dense product over the row's window (``selected_prefill``: one
+# mask a QUERY, which the kernels' one position a key cannot carry). While no
+# query of the call reaches past ``topk`` keys the selection is everything:
+# nothing is scored, and both take the key positions / the unselected kernel
+# as they are (``lax.cond``).
 
 
 class Selection(NamedTuple):
@@ -2154,81 +2160,44 @@ def select_mask(scores: jnp.ndarray, topk: int) -> jnp.ndarray:
     return above | (tie & (jnp.cumsum(tie, axis=-1) <= left))
 
 
-@jax.named_scope("attn")
-def _attend_list(q, k_arena, v_arena, layer, block_table, cols, chosen,
-                 q_positions, kv_positions, scale, live):
-    """Attention of ``q [B, 1, Nh, D]`` over the LIST of columns ``cols [B,
-    K]``: each chosen token's K and V rows are gathered out of the arena where
-    they lie — one row of ``D`` a token and head, indexed in every dim but the
-    last (a 2-D row gather of the flattened pool: nothing of a layer's or a
-    block's size is produced) — and nothing else of K/V is read. The LIVE
-    rows only (``live [B]``), one at a time: a row gather costs the chip ~10
-    ns a row of ``D`` whatever it reads (PERF.md, PR 49), so a dead row of the
-    slot would cost what a live one does. A dead row returns zeros."""
-    from ..models.cache import POS_SENTINEL  # models imports this module
-
-    _, NB, Nkv, BS, D = k_arena.shape
-    kf = k_arena.reshape(-1, D)
-    vf = v_arena.reshape(-1, v_arena.shape[-1])
-    order = jnp.argsort(~live, stable=True).astype(jnp.int32)  # live first
-
-    def one_row(i, out):
-        b = order[i]
-        row = lambda a: jax.lax.dynamic_slice_in_dim(a, b, 1, axis=0)
-        c = row(cols)  # [1, K]
-        blk = jnp.take_along_axis(row(block_table), c // BS, axis=1)
-        rows = (
-            ((layer * NB + blk)[:, :, None] * Nkv + jnp.arange(Nkv)) * BS
-            + (c % BS)[:, :, None]
-        )  # [1, K, Nkv]
-        kv_pos = jnp.where(
-            row(chosen), jnp.take_along_axis(row(kv_positions), c, axis=1),
-            POS_SENTINEL,
-        )
-        o = cached_attention(
-            row(q), kf.at[rows].get(mode="promise_in_bounds"),
-            vf.at[rows].get(mode="promise_in_bounds"), row(q_positions),
-            kv_pos, scale,
-        )
-        return jax.lax.dynamic_update_slice_in_dim(out, o, b, axis=0)
-
-    out = jnp.zeros((*q.shape[:-1], vf.shape[-1]), q.dtype)
-    return jax.lax.fori_loop(0, jnp.sum(live), one_row, out)
-
-
 def selected_attention(
     q, k_arena, v_arena, layer, block_table, q_positions, kv_positions,
     select: Selection, scale=None, backend: str = "auto",
 ):
-    """A DECODE step's attention under a selection (one query a row)."""
+    """A DECODE step's attention under a selection (one query a row): the
+    selection is a MASK over the row's columns, and it enters the attention
+    as key positions — a column the query did not choose sits at the
+    sentinel, which no query position reaches. ``paged_attention`` takes
+    them as it is: the kernel walks the row's live blocks once, where they
+    lie (``_live_blocks`` reads the masked positions: the walk ends at the
+    last block that holds a chosen token, and a dead row costs nothing), and
+    its own ``kv_pos <= q_pos`` test drops every other column; the XLA and
+    ``interpret`` paths mask by the same positions."""
+    from ..models.cache import POS_SENTINEL  # models imports this module
+
     if q.shape[1] != 1:
         raise NotImplementedError(
             "a selecting decode step takes one query a row"
         )
-    BS = k_arena.shape[3]
-    ok = _attendable(block_table, q_positions, kv_positions, BS)
-
-    def dense(_):
-        return paged_attention(
-            q, k_arena, v_arena, layer, block_table, q_positions,
-            kv_positions, scale, backend=backend,
-        )
-
+    ok = _attendable(block_table, q_positions, kv_positions, k_arena.shape[3])
     path = decode_path(backend, q.shape[-1], k_arena, block_table)
 
     def chosen(_):
         scores = index_scores(
             select, layer, block_table, q_positions, kv_positions, ok, path
         )[:, 0]
-        cols, real = select_tokens(scores, select.topk)
-        return _attend_list(
-            q, k_arena, v_arena, layer, block_table, cols, real,
-            q_positions, kv_positions, scale, jnp.any(ok, axis=(1, 2)),
-        )
+        keep = select_mask(scores, select.topk)  # [B, W]
+        with jax.named_scope("select"):
+            return jnp.where(keep, kv_positions, POS_SENTINEL)
 
-    # while no row's query reaches past topk keys the selection is everything
+    # while no row's query reaches past topk keys the selection is
+    # everything: no score, no top-k, the key positions as they are
     beyond = jnp.any(jnp.sum(ok, axis=-1) > select.topk)
-    return jax.lax.cond(beyond, chosen, dense, None)
+    kv = jax.lax.cond(beyond, chosen, lambda _: kv_positions, None)
+    return paged_attention(
+        q, k_arena, v_arena, layer, block_table, q_positions, kv, scale,
+        backend=backend,
+    )
 
 
 def selected_prefill(

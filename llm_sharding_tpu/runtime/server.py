@@ -81,6 +81,7 @@ from ..obs.metrics import (
     KV_KIND_BLOCKS_IN_USE, KV_KIND_BLOCKS_TOTAL, KV_KIND_ENTRY_BYTES,
     KV_WINDOW_BLOCKS_FREED,
     SPARSE_TOKENS_LIVE, SPARSE_TOKENS_READ, SPARSE_TOKENS_SCORED,
+    SPARSE_TOKENS_WALKED,
     DEFAULT_RATE_BUCKETS,
     KV_BLOCKS_IN_USE, KV_BLOCKS_TOTAL, KV_DISK_TIER_BLOCKS,
     KV_ENTRY_BYTES, KV_HOST_TIER_BLOCKS, KV_WASTE_FRAC, MOE_EXPERT_TOKENS,
@@ -1699,19 +1700,24 @@ class PipelineServer:
             # what the selection made of the step (ops/paged_attention.
             # selected_attention asks the same of the device arrays): once a
             # row's context is longer than topk every row's query is scored
-            # against its live index keys and each reads the tokens it chose
+            # against its live index keys and each keeps the tokens it chose;
+            # the attention streams the blocks of the rows' whole context
+            # either way (the selection is a mask over the walk)
             topk = self.cfg.index_topk
             layers = self.num_stages * int(self.state.k.shape[1])
             ctx = [max(int(self._mirror_len[r]), 1) for r in rows]
             beyond = max(ctx) > topk
+            context = sum(ctx)
             counts = [
                 n * layers * steps for n in (
-                    sum(ctx) if beyond else 0,
-                    sum(min(n, topk) for n in ctx), sum(ctx),
+                    context if beyond else 0,  # scored
+                    sum(min(n, topk) for n in ctx),  # read: kept
+                    context, context,  # live; walked: all that is live
                 )
             ]
             for counter, n in zip(
-                (SPARSE_TOKENS_SCORED, SPARSE_TOKENS_READ, SPARSE_TOKENS_LIVE),
+                (SPARSE_TOKENS_SCORED, SPARSE_TOKENS_READ, SPARSE_TOKENS_LIVE,
+                 SPARSE_TOKENS_WALKED),
                 counts,
             ):
                 counter.inc(n)

@@ -261,6 +261,138 @@ def test_a_stale_index_key_is_never_scored(params):
     np.testing.assert_allclose(np.concatenate(logits), want, atol=TOL)
 
 
+# ---- the selection as a mask over the decode kernel's walk (PR 50) ----------
+# ``selected_attention`` at the level of the op, on arenas built by hand: 2 rows
+# of 16 blocks of 8 tokens (128 columns), GQA 4 / 2 heads of 16, an indexer of 4
+# heads x 8 over a stored key of 128 lanes, ``topk`` 16, float32.
+
+_W, _TOPK, _NH, _NKV, _D, _HI, _DI = T * BS, 16, 4, 2, 16, 4, 8
+
+
+def _selecting_case(case):
+    """``(contexts, q_pos, logical index keys [B, W, Di], poison)`` of a case:
+    a row's context is the columns it has written (position = column), ``q_pos``
+    its query's position (the sentinel: a dead row)."""
+    rng = np.random.default_rng(len(case))
+    ctx, poison = [40, 50], False
+    ki = rng.standard_normal((2, _W, _DI)).astype(np.float32)
+    qi = rng.standard_normal((2, _HI, _DI)).astype(np.float32)
+    wi = rng.uniform(0.5, 1.5, (2, _HI)).astype(np.float32)
+    if case == "many scores tied at 0 across the topk-th place":
+        # every product of a key with a query is negative but for five keys a
+        # row: relu makes the others' scores exactly 0, on every backend
+        qi, ki = np.abs(qi), -np.abs(ki)
+        for b in range(2):
+            ki[b, rng.choice(ctx[b], 5, replace=False)] *= -1
+    elif case == "a dead row beside a live one":
+        ctx = [0, 50]
+    elif case == "a row under topk beside a row past it":
+        ctx = [10, 50]
+    elif case == "stale keys in a freed block and in the trash block":
+        ctx, poison = [44, 50], True  # 44: four stale columns in the last block
+    elif case == "the query's own token not among the chosen":
+        qi, ki = np.abs(qi), np.abs(ki)
+        for b in range(2):
+            ki[b, ctx[b] - 1] *= -1  # the newest key scores 0, the others > 0
+    else:
+        assert case == "no ties"
+    q_pos = [c - 1 if c else POS_SENTINEL for c in ctx]
+    return ctx, q_pos, qi, wi, ki, poison
+
+
+SELECTING = [
+    "no ties", "many scores tied at 0 across the topk-th place",
+    "a dead row beside a live one", "a row under topk beside a row past it",
+    "stale keys in a freed block and in the trash block",
+    "the query's own token not among the chosen",
+]
+
+
+@pytest.mark.parametrize("case", SELECTING)
+@pytest.mark.parametrize("backend", ["xla", "interpret"])
+def test_a_selecting_decode_step_attends_the_list_select_tokens_gives(
+        backend, case):
+    """``selected_attention`` — the mask as key positions, through
+    ``paged_attention`` — against a plain softmax over the LIST
+    ``select_tokens`` makes of scores computed here in numpy: the same set
+    (ties at the ``topk``-th score go to the lowest columns), nothing of a
+    column outside it, whatever a block the row does not own holds."""
+    ctx, q_pos, qi, wi, ki, poison = _selecting_case(case)
+    rng = np.random.default_rng(7)
+    k = rng.standard_normal((2, _W, _NKV, _D)).astype(np.float32)
+    v = rng.standard_normal((2, _W, _NKV, _D)).astype(np.float32)
+    q = rng.standard_normal((2, 1, _NH, _D)).astype(np.float32)
+    fill = 1e4 if poison else 0.0
+    L, NB = 2, 1 + 2 * T
+    ka = np.full((L, NB, _NKV, BS, _D), fill, np.float32)
+    va = np.full((L, NB, _NKV, BS, _D), fill, np.float32)
+    ia = np.full((L, NB, 1, BS, 128), fill, np.float32)
+    table = np.zeros((2, T), np.int32)
+    kv_pos = np.full((2, _W), POS_SENTINEL, np.int32)
+    for b in range(2):
+        nb = -(-ctx[b] // BS)
+        table[b, :nb] = 1 + b * T + np.arange(nb)  # the rest: the trash block
+        kv_pos[b, :ctx[b]] = np.arange(ctx[b])
+        for c in range(ctx[b]):
+            blk, slot = table[b, c // BS], c % BS
+            ka[1, blk, :, slot], va[1, blk, :, slot] = k[b, c], v[b, c]
+            ia[1, blk, 0, slot] = 0.0
+            ia[1, blk, 0, slot, :_DI] = ki[b, c]
+    select = pa.Selection(
+        jnp.asarray(qi)[:, None], jnp.asarray(wi)[:, None], jnp.asarray(ia),
+        _TOPK)
+    got = np.asarray(pa.selected_attention(
+        jnp.asarray(q), jnp.asarray(ka), jnp.asarray(va), 1,
+        jnp.asarray(table), jnp.asarray(q_pos, jnp.int32)[:, None],
+        jnp.asarray(kv_pos), select, backend=backend))
+    assert np.isfinite(got).all()
+    for b in range(2):
+        if not ctx[b]:
+            continue  # a dead row: whatever it reads is nobody's
+        score = np.full(_W, -np.inf, np.float32)
+        score[:ctx[b]] = np.einsum(
+            "h,hw->w", wi[b], np.maximum(qi[b] @ ki[b, :ctx[b]].T, 0.0))
+        cols, real = (np.asarray(a) for a in pa.select_tokens(
+            jnp.asarray(score), _TOPK))
+        cols = cols[real]
+        assert len(cols) == min(ctx[b], _TOPK)
+        if case.startswith("many scores tied"):
+            assert (score[cols] == 0).sum() == _TOPK - 5
+        if case.startswith("the query's own"):
+            assert ctx[b] - 1 not in cols
+        for h in range(_NH):
+            logit = k[b, cols, h // 2] @ q[b, 0, h] * _D ** -0.5
+            p = jax.nn.softmax(jnp.asarray(logit))
+            np.testing.assert_allclose(
+                got[b, 0, h], np.asarray(p) @ v[b, cols, h // 2], atol=TOL)
+
+
+@pytest.mark.parametrize("shape", [(3, 96), (2, 5, 96)])
+@pytest.mark.parametrize("ties", ["at the topk-th place", "none",
+                                  "fewer keys than topk"])
+def test_the_mask_is_the_set_of_the_list(shape, ties):
+    """``select_mask`` keeps exactly the columns ``select_tokens`` lists
+    (those that are real choices), with and without a leading query dim:
+    random scores, a third of them forced to tie at 0 (a sum of ``relu``s),
+    and queries with fewer attendable keys than ``topk``."""
+    rng = np.random.default_rng(len(ties) + len(shape))
+    scores = rng.standard_normal(shape).astype(np.float32)
+    if ties == "at the topk-th place":
+        scores = np.maximum(scores, 0.0)  # about half the columns tie at 0
+        scores[..., :7] = 3.0  # ... and seven tie above them
+    live = 12 if ties == "fewer keys than topk" else 80
+    scores[..., live:] = -np.inf
+    topk = 60 if ties == "at the topk-th place" else 24
+    keep = np.asarray(pa.select_mask(jnp.asarray(scores), topk))
+    cols, real = (np.asarray(a) for a in pa.select_tokens(
+        jnp.asarray(scores), topk))
+    want = np.zeros(shape, bool)
+    np.put_along_axis(want, np.where(real, cols, cols[..., :1]), True, axis=-1)
+    assert keep.sum(axis=-1).tolist() == real.sum(axis=-1).tolist()
+    assert (keep == want).all()
+    assert (keep.sum(axis=-1) == min(topk, live)).all()
+
+
 WRONG = {
     "a wrong theta": dict(theta=1e6),
     "the index key's LayerNorm bias dropped": dict(index_bias=False),

@@ -223,14 +223,15 @@ def test_the_step_programs_name_the_indexer_and_the_selection(
 
 
 def test_the_counters_the_gauges_and_the_metrics_rows(params):
-    """What a decode step scored, read and found live (host arithmetic at
-    dispatch, summed over rows and layers), and the index arena under a kind
-    name of its own beside K/V's."""
+    """What a decode step scored, kept, found live and walked (host
+    arithmetic at dispatch, summed over rows and layers), and the index arena
+    under a kind name of its own beside K/V's."""
     from llm_sharding_tpu.runtime.server import _update_load_gauges
 
     base = {c: c.value for c in (metrics.SPARSE_TOKENS_SCORED,
                                  metrics.SPARSE_TOKENS_READ,
-                                 metrics.SPARSE_TOKENS_LIVE)}
+                                 metrics.SPARSE_TOKENS_LIVE,
+                                 metrics.SPARSE_TOKENS_WALKED)}
     srv = engine(params).serve(paged_attn="xla", **dict(PAGED, batch_per_slot=1))
     item = 4  # a float32 arena here
     assert metrics.KV_KIND_ENTRY_BYTES.labels(kind="kv").value == 2 * 2 * 16 * item
@@ -250,19 +251,24 @@ def test_the_counters_the_gauges_and_the_metrics_rows(params):
     text = metrics.REGISTRY.prometheus_text()
     srv.close()
     assert len(req.tokens) == 12
-    scored, read, live = (c.value - base[c] for c in base)
+    scored, read, live, walked = (c.value - base[c] for c in base)
     # 11 decode dispatches at contexts 9..19 after the injected last prompt
     # token's (the host's length mirror), 2 layers: everything is live, the
-    # steps past a context of 16 score it all and read 16 of it
+    # steps past a context of 16 score it all and KEEP 16 of it; the
+    # attention streams the blocks of all of it on every dispatch (the
+    # selection is a mask over the decode kernel's walk)
     steps = [r["sparse_tokens"] for r in recs if "sparse_tokens" in r]
     assert sum(s["live"] for s in steps) == live > 0
     assert sum(s["read"] for s in steps) == read
     assert sum(s["scored"] for s in steps) == scored
     assert 0 < read < live and 0 < scored < live
     assert all(s["read"] <= 16 * 2 * max(1, s["live"] // 18) for s in steps)
+    assert all(s["walked"] == s["live"] for s in steps) and walked == live
+    assert all(set(s) == {"scored", "read", "live", "walked"} for s in steps)
     for family in ("server_sparse_tokens_scored_total",
                    "server_sparse_tokens_read_total",
                    "server_sparse_tokens_live_total",
+                   "server_sparse_tokens_walked_total",
                    'server_kv_kind_entry_bytes{kind="index"}',
                    'server_kv_kind_blocks_in_use{kind="index"}'):
         assert family in text

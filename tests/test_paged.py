@@ -1664,11 +1664,43 @@ def _windowed_projections(text):
     return dots, windowed
 
 
+@pytest.fixture(scope="module")
+def compiled_serve_chunk(v5e_host):
+    """``text(cell)``: the compiled text of a benchmark configuration's
+    ``serve_chunk`` at its real geometry for the described v5e
+    (``benchmark/aot_check.py`` builds the abstract inputs; the ring takes
+    four chips). Compiled once a cell, for the tests of this file."""
+    from benchmark import aot_check
+    from llm_sharding_tpu.parallel.mesh import pipeline_mesh
+
+    texts = {}
+
+    def text(cell):
+        if cell not in texts:
+            path = os.path.join(aot_check.HERE, "configs", cell + ".json")
+            with open(path) as f:
+                cfg_file = json.load(f)
+            stages = int(cfg_file["deployment"]["num_stages"])
+            mesh = pipeline_mesh(stages, v5e_host[:stages])
+            # conftest's "highest" matmul precision is the CPU oracles'; the
+            # program asks jax.default_backend() which attention to lower
+            with jax.default_matmul_precision("default"), mock.patch.object(
+                jax, "default_backend", lambda: "tpu"
+            ):
+                name, lowered = next(aot_check.programs(cfg_file, mesh))
+            assert name == "serve_chunk"
+            texts[cell] = lowered.compile().as_text()
+        return texts[cell]
+
+    return text
+
+
 @pytest.mark.parametrize(
     "cell", sorted(_CELL_SHAPES) + [
         "gigachat31_702b_a36b", "nemotron3_super_120b_a12b",
         "keye_vl2_30b_a3b"])
-def test_a_decode_step_reads_its_weights_as_they_are_stored(v5e_host, cell):
+def test_a_decode_step_reads_its_weights_as_they_are_stored(
+        compiled_serve_chunk, cell):
     """The compiled ``serve_chunk`` of each benchmark configuration, at its
     real geometry for the described v5e (``benchmark/aot_check.py`` builds
     the abstract inputs; the ring takes four chips), consumes every weight
@@ -1686,21 +1718,7 @@ def test_a_decode_step_reads_its_weights_as_they_are_stored(v5e_host, cell):
     input-minor, and the stack of ``wkv_a`` was re-laid every call until the
     leaf was padded to the arena entry's 640 columns. Nothing runs: a
     compile is not a time."""
-    from benchmark import aot_check
-    from llm_sharding_tpu.parallel.mesh import pipeline_mesh
-
-    with open(os.path.join(aot_check.HERE, "configs", cell + ".json")) as f:
-        cfg_file = json.load(f)
-    stages = int(cfg_file["deployment"]["num_stages"])
-    mesh = pipeline_mesh(stages, v5e_host[:stages])
-    # conftest's "highest" matmul precision is the CPU oracles'; the
-    # program asks jax.default_backend() which attention to lower
-    with jax.default_matmul_precision("default"), mock.patch.object(
-        jax, "default_backend", lambda: "tpu"
-    ):
-        name, lowered = next(aot_check.programs(cfg_file, mesh))
-    assert name == "serve_chunk"
-    text = lowered.compile().as_text()
+    text = compiled_serve_chunk(cell)
     assert _weight_stack_relayouts(text) == []
     dots, windowed = _windowed_projections(text)
     assert len(dots) >= 3 and windowed == []
@@ -1710,6 +1728,38 @@ def test_a_decode_step_reads_its_weights_as_they_are_stored(v5e_host, cell):
     # or staging of it around one
     assert "paged_kv_write/pallas_call" in text
     assert _arena_ops(text) == []
+
+
+def test_a_selecting_decode_step_reads_k_and_v_through_a_kernel_only(
+        compiled_serve_chunk):
+    """``keye_vl2_30b_a3b``'s compiled ``serve_chunk`` (PR 50): the selection
+    reaches the attention as key positions, so nothing but a kernel reads the
+    K or V arena — no ``gather`` has an arena, or a reshape of one, for its
+    operand (the parent gathered the 2,048 chosen tokens' rows out of the
+    flattened pools, 16,384 rows a layer call: 34% of its step on the chip) —
+    and the decode kernel appears ONCE in the layer body, outside the
+    ``cond`` that chooses the key positions (a score kernel and a top-k on
+    one side, the positions as they are on the other), not once a branch."""
+    text = compiled_serve_chunk("keye_vl2_30b_a3b")
+    shape = {
+        name: [int(x) for x in dims.split(",") if x]
+        for name, dims in re.findall(r"(%[\w.\-]+) = \(?\w+\[([\d,]*)\]", text)
+    }
+    gathers = re.findall(
+        r"= (\w+)\[([\d,]*)\][^\n]*? gather\((%[\w.\-]+), ", text)
+    assert gathers  # the embedding's rows, the experts' order
+    for dtype, dims, operand in gathers:
+        # an arena (or a flat view of one) holds 12 layers x 2305 blocks x 4
+        # heads x 32 tokens of 128: 453 M elements; the largest operand of a
+        # gather here is the embedding table's 78 M
+        assert int(np.prod(shape.get(operand, [0]) or [1])) < 100 << 20, (
+            dtype, dims, operand)
+    kernels = re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*?op_name="([^"]+)"', text)
+    decode = [k for k in kernels if k.endswith("paged_decode/pallas_call")]
+    assert len(decode) == 1 and "/cond/" not in decode[0], kernels
+    scores = [k for k in kernels if k.endswith("index_scores/pallas_call")]
+    assert len(scores) == 1 and "/cond/branch_1_fun/" in scores[0], kernels
 
 
 def test_a_windowed_models_step_programs_compile_and_read_weights_as_stored(
