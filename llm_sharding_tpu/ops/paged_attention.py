@@ -114,6 +114,7 @@ serve programs on the CPU mesh every PR.
 from __future__ import annotations
 
 import functools
+import math
 import os
 from typing import NamedTuple
 
@@ -1939,8 +1940,17 @@ def paged_attention_write(
 # ``cfg.index_cache_dim``), in the same blocks under the same table as K and V.
 # A query scores every live index key of its row (``index_scores``), keeps the
 # ``topk`` best (``select_mask``: a mask over the row's columns; all of them
-# while the context is no longer than that; ``select_tokens`` is the same choice
-# as a list) and attends those tokens ONLY. A decode step hands the mask to the
+# while the context is no longer than that) and attends those tokens ONLY.
+# Nothing reads the choice in order, so nothing sorts: the mask is "at or above
+# the ``topk``-th largest score", that score found by a search over the scores'
+# bits and a tie at it resolved, lower column first, by the same search over the
+# column's (PR 51; ``lax.top_k`` over a slot's ``[4, 9216]`` scores was the
+# largest device operation of a step). ``select_tokens`` is the same choice as
+# a sorted LIST (``lax.top_k``, stable): no path of the program runs it — it is
+# the oracle the tests hold the mask to, and with ``select_mask`` one of the two
+# names the benchmark's controls replace (``benchmark/tests/
+# calibrate_keye_vl2.py``, ``test_keye_vl2_block.py``), so every selecting path
+# goes through ``select_mask(scores, topk)``. A decode step hands the mask to the
 # attention as KEY POSITIONS — a column the query did not choose sits at the
 # sentinel (``selected_attention``) — and ``paged_attention`` runs as it is: it
 # streams the row's live blocks once, where they lie, and its own position test
@@ -2142,22 +2152,85 @@ def select_tokens(scores: jnp.ndarray, topk: int):
     """The ``topk`` best-scored columns of each query, ``[..., K]`` (``K =
     min(topk, W)``) and whether each is a real choice (a query with fewer
     attendable keys than ``K`` fills up with masked ones). A tie goes to the
-    lower column (``lax.top_k`` is stable), which is the lower position."""
+    lower column (``lax.top_k`` is stable), which is the lower position. The
+    program attends through ``select_mask``; this is the oracle its tests hold
+    that to, and one of the two names the benchmark's controls replace."""
     vals, cols = jax.lax.top_k(scores, min(topk, scores.shape[-1]))
     return cols.astype(jnp.int32), vals > -jnp.inf
+
+
+def _largest_digits(holds, nbits: int, bits: int, batch) -> jnp.ndarray:
+    """The largest ``nbits``-bit number ``v`` of each query, ``[..., 1]``
+    int32, for which ``holds(v)`` is true, where ``holds`` takes candidates
+    ``[..., D]``, is true at 0 and false from some number on: built from the
+    top bit down, ``bits`` a pass — a pass tries every value of its digit at
+    once and keeps the largest that holds (as many as hold: they are the
+    lowest ones)."""
+    v = jnp.zeros((*batch, 1), jnp.int32)
+    for shift in range(nbits - bits, -bits, -bits):
+        shift, width = max(shift, 0), min(bits, shift + bits)
+        digits = jnp.arange(1, 1 << width, dtype=jnp.int32) << shift
+        digit = jnp.sum(holds(v | digits), axis=-1, keepdims=True,
+                        dtype=jnp.int32)
+        v = v | (digit << shift)
+    return v
 
 
 @jax.named_scope("select")
 def select_mask(scores: jnp.ndarray, topk: int) -> jnp.ndarray:
     """``select_tokens`` as a mask over the window ``[..., W]``: above the
     ``topk``-th largest score, and of the columns that tie with it the lowest
-    ones, as many as are left to keep."""
-    K = min(topk, scores.shape[-1])
-    kth = jax.lax.top_k(scores, K)[0][..., -1:]
-    above = scores > kth
-    tie = (scores == kth) & (scores > -jnp.inf)
-    left = K - jnp.sum(above, axis=-1, keepdims=True)
-    return above | (tie & (jnp.cumsum(tie, axis=-1) <= left))
+    ones, as many as are left to keep — the very set ``select_tokens`` lists,
+    found by a SEARCH, not a sort: nothing reads the chosen columns in order
+    (the sort of a slot's ``[4, 9216]`` scores was 70 us a layer call, the
+    largest device operation of a Keye step; the search is 12-13: PERF.md,
+    PR 51). The ``topk``-th VALUE is the largest number that at least
+    ``topk`` scores reach, built digit by digit over the scores' bits taken
+    as integers in float order (``_largest_digits``: a pass counts the scores
+    at or above every candidate of a digit in one read); where more columns
+    tie with it than are left to keep, the last COLUMN kept is found the same
+    way over the column's bits. Exact: float32 scores, IEEE ``==`` ties (the
+    two zeros tie) and the lower column first, as ``lax.top_k``'s stable
+    order has it."""
+    W = scores.shape[-1]
+    K = min(topk, W)
+    batch = scores.shape[:-1]
+    # a pass costs its latency plus its compares: few queries (a decode
+    # step's slot) take 3 bits a pass, many (a chunk's 256) 2 — timed on the
+    # chip at [4, 9216] and [256, 9216] (PERF.md, PR 51)
+    bits = 3 if math.prod(batch) <= 16 else 2
+    lowest = jnp.int32(-(2 ** 31))
+    # integer order = float order: a negative float's magnitude bits m map
+    # to -m, so -0.0 lands on +0.0's key and -inf (an unattendable column, a
+    # dead row) is the smallest
+    raw = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    key = jnp.where(raw < 0, lowest - raw, raw)
+
+    def reached_by_k(v):  # v counts up from the smallest key: an offset
+        at_or_above = key[..., None, :] >= (v ^ lowest)[..., :, None]
+        return jnp.sum(at_or_above, axis=-1, dtype=jnp.int32) >= K
+
+    kth = _largest_digits(reached_by_k, 32, bits, batch) ^ lowest
+    above = key > kth
+    # never -inf: fewer attendable keys than K keeps every real one only
+    tie = (key == kth) & (scores > -jnp.inf)
+    left = K - jnp.sum(above, axis=-1, keepdims=True, dtype=jnp.int32)
+
+    def lowest_ties(_):
+        col = jax.lax.broadcasted_iota(
+            jnp.int32, scores.shape, scores.ndim - 1)
+
+        def fewer_before(c):  # fewer than ``left`` ties lie below column c
+            before = tie[..., None, :] & (col[..., None, :] < c[..., :, None])
+            return jnp.sum(before, axis=-1, dtype=jnp.int32) < left
+
+        last = _largest_digits(
+            fewer_before, max(W - 1, 1).bit_length(), bits, batch)
+        return above | (tie & (col <= last))
+
+    # the usual case: one tie, the topk-th score itself
+    many = jnp.any(jnp.sum(tie, axis=-1, keepdims=True, dtype=jnp.int32) > left)
+    return jax.lax.cond(many, lowest_ties, lambda _: above | tie, None)
 
 
 def selected_attention(

@@ -367,30 +367,105 @@ def test_a_selecting_decode_step_attends_the_list_select_tokens_gives(
                 got[b, 0, h], np.asarray(p) @ v[b, cols, h // 2], atol=TOL)
 
 
-@pytest.mark.parametrize("shape", [(3, 96), (2, 5, 96)])
-@pytest.mark.parametrize("ties", ["at the topk-th place", "none",
-                                  "fewer keys than topk"])
-def test_the_mask_is_the_set_of_the_list(shape, ties):
-    """``select_mask`` keeps exactly the columns ``select_tokens`` lists
-    (those that are real choices), with and without a leading query dim:
-    random scores, a third of them forced to tie at 0 (a sum of ``relu``s),
-    and queries with fewer attendable keys than ``topk``."""
+def sorted_mask(scores, topk):
+    """``select_mask`` as it was before PR 51, from ``select_tokens``' sorted
+    list: above the list's last score, and of the columns that tie with it
+    (IEEE ``==``) the lowest, by a running count."""
+    cols, _ = pa.select_tokens(scores, topk)
+    kth = jnp.take_along_axis(scores, cols[..., -1:], axis=-1)
+    above = scores > kth
+    tie = (scores == kth) & (scores > -jnp.inf)
+    left = cols.shape[-1] - jnp.sum(above, axis=-1, keepdims=True)
+    return above | (tie & (jnp.cumsum(tie, axis=-1) <= left))
+
+
+def _random_scores(shape, ties):
+    """Random scores, a third of them forced to tie at 0 (a sum of ``relu``s)
+    or queries with fewer attendable keys than ``topk``."""
     rng = np.random.default_rng(len(ties) + len(shape))
     scores = rng.standard_normal(shape).astype(np.float32)
     if ties == "at the topk-th place":
         scores = np.maximum(scores, 0.0)  # about half the columns tie at 0
         scores[..., :7] = 3.0  # ... and seven tie above them
-    live = 12 if ties == "fewer keys than topk" else 80
-    scores[..., live:] = -np.inf
-    topk = 60 if ties == "at the topk-th place" else 24
+    scores[..., 12 if ties == "fewer keys than topk" else 80:] = -np.inf
+    return scores, 60 if ties == "at the topk-th place" else 24
+
+
+def _signed_zeros():
+    # a sum of relus under a NEGATIVE index weight: -0.0 and +0.0 mixed, the
+    # largest scores of the row, more of them than topk
+    rng = np.random.default_rng(51)
+    scores = -np.abs(rng.standard_normal((3, 96))).astype(np.float32)
+    zero = rng.random((3, 96)) < 0.5
+    scores[zero] = np.where(rng.random(zero.sum()) < 0.5, 0.0, -0.0)
+    scores[..., 90:] = -np.inf
+    return scores, 24
+
+
+def _negative_at_the_threshold():
+    rng = np.random.default_rng(52)
+    scores = rng.standard_normal((2, 5, 96)).astype(np.float32)
+    scores[..., rng.random(96) < 0.4] = -0.75  # the topk-th score, tied
+    return scores, 70
+
+
+def _a_dead_row():
+    scores = np.random.default_rng(53).standard_normal((4, 96))
+    scores = np.round(scores.astype(np.float32), 1)  # ties everywhere
+    scores[[0, 2, 3]] = -np.inf
+    return scores, 24
+
+
+def _the_cells_slot():
+    # keye_vl2_30b_a3b.longgen: a slot of four rows over a 9,216-column
+    # window, one of them live at a context of 2,900, a third of its columns
+    # 0: under 2,048 are positive, so the zeros tie across the topk-th place
+    rng = np.random.default_rng(54)
+    scores = np.full((4, 9216), -np.inf, np.float32)
+    scores[1, :2900] = np.abs(rng.standard_normal(2900))
+    scores[1, :2900][rng.random(2900) < 1 / 3] = 0.0
+    return scores, 2048
+
+
+MASK_CASES = {
+    **{f"ties {ties}, {len(shape)} dims": (
+        lambda s=shape, t=ties: _random_scores(s, t))
+       for shape in [(3, 96), (2, 5, 96)]
+       for ties in ["at the topk-th place", "none", "fewer keys than topk"]},
+    "-0.0 and +0.0 tied across the topk-th place": _signed_zeros,
+    "negative scores at the threshold": _negative_at_the_threshold,
+    "all scores equal": lambda: (np.full((3, 96), 0.5, np.float32), 24),
+    "a dead row beside a live one": _a_dead_row,
+    "topk >= W": lambda: _random_scores((3, 96), "none")[:1] + (96,),
+    "topk past W": lambda: _random_scores((3, 96), "none")[:1] + (200,),
+    "topk 1": lambda: (np.round(np.random.default_rng(55).standard_normal(
+        (3, 96)).astype(np.float32), 1), 1),
+    # the ``recent`` control's input: the column index as float32
+    "distinct increasing scores": lambda: (
+        np.tile(np.arange(96, dtype=np.float32), (2, 5, 1)), 24),
+    "the cell's slot": _the_cells_slot,
+}
+
+
+@pytest.mark.parametrize("case", list(MASK_CASES))
+def test_the_mask_is_the_set_of_the_list(case):
+    """``select_mask`` keeps exactly the columns ``select_tokens`` lists
+    (those that are real choices), with and without a leading query dim: the
+    search (PR 51) against the sort. Scores tie by IEEE ``==`` — the oracle
+    reads the two zeros as one — and the set is the one the sort-based mask
+    kept, bit for bit."""
+    scores, topk = MASK_CASES[case]()
     keep = np.asarray(pa.select_mask(jnp.asarray(scores), topk))
     cols, real = (np.asarray(a) for a in pa.select_tokens(
-        jnp.asarray(scores), topk))
-    want = np.zeros(shape, bool)
+        jnp.asarray(np.where(scores == 0, np.float32(0), scores)), topk))
+    want = np.zeros(scores.shape, bool)
     np.put_along_axis(want, np.where(real, cols, cols[..., :1]), True, axis=-1)
+    want &= scores > -np.inf  # a dead row's first column is no choice
     assert keep.sum(axis=-1).tolist() == real.sum(axis=-1).tolist()
     assert (keep == want).all()
-    assert (keep.sum(axis=-1) == min(topk, live)).all()
+    live = (scores > -np.inf).sum(axis=-1)
+    assert (keep.sum(axis=-1) == np.minimum(topk, live)).all()
+    assert (keep == np.asarray(sorted_mask(jnp.asarray(scores), topk))).all()
 
 
 WRONG = {

@@ -272,3 +272,38 @@ def test_the_counters_the_gauges_and_the_metrics_rows(params):
                    'server_kv_kind_entry_bytes{kind="index"}',
                    'server_kv_kind_blocks_in_use{kind="index"}'):
         assert family in text
+
+
+@pytest.mark.parametrize("scores", ["as they are", "rounded until they tie"])
+def test_the_search_serves_the_ids_the_sort_served(params, monkeypatch, scores):
+    """``select_mask`` as it is (PR 51: the ``topk``-th score by a search)
+    and the sort-based body it had (``test_keye_vl2.sorted_mask``, built from
+    ``select_tokens``) serve the same ids, id for id: two rows in one slot,
+    one crossing ``topk`` inside its prompt's chunks and one in its reply —
+    on the indexer's scores, and on the same scores rounded to whole numbers,
+    where many columns tie across the ``topk``-th place and the lower column
+    has to win on both sides."""
+    from llm_sharding_tpu.ops import paged_attention as pa
+    from test_keye_vl2 import sorted_mask
+
+    def coarse(s):
+        return s if scores == "as they are" else jnp.round(s)
+
+    rng = np.random.default_rng(51)
+    prompts = [rng.integers(0, 250, size=n).astype(np.int32) for n in (5, 37)]
+    served = {}
+    for name, mask in (("search", pa.select_mask), ("sort", sorted_mask)):
+        monkeypatch.setattr(
+            pa, "select_mask", lambda s, topk, _m=mask: _m(coarse(s), topk))
+        jax.clear_caches()  # the step programs trace the selection they find
+        try:
+            srv = engine(params).serve(paged_attn="xla", **PAGED)
+            reqs = [srv.submit(p, 40) for p in prompts]
+            srv.run_until_idle()
+            srv.close()
+        finally:
+            monkeypatch.undo()
+            jax.clear_caches()
+        served[name] = [[int(t) for t in r.tokens] for r in reqs]
+    assert [len(t) for t in served["search"]] == [40, 40]
+    assert served["search"] == served["sort"]

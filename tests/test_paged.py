@@ -1762,6 +1762,85 @@ def test_a_selecting_decode_step_reads_k_and_v_through_a_kernel_only(
     assert len(scores) == 1 and "/cond/branch_1_fun/" in scores[0], kernels
 
 
+def _called_from(text, root):
+    """The instructions of computation ``root`` and of every computation it
+    calls (fusions, reductions, branches, loops)."""
+    comps = {
+        m.group(1): m.group(2).split("\n") for m in re.finditer(
+            r"\n(?:ENTRY )?(%[\w.\-]+) [^\n]*\{\n(.*?)\n\}", text, re.S)
+    }
+    seen, todo = set(), [root]
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            todo += re.findall(
+                r"(?:calls|to_apply|body|condition)=(%[\w.\-]+)", line)
+            for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
+                todo += [ref.strip() for ref in group.split(",")]
+    return [line for name in seen for line in comps[name]]
+
+
+def _elements(line):
+    dims = re.search(r"= \(?\w+\[([\d,]*)\]", line)
+    return int(np.prod([int(d) for d in dims.group(1).split(",") if d] or [1]))
+
+
+def test_a_selecting_decode_step_finds_its_topk_th_score_without_a_sort(
+        compiled_serve_chunk):
+    """``keye_vl2_30b_a3b``'s compiled ``serve_chunk`` (PR 51): nothing of
+    the selecting branch of the layer body sorts or scans — ``select_mask``
+    finds the ``topk``-th score by a search (the parent's ``lax.top_k`` over
+    the slot's ``[4, 9216]`` scores was 70 us a layer call on the chip, the
+    largest device operation of a step, and its tie rule's ``cumsum`` 6.5 us
+    more OUTSIDE every scope: the compiler's ``reduce-window`` rewrite drops
+    the metadata) — and all of the branch lies under ``indexer`` or
+    ``select``, the scopes ``decode_index_pct`` and ``index_hbm_pct`` divide
+    by: by name where an instruction has one, and no instruction without one
+    makes an array as wide as the window."""
+    text = compiled_serve_chunk("keye_vl2_30b_a3b")
+    lines = text.split("\n")
+    # what sorts is the router's top-k and the experts' order
+    sorts = [ln for ln in lines if " sort(" in ln or "TopK" in ln]
+    assert sorts and all(
+        re.search(r'op_name="[^"]*/(router|moe)/', ln) for ln in sorts), sorts
+    # what scans is the kernels' walk over the slot's ROWS (``_end_to_end``)
+    scans = [ln for ln in lines if " reduce-window(" in ln]
+    assert all(_elements(ln) <= 4 for ln in scans), scans
+    # the layer's cond: the score kernel lies in its branch 1
+    (branch,) = [
+        ref.split(",")[1].strip() for ln in lines
+        for ref in re.findall(r"branch_computations=\{([^}]*)\}", ln)
+        if "cond/branch_1_fun" not in ln
+    ]
+    chosen = _called_from(text, branch)
+    assert any("index_scores/pallas_call" in ln for ln in chosen)
+    named = [ln for ln in chosen if "/cond/branch_1_fun/" in ln]
+    searched = [ln for ln in named if "/select/" in ln]
+    # a pass of the search: candidates compared, the hits counted
+    assert sum(" reduce(" in ln for ln in searched) >= 10
+    assert sum(" compare(" in ln for ln in searched) >= 10
+    for ln in named:
+        assert re.search(r'op_name="[^"]*/(select|indexer)/', ln), ln
+    for ln in chosen:
+        if "op_name=" not in ln and _elements(ln) >= 9216:
+            assert re.search(
+                r" (parameter|get-tuple-element|bitcast|tuple|copy)\(", ln), ln
+
+
+@pytest.mark.parametrize("cell", ["qwen25_7b", "olmoe_1b_7b"])
+def test_a_model_without_an_indexer_traces_nothing_of_the_selection(
+        compiled_serve_chunk, cell):
+    """No operation of a configuration without ``sparse_attn`` lies under the
+    ``select`` or the ``indexer`` scope (by the scope, not by the word
+    ``sort``: a router's own ``top_k`` is not the selection's)."""
+    names = re.findall(r'op_name="([^"]*)"', compiled_serve_chunk(cell))
+    assert len(names) > 100
+    assert not [n for n in names if re.search(r"/(select|indexer)/", n)]
+
+
 def test_a_windowed_models_step_programs_compile_and_read_weights_as_stored(
         v5e_host):
     """``mimo_v25`` (a KV state per kind of attention layer, which
