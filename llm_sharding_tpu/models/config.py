@@ -40,6 +40,16 @@ class RopeScaling:
 #: publishes the string as ``hybrid_override_pattern``; ``jamba``'s is made
 #: from its period and offset)
 NEMOTRON_KINDS = {"M": "mamba", "E": "moe", "*": "attn"}
+#: ... and of ``solar_open2`` (``K`` a KDA mixer, ``G`` gated GQA; each with
+#: its expert MLP)
+SOLAR_KINDS = {"K": "kda", "G": "gqa"}
+
+#: the kinds of layer (``ModelConfig.layer_kinds``) that keep a recurrent
+#: state of fixed size a request, and those that keep entries in the paged
+#: arena, of the models whose arena holds SOME layers only (a model with a
+#: recurrent state: ``parallel/serve.init_state`` sizes both from these)
+RECURRENT_KINDS = ("mamba", "kda")
+ARENA_KINDS = ("attn", "gqa")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -165,6 +175,19 @@ class ModelConfig:
     # channel AND state value, no heads).
     mamba_d_inner: int = 0
     ssm_dt_rank: int = 0
+    # ``solar_open2`` (models/solar_open2.py): every layer is TWO sub-blocks,
+    # a mixer — ``K`` a KDA linear-attention mixer (``ops/kda.py``), ``G``
+    # softmax GQA WITHOUT positions and with a sigmoid gate a channel on its
+    # output (``attn_gate``), by ``layer_pattern`` — and ``deepseek_v3``'s
+    # routed experts beside the shared one. A KDA mixer has ``kda_num_heads``
+    # heads of ``kda_head_dim`` keys x ``kda_head_dim`` values, a conv of
+    # ``conv_kernel`` taps over ``[q | k | v]``, a decay per key channel
+    # through a low-rank pair of width ``kda_head_dim`` and a write strength
+    # ``β = kda_beta_scale · sigmoid(·)`` (2 under ``kda_allow_neg_eigval``).
+    kda_num_heads: int = 0
+    kda_head_dim: int = 0
+    kda_beta_scale: float = 1.0
+    attn_gate: bool = False
     # GPT-2 specifics
     layer_norm_epsilon: float = 1e-5
     # Token ids. ``eos_token_ids`` holds ALL stop ids (Llama-3.x instruct
@@ -223,9 +246,9 @@ class ModelConfig:
     @property
     def recurrent(self) -> bool:
         """Some layers keep a recurrent state of FIXED size a request (a
-        Mamba mixer's, of either family): indexed by row beside the paged
-        arenas, not paged by token."""
-        return "M" in self.layer_pattern
+        Mamba mixer's, of either family; a KDA mixer's matrices): indexed by
+        row beside the paged arenas, not paged by token."""
+        return "M" in self.layer_pattern or "K" in self.layer_pattern
 
     @property
     def ssm_inner(self) -> int:
@@ -234,7 +257,9 @@ class ModelConfig:
     @property
     def conv_dim(self) -> int:
         """Channels the mixer's conv runs over: Mamba-2's ``[x | B | C]``,
-        Mamba-1's ``x`` alone."""
+        Mamba-1's ``x`` alone, KDA's ``[q | k | v]``."""
+        if self.kda_num_heads:
+            return 3 * self.kda_num_heads * self.kda_head_dim
         if self.ssm_dt_rank:
             return self.ssm_inner
         return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state_size
@@ -248,16 +273,22 @@ class ModelConfig:
         ``ssm``: Mamba-2's ``[heads, head_dim, state]``; Mamba-1's ``[state,
         8, d_inner / 8]`` — channel ``c`` at ``(c // (d_inner / 8), c %
         (d_inner / 8))``, so that a state value's channels fill whole (8,
-        128) tiles where ``ops/ssm.scan_rows_tpu`` advances them. ``conv``:
-        the conv's last ``conv_kernel - 1`` inputs."""
+        128) tiles where ``ops/ssm.scan_rows_tpu`` advances them. ``kda`` (in
+        ``ssm``'s place): a KDA mixer's ``[heads, d_k, d_v]``, a MATRIX a head
+        (``ops/kda.py``). ``conv``: the conv's last ``conv_kernel - 1``
+        inputs."""
         if not self.recurrent:
             return {}
+        conv = (self.conv_kernel - 1, self.conv_dim)
+        if self.kda_num_heads:
+            nh, hd = self.kda_num_heads, self.kda_head_dim
+            return {"kda": (nh, hd, hd), "conv": conv}
         if self.ssm_dt_rank:
             ssm = (self.ssm_state_size, 8, self.ssm_inner // 8)
         else:
             ssm = (self.mamba_num_heads, self.mamba_head_dim,
                    self.ssm_state_size)
-        return {"ssm": ssm, "conv": (self.conv_kernel - 1, self.conv_dim)}
+        return {"ssm": ssm, "conv": conv}
 
     @property
     def recurrent_row_bytes(self) -> int:
@@ -326,6 +357,8 @@ class ModelConfig:
             )
         if self.model_type in ("nemotron_h", "jamba"):
             return tuple(NEMOTRON_KINDS[c] for c in self.layer_pattern)
+        if self.model_type == "solar_open2":
+            return tuple(SOLAR_KINDS[c] for c in self.layer_pattern)
         if self.model_type != "deepseek_v3":
             return ()
         k = self.first_k_dense_replace
@@ -425,6 +458,8 @@ class ModelConfig:
             return cls._from_nemotron_h(hf)
         if mt == "jamba":
             return cls._from_jamba(hf)
+        if mt == "solar_open2":
+            return cls._from_solar_open2(hf)
         if mt in ("llama",):
             act = hf.get("hidden_act", "silu")
             if act not in ("silu", "gelu_tanh"):
@@ -996,6 +1031,136 @@ class ModelConfig:
         )
 
 
+    @classmethod
+    def _from_solar_open2(cls, hf: dict[str, Any]) -> "ModelConfig":
+        """``solar_open2`` as Solar-Open2-250B publishes it. Layer ``l`` is
+        softmax GQA where ``l`` is in ``gqa_layers`` — NO positions (``use_rope``
+        false) and, under ``use_gqa_gate``, a sigmoid gate a channel on its
+        output — and a KDA linear-attention mixer otherwise
+        (``linear_attn_config``: ``num_heads`` heads of ``head_dim`` x
+        ``head_dim``, a conv of ``short_conv_kernel_size``; low-rank gate
+        projections of width ``head_dim``; ``kda_allow_neg_eigval``: the write
+        strength ``β`` in (0, 2)). Every layer's MLP is the routed experts —
+        sigmoid scores with a correction bias (``noaux_tc``), ONE group —
+        beside ``n_shared_experts`` shared ones of the same width. Beside the
+        published keys, a chip's share of the experts exactly as
+        ``_from_deepseek_v3`` reads one: ``n_routed_experts`` is how many are
+        HELD, ``n_routed_experts_total`` (default: the same) how many the
+        router scores, ``ep_rank`` which run of them this is. Kept and NOT
+        read, each for its reason (``SOLAR_KEYS_NOT_READ``). What is not done is
+        refused by name."""
+        need = (
+            "gqa_layers", "linear_attn_config", "head_dim", "n_routed_experts",
+            "num_experts_per_tok", "moe_intermediate_size",
+        )
+        for key in need:
+            if hf.get(key) is None:
+                raise ValueError(f"solar_open2 config.json lacks {key!r}")
+        refuse = {
+            "kda_use_full_proj": (False,), "use_rope": (False,),
+            "first_k_dense_replace": (0,), "norm_topk_prob": (True,),
+            "tie_word_embeddings": (False,), "hidden_act": ("silu",),
+            "scoring_func": ("sigmoid",), "topk_method": ("noaux_tc",),
+            "n_group": (1,), "topk_group": (1,), "attention_bias": (False,),
+        }
+        why = {
+            "kda_use_full_proj": "the gate projections are the low-rank pairs "
+            "(W_a↓ / W_a↑, W_g↓ / W_g↑)",
+            "use_rope": "the attention layers carry no positions (NoPE)",
+            "first_k_dense_replace": "every layer's MLP is the routed "
+            "experts; a dense layer is not built",
+        }
+        for key, ok in refuse.items():
+            if key in hf and hf[key] not in ok:
+                raise ValueError(
+                    f"solar_open2 {key}={hf[key]!r} is not supported (only "
+                    f"{', '.join(map(repr, ok))})"
+                    + (f": {why[key]}" if key in why else "")
+                )
+        lin = hf["linear_attn_config"]
+        if lin.get("num_kv_heads") is not None:
+            raise ValueError(
+                "solar_open2 linear_attn_config.num_kv_heads "
+                f"{lin['num_kv_heads']!r} is not supported (only null: a KDA "
+                "layer has a key and a value head a query head)"
+            )
+        for key in ("num_heads", "head_dim"):
+            if not lin.get(key):
+                raise ValueError(
+                    f"solar_open2 linear_attn_config lacks {key!r}"
+                )
+        L = int(hf["num_hidden_layers"])
+        gqa = sorted(int(l) for l in hf["gqa_layers"])
+        if not gqa or gqa[0] < 0 or gqa[-1] >= L or len(set(gqa)) != len(gqa):
+            raise ValueError(
+                f"solar_open2 gqa_layers {hf['gqa_layers']!r}: distinct layer "
+                f"indices in 0..{L - 1}, at least one"
+            )
+        if len(gqa) == L:
+            raise ValueError(
+                f"solar_open2 gqa_layers names all {L} layers: a model with "
+                "no KDA layer is the llama block's"
+            )
+        held = int(hf["n_routed_experts"])
+        total = int(hf.get("n_routed_experts_total", held))
+        rank = int(hf.get("ep_rank", 0))
+        if total % held or not 0 <= rank < total // held:
+            raise ValueError(
+                f"solar_open2 share: {held} experts held of {total}, rank "
+                f"{rank}: the held count must divide the total and the rank "
+                f"lie in 0..{total // max(held, 1) - 1}"
+            )
+        eos = hf.get("eos_token_id", 2)
+        eos_ids = tuple(eos) if isinstance(eos, list) else (eos,)
+        return cls(
+            model_type="solar_open2",
+            vocab_size=hf["vocab_size"],
+            hidden_size=int(hf["hidden_size"]),
+            intermediate_size=int(hf.get("intermediate_size", 0)),
+            num_hidden_layers=L,
+            num_attention_heads=int(hf["num_attention_heads"]),
+            num_key_value_heads=int(
+                hf.get("num_key_value_heads", hf["num_attention_heads"])
+            ),
+            head_dim=int(hf["head_dim"]),
+            max_position_embeddings=hf.get("max_position_embeddings", 4096),
+            rms_norm_eps=float(hf.get("rms_norm_eps", 1e-5)),
+            rope_theta=float(hf.get("rope_theta", 10000.0)),
+            num_experts=total,
+            num_experts_per_tok=int(hf["num_experts_per_tok"]),
+            norm_topk_prob=True,
+            moe_intermediate_size=int(hf["moe_intermediate_size"]),
+            n_shared_experts=int(hf.get("n_shared_experts") or 0),
+            n_group=1,
+            topk_group=1,
+            routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+            experts_held=held,
+            ep_rank=rank,
+            layer_pattern="".join("G" if l in gqa else "K" for l in range(L)),
+            conv_kernel=int(lin.get("short_conv_kernel_size", 4)),
+            kda_num_heads=int(lin["num_heads"]),
+            kda_head_dim=int(lin["head_dim"]),
+            kda_beta_scale=2.0 if hf.get("kda_allow_neg_eigval") else 1.0,
+            attn_gate=bool(hf.get("use_gqa_gate", False)),
+            bos_token_id=(
+                1 if hf.get("bos_token_id") is None else hf["bos_token_id"]
+            ),
+            eos_token_id=eos_ids[0],
+            eos_token_ids=eos_ids,
+        )
+
+
+#: ``solar_open2`` keys a published config carries that ``_from_solar_open2``
+#: keeps and does NOT read, each with its reason
+SOLAR_KEYS_NOT_READ = {
+    "gqa_interval": "gqa_layers is the list of the attention layers",
+    "rope_theta": "use_rope is false: no layer rotates",
+    "partial_rotary_factor": "likewise",
+    "intermediate_size": "the dense MLP's width: first_k_dense_replace is 0, "
+    "no layer has one",
+}
+
+
 # Convenience presets (sizes mirror the models the reference targets:
 # Llama-2-7B / Llama-3.2-3B / GPT-2, /root/reference/README.md + model_sharder.py)
 def llama2_7b() -> ModelConfig:
@@ -1422,6 +1587,36 @@ def tiny_jamba(**kw) -> ModelConfig:
     channel and a step rank of 3, a gated MLP of 96 in every layer, a tied
     head."""
     return ModelConfig.from_hf_config(tiny_jamba_keys(**kw))
+
+
+def tiny_solar_open2_keys(**kw) -> dict:
+    """The published-style keys of ``tiny_solar_open2``."""
+    base = dict(
+        model_type="solar_open2",
+        vocab_size=256, hidden_size=64, intermediate_size=96,
+        moe_intermediate_size=32, num_hidden_layers=8,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        linear_attn_config=dict(
+            short_conv_kernel_size=4, head_dim=16, num_heads=4,
+            num_kv_heads=None,
+        ),
+        gqa_interval=3, gqa_layers=[0, 4], use_rope=False, use_gqa_gate=True,
+        kda_use_full_proj=False, kda_allow_neg_eigval=True,
+        first_k_dense_replace=0, n_routed_experts=8, n_shared_experts=1,
+        num_experts_per_tok=2, norm_topk_prob=True, routed_scaling_factor=1,
+        max_position_embeddings=256, rms_norm_eps=1e-5, rope_theta=10000,
+        partial_rotary_factor=1, tie_word_embeddings=False, eos_token_id=255,
+    )
+    base.update(kw)
+    return base
+
+
+def tiny_solar_open2(**kw) -> ModelConfig:
+    """Tiny solar_open2-layout config for CPU tests: two periods of 4
+    (``GKKKGKKK``: gated GQA without positions — 4 query / 2 key-value heads
+    of 16 —, then three KDA mixers of 4 heads of 16 x 16 state, a conv of 4),
+    every layer's MLP 8 routed experts, 2 a token, and one shared expert."""
+    return ModelConfig.from_hf_config(tiny_solar_open2_keys(**kw))
 
 
 def tiny_qwen2(**kw) -> ModelConfig:

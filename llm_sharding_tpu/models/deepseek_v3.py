@@ -288,13 +288,29 @@ def mla_block(
         o = absorb_o(o_lat, p["w_uv"])
     with jax.named_scope("o_proj"):
         h = h + qmatmul(o.reshape(B, S, -1), p["wo"])
+    h, stats = mlp_sub_block(cfg, p, h, moe_live, moe_backend)
+    return h, cache, stats
 
+
+def mlp_sub_block(
+    cfg: ModelConfig,
+    p: Params,
+    h: jnp.ndarray,  # [B, S, H]
+    moe_live: Optional[jnp.ndarray] = None,  # [B, S] positions that route
+    moe_backend: str = "auto",
+):
+    """A layer's second sub-block, ``h + MLP(RMSNorm_post(h))``: the dense
+    gated MLP or, keyed by the presence of ``router``, the routed experts held
+    here beside the shared expert (``models/solar_open2.py`` runs it after its
+    own mixers). Returns ``(h, stats)``, ``stats`` the layer's ``MoeStats``
+    (None for a dense layer)."""
+    B, S, H = h.shape
     with jax.named_scope("norm"):
-        x = rms_norm(h, p["post_norm"], eps)
+        x = rms_norm(h, p["post_norm"], cfg.rms_norm_eps)
     if "router" not in p:
         with jax.named_scope("mlp"):
             mlp = gated_mlp(x, p["w_gate"], p["w_up"], p["w_down"])
-            return h + mlp, cache, None
+            return h + mlp, None
     x2 = x.reshape(B * S, H)
     with jax.named_scope("router"):
         weights, ids = moe.route_noaux_tc(
@@ -309,7 +325,7 @@ def mla_block(
     )
     with jax.named_scope("mlp"):  # the shared expert: every token, in full
         shared = gated_mlp(x, p["ws_gate"], p["ws_up"], p["ws_down"])
-    return h + y.reshape(B, S, H) + shared, cache, stats
+    return h + y.reshape(B, S, H) + shared, stats
 
 
 def _zero_stats(cfg: ModelConfig, count: int) -> moe.MoeStats:

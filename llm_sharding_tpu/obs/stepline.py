@@ -132,6 +132,13 @@ SCOPES = (
                   # Mamba-2 has no counterpart of (models/jamba.py)
     "moe_latent", # a LatentMoE's two projections, into the experts' latent
                   # space and out of it
+    "kda_proj",   # a KDA mixer's projections: ``wq``, ``wk``, ``wv``, the
+                  # two low-rank pairs (decay, output gate), ``w_beta``, and
+                  # ``wo`` with its residual add (models/solar_open2.py; its
+                  # conv keeps the word ``conv``)
+    "kda",        # the decay's softplus and exp, the L2 norms of q and k, the
+                  # state update (a decode step) or the chunkwise WY form (a
+                  # prefill chunk), the read-out, the gated per-head norm
     "head",       # final norm + this stage's logit slice
     "sample",     # argmax assembly / per-row sampling over the logits
     "ring_hop",   # stage->stage ppermute and the last stage's broadcast

@@ -194,6 +194,12 @@ NEMOTRON_QUANT_KEYS = ("w_in", "w_out", "w_lat_down", "w_lat_up")
 # and ``C`` beside them (``w_x``, ``w_dt``); conv, ``A_log``, ``D``,
 # ``dt_bias`` and the norms stay
 JAMBA_QUANT_KEYS = ("w_x", "w_dt")
+# solar_open2 (models/solar_open2.py): a mixer's ``wq`` / ``wk`` / ``wv`` /
+# ``wo`` and the attention layers' ``w_gate`` are llama's names, its experts
+# deepseek_v3's; a KDA mixer's two low-rank pairs (decay, output gate) beside
+# them. ``w_beta`` (a column a head), the conv, ``A_log``, ``dt_bias`` and the
+# norm gains stay
+SOLAR_QUANT_KEYS = ("w_a_down", "w_a_up", "w_g_down", "w_g_up")
 
 
 def is_kinds_tree(layers: dict) -> bool:
@@ -215,6 +221,7 @@ def quantize_layer_params(
         keys = (
             LLAMA_QUANT_KEYS + GPT2_QUANT_KEYS + DEEPSEEK_QUANT_KEYS
             + MIMO_QUANT_KEYS + NEMOTRON_QUANT_KEYS + JAMBA_QUANT_KEYS
+            + SOLAR_QUANT_KEYS
         )
     if is_kinds_tree(layers):  # one stack per kind: each kind's leaves
         return {

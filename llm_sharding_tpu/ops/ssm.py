@@ -115,16 +115,19 @@ f32 = jnp.float32
 
 # ------------------------------------------------------------------- the conv
 
-def conv_step(tail, x, w, b):
+def conv_step(tail, x, w, b=None):
     """One position a row. ``tail [B, K-1, C]`` (the last inputs, oldest
     first), ``x [B, C]``, ``w [K, C]`` (tap ``k`` meets the input ``K-1-k``
-    positions back), ``b [C]`` → ``(silu(conv) [B, C], new tail)``."""
+    positions back), ``b [C]`` (None: a conv without a bias, KDA's) →
+    ``(silu(conv) [B, C], new tail)``."""
     win = jnp.concatenate([tail, x[:, None].astype(f32)], axis=1)  # [B, K, C]
-    y = jnp.sum(win * w.astype(f32)[None], axis=1) + b.astype(f32)
+    y = jnp.sum(win * w.astype(f32)[None], axis=1)
+    if b is not None:
+        y = y + b.astype(f32)
     return jax.nn.silu(y), win[:, 1:]
 
 
-def conv_chunk(tail, x, n_real, w, b):
+def conv_chunk(tail, x, n_real, w, b=None):
     """A chunk. ``x [B, S, C]``; the row's first ``n_real [B]`` positions are
     real. Returns ``(silu(conv) [B, S, C], new tail)``: the tail is the
     ``K - 1`` inputs that END at the row's last real position (the old tail's
@@ -133,7 +136,7 @@ def conv_chunk(tail, x, n_real, w, b):
     S = x.shape[1]
     xin = jnp.concatenate([tail, x.astype(f32)], axis=1)  # [B, S + K - 1, C]
     wf = w.astype(f32)
-    y = b.astype(f32)
+    y = 0.0 if b is None else b.astype(f32)
     for k in range(K):
         y = y + xin[:, k:k + S] * wf[k]
     at = n_real[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None]  # [B, K-1]
@@ -209,7 +212,14 @@ def rows_backend(backend: str, cfg) -> str:
     """The path the state update of ``cfg``'s mixers takes for ``backend``
     (``ssm_step_rows``; a Mamba-1 chunk's ``scan_rows`` too): ``kernel``,
     ``interpret`` or ``xla`` — the one question a server asks, whatever the
-    family, from ``cfg.recurrent_shapes``."""
+    family, from ``cfg.recurrent_shapes`` (a KDA mixer's matrix state:
+    ``ops/kda.py``'s kernel)."""
+    if "kda" in cfg.recurrent_shapes:
+        from . import kda
+
+        return _resolve(
+            backend, kda.kernel_eligible(*cfg.recurrent_shapes["kda"])
+        )
     shape = cfg.recurrent_shapes["ssm"]
     if cfg.ssm_dt_rank:
         return _resolve(backend, scan_eligible(*shape))
@@ -219,8 +229,9 @@ def rows_backend(backend: str, cfg) -> str:
 
 def scan_path(backend: str, cfg) -> str:
     """The path a prefill chunk's scan of ``cfg``'s mixers takes: ``block``
-    (Mamba-2's block form, ``ssm_chunk``) or, Mamba-1, ``rows_backend``'s
-    answer (``scan_rows``)."""
+    (matrix products inside blocks of positions: Mamba-2's block form,
+    ``ssm_chunk``; KDA's chunkwise WY form, ``ops/kda.kda_chunk``) or,
+    Mamba-1, ``rows_backend``'s answer (``scan_rows``)."""
     return rows_backend(backend, cfg) if cfg.ssm_dt_rank else "block"
 
 

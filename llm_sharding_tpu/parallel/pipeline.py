@@ -134,6 +134,14 @@ def model_fns(
         jamba._refuse_tp(tp_axis, cp_axis)
         fwd, fwd_paged = jamba.forward_layers, jamba.forward_layers_paged
         walks = jamba.prefill_walks
+    elif cfg.model_type == "solar_open2":
+        from ..models import solar_open2
+
+        solar_open2._refuse_tp(tp_axis, cp_axis)
+        fwd, fwd_paged = (
+            solar_open2.forward_layers, solar_open2.forward_layers_paged
+        )
+        walks = solar_open2.prefill_walks
     else:
         raise ValueError(f"unsupported model_type: {cfg.model_type!r}")
 

@@ -41,7 +41,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.cache import KVCache, POS_SENTINEL
-from ..models.config import ModelConfig
+from ..models.config import ARENA_KINDS, RECURRENT_KINDS, ModelConfig
 from ..obs.metrics import REGISTRY
 from ..ops.paged_attention import prefill_walk, window_from_blocks
 from ..ops.quant import is_kv_quantized, kv_dequantize, kv_qmax, kv_quantize
@@ -121,13 +121,14 @@ class ServeState(NamedTuple):
     v_swa: Any = None
     tables_swa: Any = None
     # A model with recurrent layers (``cfg.recurrent``, paged only;
-    # ``models/nemotron_h.py``, ``models/jamba.py``) keeps, beside the arena,
-    # a recurrent state of FIXED size a request, indexed by ROW and not paged
-    # by token: a small tree by name — ``{name: [S, L_mamba, M,
-    # *cfg.recurrent_shapes[name]] f32}`` [dev], ``ssm`` the mixers' state and
-    # ``conv`` the conv's last inputs, shaped by the configuration alone.
-    # ``k`` / ``v`` above are then the ATTENTION layers' arena alone (``[S,
-    # L_attn, NB, ...]``). None (an empty pytree: no operand of any program)
+    # ``models/nemotron_h.py``, ``models/jamba.py``, ``models/solar_open2.py``)
+    # keeps, beside the arena, a recurrent state of FIXED size a request,
+    # indexed by ROW and not paged by token: a small tree by name — ``{name:
+    # [S, L_mixer, M, *cfg.recurrent_shapes[name]] f32}`` [dev], ``ssm`` (a KDA
+    # mixer: ``kda``) the mixers' state and ``conv`` the conv's last inputs,
+    # shaped by the configuration alone. ``k`` / ``v`` above are then the
+    # ATTENTION layers' arena alone (``[S, L_attn, NB, ...]``). None (an
+    # empty pytree: no operand of any program)
     # for every other model.
     recurrent: Any = None
     # A token-selecting model (``cfg.sparse_attn``, paged only): ONE index key
@@ -383,9 +384,10 @@ def make_state(
     Bs = batch_per_slot
     M = S * Bs
     Lp = layers_per_stage
-    recurrent_layers = (
-        cfg.layer_kinds[:Lp].count("mamba") if cfg.recurrent else 0
-    )
+    # a stage's first Lp layers (every stage holds the same kinds)
+    recurrent_layers = sum(
+        k in RECURRENT_KINDS for k in cfg.layer_kinds[:Lp]
+    ) if cfg.recurrent else 0
     paged = kv_block_size > 0
     if paged:
         # logical window: capacity rounded up to whole blocks. out/kpos are
@@ -435,8 +437,7 @@ def make_state(
 
         arena_layers = Lp - swa_layers
         if recurrent_layers:
-            # a stage's first Lp layers (every stage holds the same kinds)
-            arena_layers = cfg.layer_kinds[:Lp].count("attn")
+            arena_layers = sum(k in ARENA_KINDS for k in cfg.layer_kinds[:Lp])
         kv_shape = (
             S, *paged_arena_shape(
                 cfg, cp * kv_blocks, kv_block_size, arena_layers,

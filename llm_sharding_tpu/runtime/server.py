@@ -1434,7 +1434,8 @@ class PipelineServer:
             # values, or a latent cache's one padded entry
             if self.recurrent:
                 RECURRENT_ROW_BYTES.set(float(
-                    int(self.state.recurrent["ssm"].shape[1])
+                    # (the stage's mixer layers: any leaf of the tree)
+                    int(next(iter(self.state.recurrent.values())).shape[1])
                     * self.cfg.recurrent_row_bytes
                 ))
             item = np.dtype(self.kv_store_dtype).itemsize

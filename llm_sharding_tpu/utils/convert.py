@@ -44,6 +44,14 @@ def _refuse_unmapped(cfg: ModelConfig) -> None:
             "repository — the converter maps it once they are; the block runs "
             "on seeded weights (benchmark/blocks/KeyeVL2.py)"
         )
+    if cfg.model_type == "solar_open2":
+        raise NotImplementedError(
+            "model_type 'solar_open2': the names of a Solar-Open2 "
+            "checkpoint's tensors (a KDA mixer's projections, low-rank pairs, "
+            "conv and norm leaves; the attention layers' gate) are in no file "
+            "of this repository — the converter maps it once they are; the "
+            "block runs on seeded weights (benchmark/blocks/solar_open2.py)"
+        )
 
 
 def llama_layer_arrays(

@@ -47,6 +47,7 @@ from .convert import (
     KIND_LAYER_ARRAYS,
     TensorGetter,
     _getter,
+    _refuse_unmapped,
     head_names,
     gpt2_layer_arrays,
     llama_layer_arrays,
@@ -294,6 +295,8 @@ def save_shards_streaming(
     ``ops/quant.quantize_params``).
     """
     from ..ops.quant import quantize_layer_params, quantize_tensor
+
+    _refuse_unmapped(cfg)  # before anything is written
 
     def maybe_q_embed(t):  # [V, H]: scale per vocab row
         if not quantize_head:

@@ -2154,9 +2154,12 @@ _NO_ARENA_COPY = {"kv_take", "kv_layout", "kv_put"}
 _NO_HEAD = {"head", "sample"}
 #: ``absorb`` is latent attention's (``models/deepseek_v3.py``): neither model here has it.
 # (a Mamba mixer's and a LatentMoE's words are ``nemotron_h``'s and
-# ``jamba``'s alone: ``tests/test_nemotron_h_serve.py`` and
-# ``tests/test_jamba_serve.py`` hold their programs to them)
-_RECURRENT_WORDS = {"ssm_proj", "conv", "ssm", "ssm_x", "moe_latent"}
+# ``jamba``'s alone, a KDA mixer's ``solar_open2``'s:
+# ``tests/test_nemotron_h_serve.py``, ``tests/test_jamba_serve.py`` and
+# ``tests/test_solar_open2_serve.py`` hold their programs to them)
+_RECURRENT_WORDS = {
+    "ssm_proj", "conv", "ssm", "ssm_x", "moe_latent", "kda_proj", "kda",
+}
 # (``indexer`` / ``select`` are a token-selecting model's alone:
 # ``tests/test_keye_vl2_serve.py`` holds its programs to them)
 _SELECT_WORDS = {"indexer", "select"}
