@@ -49,8 +49,9 @@ the row-wise write outside block 0. The last line, also left in
 ``chiprun_out/kv_write.json``: ``{"ok": true, "kv_write": [...], "device":
 {...}}``.
 ``--kv-decode`` likewise: a DECODE step's K/V write with the attention it
-feeds at OLMoE's, the 7B's and MiMo's window shapes, one and four live rows
-of four — the one op a layer calls (``paged_attention_write``) against the
+feeds at OLMoE's, the 7B's, MiMo's window and Keye's shapes (the last at
+three contexts: the walk's nanoseconds a token), one and four live rows of
+four — the one op a layer calls (``paged_attention_write``) against the
 pair it replaced (``write_block_kv`` then ``paged_attention``): the arenas
 bit for bit, and the DEVICE time of one layer call of each from a profiler
 trace, with the operations it is made of. The last line, also left in
@@ -810,18 +811,23 @@ def child_kv_write(spec: dict, out_path: str) -> None:
         )
 
 
-#: A DECODE step's K/V write with the attention it feeds, at three of the
+#: A DECODE step's K/V write with the attention it feeds, at four of the
 #: cells' shapes: ``group`` query heads a key/value head, ``table`` entries a
-#: row (capacity / 32), each live row ``context`` tokens long; MiMo's window
-#: layers keep the blocks their window reaches and a sink logit.
+#: row (capacity / 32), each live row ``context`` tokens long, once a context
+#: of ``contexts``; MiMo's window layers keep the blocks their window reaches
+#: and a sink logit; Keye's rows grow to 8.7 k tokens inside one reply, so
+#: its three contexts read the walk's slope (PERF.md, PR 54).
 KV_DECODE_SHAPES = (
     {"name": "olmoe_1b_7b", "heads": 16, "group": 1, "dk": 128, "dv": 128,
-     "blocks": 1025, "layers": 16, "table": 128, "context": 1024},
+     "blocks": 1025, "layers": 16, "table": 128, "contexts": (1024,)},
     {"name": "qwen25_7b", "heads": 4, "group": 7, "dk": 128, "dv": 128,
-     "blocks": 1921, "layers": 28, "table": 128, "context": 1024},
+     "blocks": 1921, "layers": 28, "table": 128, "contexts": (1024,)},
     {"name": "mimo_v25.swa", "heads": 8, "group": 8, "dk": 256, "dv": 128,
-     "blocks": 53, "layers": 9, "table": 256, "context": 4096,
+     "blocks": 53, "layers": 9, "table": 256, "contexts": (4096,),
      "window": 128},
+    {"name": "keye_vl2_30b_a3b", "heads": 4, "group": 8, "dk": 128,
+     "dv": 128, "blocks": 2305, "layers": 12, "table": 288,
+     "contexts": (2560, 5120, 8704)},
 )
 #: ``scatter``: ``write_block_kv`` then ``paged_attention``, the pair a layer
 #: called before; ``fused``: ``paged_attention_write``, the one op it calls
@@ -1001,6 +1007,14 @@ def time_kv_decode(shape: dict, live: int, form: str, backend: str = "kernel",
     }
 
 
+def walk_slope(walk: list, live: int) -> float:
+    """Nanoseconds a token walked between the shortest and the longest of
+    ``walk``'s ``(context, us a layer call)`` readings, ``live`` rows each
+    ``context`` long."""
+    (c0, us0), (c1, us1) = walk[0], walk[-1]
+    return round(1e3 * (us1 - us0) / (live * (c1 - c0)), 2)
+
+
 def child_kv_decode(spec: dict, out_path: str) -> None:
     import jax
 
@@ -1013,15 +1027,28 @@ def child_kv_decode(spec: dict, out_path: str) -> None:
     results = []
     for shape in KV_DECODE_SHAPES:
         for live in KV_DECODE_LIVE:
-            same = check_kv_decode(shape, live, "kernel")
-            timed = {f: time_kv_decode(shape, live, f)
-                     for f in KV_DECODE_FORMS}
-            results.append({"shape": shape["name"], "live": live, **same,
-                            **timed})
-            print(f"[kv-decode] {shape['name']} live {live}: "
-                  + ", ".join(f"{f} {t['us_per_layer_call']} us {t['ops_us']}"
-                              for f, t in timed.items())
-                  + f"; {same}", flush=True)
+            walk = []  # (context, the decode kernel's us a layer call)
+            for context in shape["contexts"]:
+                at = {**shape, "context": context}
+                same = check_kv_decode(at, live, "kernel")
+                timed = {f: time_kv_decode(at, live, f)
+                         for f in KV_DECODE_FORMS}
+                results.append({"shape": shape["name"], "live": live,
+                                "context": context, **same, **timed})
+                walk.append((context, next(
+                    us for name, us in timed["fused"]["ops_us"]
+                    if name.startswith("paged_decode"))))
+                print(f"[kv-decode] {shape['name']} at {context} live {live}: "
+                      + ", ".join(
+                          f"{f} {t['us_per_layer_call']} us {t['ops_us']}"
+                          for f, t in timed.items())
+                      + f"; {same}", flush=True)
+            if len(walk) > 1:
+                results[-1]["walk_ns_per_token"] = walk_slope(walk, live)
+                print(f"[kv-decode] {shape['name']} live {live}: "
+                      f"paged_decode {walk} (context, us a layer call): "
+                      f"{results[-1]['walk_ns_per_token']} ns a token of "
+                      "context", flush=True)
     with open(out_path, "w") as f:
         json.dump({"device": device_report(), "kv_decode": results}, f)
     if not all(r["arenas_same"] and r["max_err"] <= KERNEL_TOL

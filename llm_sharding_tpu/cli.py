@@ -1467,8 +1467,9 @@ def build_parser() -> argparse.ArgumentParser:
         "elsewhere; kernel = require the Pallas kernels (fails at "
         "startup if ineligible); xla = force the gather fallback. The "
         "decode kernel streams only each row's mapped blocks per step "
-        "(multiple per grid step, double-buffered — blocks_per_step "
-        "auto-tunes from the table width); the chunked-prefill kernel "
+        "(a cell of several at a time, copied by its body into a double "
+        "buffer — the cell's width follows from the shapes); the "
+        "chunked-prefill kernel "
         "(--prefill-chunk) attends the arena in place up to each row's "
         "written frontier, so admission never round-trips a gathered "
         "window through HBM",

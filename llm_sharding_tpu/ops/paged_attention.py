@@ -26,25 +26,30 @@ in place). This module provides the attention over that layout:
   the gathered window in HBM and whose work is the tokens that are
   WRITTEN, not the width the table reserves. It derives each row's
   frontier — the last table entry holding a key some query may attend —
-  from the table and the position arrays it is given, and its one grid
-  axis runs over the rows' live cells laid end to end (a traced bound): a
-  dead row, and the unwritten tail of a live one, cost nothing. The
-  block table, the layer index, the frontiers and each grid step's (row,
-  cell) ride as SCALAR-PREFETCH operands
-  (``pltpu.PrefetchScalarGridSpec``), so each step's ``BlockSpec`` index
-  maps pick the arena blocks to DMA directly from the table —
-  ``blocks_per_step`` of them per step (``auto_blocks_per_step``;
-  independent refs the compiler overlaps and double-buffers), each ALL
-  key/value heads of a block in one ``(Nkv, block_size, D)`` DMA scored by
-  one dot under a block-diagonal head mask — and blocks stream through
-  VMEM with online-softmax accumulation exactly like
-  ``ops/flash_attention``.
+  from the table and the position arrays it is given, and ONE invocation
+  walks the rows' live cells end to end in a loop of its body whose trip
+  count is read from the frontiers: a dead row, and the unwritten tail of
+  a live one, cost nothing. The block table, the layer index and the
+  frontiers ride as SCALAR-PREFETCH operands
+  (``pltpu.PrefetchScalarGridSpec``); the arenas ride in ONCE each, where
+  they lie in HBM, and the body fetches a cell's blocks BY HAND: one
+  async copy a block — ALL key/value heads of it, the ``(Nkv, block_size,
+  D)`` tile at ``(layer, table[b, idx])`` — into one slot of a
+  double-buffered VMEM scratch, the next cell's copies started before
+  this one is scored (``decode_blocks_per_cell`` blocks a cell: as many
+  as the scratch and the score tiles hold, 16 at 4 heads). A block was a
+  ``BlockSpec`` operand until PR 54 and the pipeline's bookkeeping a ref
+  cost as much as its 32 KiB DMA (PERF.md, PR 54: 5.9 → 3.1 ns a token of
+  context at Keye's shape). Every head of a block is scored by one dot
+  under a block-diagonal head mask, and blocks stream through VMEM with
+  online-softmax accumulation exactly like ``ops/flash_attention``.
 - ``paged_prefill_tpu``: the CHUNKED-PREFILL kernel — same table-driven
   KV streaming, but the query axis is a whole prompt chunk, GQA-folded
   and tiled at ``BLOCK_Q_PREFILL`` like the flash kernel, and the one
   grid axis runs over the live cells of the chunk's (row, key/value
-  head, query tile) runs (``prefill_walk``, the decode kernel's recipe:
-  a traced bound, the walk scalar-prefetched): a padded row of the slot,
+  head, query tile) runs (``prefill_walk``: a traced bound, the walk
+  scalar-prefetched, a block a ``BlockSpec`` operand): a padded row of
+  the slot,
   a query tile past a short prompt and the cells past a tile's causal
   frontier cost nothing. This is what lets ``serve_prefill_chunk``
   attend the arena in place instead of round-tripping a gathered
@@ -151,32 +156,68 @@ def forced_backend() -> str | None:
     return raw
 
 
-def auto_blocks_per_step(
-    t_blocks: int, block_size: int, kv_heads: int = 1
-) -> int:
+def auto_blocks_per_step(t_blocks: int, block_size: int) -> int:
     """Auto-selected KV blocks batched per sequential grid step of the
-    Pallas kernels: the largest of 8/4/2/1 that divides the table width
-    and keeps a step's keys at or under 512 tokens and its score tile at
-    or under 2,048 lanes — ``bps·BS`` lanes in the prefill kernel (one
-    head a step), ``bps·BS·kv_heads`` in the decode kernel, which takes a
-    block's ``kv_heads`` heads together (so its K and V, double buffered,
-    stay ≤ 2 MiB at D=128 bf16). At small serving block sizes one arena
-    block is a skinny tile that underfeeds the MXU and pays one DMA
-    turnaround and the pipeline's per-operand bookkeeping (~50 ns a ref a
-    step on a v5e) per block; batching ``bps`` blocks per step gives the
-    compiler ``bps`` independent in-flight DMAs (double-buffered across
-    steps) and dots that do not wait on each other. Swept on the chip at
-    the three benchmark cells' decode shapes (PERF.md, PR 28): 8 is best
-    or within 5% of it at 4 and 8 key/value heads in every state the
-    cells decode in, 4 at 16 heads; 16 wins (10%) only where every row
-    holds the full table."""
+    kernels that take a block as a ``BlockSpec`` operand (the chunked
+    prefill, the index scores; one head of a block a ref): the largest of
+    8/4/2/1 that divides the table width and keeps a step's keys — the
+    lanes of its score tile — at or under 512 tokens. At small serving
+    block sizes one arena block is a skinny tile that underfeeds the MXU
+    and pays one DMA turnaround and the pipeline's per-operand bookkeeping
+    (~50 ns a ref a step on a v5e) per block; batching ``bps`` blocks per
+    step gives the compiler ``bps`` independent in-flight DMAs
+    (double-buffered across steps) and dots that do not wait on each
+    other. The decode kernel issues its own copies and is not held to
+    eight operands: ``decode_blocks_per_cell``."""
     for bps in (8, 4, 2, 1):
-        if (
-            t_blocks % bps == 0 and bps * block_size <= 512
-            and bps * block_size * kv_heads <= 2048
-        ):
+        if t_blocks % bps == 0 and bps * block_size <= 512:
             return bps
     return 1
+
+
+#: VMEM a cell of the decode kernel's walk may hold in K and V tiles, both
+#: slots of its double buffer together (``decode_blocks_per_cell``); the
+#: kernel's other scratch is a few hundred KiB and the compiler's scoped
+#: limit on a v5e 16 MiB.
+DECODE_CELL_VMEM = 4 << 20
+
+
+def decode_blocks_per_cell(
+    t_blocks: int, block_size: int, kv_heads: int, kv_lanes: int,
+    itemsize: int,
+) -> int:
+    """Blocks a cell of the decode kernel's walk (``paged_attention_tpu``),
+    from the shapes alone: the largest power of two that divides the table
+    width and keeps
+
+    - the cell's K and V tiles (``kv_heads`` x ``block_size`` x
+      ``kv_lanes`` x ``itemsize`` bytes a block, ``kv_lanes`` = a key's
+      lanes + a value's; a latent block is its keys alone), double
+      buffered, inside ``DECODE_CELL_VMEM``;
+    - its score tiles — ``bps`` of ``kv_heads·block_size`` lanes, every
+      head of a block scored in one dot — at or under 2,048 lanes, which
+      is 2 MiB of the above at 128-lane bf16 keys and values;
+    - its keys at or under 512 tokens: what a row reads past its frontier
+      inside its last cell (trash, masked) is half a cell on average.
+
+    The body copies a block by hand, so nothing ties the width to a count
+    of operands: 16 at 4 key/value heads (the 7B, Keye: 32 KiB a tile, 2
+    MiB a cell's two slots), 8 at 8 (a 14B stage; MiMo's window layers at
+    256 + 128 lanes: 3 MiB), 4 at 16 (OLMoE: 128 KiB a tile), 16 over a
+    latent arena. Swept on the chip when a block was an operand (PERF.md,
+    PR 28: 8 best at 4 and 8 heads, 4 at 16, 16 ahead only where every row
+    held the full table, the per-operand bookkeeping in its way); by hand
+    16 walks Keye's 8.7 k tokens at 3.1 ns a token where 8 operands took
+    5.9 (PERF.md, PR 54)."""
+    bps = 1
+    while (
+        t_blocks % (2 * bps) == 0 and 2 * bps * block_size <= 512
+        and 2 * bps * block_size * kv_heads <= 2048
+        and 2 * (2 * bps) * kv_heads * block_size * kv_lanes * itemsize
+        <= DECODE_CELL_VMEM
+    ):
+        bps *= 2
+    return bps
 
 
 def kernel_sublane(cache_dtype) -> int:
@@ -187,15 +228,17 @@ def kernel_sublane(cache_dtype) -> int:
 
 
 #: Scalar-memory budget for the kernels' scalar-prefetched operands: the
-#: block table, and a kernel's walk (decode: ``nlive`` and ``start`` per
-#: row, ``row_of`` per cell; prefill: ``PrefillWalk``). The v5e compiler
-#: reports 1 MiB of SMEM and lays an int32 ``[rows, T]`` table out with rows padded to a multiple of
-#: 8 and each row to 128 words, a 1-D array in whole KiB-words: a ``[128,
-#: 2048]`` table alone "exceeded smem capacity by 1.2K"; with the walk of
-#: the decode kernel (PR 28) ``[120, 2048]`` exceeds it by 62.1K and
-#: ``[2000, 33]`` (one block a cell: 66,001 entries) by 253.1K, while
-#: ``[110, 2048]``, ``[104, 2048]`` and ``[1500, 33]`` compile. 16 KiB is
-#: held back for the compiler's own scalars.
+#: block table, and beside it the decode kernel's frontiers (``nlive`` and a
+#: windowed layer's ``first``, an entry a row each) or the prefill kernel's
+#: walk (``PrefillWalk``).
+#: The v5e compiler reports 1 MiB of SMEM and lays an int32 ``[rows, T]``
+#: table out with rows padded to a multiple of 8 and each row to 128 words,
+#: a 1-D array in whole KiB-words: a ``[128, 2048]`` table alone "exceeded
+#: smem capacity by 1.2K"; with a walk of an entry a cell beside it (the
+#: decode kernel's until PR 54, the prefill kernel's still) ``[120, 2048]``
+#: exceeds it by 62.1K and ``[2000, 33]`` (one block a cell: 66,001
+#: entries) by 253.1K, while ``[110, 2048]``, ``[104, 2048]`` and ``[1500,
+#: 33]`` compile. 16 KiB is held back for the compiler's own scalars.
 SMEM_TABLE_BUDGET = (1 << 20) - (16 << 10)
 
 
@@ -211,14 +254,15 @@ def kernel_eligible(
       (``kernel_sublane``);
     - the ``[rows, table_width]`` block table (``rows`` = the rows one call
       attends: a slot's ``batch_per_slot``) is scalar-prefetched whole,
-      and beside it the decode kernel's walk — one entry per cell of every
-      row, ``table_width / bps`` of them at the ``bps`` that
-      ``auto_blocks_per_step`` picks for ``kv_heads`` local key/value
-      heads, and two entries per row; together they must fit
-      ``SMEM_TABLE_BUDGET``. So must the table and the prefill kernel's
-      walk where chunks are prefilled (``prefill_tiles`` =
-      ``prefill_query_tiles`` of the chunk, 0 = none): an entry per cell of
-      every (row, key/value head, query tile) and two per run.
+      and beside it the decode kernel's two entries a row (its frontier, a
+      windowed layer's first cell); together they must fit
+      ``SMEM_TABLE_BUDGET`` (a 1-byte arena's scales take two cells' worth
+      of it, a few KiB). So
+      must the table and the prefill kernel's walk where chunks are
+      prefilled (``prefill_tiles`` = ``prefill_query_tiles`` of the chunk,
+      0 = none): an entry per cell of every (row, key/value head, query
+      tile) — ``table_width / bps`` cells at ``auto_blocks_per_step``'s
+      ``bps`` — and two per run.
 
     Shared by the trace-time dispatch below and the host-side serve
     validation (``runtime/server.py``), so ``--paged-attn kernel`` fails
@@ -226,23 +270,16 @@ def kernel_eligible(
     def pad(n, m):
         return -(-n // m) * m
 
-    def smem_bytes(runs, bps):
-        return 4 * (
-            pad(rows, 8) * pad(table_width, 128)
-            + pad(runs * (table_width // bps) + 1, 1024)
-            + 2 * pad(runs, 128)
-        )
-
+    table = pad(rows, 8) * pad(table_width, 128)
+    decode = table + 2 * pad(rows, 128)
+    runs = rows * kv_heads * prefill_tiles
+    cells = table_width // auto_blocks_per_step(table_width, block_size)
+    prefill = table + pad(runs * cells + 1, 1024) + 2 * pad(runs, 128)
     return (
         head_dim % 128 == 0
         and block_size % kernel_sublane(cache_dtype) == 0
-        and smem_bytes(
-            rows, auto_blocks_per_step(table_width, block_size, kv_heads)
-        ) <= SMEM_TABLE_BUDGET
-        and smem_bytes(
-            rows * kv_heads * prefill_tiles,
-            auto_blocks_per_step(table_width, block_size),
-        ) <= SMEM_TABLE_BUDGET
+        and 4 * decode <= SMEM_TABLE_BUDGET
+        and 4 * prefill <= SMEM_TABLE_BUDGET
     )
 
 
@@ -609,19 +646,20 @@ def combine_attn_stats(
 
 
 def _scale_operand(scale: jnp.ndarray) -> jnp.ndarray:
-    """The kernels' view of a ``[L, NB, Nkv]`` scale arena: ``[L, NB, Nkv,
-    1, 1]`` f32, so one layer's one block's scales are a VMEM tile (layer
-    dim squeezed) whose last two dims ARE the array's — ``(1, 1, 1, 1)``
-    for one head (the prefill kernel), ``(1, Nkv, 1, 1)`` for the block's
-    heads together (the decode kernel). Mosaic refuses a ``(1, 1)`` block
-    of the lower-rank array in any memory space (the last two block dims
-    must be multiples of (8, 128) or the whole array's)."""
+    """The prefill kernel's view of a ``[L, NB, Nkv]`` scale arena: ``[L,
+    NB, Nkv, 1, 1]`` f32, so one layer's one block's one head's scale is a
+    ``(1, 1, 1, 1)`` VMEM tile (layer dim squeezed) whose last two dims ARE
+    the array's. Mosaic refuses a ``(1, 1)`` block of the lower-rank array
+    in any memory space (the last two block dims must be multiples of (8,
+    128) or the whole array's). (The decode kernel reads a cell's scales
+    as scalars: ``paged_attention_tpu``.)"""
     return scale.astype(jnp.float32)[..., None, None]
 
 
 def _layer_operand(layer) -> jnp.ndarray:
     """The layer index as the kernels' scalar-prefetch operand: ``[1]``
-    int32 in scalar memory, read by every arena/scale index map."""
+    int32 in scalar memory, read by every arena/scale index map and by the
+    decode kernel's copies."""
     return jnp.asarray(layer, jnp.int32).reshape(1)
 
 
@@ -710,7 +748,8 @@ def _live_blocks(block_table, q_positions, kv_positions):
 
 def _end_to_end(nent, bps, width):
     """Runs of ``nent[r]`` table entries laid end to end in cells of ``bps``
-    (a kernel's walk: the decode kernel's rows, the prefill kernel's runs):
+    (a kernel's walk over a grid: the score kernel's rows, the prefill
+    kernel's runs; the decode kernel walks in its body and needs none):
     ``start[r]`` the grid step of run ``r``'s cell 0, ``owner[i]`` the run
     grid step ``i`` walks, ``ends`` the running sum of the runs' cells
     (``ends[-1]`` = the grid's length). Steps past the cells' sum never run,
@@ -733,60 +772,97 @@ def _cells_end_to_end(cells, width):
     return start, owner, ends
 
 
+#: Score tiles of a decode cell that fold into the running softmax in one
+#: ``_online_update`` (``_paged_kernel``).
+FOLD_TILES = 8
+
+
 def _paged_kernel(
-    layer_ref,  # scalar-prefetch [1] — read by the index maps only
-    tbl_ref,  # scalar-prefetch [B, T] (index maps + the trash gate)
+    layer_ref,  # scalar-prefetch [1] — the layer of the stack the copies read
+    tbl_ref,  # scalar-prefetch [B, T] (the copies' block ids + the trash gate)
     nlive_ref,  # scalar-prefetch [B] — the row's frontier (_live_blocks)
-    start_ref,  # scalar-prefetch [B] — the grid step of the row's cell 0
-    row_ref,  # scalar-prefetch [B·T/bps + 1] — the row grid step i walks
-    *rest,  # windowed: first scalar-prefetch [B] — the table CELL the
-    #   row's walk starts at; then q [1, M, D] — every head's query rows,
-    #   M = Nkv·G·S; bps k refs [1, Nkv, BS, D] (the arena blocks the index maps
-    #   picked, ALL key/value heads of each), bps v refs; quantized: bps ks
-    #   refs + bps vs refs ([1, Nkv, 1, 1] per-block-per-head scales); then
-    #   the common refs — qpos [1, M, 1], qhead [M, 1], kvpos [1, bps, 1,
-    #   BS], khead [1, Nkv·BS], out [1, M, D], scratch acc [M, D] f32, m
-    #   [M, 128] f32, l [M, 128] f32
+    *rest,  # windowed: first scalar-prefetch [B] — the table CELL the row's
+    #   walk starts at; then q [B, M, D] — every row's every head's query
+    #   rows, M = Nkv·G·S; the K arena and (not latent) the V arena where
+    #   they lie in HBM, [L, NB, Nkv, BS, D]; qpos [B, M, 1], qhead [M, 1],
+    #   kvpos in HBM [B, T/bps, 1, bps·BS], khead [1, Nkv·BS]; quantized:
+    #   the scales of the table's blocks in HBM [B, T/bps, 1, bps·2·Nkv]
+    #   f32 (a block's K scales, then its V scales); (sink [M, 1]), out [B,
+    #   M, Dv]; scratch: the cell buffers, TWO slots each — k [2, bps, Nkv,
+    #   BS, D], v, kvpos [2, 1, bps·BS] in VMEM, quantized: the scales [2,
+    #   1, bps·2·Nkv] in SMEM — a DMA semaphore a slot, acc [M, Dv] f32, m
+    #   [M, 128] f32, l [M, 128] f32 (the two lane rows padded to whole
+    #   128-lane tiles)
     scale,
     bps,
     quantized=False,
-    latent_v=0,  # a latent arena: no v refs, a block's values are the first
-    #   ``latent_v`` lanes of its keys (one DMA a block, not two)
+    latent_v=0,  # a latent arena: no V arena, a block's values are the first
+    #   ``latent_v`` lanes of its keys (one copy a block, not two)
     window=0,  # > 0: a query keeps keys ``q_pos - window < kv_pos`` and the
-    #   walk starts at the row's ``first`` cell (cells behind it are not in
-    #   the grid; the cell the window's edge cuts is masked)
+    #   walk starts at the row's ``first`` cell (cells behind it are not
+    #   walked; the cell the window's edge cuts is masked)
     sink=False,  # a [M, 1] f32 ref after khead: a per-head logit that joins
     #   the softmax's denominator and nothing else
 ):
     if window:
         first_ref, rest = rest[0], rest[1:]
-    q_ref, rest = rest[0], rest[1:]
-    k_refs, rest = rest[:bps], rest[bps:]
-    if not latent_v:
-        v_refs, rest = rest[:bps], rest[bps:]
+    n_src = 1 if latent_v else 2  # the arenas a block is copied out of
+    q_ref, srcs, rest = rest[0], rest[1:1 + n_src], rest[1 + n_src:]
+    qpos_ref, qhead_ref, kvpos_hbm, khead_ref, rest = *rest[:4], rest[4:]
+    rows = [kvpos_hbm]  # what a cell brings in one copy each, a lane row
     if quantized:
-        ks_refs, rest = rest[:bps], rest[bps:]
-        vs_refs, rest = rest[:bps], rest[bps:]
+        rows.append(rest[0])
+        rest = rest[1:]
     if sink:
-        sink_ref, rest = rest[4], rest[:4] + rest[5:]
-    (qpos_ref, qhead_ref, kvpos_ref, khead_ref, out_ref, acc_ref, m_ref,
-     l_ref) = rest
-    i = pl.program_id(0)
-    b = row_ref[i]
-    t = i - start_ref[b]  # which cell of the row's walk
-    nlive = nlive_ref[b]
-    first = t == 0
-    if window:
-        t = t + first_ref[b]  # which cell of the row's table
+        sink_ref, rest = rest[0], rest[1:]
+    out_ref, rest = rest[0], rest[1:]
+    bufs, rest = rest[:n_src], rest[n_src:]
+    row_bufs, rest = rest[:len(rows)], rest[len(rows):]
+    sem, acc_ref, m_ref, l_ref = rest
+    pos_buf = row_bufs[0]
+    B = q_ref.shape[0]
+    Nkv, BS, D = bufs[0].shape[2:]
 
-    @pl.when(first)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    def cells(b):
+        """Row ``b``'s walk: the table cells ``lo <= c < hi``."""
+        hi = (nlive_ref[b] + bps - 1) // bps
+        return (first_ref[b] if window else 0), hi
 
-    q = q_ref[0]  # [M, D]
-    Nkv, BS, D = k_refs[0].shape[1:]
+    def next_live(b):
+        """The first row at or after ``b`` with a cell to walk; ``B``: none."""
+        def dead(r):
+            lo, hi = cells(jnp.minimum(r, B - 1))
+            return (r < B) & (hi <= lo)
+        return jax.lax.while_loop(dead, lambda r: r + 1, b)
+
+    def copies(slot, b=0, c=0, fetch=True):
+        """The async copies that bring cell ``c`` of row ``b`` into ``slot``:
+        its key positions (a quantized arena: its blocks' scales too, into
+        scalar memory), and a block's ``(Nkv, BS, D)`` tile out of each
+        arena at ``(layer, table[b, idx])`` — every head of a block in one
+        contiguous copy, read where it lies. An entry past the frontier
+        inside the frontier's cell names the trash block. ``fetch=False``:
+        the same copies to WAIT on — a wait reads its copy's size and
+        semaphore, not its source, so it spares the table reads."""
+        out = [
+            pltpu.make_async_copy(row.at[b, c], buf.at[slot], sem.at[slot])
+            for row, buf in zip(rows, row_bufs)
+        ]
+        for j in range(bps):
+            idx = c * bps + j
+            at = (layer_ref[0], jnp.where(
+                idx < nlive_ref[b], tbl_ref[b, idx], 0
+            )) if fetch else (0, 0)
+            out += [
+                pltpu.make_async_copy(
+                    src.at[at], buf.at[slot, j], sem.at[slot]
+                )
+                for src, buf in zip(srcs, bufs)
+            ]
+        return out
+
+    # a row the walk never visits reads zeros
+    out_ref[...] = jnp.zeros_like(out_ref)
     # one block's Nkv head tiles are one [Nkv·BS, D] tile, and the score of
     # EVERY query row against it is one dot: a query row keeps the columns
     # of its own key/value head (block-diagonal) and of positions it may
@@ -795,50 +871,106 @@ def _paged_kernel(
     # broadcast maps onto the score tile with no Mosaic relayout. Sentinel
     # positions (never-written block tails) mask out here.
     own = qhead_ref[...] == khead_ref[...]  # [M, Nkv·BS]
-    qpos = qpos_ref[0]  # [M, 1]
-    tiles = []
-    for j in range(bps):
-        k_blk = k_refs[j][0]  # [Nkv, BS, D]
-        v_blk = k_blk[..., :latent_v] if latent_v else v_refs[j][0]
-        if quantized:
-            # THE fused dequant: the block streamed into VMEM as 1-byte
-            # codes (half/quarter the DMA bytes of bf16) and dequantizes
-            # here against its per-(block, head) scale — the bf16 window
-            # never exists in HBM. Dequant target is the query dtype,
-            # matching the XLA gather path bit for bit.
-            k_blk = (
-                k_blk.astype(jnp.float32) * ks_refs[j][0]  # [Nkv, 1, 1]
-            ).astype(q.dtype)
-            v_blk = (
-                v_blk.astype(jnp.float32) * vs_refs[j][0]
-            ).astype(q.dtype)
-        # trash blocks (table entry 0, and what a sub-block past the
-        # frontier inside the frontier's cell names) stream as zeros:
-        # their garbage contents are position-masked to probability 0
-        # below, but non-finite garbage would still NaN the masked
-        # positions (0 x Inf) through the score and PV products. where(),
-        # not multiply — Inf * 0 is itself NaN.
-        idx = t * bps + j
-        live = (idx < nlive) & (tbl_ref[b, idx] != 0)
-        k_blk = jnp.where(live, k_blk, jnp.zeros_like(k_blk))
-        v_blk = jnp.where(live, v_blk, jnp.zeros_like(v_blk))
-        kvpos = jnp.concatenate([kvpos_ref[0, j]] * Nkv, axis=1)
-        k_tile = k_blk.reshape(Nkv * BS, D)
-        v_tile = v_blk.reshape(Nkv * BS, v_blk.shape[-1])
-        seen = own & (kvpos <= qpos)
-        if window:
-            seen &= kvpos > qpos - window
-        tiles.append((k_tile, v_tile, seen))
-    _online_update(q, tiles, scale, acc_ref, m_ref, l_ref)
 
-    @pl.when((t + 1) * bps >= nlive)  # the row's frontier cell
-    def _finish():
-        l = l_ref[:, :1]
-        if sink:
-            l = l + jnp.exp(sink_ref[...] - m_ref[:, :1])
-        out_ref[0] = (acc_ref[:] / jnp.maximum(l, 1e-30)).astype(
-            out_ref.dtype
-        )
+    def cell(carry):
+        """One cell of the walk: the rows' live cells end to end, the next
+        cell's copies in flight (the other slot) while this one is scored."""
+        b, c, slot = carry
+        lo, hi = cells(b)
+        nlive = nlive_ref[b]
+        last = c + 1 >= hi  # the row's frontier cell
+        nb = next_live(jnp.where(last, b + 1, b))
+        nc = jnp.where(last, cells(jnp.minimum(nb, B - 1))[0], c + 1)
+
+        @pl.when(nb < B)
+        def _prefetch():
+            for cp in copies(1 - slot, nb, nc):
+                cp.start()
+
+        for cp in copies(slot, fetch=False):
+            cp.wait()
+
+        @pl.when(c == lo)
+        def _init():
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+            m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[:] = jnp.zeros_like(l_ref)
+
+        q = q_ref[b]  # [M, D]
+        qpos = qpos_ref[b]  # [M, 1]
+
+        def tile(j):
+            """Block ``j`` of the cell as ``_online_update`` takes it."""
+            idx = c * bps + j
+            k_blk = bufs[0][slot, j]  # [Nkv, BS, D]
+            v_blk = k_blk[..., :latent_v] if latent_v else bufs[1][slot, j]
+            if quantized:
+                # THE fused dequant: the block streamed into VMEM as 1-byte
+                # codes (half/quarter the DMA bytes of bf16) and dequantizes
+                # here against its per-(block, head) scales, scalars the
+                # cell's copy left in SMEM — the bf16 window never exists in
+                # HBM. Dequant target is the query dtype, matching the XLA
+                # gather path bit for bit.
+                k_blk, v_blk = (
+                    jnp.stack([
+                        blk[h].astype(jnp.float32)
+                        * row_bufs[1][slot, 0, (2 * j + kv) * Nkv + h]
+                        for h in range(Nkv)
+                    ]).astype(q.dtype)
+                    for kv, blk in enumerate((k_blk, v_blk))
+                )
+            # trash blocks (table entry 0, and what a sub-block past the
+            # frontier inside the frontier's cell names) stream as zeros:
+            # their garbage contents are position-masked to probability 0
+            # below, but non-finite garbage would still NaN the masked
+            # positions (0 x Inf) through the score and PV products. where(),
+            # not multiply — Inf * 0 is itself NaN.
+            live = (idx < nlive) & (tbl_ref[b, idx] != 0)
+            k_blk = jnp.where(live, k_blk, jnp.zeros_like(k_blk))
+            v_blk = jnp.where(live, v_blk, jnp.zeros_like(v_blk))
+            kvpos = jnp.concatenate(
+                [pos_buf[slot, :, j * BS:(j + 1) * BS]] * Nkv, axis=1
+            )
+            k_tile = k_blk.reshape(Nkv * BS, D)
+            v_tile = v_blk.reshape(Nkv * BS, v_blk.shape[-1])
+            seen = own & (kvpos <= qpos)
+            if window:
+                seen &= kvpos > qpos - window
+            return k_tile, v_tile, seen
+
+        # the cell's score tiles fold into the running softmax EIGHT at a
+        # time: eight ``[M, Nkv·BS]`` float32 tiles and their probabilities
+        # fill the vector registers, sixteen spill — and a row's output
+        # then does not depend on how wide the shapes make a cell
+        for j in range(0, bps, FOLD_TILES):
+            _online_update(
+                q, [tile(i) for i in range(j, min(j + FOLD_TILES, bps))],
+                scale, acc_ref, m_ref, l_ref,
+            )
+
+        @pl.when(last)
+        def _finish():
+            l = l_ref[:, :1]
+            if sink:
+                l = l + jnp.exp(sink_ref[...] - m_ref[:, :1])
+            out_ref[b] = (acc_ref[:] / jnp.maximum(l, 1e-30)).astype(
+                out_ref.dtype
+            )
+
+        return nb, nc, 1 - slot
+
+    b0 = next_live(jnp.int32(0))
+    c0 = cells(jnp.minimum(b0, B - 1))[0]
+
+    @pl.when(b0 < B)
+    def _first():
+        for cp in copies(0, b0, c0):
+            cp.start()
+
+    jax.lax.while_loop(
+        lambda carry: carry[0] < B, cell,
+        (b0, jnp.asarray(c0, jnp.int32), jnp.int32(0)),
+    )
 
 
 @functools.partial(
@@ -870,51 +1002,56 @@ def paged_attention_tpu(
     #   denominator (its column dropped: it adds nothing to the output)
 ) -> jnp.ndarray:
     """Pallas paged DECODE attention whose work is the tokens that are
-    written: ONE sequential grid axis over the LIVE cells of the call, a
-    cell being ``bps`` consecutive table entries of one row
-    (``blocks_per_step``, auto-selected by ``auto_blocks_per_step`` when
-    None), for all key/value heads.
+    written: ONE kernel invocation walks the LIVE cells of the call, a cell
+    being ``bps`` consecutive table entries of one row (``blocks_per_step``,
+    ``decode_blocks_per_cell`` of the shapes when None), for all key/value
+    heads.
 
     The frontier. ``nlive[b]`` (``_live_blocks``) is derived here from the
-    table and the two position arrays — no caller passes it — and with it
-    the walk: row ``b`` has ``ceil(nlive[b] / bps)`` cells, the grid's
-    bound is their sum over the rows (a traced scalar; at least one), and
-    two small arrays name the row of every grid step and the step each
-    row starts at. All three ride as scalar-prefetch operands beside the
-    layer index and the table, so the ``BlockSpec`` index maps pick the arena blocks to DMA
-    straight from the table: a row costs the blocks that are written, a
-    dead row nothing. A skipped block is one the position mask wiped
-    whole, so the result is the whole table's; a row with no live block
-    returns zeros.
+    table and the two position arrays — no caller passes it — and rides as
+    a scalar-prefetch operand beside the layer index and the table (a
+    windowed layer's first cell too). The walk is a loop INSIDE the body:
+    row ``b`` has ``ceil(nlive[b] / bps)`` cells, the loop runs the rows'
+    cells end to end and reads its trip count from ``nlive``, so a row
+    costs the blocks that are written and a dead row a compare. A skipped
+    block is one the position mask wiped whole, so the result is the whole
+    table's; a row with no live block returns zeros.
 
-    A block's heads together. Each cell DMAs ``bps`` arena blocks, each
-    the ``(Nkv, BS, D)`` tile at ``(layer, table[b, t])`` of the 5-D
-    stacked pool — ALL key/value heads of a block in one contiguous DMA;
-    the arena is read where it lies (no slice of a layer, no layout
-    change), the gathered window never exists in HBM, and the ``bps``
-    fetches are independent refs the compiler overlaps and double-buffers
-    across cells. Every head of a block is scored in ONE dot: the query
-    tile is all ``M = Nkv·G·S`` rows (head ``h = k·G + g``, the fold of
-    ``cached_attention``), a block is one ``[Nkv·BS, D]`` tile, and a
+    The copies are the body's. Both arenas ride in ONCE, unblocked, where
+    they lie in HBM (``memory_space=pl.ANY``: no slice of a layer, no
+    layout change, the gathered window never exists), and the body starts
+    one async copy a block — the ``(Nkv, BS, D)`` tile at ``(layer,
+    table[b, idx])`` of the 5-D stacked pool, ALL key/value heads of a
+    block in one contiguous DMA — into one of the two slots of a VMEM
+    scratch: cell ``c + 1``'s copies (the next live row's first cell after
+    a row's last) are started before cell ``c`` is scored and waited on
+    just before their use. No ``BlockSpec`` operand a block: the pipeline
+    paid ~50 ns of bookkeeping a ref a grid step, sixteen refs a cell
+    (PERF.md, PR 54). Every head of a block is scored in ONE dot: the
+    query tile is all ``M = Nkv·G·S`` rows (head ``h = k·G + g``, the fold
+    of ``cached_attention``), a block is one ``[Nkv·BS, D]`` tile, and a
     query row keeps the columns of its own key/value head — the mask is
     block-diagonal over heads times ``kv_pos <= q_pos``. The cell's
-    ``bps`` score tiles fold into the running softmax with one rescale
-    (``_online_update``). Decode-shaped: the ``M`` query rows stay in one
-    tile, so keep ``Nh·S`` small (serving decode is S = 1, verify K + 1).
+    ``bps`` score tiles fold into the running softmax ``FOLD_TILES`` (8)
+    at a time, one rescale a fold (``_online_update``): a cell's width
+    decides who copies a block and when, not a bit of the result. Decode-shaped: every row's ``M`` query rows sit
+    in VMEM whole, so keep ``B·Nh·S`` small (serving decode is S = 1,
+    verify K + 1).
 
-    VMEM per cell is 2 x 2 x bps ``(Nkv, BS, D)`` blocks (K and V, double
-    buffered: 2 MiB at 16 bf16 heads of 32 x 128 and bps 4) + the ``(M,
-    Nkv·BS)`` f32 score tiles + (M, D) + 2·(M, 128) scratch. Real-TPU use
-    wants D a lane multiple (128) and BS a sublane multiple for the cache
-    dtype; ``paged_attention`` gates on that and interpret mode covers the
-    rest.
+    VMEM is 2 x bps ``(Nkv, BS, D)`` K blocks and as many V blocks (the
+    two slots: 2 MiB at 4 bf16 heads of 32 x 128 and bps 16, or 16 heads
+    and bps 4) + the ``(M, Nkv·BS)`` f32 score tiles + ``(B, M, D)``
+    queries and outputs + (M, D) + 2·(M, 128) scratch. Real-TPU use wants
+    D a lane multiple (128) and BS a sublane multiple for the cache dtype;
+    ``paged_attention`` gates on that and interpret mode covers the rest.
 
-    Quantized arenas (``k_scale``/``v_scale``): the per-block DMA moves
+    Quantized arenas (``k_scale``/``v_scale``): a block's copy moves
     1-byte codes — HALF (int8 vs bf16) the per-step attention HBM traffic
-    — plus the block's ``Nkv`` per-head scales as one ``(Nkv, 1, 1)`` VMEM
-    tile (``_scale_operand``), and the dequant multiply runs in VMEM right
-    before the score dot. Int8 tiles want BS a multiple of 32 (1-byte
-    sublane — ``kernel_eligible``)."""
+    — and the scales of the blocks the table names (``[B, T, 2, Nkv]``, one
+    small gather out of each scale arena) come a cell at a time, one more
+    copy, into scalar memory; the dequant multiply, a head's tile by its
+    scalar, runs in VMEM right before the score dot. Int8 tiles want BS a
+    multiple of 32 (1-byte sublane — ``kernel_eligible``)."""
     B, S, Nh, D = q.shape
     Nkv, BS = k_arena.shape[2], k_arena.shape[3]
     T = block_table.shape[1]
@@ -931,11 +1068,23 @@ def paged_attention_tpu(
             f"kv_positions must be [B, T*BS]={B, T * BS}, got "
             f"{kv_positions.shape}"
         )
-    bps = blocks_per_step or auto_blocks_per_step(T, BS, Nkv)
+    bps = blocks_per_step or decode_blocks_per_cell(
+        T, BS, Nkv, D + (0 if latent_v else Dv), k_arena.dtype.itemsize
+    )
     if T % bps != 0:
         raise ValueError(
             f"blocks_per_step={bps} does not divide the table width {T}"
         )
+
+    def cell_rows(x):
+        """``[B, T, ...]`` as a lane row a cell, ``[B, T/bps, 1, lanes]``:
+        the body copies a cell's row by hand, and Mosaic slices a copy's
+        source in whole 128-lane tiles, so a narrower or ragged row (an odd
+        table width, 32 heads a block, a cell's few scales) is padded up."""
+        x = x.reshape(B, T // bps, 1, -1)
+        return jnp.pad(x, ((0, 0),) * 3 + ((0, -x.shape[-1] % 128),))
+
+    kp = cell_rows(kv_positions)
 
     # GQA fold (the reshape contract of cached_attention: head h = k*G + g):
     # query row r = (k*G + g)*S + s belongs to key/value head r // (G*S)
@@ -944,103 +1093,53 @@ def paged_attention_tpu(
     qp = jnp.tile(q_positions, (1, Nh))[..., None]  # [B, M, 1]
     qhead = (np.arange(M, dtype=np.int32) // (G * S))[:, None]  # [M, 1]
     khead = (np.arange(Nkv * BS, dtype=np.int32) // BS)[None]  # [1, Nkv*BS]
-    kp = kv_positions.reshape(B, T, 1, BS)  # one [1, BS] lane row per block
 
-    # the walk: the rows' live cells laid end to end — grid step i is cell
-    # ``i - start[b]`` of row ``b = row_of[i]`` (``_end_to_end``; the index
-    # maps keep the cell inside the table)
     nlive = _live_blocks(block_table, q_positions, kv_positions)
+    prefetch = [_layer_operand(layer), block_table, nlive]
     if window:
         # the walk from the other side: cells wholly behind the window are
-        # not in the grid, the walk's cell 0 is the table's cell ``first``
-        first = _first_blocks(
+        # not walked, a row's first cell is the table's cell ``first``
+        prefetch.append(_first_blocks(
             block_table, q_positions, kv_positions, window, nlive
-        ) // bps
-        start, row_of, ends = _cells_end_to_end(
-            -(-nlive // bps) - first, T // bps
-        )
-        lead = [first]
-    else:
-        start, row_of, ends = _end_to_end(nlive, bps, T // bps)
-        lead = []
-    n_pre = 5 + len(lead)
+        ) // bps)
 
-    # the arena-block specs: a cell streams the bps blocks the
-    # scalar-prefetched table names out of the scalar-prefetched layer of
-    # the stacked pool, every head of each (one ref per sub-block —
-    # independent DMAs; the layer dim is squeezed). A sub-block past the
-    # frontier inside the row's last cell names the trash block.
-    # Quantized runs add each block's per-head scales, picked by the same
-    # indices out of a [L, NB, Nkv, 1, 1] view (see _scale_operand).
-    def cell(i, b, st, *fst):
-        c = i - st[b]
-        if fst:
-            c = c + fst[0][b]
-        return jnp.minimum(c, T // bps - 1)
+    def whole(shape):
+        return pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape))
 
-    def arena_index(i, lyr, tbl, nl, st, row, *fst, j):
-        b = row[i]
-        idx = cell(i, b, st, *fst) * bps + j
-        return (lyr[0], jnp.where(idx < nl[b], tbl[b, idx], 0), 0, 0, 0)
-
-    def block_spec(j, width=D):
-        return pl.BlockSpec(
-            (None, 1, Nkv, BS, width), functools.partial(arena_index, j=j)
-        )
-
-    def scale_spec(j):
-        return pl.BlockSpec(
-            (None, 1, Nkv, 1, 1), functools.partial(arena_index, j=j)
-        )
-
-    def of_row(i, lyr, tbl, nl, st, row, *fst):
-        return (row[i], 0, 0)
-
-    def whole(i, lyr, tbl, nl, st, row, *fst):
-        return (0, 0)
-
+    in_hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    # what a block brings: its keys and its values (a latent block is read
+    # once: its values are a slice of its keys)
+    arenas = [k_arena] + ([] if latent_v else [v_arena])
+    operands = [qh, *arenas, qp, qhead, kp, khead]
     in_specs = [
-        pl.BlockSpec((1, M, D), of_row),
-        *[block_spec(j) for j in range(bps)],
-        # a latent block is read once: its values are a slice of its keys
-        *([] if latent_v else [block_spec(j, Dv) for j in range(bps)]),
+        whole((B, M, D)), *[in_hbm] * len(arenas), whole((B, M, 1)),
+        whole((M, 1)), in_hbm, whole((1, Nkv * BS)),
     ]
-    operands = [
-        _layer_operand(layer), block_table, nlive, start, row_of, *lead, qh,
-        *([k_arena] * bps), *([] if latent_v else [v_arena] * bps),
-    ]
+    row_bufs = [pltpu.VMEM((2, *kp.shape[2:]), kp.dtype)]
     if quantized:
-        in_specs += (
-            [scale_spec(j) for j in range(bps)]
-            + [scale_spec(j) for j in range(bps)]
-        )
-        operands += (
-            [_scale_operand(k_scale)] * bps + [_scale_operand(v_scale)] * bps
-        )
-    in_specs += [
-        pl.BlockSpec((1, M, 1), of_row),
-        pl.BlockSpec((M, 1), whole),
-        pl.BlockSpec(
-            (1, bps, 1, BS),
-            lambda i, lyr, tbl, nl, st, row, *fst: (
-                row[i], cell(i, row[i], st, *fst), 0, 0
-            ),
-        ),
-        pl.BlockSpec((1, Nkv * BS), whole),
-    ]
-    operands += [qp, qhead, kp, khead]
+        # the scales of the blocks the table names, a block's Nkv of K then
+        # its Nkv of V: one small gather out of each scale arena, a row a
+        # cell that the body copies into SCALAR memory
+        sc = cell_rows(jnp.stack(
+            [s.astype(jnp.float32)[layer, block_table]
+             for s in (k_scale, v_scale)], axis=2,
+        ))
+        operands.append(sc)
+        in_specs.append(in_hbm)
+        row_bufs.append(pltpu.SMEM((2, *sc.shape[2:]), sc.dtype))
     if sink is not None:
         # query row r = h·S + s carries head h's logit, sublane-major
-        in_specs.append(pl.BlockSpec((M, 1), whole))
-        operands.append(
-            jnp.repeat(sink.astype(jnp.float32), S)[:, None]
-        )
+        in_specs.append(whole((M, 1)))
+        operands.append(jnp.repeat(sink.astype(jnp.float32), S)[:, None])
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_pre,
-        grid=(jnp.maximum(ends[-1], 1),),
+        num_scalar_prefetch=len(prefetch),
+        grid=(1,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, M, Dv), of_row),
+        out_specs=whole((B, M, Dv)),
         scratch_shapes=[
+            *[pltpu.VMEM((2, bps, *a.shape[2:]), a.dtype) for a in arenas],
+            *row_bufs,
+            pltpu.SemaphoreType.DMA((2,)),
             pltpu.VMEM((M, Dv), jnp.float32),
             pltpu.VMEM((M, 128), jnp.float32),
             pltpu.VMEM((M, 128), jnp.float32),
@@ -1059,10 +1158,7 @@ def paged_attention_tpu(
         ),
         interpret=interpret,
         name="paged_decode",
-    )(*operands)
-    # a row the walk never visited was never written: it reads zeros
-    walked = nlive > first * bps if window else nlive > 0
-    out = jnp.where(walked[:, None, None], out, jnp.zeros_like(out))
+    )(*prefetch, *operands)
     return jnp.transpose(out.reshape(B, Nh, S, Dv), (0, 2, 1, 3))
 
 
@@ -2061,7 +2157,8 @@ def _index_kernel(
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def index_scores_tpu(qi, wi, idx_arena, layer, block_table, q_positions,
                      kv_positions, interpret: bool = False):
-    """The decode kernel's walk over the INDEX arena: one grid axis over the
+    """The decode kernel's walk as it was until PR 54, over the INDEX arena
+    (a block a ``BlockSpec`` operand): one grid axis over the
     rows' live cells (``bps`` blocks of one row: ``_live_blocks``,
     ``_end_to_end``), a cell's index keys scored against the row's ``Hi``
     index queries in one dot, ``relu``, weighted and summed over the index

@@ -25,18 +25,21 @@ def make_stack(rng, NB, Nkv, bs, D, dtype=jnp.float32, L=LAYERS):
     )
 
 
-def int8_stack(rng, k_arena, v_arena):
-    """Two float stacks as an int8 arena: codes spanning the code range
-    and random per-(layer, block, head) scales. Returns ``(k_codes,
-    v_codes, scales)``, ``scales`` the ops' ``k_scale``/``v_scale``
-    keywords."""
+def int8_stack(rng, k_arena, v_arena, dtype=jnp.int8):
+    """Two float stacks as an int8 (or, ``dtype``, fp8) arena: codes
+    spanning the code range and random per-(layer, block, head) scales.
+    Returns ``(k_codes, v_codes, scales)``, ``scales`` the ops'
+    ``k_scale``/``v_scale`` keywords."""
     from llm_sharding_tpu.ops.quant import kv_qmax
 
-    qmax = kv_qmax(jnp.int8)
-    k_codes, v_codes = (
-        jnp.asarray(np.round(np.clip(
-            np.asarray(a) * (qmax / 3.0), -qmax, qmax)), jnp.int8)
+    qmax = kv_qmax(dtype)
+    coded = (
+        np.clip(np.asarray(a) * (qmax / 3.0), -qmax, qmax)
         for a in (k_arena, v_arena)
+    )
+    k_codes, v_codes = (
+        jnp.asarray(np.round(c) if dtype == jnp.int8 else c, dtype)
+        for c in coded
     )
     sc = rng.uniform(0.5, 1.5, (2, *k_arena.shape[:3])) * (3.0 / qmax)
     return k_codes, v_codes, {"k_scale": jnp.asarray(sc[0], jnp.float32),

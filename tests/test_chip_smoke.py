@@ -167,6 +167,9 @@ KV_DECODE_TOY = {
     "window": {"name": "toy_swa", "heads": 2, "group": 2, "dk": 16, "dv": 8,
                "blocks": 13, "layers": 2, "table": 8, "context": 40,
                "window": 12},
+    # Keye's: one row of a long context, a walk of three cells of 8 blocks
+    "long": {"name": "toy_long", "heads": 4, "group": 2, "dk": 16, "dv": 16,
+             "blocks": 81, "layers": 2, "table": 24, "context": 150},
 }
 
 
@@ -193,23 +196,41 @@ def test_kv_decode_forms_agree_and_are_traced(
 
 def test_kv_decode_shapes_are_the_cells():
     """The shapes ``--kv-decode`` times are OLMoE's, the 7B's and MiMo's
-    window layers' as ``--kv-write`` holds them, a table as wide as the
-    cell's capacity."""
+    window layers' as ``--kv-write`` holds them and Keye's as its
+    configuration does, a table as wide as the cell's capacity; Keye's three
+    contexts lie inside a reply of its cell (2,048 to 8,704 tokens) and its
+    slope is read between the first and the last."""
     import json
     import os
 
     cfgs = os.path.join(chip_smoke.HERE, "benchmark", "configs")
     write = {s["name"]: s for s in chip_smoke.KV_WRITE_SHAPES}
     for shape in chip_smoke.KV_DECODE_SHAPES:
-        for key in ("heads", "dk", "dv", "blocks", "layers"):
-            assert shape[key] == write[shape["name"]][key], (shape, key)
         with open(os.path.join(
                 cfgs, shape["name"].split(".")[0] + ".json")) as f:
             cfg = json.load(f)
         serve = cfg["serve"]
+        if shape["name"] in write:
+            for key in ("heads", "dk", "dv", "blocks", "layers"):
+                assert shape[key] == write[shape["name"]][key], (shape, key)
+        else:
+            assert (shape["heads"], shape["heads"] * shape["group"],
+                    shape["dk"], shape["dv"], shape["blocks"],
+                    shape["layers"]) == (
+                cfg["num_key_value_heads"], cfg["num_attention_heads"],
+                cfg["head_dim"], cfg["head_dim"], serve["kv_blocks"],
+                cfg["num_hidden_layers"])
         assert shape["table"] == serve["capacity"] // serve["kv_block_size"]
+        assert max(shape["contexts"]) < serve["capacity"]
         assert shape.get("window", 0) == (
             cfg.get("sliding_window") or 0 if "." in shape["name"] else 0)
+    keye = chip_smoke.KV_DECODE_SHAPES[-1]
+    assert keye["name"] == "keye_vl2_30b_a3b"
+    assert keye["contexts"] == (2560, 5120, 8704)
+    # the parent's readings (PERF.md, PR 50): 6.0 ns a token of context
+    assert chip_smoke.walk_slope([(2560, 22.2), (5120, 37.4), (8704, 59.4)],
+                                 1) == 6.05
+    assert chip_smoke.walk_slope([(2560, 40.0), (8704, 138.3)], 4) == 4.0
 
 
 def test_store_writer_driver_and_assertions_on_cpu(tmp_path, monkeypatch):

@@ -294,6 +294,11 @@ def _selecting_case(case):
         qi, ki = np.abs(qi), np.abs(ki)
         for b in range(2):
             ki[b, ctx[b] - 1] *= -1  # the newest key scores 0, the others > 0
+    elif case == "the chosen in two blocks, the other blocks whole unchosen":
+        # columns 8-23 (table entries 1 and 2) outscore every other: the
+        # kernel's copies bring five blocks of which no column is kept
+        qi, ki = np.abs(qi), np.abs(ki)
+        ki[:, BS:3 * BS] *= 100.0
     else:
         assert case == "no ties"
     q_pos = [c - 1 if c else POS_SENTINEL for c in ctx]
@@ -305,6 +310,7 @@ SELECTING = [
     "a dead row beside a live one", "a row under topk beside a row past it",
     "stale keys in a freed block and in the trash block",
     "the query's own token not among the chosen",
+    "the chosen in two blocks, the other blocks whole unchosen",
 ]
 
 
@@ -360,6 +366,8 @@ def test_a_selecting_decode_step_attends_the_list_select_tokens_gives(
             assert (score[cols] == 0).sum() == _TOPK - 5
         if case.startswith("the query's own"):
             assert ctx[b] - 1 not in cols
+        if case.startswith("the chosen in two blocks"):
+            assert sorted(cols) == list(range(BS, 3 * BS))
         for h in range(_NH):
             logit = k[b, cols, h // 2] @ q[b, 0, h] * _D ** -0.5
             p = jax.nn.softmax(jnp.asarray(logit))

@@ -810,6 +810,11 @@ _FUSED_ARENAS = {
     "mha_16x1_bf16": dict(Nkv=16, G=1, D=8, Dv=8, dtype=jnp.bfloat16),
     "window_sink_k_wider": dict(Nkv=2, G=2, D=12, Dv=8, window=6, sink=True),
     "latent": dict(Nkv=1, G=4, D=8, Dv=0, latent_v=6),
+    # a table of six entries walks in cells of two: a row's second cell
+    # copied while its first is scored, the next row's first from its last
+    "latent_cells_of_2": dict(Nkv=1, G=4, D=8, Dv=0, latent_v=6, T=6),
+    "window_sink_k_wider_cells_of_2": dict(
+        Nkv=2, G=2, D=12, Dv=8, window=6, sink=True, T=6),
 }
 
 
@@ -822,6 +827,7 @@ def _fused_case(arena, bs=4, T=5, NB=24, seed=9):
     from llm_sharding_tpu.models.cache import POS_SENTINEL
 
     a = dict(_FUSED_ARENAS[arena])
+    T = a.pop("T", T)
     rng = np.random.default_rng(seed)
     dt = a.pop("dtype", jnp.float32)
     Nkv, G, D, Dv = a.pop("Nkv"), a.pop("G"), a.pop("D"), a.pop("Dv")
@@ -1129,15 +1135,17 @@ def test_paged_attention_pallas_interpret_multiquery_matches_xla():
     )
 
 
-def _frontier_case(seed, S, Nkv, kv_dtype, T=8, bs=4):
-    """Four rows at four frontiers in ONE call, over a stack of ``LAYERS``
-    different layers: row 0 dead, row 1 one block, row 2 a frontier inside
-    a group of four blocks with a TRASH entry below it, row 3 the full
-    table. The dead row is a finished row as each decode program leaves
-    it: S = 1 (``serve_chunk``) a real query position over a table the
-    host remapped to trash; S > 1 (``serve_verify``) sentinel queries over
-    a table still mapped. Returns the ops' positional arguments, the scale
-    keywords, and the expected live blocks per row."""
+def _frontier_case(seed, S, Nkv, kv_dtype, T=8, bs=4, rows="0123"):
+    """Four rows in ONE call, over a stack of ``LAYERS`` different layers,
+    each at the frontier its digit of ``rows`` names: 0 dead, 1 one block,
+    2 a frontier inside a group of four blocks with a TRASH entry below it,
+    3 the full table — every row's blocks drawn from one shuffle of the
+    pool, so no two table entries are neighbours in the arena. A dead row is
+    a finished row as each decode program leaves it: S = 1
+    (``serve_chunk``) a real query position over a table the host remapped
+    to trash; S > 1 (``serve_verify``) sentinel queries over a table still
+    mapped. Returns the ops' positional arguments, the scale keywords, and
+    the expected live blocks per row."""
     from llm_sharding_tpu.models.cache import POS_SENTINEL
 
     rng = np.random.default_rng([seed, S, Nkv, kv_dtype == "int8"])
@@ -1145,49 +1153,76 @@ def _frontier_case(seed, S, Nkv, kv_dtype, T=8, bs=4):
     Nh, W, NB = Nkv * G, T * bs, 4 * T + 1
     k_arena, v_arena = make_stack(rng, NB, Nkv, bs, D)
     scales = {}
-    if kv_dtype == "int8":
-        k_arena, v_arena, scales = int8_stack(rng, k_arena, v_arena)
-    nlive = np.array([0, 1, 6, T])
+    if kv_dtype != "bf16":
+        k_arena, v_arena, scales = int8_stack(
+            rng, k_arena, v_arena,
+            jnp.int8 if kv_dtype == "int8" else jnp.float8_e4m3fn,
+        )
+    kinds = np.array([int(c) for c in rows])
+    nlive = np.array([0, 1, 6, T])[kinds]
     # tokens in the window, the S in flight included (their KV is written
     # before the kernel runs)
-    ctx = np.array([9, max(S, 2), 6 * bs - 1, T * bs])
+    ctx = np.array([9, max(S, 2), 6 * bs - 1, T * bs])[kinds]
     ids = rng.permutation(np.arange(1, NB))
     tbl = np.zeros((B, T), np.int32)
     for b in range(B):
-        mapped = T if b == 3 else min(nlive[b] + 1, T)  # + a budget block
+        # + a budget block
+        mapped = T if kinds[b] == 3 else min(nlive[b] + 1, T)
         tbl[b, :mapped] = ids[b * T: b * T + mapped]
-    tbl[2, 2] = 0  # trash below row 2's frontier
+    tbl[kinds == 2, 2] = 0  # trash below the frontier
     cols = np.arange(W)[None]
     kvpos = np.where(cols < ctx[:, None], cols, int(POS_SENTINEL))
     qpos = (ctx - S)[:, None] + np.arange(S)[None]
     if S == 1:
-        tbl[0] = 0  # finished, remapped to trash; its position stays real
+        # finished, remapped to trash; its position stays real
+        tbl[kinds == 0] = 0
     else:
-        tbl[0, :3] = ids[-3:]
-        qpos[0] = int(POS_SENTINEL)
+        tbl[kinds == 0, :3] = ids[-3:]
+        qpos[kinds == 0] = int(POS_SENTINEL)
     q = jnp.asarray(rng.normal(size=(B, S, Nh, D)), jnp.float32)
     args = (q, k_arena, v_arena, 1, jnp.asarray(tbl),
             jnp.asarray(qpos, jnp.int32), jnp.asarray(kvpos, jnp.int32))
     return args, scales, nlive
 
 
-@pytest.mark.parametrize("kv_dtype", ("bf16", "int8"))
-@pytest.mark.parametrize("Nkv", (1, 4, 16))
-@pytest.mark.parametrize("S", (1, 3))
-def test_decode_walk_ends_at_each_rows_frontier(S, Nkv, kv_dtype):
+#: ``(S, Nkv, kv_dtype, rows)``: the four frontiers in the order the walk
+#: was written for, at every fold and store; fp8 codes; then 0 / 1 / 2 / 4
+#: live rows of the four in a shuffled order (the body finds the next live
+#: row itself and starts ITS first cell's copies from the row before)
+_WALK_CASES = [
+    *[(S, Nkv, kv, "0123") for kv in ("bf16", "int8") for Nkv in (1, 4, 16)
+      for S in (1, 3)],
+    (1, 4, "fp8", "0123"), (3, 1, "fp8", "3120"),
+    (1, 4, "bf16", "0000"), (3, 4, "bf16", "0000"), (1, 4, "bf16", "0020"),
+    (1, 4, "bf16", "3002"), (3, 1, "int8", "2003"), (1, 16, "bf16", "2313"),
+    (3, 4, "int8", "1232"), (1, 1, "bf16", "3210"),
+]
+
+
+@pytest.mark.parametrize(
+    "S, Nkv, kv_dtype, rows", _WALK_CASES,
+    ids=["-".join(map(str, c)) for c in _WALK_CASES],
+)
+def test_decode_walk_ends_at_each_rows_frontier(S, Nkv, kv_dtype, rows):
     """The decode kernel (interpret) walks each row to its written
-    frontier and no further, all key/value heads of a block in one tile:
+    frontier and no further, all key/value heads of a block in one tile,
+    every block fetched by the body's own copy out of a shuffled pool:
     rows at four frontiers in one call — dead, one block, inside a
     ``bps`` group with a trash entry below it, the full table — at S = 1
-    and verify-shaped S = 3, ``Nkv`` 1 / 4 / 16, float and int8 arenas.
+    and verify-shaped S = 3, ``Nkv`` 1 / 4 / 16, float, int8 and fp8
+    arenas, and 0 / 1 / 2 / 4 of the four rows live in any order.
     ``_live_blocks`` reads the frontiers off the operands; live rows equal
-    the XLA gather and the single-block grid; the dead row comes back
+    the XLA gather and the single-block walk; the dead row comes back
     zeros; and the cells the walk skips contribute NOTHING: a row's output
     is bit for bit that of the same call on a table cut off at the row's
     frontier cell."""
     from llm_sharding_tpu.ops import paged_attention as pa
 
-    args, scales, nlive = _frontier_case(5, S, Nkv, kv_dtype)
+    from llm_sharding_tpu.ops.quant import fp8_kv_supported
+
+    if kv_dtype == "fp8" and not fp8_kv_supported():
+        pytest.skip("no fp8 on this backend")
+    args, scales, nlive = _frontier_case(5, S, Nkv, kv_dtype, rows=rows)
     q, ka, va, layer, tbl, qpos, kvpos = args
     bs = ka.shape[3]
     np.testing.assert_array_equal(
@@ -1218,6 +1253,30 @@ def test_decode_walk_ends_at_each_rows_frontier(S, Nkv, kv_dtype):
     # S = 1: the dead row attends zeros on the XLA path too
     if S == 1:
         assert not want[~live].any()
+
+
+@pytest.mark.parametrize("S, Nkv, kv_dtype, rows", [
+    (1, 4, "bf16", "0123"), (3, 1, "int8", "3231"), (1, 16, "bf16", "2013"),
+])
+def test_a_wider_cell_folds_its_tiles_eight_at_a_time(S, Nkv, kv_dtype, rows):
+    """A cell of 16 or 32 blocks gives bit for bit what cells of 8 give:
+    its score tiles fold into the running softmax ``FOLD_TILES`` at a time
+    (what the vector registers hold), so a cell's width — what the shapes
+    allow, 8 blocks when a block was an operand — changes who copies a
+    block and when, never a bit of a row's output or a token a model
+    serves."""
+    from llm_sharding_tpu.ops import paged_attention as pa
+
+    assert pa.FOLD_TILES == 8
+    args, scales, nlive = _frontier_case(
+        11, S, Nkv, kv_dtype, T=32, rows=rows)
+    eight = np.asarray(pa.paged_attention_tpu(
+        *args, interpret=True, blocks_per_step=8, **scales))
+    assert np.abs(eight[nlive > 0]).min() > 0
+    for bps in (16, 32):
+        wide = np.asarray(pa.paged_attention_tpu(
+            *args, interpret=True, blocks_per_step=bps, **scales))
+        np.testing.assert_array_equal(wide, eight)
 
 
 @pytest.mark.parametrize("layer", LAYER_CASES)
@@ -1314,14 +1373,16 @@ def test_paged_attn_kwarg_validation(setup):
 def test_kernel_rules_learned_from_the_v5e_compiler(monkeypatch):
     """What Mosaic refused during bring-up stays refused — or repaired.
 
-    Shape rule: the scalar-prefetched block table, and since PR 28 the
-    decode kernel's walk beside it (an entry per cell of every row), must
-    fit scalar memory: ``[128, 2048]`` int32 alone "exceeded smem
-    capacity"; with the walk at 4 key/value heads ``[120, 2048]`` exceeds
-    it by 62.1K and ``[2000, 33]`` (an odd width: one block a cell) by
-    253.1K, while ``[104, 2048]`` and ``[1500, 33]`` — rows pad to 128
-    entries — compile (AOT compiles of ``paged_attention_tpu`` for a
-    described v5e; PERF.md, PR 28). Repairs: a
+    Shape rule: the scalar-prefetched block table, and the decode kernel's
+    two entries a row beside it, must fit scalar memory: ``[128, 2048]``
+    and ``[124, 2048]`` int32 "exceeded smem capacity" (by 1.6K), ``[120,
+    2048]`` compiles since the walk is a loop in the body and no longer an
+    entry a cell (with it, PR 28 to PR 53, it exceeded by 62.1K); ``[2000,
+    33]`` (an odd width: rows pad to 128 entries) is held ineligible with
+    16 KiB to spare, ``[1500, 33]`` and ``[1900, 33]`` compile, and the
+    number of key/value heads no longer counts — ``[100, 2048]`` compiles
+    at 32 (AOT compiles of ``paged_attention_tpu`` for a described v5e;
+    PERF.md, PR 28 and PR 54). Repairs: a
     ``kv_positions`` tile that is neither 128 lanes wide nor the whole
     window (odd table width at block 16) and the int8/fp8 scale operand
     (a ``(1, 1)`` block of ``[NB, Nkv]``) now lower for the TPU platform —
@@ -1335,15 +1396,19 @@ def test_kernel_rules_learned_from_the_v5e_compiler(monkeypatch):
     ok = dict(head_dim=128, block_size=16, cache_dtype=jnp.bfloat16,
               kv_heads=4)
     assert not kernel_eligible(**ok, rows=128, table_width=2048)
-    assert not kernel_eligible(**ok, rows=120, table_width=2048)
+    assert not kernel_eligible(**ok, rows=124, table_width=2048)
+    assert kernel_eligible(**ok, rows=120, table_width=2048)
     assert kernel_eligible(**ok, rows=104, table_width=2048)
     assert not kernel_eligible(**ok, rows=2000, table_width=33)
+    assert kernel_eligible(**ok, rows=1900, table_width=33)
     assert kernel_eligible(**ok, rows=1500, table_width=33)
     assert not kernel_eligible(**ok, rows=4000, table_width=33)
-    # more heads a block, fewer blocks a cell, a longer walk
-    assert not kernel_eligible(**{**ok, "kv_heads": 32}, rows=100,
-                               table_width=2048)
-    assert kernel_eligible(**ok, rows=100, table_width=2048)
+    # the walk is the body's: neither the heads a block nor the store count
+    assert kernel_eligible(**{**ok, "kv_heads": 32}, rows=100,
+                           table_width=2048)
+    assert kernel_eligible(**{**ok, "block_size": 32,
+                              "cache_dtype": jnp.int8},
+                           rows=104, table_width=2048)
 
     S = jax.ShapeDtypeStruct
     B, Nh, Nkv, D, NB, Lp = 4, 28, 4, 128, 64, 3  # G = 7: Qwen2.5-7B's fold
@@ -1826,8 +1891,11 @@ def test_a_selecting_decode_step_finds_its_topk_th_score_without_a_sort(
         assert re.search(r'op_name="[^"]*/(select|indexer)/', ln), ln
     for ln in chosen:
         if "op_name=" not in ln and _elements(ln) >= 9216:
+            # (the positions leave the branch's fast memory by an async copy
+            # since the decode kernel takes them as a lane row a cell)
             assert re.search(
-                r" (parameter|get-tuple-element|bitcast|tuple|copy)\(", ln), ln
+                r" (parameter|get-tuple-element|bitcast|tuple|copy"
+                r"|copy-start|copy-done)\(", ln), ln
 
 
 @pytest.mark.parametrize("cell", ["qwen25_7b", "olmoe_1b_7b"])
@@ -1966,20 +2034,22 @@ def _block_shapes(eqn):
 @pytest.mark.parametrize("store", ["bf16", "int8"])
 @pytest.mark.parametrize("cell", sorted(_CELL_SHAPES))
 def test_decode_kernel_takes_a_blocks_heads_together(cell, store):
-    """The decode kernel's ONE grid at the three cells' shapes, read from
-    the traced ``pallas_call`` (nothing runs): a single axis whose bound is
-    a traced scalar (the call's live cells) — no head axis, no row axis —,
-    five scalar-prefetch operands (layer, table, the frontier, each
-    step's row and cell), and every arena operand block ``(Nkv, BS, D)``
-    wide: all key/value heads of one block of the squeezed layer; an int8
-    arena's scale block is the block's ``Nkv`` scales."""
+    """The decode kernel at the three cells' shapes, read from the traced
+    ``pallas_call`` (nothing runs): ONE invocation — no grid over cells,
+    heads or rows: the walk is a loop in the body —, three scalar-prefetch
+    operands (layer, table, the frontier), both arenas WHOLE and in HBM —
+    no operand a block: the body copies them by hand — and a double-buffered
+    VMEM scratch a cell wide for each, ``(2, bps, Nkv, BS, D)``: all
+    key/value heads of a block in one copy, ``bps`` the shapes'
+    (``decode_blocks_per_cell``); an int8 arena's scales a cell's row in
+    SCALAR memory, the blocks' ``2·Nkv`` side by side."""
     from llm_sharding_tpu.ops import paged_attention as pa
 
     Nh, Nkv = _CELL_SHAPES[cell]
     B, T, BS, D, Lp, NB = 4, 128, 32, 128, 3, 260
     S = jax.ShapeDtypeStruct
-    arena = S((Lp, NB, Nkv, BS, D),
-              jnp.bfloat16 if store == "bf16" else jnp.int8)
+    dt = jnp.bfloat16 if store == "bf16" else jnp.int8
+    arena = S((Lp, NB, Nkv, BS, D), dt)
     scale = S((Lp, NB, Nkv), jnp.float32) if store == "int8" else None
     jaxpr = jax.make_jaxpr(
         lambda q, k, v, l, t, qp, kp, ks, vs: pa.paged_attention_tpu(
@@ -1992,16 +2062,21 @@ def test_decode_kernel_takes_a_blocks_heads_together(cell, store):
     )
     (call,) = _pallas_calls(jaxpr.jaxpr)
     gm = call.params["grid_mapping"]
-    bps = pa.auto_blocks_per_step(T, BS, Nkv)
-    assert len(gm.grid) == 1 and not isinstance(gm.grid[0], int)
-    assert gm.num_index_operands == 5
-    blocks = _block_shapes(call)
-    assert blocks.count((None, 1, Nkv, BS, D)) == 2 * bps  # K and V
-    assert blocks.count((None, 1, Nkv, 1, 1)) == (
-        2 * bps if store == "int8" else 0
-    )
-    # nothing else of the pool reaches the kernel
-    assert not [b for b in blocks if len(b) == 5 and b[2] != Nkv]
+    bps = pa.decode_blocks_per_cell(T, BS, Nkv, 2 * D, dt.dtype.itemsize)
+    assert bps == {4: 16, 8: 8, 16: 4}[Nkv]
+    assert tuple(gm.grid) == (1,)
+    assert gm.num_index_operands == 3
+    # the pool reaches the kernel twice, whole, where it lies
+    pools = [bm.block_aval for bm in gm.block_mappings
+             if len(bm.block_aval.shape) == 5]
+    assert [(a.shape, str(a.memory_space)) for a in pools] == [
+        ((Lp, NB, Nkv, BS, D), "hbm")] * 2
+    scratch = [v.aval for v in call.params["jaxpr"].invars][
+        -gm.num_scratch_operands:]
+    cells = [a.shape for a in scratch if len(a.shape) == 5]
+    assert cells == [(2, bps, Nkv, BS, D)] * 2
+    smem = [a.shape for a in scratch if str(a.memory_space) == "smem"]
+    assert smem == ([(2, 1, bps * 2 * Nkv)] if store == "int8" else [])
 
 
 @pytest.mark.parametrize("weights", ["int8", "bf16"])
@@ -2490,8 +2565,8 @@ def test_a_layer_scan_holds_one_decode_kernel_over_whole_blocks(
 ):
     """The decode programs as a server dispatched them: every layer scan
     that carries the arena holds exactly ONE attention ``pallas_call``,
-    named ``paged_decode``, and its arena operand blocks are ``(Nkv, BS,
-    D)`` wide — a block's key/value heads together, the layer dim squeezed.
+    named ``paged_decode``, and the tiles of its two cell buffers are
+    ``(Nkv, BS, D)`` wide — a block's key/value heads together in one copy.
     Before it, where a step writes one entry a row into a plain arena
     (``serve_chunk`` over bf16), ONE write kernel ``paged_kv_write`` whose
     arena blocks are the sublane tile ``(Nkv, SUB, D)`` that holds the slot;
@@ -2519,9 +2594,10 @@ def test_a_layer_scan_holds_one_decode_kernel_over_whole_blocks(
             ["paged_kv_write"] if writes else []
         )
         assert call.params["name"] == "paged_decode"
-        arena_blocks = [b for b in _block_shapes(call) if len(b) == 5
-                        and b[-1] == D]
-        assert arena_blocks and set(arena_blocks) == {(None, 1, Nkv, BS, D)}
+        scratch = call.params["jaxpr"].invars[
+            -call.params["grid_mapping"].num_scratch_operands:]
+        cells = [v.aval.shape for v in scratch if len(v.aval.shape) == 5]
+        assert len(cells) == 2 and {c[2:] for c in cells} == {(Nkv, BS, D)}
         for w in write:
             tiles = [b for b in _block_shapes(w) if len(b) == 5]
             assert tiles and set(tiles) == {(None, None, Nkv, sub, D)}

@@ -152,12 +152,34 @@ def test_auto_blocks_per_step():
     assert auto_blocks_per_step(64, 64) == 8
     assert auto_blocks_per_step(64, 512) == 1  # tile cap
     assert auto_blocks_per_step(6, 8) == 2
-    # the decode kernel takes a block's key/value heads together: the
-    # step's score tile is bps x block_size x heads lanes wide
-    assert auto_blocks_per_step(128, 32, 4) == 8  # Qwen2.5-7B
-    assert auto_blocks_per_step(128, 32, 8) == 8  # a Qwen2.5-14B stage
-    assert auto_blocks_per_step(128, 32, 16) == 4  # OLMoE-1B-7B
-    assert auto_blocks_per_step(128, 128, 32) == 1
+
+
+@pytest.mark.parametrize("shape, bps", [
+    # (table, block, key/value heads, key + value lanes, bytes an element)
+    ((128, 32, 4, 256, 2), 16),  # Qwen2.5-7B: the score tiles' 2,048 lanes
+    ((288, 32, 4, 256, 2), 16),  # Keye: 32 KiB a tile, 2 MiB the two slots
+    ((128, 32, 8, 256, 2), 8),  # a Qwen2.5-14B stage
+    ((128, 32, 16, 256, 2), 4),  # OLMoE-1B-7B: 128 KiB a tile
+    ((256, 32, 8, 384, 2), 8),  # MiMo's window layers: keys of 256 lanes
+    ((256, 32, 4, 384, 2), 16),  # MiMo's full layers
+    ((128, 32, 1, 640, 2), 16),  # a latent arena: 512 tokens a cell
+    ((128, 32, 4, 256, 1), 16),  # int8: the lanes still bound it
+    ((128, 32, 4, 1024, 4), 4),  # float32 keys of 512 lanes: the bytes do
+    ((128, 128, 32, 256, 2), 1),  # one block is already 4,096 lanes
+    ((33, 16, 4, 256, 2), 1), ((24, 8, 4, 32, 4), 8),  # divides the table
+])
+def test_decode_blocks_per_cell(shape, bps):
+    """The decode kernel's cell is as wide as its K and V tiles, double
+    buffered, its score tiles and 512 tokens allow: a function of the
+    shapes, no operand count in it."""
+    from llm_sharding_tpu.ops.paged_attention import (
+        DECODE_CELL_VMEM, decode_blocks_per_cell,
+    )
+
+    assert decode_blocks_per_cell(*shape) == bps
+    T, BS, Nkv, lanes, size = shape
+    assert T % bps == 0
+    assert 2 * bps * Nkv * BS * lanes * size <= DECODE_CELL_VMEM or bps == 1
 
 
 def test_paged_prefill_backend_validation():

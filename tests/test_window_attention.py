@@ -57,10 +57,20 @@ def rows(rng, lengths, freed):
     return tbl, kvpos
 
 
-@pytest.mark.parametrize("window, use_sink, Dv", [
-    (0, False, 8), (6, False, 16), (0, True, 16), (6, True, 8), (13, True, 8),
+@pytest.mark.parametrize("window, use_sink, Dv, bps", [
+    (0, False, 8, None), (6, False, 16, None), (0, True, 16, None),
+    (6, True, 8, None), (13, True, 8, None),
+    # cells narrower than the table: a windowed row's walk starts at a cell
+    # past the table's first (``first`` 6 of 8, 3 of 4), its first copies
+    # started by the row before it, and the window's edge cuts a cell
+    (6, True, 8, 2), (13, False, 16, 4), (13, True, 8, 2), (0, True, 8, 4),
 ])
-def test_decode_kernel_lower_bound_sink_and_value_width(window, use_sink, Dv):
+def test_decode_kernel_lower_bound_sink_and_value_width(
+        window, use_sink, Dv, bps):
+    """The decode kernel's copies by hand (the table's blocks a shuffle of
+    the pool) under each of the three arguments, alone and together, the
+    whole table one cell (``bps`` None: what the shapes give) and in cells
+    of 2 and 4 blocks."""
     rng = np.random.default_rng(3)
     Nkv, G, D = 2, 2, 16
     Nh = Nkv * G
@@ -76,8 +86,14 @@ def test_decode_kernel_lower_bound_sink_and_value_width(window, use_sink, Dv):
     kw = dict(window=window, sink=None if sink is None else jnp.asarray(sink))
     got = pa.paged_attention_tpu(
         q, k_a, v_a, 1, jnp.asarray(tbl), jnp.asarray(qpos),
-        jnp.asarray(kvpos), 0.25, interpret=True, **kw,
+        jnp.asarray(kvpos), 0.25, interpret=True, blocks_per_step=bps, **kw,
     )
+    if window and bps:
+        first = pa._first_blocks(
+            jnp.asarray(tbl), jnp.asarray(qpos), jnp.asarray(kvpos), window,
+            pa._live_blocks(jnp.asarray(tbl), jnp.asarray(qpos),
+                            jnp.asarray(kvpos)))
+        assert int(first[3]) // bps > 0
     xla = pa.paged_attention_xla(
         q, k_a, v_a, 1, jnp.asarray(tbl), jnp.asarray(qpos),
         jnp.asarray(kvpos), 0.25, **kw,
