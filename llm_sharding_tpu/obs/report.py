@@ -20,8 +20,10 @@ a per-tenant rollup, and ``--trace ID`` to print one trace's tree.
 half: it accepts ``/profilez`` capture bundles (single-server or the dp
 ``{"r<d>": bundle}`` fan-out), ``/debugz`` bundles (their ``recent_steps``
 ring tails) or raw ``StepRecord`` lists, and renders per-phase host
-attribution, host-occupancy-over-time, and the worst device-idle-bubble
-steps — the offline view of ``obs/stepline``.
+attribution, host-occupancy-over-time, the worst device-idle-bubble steps
+and the token's path (emit lag, the device's pace between landings, the
+host-bound share, the starved time's two bounds) — the offline view of
+``obs/stepline``.
 
 Stdlib-only (no numpy/jax): the report runs anywhere the JSONL landed,
 including hosts with no accelerator stack installed.
@@ -34,10 +36,6 @@ from typing import Dict, List, Optional
 
 #: Span names that are per-request tree NODES (own span_id) vs leaf events.
 ROOT_SPANS = ("ingress", "request")
-
-#: Per-step loop spans with no request attribution — excluded from the
-#: per-phase attribution table (they describe the server, not a request).
-LOOP_SPANS = frozenset(("chunk", "apply"))
 
 
 def load_events(paths) -> List[dict]:
@@ -156,7 +154,7 @@ def phase_stats(traces: Dict[str, Trace]) -> List[dict]:
     buckets: Dict[str, List[float]] = {}
     for tr in traces.values():
         for ev in tr.spans:
-            if ev["span"] in LOOP_SPANS or "dur_s" not in ev:
+            if "dur_s" not in ev:
                 continue
             buckets.setdefault(ev["span"], []).append(float(ev["dur_s"]))
     rows = []
@@ -529,6 +527,83 @@ def worst_bubbles(steps, top: int = 5) -> List[dict]:
     return ranked[:top]
 
 
+def token_path(steps) -> Optional[dict]:
+    """The token's path over ``steps`` (timestamp-sorted records, tagged
+    with their source): the emit lag of every log that carried tokens (its landing on
+    the host → the end of the step that applied it) and the last log's split
+    by phase (``after_landing``); the gaps between the landings of decode
+    logs next to each other in the device's queue, both stamped while the
+    host waited — the device's pace; the share of ``chunk``-applying steps
+    that did not wait (host-bound); the starved time's two bounds, over the
+    wall of the steps that held work. The definitions are ``obs/stepline``'s
+    (its docstring), shared with the benchmark's ``path_reduce``. None for
+    records of a build without the stamps."""
+    steps = [s for s in steps if "logs" in s and "end" in s]
+    if not steps:
+        return None
+    lags = [
+        float(s["end"]) - log["landed"] for s in steps for log in s["logs"]
+        if log.get("tokens") and log.get("landed") is not None
+    ]
+    gaps: List[float] = []
+    for src in sorted({str(s.get("src", "-")) for s in steps}):
+        logs = [  # one server's queue: programs are numbered per server
+            (float(s["t0"]), log) for s in steps
+            if str(s.get("src", "-")) == src for log in s["logs"]
+        ]
+        gaps += [
+            (tb + b["landed"]) - (ta + a["landed"])
+            for (ta, a), (tb, b) in zip(logs, logs[1:])
+            if a["kind"] == b["kind"] == "chunk" and b["n"] == a["n"] + 1
+            and a["exact"] and b["exact"]
+        ]
+    decode = [
+        all(log["waited"] for log in s["logs"] if log["kind"] == "chunk")
+        for s in steps if any(log["kind"] == "chunk" for log in s["logs"])
+    ]
+    wall = sum(  # of the steps that held work (obs/stepline's definition)
+        float(s.get("wall_s", 0.0)) for s in steps
+        if s.get("dispatches") or s["logs"] or s.get("rows")
+        or s.get("queued") or s.get("pending")
+    )
+    buckets: Dict[str, List[float]] = {}
+    for s in steps:
+        for name, dur in (s.get("after_landing") or {}).items():
+            buckets.setdefault(name, []).append(float(dur))
+    lag_total = sum(sum(v) for v in buckets.values()) or 1.0
+    after = [
+        {
+            "phase": name,
+            "count": len(vals),
+            "p50_ms": _pctile(vals, 0.50) * 1e3,
+            "p95_ms": _pctile(vals, 0.95) * 1e3,
+            "total_s": sum(vals),
+            "lag_pct": 100.0 * sum(vals) / lag_total,
+        }
+        for name, vals in buckets.items()
+    ]
+    after.sort(key=lambda r: -r["total_s"])
+    lo = sum(float(s.get("idle_s", 0.0)) for s in steps)
+    hi = sum(float(s.get("starved_hi_s", 0.0)) for s in steps)
+    return {
+        "token_logs": len(lags),
+        "emit_lag_p50_ms": _pctile(lags, 0.50) * 1e3,
+        "emit_lag_p95_ms": _pctile(lags, 0.95) * 1e3,
+        "landing_gaps": len(gaps),
+        "landing_gap_p50_ms": _pctile(gaps, 0.50) * 1e3,
+        "landing_gap_p95_ms": _pctile(gaps, 0.95) * 1e3,
+        "decode_steps": len(decode),
+        "host_bound_frac": (
+            decode.count(False) / len(decode) if decode else 0.0
+        ),
+        "starved_lo_s": lo,
+        "starved_hi_s": hi,
+        "starved_lo_frac": lo / wall if wall > 0 else 0.0,
+        "starved_hi_frac": hi / wall if wall > 0 else 0.0,
+        "after_landing": after,
+    }
+
+
 def render_step_report(steps, top: int = 5) -> str:
     """The step-report text: summary, per-phase attribution, occupancy
     over time, worst bubbles."""
@@ -564,6 +639,34 @@ def render_step_report(steps, top: int = 5) -> str:
                 f"  [{i:>3}] occ={b['occupancy']:.3f} "
                 f"rows<={b['rows_max']:<4} |{bar:<40}|"
             )
+    path = token_path(steps)
+    if path is not None:
+        lines += [
+            "",
+            f"token's path ({path['token_logs']} log(s) with tokens, "
+            f"{path['landing_gaps']} landing gap(s), "
+            f"{path['decode_steps']} decode step(s)):",
+            f"  emit lag (landing -> step end) "
+            f"p50={path['emit_lag_p50_ms']:.3f}ms "
+            f"p95={path['emit_lag_p95_ms']:.3f}ms",
+            f"  landing gap (device's pace)    "
+            f"p50={path['landing_gap_p50_ms']:.3f}ms "
+            f"p95={path['landing_gap_p95_ms']:.3f}ms",
+            f"  host_bound={path['host_bound_frac']:.3f}  "
+            f"device_starved lo={path['starved_lo_frac']:.4f} "
+            f"hi={path['starved_hi_frac']:.4f} of the wall with work "
+            f"({path['starved_lo_s'] * 1e3:.2f} / "
+            f"{path['starved_hi_s'] * 1e3:.2f} ms)",
+            "  after the last log's landing, by phase:",
+            f"    {'phase':<14} {'count':>7} {'p50_ms':>9} {'p95_ms':>9} "
+            f"{'total_s':>9} {'lag%':>7}",
+        ]
+        for r in path["after_landing"]:
+            lines.append(
+                f"    {r['phase']:<14} {r['count']:>7} {r['p50_ms']:>9.3f} "
+                f"{r['p95_ms']:>9.3f} {r['total_s']:>9.4f} "
+                f"{r['lag_pct']:>6.1f}%"
+            )
     bubbles = worst_bubbles(steps, top)
     if bubbles:
         lines += ["", f"top {len(bubbles)} device-idle bubble step(s):"]
@@ -584,4 +687,5 @@ def step_report_json(steps, top: int = 5) -> dict:
         "phases": step_phase_table(steps),
         "timeline": occupancy_timeline(steps),
         "worst_bubbles": worst_bubbles(steps, top),
+        "token_path": token_path(steps),
     }

@@ -9,10 +9,20 @@ models play for placement:
   ``table_push`` / ``dispatch`` / ``fetch`` / ``apply`` / ``gauge_sweep``
   — finer than the old three-bucket histogram), time *blocked on device*
   (the log-fetch materialization wait, measured separately from host
-  compute), the estimated device-idle bubble, rows in flight, tokens
-  applied, and queue depths.
-- Derived gauges feed continuously: ``server_host_occupancy``,
-  ``server_device_idle_frac``, ``server_step_wall_seconds``.
+  compute), the device's starved time, rows in flight, tokens applied, and
+  queue depths.
+- The record also carries the token's path on the host's clock: every
+  program the step handed the device (``dispatches``: when it joined the
+  queue, the depth found, the starved time that enqueue ended) and every
+  log it applied (``logs``: when its program was enqueued and by which
+  step, when it landed, whether the host had to wait for it, when its
+  tokens were on the requests), plus ``after_landing`` — the stretch from
+  the last log's landing to the step's end, split by phase.
+- Derived series feed continuously: ``server_host_occupancy``,
+  ``server_device_idle_frac``, ``server_step_wall_seconds``,
+  ``server_token_emit_lag_seconds``,
+  ``server_device_starved_seconds_total``,
+  ``server_steps_host_bound_total``.
 - Lock-wait accounting rides the :func:`~..analysis.lockorder.named_lock`
   factory's opt-in timed mode (``STEPLINE_LOCK_TIMING=1``); this module
   installs the process-wide sink that observes
@@ -29,13 +39,39 @@ phase it interrupts, so ``sum(phases) + blocked_s + unattributed_s ==
 wall_s`` exactly, with ``unattributed_s`` (inter-phase gaps: autosnapshot,
 metric observes) expected under 5% of wall on the CPU smoke serve.
 
-The builder API (``begin_step``/``push``/``pop``/``blocked``/``idle``/
-``end_step``) is single-threaded by construction — only the step pump calls
-it — so builder state is unlocked; only the ring itself takes a lock
-(``obs.stepline.ring``), and gauge/histogram feeds happen outside it. The
-device-idle estimate (``idle``) keys off the NEWEST in-flight chunk's
-``done_at``: if it has already landed before the next dispatch, the device
-queue truly drained and the gap is a bubble.
+The builder API (``begin_step``/``push``/``pop``/``blocked``/
+``dispatched``/``log_landed``/``log_applied``/``end_step``) is
+single-threaded by construction — only the step pump calls it — so builder
+state is unlocked; only the ring itself takes a lock
+(``obs.stepline.ring``), and gauge/histogram feeds happen outside it.
+
+Starved time (``dispatched``) is counted where a program joins the device's
+queue: if nothing enqueued before it is still un-landed, the device had
+nothing to run from the previous program's landing to this enqueue. A
+landing the host waited for is known to the moment; one a poll FOUND lies
+between the last poll that saw the device busy and the poll that found it
+done, so every bubble is a bracket: ``starved_lo_s`` from the found stamp
+(``idle_s``, the lower bound), ``starved_hi_s`` from the last busy one.
+Only time in which the server held work counts: a step that ENDS with no
+live rows and no queue closes the account (logs still out then belong to
+rows that are done), and the next bubble starts no earlier than the begin of
+the next step — the one that first sees work again — so a client's think
+time between two requests is never starved time, whether or not its caller
+goes on stepping an empty server.
+
+One definition for every reader of these records (the series below,
+``obs/report.token_path``, the benchmark's ``path_reduce``): a step is
+HOST-BOUND if it applied a ``chunk`` log it did not have to wait for (a
+``verify``'s step drains its own program and always waits); the starved
+SHARE is the starved time over the wall of the steps that held work — those
+that dispatched, applied, or ended with rows, queue or logs.
+
+A step has two ends. ``wall_s`` is read first and closes the invariant
+above; ``end`` is read last, after the record is built and the series are
+fed, and is as near as the profiler gets to ``step()`` returning — when a
+stream's reader sees the tokens. A log's emit lag is ``end - landed``;
+``after_landing`` splits the last log's by phase, and what ``end_step``
+itself costs falls into its ``unattributed``.
 
 Profiler annotations: the same phase stack also writes into the JAX
 profiler's trace, on the profiler's clock, through an injected ``annotate``
@@ -167,9 +203,26 @@ HOST_OCCUPANCY = REGISTRY.gauge(
 )
 DEVICE_IDLE_FRAC = REGISTRY.gauge(
     "server_device_idle_frac",
-    "Estimated device-idle bubble per step: time between the newest "
-    "in-flight chunk's log landing on host and the next dispatch, as a "
-    "fraction of step wall (most recent step of any live server)",
+    "Device-starved share of the most recent step's wall (any live "
+    "server): the lower bound of server_device_starved_seconds_total, "
+    "per step",
+)
+EMIT_LAG = REGISTRY.histogram(
+    "server_token_emit_lag_seconds",
+    "From the landing on host of a log that carried tokens to the end of "
+    "the step that applied it: what the host adds to a token's gap",
+)
+DEVICE_STARVED = REGISTRY.counter(
+    "server_device_starved_seconds_total",
+    "Time the device had nothing queued while the server held work, "
+    "counted at each enqueue from the previous program's landing: lo from "
+    "the stamp that found it landed, hi from the last poll that saw it busy",
+    labels=("bound",),
+)
+STEPS_HOST_BOUND = REGISTRY.counter(
+    "server_steps_host_bound_total",
+    "Steps that applied a decode chunk's log the device had finished before "
+    "the host came for it (no wait); beside server_step_wall_seconds' count",
 )
 LOCK_WAIT = REGISTRY.histogram(
     "server_lock_wait_seconds",
@@ -184,6 +237,18 @@ LOCK_WAIT = REGISTRY.histogram(
 # profiler's hot path, and the label space is closed over PHASES — no
 # reason to pay the family lock + label lookup on every step.
 _PHASE_CHILD = {p: STEP_PHASE.labels(phase=p) for p in PHASES}
+_STARVED_LO = DEVICE_STARVED.labels(bound="lo")
+_STARVED_HI = DEVICE_STARVED.labels(bound="hi")
+_EMIT_LAG = EMIT_LAG.labels()
+_HOST_BOUND = STEPS_HOST_BOUND.labels()
+
+_LOG_KEYS = ("n", "kind", "by", "enq", "landed", "exact", "waited",
+             "applied", "tokens")
+_DISPATCH_KEYS = ("n", "kind", "enq", "in_flight", "starved_lo_s",
+                  "starved_hi_s")
+_LANDED, _APPLIED, _TOKENS = (
+    _LOG_KEYS.index(k) for k in ("landed", "applied", "tokens")
+)
 
 
 def _lock_wait_sink(name: str, dt: float) -> None:
@@ -220,7 +285,8 @@ class StepRecord:
         "decode_blocks_live", "decode_blocks_reserved",
         "prefill_cells_live", "prefill_cells_walked", "kv_kinds",
         "prefill_kv_blocks", "decode_kv_entries", "recurrent_rows",
-        "scan_positions", "sparse_tokens",
+        "scan_positions", "sparse_tokens", "seq", "t0", "end",
+        "starved_hi_s", "logs", "dispatches", "after_landing",
     )
 
     def __init__(self, ts, wall_s, phases, blocked_s, idle_s,
@@ -295,6 +361,20 @@ class StepRecord:
         # tokens whose K/V blocks the attention streamed}`` (host arithmetic
         # at dispatch, from the length mirrors)
         self.sparse_tokens = None
+        # the token's path (module docstring). ``seq`` is the number the
+        # step's ``serve.step`` annotation carries as ``step_num``; ``t0``
+        # the clock at its begin; every other time an offset from ``t0``.
+        # ``idle_s`` is the lower bound of the step's starved time,
+        # ``starved_hi_s`` the upper. ``logs`` / ``dispatches`` hold one
+        # sequence per applied log / enqueued program, in the order of
+        # ``_LOG_KEYS`` / ``_DISPATCH_KEYS``
+        self.seq = 0
+        self.t0 = 0.0
+        self.end = wall_s
+        self.starved_hi_s = idle_s
+        self.logs = ()
+        self.dispatches = ()
+        self.after_landing = None
 
     @property
     def host_s(self) -> float:
@@ -324,7 +404,17 @@ class StepRecord:
             "prefill_cells_walked": self.prefill_cells_walked,
             "queued": self.queued,
             "pending": self.pending,
+            "seq": self.seq,
+            "t0": self.t0,
+            "end": self.end,
+            "starved_hi_s": self.starved_hi_s,
+            "logs": [dict(zip(_LOG_KEYS, log)) for log in self.logs],
+            "dispatches": [
+                dict(zip(_DISPATCH_KEYS, d)) for d in self.dispatches
+            ],
         }
+        if self.after_landing is not None:
+            d["after_landing"] = dict(self.after_landing)
         if self.kv_kinds is not None:
             d["kv_kinds"] = {k: dict(v) for k, v in self.kv_kinds.items()}
         if self.prefill_kv_blocks is not None:
@@ -374,6 +464,8 @@ class StepProfiler:
         self._ring_mu = lockorder.named_lock("obs.stepline.ring")
         self._enabled = True
         self.steps_total = 0
+        #: number of the step now open (``serve.step``'s ``step_num``)
+        self.seq = 0
         # builder state (step-pump thread only; unlocked by design)
         self._t0: Optional[float] = None
         self._step_armed = False
@@ -395,6 +487,17 @@ class StepProfiler:
         self._phases: Dict[str, float] = {}
         self._blocked_s = 0.0
         self._idle_s = 0.0
+        self._starved_hi_s = 0.0
+        self._logs: List[list] = []
+        self._dispatches: List[tuple] = []
+        # (landed_at, the phase totals at that moment): the last landing an
+        # enqueue's poll found in this step, and that of the last log applied
+        self._found: tuple = (None, None)
+        self._landing: Optional[tuple] = None
+        self._host_bound = False
+        # the clock at the begin of the first step after the last idle one:
+        # no starved time is counted before it
+        self._work_from: Optional[float] = None
         self._segments: Optional[List[tuple]] = None
         self._exemplars: Optional[List[str]] = None
         self._lock_base: Optional[Dict[str, tuple]] = None
@@ -486,10 +589,19 @@ class StepProfiler:
             self._exit(self._step_span)
             self._step_span = None
         self._t0 = self._clock()
+        self.seq = self.steps_total
+        if self._work_from is None:
+            self._work_from = self._t0
         self._stack = []
         self._phases = {}
         self._blocked_s = 0.0
         self._idle_s = 0.0
+        self._starved_hi_s = 0.0
+        self._logs = []
+        self._dispatches = []
+        self._found = (None, None)
+        self._landing = None
+        self._host_bound = False
         self._prompt_tokens = 0
         self._prefill_positions = 0
         self._experts = None
@@ -504,7 +616,7 @@ class StepProfiler:
         work = bool(rows or queued or pending)
         if self._annotate is not None and (work or self._had_work):
             self._step_span = self._annotate(
-                STEP_ANNOTATION, step_num=self.steps_total, rows=int(rows),
+                STEP_ANNOTATION, step_num=self.seq, rows=int(rows),
                 queued=int(queued), pending=int(pending),
             )
             self._step_span.__enter__()
@@ -703,12 +815,83 @@ class StepProfiler:
             acc[2] += int(steps)
             acc[3] += int(rows)
 
-    def idle(self, dt: float) -> None:
-        """Account an estimated device-idle bubble (log landed on host at
-        T, next dispatch at T+dt). Host time, not excluded from phases."""
-        if not self._enabled or self._t0 is None or dt <= 0.0:
+    def dispatched(self, kind: str, n: int, enq_at: float, in_flight: int,
+                   landed_at: Optional[float] = None,
+                   busy_seen_at: Optional[float] = None) -> None:
+        """Program number ``n`` joined the device's queue at ``enq_at``
+        behind ``in_flight`` programs not known to have landed. With none,
+        the device had nothing to run since the previous program landed:
+        between ``busy_seen_at`` (the last poll that saw it busy) and
+        ``landed_at`` (the stamp that found it done; the same moment where
+        the host waited for it). ``landed_at=None``: nothing ran before.
+        Host time, not excluded from phases."""
+        if not self._enabled or self._t0 is None:
             return
-        self._idle_s += dt
+        lo = hi = 0.0
+        if in_flight == 0 and landed_at is not None:
+            if busy_seen_at is None:
+                busy_seen_at = landed_at
+            lo = max(0.0, enq_at - max(landed_at, self._work_from))
+            hi = max(lo, enq_at - max(busy_seen_at, self._work_from))
+            self._idle_s += lo
+            self._starved_hi_s += hi
+            if landed_at > self._t0 and landed_at != self._found[0]:
+                # the caller's poll has just found that landing: the phase
+                # totals as they stand here are those ``after_landing``
+                # starts from, should this step apply the log
+                self._found = (landed_at, self._phase_totals(self._clock()))
+        self._dispatches.append(
+            (n, kind, enq_at - self._t0, int(in_flight), lo, hi)
+        )
+
+    def _phase_totals(self, now: float) -> Dict[str, float]:
+        """The step's phase totals as they stand at ``now``, the open
+        phases' time so far included (a parent's less its open child's)."""
+        totals = dict(self._phases)
+        inner = 0.0
+        for name, start, excluded, _ in reversed(self._stack):
+            elapsed = now - start
+            totals[name] = totals.get(name, 0.0) + max(
+                0.0, elapsed - excluded - inner
+            )
+            inner = elapsed
+        return totals
+
+    def log_landed(self, kind: str, n: int, by: int, enq_at: float,
+                   landed_at: Optional[float], exact: bool,
+                   waited: bool) -> None:
+        """The step holds the log of program ``n`` (enqueued at ``enq_at``
+        by step ``by``) and is about to apply it. ``landed_at`` is when it
+        reached the host (None: the read failed); ``exact`` whether the
+        host was waiting for it at that moment or a poll found it;
+        ``waited`` whether this step had to block for it."""
+        if not self._enabled or self._t0 is None:
+            return
+        if landed_at is not None:
+            if landed_at <= self._t0:  # an earlier step's poll found it
+                at_landing: Dict[str, float] = {}
+            elif landed_at == self._found[0]:
+                at_landing = self._found[1]
+            else:  # this step's drain did, just now
+                at_landing = self._phase_totals(self._clock())
+            self._landing = (landed_at, at_landing)
+        if not waited and kind == "chunk":
+            self._host_bound = True
+        t0 = self._t0
+        self._logs.append([
+            n, kind, by, enq_at - t0,
+            None if landed_at is None else landed_at - t0,
+            bool(exact), bool(waited), None, 0,
+        ])
+
+    def log_applied(self, tokens: int) -> None:
+        """The newest ``log_landed`` log's tokens (``tokens`` of them) are
+        on their requests: a stream's reader can see them from now."""
+        if not self._enabled or self._t0 is None or not self._logs:
+            return
+        log = self._logs[-1]
+        log[_APPLIED] = self._clock() - self._t0
+        log[_TOKENS] = int(tokens)
 
     def note_exemplar(self, trace_id: str) -> None:
         """Record an applied row's trace_id — deep-capture steps only."""
@@ -722,7 +905,8 @@ class StepProfiler:
             return None
         while self._stack:  # unbalanced push (exception paths): close out
             self.pop()
-        wall = max(self._clock() - self._t0, 0.0)
+        t0 = self._t0
+        wall = max(self._clock() - t0, 0.0)
         self._t0 = None
         self._exit(self._step_span)
         self._step_span = None
@@ -758,6 +942,40 @@ class StepProfiler:
         if self._experts is not None:
             tokens, read, rec.expert_steps, rec.expert_rows = self._experts
             rec.expert_tokens, rec.experts_read = tokens, read or []
+        rec.seq, rec.t0 = self.seq, t0
+        rec.starved_hi_s = self._starved_hi_s
+        rec.logs, rec.dispatches = self._logs, self._dispatches
+        if not (rows or queued):
+            self._work_from = None  # no work held: the device owes nothing
+        # metric feeds OUTSIDE the ring lock (family locks rank below it,
+        # but obs never needs to nest — keep the ring hold minimal), and
+        # BEFORE the step's last look at the clock: what they cost lies
+        # between a log's landing and its tokens' stamp, and is accounted
+        for name, dur in phases.items():
+            _PHASE_CHILD[name].observe(dur)
+        STEP_WALL.observe(wall)
+        if wall > 0:
+            HOST_OCCUPANCY.set(min(1.0, host / wall))
+            DEVICE_IDLE_FRAC.set(min(1.0, self._idle_s / wall))
+        if self._starved_hi_s > 0.0:
+            _STARVED_LO.inc(self._idle_s)
+            _STARVED_HI.inc(self._starved_hi_s)
+        if self._host_bound:
+            _HOST_BOUND.inc()
+        after = None
+        if self._landing is not None:
+            landed_at, at_landing = self._landing
+            after = {
+                name: dur - at_landing.get(name, 0.0)
+                for name, dur in phases.items()
+                if dur > at_landing.get(name, 0.0)
+            }
+            in_phases = sum(after.values())
+        end_at = self._clock()
+        rec.end = end_at - t0
+        if after is not None:
+            after["unattributed"] = (end_at - landed_at) - in_phases
+            rec.after_landing = after
         with self._ring_mu:
             if len(self._ring) < self._ring_size:
                 self._ring.append(rec)
@@ -765,14 +983,9 @@ class StepProfiler:
                 self._ring[self._ring_next] = rec
                 self._ring_next = (self._ring_next + 1) % self._ring_size
             self.steps_total += 1
-        # metric feeds OUTSIDE the ring lock (family locks rank below it,
-        # but obs never needs to nest — keep the ring hold minimal)
-        for name, dur in phases.items():
-            _PHASE_CHILD[name].observe(dur)
-        STEP_WALL.observe(wall)
-        if wall > 0:
-            HOST_OCCUPANCY.set(min(1.0, host / wall))
-            DEVICE_IDLE_FRAC.set(min(1.0, self._idle_s / wall))
+        for log in self._logs:
+            if log[_TOKENS] and log[_LANDED] is not None:
+                _EMIT_LAG.observe(rec.end - log[_LANDED])
         if self._step_armed and self._armed_left > 0:
             self._capture.append(rec)
             self._armed_left -= 1

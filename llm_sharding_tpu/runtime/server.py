@@ -480,20 +480,31 @@ class _Prefetched:
     read: a log handed from a fetching thread to the step reached it 0.17 ms
     after it landed (sd 0.04: an event's wake and the interpreter lock's,
     each a futex under a sandboxed kernel) — at the moment a stream's reader
-    is waiting for the token (PERF.md §6, PR 39)."""
+    is waiting for the token (PERF.md §6, PR 39).
 
-    __slots__ = ("handle", "value", "error", "tag", "done_at")
+    It is also the program's stamp card on ``time.perf_counter()``: made
+    right after the jitted call returned, so ``enq_at`` is when the program
+    that writes the log joined the device's queue; ``done_at`` when the log
+    reached the host. That is known to the moment only when the thread
+    waited for it (``exact``); a landing that ``landed`` FOUND lies between
+    ``busy_seen_at`` — the last poll that still saw the device busy, or the
+    enqueue — and ``done_at``."""
 
-    def __init__(self, handle, tag: str = "?"):
+    __slots__ = ("handle", "value", "error", "tag", "kind", "by", "n",
+                 "enq_at", "done_at", "exact", "busy_seen_at")
+
+    def __init__(self, handle, tag: str = "?", kind: str = "chunk",
+                 by: int = 0, n: int = 0):
+        self.enq_at = self.busy_seen_at = time.perf_counter()
         self.handle = handle
         self.tag = tag  # what this read belongs to ("chunk m0=…", "admit …")
+        self.kind = kind  # chunk | admit | verify (the others have no log)
+        self.by = by  # number of the step that dispatched the program
+        self.n = n  # the program's number in this server's queue
         self.value = None
         self.error: Optional[BaseException] = None
-        # perf_counter stamp of when the value landed on host — the step
-        # profiler's device-idle estimate (log ready vs next dispatch): known
-        # to the moment only when the thread waited; otherwise it is when
-        # ``landed`` first found the device done
         self.done_at: Optional[float] = None
+        self.exact = True
         begin = getattr(handle, "copy_to_host_async", None)
         if begin is not None:
             begin()
@@ -510,6 +521,8 @@ class _Prefetched:
         else:
             self.handle = None  # drop the device reference promptly
             self.done_at = time.perf_counter()
+            if self.exact:
+                self.busy_seen_at = self.done_at
 
     def landed(self) -> bool:
         """Has the value (or its failure) reached the host? Never waits for
@@ -517,11 +530,14 @@ class _Prefetched:
         if self.value is None and self.error is None:
             ready = getattr(self.handle, "is_ready", None)
             if ready is None or ready():
+                self.exact = False
                 self.read()
+            else:
+                self.busy_seen_at = time.perf_counter()
         return self.value is not None or self.error is not None
 
     def wait(self) -> None:
-        if self.value is None and self.error is None:
+        if not self.landed():
             self.read()
 
     def get(self) -> np.ndarray:
@@ -593,11 +609,11 @@ def _watch_next_log(landed) -> bool:
             armed.pop().__dict__.pop("_fetch", None)
 
     def shadow(srv):
-        def fetch(handle, tag: str) -> _Prefetched:
+        def fetch(handle, tag: str, kind: str) -> _Prefetched:
             if threading.get_ident() != me:
-                return PipelineServer._fetch(srv, handle, tag)
+                return PipelineServer._fetch(srv, handle, tag, kind)
             disarm()
-            log = _FirstLog(handle, tag)
+            log = PipelineServer._fetch(srv, handle, tag, kind, _FirstLog)
             log.on_landed = landed
             return log
         return fetch
@@ -1544,6 +1560,12 @@ class PipelineServer:
         self.stepline = StepProfiler(
             name="server", annotate=_profiler_annotation
         )
+        # the device's queue as the host knows it (``_enqueued``): programs
+        # handed over so far, the newest log among them, and the programs
+        # without a log of their own enqueued since
+        self._programs = 0
+        self._last_log: Optional[_Prefetched] = None
+        self._logless = 0
         # gauge_sweep_every_s paces the per-step load/KV/attn gauge sweep:
         # 0.0 (default) sweeps every step; at 64+ rows the sweep's row scan
         # is real per-step host work (visible as the profiler's gauge_sweep
@@ -2425,8 +2447,10 @@ class PipelineServer:
         dispatch|fetch|apply|gauge_sweep}``, device-blocked wait, and the
         derived ``server_host_occupancy`` / ``server_device_idle_frac``
         gauges — note the dispatch figure is HOST dispatch time (the chunk
-        executes async on device); with ``trace_path=`` the coarse phases
-        also land as JSONL spans. Inside a ``jax.profiler`` session the
+        executes async on device). The record also carries the token's
+        path — each program's enqueue, each log's landing and application,
+        the device's starved time (``obs/stepline``). Inside a
+        ``jax.profiler`` session the
         same phase stack writes ``serve.step`` / ``serve.<phase>`` /
         ``serve.blocked`` / ``serve.prefill`` annotations for every step
         that began with work (README "Step profiling").
@@ -2470,7 +2494,6 @@ class PipelineServer:
                 self._spec_step()
                 sl.pop()
                 progressed = True
-                t0 = time.perf_counter()
                 applied = self._drain(0)  # next drafts need these commits
             elif self._any_active():
                 self._dispatch_chunk()
@@ -2486,22 +2509,12 @@ class PipelineServer:
                 self._settle_counts()
                 sl.pop()
                 swept = self._sweep_gauges_if_due()
-                t0 = time.perf_counter()
                 applied = self._drain(self.pipeline_depth, park_counts=True)
             else:
-                t0 = time.perf_counter()
                 applied = self._drain(0)
                 self._settle_counts()  # nothing in flight: the series are whole
-            dt_apply = time.perf_counter() - t0
-            if progressed or applied:
-                # span emission is real per-step host work (the flight
-                # recorder ring write) — attribute it to the apply phase
-                # it reports on instead of leaving it unattributed
-                sl.push("apply")
-                self._span("apply", dur_s=dt_apply, applied=applied)
-                sl.pop()
-                if not swept:
-                    self._sweep_gauges_if_due()
+            if (progressed or applied) and not swept:
+                self._sweep_gauges_if_due()
             if self._radix is not None and self._queue:
                 # stage the NEXT admission's radix plan now, AFTER this
                 # step's decode dispatch: a host-tier restore it triggers
@@ -2530,8 +2543,10 @@ class PipelineServer:
                 self._set_health(SERVING)
             if self._pending:
                 # a look at the newest log as the step ends: a device that
-                # is already done is idle from here (at least) until the
-                # next dispatch, which counts it (_dispatch_chunk)
+                # is already done is starved from here (at least) until the
+                # next enqueue, which counts it (_enqueued) — the stamp the
+                # bracket's lower bound starts from; one still busy moves
+                # the upper bound's start up to here
                 self._pending[-1][1].landed()
             rows, queued, pending = self._held()
             sl.end_step(
@@ -2563,9 +2578,43 @@ class PipelineServer:
         PREFILL_POSITIONS.labels(kind="pad").inc(positions - prompt_tokens)
         return self.stepline.prefill(rows, prompt_tokens, positions)
 
-    def _fetch(self, handle, tag: str) -> _Prefetched:
-        """Begin the device→host read of a step's log."""
-        return _Prefetched(handle, tag)
+    def _fetch(self, handle, tag: str, kind: str,
+               cls=_Prefetched) -> _Prefetched:
+        """Begin the device→host read of the log of the program just
+        dispatched (``kind``: chunk | admit | verify), which is on the
+        device's queue from here."""
+        log = cls(handle, tag, kind, self.stepline.seq, self._programs)
+        self._enqueued(kind, log.enq_at)
+        self._last_log = log
+        self._logless = 0  # the device works in order: this log covers them
+        return log
+
+    def _enqueued(self, kind: str, at: Optional[float] = None) -> None:
+        """A program joined the device's queue at ``at`` (now: a program
+        with no log of its own — ``prefill_chunk``, or ``arm``, the slot's
+        arming that closes a chunked admission). The
+        ONE place starved time is counted (``StepProfiler.dispatched``):
+        where nothing enqueued before is still un-landed, the device had
+        nothing to run since the newest log's landing."""
+        logless = at is None
+        if logless:
+            at = time.perf_counter()
+        # still out: the programs with no log since the newest log, and the
+        # un-applied logs no poll has seen land (polled newest first: the
+        # device works in order, an older one can only have landed sooner)
+        in_flight = self._logless
+        for entry in reversed(self._pending):
+            if entry[1].landed():
+                break
+            in_flight += 1
+        last = self._last_log
+        self.stepline.dispatched(
+            kind, self._programs, at, in_flight,
+            None if last is None else last.done_at,
+            None if last is None else last.busy_seen_at,
+        )
+        self._programs += 1
+        self._logless += logless
 
     def _sweep_gauges_if_due(self) -> bool:
         """The step's paced gauge sweep (``gauge_sweep_every_s``); True if
@@ -2586,17 +2635,6 @@ class PipelineServer:
         """Dispatch one interleaved decode chunk, retrying transient
         dispatch failures; a persistent failure is contained (the rows this
         chunk was driving fail, the daemon survives)."""
-        t0 = time.perf_counter()
-        if self._pending:
-            # device-idle estimate: the newest in-flight chunk is the last
-            # work the device was given — if its log has already landed on
-            # host (done_at stamped), the device has been draining/idle
-            # since then, and this dispatch ends the bubble. The step reads
-            # its own logs (_Prefetched) and knows that moment as the last
-            # step's end found it: its estimate is a lower bound
-            newest = self._pending[-1][1]
-            if newest.landed() and newest.done_at is not None:
-                self.stepline.idle(t0 - newest.done_at)
         self.stepline.push("dispatch")
         cycles = self.num_stages  # one ring cycle: a token a live row
         # the dispatched static, not attn_impl: dense servers compile the
@@ -2645,7 +2683,7 @@ class PipelineServer:
             CP_COMBINE_SECONDS.observe(time.perf_counter() - t_dispatch)
         self._pending.append(
             ("chunk",
-             self._fetch(log, tag=f"chunk m0={self._m}"),
+             self._fetch(log, f"chunk m0={self._m}", "chunk"),
              self._m)
         )
         self._record_blocks_read(
@@ -2654,8 +2692,6 @@ class PipelineServer:
             served=len(self._rows), steps=1,
         )
         self.stepline.pop()
-        dt_dispatch = time.perf_counter() - t0
-        self._span("chunk", dur_s=dt_dispatch, m0=self._m, cycles=cycles)
         self._m += cycles
         self.counters.inc("chunks")
 
@@ -4503,8 +4539,9 @@ class PipelineServer:
                         "admit",
                         self._fetch(
                             tok0,
-                            tag=f"admit slot={slot} "
-                                f"ids={[r.id for r in batch]}",
+                            f"admit slot={slot} "
+                            f"ids={[r.id for r in batch]}",
+                            "admit",
                         ),
                         [(r.row, r) for r in batch],
                     )
@@ -4681,6 +4718,7 @@ class PipelineServer:
                 )
                 self.state, counts = chunk_out
                 self._chunk_lazy.append(counts)
+            self._enqueued("prefill_chunk")
             # interleave only when some OTHER request is mid-decode — the
             # admitting rows themselves are in _rows already and must not
             # count, or an idle server would pay a useless cycle per chunk
@@ -4712,7 +4750,7 @@ class PipelineServer:
                 )
                 self._pending.append(
                     ("chunk",
-                     self._fetch(log, tag=f"chunk m0={self._m}"),
+                     self._fetch(log, f"chunk m0={self._m}", "chunk"),
                      self._m)
                 )
                 self._m += self.num_stages
@@ -4753,6 +4791,7 @@ class PipelineServer:
                 cp=self.cp,
                 block_size=self.kv_block_size or 0,
             )
+        self._enqueued("arm")
         self._admitting_rows.difference_update(range(row0, row0 + Bs))
 
     def _spec_step(self) -> None:
@@ -4845,7 +4884,7 @@ class PipelineServer:
             self._pending.append(
                 (
                     "spec",
-                    self._fetch(log, tag=f"verify slot={slot}"),
+                    self._fetch(log, f"verify slot={slot}", "verify"),
                     [
                         (row, req, int(draft_len[row - slot * Bs]),
                          draft[row - slot * Bs].copy())
@@ -4912,15 +4951,23 @@ class PipelineServer:
         while len(self._pending) > max_pending:
             entry = self._pending.popleft()
             applied += 1
-            if not entry[1].landed():
+            log = entry[1]
+            waited = not log.landed()
+            if waited:
                 # blocked on device: the log hasn't materialized on host
                 # yet. The wait is measured SEPARATELY from host compute
                 # (the profiler's blocked_s — excluded from the fetch
                 # phase — and its serve.blocked annotation); the retryable
                 # get below then returns instantly.
                 with sl.blocking():
-                    entry[1].wait()
+                    log.wait()
+            sl.log_landed(
+                log.kind, log.n, log.by, log.enq_at, log.done_at, log.exact,
+                waited,
+            )
+            tok0 = self.counters.tokens_generated
             self._apply_entry(entry, park_counts)
+            sl.log_applied(self.counters.tokens_generated - tok0)
         sl.pop()
         return applied
 

@@ -216,7 +216,8 @@ def test_stats_occupancy_math():
         p.push("dispatch")
         clk.t += work
         p.pop()
-        p.idle(0.1)
+        # the device's queue was empty for the 0.1 before this enqueue
+        p.dispatched("chunk", 0, clk.t, 0, clk.t - 0.1, clk.t - 0.1)
         clk.t += wall - work
         p.end_step()
     st = p.stats()
