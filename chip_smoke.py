@@ -57,6 +57,17 @@ bit for bit, and the DEVICE time of one layer call of each from a profiler
 trace, with the operations it is made of. The last line, also left in
 ``chiprun_out/kv_decode.json``: ``{"ok": true, "kv_decode": [...], "device":
 {...}}``.
+``--index-scores`` likewise: a selecting decode step's SCORE call alone
+(``ops/paged_attention.index_scores_tpu``) at Keye's shape (16 index heads,
+128 lanes, block 32, table 288, four rows of which one live) at 2.5 / 5 /
+8.7 k of context: DEVICE microseconds a layer call from a profiler trace,
+nanoseconds a token of context, the share of its bytes' roofline, the same
+at every cell width the tree's kernel takes, and a hash of the scores of
+the attendable columns — the same seed gives the same hash on every tree
+whose kernel scores a column as this one does. The last line, also left in
+``chiprun_out/index_scores.json``: ``{"ok": true, "index_scores": [...],
+"device": {...}}``; exit 1 where the kernel's scores are not the XLA
+branch's.
 ``--ssm`` likewise: a decode step's state update of a recurrent-state model
 (``ops/ssm.ssm_step_rows``) at Nemotron-3-Super's published mixer shape (128
 heads of 64, a state of 128, 8 groups; 8 layers x 4 rows of float32 state
@@ -1058,6 +1069,170 @@ def child_kv_decode(spec: dict, out_path: str) -> None:
         )
 
 
+#: a selecting decode step's score call at Keye-VL-2.0-30B-A3B's shape: the
+#: slot's four rows of which one is live, 12 selecting layers' index arena
+INDEX_SHAPE = {"name": "keye_vl2_30b_a3b", "rows": 4, "live": 1, "heads": 16,
+               "lanes": 128, "block_size": 32, "table": 288, "blocks": 2305,
+               "layers": 12, "contexts": (2560, 5120, 8704)}
+#: blocks a cell the sweep tries beside the rule's own choice (None)
+INDEX_WIDTHS = (None, 8, 16, 32, 48, 64, 96, 144)
+#: one v5e chip's HBM bytes a second (Google Cloud documentation, "TPU v5e")
+HBM_BYTES_PER_S = 819e9
+
+
+def index_inputs(shape: dict, context: int, table: int, seed: int = 0):
+    """What the score call of one layer takes: the first ``live`` rows
+    ``context`` tokens long, the query at the last of them, the others dead
+    (table all trash, position at the sentinel); block 0 holds ``inf``."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    from llm_sharding_tpu.models.cache import POS_SENTINEL
+
+    B, live, BS = shape["rows"], shape["live"], shape["block_size"]
+    L, NB, Hi, lanes = (shape[k] for k in ("layers", "blocks", "heads",
+                                           "lanes"))
+    own = -(-context // BS)
+    rng = np.random.default_rng(seed)
+    tbl = np.zeros((B, table), np.int32)
+    tbl[:live, :own] = (
+        1 + rng.permutation(NB - 1)[: live * own].reshape(live, own)
+    )
+    kvpos = np.full((B, table * BS), POS_SENTINEL, np.int32)
+    kvpos[:live, :context] = np.arange(context)
+    qpos = np.where(np.arange(B)[:, None] < live, context - 1, POS_SENTINEL)
+    ks = jax.random.split(jax.random.key(seed), 3)
+    arena = jax.random.normal(ks[0], (L, NB, 1, BS, lanes), jnp.bfloat16)
+    return dict(
+        qi=jax.random.normal(ks[1], (B, Hi, lanes), jnp.bfloat16),
+        wi=jax.random.uniform(ks[2], (B, Hi), jnp.float32, 0.5, 1.5),
+        arena=arena.at[:, 0].set(jnp.inf),
+        table=jnp.asarray(tbl), qpos=jnp.asarray(qpos.astype(np.int32)),
+        kvpos=jnp.asarray(kvpos),
+    )
+
+
+def _index_program(shape: dict, width, interpret: bool = False):
+    """Every layer's score call once, ``[L, B, W]``: ``width`` blocks a cell
+    (None: the kernel's own rule), or the XLA branch (``"xla"``)."""
+    import jax
+    import jax.numpy as jnp
+    import llm_sharding_tpu.models  # noqa: F401 — ops import through models
+    from llm_sharding_tpu.ops import paged_attention as pa
+
+    kw = {} if width is None else {"blocks_per_cell": width}
+    if interpret:
+        kw["interpret"] = True
+
+    @jax.jit
+    def run(qi, wi, arena, table, qpos, kvpos):
+        ok = pa._attendable(table, qpos, kvpos, shape["block_size"])
+
+        def one(_, layer):
+            if width == "xla":
+                sel = pa.Selection(qi[:, None], wi[:, None], arena, 0)
+                return None, pa.index_scores(
+                    sel, layer, table, qpos, kvpos, ok)[:, 0]
+            score = pa.index_scores_tpu(
+                qi, wi, arena, layer, table, qpos, kvpos, **kw)
+            return None, jnp.where(ok[:, 0], score, -jnp.inf)
+        return jax.lax.scan(
+            one, None, jnp.arange(shape["layers"], dtype=jnp.int32))[1]
+
+    return lambda inp: run(*(inp[k] for k in (
+        "qi", "wi", "arena", "table", "qpos", "kvpos")))
+
+
+def index_widths() -> list:
+    """The cell widths this tree's score kernel can be asked for: its own
+    (None) alone where the width is not the caller's to give."""
+    import inspect
+    from llm_sharding_tpu.ops import paged_attention as pa
+
+    fn = inspect.unwrap(pa.index_scores_tpu)
+    if "blocks_per_cell" not in inspect.signature(fn).parameters:
+        return [None]
+    return list(INDEX_WIDTHS)
+
+
+def time_index_scores(shape: dict, context: int, width, want: dict,
+                      runs: int = 8, interpret: bool = False) -> dict:
+    """DEVICE microseconds of ONE layer's score call from a profiler trace
+    (``time_kv_decode`` says why a trace): the kernel alone and with what
+    XLA puts around it, and a hash of the attendable columns' scores. A
+    width that does not divide the table gets a table padded up to it;
+    ``want`` keeps the XLA branch's scores by ``(context, table)``."""
+    import numpy as np
+    import jax
+
+    table = shape["table"]
+    if width is not None:
+        table = -(-table // width) * width
+    inp = index_inputs(shape, context, table)
+    call = _index_program(shape, width, interpret)
+    scores = np.asarray(jax.block_until_ready(call(inp)))  # compiles
+    trace_dir = os.path.join(WORK, "trace_index_scores")
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    jax.profiler.start_trace(trace_dir)
+    for _ in range(runs):
+        out = call(inp)
+    jax.block_until_ready(out)
+    jax.profiler.stop_trace()
+    calls = runs * shape["layers"]
+    ops = sorted(device_op_us(trace_dir).items(), key=lambda kv: -kv[1])
+    kernel = sum(u for n, u in ops if n.startswith("index_scores")) / calls
+    if (context, table) not in want:
+        want[context, table] = np.asarray(_index_program(shape, "xla")(inp))
+    want = want[context, table]
+    seen = want > -np.inf  # the attendable columns
+    return {
+        "kernel_us": round(kernel, 2),
+        "us_per_layer_call": round(sum(u for _, u in ops) / calls, 2),
+        "ops_us": [[n, round(u / calls, 2)] for n, u in ops[:6]],
+        "max_err": float(np.max(np.abs(scores[seen] - want[seen]))),
+        "masked_same": bool(np.array_equal(scores > -np.inf, seen)),
+        "hash": "%08x" % zlib.crc32(scores[seen].tobytes()),
+    }
+
+
+def child_index_scores(spec: dict, out_path: str) -> None:
+    import jax
+
+    from llm_sharding_tpu.utils.compile_cache import enable_persistent_cache
+    from llm_sharding_tpu.utils.device_report import device_report
+
+    platform = jax.devices()[0].platform
+    require_tpu(platform, "the index score call")
+    enable_persistent_cache(platform)
+    shape, results, want = INDEX_SHAPE, [], {}
+    live = shape["live"]
+    for width in index_widths():
+        walk = []
+        for context in shape["contexts"]:
+            got = time_index_scores(shape, context, width, want)
+            # what the call must read: the live rows' index keys
+            floor_us = 1e6 * live * context * shape["lanes"] * 2 / (
+                HBM_BYTES_PER_S)
+            got["roofline_pct"] = round(100 * floor_us / got["kernel_us"], 1)
+            results.append({"shape": shape["name"], "width": width,
+                            "context": context, **got})
+            walk.append((context, got["kernel_us"]))
+            print(f"[index-scores] width {width} at {context}: {got}",
+                  flush=True)
+        results[-1]["walk_ns_per_token"] = walk_slope(walk, live)
+        print(f"[index-scores] width {width}: {walk} (context, us a layer "
+              f"call): {results[-1]['walk_ns_per_token']} ns a token of "
+              "context", flush=True)
+    with open(out_path, "w") as f:
+        json.dump({"device": device_report(), "index_scores": results}, f)
+    # bf16 products summed in float32: the two paths differ in the order
+    if not all(r["masked_same"] and r["max_err"] <= KERNEL_TOL
+               for r in results):
+        raise SystemExit(
+            "chip_smoke: the score kernel differs from the XLA branch"
+        )
+
+
 #: the one-step state update at Nemotron-3-Super-120B-A12B's published mixer
 #: shape, carried as the cell carries it: 8 mixer layers x 4 rows of float32
 SSM_SHAPE = {"layers": 8, "rows": 4, "heads": 128, "head_dim": 64,
@@ -1763,6 +1938,10 @@ def main(argv=None) -> int:
                     help="only check and time a decode step's K/V write "
                          "with the attention it feeds (ops/paged_attention."
                          "py): the one fused op beside the scatter pair")
+    ap.add_argument("--index-scores", action="store_true",
+                    help="only check and time a selecting decode step's "
+                         "score call (ops/paged_attention.index_scores_tpu) "
+                         "at Keye's shape, at every cell width it takes")
     ap.add_argument("--ssm", action="store_true",
                     help="only check and time a decode step's state update "
                          "(ops/ssm.py) at Nemotron-3-Super's mixer shape, the "
@@ -1771,7 +1950,7 @@ def main(argv=None) -> int:
                          "Jamba2-3B's")
     ap.add_argument("--child",
                     choices=("kernels", "store", "moe", "kv_write",
-                             "kv_decode", "ssm"))
+                             "kv_decode", "index_scores", "ssm"))
     ap.add_argument("--spec")
     ap.add_argument("--out")
     args = ap.parse_args(argv)
@@ -1780,10 +1959,11 @@ def main(argv=None) -> int:
         {"kernels": child_kernels, "store": child_store,
          "moe": child_moe, "kv_write": child_kv_write,
          "kv_decode": child_kv_decode,
+         "index_scores": child_index_scores,
          "ssm": child_ssm}[args.child](spec, args.out)
         return 0
     # one check, its line left behind too
-    for mode in ("ssm", "kv_write", "kv_decode"):
+    for mode in ("ssm", "kv_write", "kv_decode", "index_scores"):
         if getattr(args, mode):
             os.makedirs(WORK, exist_ok=True)
             got = wait_child(run_child(

@@ -158,8 +158,8 @@ def forced_backend() -> str | None:
 
 def auto_blocks_per_step(t_blocks: int, block_size: int) -> int:
     """Auto-selected KV blocks batched per sequential grid step of the
-    kernels that take a block as a ``BlockSpec`` operand (the chunked
-    prefill, the index scores; one head of a block a ref): the largest of
+    kernel that takes a block as a ``BlockSpec`` operand (the chunked
+    prefill; one head of a block a ref): the largest of
     8/4/2/1 that divides the table width and keeps a step's keys — the
     lanes of its score tile — at or under 512 tokens. At small serving
     block sizes one arena block is a skinny tile that underfeeds the MXU
@@ -167,8 +167,9 @@ def auto_blocks_per_step(t_blocks: int, block_size: int) -> int:
     (~50 ns a ref a step on a v5e) per block; batching ``bps`` blocks per
     step gives the compiler ``bps`` independent in-flight DMAs
     (double-buffered across steps) and dots that do not wait on each
-    other. The decode kernel issues its own copies and is not held to
-    eight operands: ``decode_blocks_per_cell``."""
+    other. The decode kernel and the score kernel issue their own copies
+    and are not held to eight operands: ``decode_blocks_per_cell``,
+    ``index_blocks_per_cell``."""
     for bps in (8, 4, 2, 1):
         if t_blocks % bps == 0 and bps * block_size <= 512:
             return bps
@@ -218,6 +219,44 @@ def decode_blocks_per_cell(
     ):
         bps *= 2
     return bps
+
+
+#: What a cell of the score kernel's walk may hold (``index_blocks_per_cell``):
+#: index keys in ONE slot of its double buffer, and copies — a copy of an 8 KiB
+#: block takes ~19 ns to issue whatever it moves, and 96 a cell was the best
+#: width swept at Keye's shape (PERF.md, PR 56).
+INDEX_CELL_VMEM = 1 << 20
+INDEX_CELL_BLOCKS = 96
+
+
+def index_blocks_per_cell(
+    t_blocks: int, block_size: int, lanes: int, itemsize: int,
+) -> int:
+    """Blocks a cell of the score kernel's walk (``index_scores_tpu``), from
+    the shapes alone: the most that divide the table width, stay within
+    ``INDEX_CELL_BLOCKS`` copies and ``INDEX_CELL_VMEM`` of index keys a slot
+    (``block_size`` x ``lanes`` x ``itemsize`` bytes a block: one head, no
+    values) and make a cell's scores whole 128-lane tiles (a cell stores
+    them at its own lane offset of the call's one ``[B, T·BS]`` output); the
+    whole table where no width does (one cell, at offset 0).
+
+    Not the decode kernel's rule. An index block is 8 KiB at Keye's shape —
+    10 ns of HBM time under ~19 ns of issue, a trash copy past a row's
+    frontier as much — and a cell costs ~0.27 us whatever its width: the
+    walk is bound by the count of its copies and of its cells, not by its
+    bytes. So wide cells, until the half cell of trash copies a row pays on
+    average outweighs the cells saved: Keye's 8.7 k of context walk in 14.1
+    / 10.1 / 8.8 / 8.2 / 7.2 us at 8 / 16 / 32 / 48 / 96 blocks a cell; 144
+    take 6.5 there and 7.1 at 5.1 k where 96 take 4.8 (swept on the chip:
+    PERF.md, PR 56)."""
+    cap = min(
+        INDEX_CELL_BLOCKS, INDEX_CELL_VMEM // (block_size * lanes * itemsize)
+    )
+    whole = [
+        d for d in range(1, min(cap, t_blocks) + 1)
+        if t_blocks % d == 0 and d * block_size % 128 == 0
+    ]
+    return max(whole, default=t_blocks)
 
 
 def kernel_sublane(cache_dtype) -> int:
@@ -748,8 +787,8 @@ def _live_blocks(block_table, q_positions, kv_positions):
 
 def _end_to_end(nent, bps, width):
     """Runs of ``nent[r]`` table entries laid end to end in cells of ``bps``
-    (a kernel's walk over a grid: the score kernel's rows, the prefill
-    kernel's runs; the decode kernel walks in its body and needs none):
+    (a kernel's walk over a grid: the prefill kernel's runs; the decode
+    kernel and the score kernel walk in their bodies and need none):
     ``start[r]`` the grid step of run ``r``'s cell 0, ``owner[i]`` the run
     grid step ``i`` walks, ``ends`` the running sum of the runs' cells
     (``ends[-1]`` = the grid's length). Steps past the cells' sum never run,
@@ -2123,84 +2162,159 @@ def _attendable(block_table, q_positions, kv_positions, block_size):
 
 
 def _index_kernel(
-    layer_ref,  # scalar-prefetch [1] — read by the index maps only
-    tbl_ref,  # scalar-prefetch [B, T] (index maps + the trash gate)
+    layer_ref,  # scalar-prefetch [1] — the layer of the stack the copies read
+    tbl_ref,  # scalar-prefetch [B, T] (the copies' block ids + the trash gate)
     nlive_ref,  # scalar-prefetch [B] — the row's frontier (_live_blocks)
-    start_ref,  # scalar-prefetch [B] — the grid step of the row's cell 0
-    row_ref,  # scalar-prefetch [B·T/bps + 1] — the row grid step i walks
-    qi_ref,  # [1, Hi, lanes] the row's index queries
-    wi_ref,  # [1, Hi, 1] f32 a weight an index head
-    *rest,  # bps index-key refs [1, 1, BS, lanes]; out [1, 1, 1, bps·BS] f32
+    qi_ref,  # [B, Hi, lanes] every row's index queries
+    wi_ref,  # [B, Hi, 1] f32 a weight an index head
+    arena,  # the index arena where it lies in HBM, [L, NB, 1, BS, lanes]
+    out_ref,  # [B, T·BS] f32: every row's scores by logical column
+    buf,  # scratch: a cell's index keys, TWO slots, [2, bps·BS, lanes]
+    sem,  # a DMA semaphore a slot
     bps,
 ):
-    k_refs, out_ref = rest[:bps], rest[bps]
-    i = pl.program_id(0)
-    b = row_ref[i]
-    t = i - start_ref[b]
-    tiles = []
-    for j in range(bps):
-        k = k_refs[j][0, 0]  # [BS, lanes]
-        idx = t * bps + j
-        # a trash block's garbage may be non-finite: it scores as zeros (and
-        # its columns are masked by position outside)
-        live = (idx < nlive_ref[b]) & (tbl_ref[b, idx] != 0)
-        tiles.append(jnp.where(live, k, jnp.zeros_like(k)))
-    s = jax.lax.dot_general(
-        qi_ref[0], jnp.concatenate(tiles, axis=0), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # [Hi, bps·BS]
-    out_ref[0, 0] = jnp.sum(
-        wi_ref[0] * jnp.maximum(s, 0.0), axis=0, keepdims=True
+    B = qi_ref.shape[0]
+    BS = arena.shape[3]
+    W = bps * BS  # a cell's columns
+
+    def cells(b):
+        """Row ``b``'s walk: the table cells ``0 <= c < cells(b)``."""
+        return (nlive_ref[b] + bps - 1) // bps
+
+    def next_live(b):
+        """The first row at or after ``b`` with a cell to walk; ``B``: none."""
+        def dead(r):
+            return (r < B) & (cells(jnp.minimum(r, B - 1)) <= 0)
+        return jax.lax.while_loop(dead, lambda r: r + 1, b)
+
+    def copies(slot, b=0, c=0, fetch=True):
+        """The async copies that bring cell ``c`` of row ``b`` into ``slot``:
+        a block's one contiguous ``(BS, lanes)`` tile at ``(layer, table[b,
+        idx])``, read where it lies. An entry past the frontier inside the
+        frontier's cell names the trash block. ``fetch=False``: the same
+        copies to WAIT on (a wait reads its copy's size and semaphore, not
+        its source: no table reads)."""
+        out = []
+        for j in range(bps):
+            idx = c * bps + j
+            at = (layer_ref[0], jnp.where(
+                idx < nlive_ref[b], tbl_ref[b, idx], 0
+            )) if fetch else (0, 0)
+            out.append(pltpu.make_async_copy(
+                arena.at[(*at, 0)], buf.at[slot, pl.ds(j * BS, BS)],
+                sem.at[slot],
+            ))
+        return out
+
+    # a cell the walk never visits reads zeros (masked by position outside)
+    out_ref[...] = jnp.zeros_like(out_ref)
+
+    def cell(carry):
+        """One cell of the walk: the rows' live cells end to end, the next
+        cell's copies in flight (the other slot) while this one is scored."""
+        b, c, slot = carry
+        last = c + 1 >= cells(b)  # the row's frontier cell
+        nb = next_live(jnp.where(last, b + 1, b))
+        nc = jnp.where(last, 0, c + 1)
+
+        @pl.when(nb < B)
+        def _prefetch():
+            for cp in copies(1 - slot, nb, nc):
+                cp.start()
+
+        for cp in copies(slot, fetch=False):
+            cp.wait()
+
+        tiles = []
+        for j in range(bps):
+            k = buf[slot, j * BS:(j + 1) * BS]  # [BS, lanes]
+            idx = c * bps + j
+            # a trash block's garbage may be non-finite: it scores as zeros
+            # (and its columns are masked by position outside). where(), not
+            # multiply — Inf * 0 is itself NaN.
+            live = (idx < nlive_ref[b]) & (tbl_ref[b, idx] != 0)
+            tiles.append(jnp.where(live, k, jnp.zeros_like(k)))
+        # ONE dot a cell, a column a dot product of its own: a column's score
+        # does not depend on how wide the shapes make a cell
+        s = jax.lax.dot_general(
+            qi_ref[b], jnp.concatenate(tiles, axis=0),
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        )  # [Hi, bps·BS]
+        # a cell's columns start on a lane tile (``index_blocks_per_cell``);
+        # a table of one cell has no other cell to start anywhere
+        col = 0 if W == out_ref.shape[1] else (
+            pl.multiple_of(c * W, 128) if W % 128 == 0 else c * W
+        )
+        out_ref[pl.ds(b, 1), pl.ds(col, W)] = jnp.sum(
+            wi_ref[b] * jnp.maximum(s, 0.0), axis=0, keepdims=True
+        )
+        return nb, nc, 1 - slot
+
+    b0 = next_live(jnp.int32(0))
+
+    @pl.when(b0 < B)
+    def _first():
+        for cp in copies(0, b0, 0):
+            cp.start()
+
+    jax.lax.while_loop(
+        lambda carry: carry[0] < B, cell, (b0, jnp.int32(0), jnp.int32(0)),
     )
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "blocks_per_cell"))
 def index_scores_tpu(qi, wi, idx_arena, layer, block_table, q_positions,
-                     kv_positions, interpret: bool = False):
-    """The decode kernel's walk as it was until PR 54, over the INDEX arena
-    (a block a ``BlockSpec`` operand): one grid axis over the
-    rows' live cells (``bps`` blocks of one row: ``_live_blocks``,
-    ``_end_to_end``), a cell's index keys scored against the row's ``Hi``
-    index queries in one dot, ``relu``, weighted and summed over the index
-    heads. ``qi [B, Hi, lanes]`` (padded to the stored key's lanes), ``wi [B,
-    Hi]`` f32 → ``[B, T·BS]`` f32 scores by logical column; a cell the walk
-    did not visit is UNWRITTEN (the caller masks by position)."""
+                     kv_positions, interpret: bool = False,
+                     blocks_per_cell: int | None = None):
+    """The decode kernel's walk (``paged_attention_tpu``) over the INDEX
+    arena: ONE invocation walks the rows' live cells end to end in a loop of
+    its body (``_live_blocks`` gives each row's frontier; a dead row costs a
+    compare), a cell being ``bps`` consecutive table entries of one row
+    (``blocks_per_cell``, ``index_blocks_per_cell`` of the shapes when None).
+    The arena rides in ONCE, where it lies in HBM, and the body fetches a
+    cell's blocks BY HAND — one async copy a block, the contiguous ``(BS,
+    lanes)`` tile at ``(layer, table[b, idx])`` — into one of two VMEM
+    slots, the next cell's copies started before this one is waited on and
+    scored: against the row's ``Hi`` index queries in one dot, ``relu``,
+    weighted and summed over the index heads. A block was a ``BlockSpec``
+    operand of a grid over the cells until PR 56: eight refs' pipeline
+    bookkeeping a grid step for 64 KiB (PERF.md, PR 56). ``qi [B, Hi,
+    lanes]`` (padded to the stored key's lanes), ``wi [B, Hi]`` f32 → ``[B,
+    T·BS]`` f32 scores by logical column, held in VMEM whole for the call
+    (147 KiB at Keye's ``[4, 9216]``; a cell stores its columns at its own
+    lane offset) and written back once, in the layout the search reads; a
+    cell the walk did not visit reads ZEROS (the caller masks by
+    position)."""
     B, Hi, lanes = qi.shape
     BS = idx_arena.shape[3]
     T = block_table.shape[1]
-    bps = auto_blocks_per_step(T, BS)
+    bps = blocks_per_cell or index_blocks_per_cell(
+        T, BS, lanes, idx_arena.dtype.itemsize
+    )
+    if T % bps != 0:
+        raise ValueError(
+            f"blocks_per_cell={bps} does not divide the table width {T}"
+        )
     nlive = _live_blocks(block_table, q_positions, kv_positions)
-    start, row_of, ends = _end_to_end(nlive, bps, T // bps)
 
-    def cell(i, st, row):
-        return jnp.minimum(i - st[row[i]], T // bps - 1)
+    def whole(shape):
+        return pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape))
 
-    def arena_index(i, lyr, tbl, nl, st, row, *, j):
-        b = row[i]
-        idx = cell(i, st, row) * bps + j
-        return (lyr[0], jnp.where(idx < nl[b], tbl[b, idx], 0), 0, 0, 0)
-
-    def of_row(i, lyr, tbl, nl, st, row):
-        return (row[i], 0, 0)
-
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_index_kernel, bps=bps),
-        out_shape=jax.ShapeDtypeStruct((B, T // bps, 1, bps * BS), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, T * BS), jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
-            grid=(jnp.maximum(ends[-1], 1),),
+            num_scalar_prefetch=3,
+            grid=(1,),
             in_specs=[
-                pl.BlockSpec((1, Hi, lanes), of_row),
-                pl.BlockSpec((1, Hi, 1), of_row),
-                *[pl.BlockSpec((None, 1, 1, BS, lanes),
-                               functools.partial(arena_index, j=j))
-                  for j in range(bps)],
+                whole((B, Hi, lanes)), whole((B, Hi, 1)),
+                pl.BlockSpec(memory_space=pltpu.HBM),
             ],
-            out_specs=pl.BlockSpec(
-                (1, 1, 1, bps * BS),
-                lambda i, lyr, tbl, nl, st, row: (
-                    row[i], cell(i, st, row), 0, 0),
-            ),
+            out_specs=whole((B, T * BS)),
+            scratch_shapes=[
+                pltpu.VMEM((2, bps * BS, lanes), idx_arena.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
         ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
@@ -2208,10 +2322,9 @@ def index_scores_tpu(qi, wi, idx_arena, layer, block_table, q_positions,
         interpret=interpret,
         name="index_scores",
     )(
-        _layer_operand(layer), block_table, nlive, start, row_of, qi,
-        wi.astype(jnp.float32)[..., None], *([idx_arena] * bps),
+        _layer_operand(layer), block_table, nlive, qi,
+        wi.astype(jnp.float32)[..., None], idx_arena,
     )
-    return out.reshape(B, T * BS)
 
 
 @jax.named_scope("indexer")

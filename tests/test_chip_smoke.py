@@ -233,6 +233,67 @@ def test_kv_decode_shapes_are_the_cells():
     assert chip_smoke.walk_slope([(2560, 40.0), (8704, 138.3)], 4) == 4.0
 
 
+#: ``--index-scores``' shape at toy widths: two live rows of four, a table of
+#: 24 blocks of 8 tokens, four index heads
+INDEX_TOY = {"name": "toy", "rows": 4, "live": 2, "heads": 4, "lanes": 128,
+             "block_size": 8, "table": 24, "blocks": 97, "layers": 2}
+
+
+@pytest.mark.parametrize("width", [None, 1, 8, 16])
+def test_index_scores_probe_at_toy_widths(width, monkeypatch, tmp_path):
+    """``chip_smoke.py --index-scores`` at toy widths, the kernel in
+    interpret mode: at the kernel's own cell width, at one and eight blocks a
+    cell and at one that does not divide the table (16: the table padded to
+    32) the scores are the XLA branch's on every attendable column, nothing
+    else is attendable (block 0 holds ``inf``), the hash is the same at every
+    width, and the traced timing runs end to end — a CPU trace holds no TPU
+    plane, so it reads nothing, and no speed."""
+    monkeypatch.setattr(chip_smoke, "WORK", str(tmp_path))
+    want = {}
+    got = chip_smoke.time_index_scores(
+        INDEX_TOY, 150, width, want, runs=1, interpret=True)
+    own = chip_smoke.time_index_scores(
+        INDEX_TOY, 150, None, want, runs=1, interpret=True)
+    assert sorted(want) == sorted({(150, 24), (150, 32 if width == 16 else 24)})
+    assert got["masked_same"] and got["max_err"] <= 1e-3
+    assert got["hash"] == own["hash"]
+    assert (got["kernel_us"], got["ops_us"]) == (0.0, [])
+
+
+def test_index_shape_is_the_cell():
+    """The shape ``--index-scores`` times is Keye's as its configuration
+    holds it — the indexer's heads, its stored key's lanes, the cell's table,
+    pool and depth, the slot's four rows of which one decodes — at the
+    contexts ``--kv-decode`` walks, and the widths it sweeps include the one
+    the kernel's rule takes there."""
+    import json
+    import os
+
+    from llm_sharding_tpu.ops.paged_attention import index_blocks_per_cell
+
+    shape = chip_smoke.INDEX_SHAPE
+    with open(os.path.join(chip_smoke.HERE, "benchmark", "configs",
+                           shape["name"] + ".json")) as f:
+        cfg = json.load(f)
+    serve, sa = cfg["serve"], cfg["sa_config"]
+    assert (shape["heads"], shape["rows"], shape["block_size"],
+            shape["blocks"], shape["layers"]) == (
+        sa["indexer_num_heads"], serve["batch_per_slot"],
+        serve["kv_block_size"], serve["kv_blocks"], cfg["num_hidden_layers"])
+    assert shape["lanes"] == -(-sa["indexer_head_dim"] // 128) * 128
+    assert shape["table"] == serve["capacity"] // serve["kv_block_size"]
+    assert shape["live"] == 1  # one closed-loop client
+    assert shape["contexts"] == chip_smoke.KV_DECODE_SHAPES[-1]["contexts"]
+    assert min(shape["contexts"]) > sa["topk"]  # the selection engages
+    assert index_blocks_per_cell(
+        shape["table"], shape["block_size"], shape["lanes"], 2
+    ) in chip_smoke.INDEX_WIDTHS
+    assert chip_smoke.INDEX_WIDTHS[0] is None
+    # the parent's readings (PERF.md, PR 56): 2.43 ns a token of context
+    assert chip_smoke.walk_slope(
+        [(2560, 7.43), (5120, 13.64), (8704, 22.33)], 1) == 2.43
+
+
 def test_store_writer_driver_and_assertions_on_cpu(tmp_path, monkeypatch):
     """The smoke's daemon phase end to end at toy size: seeded store through
     the product's writer, the real ``serve`` daemon as a child, the smoke's

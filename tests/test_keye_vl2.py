@@ -290,6 +290,8 @@ def _selecting_case(case):
         ctx = [10, 50]
     elif case == "stale keys in a freed block and in the trash block":
         ctx, poison = [44, 50], True  # 44: four stale columns in the last block
+    elif case == "non-finite index keys in the trash block":
+        ctx, poison = [44, 50], "nan"
     elif case == "the query's own token not among the chosen":
         qi, ki = np.abs(qi), np.abs(ki)
         for b in range(2):
@@ -309,21 +311,19 @@ SELECTING = [
     "no ties", "many scores tied at 0 across the topk-th place",
     "a dead row beside a live one", "a row under topk beside a row past it",
     "stale keys in a freed block and in the trash block",
+    "non-finite index keys in the trash block",
     "the query's own token not among the chosen",
     "the chosen in two blocks, the other blocks whole unchosen",
 ]
 
 
-@pytest.mark.parametrize("case", SELECTING)
-@pytest.mark.parametrize("backend", ["xla", "interpret"])
-def test_a_selecting_decode_step_attends_the_list_select_tokens_gives(
-        backend, case):
-    """``selected_attention`` — the mask as key positions, through
-    ``paged_attention`` — against a plain softmax over the LIST
-    ``select_tokens`` makes of scores computed here in numpy: the same set
-    (ties at the ``topk``-th score go to the lowest columns), nothing of a
-    column outside it, whatever a block the row does not own holds."""
-    ctx, q_pos, qi, wi, ki, poison = _selecting_case(case)
+def _selecting_arenas(case):
+    """A case's logical ``k``, ``v`` ``[B, W, Nkv, D]`` and queries ``[B, 1,
+    Nh, D]``, and layer 1 of the three arenas holding them (and the case's
+    index keys) through the table: a row's blocks in order, the rest of its
+    table the trash block; every slot nothing wrote holds the case's poison
+    (the trash block's index keys ``inf`` and ``nan`` where it is "nan")."""
+    ctx, _, _, _, ki, poison = _selecting_case(case)
     rng = np.random.default_rng(7)
     k = rng.standard_normal((2, _W, _NKV, _D)).astype(np.float32)
     v = rng.standard_normal((2, _W, _NKV, _D)).astype(np.float32)
@@ -333,6 +333,8 @@ def test_a_selecting_decode_step_attends_the_list_select_tokens_gives(
     ka = np.full((L, NB, _NKV, BS, _D), fill, np.float32)
     va = np.full((L, NB, _NKV, BS, _D), fill, np.float32)
     ia = np.full((L, NB, 1, BS, 128), fill, np.float32)
+    if poison == "nan":
+        ia[:, 0, :, ::2], ia[:, 0, :, 1::2] = np.inf, np.nan
     table = np.zeros((2, T), np.int32)
     kv_pos = np.full((2, _W), POS_SENTINEL, np.int32)
     for b in range(2):
@@ -344,6 +346,57 @@ def test_a_selecting_decode_step_attends_the_list_select_tokens_gives(
             ka[1, blk, :, slot], va[1, blk, :, slot] = k[b, c], v[b, c]
             ia[1, blk, 0, slot] = 0.0
             ia[1, blk, 0, slot, :_DI] = ki[b, c]
+    return k, v, q, ka, va, ia, table, kv_pos
+
+
+@pytest.mark.parametrize("width", [1, 2, 8, 16])
+@pytest.mark.parametrize("case", SELECTING)
+def test_the_score_kernels_scores_choose_the_list_at_every_cell_width(
+        case, width):
+    """The score kernel (``index_scores_tpu``, interpret mode) over a case's
+    index arena at one, two, eight and sixteen blocks a cell — sixteen, eight,
+    two cells and one a row: its scores on the attendable columns are the
+    ones computed here in numpy, and ``select_mask`` over them keeps the very
+    columns ``select_tokens`` lists of the numpy scores (ties at the
+    ``topk``-th score included: a ``relu``'s zeros are exact on every
+    path)."""
+    ctx, q_pos, qi, wi, ki, _ = _selecting_case(case)
+    *_, ia, table, kv_pos = _selecting_arenas(case)
+    table, kv_pos = jnp.asarray(table), jnp.asarray(kv_pos)
+    q_pos = jnp.asarray(q_pos, jnp.int32)[:, None]
+    raw = pa.index_scores_tpu(
+        jnp.pad(jnp.asarray(qi), [(0, 0), (0, 0), (0, 128 - _DI)]),
+        jnp.asarray(wi), jnp.asarray(ia), 1, table, q_pos, kv_pos,
+        interpret=True, blocks_per_cell=width)
+    assert np.isfinite(np.asarray(raw)).all()
+    ok = pa._attendable(table, q_pos, kv_pos, BS)[:, 0]
+    keep = np.asarray(pa.select_mask(jnp.where(ok, raw, -jnp.inf), _TOPK))
+    for b in range(2):
+        score = np.full(_W, -np.inf, np.float32)
+        score[:ctx[b]] = np.einsum(
+            "h,hw->w", wi[b], np.maximum(qi[b] @ ki[b, :ctx[b]].T, 0.0))
+        np.testing.assert_allclose(
+            np.asarray(raw)[b, :ctx[b]], score[:ctx[b]], rtol=1e-6, atol=TOL)
+        assert not np.asarray(ok)[b, ctx[b]:].any()
+        cols, real = (np.asarray(a) for a in pa.select_tokens(
+            jnp.asarray(score), _TOPK))
+        if case == "no ties" or case.startswith("many scores tied"):
+            assert sorted(np.flatnonzero(keep[b])) == sorted(cols[real])
+        else:  # the sums' order may move a score by a digit: the count
+            assert keep[b].sum() == real.sum()
+
+
+@pytest.mark.parametrize("case", SELECTING)
+@pytest.mark.parametrize("backend", ["xla", "interpret"])
+def test_a_selecting_decode_step_attends_the_list_select_tokens_gives(
+        backend, case):
+    """``selected_attention`` — the mask as key positions, through
+    ``paged_attention`` — against a plain softmax over the LIST
+    ``select_tokens`` makes of scores computed here in numpy: the same set
+    (ties at the ``topk``-th score go to the lowest columns), nothing of a
+    column outside it, whatever a block the row does not own holds."""
+    ctx, q_pos, qi, wi, ki, _ = _selecting_case(case)
+    k, v, q, ka, va, ia, table, kv_pos = _selecting_arenas(case)
     select = pa.Selection(
         jnp.asarray(qi)[:, None], jnp.asarray(wi)[:, None], jnp.asarray(ia),
         _TOPK)

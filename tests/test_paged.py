@@ -1825,6 +1825,15 @@ def test_a_selecting_decode_step_reads_k_and_v_through_a_kernel_only(
     assert len(decode) == 1 and "/cond/" not in decode[0], kernels
     scores = [k for k in kernels if k.endswith("index_scores/pallas_call")]
     assert len(scores) == 1 and "/cond/branch_1_fun/" in scores[0], kernels
+    # and the score kernel takes the layer-stacked index arena WHOLE, once
+    # (PR 56: it copies a block by hand; a block was an operand, the arena
+    # eight times over)
+    (call,) = [
+        ln for ln in text.split("\n")
+        if "tpu_custom_call" in ln and "index_scores/pallas_call" in ln]
+    operands = call.split("operand_layout_constraints=")[1].split("}}")[0]
+    assert re.findall(r"\w+\[(?:\d+,){4}\d+\]", operands) == [
+        "bf16[12,2305,1,32,128]"], operands
 
 
 def _called_from(text, root):
@@ -1871,7 +1880,8 @@ def test_a_selecting_decode_step_finds_its_topk_th_score_without_a_sort(
     sorts = [ln for ln in lines if " sort(" in ln or "TopK" in ln]
     assert sorts and all(
         re.search(r'op_name="[^"]*/(router|moe)/', ln) for ln in sorts), sorts
-    # what scans is the kernels' walk over the slot's ROWS (``_end_to_end``)
+    # nothing scans: the kernels of a decode step walk the slot's rows in
+    # their bodies (the score kernel laid its rows end to end until PR 56)
     scans = [ln for ln in lines if " reduce-window(" in ln]
     assert all(_elements(ln) <= 4 for ln in scans), scans
     # the layer's cond: the score kernel lies in its branch 1
@@ -2077,6 +2087,176 @@ def test_decode_kernel_takes_a_blocks_heads_together(cell, store):
     assert cells == [(2, bps, Nkv, BS, D)] * 2
     smem = [a.shape for a in scratch if str(a.memory_space) == "smem"]
     assert smem == ([(2, 1, bps * 2 * Nkv)] if store == "int8" else [])
+
+
+# ---- the score kernel of a selecting decode step (``index_scores_tpu``) ------
+# Rows of 16 table entries of 8 tokens over an index arena of 128 lanes, 4 index
+# heads; ``width`` is the blocks a cell (None: the shapes' own, here the whole
+# table in one cell).
+
+#: case -> (a row's written columns [B], what the trash block holds, the index
+#: key's own width, the store)
+_SCORE_CASES = {
+    "rows of unequal frontiers": ([37, 128, 9, 70], 0.0, 128, "f32"),
+    "a dead row in the middle of the slot": ([40, 0, 0, 100], 0.0, 128, "f32"),
+    "a frontier that ends inside a cell": ([33, 17, 1, 95], 0.0, 128, "f32"),
+    "a trash block holding inf": ([20, 0, 61, 128], np.inf, 128, "f32"),
+    "a trash block holding nan": ([20, 0, 61, 128], np.nan, 128, "bf16"),
+    "a 64-wide key padded to 128 lanes": ([50, 77, 0, 12], 0.0, 64, "bf16"),
+}
+
+
+def _score_inputs(case, T=16, BS=8, Hi=4, lanes=128):
+    from llm_sharding_tpu.models.cache import POS_SENTINEL
+    from llm_sharding_tpu.ops import paged_attention as pa
+
+    ctx, trash, width, store = _SCORE_CASES[case]
+    dt = jnp.float32 if store == "f32" else jnp.bfloat16
+    B, L = len(ctx), 2
+    NB = 1 + B * T
+    rng = np.random.default_rng(len(case))
+    arena = np.zeros((L, NB, 1, BS, lanes), np.float32)
+    arena[..., :width] = rng.standard_normal((L, NB, 1, BS, width))
+    arena[:, 0] = trash
+    table = np.zeros((B, T), np.int32)
+    kv_pos = np.full((B, T * BS), POS_SENTINEL, np.int32)
+    for b, n in enumerate(ctx):
+        own = -(-n // BS)
+        # a row's blocks lie in the arena in no order
+        table[b, :own] = 1 + b * T + rng.permutation(T)[:own]
+        kv_pos[b, :n] = np.arange(n)
+    q_pos = np.asarray(
+        [[n - 1 if n else POS_SENTINEL] for n in ctx], np.int32)
+    select = pa.Selection(
+        jnp.asarray(rng.standard_normal((B, 1, Hi, width)), dt),
+        jnp.asarray(rng.uniform(0.5, 1.5, (B, 1, Hi)), jnp.float32),
+        jnp.asarray(arena, dt), 16,
+    )
+    return select, jnp.asarray(table), jnp.asarray(q_pos), jnp.asarray(kv_pos)
+
+
+@pytest.mark.parametrize("width", [None, 4, 1])
+@pytest.mark.parametrize("case", sorted(_SCORE_CASES))
+def test_the_score_kernel_scores_what_the_xla_branch_scores(case, width):
+    """``index_scores_tpu`` (interpret mode: the body the chip runs) against
+    the XLA branch of ``index_scores`` — the gathered window's einsum — on
+    every attendable column, over a table of ONE cell (the shapes' own
+    width: narrower than a cell's cap), of four and of sixteen: the same
+    scores whatever the width, zeros (never a trash block's ``inf`` /
+    ``nan``) where the walk did not go or the table names the trash block,
+    and ``select_mask`` over them keeps the very set ``select_tokens``
+    lists."""
+    from llm_sharding_tpu.ops import paged_attention as pa
+
+    select, table, q_pos, kv_pos = _score_inputs(case)
+    BS = select.idx_arena.shape[3]
+    ok = pa._attendable(table, q_pos, kv_pos, BS)
+    want = pa.index_scores(select, 1, table, q_pos, kv_pos, ok)[:, 0]
+    lanes = select.idx_arena.shape[-1]
+    qi = jnp.pad(select.qi, [(0, 0)] * 3 + [(0, lanes - select.qi.shape[-1])])
+    raw = pa.index_scores_tpu(
+        qi[:, 0], select.wi[:, 0], select.idx_arena, 1, table, q_pos,
+        kv_pos, interpret=True, blocks_per_cell=width,
+    )
+    assert np.isfinite(np.asarray(raw)).all()
+    seen = np.asarray(ok[:, 0])
+    assert (np.asarray(raw)[np.repeat(np.asarray(table) == 0, BS, 1)] == 0).all()
+    np.testing.assert_allclose(
+        np.asarray(raw)[seen], np.asarray(want)[seen], rtol=1e-5, atol=1e-5)
+    # through the dispatch (the shapes' own width) the masked scores too
+    if width is None:
+        got = pa.index_scores(
+            select, 1, table, q_pos, kv_pos, ok, "interpret")[:, 0]
+        np.testing.assert_array_equal(
+            np.asarray(got) == -np.inf, np.asarray(want) == -np.inf)
+    score = jnp.where(ok[:, 0], raw, -jnp.inf)
+    keep = np.asarray(pa.select_mask(score, select.topk))
+    cols, real = (np.asarray(a) for a in pa.select_tokens(score, select.topk))
+    for b in range(seen.shape[0]):
+        assert sorted(np.flatnonzero(keep[b])) == sorted(cols[b][real[b]])
+        assert keep[b].sum() == min(seen[b].sum(), select.topk)
+
+
+def test_the_score_kernel_refuses_a_width_that_does_not_divide_the_table():
+    from llm_sharding_tpu.ops import paged_attention as pa
+
+    select, table, q_pos, kv_pos = _score_inputs("rows of unequal frontiers")
+    with pytest.raises(ValueError, match="does not divide the table width"):
+        pa.index_scores_tpu(
+            select.qi[:, 0], select.wi[:, 0], select.idx_arena, 1, table,
+            q_pos, kv_pos, interpret=True, blocks_per_cell=5,
+        )
+
+
+#: table widths at Keye's index arena (12 layers x 2305 blocks of 32 tokens x
+#: 128 bf16 lanes, 16 index heads, 4 rows) -> the blocks a cell
+_SCORE_TABLES = {288: 96, 256: 64, 33: 33}
+
+
+@pytest.mark.parametrize("table", sorted(_SCORE_TABLES))
+def test_the_score_kernel_walks_the_index_arena_by_hand(table):
+    """The score kernel at Keye's shape, read from the traced ``pallas_call``
+    (nothing runs): ONE invocation — no grid over cells: the walk is a loop
+    in the body —, three scalar-prefetch operands (layer, table, the
+    frontier: no walk laid end to end), the index arena WHOLE and in HBM,
+    once — no operand a block —, a double-buffered VMEM scratch a cell wide
+    and the whole call's scores one output block, ``[B, T·BS]`` as the search
+    reads them."""
+    from llm_sharding_tpu.ops import paged_attention as pa
+
+    B, Hi, lanes, BS, L, NB = 4, 16, 128, 32, 12, 2305
+    S = jax.ShapeDtypeStruct
+    jaxpr = jax.make_jaxpr(pa.index_scores_tpu)(
+        S((B, Hi, lanes), jnp.bfloat16), S((B, Hi), jnp.float32),
+        S((L, NB, 1, BS, lanes), jnp.bfloat16), S((), jnp.int32),
+        S((B, table), jnp.int32), S((B, 1), jnp.int32),
+        S((B, table * BS), jnp.int32),
+    )
+    (call,) = _pallas_calls(jaxpr.jaxpr)
+    gm = call.params["grid_mapping"]
+    bps = pa.index_blocks_per_cell(table, BS, lanes, 2)
+    assert bps == _SCORE_TABLES[table]
+    assert tuple(gm.grid) == (1,) and gm.num_index_operands == 3
+    pools = [bm.block_aval for bm in gm.block_mappings
+             if len(bm.block_aval.shape) == 5]
+    assert [(a.shape, str(a.memory_space)) for a in pools] == [
+        ((L, NB, 1, BS, lanes), "hbm")]
+    assert _block_shapes(call)[-1] == (B, table * BS)
+    scratch = [v.aval for v in call.params["jaxpr"].invars][
+        -gm.num_scratch_operands:]
+    assert [a.shape for a in scratch if len(a.shape) == 3] == [
+        (2, bps * BS, lanes)]
+
+
+@pytest.mark.parametrize("table", sorted(_SCORE_TABLES))
+def test_the_score_kernel_compiles_for_a_described_v5e(v5e_chip, table):
+    """The TPU's own compiler (Mosaic included) accepts the score kernel at
+    Keye's shape — a block's ``(BS, lanes)`` tile copied by hand out of the
+    5-D stacked arena into a slice of a slot, a cell's scores stored at its
+    lane offset of the one ``[B, T·BS]`` output block — at a table of three
+    cells of 96 blocks, of four of 64 and an odd one of ONE cell; and
+    nothing re-lays the scores after the call. No chip: the compile is real,
+    nothing runs."""
+    from llm_sharding_tpu.ops import paged_attention as pa
+
+    B, Hi, lanes, BS, L, NB = 4, 16, 128, 32, 12, 2305
+    S = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=v5e_chip
+    )
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(pa.index_scores_tpu).lower(
+            S((B, Hi, lanes), jnp.bfloat16), S((B, Hi), jnp.float32),
+            S((L, NB, 1, BS, lanes), jnp.bfloat16), S((), jnp.int32),
+            S((B, table), jnp.int32), S((B, 1), jnp.int32),
+            S((B, table * BS), jnp.int32),
+        ).compile().as_text()
+    assert text.count("tpu_custom_call") == 1 and "index_scores" in text
+    for m in re.finditer(r"= f32\[([\d,]+)\][^ ]* (copy|reduce)\(", text):
+        assert np.prod([int(x) for x in m.group(1).split(",")]) < (
+            B * table * BS)
+    for m in re.finditer(r"= \w+\[([\d,]+)\][^ ]* (copy|transpose)\(", text):
+        assert np.prod([int(x) for x in m.group(1).split(",")]) < (
+            L * NB * BS * lanes)
 
 
 @pytest.mark.parametrize("weights", ["int8", "bf16"])

@@ -182,6 +182,33 @@ def test_decode_blocks_per_cell(shape, bps):
     assert 2 * bps * Nkv * BS * lanes * size <= DECODE_CELL_VMEM or bps == 1
 
 
+@pytest.mark.parametrize("shape, bps", [
+    # (table, block, lanes of a stored index key, bytes an element)
+    ((288, 32, 128, 2), 96),  # Keye: 8 KiB a block, three cells of 3,072
+    ((256, 32, 128, 2), 64), ((128, 32, 128, 2), 64),  # 128 copies: too many
+    ((64, 32, 128, 2), 64),  # one cell
+    ((288, 32, 512, 2), 32),  # a wide index key: the slot's bytes bound it
+    ((288, 32, 128, 1), 96),  # an fp8 index key: the copies still do
+    ((512, 16, 128, 2), 64),  # 16-token blocks: eight to a lane tile
+    ((33, 32, 128, 2), 33),  # no width's scores are whole lane tiles: one
+    ((16, 8, 128, 4), 16),  # cell, the table
+])
+def test_index_blocks_per_cell(shape, bps):
+    """The score kernel's cell is as wide as divides the table, stays within
+    the copies and the bytes a slot of its double buffer may hold and stores
+    whole 128-lane tiles of scores: a function of the shapes."""
+    from llm_sharding_tpu.ops.paged_attention import (
+        INDEX_CELL_BLOCKS, INDEX_CELL_VMEM, index_blocks_per_cell,
+    )
+
+    assert index_blocks_per_cell(*shape) == bps
+    T, BS, lanes, size = shape
+    assert T % bps == 0
+    assert bps == T or (
+        bps * BS % 128 == 0 and bps <= INDEX_CELL_BLOCKS
+        and bps * BS * lanes * size <= INDEX_CELL_VMEM)
+
+
 def test_paged_prefill_backend_validation():
     args = _op_case()
     with pytest.raises(ValueError, match="expected one of"):
