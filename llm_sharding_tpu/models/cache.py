@@ -65,8 +65,10 @@ def init_cache(
     num_layers: int | None = None,
     dtype=jnp.bfloat16,
 ) -> KVCache:
-    """Allocate an empty cache for ``num_layers`` (a pipeline stage's slice)."""
+    """Allocate an empty cache for ``num_layers`` LAYERS (a pipeline stage's
+    slice): ``cfg.arena_slots`` cache layer slots each."""
     L = cfg.num_hidden_layers if num_layers is None else num_layers
+    L *= cfg.arena_slots
     shape = (L, batch_size, capacity, cfg.cache_heads)
     return KVCache(
         k=jnp.zeros((*shape, cfg.cache_k_dim), dtype),

@@ -188,6 +188,17 @@ class ModelConfig:
     kda_head_dim: int = 0
     kda_beta_scale: float = 1.0
     attn_gate: bool = False
+    # ``longcat_flash`` (models/longcat_flash.py): a layer is TWO attention +
+    # dense-MLP sub-layers (latent attention as ``deepseek_v3``'s, so the cache
+    # holds ``arena_slots`` = 2 latent entries a token and layer) with ONE
+    # shortcut-connected expert product between them. The softmax router
+    # scores ``num_experts`` real experts AND ``zero_experts`` zero-compute
+    # ones (ids ``num_experts …``: they return their input and have no
+    # weights). ``mla_q_scale`` multiplies ``q`` after ``q_b_proj``,
+    # ``mla_kv_scale`` the normed latent before ``kv_b_proj``.
+    zero_experts: int = 0
+    mla_q_scale: float = 1.0
+    mla_kv_scale: float = 1.0
     # GPT-2 specifics
     layer_norm_epsilon: float = 1e-5
     # Token ids. ``eos_token_ids`` holds ALL stop ids (Llama-3.x instruct
@@ -336,6 +347,21 @@ class ModelConfig:
         return self.qk_rope_head_dim if self.latent_kv else self.head_dim_
 
     @property
+    def arena_slots(self) -> int:
+        """Cache / arena layer slots ONE layer fills: a ``longcat_flash``
+        layer runs two attentions, each over a latent entry of its own, so a
+        stage of ``Lp`` layers has ``arena_slots · Lp`` slots (layer ``l``
+        writes and reads slots ``2l`` and ``2l + 1``). Every place that sizes
+        or walks a cache's layer axis multiplies by this."""
+        return 2 if self.model_type == "longcat_flash" else 1
+
+    @property
+    def router_experts(self) -> int:
+        """Width of the router and of the expert counters: the real experts
+        and, after them, the zero-compute ones."""
+        return self.num_experts + self.zero_experts
+
+    @property
     def experts_held_(self) -> int:
         return self.experts_held or self.num_experts
 
@@ -460,6 +486,8 @@ class ModelConfig:
             return cls._from_jamba(hf)
         if mt == "solar_open2":
             return cls._from_solar_open2(hf)
+        if mt == "longcat_flash":
+            return cls._from_longcat_flash(hf)
         if mt in ("llama",):
             act = hf.get("hidden_act", "silu")
             if act not in ("silu", "gelu_tanh"):
@@ -622,6 +650,11 @@ class ModelConfig:
                     + ("; the multi-token-prediction module is not served: "
                        "drop its layers at conversion"
                        if key == "num_nextn_predict_layers" else "")
+                    + ("; a softmax router over latent attention (a "
+                       "correction bias for the choice, weights not "
+                       "renormalised) is the longcat_flash family's, "
+                       "models/longcat_flash.py"
+                       if key == "scoring_func" else "")
                     + ")"
                 )
         if hf.get("rope_interleave") is False:
@@ -1149,6 +1182,114 @@ class ModelConfig:
             eos_token_ids=eos_ids,
         )
 
+    @classmethod
+    def _from_longcat_flash(cls, hf: dict[str, Any]) -> "ModelConfig":
+        """``longcat_flash`` under the family's OWN key names (LongCat-Flash's
+        decoder, which LongCat-Flash-Omni's language model is): ``num_layers``
+        double layers — two latent attentions (``deepseek_v3``'s MLA;
+        ``mla_scale_q_lora`` / ``mla_scale_kv_lora``: ``q`` after ``q_b_proj``
+        times ``(hidden / q_lora_rank)^½``, the normed latent before
+        ``kv_b_proj`` times ``(hidden / kv_lora_rank)^½``) and two dense MLPs
+        of ``ffn_hidden_size`` — around ONE shortcut-connected expert product:
+        ``n_routed_experts`` gated experts of ``expert_ffn_hidden_size`` and
+        ``zero_expert_num`` zero-compute experts that return their input,
+        ``moe_topk`` a token by a float32 softmax over all of them (a
+        correction bias for the choice only, weights not renormalised, times
+        ``routed_scaling_factor``). A chip's share of the REAL experts as
+        ``_from_deepseek_v3`` reads one: ``n_routed_experts`` is how many are
+        HELD, ``n_routed_experts_total`` (default: the same) how many the
+        router scores, ``ep_rank`` which run of them this is. What is not
+        done is refused by name."""
+        need = (
+            "num_layers", "hidden_size", "ffn_hidden_size",
+            "expert_ffn_hidden_size", "num_attention_heads", "q_lora_rank",
+            "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+            "v_head_dim", "n_routed_experts", "moe_topk", "vocab_size",
+        )
+        for key in need:
+            if hf.get(key) is None:
+                raise ValueError(
+                    f"longcat_flash config.json lacks {key!r} (a model "
+                    "without the q bottleneck, q_lora_rank null, is not "
+                    "supported)"
+                )
+        refuse = {
+            "zero_expert_type": ("identity",), "attention_method": ("MLA",),
+            "norm_topk_prob": (False,), "router_bias": (False,),
+            "tie_word_embeddings": (False,), "hidden_act": ("silu",),
+            "attention_bias": (False,), "rope_interleave": (True,),
+        }
+        for key, ok in refuse.items():
+            if key in hf and hf[key] is not None and hf[key] not in ok:
+                raise ValueError(
+                    f"longcat_flash {key}={hf[key]!r} is not supported (only "
+                    f"{', '.join(map(repr, ok))})"
+                )
+        if hf.get("rope_scaling"):
+            raise ValueError(
+                "longcat_flash rope_scaling is not supported: the family "
+                "publishes plain RoPE (serve a scaled checkpoint once its "
+                "softmax-scale convention is in this repository)"
+            )
+        held = int(hf["n_routed_experts"])
+        total = int(hf.get("n_routed_experts_total", held))
+        rank = int(hf.get("ep_rank", 0))
+        zero = int(hf.get("zero_expert_num") or 0)
+        top_k = int(hf["moe_topk"])
+        if total % held or not 0 <= rank < total // held:
+            raise ValueError(
+                f"longcat_flash share: {held} experts held of {total}, rank "
+                f"{rank}: the held count must divide the total and the rank "
+                f"lie in 0..{total // max(held, 1) - 1}"
+            )
+        if not 0 < top_k <= total + zero:
+            raise ValueError(
+                f"longcat_flash moe_topk {top_k} is not in 1..{total + zero} "
+                "(the real and the zero-compute experts)"
+            )
+        H = int(hf["hidden_size"])
+        eos = hf.get("eos_token_id", 2)
+        eos_ids = tuple(eos) if isinstance(eos, list) else (eos,)
+        return cls(
+            model_type="longcat_flash",
+            vocab_size=hf["vocab_size"],
+            hidden_size=H,
+            intermediate_size=int(hf["ffn_hidden_size"]),
+            num_hidden_layers=int(hf["num_layers"]),
+            num_attention_heads=hf["num_attention_heads"],
+            num_key_value_heads=hf["num_attention_heads"],
+            head_dim=int(hf["qk_nope_head_dim"]) + int(hf["qk_rope_head_dim"]),
+            max_position_embeddings=hf.get("max_position_embeddings", 4096),
+            rms_norm_eps=hf.get("rms_norm_eps", 1e-5),
+            rope_theta=float(hf.get("rope_theta", 10000.0)),
+            num_experts=total,
+            num_experts_per_tok=top_k,
+            norm_topk_prob=False,
+            q_lora_rank=int(hf["q_lora_rank"]),
+            kv_lora_rank=int(hf["kv_lora_rank"]),
+            qk_nope_head_dim=int(hf["qk_nope_head_dim"]),
+            qk_rope_head_dim=int(hf["qk_rope_head_dim"]),
+            v_head_dim=int(hf["v_head_dim"]),
+            moe_intermediate_size=int(hf["expert_ffn_hidden_size"]),
+            routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+            experts_held=held,
+            ep_rank=rank,
+            zero_experts=zero,
+            mla_q_scale=(
+                (H / int(hf["q_lora_rank"])) ** 0.5
+                if hf.get("mla_scale_q_lora") else 1.0
+            ),
+            mla_kv_scale=(
+                (H / int(hf["kv_lora_rank"])) ** 0.5
+                if hf.get("mla_scale_kv_lora") else 1.0
+            ),
+            bos_token_id=(
+                1 if hf.get("bos_token_id") is None else hf["bos_token_id"]
+            ),
+            eos_token_id=eos_ids[0],
+            eos_token_ids=eos_ids,
+        )
+
 
 #: ``solar_open2`` keys a published config carries that ``_from_solar_open2``
 #: keeps and does NOT read, each with its reason
@@ -1617,6 +1758,32 @@ def tiny_solar_open2(**kw) -> ModelConfig:
     of 16 —, then three KDA mixers of 4 heads of 16 x 16 state, a conv of 4),
     every layer's MLP 8 routed experts, 2 a token, and one shared expert."""
     return ModelConfig.from_hf_config(tiny_solar_open2_keys(**kw))
+
+
+def tiny_longcat_flash_keys(**kw) -> dict:
+    """The published-style keys of ``tiny_longcat_flash`` (the family's own
+    names, as LongCat-Flash's ``config.json`` holds them)."""
+    base = dict(
+        model_type="longcat_flash",
+        vocab_size=256, hidden_size=64, ffn_hidden_size=96,
+        expert_ffn_hidden_size=32, num_layers=3, num_attention_heads=4,
+        q_lora_rank=24, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=24,
+        mla_scale_q_lora=True, mla_scale_kv_lora=True,
+        n_routed_experts=8, zero_expert_num=4, zero_expert_type="identity",
+        moe_topk=3, routed_scaling_factor=6.0, attention_method="MLA",
+        attention_bias=False, max_position_embeddings=256,
+        rms_norm_eps=1e-5, rope_theta=10000.0, eos_token_id=255,
+    )
+    base.update(kw)
+    return base
+
+
+def tiny_longcat_flash(**kw) -> ModelConfig:
+    """Tiny longcat_flash-layout config for CPU tests: 3 double layers (6
+    latent attentions of 4 heads, ``v_head_dim`` != ``qk_nope_head_dim``, both
+    latent scales on), 8 real + 4 zero-compute experts, 3 a token, scale 6."""
+    return ModelConfig.from_hf_config(tiny_longcat_flash_keys(**kw))
 
 
 def tiny_qwen2(**kw) -> ModelConfig:

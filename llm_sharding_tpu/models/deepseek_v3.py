@@ -47,6 +47,16 @@ shared expert (``ws_*``) is computed in full. Leaves: ``router [H, E]``, ``route
 (float32, used for the choice only), ``we_gate`` / ``we_up [H, held·F]``,
 ``we_down [held·F, H]``, ``ws_gate`` / ``ws_up [H, Fs]``, ``ws_down [Fs, H]``.
 
+``mla_block`` is ``mla_attention`` (everything up to and including
+``o_proj``'s residual add) followed by ``mlp_sub_block``; other families run
+the halves on their own: ``models/solar_open2.py`` the second after its own
+mixers, ``models/longcat_flash.py`` the first TWICE a layer with that family's
+two latent scales (``q_scale`` after ``wq_b``, ``kv_scale`` on the normed
+latent: folded into the norms' gains, and in the program only where they are
+not 1) around a softmax-routed expert product of its own (a correction bias
+for the choice, experts without weights: ``ops/moe.route(bias=)``,
+``expert_mlp(zero_from=)``) — this block's router stays sigmoid ``noaux_tc``.
+
 Refused by name: tensor and context parallelism over this model, a
 quantized (int8/fp8) latent cache.
 """
@@ -237,11 +247,43 @@ def mla_block(
     moe_backend: str = "auto",
 ):
     """One layer, dense or expert (keyed by the presence of ``router``),
-    with the cache mechanism injected. Returns ``(h, cache, stats)``:
-    ``cache`` is what ``attend`` returned beside its output, ``stats`` the
-    layer's ``MoeStats`` (None for a dense layer; the kinds' stats are
-    joined over the stage's layer slots). The named scopes
-    are words of ``obs.stepline.SCOPES``."""
+    with the cache mechanism injected: ``mla_attention`` then
+    ``mlp_sub_block``. Returns ``(h, cache, stats)``: ``cache`` is what
+    ``attend`` returned beside its output, ``stats`` the layer's ``MoeStats``
+    (None for a dense layer; the kinds' stats are joined over the stage's
+    layer slots)."""
+    h, cache = mla_attention(cfg, p, h, cos, sin, attend)
+    h, stats = mlp_sub_block(cfg, p, h, moe_live, moe_backend)
+    return h, cache, stats
+
+
+def _scaled_norm(x, g, scale: float, eps: float):
+    """``RMSNorm(x; g) · scale`` with the constant folded into the gain in
+    float32 (the product rounds once, where the unscaled gain rounds); the
+    plain norm at 1 — the program then is what it was without a scale."""
+    if scale == 1.0:
+        return rms_norm(x, g, eps)
+    return rms_norm(x, g.astype(jnp.float32) * scale, eps).astype(x.dtype)
+
+
+def mla_attention(
+    cfg: ModelConfig,
+    p: Params,
+    h: jnp.ndarray,  # [B, S, H]
+    cos: jnp.ndarray,  # [B, S, rope]
+    sin: jnp.ndarray,
+    attend,  # as ``mla_block``'s
+    q_scale: float = 1.0,  # static: ``q`` after ``wq_b`` times this
+    kv_scale: float = 1.0,  # static: the normed latent times this
+):
+    """A layer's attention half, ``h + MLA(RMSNorm_in(h)) W_o``, with the
+    cache mechanism injected (``models/longcat_flash.py`` runs it twice a
+    layer). Returns ``(h, cache)``. The two scales (``longcat_flash``'s
+    ``mla_scale_q_lora`` / ``mla_scale_kv_lora``) are folded into the gains
+    of the norms before ``wq_b`` and before the absorbed factors — ``q`` is
+    linear in ``c_q``, and the arena entry then HOLDS the scaled latent, once
+    — and are in the program only where they are not 1. The named scopes are
+    words of ``obs.stepline.SCOPES``."""
     B, S, H = h.shape
     Nh = cfg.num_attention_heads
     dn, dr, r = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
@@ -259,8 +301,8 @@ def mla_block(
         # every call
         kv_a = jax.lax.optimization_barrier(kv_a)
     with jax.named_scope("norm"):
-        c_q = rms_norm(c_q, p["q_a_norm"], eps)
-        c_kv = rms_norm(kv_a[..., :r], p["kv_a_norm"], eps)
+        c_q = _scaled_norm(c_q, p["q_a_norm"], q_scale, eps)
+        c_kv = _scaled_norm(kv_a[..., :r], p["kv_a_norm"], kv_scale, eps)
     with jax.named_scope("qkv"):
         # likewise: the head split folded into this dot cost a copy of the
         # stack of ``wq_b`` (151 MB at 8 layers of GigaChat3.1's widths,
@@ -288,8 +330,7 @@ def mla_block(
         o = absorb_o(o_lat, p["w_uv"])
     with jax.named_scope("o_proj"):
         h = h + qmatmul(o.reshape(B, S, -1), p["wo"])
-    h, stats = mlp_sub_block(cfg, p, h, moe_live, moe_backend)
-    return h, cache, stats
+    return h, cache
 
 
 def mlp_sub_block(

@@ -856,6 +856,13 @@ MOE_PAIRS_HELD = REGISTRY.counter(
     "chip holds (a chip's share of the experts; all of them where it holds "
     "every expert): the others form no tile and are not read for",
 )
+MOE_ZERO_PAIRS = REGISTRY.counter(
+    "server_moe_zero_pairs_total",
+    "Of server_moe_pairs_routed_total, the pairs that fell on zero-compute "
+    "experts (longcat_flash: ids past the real experts, which return their "
+    "input): they form no tile, read nothing and cost a scaled add; 0 for "
+    "a model without such experts",
+)
 MOE_EXPERTS_READ = REGISTRY.gauge(
     "server_moe_experts_read",
     "A model with sparse experts: mean distinct experts read per layer per "

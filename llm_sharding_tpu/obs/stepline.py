@@ -157,6 +157,11 @@ SCOPES = (
     "router",     # a sparse MLP's router: float32 logits, softmax, top-k
     "moe",        # a sparse MLP's expert product: tiles, the expert kernel
                   # (``moe_experts``), the weighted sum, its counters
+    "zero_expert",  # the zero-compute experts' term (the sum of the chosen
+                  # identity experts' weights a token, times the expert
+                  # path's input) and the add that joins a shortcut layer's
+                  # expert output to the residual stream at the layer's end
+                  # (models/longcat_flash.py)
     "ssm_proj",   # a Mamba mixer's two projections, ``w_in`` and ``w_out``
                   # with its residual add (models/nemotron_h.py, jamba.py)
     "conv",       # the mixer's causal depthwise conv, its tail shift, silu

@@ -13,7 +13,10 @@ of them would cost five times the bytes.
 **The router** (``route``): logits ``x · router`` multiplied out in float32
 (a bf16 router flips the top-k between near ties), softmax over ALL experts,
 the ``k`` largest kept as they are — renormalised to sum 1 only under
-``norm_topk_prob``.
+``norm_topk_prob``. With a correction ``bias`` (``longcat_flash``) the CHOICE
+is the ``k`` largest of ``p + bias`` and the weights stay the UNbiased ``p``
+there, times a ``scale``; the router's width may then be ``E + Z``: ``E``
+real experts and, after them, ``Z`` zero-compute ones (below).
 
 **The expert product** (``expert_mlp``) runs as row TILES, each tile one
 expert: ``y_tile = (silu(x_tile · Wg_e) ⊙ (x_tile · Wu_e)) · Wd_e``. Two
@@ -62,7 +65,22 @@ kept); a pair that falls on an expert held elsewhere forms no tile, is not
 read for, and adds nothing — what the absent experts would add is left out,
 and nothing stands in for the chips that hold them. ``expert_tokens`` stays
 ``[E]`` (every pair routed, so pairs held = its slice over the held ids),
-``experts_read`` counts held experts only.
+``experts_read`` counts held experts only. The SOFTMAX router scores and
+normalises over all of them just the same (``route``; ``models/
+longcat_flash.py``).
+
+**Experts without weights** (``expert_mlp(zero_from=E)``; ``num_experts`` is
+then the router's width ``E + Z``): an id ``>= zero_from`` is a zero-compute
+expert that returns its input. Such a pair forms no tile in EITHER regime, is
+never fetched for, and is not an absent expert either: it adds ``w · x``, so
+a token gets ``(Σ w over such picks) · x`` — the weights summed in float32,
+the product added to the experts' float32 sum before the one cast to the
+output's dtype. Of a token's ``k`` picks 0 to ``k`` are real: its compute
+varies. ``expert_tokens`` is ``[E + Z]`` wide, so the pairs that cost nothing
+are COUNTED (its slice from ``E``); ``experts_read`` counts held real experts
+only. Under a share the term needs no weights and is computed where the
+token lives: what every chip computes alike, counted once when shares are
+added up.
 """
 
 from __future__ import annotations
@@ -130,18 +148,27 @@ class MoeStats(NamedTuple):
     experts_read: jax.Array  # scalar int32 distinct experts read
 
 
-def route(x, router, top_k: int, renormalize: bool = False):
+def route(x, router, top_k: int, renormalize: bool = False, bias=None,
+          scale: float = 1.0):
     """``x [N, H]``, ``router [H, E]`` → ``(weights [N, k] f32, ids [N, k])``:
     float32 softmax over all experts, the ``top_k`` largest kept as they are
-    (renormalised only when asked)."""
+    (renormalised only when asked). With ``bias [E]`` (``longcat_flash``) the
+    CHOICE is the ``top_k`` largest of ``p + bias`` and the weights are the
+    UNbiased ``p`` there; ``scale`` multiplies the kept weights."""
     logits = jnp.dot(
         x.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
     )
     probs = jax.nn.softmax(logits, axis=-1)
-    w, ids = jax.lax.top_k(probs, top_k)
+    if bias is None:
+        w, ids = jax.lax.top_k(probs, top_k)
+    else:
+        _, ids = jax.lax.top_k(probs + bias.astype(jnp.float32), top_k)
+        w = jnp.take_along_axis(probs, ids, axis=-1)
     if renormalize:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
+    if scale != 1.0:
+        w = w * scale
     return w, ids.astype(jnp.int32)
 
 
@@ -478,12 +505,17 @@ def expert_mlp(
     backend: str = "auto",
     held=None,  # static (first, count): the leaves hold only these experts
     act: str = "silu",  # static, one of ``ACTS``; "relu2": ``we_gate`` None
+    zero_from=None,  # static: ids from here to ``num_experts`` have NO weights
 ):
     """``Σ_k weights[n, k] · MLP_{ids[n, k]}(x[n])`` for the live rows (zero
     for the others) and the layer's ``MoeStats``. With ``held`` the sum is
-    over the pairs whose expert is held here (the module docstring)."""
+    over the pairs whose expert is held here (the module docstring). With
+    ``zero_from`` the ids ``zero_from … num_experts - 1`` are zero-compute
+    experts that return their input: ``+ (Σ_{k: id >= zero_from} w_k) · x``."""
     N, H = x.shape
     E = num_experts
+    if zero_from is not None and held is None:
+        held = (0, zero_from)  # the real experts, all of them held
     if act not in ACTS or (act == "relu2") != (we_gate is None):
         raise ValueError(
             f"expert activation {act!r}: one of {ACTS}, and 'relu2' (not "
@@ -492,6 +524,14 @@ def expert_mlp(
     backend = resolve_backend(backend)
     if live is None:
         live = jnp.ones((N,), bool)
+    if zero_from is not None:
+        # the pairs that cost nothing: no tile in either regime, nothing
+        # fetched — their weights summed in float32, for the live rows
+        with jax.named_scope("zero_expert"):
+            zero_w = jnp.sum(
+                jnp.where((ids >= zero_from) & live[:, None], weights, 0.0),
+                axis=1,
+            )  # [N]
     routed = None
     if held is not None and tuple(held) != (0, E):
         first, E = held
@@ -561,6 +601,9 @@ def expert_mlp(
         out = out * jax.lax.dynamic_index_in_dim(
             sd, lyr, keepdims=False
         ).astype(jnp.float32)
+        if zero_from is not None:
+            with jax.named_scope("zero_expert"):
+                out = out + zero_w[:, None] * x.astype(jnp.float32)
         stats = MoeStats(
             counts if routed is None else routed,
             jnp.sum(counts > 0).astype(jnp.int32),

@@ -200,6 +200,16 @@ JAMBA_QUANT_KEYS = ("w_x", "w_dt")
 # them. ``w_beta`` (a column a head), the conv, ``A_log``, ``dt_bias`` and the
 # norm gains stay
 SOLAR_QUANT_KEYS = ("w_a_down", "w_a_up", "w_g_down", "w_g_up")
+# longcat_flash (models/longcat_flash.py): a layer's two sub-layers carry
+# deepseek_v3's attention and dense-MLP names with a ``_0`` / ``_1`` suffix,
+# every one a plain ``[in, out]`` matmul; the experts' three are ``we_*``
+# above; ``router``, ``router_bias`` and the norm gains stay
+LONGCAT_QUANT_KEYS = tuple(
+    f"{name}_{i}" for i in (0, 1) for name in (
+        "wq_a", "wq_b", "wkv_a", "w_uk", "w_uv", "wo", "w_gate", "w_up",
+        "w_down",
+    )
+)
 
 
 def is_kinds_tree(layers: dict) -> bool:
@@ -221,7 +231,7 @@ def quantize_layer_params(
         keys = (
             LLAMA_QUANT_KEYS + GPT2_QUANT_KEYS + DEEPSEEK_QUANT_KEYS
             + MIMO_QUANT_KEYS + NEMOTRON_QUANT_KEYS + JAMBA_QUANT_KEYS
-            + SOLAR_QUANT_KEYS
+            + SOLAR_QUANT_KEYS + LONGCAT_QUANT_KEYS
         )
     if is_kinds_tree(layers):  # one stack per kind: each kind's leaves
         return {

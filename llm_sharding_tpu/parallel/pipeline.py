@@ -142,6 +142,14 @@ def model_fns(
             solar_open2.forward_layers, solar_open2.forward_layers_paged
         )
         walks = solar_open2.prefill_walks
+    elif cfg.model_type == "longcat_flash":
+        from ..models import longcat_flash
+
+        longcat_flash._refuse_tp(tp_axis, cp_axis)
+        fwd, fwd_paged = (
+            longcat_flash.forward_layers, longcat_flash.forward_layers_paged
+        )
+        walks = longcat_flash.prefill_walks
     else:
         raise ValueError(f"unsupported model_type: {cfg.model_type!r}")
 
@@ -234,7 +242,7 @@ def moe_stats_zero(cfg: ModelConfig, num_layers: int):
     if not cfg.num_experts:
         return None
     return MoeStats(
-        jnp.zeros((num_layers, cfg.num_experts), jnp.int32),
+        jnp.zeros((num_layers, cfg.router_experts), jnp.int32),
         jnp.zeros((num_layers,), jnp.int32),
     )
 
@@ -396,7 +404,7 @@ def _pipeline_generate_jit(
     B, S = prompt.shape
     Bl = B // dp  # rows per data replica
     total = S + max_new_tokens
-    Lp = layer_masks.shape[1]
+    Lp = layer_masks.shape[1] * cfg.arena_slots  # the cache's layer slots
     Nkv_local = cfg.cache_heads // tp
     ring = [(i, (i + 1) % num_stages) for i in range(num_stages)]
 

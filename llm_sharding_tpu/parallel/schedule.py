@@ -98,7 +98,7 @@ def _interleaved_jit(
     M, S = prompts.shape
     Bs = M // num_stages  # rows per slot
     total = S + max_new_tokens
-    Lp = layer_masks.shape[1]
+    Lp = layer_masks.shape[1] * cfg.arena_slots  # the cache's layer slots
     ring = [(i, (i + 1) % num_stages) for i in range(num_stages)]
     last = num_stages - 1
 

@@ -276,7 +276,7 @@ def moe_log_width(cfg: ModelConfig, num_stages: int, layers_per_stage: int) -> i
     without experts: its programs return what they always did."""
     if not cfg.num_experts:
         return 0
-    return cfg.num_experts + num_stages * layers_per_stage + 1
+    return cfg.router_experts + num_stages * layers_per_stage + 1
 
 
 def _moe_counts(stats, live, sidx, num_stages):
@@ -435,7 +435,8 @@ def make_state(
     if paged:
         from ..models.cache import paged_arena_shape
 
-        arena_layers = Lp - swa_layers
+        # (``cfg.arena_slots``: a layer of two attentions fills two slots)
+        arena_layers = Lp * cfg.arena_slots - swa_layers
         if recurrent_layers:
             arena_layers = sum(k in ARENA_KINDS for k in cfg.layer_kinds[:Lp])
         kv_shape = (
@@ -445,7 +446,9 @@ def make_state(
             )
         )
     else:
-        kv_shape = (S, Lp, M, C, cfg.cache_heads, cfg.cache_k_dim)
+        kv_shape = (
+            S, Lp * cfg.arena_slots, M, C, cfg.cache_heads, cfg.cache_k_dim
+        )
     # the value array beside it: the same but for the last dim — ZERO wide
     # for a latent cache, whose value read is a slice of the key read. The
     # array stays, empty, so that state, snapshots and host tiers keep one
@@ -562,7 +565,7 @@ def prefix_prefill(
         lmask = layer_mask[0]
         hd = local_view(head_params)
         sidx = jax.lax.axis_index(PIPE_AXIS)
-        Lp = lmask.shape[0]
+        Lp = lmask.shape[0] * cfg.arena_slots  # the cache's layer slots
         cache = KVCache(
             k=jnp.zeros((Lp, 1, Sp, nkv, cfg.cache_k_dim), cache_dtype),
             v=jnp.zeros((Lp, 1, Sp, nkv, cfg.cache_v_dim), cache_dtype),
@@ -846,8 +849,8 @@ def serve_admit(
         )
         row0 = slot * Bs
 
-        # fresh cache rows for this slot only
-        Lp = lmask.shape[0]
+        # fresh cache rows for this slot only (``Lp``: the layer slots)
+        Lp = lmask.shape[0] * cfg.arena_slots
         kv_shape = (Lp, Bs, C, nkv)
         cache = KVCache(
             k=jnp.zeros((*kv_shape, cfg.cache_k_dim), cache_dtype),

@@ -23,7 +23,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..models import deepseek_v3, gpt2, llama, mimo_v2
+from ..models import deepseek_v3, gpt2, llama, longcat_flash, mimo_v2
 from ..models.cache import KVCache, POS_SENTINEL, init_cache
 from ..models.config import ModelConfig
 from ..ops.sampling import (
@@ -41,6 +41,7 @@ def forward_fn_for(cfg: ModelConfig) -> ForwardFn:
     return {
         "llama": llama.forward, "gpt2": gpt2.forward,
         "deepseek_v3": deepseek_v3.forward, "mimo_v2": mimo_v2.forward,
+        "longcat_flash": longcat_flash.forward,
     }[cfg.model_type]
 
 

@@ -85,7 +85,7 @@ from ..obs.metrics import (
     DEFAULT_RATE_BUCKETS,
     KV_BLOCKS_IN_USE, KV_BLOCKS_TOTAL, KV_DISK_TIER_BLOCKS,
     KV_ENTRY_BYTES, KV_HOST_TIER_BLOCKS, KV_WASTE_FRAC, MOE_EXPERT_TOKENS,
-    MOE_EXPERTS_READ, MOE_PAIRS_HELD, MOE_PAIRS_ROUTED,
+    MOE_EXPERTS_READ, MOE_PAIRS_HELD, MOE_PAIRS_ROUTED, MOE_ZERO_PAIRS,
     PREFILL_BLOCKS_READ, PREFILL_CELLS_LIVE, PREFILL_CELLS_WALKED,
     PREFILL_KV_BLOCKS_WRITTEN, PREFILL_POSITIONS, PREFILL_SCAN_POSITIONS,
     PREFIX_HIT_RATE, RECURRENT_BACKEND, RECURRENT_BACKENDS,
@@ -1072,7 +1072,16 @@ class PipelineServer:
         #: any of the three: every prompt admits chunk by chunk, in WHOLE
         #: chunks (``_bucket``, ``_chunked``) — the chunk program is the one
         #: that carries what such a model keeps beside ONE arena
-        self._chunked_only = name is not None
+        #: ... and a model whose layer fills several arena slots
+        #: (``cfg.arena_slots``: two latent attentions a layer) wherever it
+        #: CAN (a paged server with ``prefill_chunk``): the one-shot path
+        #: builds a dense window of ``capacity`` columns for every slot and
+        #: attends it twice a layer — 5.3 GiB of temporaries at the
+        #: benchmark's geometry, beside weights that fill the chip
+        self._chunked_only = name is not None or (
+            self.cfg.arena_slots > 1 and options.paged
+            and options.prefill_chunk is not None
+        )
         if name is not None and (
             not options.paged or options.prefill_chunk is None
         ):
@@ -1382,7 +1391,7 @@ class PipelineServer:
             )
             self._moe_children = [
                 MOE_EXPERT_TOKENS.labels(expert=str(e))
-                for e in range(self.cfg.num_experts)
+                for e in range(self.cfg.router_experts)
             ]
         arena = SETUP.begin("setup.server.arena")
         self.state = serve_ops.make_state(
@@ -5024,7 +5033,7 @@ class PipelineServer:
         """The ``moe_log_width`` counters behind the tokens of a fetched
         chunk log or admission result of a model with experts go to the
         step record and the ``server_moe_*`` series."""
-        E, W = self.cfg.num_experts, self._moe_width
+        E, W = self.cfg.router_experts, self._moe_width
         own = own.reshape(-1, W)
         tokens = own[:, :E].sum(axis=0)
         self._count_expert_tokens(tokens)
@@ -5047,6 +5056,8 @@ class PipelineServer:
         lo, held = self.cfg.held_experts_
         MOE_PAIRS_ROUTED.inc(int(tokens.sum()))
         MOE_PAIRS_HELD.inc(int(tokens[lo:lo + held].sum()))
+        if self.cfg.zero_experts:  # the ids past the real experts
+            MOE_ZERO_PAIRS.inc(int(tokens[self.cfg.num_experts:].sum()))
 
     def _apply_chunk_counts(self) -> None:
         """Read the counters of the chunked prefills that have landed
@@ -5065,7 +5076,7 @@ class PipelineServer:
         PREFILL_CELLS_WALKED.inc(walked)
         self.stepline.prefill_cells(live, walked)
         if self._moe_width:
-            tokens = total[:self.cfg.num_experts]
+            tokens = total[:self.cfg.router_experts]
             self._count_expert_tokens(tokens)
             self.stepline.experts(tokens)
 

@@ -52,6 +52,15 @@ def _refuse_unmapped(cfg: ModelConfig) -> None:
             "of this repository — the converter maps it once they are; the "
             "block runs on seeded weights (benchmark/blocks/solar_open2.py)"
         )
+    if cfg.model_type == "longcat_flash":
+        raise NotImplementedError(
+            "model_type 'longcat_flash': the names of a LongCat-Flash "
+            "checkpoint's tensors (a layer's two attentions, two dense MLPs "
+            "and four norms, its router's classifier and correction bias) "
+            "are in no file of this repository — the converter maps it once "
+            "they are; the block runs on seeded weights "
+            "(benchmark/blocks/longcat_flash.py)"
+        )
 
 
 def llama_layer_arrays(

@@ -1763,7 +1763,7 @@ def compiled_serve_chunk(v5e_host):
 @pytest.mark.parametrize(
     "cell", sorted(_CELL_SHAPES) + [
         "gigachat31_702b_a36b", "nemotron3_super_120b_a12b",
-        "keye_vl2_30b_a3b"])
+        "keye_vl2_30b_a3b", "longcat_flash_omni"])
 def test_a_decode_step_reads_its_weights_as_they_are_stored(
         compiled_serve_chunk, cell):
     """The compiled ``serve_chunk`` of each benchmark configuration, at its
@@ -1781,8 +1781,9 @@ def test_a_decode_step_reads_its_weights_as_they_are_stored(
     (``wq_b``'s head split, held the same way) and once from the STORED side:
     a ``[H, 576]`` weight is not whole lane tiles, the chip keeps it
     input-minor, and the stack of ``wkv_a`` was re-laid every call until the
-    leaf was padded to the arena entry's 640 columns. Nothing runs: a
-    compile is not a time."""
+    leaf was padded to the arena entry's 640 columns; ``longcat_flash`` (PR
+    57) runs that attention TWICE a layer over leaves with a ``_0`` / ``_1``
+    suffix — the same edges, twice. Nothing runs: a compile is not a time."""
     text = compiled_serve_chunk(cell)
     assert _weight_stack_relayouts(text) == []
     dots, windowed = _windowed_projections(text)
@@ -2418,9 +2419,14 @@ _RECURRENT_WORDS = {
 # (``indexer`` / ``select`` are a token-selecting model's alone:
 # ``tests/test_keye_vl2_serve.py`` holds its programs to them)
 _SELECT_WORDS = {"indexer", "select"}
+# (``zero_expert`` is ``longcat_flash``'s alone — experts without weights and
+# the shortcut's join: ``tests/test_longcat_flash_serve.py`` holds its
+# programs to it)
+_SHORTCUT_WORDS = {"zero_expert"}
+_OTHERS_WORDS = _RECURRENT_WORDS | _SELECT_WORDS | _SHORTCUT_WORDS
 _MLP_WORDS = {
-    "dense": {"router", "moe", "absorb"} | _RECURRENT_WORDS | _SELECT_WORDS,
-    "experts": {"mlp", "absorb"} | _RECURRENT_WORDS | _SELECT_WORDS,
+    "dense": {"router", "moe", "absorb"} | _OTHERS_WORDS,
+    "experts": {"mlp", "absorb"} | _OTHERS_WORDS,
 }
 PROGRAM_SCOPES = {
     "serve_chunk": _NO_ARENA_COPY,

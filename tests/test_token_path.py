@@ -531,7 +531,11 @@ def test_the_new_entries_are_appended_with_their_cells():
         bench = json.load(f)
     nine = next(m for m in bench["end_to_end"]
                 if m["name"] == "itl_p95_ms")["workloads"]
-    tail = bench["per_layer"][-9:]
+    # PR 55's nine, together where they were appended (a later PR's entries
+    # come after them: PR 57's ten)
+    first = [m["name"] for m in bench["per_layer"]].index(
+        "token_emit_lag_p50_ms")
+    tail = bench["per_layer"][first:first + 9]
     assert [m["name"] for m in tail] == [
         "token_emit_lag_p50_ms", "token_emit_lag_p95_ms",
         "landing_gap_p95_ms", "host_bound_steps_pct",
