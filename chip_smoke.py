@@ -68,6 +68,18 @@ whose kernel scores a column as this one does. The last line, also left in
 ``chiprun_out/index_scores.json``: ``{"ok": true, "index_scores": [...],
 "device": {...}}``; exit 1 where the kernel's scores are not the XLA
 branch's.
+``--select`` likewise: a selecting decode step's SEARCH alone
+(``ops/paged_attention.select_mask`` with the fusion that reads its mask) at
+Keye's shape (a slot's ``[4, 9216]`` float32 scores, ``topk`` 2,048, 12
+layers), one live row and four, at 2.5 / 5 / 8.7 k of context, plus an input
+whose zeros of both signs tie across the ``topk``-th score: the search in XLA
+(``PAGED_FORCE_KERNEL=xla``) beside the search as the tree runs it on the
+chip (the kernel ``select_topk`` where it has one, at 1, 2 and 3 bits a
+pass), each mask held to ``select_tokens``' set, DEVICE microseconds a layer
+call from a profiler trace with the operations it is made of; then the mask
+alone at slots of 1, 2, 8 and 16 rows. The last line,
+also left in ``chiprun_out/select.json``: ``{"ok": true, "select": [...],
+"device": {...}}``; exit 1 where a mask is not the oracle's set.
 ``--ssm`` likewise: a decode step's state update of a recurrent-state model
 (``ops/ssm.ssm_step_rows``) at Nemotron-3-Super's published mixer shape (128
 heads of 64, a state of 128, 8 groups; 8 layers x 4 rows of float32 state
@@ -1233,6 +1245,177 @@ def child_index_scores(spec: dict, out_path: str) -> None:
         )
 
 
+#: a selecting decode step's search at Keye-VL-2.0-30B-A3B's shape: a slot's
+#: four rows over the table's 288 blocks of 32, of which one row is live in
+#: the cell (and four once admission goes by row), 2,048 tokens kept
+SELECT_SHAPE = {"name": "keye_vl2_30b_a3b", "rows": 4, "width": 9216,
+                "topk": 2048, "layers": 12, "lives": (1, 4),
+                "contexts": (2560, 5120, 8704)}
+#: the forms timed: the search in XLA, the search as the tree's
+#: ``select_mask`` resolves it on this backend, the kernel at other widths of
+#: a pass
+SELECT_FORMS = ("xla", "own", 1, 2, 4)
+#: slots of other sizes whose mask is checked (not timed): the kernel re-lays
+#: a row out of tiles that hold as many sublanes as the slot has rows
+SELECT_ROWS_CHECKED = (1, 2, 8, 16)
+
+
+def select_inputs(shape: dict, live: int, context: int, ties: bool = False,
+                  seed: int = 0) -> dict:
+    """Every layer's scores ``[L, B, W]`` as ``index_scores`` hands them —
+    the first ``live`` rows ``context`` attendable columns, ``-inf`` past
+    them and in the dead rows — and the key positions the mask is read
+    into. ``ties``: a live row's columns are zeros of both signs but for
+    ``topk // 2`` positive ones, so the ``topk``-th score is a zero and far
+    more columns tie with it than are left to keep."""
+    import numpy as np
+    import jax.numpy as jnp
+    from llm_sharding_tpu.models.cache import POS_SENTINEL
+
+    L, B, W = shape["layers"], shape["rows"], shape["width"]
+    rng = np.random.default_rng(seed)
+    scores = rng.standard_normal((L, B, W)).astype(np.float32)
+    if ties:
+        zeros = np.where(rng.random((L, B, W)) < 0.5, 0.0, -0.0)
+        few = rng.random((L, B, W)) < shape["topk"] / 2 / context
+        scores = np.where(few, np.abs(scores) + 1e-3, zeros).astype(np.float32)
+    seen = (np.arange(B)[:, None] < live) & (np.arange(W)[None] < context)
+    return dict(
+        scores=jnp.asarray(np.where(seen[None], scores, -np.inf)),
+        kvpos=jnp.asarray(np.where(
+            seen, np.arange(W)[None], POS_SENTINEL).astype(np.int32)),
+    )
+
+
+def select_forms() -> list:
+    """The forms this tree can be timed in: the kernel's other widths of a
+    pass only where the tree has the kernel."""
+    import llm_sharding_tpu.models  # noqa: F401 — ops import through models
+    from llm_sharding_tpu.ops import paged_attention as pa
+
+    return [f for f in SELECT_FORMS
+            if isinstance(f, str) or hasattr(pa, "select_topk_tpu")]
+
+
+def _select_program(shape: dict, form, interpret: bool = False):
+    """Every layer's search once, the mask read into the key positions as
+    ``selected_attention`` reads it: ``[L, B, W]`` int32, the sentinel where
+    a column is not kept. ``form``: ``"xla"`` — ``select_mask`` under
+    ``PAGED_FORCE_KERNEL=xla``, the search every tree has —, ``"own"`` —
+    ``select_mask`` as the tree resolves it here (``interpret``: under
+    ``PAGED_FORCE_KERNEL=interpret``) —, or the kernel at that many bits a
+    pass."""
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+    import llm_sharding_tpu.models  # noqa: F401 — ops import through models
+    from llm_sharding_tpu.models.cache import POS_SENTINEL
+    from llm_sharding_tpu.ops import paged_attention as pa
+
+    forced = "xla" if form == "xla" else "interpret" if interpret else ""
+
+    @jax.jit
+    def run(scores, kvpos):
+        def one(_, s):
+            if isinstance(form, int):
+                keep = pa.select_topk_tpu(
+                    s, shape["topk"], bits=form, interpret=interpret)
+            else:
+                keep = pa.select_mask(s, shape["topk"])
+            return None, jnp.where(keep, kvpos, POS_SENTINEL)
+        return jax.lax.scan(one, None, scores)[1]
+
+    def call(inp):
+        # the path is read where the program is traced: its first call
+        with mock.patch.dict(os.environ, PAGED_FORCE_KERNEL=forced):
+            return run(inp["scores"], inp["kvpos"])
+
+    return call
+
+
+def time_select(shape: dict, live: int, context: int, form,
+                ties: bool = False, runs: int = 8,
+                interpret: bool = False) -> dict:
+    """DEVICE microseconds of ONE layer's search in ``form`` from a profiler
+    trace (``time_kv_decode`` says why a trace): everything between the
+    scores and the masked key positions, the kernel's own share of it, and
+    whether the kept columns are ``select_tokens``' set (``lax.top_k``,
+    stable: the lower column of a tie; the two zeros made one for it).
+    ``runs=0``: the mask alone, no trace."""
+    import numpy as np
+    import jax
+    from llm_sharding_tpu.models.cache import POS_SENTINEL
+    from llm_sharding_tpu.ops import paged_attention as pa
+
+    inp = select_inputs(shape, live, context, ties)
+    call = _select_program(shape, form, interpret)
+    kept = np.asarray(jax.block_until_ready(call(inp))) != POS_SENTINEL
+    ops = []
+    if runs:
+        trace_dir = os.path.join(WORK, "trace_select")
+        shutil.rmtree(trace_dir, ignore_errors=True)
+        jax.profiler.start_trace(trace_dir)
+        for _ in range(runs):
+            out = call(inp)
+        jax.block_until_ready(out)
+        jax.profiler.stop_trace()
+        ops = sorted(device_op_us(trace_dir).items(), key=lambda kv: -kv[1])
+    calls = max(runs, 1) * shape["layers"]
+    scores = np.asarray(inp["scores"])
+    # the sort reads -0.0 below +0.0; the selection ties them (IEEE ==)
+    cols, real = (np.asarray(a) for a in jax.jit(
+        pa.select_tokens, static_argnums=1)(
+            np.where(scores == 0, np.float32(0), scores), shape["topk"]))
+    want = np.zeros_like(kept)
+    np.put_along_axis(want, np.where(real, cols, cols[..., :1]), True, axis=-1)
+    want &= scores > -np.inf  # a dead row's first column is no choice
+    return {
+        "us_per_layer_call": round(sum(u for _, u in ops) / calls, 2),
+        "kernel_us": round(
+            sum(u for n, u in ops if n.startswith("select_topk")) / calls, 2),
+        "ops_us": [[n, round(u / calls, 2)] for n, u in ops[:6]],
+        "kept": int(kept.sum()), "oracle_set": bool((kept == want).all()),
+    }
+
+
+def child_select(spec: dict, out_path: str) -> None:
+    import jax
+
+    from llm_sharding_tpu.utils.compile_cache import enable_persistent_cache
+    from llm_sharding_tpu.utils.device_report import device_report
+
+    platform = jax.devices()[0].platform
+    require_tpu(platform, "the selection's search")
+    enable_persistent_cache(platform)
+    shape, results = SELECT_SHAPE, []
+    cases = [(live, context, False) for live in shape["lives"]
+             for context in shape["contexts"]]
+    cases += [(live, shape["contexts"][-1], True) for live in shape["lives"]]
+    for live, context, ties in cases:
+        for form in select_forms():
+            got = time_select(shape, live, context, form, ties)
+            results.append({"shape": shape["name"], "live": live,
+                            "context": context, "ties": ties,
+                            "form": form, **got})
+            print(f"[select] live {live} at {context}"
+                  f"{' (zeros tie)' if ties else ''}, {form}: {got}",
+                  flush=True)
+    for rows in SELECT_ROWS_CHECKED:
+        slot = dict(shape, rows=rows, layers=2)
+        got = time_select(slot, -(-rows // 2), shape["contexts"][0], "own",
+                          ties=rows > 2, runs=0)
+        results.append({"shape": shape["name"], "rows": rows, "form": "own",
+                        **{k: got[k] for k in ("kept", "oracle_set")}})
+        print(f"[select] a slot of {rows} rows: {results[-1]}", flush=True)
+    with open(out_path, "w") as f:
+        json.dump({"device": device_report(), "select": results}, f)
+    if not all(r["oracle_set"] for r in results):
+        raise SystemExit(
+            "chip_smoke: a search's mask is not select_tokens' set"
+        )
+
+
 #: the one-step state update at Nemotron-3-Super-120B-A12B's published mixer
 #: shape, carried as the cell carries it: 8 mixer layers x 4 rows of float32
 SSM_SHAPE = {"layers": 8, "rows": 4, "heads": 128, "head_dim": 64,
@@ -1942,6 +2125,10 @@ def main(argv=None) -> int:
                     help="only check and time a selecting decode step's "
                          "score call (ops/paged_attention.index_scores_tpu) "
                          "at Keye's shape, at every cell width it takes")
+    ap.add_argument("--select", action="store_true",
+                    help="only check and time a selecting decode step's "
+                         "search (ops/paged_attention.select_mask) at "
+                         "Keye's shape: the kernel beside the XLA search")
     ap.add_argument("--ssm", action="store_true",
                     help="only check and time a decode step's state update "
                          "(ops/ssm.py) at Nemotron-3-Super's mixer shape, the "
@@ -1950,7 +2137,7 @@ def main(argv=None) -> int:
                          "Jamba2-3B's")
     ap.add_argument("--child",
                     choices=("kernels", "store", "moe", "kv_write",
-                             "kv_decode", "index_scores", "ssm"))
+                             "kv_decode", "index_scores", "select", "ssm"))
     ap.add_argument("--spec")
     ap.add_argument("--out")
     args = ap.parse_args(argv)
@@ -1959,11 +2146,11 @@ def main(argv=None) -> int:
         {"kernels": child_kernels, "store": child_store,
          "moe": child_moe, "kv_write": child_kv_write,
          "kv_decode": child_kv_decode,
-         "index_scores": child_index_scores,
+         "index_scores": child_index_scores, "select": child_select,
          "ssm": child_ssm}[args.child](spec, args.out)
         return 0
     # one check, its line left behind too
-    for mode in ("ssm", "kv_write", "kv_decode", "index_scores"):
+    for mode in ("ssm", "kv_write", "kv_decode", "index_scores", "select"):
         if getattr(args, mode):
             os.makedirs(WORK, exist_ok=True)
             got = wait_child(run_child(

@@ -557,6 +557,22 @@ SPARSE_TOKENS_SCORED = REGISTRY.counter(
     "context once it is longer than topk; 0 while the selection is "
     "everything and nothing is scored), summed over rows and layers",
 )
+#: forms a decode step's search can take (``ops/paged_attention.select_path``)
+SELECT_BACKENDS = ("kernel", "interpret", "xla")
+SELECT_BACKEND = REGISTRY.gauge(
+    "server_select_backend",
+    "Live servers of a token-selecting model by the form a decode step's "
+    "search takes (ops/paged_attention.select_path at the slot's rows and "
+    "the window's columns: select_mask's own resolution): kernel = ONE "
+    "Pallas call a layer (select_topk) that holds the slot's scores in VMEM "
+    "and searches the live rows only, xla = the digit search as XLA "
+    "operations over every row (the CPU path; on a TPU, a slot of more than "
+    "16 rows or a window that is no whole number of lane tiles: a server "
+    "whose search fell back says so here), interpret = the kernel emulated "
+    "off-TPU. One-hot for a single-server process, all zero where no live "
+    "server's model selects",
+    labels=("backend",),
+)
 SPARSE_TOKENS_READ = REGISTRY.counter(
     "server_sparse_tokens_read_total",
     "Tokens kept by the selection, the ones a decode step's attention "

@@ -2080,12 +2080,23 @@ def paged_attention_write(
 # the ``topk``-th largest score", that score found by a search over the scores'
 # bits and a tie at it resolved, lower column first, by the same search over the
 # column's (PR 51; ``lax.top_k`` over a slot's ``[4, 9216]`` scores was the
-# largest device operation of a step). ``select_tokens`` is the same choice as
+# largest device operation of a step). The search has TWO FORMS, one algorithm,
+# and ``select_mask`` takes the one its call can observe (``select_path``: the
+# scores' shape and the backend, no argument): a decode step's FEW queries on a
+# TPU run it as ONE Pallas call (``select_topk_tpu``, PR 58: the scores held in
+# VMEM, every pass a turn of a loop in the body, the live rows only — eleven
+# dependent XLA fusions over all four rows were 12.5 us a layer call for a
+# search of one row that takes 3.2); a chunk's ``[256, W]`` queries and every
+# off-TPU call run it as XLA operations, which stay in ``select_mask`` as they
+# were: they are also what the tests hold the kernel to, bit for bit.
+# ``select_tokens`` is the same choice as
 # a sorted LIST (``lax.top_k``, stable): no path of the program runs it — it is
 # the oracle the tests hold the mask to, and with ``select_mask`` one of the two
 # names the benchmark's controls replace (``benchmark/tests/
 # calibrate_keye_vl2.py``, ``test_keye_vl2_block.py``), so every selecting path
-# goes through ``select_mask(scores, topk)``. A decode step hands the mask to the
+# goes through ``select_mask(scores, topk)`` — two arguments, looked up in this
+# module where the step is traced — and the score call and the search stay two
+# calls. A decode step hands the mask to the
 # attention as KEY POSITIONS — a column the query did not choose sits at the
 # sentinel (``selected_attention``) — and ``paged_attention`` runs as it is: it
 # streams the row's live blocks once, where they lie, and its own position test
@@ -2386,6 +2397,133 @@ def _largest_digits(holds, nbits: int, bits: int, batch) -> jnp.ndarray:
     return v
 
 
+#: the search's kernel holds a step's scores and its mask in VMEM whole: this
+#: many bytes of float32 scores at most (16 queries of 32 k columns)
+SELECT_KERNEL_BYTES = 2 << 20
+
+
+def select_path(batch, width: int) -> str:
+    """The form ``select_mask``'s search takes for ``batch`` queries over
+    ``width`` columns, from what the call can observe: ``kernel`` (ONE Pallas
+    call, ``select_topk_tpu``) for a decode step's FEW queries (the count
+    that already chose 3 bits a pass) over whole lane tiles that VMEM holds,
+    on a TPU; ``interpret``, the same emulated, where ``PAGED_FORCE_KERNEL``
+    asks for it; ``xla`` everywhere else — a chunk's many queries, every
+    off-TPU call. The backend is ``ops/moe.resolve_backend``'s: the
+    ``PAGED_FORCE_KERNEL`` override, else the platform."""
+    from .moe import resolve_backend  # moe imports this module
+
+    n = math.prod(batch)
+    if not (0 < n <= 16 and width % 128 == 0
+            and n * width * 4 <= SELECT_KERNEL_BYTES):
+        return "xla"
+    return resolve_backend("auto")
+
+
+def _select_kernel(x_ref, out_ref, *, K: int, nbits: int, bits: int):
+    """``select_mask`` of every row of ``x_ref [B, W]`` into ``out_ref``
+    (int32, 1 = kept): the parent's digit search with everything it reads
+    held in VMEM and every pass — the compares, the count, the next
+    candidate — a turn of a loop INSIDE the body. A row is re-laid once as
+    ``[W / 128, 128]``, WHOLE vregs (nine a thousand columns; the slot's
+    ``[4, W]`` tiles hold a row in every fourth sublane) — as a VALUE: a
+    reshape of the ref compiles too and reads other columns on the chip
+    (PERF.md, PR 58) —, and a row of ``-inf`` alone (a dead row) is zeros
+    for one compare. ``nbits``: a column's bits; ``bits`` a pass."""
+    B, W = x_ref.shape
+    R, lanes = W // 128, 128
+    lowest = jnp.int32(-(2 ** 31))
+    dead_key = jnp.int32(-(2 ** 31) + 0x00800000)  # -inf's key
+
+    def count(hit):  # [R, 128] bool → [1, 1]: a vector, no scalar round trip
+        return jnp.sum(hit.astype(jnp.int32), axis=(0, 1), keepdims=True)
+
+    def largest(holds, n):
+        """``_largest_digits`` for one row: the largest ``n``-bit ``v``
+        ``[1, 1]`` for which ``holds(v)``, ``bits`` a pass from the top
+        (the passes tile the ``n`` bits from bit 0 up: a digit of the first
+        pass that reaches past the top bit holds nothing)."""
+        passes = -(-n // bits)
+
+        def one(i, v):
+            shift = (passes - 1 - i) * bits
+            digit = jnp.zeros((1, 1), jnp.int32)
+            for j in range(1, 1 << bits):
+                fits = shift + j.bit_length() <= n
+                digit += (holds(v | (jnp.int32(j) << shift)) & fits).astype(
+                    jnp.int32)
+            return v | (digit << shift)
+
+        return jax.lax.fori_loop(
+            0, passes, one, jnp.zeros((1, 1), jnp.int32))
+
+    def row(b, carry):
+        x = x_ref[pl.ds(b, 1), :].reshape(R, lanes)
+        raw = jax.lax.bitcast_convert_type(x, jnp.int32)
+        key = jnp.where(raw < 0, lowest - raw, raw)
+
+        def keep(mask):  # the row's mask, back in the slot's layout
+            out_ref[pl.ds(b, 1), :] = mask.astype(jnp.int32).reshape(1, W)
+
+        keep(jnp.zeros((R, lanes), jnp.bool_))
+
+        @pl.when(jnp.max(key) > dead_key)
+        def _search():
+            kth = largest(
+                lambda v: count(key >= (v ^ lowest)) >= K, 32) ^ lowest
+            above = key > kth
+            tie = (key == kth) & (x > -jnp.inf)
+            left = K - count(above)
+            keep(above | tie)
+
+            @pl.when(jnp.sum(tie.astype(jnp.int32))
+                     > K - jnp.sum(above.astype(jnp.int32)))
+            def _lowest_ties():
+                col = (
+                    jax.lax.broadcasted_iota(jnp.int32, (R, lanes), 0) * lanes
+                    + jax.lax.broadcasted_iota(jnp.int32, (R, lanes), 1)
+                )
+                last = largest(
+                    lambda c: count(tie & (col < c)) < left, nbits)
+                keep(above | (tie & (col <= last)))
+
+        return carry
+
+    jax.lax.fori_loop(0, B, row, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("topk", "bits", "interpret"))
+def select_topk_tpu(scores, topk: int, bits: int = 3,
+                    interpret: bool = False):
+    """``select_mask`` of a decode step's ``[B, W]`` float32 scores (``W`` a
+    whole number of lane tiles) as ONE Pallas call: the scores ride in whole,
+    as the score kernel wrote them (147 KiB at Keye's ``[4, 9216]``), and
+    the body walks the rows: a row with a score above ``-inf`` is searched
+    (``bits`` a pass, the passes a loop of the body, not unrolled), a dead
+    row is a compare. The mask leaves as int32 and is a bool again in the
+    fusion that reads it."""
+    B, W = scores.shape
+
+    def whole(shape):
+        return pl.BlockSpec(shape, lambda i: (0,) * len(shape))
+
+    return pl.pallas_call(
+        functools.partial(
+            _select_kernel, K=min(topk, W),
+            nbits=max(W - 1, 1).bit_length(), bits=bits,
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, W), jnp.int32),
+        grid=(1,),
+        in_specs=[whole((B, W))],
+        out_specs=whole((B, W)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+        ),
+        interpret=interpret,
+        name="select_topk",
+    )(scores) != 0
+
+
 @jax.named_scope("select")
 def select_mask(scores: jnp.ndarray, topk: int) -> jnp.ndarray:
     """``select_tokens`` as a mask over the window ``[..., W]``: above the
@@ -2401,10 +2539,25 @@ def select_mask(scores: jnp.ndarray, topk: int) -> jnp.ndarray:
     tie with it than are left to keep, the last COLUMN kept is found the same
     way over the column's bits. Exact: float32 scores, IEEE ``==`` ties (the
     two zeros tie) and the lower column first, as ``lax.top_k``'s stable
-    order has it."""
+    order has it.
+
+    Which form runs is read from the call itself (``select_path``): few
+    queries (a decode step's slot) over whole lane tiles on a TPU — or
+    wherever ``PAGED_FORCE_KERNEL`` asks for the kernel emulated — take ONE
+    Pallas call, ``select_topk_tpu`` (3.2 us a layer call at one live row of
+    four where the operations below take 12.5: PERF.md, PR 58); every other
+    call — a chunk's many queries, the CPU — takes the XLA operations below,
+    which are also the form the tests hold the kernel to. The signature is
+    the seam the benchmark's controls replace: it takes no third argument."""
     W = scores.shape[-1]
     K = min(topk, W)
     batch = scores.shape[:-1]
+    path = select_path(batch, W)
+    if path != "xla":
+        return select_topk_tpu(
+            scores.reshape(-1, W), topk,
+            interpret=path == "interpret",
+        ).reshape(scores.shape)
     # a pass costs its latency plus its compares: few queries (a decode
     # step's slot) take 3 bits a pass, many (a chunk's 256) 2 — timed on the
     # chip at [4, 9216] and [256, 9216] (PERF.md, PR 51)
@@ -2450,7 +2603,11 @@ def selected_attention(
     """A DECODE step's attention under a selection (one query a row): the
     selection is a MASK over the row's columns, and it enters the attention
     as key positions — a column the query did not choose sits at the
-    sentinel, which no query position reaches. ``paged_attention`` takes
+    sentinel, which no query position reaches. The mask is ``select_mask``'s,
+    called with the scores and ``topk`` alone and looked up in this module
+    at trace time (the benchmark's controls replace it by that name): on a
+    TPU a slot's few queries run its search as the kernel ``select_topk``,
+    after the score kernel and as a call of its own. ``paged_attention`` takes
     them as it is: the kernel walks the row's live blocks once, where they
     lie (``_live_blocks`` reads the masked positions: the walk ends at the
     last block that holds a chosen token, and a dead row costs nothing), and

@@ -80,6 +80,7 @@ from ..obs.metrics import (
     DECODE_KV_ENTRIES_WRITTEN,
     KV_KIND_BLOCKS_IN_USE, KV_KIND_BLOCKS_TOTAL, KV_KIND_ENTRY_BYTES,
     KV_WINDOW_BLOCKS_FREED,
+    SELECT_BACKEND, SELECT_BACKENDS,
     SPARSE_TOKENS_LIVE, SPARSE_TOKENS_READ, SPARSE_TOKENS_SCORED,
     SPARSE_TOKENS_WALKED,
     DEFAULT_RATE_BUCKETS,
@@ -248,6 +249,7 @@ def _update_load_gauges() -> None:
     state_backends = dict.fromkeys(RECURRENT_BACKENDS, 0)
     scan_paths = dict.fromkeys(RECURRENT_SCAN_PATHS, 0)
     mixer_steps = dict.fromkeys(RECURRENT_MIXER_STEPS, 0)
+    select_backends = dict.fromkeys(SELECT_BACKENDS, 0)
     arena_bytes = dict.fromkeys(KV_DTYPES, 0)
     for s in list(_LIVE_SERVERS):
         queued += len(s._queue)
@@ -266,6 +268,8 @@ def _update_load_gauges() -> None:
                 scan_paths[s.recurrent_scan_path] += 1
                 if s.recurrent_mixer_step:
                     mixer_steps[s.recurrent_mixer_step] += 1
+            if getattr(s, "sparse", False):
+                select_backends[s.select_backend] += 1
         if getattr(s, "paged", False):
             kv_total += s._alloc.capacity_blocks
             kv_used += s._alloc.in_use
@@ -314,6 +318,8 @@ def _update_load_gauges() -> None:
         RECURRENT_SCAN_PATH.labels(path=path).set(n)
     for path, n in mixer_steps.items():
         RECURRENT_MIXER_STEP.labels(path=path).set(n)
+    for b, n in select_backends.items():
+        SELECT_BACKEND.labels(backend=b).set(n)
     for name, nbytes in arena_bytes.items():
         ARENA_BYTES.labels(dtype=name).set(nbytes)
     KV_BLOCKS_TOTAL.set(kv_total)
@@ -1475,6 +1481,15 @@ class PipelineServer:
                 KV_KIND_ENTRY_BYTES.labels(kind="kv").set(kv_entry)
                 KV_KIND_ENTRY_BYTES.labels(kind="index").set(
                     float(self.cfg.index_cache_dim * item)
+                )
+                # the form a decode step's search takes: select_mask's own
+                # resolution, at a slot's scores [rows, the window's columns]
+                from ..ops.paged_attention import select_path
+
+                self.select_backend = select_path(
+                    (self.batch_per_slot,),
+                    int(self.state.block_tables.shape[-1])
+                    * self.kv_block_size,
                 )
             # host mirror of the device block tables (all-trash at birth);
             # _push_tables ships it whole — [M, T] int32 is a few hundred

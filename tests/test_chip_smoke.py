@@ -294,6 +294,78 @@ def test_index_shape_is_the_cell():
         [(2560, 7.43), (5120, 13.64), (8704, 22.33)], 1) == 2.43
 
 
+#: ``--select``'s shape at toy widths: a slot of four rows over 256 columns
+SELECT_TOY = {"name": "toy", "rows": 4, "width": 256, "topk": 64, "layers": 2,
+              "lives": (1, 4), "contexts": (100, 200)}
+
+
+@pytest.mark.parametrize("form", list(chip_smoke.SELECT_FORMS))
+def test_select_probe_at_toy_widths(form, monkeypatch, tmp_path):
+    """``chip_smoke.py --select`` at toy widths, the kernel in interpret mode:
+    in every form it times — the XLA search, ``select_mask`` as the tree
+    resolves it, the kernel at 1, 2 and 4 bits a pass — one live row and four,
+    with and without zeros of both signs tying across the ``topk``-th score,
+    the mask is ``select_tokens``' set and keeps ``topk`` columns a live row
+    and layer; the traced timing runs end to end (a CPU trace holds no TPU
+    plane, so it reads nothing, and no speed)."""
+    monkeypatch.setattr(chip_smoke, "WORK", str(tmp_path))
+    assert form in chip_smoke.select_forms()
+    for live in SELECT_TOY["lives"]:
+        for ties in (False, True):
+            got = chip_smoke.time_select(
+                SELECT_TOY, live, 200, form, ties, runs=1, interpret=True)
+            assert got["oracle_set"], (live, ties)
+            assert got["kept"] == 2 * live * 64
+            assert (got["us_per_layer_call"], got["kernel_us"],
+                    got["ops_us"]) == (0.0, 0.0, [])
+    # the mask alone (no trace) at a slot of another size, as --select
+    # checks 1, 2, 8 and 16 rows on the chip
+    assert chip_smoke.SELECT_ROWS_CHECKED == (1, 2, 8, 16)
+    got = chip_smoke.time_select(dict(SELECT_TOY, rows=8), 4, 100, form,
+                                 ties=True, runs=0, interpret=True)
+    assert got["oracle_set"] and got["kept"] == 2 * 4 * 64
+
+
+def test_select_shape_is_the_cell():
+    """The shape ``--select`` times is Keye's as its configuration holds it —
+    a slot's rows over the window's columns, the indexer's ``topk``, the
+    depth — at the contexts ``--index-scores`` walks (all past ``topk``: the
+    selection engages), one live row as the cell has and four; the tying
+    input's zeros tie ACROSS the ``topk``-th score; and ``select_mask`` runs
+    that shape as the kernel wherever a TPU is the backend."""
+    import json
+    import os
+    from unittest import mock
+
+    import jax
+    import numpy as np
+
+    from llm_sharding_tpu.ops import paged_attention as pa
+
+    shape = chip_smoke.SELECT_SHAPE
+    with open(os.path.join(chip_smoke.HERE, "benchmark", "configs",
+                           shape["name"] + ".json")) as f:
+        cfg = json.load(f)
+    serve, sa = cfg["serve"], cfg["sa_config"]
+    assert (shape["rows"], shape["width"], shape["topk"], shape["layers"]) == (
+        serve["batch_per_slot"], serve["capacity"], sa["topk"],
+        cfg["num_hidden_layers"])
+    assert shape["contexts"] == chip_smoke.INDEX_SHAPE["contexts"]
+    assert shape["lives"] == (1, shape["rows"])
+    assert chip_smoke.SELECT_FORMS[:2] == ("xla", "own")
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        assert pa.select_path((shape["rows"],), shape["width"]) == "kernel"
+        assert pa.select_path(
+            (1, serve["prefill_chunk"]), shape["width"]) == "xla"
+    toy = dict(SELECT_TOY, layers=1)
+    scores = np.asarray(chip_smoke.select_inputs(toy, 1, 200, ties=True)[
+        "scores"])[0, 0]
+    assert 0 < (scores > 0).sum() < toy["topk"] < (scores == 0).sum()
+    assert (np.signbit(scores) & (scores == 0)).any()
+    assert (~np.signbit(scores) & (scores == 0)).any()
+    assert np.isneginf(scores[200:]).all()
+
+
 def test_store_writer_driver_and_assertions_on_cpu(tmp_path, monkeypatch):
     """The smoke's daemon phase end to end at toy size: seeded store through
     the product's writer, the real ``serve`` daemon as a child, the smoke's

@@ -529,6 +529,133 @@ def test_the_mask_is_the_set_of_the_list(case):
     assert (keep == np.asarray(sorted_mask(jnp.asarray(scores), topk))).all()
 
 
+def _random_slot(shape, live=None, seed=58):
+    scores = np.random.default_rng(seed).standard_normal(shape)
+    scores = scores.astype(np.float32)
+    if live is not None:
+        scores[[b for b in range(shape[0]) if b != live]] = -np.inf
+    return scores
+
+
+def _zeros_of_both_signs(shape=(2, 256), topk=64):
+    # a tenth of the columns positive, the others zeros of either sign: the
+    # topk-th score is a zero and far more columns tie with it than are left
+    rng = np.random.default_rng(59)
+    scores = np.where(rng.random(shape) < 0.5, 0.0, -0.0).astype(np.float32)
+    few = rng.random(shape) < 0.1
+    scores[few] = np.abs(rng.standard_normal(few.sum())) + 1e-3
+    return scores, topk
+
+
+KERNEL_CASES = {
+    "random scores at the cell's [4, 9216]": lambda: (
+        _random_slot((4, 9216)), 2048),
+    "random scores at a small [2, 256]": lambda: (_random_slot((2, 256)), 64),
+    "one live row and three of -inf": lambda: (
+        _random_slot((4, 256), live=2), 64),
+    "fewer finite columns than topk": lambda: (
+        np.where(np.arange(256) < 40, _random_slot((2, 256)), -np.inf), 64),
+    "all columns equal": lambda: (np.full((2, 256), 0.5, np.float32), 64),
+    "zeros of both signs tying across the threshold": _zeros_of_both_signs,
+    "negative scores only": lambda: (-np.abs(_random_slot((2, 256))) - 1, 64),
+    "topk >= W": lambda: (_random_slot((2, 256)), 256),
+    "topk past W": lambda: (_random_slot((2, 128)), 200),
+    "ties at the topk-th place, sixteen rows": lambda: (
+        np.round(_random_slot((16, 128)), 1), 24),
+    # the ``recent`` control's input: the column index as float32
+    "distinct increasing scores": lambda: (
+        np.tile(np.arange(256, dtype=np.float32), (2, 1)), 64),
+    "the cell's slot": _the_cells_slot,
+}
+
+
+def _the_lists_set(scores, topk):
+    """``select_tokens``' real choices as a mask (the two zeros made one for
+    the sort, which reads -0.0 below +0.0)."""
+    cols, real = (np.asarray(a) for a in pa.select_tokens(
+        jnp.asarray(np.where(scores == 0, np.float32(0), scores)), topk))
+    want = np.zeros(scores.shape, bool)
+    np.put_along_axis(want, np.where(real, cols, cols[..., :1]), True, axis=-1)
+    return want & (scores > -np.inf)  # a dead row's first column is no choice
+
+
+@pytest.mark.parametrize("case", list(KERNEL_CASES))
+def test_the_kernels_mask_is_the_set_of_the_list(case):
+    """The search as ONE Pallas call (``select_topk_tpu``, PR 58: what a
+    decode step's few queries run on the chip), emulated: exactly the columns
+    ``select_tokens`` lists, and the XLA search's mask bit for bit — above
+    the ``topk``-th score, the LOWEST columns of a tie with it, never a
+    ``-inf`` column, a dead row all False."""
+    scores, topk = KERNEL_CASES[case]()
+    keep = np.asarray(pa.select_topk_tpu(
+        jnp.asarray(scores), topk, interpret=True))
+    assert keep.dtype == bool and keep.shape == scores.shape
+    assert (keep == _the_lists_set(scores, topk)).all()
+    assert (keep == np.asarray(pa.select_mask(jnp.asarray(scores), topk))).all()
+    live = (scores > -np.inf).sum(axis=-1)
+    assert (keep.sum(axis=-1) == np.minimum(topk, live)).all()
+
+
+def test_the_tying_cases_reach_the_second_search():
+    """More columns tie with the ``topk``-th score than are left to keep in
+    the cases that say so: the kernel's second search (the last column kept)
+    runs there, and only there."""
+    for case, many in (
+        ("zeros of both signs tying across the threshold", True),
+        ("all columns equal", True), ("the cell's slot", True),
+        ("random scores at a small [2, 256]", False),
+    ):
+        scores, topk = KERNEL_CASES[case]()
+        kth = np.sort(scores, axis=-1)[:, -topk][:, None]
+        ties = ((scores == kth) & (scores > -np.inf)).sum(axis=-1)
+        left = topk - (scores > kth).sum(axis=-1)
+        assert (ties > left).any() == many, case
+
+
+@pytest.mark.parametrize("bits", [1, 2, 4])
+def test_the_kernels_mask_at_every_width_of_a_pass(bits):
+    """1, 2 and 4 bits a pass (``chip_smoke.py --select`` times them beside
+    the 3 the kernel takes) find the same set: the passes tile the key's 32
+    bits and a column's from bit 0 up, and a digit that reaches past the top
+    bit holds nothing."""
+    for case in ("zeros of both signs tying across the threshold",
+                 "one live row and three of -inf", "negative scores only"):
+        scores, topk = KERNEL_CASES[case]()
+        keep = np.asarray(pa.select_topk_tpu(
+            jnp.asarray(scores), topk, bits=bits, interpret=True))
+        assert (keep == _the_lists_set(scores, topk)).all(), case
+
+
+def test_the_search_takes_the_form_its_call_can_observe(monkeypatch):
+    """``select_mask`` chooses from the shape and the backend alone: the
+    kernel for a decode step's few queries over whole lane tiles that VMEM
+    holds — emulated where ``PAGED_FORCE_KERNEL`` says so, leading dims and
+    all — and the XLA search for a chunk's many queries, a window that is no
+    whole number of lane tiles, and every call off the TPU."""
+    assert pa.select_path((4,), 9216) == "xla"  # the CPU
+    monkeypatch.setenv("PAGED_FORCE_KERNEL", "interpret")
+    assert pa.select_path((4,), 9216) == "interpret"
+    assert pa.select_path((1, 16), 256) == "interpret"
+    assert pa.select_path((1, 256), 9216) == "xla"  # a chunk's queries
+    assert pa.select_path((4,), 96) == "xla"  # no whole lane tile
+    assert pa.select_path((16,), 1 << 16) == "xla"  # past what VMEM holds
+    assert pa.select_path((0,), 128) == "xla"
+    calls = []
+    kernel = pa.select_topk_tpu
+    monkeypatch.setattr(
+        pa, "select_topk_tpu",
+        lambda s, k, **kw: calls.append((s.shape, kw)) or kernel(s, k, **kw))
+    scores, topk = _negative_at_the_threshold()  # [2, 5, 96]
+    scores = np.pad(scores, [(0, 0), (0, 0), (0, 32)], constant_values=-np.inf)
+    keep = np.asarray(pa.select_mask(jnp.asarray(scores), topk))
+    assert calls == [((10, 128), {"interpret": True})]
+    assert (keep == _the_lists_set(scores, topk)).all()
+    monkeypatch.setenv("PAGED_FORCE_KERNEL", "xla")
+    assert pa.select_path((4,), 9216) == "xla"
+    assert (keep == np.asarray(pa.select_mask(jnp.asarray(scores), topk))).all()
+    assert len(calls) == 1
+
+
 WRONG = {
     "a wrong theta": dict(theta=1e6),
     "the index key's LayerNorm bias dropped": dict(index_bias=False),

@@ -307,3 +307,51 @@ def test_the_search_serves_the_ids_the_sort_served(params, monkeypatch, scores):
         served[name] = [[int(t) for t in r.tokens] for r in reqs]
     assert [len(t) for t in served["search"]] == [40, 40]
     assert served["search"] == served["sort"]
+
+
+def test_the_kernel_search_serves_the_ids_the_xla_search_serves(
+        params, monkeypatch):
+    """Under ``PAGED_FORCE_KERNEL=interpret`` a slot's search runs as the
+    Pallas call (``select_topk``, PR 58) — a decode step's four rows and a
+    chunk's sixteen queries both — and the server says so
+    (``server_select_backend``); the same server with the search held to its
+    XLA form (every other kernel still emulated) serves the same ids, id for
+    id: two rows, one crossing ``topk`` inside its prompt's chunks and one
+    in its reply."""
+    from llm_sharding_tpu.ops import paged_attention as pa
+    from llm_sharding_tpu.runtime.server import _update_load_gauges
+
+    monkeypatch.setenv("PAGED_FORCE_KERNEL", "interpret")
+    rng = np.random.default_rng(58)
+    prompts = [rng.integers(0, 250, size=n).astype(np.int32) for n in (5, 37)]
+    served, searched = {}, {}
+    kernel = pa.select_topk_tpu
+    for form in ("interpret", "xla"):
+        shapes = searched[form] = []
+        monkeypatch.setattr(
+            pa, "select_topk_tpu",
+            lambda s, k, _seen=shapes, **kw: (
+                _seen.append(s.shape) or kernel(s, k, **kw)))
+        if form == "xla":
+            monkeypatch.setattr(pa, "select_path", lambda batch, width: "xla")
+        jax.clear_caches()  # the step programs trace the search they find
+        try:
+            srv = engine(params).serve(**PAGED)
+            assert srv.attn_impl == "interpret"
+            assert srv.select_backend == form
+            reqs = [srv.submit(p, 14) for p in prompts]
+            srv.run_until_idle()
+            _update_load_gauges()
+            text = metrics.REGISTRY.prometheus_text()
+            srv.close()
+        finally:
+            jax.clear_caches()
+        for b in metrics.SELECT_BACKENDS:
+            assert f'server_select_backend{{backend="{b}"}} {int(b == form)}' in text
+        served[form] = [[int(t) for t in r.tokens] for r in reqs]
+    # a decode step's slot of four rows and a chunk's sixteen queries, over
+    # the window's 256 columns
+    assert set(searched["interpret"]) == {(4, 256), (16, 256)}
+    assert searched["xla"] == []
+    assert [len(t) for t in served["interpret"]] == [14, 14]
+    assert served["interpret"] == served["xla"]
