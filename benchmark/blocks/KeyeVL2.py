@@ -225,10 +225,53 @@ def tables(model: dict) -> tuple:
 #   ``bf16_scores`` (the index scores rounded to bfloat16 after they are
 #   summed) reads 0.00476 where its seed's sound run reads 0.00568: inside the
 #   noise of ONE changed rounding, as expected of a choice among near-ties.
-# The limit sits 36% over the largest sound reading (3.4 deviations over the
-# sound mean), a fifteenth of the smaller control. ``DELTA_MAX`` 1.5: 2.3 times
-# the largest sound worst position, under both controls' worst.
-DELTA_MEAN = 0.009
+# PR 49 set 0.009 from those: 36% over the largest reading of ONE request, the
+# cycle's first. A run scores every request the program finishes — one at
+# PR 49's speed, two since PR 50, three since PR 51, a fourth from ~16 s a
+# reply — and about one sound run in eight read over 0.009 (0.0090116 at PR
+# 51, 0.009705 at PR 50; PERF.md sections 2 and 7).
+#
+# Read again on the chip, PR 59 (benchmark/tests/calibrate_keye_vl2.py, which
+# prints a request's own margins; ``sound``, ``--seconds 92``: the cycle's
+# first FIVE requests finish and are scored, 40,960 positions a run; PR 58's
+# program; twelve seeds of the builder's own, chiprun_out/cal59):
+# - mean margin over the first five: 0.004782, 0.005820, 0.005893, 0.006108,
+#   0.006141, 0.006190, 0.006437, 0.006454, 0.006865, 0.007157, 0.007918,
+#   0.008318 (mean 0.0065, deviation 0.0010);
+# - over the first k, the largest of the twelve at k = 1 .. 5: 0.007938,
+#   0.007824, 0.007873, 0.008054, 0.008318 (the smallest 0.002438 .. 0.004782):
+#   whatever count a run finishes, the LARGEST sound reading is 0.008318;
+# - ONE request's own mean 0.000019-0.010830 (the sixty: mean 0.0065; the
+#   largest at each place in the cycle 0.007938, 0.009791, 0.009273, 0.010336,
+#   0.010830; PR 50 read a 512-token prompt's at 0.0121): it follows the seed
+#   as much as the request, and rises through a reply;
+# - worst position of a run 0.486-0.767.
+# The controls that must fail, through the harness at 50 s at two of those
+# seeds, 2147059003 / 3000059801, whose sound runs read 0.006870 / 0.007753
+# over their first three (mean margin of the run; worst position):
+#   ``all`` **0.190960 / 0.165619** (2.30 / 2.42; FOUR requests: its step is
+#   2.1 ms; the first request alone 0.131625 / 0.104832, the smallest reading
+#   of a control at any count);
+#   ``int4_weights`` **0.380700 / 0.457209** (2.55 / 2.43; three requests);
+#   ``recent`` **1.086459 / 1.150422** (5.32 / 5.56; three requests)
+# — each `"correct": false` by the mean and by the worst position.
+# The limit: 0.02. Lower reading 0.008318 (the largest sound one at any count
+# of requests), upper reading 0.104832 (the smallest control's, 12.6 times the
+# lower). 0.02 is 2.4 times the lower (14 deviations over the first-five
+# mean), 2.06 times PR 50's 0.009705 and 1.65 times the largest single request
+# ever read (0.0121: a run that finished only such a request still passes
+# with more than the 25% of room ISSUE 59 asks for); ``all`` fails 8.3 and 9.5
+# times over as a run reads it, and 5.2 times by its first request alone: the
+# largest round limit under which every reading of ``all`` fails five times
+# over, as ISSUE 59 asks (the records expected ~0.016 from ``all`` at 0.136;
+# it reads 0.166-0.191 over four requests, and the room goes above the sound
+# readings: fresh seeds read higher than a dozen did). What it cannot do is
+# what 0.009 could not do either: an fp8 KV state (0.00398-0.01033 above) lies
+# inside the seeds' own band — the arena's type check and the tier-1 logits
+# tests refuse that, not this limit. ``DELTA_MAX`` 1.5 stays: 1.96 times the
+# largest sound worst position of the sixty requests (0.767), under every
+# control run's worst.
+DELTA_MEAN = 0.02
 DELTA_MAX = 1.5
 
 #: query rows of scores the reference holds at a time

@@ -13,7 +13,10 @@ differs in every part, kept at tiny widths for the tests.
 
 A block whose layers are not all alike also gives ``layer_kinds(model)``, one
 kind name per layer; ``kinds`` below is how the shared code asks, and a block
-without it is read exactly as before there was such a thing.
+without it is read exactly as before there was such a thing. A block whose
+layers run several times for one token gives ``passes(model)`` (and, as a
+rule, ``close_pass``); ``passes`` below is how the shared code asks, the same
+way.
 """
 
 from __future__ import annotations
@@ -66,6 +69,28 @@ def kinds(block, model: dict):
     if len(got) != layers:
         raise ValueError(
             f"layer_kinds names {len(got)} layers, the model has {layers}")
+    return got
+
+
+def looped(block) -> bool:
+    """Whether the block says how often its layers run. Such a block's
+    ``logits`` is handed the closed state of every pass, ``[T, rows, H]``,
+    also where T is 1."""
+    return hasattr(block, "passes")
+
+
+def passes(block, model: dict) -> int:
+    """How many times the stack of layers runs for one token: the block's
+    ``passes(model)``, or 1 for a block whose layers run once (it has no
+    ``passes``)."""
+    if not looped(block):
+        return 1
+    got = int(block.passes(model))
+    if got < 1:
+        name = os.path.basename(getattr(block, "__file__", block.__name__))
+        raise ValueError(
+            f"block {name}: passes(model) says the layers run {got} times, "
+            "and they run once at least")
     return got
 
 

@@ -3,8 +3,10 @@
 The plain reference itself — one decoder layer over a whole sequence, the
 embedding, the final norm and logits, and the two thresholds — is the
 block's (``blocks/<model_type>.py``: ``layer_forward``, ``embed``, ``logits``,
-``DELTA_MEAN``, ``DELTA_MAX``; layers are walked in order, and a block with
-``layer_kinds`` is told each layer's ``kind``). It reads nothing from the
+``DELTA_MEAN``, ``DELTA_MAX``; layers are walked in order, ``passes`` times,
+and a block with ``layer_kinds`` is told each layer's ``kind``; a block with
+``passes`` closes each pass itself, ``close_pass``, and its ``logits`` is
+handed every pass's closed state). It reads nothing from the
 program under test; weights come in as plain arrays (an int8 weight as its
 ``(q, scale)`` pair, dequantised here as ``q · scale``), one layer at a time.
 
@@ -61,8 +63,10 @@ def round_kv(x, kv_round):
 
 @functools.partial(jax.jit, static_argnames=("logits", "kw"))
 def margins_from_hidden(h, tables, served, *, logits, kw):
-    """h: [T, H] hidden states at the positions that predict ``served``;
-    ``logits`` is the block's, ``kw`` its static keywords as sorted items."""
+    """h: [rows, H] hidden states at the positions that predict ``served`` —
+    for a block with ``passes`` [T, rows, H], the closed state of every pass,
+    among which its ``logits`` chooses; ``logits`` is the block's, ``kw`` its
+    static keywords as sorted items."""
     with jax.default_matmul_precision("highest"):
         out = logits(h, tables, **dict(kw))
         best = jnp.max(out, axis=-1)
@@ -84,7 +88,14 @@ def hidden_states(block, model: dict, get_layer, tables: dict,
     gives layer l's leaves on the device (of whatever kind layer l is); only
     one layer is resident at a time. Layers are the outer loop, so each is
     fetched once for all sequences. ``overrides`` (tests only) replace a
-    keyword of the block's ``layer_forward``, e.g. a wrong ``theta``."""
+    keyword of the block's ``layer_forward``, e.g. a wrong ``theta``.
+
+    A block with ``passes`` has the SAME layers walked that many times, each
+    fetched again in every pass and told nothing of the pass (a whole
+    sequence needs no cache, so a pass attends its own keys). The block's
+    ``close_pass`` closes each pass: its result enters the next one and is
+    that pass's closed state. All of them are returned, [T, S_padded, H] a
+    sequence."""
     kinds = blocks.kinds(block, model)
     head_kw = block.head_static(model)
     hidden = []
@@ -92,13 +103,22 @@ def hidden_states(block, model: dict, get_layer, tables: dict,
         ids = np.asarray(ids, np.int32)
         padded = jnp.asarray(np.pad(ids, (0, -len(ids) % PAD_TO)))
         hidden.append(block.embed(tables, padded, **head_kw))
-    for l in range(block.dims(model)["layers"]):
-        # a block with ``layer_kinds`` is told which kind layer l is
-        kw = dict(blocks.static_of(block, model, kinds, l), **overrides)
-        p = _as_ref_layer(get_layer(l))
-        hidden = [block.layer_forward(h, p, **kw) for h in hidden]
-        del p
-    return hidden
+    looped = blocks.looped(block)
+    close = getattr(block, "close_pass", None) if looped else None
+    closed = []  # of a block with ``passes``: every pass's closed states
+    for step in range(blocks.passes(block, model)):
+        for l in range(block.dims(model)["layers"]):
+            # a block with ``layer_kinds`` is told which kind layer l is
+            kw = dict(blocks.static_of(block, model, kinds, l), **overrides)
+            p = _as_ref_layer(get_layer(l))
+            hidden = [block.layer_forward(h, p, **kw) for h in hidden]
+            del p
+        if close is not None:
+            hidden = [close(h, tables, step=step, **head_kw) for h in hidden]
+        closed.append(hidden)
+    if not looped:
+        return hidden
+    return [jnp.stack(of_seq) for of_seq in zip(*closed)]
 
 
 def score(block, model: dict, get_layer, tables: dict, samples: list) -> dict:
@@ -112,7 +132,7 @@ def score(block, model: dict, get_layer, tables: dict, samples: list) -> dict:
     )
     margins, agree = [], []
     for (n_prompt, n_out), h, (_, served) in zip(seqs, hidden, samples):
-        rows = h[n_prompt - 1 : n_prompt - 1 + n_out]
+        rows = h[..., n_prompt - 1 : n_prompt - 1 + n_out, :]
         m, best = margins_from_hidden(
             rows, tables, jnp.asarray(np.asarray(served, np.int32)),
             logits=block.logits, kw=kw,

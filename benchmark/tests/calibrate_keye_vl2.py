@@ -11,7 +11,12 @@ It changes the program in memory (nothing on disk, no option of the program)
 and then runs the cell as ``run.py`` does — same traffic, same window, same
 sample of scored requests, so the reading stands beside a sound run's at the
 same count of positions. The result line's ``correct`` is the verdict under
-the limits as they stand. Modes:
+the limits as they stand. Every mode also prints each scored request's own
+margins on standard error (``request 2: ...``, in the order the cycle sent
+them, with the mean over the first k beside it): a run scores as many requests
+as the program finishes — one at PR 49's speed, three since PR 51 — and the
+limit has to hold whatever that count is (``--seconds 92`` finishes the
+cycle's first five at PR 58's speed). Modes:
 
 - ``sound``: nothing changed (the control of the controls).
 - ``recent``: a query keeps the most recent ``topk`` keys in place of the
@@ -143,6 +148,28 @@ def patch(mode: str) -> None:
         raise SystemExit(f"mode {mode!r}: one of {MODES}")
 
 
+def print_by_request() -> None:
+    """Each scored request's margins beside the harness's pooled reading:
+    ``reference.score`` scores the sample in the order it was submitted."""
+    import numpy as np
+
+    from benchmark import reference
+
+    margins, seen = reference.margins_from_hidden, []
+
+    def each(h, tables, served, **kw):
+        m, best = margins(h, tables, served, **kw)
+        seen.append(np.asarray(m))
+        agree = np.asarray(best) == np.asarray(served)
+        print(f"request {len(seen)}: positions {seen[-1].size} margin_mean "
+              f"{seen[-1].mean():.6f} margin_max {seen[-1].max():.4f} argmax "
+              f"{agree.mean():.4f}; the first {len(seen)}: margin_mean "
+              f"{np.concatenate(seen).mean():.6f}", file=sys.stderr, flush=True)
+        return m, best
+
+    reference.margins_from_hidden = each
+
+
 def long_prefill(n_prompt: int, seed: int, config: str, new: int = 256) -> None:
     """One prompt of ``n_prompt`` tokens through the cell's own server, the
     ``new`` tokens decoded after it scored under the reference. ``config``:
@@ -194,4 +221,5 @@ if __name__ == "__main__":
         seed = int(sys.argv[sys.argv.index("--seed") + 1])
         long_prefill(n, seed, config)
     else:
+        print_by_request()
         runpy.run_path(os.path.join(BENCH, "run.py"), run_name="__main__")

@@ -145,31 +145,53 @@ JUDGED = {
 }
 
 
-@pytest.mark.parametrize("cell", list(JUDGED))
+STEP_STEMS = {"decode_step_ms", "decode_hbm_pct", "device_idle_pct",
+              "host_ms_per_step"}
+
+
+def judged_in(cell):
+    """The end-to-end metrics ``BENCHMARK.json`` has ``cell`` report: those
+    without a list, and those whose list names it."""
+    return sorted(m["name"] for m in BENCHMARK["end_to_end"]
+                  if cell in m.get("workloads", [cell]))
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in BENCHMARK["workloads"]])
 def test_a_cell_reports_what_it_is_judged_on_and_what_moves_that(cell):
-    """``olmoe_1b_7b.backlog`` is judged on tokens per second and set-up, not
-    on a 95th percentile that lies on the edge between one live row and two
-    (PERF.md section 2). A per-layer metric is read in the cells its list
-    names, or without a list in every cell that reports the end-to-end metric
-    it moves — so each cell reports what its per-layer metrics move, and the
-    quantities of the decode step are entries of their own (``.backlog``,
-    moving ``out_tok_s``) in the cell that reports no ``itl_p95_ms``."""
-    assert [w["name"] for w in BENCHMARK["workloads"]] == list(JUDGED)
+    """Every cell there is, and any that is added: ``JUDGED`` is a FLOOR for
+    PR 32's four (their judged sets as written, the four still first and in
+    that order); every other cell's judged set is read from ``BENCHMARK.json``
+    and held to the rules. ``olmoe_1b_7b.backlog`` is judged on tokens per
+    second and set-up, not on a 95th percentile that lies on the edge between
+    one live row and two (PERF.md section 2). A per-layer metric is read in
+    the cells its list names, or without a list in every cell that reports
+    the end-to-end metric it moves — so each cell reports what its per-layer
+    metrics move, and the quantities of the decode step are entries of their
+    own (``.backlog``, moving ``out_tok_s``) in the cell that reports no
+    ``itl_p95_ms``."""
+    names = [w["name"] for w in BENCHMARK["workloads"]]
+    assert names[:len(JUDGED)] == list(JUDGED) and len(set(names)) == len(names)
+    judged = judged_in(cell)
+    assert judged == JUDGED.get(cell, judged)
+    # set-up and at least one thing a user of the cell would feel
+    assert "setup_s" in judged and len(judged) >= 2
     e2e, layer, _ = _readers(cell)
-    assert sorted(e2e) == JUDGED[cell]
+    assert sorted(e2e) == judged
     moves = {m["name"]: m["moves"] for m in BENCHMARK["per_layer"]}
     assert layer and all(moves[name] in e2e for name in layer)
     stems = {name.split(".")[0] for name in layer}
-    assert {"decode_step_ms", "decode_hbm_pct", "device_idle_pct",
-            "host_ms_per_step"} <= stems
+    assert STEP_STEMS <= stems  # the decode step's four, under some suffix
+    why = next(w["why"] for w in BENCHMARK["workloads"] if w["name"] == cell)
+    assert 0 < len(why) <= 200 and "\n" not in why and "\t" not in why
     if cell == "olmoe_1b_7b.backlog":
-        assert all(name.endswith(".backlog") for name in layer)
+        # (set-up's account, PR 41, moves ``setup_s`` and has no suffix)
+        assert all(name.endswith(".backlog") for name in layer
+                   if moves[name] != "setup_s")
         assert {"decode_moe_pct", "moe_hbm_pct", "experts_read_per_layer"} <= stems
-        why = next(w["why"] for w in BENCHMARK["workloads"] if w["name"] == cell)
-        assert "out_tok_s" in why and "5%" not in why and len(why) <= 200
+        assert "out_tok_s" in why and "5%" not in why
     for m in BENCHMARK["per_layer"]:  # a listed cell reports what is moved
-        for name in m.get("workloads", ()):
-            assert m["moves"] in JUDGED[name], (m["name"], name)
+        if cell in m.get("workloads", ()):
+            assert m["moves"] in judged, (m["name"], cell)
 
 
 def test_lengths_follow_the_mix():
@@ -371,6 +393,14 @@ def test_reduction_of_a_recorded_trace():
 MODEL = harness.model_keys(TINY)
 BLOCK = blocks.load(TINY["model_type"])
 GPT2_BLOCK = blocks.load(TINY_GPT2["model_type"], os.path.join(HERE, "blocks"))
+LOOP_BLOCK = blocks.load("qwen2_loop", os.path.join(HERE, "blocks"))
+
+
+def loop_model(passes, threshold=1.0, close="final_norm", **more):
+    """The tiny Qwen2 model run ``passes`` times (``tests/blocks/qwen2_loop.py``;
+    the keys are named as Ouro's configuration names them)."""
+    return dict(MODEL, model_type="qwen2_loop", total_ut_steps=passes,
+                early_exit_threshold=threshold, loop_close=close, **more)
 
 
 def tiny_weights(seed=3, dtype="int8"):
@@ -388,7 +418,8 @@ def greedy(tables, get, prompt, n, block=BLOCK, model=MODEL, **wrong):
     for _ in range(n):
         (h,) = reference.hidden_states(block, model, get, tables, [ids], **wrong)
         _, best = reference.margins_from_hidden(
-            h[len(ids) - 1 : len(ids)], tables, jnp.zeros((1,), jnp.int32),
+            h[..., len(ids) - 1 : len(ids), :], tables,
+            jnp.zeros((1,), jnp.int32),
             logits=block.logits, kw=kw)
         out.append(int(best[0]))
         ids.append(out[-1])
@@ -502,6 +533,20 @@ def test_bytes_of_a_decode_step():
     assert GPT2_BLOCK.decode_step_bytes(g, "bf16", 1, 10.0) == (
         4 * GPT2_BLOCK.layer_weight_bytes(g, "bf16") + 2 * H * 512
         + 4 * 10.0 * 2 * H * 2)
+    # a looped block counts its passes itself: the layers and the live K/V
+    # three times (a pass keeps keys and values of its own), the head once
+    loop, once = loop_model(3), loop_model(1)
+    per_layer = BLOCK.layer_weight_bytes(MODEL, "int8")
+    kv = roofline.kv_bytes_per_token_layer(BLOCK.dims(MODEL))
+    head = roofline.head_bytes(BLOCK.dims(MODEL))
+    assert LOOP_BLOCK.dims(loop)["layers"] == 4  # ONE pass's layers
+    assert LOOP_BLOCK.decode_step_bytes(loop, "int8", 1, 10.0) == (
+        3 * 4 * per_layer + head + 3 * 4 * 10.0 * kv)
+    assert LOOP_BLOCK.decode_step_bytes(once, "int8", 1, 10.0) == (
+        BLOCK.decode_step_bytes(MODEL, "int8", 1, 10.0))
+    assert LOOP_BLOCK.decode_step_bytes(loop, "bf16", 2, 10.0) == (
+        3 * 2 * BLOCK.layer_weight_bytes(MODEL, "bf16") + head / 2
+        + 3 * 2 * 10.0 * kv)
 
 
 # ------------------------------------------------------------------ the run
@@ -878,3 +923,227 @@ def test_the_rehearsal_builds_the_abstract_tree_per_kind(monkeypatch):
     monkeypatch.undo()
     one = aot.abstract_inputs(TINY, pipeline_mesh(1, jax.devices()[:1]))[1]
     assert one["wq"].q.shape == (1, 4, 128, 128)  # no kinds: as ever
+
+
+# ------------------------------------------- layers that run several times
+
+def loop_weights(model, seed=3, dtype="int8", block=LOOP_BLOCK):
+    """``(params, tables, get_layer)`` of a looped model; ``get_layer`` is the
+    one ``harness.check`` builds."""
+    params = weights.make_params(block, model, seed, dtype, jax.devices()[:1])
+    tables = {t.name: params[t.name] for t in block.tables(model)}
+    kinds = blocks.kinds(block, model)
+    get = lambda l: weights.take_layer(params["layers"], kinds, l)
+    return params, tables, get
+
+
+def three_prompts():
+    rng = np.random.default_rng(0)
+    return [rng.integers(0, 512, size=n, dtype=np.int32) for n in (12, 20, 31)]
+
+
+def three_passes_by_hand(get, tables, prompt, n):
+    """n greedy tokens of the tiny model run THREE times, written out: three
+    loops over the same four layers, the final norm after each, the head over
+    the third pass's normed state (the gate's threshold at 1). Nothing of
+    ``reference.py`` but ``dequant`` inside the Qwen2 layer."""
+    kw = BLOCK.layer_static(MODEL)
+    gain = jnp.asarray(tables["final_norm"], jnp.float32)
+    head = jnp.asarray(tables["lm_head"], jnp.float32)
+    layers = [{k: (tuple(v) if isinstance(v, tuple) else v)
+               for k, v in get(l).items()} for l in range(4)]
+
+    def norm(x):
+        return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + 1e-6) * gain
+
+    ids, out = list(prompt), []
+    for _ in range(n):
+        padded = np.pad(np.asarray(ids, np.int32), (0, -len(ids) % 256))
+        h = jnp.asarray(tables["embed"], jnp.float32)[padded]
+        for l in range(4):
+            h = BLOCK.layer_forward(h, layers[l], **kw)
+        h = norm(h)  # closes pass 0 and enters pass 1
+        for l in range(4):
+            h = BLOCK.layer_forward(h, layers[l], **kw)
+        h = norm(h)
+        for l in range(4):
+            h = BLOCK.layer_forward(h, layers[l], **kw)
+        h = norm(h)
+        with jax.default_matmul_precision("highest"):
+            out.append(int(jnp.argmax(h[len(ids) - 1] @ head)))
+        ids.append(out[-1])
+    return np.asarray(out, np.int32)
+
+
+@pytest.mark.parametrize("dtype", ["bf16", "int8"])
+def test_one_pass_closed_by_the_identity_is_the_one_pass_model(dtype):
+    """(a) ``passes`` of 1 and a close that does nothing: the layers and the
+    shared tables are the Qwen2 model's bit for bit (the gate's two tables
+    are drawn after them), and the margins of the three prompts are equal to
+    the last digit though ``logits`` was handed ``[1, rows, H]``."""
+    model = loop_model(1, close="identity")
+    params, tables, get = loop_weights(model, 3, dtype)
+    one, one_tables, one_get = tiny_weights(3, dtype)
+    assert digests(params["layers"]) == digests(one["layers"])
+    assert digests({k: params[k] for k in one_tables}) == digests(
+        {k: one[k] for k in one_tables})
+    assert set(tables) - set(one_tables) == {"exit_gate", "exit_bias"}
+    served = [(p, greedy(one_tables, one_get, p, 6)) for p in three_prompts()]
+    (h,) = reference.hidden_states(LOOP_BLOCK, model, get, tables, [served[0][0]])
+    assert h.shape == (1, 256, 128)
+    a = reference.score(LOOP_BLOCK, model, get, tables, served)
+    assert a == reference.score(BLOCK, MODEL, one_get, one_tables, served)
+    assert a["margin_max"] == 0.0
+    # and a block without ``passes`` is asked nothing new
+    assert blocks.passes(BLOCK, MODEL) == 1 and not blocks.looped(BLOCK)
+    assert blocks.passes(LOOP_BLOCK, loop_model(4)) == 4
+
+
+def test_three_passes_written_out_by_hand_score_correct_and_fewer_do_not(
+        monkeypatch):
+    """(b) tokens decoded by a forward written out in this file — three loops
+    over the SAME layers, the norm between — score a worst margin of 0.0
+    through ``harness.check``, on one chip and through a ring's staged host
+    copy, alike. (c) The same tokens are NOT correct under two passes, with
+    the close dropped, or with the close after the last pass only."""
+    model = loop_model(3)
+    cfg = dict(TINY, **model)
+    _, tables, get = loop_weights(model)
+    served = [(p, three_passes_by_hand(get, tables, p, 6))
+              for p in three_prompts()]
+    # the layers are fetched again in every pass, one resident at a time
+    fetched = []
+    scored = reference.score(
+        LOOP_BLOCK, model, lambda l: fetched.append(l) or get(l), tables,
+        served)
+    assert fetched == [0, 1, 2, 3] * 3
+    # (``harness.check`` frees every array of the process before it starts)
+    right = harness.check(cfg, LOOP_BLOCK, 3, jax.devices()[:1], None, served)
+    assert right["margin_max"] == 0.0 and reference.verdict(right, LOOP_BLOCK)
+    assert right["positions"] == 18 and right["argmax_share"] == 1.0
+    assert right == scored
+    host = weights.to_host(weights.make_params(
+        LOOP_BLOCK, model, 3, "int8", jax.devices()[:2]))
+    ring = harness.check(cfg, LOOP_BLOCK, 3, jax.devices()[:2], host, served)
+    assert ring == right
+
+    def check(cfg=cfg):
+        return harness.check(cfg, LOOP_BLOCK, 3, jax.devices()[:1], None, served)
+
+    two = check(dict(TINY, **loop_model(2)))
+    assert not reference.verdict(two, LOOP_BLOCK), two
+    close = LOOP_BLOCK.close_pass
+    monkeypatch.setattr(
+        LOOP_BLOCK, "close_pass",
+        lambda h, t, *, step, **kw: close(h, t, step=step, **kw)
+        if step == 2 else h)
+    last_only = check()
+    assert not reference.verdict(last_only, LOOP_BLOCK), last_only
+    monkeypatch.delattr(LOOP_BLOCK, "close_pass")
+    dropped = check()
+    assert not reference.verdict(dropped, LOOP_BLOCK), dropped
+    monkeypatch.undo()
+    assert check() == right
+
+
+def test_the_block_chooses_among_the_passes():
+    """(d) ``logits`` is handed every pass's closed state. With the toy
+    gate's threshold at 1 its logits are the last pass's to the last digit;
+    at 0.5 some scored positions leave at an earlier pass, tokens served from
+    the last pass are not correct there, and tokens decoded under the gate
+    are."""
+    late, early = loop_model(3), loop_model(3, threshold=0.5)
+    _, tables, get = loop_weights(late)
+    prompts = three_prompts()
+    from_last = [(p, greedy(tables, get, p, 6, LOOP_BLOCK, late))
+                 for p in prompts]
+    assert all(np.array_equal(s, three_passes_by_hand(get, tables, p, 6))
+               for p, s in from_last[:1])
+    hidden = reference.hidden_states(
+        LOOP_BLOCK, late, get, tables, [np.concatenate(x) for x in from_last])
+    rows = jnp.concatenate([h[:, len(p) - 1 : len(p) + 5]
+                            for h, (p, _) in zip(hidden, from_last)], axis=1)
+    assert rows.shape == (3, 18, 128)
+    kw = LOOP_BLOCK.head_static(late)
+    with jax.default_matmul_precision("highest"):
+        chosen = LOOP_BLOCK.logits(rows, tables, **kw)
+        last = LOOP_BLOCK.logits(rows[-1:], tables, **kw)
+        gated = LOOP_BLOCK.logits(rows, tables, **LOOP_BLOCK.head_static(early))
+    assert np.array_equal(np.asarray(chosen), np.asarray(last))
+    at = np.asarray(LOOP_BLOCK.exit_pass(rows, tables, 0.5))
+    assert (at < 2).sum() >= 1 and len(set(at.tolist())) > 1, at
+    assert np.array_equal(np.asarray(LOOP_BLOCK.exit_pass(rows, tables, 1.0)),
+                          np.full(18, 2))
+    with jax.default_matmul_precision("highest"):
+        of_pass = np.stack([np.asarray(LOOP_BLOCK.logits(
+            rows[t : t + 1], tables, **kw)) for t in range(3)])
+    assert np.array_equal(np.asarray(gated), of_pass[at, np.arange(18)])
+    assert (np.asarray(gated) != np.asarray(chosen)).any(axis=-1)[at < 2].all()
+    score = lambda model, served: reference.score(
+        LOOP_BLOCK, model, get, tables, served)
+    assert reference.verdict(score(late, from_last), LOOP_BLOCK)
+    wrong = score(early, from_last)
+    assert not reference.verdict(wrong, LOOP_BLOCK), wrong
+    under_gate = [(p, greedy(tables, get, p, 6, LOOP_BLOCK, early))
+                  for p in prompts]
+    ok = score(early, under_gate)
+    assert ok["margin_max"] == 0.0 and reference.verdict(ok, LOOP_BLOCK)
+    assert not reference.verdict(score(late, under_gate), LOOP_BLOCK)
+
+
+def test_a_block_whose_layers_run_no_times_dies_naming_the_block():
+    """(e)"""
+    with pytest.raises(ValueError, match=r"qwen2_loop\.py.*run 0 times"):
+        blocks.passes(LOOP_BLOCK, loop_model(0))
+    _, tables, get = loop_weights(loop_model(1))
+    with pytest.raises(ValueError, match="qwen2_loop"):
+        reference.score(LOOP_BLOCK, loop_model(-1), get, tables,
+                        [(np.arange(5), np.arange(3))])
+
+
+def loop_of_kinds():
+    """A looped block WITH kinds, put together here: the layers of
+    ``tests/blocks/qwen2_kinds.py``, the passes, close, tables and logits of
+    ``tests/blocks/qwen2_loop.py``."""
+    import types
+
+    both = types.ModuleType("benchmark_block_qwen2_loop_kinds")
+    for name in ("dims", "layer_kinds", "layer_leaves", "layer_static",
+                 "layer_forward", "DELTA_MEAN", "DELTA_MAX"):
+        setattr(both, name, getattr(KINDS_BLOCK, name))
+    for name in ("passes", "close_pass", "tables", "head_static", "embed",
+                 "logits", "decode_step_bytes"):
+        setattr(both, name, getattr(LOOP_BLOCK, name))
+    return both
+
+
+def test_kinds_and_passes_compose(monkeypatch):
+    """(f) a looped block with two kinds: every pass walks the layers in
+    layer order, each told its kind and taken from its kind's stack. Tokens
+    decoded so score correct through ``harness.check`` — and not under the
+    kinds permuted, nor under one pass."""
+    both = loop_of_kinds()
+    model = loop_model(3, layer_types=["plain", "biased", "plain", "biased"])
+    cfg = dict(TINY, **model)
+    params, tables, get = loop_weights(model, block=both)
+    assert sorted(params["layers"]) == ["biased", "plain"]
+    assert params["layers"]["plain"]["wq"].q.shape[0] == 2  # drawn ONCE
+    walked = []
+    forward = both.layer_forward
+    monkeypatch.setattr(
+        both, "layer_forward",
+        lambda h, p, *, kind, **kw: walked.append(kind) or forward(
+            h, p, kind=kind, **kw))
+    prompts = three_prompts()
+    served = [(p, greedy(tables, get, p, 4, both, model)) for p in prompts[:1]]
+    assert walked[:12] == ["plain", "biased"] * 6
+    right = harness.check(cfg, both, 3, jax.devices()[:1], None, served)
+    assert right["margin_max"] == 0.0 and reference.verdict(right, both)
+    monkeypatch.setattr(both, "layer_kinds",
+                        lambda m: tuple(m["layer_types"])[::-1])
+    wrong = harness.check(cfg, both, 3, jax.devices()[:1], None, served)
+    assert not reference.verdict(wrong, both), wrong
+    monkeypatch.undo()
+    once = harness.check(dict(cfg, total_ut_steps=1), both, 3,
+                         jax.devices()[:1], None, served)
+    assert not reference.verdict(once, both), once
