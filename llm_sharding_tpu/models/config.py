@@ -199,6 +199,20 @@ class ModelConfig:
     zero_experts: int = 0
     mla_q_scale: float = 1.0
     mla_kv_scale: float = 1.0
+    # ``ouro`` (the llama block again, ``models/llama.py``): a LOOPED stack.
+    # The same ``num_hidden_layers`` layers run ``passes`` times for every
+    # token, the final norm closing EVERY pass (its result enters the next);
+    # pass ``t`` attends pass ``t``'s keys, so the cache holds ``arena_slots``
+    # = ``passes`` entries a token and layer (slot ``t · L + l``). An exit
+    # gate over the passes' closed states (``exit_gate`` ``[H]`` and
+    # ``exit_bias`` in the head's tables) chooses the pass the head reads:
+    # the first at which the running exit probability reaches
+    # ``exit_threshold``, else the last — every pass runs whatever it says.
+    # ``out_norms``: a norm on each branch's OUTPUT before the residual add
+    # (leaves ``attn_out_norm`` / ``mlp_out_norm``), beside the two on its input.
+    passes: int = 1
+    exit_threshold: float = 1.0
+    out_norms: bool = False
     # GPT-2 specifics
     layer_norm_epsilon: float = 1e-5
     # Token ids. ``eos_token_ids`` holds ALL stop ids (Llama-3.x instruct
@@ -351,9 +365,12 @@ class ModelConfig:
         """Cache / arena layer slots ONE layer fills: a ``longcat_flash``
         layer runs two attentions, each over a latent entry of its own, so a
         stage of ``Lp`` layers has ``arena_slots · Lp`` slots (layer ``l``
-        writes and reads slots ``2l`` and ``2l + 1``). Every place that sizes
-        or walks a cache's layer axis multiplies by this."""
-        return 2 if self.model_type == "longcat_flash" else 1
+        writes and reads slots ``2l`` and ``2l + 1``); a looped stack
+        (``passes``) keeps keys and values of its own for every pass, pass
+        major (pass ``t`` of layer ``l`` of ``Lp``: slot ``t · Lp + l``).
+        Every place that sizes or walks a cache's layer axis multiplies by
+        this."""
+        return 2 if self.model_type == "longcat_flash" else self.passes
 
     @property
     def router_experts(self) -> int:
@@ -474,6 +491,9 @@ class ModelConfig:
         elif mt == "KeyeVL2":
             hf, moe = cls._keye_vl2_keys(hf)
             mt = "llama"
+        elif mt == "ouro":
+            hf, moe = cls._ouro_keys(hf)
+            mt = "llama"
         else:
             moe = {}
         if mt == "deepseek_v3":
@@ -562,6 +582,41 @@ class ModelConfig:
                 eos_token_id=hf.get("eos_token_id", 50256),
             )
         raise ValueError(f"unsupported model_type: {mt!r}")
+
+    @staticmethod
+    def _ouro_keys(hf: dict[str, Any]) -> tuple:
+        """Ouro (ByteDance's LoopLM family) as the llama block's keys: Qwen2's
+        key names but NO projection bias (the released ``OuroAttention``), four
+        norms a layer, ``total_ut_steps`` passes over the same layers and an
+        exit gate at ``early_exit_threshold``. ``max_window_layers`` is
+        accepted and not read (every layer attends in full). What the block
+        cannot honour is refused by name."""
+        for key in ("total_ut_steps", "early_exit_threshold"):
+            if key not in hf:
+                raise ValueError(f"ouro config.json lacks {key!r}")
+        T, theta = int(hf["total_ut_steps"]), float(hf["early_exit_threshold"])
+        if T < 1:
+            raise ValueError(
+                f"ouro total_ut_steps {T}: the layers run once at least"
+            )
+        if not 0.0 < theta <= 1.0:
+            raise ValueError(
+                f"ouro early_exit_threshold {theta} is not in (0, 1]: it is "
+                "compared with a running sum of exit probabilities"
+            )
+        if hf.get("use_sliding_window") or hf.get("sliding_window"):
+            raise ValueError(
+                "ouro sliding-window attention is not supported: every pass "
+                "of every layer attends its whole context"
+            )
+        other = sorted(set(hf.get("layer_types") or ()) - {"full_attention"})
+        if other:
+            raise ValueError(
+                f"ouro layer_types {other}: only 'full_attention' layers are "
+                "supported"
+            )
+        moe = dict(passes=T, exit_threshold=theta, out_norms=True)
+        return dict(hf, model_type="llama", attention_bias=False), moe
 
     @staticmethod
     def _keye_vl2_keys(hf: dict[str, Any]) -> tuple:
@@ -1784,6 +1839,36 @@ def tiny_longcat_flash(**kw) -> ModelConfig:
     latent attentions of 4 heads, ``v_head_dim`` != ``qk_nope_head_dim``, both
     latent scales on), 8 real + 4 zero-compute experts, 3 a token, scale 6."""
     return ModelConfig.from_hf_config(tiny_longcat_flash_keys(**kw))
+
+
+def tiny_ouro_keys(**kw) -> dict:
+    """The published-style keys of ``tiny_ouro`` (Ouro's own names)."""
+    base = dict(
+        model_type="ouro",
+        vocab_size=256,
+        hidden_size=64,
+        intermediate_size=128,
+        num_hidden_layers=3,
+        num_attention_heads=4,
+        num_key_value_heads=4,
+        head_dim=16,
+        max_position_embeddings=128,
+        rms_norm_eps=1e-6,
+        rope_theta=1000000.0,
+        layer_types=["full_attention"] * 3,
+        use_sliding_window=False,
+        sliding_window=None,
+        total_ut_steps=3,
+        early_exit_threshold=1.0,
+    )
+    base.update(kw)
+    return base
+
+
+def tiny_ouro(**kw) -> ModelConfig:
+    """Tiny ouro-layout config for CPU tests: 3 sandwich-norm layers run 3
+    times a token, an exit gate over the passes."""
+    return ModelConfig.from_hf_config(tiny_ouro_keys(**kw))
 
 
 def tiny_qwen2(**kw) -> ModelConfig:

@@ -31,7 +31,7 @@ from ..ops.quant import embed_rows, head_logits, out_dim, qmatmul, tied_logits
 from ..ops.rope import apply_rope, rope_cos_sin
 from .cache import KVCache
 from .config import ModelConfig
-from .stack import scan_layers
+from .stack import close_tables, run_passes, scan_layers
 
 Params = dict[str, Any]
 
@@ -101,6 +101,11 @@ def init_layer_params(
             k_idx_norm=jnp.ones((L, DI), dtype),
             k_idx_bias=jnp.zeros((L, DI), dtype),
         )
+    if cfg.out_norms:
+        # a norm on each branch's OUTPUT too (Ouro's sandwich); keyed by
+        # presence like the biases below
+        p["attn_out_norm"] = jnp.ones((L, H), dtype)
+        p["mlp_out_norm"] = jnp.ones((L, H), dtype)
     if cfg.attention_bias:
         # qkv biases (the Qwen2-family layout: q/k/v biased, o not); presence
         # of the keys — not the flag — drives the forward path, so converted
@@ -123,6 +128,16 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     if not cfg.tie_word_embeddings:
         params["lm_head"] = (
             jax.random.normal(k_head, (H, V), jnp.float32) * H**-0.5
+        ).astype(dtype)
+    if cfg.passes > 1:
+        # a looped stack's exit gate over the passes' closed states
+        # (``stack.run_passes``): a linear map H -> 1 with a bias
+        k_gate, k_bias = jax.random.split(jax.random.fold_in(key, 3))
+        params["exit_gate"] = (
+            jax.random.normal(k_gate, (H,), jnp.float32) * H**-0.5
+        ).astype(dtype)
+        params["exit_bias"] = (
+            0.1 * jax.random.normal(k_bias, (1,), jnp.float32)
         ).astype(dtype)
     return params
 
@@ -209,8 +224,13 @@ def attn_mlp_block(
             kx = kx + p["bk"]
         if "bv" in p:
             vx = vx + p["bv"]
-        if cfg.qk_norm_per_head:
-            # (q too where the heads are split before the norm: see below)
+        if cfg.qk_norm_per_head or ("bq" not in p and "q_norm" not in p):
+            # (q too where the heads are split before the norm, or where
+            # nothing — no bias, no norm over the whole width — stands
+            # between the dot and the head split: see below. At 16 heads of
+            # 128 the whole stack of ``wq``, 403 MB, was re-laid every call
+            # and a layer's slice copied again before its dot: 3.6 ms of a
+            # 33.4 ms step, PERF.md PR 60)
             qx = jax.lax.optimization_barrier(qx)
         # k and v leave the projection as the dot made them. Without this
         # edge XLA folds the head split below into the two small dots: a
@@ -263,6 +283,14 @@ def attn_mlp_block(
             attn_out = jax.lax.psum(attn_out, tp_axis)
         if "bo" in p:  # row-parallel bias: added ONCE, after the psum
             attn_out = attn_out + p["bo"]
+        if "attn_out_norm" in p:
+            # a norm on the branch's OUTPUT (Ouro), keyed by presence: over
+            # the whole width, so after the psum
+            with jax.named_scope("norm"):
+                attn_out = rms_norm(
+                    attn_out, p["attn_out_norm"], cfg.rms_norm_eps,
+                    cfg.norm_offset,
+                )
         h = h + attn_out
 
     with jax.named_scope("norm"):
@@ -296,6 +324,11 @@ def attn_mlp_block(
         mlp = gated_mlp(cfg, p, x)
         if tp_axis is not None:
             mlp = jax.lax.psum(mlp, tp_axis)
+        if "mlp_out_norm" in p:
+            with jax.named_scope("norm"):
+                mlp = rms_norm(
+                    mlp, p["mlp_out_norm"], cfg.rms_norm_eps, cfg.norm_offset
+                )
         return h + mlp, None
 
 
@@ -490,6 +523,7 @@ def forward_layers_paged(
     #   arena/table are per-shard; see paged_decoder_layer)
     moe_live: Optional[jnp.ndarray] = None,  # [B, S] bool — a model with
     #   experts: the positions that route (dead rows and pads route nowhere)
+    close=None,  # a looped stack: what closes a pass (``stack.close_tables``)
 ):
     """Paged counterpart of ``forward_layers`` for the serve decode path:
     scans the layer stack over the pooled arena (``stack.scan_layers_paged``)
@@ -498,7 +532,10 @@ def forward_layers_paged(
     unquantized; kpos bookkeeping stays with the caller. ``stats`` is None
     for a dense model, else its ``MoeStats`` stacked over layers (``[L, E]``
     tokens per expert, ``[L]`` distinct experts read; zero at masked
-    layers)."""
+    layers). A looped stack (``cfg.passes`` > 1) runs the scan once a pass
+    over that pass's arena slots (``stack.run_passes``): ``h`` is then the
+    closed state the exit gate chose and ``stats`` the pass it came from,
+    ``[B, S]`` int32."""
     from .stack import scan_layers_paged
 
     with jax.named_scope("rope"):
@@ -521,10 +558,33 @@ def forward_layers_paged(
             cp_axis=cp_axis, moe_live=moe_live, index_rope=index_rope,
         )
 
-    return scan_layers_paged(
-        layers, h, k_arena, v_arena, apply, layer_mask,
-        k_scale=k_scale, v_scale=v_scale,
+    if cfg.passes == 1:
+        return scan_layers_paged(
+            layers, h, k_arena, v_arena, apply, layer_mask,
+            k_scale=k_scale, v_scale=v_scale,
+        )
+    layer_mask, L = _stack_mask(layers, layer_mask)
+
+    def one_pass(first, h, arenas):
+        h, *arenas, _ = scan_layers_paged(
+            layers, h, *arenas[:2], apply, layer_mask,
+            k_scale=arenas[2], v_scale=arenas[3], first_layer=first,
+        )
+        return h, tuple(arenas)
+
+    h, arenas, exit_pass = run_passes(
+        cfg, close, h, (k_arena, v_arena, k_scale, v_scale), one_pass, L
     )
+    return (h, *arenas, exit_pass)
+
+
+def _stack_mask(layers: Params, layer_mask):
+    """``(layer_mask, L)`` of a stack of ``L`` layers (all of them real where
+    no mask came)."""
+    if layer_mask is None:
+        L = jax.tree.leaves(layers)[0].shape[0]
+        return jnp.ones((L,), bool), L
+    return layer_mask, layer_mask.shape[0]
 
 
 def forward_layers(
@@ -537,6 +597,7 @@ def forward_layers(
     tp_axis: Optional[str] = None,
     moe_live: Optional[jnp.ndarray] = None,  # [B, S] bool (experts only):
     #   the positions that route; None = all of them
+    close=None,  # a looped stack: what closes a pass (``stack.close_tables``)
 ):
     """Run ``h`` through a stack of decoder layers via ``lax.scan``.
 
@@ -546,7 +607,11 @@ def forward_layers(
     splits"). ``tp_axis`` turns on explicit megatron TP inside every layer
     (weights and KV cache must carry the matching local head slices).
     Returns ``(h, cache, stats)``; ``stats`` is None for a dense model,
-    else the ``MoeStats`` stacked over layers.
+    else the ``MoeStats`` stacked over layers. A looped stack (``cfg.passes``
+    > 1) runs the scan once a pass over that pass's cache slots, every pass
+    at the step's one write offset (``stack.run_passes``): ``h`` is then the
+    closed state the exit gate chose and ``stats`` the pass it came from,
+    ``[B, S]`` int32.
     """
     with jax.named_scope("rope"):
         cos, sin = rope_cos_sin(positions, cfg, dtype=jnp.float32)
@@ -557,7 +622,23 @@ def forward_layers(
             tp_axis, moe_live=moe_live,
         )
 
-    return scan_layers(layers, h, cache, positions, apply, layer_mask)
+    if cfg.passes == 1:
+        return scan_layers(layers, h, cache, positions, apply, layer_mask)
+    layer_mask, L = _stack_mask(layers, layer_mask)
+
+    def one_pass(first, h, kv):
+        # every pass writes at the step's offset: the cache as it came in
+        h, new, _ = scan_layers(
+            layers, h, cache._replace(k=kv[0], v=kv[1]), positions, apply,
+            layer_mask, first_layer=first,
+        )
+        return h, (new.k, new.v, new.pos, new.length)
+
+    h, (k, v, pos, length), exit_pass = run_passes(
+        cfg, close, h,
+        (cache.k, cache.v, cache.pos, cache.length + h.shape[1]), one_pass, L,
+    )
+    return h, KVCache(k=k, v=v, pos=pos, length=length), exit_pass
 
 
 def final_logits(cfg: ModelConfig, params: Params, h: jnp.ndarray) -> jnp.ndarray:
@@ -567,7 +648,10 @@ def final_logits(cfg: ModelConfig, params: Params, h: jnp.ndarray) -> jnp.ndarra
     Tied checkpoints carry no ``lm_head`` array — the projection contracts
     against the embedding table directly (XLA folds the transpose into the
     matmul; no duplicate vocab×hidden buffer in HBM)."""
-    h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps, cfg.norm_offset)
+    if cfg.passes == 1:  # (a looped stack's last pass is closed already)
+        h = rms_norm(
+            h, params["final_norm"], cfg.rms_norm_eps, cfg.norm_offset
+        )
     if "lm_head" in params:
         return head_logits(h, params["lm_head"])
     return tied_logits(h, params["embed"])
@@ -586,5 +670,8 @@ def forward(
     h = embed(params, token_ids)
     if cfg.embed_multiplier != 1.0:  # gemma: hidden scaled by sqrt(H)
         h = h * jnp.asarray(cfg.embed_multiplier, h.dtype)
-    h, cache, _ = forward_layers(cfg, params["layers"], h, cache, positions)
+    h, cache, _ = forward_layers(
+        cfg, params["layers"], h, cache, positions,
+        close=close_tables(cfg, params),
+    )
     return final_logits(cfg, params, h), cache

@@ -89,6 +89,79 @@ def masked_stats(stats, valid):
     return jax.tree.map(lambda a: jnp.where(valid, a, jnp.zeros_like(a)), stats)
 
 
+def _slot(i, first_layer):
+    """Layer ``i`` of a stack that fills the slots ``first_layer …``."""
+    if isinstance(first_layer, int) and first_layer == 0:
+        return i
+    return i + first_layer
+
+
+def close_tables(cfg, tables: dict):
+    """What closes a looped stack's pass, out of the head's tables: the final
+    norm's gain and the exit gate (``[H]`` and its bias). None for a stack
+    that runs once — the keyword every stage function takes as ``close``."""
+    if cfg.passes == 1:
+        return None
+    return {k: tables[k] for k in ("final_norm", "exit_gate", "exit_bias")}
+
+
+def run_passes(cfg, close: dict, h: jnp.ndarray, carry, one_pass,
+               num_layers: int):
+    """The ONE loop over a looped stack's passes (``cfg.passes`` = T > 1):
+    ``one_pass(first_slot, h, carry) -> (h, carry)`` runs the stack's layers
+    once over the cache slots ``first_slot …`` (pass ``t`` of ``num_layers``
+    layers: ``t · num_layers``, traced), and every pass is CLOSED by the
+    final norm, whose result enters the next pass and is that pass's closed
+    state ``s_t``.
+
+    The exit gate reads each closed state: ``g_t = sigmoid(s_t · exit_gate +
+    exit_bias)``, ``p_t = g_t · Π_{u<t} (1 - g_u)`` (the last pass takes what
+    is left), and a position's output is the closed state of the FIRST pass
+    at which the running sum of ``p`` reaches ``cfg.exit_threshold``, else of
+    the last. Every pass runs whatever the gate says (later tokens attend
+    every pass's keys); the choice is kept as it goes — the chosen state, the
+    survival product, the running sum and the exit pass — so no ``[T, B, S,
+    H]`` stack is built. Returns ``(chosen [B, S, H], carry, exit_pass [B, S]
+    int32)``."""
+    from ..ops.norms import rms_norm
+
+    if close is None:
+        raise ValueError(
+            f"a looped stack ({cfg.passes} passes) needs what closes a pass: "
+            "hand the stage function close=close_tables(cfg, tables)"
+        )
+    T, L = cfg.passes, num_layers
+    gate = close["exit_gate"].astype(jnp.float32)
+    bias = close["exit_bias"].astype(jnp.float32).reshape(())
+
+    def body(t, c):
+        h, carry, chosen, stay, cum, exit_pass = c
+        h, carry = one_pass(t * L, h, carry)
+        with jax.named_scope("pass_close"):
+            h = rms_norm(
+                h, close["final_norm"], cfg.rms_norm_eps, cfg.norm_offset
+            )
+            g = jax.nn.sigmoid(
+                jnp.sum(h.astype(jnp.float32) * gate, axis=-1) + bias
+            )
+            cum = cum + jnp.where(t == T - 1, stay, g * stay)
+            stay = stay * (1.0 - g)
+            take = (exit_pass < 0) & (
+                (cum >= cfg.exit_threshold) | (t == T - 1)
+            )
+            chosen = jnp.where(take[..., None], h, chosen)
+            exit_pass = jnp.where(take, t, exit_pass)
+        return h, carry, chosen, stay, cum, exit_pass
+
+    rows = h.shape[:2]
+    _, carry, chosen, _, _, exit_pass = jax.lax.fori_loop(
+        0, T, body,
+        (h, carry, jnp.zeros_like(h), jnp.ones(rows, jnp.float32),
+         jnp.zeros(rows, jnp.float32), jnp.full(rows, -1, jnp.int32)),
+    )
+    return chosen, carry, exit_pass
+
+
 def scan_layers(
     layers,
     h: jnp.ndarray,
@@ -96,11 +169,12 @@ def scan_layers(
     positions: jnp.ndarray,
     apply_layer: ApplyLayerFn,
     layer_mask: Optional[jnp.ndarray] = None,
-    first_layer: int = 0,
+    first_layer=0,
 ):
     """Returns ``(h, cache, stats)``. ``layers`` fill the cache's layer slots
     ``first_layer …`` (one kind's stack of a model with several,
-    ``kind_spans``); ``layer_mask`` is theirs."""
+    ``kind_spans``; a looped stack's pass, ``run_passes``: a traced offset);
+    ``layer_mask`` is theirs."""
     S = h.shape[1]
     layers, whole = split_whole(layers)
     L = cache.num_layers if layer_mask is None else layer_mask.shape[0]
@@ -122,7 +196,7 @@ def scan_layers(
     def body(carry, xs):
         h, k_all, v_all = carry
         p, i, valid = xs
-        l = i + first_layer if first_layer else i  # the cache's layer slot
+        l = _slot(i, first_layer)  # the cache's layer slot
         with jax.named_scope("kv_take"):
             k_row = jax.lax.dynamic_index_in_dim(k_all, l, keepdims=False)
             v_row = jax.lax.dynamic_index_in_dim(v_all, l, keepdims=False)
@@ -163,8 +237,9 @@ def scan_layers_paged(
     layer_mask: Optional[jnp.ndarray] = None,
     k_scale: Optional[jnp.ndarray] = None,  # [L, NB, Nkv] f32 per-block-
     v_scale: Optional[jnp.ndarray] = None,  # per-head scales (quantized)
-    first_layer: int = 0,  # ``layers`` fill the arena's layer slots from
-    #   here on (one kind's stack, ``kind_spans``); ``layer_mask`` is theirs
+    first_layer=0,  # ``layers`` fill the arena's layer slots from here on
+    #   (one kind's stack, ``kind_spans``; a looped stack's pass,
+    #   ``run_passes``: a traced offset); ``layer_mask`` is theirs
 ):
     """Paged analogue of ``scan_layers``: the cache is the pooled block
     arena, and a layer's update is the tiny block-indexed write of this
@@ -199,7 +274,7 @@ def scan_layers_paged(
     def body(carry, xs):
         h, k_all, v_all, ks_all, vs_all = carry
         p, i, valid = xs
-        l = i + first_layer if first_layer else i  # the arena's layer slot
+        l = _slot(i, first_layer)  # the arena's layer slot
         h_new, k_all, v_all, ks_all, vs_all, stats = apply_layer(
             join_whole(p, whole, i), l, valid, h, k_all, v_all, ks_all, vs_all
         )

@@ -879,6 +879,19 @@ MOE_ZERO_PAIRS = REGISTRY.counter(
     "input): they form no tile, read nothing and cost a scaled add; 0 for "
     "a model without such experts",
 )
+# a looped model (``cfg.passes`` > 1: the same layers run several times a
+# token, an exit gate over the passes' closed states chooses the one the head
+# reads): nothing is counted — no label is made — for a model whose layers
+# run once
+EXIT_PASS = REGISTRY.counter(
+    "server_exit_pass_total",
+    "A looped model: tokens whose logits were read from pass `pass` (the "
+    "first at which the exit gate's running probability reached the "
+    "model's threshold, else the last; \"0\" .. str(passes - 1)), from the "
+    "step programs' logs; every pass runs for every token whatever the gate "
+    "says",
+    labels=("pass",),
+)
 MOE_EXPERTS_READ = REGISTRY.gauge(
     "server_moe_experts_read",
     "A model with sparse experts: mean distinct experts read per layer per "

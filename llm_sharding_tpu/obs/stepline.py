@@ -180,6 +180,9 @@ SCOPES = (
     "kda",        # the decay's softplus and exp, the L2 norms of q and k, the
                   # state update (a decode step) or the chunkwise WY form (a
                   # prefill chunk), the read-out, the gated per-head norm
+    "pass_close", # a looped stack's close of a pass: the final norm whose
+                  # result enters the next pass, the exit gate's product and
+                  # sigmoid, the running exit choice (models/stack.py)
     "head",       # final norm + this stage's logit slice
     "sample",     # argmax assembly / per-row sampling over the logits
     "ring_hop",   # stage->stage ppermute and the last stage's broadcast
@@ -290,7 +293,7 @@ class StepRecord:
         "decode_blocks_live", "decode_blocks_reserved",
         "prefill_cells_live", "prefill_cells_walked", "kv_kinds",
         "prefill_kv_blocks", "decode_kv_entries", "recurrent_rows",
-        "scan_positions", "sparse_tokens", "seq", "t0", "end",
+        "scan_positions", "sparse_tokens", "exit_passes", "seq", "t0", "end",
         "starved_hi_s", "logs", "dispatches", "after_landing",
     )
 
@@ -366,6 +369,9 @@ class StepRecord:
         # tokens whose K/V blocks the attention streamed}`` (host arithmetic
         # at dispatch, from the length mirrors)
         self.sparse_tokens = None
+        # a looped model (None otherwise): tokens applied in this step by
+        # the pass their logits were read from, ``[passes]``
+        self.exit_passes = None
         # the token's path (module docstring). ``seq`` is the number the
         # step's ``serve.step`` annotation carries as ``step_num``; ``t0``
         # the clock at its begin; every other time an offset from ``t0``.
@@ -432,6 +438,8 @@ class StepRecord:
             d["scan_positions"] = dict(self.scan_positions)
         if self.sparse_tokens is not None:
             d["sparse_tokens"] = dict(self.sparse_tokens)
+        if self.exit_passes is not None:
+            d["exit_passes"] = list(self.exit_passes)
         if self.expert_tokens is not None:
             d["expert_tokens"] = list(self.expert_tokens)
             d["experts_read"] = list(self.experts_read)
@@ -488,6 +496,7 @@ class StepProfiler:
         self._recurrent_rows = None
         self._scan_positions = None  # {"real" | "pad": positions}
         self._sparse_tokens = None  # {"scored" | "read" | "live" | "walked": n}
+        self._exit_passes = None  # [passes] tokens by exit pass
         self._kv_kinds = None
         self._phases: Dict[str, float] = {}
         self._blocked_s = 0.0
@@ -617,6 +626,7 @@ class StepProfiler:
         self._recurrent_rows = None
         self._scan_positions = None
         self._sparse_tokens = None
+        self._exit_passes = None
         self._kv_kinds = None
         work = bool(rows or queued or pending)
         if self._annotate is not None and (work or self._had_work):
@@ -804,6 +814,14 @@ class StepProfiler:
             acc[key] = acc.get(key, 0) + int(n)
         self._sparse_tokens = acc
 
+    def exit_passes(self, counts) -> None:
+        """Add the tokens of a fetched log to the step's record by the pass
+        their logits were read from (a looped model): ``counts`` [passes]."""
+        if not self._enabled or self._t0 is None:
+            return
+        acc = self._exit_passes or [0] * len(counts)
+        self._exit_passes = [a + int(b) for a, b in zip(acc, counts)]
+
     def experts(self, tokens, read=None, steps: int = 0, rows: int = 0) -> None:
         """Add a fetched set of expert counters to the step's record:
         ``tokens`` [E] per expert; for decode microsteps also ``read`` [L]
@@ -944,6 +962,7 @@ class StepProfiler:
         rec.recurrent_rows = self._recurrent_rows
         rec.scan_positions = self._scan_positions
         rec.sparse_tokens = self._sparse_tokens
+        rec.exit_passes = self._exit_passes
         if self._experts is not None:
             tokens, read, rec.expert_steps, rec.expert_rows = self._experts
             rec.expert_tokens, rec.experts_read = tokens, read or []

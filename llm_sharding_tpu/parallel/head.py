@@ -180,6 +180,10 @@ def _local_logits(
             h_last, head["final_norm"], head["final_norm_bias"],
             cfg.layer_norm_epsilon,
         )
+    elif cfg.passes > 1:
+        # a looped stack hands on a CLOSED state: the final norm closed its
+        # every pass, the last one too (``models/stack.run_passes``)
+        x = h_last
     else:
         x = rms_norm(h_last, head["final_norm"], cfg.rms_norm_eps,
                      cfg.norm_offset)

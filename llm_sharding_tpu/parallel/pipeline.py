@@ -50,6 +50,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..models import gpt2, llama
 from ..models.cache import KVCache, POS_SENTINEL
 from ..models.config import ModelConfig
+from ..models.stack import close_tables
 from ..ops.quant import base
 from ..ops.sampling import is_stop as _is_stop, validate_top_p
 from .head import (
@@ -156,8 +157,14 @@ def model_fns(
     # ``moe_live`` ([B, S] bool; a model with experts only): the positions
     # that route. Both stage fns return the layers' stats as their LAST
     # result (``MoeStats``; None for a dense model — models/stack.py).
-    def stage(cfg_, layers, h, cache, positions, mask, moe_live=None):
+    # ``close`` (a looped stack only, ``stack.close_tables``): what closes a
+    # pass; the stage then hands on the closed state its exit gate chose and,
+    # as its stats, the pass that came from (``[B, S]`` int32).
+    def stage(cfg_, layers, h, cache, positions, mask, moe_live=None,
+              close=None):
         kw = {} if moe_live is None else {"moe_live": moe_live}
+        if close is not None:
+            kw["close"] = close
         return fwd(
             cfg_, layers, h, cache, positions, mask, tp_axis=tp_axis, **kw
         )
@@ -165,10 +172,12 @@ def model_fns(
     def stage_paged(cfg_, layers, h, k_arena, v_arena, tbl, cols, kv_pos,
                     positions, mask, write_valid=True, backend="auto",
                     k_scale=None, v_scale=None, prefill=False, walk=None,
-                    moe_live=None):
+                    moe_live=None, close=None):
         kw = {} if cp_axis is None else {"cp_axis": cp_axis}
         if moe_live is not None:
             kw["moe_live"] = moe_live
+        if close is not None:
+            kw["close"] = close
         return fwd_paged(
             cfg_, layers, h, k_arena, v_arena, tbl, cols, kv_pos,
             positions, mask, write_valid=write_valid, tp_axis=tp_axis,
@@ -233,12 +242,27 @@ def _tree_where(pred, new, old):
     return jax.tree.map(lambda a, b: jnp.where(pred, a, b), new, old)
 
 
-def moe_stats_zero(cfg: ModelConfig, num_layers: int):
+def refuse_looped_ring(cfg: ModelConfig, num_stages: int) -> None:
+    """A looped stack over a ring of stages is refused by name: a token
+    would lap the ring once a pass (the chains' microstep count, the
+    interleaved schedule's slots), which no schedule here does."""
+    if cfg.passes > 1 and num_stages > 1:
+        raise NotImplementedError(
+            f"a looped stack ({cfg.passes} passes over the same layers) over "
+            f"a ring of {num_stages} stages is not implemented: a token would "
+            "lap the ring once a pass — serve it with num_stages=1"
+        )
+
+
+def moe_stats_zero(cfg: ModelConfig, num_layers: int, h=None):
     """An all-zero ``MoeStats`` stacked over ``num_layers`` layers: what the
     ring chains start their sums from. None for a dense model (an empty
-    pytree: no leaf joins its loop carry)."""
+    pytree: no leaf joins its loop carry); a looped stack's stats are the
+    exit pass a position of ``h``."""
     from ..ops.moe import MoeStats
 
+    if cfg.passes > 1:
+        return jnp.zeros(h.shape[:2], jnp.int32)
     if not cfg.num_experts:
         return None
     return MoeStats(
@@ -248,19 +272,22 @@ def moe_stats_zero(cfg: ModelConfig, num_layers: int):
 
 
 def ring_chain(fns, cfg, layers, lmask, sidx, ring, num_stages, h, cache,
-               positions, moe_live=None):
+               positions, moe_live=None, close=None):
     """One full trip around the ring: each stage applies its layer slice on
     its active microstep, then the block hops to the next device
     (≙ one traversal of the reference's device chain,
     ``node_worker.py:541-543``). Shared by the sequential pipeline and the
     interleaved scheduler's prefill. Returns ``(h, cache, stats)``: this
     stage's ``MoeStats`` of its active microstep, stacked over its layers
-    (None for a dense model); ``moe_live`` names the positions that route."""
+    (None for a dense model); ``moe_live`` names the positions that route.
+    ``close``: a looped stack's (``model_fns``; one stage only)."""
+    refuse_looped_ring(cfg, num_stages)
 
     def micro(m, carry):
         h, cache, stats = carry
         h_new, cache_new, stats_new = fns.stage(
-            cfg, layers, h, cache, positions, lmask, moe_live=moe_live
+            cfg, layers, h, cache, positions, lmask, moe_live=moe_live,
+            close=close,
         )
         active = m == sidx
         h = jnp.where(active, h_new, h)
@@ -274,14 +301,15 @@ def ring_chain(fns, cfg, layers, lmask, sidx, ring, num_stages, h, cache,
         return h, cache, stats
 
     return jax.lax.fori_loop(
-        0, num_stages, micro, (h, cache, moe_stats_zero(cfg, lmask.shape[0]))
+        0, num_stages, micro,
+        (h, cache, moe_stats_zero(cfg, lmask.shape[0], h)),
     )
 
 
 def ring_chain_paged(fns, cfg, layers, lmask, sidx, ring, num_stages, h,
                      k_arena, v_arena, tbl, cols, kv_positions, positions,
                      backend="auto", k_scale=None, v_scale=None,
-                     prefill=False, walk=None, moe_live=None):
+                     prefill=False, walk=None, moe_live=None, close=None):
     """``ring_chain`` over the pooled paged arena (the serve programs'
     kernel decode path): the per-microstep activity gate moves from a
     whole-cache ``_tree_where`` (which would copy the ARENA — the whole
@@ -297,7 +325,8 @@ def ring_chain_paged(fns, cfg, layers, lmask, sidx, ring, num_stages, h,
     built it for all layers (``prefill_walk``) — the ``stage_paged``-style
     prefill traversal behind ``serve_prefill_chunk``. The sixth result is
     this stage's ``MoeStats`` as in ``ring_chain`` (an inactive microstep
-    routes nowhere and counts nothing)."""
+    routes nowhere and counts nothing). ``close`` as in ``ring_chain``."""
+    refuse_looped_ring(cfg, num_stages)
 
     def micro(m, carry):
         h, ka, va, ks, vs, stats = carry
@@ -306,7 +335,7 @@ def ring_chain_paged(fns, cfg, layers, lmask, sidx, ring, num_stages, h,
             cfg, layers, h, ka, va, tbl, cols, kv_positions, positions,
             lmask, write_valid=active, backend=backend,
             k_scale=ks, v_scale=vs, prefill=prefill, walk=walk,
-            moe_live=moe_live,
+            moe_live=moe_live, close=close,
         )
         h = jnp.where(active, h_new, h)
         with jax.named_scope("ring_hop"):
@@ -316,7 +345,7 @@ def ring_chain_paged(fns, cfg, layers, lmask, sidx, ring, num_stages, h,
     return jax.lax.fori_loop(
         0, num_stages, micro,
         (h, k_arena, v_arena, k_scale, v_scale,
-         moe_stats_zero(cfg, lmask.shape[0])),
+         moe_stats_zero(cfg, lmask.shape[0], h)),
     )
 
 
@@ -439,7 +468,8 @@ def _pipeline_generate_jit(
 
         def chain(h, cache, positions):
             return ring_chain(
-                fns, cfg, layers, mask, sidx, ring, num_stages, h, cache, positions
+                fns, cfg, layers, mask, sidx, ring, num_stages, h, cache,
+                positions, close=close_tables(cfg, hd),
             )[:2]
 
         # ---- prefill (≙ receive_user_request → chain traversal,

@@ -344,6 +344,12 @@ def interleaved_generate(
     temperature, top_k, top_p, seed=seeds[r])`` tokens exactly (the same
     key-chain contract as the serve path). ``top_k``/``top_p`` are dynamic
     per-row values — mixed filter settings share one compiled program."""
+    if cfg.passes > 1:
+        raise NotImplementedError(
+            f"the interleaved schedule over a looped stack ({cfg.passes} "
+            "passes) is not implemented: its slots advance one stage a "
+            "microstep, and a looped token would lap the ring once a pass"
+        )
     prompts = jnp.asarray(prompts, jnp.int32)
     if prompts.ndim == 1:
         prompts = prompts[None]

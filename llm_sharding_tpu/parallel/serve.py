@@ -42,6 +42,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.cache import KVCache, POS_SENTINEL
 from ..models.config import ARENA_KINDS, RECURRENT_KINDS, ModelConfig
+from ..models.stack import close_tables
 from ..obs.metrics import REGISTRY
 from ..ops.paged_attention import prefill_walk, window_from_blocks
 from ..ops.quant import is_kv_quantized, kv_dequantize, kv_qmax, kv_quantize
@@ -296,6 +297,16 @@ def _moe_counts(stats, live, sidx, num_stages):
         return jax.lax.psum(vec, PIPE_AXIS)
 
 
+def pass_log_width(cfg: ModelConfig, rows: int) -> int:
+    """Columns a LOOPED model (``cfg.passes`` > 1) appends to what the host
+    already fetches, after a model with experts' counters: the pass each of
+    the ``rows`` committed tokens' logits were read from (the exit gate's
+    choice, ``models/stack.run_passes``), -1 beside a row that committed
+    nothing. 0 for a model whose layers run once: its programs return what
+    they always did."""
+    return rows if cfg.passes > 1 else 0
+
+
 def _slot_tables(st, row0, Bs):
     """The slot rows' block tables — a windowed model's a PAIR, full then
     window (``models/mimo_v2.forward_layers_paged`` takes pairs)."""
@@ -459,7 +470,8 @@ def make_state(
     # treatment as dense mode's [M, 1] block-table stub)
     quantized = paged and is_kv_quantized(cache_dtype)
     scale_shape = (
-        (S, Lp, cp * kv_blocks, cfg.cache_heads) if quantized
+        (S, Lp * cfg.arena_slots, cp * kv_blocks, cfg.cache_heads)
+        if quantized
         else (S, 1, 1, 1)
     )
     dev_scale = (
@@ -581,6 +593,7 @@ def prefix_prefill(
             fns, cfg, layers, lmask, sidx, ring, num_stages, h, cache,
             positions,
             moe_live=(positions != POS_SENTINEL) if cfg.num_experts else None,
+            close=close_tables(cfg, hd),
         )
         return cache.k[None], cache.v[None], cache.pos[None]
 
@@ -898,7 +911,7 @@ def serve_admit(
         )
         h, cache, moe_stats = ring_chain(
             fns, cfg, layers, lmask, sidx, ring, num_stages, h, cache,
-            positions, moe_live=moe_live,
+            positions, moe_live=moe_live, close=close_tables(cfg, hd),
         )
         h_last = jnp.take_along_axis(
             h, (prompt_len - 1)[:, None, None], axis=1
@@ -1031,11 +1044,18 @@ def serve_admit(
             lambda spec, leaf: leaf[None] if _dev(spec) else leaf,
             state_specs(state, tp, cp, quantized, bool(block_size)), new,
         )
-        if moe_stats is not None:
+        if cfg.num_experts:
             # the experts' counters ride the array the host fetches anyway
             tok0 = jnp.concatenate(
                 [tok0, _moe_counts(moe_stats, moe_live, sidx, num_stages)]
             )
+        if cfg.passes > 1:
+            # ... and a looped model's exit pass of each first token
+            # (``moe_stats``: the pass a position's closed state came from)
+            exit0 = jnp.take_along_axis(
+                moe_stats, (prompt_len - 1)[:, None], axis=1
+            )[:, 0]
+            tok0 = jnp.concatenate([tok0, jnp.where(row_valid, exit0, -1)])
         return new, tok0
 
     specs = state_specs(
@@ -1253,7 +1273,7 @@ def serve_prefill_chunk(
                 *_arenas(st, row0, reset), tbl, cols, kv_pos, positions,
                 backend=attn,
                 k_scale=ks, v_scale=vs, prefill=True, walk=walk,
-                moe_live=moe_live,
+                moe_live=moe_live, close=close_tables(cfg, hd),
             )
             if quantized:
                 scale_upd = {"k_scale": ks_new, "v_scale": vs_new}
@@ -1274,7 +1294,7 @@ def serve_prefill_chunk(
             h = sp_embed(cfg, hd, tokens, positions)
             h, cache, moe_stats = ring_chain(
                 fns, cfg, layers, lmask, sidx, ring, num_stages, h, cache,
-                positions, moe_live=moe_live,
+                positions, moe_live=moe_live, close=close_tables(cfg, hd),
             )
             k_new = jax.lax.dynamic_update_slice_in_dim(
                 st.k, cache.k, row0, axis=1
@@ -1304,7 +1324,7 @@ def serve_prefill_chunk(
             state_specs(state, tp, cp, quantized, bool(block_size)), new,
         )
         counts = jax.lax.psum(counts, PIPE_AXIS)
-        if moe_stats is not None:
+        if cfg.num_experts:
             counts = jnp.concatenate(
                 [_moe_counts(moe_stats, moe_live, sidx, num_stages), counts]
             )
@@ -1529,6 +1549,7 @@ def serve_chunk(
         layers = jax.tree.map(lambda a: a[0], stage_layers)
         lmask = layer_mask[0]
         hd = local_view(head_params)
+        close = close_tables(cfg, hd)  # a looped stack's; else None
         sidx = jax.lax.axis_index(PIPE_AXIS)
         st = jax.tree.map(
             lambda spec, leaf: leaf[0] if _dev(spec) else leaf,
@@ -1608,7 +1629,7 @@ def serve_chunk(
                     backend=attn,
                     k_scale=s.k_scale if quantized else None,
                     v_scale=s.v_scale if quantized else None,
-                    moe_live=moe_live,
+                    moe_live=moe_live, close=close,
                 )
                 scale_upd = (
                     {"k_scale": ks_st, "v_scale": vs_st} if quantized
@@ -1626,7 +1647,7 @@ def serve_chunk(
                 )
                 h_new, cache_r_new, moe_stats = fns.stage(
                     cfg, layers, h_in, cache_r, pos_rows[:, None], lmask,
-                    moe_live=moe_live,
+                    moe_live=moe_live, close=close,
                 )
                 k_st = upd(s.k, cache_r_new.k, 1)
                 v_st = upd(s.v, cache_r_new.v, 1)
@@ -1718,11 +1739,17 @@ def serve_chunk(
             inject_pending = s.inject_pending.at[clear0].set(False)
 
             log_i = jnp.where(commit, nxt, -1)  # [Bs] this microstep's commits
-            if moe_stats is not None:
+            if cfg.num_experts:
                 log_i = jnp.concatenate([
                     log_i,
                     _moe_counts(moe_stats, moe_live, sidx, num_stages),
                 ])
+            if cfg.passes > 1:
+                # a looped model: the pass each committed token's logits
+                # were read from (one stage: this device served the rows)
+                log_i = jnp.concatenate(
+                    [log_i, jnp.where(commit, moe_stats[:, 0], -1)]
+                )
 
             new_s = s._replace(
                 **_arena_upd(s, k_st, v_st), kpos=kpos_st, h=h_out,
@@ -1742,7 +1769,8 @@ def serve_chunk(
 
         log0 = jnp.full(
             (n_micro,
-             Bs + moe_log_width(cfg, num_stages, layer_mask.shape[1])),
+             Bs + moe_log_width(cfg, num_stages, layer_mask.shape[1])
+             + pass_log_width(cfg, Bs)),
             -1, jnp.int32,
         )
         st, log = jax.lax.fori_loop(0, n_micro, micro_carry, (st, log0))

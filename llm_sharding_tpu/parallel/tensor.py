@@ -62,10 +62,17 @@ def llama_tp_specs(stacked: bool = True) -> dict[str, P]:
             "bk": col_b,
             "bv": col_b,
             "bo": rep,  # row-parallel output bias: added once, post-psum
+            # a norm on each branch's output (Ouro): over the whole width,
+            # after the row-parallel psum
+            "attn_out_norm": rep,
+            "mlp_out_norm": rep,
         },
         "embed": rep,
         "final_norm": rep,
         "lm_head": P(None, TENSOR_AXIS),
+        # a looped stack's exit gate (present only where the model has one)
+        "exit_gate": rep,
+        "exit_bias": rep,
     }
 
 

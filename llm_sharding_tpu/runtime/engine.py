@@ -273,6 +273,9 @@ class PipelineEngine:
                 f"placement covers {spec.num_layers} layers but model has "
                 f"{self.cfg.num_hidden_layers}"
             )
+        from ..parallel.pipeline import refuse_looped_ring
+
+        refuse_looped_ring(self.cfg, spec.num_stages)
         swap_t0 = time.perf_counter()
         # A chain longer than the pipe axis executes grouped: k consecutive
         # stages per device, ppermute once per k virtual stages (r3 next-#8).

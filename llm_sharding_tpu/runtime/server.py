@@ -85,6 +85,7 @@ from ..obs.metrics import (
     SPARSE_TOKENS_WALKED,
     DEFAULT_RATE_BUCKETS,
     KV_BLOCKS_IN_USE, KV_BLOCKS_TOTAL, KV_DISK_TIER_BLOCKS,
+    EXIT_PASS,
     KV_ENTRY_BYTES, KV_HOST_TIER_BLOCKS, KV_WASTE_FRAC, MOE_EXPERT_TOKENS,
     MOE_EXPERTS_READ, MOE_PAIRS_HELD, MOE_PAIRS_ROUTED, MOE_ZERO_PAIRS,
     PREFILL_BLOCKS_READ, PREFILL_CELLS_LIVE, PREFILL_CELLS_WALKED,
@@ -1102,6 +1103,22 @@ class PipelineServer:
             )
         options.validate()
         self.paged = options.paged
+        if self.cfg.passes > 1:
+            # a looped stack (the same layers several times a token): what
+            # the loop is not carried through is refused by name (a ring of
+            # stages: by the engine's placement, ``refuse_looped_ring``)
+            if options.speculate:
+                raise NotImplementedError(
+                    f"speculate over a looped stack ({self.cfg.passes} "
+                    "passes): serve_verify closes no pass and its log "
+                    "carries no exit pass — serve it with speculate=0"
+                )
+            if options.cp > 1:
+                raise NotImplementedError(
+                    f"cp over a looped stack ({self.cfg.passes} passes) is "
+                    "not implemented: the cross-shard combine has not been "
+                    "held to a logit test under a loop of passes"
+                )
         if name is not None and options.prefix_cache != "off":
             # a hit would map the full layers' old blocks while the window
             # layers' are gone — or the attention layers' while a recurrent
@@ -1389,6 +1406,15 @@ class PipelineServer:
         self._moe_width = serve_ops.moe_log_width(
             self.cfg, self.num_stages, Lp
         )
+        # a looped model: the exit pass of each committed token, after them
+        self._pass_width = serve_ops.pass_log_width(
+            self.cfg, self.batch_per_slot
+        )
+        if self._pass_width:
+            self._exit_children = [
+                EXIT_PASS.labels(**{"pass": str(t)})
+                for t in range(self.cfg.passes)
+            ]
         self._chunk_lazy: list = []
         self._parked_counts: list = []  # (counters, decode) of applied logs
         if self._moe_width:
@@ -5017,6 +5043,10 @@ class PipelineServer:
         sl.push("apply")
         if not park_counts:
             self._settle_counts()
+        if self._pass_width and entry[0] in ("chunk", "admit"):
+            W = self._pass_width  # (last in the row: after the experts')
+            took, value = np.asarray(value)[..., -W:], value[..., :-W]
+            self._count_exit_passes(took)
         if self._moe_width and entry[0] in ("chunk", "admit"):
             W = self._moe_width
             own, value = np.asarray(value)[..., -W:], value[..., :-W]
@@ -5043,6 +5073,16 @@ class PipelineServer:
             self._count_moe(own, decode)
         self._parked_counts.clear()
         self._apply_chunk_counts()
+
+    def _count_exit_passes(self, took: np.ndarray) -> None:
+        """The exit pass behind each token of a fetched chunk log or
+        admission result of a looped model (-1: no token) goes to the step
+        record and ``server_exit_pass_total``."""
+        counts = np.bincount(took[took >= 0], minlength=self.cfg.passes)
+        for child, n in zip(self._exit_children, counts):
+            if n:
+                child.inc(int(n))
+        self.stepline.exit_passes(counts)
 
     def _count_moe(self, own: np.ndarray, decode: bool) -> None:
         """The ``moe_log_width`` counters behind the tokens of a fetched

@@ -44,6 +44,15 @@ def _refuse_unmapped(cfg: ModelConfig) -> None:
             "repository — the converter maps it once they are; the block runs "
             "on seeded weights (benchmark/blocks/KeyeVL2.py)"
         )
+    if cfg.out_norms or cfg.passes > 1:
+        raise NotImplementedError(
+            "model_type 'ouro': the names of an Ouro checkpoint's tensors (a "
+            "layer's four norms, the exit gate and its bias) are in no file "
+            "of this repository — the converter maps it once they are, and "
+            "maps nothing by guess: a llama-family read would drop the two "
+            "output norms and the gate; the block runs on seeded weights "
+            "(benchmark/blocks/ouro.py)"
+        )
     if cfg.model_type == "solar_open2":
         raise NotImplementedError(
             "model_type 'solar_open2': the names of a Solar-Open2 "

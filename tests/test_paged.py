@@ -1763,7 +1763,7 @@ def compiled_serve_chunk(v5e_host):
 @pytest.mark.parametrize(
     "cell", sorted(_CELL_SHAPES) + [
         "gigachat31_702b_a36b", "nemotron3_super_120b_a12b",
-        "keye_vl2_30b_a3b", "longcat_flash_omni"])
+        "keye_vl2_30b_a3b", "longcat_flash_omni", "ouro_2p6b"])
 def test_a_decode_step_reads_its_weights_as_they_are_stored(
         compiled_serve_chunk, cell):
     """The compiled ``serve_chunk`` of each benchmark configuration, at its
@@ -1783,7 +1783,11 @@ def test_a_decode_step_reads_its_weights_as_they_are_stored(
     input-minor, and the stack of ``wkv_a`` was re-laid every call until the
     leaf was padded to the arena entry's 640 columns; ``longcat_flash`` (PR
     57) runs that attention TWICE a layer over leaves with a ``_0`` / ``_1``
-    suffix — the same edges, twice. Nothing runs: a compile is not a time."""
+    suffix — the same edges, twice; ``ouro`` (PR 60), the llama block with NO
+    bias and no q norm, met it on ``wq`` (nothing stood between the dot and
+    the head split: 403 MB re-laid a call and a layer's slice copied before
+    its dot, 3.6 ms of a 33.4 ms step on the chip, until q left the projection
+    through the same edge). Nothing runs: a compile is not a time."""
     text = compiled_serve_chunk(cell)
     assert _weight_stack_relayouts(text) == []
     dots, windowed = _windowed_projections(text)
@@ -2426,7 +2430,13 @@ _SELECT_WORDS = {"indexer", "select"}
 # the shortcut's join: ``tests/test_longcat_flash_serve.py`` holds its
 # programs to it)
 _SHORTCUT_WORDS = {"zero_expert"}
-_OTHERS_WORDS = _RECURRENT_WORDS | _SELECT_WORDS | _SHORTCUT_WORDS
+# (``pass_close`` is a looped stack's alone — the final norm that closes a
+# pass and the exit gate: ``tests/test_ouro_serve.py`` holds its programs to
+# it, and these one-pass models' to being without it)
+_LOOP_WORDS = {"pass_close"}
+_OTHERS_WORDS = (
+    _RECURRENT_WORDS | _SELECT_WORDS | _SHORTCUT_WORDS | _LOOP_WORDS
+)
 _MLP_WORDS = {
     "dense": {"router", "moe", "absorb"} | _OTHERS_WORDS,
     "experts": {"mlp", "absorb"} | _OTHERS_WORDS,
