@@ -49,14 +49,17 @@ the row-wise write outside block 0. The last line, also left in
 ``chiprun_out/kv_write.json``: ``{"ok": true, "kv_write": [...], "device":
 {...}}``.
 ``--kv-decode`` likewise: a DECODE step's K/V write with the attention it
-feeds at OLMoE's, the 7B's, MiMo's window and Keye's shapes (the last at
-three contexts: the walk's nanoseconds a token), one and four live rows of
+feeds at OLMoE's, the 7B's, MiMo's window, Keye's (at three contexts: the
+walk's nanoseconds a token) and Ouro's shapes, one and four live rows of
 four — the one op a layer calls (``paged_attention_write``) against the
 pair it replaced (``write_block_kv`` then ``paged_attention``): the arenas
 bit for bit, and the DEVICE time of one layer call of each from a profiler
 trace, with the operations it is made of. The last line, also left in
 ``chiprun_out/kv_decode.json``: ``{"ok": true, "kv_decode": [...], "device":
-{...}}``.
+{...}}``. With ``--beside FILE`` (the ``kv_decode.json`` that this script
+left when run from ANOTHER tree, the parent commit's: copy this file over
+that tree's and run it there first, in the same chip call) form ``fused``
+of both trees is printed side by side and kept under ``"beside"``.
 ``--index-scores`` likewise: a selecting decode step's SCORE call alone
 (``ops/paged_attention.index_scores_tpu``) at Keye's shape (16 index heads,
 128 lanes, block 32, table 288, four rows of which one live) at 2.5 / 5 /
@@ -848,6 +851,10 @@ KV_DECODE_SHAPES = (
     {"name": "mimo_v25.swa", "heads": 8, "group": 8, "dk": 256, "dv": 128,
      "blocks": 53, "layers": 9, "table": 256, "contexts": (4096,),
      "window": 128},
+    # a looped step calls its 48 layers four times, 192 attention layer
+    # calls: a pass's 48 arena slots of the 192 are enough to time one
+    {"name": "ouro_2p6b", "heads": 16, "group": 1, "dk": 128, "dv": 128,
+     "blocks": 161, "layers": 48, "table": 64, "contexts": (448,)},
     {"name": "keye_vl2_30b_a3b", "heads": 4, "group": 8, "dk": 128,
      "dv": 128, "blocks": 2305, "layers": 12, "table": 288,
      "contexts": (2560, 5120, 8704)},
@@ -1028,6 +1035,28 @@ def time_kv_decode(shape: dict, live: int, form: str, backend: str = "kernel",
         "us_per_layer_call": round(sum(u for _, u in ops) / calls, 2),
         "ops_us": [[n, round(u / calls, 2)] for n, u in ops[:8]],
     }
+
+
+def fused_beside(parent: list, change: list) -> list:
+    """Form ``fused`` of two trees' ``kv_decode`` results, a row a (shape,
+    live rows, context) both timed: DEVICE microseconds a layer call and
+    the operations they are made of."""
+    at = lambda r: (r["shape"], r["live"], r["context"])
+    theirs = {at(r): r["fused"] for r in parent}
+    rows = []
+    for r in change:
+        if at(r) not in theirs:
+            continue
+        old, new = theirs[at(r)], r["fused"]
+        rows.append({
+            "shape": r["shape"], "live": r["live"], "context": r["context"],
+            "parent_us": old["us_per_layer_call"],
+            "change_us": new["us_per_layer_call"],
+            "delta_us": round(
+                new["us_per_layer_call"] - old["us_per_layer_call"], 2),
+            "parent_ops_us": old["ops_us"], "change_ops_us": new["ops_us"],
+        })
+    return rows
 
 
 def walk_slope(walk: list, live: int) -> float:
@@ -2121,6 +2150,10 @@ def main(argv=None) -> int:
                     help="only check and time a decode step's K/V write "
                          "with the attention it feeds (ops/paged_attention."
                          "py): the one fused op beside the scatter pair")
+    ap.add_argument("--beside",
+                    help="with --kv-decode: the kv_decode.json this script "
+                         "left in another tree (the parent's): form fused "
+                         "of both, side by side")
     ap.add_argument("--index-scores", action="store_true",
                     help="only check and time a selecting decode step's "
                          "score call (ops/paged_attention.index_scores_tpu) "
@@ -2155,8 +2188,18 @@ def main(argv=None) -> int:
             os.makedirs(WORK, exist_ok=True)
             got = wait_child(run_child(
                 mode, {}, dict(os.environ, PYTHONPATH=HERE), mode + ".log"))
-            line = json.dumps({"ok": True, mode: got[mode],
-                               "device": got["device"]})
+            report = {"ok": True, mode: got[mode], "device": got["device"]}
+            if mode == "kv_decode" and args.beside:
+                with open(args.beside) as f:
+                    report["beside"] = fused_beside(
+                        json.load(f)["kv_decode"], got[mode])
+                for r in report["beside"]:
+                    print(f"[kv-decode] fused, {r['shape']} at "
+                          f"{r['context']} live {r['live']}: parent "
+                          f"{r['parent_us']} us {r['parent_ops_us'][:3]}, "
+                          f"change {r['change_us']} us "
+                          f"{r['change_ops_us'][:3]}: {r['delta_us']:+} us")
+            line = json.dumps(report)
             os.makedirs(os.path.join(HERE, "chiprun_out"), exist_ok=True)
             with open(os.path.join(HERE, "chiprun_out", mode + ".json"),
                       "w") as f:

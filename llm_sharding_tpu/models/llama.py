@@ -401,9 +401,9 @@ def paged_decoder_layer(
 ):
     """Decode-path layer over the pooled arena: the step's fresh KV lands
     in the blocks the table names of layer ``layer`` of the stacked pool
-    (``paged_attention_write``: a write kernel that leaves the arena in
-    place where the step's statics allow, the block-indexed scatter
-    otherwise) and attention streams exactly those blocks out of that
+    (``paged_attention_write``: stored by the attention kernel itself, the
+    arena left in place, where the step's statics allow, the block-indexed
+    scatter otherwise) and attention streams exactly those blocks out of that
     layer (``ops/paged_attention``) — the logical window is never
     materialized and the layer is never sliced out of the stack. A quantized arena (``k_scale``/``v_scale``)
     quantizes the fresh entries at insert and dequantizes inside the

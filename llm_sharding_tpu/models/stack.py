@@ -244,7 +244,8 @@ def scan_layers_paged(
     """Paged analogue of ``scan_layers``: the cache is the pooled block
     arena, and a layer's update is the tiny block-indexed write of this
     step's entries (``ops/paged_attention.paged_attention_write`` inside
-    ``apply_layer``: a write kernel or ``write_block_kv``'s scatter) —
+    ``apply_layer``: inside the attention kernel or ``write_block_kv``'s
+    scatter) —
     never a full-row or full-window write. The
     layer-stacked arena rides the scan carry and goes to ``apply_layer``
     WHOLE, with the layer index: the attention ops address ``(l, block)``

@@ -736,21 +736,25 @@ PREFILL_KV_BLOCKS_WRITTEN = REGISTRY.counter(
     "prefill writes that took the block-sized form",
     labels=("write",),
 )
-#: The two forms of a decode step's K/V write
-#: (``ops/paged_attention.paged_attention_write``): the write kernel that
-#: leaves the arena in place, or ``write_block_kv``'s scatter.
-DECODE_KV_WRITES = ("kernel", "scatter")
+#: The three forms of a decode step's K/V write
+#: (``ops/paged_attention.paged_attention_write``): the attention call
+#: stores the entry itself; the write kernel ``paged_kv_write`` (what is left
+#: to it: a selecting model's index keys); ``write_block_kv``'s scatter.
+DECODE_KV_WRITES = ("attention", "kernel", "scatter")
 DECODE_KV_ENTRIES_WRITTEN = REGISTRY.counter(
     "server_decode_kv_entries_written_total",
     "Fresh key/value entries a decode or verify dispatch lands in the "
     "paged arena, per layer, summed over live rows per step (host-side: "
     "one entry a row a decode step, K + 1 a verify), by the form the step "
-    "program's statics chose: write=kernel — one sublane tile a row moved "
-    "by the write kernel, the arena left in place (one entry a row, a "
-    "plain arena, the attention on its kernel); write=scatter — the "
-    "row-wise scatter (a verify's K + 1 entries, an int8/fp8 arena, "
-    "context parallel, the XLA attention path). kernel / (kernel + "
-    "scatter) is the share of decode writes that took the kernel",
+    "program's statics chose: write=attention — stored by the attention "
+    "kernel itself from the frontier cell it holds, the arena left in "
+    "place (one entry a row, a plain arena, the attention on its kernel); "
+    "write=kernel — one sublane tile a row moved by the write kernel "
+    "paged_kv_write (what is left to it: a selecting model's index keys, "
+    "one a row beside its K/V entry); write=scatter — the row-wise scatter "
+    "(a verify's K + 1 entries, an int8/fp8 arena, context parallel, the "
+    "XLA attention path). attention / (attention + scatter) is the share "
+    "of K/V entries that cost no call of their own",
     labels=("write",),
 )
 # a model with recurrent layers (models/nemotron_h.py, models/jamba.py): a

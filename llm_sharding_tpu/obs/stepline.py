@@ -492,7 +492,7 @@ class StepProfiler:
         self._decode_blocks = [0, 0]  # [live, reserved]
         self._prefill_cells = [0, 0]  # [live, walked]
         self._prefill_kv_blocks = None  # {"tile" | "rows": blocks}
-        self._decode_kv_entries = None  # {"kernel" | "scatter": entries}
+        self._decode_kv_entries = None  # {a DECODE_KV_WRITES form: entries}
         self._recurrent_rows = None
         self._scan_positions = None  # {"real" | "pad": positions}
         self._sparse_tokens = None  # {"scored" | "read" | "live" | "walked": n}
@@ -774,7 +774,8 @@ class StepProfiler:
     def decode_kv_entries(self, write: str, entries: int) -> None:
         """Add one decode or verify dispatch's K/V write to the step's
         record: the entries it landed, under the form of the write
-        (``kernel`` or ``scatter``)."""
+        (``attention``, ``kernel`` or ``scatter``:
+        ``obs.metrics.DECODE_KV_WRITES``)."""
         if not self._enabled or self._t0 is None:
             return
         if self._decode_kv_entries is None:

@@ -77,18 +77,24 @@ remains the bit-exact CPU/tier-1 fallback behind the same dispatch.
 
 Who writes the arena, and at what granularity (one algorithm — fresh
 entries land in the blocks the table names, invalid ones in block 0 of
-their layer — at three granularities, chosen by what a program's statics
-say, never by an option):
+their layer or nowhere — at three granularities, chosen by what a
+program's statics say, never by an option):
 
-- ONE SUBLANE TILE A ROW, ``write_rows_tpu`` (the kernel
-  ``paged_kv_write``): a decode step (``serve_chunk``: one entry a row, at
-  each row's own column) over a plain arena with the attention on its
-  kernel (``decode_writes_in_kernel``). The arena rides in once, aliased
-  over itself; a grid step a row moves the ``(Nkv, SUB, D)`` tile that
-  holds the slot in, puts the entry into it and moves it back, K and V
-  together — 3.2-3.5 us a layer call at OLMoE's 16 heads where the two
-  scatters took 12.8 (``chip_smoke.py --kv-decode``, PERF.md PR 46), and
-  XLA, seeing no scatter, re-lays and stages nothing.
+- ONE SUBLANE TILE A ROW, by the ATTENTION kernel (``paged_decode``,
+  ``paged_attention_tpu(fresh=)``): a decode step (``serve_chunk``: one
+  entry a row, at each row's own column) over a plain arena with the
+  attention on its kernel (``decode_writes_in_kernel``). Each arena rides
+  in once, aliased over itself; the row's frontier cell has the fresh
+  column's block in its buffer anyway, so the body puts the entry into the
+  ``(Nkv, SUB, D)`` tile that holds the slot there, scores the cell and
+  meanwhile copies the tile back, K and V alike: a decode layer call is
+  ONE kernel (PR 61; a call of its own before the attention,
+  ``write_rows_tpu``'s ``paged_kv_write``, took 2.8-3.5 us a layer call
+  from PR 46 on, the two scatters before it 12.8: ``chip_smoke.py
+  --kv-decode``, PERF.md), and XLA, seeing no scatter, re-lays and stages
+  nothing. A gated entry and a trash-mapped column are not stored at all.
+  ``write_rows_tpu`` stays for a selecting model's index keys
+  (``write_index_keys``), which the score call reads before the attention.
 - ROWS, ``write_block_kv``: ``B x S x Nkv`` rows of ``D``, each with its
   own ``(layer, block, head, slot)``. What a decode step writes everywhere
   else — speculation's verify (``serve_verify``: ``K + 1`` entries a row),
@@ -293,8 +299,10 @@ def kernel_eligible(
       (``kernel_sublane``);
     - the ``[rows, table_width]`` block table (``rows`` = the rows one call
       attends: a slot's ``batch_per_slot``) is scalar-prefetched whole,
-      and beside it the decode kernel's two entries a row (its frontier, a
-      windowed layer's first cell); together they must fit
+      and beside it the decode kernel's six entries a row (its frontier, a
+      windowed layer's first cell, and what a call that stores the step's
+      fresh entries adds: their columns, the gate, the stretched frontier
+      and the cell to patch); together they must fit
       ``SMEM_TABLE_BUDGET`` (a 1-byte arena's scales take two cells' worth
       of it, a few KiB). So
       must the table and the prefill kernel's walk where chunks are
@@ -310,7 +318,9 @@ def kernel_eligible(
         return -(-n // m) * m
 
     table = pad(rows, 8) * pad(table_width, 128)
-    decode = table + 2 * pad(rows, 128)
+    # the frontier and a windowed layer's first cell; a call that stores the
+    # step's entries: their columns, the gate and two arrays of its own
+    decode = table + 6 * pad(rows, 128)
     runs = rows * kv_heads * prefill_tiles
     cells = table_width // auto_blocks_per_step(table_width, block_size)
     prefill = table + pad(runs * cells + 1, 1024) + 2 * pad(runs, 128)
@@ -816,6 +826,31 @@ def _cells_end_to_end(cells, width):
 FOLD_TILES = 8
 
 
+def entry_tile_rows(cache_dtype, block_size: int) -> int:
+    """Rows of the tile a decode step's ONE fresh entry is stored with: the
+    storage dtype's sublanes (one ``(SUB, 128)`` tile a head and lane
+    group), or the block where it is not whole tiles."""
+    sub = kernel_sublane(cache_dtype)
+    return sub if block_size % sub == 0 else block_size
+
+
+def _entry_into_tile(new, old_ref, out_ref, at):
+    """``old_ref`` ``[Nkv, SUB, D]`` into ``out_ref`` (which may be the same
+    ref) with the fresh entry ``new`` ``[Nkv, D]`` at sublane ``at``: a
+    select along the tile's sublanes against the slot, on 32-bit lanes (a
+    2-byte float widens and narrows again exactly) — the entry's bits land
+    as the scatter would have stored them and every other row of the tile
+    goes back as it came."""
+    Nkv, sub, D = old_ref.shape
+    wide = old_ref.dtype if old_ref.dtype.itemsize == 4 else jnp.float32
+    here = jax.lax.broadcasted_iota(jnp.int32, (sub, D), 0) == at
+    new = new.astype(wide)  # a head a sublane
+    for h in range(Nkv):
+        out_ref[h] = jnp.where(
+            here, new[h:h + 1], old_ref[h].astype(wide)
+        ).astype(out_ref.dtype)
+
+
 def _paged_kernel(
     layer_ref,  # scalar-prefetch [1] — the layer of the stack the copies read
     tbl_ref,  # scalar-prefetch [B, T] (the copies' block ids + the trash gate)
@@ -842,9 +877,19 @@ def _paged_kernel(
     #   walked; the cell the window's edge cuts is masked)
     sink=False,  # a [M, 1] f32 ref after khead: a per-head logit that joins
     #   the softmax's denominator and nothing else
+    fresh=False,  # the call STORES the step's one fresh entry a row: two more
+    #   scalar-prefetch operands after ``first`` — col [B], the entry's
+    #   column, and ok [B], the write gate —, the entries new_k [B, Nkv, D]
+    #   (and new_v) after khead, after ``out`` each arena AGAIN as an output,
+    #   aliased over its operand (the body reads and writes the arena through
+    #   that one ref), and two more scratch arrays in scalar memory, [B]
+    #   each: the row's frontier stretched to the entry's block, and the
+    #   table CELL that holds it (-1: the row stores nothing)
 ):
     if window:
         first_ref, rest = rest[0], rest[1:]
+    if fresh:
+        col_ref, ok_ref, rest = rest[0], rest[1], rest[2:]
     n_src = 1 if latent_v else 2  # the arenas a block is copied out of
     q_ref, srcs, rest = rest[0], rest[1:1 + n_src], rest[1 + n_src:]
     qpos_ref, qhead_ref, kvpos_hbm, khead_ref, rest = *rest[:4], rest[4:]
@@ -852,19 +897,49 @@ def _paged_kernel(
     if quantized:
         rows.append(rest[0])
         rest = rest[1:]
+    if fresh:
+        news, rest = rest[:n_src], rest[n_src:]
     if sink:
         sink_ref, rest = rest[0], rest[1:]
     out_ref, rest = rest[0], rest[1:]
+    if fresh:
+        srcs, rest = rest[:n_src], rest[n_src:]
     bufs, rest = rest[:n_src], rest[n_src:]
     row_bufs, rest = rest[:len(rows)], rest[len(rows):]
+    if fresh:
+        live_ref, wcell_ref, rest = rest[0], rest[1], rest[2:]
     sem, acc_ref, m_ref, l_ref = rest
     pos_buf = row_bufs[0]
     B = q_ref.shape[0]
     Nkv, BS, D = bufs[0].shape[2:]
 
+    if fresh:
+        def column(b):
+            """Row ``b``'s fresh column, clipped as ``write_block_kv``'s."""
+            return jnp.clip(col_ref[b], 0, tbl_ref.shape[1] * BS - 1)
+
+        # ONCE a row, before the walk (scalar work a CELL would be paid a
+        # cell: 60 ns each on the chip, PERF.md PR 61): a row STORES its
+        # entry where the gate is open and the column lies in a block the
+        # row owns; its walk then reaches that block at least — a selection
+        # may have masked the fresh key itself out of ``nlive``'s sight, and
+        # an entry the mask wipes whole adds exactly nothing to the softmax
+        def row(b, _):
+            went = column(b) // BS
+            stores = (ok_ref[b] != 0) & (tbl_ref[b, went] != 0)
+            live_ref[b] = jnp.maximum(
+                nlive_ref[b], jnp.where(stores, went + 1, 0)
+            )
+            wcell_ref[b] = jnp.where(stores, went // bps, -1)
+            return _
+
+        jax.lax.fori_loop(0, B, row, 0)
+    else:
+        live_ref = nlive_ref
+
     def cells(b):
         """Row ``b``'s walk: the table cells ``lo <= c < hi``."""
-        hi = (nlive_ref[b] + bps - 1) // bps
+        hi = (live_ref[b] + bps - 1) // bps
         return (first_ref[b] if window else 0), hi
 
     def next_live(b):
@@ -890,7 +965,7 @@ def _paged_kernel(
         for j in range(bps):
             idx = c * bps + j
             at = (layer_ref[0], jnp.where(
-                idx < nlive_ref[b], tbl_ref[b, idx], 0
+                idx < live_ref[b], tbl_ref[b, idx], 0
             )) if fetch else (0, 0)
             out += [
                 pltpu.make_async_copy(
@@ -899,6 +974,37 @@ def _paged_kernel(
                 for src, buf in zip(srcs, bufs)
             ]
         return out
+
+    def fresh_tiles(slot, b):
+        """Per arena, the sublane tile ``(Nkv, SUB, D)`` that holds row
+        ``b``'s fresh slot, in the cell's buffer and where it lies in the
+        arena, ``(layer, table[b, col // BS], :, tile, :)``; and the slot's
+        sublane inside it."""
+        col = column(b)
+        went = col // BS
+        sub = entry_tile_rows(bufs[0].dtype, BS)
+        rows_of = pl.ds(pl.multiple_of(col % BS // sub * sub, sub), sub)
+        return [
+            (buf.at[slot, went % bps, :, rows_of, :],
+             arena.at[layer_ref[0], tbl_ref[b, went], :, rows_of, :])
+            for buf, arena in zip(bufs, srcs)
+        ], col % sub
+
+    def stores(tiles):
+        """The copies that put the patched tiles back: ONE an arena."""
+        return [
+            pltpu.make_async_copy(tile, home, sem.at[2])
+            for tile, home in tiles
+        ]
+
+    def patch(slot, b):
+        """The fresh entry into its slot of the block's buffer, so that the
+        scores see it, and the tile on its way back to the arena."""
+        tiles, at = fresh_tiles(slot, b)
+        for (tile, _), new_ref in zip(tiles, news):
+            _entry_into_tile(new_ref[b], tile, tile, at)
+        for cp in stores(tiles):
+            cp.start()
 
     # a row the walk never visits reads zeros
     out_ref[...] = jnp.zeros_like(out_ref)
@@ -916,7 +1022,7 @@ def _paged_kernel(
         cell's copies in flight (the other slot) while this one is scored."""
         b, c, slot = carry
         lo, hi = cells(b)
-        nlive = nlive_ref[b]
+        nlive = live_ref[b]
         last = c + 1 >= hi  # the row's frontier cell
         nb = next_live(jnp.where(last, b + 1, b))
         nc = jnp.where(last, cells(jnp.minimum(nb, B - 1))[0], c + 1)
@@ -934,6 +1040,13 @@ def _paged_kernel(
             acc_ref[:] = jnp.zeros_like(acc_ref)
             m_ref[:] = jnp.full_like(m_ref, NEG_INF)
             l_ref[:] = jnp.zeros_like(l_ref)
+
+        if fresh:
+            # the cell that holds the fresh column's block (the row's
+            # frontier cell): patched in VMEM, attended, stored under the
+            # scoring
+            storing = c == wcell_ref[b]
+            pl.when(storing)(lambda: patch(slot, b))
 
         q = q_ref[b]  # [M, D]
         qpos = qpos_ref[b]  # [M, 1]
@@ -996,6 +1109,14 @@ def _paged_kernel(
                 out_ref.dtype
             )
 
+        if fresh:
+            # the tile has left the buffer before the next cell's prefetch
+            # (or the call's end) takes this slot
+            @pl.when(storing)
+            def _stored():
+                for cp in stores(fresh_tiles(slot, b)[0]):
+                    cp.wait()
+
         return nb, nc, 1 - slot
 
     b0 = next_live(jnp.int32(0))
@@ -1010,6 +1131,19 @@ def _paged_kernel(
         lambda carry: carry[0] < B, cell,
         (b0, jnp.asarray(c0, jnp.int32), jnp.int32(0)),
     )
+
+
+class Fresh(NamedTuple):
+    """A decode step's ONE fresh entry a row, handed to the attention call
+    that stores it (``paged_attention_tpu(fresh=)``): the keys ``[B, Nkv,
+    D]`` and values ``[B, Nkv, Dv]`` (None over a latent arena, which holds
+    none), each row's column ``[B]`` and the write gate, a scalar or ``[B]``
+    bool (False: the row stores nothing)."""
+
+    k: jnp.ndarray
+    v: jnp.ndarray
+    cols: jnp.ndarray
+    ok: jnp.ndarray
 
 
 @functools.partial(
@@ -1039,6 +1173,8 @@ def paged_attention_tpu(
     #   first cell that holds one (``_first_blocks``)
     sink: jnp.ndarray = None,  # [Nh] a per-head logit in the softmax's
     #   denominator (its column dropped: it adds nothing to the output)
+    fresh: Fresh = None,  # the step's one fresh entry a row: the call
+    #   stores it and returns ``(out, k_arena, v_arena)``
 ) -> jnp.ndarray:
     """Pallas paged DECODE attention whose work is the tokens that are
     written: ONE kernel invocation walks the LIVE cells of the call, a cell
@@ -1090,7 +1226,30 @@ def paged_attention_tpu(
     small gather out of each scale arena) come a cell at a time, one more
     copy, into scalar memory; the dequant multiply, a head's tile by its
     scalar, runs in VMEM right before the score dot. Int8 tiles want BS a
-    multiple of 32 (1-byte sublane — ``kernel_eligible``)."""
+    multiple of 32 (1-byte sublane — ``kernel_eligible``).
+
+    The kernel writes what it attends (``fresh=``, a decode step's ONE
+    entry a row over a plain arena: ``decode_writes_in_kernel``). The cell
+    that holds the fresh column's block — the row's frontier cell: the
+    step's ``kv_positions`` already hold the fresh column, and the walk is
+    stretched to its block where a selection masked the fresh key out — has
+    that block in its buffer anyway: after the cell's copies have landed
+    the body puts the entry into its slot there (the sublane tile ``(Nkv,
+    SUB, D)`` that holds it, a select against the slot), scores the cell —
+    the fresh key with it —, and meanwhile ONE copy an arena takes the tile
+    back to ``(layer, table[b, col // BS], :, tile, :)``, waited on before
+    the slot is reused. "Stored, then attended" is "patched in VMEM,
+    attended, stored under the scoring": the bytes that land in OWNED blocks
+    and the scores are ``write_block_kv`` + this kernel's, bit for bit.
+    Each arena rides in ONCE, aliased over an output of the call
+    (``input_output_aliases``; the body reads and writes it through that
+    one ref), so the carried pool stays where it lies — handed in as a
+    read-only operand AND an aliased one, XLA copied it whole every call
+    (PERF.md, PR 46). A gated row (``ok`` 0: a ring stage's bubble
+    microstep, a parked slot) and a column the table maps to trash store
+    NOTHING — block 0, which ``write_block_kv`` uses as their sink, is left
+    as it was, and every owned block too; a row the walk does not visit
+    (table all trash) likewise."""
     B, S, Nh, D = q.shape
     Nkv, BS = k_arena.shape[2], k_arena.shape[3]
     T = block_table.shape[1]
@@ -1100,6 +1259,10 @@ def paged_attention_tpu(
     Dv = latent_v or v_arena.shape[-1]  # a value may be narrower than a key
     if latent_v and quantized:
         raise NotImplementedError("a quantized latent arena is not done")
+    if fresh is not None and (quantized or S != 1):
+        raise NotImplementedError(
+            "the attention call stores ONE entry a row into a plain arena"
+        )
     if scale is None:
         scale = D ** -0.5
     if kv_positions.shape != (B, T * BS):
@@ -1141,6 +1304,11 @@ def paged_attention_tpu(
         prefetch.append(_first_blocks(
             block_table, q_positions, kv_positions, window, nlive
         ) // bps)
+    if fresh is not None:
+        prefetch += [
+            fresh.cols.astype(jnp.int32),
+            jnp.broadcast_to(fresh.ok, (B,)).astype(jnp.int32),
+        ]
 
     def whole(shape):
         return pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape))
@@ -1166,6 +1334,13 @@ def paged_attention_tpu(
         operands.append(sc)
         in_specs.append(in_hbm)
         row_bufs.append(pltpu.SMEM((2, *sc.shape[2:]), sc.dtype))
+    stored = []
+    if fresh is not None:
+        # the arenas the call stores into, and the entry for each
+        stored = arenas
+        for arena, new in zip(arenas, (fresh.k, fresh.v)):
+            in_specs.append(whole(new.shape))
+            operands.append(new.astype(arena.dtype))
     if sink is not None:
         # query row r = h·S + s carries head h's logit, sublane-major
         in_specs.append(whole((M, 1)))
@@ -1174,31 +1349,43 @@ def paged_attention_tpu(
         num_scalar_prefetch=len(prefetch),
         grid=(1,),
         in_specs=in_specs,
-        out_specs=whole((B, M, Dv)),
+        out_specs=[whole((B, M, Dv)), *[in_hbm] * len(stored)],
         scratch_shapes=[
             *[pltpu.VMEM((2, bps, *a.shape[2:]), a.dtype) for a in arenas],
             *row_bufs,
-            pltpu.SemaphoreType.DMA((2,)),
+            *[pltpu.SMEM((B,), jnp.int32)] * (2 if stored else 0),
+            # a slot's copies in; the fresh tiles' copies out
+            pltpu.SemaphoreType.DMA((3 if stored else 2,)),
             pltpu.VMEM((M, Dv), jnp.float32),
             pltpu.VMEM((M, 128), jnp.float32),
             pltpu.VMEM((M, 128), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
+    out, *stored = pl.pallas_call(
         functools.partial(
             _paged_kernel, scale=scale, bps=bps, quantized=quantized,
             latent_v=latent_v,
-            window=window, sink=sink is not None,
+            window=window, sink=sink is not None, fresh=fresh is not None,
         ),
-        out_shape=jax.ShapeDtypeStruct((B, M, Dv), q.dtype),
+        out_shape=[
+            jax.ShapeDtypeStruct((B, M, Dv), q.dtype),
+            *[jax.ShapeDtypeStruct(a.shape, a.dtype) for a in stored],
+        ],
         grid_spec=grid_spec,
+        # each arena over itself: it follows the scalars and the queries
+        input_output_aliases={
+            len(prefetch) + 1 + i: 1 + i for i in range(len(stored))
+        },
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
         name="paged_decode",
     )(*prefetch, *operands)
-    return jnp.transpose(out.reshape(B, Nh, S, Dv), (0, 2, 1, 3))
+    out = jnp.transpose(out.reshape(B, Nh, S, Dv), (0, 2, 1, 3))
+    if fresh is None:
+        return out
+    return out, stored[0], (v_arena if latent_v else stored[1])
 
 
 #: Query-row tile of the chunked-prefill kernel (G·Sc folded rows per
@@ -1813,6 +2000,8 @@ def paged_attention(
     latent_v: int = 0,  # static: a latent arena (see paged_prefill)
     window: int = 0,  # static: a windowed layer keeps ``window`` keys back
     sink: jnp.ndarray = None,  # [Nh] a per-head logit in the denominator
+    fresh: Fresh = None,  # the step's fresh entry a row, for the KERNEL to
+    #   store: the result is then ``(out, k_arena, v_arena)``
 ) -> jnp.ndarray:
     """Backend dispatch: the Pallas kernel on TPU for MXU-aligned shapes,
     the exact XLA gather path otherwise (CPU meshes, ragged head dims,
@@ -1829,7 +2018,11 @@ def paged_attention(
     cross-shard ``combine_attn_stats`` reduction; stats mode always runs
     the XLA gather path (the stats-emitting kernel is the ROADMAP
     ring-fusion leftover), so ``backend`` only governs the plain
-    single-shard dispatch."""
+    single-shard dispatch.
+
+    ``fresh`` is ``paged_attention_write``'s: it hands the entries over
+    only where ``decode_writes_in_kernel`` holds, i.e. where this dispatch
+    ends in the kernel."""
     if stats and (latent_v or window or sink is not None):
         raise NotImplementedError(
             "context-parallel attention over a latent arena, a windowed "
@@ -1847,8 +2040,10 @@ def paged_attention(
             q, k_arena, v_arena, layer, block_table, q_positions,
             kv_positions, scale, interpret=path == "interpret",
             k_scale=k_scale, v_scale=v_scale, latent_v=latent_v,
-            window=window, sink=sink,
+            window=window, sink=sink, fresh=fresh,
         )
+    if fresh is not None:
+        raise ValueError("only the kernel stores a step's fresh entries")
     return paged_attention_xla(
         q, k_arena, v_arena, layer, block_table, q_positions,
         kv_positions, scale, k_scale=k_scale, v_scale=v_scale,
@@ -1859,15 +2054,17 @@ def paged_attention(
 def decode_writes_in_kernel(
     entries: int, quantized: bool, stats: bool, path: str
 ) -> bool:
-    """Whether ``paged_attention_write`` lands a step's fresh K/V through
-    the write KERNEL (``write_rows_tpu``) instead of ``write_block_kv``'s
-    scatters: what the step program's statics say — ONE entry a row (a
-    decode step; a verify's ``K + 1`` entries are rows), a plain arena (an
-    int8/fp8 one keeps running per-block scales), no partial statistics
-    (context parallel: its attention is the gather) and the attention
-    itself on the kernel (``decode_path``: ``"kernel"`` or ``"interpret"``).
-    The host asks the same question for its counter
-    (``runtime/server.py``)."""
+    """Whether a decode step's fresh entries are stored by a KERNEL that
+    leaves the arena where it lies instead of by ``write_block_kv``'s
+    scatters — K and V by the attention call itself
+    (``paged_attention_write`` → ``paged_attention_tpu(fresh=)``), a
+    selecting model's index keys by ``write_rows_tpu``: what the step
+    program's statics say — ONE entry a row (a decode step; a verify's ``K +
+    1`` entries are rows), a plain arena (an int8/fp8 one keeps running
+    per-block scales), no partial statistics (context parallel: its
+    attention is the gather) and the attention itself on the kernel
+    (``decode_path``: ``"kernel"`` or ``"interpret"``). The host asks the
+    same question for its counter (``runtime/server.py``)."""
     return entries == 1 and not quantized and not stats and path != "xla"
 
 
@@ -1886,18 +2083,9 @@ def _write_rows_kernel(
     for new_ref, old_ref, out_ref in zip(
         refs[:n], refs[n:2 * n], refs[2 * n:]
     ):
-        Nkv, sub, D = old_ref.shape
-        # a select along the tile's sublanes against the slot, on 32-bit
-        # lanes (a 2-byte float widens and narrows again exactly): the
-        # entry's bits land as the scatter would have stored them and
-        # every other row of the tile goes back as it came
-        wide = old_ref.dtype if old_ref.dtype.itemsize == 4 else jnp.float32
-        here = jax.lax.broadcasted_iota(jnp.int32, (sub, D), 0) == slot % sub
-        new = new_ref[0].astype(wide)  # [Nkv, D]: a head a sublane
-        for h in range(Nkv):
-            out_ref[h] = jnp.where(
-                here, new[h:h + 1], old_ref[h].astype(wide)
-            ).astype(out_ref.dtype)
+        _entry_into_tile(
+            new_ref[0], old_ref, out_ref, slot % old_ref.shape[1]
+        )
 
 
 @jax.named_scope("kv_write")
@@ -1933,11 +2121,17 @@ def write_rows_tpu(
     the index maps look the block up, so no XLA operation prepares the
     call but the gate's conversion.
 
-    Why not inside ``paged_decode``, whose frontier cell holds this very
-    block: that kernel takes the arena once per sub-block ref, and XLA
-    answers a buffer that one call both reads through other operands and
-    aliases to an output with a copy of the WHOLE arena a layer call
-    (compiled for a v5e: PERF.md, PR 46)."""
+    What still calls it: ``write_index_keys`` alone (a selecting model's
+    index arena — the score call that reads it runs BEFORE the attention,
+    so the attention cannot be its writer). K and V went this way from PR
+    46 to PR 60, a call of their own before ``paged_decode``, because that
+    kernel then took the arena once per sub-block ``BlockSpec`` ref, and
+    XLA answers a buffer that one call both reads through other operands
+    and aliases to an output with a copy of the WHOLE arena a layer call;
+    since PR 54 ``paged_decode`` takes each arena once and copies its
+    blocks by hand, and since PR 61 it stores K's and V's fresh entry
+    itself, in the frontier cell it already holds
+    (``paged_attention_tpu(fresh=)``)."""
     B, Nkv, _ = k_new.shape
     BS = k_arena.shape[3]
     W = block_table.shape[1] * BS
@@ -1952,10 +2146,7 @@ def write_rows_tpu(
         jnp.ones((), jnp.int32) if valid is None
         else jnp.asarray(valid).astype(jnp.int32), (B,)
     )
-    # the tile a step moves: the storage dtype's sublanes (one (SUB, 128)
-    # tile a head and lane group), or the block where it is not tiles
-    sub = kernel_sublane(k_arena.dtype)
-    sub = sub if BS % sub == 0 else BS
+    sub = entry_tile_rows(k_arena.dtype, BS)  # the tile a step moves
     arenas = [(k_arena, k_new)]
     if v_arena.shape[-1]:  # a latent arena holds no values
         arenas.append((v_arena, v_new))
@@ -2021,30 +2212,34 @@ def paged_attention_write(
     select=None,  # a ``Selection``: the query attends the keys it chose
 ):
     """A DECODE layer call's two halves as one op: the step's fresh K/V
-    lands in the arena, then ``paged_attention`` attends it (the fresh
+    lands in the arena and ``paged_attention`` attends it (the fresh
     entries included). Returns ``(out, k_arena, v_arena, k_scale,
     v_scale)`` — the scales None over a plain arena, ``out`` the ``(acc, m,
     l)`` triple with ``stats``.
 
     HOW the entries land follows what the call can see
     (``decode_writes_in_kernel``), never an option: one entry a row over a
-    plain arena with the attention on its kernel → ``write_rows_tpu``, a
-    kernel that moves one sublane tile a row and leaves the arena in place;
-    everything else — a verify's ``S = K + 1`` entries, an int8/fp8 arena's
-    running scales, context parallel's partial statistics, the XLA path
-    (the CPU mesh) — ``write_block_kv``'s scatter as before. The stored
-    bytes and the scores are the same on both."""
+    plain arena with the attention on its kernel → the entries ride INTO
+    the attention call, which stores them from the frontier cell it holds
+    (``paged_attention_tpu(fresh=)``: ONE Pallas call a layer,
+    ``paged_decode``, the arena aliased over itself; under a selection
+    too, whichever side of ``selected_attention``'s ``cond`` made the key
+    positions); everything else — a verify's ``S = K + 1`` entries, an
+    int8/fp8 arena's running scales, context parallel's partial
+    statistics, the XLA path (the CPU mesh) — ``write_block_kv``'s
+    scatter, then the attention. The stored bytes of every OWNED block and
+    the scores are the same on both; a gated entry and a trash-mapped
+    column land in block 0 on the scatter's path and nowhere on the
+    kernel's (a garbage sink by contract: nothing reads it)."""
     path = "xla" if stats else decode_path(
         backend, q.shape[-1], k_arena, block_table
     )
-    ks, vs = k_scale, v_scale
+    ks, vs, fresh = k_scale, v_scale, None
     if decode_writes_in_kernel(q.shape[1], k_scale is not None, stats, path):
-        k_arena, v_arena = write_rows_tpu(
-            k_arena, v_arena, layer, block_table, cols[:, 0], k_new[:, 0],
-            None if v_new is None else v_new[:, 0],
-            valid=valid if valid is None or not jnp.ndim(valid)
-            else valid[:, 0],
-            interpret=path == "interpret",
+        ok = True if valid is None else valid
+        fresh = Fresh(
+            k_new[:, 0], None if v_new is None else v_new[:, 0], cols[:, 0],
+            ok[:, 0] if jnp.ndim(ok) else ok,
         )
     else:
         wrote = write_block_kv(
@@ -2057,14 +2252,17 @@ def paged_attention_write(
     if select is not None:
         out = selected_attention(
             q, k_arena, v_arena, layer, block_table, q_positions,
-            kv_positions, select, scale, backend=backend,
+            kv_positions, select, scale, backend=backend, fresh=fresh,
         )
-        return out, k_arena, v_arena, ks, vs
-    out = paged_attention(
-        q, k_arena, v_arena, layer, block_table, q_positions, kv_positions,
-        scale, backend=backend, k_scale=ks, v_scale=vs, stats=stats,
-        latent_v=latent_v, window=window, sink=sink,
-    )
+    else:
+        out = paged_attention(
+            q, k_arena, v_arena, layer, block_table, q_positions,
+            kv_positions, scale, backend=backend, k_scale=ks, v_scale=vs,
+            stats=stats, latent_v=latent_v, window=window, sink=sink,
+            fresh=fresh,
+        )
+    if fresh is not None:
+        out, k_arena, v_arena = out
     return out, k_arena, v_arena, ks, vs
 
 
@@ -2598,7 +2796,7 @@ def select_mask(scores: jnp.ndarray, topk: int) -> jnp.ndarray:
 
 def selected_attention(
     q, k_arena, v_arena, layer, block_table, q_positions, kv_positions,
-    select: Selection, scale=None, backend: str = "auto",
+    select: Selection, scale=None, backend: str = "auto", fresh=None,
 ):
     """A DECODE step's attention under a selection (one query a row): the
     selection is a MASK over the row's columns, and it enters the attention
@@ -2612,7 +2810,11 @@ def selected_attention(
     lie (``_live_blocks`` reads the masked positions: the walk ends at the
     last block that holds a chosen token, and a dead row costs nothing), and
     its own ``kv_pos <= q_pos`` test drops every other column; the XLA and
-    ``interpret`` paths mask by the same positions."""
+    ``interpret`` paths mask by the same positions. ``fresh``
+    (``paged_attention``'s) goes to that one call after the ``cond``, so
+    both of its sides store the step's entry — also where the selection
+    did not keep the fresh key (the kernel's walk reaches its block all
+    the same)."""
     from ..models.cache import POS_SENTINEL  # models imports this module
 
     if q.shape[1] != 1:
@@ -2636,7 +2838,7 @@ def selected_attention(
     kv = jax.lax.cond(beyond, chosen, lambda _: kv_positions, None)
     return paged_attention(
         q, k_arena, v_arena, layer, block_table, q_positions, kv, scale,
-        backend=backend,
+        backend=backend, fresh=fresh,
     )
 
 

@@ -212,9 +212,10 @@ def state_specs(
 # spec-verify traversals (serve_verify) AND chunked prefill
 # (serve_prefill_chunk — the ``_gather_window`` gather→recompute→scatter
 # round trip it used to pay per chunk is retired) land fresh KV in the
-# owning blocks — serve_chunk through the write KERNEL
-# (ops/paged_attention.write_rows_tpu: one entry a row over a plain arena
-# with the attention on its kernel, the arena left in place) and otherwise,
+# owning blocks — serve_chunk inside the attention KERNEL itself
+# (ops/paged_attention.paged_attention_tpu(fresh=): one entry a row over a
+# plain arena with the attention on its kernel, stored from the frontier
+# cell the kernel holds, the arena left in place) and otherwise,
 # like serve_verify's K + 1 entries a row, as ROWS (write_block_kv: a
 # per-entry scatter, each row at its own column; paged_attention_write
 # picks, from the program's statics), serve_prefill_chunk as whole-block TILES

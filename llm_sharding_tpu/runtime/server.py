@@ -1760,13 +1760,19 @@ class PipelineServer:
         # choose (paged_attention_write asks the same function)
         from ..ops.paged_attention import decode_writes_in_kernel
 
-        kv_write = "kernel" if decode_writes_in_kernel(
+        in_kernel = decode_writes_in_kernel(
             entries, self.kv_quantized, self.cp > 1, self.attn_impl
-        ) else "scatter"
+        )
+        # K and V: stored by the attention call itself, or scattered; a
+        # selecting model's index key beside them: the write kernel's
+        forms = ["attention" if in_kernel else "scatter"]
+        if self.sparse:
+            forms.append("kernel" if in_kernel else "scatter")
         if rows:
             written = len(rows) * entries * steps
-            DECODE_KV_ENTRIES_WRITTEN.labels(write=kv_write).inc(written)
-            self.stepline.decode_kv_entries(kv_write, written)
+            for kv_write in forms:
+                DECODE_KV_ENTRIES_WRITTEN.labels(write=kv_write).inc(written)
+                self.stepline.decode_kv_entries(kv_write, written)
         if self.recurrent:
             self.stepline.recurrent_rows(len(rows))
         if self.sparse and rows:

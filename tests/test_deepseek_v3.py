@@ -312,14 +312,17 @@ def test_what_is_not_done_is_refused_by_name(params):
 # ``paged_kv_write`` before ``paged_decode``; ``serve_prefill_chunk`` and
 # ``serve_admit`` kept their texts); PR 54 re-recorded both ``serve_chunk``
 # again (``paged_decode`` is one invocation that copies a cell's blocks by
-# hand and walks in its body; the other two kept their texts); ``qwen2``'s
+# hand and walks in its body; the other two kept their texts); PR 61
+# re-recorded both ``serve_chunk`` once more (``paged_decode`` stores the
+# step's fresh K/V itself, the arenas aliased over its outputs: no
+# ``paged_kv_write`` before it; the other two kept their texts); ``qwen2``'s
 # ``serve_admit`` is still the parent of PR 34's.
 GOLDEN = {
     ("qwen2", "serve_admit"): "41a2afe52004928f",
-    ("qwen2", "serve_chunk"): "ffac4fe632df9c76",
+    ("qwen2", "serve_chunk"): "294ec14a73fc8447",
     ("qwen2", "serve_prefill_chunk"): "1f5a149bea8b1099",
     ("olmoe", "serve_admit"): "ff5a01947e2fda27",
-    ("olmoe", "serve_chunk"): "de6888b0b308bff4",
+    ("olmoe", "serve_chunk"): "7264bbc92937da96",
     ("olmoe", "serve_prefill_chunk"): "2578c200e397507d",
 }
 

@@ -428,6 +428,64 @@ def test_a_selecting_decode_step_attends_the_list_select_tokens_gives(
                 got[b, 0, h], np.asarray(p) @ v[b, cols, h // 2], atol=TOL)
 
 
+@pytest.mark.parametrize("side", ["chosen", "everything"])
+@pytest.mark.parametrize("case", SELECTING)
+def test_a_selecting_decode_step_stores_its_entry_in_the_attention_call(
+        case, side):
+    """``paged_attention_write(select=)`` on the kernel path (interpreted):
+    the step's fresh K/V rides into the ONE ``paged_decode`` after
+    ``selected_attention``'s ``cond`` and is stored there, on both of the
+    ``cond``'s sides (``chosen``: a row's context is past ``topk``;
+    ``everything``: every context cut to twelve tokens, no score taken) —
+    the output the same kernel's over the arena ``write_block_kv`` leaves,
+    bit for bit, and both arenas that scatter's over every owned block. Also
+    where the selection keeps no column of the fresh entry's block (the
+    query's own token not chosen; the chosen in two early blocks): the
+    kernel's walk is stretched to that block, which adds nothing to the
+    softmax, so that the entry lands all the same."""
+    ctx, q_pos, qi, wi, ki, _ = _selecting_case(case)
+    _, _, q, ka, va, ia, table, kv_pos = _selecting_arenas(case)
+    if side == "everything":
+        ctx = [min(c, 12) for c in ctx]
+        q_pos = [c - 1 if c else POS_SENTINEL for c in ctx]
+        for b in range(2):
+            kv_pos[b, ctx[b]:] = POS_SENTINEL
+            table[b, -(-ctx[b] // BS):] = 0
+    rng = np.random.default_rng(61)
+    k_new, v_new = (jnp.asarray(
+        rng.standard_normal((2, 1, _NKV, _D)), jnp.float32) for _ in "kv")
+    # the fresh entry is the query's own token; a dead row's lands in trash
+    cols = jnp.asarray([[max(c - 1, 0)] for c in ctx], jnp.int32)
+    select = pa.Selection(
+        jnp.asarray(qi)[:, None], jnp.asarray(wi)[:, None], jnp.asarray(ia),
+        _TOPK)
+    args = (jnp.asarray(table), jnp.asarray(q_pos, jnp.int32)[:, None],
+            jnp.asarray(kv_pos))
+    ok = pa._attendable(*args, BS)
+    assert bool(jnp.any(jnp.sum(ok, axis=-1) > _TOPK)) == (side == "chosen")
+    out, k, v, _, _ = pa.paged_attention_write(
+        jnp.asarray(q), k_new, v_new, jnp.asarray(ka), jnp.asarray(va), 1,
+        args[0], cols, *args[1:], backend="interpret", select=select)
+    k_w, v_w = pa.write_block_kv(
+        jnp.asarray(ka), jnp.asarray(va), 1, args[0], cols, k_new, v_new)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(
+        pa.selected_attention(
+            jnp.asarray(q), k_w, v_w, 1, *args, select, backend="interpret")))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(
+        pa.selected_attention(
+            jnp.asarray(q), k_w, v_w, 1, *args, select, backend="xla")),
+        atol=TOL)
+    for got, want, new in ((k, k_w, k_new), (v, v_w, v_new)):
+        np.testing.assert_array_equal(
+            np.asarray(got)[:, 1:], np.asarray(want)[:, 1:])
+        for b in range(2):
+            if ctx[b]:
+                np.testing.assert_array_equal(
+                    np.asarray(got)[
+                        1, table[b, (ctx[b] - 1) // BS], :, (ctx[b] - 1) % BS],
+                    np.asarray(new)[b, 0])
+
+
 def sorted_mask(scores, topk):
     """``select_mask`` as it was before PR 51, from ``select_tokens``' sorted
     list: above the list's last score, and of the columns that tie with it

@@ -281,8 +281,11 @@ def test_the_step_programs_name_the_close_of_a_pass(params):
         paths = set(re.findall(r'loc\("([^"]+)"', text))
         found = {w for w in SCOPES
                  if any(re.search(rf"(^|/){w}(/|$)", p) for p in paths)}
-        assert {"pass_close", "norm", "qkv", "rope", "kv_write", "attn",
-                "o_proj", "mlp", "state"} <= found
+        assert {"pass_close", "norm", "qkv", "rope", "attn", "o_proj", "mlp",
+                "state"} <= found
+        # a chunk scatters its tiles; a decode step's entry is stored inside
+        # the interpreted ``paged_decode``, under ``attn`` (PR 61)
+        assert ("kv_write" in found) == (name == "serve_prefill_chunk")
         assert not found & {"router", "moe", "absorb", "zero_expert", "ssm",
                             "ssm_proj", "ssm_x", "moe_latent", "kda",
                             "kda_proj", "conv", "indexer", "select",

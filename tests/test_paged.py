@@ -934,6 +934,132 @@ def test_the_fused_decode_write_leaves_what_the_scatter_leaves(
             )
 
 
+#: where a row's fresh slot lies in its block of 16 float32 tokens (two
+#: sublane tiles of 8): the block's first and last column, and either side
+#: of the tiles' edge
+_FRESH_SLOTS = {"block_first": 0, "tile_last": 7, "tile_first": 8,
+                "block_last": 15}
+
+
+def _store_case(slot, live, gate, seed=61):
+    """Four rows of a slot over blocks of 16 tokens and a table of 8 entries
+    (ONE cell of eight blocks a row: what follows a row's frontier block in
+    its cell names the trash block): the first ``live`` rows hold two full
+    blocks and write at ``slot`` of their third, the others are dead (table
+    all trash, no real query, column 0). ``gate``: ``write_block_kv``'s
+    ``valid``."""
+    from llm_sharding_tpu.models.cache import POS_SENTINEL
+
+    bs, T, NB, Nkv, G, D = 16, 8, 20, 2, 3, 8
+    rng = np.random.default_rng(seed)
+    k, v = make_stack(rng, NB, Nkv, bs, D)
+    col = 2 * bs + slot
+    table = np.zeros((4, T), np.int32)
+    kvpos = np.full((4, T * bs), POS_SENTINEL, np.int32)
+    for b in range(live):
+        table[b, :3] = 1 + 3 * b + np.arange(3)
+        kvpos[b, : col + 1] = np.arange(col + 1)
+    alive = np.arange(4) < live
+    normal = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    return dict(
+        q=normal(4, 1, Nkv * G, D), k_new=normal(4, 1, Nkv, D),
+        v_new=normal(4, 1, Nkv, D), k=k, v=v, table=jnp.asarray(table),
+        cols=jnp.asarray(np.where(alive, col, 0)[:, None], jnp.int32),
+        qpos=jnp.asarray(
+            np.where(alive, col, POS_SENTINEL)[:, None], jnp.int32),
+        kvpos=jnp.asarray(kvpos), kw={},
+    ), {
+        "open": None, "shut": jnp.asarray(False),
+        "row_0_shut": jnp.asarray([[False], [True], [True], [True]]),
+    }[gate]
+
+
+@pytest.mark.parametrize("gate", ("open", "shut", "row_0_shut"))
+@pytest.mark.parametrize("live", (1, 4))
+@pytest.mark.parametrize("slot", sorted(_FRESH_SLOTS))
+def test_the_decode_kernel_stores_what_it_attends(slot, live, gate):
+    """The interpreted decode kernel with the write INSIDE
+    (``paged_attention_write`` where ``decode_writes_in_kernel`` holds: ONE
+    Pallas call) against the parent's form — ``write_block_kv``, then the
+    attention: the output bit for bit the same kernel's over the scattered
+    arena and within tolerance of the XLA path's, both arenas bit for bit
+    over every block a table can own — with the fresh slot at a block's
+    first and last column and on either side of a sublane tile's edge, one
+    live row of four and all four, the gate open, shut (a ring stage's
+    bubble microstep: no owned block changes) and shut for one row. The
+    frontier block is followed in its cell by entries that name the trash
+    block, and a dead row is one the walk skips: neither stores anything."""
+    from llm_sharding_tpu.ops import paged_attention as pa
+
+    c, valid = _store_case(_FRESH_SLOTS[slot], live, gate)
+    layer = 2
+    assert pa.decode_blocks_per_cell(8, 16, 2, 16, 4) == 8  # one cell a row
+    fused = lambda k, v: pa.paged_attention_write(
+        c["q"], c["k_new"], c["v_new"], k, v, layer, c["table"], c["cols"],
+        c["qpos"], c["kvpos"], valid=valid, backend="interpret",
+    )
+    assert [e.params["name"] for e in _pallas_calls(
+        jax.make_jaxpr(fused)(c["k"], c["v"]).jaxpr)] == ["paged_decode"]
+    out, k, v, _, _ = jax.jit(fused)(c["k"], c["v"])
+    want, k_w, v_w = _scatter_then_attend(c, layer, valid)
+    _close(out, want, jnp.float32)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(
+        pa.paged_attention(
+            c["q"], k_w, v_w, layer, c["table"], c["qpos"], c["kvpos"],
+            backend="interpret",
+        )
+    ))
+    col = 2 * 16 + _FRESH_SLOTS[slot]
+    for before, a, w, new in zip(
+        (c["k"], c["v"]), (k, v), (k_w, v_w), (c["k_new"], c["v_new"])
+    ):
+        a, w, before = np.asarray(a), np.asarray(w), np.asarray(before)
+        np.testing.assert_array_equal(a[:, 1:], w[:, 1:])
+        others_untouched(before, a, layer)
+        # nothing lands in the sink: the kernel stores owned entries only
+        np.testing.assert_array_equal(a[:, 0], before[:, 0])
+        for b in range(live):
+            shut = gate == "shut" or (gate == "row_0_shut" and b == 0)
+            blk = int(c["table"][b, 2])
+            np.testing.assert_array_equal(
+                a[layer, blk, :, col % 16],
+                before[layer, blk, :, col % 16] if shut
+                else np.asarray(new)[b, 0],
+            )
+
+
+def test_a_rows_fresh_column_lies_in_its_frontier_block():
+    """What lets the decode kernel store the entry from the cell it ends a
+    row's walk in: the step's ``kv_positions`` already hold the fresh
+    column at the query's position, so ``_live_blocks``' frontier — the
+    last owned entry holding a key position at or under the row's query
+    position — IS the entry of the fresh column, ``cols // BS``, for every
+    live row of a decode step as ``serve_chunk`` makes one (a slot's rows
+    share their column; rows of unlike prompt lengths hold the sentinel
+    between their prompt's end and it). Where a selection has masked the
+    fresh key itself out, the kernel stretches the walk to that entry
+    (``tests/test_keye_vl2.py``)."""
+    from llm_sharding_tpu.models.cache import POS_SENTINEL
+    from llm_sharding_tpu.ops.paged_attention import _live_blocks
+
+    bs, T = 8, 6
+    for col in (0, 7, 8, 23, 40, 47):
+        # three rows: a prompt as long as the slot's column, a shorter one
+        # (sentinels between its end and the column), a dead row
+        kvpos = np.full((3, T * bs), POS_SENTINEL, np.int32)
+        kvpos[0, :col] = np.arange(col)
+        kvpos[1, : col // 2] = np.arange(col // 2)
+        qpos = np.asarray([col, col // 2, POS_SENTINEL], np.int32)
+        kvpos[np.arange(2), col] = qpos[:2]  # serve_chunk: the fresh column
+        table = np.zeros((3, T), np.int32)
+        table[:2, : col // bs + 1] = 1 + np.arange(2 * (col // bs + 1)).reshape(
+            2, -1)
+        nlive = np.asarray(_live_blocks(
+            jnp.asarray(table), jnp.asarray(qpos[:, None]),
+            jnp.asarray(kvpos)))
+        np.testing.assert_array_equal(nlive, [col // bs + 1, col // bs + 1, 0])
+
+
 def test_the_fused_decode_write_carries_the_arena_through_a_layer_scan():
     """Inside ``lax.scan`` over two layers with the arenas donated (the
     kernel's output aliased over its operand, as the step programs carry
@@ -1792,11 +1918,20 @@ def test_a_decode_step_reads_its_weights_as_they_are_stored(
     assert _weight_stack_relayouts(text) == []
     dots, windowed = _windowed_projections(text)
     assert len(dots) >= 3 and windowed == []
-    # and writes its arena where it lies (PR 46): the step's fresh K/V
-    # lands through the write kernel, so no operation of the program but a
-    # kernel produces an arena — no scatter into the carried stack, no copy
-    # or staging of it around one
-    assert "paged_kv_write/pallas_call" in text
+    # and writes its arena where it lies (PR 46): no operation of the
+    # program but a kernel produces an arena — no scatter into the carried
+    # stack, no copy or staging of it around one. Since PR 61 that kernel is
+    # the attention's own (``paged_decode`` stores the step's fresh K/V from
+    # its frontier cell, each arena aliased over itself): the write kernel
+    # ``paged_kv_write`` is gone from every program but Keye's, whose index
+    # arena it still feeds (the score call reads it before the attention)
+    writes = text.count("paged_kv_write/pallas_call")
+    assert writes == (1 if cell == "keye_vl2_30b_a3b" else 0)
+    decodes = [
+        ln for ln in text.split("\n")
+        if "tpu_custom_call" in ln and "paged_decode/pallas_call" in ln]
+    assert decodes and all(
+        "output_to_operand_aliasing" in ln for ln in decodes), decodes
     assert _arena_ops(text) == []
 
 
@@ -1951,13 +2086,14 @@ def test_a_windowed_models_step_programs_compile_and_read_weights_as_stored(
     assert _weight_stack_relayouts(decode) == []
     dots, windowed = _windowed_projections(decode)
     assert len(dots) >= 3 and windowed == []
-    # five runs of one kind: a write kernel and an attention kernel each, an
+    # five runs of one kind: ONE attention kernel each (it stores the step's
+    # fresh K/V itself since PR 61: the write kernel before it is gone), an
     # expert kernel in four
-    assert decode.count("tpu_custom_call") == 14
-    assert decode.count("paged_kv_write/pallas_call") >= 5
-    assert "paged_decode" in decode and "paged_prefill" in texts[
-        "serve_prefill_chunk[256]"]
-    # a decode step's fresh K/V lands through the write kernel: XLA scatters
+    assert decode.count("tpu_custom_call") == 9
+    assert "paged_kv_write" not in decode
+    assert decode.count("paged_decode/pallas_call") >= 5
+    assert "paged_prefill" in texts["serve_prefill_chunk[256]"]
+    # a decode step's fresh K/V lands inside the attention kernel: XLA scatters
     # into no arena. What is left is its own choice of memory for a SMALL
     # array the loop carries: the window layers' 31 MB value arena moves
     # into fast memory before the step's loops and back after them, once a
@@ -1994,9 +2130,11 @@ def test_a_recurrent_models_step_programs_compile_and_read_weights_as_stored(
     assert _weight_stack_relayouts(decode) == []
     dots, windowed = _windowed_projections(decode)
     assert len(dots) >= 3 and windowed == []
-    # seventeen runs of one kind: an expert kernel in seven, the write and
-    # the decode kernel in two, the state kernel in eight
-    assert decode.count("tpu_custom_call") == 19
+    # seventeen runs of one kind: an expert kernel in seven, the decode
+    # kernel (it stores the step's fresh K/V itself: PR 61) in two, the
+    # state kernel in eight
+    assert decode.count("tpu_custom_call") == 17
+    assert "paged_kv_write" not in decode
     assert "paged_decode" in decode and "moe_experts" in decode
     assert decode.count("ssm_rows/pallas_call") >= 8
     prefill = texts["serve_prefill_chunk[256]"]
@@ -2442,7 +2580,10 @@ _MLP_WORDS = {
     "experts": {"mlp", "absorb"} | _OTHERS_WORDS,
 }
 PROGRAM_SCOPES = {
-    "serve_chunk": _NO_ARENA_COPY,
+    # a decode step's fresh K/V is stored INSIDE ``paged_decode`` (under
+    # ``attn``) on the kernel path these programs take: nothing is left under
+    # ``kv_write`` there (PR 61)
+    "serve_chunk": _NO_ARENA_COPY | {"kv_write"},
     "serve_prefill_chunk": _NO_ARENA_COPY | _NO_HEAD,
     "serve_admit": set(),
     "serve_admit_finish": None,  # exactly: embed, state
@@ -2549,13 +2690,12 @@ def test_step_programs_carry_the_scope_vocabulary(request, program, model):
             assert not any(gone in p + "/" for p in paths), gone
         # the read is the kernel's own block DMAs; a chunk's write is the
         # scatter into the carried stack, a decode step's (one entry a row,
-        # a plain arena, the attention on its kernel) the write kernel,
-        # which leaves XLA no scatter into the arena
+        # a plain arena, the attention on its kernel) the attention
+        # kernel's own, which leaves XLA no scatter into the arena and the
+        # program no write kernel
         scatter = any(p.endswith("kv_write/scatter") for p in paths)
-        kernel = any("kv_write/paged_kv_write" in p for p in paths)
-        assert (scatter, kernel) == (
-            (False, True) if program == "serve_chunk" else (True, False)
-        )
+        assert not any("paged_kv_write" in p for p in paths)
+        assert scatter == (program != "serve_chunk")
     if program == "serve_chunk":
         assert any(p.endswith("ring_hop/ppermute") for p in paths)
 
@@ -2766,10 +2906,12 @@ def test_a_layer_scan_holds_one_decode_kernel_over_whole_blocks(
     that carries the arena holds exactly ONE attention ``pallas_call``,
     named ``paged_decode``, and the tiles of its two cell buffers are
     ``(Nkv, BS, D)`` wide — a block's key/value heads together in one copy.
-    Before it, where a step writes one entry a row into a plain arena
-    (``serve_chunk`` over bf16), ONE write kernel ``paged_kv_write`` whose
-    arena blocks are the sublane tile ``(Nkv, SUB, D)`` that holds the slot;
-    a verify's ``K + 1`` entries and an int8 arena keep the scatter."""
+    It is the scan's ONLY Pallas call: where a step writes one entry a row
+    into a plain arena (``serve_chunk`` over bf16) the kernel stores the
+    entry itself, both arenas aliased over outputs of the call and the
+    entries among its operands (PR 61: no ``paged_kv_write`` before it); a
+    verify's ``K + 1`` entries and an int8 arena keep the scatter, and
+    their attention call aliases nothing."""
     jaxprs, stack_shape, _, _, _ = traced_programs
     jaxpr = jaxprs[program, kv_dtype]
     _, _, Nkv, BS, D = stack_shape
@@ -2782,24 +2924,24 @@ def test_a_layer_scan_holds_one_decode_kernel_over_whole_blocks(
                      for e in _leaf_eqns(scan.params["jaxpr"].jaxpr)}
             assert "gather" in names
         return
-    from llm_sharding_tpu.ops.paged_attention import kernel_sublane
-
     writes = program == "serve_chunk" and kv_dtype == "bf16"
-    sub = kernel_sublane(jnp.float32)  # the fixture's cache dtype
-    sub = sub if BS % sub == 0 else BS
     for scan in scans:
-        *write, call = _pallas_calls(scan.params["jaxpr"].jaxpr)
-        assert [c.params["name"] for c in write] == (
-            ["paged_kv_write"] if writes else []
-        )
+        (call,) = _pallas_calls(scan.params["jaxpr"].jaxpr)
         assert call.params["name"] == "paged_decode"
         scratch = call.params["jaxpr"].invars[
             -call.params["grid_mapping"].num_scratch_operands:]
         cells = [v.aval.shape for v in scratch if len(v.aval.shape) == 5]
         assert len(cells) == 2 and {c[2:] for c in cells} == {(Nkv, BS, D)}
-        for w in write:
-            tiles = [b for b in _block_shapes(w) if len(b) == 5]
-            assert tiles and set(tiles) == {(None, None, Nkv, sub, D)}
+        # the arenas, each handed in ONCE and aliased over its own output;
+        # the fresh entries [rows, Nkv, D] ride in beside them
+        aliased = [
+            (call.invars[i].aval.shape, call.outvars[o].aval.shape)
+            for i, o in call.params["input_output_aliases"]]
+        assert aliased == ([(stack_shape,) * 2] * 2 if writes else [])
+        arenas = [v for v in call.invars if v.aval.shape == stack_shape]
+        assert len(arenas) == 2
+        entries = [v for v in call.invars if v.aval.shape[1:] == (Nkv, D)]
+        assert len(entries) == (2 if writes else 0)
 
 
 @pytest.mark.parametrize("spec", [0, 2])
@@ -2809,18 +2951,20 @@ def test_the_decode_write_is_counted_by_its_form(
 ):
     """``server_decode_kv_entries_written_total{write=}`` and the step
     record's ``decode_kv_entries`` say how a served step's fresh K/V landed:
-    ``kernel`` where the step program's statics choose the write kernel (one
-    entry a row, a plain arena, the attention on its kernel) — the same
-    predicate ``paged_attention_write`` asks, so the count is the program's
-    — and ``scatter`` for a verify step, an int8 arena and the XLA path."""
+    ``attention`` where the step program's statics let the attention kernel
+    store it (one entry a row, a plain arena, the attention on its kernel) —
+    the same predicate ``paged_attention_write`` asks, so the count is the
+    program's — and ``scatter`` for a verify step, an int8 arena and the XLA
+    path; ``kernel`` (what ``paged_kv_write`` still stores: a selecting
+    model's index keys) stays 0 for a model without an indexer."""
     *_, writes = traced_programs
     backend = request.node.callspec.params["traced_programs"]
     counted, recorded = writes[kv_dtype, spec]
-    form = "kernel" if (
+    form = "attention" if (
         backend == "interpret" and kv_dtype == "bf16" and not spec
     ) else "scatter"
-    other = "scatter" if form == "kernel" else "kernel"
-    assert counted[form] > 0 and counted[other] == 0, counted
+    assert counted[form] > 0 and sum(counted.values()) == counted[form], (
+        counted)
     assert recorded == {form: counted[form]}
     if spec:  # a verify writes K + 1 entries a live row
         assert counted[form] % (spec + 1) == 0
