@@ -1171,17 +1171,19 @@ def cmd_profile(args) -> int:
     else:
         from .models import config as config_mod
 
+        from .models.family import family
+
         cfg = getattr(config_mod, args.preset)()
-        if cfg.model_type == "llama":
-            from .models import llama as model_mod
-        elif cfg.model_type == "gpt2":
-            from .models import gpt2 as model_mod
-        else:
+        fam = family(cfg)
+        # the Profiler times the whole-model forward and sizes a layer's
+        # cache as K/V heads × head_dim: not layers of several kinds, not a
+        # latent entry
+        if fam.forward is None or cfg.layer_kinds or cfg.latent_kv:
             raise SystemExit(
                 f"preset {args.preset!r} has unsupported model_type "
                 f"{cfg.model_type!r} for random-weight profiling"
             )
-        params = model_mod.init_params(cfg, jax.random.key(0), dtype=dtype)
+        params = fam.init_params(cfg, jax.random.key(0), dtype=dtype)
 
     prof = Profiler(cfg, params, dtype=dtype)
     prefill = prof.profile_prefill()

@@ -61,7 +61,7 @@ class ModelConfig:
     (``/root/reference/utils/model_sharder.py:64,96``).
     """
 
-    model_type: str = "llama"  # "llama" | "gpt2"
+    model_type: str = "llama"  # a key of ``models/family.py``'s table
     vocab_size: int = 32000
     hidden_size: int = 4096
     intermediate_size: int = 11008

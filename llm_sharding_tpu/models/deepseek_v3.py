@@ -75,8 +75,9 @@ from ..ops.quant import QTensor, qmatmul
 from ..ops.rope import apply_rope, rope_cos_sin, yarn_mscale
 from .cache import KVCache
 from .config import ModelConfig
+from .family import refuse_axes
 from .llama import embed, final_logits  # noqa: F401  (the family's own)
-from .stack import kind_spans, scan_layers, scan_layers_paged
+from .stack import kind_spans, scan_layers, scan_layers_paged, zero_stats
 
 Params = dict[str, Any]
 
@@ -369,29 +370,14 @@ def mlp_sub_block(
     return h + y.reshape(B, S, H) + shared, stats
 
 
-def _zero_stats(cfg: ModelConfig, count: int) -> moe.MoeStats:
-    return moe.MoeStats(
-        jnp.zeros((count, cfg.num_experts), jnp.int32),
-        jnp.zeros((count,), jnp.int32),
-    )
-
-
 def _join_stats(cfg, parts):
     """The kinds' stacked stats, laid over the stage's layer slots."""
     parts = [
-        _zero_stats(cfg, count) if st is None else st for st, count in parts
+        zero_stats(cfg, count) if st is None else st for st, count in parts
     ]
     if len(parts) == 1:
         return parts[0]
     return jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *parts)
-
-
-def _refuse_tp(tp_axis, cp_axis=None):
-    if tp_axis is not None or cp_axis is not None:
-        raise NotImplementedError(
-            "tensor / context parallelism over deepseek_v3 (latent "
-            "attention, a share of the experts) is not implemented"
-        )
 
 
 def forward_layers(
@@ -406,7 +392,7 @@ def forward_layers(
 ):
     """Dense-cache path (the monolith, one-shot admission). Returns ``(h,
     cache, stats)``, ``stats`` stacked over the stage's layer slots."""
-    _refuse_tp(tp_axis)
+    refuse_axes(cfg, tp_axis)
     with jax.named_scope("rope"):
         cos, sin = rope_cos_sin(positions, cfg, dtype=jnp.float32)
     scale = softmax_scale(cfg)
@@ -470,7 +456,7 @@ def forward_layers_paged(
         paged_attention_write, paged_prefill, write_chunk_kv,
     )
 
-    _refuse_tp(tp_axis, cp_axis)
+    refuse_axes(cfg, tp_axis, cp_axis)
     if k_scale is not None:
         raise NotImplementedError(
             "a quantized (int8/fp8) latent cache is not implemented"

@@ -8,8 +8,8 @@ then ``h ← h + MLP(RMSNorm_ff(h))``; ``cfg.layer_pattern[l]`` names the mixer:
 ``l`` attends where ``l % attn_layer_period == attn_layer_offset``. A kind's
 layer is its mixer AND the MLP: ``params["layers"] = {kind: {leaf: [L_kind,
 ...]}}``, one stack per kind in layer order; a stage runs its layers as RUNS
-of one kind in model order (``models/nemotron_h.stage_runs``, ``_scan_run``
-— the helpers ``mimo_v2`` and ``nemotron_h`` use). Every stage of a ring must
+of one kind in model order (``models/stack.stage_runs``, ``scan_run`` — the
+helpers ``mimo_v2`` and ``nemotron_h`` use). Every stage of a ring must
 hold the same sequence of kinds. NO positional embedding anywhere.
 
 **``mamba``** (``ops/ssm.py``, "Mamba-1"). ``[x | z] = ĥ w_in`` (``H → 2
@@ -75,11 +75,11 @@ from ..ops.norms import rms_norm
 from ..ops.quant import qmatmul
 from .config import ModelConfig
 from .llama import embed, final_logits, gated_mlp  # noqa: F401
-from .mimo_v2 import _scan_run
+from .family import refuse_axes
 from .nemotron_h import (  # noqa: F401  (``prefill_walks``: the family's)
-    attn_block, kind_layer_counts, prefill_walks, stage_runs,
+    attn_block, kind_layer_counts, prefill_walks,
 )
-from .stack import MAMBA1_WHOLE_KEYS, zero_recurrent
+from .stack import MAMBA1_WHOLE_KEYS, scan_run, stage_runs, zero_recurrent
 
 Params = dict[str, Any]
 f32 = jnp.float32
@@ -361,14 +361,6 @@ def mlp_block(cfg: ModelConfig, p: Params, h):
 # Stage functions
 # ---------------------------------------------------------------------------
 
-def _refuse_tp(tp_axis, cp_axis=None):
-    if tp_axis is not None or cp_axis is not None:
-        raise NotImplementedError(
-            "tensor / context parallelism over jamba (a recurrent state "
-            "beside the arena) is not implemented"
-        )
-
-
 def forward_layers(cfg, layers, h, cache, positions, layer_mask=None,
                    tp_axis=None, moe_live=None):
     """The dense-cache path is REFUSED: a ``KVCache`` row has no place for a
@@ -408,7 +400,7 @@ def forward_layers_paged(
         paged_attention_write, paged_prefill, write_chunk_kv,
     )
 
-    _refuse_tp(tp_axis, cp_axis)
+    refuse_axes(cfg, tp_axis, cp_axis)
     if k_scale is not None:
         raise NotImplementedError(
             "a quantized (int8/fp8) arena under jamba is not implemented"
@@ -477,7 +469,7 @@ def forward_layers_paged(
                 jnp.where(valid, h_new, h), k_a, v_a, s_all, c_all
             ), None
 
-        carry, _ = _scan_run(
+        carry, _ = scan_run(
             run, layers[run.kind],
             layer_mask[run.slot_first:run.slot_first + run.count],
             carry, apply,

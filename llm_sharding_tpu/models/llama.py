@@ -238,8 +238,8 @@ def attn_mlp_block(
         # windowed over the heads that wants ``wk``/``wv`` with the input
         # dim minor, and the compiled v5e program re-lays the WHOLE layer
         # stack of both (parameters: they cannot stay transposed) at the
-        # top of every call (tests/test_paged.py holds the compiled program
-        # to it).
+        # top of every call (tests/test_paged_programs.py holds the compiled
+        # program to it).
         kx, vx = jax.lax.optimization_barrier((kx, vx))
     if "q_norm" in p:
         # OLMoE: an RMSNorm over the WHOLE projected width of q and of k,

@@ -66,6 +66,7 @@ from ..ops.rope import rope_cos_sin
 from .cache import KVCache
 from .config import ModelConfig
 from .deepseek_v3 import gated_mlp, mla_attention, softmax_scale
+from .family import refuse_axes
 from .llama import embed, final_logits  # noqa: F401  (the family's own)
 from .stack import join_whole, masked_stats, scan_layers_paged, split_whole
 
@@ -211,14 +212,6 @@ def layer_block(
     return h, cache, stats
 
 
-def _refuse_tp(tp_axis, cp_axis=None):
-    if tp_axis is not None or cp_axis is not None:
-        raise NotImplementedError(
-            "tensor / context parallelism over longcat_flash (two latent "
-            "attentions a layer, a share of the experts) is not implemented"
-        )
-
-
 def forward_layers(
     cfg: ModelConfig,
     layers: Params,  # stacked leaves [L, ...]
@@ -234,7 +227,7 @@ def forward_layers(
     scan carry with in-place writes of the step's positions only, a masked
     layer changing nothing — over TWO cache slots a layer, which that scan's
     one row a layer cannot hand out. Returns ``(h, cache, stats)``."""
-    _refuse_tp(tp_axis)
+    refuse_axes(cfg, tp_axis)
     S = h.shape[1]
     with jax.named_scope("rope"):
         cos, sin = rope_cos_sin(positions, cfg, dtype=jnp.float32)
@@ -325,7 +318,7 @@ def forward_layers_paged(
         paged_attention_write, paged_prefill, write_chunk_kv,
     )
 
-    _refuse_tp(tp_axis, cp_axis)
+    refuse_axes(cfg, tp_axis, cp_axis)
     if k_scale is not None:
         raise NotImplementedError(
             "a quantized (int8/fp8) latent cache is not implemented"

@@ -50,8 +50,8 @@ arena, a stage whose kinds differ from the model's first stage's.
 
 from __future__ import annotations
 
-import itertools
-from typing import Any, NamedTuple, Optional
+import functools
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -63,11 +63,11 @@ from ..ops.quant import qmatmul
 from ..ops.rope import apply_rope, rope_cos_sin
 from .cache import KVCache
 from .config import ModelConfig
+from . import stack
 from .deepseek_v3 import gated_mlp
+from .family import refuse_axes
 from .llama import embed, final_logits  # noqa: F401  (the family's own)
-from .stack import (
-    join_whole, kind_spans, masked_stats, scan_layers, split_whole,
-)
+from .stack import place_stats, scan_layers, scan_run
 
 Params = dict[str, Any]
 
@@ -81,40 +81,9 @@ def has_sink(cfg: ModelConfig, attn: str) -> bool:
     return cfg.swa_sink if attn == "swa" else cfg.full_sink
 
 
-class Run(NamedTuple):
-    """Consecutive layers of one kind, as a stage runs them."""
-
-    kind: str
-    stack_first: int  # the run's first layer in its kind's stack
-    count: int
-    slot_first: int  # ... in the stage's layer slots (mask, stats, dense cache)
-    attn: str  # "full" | "swa"
-    arena_first: int  # ... in its attention kind's arena
-
-
-def stage_runs(cfg: ModelConfig, layers: Params) -> list:
-    """The stage's layers as runs of one kind in MODEL order. The stage
-    holds ``sum of its stacks`` layers and every stage the same sequence of
-    kinds, so the sequence is the model's first that many
-    (``parallel/placement`` refuses a ring that would not)."""
-    spans = {k: (first, n) for k, first, n in kind_spans(layers, cfg.layer_kinds)}
-    total = sum(n for _, n in spans.values())
-    seq = cfg.layer_kinds[:total]
-    for kind, (_, n) in spans.items():
-        if seq.count(kind) != n:
-            raise NotImplementedError(
-                f"mimo_v2: a stage holds {n} layers of kind {kind!r} where "
-                f"the model's first {total} layers have {seq.count(kind)}: "
-                "every stage must hold the same sequence of layer kinds "
-                "(whole periods of the pattern, none padded)"
-            )
-    runs, in_stack, in_arena = [], {}, {}
-    for kind, group in itertools.groupby(seq):
-        n, attn = len(list(group)), attn_of(kind)
-        s0, a0 = in_stack.get(kind, 0), in_arena.get(attn, 0)
-        runs.append(Run(kind, s0, n, spans[kind][0] + s0, attn, a0))
-        in_stack[kind], in_arena[attn] = s0 + n, a0 + n
-    return runs
+#: ``stack.stage_runs``, each run with its kind's attention and its first
+#: layer in that attention's arena
+stage_runs = functools.partial(stack.stage_runs, attn_of=attn_of)
 
 
 def attn_layer_counts(cfg: ModelConfig, layers: Params, axis: int = 1) -> dict:
@@ -295,36 +264,6 @@ def _window(cfg: ModelConfig, attn: str) -> int:
     return cfg.sliding_window if attn == "swa" else 0
 
 
-def _zero_stats(cfg: ModelConfig, count: int) -> moe.MoeStats:
-    return moe.MoeStats(
-        jnp.zeros((count, cfg.num_experts), jnp.int32),
-        jnp.zeros((count,), jnp.int32),
-    )
-
-
-def _place_stats(cfg, total, parts):
-    """The runs' stacked stats, laid over the stage's ``total`` layer slots
-    (a dense run reads and counts nothing)."""
-    if not cfg.num_experts:
-        return None
-    out = _zero_stats(cfg, total)
-    for run, st in parts:
-        if st is not None:
-            out = jax.tree.map(
-                lambda o, s: o.at[run.slot_first:run.slot_first + run.count].set(s),
-                out, st,
-            )
-    return out
-
-
-def _refuse_tp(tp_axis, cp_axis=None):
-    if tp_axis is not None or cp_axis is not None:
-        raise NotImplementedError(
-            "tensor / context parallelism over mimo_v2 (a KV state per kind "
-            "of layer, a share of the experts) is not implemented"
-        )
-
-
 def forward_layers(
     cfg: ModelConfig,
     layers: Params,  # {kind: stacked leaves}
@@ -340,7 +279,7 @@ def forward_layers(
     the whole row (nothing is freed in a dense cache); attention is the XLA
     form (``ops/attention.cached_attention``). Returns ``(h, cache,
     stats)``."""
-    _refuse_tp(tp_axis)
+    refuse_axes(cfg, tp_axis)
     rope = _rope_tables(cfg, positions)
     scale = cfg.head_dim_ ** -0.5
     if layer_mask is None:
@@ -384,31 +323,7 @@ def forward_layers(
         )
         k_all, v_all = new.k, new.v
         parts.append((run, stats))
-    return h, new, _place_stats(cfg, layer_mask.shape[0], parts)
-
-
-def _scan_run(run: Run, stack: Params, mask, carry, apply_layer):
-    """One run: ``lax.scan`` over layers ``run.stack_first …`` of ``stack``
-    (its kind's whole stack), each layer's leaves taken out where they lie
-    (the scan's own per-iteration slice, at an offset) — never a slice of
-    the stack made beforehand, which would copy the run's weights a call.
-    ``apply_layer(p, i, valid, carry) -> (carry, stats)``, ``i`` the layer's
-    index in the run."""
-    scanned, whole = split_whole(stack)
-
-    def body(carry, xs):
-        i, valid = xs
-        at = i + run.stack_first
-        p = jax.tree.map(
-            lambda a: jax.lax.dynamic_index_in_dim(a, at, keepdims=False),
-            scanned,
-        )
-        carry, stats = apply_layer(join_whole(p, whole, at), i, valid, carry)
-        return carry, masked_stats(stats, valid)
-
-    return jax.lax.scan(
-        body, carry, (jnp.arange(run.count, dtype=jnp.int32), mask)
-    )
+    return h, new, place_stats(cfg, layer_mask.shape[0], parts)
 
 
 def forward_layers_paged(
@@ -442,7 +357,7 @@ def forward_layers_paged(
         paged_attention_write, paged_prefill, write_chunk_kv,
     )
 
-    _refuse_tp(tp_axis, cp_axis)
+    refuse_axes(cfg, tp_axis, cp_axis)
     if k_scale is not None:
         raise NotImplementedError(
             "a quantized (int8/fp8) arena under mimo_v2 is not implemented"
@@ -502,7 +417,7 @@ def forward_layers_paged(
             )
             return (jnp.where(valid, h_new, h), k_a, v_a), stats
 
-        (h, k_a, v_a), stats = _scan_run(
+        (h, k_a, v_a), stats = scan_run(
             run, layers[run.kind],
             layer_mask[run.slot_first:run.slot_first + run.count],
             (h, *arenas[a]), apply,
@@ -511,7 +426,7 @@ def forward_layers_paged(
         parts.append((run, stats))
     return (
         h, (arenas[0][0], arenas[1][0]), (arenas[0][1], arenas[1][1]),
-        None, None, _place_stats(cfg, n_slots, parts),
+        None, None, place_stats(cfg, n_slots, parts),
     )
 
 

@@ -7,7 +7,7 @@ RECURRENT state of fixed size a request beside the paged KV arena.
 (kind ``mamba``), ``E`` a LatentMoE (``moe``), ``*`` attention (``attn``).
 ``params["layers"] = {kind: {leaf: [L_kind, ...]}}``, one stack per kind in
 layer order; a stage runs its layers as RUNS of one kind in model order
-(``stage_runs``, as ``models/mimo_v2.py``), each run one ``lax.scan`` over a
+(``models/stack.stage_runs``), each run one ``lax.scan`` over a
 range of its kind's stack. Every stage of a ring must hold the same sequence
 of kinds.
 
@@ -57,8 +57,7 @@ a quantized arena, a stage whose kinds differ from the model's first stage's.
 
 from __future__ import annotations
 
-import itertools
-from typing import Any, NamedTuple, Optional
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -68,46 +67,13 @@ from ..ops.norms import rms_norm
 from ..ops.quant import qmatmul
 from .config import ModelConfig
 from .llama import embed, final_logits  # noqa: F401  (the family's own)
-from .mimo_v2 import _place_stats, _scan_run
-from .stack import kind_spans, zero_recurrent  # noqa: F401
+from .family import refuse_axes
+from .stack import (  # noqa: F401
+    place_stats, scan_run, stage_runs, zero_recurrent,
+)
 
 Params = dict[str, Any]
 f32 = jnp.float32
-
-
-class Run(NamedTuple):
-    """Consecutive layers of one kind, as a stage runs them. A layer's index
-    in ITS kind's state (the arena for ``attn``, the recurrent state for
-    ``mamba``) is its index in its kind's stack."""
-
-    kind: str
-    stack_first: int  # the run's first layer in its kind's stack
-    count: int
-    slot_first: int  # ... in the stage's layer slots (mask, stats)
-
-
-def stage_runs(cfg: ModelConfig, layers: Params) -> list:
-    """The stage's layers as runs of one kind in MODEL order (every stage
-    holds the model's first ``sum of its stacks`` kinds)."""
-    spans = {k: (first, n) for k, first, n in kind_spans(layers, cfg.layer_kinds)}
-    total = sum(n for _, n in spans.values())
-    seq = cfg.layer_kinds[:total]
-    for kind, (_, n) in spans.items():
-        if seq.count(kind) != n:
-            raise NotImplementedError(
-                f"{cfg.model_type}: a stage holds {n} layers of kind {kind!r} "
-                f"where the model's first {total} layers have "
-                f"{seq.count(kind)}: "
-                "every stage must hold the same sequence of layer kinds "
-                "(whole periods of the pattern, none padded)"
-            )
-    runs, in_stack = [], {}
-    for kind, group in itertools.groupby(seq):
-        n = len(list(group))
-        s0 = in_stack.get(kind, 0)
-        runs.append(Run(kind, s0, n, spans[kind][0] + s0))
-        in_stack[kind] = s0 + n
-    return runs
 
 
 def kind_layer_counts(cfg: ModelConfig, layers: Params, axis: int = 1) -> dict:
@@ -371,14 +337,6 @@ def attn_block(cfg: ModelConfig, p: Params, h, attend):
 # Stage functions
 # ---------------------------------------------------------------------------
 
-def _refuse_tp(tp_axis, cp_axis=None):
-    if tp_axis is not None or cp_axis is not None:
-        raise NotImplementedError(
-            "tensor / context parallelism over nemotron_h (a recurrent state "
-            "beside the arena, a share of the experts) is not implemented"
-        )
-
-
 def forward_layers(cfg, layers, h, cache, positions, layer_mask=None,
                    tp_axis=None, moe_live=None):
     """The dense-cache path is REFUSED: a ``KVCache`` row has no place for a
@@ -419,7 +377,7 @@ def forward_layers_paged(
         paged_attention_write, paged_prefill, write_chunk_kv,
     )
 
-    _refuse_tp(tp_axis, cp_axis)
+    refuse_axes(cfg, tp_axis, cp_axis)
     if k_scale is not None:
         raise NotImplementedError(
             "a quantized (int8/fp8) arena under nemotron_h is not implemented"
@@ -507,7 +465,7 @@ def forward_layers_paged(
                 jnp.where(valid, h_new, h), k_a, v_a, s_all, c_all
             ), stats
 
-        carry, stats = _scan_run(
+        carry, stats = scan_run(
             run, layers[run.kind],
             layer_mask[run.slot_first:run.slot_first + run.count],
             carry, apply,
@@ -517,7 +475,7 @@ def forward_layers_paged(
     s_all, c_all = jax.lax.optimization_barrier((s_all, c_all))
     rec = {"ssm": s_all, "conv": c_all, "row0": row0, "fresh": fresh}
     return (
-        h, (k_all, rec), v_all, None, None, _place_stats(cfg, n_slots, parts)
+        h, (k_all, rec), v_all, None, None, place_stats(cfg, n_slots, parts)
     )
 
 

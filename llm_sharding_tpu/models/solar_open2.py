@@ -9,7 +9,7 @@ then ``h ← h + MoE(RMSNorm_post(h))``. ``cfg.layer_pattern[l]`` names the mixe
 published ``gqa_layers``). A kind's layer is its mixer AND its MLP:
 ``params["layers"] = {kind: {leaf: [L_kind, ...]}}``, one stack per kind in
 layer order; a stage runs its layers as RUNS of one kind in model order
-(``models/nemotron_h.stage_runs``, ``_scan_run``). Every stage of a ring must
+(``models/stack.stage_runs``, ``scan_run``). Every stage of a ring must
 hold the same sequence of kinds.
 
 **``kda``** (``ops/kda.py``; Kimi Linear, arXiv:2510.26692). ``q̃``, ``k̃``, ``ṽ`` =
@@ -70,10 +70,9 @@ from ..ops.norms import rms_norm
 from ..ops.quant import qmatmul
 from .config import ModelConfig
 from .deepseek_v3 import mlp_sub_block
+from .family import refuse_axes
 from .llama import embed, final_logits  # noqa: F401  (the family's own)
-from .mimo_v2 import _place_stats, _scan_run
-from .nemotron_h import stage_runs
-from .stack import zero_recurrent
+from .stack import place_stats, scan_run, stage_runs, zero_recurrent
 
 Params = dict[str, Any]
 f32 = jnp.float32
@@ -325,15 +324,6 @@ def gqa_block(cfg: ModelConfig, p: Params, h, attend):
 # Stage functions
 # ---------------------------------------------------------------------------
 
-def _refuse_tp(tp_axis, cp_axis=None):
-    if tp_axis is not None or cp_axis is not None:
-        raise NotImplementedError(
-            "tensor / context parallelism over solar_open2 (a recurrent "
-            "matrix state beside the arena, a share of the experts) is not "
-            "implemented"
-        )
-
-
 def forward_layers(cfg, layers, h, cache, positions, layer_mask=None,
                    tp_axis=None, moe_live=None):
     """The dense-cache path is REFUSED: a ``KVCache`` row has no place for a
@@ -373,7 +363,7 @@ def forward_layers_paged(
         paged_attention_write, paged_prefill, write_chunk_kv,
     )
 
-    _refuse_tp(tp_axis, cp_axis)
+    refuse_axes(cfg, tp_axis, cp_axis)
     if k_scale is not None:
         raise NotImplementedError(
             "a quantized (int8/fp8) arena under solar_open2 is not implemented"
@@ -456,7 +446,7 @@ def forward_layers_paged(
                 jnp.where(valid, h_new, h), k_a, v_a, s_all, c_all
             ), stats
 
-        carry, stats = _scan_run(
+        carry, stats = scan_run(
             run, layers[run.kind],
             layer_mask[run.slot_first:run.slot_first + run.count],
             carry, apply,
@@ -466,7 +456,7 @@ def forward_layers_paged(
     s_all, c_all = jax.lax.optimization_barrier((s_all, c_all))
     rec = {"kda": s_all, "conv": c_all, "row0": row0, "fresh": fresh}
     return (
-        h, (k_all, rec), v_all, None, None, _place_stats(cfg, n_slots, parts)
+        h, (k_all, rec), v_all, None, None, place_stats(cfg, n_slots, parts)
     )
 
 

@@ -1,16 +1,19 @@
-"""Shared layer-stack scan machinery (llama + gpt2 + future families).
+"""Shared layer-stack scan machinery (every family's; ``models/family.py``).
 
 One implementation of: record this step's key positions, ``lax.scan`` over
 layer-stacked params + per-layer cache rows, commit hidden/cache updates only
 for valid (non-padding) layers. Architecture modules supply only the per-layer
 function. Centralizing this keeps the ragged-stage and cache-write semantics
 identical across model families (they power the pipeline's SPMD padding —
-SURVEY.md §7 "uneven layer splits").
+SURVEY.md §7 "uneven layer splits"). A model whose layers are of several kinds
+runs them as RUNS of one kind (``Run``, ``stage_runs``, ``scan_run``,
+``place_stats``); a looped stack's passes are ``run_passes``.
 """
 
 from __future__ import annotations
 
-from typing import Callable, Optional
+import itertools
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -73,6 +76,53 @@ def kind_spans(layers: dict, kinds: tuple):
     return spans
 
 
+class Run(NamedTuple):
+    """Consecutive layers of one kind, as a stage runs them. A layer's index
+    in ITS kind's state (an arena, a recurrent state) is its index in its
+    kind's stack, unless the kinds share arenas by their attention (MiMo:
+    ``attn``, ``arena_first``)."""
+
+    kind: str
+    stack_first: int  # the run's first layer in its kind's stack
+    count: int
+    slot_first: int  # ... in the stage's layer slots (mask, stats, dense cache)
+    attn: str = ""  # the kind's attention ("full" | "swa"), where kinds have one
+    arena_first: int = 0  # ... in its attention's arena
+
+
+def stage_runs(cfg, layers: dict, attn_of: Optional[Callable] = None) -> list:
+    """The stage's layers as runs of one kind in MODEL order. The stage
+    holds ``sum of its stacks`` layers and every stage the same sequence of
+    kinds, so the sequence is the model's first that many
+    (``parallel/placement`` refuses a ring that would not). ``attn_of(kind)``
+    names the attention of a kind where the arenas are per attention."""
+    spans = {k: (first, n) for k, first, n in kind_spans(layers, cfg.layer_kinds)}
+    total = sum(n for _, n in spans.values())
+    seq = cfg.layer_kinds[:total]
+    for kind, (_, n) in spans.items():
+        if seq.count(kind) != n:
+            raise NotImplementedError(
+                f"{cfg.model_type}: a stage holds {n} layers of kind {kind!r} "
+                f"where the model's first {total} layers have "
+                f"{seq.count(kind)}: "
+                "every stage must hold the same sequence of layer kinds "
+                "(whole periods of the pattern, none padded)"
+            )
+    runs, in_stack, in_arena = [], {}, {}
+    for kind, group in itertools.groupby(seq):
+        n = len(list(group))
+        s0 = in_stack.get(kind, 0)
+        if attn_of is None:
+            runs.append(Run(kind, s0, n, spans[kind][0] + s0))
+        else:
+            attn = attn_of(kind)
+            a0 = in_arena.get(attn, 0)
+            runs.append(Run(kind, s0, n, spans[kind][0] + s0, attn, a0))
+            in_arena[attn] = a0 + n
+        in_stack[kind] = s0 + n
+    return runs
+
+
 def zero_recurrent(cfg, layers: int, rows: int) -> dict:
     """An empty recurrent state of ``layers`` mixers and ``rows`` rows, by
     name: ``{name: [layers, rows, *cfg.recurrent_shapes[name]]}`` float32 —
@@ -87,6 +137,31 @@ def zero_recurrent(cfg, layers: int, rows: int) -> dict:
 def masked_stats(stats, valid):
     """A masked (padding) layer read and counted nothing."""
     return jax.tree.map(lambda a: jnp.where(valid, a, jnp.zeros_like(a)), stats)
+
+
+def zero_stats(cfg, count: int):
+    """An all-zero ``MoeStats`` of ``count`` layers."""
+    from ..ops.moe import MoeStats
+
+    return MoeStats(
+        jnp.zeros((count, cfg.num_experts), jnp.int32),
+        jnp.zeros((count,), jnp.int32),
+    )
+
+
+def place_stats(cfg, total: int, parts):
+    """The runs' stacked stats ``[(run, stats)]``, laid over the stage's
+    ``total`` layer slots (a dense run reads and counts nothing)."""
+    if not cfg.num_experts:
+        return None
+    out = zero_stats(cfg, total)
+    for run, st in parts:
+        if st is not None:
+            out = jax.tree.map(
+                lambda o, s: o.at[run.slot_first:run.slot_first + run.count].set(s),
+                out, st,
+            )
+    return out
 
 
 def _slot(i, first_layer):
@@ -287,3 +362,27 @@ def scan_layers_paged(
         (layers, jnp.arange(L, dtype=jnp.int32), layer_mask),
     )
     return h, k_arena, v_arena, k_scale, v_scale, stats
+
+
+def scan_run(run: Run, stack, mask, carry, apply_layer):
+    """One run: ``lax.scan`` over layers ``run.stack_first …`` of ``stack``
+    (its kind's whole stack), each layer's leaves taken out where they lie
+    (the scan's own per-iteration slice, at an offset) — never a slice of
+    the stack made beforehand, which would copy the run's weights a call.
+    ``apply_layer(p, i, valid, carry) -> (carry, stats)``, ``i`` the layer's
+    index in the run."""
+    scanned, whole = split_whole(stack)
+
+    def body(carry, xs):
+        i, valid = xs
+        at = i + run.stack_first
+        p = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, at, keepdims=False),
+            scanned,
+        )
+        carry, stats = apply_layer(join_whole(p, whole, at), i, valid, carry)
+        return carry, masked_stats(stats, valid)
+
+    return jax.lax.scan(
+        body, carry, (jnp.arange(run.count, dtype=jnp.int32), mask)
+    )

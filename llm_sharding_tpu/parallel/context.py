@@ -26,6 +26,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.cache import POS_SENTINEL
 from ..models.config import ModelConfig
+from ..models.family import family
 from ..ops.norms import layer_norm, rms_norm
 from ..ops.quant import embed_rows, head_logits, tied_logits
 from ..ops.ring_attention import ring_attention
@@ -46,14 +47,11 @@ def _ctx_layer(cfg: ModelConfig, p: Any, h, cos, sin, q_pos, kv_pos):
         got["k"], got["v"] = k, v
         return ring_attention(q, k, v, q_pos, kv_pos, SEQ_AXIS)
 
-    if cfg.model_type == "llama":
-        from ..models.llama import attn_mlp_block
-
-        h, _ = attn_mlp_block(cfg, p, h, cos, sin, attn_fn)
-    else:  # gpt2: nothing positional inside the layers (wpe added at embed)
-        from ..models.gpt2 import attn_mlp_block
-
-        h = attn_mlp_block(cfg, p, h, attn_fn)
+    fam = family(cfg)
+    if fam.learned_positions:  # gpt2: nothing positional inside the layers
+        h = fam.attn_mlp_block(cfg, p, h, attn_fn)
+    else:
+        h, _ = fam.attn_mlp_block(cfg, p, h, cos, sin, attn_fn)
     return h, got["k"], got["v"]
 
 
@@ -76,21 +74,22 @@ def _context_prefill_jit(
     per-layer K/V chunks additionally when ``want_cache`` (the decode
     handoff). Returns ``logits`` or ``(logits, ks, vs)`` — the structure is
     switched by the static flag."""
-    if cfg.model_type not in ("llama", "gpt2"):
+    fam = family(cfg)
+    if fam.attn_mlp_block is None:
         raise NotImplementedError(
             f"context parallelism: {cfg.model_type!r} unsupported"
         )
 
     def body(params, ids_chunk, pos_chunk, last_position):
-        if cfg.model_type == "llama":
-            h = embed_rows(params["embed"], ids_chunk)
-            cos, sin = rope_cos_sin(pos_chunk, cfg, dtype=jnp.float32)
-        else:  # gpt2: learned positions added at embed; sentinel pads clamp
+        if fam.learned_positions:  # added at embed; sentinel pads clamp
             h = (
                 embed_rows(params["embed"], ids_chunk)
                 + params["pos_embed"][pos_chunk]
             )
             cos = sin = None
+        else:
+            h = embed_rows(params["embed"], ids_chunk)
+            cos, sin = rope_cos_sin(pos_chunk, cfg, dtype=jnp.float32)
         if cfg.embed_multiplier != 1.0:  # gemma: hidden scaled by sqrt(H)
             h = h * jnp.asarray(cfg.embed_multiplier, h.dtype)
 
@@ -103,14 +102,14 @@ def _context_prefill_jit(
             return h, ys
 
         h, ys = jax.lax.scan(scan_body, h, params["layers"])
-        if cfg.model_type == "llama":
-            h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps,
-                         cfg.norm_offset)
-        else:
+        if fam.final_layer_norm:
             h = layer_norm(
                 h, params["final_norm"], params["final_norm_bias"],
                 cfg.layer_norm_epsilon,
             )
+        else:
+            h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps,
+                         cfg.norm_offset)
 
         def project(x):
             if "lm_head" in params:

@@ -42,6 +42,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..models.config import ModelConfig
+from ..models.family import family
 from ..ops.norms import layer_norm, rms_norm
 from ..ops.quant import QTensor, base, embed_rows, head_logits, tied_logits
 from .mesh import PIPE_AXIS
@@ -159,7 +160,7 @@ def sp_embed(
     rows = embed_rows(table, jnp.clip(local, 0, Vs - 1))
     h = jnp.where(ok[..., None], rows, 0)
     h = jax.lax.psum(h, PIPE_AXIS)
-    if cfg.model_type == "gpt2":
+    if family(cfg).learned_positions:
         # plain indexing clamps out-of-bounds (sentinel positions of padded
         # prompt slots) exactly like the monolithic gpt2.embed
         h = h + head["pos_embed"][positions]
@@ -175,7 +176,7 @@ def _local_logits(
     """Final norm + this stage's [B, V/S] fp32 logit slice (pad columns
     already masked to -inf). Returns (logits, lo) with ``lo`` the slice's
     global vocab offset."""
-    if cfg.model_type == "gpt2":
+    if family(cfg).final_layer_norm:
         x = layer_norm(
             h_last, head["final_norm"], head["final_norm_bias"],
             cfg.layer_norm_epsilon,

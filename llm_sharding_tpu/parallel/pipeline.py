@@ -47,9 +47,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..models import gpt2, llama
 from ..models.cache import KVCache, POS_SENTINEL
 from ..models.config import ModelConfig
+from ..models.family import family, refuse_axes
 from ..models.stack import close_tables
 from ..ops.quant import base
 from ..ops.sampling import is_stop as _is_stop, validate_top_p
@@ -67,7 +67,8 @@ from jax import shard_map
 
 
 class ModelFns(NamedTuple):
-    """Architecture dispatch for the pipeline (llama / gpt2)."""
+    """A family's stage functions (``models/family.py``) as the ring calls
+    them, the parallel axes bound."""
 
     stage: Any  # (cfg, layers, h, cache, positions, mask) -> (h, cache)
     # paged serve-decode stage over the pooled arena (no materialized
@@ -93,66 +94,13 @@ def model_fns(
     arena/table slice and attention partials reduce across ``cp_axis``
     (``models/llama.paged_decoder_layer``). Gated to llama upstream
     (``engine.serve`` validation) — gpt2's paged path never sees it."""
-    walks = None
-    if cfg.model_type == "llama":
-        fwd, fwd_paged = llama.forward_layers, llama.forward_layers_paged
-    elif cfg.model_type == "gpt2":
-        if cp_axis is not None:
-            raise NotImplementedError(
-                "context-parallel serving supports the llama family only"
-            )
-        fwd, fwd_paged = gpt2.forward_layers, gpt2.forward_layers_paged
-    elif cfg.model_type == "deepseek_v3":
-        from ..models import deepseek_v3 as deepseek
-
-        if tp_axis is not None or cp_axis is not None:
-            raise NotImplementedError(
-                "tensor / context parallelism over deepseek_v3 (latent "
-                "attention, a share of the experts) is not implemented"
-            )
-        fwd, fwd_paged = deepseek.forward_layers, deepseek.forward_layers_paged
-    elif cfg.model_type == "mimo_v2":
-        from ..models import mimo_v2
-
-        if tp_axis is not None or cp_axis is not None:
-            raise NotImplementedError(
-                "tensor / context parallelism over mimo_v2 (a KV state per "
-                "kind of layer, a share of the experts) is not implemented"
-            )
-        fwd, fwd_paged = mimo_v2.forward_layers, mimo_v2.forward_layers_paged
-        walks = mimo_v2.prefill_walks
-    elif cfg.model_type == "nemotron_h":
-        from ..models import nemotron_h
-
-        nemotron_h._refuse_tp(tp_axis, cp_axis)
-        fwd, fwd_paged = (
-            nemotron_h.forward_layers, nemotron_h.forward_layers_paged
+    fam = family(cfg)
+    refuse_axes(cfg, tp_axis, cp_axis)
+    if cp_axis is not None and not fam.paged_cp:
+        raise NotImplementedError(
+            "context-parallel serving supports the llama family only"
         )
-        walks = nemotron_h.prefill_walks
-    elif cfg.model_type == "jamba":
-        from ..models import jamba
-
-        jamba._refuse_tp(tp_axis, cp_axis)
-        fwd, fwd_paged = jamba.forward_layers, jamba.forward_layers_paged
-        walks = jamba.prefill_walks
-    elif cfg.model_type == "solar_open2":
-        from ..models import solar_open2
-
-        solar_open2._refuse_tp(tp_axis, cp_axis)
-        fwd, fwd_paged = (
-            solar_open2.forward_layers, solar_open2.forward_layers_paged
-        )
-        walks = solar_open2.prefill_walks
-    elif cfg.model_type == "longcat_flash":
-        from ..models import longcat_flash
-
-        longcat_flash._refuse_tp(tp_axis, cp_axis)
-        fwd, fwd_paged = (
-            longcat_flash.forward_layers, longcat_flash.forward_layers_paged
-        )
-        walks = longcat_flash.prefill_walks
-    else:
-        raise ValueError(f"unsupported model_type: {cfg.model_type!r}")
+    fwd, fwd_paged = fam.forward_layers, fam.forward_layers_paged
 
     # ``moe_live`` ([B, S] bool; a model with experts only): the positions
     # that route. Both stage fns return the layers' stats as their LAST
@@ -185,7 +133,9 @@ def model_fns(
             prefill=prefill, walk=walk, **kw,
         )
 
-    return ModelFns(stage=stage, stage_paged=stage_paged, prefill_walks=walks)
+    return ModelFns(
+        stage=stage, stage_paged=stage_paged, prefill_walks=fam.prefill_walks
+    )
 
 
 def mesh_axis_sizes(mesh: Mesh) -> tuple[int, int, int]:
@@ -213,16 +163,10 @@ def stage_layer_specs(cfg: ModelConfig, tp: int, stage_layers: Any = None):
     weight, scale on the output axis (``tensor.quant_leaf_spec``)."""
     if tp == 1:
         return P(PIPE_AXIS)  # pytree-prefix spec: applies to every leaf
-    if cfg.model_type == "llama":
-        from .tensor import llama_tp_specs
-
-        per_leaf = llama_tp_specs(stacked=False)["layers"]
-    elif cfg.model_type == "gpt2":
-        from .tensor import gpt2_tp_specs
-
-        per_leaf = gpt2_tp_specs(stacked=False)["layers"]
-    else:
+    tp_specs = family(cfg).tp_specs
+    if tp_specs is None:
         raise NotImplementedError(f"pp×tp: {cfg.model_type!r} unsupported")
+    per_leaf = tp_specs(stacked=False)["layers"]
     from .tensor import quant_leaf_spec
 
     # restrict to the keys actually present (optional bias keys exist only
@@ -639,14 +583,13 @@ def pipeline_generate(
         from .tensor import validate_tp
 
         validate_tp(cfg, tp)
-        if cfg.model_type == "gpt2":
-            # fused-qkv column permutation happens HERE, not as a caller
-            # precondition — callers pass raw layers and can neither forget
-            # nor double-apply it; memoized so repeated requests over the
-            # same stage arrays don't re-gather the weights
-            from .tensor import permute_gpt2_tp_layers_cached
-
-            stage_layers = permute_gpt2_tp_layers_cached(stage_layers, tp)
+        tp_permute = family(cfg).tp_permute
+        if tp_permute is not None:
+            # gpt2's fused-qkv column permutation happens HERE, not as a
+            # caller precondition — callers pass raw layers and can neither
+            # forget nor double-apply it; memoized so repeated requests over
+            # the same stage arrays don't re-gather the weights
+            stage_layers = tp_permute(stage_layers, tp)
     if B % dp != 0:
         raise ValueError(f"batch {B} not divisible by data-parallel size {dp}")
 

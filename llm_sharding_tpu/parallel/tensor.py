@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.config import ModelConfig
+from ..models.family import family
 from ..ops.quant import QTensor
 
 TENSOR_AXIS = "tensor"
@@ -220,12 +221,10 @@ def shard_params_tp(cfg: ModelConfig, params: Any, mesh: Mesh) -> Any:
     leaves get per-component specs via ``quant_leaf_spec`` — int8 and TP
     compose (≙ the reference quantizing and sharding together,
     ``/root/reference/utils/model_sharder.py:28-45``)."""
-    if cfg.model_type == "llama":
-        specs = llama_tp_specs()
-    elif cfg.model_type == "gpt2":
-        specs = gpt2_tp_specs()
-    else:
+    tp_specs = family(cfg).tp_specs
+    if tp_specs is None:
         raise NotImplementedError(f"TP specs: {cfg.model_type!r} unsupported")
+    specs = tp_specs()
     tp = mesh.shape[TENSOR_AXIS]
     validate_tp(cfg, tp)
 

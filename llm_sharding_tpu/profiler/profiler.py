@@ -39,9 +39,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..models import llama
 from ..models.cache import init_cache
 from ..models.config import ModelConfig
+from ..models.family import family
 from ..runtime.generate import forward_fn_for
 from jax import shard_map
 
@@ -358,18 +358,15 @@ class Profiler:
 # ---------------------------------------------------------------------------
 
 def layer_param_bytes(cfg: ModelConfig, dtype=jnp.bfloat16) -> int:
-    """Exact per-decoder-layer parameter bytes from the config."""
-    H, I, D = cfg.hidden_size, cfg.intermediate_size, cfg.head_dim_
-    Nh, Nkv = cfg.num_attention_heads, cfg.num_key_value_heads
-    if cfg.model_type == "llama":
-        n = (
-            2 * H  # norms
-            + H * Nh * D + 2 * H * Nkv * D + Nh * D * H  # attention
-            + 3 * H * I  # mlp
-        )
-    else:  # gpt2
-        n = 4 * H + H * 3 * H + 3 * H + H * H + H + 2 * H * I + I + H
-    return n * jnp.dtype(dtype).itemsize
+    """Per-decoder-layer parameter bytes, every element stored as ``dtype``:
+    the elements of the leaves the family's ``init_params`` would make for the
+    layers (shapes only, nothing allocated), over the layers — the mean,
+    rounded up, where they are of several kinds (``cfg.layer_kinds``)."""
+    shapes = jax.eval_shape(
+        lambda: family(cfg).init_params(cfg, jax.random.key(0))["layers"]
+    )
+    elements = sum(a.size for a in jax.tree.leaves(shapes))
+    return -(-elements // cfg.num_hidden_layers) * jnp.dtype(dtype).itemsize
 
 
 def kv_cache_bytes_per_layer(

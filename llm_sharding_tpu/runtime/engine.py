@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models.config import ModelConfig
+from ..models.family import family
 from ..obs.metrics import REGISTRY, record_shape_key
 from ..obs.setupline import SETUP, install as install_setup_listeners
 from ..analysis.lockorder import named_lock
@@ -367,7 +368,7 @@ class PipelineEngine:
         # its fused qkv device-side before the tensor split applies.
         # int8 QTensor leaves take per-component specs (q like the raw
         # weight, scale on the output axis) — int8 × TP compose (r3 next-#4).
-        relaid = self.tensor_parallel > 1 and self.cfg.model_type == "llama"
+        relaid = self.tensor_parallel > 1 and family(self.cfg).presplit
         # one span from the first put to the last array's arrival: the
         # head's host staging runs while the layers' copies are in flight,
         # as a child (a reader takes it off the put's seconds)
@@ -555,7 +556,7 @@ class PipelineEngine:
                 "tensor_parallel, so dp×pp×tp serving is replicas of a "
                 "pp×tp server)"
             )
-        if self.tensor_parallel > 1 and self.cfg.model_type != "llama":
+        if self.tensor_parallel > 1 and not family(self.cfg).presplit:
             raise NotImplementedError(
                 "serve×tp supports the llama family (llama/qwen2): the "
                 "engine stores llama weights megatron-pre-split, while "
@@ -753,7 +754,7 @@ class PipelineEngine:
             h = h.astype(np.asarray(table.scale).dtype)
         else:
             h = np.asarray(table)[ids]
-        if self.cfg.model_type == "gpt2":
+        if family(self.cfg).learned_positions:
             pos = np.arange(ids.shape[1])
             h = h + np.asarray(self._head_host["pos_embed"])[pos][None]
         if self.cfg.embed_multiplier != 1.0:  # gemma: hidden × sqrt(H)

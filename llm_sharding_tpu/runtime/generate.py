@@ -23,9 +23,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..models import deepseek_v3, gpt2, llama, longcat_flash, mimo_v2
 from ..models.cache import KVCache, POS_SENTINEL, init_cache
 from ..models.config import ModelConfig
+from ..models.family import family
 from ..ops.sampling import (
     is_stop as _is_stop_op,
     sample as _sample_op,
@@ -36,13 +36,16 @@ ForwardFn = Callable[..., tuple[jnp.ndarray, KVCache]]
 
 
 def forward_fn_for(cfg: ModelConfig) -> ForwardFn:
-    """Architecture dispatch (≙ the llama/gpt branch in
-    ``/root/reference/utils/model_sharder.py:64,96``)."""
-    return {
-        "llama": llama.forward, "gpt2": gpt2.forward,
-        "deepseek_v3": deepseek_v3.forward, "mimo_v2": mimo_v2.forward,
-        "longcat_flash": longcat_flash.forward,
-    }[cfg.model_type]
+    """The family's whole-model forward over a dense ``KVCache`` (≙ the
+    llama/gpt branch in ``/root/reference/utils/model_sharder.py:64,96``)."""
+    fwd = family(cfg).forward
+    if fwd is None:
+        raise NotImplementedError(
+            f"{cfg.model_type} has no whole-model forward over a dense KV "
+            "cache (a recurrent state lives beside the PAGED arena only): "
+            "serve it with kv_block_size, kv_blocks and prefill_chunk set"
+        )
+    return fwd
 
 
 _is_stop = _is_stop_op

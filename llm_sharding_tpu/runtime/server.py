@@ -99,6 +99,7 @@ from ..obs.trace import TraceContext, TraceWriter, emit_span
 from ..obs.setupline import SETUP
 from ..obs.stepline import STEP_ANNOTATION, StepProfiler
 from ..analysis.lockorder import named_lock
+from ..models.family import family
 from ..parallel import serve as serve_ops
 from ..parallel.mesh import PIPE_AXIS
 from .blocks import PAGED_KV_LAYOUT
@@ -1361,7 +1362,7 @@ class PipelineServer:
                     "heads sharding both claim the KV leaves' trailing "
                     "dims — pick one"
                 )
-            if self.cfg.model_type != "llama":
+            if not family(self.cfg).paged_cp:
                 raise NotImplementedError(
                     "context-parallel serving supports the llama family "
                     "only (the cross-shard softmax combine is threaded "

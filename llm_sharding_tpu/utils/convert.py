@@ -23,6 +23,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from ..models.config import ModelConfig
+from ..models.family import family
 
 TensorGetter = Callable[[str], np.ndarray]
 
@@ -53,23 +54,9 @@ def _refuse_unmapped(cfg: ModelConfig) -> None:
             "output norms and the gate; the block runs on seeded weights "
             "(benchmark/blocks/ouro.py)"
         )
-    if cfg.model_type == "solar_open2":
-        raise NotImplementedError(
-            "model_type 'solar_open2': the names of a Solar-Open2 "
-            "checkpoint's tensors (a KDA mixer's projections, low-rank pairs, "
-            "conv and norm leaves; the attention layers' gate) are in no file "
-            "of this repository — the converter maps it once they are; the "
-            "block runs on seeded weights (benchmark/blocks/solar_open2.py)"
-        )
-    if cfg.model_type == "longcat_flash":
-        raise NotImplementedError(
-            "model_type 'longcat_flash': the names of a LongCat-Flash "
-            "checkpoint's tensors (a layer's two attentions, two dense MLPs "
-            "and four norms, its router's classifier and correction bias) "
-            "are in no file of this repository — the converter maps it once "
-            "they are; the block runs on seeded weights "
-            "(benchmark/blocks/longcat_flash.py)"
-        )
+    unmapped = family(cfg).unmapped
+    if unmapped:
+        raise NotImplementedError(unmapped)
 
 
 def llama_layer_arrays(
@@ -446,22 +433,16 @@ def _stack(layer_dicts: list[dict[str, jnp.ndarray]]) -> dict[str, jnp.ndarray]:
     return {k: jnp.stack([d[k] for d in layer_dicts]) for k in layer_dicts[0]}
 
 
-#: the models whose layers are of several kinds (one stack per kind)
-KIND_LAYER_ARRAYS = {
-    "deepseek_v3": deepseek_layer_arrays, "mimo_v2": mimo_layer_arrays,
-    "nemotron_h": nemotron_layer_arrays, "jamba": jamba_layer_arrays,
-}
-
-
-def head_names(cfg: ModelConfig) -> tuple:
-    """``(embedding, final norm)`` tensor names of a llama-style checkpoint
-    (``nemotron_h`` keeps its stack under ``backbone``; ``jamba`` names its
-    last norm ``final_layernorm``)."""
-    if cfg.model_type == "nemotron_h":
-        return "backbone.embeddings.weight", "backbone.norm_f.weight"
-    if cfg.model_type == "jamba":
-        return "model.embed_tokens.weight", "model.final_layernorm.weight"
-    return "model.embed_tokens.weight", "model.norm.weight"
+def gpt2_head_arrays(get: TensorGetter, dtype) -> dict[str, jnp.ndarray]:
+    """What a GPT-2 checkpoint holds outside its blocks, under its optional
+    ``transformer.`` prefix (the lm_head is tied to ``wte``: no buffer)."""
+    pre = "transformer." if _has(get, "transformer.wte.weight") else ""
+    return {
+        "embed": jnp.asarray(get(pre + "wte.weight"), dtype),
+        "pos_embed": jnp.asarray(get(pre + "wpe.weight"), dtype),
+        "final_norm": jnp.asarray(get(pre + "ln_f.weight"), dtype),
+        "final_norm_bias": jnp.asarray(get(pre + "ln_f.bias"), dtype),
+    }
 
 
 def params_from_hf(
@@ -472,55 +453,31 @@ def params_from_hf(
     """Full-model params pytree from an HF name→tensor source."""
     _refuse_unmapped(cfg)
     get = _getter(src)
-    if cfg.model_type == "llama":
-        embed = jnp.asarray(get("model.embed_tokens.weight"), dtype)
-        layers = _stack(
-            [llama_layer_arrays(cfg, get, i, dtype) for i in range(cfg.num_hidden_layers)]
-        )
-        params = {
-            "embed": embed,
-            "layers": layers,
-            "final_norm": jnp.asarray(get("model.norm.weight"), dtype),
-        }
-        if not cfg.tie_word_embeddings:
-            params["lm_head"] = jnp.asarray(get("lm_head.weight").T, dtype)
+    fam = family(cfg)
+    kinds = cfg.layer_kinds
+
+    def stack(kind=None):
+        return _stack([
+            fam.layer_arrays(cfg, get, i, dtype)
+            for i in range(cfg.num_hidden_layers)
+            if kind is None or kinds[i] == kind
+        ])
+
+    # layers of several kinds: one stack per kind, in layer order
+    layers = {k: stack(k) for k in dict.fromkeys(kinds)} if kinds else stack()
+    if fam.learned_positions:
+        return {**gpt2_head_arrays(get, dtype), "layers": layers}
+    # a model of several kinds may hold a SLICE of the vocabulary: its tables
+    # keep the rows held here (rows 0..vocab_size-1)
+    V = cfg.vocab_size if kinds else None
+    embed_name, norm_name = fam.head_names
+    params = {
+        "embed": jnp.asarray(get(embed_name)[:V], dtype),
+        "layers": layers,
+        "final_norm": jnp.asarray(get(norm_name), dtype),
+    }
+    if not cfg.tie_word_embeddings:
         # tied: no duplicate vocab×hidden buffer — final_logits contracts
         # against the embedding table (see models/llama.py:final_logits)
-        return params
-    elif cfg.model_type in KIND_LAYER_ARRAYS:
-        # one stack per kind, in layer order (cfg.layer_kinds); the
-        # vocabulary tables keep the rows held here (rows 0..vocab_size-1)
-        kinds = cfg.layer_kinds
-        V = cfg.vocab_size
-        layer_arrays = KIND_LAYER_ARRAYS[cfg.model_type]
-        embed_name, norm_name = head_names(cfg)
-        params = {
-            "embed": jnp.asarray(get(embed_name)[:V], dtype),
-            "layers": {
-                kind: _stack([
-                    layer_arrays(cfg, get, i, dtype)
-                    for i in range(cfg.num_hidden_layers) if kinds[i] == kind
-                ])
-                for kind in dict.fromkeys(kinds)
-            },
-            "final_norm": jnp.asarray(get(norm_name), dtype),
-        }
-        if not cfg.tie_word_embeddings:
-            params["lm_head"] = jnp.asarray(
-                get("lm_head.weight")[:V].T, dtype
-            )
-        return params
-    elif cfg.model_type == "gpt2":
-        pre = "transformer." if _has(get, "transformer.wte.weight") else ""
-        wte = jnp.asarray(get(pre + "wte.weight"), dtype)
-        layers = _stack(
-            [gpt2_layer_arrays(cfg, get, i, dtype) for i in range(cfg.num_hidden_layers)]
-        )
-        return {
-            "embed": wte,  # lm_head is tied to wte — no separate buffer
-            "pos_embed": jnp.asarray(get(pre + "wpe.weight"), dtype),
-            "layers": layers,
-            "final_norm": jnp.asarray(get(pre + "ln_f.weight"), dtype),
-            "final_norm_bias": jnp.asarray(get(pre + "ln_f.bias"), dtype),
-        }
-    raise ValueError(f"unsupported model_type: {cfg.model_type!r}")
+        params["lm_head"] = jnp.asarray(get("lm_head.weight")[:V].T, dtype)
+    return params

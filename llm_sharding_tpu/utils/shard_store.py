@@ -43,14 +43,12 @@ import jax
 import jax.numpy as jnp
 
 from ..models.config import ModelConfig
+from ..models.family import family
 from .convert import (
-    KIND_LAYER_ARRAYS,
     TensorGetter,
     _getter,
     _refuse_unmapped,
-    head_names,
-    gpt2_layer_arrays,
-    llama_layer_arrays,
+    gpt2_head_arrays,
 )
 
 # Tokenizer/config files copied verbatim, skipping weights — the same skip
@@ -310,21 +308,18 @@ def save_shards_streaming(
     if tokenizer_dir:
         copy_tokenizer_files(tokenizer_dir, out_dir)
 
-    layer_fn = {
-        "llama": llama_layer_arrays, "gpt2": gpt2_layer_arrays,
-        **KIND_LAYER_ARRAYS,
-    }[cfg.model_type]
+    fam = family(cfg)
     for i in range(cfg.num_hidden_layers):
-        block = layer_fn(cfg, get, i, dtype)
+        block = fam.layer_arrays(cfg, get, i, dtype)
         if quantize:
             block = quantize_layer_params(block, bits=quant_bits)
         _save_npz(os.path.join(out_dir, f"block_{i}.npz"), block)
 
-    if cfg.model_type in ("llama", *KIND_LAYER_ARRAYS):
+    if not fam.learned_positions:
         # a model with a share of the experts may hold a SLICE of the
         # vocabulary: rows 0..V-1
         V = cfg.vocab_size
-        embed_name, norm_name = head_names(cfg)
+        embed_name, norm_name = fam.head_names
         embed = jnp.asarray(get(embed_name)[:V], dtype)
         _save_npz(
             os.path.join(out_dir, "embedding.npz"),
@@ -339,26 +334,19 @@ def save_shards_streaming(
             if quantize_head:
                 head = quantize_tensor(head, contract_axis=-2, bits=quant_bits)
             _save_npz(os.path.join(out_dir, "lm_head.npz"), {"lm_head": head})
-    else:  # gpt2
-        from .convert import _has
-
-        pre = "transformer." if _has(get, "transformer.wte.weight") else ""
-        wte = jnp.asarray(get(pre + "wte.weight"), dtype)
+    else:  # gpt2's checkpoint; lm_head tied to wte — nothing extra to save
+        head = gpt2_head_arrays(get, dtype)
         _save_npz(
             os.path.join(out_dir, "embedding.npz"),
             {
-                "embed": maybe_q_embed(wte),
-                "pos_embed": jnp.asarray(get(pre + "wpe.weight"), dtype),
+                "embed": maybe_q_embed(head["embed"]),
+                "pos_embed": head["pos_embed"],
             },
         )
         _save_npz(
             os.path.join(out_dir, "final_norm.npz"),
-            {
-                "final_norm": jnp.asarray(get(pre + "ln_f.weight"), dtype),
-                "final_norm_bias": jnp.asarray(get(pre + "ln_f.bias"), dtype),
-            },
+            {k: head[k] for k in ("final_norm", "final_norm_bias")},
         )
-        # lm_head tied to wte — nothing extra to save
 
 
 def copy_tokenizer_files(src_dir: str, out_dir: str) -> None:

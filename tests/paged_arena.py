@@ -8,7 +8,14 @@ import contextlib
 from unittest import mock
 
 import numpy as np
+import jax
 import jax.numpy as jnp
+
+from llm_sharding_tpu.models import llama
+from llm_sharding_tpu.models.config import tiny_llama
+
+#: the model of the serve-level paged tests and of their step programs
+CFG = tiny_llama(num_hidden_layers=8)
 
 #: layers of a test stack, and the layers the parametrised cases attend:
 #: the first, a middle one, the last
@@ -111,3 +118,57 @@ def tiles_then_rows(run):
     assert n_rows == {"tile": 0, "rows": n_tiles["tile"]}, n_rows
     assert tiles == rows
     return tiles
+
+
+def tiny_engine():
+    """``(params, engine)``: ``CFG`` over a ring of four, float32."""
+    from llm_sharding_tpu.runtime.engine import PipelineEngine
+
+    params = llama.init_params(CFG, jax.random.key(11), dtype=jnp.float32)
+    eng = PipelineEngine(CFG, params, num_stages=4, cache_dtype=jnp.float32)
+    return params, eng
+
+
+def prompt(seed, n=5):
+    return np.random.default_rng(seed).integers(
+        1, CFG.vocab_size, n
+    ).astype(np.int32)
+
+
+def oracle_tokens(params, p, n, **kw):
+    from llm_sharding_tpu.runtime.generate import generate
+
+    res = generate(CFG, params, p, n, cache_dtype=jnp.float32, **kw)
+    return list(res.tokens[0, len(p): int(res.lengths[0])])
+
+
+def _inner_jaxprs(eqn):
+    """The jaxprs an equation holds in its parameters (scan, while, cond,
+    pjit, shard_map alike)."""
+    from jax.extend import core as jex
+
+    for v in eqn.params.values():
+        for x in v if isinstance(v, (tuple, list)) else (v,):
+            if isinstance(x, jex.ClosedJaxpr):
+                yield x.jaxpr
+            elif isinstance(x, jex.Jaxpr):
+                yield x
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation under ``jaxpr``, inner jaxprs walked."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        else:
+            for sub in _inner_jaxprs(eqn):
+                yield from _pallas_calls(sub)
+
+
+def _block_shapes(eqn):
+    """A ``pallas_call``'s operand and result blocks as the kernel sees
+    them, from its grid mapping: one tuple per block, a squeezed dim None."""
+    return [
+        tuple(getattr(d, "block_size", None) for d in bm.block_shape)
+        for bm in eqn.params["grid_mapping"].block_mappings
+    ]

@@ -526,10 +526,12 @@ def test_what_the_configuration_cannot_honour_is_refused(kw, word):
 
 
 def test_the_dense_cache_path_and_tp_are_refused(params):
+    from llm_sharding_tpu.models.family import refuse_axes
+
     with pytest.raises(NotImplementedError, match="dense KV cache"):
         jamba.forward_layers(CFG, params["layers"], None, None, None)
     with pytest.raises(NotImplementedError, match="tensor / context"):
-        jamba._refuse_tp("tensor")
+        refuse_axes(CFG, "tensor")
     from llm_sharding_tpu.parallel.pipeline import model_fns
 
     with pytest.raises(NotImplementedError, match="over jamba"):

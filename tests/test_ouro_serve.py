@@ -268,7 +268,7 @@ def _lowered(eng, names):
 def test_the_step_programs_name_the_close_of_a_pass(params):
     """(k) The decode and the chunk program carry ``pass_close`` beside the
     dense llama block's words, and no word of another family's
-    (``tests/test_paged.py`` holds the one-pass models' programs to being
+    (``tests/test_paged_programs.py`` holds the one-pass models' programs to being
     WITHOUT it); the layer body is traced ONCE: one decode kernel in the
     program's text, not one a pass."""
     from llm_sharding_tpu.obs.stepline import SCOPES

@@ -102,6 +102,31 @@ def test_layer_bytes_exact(params):
     assert layer_param_bytes(CFG, jnp.float32) == actual
 
 
+@pytest.mark.parametrize("preset", [
+    "tiny_gpt2", "tiny_qwen2", "tiny_olmoe", "tiny_keye_vl2", "tiny_ouro",
+    "tiny_deepseek_v3", "tiny_mimo_v2", "tiny_nemotron_h", "tiny_jamba",
+    "tiny_solar_open2", "tiny_longcat_flash",
+])
+def test_layer_bytes_are_the_familys_own_leaves(preset):
+    """Every family's layer is counted from the leaves its ``init_params``
+    makes (gpt2's formula used to answer for every family but llama, and
+    llama's dense count for its experts, indexer and biases); layers of
+    several kinds count as their mean, rounded up."""
+    from llm_sharding_tpu.models import config
+    from llm_sharding_tpu.models.family import family
+
+    cfg = getattr(config, preset)()
+    layers = family(cfg).init_params(cfg, jax.random.key(0), jnp.float32)[
+        "layers"]
+    elements = sum(a.size for a in jax.tree.leaves(layers))
+    L = cfg.num_hidden_layers
+    assert layer_param_bytes(cfg, jnp.float32) == -(-elements // L) * 4
+    if not cfg.layer_kinds:  # layers all alike: one layer's leaves, exactly
+        assert elements % L == 0
+    assert layer_param_bytes(cfg, jnp.int8) * 4 == layer_param_bytes(
+        cfg, jnp.float32)
+
+
 def test_max_layers_fit_accounting():
     # budget for exactly 3 layers + head/embed + 10% reserve
     head = CFG.vocab_size * CFG.hidden_size * 2 * 2 + CFG.hidden_size * 2
