@@ -30,14 +30,18 @@ Everything it writes (the store, child logs, ``result.json``) lands in
 ``.smoke/`` inside the checkout, which ``.gitignore`` lists. The four-chip
 forms are the same script: ``--stages 4``, ``--weights bf16``,
 ``--data-parallel 2 --stages 2``. ``--layers N`` cuts depth, never width.
-``--moe`` runs one check only and no daemon: the expert kernel of
-``ops/moe.py`` (``moe_experts``) against its XLA path at OLMoE-1B-7B's
+``--moe`` runs one check only and no daemon: the expert kernels of
+``ops/moe.py`` (``moe_experts``) against their XLA path at OLMoE-1B-7B's
 published widths, in the decode regime (4 rows) and the grouped prefill
-regime (1,024 positions), a third of the rows dead; then it TIMES a decode
-call (one live row of four) that meets 0, 1 and 8 experts at OLMoE's and at
-GigaChat's shapes, microseconds a call: the call's fixed part and what one
-more expert costs. The last line is then ``{"ok": true, "moe": [...],
-"moe_us_per_call": [...], "device": {...}}``.
+regime (1,024 positions), a third of the rows dead, on a stack of nine
+layers read at the last; then it TIMES a decode call (one live row of four)
+that meets 0, 1 and 8 experts at OLMoE's, GigaChat's and Keye's shapes,
+microseconds a call: the call's fixed part and what one more expert costs —
+and, at 8 met, the call's PARTS each alone (``parts_us``: the loop around
+nothing, what runs before the kernel, the kernel, and the two XLA parts the
+kernel took in with PR 63). The last line is then ``{"ok": true, "moe":
+[...], "moe_us_per_call": [{"shape", "us_per_call", "parts_us"}, ...],
+"device": {...}}``.
 ``--kv-write`` likewise runs one check only: a prefill chunk's K/V write
 (``ops/paged_attention.write_chunk_kv``) at the arena shapes of the
 benchmark's cells, microseconds a layer call with the arenas carried as the
@@ -453,7 +457,7 @@ MOE_ROWS = (4, 1024)
 def check_moe_kernel(cfg, rows: int, backend: str, seed: int = 0) -> float:
     """The expert product of ``ops/moe.py`` through ``backend`` ("kernel" on
     the chip, "interpret" under pytest) and through its XLA path on the same
-    seeded int8 experts — a stack of two layers read at the second, a third
+    seeded int8 experts — a stack of ``MOE_DEPTH`` layers read at the last, a third
     of the rows dead; max |difference| relative to the output's scale."""
     import numpy as np
     import jax
@@ -464,7 +468,7 @@ def check_moe_kernel(cfg, rows: int, backend: str, seed: int = 0) -> float:
     from llm_sharding_tpu.ops.quant import QTensor
 
     H, F = cfg.hidden_size, cfg.intermediate_size
-    E, k, L = cfg.num_experts, cfg.num_experts_per_tok, 2
+    E, k, L = cfg.num_experts, cfg.num_experts_per_tok, MOE_DEPTH
     ks = jax.random.split(jax.random.key(seed * 7919 + rows), 9)
     dt = jnp.bfloat16
 
@@ -488,7 +492,7 @@ def check_moe_kernel(cfg, rows: int, backend: str, seed: int = 0) -> float:
     def run(x, live, router, wg, wu, wd, how):
         w, ids = moe.route(x, router, k, cfg.norm_topk_prob)
         return moe.expert_mlp(
-            x, w, ids, wg, wu, wd, E, live=live, layer=jnp.int32(1),
+            x, w, ids, wg, wu, wd, E, live=live, layer=jnp.int32(L - 1),
             backend=how,
         )
 
@@ -509,8 +513,12 @@ MOE_TIMED = (
     {"name": "olmoe_1b_7b", "H": 2048, "F": 1024, "E": 64, "held": None},
     {"name": "gigachat31_702b_a36b", "H": 7168, "F": 2048, "E": 256,
      "held": (0, 16)},
+    {"name": "keye_vl2_30b_a3b", "H": 2048, "F": 768, "E": 128, "held": None},
 )
 MOE_MET = (0, 1, 8)
+#: layers of the stacks the expert calls are checked and timed on: NOT whole
+#: sublane tiles of scale rows, as the benchmark's stages are not (12, 9, ..)
+MOE_DEPTH = 9
 
 
 def time_moe(shape: dict, met: int, backend: str, calls: int = 64,
@@ -520,7 +528,7 @@ def time_moe(shape: dict, met: int, backend: str, calls: int = 64,
     (the rest of them repeat the first, or fall on experts held elsewhere;
     0: no row routes) — the tiles' building, the kernel and the combine, as
     a decode step pays them. ``calls`` calls inside ONE program over a stack
-    of two layers, each call's input hanging on the one before, warmed up,
+    of ``MOE_DEPTH`` layers, each call's input hanging on the one before, warmed up,
     best of three."""
     import jax
     import jax.numpy as jnp
@@ -529,7 +537,7 @@ def time_moe(shape: dict, met: int, backend: str, calls: int = 64,
     from llm_sharding_tpu.ops import moe
     from llm_sharding_tpu.ops.quant import QTensor
 
-    H, F, E, L = shape["H"], shape["F"], shape["E"], 2
+    H, F, E, L = shape["H"], shape["F"], shape["E"], MOE_DEPTH
     first, count = shape["held"] or (0, E)
     ks = jax.random.split(jax.random.key(met), 5)
     dt = jnp.bfloat16
@@ -577,6 +585,108 @@ def time_moe(shape: dict, met: int, backend: str, calls: int = 64,
     return round(best_of_three(run, operands) / calls * 1e6, 2)
 
 
+def time_moe_parts(shape: dict, backend: str, calls: int = 64,
+                   k: int = 8, met: int = 8) -> dict:
+    """Microseconds per call of the PARTS of the decode call ``time_moe``
+    times whole (one live row of four that meets ``met`` held experts), each
+    alone in a loop of its own, ``calls`` calls in one program, each call's
+    input hanging on the one before; ``loop`` is that loop around nothing,
+    what every other part's reading holds of it. ``tiles`` — what
+    ``expert_mlp`` computes before its kernel (``moe.live_pairs``: the dead
+    rows' ids masked, the pairs an expert has); ``kernel`` — ``expert_decode_tpu`` on counts
+    built once outside the loop. And what the call paid BESIDE its kernel
+    until PR 63, which the kernel now does inside, as the plain XLA they
+    were: ``scales`` — two ``dynamic_index_in_dim`` slices of ``[L, E·F]``
+    scale stacks; ``combine`` — the ``where`` over the live tiles of a ``[NT,
+    8, H]`` float32 output, the ``einsum`` with the router weights and
+    ``we_down``'s scale."""
+    import jax
+    import jax.numpy as jnp
+
+    import llm_sharding_tpu.models  # noqa: F401 — ops import through models
+    from llm_sharding_tpu.ops import moe
+
+    H, F, E, L = shape["H"], shape["F"], shape["E"], MOE_DEPTH
+    first, count = shape["held"] or (0, E)
+    ks = jax.random.split(jax.random.key(met), 5)
+    dt, f32 = jnp.bfloat16, jnp.float32
+
+    def codes(key, rows, cols):
+        return jax.random.randint(key, (L, rows, cols), -127, 128, jnp.int8)
+
+    wg, wu = codes(ks[0], H, count * F), codes(ks[1], H, count * F)
+    wd = codes(ks[2], count * F, H)
+    sg = jnp.full((L, count * F), H ** -0.5 / 64.0, dt)
+    su = jnp.full((L, count * F), H ** -0.5 / 32.0, dt)
+    sd = jnp.full((L, H), F ** -0.5 / 64.0, dt)
+    x = jax.random.normal(ks[3], (4, H), f32).astype(dt)
+    ids = jnp.asarray(
+        [[j if j < met else (0 if shape["held"] is None else count)
+          for j in range(k)]] * 4, jnp.int32
+    )  # (relative to the first held expert; ``count``: held elsewhere)
+    w = jnp.where(ids < count, 1.0 / k, 0.0).astype(f32)
+    live = jnp.asarray([met > 0, False, False, False])
+    layers = jnp.arange(calls, dtype=jnp.int32) % L
+
+    masked, counts = moe.live_pairs(ids, live, count)
+    tiles, cw, _ = jax.jit(moe._decode_tiles, static_argnums=4)(
+        x, w, ids, live, count
+    )
+    y = jax.random.normal(ks[4], (cw.shape[0], 8, H), f32)
+    # (the weights are arguments: closed over they would be baked into each
+    # program as constants)
+    operands = (wg, wu, wd, sg, su, sd, y)
+
+    # each part: (carry, layer, operands) -> f32 that hangs on what the part
+    # computed
+    def part_loop(tied, layer, _):
+        return (layer + tied.astype(jnp.int32)).astype(f32)
+
+    def part_tiles(tied, layer, _):
+        m, c = moe.live_pairs(ids + tied.astype(jnp.int32), live, count)
+        return (m.sum() + c.sum()).astype(f32)
+
+    def part_kernel(tied, layer, ops):
+        return moe.expert_decode_tpu(
+            x + tied.astype(dt), w, masked, counts, layer, *ops[:6],
+            interpret=backend == "interpret",
+        ).sum()
+
+    def part_scales(tied, layer, ops):
+        lyr = layer + tied.astype(jnp.int32)
+        rows = jax.lax.optimization_barrier(tuple(
+            jax.lax.dynamic_index_in_dim(s, lyr, keepdims=True)
+            for s in ops[3:5]
+        ))
+        return sum(r[0, 0].astype(f32) for r in rows)
+
+    def part_combine(tied, layer, ops):
+        sd, y = ops[5:]
+        alive = jnp.arange(cw.shape[0], dtype=jnp.int32) < tiles.n_live
+        out = jnp.einsum(
+            "jn,jnh->nh", cw + tied,
+            jnp.where(alive[:, None, None], y[:, :4], 0.0),
+            precision=jax.lax.Precision.HIGHEST,
+        ) * jax.lax.dynamic_index_in_dim(sd, layer, keepdims=False).astype(f32)
+        return out.astype(dt).astype(f32).sum()
+
+    def timed(part):
+        @jax.jit
+        def run(layers, ops):
+            def one(total, layer):
+                return total + part(total * 0.0, layer, ops), None
+            return jax.lax.scan(one, f32(0), layers)[0]
+
+        run(layers, operands).block_until_ready()
+        return round(
+            best_of_three(run, (layers, operands)) / calls * 1e6, 2
+        )
+
+    return {"loop": timed(part_loop), "tiles": timed(part_tiles),
+            "kernel": timed(part_kernel), "scales": timed(part_scales),
+            "combine": timed(part_combine)}
+
+
 def child_moe(spec: dict, out_path: str) -> None:
     import jax
 
@@ -598,9 +708,13 @@ def child_moe(spec: dict, out_path: str) -> None:
     timed = []
     for shape in MOE_TIMED:
         us = {met: time_moe(shape, met, "kernel") for met in MOE_MET}
-        timed.append({"shape": shape["name"], "us_per_call": us})
+        parts = time_moe_parts(shape, "kernel")
+        timed.append({"shape": shape["name"], "us_per_call": us,
+                      "parts_us": parts})
         print(f"[moe] moe call {shape['name']}: "
-              + ", ".join(f"{m} met {u} us" for m, u in us.items()),
+              + ", ".join(f"{m} met {u} us" for m, u in us.items())
+              + "; of 8 met: "
+              + ", ".join(f"{p} {u} us" for p, u in parts.items()),
               flush=True)
     with open(out_path, "w") as f:
         json.dump({"device": device_report(), "kernels": results,

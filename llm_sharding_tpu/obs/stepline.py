@@ -155,8 +155,9 @@ SCOPES = (
     "o_proj",     # output projection, its psum, the residual add
     "mlp",        # gated MLP, its psum, the residual add
     "router",     # a sparse MLP's router: float32 logits, softmax, top-k
-    "moe",        # a sparse MLP's expert product: tiles, the expert kernel
-                  # (``moe_experts``), the weighted sum, its counters
+    "moe",        # a sparse MLP's expert product: the pairs an expert
+                  # has, the expert kernel (``moe_experts``; a decode call
+                  # sums the rows' weighted outputs inside it), its counters
     "zero_expert",  # the zero-compute experts' term (the sum of the chosen
                   # identity experts' weights a token, times the expert
                   # path's input) and the add that joins a shortcut layer's

@@ -18,19 +18,34 @@ is the ``k`` largest of ``p + bias`` and the weights stay the UNbiased ``p``
 there, times a ``scale``; the router's width may then be ``E + Z``: ``E``
 real experts and, after them, ``Z`` zero-compute ones (below).
 
-**The expert product** (``expert_mlp``) runs as row TILES, each tile one
-expert: ``y_tile = (silu(x_tile · Wg_e) ⊙ (x_tile · Wu_e)) · Wd_e``. Two
-regimes build the tiles, one kernel runs them:
+**The expert product** (``expert_mlp``): ``y = (silu(x · Wg_e) ⊙ (x · Wu_e))
+· Wd_e`` for each chosen expert, weighed and summed a row. Two regimes, chosen
+by the static row count, each with a kernel of its own — they want opposite
+things:
 
-- *decode* (a handful of rows): one tile per DISTINCT expert the live rows
-  chose, every tile holding all the rows; the rows' outputs are then summed
-  with their router weights (zero where a row did not choose the expert).
-  Each distinct expert of a layer is read once.
+- *decode* (a handful of rows, ``N <= DECODE_ROWS_MAX``): every DISTINCT
+  expert the live rows chose is read once, multiplied with ALL the rows, and
+  its output added to the rows' sum with their router weights (zero where a
+  row did not choose it). ONE Pallas call that fetches by hand
+  (``expert_decode_tpu``): every operand stays in HBM; the body walks the
+  experts that have a pair in ascending id and copies each block — a chunk
+  of the expert's width (``decode_chunk``) of the three leaves and the same
+  columns of the sublane tile of scale rows around the layer's — into a ring
+  of VMEM slots, the next block's copies in flight while this one is multiplied, the
+  walk's first copies started before anything else. The weighted sum runs in
+  the kernel, in float32, in that one order, into one resident ``[N, H]``
+  block that ``we_down``'s scale row multiplies at the end. Beside the call
+  XLA masks the dead rows' ids and counts the pairs an expert has (the
+  counters need them anyway). Until PR 63 this regime shared the grouped
+  kernel: two XLA slices of the scale stacks before it, a sort over ``E`` and
+  two gathers to list the tiles, a ``[NT, 8, H]`` tile output and an
+  ``einsum`` after it — 11 us a call beside a 54 us kernel on the chip.
 - *prefill* (hundreds of positions): (token, expert) pairs sorted by expert
   and padded per expert to whole tiles of ``TILE_ROWS`` — a grouped matmul;
-  consecutive tiles of one expert reuse the fetched weights.
+  consecutive tiles of one expert reuse the fetched weights, which the
+  pipeline's revisit rule gives for nothing (``expert_tiles_tpu``).
 
-The Pallas kernel takes the whole LAYER-STACKED weights and reads the
+The GROUPED kernel takes the whole LAYER-STACKED weights and reads the
 ``(H, F)`` / ``(F, H)`` int8 tiles of expert ``tile_expert[i]`` of layer
 ``layer`` through scalar-prefetched indices — the stack is never sliced.
 Its grid is ``(live tiles, slices of an expert's width)`` and the FIRST
@@ -39,15 +54,15 @@ extent is traced: a call walks its ``n_live`` tiles and ends where they do
 nothing is fetched: a tile is skipped by not being in the grid). Two rules
 follow. The pipeline evaluates the index maps one step AHEAD of the one it
 runs, so ``Tiles.expert`` and ``Tiles.row`` hold one entry more than the
-most tiles there can be. And a tile past ``n_live`` is NOT WRITTEN: both
-combines select the live tiles (or pairs) before they weigh them — zero
-times what a buffer happened to hold is not zero. A call with no live tile
-would still walk one tile's slices and fetch that expert to compute nothing;
-where the leaves hold a SHARE of the experts most calls of a row or two are
-such calls, and the decode regime branches around the kernel for them
-(``lax.cond`` on ``n_live``). The XLA path (``backend="xla"``: the CPU
-tests, and the dense-cache oracle paths) gathers the same tiles and does the
-same arithmetic.
+most tiles there can be. And a tile past ``n_live`` is NOT WRITTEN: the
+combine selects the live pairs before it weighs them — zero times what a
+buffer happened to hold is not zero. The decode call has no such output: it
+walks nothing when no expert has a pair and returns zeros; where the leaves
+hold a SHARE of the experts most calls of a row or two are such calls, and
+the decode regime branches around the kernel for them (``lax.cond``: a call
+launched for nothing still costs its launch). The XLA path (``backend="xla"``:
+the CPU tests, and the dense-cache oracle paths) gathers the same experts and
+does the same arithmetic, one tile per distinct expert in the decode regime.
 
 **Dead rows and pad positions route nowhere** (``live``): they form no pair,
 are neither read for nor counted, and get a zero MLP output. No token is
@@ -217,9 +232,10 @@ class Tiles(NamedTuple):
 
 
 def _decode_tiles(x, w, ids, live, E):
-    """One tile per distinct expert of the live rows, all rows in each.
-    Returns ``(tiles, combine [NT, N] f32, counts [E])``. An id of ``E`` (an
-    expert held elsewhere, ``expert_mlp(held=)``) matches no tile."""
+    """The XLA path's decode regime: one tile per distinct expert of the live
+    rows, all rows in each. Returns ``(tiles, combine [NT, N] f32, counts
+    [E])``. An id of ``E`` (an expert held elsewhere, ``expert_mlp(held=)``)
+    matches no tile."""
     N, H = x.shape
     k = ids.shape[1]
     onehot = (ids[:, :, None] == jnp.arange(E, dtype=jnp.int32)) & live[
@@ -234,9 +250,7 @@ def _decode_tiles(x, w, ids, live, E):
     order = jnp.argsort(~hit, stable=True).astype(jnp.int32)
     expert = order[jnp.minimum(jnp.arange(NT + 1, dtype=jnp.int32), E - 1)]
     cw = comb.T[expert[:NT]]  # [NT, N]; no row chose a tile past n_live: 0
-    pad = -N % 8
-    xt = jnp.pad(x, ((0, pad), (0, 0)))[None]  # [1, N + pad, H]
-    tiles = Tiles(xt, jnp.zeros((NT + 1,), jnp.int32), expert, n_live)
+    tiles = Tiles(x[None], jnp.zeros((NT + 1,), jnp.int32), expert, n_live)
     return tiles, cw, counts
 
 
@@ -472,6 +486,286 @@ def expert_tiles_tpu(tiles: Tiles, layer, wg, wu, wd, sg, su, *, E: int,
     )
 
 
+#: VMEM slots of the decode call's ring: a block being multiplied and the
+#: next one's copies in flight (a third slot gave nothing on the chip: a
+#: block's copies take longer than its conversion and dots, so one block in
+#: flight keeps the queue fed)
+DECODE_SLOTS = 2
+#: bytes of one int8 leaf's part of a block of that ring
+DECODE_BLOCK_BYTES = 512 * 1024
+
+
+def decode_chunk(hidden: int, width: int) -> int:
+    """Columns of an expert's ``width`` one block of the decode call holds:
+    the largest multiple of 128 that divides the width and keeps an ``H x
+    chunk`` int8 block at most ``DECODE_BLOCK_BYTES`` — 256 at ``H`` 2,048
+    (OLMoE's 1,024 in four blocks, Keye's 768 in three), 384 of ``nemotron_h``'s
+    2,688 at ``H`` 1,024 — and at least 128 (``H`` 4,096 and over); the
+    whole width where that is no multiple of 128 (toy shapes). Finer than
+    the grouped kernel's ``f_tile``: what nothing overlaps in a call is its
+    LAST block's conversion and dots, and a by-hand copy costs next to
+    nothing to start (the kernel alone on the chip, 8 experts met, two slots:
+    Keye 67.5 us at 768 columns, 65.8 at 256; OLMoE 85.1 at 512, 81.6 at
+    256; GigaChat's 6 of 16 held 386.1 at 256, 366.7 at 128: ``PERF.md``,
+    PR 63)."""
+    most = max(128, DECODE_BLOCK_BYTES // hidden // 128 * 128)
+    for n in range(min(most, width) // 128, 0, -1):
+        if width % (128 * n) == 0:
+            return 128 * n
+    return width
+
+
+#: rows of a sublane tile of an array in HBM: a copy slices rows by whole
+#: tiles (Mosaic: "slice shape must be aligned to tiling (8)")
+SUBLANES = 8
+
+
+def _decode_kernel(lyr_ref, cnt_ref, x_hbm, w_hbm, ids_hbm, *refs, gated: bool,
+                   n_f: int):
+    """The decode regime's expert product, ONE invocation: walk the experts
+    that have a pair (``cnt_ref [E]`` > 0) in ascending id, an expert's width
+    in ``n_f`` chunks; fetch each block BY HAND — the chunk's ``(H, fc)``
+    columns of the gate and up leaves, its ``(fc, H)`` rows of the down leaf
+    and the same columns of a few rows of the scale stacks around the
+    layer's, all read where they lie in HBM — into a ring of VMEM slots, the
+    next blocks' copies in flight while this one is multiplied; weigh an
+    expert's output by the rows' router weights for it and add it to the one
+    resident ``[rows, H]`` float32 block, which ``we_down``'s scale row
+    multiplies at the end."""
+    n_w = 2 if gated else 1  # leaves read by columns: (gate,) up
+    cols, wd_hbm = refs[:n_w], refs[n_w]
+    scales, sd_hbm = refs[n_w + 1:2 * n_w + 1], refs[2 * n_w + 1]
+    out_ref = refs[2 * n_w + 2]
+    xbuf, wbuf, idbuf = refs[2 * n_w + 3:2 * n_w + 6]
+    scratch = refs[2 * n_w + 6:]
+    colbufs, dbuf = scratch[:n_w], scratch[n_w]
+    sbufs, sdbuf = scratch[n_w + 1:2 * n_w + 1], scratch[2 * n_w + 1]
+    acc_ref, sem, small_sem = scratch[2 * n_w + 2:]
+    E = cnt_ref.shape[0]
+    F = wd_hbm.shape[1] // E
+    nslot, fc = dbuf.shape[0], dbuf.shape[1]
+    f32 = jnp.float32
+    layer = lyr_ref[0]
+
+    # the sublane tile of rows the layer's row of a scale stack lies in: what
+    # a copy brings of one, the row picked out of it in VMEM
+    tile_row = pl.multiple_of(layer // SUBLANES * SUBLANES, SUBLANES)
+    tile_rows = pl.ds(tile_row, SUBLANES)
+
+    def pick(tile):
+        """The layer's row of a fetched tile of scale rows, ``[1, n]`` f32
+        (a select: the stack may end inside the tile, and what lies past its
+        end is whatever the memory holds)."""
+        t = tile.astype(f32)
+        at = jax.lax.broadcasted_iota(jnp.int32, t.shape, 0)
+        return jnp.sum(
+            jnp.where(at == layer - tile_row, t, 0.0), axis=0, keepdims=True
+        )
+
+    def next_hit(e):
+        """The first expert at or after ``e`` that has a pair; ``E``: none."""
+        def missed(r):
+            return (r < E) & (cnt_ref[jnp.minimum(r, E - 1)] <= 0)
+        return jax.lax.while_loop(missed, lambda r: r + 1, e)
+
+    def advance(pos):
+        """The block after ``pos = (expert, chunk)`` in the walk."""
+        e, f = pos
+        if n_f == 1:
+            return next_hit(jnp.minimum(e + 1, E)), f
+        last = f + 1 >= n_f  # (the walk over ``cnt_ref`` only then)
+        return (
+            jax.lax.cond(
+                last, lambda: next_hit(jnp.minimum(e + 1, E)), lambda: e
+            ),
+            jnp.where(last, 0, f + 1),
+        )
+
+    def copies(slot, e=0, f=0):
+        """``(columns' and scales' copies, the down rows' copy)`` of chunk
+        ``f`` of expert ``e`` into ``slot`` (the defaults: the same copies
+        to WAIT on — a wait reads its copy's size and semaphore only)."""
+        c0 = e * F + f * fc
+        if F % 128 == 0 and fc % 128 == 0 and not isinstance(c0, int):
+            c0 = pl.multiple_of(c0, 128)
+        first = [
+            pltpu.make_async_copy(
+                s.at[tile_rows, pl.ds(c0, fc)], b.at[slot],
+                sem.at[slot, 0],
+            ) for s, b in zip(scales, sbufs)
+        ] + [
+            pltpu.make_async_copy(
+                w.at[layer, :, pl.ds(c0, fc)], b.at[slot], sem.at[slot, 0]
+            ) for w, b in zip(cols, colbufs)
+        ]
+        down = pltpu.make_async_copy(
+            wd_hbm.at[layer, pl.ds(c0, fc), :], dbuf.at[slot], sem.at[slot, 1]
+        )
+        return first, down
+
+    def fetch(slot, pos):
+        @pl.when(pos[0] < E)
+        def _():
+            first, down = copies(slot, *pos)
+            for cp in (*first, down):
+                cp.start()
+
+    # the walk's first blocks are asked for before anything else happens
+    ahead = [(next_hit(jnp.int32(0)), jnp.int32(0))]
+    fetch(0, ahead[0])
+    for slot in range(1, nslot - 1):
+        ahead.append(advance(ahead[-1]))
+        fetch(slot, ahead[-1])
+    ahead.append(advance(ahead[-1]))  # the next block to ask for
+    small = [
+        pltpu.make_async_copy(src, dst, small_sem.at[i])
+        for i, (src, dst) in enumerate((
+            (x_hbm, xbuf), (w_hbm, wbuf), (ids_hbm, idbuf),
+            (sd_hbm.at[tile_rows], sdbuf),
+        ))
+    ]
+    for cp in small:
+        cp.start()
+    out_ref[...] = jnp.zeros_like(out_ref)
+    for cp in small:
+        cp.wait()
+
+    def block(carry):
+        """One block of the walk: ask for the block ``nslot - 1`` ahead (its
+        slot is the one the block before this one was multiplied from), then
+        wait for this one's copies and multiply."""
+        slot, *flat = carry
+        pos = list(zip(flat[0::2], flat[1::2]))
+        e, f = pos[0]
+        fetch((slot + nslot - 1) % nslot, pos[-1])
+        first, down = copies(slot)
+        for cp in first:
+            cp.wait()
+        x = xbuf[...]
+        u = jnp.dot(
+            x, colbufs[-1][slot].astype(x.dtype), preferred_element_type=f32
+        ) * pick(sbufs[-1][slot])
+        if gated:
+            g = jnp.dot(
+                x, colbufs[0][slot].astype(x.dtype),
+                preferred_element_type=f32,
+            ) * pick(sbufs[0][slot])
+            a = (g * jax.nn.sigmoid(g) * u).astype(x.dtype)
+        else:
+            u = jnp.maximum(u, 0.0)
+            a = (u * u).astype(x.dtype)
+        down.wait()
+        y = jnp.dot(
+            a, dbuf[slot].astype(x.dtype), preferred_element_type=f32
+        )
+
+        def weighed(y):
+            """``y`` times each row's router weight for expert ``e`` (zero
+            for a row that did not choose it)."""
+            return y * jnp.sum(
+                jnp.where(idbuf[...] == e, wbuf[...], 0.0), axis=1,
+                keepdims=True,
+            )
+
+        if n_f == 1:
+            out_ref[...] += weighed(y)
+        else:
+            @pl.when(f == 0)
+            def _first():
+                acc_ref[...] = y
+
+            @pl.when(f > 0)
+            def _more():
+                acc_ref[...] += y
+
+            @pl.when(f == n_f - 1)
+            def _whole():
+                out_ref[...] += weighed(acc_ref[...])
+
+        pos = pos[1:] + [advance(pos[-1])]
+        return ((slot + 1) % nslot, *(v for p in pos for v in p))
+
+    jax.lax.while_loop(
+        lambda carry: carry[1] < E, block,
+        (jnp.int32(0), *(v for p in ahead for v in p)),
+    )
+    # we_down's scale: one per output channel, every expert's alike
+    out_ref[...] = out_ref[...] * pick(sdbuf[...])
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "act"))
+def expert_decode_tpu(x, w, ids, counts, layer, wg, wu, wd, sg, su, sd, *,
+                      interpret: bool = False, act: str = "silu"):
+    """The decode regime as ONE Pallas call (``_decode_kernel``): ``x [n,
+    H]``, the rows' router weights ``w [n, k]`` f32 and ids ``[n, k]`` (``E``
+    for a pair that adds nothing: a dead row's, an expert's held elsewhere),
+    ``counts [E]`` the pairs an expert has → ``[n, H]`` float32: ``Σ_e w_e ·
+    MLP_e(x) · scale_down`` over the experts with a pair, in ascending id.
+    Every array operand stays in HBM (``memory_space=pl.ANY``) and the body
+    copies what it reads: the layer-stacked leaves ``[L, H, E·F]`` / ``[L,
+    E·F, H]`` and the ``[L, E·F]`` / ``[L, H]`` scale stacks are read where
+    they lie, never sliced. A block of the ring holds ``decode_chunk``
+    columns of an expert's width."""
+    n, H = x.shape
+    E = counts.shape[0]
+    gated = act != "relu2"
+    F = wu.shape[-1] // E
+    fc, slots = decode_chunk(H, F), DECODE_SLOTS
+
+    def whole_tiles(s):
+        """A ``[L, n]`` scale stack as the body may slice it: by sublane
+        tiles of rows. On the chip an array over four rows deep IS whole
+        tiles of eight — the rows past a stack's depth are there, and never
+        picked — so the stored stack goes in as it is; the interpreter's
+        arrays end where the stack does, and an array of up to four rows (a
+        stack of one: 2-D leaves) is tiled by 1, 2 or 4 on the chip: those
+        are handed over padded."""
+        short = -s.shape[0] % SUBLANES
+        if short and (interpret or s.shape[0] <= SUBLANES // 2):
+            s = jnp.pad(s, ((0, short), (0, 0)))
+        return s
+
+    col_leaves = (wg, wu) if gated else (wu,)
+    col_scales = tuple(whole_tiles(s) for s in ((sg, su) if gated else (su,)))
+    sd = whole_tiles(sd)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    n_in = 3 + 2 * len(col_leaves) + 2
+    return pl.pallas_call(
+        functools.partial(_decode_kernel, gated=gated, n_f=F // fc),
+        out_shape=jax.ShapeDtypeStruct((n, H), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(1,),
+            in_specs=[hbm] * n_in,
+            out_specs=pl.BlockSpec((n, H), lambda i, *_: (0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM(x.shape, x.dtype),
+                pltpu.VMEM(w.shape, w.dtype),
+                pltpu.VMEM(ids.shape, ids.dtype),
+                *(pltpu.VMEM((slots, H, fc), q.dtype) for q in col_leaves),
+                pltpu.VMEM((slots, fc, H), wd.dtype),
+                *(
+                    pltpu.VMEM((slots, SUBLANES, fc), s.dtype)
+                    for s in col_scales
+                ),
+                pltpu.VMEM((SUBLANES, H), sd.dtype),
+                pltpu.VMEM((n, H), jnp.float32),
+                pltpu.SemaphoreType.DMA((slots, 2)),
+                pltpu.SemaphoreType.DMA((4,)),
+            ],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
+        interpret=interpret,
+        name="moe_experts",
+    )(
+        jnp.reshape(layer, (1,)).astype(jnp.int32), counts, x, w, ids,
+        *col_leaves, wd, *col_scales, sd,
+    )
+
+
 def resolve_backend(backend: str) -> str:
     """``auto`` follows ``PAGED_FORCE_KERNEL`` like the paged attention ops,
     then the platform: the kernel on a TPU, XLA elsewhere."""
@@ -492,6 +786,54 @@ def resolve_backend(backend: str) -> str:
             "the kernel or 'xla'"
         )
     return backend
+
+
+def live_pairs(ids, live, E):
+    """What XLA computes before the decode call: ``(ids [N, k] with a dead
+    row's pairs turned into id E — as a pair held elsewhere is: it matches no
+    expert, so it is neither counted nor weighed —, counts [E] the pairs an
+    expert has)``."""
+    ids = jnp.where(live[:, None], ids, E)
+    counts = jnp.sum(
+        ids[:, :, None] == jnp.arange(E, dtype=jnp.int32), axis=(0, 1)
+    ).astype(jnp.int32)
+    return ids, counts
+
+
+def _decode_product(x, weights, ids, live, lyr, wg, wu, wd, sg, su, sd, E,
+                    backend, act, branch):
+    """The decode regime: ``(Σ_e w_e · MLP_e(x) · scale_down [N, H] f32,
+    counts [E])``. On the kernel backends one call (``expert_decode_tpu``)
+    that is handed the live pairs an expert and the rows' weights and ids as
+    they are; ``branch`` (the leaves hold a SHARE of the experts) puts a
+    ``lax.cond`` around it — most calls of a row or two then meet none
+    (without a share a live row always meets one), and a call launched for
+    nothing still costs its launch; the branch costs a call that runs ~2
+    us. The XLA path builds one tile per distinct expert and gathers."""
+    N, H = x.shape
+    if backend == "xla":
+        tiles, cw, counts = _decode_tiles(x, weights, ids, live, E)
+        y = _tiles_xla(tiles, lyr, wg, wu, wd, sg, su, E, jnp.float32, act)
+        out = jnp.einsum(
+            "jn,jnh->nh", cw, y, precision=jax.lax.Precision.HIGHEST
+        )  # (the XLA tiles past n_live are zeros)
+        return out * jax.lax.dynamic_index_in_dim(
+            sd, lyr, keepdims=False
+        ).astype(jnp.float32), counts
+    ids, counts = live_pairs(ids, live, E)
+
+    def product():
+        return expert_decode_tpu(
+            x, weights, ids, counts, lyr, wg, wu, wd, sg, su, sd,
+            interpret=backend == "interpret", act=act,
+        )
+
+    if not branch:
+        return product(), counts
+    return jax.lax.cond(
+        jnp.sum(counts > 0) > 0, product,  # (the counters' own sum)
+        lambda: jnp.zeros((N, H), jnp.float32),
+    ), counts
 
 
 def expert_mlp(
@@ -554,53 +896,30 @@ def expert_mlp(
     decode = N <= DECODE_ROWS_MAX
     with jax.named_scope("moe"):
         if decode:
-            tiles, cw, counts = _decode_tiles(x, weights, ids, live, E)
+            out, counts = _decode_product(
+                x, weights, ids, live, lyr, wg, wu, wd, sg, su, sd, E,
+                backend, act, branch=routed is not None,
+            )
         else:
             tiles, pos, counts = _grouped_tiles(x, ids, live, E, TILE_ROWS)
-        out_dtype = jnp.float32 if decode else x.dtype
-
-        def product():
             if backend == "xla":
-                return _tiles_xla(
-                    tiles, lyr, wg, wu, wd, sg, su, E, out_dtype, act
-                )
-            return expert_tiles_tpu(
-                tiles, lyr, wg, wu, wd, sg, su, E=E, out_dtype=out_dtype,
-                interpret=backend == "interpret", act=act,
-            )
-
-        # the kernel leaves the tiles past n_live unwritten: select what is
-        # live, THEN weigh it (0 x whatever the buffer held is not 0)
-        if decode:
-            def combined():
-                alive = jnp.arange(cw.shape[0], dtype=jnp.int32) < tiles.n_live
-                return jnp.einsum(
-                    "jn,jnh->nh", cw,
-                    jnp.where(alive[:, None, None], product()[:, :N], 0.0),
-                    precision=jax.lax.Precision.HIGHEST,
-                )
-
-            if routed is None:
-                out = combined()
+                y = _tiles_xla(tiles, lyr, wg, wu, wd, sg, su, E, x.dtype, act)
             else:
-                # a share of the experts: most calls of a row or two meet
-                # none of them (without a share a live row always meets
-                # one), and a call launched for nothing still fetches an
-                # expert; the branch costs a call that runs ~2 us
-                out = jax.lax.cond(
-                    tiles.n_live > 0, combined,
-                    lambda: jnp.zeros((N, H), jnp.float32),
+                y = expert_tiles_tpu(
+                    tiles, lyr, wg, wu, wd, sg, su, E=E, out_dtype=x.dtype,
+                    interpret=backend == "interpret", act=act,
                 )
-        else:
-            picked = product().reshape(-1, H)[pos].astype(jnp.float32)
+            # the kernel leaves the tiles past n_live unwritten: select what
+            # is live, THEN weigh it (0 x whatever the buffer held is not 0)
+            picked = y.reshape(-1, H)[pos].astype(jnp.float32)
             paired = (live[:, None] & (ids < E))[:, :, None]  # in a live tile
             out = jnp.sum(
                 jnp.where(paired, picked * weights[:, :, None], 0.0), axis=1
             )  # [N, k, H] → [N, H]
-        # we_down's scale: one per output channel, every expert's alike
-        out = out * jax.lax.dynamic_index_in_dim(
-            sd, lyr, keepdims=False
-        ).astype(jnp.float32)
+            # we_down's scale: one per output channel, every expert's alike
+            out = out * jax.lax.dynamic_index_in_dim(
+                sd, lyr, keepdims=False
+            ).astype(jnp.float32)
         if zero_from is not None:
             with jax.named_scope("zero_expert"):
                 out = out + zero_w[:, None] * x.astype(jnp.float32)

@@ -112,6 +112,22 @@ def test_expert_call_timing_meets_the_experts_it_says(met, held):
     assert chip_smoke.time_moe(shape, met, "interpret", calls=2) > 0
 
 
+@pytest.mark.parametrize("held", [None, (0, 8)])
+def test_expert_call_parts_are_timed_each_alone(held):
+    """``chip_smoke.py --moe``'s split of a decode call at toy widths (PR
+    63): the loop around nothing, what ``expert_mlp`` computes before its
+    kernel, the kernel on counts built outside the loop, and the two XLA
+    parts the kernel took in (the scale slices, the combine) — a CPU time is
+    no speed; and Keye's shape is timed beside OLMoE's and GigaChat's."""
+    shape = {"name": "toy", "H": 64, "F": 128, "E": 16, "held": held}
+    parts = chip_smoke.time_moe_parts(shape, "interpret", calls=2)
+    assert list(parts) == ["loop", "tiles", "kernel", "scales", "combine"]
+    assert all(us > 0 for us in parts.values())
+    keye = [s for s in chip_smoke.MOE_TIMED if s["name"] == "keye_vl2_30b_a3b"]
+    assert keye == [{"name": "keye_vl2_30b_a3b", "H": 2048, "F": 768,
+                     "E": 128, "held": None}]
+
+
 #: ``--kv-write``'s shapes at toy widths: key and value alike, a latent
 #: arena, keys wider than values over a pool too small to give every row
 #: blocks of its own (MiMo's window pool)

@@ -124,15 +124,18 @@ def test_expert_product_equals_the_dense_form(
         assert np.asarray(out).any() == (read > 0)
 
 
-@pytest.mark.parametrize("N", [4, 100])  # decode tiles, grouped tiles
+@pytest.mark.parametrize("N", [100])  # grouped tiles (decode: the ring, below)
 @pytest.mark.parametrize("poison", [np.nan, np.inf, 3e38])
 def test_what_lies_past_the_live_tiles_never_reaches_the_output(
     N, poison, monkeypatch
 ):
-    """The kernel writes the live tiles only. Whatever the rest of its
-    output buffer holds — here every unwritten tile is overwritten with
+    """The grouped kernel writes the live tiles only. Whatever the rest of
+    its output buffer holds — here every unwritten tile is overwritten with
     ``poison`` between the kernel and the combine, over whatever interpret
-    mode left there — the output is finite and the dense form's."""
+    mode left there — the output is finite and the dense form's. (The decode
+    call has no tile output since PR 63: what it must not read is its ring
+    of VMEM buffers as the call before left them —
+    ``test_the_decode_call_reads_nothing_its_ring_held_before``.)"""
     E, k = 16, 2
     x, router, live, given, plain = make(N, N, E, 32, 64, 2, False)
     w, ids = moe.route(x, router, k)
@@ -231,17 +234,8 @@ def test_backend_names():
 
 def plain_loop_relu2(x, weights, ids, wu, wd, E, live, held=None):
     """Σ over the chosen experts, one expert at a time, in plain numpy."""
-    first, count = held or (0, E)
-    x, wu, wd = (np.asarray(a, np.float64) for a in (x, wu, wd))
-    F = wu.shape[-1] // count
-    out = np.zeros((x.shape[0], wd.shape[-1]))
-    for n in np.flatnonzero(np.asarray(live)):
-        for w, e in zip(np.asarray(weights[n]), np.asarray(ids[n])):
-            if first <= e < first + count:
-                j = e - first
-                a = np.maximum(x[n] @ wu[:, j * F:(j + 1) * F], 0.0) ** 2
-                out[n] += w * (a @ wd[j * F:(j + 1) * F])
-    return out
+    return plain_loop(
+        x, weights, ids, None, wu, wd, live, held or (0, E), "relu2", None)
 
 
 @pytest.mark.parametrize("backend", ["xla", "interpret"])
@@ -296,6 +290,18 @@ def test_an_activation_goes_with_its_leaves():
         moe.expert_mlp(x, w, ids, *given, num_experts=8, act="gelu")
 
 
+@pytest.mark.parametrize("hidden, width, chunk", [
+    (2048, 1024, 256), (2048, 768, 256), (7168, 2048, 128), (4096, 2048, 128),
+    (6144, 2048, 128), (1024, 2688, 384), (4096, 256, 128),
+    (64, 32, 32), (64, 1024, 1024), (32, 24, 24),  # toy shapes: one block
+])
+def test_the_chunk_of_a_decode_block(hidden, width, chunk):
+    """An ``H x chunk`` int8 block of the decode call's ring is at most half a
+    MiB where 128 columns allow it, and whole chunks make up the width."""
+    assert moe.decode_chunk(hidden, width) == chunk and width % chunk == 0
+    assert hidden * chunk <= max(moe.DECODE_BLOCK_BYTES, hidden * 128)
+
+
 @pytest.mark.parametrize("hidden, width, tile", [
     (2048, 1024, 512), (4096, 2048, 512), (7168, 2048, 256),  # as before
     (64, 32, 32), (4096, 1408, 128),
@@ -306,3 +312,169 @@ def test_the_tile_of_an_experts_width(hidden, width, tile):
     assert moe.f_tile(hidden, width) == tile and width % tile == 0
     if width % min(width, moe.f_chunk(hidden)) == 0:
         assert tile == min(width, moe.f_chunk(hidden))
+
+
+# ---- the decode call (PR 63): one kernel that fetches by hand ---------------
+
+def plain_loop(x, weights, ids, wg, wu, wd, live, held, act, zero_from):
+    """Σ over a live row's chosen experts, one expert at a time, in float64
+    numpy: a held expert's MLP (``act``), the row itself for an id from
+    ``zero_from``, nothing for an expert held elsewhere."""
+    first, count = held
+    x, wu, wd = (np.asarray(a, np.float64) for a in (x, wu, wd))
+    wg = None if wg is None else np.asarray(wg, np.float64)
+    F = wu.shape[-1] // count
+    out = np.zeros((x.shape[0], wd.shape[-1]))
+    for n in np.flatnonzero(np.asarray(live)):
+        for w, e in zip(np.asarray(weights[n]), np.asarray(ids[n])):
+            if zero_from is not None and e >= zero_from:
+                out[n] += w * x[n]
+            elif first <= e < first + count:
+                cols = slice((e - first) * F, (e - first + 1) * F)
+                u = x[n] @ wu[:, cols]
+                if act == "relu2":
+                    a = np.maximum(u, 0.0) ** 2
+                else:
+                    g = x[n] @ wg[:, cols]
+                    a = g / (1.0 + np.exp(-g)) * u
+                out[n] += w * (a @ wd[cols])
+    return out
+
+
+#: a decode call: rows, the router's width, k, an expert's width, hidden, the
+#: stack's depth and the layer read (None: 2-D leaves), the activation, the
+#: share held, where the zero-compute ids start, which rows route and among
+#: which experts, int8
+DECODE = dict(N=4, E=16, k=4, F=32, H=64, L=2, layer=1, act="silu", held=None,
+              zero=None, rows="some", among=None, quant=True, read=None)
+DECODE_CASES = {
+    "one-row": dict(N=1),
+    "four-rows": dict(),
+    "32-rows": dict(N=32, k=2),
+    "32-rows-every-expert": dict(N=32, k=8, rows="all", read=16),
+    "no-tile": dict(rows="none", read=0),
+    "one-tile": dict(k=1, rows="all", among=(5, 6), read=1),
+    "every-tile": dict(rows="all", read=16),
+    "all-held-elsewhere": dict(k=2, among=(0, 8), held=(8, 8), read=0),
+    "a-share": dict(held=(4, 8), rows="all", read=8),
+    "relu2-one-row": dict(N=1, act="relu2", k=3),
+    "relu2-a-share": dict(act="relu2", k=6, held=(4, 4), L=3, layer=2),
+    "relu2-32-rows": dict(N=32, act="relu2", k=3),
+    "relu2-width-24": dict(act="relu2", F=24, H=32, E=8, k=3),
+    "zero-experts": dict(zero=12, k=6),
+    "zero-experts-a-share": dict(zero=12, held=(4, 4), k=6),
+    "zero-experts-only": dict(zero=12, k=2, among=(12, 16)),
+    # an expert's width in two blocks: decode_chunk(4096, 256) = 128
+    "two-chunks": dict(H=4096, F=256, E=4, k=2),
+    "two-chunks-relu2": dict(H=4096, F=256, E=4, k=2, act="relu2", N=3),
+    "raw-weights": dict(quant=False),
+    "unstacked": dict(layer=None),
+    "unstacked-raw": dict(layer=None, quant=False, N=5),
+    # stacks whose depth is not whole sublane tiles of scale rows
+    "depth-2-first": dict(L=2, layer=0),
+    "depth-12-first": dict(L=12, layer=0),
+    "depth-12-middle": dict(L=12, layer=5),
+    "depth-12-last": dict(L=12, layer=11),
+    "depth-17-first": dict(L=17, layer=0),
+    "depth-17-middle": dict(L=17, layer=8),
+    "depth-17-last": dict(L=17, layer=16, N=32, k=2),
+    "depth-16-last": dict(L=16, layer=15),  # whole tiles
+}
+
+
+def decode_case(name):
+    """A case's operands: ``(call(backend) -> (out, stats), the plain loop's
+    output, the counters' (pairs per expert, held experts read))``."""
+    c = dict(DECODE, **DECODE_CASES[name])
+    N, E, k, L, layer = c["N"], c["E"], c["k"], c["L"], c["layer"]
+    real = c["zero"] or E  # the experts with weights, of the router's E
+    held = c["held"] or (0, real)
+    x, _, live, given, plain = make(
+        N + E + k, N, held[1], c["F"], c["H"], L, c["quant"])
+    router = jax.random.normal(jax.random.key(N * E), (c["H"], E), jnp.float32)
+    x, w, ids, live = routing(x, router, k, live, c["rows"], c["among"])
+    if layer is None:  # the last layer's leaves, handed over 2-D
+        given = tuple(
+            QTensor(g.q[-1], g.scale[-1]) if c["quant"] else g[-1]
+            for g in given)
+    at = L - 1 if layer is None else layer
+    relu2 = c["act"] == "relu2"
+
+    def call(backend):
+        return moe.expert_mlp(
+            x, w, ids, None if relu2 else given[0], given[1], given[2],
+            num_experts=E, live=live,
+            layer=None if layer is None else jnp.int32(layer),
+            backend=backend, held=c["held"], act=c["act"],
+            zero_from=c["zero"],
+        )
+
+    want = plain_loop(
+        x, w, ids, None if relu2 else plain[0][at], plain[1][at],
+        plain[2][at], live, held, c["act"], c["zero"])
+    counts = np.zeros(E, int)
+    for n in np.flatnonzero(np.asarray(live)):
+        counts[np.asarray(ids[n])] += 1
+    read = int((counts[held[0]:held[0] + held[1]] > 0).sum())
+    assert c["read"] in (None, read), (c["read"], read)
+    return call, want, (counts, read)
+
+
+@pytest.mark.parametrize("name", sorted(DECODE_CASES))
+def test_the_decode_call_is_the_xla_path_is_the_plain_loop(name):
+    """The decode regime's ONE kernel (emulated) against the XLA path of the
+    same arithmetic and a plain loop over the pairs: rows 1 / 4 / 32, no
+    tile, one, every one, all held elsewhere, both activations, a share,
+    zero-compute ids, an expert's width in two chunks, and the layer's scale
+    rows picked at the first, a middle and the LAST layer of stacks 2, 12, 16
+    and 17 deep (a copy brings whole sublane tiles of rows: the last tile of
+    a stack 12 or 17 deep ends past the stack)."""
+    call, want, (counts, read) = decode_case(name)
+    got, stats = call("interpret")
+    ref, stats_x = call("xla")
+    np.testing.assert_allclose(np.asarray(got), want, atol=5e-5)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5)
+    for st in (stats, stats_x):
+        assert np.array_equal(np.asarray(st.expert_tokens), counts)
+        assert int(st.experts_read) == read
+    if read == 0 and DECODE_CASES[name].get("zero") is None:
+        assert not np.asarray(got).any()
+
+
+@pytest.mark.parametrize("poison", [np.nan, np.inf, 3e38])
+@pytest.mark.parametrize(
+    "name", ["four-rows", "raw-weights", "two-chunks", "depth-12-last"])
+def test_the_decode_call_reads_nothing_its_ring_held_before(
+        name, poison, monkeypatch):
+    """Every VMEM buffer of the decode call — the ring of weight blocks and
+    scale tiles, the rows, the accumulator — starts as ``poison`` (the
+    interpreter hands a kernel its scratch as ``uninitialized_value`` says:
+    the chip hands it what the call before left) and the output is bit for
+    bit what it is over the interpreter's own fill, finite, and the plain
+    loop's: nothing is read that this call did not fetch."""
+    from jax._src.pallas import primitives
+
+    call, want, _ = decode_case(name)
+    clean, _ = call("interpret")
+    fill, planted = primitives.uninitialized_value, []
+
+    def poisoned(shape, dtype):
+        if jnp.issubdtype(dtype, jnp.floating):
+            planted.append(tuple(shape))
+            return jnp.full(shape, poison, dtype)
+        if dtype == jnp.int8:  # a ring of int8 codes
+            planted.append(tuple(shape))
+            return jnp.full(shape, 127, dtype)
+        return fill(shape, dtype)
+
+    monkeypatch.setattr(primitives, "uninitialized_value", poisoned)
+    # the fill is read when the kernel is traced: trace it anew, outside the
+    # jit cache that holds the clean call's program
+    monkeypatch.setattr(
+        moe, "expert_decode_tpu", moe.expert_decode_tpu.__wrapped__)
+    got, _ = call("interpret")
+    slots = moe.DECODE_SLOTS
+    assert any(len(sh) == 3 and sh[0] == slots for sh in planted), planted
+    assert np.isfinite(np.asarray(got)).all()
+    assert np.array_equal(np.asarray(got), np.asarray(clean))
+    np.testing.assert_allclose(np.asarray(got), want, atol=5e-5)

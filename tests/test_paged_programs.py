@@ -471,6 +471,51 @@ def test_a_decode_step_reads_its_weights_as_they_are_stored(
     assert _arena_ops(text) == []
 
 
+@pytest.mark.parametrize("cell", [
+    "olmoe_1b_7b", "gigachat31_702b_a36b", "nemotron3_super_120b_a12b",
+    "keye_vl2_30b_a3b", "longcat_flash_omni"])
+def test_a_decode_expert_call_is_one_kernel(compiled_serve_chunk, cell):
+    """The compiled ``serve_chunk`` of the expert cells (PR 63): under the
+    ``moe`` scope the layer body holds ONE ``moe_experts`` call and, beside
+    it, nothing but the compares, selects and sums that mask the dead rows'
+    ids and count the pairs an expert has — no ``dynamic_index_in_dim`` of a
+    scale stack (the parent sliced two a call, 2.2 us each on the chip), no
+    sort or gather that lists the tiles, no ``[NT, tm, H]`` float32 tile
+    output and no ``einsum`` that weighs it; and no scale stack is re-laid
+    for the call (``[L, E·F]`` rides in where it lies: the kernel copies the
+    sublane tile of rows the layer's row is in)."""
+    text = compiled_serve_chunk(cell)
+    calls = [
+        ln for ln in text.split("\n")
+        if 'custom_call_target="tpu_custom_call"' in ln
+        and "moe_experts/pallas_call" in ln]
+    # one a layer body (``nemotron_h``'s pattern of layer kinds is written
+    # out: seven bodies with experts)
+    assert len(calls) == (7 if cell == "nemotron3_super_120b_a12b" else 1)
+    # (a share's call sits inside the ``cond`` that skips a call that meets
+    # no held expert: ``moe/cond/branch_1_fun/jit(expert_decode_tpu)/..``)
+    assert all(re.search(
+        r"/moe/(cond/\w+/)?jit\(expert_decode_tpu\)/moe_experts", ln)
+        for ln in calls)
+    beside = {
+        op for op in re.findall(r'op_name="[^"]*/moe/([^"]*)"', text)
+        if not op.startswith("zero_expert/") and "/moe_experts/" not in op}
+    assert beside, cell
+    for op in beside:
+        assert not re.search(
+            r"dynamic_slice|dynamic_update_slice|dot_general|sort|gather"
+            r"|pad|transpose|argsort|cumsum", op), (cell, op)
+    # nothing under the scope is a stack of tiles: the kernel's one output
+    # is the rows' [N, H]
+    for ln in text.split("\n"):
+        if "/moe/" in ln and "zero_expert" not in ln:
+            assert not re.search(r"= \(?f32\[\d+,\d+,\d+\]", ln), ln[:200]
+    # and no scale stack (two dims, above 256 K entries) is copied
+    for m in re.finditer(
+            r"= (?:bf16|f32)\[(\d+),(\d+)\]\S* (?:copy|transpose)\(", text):
+        assert int(m.group(1)) * int(m.group(2)) < 1 << 18, m.group(0)
+
+
 def test_a_selecting_decode_step_reads_k_and_v_through_a_kernel_only(
         compiled_serve_chunk):
     """``keye_vl2_30b_a3b``'s compiled ``serve_chunk`` (PR 50): the selection
